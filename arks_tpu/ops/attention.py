@@ -6,7 +6,9 @@ arksapplication_controller.go:941-1014 only builds their command lines).
 Here attention is ours.  Two decode implementations behind one dispatcher:
 
 - ``xla``: batched einsums that tile onto the MXU, masks as fused selects —
-  the portable fallback and the CPU-test oracle.  Reads the full cache.
+  the CPU tests' oracle, and what a shape the kernels cannot take runs
+  under ``auto`` (``kernel_blockers``; the engine labels it so).  Reads
+  the full cache.
 - ``pallas``: ragged flash-decoding kernel (arks_tpu.ops.pallas_attention)
   that reads only each slot's valid KV prefix — the TPU default, since
   decode is HBM-bandwidth-bound.
@@ -24,7 +26,6 @@ Conventions:
 
 from __future__ import annotations
 
-import logging
 import os
 
 import jax
@@ -32,10 +33,7 @@ import jax.numpy as jnp
 
 from arks_tpu.utils import knobs
 
-log = logging.getLogger("arks_tpu.ops.attention")
-
 _NEG_INF = -1e30
-_lane_warned: set[int] = set()
 
 
 def _pad_last(x, d_store: int):
@@ -54,6 +52,47 @@ def default_decode_impl() -> str:
     if impl == "auto":
         return "pallas" if jax.default_backend() == "tpu" else "xla"
     return impl
+
+
+def kernel_blockers(d_store: int, mesh=None, kv_sharded: bool = False,
+                    model_axis: str = "model", *, int4_decode: bool = False,
+                    pp: bool = False) -> list[str]:
+    """Why the Pallas decode kernels cannot serve this shape (empty: they
+    can).  The attention dispatchers below and the engine both decide from
+    this one list, so ``engine_config_info{decode_impl}`` names the path
+    that is traced: the engine raises at construction when the kernels were
+    asked for by name (ARKS_ATTN_IMPL=pallas) and refuses the layouts that
+    need them; under ``auto`` a blocked shape runs, and is labelled, xla.
+
+    ``d_store`` is the STORED head dim (lane-padded caches store 128).
+    ``int4_decode``: an int4 pool on the dedicated decode entry (the engine:
+    int4 KV off the mixed scheduler).  ``pp``: a pipeline-parallel engine,
+    whose per-stage bodies (parallel/pipeline.py) name ``impl="xla"``."""
+    out = []
+    if int4_decode:
+        out.append("int4 KV off the mixed scheduler (there is no "
+                   "standalone int4 decode kernel)")
+    if pp:
+        out.append("pipeline parallelism (per-stage decode runs the XLA "
+                   "path)")
+    # Mosaic tiles the last (lane) dim at 128.  Interpret mode has no such
+    # constraint, so CPU kernel tests still run the kernels at small D.
+    if d_store % 128 and jax.default_backend() == "tpu":
+        out.append(f"stored head_dim {d_store} is not 128-lane aligned")
+    # The kernels are embarrassingly parallel over (batch, kv head) and run
+    # inside shard_map without collectives; replicated KV heads under a
+    # non-trivial model axis need the XLA partitioner instead.
+    if not (kv_sharded or mesh is None
+            or mesh.shape.get(model_axis, 1) == 1):
+        out.append("KV heads do not divide the tensor-parallel axis")
+    return out
+
+
+def _use_pallas(impl: str | None, d_store: int, mesh, kv_sharded: bool,
+                model_axis: str, int4_decode: bool = False) -> bool:
+    return ((impl or default_decode_impl()) == "pallas"
+            and not kernel_blockers(d_store, mesh, kv_sharded, model_axis,
+                                    int4_decode=int4_decode))
 
 
 def _softmax(scores: jnp.ndarray, axis: int) -> jnp.ndarray:
@@ -388,10 +427,7 @@ def paged_mixed_update_and_attend(
         k_new = _pad_last(k_new, d)
         v_new = _pad_last(v_new, d)
     quantized = k_scale is not None
-    impl = impl or default_decode_impl()
-    tp_trivial = mesh is None or mesh.shape.get(model_axis, 1) == 1
-    lane_ok = d % 128 == 0 or jax.default_backend() != "tpu"
-    use_pallas = impl == "pallas" and (kv_sharded or tp_trivial) and lane_ok
+    use_pallas = _use_pallas(impl, d, mesh, kv_sharded, model_axis)
 
     tables_tok = jnp.take(tables, jnp.maximum(token_slot, 0),
                           axis=0)                       # [T, MaxP]
@@ -463,14 +499,13 @@ def paged_mixed_update_and_attend(
                                     seq_pos_start, layer)
         return out.reshape(t_flat, h, d)[..., :d_model], kp, vp, ks, vs
 
-    from arks_tpu.parallel.compat import shard_map
     from jax.sharding import PartitionSpec as P
     model = model_axis if kv_sharded else None
     qspec = P(None, model, None, None)
     kvspec = P(None, model, None)
     pspec = P(None, None, model, None, None)
     sspec = P(None, None, model, None) if quantized else None
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(qspec, kvspec, kvspec, pspec, pspec, sspec, sspec,
                   P(None, None), P(None, None), P(None), P(None), P(None),
@@ -528,14 +563,11 @@ def paged_decode_update_and_attend(
         k_new = _pad_last(k_new, d)
         v_new = _pad_last(v_new, d)
     quantized = k_scale is not None
-    impl = impl or default_decode_impl()
-    tp_trivial = mesh is None or mesh.shape.get(model_axis, 1) == 1
-    lane_ok = d % 128 == 0 or jax.default_backend() != "tpu"
     # int4 pools have no standalone decode kernel (decode traffic rides the
-    # mixed kernel's fused dequant); this dedicated-decode entry falls back
-    # to the XLA oracle — see the fallback matrix in docs.
-    use_pallas = (impl == "pallas" and (kv_sharded or tp_trivial)
-                  and lane_ok and not int4)
+    # mixed kernel's fused dequant): this dedicated-decode entry takes the
+    # XLA oracle, which kernel_blockers names for the engine's label too.
+    use_pallas = _use_pallas(impl, d, mesh, kv_sharded, model_axis,
+                             int4_decode=int4)
     # Inactive slots attend nothing (their stale tables may point at pages
     # other slots now own — reading them is wasted bandwidth at best).
     attend_lens = jnp.where(write_idx >= cover, 0, write_idx + 1)
@@ -583,14 +615,13 @@ def paged_decode_update_and_attend(
                                     attend_lens, layer)
         return out.reshape(b, h, d)[..., :d_model], kp, vp, ks, vs
 
-    from arks_tpu.parallel.compat import shard_map
     from jax.sharding import PartitionSpec as P
     model = model_axis if kv_sharded else None
     qspec = P(None, model, None, None)
     kvspec = P(None, model, None)
     pspec = P(None, None, model, None, None)
     sspec = P(None, None, model, None) if quantized else None
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(qspec, kvspec, kvspec, pspec, pspec, sspec, sspec,
                   P(None, None), P(None), P(None), P()),
@@ -652,24 +683,11 @@ def decode_update_and_attend(
         k_new = _pad_last(k_new, d)
         v_new = _pad_last(v_new, d)
     quantized = k_scale is not None
-    impl = impl or default_decode_impl()
     # The kernels also serve dp-only meshes (trivial model axis): the op is
-    # embarrassingly parallel over batch.  Only the replicated-KV TP regime
-    # (tp > 1 not dividing Hkv) needs the XLA partitioner.
-    tp_trivial = mesh is None or mesh.shape.get(model_axis, 1) == 1
-    # Mosaic tiles the last (lane) dim at 128: compiled-TPU kernels require
-    # a 128-multiple STORED head dim.  The engine pads the cache for d<128
-    # models (ARKS_PAD_HEAD_DIM=0 disables); an unpadded narrow cache
-    # falls back to the XLA path — slower per step but correct.  Interpret
-    # mode has no such constraint, so CPU kernel tests still exercise the
-    # Pallas path at small D.
-    lane_ok = d % 128 == 0 or jax.default_backend() != "tpu"
-    if impl == "pallas" and not lane_ok and d not in _lane_warned:
-        _lane_warned.add(d)
-        log.warning(
-            "head_dim=%d is not 128-lane aligned: decode falls back to the "
-            "XLA attention path on TPU (slower per step, same results)", d)
-    use_pallas = impl == "pallas" and (kv_sharded or tp_trivial) and lane_ok
+    # embarrassingly parallel over batch.  The engine pads the cache for
+    # d<128 models (ARKS_PAD_HEAD_DIM=0 disables) so they take the kernels
+    # too; what is left is in kernel_blockers.
+    use_pallas = _use_pallas(impl, d, mesh, kv_sharded, model_axis)
 
     if not use_pallas:
         from arks_tpu.ops.pallas_attention import quantize_kv
@@ -731,14 +749,13 @@ def decode_update_and_attend(
                                     k_scale, v_scale, write_idx, layer)
         return out.reshape(b, h, d)[..., :d_model], kc, vc, ks, vs
 
-    from arks_tpu.parallel.compat import shard_map
     from jax.sharding import PartitionSpec as P
     model = model_axis if kv_sharded else None
     qspec = P(batch_axis, model, None, None)
     kvspec = P(batch_axis, model, None)
     cspec = P(None, batch_axis, model, None, None)
     sspec = P(None, batch_axis, model, None) if quantized else None
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(qspec, kvspec, kvspec, cspec, cspec, sspec, sspec,
                   P(batch_axis), P()),

@@ -158,8 +158,12 @@ def sweep(kernel: str, signature: str, candidates: list[dict],
                 bench_fn(**cand)
             t = (time.perf_counter() - t0) / repeats
         except Exception as e:  # an infeasible candidate is not fatal
-            log.debug("autotune candidate %s failed: %s", cand, e,
-                      exc_info=True)
+            # ... but never silent: a candidate the chip's compiler refuses
+            # must not look like one that merely lost the timing.
+            first = (str(e).strip().splitlines() or [""])[0]
+            log.warning("autotune %s %s: candidate %s refused: %s: %s",
+                        kernel, signature, cand, type(e).__name__, first)
+            log.debug("autotune candidate %s traceback", cand, exc_info=True)
             continue
         log.info("autotune %s %s %s: %.3f ms", kernel, signature, cand,
                  t * 1e3)

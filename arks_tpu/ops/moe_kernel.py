@@ -19,13 +19,12 @@ Layout contract (prepared by ``pad_groups``):
   out-of-range tiles can point at any expert.
 
 Opt-in for now (``ARKS_MOE_KERNEL=pallas``): the ragged_dot path remains
-the default until the kernel is measured on hardware (docs/roadmap.md).
+the default until the kernel is measured on hardware (ROADMAP.md S5).
 """
 
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -33,13 +32,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from arks_tpu.utils import knobs
-
-
-def _compiler_params(**kw):
-    """Compat shim: pallas renamed TPUCompilerParams -> CompilerParams across
-    jax releases; resolve whichever this jax ships."""
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kw)
 
 
 def moe_impl() -> str:
@@ -101,7 +93,7 @@ def _gm_kernel(bexp_ref, x_ref, w_ref, *rest, quantized: bool,
     acc = jax.lax.dot(x, w.astype(x.dtype),
                       preferred_element_type=jnp.float32)
     if quantized:
-        acc = acc * ws_ref[0]
+        acc = acc * ws_ref[0]                            # [1, bn] f32
     o_ref[...] = acc.astype(o_ref.dtype)
 
 
@@ -111,7 +103,7 @@ def grouped_matmul(
     xs: jnp.ndarray,           # [Tp, K] expert-sorted, block-aligned groups
     w: jnp.ndarray,            # [E, K, N] (int8/int4 when scales given)
     block_expert: jnp.ndarray,  # [Tp/block_t] int32 tile -> expert
-    w_scale: jnp.ndarray | None = None,  # int8: [E, N] per-channel scales
+    w_scale: jnp.ndarray | None = None,  # int8: [E, 1, N] per-channel scales
     w_group_scale: jnp.ndarray | None = None,  # int4: [E, K/G, N] scales
     block_t: int = 128,
     block_n: int = 128,
@@ -140,7 +132,7 @@ def grouped_matmul(
         return (bexp[ti], 0, ni)
 
     def ws_map(ti, ni, bexp):
-        return (bexp[ti], ni)
+        return (bexp[ti], 0, ni)
 
     def o_map(ti, ni, bexp):
         del bexp
@@ -158,7 +150,10 @@ def grouped_matmul(
         in_specs.append(pl.BlockSpec((1, k // group, block_n), gs_map))
         inputs.append(w_group_scale)
     elif quantized:
-        in_specs.append(pl.BlockSpec((1, block_n), ws_map))
+        # [E, 1, N] with a (1, 1, block_n) block: the TPU lowering wants a
+        # block's last two dims (8, 128)-divisible or equal to the array's
+        # own, which a (1, block_n) block of an [E, N] array is not.
+        in_specs.append(pl.BlockSpec((1, 1, block_n), ws_map))
         inputs.append(w_scale)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -171,9 +166,10 @@ def grouped_matmul(
         functools.partial(_gm_kernel, quantized=quantized, group=group),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((tp, n), xs.dtype),
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
+        name="moe_grouped_matmul",
     )(*inputs)
 
 
@@ -195,10 +191,8 @@ def grouped_ffn(xs: jnp.ndarray, sorted_expert: jnp.ndarray,
             if "gs" in wq:    # int4 groupwise [E, K/G, N]
                 return wq["q"], {"w_group_scale":
                                  wq["gs"].astype(jnp.float32)}
-            s = wq["s"].astype(jnp.float32)
-            if s.ndim == 3:       # [E, 1, N] per-output-channel -> [E, N]
-                s = s[:, 0, :]
-            return wq["q"], {"w_scale": s}
+            # [E, 1, N] per-output-channel, as quantize_tensor stores it.
+            return wq["q"], {"w_scale": wq["s"].astype(jnp.float32)}
         return wq, {}
 
     wg, sg = wv(w_gate)

@@ -39,13 +39,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from arks_tpu.utils import knobs
 
-
-def _compiler_params(**kw):
-    """Compat shim: pallas renamed TPUCompilerParams -> CompilerParams across
-    jax releases; resolve whichever this jax ships."""
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kw)
-
 _NEG_INF = -1e30
 
 
@@ -606,9 +599,10 @@ def paged_decode_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_decode_attention",
     )(*inputs)
 
 
@@ -621,19 +615,39 @@ def _unpack_int4_tile(w: jnp.ndarray) -> jnp.ndarray:
     """In-kernel nibble dequant, fused on the page stream: an int4 page
     tile [Hkv, page//2, D] of packed pairs -> [Hkv, page, D] int8 values.
     Sign extension is two arithmetic shifts; the interleave restores token
-    order (low nibble = even token, high = odd)."""
-    lo = jnp.right_shift(jnp.left_shift(w, 4), 4)
+    order (low nibble = even token, high = odd).  The shifts run on int32:
+    Mosaic legalises no 8-bit vector shift on a v5e (``arith.shli`` on
+    ``vector<..xi8>`` is refused)."""
+    w = w.astype(jnp.int32)
+    lo = jnp.right_shift(jnp.left_shift(w, 28), 28)
     hi = jnp.right_shift(w, 4)
     hkv, p2, d = w.shape
     return jnp.stack([lo, hi], axis=2).reshape(hkv, p2 * 2, d)
 
 
+def _group_heads(stripe: jnp.ndarray, h0, head_group: int) -> jnp.ndarray:
+    """Rows [h0, h0 + head_group) of a [Hkv, P] scale stripe held in VMEM.
+    ``h0`` is None (ungrouped: the whole stripe) or a traced multiple of
+    ``head_group``.  A dynamic sublane slice narrower than the f32 tile is
+    refused by Mosaic ("cannot statically prove that index ... is a
+    multiple of 4"), so the group is picked by select over the Hkv /
+    head_group static slices."""
+    if h0 is None:
+        return stripe
+    out = stripe[:head_group]
+    for lo in range(head_group, stripe.shape[0], head_group):
+        out = jnp.where(h0 == lo, stripe[lo:lo + head_group], out)
+    return out
+
+
 def _mixed_softmax_block(q_ref, kbuf, vbuf, ksbuf, vsbuf, m_ref, l_ref,
                          acc_ref, buf, si, pos0, q_lo, *, page, scale,
-                         quantized, int4):
+                         quantized, int4, h0=None):
     """One page of online-softmax accumulation — the SHARED compute body of
     the dense and ragged mixed kernels, so byte-identity between the two
-    grids is structural, not coincidental."""
+    grids is structural, not coincidental.  ``h0`` (grouped ragged items
+    only) is the first KV head of the item's group inside the scale
+    buffers, which always hold the page's whole head stripe."""
     _, hkv, g, bq, d = q_ref.shape
     q = q_ref[0].reshape(hkv, g * bq, d)
     kt = kbuf[buf]
@@ -647,7 +661,7 @@ def _mixed_softmax_block(q_ref, kbuf, vbuf, ksbuf, vsbuf, m_ref, l_ref,
         q, k, (((2,), (2,)), ((0,), (0,))),
         preferred_element_type=jnp.float32) * scale   # [Hkv, G*BQ, page]
     if quantized:
-        scores = scores * ksbuf[buf][:, None, :]
+        scores = scores * _group_heads(ksbuf[buf], h0, hkv)[:, None, :]
     # Row r of the G*BQ axis is query index r % BQ (g-major layout).
     row = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
     qpos = pos0 + q_lo + row % bq
@@ -663,7 +677,7 @@ def _mixed_softmax_block(q_ref, kbuf, vbuf, ksbuf, vsbuf, m_ref, l_ref,
     l_curr = jnp.sum(p, axis=2, keepdims=True)
     l_next = l_prev * correction + jnp.broadcast_to(l_curr, l_prev.shape)
     if quantized:
-        p = p * vsbuf[buf][:, None, :]
+        p = p * _group_heads(vsbuf[buf], h0, hkv)[:, None, :]
     pv = jax.lax.dot_general(
         p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32)           # [Hkv, G*BQ, D]
@@ -827,6 +841,7 @@ def _paged_mixed_ragged_kernel(layer_ref, tables_ref, pos_start_ref,
     pos0 = pos_start_ref[s_i]
     q_lo = qb * block_q
     h0 = hg_i * head_group
+    grouped = head_group != kpool.shape[2]
 
     def start_copies(page_i, buf):
         pg = tables_ref[s_i, page_i]
@@ -835,12 +850,14 @@ def _paged_mixed_ragged_kernel(layer_ref, tables_ref, pos_start_ref,
         pltpu.make_async_copy(vpool.at[lyr, pg, pl.ds(h0, head_group)],
                               vbuf.at[buf], sem.at[1, buf]).start()
         if quantized:
-            pltpu.make_async_copy(kspool.at[lyr, pg,
-                                            pl.ds(h0, head_group)],
-                                  ksbuf.at[buf], sem.at[2, buf]).start()
-            pltpu.make_async_copy(vspool.at[lyr, pg,
-                                            pl.ds(h0, head_group)],
-                                  vsbuf.at[buf], sem.at[3, buf]).start()
+            # The f32 scale stripe [Hkv, P] is tiled (Hkv, 128) in HBM: a
+            # sub-tile head slice cannot be DMA'd (Mosaic: "slice shape
+            # must be aligned to tiling").  Fetch the page's whole stripe
+            # (4 bytes/token/head) and pick the group's heads in VMEM.
+            pltpu.make_async_copy(kspool.at[lyr, pg], ksbuf.at[buf],
+                                  sem.at[2, buf]).start()
+            pltpu.make_async_copy(vspool.at[lyr, pg], vsbuf.at[buf],
+                                  sem.at[3, buf]).start()
 
     def wait_copies(buf):
         pltpu.make_async_copy(kpool.at[lyr, 0, pl.ds(0, head_group)],
@@ -848,12 +865,10 @@ def _paged_mixed_ragged_kernel(layer_ref, tables_ref, pos_start_ref,
         pltpu.make_async_copy(vpool.at[lyr, 0, pl.ds(0, head_group)],
                               vbuf.at[buf], sem.at[1, buf]).wait()
         if quantized:
-            pltpu.make_async_copy(kspool.at[lyr, 0,
-                                            pl.ds(0, head_group)],
-                                  ksbuf.at[buf], sem.at[2, buf]).wait()
-            pltpu.make_async_copy(vspool.at[lyr, 0,
-                                            pl.ds(0, head_group)],
-                                  vsbuf.at[buf], sem.at[3, buf]).wait()
+            pltpu.make_async_copy(kspool.at[lyr, 0], ksbuf.at[buf],
+                                  sem.at[2, buf]).wait()
+            pltpu.make_async_copy(vspool.at[lyr, 0], vsbuf.at[buf],
+                                  sem.at[3, buf]).wait()
 
     # Padding item (npages == 0 <= plo): compute nothing, write nothing —
     # the output window still holds the previous (aliased) item's block
@@ -890,7 +905,8 @@ def _paged_mixed_ragged_kernel(layer_ref, tables_ref, pos_start_ref,
             _mixed_softmax_block(q_ref, kbuf, vbuf, ksbuf, vsbuf, m_ref,
                                  l_ref, acc_ref, buf, si, pos0, q_lo,
                                  page=page, scale=scale,
-                                 quantized=quantized, int4=int4)
+                                 quantized=quantized, int4=int4,
+                                 h0=h0 if grouped else None)
             return loop_c
 
         jax.lax.fori_loop(plo, npages, body, 0)
@@ -947,8 +963,8 @@ def _paged_mixed_call(q, k_pool, v_pool, tables, pos_start, q_len, layer,
         ]
         n_sem = 2
         if quantized:
-            scratch += [pltpu.VMEM((nbuf, head_group, page), jnp.float32),
-                        pltpu.VMEM((nbuf, head_group, page), jnp.float32)]
+            scratch += [pltpu.VMEM((nbuf, hkv, page), jnp.float32),
+                        pltpu.VMEM((nbuf, hkv, page), jnp.float32)]
             n_sem = 4
         scratch += [
             pltpu.VMEM((head_group, g * block_q, 128), jnp.float32),  # m
@@ -1042,8 +1058,9 @@ def _paged_mixed_call(q, k_pool, v_pool, tables, pos_start, q_len, layer,
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=_compiler_params(dimension_semantics=dims),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=dims),
         interpret=interpret,
+        name=f"paged_mixed_attention_{grid}",
     )(*inputs)
     # Rows past q_len[s] are undefined (dense: skipped blocks; ragged:
     # never-visited items) — zero them so both grids return IDENTICAL
@@ -1208,6 +1225,7 @@ def paged_kv_update(
         # 0=layer, 1=idx, 2=tables, 3=kn, 4=vn, 5=k_pool, 6=v_pool.
         input_output_aliases={5: 0, 6: 1},
         interpret=interpret,
+        name="paged_kv_update",
     )(layer_arr, write_idx.astype(jnp.int32), tables.astype(jnp.int32),
       kn, vn, k_pool, v_pool)
 
@@ -1259,26 +1277,26 @@ def _paged_update_quant_kernel(layer_ref, idx_ref, tables_ref,
         row = jax.lax.broadcasted_iota(jnp.int32, (1, 1, hkv, ch, d), 3)
         hit = row == (boff - base)
         if int4:
-            # Merge ONE nibble of the hit byte, int8-domain bitwise: low
-            # nibble = even token (keep 0xF0), high = odd (keep 0x0F; the
-            # int8 left shift wraps the value into the high nibble).
+            # Merge ONE nibble of the hit byte: low nibble = even token
+            # (keep 0xF0), high = odd (keep 0x0F).  The bit math runs on
+            # int32 (Mosaic legalises no 8-bit vector shift on a v5e) and
+            # truncates back: the new value is in [-7, 7], so value << 4
+            # and either merge stay inside int8's range.
             even = (off % 2) == 0
-            newk = kn_ref[pl.ds(i, 1)][None]
-            newv = vn_ref[pl.ds(i, 1)][None]
-            mk = jnp.where(
-                even,
-                jnp.bitwise_or(jnp.bitwise_and(kscr[:], jnp.int8(-16)),
-                               jnp.bitwise_and(newk, jnp.int8(15))),
-                jnp.bitwise_or(jnp.bitwise_and(kscr[:], jnp.int8(15)),
-                               jnp.left_shift(newk, 4)))
-            mv = jnp.where(
-                even,
-                jnp.bitwise_or(jnp.bitwise_and(vscr[:], jnp.int8(-16)),
-                               jnp.bitwise_and(newv, jnp.int8(15))),
-                jnp.bitwise_or(jnp.bitwise_and(vscr[:], jnp.int8(15)),
-                               jnp.left_shift(newv, 4)))
-            kscr[:] = jnp.where(hit, mk, kscr[:])
-            vscr[:] = jnp.where(hit, mv, vscr[:])
+
+            def merge(scr, new_ref):
+                old = scr[:].astype(jnp.int32)
+                new = new_ref[pl.ds(i, 1)][None].astype(jnp.int32)
+                merged = jnp.where(
+                    even,
+                    jnp.bitwise_or(jnp.bitwise_and(old, -16),
+                                   jnp.bitwise_and(new, 15)),
+                    jnp.bitwise_or(jnp.bitwise_and(old, 15),
+                                   jnp.left_shift(new, 4)))
+                scr[:] = jnp.where(hit, merged, old).astype(jnp.int8)
+
+            merge(kscr, kn_ref)
+            merge(vscr, vn_ref)
         else:
             kscr[:] = jnp.where(hit, kn_ref[pl.ds(i, 1)][None], kscr[:])
             vscr[:] = jnp.where(hit, vn_ref[pl.ds(i, 1)][None], vscr[:])
@@ -1358,6 +1376,7 @@ def paged_kv_update_quant(
         # 7=k_pool, 8=v_pool, 9=k_scale, 10=v_scale.
         input_output_aliases={7: 0, 8: 1, 9: 2, 10: 3},
         interpret=interpret,
+        name="paged_kv_update_quant",
     )(layer_arr, write_idx.astype(jnp.int32), tables.astype(jnp.int32),
       kq[:, :, None, :], vq[:, :, None, :], ks, vs,
       k_pool, v_pool, k_scale, v_scale)

@@ -45,13 +45,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _compiler_params(**kw):
-    """Compat shim: pallas renamed TPUCompilerParams -> CompilerParams across
-    jax releases; resolve whichever this jax ships."""
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kw)
-
 _NEG_INF = -1e30
 
 
@@ -127,6 +120,12 @@ def _attn_kernel(layer_ref, glens_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
         o_ref[:] = out.astype(o_ref.dtype)
 
 
+# Mosaic's default scoped-VMEM limit on a v5e is 16 MiB.  The BlockSpec
+# pipeline double-buffers the K and V blocks, so they get half of it; the
+# q/out blocks, scales and the f32 m/l/acc scratch live in the rest.
+_KV_TILE_VMEM_BYTES = 8 * 2**20
+
+
 def _pick_block_b(b: int, target: int) -> int:
     best = 1
     for cand in range(1, min(b, target) + 1):
@@ -157,7 +156,12 @@ def ragged_decode_attention(
     if s % block_s != 0:
         raise ValueError(f"cache len {s} not divisible by block_s {block_s}")
     quantized = k_scale is not None
-    block_b = _pick_block_b(b, block_b)
+    # 2 arrays (K, V) x 2 pipeline buffers of [block_b, Hkv, block_s, D]:
+    # bf16 at the default block_b=16 / block_s=256 / Hkv=4 / D=128 is
+    # exactly 16 MiB and the chip's compiler refuses it.
+    slot_bytes = 4 * hkv * block_s * d * k_cache.dtype.itemsize
+    block_b = _pick_block_b(
+        b, max(1, min(block_b, _KV_TILE_VMEM_BYTES // slot_bytes)))
     num_groups = b // block_b
     num_blocks = s // block_s
     scale = 1.0 / (d ** 0.5)
@@ -220,9 +224,10 @@ def ragged_decode_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="ragged_decode_attention",
     )(*inputs)
 
 
@@ -325,6 +330,7 @@ def kv_cache_update(
         # 2=kn, 3=vn, 4=k_cache, 5=v_cache.
         input_output_aliases={4: 0, 5: 1},
         interpret=interpret,
+        name="kv_cache_update",
     )(layer_arr, write_idx.astype(jnp.int32), kn, vn, k_cache, v_cache)
 
 
@@ -450,6 +456,7 @@ def kv_cache_update_quant(
         # 0=layer, 1=idx, 2=kq, 3=vq, 4=ks, 5=vs, 6=kc, 7=vc, 8=kss, 9=vss.
         input_output_aliases={6: 0, 7: 1, 8: 2, 9: 3},
         interpret=interpret,
+        name="kv_cache_update_quant",
     )(layer_arr, write_idx.astype(jnp.int32),
       kq[:, :, None, :], vq[:, :, None, :], ks, vs,
       k_cache, v_cache, k_scale, v_scale)

@@ -26,7 +26,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from arks_tpu.parallel.compat import shard_map, axis_size
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from arks_tpu.models import transformer as tf
@@ -69,7 +68,7 @@ def pipeline_forward(
     x_mb = tokens.reshape(m, mb, t)
 
     def local(layers_local, embed, x_mb):
-        s_ax = axis_size(stage_axis)
+        s_ax = jax.lax.axis_size(stage_axis)
         s_id = jax.lax.axis_index(stage_axis)
         perm = [(i, (i + 1) % s_ax) for i in range(s_ax)]
         positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (mb, t))
@@ -110,7 +109,7 @@ def pipeline_forward(
         mask = (s_id == s_ax - 1).astype(outputs.dtype)
         return jax.lax.psum(outputs * mask, stage_axis)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(stage_axis), P(), P()),
         out_specs=P(),
@@ -229,7 +228,7 @@ def pp_decode_step_paged(
     from arks_tpu.ops.attention import paged_decode_update_and_attend
 
     def local(layers_local, embed, kc, vc, ksc, vsc, tables, tokens, lengths):
-        s_ax = axis_size(stage_axis)
+        s_ax = jax.lax.axis_size(stage_axis)
         s_id = jax.lax.axis_index(stage_axis)
         perm = [(i, (i + 1) % s_ax) for i in range(s_ax)]
         toks_mb = tokens.reshape(m, mbs)
@@ -306,7 +305,7 @@ def pp_decode_step_paged(
 
     cspec = P(stage_axis)
     sspec = cspec if quantized else None
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(stage_axis), P(), cspec, cspec, sspec, sspec,
                   P(), P(), P()),
@@ -362,7 +361,7 @@ def pp_decode_step(
     from arks_tpu.ops.attention import decode_update_and_attend
 
     def local(layers_local, embed, kc, vc, ksc, vsc, tokens, lengths):
-        s_ax = axis_size(stage_axis)
+        s_ax = jax.lax.axis_size(stage_axis)
         s_id = jax.lax.axis_index(stage_axis)
         perm = [(i, (i + 1) % s_ax) for i in range(s_ax)]
         toks_mb = tokens.reshape(m, mbs)
@@ -449,7 +448,7 @@ def pp_decode_step(
 
     cspec = P(stage_axis)
     sspec = cspec if quantized else None
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(stage_axis), P(), cspec, cspec, sspec, sspec, P(), P()),
         out_specs=(P(), cspec, cspec, sspec, sspec),
@@ -487,7 +486,7 @@ def pp_prefill(
     compute_dtype = params["layers"]["attn_norm"].dtype
 
     def local(layers_local, embed, tokens):
-        s_ax = axis_size(stage_axis)
+        s_ax = jax.lax.axis_size(stage_axis)
         s_id = jax.lax.axis_index(stage_axis)
         perm = [(i, (i + 1) % s_ax) for i in range(s_ax)]
         positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
@@ -516,7 +515,7 @@ def pp_prefill(
         h_final = jax.lax.psum(h * mask, stage_axis)
         return h_final, ks, vs
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(stage_axis), P(), P()),
         out_specs=(P(), P(stage_axis), P(stage_axis)),

@@ -24,8 +24,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from arks_tpu.parallel.compat import axis_size
-
 _NEG_INF = -1e30
 
 
@@ -38,7 +36,7 @@ def ring_self_attention(
     causal: bool = True,
 ) -> jnp.ndarray:
     """Runs INSIDE shard_map over ``axis_name``. Returns [B, Tl, H, D]."""
-    p = axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     b, tl, h, d = q.shape
     hkv = k.shape[2]
@@ -100,12 +98,11 @@ def ring_prefill_attention(
     dim stays model-sharded inside the ring — TP devices each ring their own
     heads instead of all-gathering q/k/v and redoing every head's FLOPs.
     """
-    from arks_tpu.parallel.compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     model = model_axis if heads_sharded else None
     spec = P(batch_axis, seq_axis, model, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(ring_self_attention, axis_name=seq_axis, causal=causal),
         mesh=mesh,
         in_specs=(spec, spec, spec),

@@ -24,7 +24,7 @@ from arks_tpu.utils import knobs
 log = logging.getLogger("arks_tpu.server")
 
 
-def main() -> None:
+def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
     p = argparse.ArgumentParser("arks_tpu.server")
     p.add_argument("--model", required=True, help="model config name (arks_tpu.models) "
                    "or path to a model dir with config.json")
@@ -123,11 +123,14 @@ def main() -> None:
                    default=None, dest="disagg",
                    help="PD-separated serving role (reference flag parity: "
                         "arksdisaggregatedapplication_controller.go:1672-1724)")
-    args = p.parse_args()
+    return p.parse_args(argv)
 
-    logging.basicConfig(level=logging.INFO,
-                        format="%(asctime)s %(name)s %(levelname)s %(message)s")
 
+def build_engine(args: argparse.Namespace):
+    """Everything from the parsed flags to a constructed (not yet started)
+    ``InferenceEngine``: platform, multi-host init, compile cache, mesh,
+    weights, tokenizer, model pool.  ``main`` and ``chip_smoke.py`` both
+    come through here, so the smoke proves the pod's own start-up path."""
     # Fault-tolerance knobs travel by env (the engine and its watchdog
     # read them at start); explicit flags win over inherited env.
     if args.dispatch_deadline is not None:
@@ -146,12 +149,15 @@ def main() -> None:
         log.info("multi-host init: coordinator=%s process=%d/%d", coord, pid, nproc)
         jax.distributed.initialize(coordinator_address=coord,
                                    num_processes=nproc, process_id=pid)
+    # After the multi-host init: placing the cache asks for the backend,
+    # and jax.distributed.initialize must come before the backend exists.
+    from arks_tpu.utils import compile_cache
+    compile_cache.configure()
 
     from arks_tpu.engine.engine import EngineConfig, InferenceEngine
     from arks_tpu.engine.tokenizer import load_tokenizer
     from arks_tpu.models import get_config
     from arks_tpu.models.config import ModelConfig
-    from arks_tpu.server.openai_server import OpenAIServer
 
     if os.path.isdir(args.model):
         cfg = ModelConfig.from_hf_config(args.model, name=os.path.basename(args.model))
@@ -160,7 +166,12 @@ def main() -> None:
         cfg = get_config(args.model)
         model_path = args.model_path
 
-    n_dev = len(jax.devices())
+    devs = jax.devices()
+    n_dev = len(devs)
+    # A process that came up on the wrong platform serves from
+    # interpret-mode kernels on the CPU and says nothing else about it.
+    log.info("jax %s on platform=%s device_kind=%s devices=%d",
+             jax.__version__, devs[0].platform, devs[0].device_kind, n_dev)
     # The k8s renderer passes the slice count by env (ARKS_NUM_SLICES);
     # an explicit --num-slices flag wins — including an explicit 1 (the
     # unset default is None, so forcing single-slice in a multi-slice pod
@@ -276,33 +287,19 @@ def main() -> None:
     tokenizer = load_tokenizer(
         model_path if model_path and os.path.isdir(model_path) else None,
         strict=has_real_weights(model_path))
-    engine = InferenceEngine(cfg, ecfg, tokenizer, params=params, mesh=mesh,
-                             draft_params=draft_params, draft_cfg=draft_cfg,
-                             pool=pool)
+    return InferenceEngine(cfg, ecfg, tokenizer, params=params, mesh=mesh,
+                           draft_params=draft_params, draft_cfg=draft_cfg,
+                           pool=pool)
 
-    served = args.served_model_name or cfg.name
 
-    # Multi-host gang: process 0 serves HTTP and broadcasts every device
-    # dispatch; the other processes mirror them so the gang's collectives
-    # stay in lockstep (arks_tpu.engine.multihost).
-    if coord and nproc > 1:
-        import signal as _signal
+def build_server(args: argparse.Namespace, engine):
+    """Register the extra pool models, start the engine's step loop (every
+    role but disaggregated prefill) and return the role's HTTP server,
+    not yet listening."""
+    from arks_tpu.models.config import ModelConfig
+    from arks_tpu.server.openai_server import OpenAIServer
 
-        from arks_tpu.engine.multihost import (
-            DispatchFollower, DispatchLeader, dispatch_address)
-        dhost, dport = dispatch_address(coord)
-        pid = knobs.get_int("ARKS_PROCESS_ID")
-        if pid != 0:
-            # The gang driver SIGTERMs every member at once; a follower
-            # dying instantly would strand the leader's drain mid-
-            # collective.  Followers ignore SIGTERM and exit when the
-            # leader (who coordinates the drain) closes the channel.
-            _signal.signal(_signal.SIGTERM, _signal.SIG_IGN)
-            log.info("follower %d/%d: mirroring leader dispatches", pid, nproc)
-            DispatchFollower(engine, dhost, dport).run()
-            return
-        engine.dispatcher = DispatchLeader("0.0.0.0", dport, nproc - 1)
-
+    served = args.served_model_name or engine.cfg.name
     # Extra pool models (after the multihost wiring so the single-host-only
     # check in register_model sees the dispatcher).
     for spec in args.extra_model or []:
@@ -325,6 +322,39 @@ def main() -> None:
     else:
         engine.start()
         server = OpenAIServer(engine, served, host=args.host, port=args.port)
+    return server
+
+
+def main() -> None:
+    args = parse_args()
+    logging.basicConfig(level=logging.INFO,
+                        format="%(asctime)s %(name)s %(levelname)s %(message)s")
+    engine = build_engine(args)
+
+    # Multi-host gang: process 0 serves HTTP and broadcasts every device
+    # dispatch; the other processes mirror them so the gang's collectives
+    # stay in lockstep (arks_tpu.engine.multihost).
+    coord = knobs.get_str("ARKS_COORDINATOR_ADDRESS")
+    nproc = knobs.get_int("ARKS_NUM_PROCESSES")
+    if coord and nproc > 1:
+        import signal as _signal
+
+        from arks_tpu.engine.multihost import (
+            DispatchFollower, DispatchLeader, dispatch_address)
+        dhost, dport = dispatch_address(coord)
+        pid = knobs.get_int("ARKS_PROCESS_ID")
+        if pid != 0:
+            # The gang driver SIGTERMs every member at once; a follower
+            # dying instantly would strand the leader's drain mid-
+            # collective.  Followers ignore SIGTERM and exit when the
+            # leader (who coordinates the drain) closes the channel.
+            _signal.signal(_signal.SIGTERM, _signal.SIG_IGN)
+            log.info("follower %d/%d: mirroring leader dispatches", pid, nproc)
+            DispatchFollower(engine, dhost, dport).run()
+            return
+        engine.dispatcher = DispatchLeader("0.0.0.0", dport, nproc - 1)
+
+    server = build_server(args, engine)
     # Graceful drain: SIGTERM (rolling update, scale-down, kubelet stop)
     # flips readiness off, 503s new work, and lets in-flight requests
     # finish before serve_forever returns.
@@ -339,8 +369,8 @@ def main() -> None:
 
     signal.signal(signal.SIGTERM, _on_term)
 
-    log.info("serving %s on %s:%d (devices=%d, mode=%s)",
-             served, args.host, args.port, n_dev, args.disagg or "unified")
+    log.info("serving %s on %s:%d (mode=%s)", server.served_model_name,
+             args.host, args.port, args.disagg or "unified")
     server.start(background=False)
     engine.stop()
     if engine.dispatcher is not None:

@@ -389,9 +389,6 @@ _k("ARKS_GANG_WORKER_INDEX", "str", None,
    "in-process).", "control")
 
 # ---------------------------------------------------------------- bench
-_k("ARKS_BENCH_PROBE_DEADLINE_S", "float", "0",
-   "Deadline of the persistent accelerator-availability prober run by "
-   "bench.py; 0 = single immediate probe.", "bench")
 _k("ARKS_BENCH_DRAFT_MODEL", "str", None,
    "Draft model path/name enabling the speculative-decoding bench "
    "ladder.", "bench")

@@ -118,13 +118,22 @@ def test_sweep_picks_and_persists_fastest(monkeypatch):
     assert autotune.lookup("paged_mixed", sig) == {"block_q": 2}
 
 
-def test_sweep_skips_infeasible_candidates():
+def test_sweep_skips_infeasible_candidates(caplog):
     def bench(block_q):
         if block_q == 8:
-            raise ValueError("infeasible")
+            raise ValueError("Mosaic failed to compile TPU kernel: why\n"
+                             "a long dump that must not reach the log")
 
-    best = autotune.sweep("k", "s", [{"block_q": 8}, {"block_q": 2}], bench)
+    with caplog.at_level("WARNING", logger="arks.autotune"):
+        best = autotune.sweep("k", "s", [{"block_q": 8}, {"block_q": 2}],
+                              bench)
     assert best == {"block_q": 2}
+    # Skipped, but never silently: the refusal is logged with the
+    # compiler's first line, so a candidate the chip cannot launch does
+    # not read as one that merely lost the timing.
+    (rec,) = [r for r in caplog.records if r.levelname == "WARNING"]
+    msg = rec.getMessage()
+    assert "'block_q': 8" in msg and msg.endswith("TPU kernel: why")
     with pytest.raises(RuntimeError, match="every candidate"):
         autotune.sweep("k", "s2", [{"block_q": 8}], bench)
 

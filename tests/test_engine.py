@@ -117,7 +117,7 @@ def test_seeded_sampling_independent_of_scheduler_timing(monkeypatch):
     pending/free slots' keys or the stream would depend on scheduler
     timing.  (CPU resolves admissions near-instantly, so the deferral
     window is forced by holding back the drain for a few steps — the
-    shape a slow tunneled device produces naturally.)"""
+    shape a device that answers slowly produces naturally.)"""
     from arks_tpu.engine.engine import InferenceEngine as IE
     cfg = get_config("tiny")
 

@@ -201,10 +201,8 @@ def test_grouped_matmul_kernel_matches_ragged_dot():
     wq = quantize_tensor(w)
     from arks_tpu.models.quant import dequantize
     ref_q = jax.lax.ragged_dot(xs, dequantize(wq, jnp.float32), group_sizes)
-    s = wq["s"].astype(jnp.float32)
-    s2 = s[:, 0, :] if s.ndim == 3 else s
-    got_q = grouped_matmul(xs_p, wq["q"], bexp, s2, block_t=bt, block_n=16,
-                           interpret=True)[dest]
+    got_q = grouped_matmul(xs_p, wq["q"], bexp, wq["s"].astype(jnp.float32),
+                           block_t=bt, block_n=16, interpret=True)[dest]
     np.testing.assert_allclose(np.asarray(got_q), np.asarray(ref_q),
                                atol=1e-3, rtol=1e-3)
 
