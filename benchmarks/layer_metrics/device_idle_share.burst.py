@@ -1,0 +1,6 @@
+"""``device_idle_share`` for the flood cell, where it moves ``output_tok_s.burst``;
+the reading is the same reader's."""
+
+from benchmarks import manifest
+
+read = manifest.load_reader("device_idle_share")

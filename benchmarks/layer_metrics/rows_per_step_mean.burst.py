@@ -1,0 +1,6 @@
+"""``rows_per_step_mean`` for the flood cell, where it moves ``output_tok_s.burst``;
+the reading is the same reader's."""
+
+from benchmarks import manifest
+
+read = manifest.load_reader("rows_per_step_mean")
