@@ -29,6 +29,7 @@ import os
 import queue
 import threading
 import time
+import weakref
 
 import jax
 import jax.numpy as jnp
@@ -629,6 +630,21 @@ class EngineMetrics:
         self.mixed_chunk_tokens_total = r.counter(
             "mixed_chunk_tokens_total",
             "Prefill-chunk tokens processed inside mixed dispatches")
+        self.mixed_chunk_budget_tokens_total = r.counter(
+            "mixed_chunk_budget_tokens_total",
+            "Prefill-chunk token budget offered by sequential mixed "
+            "dispatches issued while a request was prefilling or waited "
+            "in the admission queue (mixed_chunk_tokens_total over this "
+            "is the share of the budget the steps used)")
+        # XLA compilations seen by this process (jax.monitoring): which
+        # step recompiled is an operator's question, not only a bench's.
+        self.xla_compilations_total = r.counter(
+            "xla_compilations_total",
+            "Backend (XLA) compilations in this process since the engine "
+            "was built")
+        self.xla_compile_seconds_total = r.counter(
+            "xla_compile_seconds_total",
+            "Seconds spent in backend (XLA) compilation")
         # Ragged-grid padding waste (ops.paged_attention ragged work list):
         # steps_total counts the page-compute steps the ACTIVE grid mode
         # executes per mixed dispatch; ideal_total counts the per-sequence
@@ -818,6 +834,47 @@ def _scoped(phase: str):
     return deco
 
 
+def _named_jit(name: str, fn, **jit_kw):
+    """``jax.jit`` under a name of the program's own: the profiler's
+    ``XLA Modules`` line and the HLO module read ``jit_<name>`` instead of
+    ``jit__unknown`` (a ``functools.partial``) or ``jit__lambda_``.  The
+    fresh wrapper also keeps jit's trace cache per engine (it is keyed on
+    the underlying callable, see _insert_fn)."""
+    def prog(*args, **kwargs):
+        return fn(*args, **kwargs)
+    prog.__name__ = prog.__qualname__ = name
+    return jax.jit(prog, **jit_kw)
+
+
+# jax.monitoring listeners cannot be taken back, so ONE is registered per
+# process and fans out to the engines alive (a serving pod has one).
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compile_watchers: "weakref.WeakSet[InferenceEngine]" = weakref.WeakSet()
+_compile_listener_lock = threading.Lock()
+_compile_listener_on = False
+
+
+def _watch_compilations(engine: "InferenceEngine") -> None:
+    global _compile_listener_on
+    with _compile_listener_lock:
+        _compile_watchers.add(engine)
+        if _compile_listener_on:
+            return
+        _compile_listener_on = True
+
+    def on_duration(event: str, secs: float, **_) -> None:
+        if event != _COMPILE_EVENT:
+            return
+        for eng in list(_compile_watchers):
+            eng.metrics.xla_compilations_total.inc()
+            eng.metrics.xla_compile_seconds_total.inc(secs)
+            # Engine-scope instant: an idle gap of the device can be put
+            # down to compiling (runs on whichever thread compiles).
+            eng.trace.evt("", "compile", "I", round(secs, 4))
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+
+
 class InferenceEngine:
     def __init__(
         self,
@@ -978,7 +1035,13 @@ class InferenceEngine:
         # call trace.evt — tests/test_hotpath_guard.py enforces it) and
         # doubles as the flight recorder the watchdog/fault dumps attach.
         self.trace = trace_mod.Tracer()
-        self.profiler = prof_mod.ProfilerWindows()
+        # One control: a profiler window (engine.profiler.start/stop, the
+        # /v1/profiler endpoints) also switches the step-section spans on
+        # and returns them; with no window open a section site is one
+        # attribute test (self.profiler.sections).
+        self.profiler = prof_mod.ProfilerWindows(tracer=self.trace)
+        self._admit_popped = 0   # requests _admit() took off the queue
+        _watch_compilations(self)
         self._pipe_seq = 0   # pipelined issue->resolve span pairing
         self._preempt_on = knobs.get_bool("ARKS_PREEMPT")
         preempt_max = knobs.get_int("ARKS_PREEMPT_MAX_INFLIGHT")
@@ -1822,17 +1885,20 @@ class InferenceEngine:
                 return ids[0], clp[0], vals[0], lids[0], ks, vs
             return ids[0], ks, vs
 
-        self._prefill_detached_fn = jax.jit(
+        self._prefill_detached_fn = _named_jit(
+            "arks_prefill_detached",
             functools.partial(prefill_detached_prog, want_lp=False))
-        self._prefill_detached_lp_fn = jax.jit(
+        self._prefill_detached_lp_fn = _named_jit(
+            "arks_prefill_detached_lp",
             functools.partial(prefill_detached_prog, want_lp=True))
-        # Lambda wrapper (here and for the other module-level tf.* jits
-        # below): jit's trace cache is keyed on the underlying callable,
-        # so a bare jax.jit(tf.insert) would share one process-wide cache
-        # across engines and leak other engines' shape variants into
-        # compiled_program_variants().
-        self._insert_fn = jax.jit(lambda *a: tf.insert(*a),
-                                  donate_argnums=(0,))
+        # _named_jit's fresh wrapper (here and for the other module-level
+        # tf.* jits below) matters beyond the name: jit's trace cache is
+        # keyed on the underlying callable, so a bare jax.jit(tf.insert)
+        # would share one process-wide cache across engines and leak other
+        # engines' shape variants into compiled_program_variants().
+        self._insert_fn = _named_jit(
+            "arks_insert", tf.insert,
+            donate_argnums=(0,))
 
         # Fused BATCHED admission: M queued prompts prefill + sample +
         # insert + set_slot in ONE dispatch.  Under churn admissions were
@@ -1878,10 +1944,12 @@ class InferenceEngine:
                 return ids, clp, vals, lids, cache, sampling, ks, vs
             return ids, cache, sampling, ks, vs
 
-        self._admit_fn = jax.jit(functools.partial(admit_batch, want_lp=False),
-                                 donate_argnums=(1, 2))
-        self._admit_lp_fn = jax.jit(functools.partial(admit_batch, want_lp=True),
-                                    donate_argnums=(1, 2))
+        self._admit_fn = _named_jit(
+            "arks_admit", functools.partial(admit_batch, want_lp=False),
+            donate_argnums=(1, 2))
+        self._admit_lp_fn = _named_jit(
+            "arks_admit_lp", functools.partial(admit_batch, want_lp=True),
+            donate_argnums=(1, 2))
 
         if self._paged:
             def chunk_step(params, cache, tables_row, tokens, start, valid):
@@ -1892,34 +1960,41 @@ class InferenceEngine:
                 return tf.prefill_chunk(params, cfg, cache, slot, tokens,
                                         start, valid, mesh)
 
-        self._chunk_fn = jax.jit(chunk_step, donate_argnums=(1,))
+        self._chunk_fn = _named_jit(
+            "arks_chunk", chunk_step,
+            donate_argnums=(1,))
         if self._paged:
-            self._insert_pages_fn = jax.jit(
-                lambda *a: tf.insert_pages(*a), donate_argnums=(0,))
+            self._insert_pages_fn = _named_jit(
+                "arks_insert_pages", tf.insert_pages,
+                donate_argnums=(0,))
             # Host-tier spill/restore: gather evicted pages into a D2H
             # staging block; scatter host blocks back into fresh pool
             # pages.  The restore returns a marker READ FROM the written
             # pool, so marker.is_ready() == "the scatter landed" (a
             # passed-through input would alias and read ready instantly).
-            self._spill_gather_fn = jax.jit(
-                lambda *a, **kw: tf.gather_pool_pages(*a, **kw))
+            self._spill_gather_fn = _named_jit(
+                "arks_spill_gather", tf.gather_pool_pages)
 
             def restore_scatter(cache, kb, vb, ksb, vsb, pages, n_valid):
                 cache = tf.scatter_pool_pages(cache, kb, vb, pages, n_valid,
                                               k_scale=ksb, v_scale=vsb)
                 return cache, cache.k[0, 0, 0, 0, 0]
 
-            self._restore_fn = jax.jit(restore_scatter, donate_argnums=(0,))
+            self._restore_fn = _named_jit(
+                "arks_restore", restore_scatter,
+                donate_argnums=(0,))
 
             # Preemptive swap (ARKS_PREEMPT): one victim slot's sampler
             # row out (the D2H decode-state snapshot: PRNG key, penalty
             # counts, DFA row — everything sample() evolves per slot) and
             # its counts back on resume (key/guide_row ride set_slot,
             # which RESETS counts — hence the separate restore).
-            self._sampler_row_fn = jax.jit(
+            self._sampler_row_fn = _named_jit(
+                "arks_sampler_row",
                 lambda st, slot: (st.key[slot], st.counts[slot],
                                   st.guide_row[slot]))
-            self._restore_counts_fn = jax.jit(
+            self._restore_counts_fn = _named_jit(
+                "arks_restore_counts",
                 lambda st, slot, row: st._replace(
                     counts=st.counts.at[slot].set(row)),
                 donate_argnums=(0,))
@@ -1934,7 +2009,8 @@ class InferenceEngine:
             ids, _ = sampler_mod.sample(logits, state, guide_tables=gtables)
             return ids[0]
 
-        self._sample_one_fn = jax.jit(sample_one)
+        self._sample_one_fn = _named_jit(
+            "arks_sample_one", sample_one)
 
         def sample_one_lp(logits, temperature, top_p, top_k, key,
                           bias_ids, bias_vals, sup_ids, min_first,
@@ -1947,25 +2023,26 @@ class InferenceEngine:
             clp, vals, lids = sampler_mod.top_logprobs(logits, ids)
             return ids[0], clp[0], vals[0], lids[0]
 
-        self._sample_one_lp_fn = jax.jit(sample_one_lp)
+        self._sample_one_lp_fn = _named_jit(
+            "arks_sample_one_lp", sample_one_lp)
 
         dtype = jnp.dtype(self.ecfg.dtype or cfg.dtype)
-        self._extract_fn = jax.jit(
+        self._extract_fn = _named_jit(
+            "arks_extract",
             lambda cache, slot: tf.extract(cache, slot, dtype))
 
         # Donated slot-state writes: eager .at[].set() would copy the whole
         # [num_slots, vocab] penalty-counts buffer on EVERY admission
         # (~117MB at 192 slots x 152k vocab); donation updates in place.
-        # Per-engine lambda wrappers: jax.jit's trace cache is keyed on the
-        # underlying callable, so jitting the module-level functions
+        # Per-engine wrappers (_named_jit): jax.jit's trace cache is keyed
+        # on the underlying callable, so jitting the module-level functions
         # directly would share one process-wide cache across engines and
         # make compiled_program_variants() report shapes traced by OTHER
         # engines (order-dependent compile-budget counts under pytest).
-        self._set_slot_fn = jax.jit(
-            lambda *a, **kw: sampler_mod.set_slot(*a, **kw),
-            donate_argnums=(0,))
-        self._clear_pen_fn = jax.jit(
-            lambda *a, **kw: sampler_mod.clear_slot_penalties(*a, **kw),
+        self._set_slot_fn = _named_jit(
+            "arks_set_slot", sampler_mod.set_slot, donate_argnums=(0,))
+        self._clear_pen_fn = _named_jit(
+            "arks_clear_penalties", sampler_mod.clear_slot_penalties,
             donate_argnums=(0,))
 
         # Free/pending slots park their lengths at this write-drop value;
@@ -1995,7 +2072,9 @@ class InferenceEngine:
                 body, (cache, tokens, lengths, sstate), None, length=K)
             return cache, sstate, toks  # toks [K, B]
 
-        self._decode_fn = jax.jit(decode_loop, donate_argnums=(1, 4))
+        self._decode_fn = _named_jit(
+            "arks_decode", decode_loop,
+            donate_argnums=(1, 4))
 
         def decode_loop_lp(params, cache, tokens, lengths, sstate, tables,
                            gtables):
@@ -2018,7 +2097,9 @@ class InferenceEngine:
                 body, (cache, tokens, lengths, sstate), None, length=K)
             return cache, sstate, outs  # ([K,B], [K,B], [K,B,L], [K,B,L])
 
-        self._decode_lp_fn = jax.jit(decode_loop_lp, donate_argnums=(1, 4))
+        self._decode_lp_fn = _named_jit(
+            "arks_decode_lp", decode_loop_lp,
+            donate_argnums=(1, 4))
 
         # Pipelined decode program (ARKS_PIPELINE_DEPTH): the fused loop
         # with DEVICE-RESIDENT state — tokens/lengths/liveness come in as
@@ -2071,10 +2152,11 @@ class InferenceEngine:
                         tokens, lengths, alive)
             return cache, sstate, toks, tokens, lengths, alive
 
-        self._decode_pipe_fn = jax.jit(
-            functools.partial(decode_pipe, want_lp=False),
+        self._decode_pipe_fn = _named_jit(
+            "arks_decode_pipe", functools.partial(decode_pipe, want_lp=False),
             donate_argnums=(1, 2, 3, 4, 7))
-        self._decode_pipe_lp_fn = jax.jit(
+        self._decode_pipe_lp_fn = _named_jit(
+            "arks_decode_pipe_lp",
             functools.partial(decode_pipe, want_lp=True),
             donate_argnums=(1, 2, 3, 4, 7))
 
@@ -2100,31 +2182,38 @@ class InferenceEngine:
                     params, cfg, cache, tables, tokens, token_slot,
                     token_pos, sample_src, seq_q_start, seq_q_len,
                     seq_pos_start, mesh)
-                ovc = ov_mask[:, None]
-                # Completion lanes sample with transient first-token
-                # semantics: penalties are identity (their output is
-                # empty — counts don't matter once presence/frequency are
-                # zeroed), bias/suppression/guide come from the override
-                # columns, and min_until is pre-shifted by the host so
-                # ``lengths < min_until`` reads as the min_first flag.
-                eff = sampling._replace(
-                    temperature=jnp.where(ov_mask, ov_temp,
-                                          sampling.temperature),
-                    top_p=jnp.where(ov_mask, ov_top_p, sampling.top_p),
-                    top_k=jnp.where(ov_mask, ov_top_k, sampling.top_k),
-                    key=jnp.where(ovc, ov_key, sampling.key),
-                    presence=jnp.where(ov_mask, 0.0, sampling.presence),
-                    frequency=jnp.where(ov_mask, 0.0, sampling.frequency),
-                    bias_ids=jnp.where(ovc, ov_bias_ids, sampling.bias_ids),
-                    bias_vals=jnp.where(ovc, ov_bias_vals,
-                                        sampling.bias_vals),
-                    suppress_ids=jnp.where(ovc, ov_sup,
-                                           sampling.suppress_ids),
-                    min_until=jnp.where(ov_mask, ov_min_until,
-                                        sampling.min_until),
-                    guide=jnp.where(ov_mask, ov_guide, sampling.guide),
-                    guide_row=jnp.where(ov_mask, ov_guide_row,
-                                        sampling.guide_row))
+                # The override columns are sampler work too (arks.sampler
+                # in a profile, like the sampler's own functions).
+                with jax.named_scope("arks.sampler"):
+                    ovc = ov_mask[:, None]
+                    # Completion lanes sample with transient first-token
+                    # semantics: penalties are identity (their output is
+                    # empty — counts don't matter once presence/frequency
+                    # are zeroed), bias/suppression/guide come from the
+                    # override columns, and min_until is pre-shifted by
+                    # the host so ``lengths < min_until`` reads as the
+                    # min_first flag.
+                    eff = sampling._replace(
+                        temperature=jnp.where(ov_mask, ov_temp,
+                                              sampling.temperature),
+                        top_p=jnp.where(ov_mask, ov_top_p, sampling.top_p),
+                        top_k=jnp.where(ov_mask, ov_top_k, sampling.top_k),
+                        key=jnp.where(ovc, ov_key, sampling.key),
+                        presence=jnp.where(ov_mask, 0.0,
+                                           sampling.presence),
+                        frequency=jnp.where(ov_mask, 0.0,
+                                            sampling.frequency),
+                        bias_ids=jnp.where(ovc, ov_bias_ids,
+                                           sampling.bias_ids),
+                        bias_vals=jnp.where(ovc, ov_bias_vals,
+                                            sampling.bias_vals),
+                        suppress_ids=jnp.where(ovc, ov_sup,
+                                               sampling.suppress_ids),
+                        min_until=jnp.where(ov_mask, ov_min_until,
+                                            sampling.min_until),
+                        guide=jnp.where(ov_mask, ov_guide, sampling.guide),
+                        guide_row=jnp.where(ov_mask, ov_guide_row,
+                                            sampling.guide_row))
                 ids, eff2 = sampler_mod.sample(logits, eff, feed_active,
                                                lengths,
                                                guide_tables=gtables)
@@ -2138,10 +2227,11 @@ class InferenceEngine:
                     return ids, clp, vals, lids, cache, sampling
                 return ids, cache, sampling
 
-            self._mixed_fn = jax.jit(
-                functools.partial(mixed_prog, want_lp=False),
+            self._mixed_fn = _named_jit(
+                "arks_mixed_seq", functools.partial(mixed_prog, want_lp=False),
                 donate_argnums=(1, 2))
-            self._mixed_lp_fn = jax.jit(
+            self._mixed_lp_fn = _named_jit(
+                "arks_mixed_seq_lp",
                 functools.partial(mixed_prog, want_lp=True),
                 donate_argnums=(1, 2))
 
@@ -2183,10 +2273,12 @@ class InferenceEngine:
                 return (cache, sstate, nxt[None], tokens_out, lengths,
                         alive)
 
-            self._mixed_pipe_fn = jax.jit(
+            self._mixed_pipe_fn = _named_jit(
+                "arks_mixed_pipe",
                 functools.partial(mixed_pipe, want_lp=False),
                 donate_argnums=(1, 2, 3, 4, 7))
-            self._mixed_pipe_lp_fn = jax.jit(
+            self._mixed_pipe_lp_fn = _named_jit(
+                "arks_mixed_pipe_lp",
                 functools.partial(mixed_pipe, want_lp=True),
                 donate_argnums=(1, 2, 3, 4, 7))
 
@@ -2201,8 +2293,9 @@ class InferenceEngine:
                 _, ks, vs = tf.prefill(dparams, dcfg, tokens, length, mesh)
                 return tf.insert(dcache, ks, vs, slot)
 
-            self._draft_prefill_fn = jax.jit(draft_prefill_insert,
-                                             donate_argnums=(1,))
+            self._draft_prefill_fn = _named_jit(
+                "arks_draft_prefill", draft_prefill_insert,
+                donate_argnums=(1,))
 
             def draft_propose(dparams, dcache, tokens, lengths, sstate):
                 """DK-step draft scan: propose DK-1 tokens per lane (greedy
@@ -2329,10 +2422,12 @@ class InferenceEngine:
                             dcache, sampling)
                 return out, counts, comp_ids, cache, dcache, sampling
 
-            self._spec_mixed_fn = jax.jit(
+            self._spec_mixed_fn = _named_jit(
+                "arks_spec_mixed",
                 functools.partial(spec_mixed_prog, want_lp=False),
                 donate_argnums=(2, 3, 4))
-            self._spec_mixed_lp_fn = jax.jit(
+            self._spec_mixed_lp_fn = _named_jit(
+                "arks_spec_mixed_lp",
                 functools.partial(spec_mixed_prog, want_lp=True),
                 donate_argnums=(2, 3, 4))
 
@@ -2395,10 +2490,11 @@ class InferenceEngine:
                 return (cache, dcache, sstate, toks, counts, tokens_out,
                         lengths, alive)
 
-            self._spec_pipe_fn = jax.jit(
-                functools.partial(spec_pipe, want_lp=False),
+            self._spec_pipe_fn = _named_jit(
+                "arks_spec_pipe", functools.partial(spec_pipe, want_lp=False),
                 donate_argnums=(2, 3, 4, 5, 6, 10))
-            self._spec_pipe_lp_fn = jax.jit(
+            self._spec_pipe_lp_fn = _named_jit(
+                "arks_spec_pipe_lp",
                 functools.partial(spec_pipe, want_lp=True),
                 donate_argnums=(2, 3, 4, 5, 6, 10))
 
@@ -2877,8 +2973,20 @@ class InferenceEngine:
                 if prof.active:
                     # Stamp the live span ids into the device timeline so
                     # the profile correlates back to the trace store.
+                    # ``phase.step.loop`` is the step as this loop calls
+                    # it.  Its sections are begin/end pairs, and a thread
+                    # asked for the GIL gives it up right AFTER a call
+                    # returns, so a wait to get it back falls between one
+                    # section's end and the next one's begin (on the chip:
+                    # 5-15 ms at the return of step(), behind the handler
+                    # threads the fan-out woke); this span's end comes
+                    # after that wait, so the time has a name.
                     with prof.annotate("arks_step", self.trace.live_ids()):
-                        progressed = self.step()
+                        self.trace.evt("", "phase.step.loop", "B")
+                        try:
+                            progressed = self.step()
+                        finally:
+                            self.trace.evt("", "phase.step.loop", "E")
                 else:
                     progressed = self.step()
                 self._consec_faults = 0
@@ -3359,10 +3467,18 @@ class InferenceEngine:
         stream land in whichever phase fetches first — the breakdown
         attributes WALL time, not device time."""
         t0 = time.monotonic()
+        # ``phase.step.head``: everything of a step before its dispatch
+        # (recovery, elastic, guide, park and swap servicing, the pipeline
+        # checks), so that host time there has a name in a traced slice.
+        sec = self.profiler.sections
+        if sec:
+            self.trace.evt("", "phase.step.head", "B")
         self._maybe_finish_recovery()
         if not self._armed:
             # Scaled to zero: no device state exists — the only work is
             # re-arming on demand (a queue arrival or a posted resize).
+            if sec:
+                self.trace.evt("", "phase.step.head", "E")
             return self._step_disarmed(block_s)
         worked = False
         if self._resize_req is not None or self._idle_zero_s:
@@ -3373,6 +3489,8 @@ class InferenceEngine:
             if not self._armed:
                 # This step scaled the engine to zero; nothing below may
                 # touch the dropped device state.
+                if sec:
+                    self.trace.evt("", "phase.step.head", "E")
                 return True
             te = time.monotonic()
             if te - t0 > 1e-4:
@@ -3408,6 +3526,8 @@ class InferenceEngine:
             # oldest resolves (lagged host view) only once the pipeline is
             # full, so the device never waits on Python between
             # dispatches.
+            if sec:
+                self.trace.evt("", "phase.step.head", "E")
             self._step_pipelined()
             self.metrics.scheduler_seconds_total.inc(
                 time.monotonic() - t0, phase="decode")
@@ -3427,6 +3547,8 @@ class InferenceEngine:
             # Depth-0 sampler fusion: steady-state pure decode rides the
             # fused attention+sampler program with an immediate resolve —
             # one device program per step, no host-side sampler prep.
+            if sec:
+                self.trace.evt("", "phase.step.head", "E")
             self._step_fused()
             self.metrics.scheduler_seconds_total.inc(
                 time.monotonic() - t0, phase="mixed")
@@ -3485,6 +3607,8 @@ class InferenceEngine:
                 self.metrics.scheduler_seconds_total.inc(dt, phase="preempt")
         elif self._queue_aging_s:
             self._queue_age_tick()
+        if sec:
+            self.trace.evt("", "phase.step.head", "E")
         pending = None
         issued = False
         if self._mixed:
@@ -3503,7 +3627,7 @@ class InferenceEngine:
             if issued:
                 self.metrics.scheduler_seconds_total.inc(t1 - t0,
                                                          phase=phase)
-            worked = self._admit() or worked or issued
+            worked = self._admit_section() or worked or issued
             t2 = time.monotonic()
             if t2 - t1 > 1e-4:
                 self.metrics.scheduler_seconds_total.inc(t2 - t1,
@@ -3522,7 +3646,7 @@ class InferenceEngine:
             t1 = time.monotonic()
             if issued:
                 self.metrics.scheduler_seconds_total.inc(t1 - t0, phase="decode")
-            worked = self._admit() or worked or issued
+            worked = self._admit_section() or worked or issued
             t2 = time.monotonic()
             if t2 - t1 > 1e-4:
                 self.metrics.scheduler_seconds_total.inc(t2 - t1, phase="admit")
@@ -3543,44 +3667,59 @@ class InferenceEngine:
                 self.metrics.scheduler_seconds_total.inc(
                     time.monotonic() - t2, phase="decode")
                 worked = True
-        if self._pending_admits:
-            # Deferred admissions: resolve whatever the device finished
-            # while this step ran (the decode resolve above usually means
-            # earlier admit programs are done too).  When nothing else
-            # made progress, BLOCK on the oldest — a pending admission
-            # must never starve behind an empty queue.
-            t4 = time.monotonic()
-            worked = self._drain_ready_admits(force_one=not worked) or worked
-            self.metrics.scheduler_seconds_total.inc(
-                time.monotonic() - t4, phase="admit")
-        if not worked and (self._awaiting_restore or self._spills
-                           or self._awaiting_fetch
-                           or self._disk_spill_pending
-                           or self._swap_pending or self._swapped
-                           or self._awaiting_model or self._model_loads
-                           or self._resize_req is not None):
-            # Parked restores / in-flight spills / pending model loads
-            # resolve on DEVICE (or loader-thread) time, not queue
-            # arrivals: poll again shortly instead of blocking on the
-            # admission queue for block_s.
-            time.sleep(0.001)
+        # ``phase.step.tail``: what is left of a step after its resolve (the
+        # deferred admissions below have phase.admit inside it).
+        if sec:
+            self.trace.evt("", "phase.step.tail", "B")
+        try:
+            if self._pending_admits:
+                # Deferred admissions: resolve whatever the device finished
+                # while this step ran (the decode resolve above usually means
+                # earlier admit programs are done too).  When nothing else
+                # made progress, BLOCK on the oldest — a pending admission
+                # must never starve behind an empty queue.
+                t4 = time.monotonic()
+                if sec:
+                    self.trace.evt("", "phase.admit", "B")
+                try:
+                    worked = (self._drain_ready_admits(force_one=not worked)
+                              or worked)
+                finally:
+                    if sec:
+                        self.trace.evt("", "phase.admit", "E", 0)
+                self.metrics.scheduler_seconds_total.inc(
+                    time.monotonic() - t4, phase="admit")
+            if not worked and (self._awaiting_restore or self._spills
+                               or self._awaiting_fetch
+                               or self._disk_spill_pending
+                               or self._swap_pending or self._swapped
+                               or self._awaiting_model or self._model_loads
+                               or self._resize_req is not None):
+                # Parked restores / in-flight spills / pending model loads
+                # resolve on DEVICE (or loader-thread) time, not queue
+                # arrivals: poll again shortly instead of blocking on the
+                # admission queue for block_s.
+                time.sleep(0.001)
+                return True
+            if not worked:
+                # Idle housekeeping: an abort that raced _finish (or targeted
+                # a request that never existed) must not linger in the set
+                # forever — the busy-path purges only run while slots exist.
+                self._purge_stale_aborts()
+                # Idle: wait briefly for a request, then try admission again.
+                try:
+                    _, _, req = self._queue.get(timeout=block_s)
+                except queue.Empty:
+                    return False
+                pre = self._preadmit(req)
+                if pre is not None:
+                    self._resolve_admit_batch(
+                        self._issue_admit_batch([pre], pre[0].params.logprobs
+                                                is not None))
             return True
-        if not worked:
-            # Idle housekeeping: an abort that raced _finish (or targeted
-            # a request that never existed) must not linger in the set
-            # forever — the busy-path purges only run while slots exist.
-            self._purge_stale_aborts()
-            # Idle: wait briefly for a request, then try admission again.
-            try:
-                _, _, req = self._queue.get(timeout=block_s)
-            except queue.Empty:
-                return False
-            pre = self._preadmit(req)
-            if pre is not None:
-                self._resolve_admit_batch(
-                    self._issue_admit_batch([pre], pre[0].params.logprobs
-                                            is not None))
-        return True
+        finally:
+            if sec:
+                self.trace.evt("", "phase.step.tail", "E")
 
     @staticmethod
     def _admit_batch_sizes() -> tuple[int, ...]:
@@ -3603,6 +3742,18 @@ class InferenceEngine:
             raise ValueError(
                 f"ARKS_ADMIT_BATCH_SIZES={raw!r}: sizes must be >= 1")
         return tuple(sorted(sizes | {1}, reverse=True))
+
+    def _admit_section(self) -> bool:
+        """``_admit()`` as the step loop calls it: inside a profiler
+        window it is the ``phase.admit`` section (arg: requests popped)."""
+        if not self.profiler.sections:
+            return self._admit()
+        n0 = self._admit_popped
+        self.trace.evt("", "phase.admit", "B")
+        try:
+            return self._admit()
+        finally:
+            self.trace.evt("", "phase.admit", "E", self._admit_popped - n0)
 
     def _admit(self) -> bool:
         """Admit waiting requests.  One-shot prompts are GROUPED by
@@ -3651,6 +3802,7 @@ class InferenceEngine:
                             request=req, seed=self._resolve_seed(req),
                             num_prompt=len(req.prompt_ids))]) from e
                 admitted = True
+                self._admit_popped += 1
                 pre = self._preadmit(req)
                 if pre is not None:
                     req, ids, padded = pre
@@ -7377,11 +7529,23 @@ class InferenceEngine:
             self._resolve_spills()
 
     def _pipe_issue(self) -> None:
-        """Issue one pipelined decode dispatch.  Fresh (pipeline cold):
-        device state is built from the host mirrors — the ONE host->device
-        state upload per run.  Threaded: the previous dispatch's returned
-        arrays feed this one untouched; only the block tables (host-owned
-        page bookkeeping) travel per dispatch."""
+        """Issue one pipelined decode dispatch (inside a profiler window:
+        the ``phase.decode.issue`` section, arg = dispatches in flight)."""
+        if not self.profiler.sections:
+            return self._pipe_issue_body()
+        self.trace.evt("", "phase.decode.issue", "B")
+        try:
+            self._pipe_issue_body()
+        finally:
+            self.trace.evt("", "phase.decode.issue", "E",
+                           len(self._pipe_inflight))
+
+    def _pipe_issue_body(self) -> None:
+        """Fresh (pipeline cold): device state is built from the host
+        mirrors — the ONE host->device state upload per run.  Threaded:
+        the previous dispatch's returned arrays feed this one untouched;
+        only the block tables (host-owned page bookkeeping) travel per
+        dispatch."""
         K = self._pipe_rows
         fresh = self._pipe_state is None
         if fresh:
@@ -7487,11 +7651,24 @@ class InferenceEngine:
                 self._switch_stats["max_depth"] = len(self._pipe_inflight)
 
     def _pipe_resolve_one(self) -> None:
-        """Resolve the OLDEST in-flight dispatch on the lagged host view:
-        fan its tokens out, apply the host-only semantics (stop tokens,
-        max_tokens truncation, logprob formatting), and retire finished
-        slots — whose overshoot tokens in NEWER in-flight dispatches are
-        discarded by the (slot, gen) snapshot guard."""
+        """Resolve the OLDEST in-flight dispatch (inside a profiler window:
+        the ``phase.decode.resolve`` section, arg = dispatches still in
+        flight after it)."""
+        if not self.profiler.sections:
+            return self._pipe_resolve_body()
+        self.trace.evt("", "phase.decode.resolve", "B")
+        try:
+            self._pipe_resolve_body()
+        finally:
+            self.trace.evt("", "phase.decode.resolve", "E",
+                           len(self._pipe_inflight))
+
+    def _pipe_resolve_body(self) -> None:
+        """On the lagged host view: fan the dispatch's tokens out, apply
+        the host-only semantics (stop tokens, max_tokens truncation,
+        logprob formatting), and retire finished slots — whose overshoot
+        tokens in NEWER in-flight dispatches are discarded by the (slot,
+        gen) snapshot guard."""
         (snapshot, want_lp, toks, lp_devs, K, t0,
          counts_dev) = self._pipe_inflight.popleft()
         self._faults.fire("resolve")
@@ -7887,6 +8064,72 @@ class InferenceEngine:
             per += 2 * self._cache.k_scale.shape[3] * 4
         return per
 
+    # Step-section spans (``phase.<phase>.<section>``, docs/monitoring.md):
+    # engine-scope B/E pairs through trace.evt, recorded only while a
+    # profiler window is open (``self.profiler.sections``).  The sections
+    # the plain and the spec-mixed dispatch share live in the three helpers
+    # below; ``tag`` is ``"phase.mixed."`` or ``"phase.spec."``.
+
+    def _mixed_begin(self, rows: int, tag: str) -> bool:
+        """The ``retire`` section: honor aborts, retire slots that would
+        overflow (``rows`` decode rows per slot), upload guides and grow
+        the slots' pages.  False when no sequence needs the model.  Span
+        arg: slots retired."""
+        sec = self.profiler.sections
+        n0 = len(self._slots)
+        if sec:
+            self.trace.evt("", tag + "retire", "B")
+        try:
+            self._mixed_abort_and_retire(rows)
+            if not self._slots and not self._prefilling:
+                return False
+            self._ensure_guides_uploaded()
+            self._grow_slot_pages(rows)
+            return True
+        finally:
+            if sec:
+                self.trace.evt("", tag + "retire", "E",
+                               n0 - len(self._slots))
+
+    def _mixed_account(self, a: dict, rows: int, n_chunk: int, qmax: int,
+                       tag: str) -> None:
+        """The ``count`` section: the dispatch's counters, all from the
+        host-side batch arrays.  The budget counter rises only while a
+        prompt could have used the budget (one is prefilling or queued),
+        so chunk_tokens / chunk_budget_tokens is the share of the prefill
+        budget the steps took."""
+        sec = self.profiler.sections
+        if sec:
+            self.trace.evt("", tag + "count", "B")
+        self.metrics.mixed_batch_tokens.observe(rows)
+        if n_chunk:
+            self.metrics.mixed_chunk_tokens_total.inc(n_chunk)
+        if self._mixed_budget and (self._prefilling
+                                   or self._queue.qsize() > 0):
+            self.metrics.mixed_chunk_budget_tokens_total.inc(
+                self._mixed_budget)
+        self._mixed_grid_counters(a["seq_pos_start"], a["seq_q_len"], qmax)
+        if sec:
+            self.trace.evt("", tag + "count", "E")
+
+    def _mixed_finish_chunks(self, chunk_take, completing, ids, want_lp,
+                             lp_host, tag: str) -> None:
+        """Advance every prefilling sequence and, in the ``promote``
+        section (arg: prompts completed), promote those whose prompt
+        completed inside the batch."""
+        for slot, take in chunk_take:
+            st = self._prefilling.get(slot)
+            if st is not None:
+                st.pos += take
+        sec = self.profiler.sections
+        if sec:
+            self.trace.evt("", tag + "promote", "B")
+        try:
+            self._promote_completing(completing, ids, want_lp, lp_host)
+        finally:
+            if sec:
+                self.trace.evt("", tag + "promote", "E", len(completing))
+
     @_scoped("mixed")
     def _issue_mixed(self):
         """Build and issue ONE mixed dispatch: every decoding slot's next
@@ -7897,11 +8140,9 @@ class InferenceEngine:
         sampling columns packed into their lane; everything samples in the
         program's single sampler.sample call.  Returns the pending record
         for _resolve_mixed, or None when no sequence needs the model."""
-        self._mixed_abort_and_retire()
-        if not self._slots and not self._prefilling:
+        tag = "phase.mixed."
+        if not self._mixed_begin(1, tag):
             return None
-        self._ensure_guides_uploaded()
-        self._grow_slot_pages(1)
         self._faults.fire("decode")
         num_slots = self.ecfg.num_slots
         dec_slots = list(self._slots.keys())
@@ -7913,6 +8154,10 @@ class InferenceEngine:
                          if s not in self._residency.slots]
             if not dec_slots and not self._prefilling:
                 return None
+        sec = self.profiler.sections
+        evt = self.trace.evt
+        if sec:
+            evt("", tag + "pack", "B")
         a = self._mixed_batch_arrays(num_slots + self._mixed_budget)
 
         t = 0
@@ -7938,15 +8183,15 @@ class InferenceEngine:
         lengths = np.array(self._lengths)
         tables = self._tables.copy()
         n_chunk = sum(take for _, take in chunk_take)
-        self.metrics.mixed_batch_tokens.observe(t)
-        if n_chunk:
-            self.metrics.mixed_chunk_tokens_total.inc(n_chunk)
+        if sec:
+            evt("", tag + "pack", "E", (t, n_chunk, len(self._prefilling)))
         # qmax mirrors the dispatcher: t_flat - b_lanes + 1.
-        self._mixed_grid_counters(a["seq_pos_start"], a["seq_q_len"],
-                                  self._mixed_budget + 1)
+        self._mixed_account(a, t, n_chunk, self._mixed_budget + 1, tag)
         self._emit("mixed", tables=tables, lengths=lengths, lp=want_lp,
                    **a)
         t0 = time.monotonic()
+        if sec:
+            evt("", tag + "put", "B")
         args = (self.params, self._cache, self._sampling,
                 jnp.asarray(a["tokens"]), jnp.asarray(a["token_slot"]),
                 jnp.asarray(a["token_pos"]), jnp.asarray(tables),
@@ -7960,6 +8205,9 @@ class InferenceEngine:
                 jnp.asarray(a["ov_sup"]), jnp.asarray(a["ov_min_until"]),
                 jnp.asarray(a["ov_guide"]), jnp.asarray(a["ov_guide_row"]),
                 self._guide_dev)
+        if sec:
+            evt("", tag + "put", "E")
+            evt("", tag + "dispatch", "B")
         lp_devs = None
         if want_lp:
             ids_dev, clps, lvals, lids, self._cache, self._sampling = \
@@ -7967,6 +8215,9 @@ class InferenceEngine:
             lp_devs = (clps, lvals, lids)
         else:
             ids_dev, self._cache, self._sampling = self._mixed_fn(*args)
+        if sec:
+            evt("", tag + "dispatch", "E",
+                "arks_mixed_seq_lp" if want_lp else "arks_mixed_seq")
         return (dec_slots, completing, chunk_take, want_lp, ids_dev,
                 lp_devs, t0)
 
@@ -7980,6 +8231,11 @@ class InferenceEngine:
         (dec_slots, completing, chunk_take, want_lp, ids_dev,
          lp_devs, t0) = rec
         self._faults.fire("resolve")
+        tag = "phase.mixed."
+        sec = self.profiler.sections
+        evt = self.trace.evt
+        if sec:
+            evt("", tag + "wait", "B")
         t_wait = time.monotonic()
         ids = np.asarray(ids_dev)   # [B] — host sync point
         self.metrics.decode_resolve_wait_seconds_total.inc(
@@ -7988,6 +8244,10 @@ class InferenceEngine:
             clps = np.asarray(lp_devs[0])
             lvals = np.asarray(lp_devs[1])
             lids = np.asarray(lp_devs[2])
+        if sec:
+            evt("", tag + "wait", "E")
+            evt("", tag + "fanout", "B")
+        n_live = len(self._slots)
         dt = max(time.monotonic() - t0 - exclude_s, 1e-6)
         for slot in dec_slots:
             st = self._slots[slot]
@@ -8014,12 +8274,11 @@ class InferenceEngine:
                 st.request.outputs.put(RequestOutput(
                     request_id=st.request.request_id, token_ids=delta,
                     num_prompt_tokens=st.num_prompt, logprobs=lp_delta))
-        for slot, take in chunk_take:
-            st = self._prefilling.get(slot)
-            if st is not None:
-                st.pos += take
-        self._promote_completing(completing, ids, want_lp,
-                                 lp_devs and (clps, lvals, lids))
+        if sec:
+            evt("", tag + "fanout", "E",
+                (len(dec_slots), n_live - len(self._slots)))
+        self._mixed_finish_chunks(chunk_take, completing, ids, want_lp,
+                                  lp_devs and (clps, lvals, lids), tag)
 
     def _promote_completing(self, completing, ids, want_lp, lp_host) -> None:
         """Promote sequences whose prompt completed inside a mixed (or
@@ -8073,14 +8332,16 @@ class InferenceEngine:
         the target-only mixed path, sampled slots exact in distribution.
         Returns the pending record for _resolve_spec_mixed."""
         DK = self.ecfg.draft_len
-        self._mixed_abort_and_retire(rows=DK)
-        if not self._slots and not self._prefilling:
+        tag = "phase.spec."
+        if not self._mixed_begin(DK, tag):
             return None
-        self._ensure_guides_uploaded()
-        self._grow_slot_pages(DK)
         self._faults.fire("spec")
         num_slots = self.ecfg.num_slots
         spec_t = num_slots * DK
+        sec = self.profiler.sections
+        evt = self.trace.evt
+        if sec:
+            evt("", tag + "pack", "B")
         a = self._mixed_batch_arrays(spec_t + self._mixed_budget)
         spec_enable = np.zeros((num_slots,), bool)
 
@@ -8110,16 +8371,18 @@ class InferenceEngine:
         lengths = np.array(self._lengths)
         tables = self._tables.copy()
         n_chunk = sum(take for _, take in chunk_take)
-        self.metrics.mixed_batch_tokens.observe(
-            len(dec_slots) * DK + n_chunk)
-        if n_chunk:
-            self.metrics.mixed_chunk_tokens_total.inc(n_chunk)
-        self._mixed_grid_counters(
-            a["seq_pos_start"], a["seq_q_len"],
-            spec_t + self._mixed_budget - num_slots + 1)
+        rows = len(dec_slots) * DK + n_chunk
+        if sec:
+            evt("", tag + "pack", "E",
+                (rows, n_chunk, len(self._prefilling)))
+        self._mixed_account(a, rows, n_chunk,
+                            spec_t + self._mixed_budget - num_slots + 1,
+                            tag)
         self._emit("spec_mixed", tables=tables, lengths=lengths,
                    lp=want_lp, spec_enable=spec_enable.copy(), **a)
         t0 = time.monotonic()
+        if sec:
+            evt("", tag + "put", "B")
         args = (self.params, self._draft_params, self._cache,
                 self._draft_cache, self._sampling,
                 jnp.asarray(a["tokens"]), jnp.asarray(a["token_slot"]),
@@ -8134,6 +8397,9 @@ class InferenceEngine:
                 jnp.asarray(a["ov_bias_vals"]), jnp.asarray(a["ov_sup"]),
                 jnp.asarray(a["ov_min_until"]), jnp.asarray(a["ov_guide"]),
                 jnp.asarray(a["ov_guide_row"]), self._guide_dev)
+        if sec:
+            evt("", tag + "put", "E")
+            evt("", tag + "dispatch", "B")
         lp_devs = None
         if want_lp:
             (out_dev, counts_dev, comp_dev, clps, lvals, lids, self._cache,
@@ -8143,6 +8409,9 @@ class InferenceEngine:
         else:
             (out_dev, counts_dev, comp_dev, self._cache, self._draft_cache,
              self._sampling) = self._spec_mixed_fn(*args)
+        if sec:
+            evt("", tag + "dispatch", "E",
+                "arks_spec_mixed_lp" if want_lp else "arks_spec_mixed")
         return (dec_slots, completing, chunk_take, want_lp, out_dev,
                 counts_dev, comp_dev, lp_devs, t0)
 
@@ -8156,6 +8425,11 @@ class InferenceEngine:
          comp_dev, lp_devs, t0) = rec
         self._faults.fire("resolve")
         DK = self.ecfg.draft_len
+        tag = "phase.spec."
+        sec = self.profiler.sections
+        evt = self.trace.evt
+        if sec:
+            evt("", tag + "wait", "B")
         t_wait = time.monotonic()
         out = np.asarray(out_dev)        # [B, DK] — host sync point
         counts = np.asarray(counts_dev)  # [B]
@@ -8166,6 +8440,10 @@ class InferenceEngine:
         if lp_devs is not None:
             lp_host = (np.asarray(lp_devs[0]), np.asarray(lp_devs[1]),
                        np.asarray(lp_devs[2]))
+        if sec:
+            evt("", tag + "wait", "E")
+            evt("", tag + "fanout", "B")
+        n_live = len(self._slots)
         dt = max(time.monotonic() - t0 - exclude_s, 1e-6)
         n_spec = accepted = 0
         for slot in dec_slots:
@@ -8191,11 +8469,11 @@ class InferenceEngine:
             self._spec_accepted += accepted
             self.metrics.spec_decode_acceptance_rate.set(
                 self._spec_accepted / max(self._spec_proposed, 1))
-        for slot, take in chunk_take:
-            st = self._prefilling.get(slot)
-            if st is not None:
-                st.pos += take
-        self._promote_completing(completing, comp, want_lp, lp_host)
+        if sec:
+            evt("", tag + "fanout", "E",
+                (len(dec_slots), n_live - len(self._slots)))
+        self._mixed_finish_chunks(chunk_take, completing, comp, want_lp,
+                                  lp_host, tag)
 
     # ------------------------------------------------------------------
     # Stop handling

@@ -23,6 +23,12 @@ TOP_LOGPROBS_MAX = 8
 _NP_KEY_OK: bool | None = None
 
 
+# Everything the sampler does inside a step program reads as ``arks.sampler``
+# in a profile (docs/monitoring.md; metadata only, the program is unchanged).
+_in_profile = jax.named_scope("arks.sampler")
+
+
+
 def np_prng_key(seed: int) -> np.ndarray:
     """Host-side ``jax.random.PRNGKey`` for the default threefry impl —
     byte-identical key data with ZERO device dispatches.  PRNGKey costs a
@@ -51,6 +57,7 @@ def np_prng_key(seed: int) -> np.ndarray:
     return np.array([0, seed & 0xFFFFFFFF], np.uint32)
 
 
+@_in_profile
 def top_logprobs(logits: jnp.ndarray, chosen: jnp.ndarray
                  ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Logprob data for OpenAI ``logprobs`` responses: (chosen token's
@@ -88,6 +95,7 @@ def np_stop_col(stop_ids) -> np.ndarray | None:
     return col
 
 
+@_in_profile
 def advance_liveness(toks: jnp.ndarray, alive: jnp.ndarray,
                      lengths: jnp.ndarray, stop_ids: jnp.ndarray,
                      dead_len: jnp.ndarray) -> jnp.ndarray:
@@ -320,6 +328,7 @@ def clear_slot_penalties(state: SamplingState,
         guide_row=state.guide_row.at[slot].set(0))
 
 
+@_in_profile
 def count_tokens(state: SamplingState, tokens: jnp.ndarray,
                  active: jnp.ndarray | None = None) -> SamplingState:
     """Record one emitted token per slot (called on the tokens FED to a
@@ -461,6 +470,7 @@ def filtered_probs(logits: jnp.ndarray, state: SamplingState
     return jax.nn.softmax(scaled, axis=-1), idx, scaled
 
 
+@_in_profile
 def sample(logits: jnp.ndarray, state: SamplingState,
            active: jnp.ndarray | None = None,
            lengths: jnp.ndarray | None = None,
@@ -496,6 +506,7 @@ def sample(logits: jnp.ndarray, state: SamplingState,
     return ids, state
 
 
+@_in_profile
 def draft_sample(logits: jnp.ndarray, state: SamplingState, keys: jnp.ndarray
                  ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray,
                             jnp.ndarray, jnp.ndarray]:
@@ -516,6 +527,7 @@ def draft_sample(logits: jnp.ndarray, state: SamplingState, keys: jnp.ndarray
     return tok, q, probs, idx, carry_keys
 
 
+@_in_profile
 def speculative_accept(
     drafts: jnp.ndarray,        # [B, K-1] draft proposals
     q_sel: jnp.ndarray,         # [B, K-1] q(draft) under the draft dist

@@ -126,14 +126,17 @@ def moe_ffn_grouped(x: jnp.ndarray, mp: Params, cfg) -> jnp.ndarray:
 
     from arks_tpu.models.quant import dequantize
 
-    logits = jnp.einsum("te,ex->tx", x2, mp["router"])
-    vals, idx = router_topk(logits, cfg)                    # [T, k]
+    # Profile scopes (docs/monitoring.md): routing and the sort into expert
+    # order; the dequantised expert weights; the grouped matmuls.
+    with jax.named_scope("arks.moe_route"):
+        logits = jnp.einsum("te,ex->tx", x2, mp["router"])
+        vals, idx = router_topk(logits, cfg)                # [T, k]
 
-    flat_expert = idx.reshape(-1)                           # [T*k]
-    order = jnp.argsort(flat_expert)
-    token_of = order // k                                   # source token
-    xs = jnp.take(x2, token_of, axis=0)                     # [T*k, E] sorted
-    group_sizes = jnp.bincount(flat_expert, length=nx)
+        flat_expert = idx.reshape(-1)                       # [T*k]
+        order = jnp.argsort(flat_expert)
+        token_of = order // k                               # source token
+        xs = jnp.take(x2, token_of, axis=0)                 # [T*k, E] sorted
+        group_sizes = jnp.bincount(flat_expert, length=nx)
 
     from arks_tpu.ops.moe_kernel import grouped_ffn, moe_impl
     if moe_impl() == "pallas":
@@ -142,22 +145,33 @@ def moe_ffn_grouped(x: jnp.ndarray, mp: Params, cfg) -> jnp.ndarray:
         # scales dequant the weight tile in-register — either way the
         # full-width expert weights never materialize in HBM (ragged_dot
         # below forces exactly that materialization).
-        down = grouped_ffn(xs, jnp.take(flat_expert, order), group_sizes,
-                           mp["w_gate"], mp["w_up"], mp["w_down"], x.dtype)
+        with jax.named_scope("arks.moe_dot"):
+            down = grouped_ffn(xs, jnp.take(flat_expert, order),
+                               group_sizes, mp["w_gate"], mp["w_up"],
+                               mp["w_down"], x.dtype)
     else:
         # ragged_dot needs plain arrays; dequantized expert weights
         # materialize here (prefill-only path — dense/decode keeps the
         # fused dequant).
-        gate = jax.lax.ragged_dot(xs, dequantize(mp["w_gate"], x.dtype),
-                                  group_sizes)
-        up = jax.lax.ragged_dot(xs, dequantize(mp["w_up"], x.dtype),
-                                group_sizes)
-        act = jax.nn.silu(gate.astype(jnp.float32)).astype(gate.dtype) * up
-        down = jax.lax.ragged_dot(act, dequantize(mp["w_down"], x.dtype),
-                                  group_sizes)              # [T*k, E]
+        def grouped(rows, w):
+            # Traced in the order it always was (dequantise, contract,
+            # three times over): the scopes add names, not a schedule.
+            with jax.named_scope("arks.moe_dequant"):
+                w = dequantize(w, x.dtype)
+            with jax.named_scope("arks.moe_dot"):
+                return jax.lax.ragged_dot(rows, w, group_sizes)
 
-    w = jnp.take(vals.reshape(-1), order).astype(down.dtype)   # [T*k]
-    out = jnp.zeros((n, e), down.dtype).at[token_of].add(down * w[:, None])
+        gate = grouped(xs, mp["w_gate"])
+        up = grouped(xs, mp["w_up"])
+        with jax.named_scope("arks.moe_dot"):
+            act = jax.nn.silu(gate.astype(jnp.float32)).astype(
+                gate.dtype) * up
+        down = grouped(act, mp["w_down"])                   # [T*k, E]
+
+    with jax.named_scope("arks.moe_route"):
+        w = jnp.take(vals.reshape(-1), order).astype(down.dtype)   # [T*k]
+        out = jnp.zeros((n, e), down.dtype).at[token_of].add(
+            down * w[:, None])
 
     if cfg.shared_expert_intermediate_size:
         from arks_tpu.models.quant import qeinsum
@@ -190,16 +204,20 @@ def moe_ffn(x: jnp.ndarray, mp: Params, cfg, constrain=None,
         return moe_ffn_grouped(x, mp, cfg)
     from arks_tpu.models.quant import qeinsum
 
-    logits = jnp.einsum("...e,ex->...x", x, mp["router"])
-    weights = router_weights(logits, cfg).astype(x.dtype)  # [.., X]
+    with jax.named_scope("arks.moe_route"):
+        logits = jnp.einsum("...e,ex->...x", x, mp["router"])
+        weights = router_weights(logits, cfg).astype(x.dtype)  # [.., X]
 
-    gate = qeinsum("...e,xef->...xf", x, mp["w_gate"])
-    up = qeinsum("...e,xef->...xf", x, mp["w_up"])
-    act = jax.nn.silu(gate.astype(jnp.float32)).astype(gate.dtype) * up
-    if constrain is not None:
-        act = constrain(act, act.ndim - 2)
-    down = qeinsum("...xf,xfe->...xe", act, mp["w_down"])  # per-expert out
-    out = jnp.einsum("...xe,...x->...e", down, weights)       # psum over EP
+    # The dense dispatch keeps the dequant fused into the contraction, so
+    # it has no arks.moe_dequant of its own.
+    with jax.named_scope("arks.moe_dot"):
+        gate = qeinsum("...e,xef->...xf", x, mp["w_gate"])
+        up = qeinsum("...e,xef->...xf", x, mp["w_up"])
+        act = jax.nn.silu(gate.astype(jnp.float32)).astype(gate.dtype) * up
+        if constrain is not None:
+            act = constrain(act, act.ndim - 2)
+        down = qeinsum("...xf,xfe->...xe", act, mp["w_down"])  # per expert
+        out = jnp.einsum("...xe,...x->...e", down, weights)    # psum over EP
 
     if cfg.shared_expert_intermediate_size:
         sg = qeinsum("...e,ef->...f", x, mp["shared_gate_proj"])

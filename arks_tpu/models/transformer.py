@@ -317,6 +317,14 @@ def shard_cache(cache: KVCache, cfg: ModelConfig, mesh: Mesh) -> KVCache:
 # ---------------------------------------------------------------------------
 
 
+# Named scopes (``arks.<part>``) on the parts of the step a profile is read
+# by: they travel in each op's metadata, so a trace's device time can be
+# summed per part whatever the compiler numbers its fusions
+# (docs/monitoring.md lists them; benchmarks/layer_metrics/_scopes.py reads
+# them).  Scopes are metadata only: the compiled program is unchanged.
+_scope = jax.named_scope
+
+
 def _constrain(x: jnp.ndarray, mesh: Mesh | None, *spec) -> jnp.ndarray:
     if mesh is None or mesh.size == 1:
         return x
@@ -334,6 +342,7 @@ def _qkv(h: jnp.ndarray, lp: Params, cfg: ModelConfig):
     return q, k, v
 
 
+@_scope("arks.attn_qkv")
 def _block_qkv(h: jnp.ndarray, lp: Params, cfg: ModelConfig,
                positions: jnp.ndarray):
     """Pre-norm + qkv projection + head split + rope for a [B, T, E] block —
@@ -354,11 +363,16 @@ def _block_tail(h: jnp.ndarray, attn: jnp.ndarray, lp: Params,
                 seq_axis: str | None = None) -> jnp.ndarray:
     """Output projection residual + MLP residual (post-attention half of the
     block) — the other shared piece of the prefill paths."""
-    h = h + qeinsum("...q,qe->...e", attn, lp["wo"])
+    with _scope("arks.attn_out"):
+        h = h + qeinsum("...q,qe->...e", attn, lp["wo"])
     h = h + _mlp(h, lp, cfg, mesh, batch_axis, seq_axis)
     return h
 
 
+# A routed model's parts carry scopes of their own inside moe.py
+# (arks.moe_route / moe_dequant / moe_dot); the innermost scope names an op,
+# so arks.ffn is what is left: the norm, and a dense FFN whole.
+@_scope("arks.ffn")
 def _mlp(h: jnp.ndarray, lp: Params, cfg: ModelConfig, mesh: Mesh | None,
          batch_axis: str | None, seq_axis: str | None = None) -> jnp.ndarray:
     x = rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps)
@@ -394,6 +408,7 @@ def _mlp(h: jnp.ndarray, lp: Params, cfg: ModelConfig, mesh: Mesh | None,
     return qeinsum("...f,fe->...e", act, lp["w_down"])
 
 
+@_scope("arks.lm_head")
 def _unembed(h_last: jnp.ndarray, params: Params, cfg: ModelConfig,
              mesh: Mesh | None, batch_axis: str | None) -> jnp.ndarray:
     h_last = rms_norm(h_last, params["final_norm"], cfg.rms_norm_eps)
@@ -909,8 +924,9 @@ def mixed_step(
     # RoPE positions must be real for valid tokens; padding rows only need
     # a value the cache ops drop (their write_idx is routed past coverage).
     rope_pos = jnp.minimum(token_pos, cover - 1)[None]           # [1, T]
-    h = embed_lookup(params["embed"], tokens[None],
-                     params["layers"]["attn_norm"].dtype)        # [1, T, E]
+    with _scope("arks.embed"):
+        h = embed_lookup(params["embed"], tokens[None],
+                         params["layers"]["attn_norm"].dtype)    # [1, T, E]
     kv_sharded = mesh is not None and shard_kv_heads(
         cfg, mesh.shape.get(AXIS_MODEL, 1))
     from arks_tpu.ops.attention import paged_mixed_update_and_attend
@@ -931,7 +947,8 @@ def mixed_step(
     (h, kc, vc, ksc, vsc), _ = jax.lax.scan(
         body, (h, cache.k, cache.v, cache.k_scale, cache.v_scale),
         (params["layers"], jnp.arange(cfg.num_layers, dtype=jnp.int32)))
-    h_sel = jnp.take(h[0], sample_src.astype(jnp.int32), axis=0)  # [B, E]
+    with _scope("arks.lm_head"):
+        h_sel = jnp.take(h[0], sample_src.astype(jnp.int32), axis=0)  # [B, E]
     logits = _unembed(h_sel, params, cfg, mesh, None)
     return logits, PagedKVCache(k=kc, v=vc, k_scale=ksc, v_scale=vsc)
 
@@ -1002,13 +1019,14 @@ def decode_step(
     def body(carry, xs):
         h, kc, vc, ksc, vsc = carry
         lp, layer = xs
-        x = rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps)
-        q, k, v = _qkv(x, lp, cfg)
-        q = q.reshape(b, cfg.num_heads, cfg.head_dim)
-        k = k.reshape(b, cfg.num_kv_heads, cfg.head_dim)
-        v = v.reshape(b, cfg.num_kv_heads, cfg.head_dim)
-        q = apply_rope(q, rope_idx, cfg.rope_theta)
-        k = apply_rope(k, rope_idx, cfg.rope_theta)
+        with _scope("arks.attn_qkv"):
+            x = rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps)
+            q, k, v = _qkv(x, lp, cfg)
+            q = q.reshape(b, cfg.num_heads, cfg.head_dim)
+            k = k.reshape(b, cfg.num_kv_heads, cfg.head_dim)
+            v = v.reshape(b, cfg.num_kv_heads, cfg.head_dim)
+            q = apply_rope(q, rope_idx, cfg.rope_theta)
+            k = apply_rope(k, rope_idx, cfg.rope_theta)
         if paged:
             from arks_tpu.ops.attention import paged_decode_update_and_attend
             attn, kc, vc, ksc, vsc = paged_decode_update_and_attend(
@@ -1020,7 +1038,8 @@ def decode_step(
                 kv_sharded, model_axis=AXIS_MODEL, k_scale=ksc, v_scale=vsc)
         attn = attn.reshape(b, cfg.q_dim)
         attn = _constrain(attn, mesh, batch_axis, AXIS_MODEL)
-        h = h + qeinsum("bq,qe->be", attn, lp["wo"])
+        with _scope("arks.attn_out"):
+            h = h + qeinsum("bq,qe->be", attn, lp["wo"])
         h = h + _mlp(h, lp, cfg, mesh, batch_axis)
         return (h, kc, vc, ksc, vsc), None
 

@@ -172,6 +172,10 @@ class Tracer:
         self._open_eng: dict[str, list] = {}   # engine-scope B/E pairing
         self._engine_spans: collections.deque = collections.deque(maxlen=2048)
         self._phase_spans: collections.deque = collections.deque(maxlen=2048)
+        # Engine-scope spans of the open profiler window (None: no window).
+        # A list, not a ring: it is as long as the window, so a caller of
+        # close_window() gets the window's beginning whatever its length.
+        self._window: list | None = None
         self._thread: threading.Thread | None = None
         self._stopping = threading.Event()
 
@@ -322,11 +326,19 @@ class Tracer:
             self._open_eng.setdefault(name, []).append((t, arg))
             return
         if ph == "E" and self._open_eng.get(name):
-            t0, a0 = self._open_eng[name].pop(0)
+            # Scheduler phases nest on one thread (a "decode" dispatch
+            # wraps its "decode" issue), so an end closes the LATEST
+            # begin; a begin orphaned by a fault then pairs with nothing
+            # later.  Everything else (``pipe``: several dispatches in
+            # flight) closes the oldest.
+            t0, a0 = self._open_eng[name].pop(
+                -1 if name.startswith("phase.") else 0)
             span = {"name": name, "start": t0, "end": t,
                     "arg": arg if arg is not None else a0}
         else:
             span = {"name": name, "start": t, "end": t, "arg": arg}
+        if self._window is not None:
+            self._window.append(span)
         if name in _ATTACH_NAMES:
             self._engine_spans.append(span)
         else:
@@ -400,6 +412,26 @@ class Tracer:
     def phase_spans(self) -> list[dict]:
         """Recent engine-scope scheduler-phase spans (export only)."""
         return list(self._phase_spans)
+
+    def open_window(self) -> None:
+        """Start keeping every engine-scope span (``ProfilerWindows.start``).
+        What the rings held before this call is folded first and stays
+        out of the window."""
+        if not self.enabled:
+            return
+        with self._flush_lock:
+            self._drain()
+            self._window = []
+
+    def close_window(self) -> list[dict]:
+        """The engine-scope spans folded since :meth:`open_window`, oldest
+        first, with JSON-plain payloads (``ProfilerWindows.stop``)."""
+        if not self.enabled:
+            return []
+        with self._flush_lock:
+            self._drain()
+            spans, self._window = self._window or [], None
+        return [dict(sp, arg=_plain(sp["arg"])) for sp in spans]
 
 
 def _plain(arg):

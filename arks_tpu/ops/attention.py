@@ -435,24 +435,28 @@ def paged_mixed_update_and_attend(
 
     if not use_pallas:
         from arks_tpu.ops.paged_attention import paged_gather_kv, paged_update_xla
-        kp, vp, ks, vs = paged_update_xla(
-            k_pool, v_pool, k_scale, v_scale, k_new, v_new, write_idx,
-            tables_tok, layer)
-        # int4 pools gather through the nibble unpack — the oracle attend
-        # sees a plain per-token int8 view.
-        kc = paged_gather_kv(unpack_int4_pool(kp) if int4 else kp,
-                             tables_tok, layer)         # [T, Hkv, cover, D]
-        vc = paged_gather_kv(unpack_int4_pool(vp) if int4 else vp,
-                             tables_tok, layer)
-        attend_lens = jnp.where(token_slot < 0, 0, token_pos + 1)
-        if quantized:
-            ksc = paged_gather_kv(ks, tables_tok, layer)
-            vsc = paged_gather_kv(vs, tables_tok, layer)
-            out = _decode_attention_xla_quant(
-                q.reshape(t_flat, hkv, g, d), kc, vc, ksc, vsc, attend_lens)
-        else:
-            out = decode_attention_xla(q.reshape(t_flat, hkv, g, d), kc, vc,
-                                       attend_lens)
+        # The XLA oracle has no layout step of its own: all of it reads as
+        # the kernel in a profile (scope names: docs/monitoring.md).
+        with jax.named_scope("arks.attn_kernel"):
+            kp, vp, ks, vs = paged_update_xla(
+                k_pool, v_pool, k_scale, v_scale, k_new, v_new, write_idx,
+                tables_tok, layer)
+            # int4 pools gather through the nibble unpack — the oracle
+            # attend sees a plain per-token int8 view.
+            kc = paged_gather_kv(unpack_int4_pool(kp) if int4 else kp,
+                                 tables_tok, layer)     # [T, Hkv, cover, D]
+            vc = paged_gather_kv(unpack_int4_pool(vp) if int4 else vp,
+                                 tables_tok, layer)
+            attend_lens = jnp.where(token_slot < 0, 0, token_pos + 1)
+            if quantized:
+                ksc = paged_gather_kv(ks, tables_tok, layer)
+                vsc = paged_gather_kv(vs, tables_tok, layer)
+                out = _decode_attention_xla_quant(
+                    q.reshape(t_flat, hkv, g, d), kc, vc, ksc, vsc,
+                    attend_lens)
+            else:
+                out = decode_attention_xla(q.reshape(t_flat, hkv, g, d), kc,
+                                           vc, attend_lens)
         return out.reshape(t_flat, h, d)[..., :d_model], kp, vp, ks, vs
 
     from arks_tpu.ops.paged_attention import (
@@ -467,28 +471,37 @@ def paged_mixed_update_and_attend(
 
     def local(qg, kn, vn, kp, vp, ks, vs, tbl, tok_tbl, widx, q_start,
               qlen, pos0, lyr):
-        if quantized:
-            kp, vp, ks, vs = paged_kv_update_quant(
-                kp, vp, ks, vs, kn, vn, widx, tok_tbl, lyr,
-                interpret=interpret)
-        else:
-            kp, vp = paged_kv_update(kp, vp, kn, vn, widx, tok_tbl, lyr,
-                                     interpret=interpret)
+        with jax.named_scope("arks.attn_kernel"):
+            if quantized:
+                kp, vp, ks, vs = paged_kv_update_quant(
+                    kp, vp, ks, vs, kn, vn, widx, tok_tbl, lyr,
+                    interpret=interpret)
+            else:
+                kp, vp = paged_kv_update(kp, vp, kn, vn, widx, tok_tbl, lyr,
+                                         interpret=interpret)
         hkv_l = qg.shape[1]
-        span = q_start[:, None] + jnp.arange(qmax, dtype=jnp.int32)
-        gather_idx = jnp.minimum(span, t_flat - 1)      # [B, Qmax]
-        qs = jnp.take(qg, gather_idx.reshape(-1), axis=0).reshape(
-            b_lanes, qmax, hkv_l, g, d)
-        qs = jnp.transpose(qs, (0, 2, 3, 1, 4))         # [B,Hkv,G,Qmax,D]
-        out_seq = paged_mixed_attention(qs, kp, vp, tbl, pos0, qlen, lyr,
-                                        k_scale=ks, v_scale=vs,
-                                        interpret=interpret)
-        rows = jnp.transpose(out_seq, (0, 3, 1, 2, 4)).reshape(
-            b_lanes * qmax, hkv_l, g, d)
-        q_valid = jnp.arange(qmax, dtype=jnp.int32)[None] < qlen[:, None]
-        scatter_idx = jnp.where(q_valid, span, t_flat)  # OOB rows dropped
-        out = jnp.zeros((t_flat, hkv_l, g, d), qg.dtype).at[
-            scatter_idx.reshape(-1)].set(rows)
+        # arks.attn_layout: everything between the projections and the
+        # Pallas call and back — the gather of the flat queries into one
+        # dense [Qmax] block per lane, the pad to the kernel's q blocks
+        # (inside paged_mixed_attention, whose pallas_call alone carries
+        # arks.attn_kernel: the innermost scope names an op) and the
+        # scatter back into the flat batch.
+        with jax.named_scope("arks.attn_layout"):
+            span = q_start[:, None] + jnp.arange(qmax, dtype=jnp.int32)
+            gather_idx = jnp.minimum(span, t_flat - 1)      # [B, Qmax]
+            qs = jnp.take(qg, gather_idx.reshape(-1), axis=0).reshape(
+                b_lanes, qmax, hkv_l, g, d)
+            qs = jnp.transpose(qs, (0, 2, 3, 1, 4))     # [B,Hkv,G,Qmax,D]
+            out_seq = paged_mixed_attention(qs, kp, vp, tbl, pos0, qlen,
+                                            lyr, k_scale=ks, v_scale=vs,
+                                            interpret=interpret)
+            rows = jnp.transpose(out_seq, (0, 3, 1, 2, 4)).reshape(
+                b_lanes * qmax, hkv_l, g, d)
+            q_valid = (jnp.arange(qmax, dtype=jnp.int32)[None]
+                       < qlen[:, None])
+            scatter_idx = jnp.where(q_valid, span, t_flat)  # OOB dropped
+            out = jnp.zeros((t_flat, hkv_l, g, d), qg.dtype).at[
+                scatter_idx.reshape(-1)].set(rows)
         return out, kp, vp, ks, vs
 
     qg = q.reshape(t_flat, hkv, g, d)

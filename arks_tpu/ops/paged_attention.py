@@ -1054,14 +1054,18 @@ def _paged_mixed_call(q, k_pool, v_pool, tables, pos_start, q_len, layer,
         # so the item axis is "arbitrary", never "parallel".
         dims = ("arbitrary",)
 
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        compiler_params=pltpu.CompilerParams(dimension_semantics=dims),
-        interpret=interpret,
-        name=f"paged_mixed_attention_{grid}",
-    )(*inputs)
+    # The call alone is the kernel in a profile; the pad before it and the
+    # slice and mask after it stay with the caller's scope (the layout
+    # work around the kernel: arks.attn_layout in the mixed step).
+    with jax.named_scope("arks.attn_kernel"):
+        out = pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=out_shape,
+            compiler_params=pltpu.CompilerParams(dimension_semantics=dims),
+            interpret=interpret,
+            name=f"paged_mixed_attention_{grid}",
+        )(*inputs)
     # Rows past q_len[s] are undefined (dense: skipped blocks; ragged:
     # never-visited items) — zero them so both grids return IDENTICAL
     # bytes everywhere, not just on the rows callers keep.

@@ -15,6 +15,16 @@ socket is closed (the server aborts what is still running) -> exit.
 Events go to stdout as JSON lines the parent reads; the per-request records
 go to ``--out``.
 
+``--tail-s T`` (a ``--trace 2`` run): everything up to the end of the drain
+is as above, except that the load does not stop at the window's close:
+callers keep cycling, arrivals continue (``traffic.py`` draws them from a
+continuation of its own).  At the end of the drain the records are written
+to ``--out`` as they stand then, less the requests sent after the window
+closed: what a run without a tail would have written.  Then ``tail`` is
+announced and the load goes on until the parent closes this process's
+standard input (its traced slice is done) or ``T`` seconds have passed; the
+records as they stand at the very end go to ``--out`` + ``.tail``.
+
 The SSE parsing is a copy of ``bench_serving.py::_client_main``'s (first
 content frame = first frame whose choice carries text); listed in PERF.md
 for a later PR to delete the original.
@@ -23,7 +33,9 @@ for a later PR to delete the original.
 from __future__ import annotations
 
 import argparse
+import copy
 import json
+import os
 import selectors
 import socket
 import sys
@@ -115,10 +127,14 @@ def _feed(st: Stream, data: bytes, now: float) -> None:
             rec["usage"] = obj["usage"]
 
 
-def run(args) -> dict:
+def run(args, write) -> tuple[dict, bool]:
+    """(the records as they stand at the end, whether a snapshot was
+    written at the end of the drain: ``write(result)`` is called for it)."""
     with open(args.mix) as f:
         mix = json.load(f)
-    sched = Schedule(mix, args.seed, load=args.load, seconds=args.seconds)
+    tail_s = max(args.tail_s, 0.0)
+    sched = Schedule(mix, args.seed, load=args.load, seconds=args.seconds,
+                     tail_s=mix["drain_s"] + tail_s if tail_s else 0.0)
     sel = selectors.DefaultSelector()
     records: list[dict] = []
     live: dict[int, Stream] = {}
@@ -137,9 +153,18 @@ def run(args) -> dict:
         live[st.sock.fileno()] = st
         sel.register(st.sock, selectors.EVENT_READ, st)
 
+    stop_asked = False
+
     def pump(timeout: float) -> None:
+        nonlocal stop_asked
         for key, _ in sel.select(max(timeout, 0.0)):
             st: Stream = key.data
+            if st is None:
+                # Standard input: the parent closed it (or wrote to it);
+                # the tail is over.
+                sel.unregister(sys.stdin)
+                stop_asked = True
+                continue
             try:
                 data = st.sock.recv(1 << 16)
             except BlockingIOError:
@@ -177,10 +202,22 @@ def run(args) -> dict:
     t_end = t_close + mix["drain_s"]
     emit(event="start", t0=t0, t_open=t_open, t_close=t_close,
          clients=sched.clients, offered=sched.count())
+
+    def result(recs: list[dict]) -> dict:
+        return {"t0": t0, "t_open": t_open, "t_close": t_close,
+                "t_end": t_end, "loop": sched.loop,
+                "clients": sched.clients, "offered": sched.count(),
+                "prime_requests": n_prime, "prime_failed": prime_failed,
+                "records": recs}
+
     nxt = 0
     if sched.loop == "closed":
         idle_clients.extend(range(sched.clients))
     announced_open = announced_close = False
+    t_stop = t_end + tail_s         # without a tail: the end of the drain
+    snapshot = None
+    if tail_s:
+        sel.register(sys.stdin, selectors.EVENT_READ, None)
     while True:
         now = time.monotonic()
         if not announced_open and now >= t_open:
@@ -189,11 +226,17 @@ def run(args) -> dict:
         if not announced_close and now >= t_close:
             emit(event="window_close", t=now, live=len(live))
             announced_close = True
-        if now >= t_end:
+        if tail_s and snapshot is None and now >= t_end:
+            snapshot = copy.deepcopy([r for r in records
+                                      if r["sent"] < t_close])
+            write(result(snapshot))
+            emit(event="tail", t=now, t_end=t_end, live=len(live))
+        if now >= t_stop or stop_asked:
             break
-        wait = min(t_end, t_close if not announced_close else t_end,
-                   t_open if not announced_open else t_end) - now
-        if now < t_close:
+        wait = min(t_stop, t_end if snapshot is None else t_stop,
+                   t_close if not announced_close else t_stop,
+                   t_open if not announced_open else t_stop) - now
+        if now < t_close or tail_s:
             if sched.loop == "closed":
                 while idle_clients:
                     start(sched.request(nxt), None, idle_clients.pop())
@@ -208,10 +251,7 @@ def run(args) -> dict:
     for st in list(live.values()):
         sel.unregister(st.sock)
         st.sock.close()
-    return {"t0": t0, "t_open": t_open, "t_close": t_close, "t_end": t_end,
-            "loop": sched.loop, "clients": sched.clients,
-            "offered": sched.count(), "prime_requests": n_prime,
-            "prime_failed": prime_failed, "records": records}
+    return result(records), snapshot is not None
 
 
 def main(argv: list[str] | None = None) -> int:
@@ -223,12 +263,21 @@ def main(argv: list[str] | None = None) -> int:
     p.add_argument("--load", type=float, required=True)
     p.add_argument("--seconds", type=float, required=True)
     p.add_argument("--out", required=True)
+    p.add_argument("--tail-s", type=float, default=0.0,
+                   help="keep the load going for up to this long after "
+                        "the drain, until standard input closes "
+                        "(--trace 2)")
     args = p.parse_args(argv)
     assert "jax" not in sys.modules
-    out = run(args)
+
+    def write(out: dict, path: str = args.out) -> None:
+        with open(path + ".part", "w") as f:
+            json.dump(out, f)
+        os.replace(path + ".part", path)
+
+    out, had_tail = run(args, write)
     assert "jax" not in sys.modules, "the load generator imported JAX"
-    with open(args.out, "w") as f:
-        json.dump(out, f)
+    write(out, args.out + ".tail" if had_tail else args.out)
     emit(event="done", records=len(out["records"]))
     return 0
 

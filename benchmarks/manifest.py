@@ -107,9 +107,15 @@ def validate(manifest: dict, root: str = ROOT) -> list[str]:
     bad: list[str] = []
     want = {"command", "paths", "run_seconds", "configs", "workloads",
             "end_to_end", "per_layer"}
-    if set(manifest) != want:
-        bad.append(f"top-level keys {sorted(manifest)} != {sorted(want)}")
+    # ``trace_in_run``: the driver measures with ``--trace 0`` and traces
+    # with ``--trace 2`` (one run that measures first and traces after),
+    # never ``--trace 1``.  Optional; absent reads as false.
+    if set(manifest) - {"trace_in_run"} != want:
+        bad.append(f"top-level keys {sorted(manifest)} != {sorted(want)} "
+                   "(+ trace_in_run)")
         return bad
+    if not isinstance(manifest.get("trace_in_run", False), bool):
+        bad.append("trace_in_run must be true or false")
     if not (isinstance(manifest["run_seconds"], int)
             and 1 <= manifest["run_seconds"] <= 51):
         bad.append("run_seconds must be a whole number from 1 to 51")

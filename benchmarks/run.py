@@ -1,7 +1,7 @@
 """One command, one cell, one run.
 
     python3 -m benchmarks.run --workload <cell> --seed <n> --seconds <s>
-                              --trace <0|1> [--rehearse]
+                              --trace <0|1|2> [--rehearse]
 
 Order of events (everything before the window opens is set-up):
 platform check -> compile cache -> reference weights from the seed, parked
@@ -9,6 +9,16 @@ on the host -> the pod, through the server entry point's own functions ->
 warm-up (the pipelined programs, the probes, one plain stream) -> the
 correctness comparison -> the load generator as a child process that never
 imports JAX -> ramp -> the measured window -> drain -> one JSON line.
+
+``--trace 1`` profiles a slice inside the window and prints the per-layer
+metrics.  ``--trace 2`` is a ``--trace 0`` run with a traced tail: the same
+run until the window has closed and the drain has passed (the load
+generator keeps the load going and hands over the records as they stood at
+the end of the drain), then the profiler is started and stopped once into a
+trace that is thrown away, then ``trace_s`` seconds of the same traffic are
+traced through the program's own window; the last line carries the
+end-to-end numbers of the closed window and the per-layer metrics side by
+side.
 
 Without a TPU, or with fewer chips than the cell asks for, it exits 3 and
 prints no result.  ``--rehearse`` is for the CPU at ``tiny`` size: it runs
@@ -69,7 +79,7 @@ def main(argv: list[str] | None = None) -> int:
     p.add_argument("--workload", required=True)
     p.add_argument("--seed", type=int, required=True)
     p.add_argument("--seconds", type=float, required=True)
-    p.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    p.add_argument("--trace", type=int, choices=(0, 1, 2), default=0)
     p.add_argument("--rehearse", action="store_true",
                    help="CPU rehearsal of a tiny cell of "
                         "benchmarks/rehearsal.json: no device metric, "
@@ -145,6 +155,8 @@ def main(argv: list[str] | None = None) -> int:
                "--mix", cell["mix_path"],
                "--seed", str(args.seed), "--load", repr(cell["load"]),
                "--seconds", str(args.seconds), "--out", rec_path]
+        if args.trace == 2:
+            cmd += ["--tail-s", str(_TAIL_CAP_S)]
         env = dict(os.environ, JAX_PLATFORMS="cpu")
         marks: dict = {}
         prof_dir = os.path.join(out_dir, f"{tag}.profile")
@@ -155,7 +167,7 @@ def main(argv: list[str] | None = None) -> int:
             if kind == "primed":
                 info(phase="primed", **{k: v for k, v in ev.items()
                                         if k != "event"})
-            elif kind == "start" and args.trace:
+            elif kind == "start" and args.trace == 1:
                 # A slice of the window, not the whole of it: a trace of
                 # every operation of tens of seconds comes back too large.
                 at = ev["t_open"] + mix.get("trace_offset_s", 1.0)
@@ -177,6 +189,16 @@ def main(argv: list[str] | None = None) -> int:
                     tm.daemon = True
                     tm.start()
                     timers.append(tm)
+            elif kind == "tail" and args.trace == 2:
+                # The window has closed and the drain has passed: the
+                # numbers are fixed.  Trace a slice of the tail, then let
+                # the generator go (it stops when its input closes).
+                span = min(mix["trace_s"], _TAIL_CAP_S / 3)
+                tm = threading.Thread(
+                    target=_traced_tail, name="traced-tail", daemon=True,
+                    args=(pod.engine.profiler, prof_dir, span, marks, proc))
+                tm.start()
+                timers.append(tm)
             elif kind == "window_open":
                 marks["open_t"] = ev["t"]
                 marks["open_metrics"] = podlib.scrape(pod.port)
@@ -186,12 +208,15 @@ def main(argv: list[str] | None = None) -> int:
                 marks["close_metrics"] = podlib.scrape(pod.port)
                 marks["window_compiles"] = meter.since(marks["open_compiles"])
 
-        proc = subprocess.Popen(cmd, stdout=subprocess.PIPE, text=True,
-                                env=env, cwd=manifest.ROOT)
+        proc = subprocess.Popen(
+            cmd, stdout=subprocess.PIPE, text=True, env=env,
+            cwd=manifest.ROOT,
+            stdin=subprocess.PIPE if args.trace == 2 else None)
         try:
             pump = _child_events(proc, on_event)
-            child_rc = proc.wait(timeout=mix["ramp_s"] + args.seconds
-                                 + mix["drain_s"] + 240)
+            child_rc = proc.wait(
+                timeout=mix["ramp_s"] + args.seconds + mix["drain_s"] + 240
+                + (_TAIL_CAP_S if args.trace == 2 else 0))
             pump.join(10)
         finally:
             if proc.poll() is None:
@@ -205,6 +230,13 @@ def main(argv: list[str] | None = None) -> int:
             run = json.load(f)
         os.remove(rec_path)
         client = client_metrics.reduce(run, chips=cell["chips"])
+        # A reader that lays the client's records on the traced slice
+        # (attn_roofline) needs the records that cover it: in a --trace 2
+        # run those of the tail, which hold the window's and go on.
+        sliced = run
+        if args.trace == 2:
+            sliced = _tail_line(rec_path + ".tail", marks,
+                                cell["chips"]) or run
         setup_s = run["t_open"] - _T_START
         info(phase="setup", setup_s=setup_s,
              backend_s=t_backend - _T_START, reference_weights_s=t_refw
@@ -233,15 +265,15 @@ def main(argv: list[str] | None = None) -> int:
         result: dict = {"correct": bool(correct) and not args.rehearse,
                         "attempted": client["attempted"],
                         "failed": client["failed"]}
-        if not args.trace:
-            metrics = {}
+        metrics = {}
+        if args.trace != 1:
             for m in cell["end_to_end"]:
                 v = values.get(m["name"])
                 if v is not None:
                     metrics[m["name"]] = {"value": v, "unit": m["unit"]}
-        else:
+        if args.trace:
             from benchmarks import trace_reduce
-            ctx = {"cell": cell, "client": client, "run": run,
+            ctx = {"cell": cell, "client": client, "run": sliced,
                    "metrics_open": marks.get("open_metrics") or {},
                    "metrics_close": marks.get("close_metrics") or {},
                    "traces": _engine_traces(pod, run),
@@ -249,10 +281,23 @@ def main(argv: list[str] | None = None) -> int:
                    device["memory_peak_bytes"], "engine": pod.engine,
                    "platform": dev["platform"], "kind": dev["kind"]}
             if (marks.get("trace") or {}).get("ok") and not args.rehearse:
-                ctx["device"] = trace_reduce.reduce_dir(
-                    prof_dir, marks["trace_t0"], marks["trace_t1"],
-                    phase_spans=marks.get("phase_spans"),
-                    clock_offset_s=-marks["trace_t0"])
+                if args.trace == 2:
+                    # The program's window: its own spans, laid on the
+                    # trace by the clock anchors it wrote (clock.py).
+                    from benchmarks import clock
+                    stop = marks["trace_stop"]
+                    joined = clock.offset(trace_reduce.find_xplane(prof_dir))
+                    info(phase="clock", **joined,
+                         guess_s=-stop["t0_monotonic"])
+                    ctx["device"] = trace_reduce.reduce_dir(
+                        prof_dir, stop["t0_monotonic"], stop["t1_monotonic"],
+                        phase_spans=stop["spans"],
+                        clock_offset_s=joined["offset_s"])
+                else:
+                    ctx["device"] = trace_reduce.reduce_dir(
+                        prof_dir, marks["trace_t0"], marks["trace_t1"],
+                        phase_spans=marks.get("phase_spans"),
+                        clock_offset_s=-marks["trace_t0"])
                 info(phase="step_programs", programs={
                     k: {"runs": len(v), "median_ms": sorted(v)[len(v) // 2]
                         * 1e3} for k, v in trace_reduce.step_programs(
@@ -260,7 +305,6 @@ def main(argv: list[str] | None = None) -> int:
                 device["busy_s"] = ctx["device"]["busy_s"]
                 device["window_s"] = ctx["device"]["window_s"]
                 result["breakdown"] = ctx["device"]["breakdown"]
-            metrics = {}
             for m in cell["per_layer"]:
                 v = manifest.load_reader(m["name"])(ctx)
                 if v is not None:
@@ -274,6 +318,63 @@ def main(argv: list[str] | None = None) -> int:
         pod.close()
     print(json.dumps(result), flush=True)
     return rc
+
+
+# A ``--trace 2`` run's tail: the generator keeps the load going for at
+# most this long after the drain; it ends as soon as the traced slice does.
+_TAIL_CAP_S = 90.0
+
+
+def _traced_tail(profiler, prof_dir: str, span_s: float, marks: dict,
+                 proc: subprocess.Popen) -> None:
+    """After the drain: start and stop the profiler once into a trace that
+    is thrown away (the first start in a process costs what later ones do
+    not), then trace ``span_s`` seconds through the program's own window,
+    then close the generator's input so that it ends."""
+    import shutil
+    try:
+        warm = prof_dir + ".discard"
+        t = time.monotonic()
+        if profiler.start(warm).get("ok"):
+            profiler.stop()
+        shutil.rmtree(warm, ignore_errors=True)
+        marks["discard_s"] = time.monotonic() - t
+        marks["trace"] = profiler.start(prof_dir)
+        time.sleep(span_s)
+        t = time.monotonic()
+        marks["trace_stop"] = profiler.stop()
+        marks["stop_s"] = time.monotonic() - t
+    finally:
+        proc.stdin.close()
+
+
+def _tail_line(path: str, marks: dict, chips: int) -> dict | None:
+    """The generator's records as they stood at the very end (returned).
+    Prints what the client saw in the traced slice of the tail, beside an
+    untraced slice of the same length at the end of the drain: what an
+    open window costs, by ``client_metrics.reduce`` on both."""
+    try:
+        with open(path) as f:
+            tail = json.load(f)
+        os.remove(path)
+    except OSError:
+        return None
+    stop = marks.get("trace_stop") or {}
+    if not stop.get("ok"):
+        return tail
+    t0, t1 = stop["t0_monotonic"], stop["t1_monotonic"]
+
+    def cut(a: float, b: float) -> dict:
+        c = client_metrics.reduce(dict(tail, t_open=a, t_close=b, t_end=b),
+                                  chips=chips)
+        return {"output_tok_s": c["output_tok_s"],
+                "itl_p50_ms": c["itl_p50_ms"], "itl_p95_ms": c["itl_p95_ms"]}
+
+    info(phase="tail", traced=cut(t0, t1),
+         untraced=cut(tail["t_end"] - (t1 - t0), tail["t_end"]),
+         slice_s=t1 - t0, discard_s=marks.get("discard_s"),
+         stop_s=marks.get("stop_s"), spans=len(stop.get("spans") or ()))
+    return tail
 
 
 def _engine_traces(pod, run: dict) -> list[dict]:

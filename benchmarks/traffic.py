@@ -14,7 +14,8 @@ A mix file (``benchmarks/traffic/<mix>.json``) has these keys:
 ``ramp_s``            load offered before the window opens (set-up).
 ``drain_s``           after the window closes, how long a request that was
                       due inside it may still wait for its first token.
-``trace_s``           length of the profiler slice of a ``--trace 1`` run.
+``trace_s``           length of the profiler slice of a ``--trace 1`` run,
+                      and of the traced tail of a ``--trace 2`` run.
 ``shape_seed``        seed of the fixed pools below.  A pool holds one
                       (prompt, output) size pair per caller (closed loop)
                       or per arrival of the run (open loop), so the first
@@ -42,6 +43,13 @@ another order per seed: two runs of one seed agree to 0.1 % in tokens per
 second, while seeds that shuffled or rotated the same sizes read 11-14 %
 apart, by which requests the window's edges cut (PR 23: 2 x 6 runs each
 way on the chip).  The seed must not change the work.
+
+A ``--trace 2`` run keeps the load going after the measured window for its
+traced tail (``tail_s``).  The tail is drawn from a continuation of its own
+(``tail/<shape_seed>``), after the run's schedule is complete: the first
+``count()`` arrivals, their sizes and their due times are the same bit for
+bit with and without a tail.  A closed loop needs none: its callers cycle
+through the same pool for as long as they are kept going.
 """
 
 from __future__ import annotations
@@ -73,7 +81,7 @@ class Schedule:
     carry state from turn to turn)."""
 
     def __init__(self, mix: dict, seed: int, *, load: float,
-                 seconds: float) -> None:
+                 seconds: float, tail_s: float = 0.0) -> None:
         self.mix, self.seed = mix, int(seed)
         self.loop = mix["loop"]
         shape = random.Random(f"sizes/{mix['shape_seed']}")
@@ -95,11 +103,25 @@ class Schedule:
             for g in gaps:
                 self.due.append(t)     # the first is due at 0
                 t += g * scale
-        self._pool = []
-        for _ in range(n):
-            p = _draw(shape, mix["prompt_tokens"])
-            o = _draw(shape, mix["output_tokens"])
-            self._pool.append((p, min(o, limit - p)))
+        def pair(rng: random.Random) -> tuple[int, int]:
+            p = _draw(rng, mix["prompt_tokens"])
+            o = _draw(rng, mix["output_tokens"])
+            return p, min(o, limit - p)
+
+        self._pool = [pair(shape) for _ in range(n)]
+        self._offered = n
+        if self.due is not None and tail_s > 0:
+            # Arrivals past the run's end, at the run's rate, each with a
+            # size pair of its own: appended, so request i < n reads the
+            # pool entry and the due time it always read.
+            trng = random.Random(f"tail/{mix['shape_seed']}")
+            t = self.total_s
+            while True:
+                t += trng.expovariate(load)
+                if t >= self.total_s + tail_s:
+                    break
+                self.due.append(t)
+                self._pool.append(pair(trng))
         s = mix.get("sessions")
         self._sessions = None
         if s:
@@ -140,5 +162,6 @@ class Schedule:
         return req
 
     def count(self) -> int | None:
-        """Open loop: how many requests the run offers.  Closed: None."""
-        return None if self.due is None else len(self.due)
+        """Open loop: how many requests the run offers (its tail, if any,
+        not counted).  Closed: None."""
+        return None if self.due is None else self._offered
