@@ -659,6 +659,15 @@ class EngineMetrics:
             "mixed_grid_steps_ideal_total",
             "Per-sequence causal minimum page-compute steps for the same "
             "mixed dispatches")
+        # Query rows one mixed dispatch lays out for the attention kernel
+        # (the plan's nb x block_q under the ragged grid's block-compacted
+        # layout; lanes x the padded widest chunk under the dense grid), to
+        # be read against the real rows, the sum of mixed_batch_tokens:
+        # the layout's waste factor (docs/monitoring.md).
+        self.mixed_q_layout_rows_total = r.counter(
+            "mixed_q_layout_rows_total",
+            "Query rows laid out for the mixed attention kernel by mixed "
+            "dispatches (plan mirror)")
         # KV bytes-moved pair (engine/paged.mixed_kv_bytes): bytes_total
         # mirrors the ragged kernel's actual DMA schedule (every q-block
         # re-streams its causal page prefix at the PLAN's block_q — the
@@ -1770,7 +1779,7 @@ class InferenceEngine:
         from arks_tpu.ops import autotune
         if autotune.mode() != "sweep" or not self._paged or not self._mixed:
             return
-        from arks_tpu.ops.paged_attention import paged_mixed_attention
+        from arks_tpu.ops.paged_attention import paged_mixed_attention_flat
         cfg = self.cfg
         hkv = cfg.num_kv_heads
         g = cfg.num_heads // hkv
@@ -1784,25 +1793,30 @@ class InferenceEngine:
         if autotune.lookup("paged_mixed", sig) is not None:
             return
         s = self.ecfg.num_slots
-        # Representative traffic on the engine's own (zeroed) pool: one
-        # full prefill chunk + decode lanes, tables pointing at real pages.
-        q = jnp.ones((s, hkv, g, qmax, d), jnp.float32)
+        # Representative traffic on the engine's own (zeroed) pool, in the
+        # sequential step's flat shape: every lane but the last decoding
+        # one row, the last taking the whole chunk budget; tables pointing
+        # at real pages.
+        t_flat = s + self._mixed_budget
+        q = jnp.ones((t_flat, hkv, g, d), jnp.float32)
         tables = jnp.zeros((s, self._max_pages), jnp.int32)
         pos = np.full((s,), page // 2, np.int32)
         ql = np.ones((s,), np.int32)
-        ql[0] = qmax
-        pos[0] = 0
+        ql[-1] = qmax
+        pos[-1] = 0
+        slot = np.minimum(np.arange(t_flat), s - 1).astype(np.int32)
+        slot_j, start_j = jnp.asarray(slot), jnp.arange(s, dtype=jnp.int32)
         pos_j, ql_j = jnp.asarray(pos), jnp.asarray(ql)
         layer = jnp.asarray(0, jnp.int32)
         interpret = jax.default_backend() != "tpu"
 
         def bench(block_q: int, dma_depth: int,
                   head_group: int = hkv) -> None:
-            out = paged_mixed_attention(
-                q, self._cache.k, self._cache.v, tables, pos_j, ql_j,
-                layer, self._cache.k_scale, self._cache.v_scale,
-                block_q=block_q, interpret=interpret, dma_depth=dma_depth,
-                head_group=head_group)
+            out = paged_mixed_attention_flat(
+                q, self._cache.k, self._cache.v, tables, slot_j, start_j,
+                ql_j, pos_j, layer, self._cache.k_scale,
+                self._cache.v_scale, block_q=block_q, interpret=interpret,
+                dma_depth=dma_depth, head_group=head_group)
             np.asarray(out)  # block until the kernel actually ran
 
         # GQA head grouping shrinks per-item VMEM by hkv/head_group, so
@@ -8021,7 +8035,9 @@ class InferenceEngine:
     def _mixed_grid_counters(self, pos_start, q_len, qmax: int) -> None:
         """Account the padding-waste counter pair for one mixed dispatch:
         mixed_grid_steps_total (what the active grid mode executes) and
-        mixed_grid_steps_ideal_total (the per-sequence causal minimum).
+        mixed_grid_steps_ideal_total (the per-sequence causal minimum),
+        and mixed_q_layout_rows_total (the query rows the plan lays out
+        for the kernel, whatever the batch holds).
         The counters describe the grid PLAN — they are meaningful under
         either attention impl, which is what lets the sparse-batch waste
         test run on the XLA oracle.  Inputs are the host-side numpy batch
@@ -8035,7 +8051,7 @@ class InferenceEngine:
                 qmax, hkv=self.cfg.num_kv_heads,
                 g=self.cfg.num_heads // self.cfg.num_kv_heads,
                 d=tf.cache_head_dim(self.cfg, self._pad_head()),
-                page=self._page_size(), kv=kv)
+                page=self._page_size(), kv=kv, lanes=pos_start.shape[0])
             self._grid_plans[qmax] = plan
         from arks_tpu.engine.paged import mixed_grid_steps, mixed_kv_bytes
         ideal, dense = mixed_grid_steps(
@@ -8045,6 +8061,7 @@ class InferenceEngine:
         actual = ideal if plan["grid"] == "ragged" else dense
         self.metrics.mixed_grid_steps_total.inc(actual)
         self.metrics.mixed_grid_steps_ideal_total.inc(ideal)
+        self.metrics.mixed_q_layout_rows_total.inc(plan["q_rows"])
         b_actual, b_ideal = mixed_kv_bytes(
             pos_start, q_len, page=self._page_size(),
             block_q=plan["block_q"], num_qb=plan["num_qb"],

@@ -409,7 +409,12 @@ def paged_mixed_update_and_attend(
 
     The per-token view (token_slot/token_pos) drives the KV write and the
     XLA oracle; the per-lane view (seq_q_start/q_len/pos_start) drives the
-    ragged Pallas kernel, which needs queries grouped by sequence.  Returns
+    ragged Pallas kernel's work list.  The kernel reads its queries in
+    blocks of block_q rows, one block per real (lane, q block) pair
+    (``paged_attention.paged_mixed_attention_flat``): the layout costs
+    ``lanes + ceil(chunk budget / block_q)`` blocks whatever the batch
+    holds, filled by one gather and read back by one.  A lane's rows are
+    contiguous from ``seq_q_start``.  Returns
     (out [T, H, D], k_pool, v_pool, k_scale, v_scale)."""
     from arks_tpu.ops.paged_attention import (
         is_int4_pool, pool_page_tokens, unpack_int4_pool)
@@ -460,17 +465,12 @@ def paged_mixed_update_and_attend(
         return out.reshape(t_flat, h, d)[..., :d_model], kp, vp, ks, vs
 
     from arks_tpu.ops.paged_attention import (
-        paged_kv_update, paged_kv_update_quant, paged_mixed_attention,
+        paged_kv_update, paged_kv_update_quant, paged_mixed_attention_flat,
     )
     interpret = jax.default_backend() != "tpu"
-    b_lanes = seq_q_start.shape[0]
-    # Widest possible per-lane query span.  +1 covers the spec_pipe batch
-    # shape (EVERY lane a q_len=K block, t_flat == b_lanes * K): with one
-    # lane, t_flat - b_lanes would undershoot its own block width.
-    qmax = max(t_flat - b_lanes + 1, 1)
 
-    def local(qg, kn, vn, kp, vp, ks, vs, tbl, tok_tbl, widx, q_start,
-              qlen, pos0, lyr):
+    def local(qg, kn, vn, kp, vp, ks, vs, tbl, tok_tbl, widx, tslot,
+              q_start, qlen, pos0, lyr):
         with jax.named_scope("arks.attn_kernel"):
             if quantized:
                 kp, vp, ks, vs = paged_kv_update_quant(
@@ -479,37 +479,24 @@ def paged_mixed_update_and_attend(
             else:
                 kp, vp = paged_kv_update(kp, vp, kn, vn, widx, tok_tbl, lyr,
                                          interpret=interpret)
-        hkv_l = qg.shape[1]
-        # arks.attn_layout: everything between the projections and the
-        # Pallas call and back — the gather of the flat queries into one
-        # dense [Qmax] block per lane, the pad to the kernel's q blocks
-        # (inside paged_mixed_attention, whose pallas_call alone carries
-        # arks.attn_kernel: the innermost scope names an op) and the
-        # scatter back into the flat batch.
+        # arks.attn_layout: what stands between the projections and the
+        # Pallas call and back — ONE gather of the flat rows into the
+        # kernel's block-compacted query layout (a block of block_q rows
+        # per real (lane, q block) pair) and ONE gather of the T flat rows
+        # back out of its output (the pallas_call alone carries
+        # arks.attn_kernel: the innermost scope names an op).
         with jax.named_scope("arks.attn_layout"):
-            span = q_start[:, None] + jnp.arange(qmax, dtype=jnp.int32)
-            gather_idx = jnp.minimum(span, t_flat - 1)      # [B, Qmax]
-            qs = jnp.take(qg, gather_idx.reshape(-1), axis=0).reshape(
-                b_lanes, qmax, hkv_l, g, d)
-            qs = jnp.transpose(qs, (0, 2, 3, 1, 4))     # [B,Hkv,G,Qmax,D]
-            out_seq = paged_mixed_attention(qs, kp, vp, tbl, pos0, qlen,
-                                            lyr, k_scale=ks, v_scale=vs,
-                                            interpret=interpret)
-            rows = jnp.transpose(out_seq, (0, 3, 1, 2, 4)).reshape(
-                b_lanes * qmax, hkv_l, g, d)
-            q_valid = (jnp.arange(qmax, dtype=jnp.int32)[None]
-                       < qlen[:, None])
-            scatter_idx = jnp.where(q_valid, span, t_flat)  # OOB dropped
-            out = jnp.zeros((t_flat, hkv_l, g, d), qg.dtype).at[
-                scatter_idx.reshape(-1)].set(rows)
+            out = paged_mixed_attention_flat(
+                qg, kp, vp, tbl, tslot, q_start, qlen, pos0, lyr,
+                k_scale=ks, v_scale=vs, interpret=interpret)
         return out, kp, vp, ks, vs
 
     qg = q.reshape(t_flat, hkv, g, d)
     if mesh is None or mesh.size == 1:
         out, kp, vp, ks, vs = local(qg, k_new, v_new, k_pool, v_pool,
                                     k_scale, v_scale, tables, tables_tok,
-                                    write_idx, seq_q_start, seq_q_len,
-                                    seq_pos_start, layer)
+                                    write_idx, token_slot, seq_q_start,
+                                    seq_q_len, seq_pos_start, layer)
         return out.reshape(t_flat, h, d)[..., :d_model], kp, vp, ks, vs
 
     from jax.sharding import PartitionSpec as P
@@ -522,13 +509,13 @@ def paged_mixed_update_and_attend(
         local, mesh=mesh,
         in_specs=(qspec, kvspec, kvspec, pspec, pspec, sspec, sspec,
                   P(None, None), P(None, None), P(None), P(None), P(None),
-                  P(None), P()),
+                  P(None), P(None), P()),
         out_specs=(qspec, pspec, pspec, sspec, sspec),
         check_vma=False,
     )
     out, kp, vp, ks, vs = fn(qg, k_new, v_new, k_pool, v_pool,
                              k_scale, v_scale, tables, tables_tok,
-                             write_idx, seq_q_start, seq_q_len,
+                             write_idx, token_slot, seq_q_start, seq_q_len,
                              seq_pos_start, jnp.asarray(layer, jnp.int32))
     return out.reshape(t_flat, h, d)[..., :d_model], kp, vp, ks, vs
 

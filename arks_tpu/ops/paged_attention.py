@@ -103,6 +103,13 @@ def unpack_int4_pool(pool: jnp.ndarray) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 
 
+def pool_kv_name(k_pool: jnp.ndarray, k_scale: jnp.ndarray | None) -> str:
+    """The pool's KV width as the tune table's signatures spell it."""
+    if k_scale is None:
+        return str(k_pool.dtype)
+    return "int4" if is_int4_pool(k_pool, k_scale) else "int8"
+
+
 def mixed_grid_mode() -> str:
     """ARKS_MIXED_GRID: 'ragged' (work-list grid, default) | 'dense' (the
     legacy (S, num_qb, max_pages) grid, kept as the byte-identity
@@ -117,14 +124,19 @@ def mixed_grid_plan(qmax: int, *, hkv: int, g: int, d: int, page: int,
                     kv: str, block_q: int | None = None,
                     grid: str | None = None,
                     dma_depth: int | None = None,
-                    head_group: int | None = None) -> dict:
+                    head_group: int | None = None,
+                    lanes: int | None = None) -> dict:
     """Resolve the mixed kernel's static launch parameters — ONE place, so
     the kernel wrapper, the engine's grid-step counters, and bench.py can
     never disagree on what actually launches.
 
+    ``qmax`` is the widest query span one lane can have: the per-lane
+    block's Q for :func:`paged_mixed_attention`, ``t_flat - lanes + 1``
+    for the flat batch of :func:`paged_mixed_attention_flat`.
+
     block_q defaults to the autotune table entry for this shape signature
     (arks_tpu.ops.autotune, pure lookup — never sweeps here) and falls
-    back to the min(qmax, 32) heuristic.  Non-divisible qmax is handled by
+    back to :func:`_default_block_q`, a rule over ``qmax`` and ``lanes``.  Non-divisible qmax is handled by
     PADDING the q axis to the block (qpad), not by shrinking block_q to a
     divisor — the old ``while qmax % block_q: block_q -= 1`` fallback
     degraded to tiny odd blocks (qmax=33 -> block_q=11).
@@ -136,7 +148,15 @@ def mixed_grid_plan(qmax: int, *, hkv: int, g: int, d: int, page: int,
     each causal page prefix is re-streamed fewer times, which is where
     the GQA bytes-moved win actually comes from.  Only the ragged grid
     understands grouping; invalid divisors fall back to hkv rather than
-    raising so stale tune tables can never break a launch."""
+    raising so stale tune tables can never break a launch.
+
+    With ``lanes`` (the flat batch's lane count) the plan also carries the
+    block-compacted query layout's size: ``nb``, the static bound on real
+    (lane, q_block) pairs — every lane has at most ceil(q_len / block_q)
+    blocks and the lanes share ``lanes + qmax - 1`` rows, so
+    ``nb = lanes + ceil((qmax - 1) / block_q)`` — and ``q_rows``, the
+    query rows one dispatch lays out for the kernel (``nb * block_q``;
+    ``lanes * qpad`` under the dense grid's per-lane layout)."""
     from arks_tpu.ops import autotune
 
     qmax = max(int(qmax), 1)
@@ -150,7 +170,8 @@ def mixed_grid_plan(qmax: int, *, hkv: int, g: int, d: int, page: int,
     if head_group <= 0 or hkv % head_group:
         head_group = hkv
     if block_q is None:
-        block_q = int(tuned.get("block_q", 0)) or min(qmax, 32)
+        block_q = int(tuned.get("block_q", 0)) or _default_block_q(
+            qmax, lanes)
     block_q = max(1, min(int(block_q), qmax))
     if dma_depth is None:
         dma_depth = int(tuned.get("dma_depth", 0)) or 2
@@ -158,20 +179,47 @@ def mixed_grid_plan(qmax: int, *, hkv: int, g: int, d: int, page: int,
     if grid is None:
         grid = mixed_grid_mode()
     qpad = -(-qmax // block_q) * block_q
-    return dict(block_q=block_q, qpad=qpad, num_qb=qpad // block_q,
+    plan = dict(block_q=block_q, qpad=qpad, num_qb=qpad // block_q,
                 dma_depth=dma_depth, grid=grid, head_group=head_group)
+    if lanes is not None:
+        nb = int(lanes) + -(-(qmax - 1) // block_q)
+        plan.update(nb=nb, q_rows=nb * block_q if grid == "ragged"
+                    else int(lanes) * qpad)
+    return plan
+
+
+def _default_block_q(qmax: int, lanes: int | None) -> int:
+    """Queries per work item where the tune table has no entry.
+
+    A per-lane block (``lanes`` None) keeps 32.  The flat batch's blocks
+    follow its rows per lane, ``(lanes + qmax - 1) / lanes``, in sublane
+    tiles of 8 up to 32: every lane pays for one whole block, and a decode
+    lane fills one row of it, so where the lanes outnumber the chunk's
+    blocks (192 slots + 256 rows: 2.3 rows a lane) 8 halves the kernel's
+    time and quarters the layout against 32; where one chunk is most of
+    the batch (8 slots + 256 rows) 32 re-streams its causal prefix four
+    times less often.  Measured on a v5e at qwen2.5-7b and mixtral widths
+    (PERF.md, PR 25): 8 beat 4, 16 and 32 at 192 and 64 lanes, 32 beat 16
+    and 8 at 8 lanes."""
+    if lanes is None:
+        return min(qmax, 32)
+    rows_per_lane = -(-(lanes + qmax - 1) // lanes)
+    return min(qmax, 32, -(-rows_per_lane // 8) * 8)
 
 
 def build_mixed_work_list(pos_start: jnp.ndarray, q_len: jnp.ndarray, *,
                           page: int, block_q: int, num_qb: int,
                           max_pages: int, head_groups: int = 1,
                           page_lo: jnp.ndarray | None = None,
-                          page_hi: jnp.ndarray | None = None):
+                          page_hi: jnp.ndarray | None = None,
+                          n_items: int | None = None):
     """Scalar-prefetch work list for the ragged mixed grid: one item per
     REAL (sequence, head_group, q_block), compacted to the front of a
     fixed-length [S*head_groups*num_qb] list (Pallas grids are static; the
     page axis is what actually scales with work).  Returns
-    (seq, hg, qb, plo, pages), each int32 [S*head_groups*num_qb]:
+    (seq, hg, qb, plo, pages, blk), each int32 [S*head_groups*num_qb]
+    (or its first ``n_items`` entries: a caller whose static bound on the
+    real items is shorter than the full list launches only that many):
 
     - real items: pages = ceil(causal kv end / page) clamped to the table
       width — that sequence's OWN page count, not the pool-wide max;
@@ -179,6 +227,13 @@ def build_mixed_work_list(pos_start: jnp.ndarray, q_len: jnp.ndarray, *,
     - padding items (q_len=0 lanes, blocks past a lane's q_len): pages = 0
       and (seq, hg, qb) aliased to the LAST real item, so their grid step
       re-flushes an already-written output block and computes nothing.
+
+    - blk is the rank of the item's (seq, q_block) pair among the real
+      pairs, lane-major then block (``base[seq] + qb`` with ``base`` the
+      exclusive running sum of the lanes' block counts): the block the
+      pair owns in the block-compacted query layout
+      (:func:`mixed_block_layout`).  Every head group of a pair shares it;
+      padding items carry the last real item's.
 
     head_groups replicates every (seq, q_block) item per KV head group so
     each grid step streams only its hkv/head_groups slice of the pool's
@@ -195,41 +250,87 @@ def build_mixed_work_list(pos_start: jnp.ndarray, q_len: jnp.ndarray, *,
     dispatches derive q_len on device (zero-host-sync), so the list must
     be traceable — no host round trip."""
     s = q_len.shape[0]
-    n = s * head_groups * num_qb
-    seq = jnp.repeat(jnp.arange(s, dtype=jnp.int32), head_groups * num_qb)
-    hg = jnp.tile(jnp.repeat(jnp.arange(head_groups, dtype=jnp.int32),
-                             num_qb), s)
-    qb = jnp.tile(jnp.arange(num_qb, dtype=jnp.int32), s * head_groups)
-    qlen_i = q_len.astype(jnp.int32)[seq]
-    q_lo = qb * block_q
-    active = q_lo < qlen_i
-    kv_end = jnp.where(
-        active,
-        pos_start.astype(jnp.int32)[seq] + jnp.minimum(q_lo + block_q,
-                                                       qlen_i),
-        0)
+    n = s * head_groups * num_qb if n_items is None else n_items
+    qlen = q_len.astype(jnp.int32)
+    # Real items, counted per lane and ranked without a sort: lane s owns
+    # head_groups x nblk[s] consecutive entries, head group major.
+    nblk, base = _lane_blocks(qlen, block_q, num_qb)
+    ends = (base + nblk) * head_groups
+    n_real = ends[-1]
+    i = jnp.arange(n, dtype=jnp.int32)
+    pad = i >= n_real
+    # Padding entries alias the last real item (item 0 of lane 0 where
+    # there is none).
+    at = jnp.minimum(i, jnp.maximum(n_real - 1, 0))
+    seq = jnp.where(n_real > 0, _owner(ends, at), 0)
+    within = at - base[seq] * head_groups
+    per = jnp.maximum(nblk[seq], 1)
+    hg, qb = within // per, within % per
+    kv_end = pos_start.astype(jnp.int32)[seq] + jnp.minimum(
+        (qb + 1) * block_q, qlen[seq])
     pages = jnp.minimum(-(-kv_end // page), max_pages)
     if page_hi is not None:
         pages = jnp.minimum(pages, page_hi.astype(jnp.int32)[seq])
-    if page_lo is not None:
-        plo = jnp.where(active,
-                        jnp.minimum(page_lo.astype(jnp.int32)[seq], pages),
-                        0)
-    else:
-        plo = jnp.zeros_like(pages)
-    order = jnp.argsort(jnp.logical_not(active).astype(jnp.int32),
-                        stable=True)
-    seq, hg, qb, plo, pages = (seq[order], hg[order], qb[order],
-                               plo[order], pages[order])
-    n_real = jnp.sum(active.astype(jnp.int32))
-    last = jnp.maximum(n_real - 1, 0)
-    pad = jnp.arange(n, dtype=jnp.int32) >= n_real
-    seq = jnp.where(pad, seq[last], seq)
-    hg = jnp.where(pad, hg[last], hg)
-    qb = jnp.where(pad, qb[last], qb)
-    plo = jnp.where(pad, 0, plo)
+    plo = jnp.zeros_like(pages) if page_lo is None else jnp.minimum(
+        page_lo.astype(jnp.int32)[seq], pages)
     pages = jnp.where(pad, 0, pages)
-    return seq, hg, qb, plo, pages
+    plo = jnp.where(pad, 0, plo)
+    return seq, hg, qb, plo, pages, base[seq] + qb
+
+
+def _owner(ends: jnp.ndarray, rank: jnp.ndarray) -> jnp.ndarray:
+    """Lane that owns each rank, where lane s owns ranks [ends[s-1],
+    ends[s]): the number of lanes that end at or before it (a searchsorted
+    over the S lanes as one compare), held inside the lanes for ranks past
+    the last."""
+    return jnp.minimum(
+        jnp.sum((ends[None, :] <= rank[:, None]).astype(jnp.int32), axis=1),
+        ends.shape[0] - 1)
+
+
+def _lane_blocks(q_len: jnp.ndarray, block_q: int, num_qb: int | None = None):
+    """(nblk, base), each [S] int32: the lanes' block counts
+    ceil(q_len / block_q) (at most ``num_qb``) and their exclusive running
+    sum, the rank of each lane's first block among the real blocks."""
+    nblk = -(-q_len.astype(jnp.int32) // block_q)
+    if num_qb is not None:
+        nblk = jnp.minimum(nblk, num_qb)
+    return nblk, jnp.cumsum(nblk) - nblk
+
+
+def mixed_block_layout(token_slot: jnp.ndarray, q_start: jnp.ndarray,
+                       q_len: jnp.ndarray, *, block_q: int, nb: int):
+    """Row indices of the block-compacted query layout: the ``nb`` blocks
+    of ``block_q`` rows the ragged kernel reads its queries from and
+    writes its output to, one block per real (lane, q_block) pair in
+    lane-major order.  Returns (base [S], src_rows [nb * block_q],
+    out_rows [T]), all int32:
+
+    - ``src_rows[j * block_q + r]`` is the flat row that fills row r of
+      block j: ``q_start[lane] + qb * block_q + r`` for the pair of rank
+      j, clipped into the flat batch (rows past the lane's q_len, and
+      blocks past the real ones, read some other real row: the kernel
+      computes on them and nobody reads the result);
+    - ``out_rows[t]`` is where flat row t of lane ``token_slot[t]`` at
+      offset ``o = t - q_start[lane]`` lives: block ``base[lane] +
+      o // block_q``, row ``o % block_q``.  Padding rows (token_slot < 0)
+      get some in-range row; the caller zeroes them.
+
+    Fixed-shape jnp ops only, like the work list: the pipelined programs
+    derive q_len on the device and must not sync."""
+    t_flat = token_slot.shape[0]
+    qs = q_start.astype(jnp.int32)
+    nblk, base = _lane_blocks(q_len, block_q)
+    ends = base + nblk
+    j = jnp.arange(nb, dtype=jnp.int32)
+    lane = _owner(ends, j)
+    row0 = qs[lane] + (j - base[lane]) * block_q
+    src = row0[:, None] + jnp.arange(block_q, dtype=jnp.int32)[None, :]
+    src = jnp.clip(src, 0, t_flat - 1).reshape(-1)
+    slot = jnp.maximum(token_slot.astype(jnp.int32), 0)
+    off = jnp.arange(t_flat, dtype=jnp.int32) - qs[slot]
+    out = (base[slot] + off // block_q) * block_q + off % block_q
+    return base, src, jnp.clip(out, 0, nb * block_q - 1)
 
 
 # ---------------------------------------------------------------------------
@@ -774,7 +875,7 @@ def _paged_mixed_kernel(layer_ref, tables_ref, pos_start_ref, qlen_ref,
 
 def _paged_mixed_ragged_kernel(layer_ref, tables_ref, pos_start_ref,
                                wl_seq_ref, wl_hg_ref, wl_qb_ref,
-                               wl_plo_ref, wl_pages_ref,
+                               wl_plo_ref, wl_pages_ref, wl_blk_ref,
                                q_ref, kpool, vpool, *rest,
                                page: int, block_q: int, scale: float,
                                quantized: bool, int4: bool, depth: int,
@@ -807,6 +908,7 @@ def _paged_mixed_ragged_kernel(layer_ref, tables_ref, pos_start_ref,
     DMAs are ``depth``-way multi-buffered (depth=2 reduces exactly to the
     dense kernel's double buffering; the accumulation order is identical
     for any depth, so tuned depths preserve byte identity)."""
+    del wl_blk_ref      # the index maps' column (compacted layout)
     rest = list(rest)
     if quantized:
         kspool, vspool = rest[:2]
@@ -920,6 +1022,121 @@ def _paged_mixed_ragged_kernel(layer_ref, tables_ref, pos_start_ref,
             o_ref[:] = out.reshape(1, hg, g, bq, d).astype(o_ref.dtype)
 
 
+def _mixed_scratch(k_pool, v_pool, *, nbuf: int, head_group: int,
+                   hkv: int, g: int, d: int, page: int, block_q: int,
+                   quantized: bool):
+    """VMEM scratch of one mixed-attention work item: ``nbuf`` page
+    buffers, the online-softmax state, the DMA semaphores."""
+    kv_rows = k_pool.shape[3]            # page//2 byte rows for int4 pools
+    scratch = [
+        pltpu.VMEM((nbuf, head_group, kv_rows, d), k_pool.dtype),
+        pltpu.VMEM((nbuf, head_group, kv_rows, d), v_pool.dtype),
+    ]
+    n_sem = 2
+    if quantized:
+        scratch += [pltpu.VMEM((nbuf, hkv, page), jnp.float32),
+                    pltpu.VMEM((nbuf, hkv, page), jnp.float32)]
+        n_sem = 4
+    scratch += [
+        pltpu.VMEM((head_group, g * block_q, 128), jnp.float32),  # m
+        pltpu.VMEM((head_group, g * block_q, 128), jnp.float32),  # l
+        pltpu.VMEM((head_group, g * block_q, d), jnp.float32),    # acc
+        pltpu.SemaphoreType.DMA((n_sem, nbuf)),
+    ]
+    return scratch
+
+
+def _ragged_launch(qp, k_pool, v_pool, tables32, pos32, work_list, layer,
+                   k_scale, v_scale, carry_state=None, *, compact: bool,
+                   block_q: int, dma_depth: int, interpret: bool,
+                   head_group: int, emit_state: bool = False):
+    """The ragged work-list ``pallas_call``, one grid step per entry of
+    ``work_list`` (:func:`build_mixed_work_list`).  ``qp`` holds the
+    queries in ``block_q``-row blocks, in one of two layouts that differ
+    only in the index map that finds an item's block:
+
+    - ``compact``: ``[NB, Hkv, G, block_q, D]``, block ``blk[i]`` — one
+      block per real (lane, q_block) pair, the flat batch's layout;
+    - per lane: ``[S, Hkv, G, qpad, D]``, block ``(seq[i], qb[i])`` — the
+      dense block a caller of :func:`paged_mixed_attention` brings.
+
+    The output (or, with ``emit_state``, the raw f32 m / l / acc) comes
+    back in the layout ``qp`` has; ``carry_state`` is read through the
+    same map.  Blocks no real item owns are never written."""
+    lead, hkv, g, qrows, d = qp.shape
+    quantized = k_scale is not None
+    page = pool_page_tokens(k_pool, k_scale)
+    carry = carry_state is not None
+
+    if compact:
+        def q_map(i, layer_p, tables_p, pos_p, seq_p, hg_p, qb_p, plo_p,
+                  pages_p, blk_p):
+            del layer_p, tables_p, pos_p, seq_p, qb_p, plo_p, pages_p
+            return (blk_p[i], hg_p[i], 0, 0, 0)
+    else:
+        def q_map(i, layer_p, tables_p, pos_p, seq_p, hg_p, qb_p, plo_p,
+                  pages_p, blk_p):
+            del layer_p, tables_p, pos_p, plo_p, pages_p, blk_p
+            return (seq_p[i], hg_p[i], 0, qb_p[i], 0)
+
+    blk = dict(q=(1, head_group, g, block_q, d),
+               ml=(1, head_group, g, block_q, 128))
+    carry_inputs, carry_specs = [], []
+    if carry:
+        # Carry arrays have the q layout's shape — exactly what a
+        # previous emit_state call produced, so spans chain without
+        # re-padding.
+        carry_inputs = list(carry_state)
+        carry_specs = [pl.BlockSpec(blk["ml"], q_map),
+                       pl.BlockSpec(blk["ml"], q_map),
+                       pl.BlockSpec(blk["q"], q_map)]
+    if emit_state:
+        out_specs = (pl.BlockSpec(blk["ml"], q_map),
+                     pl.BlockSpec(blk["ml"], q_map),
+                     pl.BlockSpec(blk["q"], q_map))
+        out_shape = (
+            jax.ShapeDtypeStruct((lead, hkv, g, qrows, 128), jnp.float32),
+            jax.ShapeDtypeStruct((lead, hkv, g, qrows, 128), jnp.float32),
+            jax.ShapeDtypeStruct((lead, hkv, g, qrows, d), jnp.float32))
+    else:
+        out_specs = pl.BlockSpec(blk["q"], q_map)
+        out_shape = jax.ShapeDtypeStruct(qp.shape, qp.dtype)
+
+    pool_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 2   # manual DMA
+    scale_inputs = [k_scale, v_scale] if quantized else []
+    scale_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 2 if quantized else []
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=9,  # layer, tables, pos_start, work list x6
+        grid=(work_list[0].shape[0],),
+        in_specs=[pl.BlockSpec(blk["q"], q_map)]
+        + pool_specs + scale_specs + carry_specs,
+        out_specs=out_specs,
+        scratch_shapes=_mixed_scratch(
+            k_pool, v_pool, nbuf=dma_depth, head_group=head_group, hkv=hkv,
+            g=g, d=d, page=page, block_q=block_q, quantized=quantized),
+    )
+    kernel = functools.partial(
+        _paged_mixed_ragged_kernel, page=page, block_q=block_q,
+        scale=1.0 / (d ** 0.5), quantized=quantized,
+        int4=is_int4_pool(k_pool, k_scale), depth=dma_depth,
+        head_group=head_group, carry=carry, emit_state=emit_state)
+    # The call alone is the kernel in a profile; the layout work around it
+    # stays with the caller's scope (arks.attn_layout in the mixed step).
+    with jax.named_scope("arks.attn_kernel"):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=out_shape,
+            # Consecutive items may alias one output block (padding
+            # re-flush), so the item axis is "arbitrary", never "parallel".
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            interpret=interpret,
+            name="paged_mixed_attention_ragged",
+        )(jnp.asarray(layer, jnp.int32).reshape(1), tables32, pos32,
+          *work_list, qp, k_pool, v_pool, *scale_inputs, *carry_inputs)
+
+
 @functools.partial(jax.jit, static_argnames=("block_q", "interpret", "grid",
                                              "dma_depth", "head_group",
                                              "emit_state"))
@@ -928,15 +1145,18 @@ def _paged_mixed_call(q, k_pool, v_pool, tables, pos_start, q_len, layer,
                       carry_state=None, *, block_q: int, dma_depth: int,
                       grid: str, interpret: bool, head_group: int,
                       emit_state: bool):
-    """Jitted mixed-attention launch with FULLY RESOLVED statics — the
-    public wrapper resolves the plan (env + autotune) per call so flipping
-    ARKS_MIXED_GRID / the tune table between calls can never hit a stale
-    jit cache entry keyed on unresolved defaults."""
+    """Jitted mixed-attention launch over a per-lane ``[S, Hkv, G, Q, D]``
+    query block, with FULLY RESOLVED statics — the public wrapper resolves
+    the plan (env + autotune) per call so flipping ARKS_MIXED_GRID / the
+    tune table between calls can never hit a stale jit cache entry keyed
+    on unresolved defaults.  The q axis is padded to the plan's q blocks
+    here and sliced back; the ragged grid visits the lanes' real blocks
+    through the per-lane index map of :func:`_ragged_launch` (the flat
+    batch's block-compacted layout is :func:`_paged_mixed_flat_call`)."""
     s, hkv, g, qmax, d = q.shape
     quantized = k_scale is not None
     int4 = is_int4_pool(k_pool, k_scale)
     page = pool_page_tokens(k_pool, k_scale)
-    kv_rows = k_pool.shape[3]            # page//2 byte rows for int4 pools
     max_pages = tables.shape[1]
     carry = carry_state is not None
     if grid == "dense" and (head_group != hkv or carry or emit_state
@@ -945,127 +1165,56 @@ def _paged_mixed_call(q, k_pool, v_pool, tables, pos_start, q_len, layer,
             "head grouping / span bounds / carried state need the ragged "
             "work-list grid (ARKS_MIXED_GRID=ragged); the dense grid is "
             "the legacy byte-identity reference only")
-    n_hg = hkv // head_group
     qpad = -(-qmax // block_q) * block_q
     num_qb = qpad // block_q
     qp = q if qpad == qmax else jnp.pad(
         q, ((0, 0), (0, 0), (0, 0), (0, qpad - qmax), (0, 0)))
-    scale = 1.0 / (d ** 0.5)
-    layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
     tables32 = tables.astype(jnp.int32)
     pos32 = pos_start.astype(jnp.int32)
     qlen32 = q_len.astype(jnp.int32)
-
-    def make_scratch(nbuf):
-        scratch = [
-            pltpu.VMEM((nbuf, head_group, kv_rows, d), k_pool.dtype),
-            pltpu.VMEM((nbuf, head_group, kv_rows, d), v_pool.dtype),
-        ]
-        n_sem = 2
-        if quantized:
-            scratch += [pltpu.VMEM((nbuf, hkv, page), jnp.float32),
-                        pltpu.VMEM((nbuf, hkv, page), jnp.float32)]
-            n_sem = 4
-        scratch += [
-            pltpu.VMEM((head_group, g * block_q, 128), jnp.float32),  # m
-            pltpu.VMEM((head_group, g * block_q, 128), jnp.float32),  # l
-            pltpu.VMEM((head_group, g * block_q, d), jnp.float32),    # acc
-            pltpu.SemaphoreType.DMA((n_sem, nbuf)),
-        ]
-        return scratch
-
-    pool_specs = [pl.BlockSpec(memory_space=pl.ANY),   # k pool (manual DMA)
-                  pl.BlockSpec(memory_space=pl.ANY)]   # v pool
-    scale_inputs = [k_scale, v_scale] if quantized else []
-    scale_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 2 if quantized else []
 
     if grid == "dense":
         def q_map(s_i, qb, si, *prefetch):
             del si, prefetch
             return (s_i, 0, 0, qb, 0)
 
+        scale_inputs = [k_scale, v_scale] if quantized else []
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,  # layer, tables, pos_start, q_len
             grid=(s, num_qb, max_pages),
             in_specs=[pl.BlockSpec((1, hkv, g, block_q, d), q_map)]
-            + pool_specs + scale_specs,
+            # Pools and scale stripes stay in HBM (manual DMA).
+            + [pl.BlockSpec(memory_space=pl.ANY)] * (2 + len(scale_inputs)),
             out_specs=pl.BlockSpec((1, hkv, g, block_q, d), q_map),
-            scratch_shapes=make_scratch(2),
+            scratch_shapes=_mixed_scratch(
+                k_pool, v_pool, nbuf=2, head_group=hkv, hkv=hkv, g=g, d=d,
+                page=page, block_q=block_q, quantized=quantized),
         )
-        inputs = [layer_arr, tables32, pos32, qlen32,
-                  qp, k_pool, v_pool] + scale_inputs
         kernel = functools.partial(_paged_mixed_kernel, page=page,
-                                   block_q=block_q, scale=scale,
+                                   block_q=block_q, scale=1.0 / (d ** 0.5),
                                    quantized=quantized, int4=int4)
-        dims = ("parallel", "arbitrary", "arbitrary")
-        out_shape = jax.ShapeDtypeStruct(qp.shape, q.dtype)
+        with jax.named_scope("arks.attn_kernel"):
+            out = pl.pallas_call(
+                kernel,
+                grid_spec=grid_spec,
+                out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
+                compiler_params=pltpu.CompilerParams(
+                    dimension_semantics=("parallel", "arbitrary",
+                                         "arbitrary")),
+                interpret=interpret,
+                name="paged_mixed_attention_dense",
+            )(jnp.asarray(layer, jnp.int32).reshape(1), tables32, pos32,
+              qlen32, qp, k_pool, v_pool, *scale_inputs)
     else:
-        wl_seq, wl_hg, wl_qb, wl_plo, wl_pages = build_mixed_work_list(
+        work_list = build_mixed_work_list(
             pos32, qlen32, page=page, block_q=block_q, num_qb=num_qb,
-            max_pages=max_pages, head_groups=n_hg, page_lo=page_lo,
-            page_hi=page_hi)
-
-        def q_map(i, layer_p, tables_p, pos_p, seq_p, hg_p, qb_p, plo_p,
-                  pages_p):
-            del layer_p, tables_p, pos_p, plo_p, pages_p
-            return (seq_p[i], hg_p[i], 0, qb_p[i], 0)
-
-        blk = dict(q=(1, head_group, g, block_q, d),
-                   ml=(1, head_group, g, block_q, 128))
-        carry_inputs, carry_specs = [], []
-        if carry:
-            # Carry arrays are qpad-sized along the q axis — exactly what
-            # a previous emit_state call produced, so spans chain without
-            # re-padding.
-            m0, l0, a0 = carry_state
-            carry_inputs = [m0, l0, a0]
-            carry_specs = [pl.BlockSpec(blk["ml"], q_map),
-                           pl.BlockSpec(blk["ml"], q_map),
-                           pl.BlockSpec(blk["q"], q_map)]
-        if emit_state:
-            out_specs = (pl.BlockSpec(blk["ml"], q_map),
-                         pl.BlockSpec(blk["ml"], q_map),
-                         pl.BlockSpec(blk["q"], q_map))
-            out_shape = (
-                jax.ShapeDtypeStruct((s, hkv, g, qpad, 128), jnp.float32),
-                jax.ShapeDtypeStruct((s, hkv, g, qpad, 128), jnp.float32),
-                jax.ShapeDtypeStruct((s, hkv, g, qpad, d), jnp.float32))
-        else:
-            out_specs = pl.BlockSpec(blk["q"], q_map)
-            out_shape = jax.ShapeDtypeStruct(qp.shape, q.dtype)
-
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=8,  # layer, tables, pos_start, work list x5
-            grid=(s * n_hg * num_qb,),
-            in_specs=[pl.BlockSpec(blk["q"], q_map)]
-            + pool_specs + scale_specs + carry_specs,
-            out_specs=out_specs,
-            scratch_shapes=make_scratch(dma_depth),
-        )
-        inputs = [layer_arr, tables32, pos32, wl_seq, wl_hg, wl_qb,
-                  wl_plo, wl_pages, qp, k_pool, v_pool] \
-            + scale_inputs + carry_inputs
-        kernel = functools.partial(_paged_mixed_ragged_kernel, page=page,
-                                   block_q=block_q, scale=scale,
-                                   quantized=quantized, int4=int4,
-                                   depth=dma_depth, head_group=head_group,
-                                   carry=carry, emit_state=emit_state)
-        # Consecutive items may alias one output block (padding re-flush),
-        # so the item axis is "arbitrary", never "parallel".
-        dims = ("arbitrary",)
-
-    # The call alone is the kernel in a profile; the pad before it and the
-    # slice and mask after it stay with the caller's scope (the layout
-    # work around the kernel: arks.attn_layout in the mixed step).
-    with jax.named_scope("arks.attn_kernel"):
-        out = pl.pallas_call(
-            kernel,
-            grid_spec=grid_spec,
-            out_shape=out_shape,
-            compiler_params=pltpu.CompilerParams(dimension_semantics=dims),
-            interpret=interpret,
-            name=f"paged_mixed_attention_{grid}",
-        )(*inputs)
+            max_pages=max_pages, head_groups=hkv // head_group,
+            page_lo=page_lo, page_hi=page_hi)
+        out = _ragged_launch(
+            qp, k_pool, v_pool, tables32, pos32, work_list, layer, k_scale,
+            v_scale, carry_state, compact=False, block_q=block_q,
+            dma_depth=dma_depth, interpret=interpret, head_group=head_group,
+            emit_state=emit_state)
     # Rows past q_len[s] are undefined (dense: skipped blocks; ragged:
     # never-visited items) — zero them so both grids return IDENTICAL
     # bytes everywhere, not just on the rows callers keep.
@@ -1081,6 +1230,49 @@ def _paged_mixed_call(q, k_pool, v_pool, tables, pos_start, q_len, layer,
     valid = jnp.arange(qmax, dtype=jnp.int32)[None, :] < qlen32[:, None]
     return jnp.where(valid[:, None, None, :, None], out,
                      jnp.zeros_like(out))
+
+
+@functools.partial(jax.jit, static_argnames=("block_q", "nb", "interpret",
+                                             "dma_depth", "head_group"))
+def _paged_mixed_flat_call(q, k_pool, v_pool, tables, token_slot, q_start,
+                           q_len, pos_start, layer, k_scale, v_scale, *,
+                           block_q: int, nb: int, dma_depth: int,
+                           interpret: bool, head_group: int):
+    """Jitted ragged launch over the FLAT batch's queries ``[T, Hkv, G,
+    D]`` in the block-compacted layout: ``nb`` blocks of ``block_q`` rows,
+    one per real (lane, q_block) pair (``nb`` is the plan's static bound
+    on them), filled by ONE gather from the flat rows and read back by ONE
+    gather of T rows (a per-lane layout gives every lane room for the
+    widest chunk any lane could have: ``lanes x qmax`` rows for the same
+    ``T``).  The grid is ``nb x head groups`` long: the
+    compacted work list's front, which holds every real item.  Same
+    kernel body, same work list columns, so each real row's arithmetic is
+    the per-lane call's; padding rows (token_slot < 0) return zeros."""
+    t_flat, hkv, g, d = q.shape
+    page = pool_page_tokens(k_pool, k_scale)
+    n_hg = hkv // head_group
+    pos32 = pos_start.astype(jnp.int32)
+    qlen32 = q_len.astype(jnp.int32)
+    # A lane has at most the widest span's blocks; of the full list only
+    # the first nb * n_hg entries (every real item) are launched.
+    qmax = max(t_flat - q_len.shape[0] + 1, 1)
+    work_list = build_mixed_work_list(
+        pos32, qlen32, page=page, block_q=block_q,
+        num_qb=-(-qmax // block_q), max_pages=tables.shape[1],
+        head_groups=n_hg, n_items=nb * n_hg)
+    _, src_rows, out_rows = mixed_block_layout(
+        token_slot, q_start, qlen32, block_q=block_q, nb=nb)
+    qb = jnp.take(q, src_rows, axis=0).reshape(nb, block_q, hkv, g, d)
+    out = _ragged_launch(
+        jnp.transpose(qb, (0, 2, 3, 1, 4)), k_pool, v_pool,
+        tables.astype(jnp.int32), pos32, work_list, layer, k_scale, v_scale,
+        compact=True, block_q=block_q, dma_depth=dma_depth,
+        interpret=interpret, head_group=head_group)
+    # Straight out of the kernel's layout by (block, row): a transpose to
+    # row-major first would copy the whole output once more.
+    flat = out[out_rows // block_q, :, :, out_rows % block_q]
+    return jnp.where((token_slot >= 0)[:, None, None, None], flat,
+                     jnp.zeros_like(flat))
 
 
 def paged_mixed_attention(
@@ -1119,11 +1311,9 @@ def paged_mixed_attention(
     and finishing with emit_state=False reproduces the single-call
     result bitwise."""
     s, hkv, g, qmax, d = q.shape
-    quantized = k_scale is not None
-    int4 = is_int4_pool(k_pool, k_scale)
-    page = pool_page_tokens(k_pool, k_scale)
-    kvd = "int4" if int4 else ("int8" if quantized else str(k_pool.dtype))
-    plan = mixed_grid_plan(qmax, hkv=hkv, g=g, d=d, page=page, kv=kvd,
+    plan = mixed_grid_plan(qmax, hkv=hkv, g=g, d=d,
+                           page=pool_page_tokens(k_pool, k_scale),
+                           kv=pool_kv_name(k_pool, k_scale),
                            block_q=block_q, grid=grid, dma_depth=dma_depth,
                            head_group=head_group)
     return _paged_mixed_call(q, k_pool, v_pool, tables, pos_start, q_len,
@@ -1134,6 +1324,70 @@ def paged_mixed_attention(
                              grid=plan["grid"], interpret=interpret,
                              head_group=plan["head_group"],
                              emit_state=emit_state)
+
+
+def paged_mixed_attention_flat(
+    q: jnp.ndarray,          # [T, Hkv, G, D] — the flat mixed token batch
+    k_pool: jnp.ndarray,     # [L, N, Hkv, P, D] page pool ([.., P//2, D] int4)
+    v_pool: jnp.ndarray,
+    tables: jnp.ndarray,     # [S, MaxP] int32 block tables, lane s == slot s
+    token_slot: jnp.ndarray,  # [T] int32 — lane of each flat row (-1 = pad)
+    q_start: jnp.ndarray,    # [S] int32 — lane's first flat row
+    q_len: jnp.ndarray,      # [S] int32 — lane's row count (0 = inactive)
+    pos_start: jnp.ndarray,  # [S] int32 — global position of the lane's row 0
+    layer,                   # int32
+    k_scale: jnp.ndarray | None = None,
+    v_scale: jnp.ndarray | None = None,
+    block_q: int | None = None,
+    interpret: bool = False,
+    grid: str | None = None,
+    dma_depth: int | None = None,
+    head_group: int | None = None,
+) -> jnp.ndarray:
+    """[T, Hkv, G, D] ragged mixed attention straight over the flat batch:
+    row t of lane s = token_slot[t] sits at global position
+    ``pos_start[s] + t - q_start[s]`` and attends the lane's table pages
+    over [0, that position]; padding rows return zeros.  A lane's rows are
+    contiguous from ``q_start[s]``.  On every real row the bytes are those
+    of :func:`paged_mixed_attention` over the per-lane block of the same
+    batch.
+
+    The plan is resolved HERE, outside jit, from the flat shape: ``qmax =
+    T - S + 1`` is the widest span one lane can have, ``nb`` the bound on
+    real q blocks (:func:`mixed_grid_plan`).  The ragged grid lays the
+    queries out block-compacted (:func:`_paged_mixed_flat_call`); the
+    dense grid, the byte-identity reference, keeps the per-lane
+    ``[S, Hkv, G, qmax, D]`` layout it needs."""
+    t_flat, hkv, g, d = q.shape
+    s = q_len.shape[0]
+    # +1: with every lane a q_len = K block (t_flat == S * K, one lane)
+    # t_flat - S would undershoot the lane's own width.
+    qmax = max(t_flat - s + 1, 1)
+    plan = mixed_grid_plan(qmax, hkv=hkv, g=g, d=d,
+                           page=pool_page_tokens(k_pool, k_scale),
+                           kv=pool_kv_name(k_pool, k_scale),
+                           block_q=block_q, grid=grid, dma_depth=dma_depth,
+                           head_group=head_group, lanes=s)
+    if plan["grid"] == "ragged":
+        return _paged_mixed_flat_call(
+            q, k_pool, v_pool, tables, token_slot, q_start, q_len,
+            pos_start, layer, k_scale, v_scale, block_q=plan["block_q"],
+            nb=plan["nb"], dma_depth=plan["dma_depth"],
+            interpret=interpret, head_group=plan["head_group"])
+    span = q_start[:, None] + jnp.arange(qmax, dtype=jnp.int32)
+    qs = jnp.take(q, jnp.minimum(span, t_flat - 1).reshape(-1),
+                  axis=0).reshape(s, qmax, hkv, g, d)
+    out_seq = _paged_mixed_call(
+        jnp.transpose(qs, (0, 2, 3, 1, 4)), k_pool, v_pool, tables,
+        pos_start, q_len, layer, k_scale, v_scale,
+        block_q=plan["block_q"], dma_depth=plan["dma_depth"], grid="dense",
+        interpret=interpret, head_group=plan["head_group"],
+        emit_state=False)
+    rows = jnp.transpose(out_seq, (0, 3, 1, 2, 4)).reshape(
+        s * qmax, hkv, g, d)
+    q_valid = jnp.arange(qmax, dtype=jnp.int32)[None] < q_len[:, None]
+    scatter_idx = jnp.where(q_valid, span, t_flat)          # OOB dropped
+    return jnp.zeros_like(q).at[scatter_idx.reshape(-1)].set(rows)
 
 
 # ---------------------------------------------------------------------------

@@ -171,15 +171,17 @@ def memory_stats() -> list[dict]:
 
 def _parity_lanes(*, hkv: int, d: int, page: int, max_pages: int,
                   chunk: int, decode_lanes: int, kv: str, layers: int,
-                  seed: int) -> dict:
+                  seed: int, chunk_lanes: int = 1) -> dict:
     """A seeded pool and one mixed batch over it: decode lanes at spread
-    lengths, one prefill chunk behind a cached page, one idle lane.  Every
-    lane owns its pages; page 0 stays unmapped."""
+    lengths, the prefill chunk's rows behind a cached page (one lane, or
+    shared evenly among ``chunk_lanes`` as a burst's admission shares the
+    budget), one idle lane.  Every lane owns its pages; page 0 stays
+    unmapped."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    lanes = decode_lanes + 2
+    lanes = decode_lanes + chunk_lanes + 1
     n_pages = lanes * max_pages + 1
     cover = max_pages * page
     keys = jax.random.split(jax.random.PRNGKey(seed), 8)
@@ -201,28 +203,35 @@ def _parity_lanes(*, hkv: int, d: int, page: int, max_pages: int,
     # Decode lanes: one token each, at positions spread over the window
     # (first and last positions of the table included).
     dec_pos = np.linspace(0, cover - 1, decode_lanes).astype(np.int32)
-    chunk_lane, chunk_pos0 = decode_lanes, page   # chunk sits behind 1 page
-    check(chunk_pos0 + chunk <= cover, "parity shape: chunk past the table")
+    check(page + chunk <= cover, "parity shape: chunk past the table")
     q_start = np.zeros((lanes,), np.int32)
     q_len = np.zeros((lanes,), np.int32)
     pos0 = np.zeros((lanes,), np.int32)
     q_start[:decode_lanes] = np.arange(decode_lanes)
     q_len[:decode_lanes] = 1
     pos0[:decode_lanes] = dec_pos
-    q_start[chunk_lane], q_len[chunk_lane], pos0[chunk_lane] = (
-        decode_lanes, chunk, chunk_pos0)
+    # The chunk sits behind one page; shared, lane i's part starts i rows
+    # further on, so the parts end on and off the kernel's block edges.
+    at = decode_lanes
+    for i in range(chunk_lanes):
+        rows = chunk // chunk_lanes + (i < chunk % chunk_lanes)
+        lane = decode_lanes + i
+        q_start[lane], q_len[lane], pos0[lane] = at, rows, page + i
+        at += rows
     return dict(lanes=lanes, keys=keys[4:], kp=kp, vp=vp, ks=ks, vs=vs,
                 tables=tables, q_start=q_start, q_len=q_len, pos0=pos0)
 
 
 def kernel_parity(*, kv: str, hkv: int, g: int, d: int, page: int,
                   max_pages: int, chunk: int, decode_lanes: int,
+                  chunk_lanes: int = 1,
                   layers: int = 2, seed: int = SEED, mesh=None,
                   rel_bound: float = PARITY_REL_BOUND) -> dict:
-    """``paged_kv_update[_quant]`` + ``paged_mixed_attention`` against
+    """``paged_kv_update[_quant]`` + ``paged_mixed_attention_flat`` against
     ``paged_update_xla`` + ``paged_gather_kv`` + the XLA decode attention,
-    through the one dispatcher the model calls: decode lanes, one prefill
-    chunk and one idle lane in a single call.  The written pool must match
+    through the one dispatcher the model calls: decode lanes, the prefill
+    chunk (on one lane or shared among ``chunk_lanes``) and one idle lane
+    in a single call.  The written pool must match
     bit for bit (both sides quantize with the same XLA code; only the
     writer differs); the attention output within ``rel_bound`` of the
     oracle's largest value.
@@ -241,7 +250,7 @@ def kernel_parity(*, kv: str, hkv: int, g: int, d: int, page: int,
 
     c = _parity_lanes(hkv=hkv, d=d, page=page, max_pages=max_pages,
                       chunk=chunk, decode_lanes=decode_lanes, kv=kv,
-                      layers=layers, seed=seed)
+                      layers=layers, seed=seed, chunk_lanes=chunk_lanes)
     lanes = c["lanes"]
     t_flat = lanes + chunk                # the engine's slots + budget shape
     q = jax.random.normal(c["keys"][0], (t_flat, hkv * g, d), jnp.bfloat16)
@@ -290,7 +299,7 @@ def kernel_parity(*, kv: str, hkv: int, g: int, d: int, page: int,
         (a is None and b is None) or np.array_equal(a, b)
         for a, b in zip(got[1:], ref[1:]))
     res = {"kv": kv, "hkv": hkv, "g": g, "d": d, "page": page,
-           "lanes": lanes, "chunk": chunk,
+           "lanes": lanes, "chunk": chunk, "chunk_lanes": chunk_lanes,
            "mesh": None if mesh is None else dict(mesh.shape),
            "max_abs_diff": max_diff,
            "ref_max_abs": ref_max, "rel_diff": max_diff / ref_max,
@@ -920,6 +929,9 @@ TP_TRAFFIC = dataclasses.replace(CHIP_TRAFFIC, streams=8, waves=1)
 # dim 128, 256-token pages, four pages of context, a 256-token chunk.
 CHIP_PARITY = dict(hkv=4, g=7, d=128, page=256, max_pages=4, chunk=256,
                    decode_lanes=6)
+# The benchmark's flood at its 192 slots: 115 streams decoding while the
+# chunk's 256 rows are shared among 76 prompts (3-4 rows each).
+CHIP_PARITY_FLOOD = dict(CHIP_PARITY, decode_lanes=115, chunk_lanes=76)
 
 
 def run(tp4: bool) -> dict:
@@ -948,6 +960,7 @@ def run(tp4: bool) -> dict:
     else:
         for kv in ("int8", "int4"):
             kernel_parity(kv=kv, **CHIP_PARITY)
+        kernel_parity(kv="int8", **CHIP_PARITY_FLOOD)
         head_group_parity(kv="int8", **CHIP_PARITY)
         run_pod(server_argv(MODEL, num_slots=NUM_SLOTS,
                             max_model_len=MAX_MODEL_LEN, weight_dtype="int8",

@@ -73,6 +73,23 @@ def _mixed(s, kv, q, hkv=HKV, page=PAGE, **plan):
         s((SLOTS,), jnp.int32), ks, vs)
 
 
+def _mixed_flat(s, kv, lanes, chunk, hkv=HKV, **plan):
+    """The flat batch through the block-compacted layout, as the mixed
+    step calls it: ``lanes + chunk`` rows (chunk 0 = the pipelined step,
+    one row a lane)."""
+    kp, vp, ks, vs = _pool(s, kv, hkv)
+
+    def fn(qq, kp, vp, tables, tslot, qstart, qlen, pos, ks, vs):
+        return pa.paged_mixed_attention_flat(
+            qq, kp, vp, tables, tslot, qstart, qlen, pos, 3, ks, vs, **plan)
+
+    lane = s((lanes,), jnp.int32)
+    return jax.jit(fn).lower(
+        s((lanes + chunk, hkv, G, D), jnp.bfloat16), kp, vp,
+        s((lanes, MAX_PAGES), jnp.int32), s((lanes + chunk,), jnp.int32),
+        lane, lane, lane, ks, vs)
+
+
 def _paged_decode(s, kv):
     kp, vp, ks, vs = _pool(s, kv, HKV)
     return pa.paged_decode_attention.lower(
@@ -136,6 +153,25 @@ CASES = {
     "mixed-bf16-chunk": lambda s: _mixed(s, "bf16", CHUNK + 1),
     "update-bf16": lambda s: _update(s, "bf16"),
     "update-int8": lambda s: _update(s, "int8"),
+    # The same through the flat batch's block-compacted query layout:
+    # the sequential step (slots + chunk rows) at each block_q the plan
+    # may choose, the pipelined step (blocks of one row), 192 slots (the
+    # benchmark's qwen cell), grouped heads.
+    "flat-int8-seq-bq8": lambda s: _mixed_flat(s, "int8", SLOTS, CHUNK,
+                                               block_q=8),
+    "flat-int8-seq-bq16": lambda s: _mixed_flat(s, "int8", SLOTS, CHUNK,
+                                                block_q=16),
+    "flat-int8-seq-bq32": lambda s: _mixed_flat(s, "int8", SLOTS, CHUNK,
+                                                block_q=32),
+    "flat-int8-seq-default-192": lambda s: _mixed_flat(s, "int8", 192,
+                                                       CHUNK),
+    "flat-int8-pipe": lambda s: _mixed_flat(s, "int8", SLOTS, 0),
+    "flat-bf16-seq": lambda s: _mixed_flat(s, "bf16", SLOTS, CHUNK),
+    "flat-int4-seq": lambda s: _mixed_flat(s, "int4", SLOTS, CHUNK),
+    "flat-int8-seq-head-group-1": lambda s: _mixed_flat(
+        s, "int8", SLOTS, CHUNK, head_group=1),
+    "flat-int8-seq-hkv1": lambda s: _mixed_flat(s, "int8", SLOTS, CHUNK,
+                                                hkv=1),
     # tp=4 leaves one KV head per chip.
     "mixed-int8-chunk-hkv1": lambda s: _mixed(s, "int8", CHUNK + 1, hkv=1),
     "update-int8-hkv1": lambda s: _update(s, "int8", hkv=1),

@@ -55,6 +55,14 @@ def test_kernel_parity_phase(kv, tp):
     assert (res["mesh"] or {}).get("model", 0) == tp
 
 
+def test_kernel_parity_phase_with_a_shared_chunk():
+    """The flood's shape in small: the chunk's rows shared among several
+    prefilling lanes beside the decode lanes."""
+    res = chip_smoke.kernel_parity(kv="int8", **{**PARITY, "chunk_lanes": 5})
+    assert res["pool_bit_equal"] and res["rel_diff"] <= res["rel_bound"]
+    assert res["chunk_lanes"] == 5
+
+
 def test_head_group_parity_phase():
     res = chip_smoke.head_group_parity(kv="int8", **PARITY)
     assert res["max_abs_diff_by_head_group"] == {"1": 0.0}

@@ -480,10 +480,14 @@ def test_build_mixed_work_list_compaction():
     head-group / page-span refactor must not move them."""
     pos = jnp.asarray([5, 128, 0, 3], jnp.int32)
     qlen = jnp.asarray([1, 5, 3, 0], jnp.int32)
-    seq, hg, qb, plo, pages = build_mixed_work_list(
+    seq, hg, qb, plo, pages, blk = build_mixed_work_list(
         pos, qlen, page=128, block_q=2, num_qb=3, max_pages=3)
-    seq, hg, qb, plo, pages = map(np.asarray, (seq, hg, qb, plo, pages))
+    seq, hg, qb, plo, pages, blk = map(
+        np.asarray, (seq, hg, qb, plo, pages, blk))
     assert seq.shape == (12,)
+    # The sixth column: the pair's rank among the real (seq, qb) pairs;
+    # padding items carry the last real item's.
+    np.testing.assert_array_equal(blk, [0, 1, 2, 3, 4, 5] + [5] * 6)
     # Real: (0,0) 1 page; (1,0/1/2) 2 pages each; (2,0/1) 1 page each.
     np.testing.assert_array_equal(seq[:6], [0, 1, 1, 1, 2, 2])
     np.testing.assert_array_equal(qb[:6], [0, 0, 1, 2, 0, 1])
@@ -503,13 +507,17 @@ def test_build_mixed_work_list_head_groups_and_spans():
     span — the windowed-residency hook.  Same PR 11 fixture inputs."""
     pos = jnp.asarray([5, 128, 0, 3], jnp.int32)
     qlen = jnp.asarray([1, 5, 3, 0], jnp.int32)
-    seq, hg, qb, plo, pages = build_mixed_work_list(
+    seq, hg, qb, plo, pages, blk = build_mixed_work_list(
         pos, qlen, page=128, block_q=2, num_qb=3, max_pages=3,
         head_groups=2,
         page_lo=jnp.asarray([0, 1, 0, 0], jnp.int32),
         page_hi=jnp.asarray([3, 2, 1, 3], jnp.int32))
-    seq, hg, qb, plo, pages = map(np.asarray, (seq, hg, qb, plo, pages))
+    seq, hg, qb, plo, pages, blk = map(
+        np.asarray, (seq, hg, qb, plo, pages, blk))
     assert seq.shape == (24,)
+    # Every head group of a (seq, qb) pair shares the pair's block.
+    np.testing.assert_array_equal(blk[:12],
+                                  [0, 0, 1, 2, 3, 1, 2, 3, 4, 5, 4, 5])
     # Each real item appears once per head group, hg-major inside a seq.
     np.testing.assert_array_equal(seq[:12],
                                   [0, 0, 1, 1, 1, 1, 1, 1, 2, 2, 2, 2])
@@ -528,10 +536,11 @@ def test_build_mixed_work_list_head_groups_and_spans():
 
 
 def test_build_mixed_work_list_all_inactive():
-    seq, hg, qb, plo, pages = build_mixed_work_list(
+    seq, hg, qb, plo, pages, blk = build_mixed_work_list(
         jnp.zeros((3,), jnp.int32), jnp.zeros((3,), jnp.int32),
         page=16, block_q=4, num_qb=2, max_pages=4)
     np.testing.assert_array_equal(np.asarray(pages), np.zeros(6, np.int32))
+    np.testing.assert_array_equal(np.asarray(blk), np.zeros(6, np.int32))
 
 
 def test_mixed_grid_plan_pads_awkward_qmax():

@@ -179,3 +179,229 @@ def test_dense_grid_counts_padding_waste(monkeypatch):
     steps = eng.metrics.mixed_grid_steps_total.total()
     ideal = eng.metrics.mixed_grid_steps_ideal_total.total()
     assert ideal > 0 and steps > ideal
+
+
+# ---------------------------------------------------------------------------
+# Block-compacted query layout (the flat batch's way into the ragged kernel)
+# ---------------------------------------------------------------------------
+#
+# The mixed step hands the kernel its queries in blocks of block_q rows, one
+# per real (lane, q block) pair, filled by one gather from the flat batch and
+# read back by one.  On every real row the bytes must be those of the
+# per-lane call (paged_mixed_attention over the dense [S, Hkv, G, Q, D] block
+# of the same batch) and of the dense grid.
+
+_LANES, _CHUNK, _BQ = 6, 12, 4      # t_flat = 18, qmax = 13, nb = 6 + 3
+
+
+def _flat_batch(name):
+    """(token_slot [T], q_start [S], q_len [S], pos_start [S]) of one of the
+    batch shapes the engine packs: decode rows first, then chunk lanes."""
+    t_flat = _LANES + _CHUNK
+    lanes = {
+        # one row a lane, one lane idle
+        "decode_only": [(0, 1, 5), (1, 1, 17), (2, 1, 0), (4, 1, 30),
+                        (5, 1, 9)],
+        # decode rows and ONE chunk that takes the whole budget
+        "full_chunk": [(0, 1, 5), (3, 1, 12), (1, _CHUNK, 3)],
+        # the flood's even quota: many two- and three-token chunks
+        "small_chunks": [(0, 1, 7), (1, 2, 0), (2, 3, 4), (3, 2, 15),
+                         (4, 3, 1), (5, 2, 6)],
+        # a chunk that ends exactly on a block edge ...
+        "block_edge": [(2, 1, 3), (4, 2 * _BQ, 5)],
+        # ... and one row past it
+        "block_edge_plus_one": [(2, 1, 3), (4, 2 * _BQ + 1, 5)],
+        "empty": [],
+    }[name]
+    token_slot = np.full((t_flat,), -1, np.int32)
+    q_start = np.zeros((_LANES,), np.int32)
+    q_len = np.zeros((_LANES,), np.int32)
+    pos = np.zeros((_LANES,), np.int32)
+    t = 0
+    for lane, n, p0 in lanes:
+        token_slot[t:t + n] = lane
+        q_start[lane], q_len[lane], pos[lane] = t, n, p0
+        t += n
+    return token_slot, q_start, q_len, pos
+
+
+_BATCHES = ["decode_only", "full_chunk", "small_chunks", "block_edge",
+            "block_edge_plus_one", "empty"]
+
+
+def _flat_pools(kv):
+    import jax
+    import jax.numpy as jnp
+    page, hkv, d, max_pages = (128, 2, 32, 2) if kv == "int8" \
+        else (16, 2, 32, 4)
+    n = _LANES * max_pages + 2
+    ks = jax.random.split(jax.random.PRNGKey(1), 6)
+    if kv == "int8":
+        kp = jax.random.randint(ks[0], (2, n, hkv, page, d), -127, 128,
+                                jnp.int8)
+        vp = jax.random.randint(ks[1], (2, n, hkv, page, d), -127, 128,
+                                jnp.int8)
+        kps = jax.random.uniform(ks[2], (2, n, hkv, page), jnp.float32,
+                                 0.01, 0.03)
+        vps = jax.random.uniform(ks[3], (2, n, hkv, page), jnp.float32,
+                                 0.01, 0.03)
+    else:
+        kp = jax.random.normal(ks[0], (2, n, hkv, page, d), jnp.bfloat16)
+        vp = jax.random.normal(ks[1], (2, n, hkv, page, d), jnp.bfloat16)
+        kps = vps = None
+    tables = jax.random.permutation(ks[4], n)[: _LANES * max_pages].reshape(
+        _LANES, max_pages).astype(jnp.int32)
+    q = jax.random.normal(ks[5], (_LANES + _CHUNK, hkv, 3, d), jnp.bfloat16)
+    return q, kp, vp, kps, vps, tables
+
+
+@pytest.mark.parametrize("head_group", [None, 1])
+@pytest.mark.parametrize("kv", ["int8", "bf16"])
+@pytest.mark.parametrize("batch", _BATCHES)
+def test_compacted_layout_bytes_match_per_lane_and_dense(batch, kv,
+                                                         head_group):
+    import jax.numpy as jnp
+    from arks_tpu.ops.paged_attention import (
+        paged_mixed_attention, paged_mixed_attention_flat)
+    q, kp, vp, kps, vps, tables = _flat_pools(kv)
+    token_slot, q_start, q_len, pos = _flat_batch(batch)
+    t_flat = token_slot.shape[0]
+    args = (jnp.asarray(token_slot), jnp.asarray(q_start),
+            jnp.asarray(q_len), jnp.asarray(pos))
+    kw = dict(k_scale=kps, v_scale=vps, block_q=_BQ, interpret=True)
+    got = np.asarray(paged_mixed_attention_flat(
+        q, kp, vp, tables, *args, 1, grid="ragged", head_group=head_group,
+        **kw).astype(jnp.float32))
+    dense = np.asarray(paged_mixed_attention_flat(
+        q, kp, vp, tables, *args, 1, grid="dense", **kw
+    ).astype(jnp.float32))
+    # The per-lane call over the dense block of the same batch.
+    qmax = t_flat - _LANES + 1
+    span = np.minimum(q_start[:, None] + np.arange(qmax)[None], t_flat - 1)
+    block = jnp.transpose(q[span.reshape(-1)].reshape(
+        _LANES, qmax, *q.shape[1:]), (0, 2, 3, 1, 4))
+    lane = np.asarray(paged_mixed_attention(
+        block, kp, vp, tables, args[3], args[2], 1, grid="ragged",
+        head_group=head_group, **kw).astype(jnp.float32))
+    real = token_slot >= 0
+    assert np.isfinite(got).all()
+    # Padding rows come back as zeros, whatever their block held.
+    np.testing.assert_array_equal(got[~real], 0.0)
+    np.testing.assert_array_equal(got, dense)
+    for t in np.flatnonzero(real):
+        s = token_slot[t]
+        np.testing.assert_array_equal(got[t], lane[s, :, :, t - q_start[s]])
+    if batch != "empty":
+        assert np.abs(got[real]).max() > 0
+
+
+@pytest.mark.parametrize("block_q", [1, 3, 4, 8])
+@pytest.mark.parametrize("batch", _BATCHES)
+def test_block_rank_arithmetic_matches_numpy_loop(batch, block_q):
+    """base / block / row of mixed_block_layout and the work list's sixth
+    column against a plain loop over the lanes."""
+    import jax.numpy as jnp
+    from arks_tpu.ops.paged_attention import (
+        build_mixed_work_list, mixed_block_layout, mixed_grid_plan)
+    token_slot, q_start, q_len, pos = _flat_batch(batch)
+    t_flat = token_slot.shape[0]
+    plan = mixed_grid_plan(t_flat - _LANES + 1, hkv=2, g=3, d=32, page=16,
+                           kv="bfloat16", block_q=block_q, lanes=_LANES,
+                           grid="ragged")
+    nb = plan["nb"]
+    assert nb == _LANES + -(-_CHUNK // block_q)
+    base, src, out = map(np.asarray, mixed_block_layout(
+        jnp.asarray(token_slot), jnp.asarray(q_start), jnp.asarray(q_len),
+        block_q=block_q, nb=nb))
+    # The loop: lanes in order, each lane's blocks in order.
+    want_base, pairs = [], []
+    for s in range(_LANES):
+        want_base.append(len(pairs))
+        pairs += [(s, qb) for qb in range(-(-int(q_len[s]) // block_q))]
+    assert len(pairs) <= nb
+    np.testing.assert_array_equal(base, want_base)
+    src = src.reshape(nb, block_q)
+    for j, (s, qb) in enumerate(pairs):
+        for r in range(block_q):
+            o = qb * block_q + r
+            if o < q_len[s]:
+                assert src[j, r] == q_start[s] + o
+                assert out[q_start[s] + o] == j * block_q + r
+    assert src.min() >= 0 and src.max() < t_flat
+    assert out.min() >= 0 and out.max() < nb * block_q
+    # The work list names the same blocks: item (s, hg, qb) -> rank of
+    # (s, qb), for every head group; the launched front holds them all.
+    for n_hg in (1, 2):
+        seq, hg, qb, _, pages, blk = map(np.asarray, build_mixed_work_list(
+            jnp.asarray(pos), jnp.asarray(q_len), page=16, block_q=block_q,
+            num_qb=plan["num_qb"], max_pages=4, head_groups=n_hg,
+            n_items=nb * n_hg))
+        assert seq.shape == (nb * n_hg,)
+        n_real = len(pairs) * n_hg
+        got = sorted(zip(seq[:n_real], qb[:n_real], blk[:n_real]))
+        assert got == sorted((s, b, j) for j, (s, b) in enumerate(pairs)
+                             for _ in range(n_hg))
+        assert (pages[:n_real] > 0).all() and (pages[n_real:] == 0).all()
+
+
+@pytest.mark.parametrize("lanes", [1, 4, 192])
+def test_pipelined_shape_lays_out_one_row_a_lane(lanes):
+    """t_flat == b_lanes (the pipelined step): blocks of one row, as many
+    as lanes, and a grid exactly as long as the per-lane launch's."""
+    import jax
+    import jax.numpy as jnp
+    from arks_tpu.ops import paged_attention as pa
+    plan = pa.mixed_grid_plan(1, hkv=2, g=3, d=32, page=16, kv="bfloat16",
+                              lanes=lanes, grid="ragged")
+    assert (plan["block_q"], plan["nb"], plan["q_rows"]) == (1, lanes, lanes)
+
+    def grid_of(fn, *args):
+        eqns = jax.make_jaxpr(fn)(*args).jaxpr.eqns
+        found = []
+
+        def walk(eqns):
+            for e in eqns:
+                if e.primitive.name == "pallas_call":
+                    found.append(tuple(e.params["grid_mapping"].grid))
+                for v in e.params.values():
+                    if hasattr(v, "jaxpr"):
+                        walk(getattr(v.jaxpr, "eqns", None)
+                             or v.jaxpr.jaxpr.eqns)
+        walk(eqns)
+        return found
+
+    kp = jnp.zeros((1, lanes + 1, 2, 16, 32), jnp.bfloat16)
+    tables = jnp.zeros((lanes, 1), jnp.int32)
+    lane = jnp.arange(lanes, dtype=jnp.int32)
+    ones = jnp.ones((lanes,), jnp.int32)
+    flat = grid_of(lambda q: pa.paged_mixed_attention_flat(
+        q, kp, kp, tables, lane, lane, ones, ones, 0, interpret=True,
+        grid="ragged"), jnp.zeros((lanes, 2, 3, 32), jnp.bfloat16))
+    per_lane = grid_of(lambda q: pa.paged_mixed_attention(
+        q, kp, kp, tables, ones, ones, 0, interpret=True, grid="ragged"),
+        jnp.zeros((lanes, 2, 3, 1, 32), jnp.bfloat16))
+    assert flat == per_lane == [(lanes,)]
+
+
+@pytest.mark.parametrize("grid", ["ragged", "dense"])
+def test_q_layout_rows_counter_follows_the_plan(monkeypatch, grid):
+    """mixed_q_layout_rows_total rises by the plan's q_rows a dispatch
+    (nb x block_q under the ragged grid, lanes x the padded widest chunk
+    under the dense one), whatever the batch holds: to be read against the
+    real rows, the sum of mixed_batch_tokens."""
+    cfg, eng = _mk_engine(monkeypatch, grid=grid, impl="xla", num_slots=8)
+    eng.add_request(Request("r0", [5, 6, 7], SamplingParams(
+        max_tokens=3, temperature=0.0, ignore_eos=True)))
+    _drive(eng)
+    plan = next(iter(eng._grid_plans.values()))
+    n_dispatches = sum(
+        n for _, _, n in eng.metrics.mixed_batch_tokens._data.values())
+    rows = eng.metrics.mixed_q_layout_rows_total.total()
+    assert n_dispatches > 0 and rows == n_dispatches * plan["q_rows"]
+    budget = eng._mixed_budget
+    if grid == "ragged":
+        assert plan["q_rows"] == plan["nb"] * plan["block_q"]
+        assert plan["nb"] == 8 + -(-budget // plan["block_q"])
+    else:
+        assert plan["q_rows"] == 8 * plan["qpad"]
+        assert plan["q_rows"] > 8 + budget
