@@ -32,9 +32,11 @@ def read_one(config_name: str, seed: int, control: str | None,
         config = json.load(f)
     with open(os.path.join(cdir, "deploy.json")) as f:
         deploy = json.load(f)
+    config = manifest.with_share(config, deploy)
+    ref = manifest.load_reference(deploy["reference"])
     spec = deploy["correct"]
     t0 = time.monotonic()
-    weights = correctness.reference_weights(config, deploy, seed)
+    weights = correctness.reference_weights(ref, config, deploy, seed)
     pod = podlib.build(config_name, cdir, deploy, seed,
                        overrides=spec["controls"][control] if control
                        else None, platform=platform)
@@ -43,7 +45,7 @@ def read_one(config_name: str, seed: int, control: str | None,
         prompts = correctness.probes(spec, seed)
         served = correctness.serve(pod.engine, prompts,
                                    spec["decode_tokens"])
-        out = correctness.compare(config, weights, prompts, served,
+        out = correctness.compare(ref, config, weights, prompts, served,
                                   spec)
         out["ok"] = correctness.verdict(out, spec)
     finally:

@@ -8,10 +8,12 @@ engine together, greedy, with ``logprobs=8``, for a few tokens each.  The
 engine answers each token with the eight largest log-probabilities of its
 next-token distribution and their ids: the first comes out of chunked
 prefill, the rest out of decode steps through the paged cache, mixed with
-the other probes' chunks and then pipelined.  The reference makes its own
-weights from the seed (``reference.generate_weights``), runs ONE full
-causal forward in float32 over prompt + the tokens the engine chose, and
-its log-softmax is read at the same positions and ids.
+the other probes' chunks and then pipelined.  The reference is the family
+the configuration's ``deploy.json`` names (``cell["reference"]``, a module
+of ``benchmarks/references/``; this file imports none by name): it makes
+its own weights from the seed (``generate_weights``), runs ONE full causal
+forward in float32 over prompt + the tokens the engine chose, and its
+log-softmax is read at the same positions and ids.
 
 The number.  For every (probe, token) position: the rms over the eight ids
 of ``served - reference``, divided by the standard deviation of the
@@ -31,8 +33,9 @@ precision is at fault (one layer alone, same input: ``moe_ffn_grouped``
 is within 0.9 % of the float32 reference on every one of 512 tokens, PR
 23).  The reference knows where that can happen from its own float32
 router logits: it returns each layer's margin at each compared position
-(router-logit units, so ``exp(margin)`` is the ratio of the two experts'
-probabilities), and a position whose smallest margin is under
+(in the units in which the family selects; ``decoder``: router logits, so
+``exp(margin)`` is the ratio of the two experts' probabilities), and a
+position whose smallest margin is under
 ``tie_margin`` is set aside as a tie and reported, not gated; at least
 ``min_clean_positions`` have to remain.  On the chip (PR 23, 21 seeds,
 1092 positions of probes of 200 tokens and more) 152 positions read more
@@ -59,17 +62,17 @@ import random
 
 import numpy as np
 
-from benchmarks import reference
+from benchmarks.references._common import log_softmax
 
 TOP = 8
 
 
-def reference_weights(config: dict, deploy: dict, seed: int) -> dict:
-    """The reference's own weights for this seed, at the width the
-    deployment serves (``deploy.json``'s ``weight-dtype``)."""
+def reference_weights(ref, config: dict, deploy: dict, seed: int) -> dict:
+    """The reference family ``ref``'s own weights for this seed, at the
+    width the deployment serves (``deploy.json``'s ``weight-dtype``)."""
     from benchmarks.pod import pod_seed
     bits = {"int8": 8, "bf16": 0}[deploy["server_args"]["weight-dtype"]]
-    return reference.generate_weights(config, pod_seed(seed), bits)
+    return ref.generate_weights(config, pod_seed(seed), bits)
 
 
 def probes(spec: dict, seed: int, vocab_bytes: int = 256) -> list[list[int]]:
@@ -124,11 +127,12 @@ def _at_quantile(values: np.ndarray, q: float) -> float | None:
     return float(v[max(int(np.ceil(q * v.size)) - 1, 0)])
 
 
-def compare(config: dict, weights: dict, prompts: list[list[int]],
-            served: list[dict], spec: dict) -> dict:
-    """``logprob_err`` and what it was made of; ``spec`` is ``deploy.json``'s
-    ``correct`` (``tie_margin``, ``quantile``)."""
-    tie_margin, q = spec.get("tie_margin"), spec.get("quantile", 1.0)
+def layout(prompts: list[list[int]], served: list[dict]
+           ) -> tuple[np.ndarray, np.ndarray]:
+    """What the reference's one forward runs over: ``tokens [B, T]``, each
+    prompt with the tokens the engine chose but the last, right-padded, and
+    ``rows [B, k]``, the positions whose next-token distributions the
+    engine reported."""
     k = len(served[0]["tokens"])
     seqs = [p + s["tokens"][:-1] for p, s in zip(prompts, served)]
     t_max = max(len(s) for s in seqs)
@@ -137,12 +141,22 @@ def compare(config: dict, weights: dict, prompts: list[list[int]],
     for i, (p, s) in enumerate(zip(prompts, seqs)):
         tokens[i, :len(s)] = s
         rows[i] = len(p) - 1 + np.arange(k)
+    return tokens, rows
+
+
+def compare(ref, config: dict, weights: dict, prompts: list[list[int]],
+            served: list[dict], spec: dict) -> dict:
+    """``logprob_err`` and what it was made of; ``ref`` is the
+    configuration's reference family, ``spec`` is ``deploy.json``'s
+    ``correct`` (``tie_margin``, ``quantile``)."""
+    tie_margin, q = spec.get("tie_margin"), spec.get("quantile", 1.0)
+    tokens, rows = layout(prompts, served)
+    k = rows.shape[1]
     per_layer: list[np.ndarray] = []
-    logits = reference.forward(config, weights, tokens, rows,
-                               margins=per_layer)
-    ref_lp = reference.log_softmax(logits)
+    logits = ref.forward(config, weights, tokens, rows, margins=per_layer)
+    ref_lp = log_softmax(logits)
     scale = logits.std(axis=-1)                          # [B, k]
-    errs = np.zeros((len(seqs), k))
+    errs = np.zeros(rows.shape)
     for i, s in enumerate(served):
         for j in range(k):
             ids = np.asarray(s["top_ids"][j])

@@ -5,9 +5,11 @@ Kernel time: the summed device time of the ops named
 content character a client received inside the slice is one decode token
 attending over (prompt + characters so far) cached tokens; every request
 whose prefill fell (partly) inside the slice adds that part of its chunks.
-Both come from the load generator's records, not from the program."""
+Both come from the load generator's records, not from the program.  The
+kernel's shapes come from the cell's reference family (``kernel_shapes``);
+a family without them leaves nothing to read."""
 
-from benchmarks import peaks, reference, trace_reduce
+from benchmarks import peaks, trace_reduce
 from benchmarks.kernels import paged_mixed_attention as k
 
 NEEDLE = "paged_mixed_attention"
@@ -43,13 +45,14 @@ def read(ctx):
     kernel_s, _ = trace_reduce.sum_by_name(dev["ops"], NEEDLE)
     if kernel_s <= 0:
         return None
-    a = reference.arch(ctx["cell"]["config"])
+    ref = ctx["cell"]["reference"]
+    if not hasattr(ref, "kernel_shapes"):
+        return None
+    shapes = ref.kernel_shapes(ref.arch(ctx["cell"]["config"]))
     kv = ctx["engine"].resolved_config.get("kv_dtype")
     width = {"int8": (1.0, 4.0), "int4": (0.5, 4.0)}.get(kv, (2.0, 0.0))
     t0, t1 = dev["slice_monotonic"]
-    w = k.work(heads=a["heads"], kv_heads=a["kv_heads"],
-               head_dim=a["head_dim"], layers=a["layers"],
-               kv_bytes=width[0], kv_scale_bytes=width[1],
+    w = k.work(**shapes, kv_bytes=width[0], kv_scale_bytes=width[1],
                calls=calls_in_slice(ctx["run"], t0, t1))
     least, bound = k.least_seconds(w, peaks.peaks(ctx["kind"]))
     dev["attn_roofline_detail"] = {"kernel_s": kernel_s, "least_s": least,

@@ -5,18 +5,23 @@ per-layer metric sits in files of its own, found by the name in the
 manifest:
 
     benchmarks/configs/<config>/config.json   the public config.json keys
-    benchmarks/configs/<config>/deploy.json   how the pod is started
+    benchmarks/configs/<config>/deploy.json   how the pod is started, which
+                                              reference family it is held to
+    benchmarks/references/<family>.py         the plain reference of a family
+                                              of architectures
     benchmarks/traffic/<traffic>.json         the mix's parameters
     benchmarks/knees/<config>.<traffic>.json  the swept knee: callers or req/s
     benchmarks/layer_metrics/<metric>.json    what the metric reads
     benchmarks/layer_metrics/<metric>.py      ``read(ctx) -> float | None``
 
-so a later PR adds a configuration, a mix, a cell or a metric by adding
-files and one entry each to ``BENCHMARK.json``, editing no file here.
+so a later PR adds a configuration, a mix, a cell, a metric or a reference
+family by adding files and one entry each to ``BENCHMARK.json``, editing no
+file here.
 """
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import os
@@ -28,6 +33,13 @@ ROOT = os.path.dirname(HERE)
 _NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 _UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 _SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+# What a reference family exports (README.md, "A reference family");
+# ``kernel_shapes`` is optional.
+FAMILY_CONTRACT = ("arch", "param_spec", "generate_weights", "forward")
+# A key of ``reduced`` that counts what a chip holds of a layer, so that
+# ``deploy.json`` has to state the share: routed experts, vocabulary rows.
+_EXPERT_COUNT = re.compile(r"^(?!.*shared).*experts$")
+_PROBE_IDS = 258        # ``correctness.probes`` draws token ids 2..257
 
 
 def _json(path: str) -> dict:
@@ -61,6 +73,33 @@ def metric_paths(name: str, root: str = ROOT) -> tuple[str, str]:
     return base + ".json", base + ".py"
 
 
+def reference_path(family: str, root: str = ROOT) -> str:
+    return os.path.join(root, "benchmarks", "references", family + ".py")
+
+
+def _load(module: str, path: str):
+    spec = importlib.util.spec_from_file_location(
+        module + re.sub(r"\W", "_", os.path.basename(path)[:-3]), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@functools.lru_cache(maxsize=None)
+def load_reference(family: str, root: str = ROOT):
+    """The module of a reference family, loaded once a process (its
+    compiled functions live in it)."""
+    return _load("benchmarks.references._", reference_path(family, root))
+
+
+def with_share(config: dict, deploy: dict) -> dict:
+    """The configuration as its reference family reads it: ``config.json``,
+    and under ``share`` what ``deploy.json`` states of the chip's share of
+    a layer, where it states one."""
+    return dict(config, share=deploy["share"]) if "share" in deploy \
+        else config
+
+
 def cell(manifest: dict, name: str, root: str = ROOT) -> dict:
     """Everything one run needs to know about a cell."""
     for w in manifest["workloads"]:
@@ -72,10 +111,13 @@ def cell(manifest: dict, name: str, root: str = ROOT) -> dict:
     entry = next(c for c in manifest["configs"] if c["name"] == w["config"])
     cdir = config_dir(w["config"], root)
     mix = _json(traffic_path(w["traffic"], root))
+    deploy = _json(os.path.join(cdir, "deploy.json"))
     out = {"name": name, "chips": w["chips"], "config_name": w["config"],
            "config_entry": entry, "config_dir": cdir,
-           "config": _json(os.path.join(root, entry["file"])),
-           "deploy": _json(os.path.join(cdir, "deploy.json")),
+           "config": with_share(_json(os.path.join(root, entry["file"])),
+                                deploy),
+           "deploy": deploy,
+           "reference": load_reference(deploy["reference"], root),
            "traffic_name": w["traffic"],
            "mix_path": traffic_path(w["traffic"], root), "mix": mix,
            }
@@ -93,12 +135,63 @@ def cell(manifest: dict, name: str, root: str = ROOT) -> dict:
 
 def load_reader(name: str, root: str = ROOT):
     """The ``read(ctx)`` function of a per-layer metric."""
-    _, py = metric_paths(name, root)
-    spec = importlib.util.spec_from_file_location(
-        "benchmarks.layer_metrics._" + re.sub(r"\W", "_", name), py)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load("benchmarks.layer_metrics._",
+                 metric_paths(name, root)[1]).read
+
+
+def _config_faults(c: dict, root: str) -> list[str]:
+    """What is wrong with a configuration's reference family and share."""
+    try:
+        deploy = _json(os.path.join(config_dir(c["name"], root),
+                                    "deploy.json"))
+        config = _json(os.path.join(root, c["file"]))
+    except (OSError, ValueError):
+        return []                   # reported as a missing file by the caller
+    bad = []
+    family = deploy.get("reference")
+    if not (isinstance(family, str) and _NAME.match(family)):
+        bad.append(f"deploy.json names no reference family ({family!r})")
+    elif not os.path.isfile(reference_path(family, root)):
+        bad.append(f"reference family {family!r}: no "
+                   f"benchmarks/references/{family}.py")
+    else:
+        mod = load_reference(family, root)
+        lacks = [f for f in FAMILY_CONTRACT
+                 if not callable(getattr(mod, f, None))]
+        if lacks:
+            bad.append(f"reference family {family!r} lacks {lacks}")
+    held = [k for k in c["reduced"]
+            if k == "vocab_size" or _EXPERT_COUNT.match(k)]
+    share = deploy.get("share")
+    if held and not isinstance(share, dict):
+        bad.append(f"reduced lists {held}: deploy.json has to state the share")
+    if not isinstance(share, dict):
+        return bad
+    chips, index = share.get("chips_per_layer"), share.get("index")
+    if not (isinstance(chips, int) and isinstance(index, int)
+            and 0 <= index < chips):
+        bad.append(f"share: chips_per_layer {chips!r}, index {index!r}")
+        return bad
+    published = share.get("published") or {}
+    if sorted(published) != sorted(held):
+        bad.append(f"share: published counts {sorted(published)} beside the "
+                   f"reduced counts {sorted(held)}")
+        return bad
+    for k in held:
+        here, whole = config.get(k), published[k]
+        if not (isinstance(here, int) and isinstance(whole, int)
+                and here <= whole):
+            bad.append(f"share: {k} {here!r} here of {whole!r} published")
+        elif k == "vocab_size":
+            if here * 8 < whole or here < _PROBE_IDS:
+                bad.append(f"share: vocab_size {here} is under an eighth of "
+                           f"{whole} or under the {_PROBE_IDS} ids the "
+                           "probes draw from")
+        elif here < 8 or here * chips < whole:
+            bad.append(f"share: {k} {here} here of {whole} published over "
+                       f"{chips} chips: under 8 routed experts held, or the "
+                       "shares together do not hold every expert")
+    return bad
 
 
 def validate(manifest: dict, root: str = ROOT) -> list[str]:
@@ -169,6 +262,8 @@ def validate(manifest: dict, root: str = ROOT) -> list[str]:
                     r"(_dim|_rank|hidden_size|intermediate_size|head_dim|"
                     r"num_experts_per_tok)$", k) for k in c["reduced"]):
             bad.append(f"config {c['name']!r}: reduced names a width")
+        bad += [f"config {c['name']!r}: {e}"
+                for e in _config_faults(c, root)]
     used = set()
     pairs = set()
     four = 0

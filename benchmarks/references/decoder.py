@@ -1,36 +1,21 @@
-"""The plain reference: weights from the seed, one full forward in float32.
+"""The ``decoder`` reference family: the Qwen2 / Mixtral block.
 
-Nothing here imports the program.  Two things are written down:
+The contract of a family is in ``benchmarks/README.md`` ("A reference
+family"); what every family shares (which weights a seed means, the int8
+storage rule, norms, RoPE, the embedding and the vocabulary-blocked head)
+is in ``_common.py``.  Nothing here imports the program.
 
-1. **Which weights a seed means.**  The serving pod is started with
-   ``--seed S`` and no checkpoint, so it serves seeded random weights.  The
-   rule, stated here as a specification and checked against the program by
-   ``benchmarks/tests/test_reference.py``:
+**What the model computes**: the published Qwen2 / Mixtral decoder
+(pre-norm blocks, rotate-half RoPE, grouped-query causal attention,
+SwiGLU feed-forward or top-k routed SwiGLU experts with the weights of
+the chosen experts renormalised, final norm, untied output head), on the
+stored weights widened to float32, with every matmul at the highest
+precision, no cache, no kernels, no batching tricks.  Every expert is
+computed for every token and the unchosen ones weighted zero.
 
-   - the parameter tree is the one ``param_spec`` lists (stacked layers,
-     leading ``[L]``), walked depth first with the keys of every level in
-     sorted order; every leaf, norms and biases too, takes the next number
-     of a counter that starts at 1;
-   - leaf ``n`` is drawn with ``fold_in(PRNGKey(S), n)``: norms are ones,
-     biases zeros, everything else ``normal * 0.02`` in float32 rounded to
-     bfloat16;
-   - with ``weight_bits=8`` (what every cell serves) a matmul weight
-     ``[.., K, N]`` is stored as int8 with one float32 scale per output
-     channel (``max|w| / 127`` over K), the embedding ``[V, E]`` with one
-     scale per row; the router stays bfloat16.  ``weight_bits=0`` keeps
-     every leaf bfloat16.
-
-2. **What the model computes**: the published Qwen2 / Mixtral decoder
-   (pre-norm blocks, rotate-half RoPE, grouped-query causal attention,
-   SwiGLU feed-forward or top-k routed SwiGLU experts with the weights of
-   the chosen experts renormalised, final norm, untied output head), on the
-   stored weights widened to float32, with every matmul at the highest
-   precision, no cache, no kernels, no batching tricks.  Every expert is
-   computed for every token and the unchosen ones weighted zero.
-
-The stored weights of a 7B model do not fit beside the serving engine, so
-``generate_weights`` parks them in host memory and ``forward`` brings one
-layer (one expert) at a time back to the device.
+**The routing margin** is in router-logit units: the softmax is monotone,
+so the experts with the largest logits are the ones chosen, and
+``exp(margin)`` is the ratio of the two experts' probabilities.
 """
 
 from __future__ import annotations
@@ -38,6 +23,10 @@ from __future__ import annotations
 import functools
 
 import numpy as np
+
+from benchmarks.references import _common
+from benchmarks.references._common import rms as _rms, rope as _rope, \
+    widen as _widen
 
 
 def arch(config: dict) -> dict:
@@ -100,83 +89,16 @@ def param_spec(a: dict) -> list[tuple[str, tuple[int, ...], str]]:
     return out
 
 
-@functools.lru_cache(maxsize=None)
-def _leaf_fn(shape: tuple[int, ...], kind: str, bits: int):
-    import jax
-    import jax.numpy as jnp
-
-    def q8(w, axis):
-        w = w.astype(jnp.float32)
-        s = jnp.maximum(jnp.max(jnp.abs(w), axis=axis, keepdims=True),
-                        1e-8) / 127.0
-        return {"q": jnp.clip(jnp.round(w / s), -127, 127).astype(jnp.int8),
-                "s": s}
-
-    def gen(key):
-        if kind == "ones":
-            return jnp.ones(shape, jnp.bfloat16)
-        if kind == "zeros":
-            return jnp.zeros(shape, jnp.bfloat16)
-        w = (jax.random.normal(key, shape, jnp.float32) * 0.02).astype(
-            jnp.bfloat16)
-        if kind == "embed" and bits:
-            return q8(w, -1)
-        if kind == "matmul" and bits:
-            return q8(w, -2)
-        return w
-
-    return jax.jit(gen)
-
-
 def generate_weights(config: dict, seed: int, weight_bits: int = 8) -> dict:
-    """``{path: leaf}`` in host memory; a leaf is a numpy array (bfloat16
-    leaves are widened to float32, which is exact) or ``{"q", "s"}``."""
-    if weight_bits not in (0, 8):
-        raise ValueError(f"weight_bits={weight_bits}: the reference holds "
-                         "the weights the cells state, int8 or bfloat16")
-    import jax
-
-    key = jax.random.PRNGKey(seed)
-    out = {}
-    for n, (path, shape, kind) in enumerate(param_spec(arch(config)), 1):
-        leaf = _leaf_fn(shape, kind, weight_bits)(jax.random.fold_in(key, n))
-        if isinstance(leaf, dict):
-            out[path] = {k: np.asarray(v) for k, v in leaf.items()}
-        else:
-            out[path] = np.asarray(leaf.astype("float32"))
-        del leaf
-    return out
+    """The weights seed ``seed`` means for this configuration, parked in
+    host memory (``_common.generate_weights``)."""
+    return _common.generate_weights(param_spec(arch(config)), seed,
+                                    weight_bits)
 
 
-def _layer(leaf, l: int):
-    if isinstance(leaf, dict):
-        return {k: v[l] for k, v in leaf.items()}
-    return leaf[l]
-
-
-def _widen(w):
-    """A stored leaf (already on the device) as float32."""
-    import jax.numpy as jnp
-    if not isinstance(w, dict):
-        return w.astype(jnp.float32)
-    return w["q"].astype(jnp.float32) * w["s"]
-
-
-def _rms(x, w, eps):
-    import jax.numpy as jnp
-    return x / jnp.sqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * w
-
-
-def _rope(x, theta):
-    """x [B, T, H, D]; rotate-half form, position = index along T."""
-    import jax.numpy as jnp
-    d = x.shape[-1]
-    freqs = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    ang = jnp.arange(x.shape[1], dtype=jnp.float32)[None, :, None, None] \
-        * freqs
-    sin, cos = jnp.sin(ang), jnp.cos(ang)
-    x1, x2 = x[..., : d // 2], x[..., d // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+def kernel_shapes(a: dict) -> dict:
+    """What ``benchmarks/kernels/paged_mixed_attention.py`` needs."""
+    return {k: a[k] for k in ("heads", "kv_heads", "head_dim", "layers")}
 
 
 @functools.lru_cache(maxsize=None)
@@ -233,45 +155,30 @@ def _jits(akey: tuple):
         top = jax.lax.top_k(z, a["top_k"] + 1)[0]
         return top[..., -2] - top[..., -1]
 
-    def logits(x, final_norm, head, rows):
-        """Log-softmax inputs need the whole vocabulary; ``rows`` [B, R]
-        picks the positions wanted."""
-        h = _rms(jnp.take_along_axis(x, rows[..., None], axis=1),
-                 _widen(final_norm), a["eps"])
-        return h @ _widen(head)
-
-    def embed(table, tokens):
-        rows = jnp.take(table["q"], tokens, axis=0).astype(jnp.float32) \
-            if isinstance(table, dict) else jnp.take(table, tokens, axis=0)
-        if isinstance(table, dict):
-            rows = rows * jnp.take(table["s"], tokens, axis=0)
-        return rows
-
     return {k: jax.jit(f) for k, f in dict(
         attention=attention, ffn=ffn, norm2=norm2, route=route,
-        margin=margin, logits=logits, embed=embed).items()}
+        margin=margin).items()}
 
 
 def forward(config: dict, weights: dict, tokens: np.ndarray,
-            rows: np.ndarray, vocab_block: int = 1 << 15,
-            margins: list | None = None) -> np.ndarray:
+            rows: np.ndarray, margins: list | None = None) -> np.ndarray:
     """Logits ``[B, R, V]`` (float32, host) at positions ``rows [B, R]`` of
     the right-padded sequences ``tokens [B, T]``.  Causal attention keeps a
     position blind to what follows it, padding included.  A routed model
     appends each layer's ``[B, R]`` routing margin at ``rows`` to
     ``margins`` where a list is given."""
-    import jax
     import jax.numpy as jnp
 
     a = arch(config)
+    if a["tied"]:
+        raise NotImplementedError("tied output head: no cell uses one")
     fn = _jits(tuple(sorted(a.items())))
-    put = functools.partial(jax.tree.map, jnp.asarray)
+    put = _common.put
     rows_d = jnp.asarray(rows, jnp.int32)
-    with jax.default_matmul_precision("highest"):
-        x = fn["embed"](put(weights["embed"]), jnp.asarray(tokens, jnp.int32))
+    with _common.highest_precision():
+        x = _common.embed(weights, tokens, a["eps"])
         for l in range(a["layers"]):
-            lw = {k.split("/", 1)[1]: _layer(v, l)
-                  for k, v in weights.items() if k.startswith("layers/")}
+            lw = _common.layer_weights(weights, l)
             attn = put({k: lw[k] for k in lw if k in (
                 "attn_norm", "wq", "wk", "wv", "wo", "bq", "bk", "bv")})
             x = fn["attention"](x, attn)
@@ -282,24 +189,10 @@ def forward(config: dict, weights: dict, tokens: np.ndarray,
                     margins.append(np.asarray(fn["margin"](
                         hn, jnp.asarray(lw["router"]), rows_d)))
                 for e in range(a["experts"]):
-                    w = put({k: _layer(lw[k], e)
+                    w = put({k: _common.layer(lw[k], e)
                              for k in ("w_gate", "w_up", "w_down")})
                     x = x + fn["ffn"](hn, w) * gates[..., e:e + 1]
             else:
                 x = x + fn["ffn"](hn, put(
                     {k: lw[k] for k in ("w_gate", "w_up", "w_down")}))
-        final = jnp.asarray(weights["final_norm"])
-        if a["tied"]:
-            raise NotImplementedError("tied output head: no cell uses one")
-        head = weights["lm_head"]
-        out = []
-        for c0 in range(0, a["vocab"], vocab_block):
-            blk = {k: v[..., c0:c0 + vocab_block] for k, v in head.items()} \
-                if isinstance(head, dict) else head[:, c0:c0 + vocab_block]
-            out.append(np.asarray(fn["logits"](x, final, put(blk), rows_d)))
-    return np.concatenate(out, axis=-1)
-
-
-def log_softmax(logits: np.ndarray) -> np.ndarray:
-    z = logits - logits.max(axis=-1, keepdims=True)
-    return z - np.log(np.exp(z).sum(axis=-1, keepdims=True))
+        return _common.head(weights, x, rows_d, a["eps"])

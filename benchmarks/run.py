@@ -113,8 +113,8 @@ def main(argv: list[str] | None = None) -> int:
     # ---- the reference's weights, made before the engine needs the room
     from benchmarks import correctness
     spec = deploy["correct"]
-    weights = correctness.reference_weights(cell["config"], deploy,
-                                            args.seed)
+    weights = correctness.reference_weights(cell["reference"],
+                                            cell["config"], deploy, args.seed)
     t_refw = time.monotonic()
 
     pod = podlib.build(cell["config_name"], cell["config_dir"], deploy,
@@ -137,8 +137,8 @@ def main(argv: list[str] | None = None) -> int:
         if warm["status"] != 200:
             raise RuntimeError(f"warm-up stream: HTTP {warm['status']}")
         t_warm = time.monotonic()
-        cmp_ = correctness.compare(cell["config"], weights, prompts, served,
-                                   spec)
+        cmp_ = correctness.compare(cell["reference"], cell["config"], weights,
+                                   prompts, served, spec)
         del weights
         logits_ok = correctness.verdict(cmp_, spec)
         info(check="logprob_err", value=cmp_["logprob_err"],

@@ -1,6 +1,7 @@
-"""The reference against the program, on the CPU at tiny size: the weights a
-seed means are the program's bit for bit, the served logprobs agree with
-the plain forward, and the same engine in a lower precision does not."""
+"""The ``decoder`` reference family against the program, on the CPU at tiny
+size: the weights a seed means are the program's bit for bit, the served
+logprobs agree with the plain forward, and the same engine in a lower
+precision does not."""
 
 import json
 import os
@@ -8,8 +9,9 @@ import os
 import numpy as np
 import pytest
 
-from benchmarks import check_correct, manifest, reference
+from benchmarks import check_correct, manifest
 
+reference = manifest.load_reference("decoder")
 CONFIGS = ["tiny", "tiny-mixtral"]
 
 
