@@ -659,6 +659,25 @@ class EngineMetrics:
             "mixed_grid_steps_ideal_total",
             "Per-sequence causal minimum page-compute steps for the same "
             "mixed dispatches")
+        # A latent model's pool traffic and a share's routing, counted on
+        # the device inside the step and returned with its token ids (no
+        # transfer of their own): latent rows written into the pool (one a
+        # token a layer), the (token, expert) pairs the routers chose, and
+        # those of them that landed on an expert held here.  held / routed
+        # is this chip's share of the expert traffic: 1 / the shares under
+        # a uniform router (docs/monitoring.md).
+        self.mixed_latent_rows_total = r.counter(
+            "mixed_latent_rows_total",
+            "Latent rows written into the latent KV pool by mixed "
+            "dispatches (tokens x layers)")
+        self.moe_routed_pairs_total = r.counter(
+            "moe_routed_pairs_total",
+            "(token, expert) pairs chosen by the routers of mixed "
+            "dispatches, over the routers' whole width (latent models)")
+        self.moe_held_pairs_total = r.counter(
+            "moe_held_pairs_total",
+            "(token, expert) pairs of mixed dispatches whose expert is "
+            "held by this chip's share of the layer (latent models)")
         # Query rows one mixed dispatch lays out for the attention kernel
         # (the plan's nb x block_q under the ragged grid's block-compacted
         # layout; lanes x the padded widest chunk under the dense grid), to
@@ -1203,6 +1222,11 @@ class InferenceEngine:
         tokenizer = self.tokenizer
         self.cfg = cfg
         self.ecfg = engine_cfg
+        if cfg.latent:
+            self._latent_preflight(cfg, engine_cfg, draft_cfg)
+        # The step returns two counts beside its token ids (held pairs,
+        # valid rows): _count_held.
+        self._held_stat = bool(cfg.latent and cfg.num_experts)
         # Per-model KV dtype preference: a checkpoint that ships
         # kv_cache_dtype in its ModelConfig wins over the engine's "auto"
         # (an explicit EngineConfig setting still overrides the model).
@@ -1260,7 +1284,7 @@ class InferenceEngine:
                 params = tf.init_params(cfg, jax.random.PRNGKey(engine_cfg.seed), dtype)
         elif wbits:
             from arks_tpu.models import quant
-            if not quant.is_quantized(params["layers"].get("wq")):
+            if not quant.is_quantized(params["layers"].get("wo")):
                 params = quant.quantize_params(params, bits=wbits,
                                                shards=tp_shards)
         if mesh is not None:
@@ -1346,8 +1370,10 @@ class InferenceEngine:
             kv_bits = (engine_cfg.kv_bits if engine_cfg.kv_quantized
                        else jnp.dtype(self._cache_dtype(dtype)).itemsize * 8)
             d_store = tf.cache_head_dim(cfg, self._pad_head())
+            # K and V; a latent page holds its one row once.
             page_bytes = (cfg.num_layers * cfg.num_kv_heads * page
-                          * d_store * kv_bits // 8 * 2)
+                          * d_store * kv_bits // 8
+                          * (1 if cfg.latent else 2))
             if engine_cfg.kv_quantized:
                 page_bytes += cfg.num_layers * cfg.num_kv_heads * page * 4 * 2
             extra = 0
@@ -1394,8 +1420,10 @@ class InferenceEngine:
             # dispatch rows are dropped by the kernels instead of landing
             # in (possibly shared) pages.
             self._lengths[:] = self._park_sentinel()
-            log.info("paged KV: %d pages x %d tokens (%d retention extra)",
-                     num_pages, page, extra)
+            log.info("paged KV: %d pages x %d tokens (%d retention extra), "
+                     "%d bytes a token%s", num_pages, page, extra,
+                     self._cache.token_bytes,
+                     " (one latent row, stored once)" if cfg.latent else "")
         else:
             self._max_pages = 0
             self._page_bytes = 0
@@ -1435,7 +1463,7 @@ class InferenceEngine:
             raise ValueError(
                 f"ARKS_PREFIX_HOST_MB={host_mb}: must be >= 0")
         self._host_mb = host_mb if (self._paged and self._chunk
-                                    and host_mb) else 0
+                                    and host_mb and not cfg.latent) else 0
         if keep_tiers is not None:
             # Elastic rebuild: adopt the surviving tier-1 store (blocks
             # are full logical host arrays — mesh-shape-independent).
@@ -1609,6 +1637,13 @@ class InferenceEngine:
                 f"(resolved kv_layout={'paged' if self._paged else 'slot'}, "
                 f"prefill_chunk={self._chunk or None}, "
                 f"ARKS_MIXED_STEP={_mx})")
+        if cfg.latent and not self._mixed:
+            raise ValueError(
+                f"model {cfg.name!r} (latent attention) is served by the "
+                "mixed scheduler only; the legacy scheduler speaks K and V "
+                f"(resolved kv_layout={'paged' if self._paged else 'slot'}, "
+                f"prefill_chunk={self._chunk or None}, "
+                f"ARKS_MIXED_STEP={_mx})")
         self._mixed_budget = 0
         # Per-qmax grid plans memoized for the padding-waste counters
         # (_mixed_grid_counters): the plan is static per engine shape, so
@@ -1622,6 +1657,22 @@ class InferenceEngine:
                     f"ARKS_MIXED_CHUNK_TOKENS={budget}: must be >= 1")
             self._mixed_budget = min(budget, engine_cfg.max_cache_len)
         self._decode_impl = self._resolve_decode_impl()
+        if (self._mixed and self._decode_impl == "pallas"
+                and jax.default_backend() == "tpu"):
+            # The row-write kernels' block tables must fit SMEM: a v5e
+            # refused 16 slots + a 2048-token budget at its first dispatch.
+            # Refused here instead, by name.
+            from arks_tpu.engine.paged import mixed_step_row_limit
+            rows = engine_cfg.num_slots + self._mixed_budget
+            if rows > mixed_step_row_limit(self._max_pages):
+                raise ValueError(
+                    f"num_slots {engine_cfg.num_slots} + "
+                    f"ARKS_MIXED_CHUNK_TOKENS {self._mixed_budget} = {rows} "
+                    "rows a mixed step: the KV row-write kernel prefetches "
+                    "a block-table row per flat token into 1 MiB of SMEM, "
+                    f"which holds {mixed_step_row_limit(self._max_pages)} "
+                    f"rows of {self._max_pages} pages; lower the chunk "
+                    "budget or the slots")
 
         # ---- Windowed residency (ARKS_RESIDENCY_WINDOW_PAGES) ----------
         # Created only once the mixed scheduler is resolved: the manager's
@@ -1729,6 +1780,13 @@ class InferenceEngine:
             "overlap": str(bool(self._overlap)).lower(),
             "kv_cache_dtype": self.ecfg.resolve_kv_cache_dtype(),
             "kv_dtype": self.ecfg.resolve_kv_cache_dtype(),
+            # What a page holds: K and V per KV head, or ONE latent row a
+            # token (latent attention), which is key and value at once.
+            "kv_page": "latent" if cfg.latent else "kv",
+            # This chip's share of each routed layer ("rank/size"; "0/1":
+            # every expert is held here).
+            "expert_share": f"{cfg.expert_parallel_rank}/"
+                            f"{cfg.expert_parallel_size}",
             "kernel_tune": autotune.mode(),
             "mixed_grid": mixed_grid_mode(),
             "weight_dtype": self.ecfg.weight_dtype or "native",
@@ -2183,6 +2241,17 @@ class InferenceEngine:
             # guide-row advances of DECODE lanes merge back into the
             # persistent state; completion lanes are written by the host's
             # set_slot at registration, exactly like the legacy chunk path.
+            held_stat = self._held_stat
+
+            def with_counts(ids, held, valid):
+                """The step's token ids with, for a latent routed model,
+                two counts behind them (held pairs, valid rows): they
+                ride the ids' transfer (_count_held)."""
+                if not held_stat:
+                    return ids
+                return jnp.concatenate([ids, jnp.stack(
+                    [held[0], jnp.sum(valid).astype(jnp.int32)])])
+
             def mixed_prog(params, cache, sampling, tokens, token_slot,
                            token_pos, tables, feed_tokens, feed_active,
                            lengths, sample_src, seq_q_start, seq_q_len,
@@ -2192,10 +2261,10 @@ class InferenceEngine:
                            gtables, want_lp: bool):
                 sampling = sampler_mod.count_tokens(sampling, feed_tokens,
                                                     feed_active)
-                logits, cache = tf.mixed_step(
+                logits, cache, *held = tf.mixed_step(
                     params, cfg, cache, tables, tokens, token_slot,
                     token_pos, sample_src, seq_q_start, seq_q_len,
-                    seq_pos_start, mesh)
+                    seq_pos_start, mesh, with_held=held_stat)
                 # The override columns are sampler work too (arks.sampler
                 # in a profile, like the sampler's own functions).
                 with jax.named_scope("arks.sampler"):
@@ -2238,8 +2307,9 @@ class InferenceEngine:
                                         sampling.guide_row))
                 if want_lp:
                     clp, vals, lids = sampler_mod.top_logprobs(logits, ids)
-                    return ids, clp, vals, lids, cache, sampling
-                return ids, cache, sampling
+                    return (with_counts(ids, held, token_slot >= 0), clp,
+                            vals, lids, cache, sampling)
+                return with_counts(ids, held, token_slot >= 0), cache, sampling
 
             self._mixed_fn = _named_jit(
                 "arks_mixed_seq", functools.partial(mixed_prog, want_lp=False),
@@ -2266,10 +2336,12 @@ class InferenceEngine:
                 # Decode-only flat batch, lane t == slot t: dead lanes
                 # park at the sentinel position (writes dropped, nothing
                 # attended) exactly like the host-built batch's padding.
-                logits, cache = tf.mixed_step(
+                logits, cache, *held = tf.mixed_step(
                     params, cfg, cache, tables, tokens,
                     jnp.where(alive, lane, jnp.int32(-1)), eff,
-                    lane, lane, alive.astype(jnp.int32), eff, mesh)
+                    lane, lane, alive.astype(jnp.int32), eff, mesh,
+                    with_held=held_stat)
+                fed = alive
                 nxt, sstate = sampler_mod.sample(logits, sstate, alive,
                                                  eff, guide_tables=gtables)
                 nxt = jnp.where(alive, nxt, jnp.int32(0))
@@ -2277,14 +2349,15 @@ class InferenceEngine:
                 alive = sampler_mod.advance_liveness(
                     nxt[None], alive, lengths, stop_ids, dead_len)
                 tokens_out = jnp.where(alive, nxt, jnp.int32(0))
+                toks = with_counts(nxt, held, fed)[None]
                 if want_lp:
                     clp, vals, lids = sampler_mod.top_logprobs(logits, nxt)
                     # [1, B]-shaped outputs so the resolve fanout shares
                     # the K-step record format.
-                    return (cache, sstate, nxt[None], clp[None],
+                    return (cache, sstate, toks, clp[None],
                             vals[None], lids[None], tokens_out, lengths,
                             alive)
-                return (cache, sstate, nxt[None], tokens_out, lengths,
+                return (cache, sstate, toks, tokens_out, lengths,
                         alive)
 
             self._mixed_pipe_fn = _named_jit(
@@ -2889,6 +2962,26 @@ class InferenceEngine:
         shape cannot take are an error here, not a quiet fallback."""
         from arks_tpu.ops.attention import default_decode_impl, kernel_blockers
         want = default_decode_impl()
+        if self.cfg.latent:
+            # No quiet fallback: on a TPU the latent kernel runs or the
+            # engine refuses; off a TPU "xla" is the gather oracle unless
+            # the kernel was asked for by name (interpret mode).
+            from arks_tpu.ops.attention import latent_kernel_blockers
+            blockers = latent_kernel_blockers(
+                tf.cache_head_dim(self.cfg, self._pad_head()),
+                self.cfg.kv_lora_rank, self.mesh)
+            if blockers and (want == "pallas"
+                             or jax.default_backend() == "tpu"):
+                raise ValueError(
+                    f"model {self.cfg.name!r}: the latent attention "
+                    "kernel cannot serve this engine: "
+                    + "; ".join(blockers))
+            if want != "pallas" and jax.default_backend() == "tpu":
+                raise ValueError(
+                    f"model {self.cfg.name!r}: ARKS_ATTN_IMPL={want} on a "
+                    "TPU would serve the latent pool through the XLA "
+                    "gather (a per-token copy of every page)")
+            return want
         if want != "pallas":
             return want
         mesh = self.mesh
@@ -7168,6 +7261,11 @@ class InferenceEngine:
 
         One-shot only: the transferred KV is a single [T] block, so prompts
         beyond the largest bucket are rejected (HTTP 400 at the server)."""
+        if self.cfg.latent:
+            raise ValueError(
+                f"model {self.cfg.name!r} (latent attention): a detached "
+                "prefill hands K and V to another pod (kv_transfer); a "
+                "latent page is not carried")
         if len(prompt_ids) > self._one_shot_limit():
             raise ContextLengthExceededError(
                 f"prompt has {len(prompt_ids)} tokens but the disaggregated "
@@ -7688,6 +7786,8 @@ class InferenceEngine:
         self._faults.fire("resolve")
         t_wait = time.monotonic()
         toks = np.asarray(toks)  # host sync point (async copy usually done)
+        if self._held_stat:
+            self._count_held(toks[0])
         counts = None if counts_dev is None else np.asarray(counts_dev)
         if lp_devs is not None:
             clps = np.asarray(lp_devs[0])    # [K, B]
@@ -8070,13 +8170,80 @@ class InferenceEngine:
         self.metrics.mixed_kv_bytes_total.inc(b_actual)
         self.metrics.mixed_kv_bytes_ideal_total.inc(b_ideal)
 
+    def _count_held(self, ids: np.ndarray) -> None:
+        """A latent routed model's step hands back two counts behind its
+        token ids (the last two entries: (token, expert) pairs that landed
+        on experts held here, and the rows that carried a token): the
+        three counters of docs/monitoring.md, from values already on the
+        host."""
+        held, rows = int(ids[-2]), int(ids[-1])
+        cfg = self.cfg
+        self.metrics.mixed_latent_rows_total.inc(rows * cfg.num_layers)
+        self.metrics.moe_routed_pairs_total.inc(
+            rows * cfg.num_experts_per_tok * cfg.num_routed_layers)
+        self.metrics.moe_held_pairs_total.inc(held)
+
+    def _latent_preflight(self, cfg: ModelConfig, ecfg: "EngineConfig",
+                          draft_cfg) -> None:
+        """A latent-attention model is served by the mixed scheduler over a
+        bf16 latent pool on one device.  Everything else that moves KV
+        still speaks K and V; asked for, it is refused here, at
+        construction, by name (as ops.attention.kernel_blockers does for a
+        kernel), and nothing falls back quietly.  The device-tier prefix
+        cache shares PAGES by id and keeps working."""
+        if ecfg.kv_cache_dtype == "auto":
+            ecfg.kv_cache_dtype = "bf16"
+        if ecfg.kv_layout == "auto":
+            ecfg.kv_layout = "paged"
+        why = []
+        if ecfg.kv_cache_dtype != "bf16":
+            why.append(f"kv_cache_dtype={ecfg.kv_cache_dtype} (a latent "
+                       "page is bf16 only: an int8 / int4 latent row is "
+                       "not built)")
+        if ecfg.kv_layout != "paged":
+            why.append(f"kv_layout={ecfg.kv_layout} (the slot layout holds "
+                       "K and V per head)")
+        if self.mesh is not None and self.mesh.size > 1:
+            why.append(f"a device mesh {dict(self.mesh.shape)} (tensor / "
+                       "data / context / pipeline parallelism: the latent "
+                       "block has no sharding rules)")
+        if ecfg.draft_model or draft_cfg is not None:
+            why.append("speculative decoding (the draft and the verify "
+                       "rows speak K and V)")
+        if not ecfg.prefill_chunk:
+            why.append("prefill_chunk off (the mixed scheduler needs "
+                       "chunked prefill)")
+        # The host tier is on by default: for a latent model the default
+        # is off (_init_model_state), and only a tier ASKED for is refused.
+        for knob, what in (
+                ("ARKS_PREFIX_HOST_MB", "the host spill tier"),
+                ("ARKS_PREFIX_DISK_MB", "the disk spill tier"),
+                ("ARKS_RESIDENCY_WINDOW_PAGES", "windowed residency")):
+            if knobs.is_set(knob) and knobs.get_int(knob) > 0:
+                why.append(f"{knob} ({what} moves K and V blocks)")
+        if knobs.get_bool("ARKS_PREEMPT"):
+            why.append("ARKS_PREEMPT (the KV swap moves K and V blocks)")
+        if [a for a in knobs.get_list("ARKS_PEER_ADDRS") if a.strip()]:
+            why.append("ARKS_PEER_ADDRS (peer fetch carries K and V blocks "
+                       "in the AKV1 format)")
+        if knobs.get_str("ARKS_MIXED_STEP") == "0":
+            why.append("ARKS_MIXED_STEP=0 (the legacy scheduler)")
+        if (knobs.raw("ARKS_MIXED_GRID") or "ragged").lower() != "ragged":
+            why.append("ARKS_MIXED_GRID=dense (the latent kernel is the "
+                       "ragged work-list grid)")
+        if why:
+            raise ValueError(
+                f"model {cfg.name!r} (latent attention, one latent row a "
+                "token) cannot be served with: " + "; ".join(why))
+
     def _page_head_bytes(self) -> int:
         """Bytes one (page, KV head) block moves over the mixed kernel's
         page stream: K + V rows (int4 pools store packed nibble rows, so
         the row count already reflects the halving) plus the f32 scale
         rows for quantized pools."""
         k = self._cache.k
-        per = 2 * k.shape[3] * k.shape[4] * k.dtype.itemsize
+        per = (1 if self._cache.v is None else 2) \
+            * k.shape[3] * k.shape[4] * k.dtype.itemsize
         if self._cache.k_scale is not None:
             per += 2 * self._cache.k_scale.shape[3] * 4
         return per
@@ -8257,6 +8424,8 @@ class InferenceEngine:
         ids = np.asarray(ids_dev)   # [B] — host sync point
         self.metrics.decode_resolve_wait_seconds_total.inc(
             time.monotonic() - t_wait, mode="sequential")
+        if self._held_stat:
+            self._count_held(ids)
         if lp_devs is not None:
             clps = np.asarray(lp_devs[0])
             lvals = np.asarray(lp_devs[1])

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import math
 import os
 from typing import Any
 
@@ -47,10 +48,88 @@ class ModelConfig:
     # config instead of every deployment flagging it.  "auto" = no
     # preference (the engine's backend default applies).
     kv_cache_dtype: str = "auto"
+    # Latent attention (DeepSeek-V3 / ``kimi_k2`` block; kv_lora_rank 0 =
+    # the GQA block).  Queries go down to ``q_lora_rank`` and up to
+    # ``num_heads x (qk_nope_head_dim + qk_rope_head_dim)``; keys and
+    # values of ALL heads come out of one cached row a token: the normed
+    # ``kv_lora_rank`` latent and ``qk_rope_head_dim`` rotary lanes.
+    # ``head_dim`` is qk_nope + qk_rope for such a model.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN (``rope_scaling`` of type yarn; factor 0 = plain RoPE):
+    # (factor, original_max_position_embeddings, beta_fast, beta_slow,
+    # mscale, mscale_all_dim).
+    rope_yarn: tuple[float, ...] = ()
+    # The first ``first_k_dense`` layers keep a dense SwiGLU FFN of
+    # ``intermediate_size``; the rest are routed (two stacked trees).
+    first_k_dense: int = 0
+    # "softmax" (above) | "sigmoid": DeepSeek-V3 ``noaux_tc`` routing:
+    # sigmoid scores, top-k of score + a learnt selection bias, weights
+    # from the unbiased scores, normalised when ``norm_topk_prob``, times
+    # ``routed_scaling_factor``.
+    scoring_func: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    # Ungated shared experts (``n_shared_experts x moe_intermediate_size``
+    # wide); ``shared_expert_intermediate_size`` is the sigmoid-gated kind.
+    n_shared_experts: int = 0
+    # This chip's share of each routed layer (expert parallelism seen
+    # from one chip): ``num_experts`` are HELD here, the router scores
+    # ``num_experts x expert_parallel_size`` and share ``rank`` holds the
+    # experts [rank x held, (rank + 1) x held).  Size 1 = all of them.
+    expert_parallel_size: int = 1
+    expert_parallel_rank: int = 0
 
     @property
     def q_dim(self) -> int:
         return self.num_heads * self.head_dim
+
+    @property
+    def latent(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_row(self) -> int:
+        """Width of the one cached row a token of a latent model."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def attn_out_dim(self) -> int:
+        """Width of the concatenated heads the output projection takes."""
+        return self.num_heads * (self.v_head_dim if self.latent
+                                 else self.head_dim)
+
+    @property
+    def router_width(self) -> int:
+        """Experts the router scores: the held ones times the shares."""
+        return self.num_experts * self.expert_parallel_size
+
+    @property
+    def num_routed_layers(self) -> int:
+        return self.num_layers - self.first_k_dense if self.num_experts else 0
+
+    @property
+    def softmax_scale(self) -> float:
+        """What the attention scores are multiplied by: head_dim^-0.5,
+        and under YaRN the square of ``0.1 mscale_all_dim ln(factor) + 1``
+        (DeepSeek-V3's modelling file)."""
+        scale = self.head_dim ** -0.5
+        if self.rope_yarn and self.rope_yarn[0] > 1 and self.rope_yarn[5]:
+            m = 0.1 * self.rope_yarn[5] * math.log(self.rope_yarn[0]) + 1.0
+            scale *= m * m
+        return scale
+
+    def with_expert_share(self, size: int, rank: int) -> "ModelConfig":
+        if size < 1 or not 0 <= rank < size:
+            raise ValueError(f"expert share {rank}/{size}: the rank must "
+                             "lie in [0, size)")
+        if size > 1 and not self.num_experts:
+            raise ValueError(f"expert share {rank}/{size}: model "
+                             f"{self.name!r} has no routed experts")
+        return dataclasses.replace(self, expert_parallel_size=size,
+                                   expert_parallel_rank=rank)
 
     @property
     def kv_dim(self) -> int:
@@ -59,18 +138,33 @@ class ModelConfig:
     def num_params(self) -> int:
         """Approximate parameter count (embeddings + blocks + head)."""
         e, f, v = self.hidden_size, self.intermediate_size, self.vocab_size
-        attn = e * self.q_dim + 2 * e * self.kv_dim + self.q_dim * e
+        if self.latent:
+            attn = (e * self.q_lora_rank + self.q_lora_rank * self.q_dim
+                    + e * self.latent_row
+                    + self.kv_lora_rank * self.num_heads
+                    * (self.qk_nope_head_dim + self.v_head_dim)
+                    + self.attn_out_dim * e
+                    + self.q_lora_rank + self.kv_lora_rank)
+        else:
+            attn = e * self.q_dim + 2 * e * self.kv_dim + self.q_dim * e
         if self.qkv_bias:
             attn += self.q_dim + 2 * self.kv_dim
+        dense = 3 * e * f
+        mlp = dense
         if self.num_experts:
+            # What is HELD here; the router keeps its whole width.
             mlp = self.num_experts * 3 * e * self.moe_intermediate_size \
-                + e * self.num_experts
+                + e * self.router_width
+            if self.scoring_func == "sigmoid":
+                mlp += self.router_width          # the selection bias
             if self.shared_expert_intermediate_size:
                 mlp += 3 * e * self.shared_expert_intermediate_size + e
-        else:
-            mlp = 3 * e * f
+            mlp += 3 * e * self.n_shared_experts * self.moe_intermediate_size
         norms = 2 * e
-        blocks = self.num_layers * (attn + mlp + norms)
+        routed = self.num_routed_layers if self.num_experts \
+            else self.num_layers
+        blocks = self.num_layers * (attn + norms) + routed * mlp \
+            + (self.num_layers - routed) * dense
         head = 0 if self.tie_word_embeddings else e * v
         return v * e + blocks + e + head
 
@@ -98,6 +192,14 @@ class ModelConfig:
         # num_experts (Qwen2-MoE).
         num_experts = int(d.get("num_local_experts", d.get("num_experts", 0)) or 0)
         is_mixtral = "mixtral" in arch or model_type == "mixtral"
+        if d.get("kv_lora_rank") or d.get("n_routed_experts"):
+            return _from_deepseek_v3(d, name or model_type or "hf-model",
+                                     tuple(eos))
+        if d.get("rope_scaling"):
+            raise ValueError(
+                f"rope_scaling={d['rope_scaling']!r}: only the latent-"
+                "attention block reads a rope_scaling (type yarn); serving "
+                "this model with plain RoPE would be another model")
         return ModelConfig(
             name=name or model_type or "hf-model",
             vocab_size=d["vocab_size"],
@@ -123,6 +225,81 @@ class ModelConfig:
             norm_topk_prob=bool(d.get("norm_topk_prob", is_mixtral)),
             kv_cache_dtype=str(d.get("kv_cache_dtype", "auto")),
         )
+
+
+def _from_deepseek_v3(d: dict[str, Any], name: str,
+                      eos: tuple[int, ...]) -> ModelConfig:
+    """The DeepSeek-V3 block (``deepseek_v3``, ``kimi_k2``): latent
+    attention, a dense prefix, sigmoid-routed experts with a selection
+    bias, ungated shared experts, YaRN.  Key for key from the published
+    file; what this block cannot express is refused, not approximated."""
+    def refuse(what: str) -> None:
+        raise ValueError(f"config {name!r}: {what} is not supported (the "
+                         "model would be served as another model)")
+
+    for k in ("kv_lora_rank", "q_lora_rank", "qk_nope_head_dim",
+              "qk_rope_head_dim", "v_head_dim", "n_routed_experts"):
+        if not d.get(k):
+            refuse(f"a DeepSeek-V3-style block without {k}")
+    if d.get("scoring_func", "softmax") != "sigmoid":
+        refuse(f"scoring_func={d.get('scoring_func', 'softmax')!r} with "
+               "n_routed_experts (only sigmoid)")
+    if d.get("topk_method", "noaux_tc") != "noaux_tc":
+        refuse(f"topk_method={d['topk_method']!r} (only noaux_tc)")
+    if int(d.get("n_group", 1) or 1) > 1 or int(d.get("topk_group", 1)
+                                                 or 1) > 1:
+        refuse(f"group-limited routing (n_group={d.get('n_group')}, "
+               f"topk_group={d.get('topk_group')})")
+    if int(d.get("moe_layer_freq", 1) or 1) != 1:
+        refuse(f"moe_layer_freq={d['moe_layer_freq']}")
+    if d.get("attention_bias"):
+        refuse("attention_bias")
+    if int(d.get("num_nextn_predict_layers", 0) or 0):
+        refuse("multi-token prediction layers")
+    yarn: tuple[float, ...] = ()
+    rs = d.get("rope_scaling")
+    if rs:
+        if rs.get("type", rs.get("rope_type")) != "yarn":
+            refuse(f"rope_scaling type {rs.get('type', rs.get('rope_type'))!r}"
+                   " (only yarn)")
+        yarn = (float(rs["factor"]),
+                float(rs["original_max_position_embeddings"]),
+                float(rs.get("beta_fast", 32)), float(rs.get("beta_slow", 1)),
+                float(rs.get("mscale", 1)), float(rs.get("mscale_all_dim", 0)))
+    heads = d["num_attention_heads"]
+    layers = d["num_hidden_layers"]
+    first = int(d.get("first_k_dense_replace", 0) or 0)
+    if not 0 <= first < layers:
+        refuse(f"first_k_dense_replace={first} of {layers} layers")
+    return ModelConfig(
+        name=name,
+        vocab_size=d["vocab_size"],
+        hidden_size=d["hidden_size"],
+        intermediate_size=d["intermediate_size"],
+        num_layers=layers,
+        num_heads=heads,
+        num_kv_heads=1,
+        head_dim=d["qk_nope_head_dim"] + d["qk_rope_head_dim"],
+        rope_theta=float(d.get("rope_theta", 10000.0)),
+        rms_norm_eps=float(d.get("rms_norm_eps", 1e-6)),
+        tie_word_embeddings=bool(d.get("tie_word_embeddings", False)),
+        max_position_embeddings=int(d.get("max_position_embeddings", 32768)),
+        eos_token_ids=eos,
+        num_experts=int(d["n_routed_experts"]),
+        num_experts_per_tok=int(d["num_experts_per_tok"]),
+        moe_intermediate_size=int(d["moe_intermediate_size"]),
+        norm_topk_prob=bool(d.get("norm_topk_prob", True)),
+        q_lora_rank=int(d["q_lora_rank"]),
+        kv_lora_rank=int(d["kv_lora_rank"]),
+        qk_nope_head_dim=int(d["qk_nope_head_dim"]),
+        qk_rope_head_dim=int(d["qk_rope_head_dim"]),
+        v_head_dim=int(d["v_head_dim"]),
+        rope_yarn=yarn,
+        first_k_dense=first,
+        scoring_func="sigmoid",
+        routed_scaling_factor=float(d.get("routed_scaling_factor", 1.0)),
+        n_shared_experts=int(d.get("n_shared_experts", 0) or 0),
+    )
 
 
 _REGISTRY: dict[str, ModelConfig] = {}
@@ -191,6 +368,20 @@ register_config(ModelConfig(
     num_layers=2, num_heads=8, num_kv_heads=4, head_dim=8,
     num_experts=4, num_experts_per_tok=2, moe_intermediate_size=96,
     norm_topk_prob=True, eos_token_ids=(0,),
+))
+
+# Latent attention + sigmoid-routed experts behind one dense layer (the
+# DeepSeek-V3 / kimi_k2 block) at CPU-test size: 4 heads of 16 | 8 over a
+# 32 + 8 latent row, 1 dense + 2 routed layers, 16 experts top-4.
+register_config(ModelConfig(
+    name="tiny-mla-moe", vocab_size=512, hidden_size=64,
+    intermediate_size=128, num_layers=3, num_heads=4, num_kv_heads=1,
+    head_dim=24, rms_norm_eps=1e-5, eos_token_ids=(0,),
+    num_experts=16, num_experts_per_tok=4, moe_intermediate_size=32,
+    norm_topk_prob=True, q_lora_rank=48, kv_lora_rank=32,
+    qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+    rope_yarn=(4.0, 64.0, 32.0, 1.0, 1.0, 1.0), first_k_dense=1,
+    scoring_func="sigmoid", routed_scaling_factor=2.5, n_shared_experts=1,
 ))
 
 # MoE families (HF: mistralai/Mixtral-8x7B-Instruct-v0.1, Qwen/Qwen2-57B-A14B).

@@ -23,6 +23,19 @@ Weight layout per layer (leading [L] from the stacked-layer convention):
   w_gate/up   [L, X, E, Fm]     w_down [L, X, Fm, E]
   shared gate/up [L, E, Fs], shared down [L, Fs, E], shared_gate [L, E]
 where X = num_experts, Fm = moe_intermediate_size.
+
+DeepSeek-V3 / kimi_k2 routing (``cfg.scoring_func == "sigmoid"``): the
+router also carries ``router_bias [L, W]``, the shared experts are
+ungated (``n_shared_experts x Fm`` wide, no ``shared_gate``), and L is
+the ROUTED stack's depth (the dense prefix has a tree of its own).
+
+**A share of a layer** (``cfg.expert_parallel_size`` > 1: expert
+parallelism as one chip sees it): the router keeps its whole width
+``W = X x size``, X experts are held here, the experts
+``[rank x X, (rank + 1) x X)``, and the layer returns the part of the
+result its own experts give (plus the shared expert, which every chip
+computes alike).  What the absent experts would add is left out; on one
+chip the layer runs without its exchange.
 """
 
 from __future__ import annotations
@@ -33,26 +46,37 @@ import jax.numpy as jnp
 Params = dict
 
 
+def shared_width(cfg) -> int:
+    """Width of the shared expert's FFN (0: none): the sigmoid-gated kind
+    states it, the ungated kind is ``n_shared_experts`` routed widths."""
+    return (cfg.shared_expert_intermediate_size
+            or cfg.n_shared_experts * cfg.moe_intermediate_size)
+
+
 def init_moe_params(cfg, key, dtype) -> Params:
-    l, e = cfg.num_layers, cfg.hidden_size
+    l, e = cfg.num_routed_layers, cfg.hidden_size
     x, fm = cfg.num_experts, cfg.moe_intermediate_size
-    keys = iter(jax.random.split(key, 8))
+    keys = iter(jax.random.split(key, 9))
 
     def w(k, shape, scale=0.02):
         return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dtype)
 
     p: Params = {
-        "router": w(next(keys), (l, e, x)),
+        "router": w(next(keys), (l, e, cfg.router_width)),
         "w_gate": w(next(keys), (l, x, e, fm)),
         "w_up": w(next(keys), (l, x, e, fm)),
         "w_down": w(next(keys), (l, x, fm, e)),
     }
-    if cfg.shared_expert_intermediate_size:
-        fs = cfg.shared_expert_intermediate_size
+    fs = shared_width(cfg)
+    if fs:
         p["shared_gate_proj"] = w(next(keys), (l, e, fs))
         p["shared_up"] = w(next(keys), (l, e, fs))
         p["shared_down"] = w(next(keys), (l, fs, e))
+    if cfg.shared_expert_intermediate_size:
         p["shared_gate"] = w(next(keys), (l, e))
+    if cfg.scoring_func == "sigmoid":
+        # Non-zero, so that what selects and what weighs differ.
+        p["router_bias"] = w(next(keys), (l, cfg.router_width))
     return p
 
 
@@ -68,11 +92,14 @@ def moe_pspecs(cfg, axis_model: str, shard_experts: bool) -> Params:
         "w_up": P(None, ex, None, None),
         "w_down": P(None, ex, None, None),
     }
-    if cfg.shared_expert_intermediate_size:
+    if shared_width(cfg):
         p["shared_gate_proj"] = P(None, None, axis_model)
         p["shared_up"] = P(None, None, axis_model)
         p["shared_down"] = P(None, axis_model, None)
+    if cfg.shared_expert_intermediate_size:
         p["shared_gate"] = P(None, None)
+    if cfg.scoring_func == "sigmoid":
+        p["router_bias"] = P(None, None)
     return p
 
 
@@ -80,65 +107,194 @@ def shard_experts(cfg, tp: int) -> bool:
     return tp > 1 and cfg.num_experts % tp == 0
 
 
-def router_topk(logits: jnp.ndarray, cfg) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """[.., X] router logits → ([.., k] combine weights, [.., k] expert ids):
-    softmax over all experts, top-k selected; renormalized when
-    ``norm_topk_prob`` (Mixtral semantics — equal to softmax over the top-k
-    logits).  Float32 throughout.  Shared by the dense and grouped dispatch
-    paths so routing semantics can never diverge between them."""
+def router_topk(logits: jnp.ndarray, cfg, bias: jnp.ndarray | None = None
+                ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """[.., W] router logits → ([.., k] combine weights, [.., k] expert ids)
+    over the router's whole width.  Float32 throughout.  Shared by the
+    dense and grouped dispatch paths so routing semantics can never diverge
+    between them.  By ``cfg.scoring_func``:
+
+    - ``softmax``: softmax over all experts, top-k selected; renormalized
+      when ``norm_topk_prob`` (Mixtral semantics — equal to softmax over
+      the top-k logits);
+    - ``sigmoid`` (DeepSeek-V3 ``noaux_tc``, no group limit): scores are
+      sigmoids; the top-k of ``score + bias`` are CHOSEN, their weights are
+      the UNBIASED scores, normalised over the chosen when
+      ``norm_topk_prob``, times ``routed_scaling_factor``."""
+    k = cfg.num_experts_per_tok
+    if cfg.scoring_func == "sigmoid":
+        scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+        _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+        vals = jnp.take_along_axis(scores, idx, axis=-1)
+        if cfg.norm_topk_prob:
+            vals = vals / (jnp.sum(vals, axis=-1, keepdims=True) + 1e-20)
+        return vals * cfg.routed_scaling_factor, idx
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    vals, idx = jax.lax.top_k(probs, cfg.num_experts_per_tok)
+    vals, idx = jax.lax.top_k(probs, k)
     if cfg.norm_topk_prob:
         vals = vals / (jnp.sum(vals, axis=-1, keepdims=True) + 1e-9)
     return vals, idx
 
 
-def router_weights(logits: jnp.ndarray, cfg) -> jnp.ndarray:
-    """[.., X] router logits → [.., X] combine weights (unselected experts
-    zero) — the dense-dispatch form of router_topk."""
-    vals, idx = router_topk(logits, cfg)
+def router_weights(logits: jnp.ndarray, cfg,
+                   bias: jnp.ndarray | None = None) -> jnp.ndarray:
+    """[.., W] router logits → [.., X] combine weights of the experts HELD
+    here (unselected experts zero) — the dense-dispatch form of
+    router_topk; under a share, the held columns of the whole width."""
+    vals, idx = router_topk(logits, cfg, bias)
+    if cfg.expert_parallel_size > 1:
+        idx = idx - held_first(cfg)       # absent experts fall off the one-hot
     onehot = jax.nn.one_hot(idx, cfg.num_experts, dtype=vals.dtype)  # [.., k, X]
     return jnp.einsum("...k,...kx->...x", vals, onehot)
+
+
+def held_first(cfg) -> int:
+    """Id, in the router's numbering, of the first expert held here."""
+    return cfg.expert_parallel_rank * cfg.num_experts
+
+
+def _held_capacity(n_tokens: int, cfg) -> int:
+    """Rows each held expert takes in the share's batched dispatch: four
+    times what a uniform router sends an expert, in tiles of 128, at most
+    every token.  What an expert draws beyond them goes through
+    :func:`_share_dispatch`'s overflow tiles: nothing is ever dropped."""
+    fair = -(-n_tokens * cfg.num_experts_per_tok // cfg.router_width)
+    return min(n_tokens, -(-4 * fair // 128) * 128)
+
+
+# Overflow tiles a share's dispatch runs whether it needs them or not: the
+# seeded routers of the benchmark's configuration overflow by a few tiles
+# a layer on most seeds and not at all on others, and a step whose time
+# followed that moved a 45 s closed-loop reading by 1-2 % (PERF.md §6,
+# PR 27).  A layer that needs more runs more.
+_SPARE_TILES = 4
+
+
+def _expert_dot(eq: str, x: jnp.ndarray, w) -> jnp.ndarray:
+    """``[X, C, a] x [X, a, b] -> [X, C, b]`` where ``w`` may be a
+    quantised leaf: int8 converts in the dot's operand read and scales the
+    output per channel (``quant.qeinsum``'s rule with the expert dim
+    leading); int4 dequantises its operand."""
+    from arks_tpu.models.quant import dequantize, is_quantized
+    if not is_quantized(w) or "gs" in w:
+        return jnp.einsum(eq, x, dequantize(w, x.dtype))
+    return jnp.einsum(eq, x, w["q"].astype(x.dtype)) * w["s"].astype(x.dtype)
+
+
+def _share_dispatch(x2: jnp.ndarray, vals: jnp.ndarray, idx: jnp.ndarray,
+                    mp: Params, cfg, row_valid: jnp.ndarray | None = None
+                    ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The routed experts HELD HERE on ``[n, E]`` rows: ``(out [n, E],
+    held [n, k] bool)``.  Pairs are sorted by held expert (absent experts
+    and the rows that carry no token last, never gathered: a step's
+    padding rows are all alike and would all land on the same eight
+    experts).  Every held expert then takes its first
+    :func:`_held_capacity` rows in ONE ``[X, C, E]`` batched SwiGLU, the
+    int8 dequant fused into the contraction: work that does not depend on
+    how the router spread the tokens, so a step's time is the same from
+    seed to seed.  What an expert draws beyond C rows (more than four
+    times its fair load) follows in tiles of C rows, one expert a tile, at
+    about a twelfth of the batch's cost each; ``_SPARE_TILES`` of them run
+    in any case (dead where not needed), so that only a layer that needs
+    more than those takes longer."""
+    n, e = x2.shape
+    k, nx = cfg.num_experts_per_tok, cfg.num_experts
+    cap = _held_capacity(n, cfg)
+    with jax.named_scope("arks.moe_route"):
+        local = idx - held_first(cfg)
+        held = (local >= 0) & (local < nx)
+        if row_valid is not None:
+            held = held & row_valid.reshape(-1, 1)
+        flat_expert = jnp.where(held, local, nx).reshape(-1)        # [n*k]
+        sizes = jnp.bincount(flat_expert, length=nx + 1)[:nx]
+        starts = jnp.cumsum(sizes) - sizes
+        order = jnp.argsort(flat_expert)
+        flat_w = vals.reshape(-1)
+        # Overflow tiles, expert after expert: tile t belongs to the first
+        # expert whose running count of tiles passes t.
+        tiles = (jnp.maximum(sizes - cap, 0) + cap - 1) // cap      # [X]
+        tile_ends = jnp.cumsum(tiles)
+        spare = _SPARE_TILES if cap < n else 0
+
+    def slots(out, experts, first, weights):
+        """Add what ``experts`` [x] give their rows ``first + [0, C)``."""
+        with jax.named_scope("arks.moe_route"):
+            c = first[:, None] + jnp.arange(cap)[None, :]           # [x, C]
+            live = c < jnp.take(sizes, experts)[:, None]
+            pair = jnp.take(order, jnp.where(
+                live, jnp.take(starts, experts)[:, None] + c, 0))   # [x, C]
+            token_of = pair // k
+            xs = jnp.take(x2, token_of, axis=0)                     # [x, C, E]
+        with jax.named_scope("arks.moe_dot"):
+            gate = _expert_dot("xce,xef->xcf", xs, weights["w_gate"])
+            up = _expert_dot("xce,xef->xcf", xs, weights["w_up"])
+            act = jax.nn.silu(gate.astype(jnp.float32)).astype(
+                gate.dtype) * up
+            down = _expert_dot("xcf,xfe->xce", act, weights["w_down"])
+        with jax.named_scope("arks.moe_route"):
+            w = jnp.where(live, jnp.take(flat_w, pair), 0).astype(down.dtype)
+            return out.at[token_of.reshape(-1)].add(
+                (down * w[..., None]).reshape(-1, e))
+
+    def overflow_tile(t, out):
+        with jax.named_scope("arks.moe_route"):
+            # A spare tile (t past the last one needed) runs on the last
+            # expert from past the end of its rows: every slot dead.
+            ex = jnp.minimum(jnp.searchsorted(tile_ends, t, side="right"),
+                             nx - 1)
+            nth = t - (jnp.take(tile_ends, ex) - jnp.take(tiles, ex))
+            one = {name: jax.tree.map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, ex, 0), mp[name])
+                for name in ("w_gate", "w_up", "w_down")}
+        return slots(out, ex[None], (cap * (1 + nth))[None], one)
+
+    out = slots(jnp.zeros((n, e), x2.dtype), jnp.arange(nx),
+                jnp.zeros((nx,), jnp.int32), mp)
+    out = jax.lax.fori_loop(0, jnp.maximum(tile_ends[-1], spare),
+                            overflow_tile, out)
+    return out, held
+
+
+def _shared_expert(x2: jnp.ndarray, mp: Params, cfg,
+                   constrain=None) -> jnp.ndarray:
+    """The shared expert's SwiGLU on [.., E] rows: every chip computes it
+    alike; sigmoid-gated by ``shared_gate`` (Qwen2-MoE) or as it is
+    (DeepSeek-V3)."""
+    from arks_tpu.models.quant import qeinsum
+    with jax.named_scope("arks.moe_shared"):
+        sg = qeinsum("...e,ef->...f", x2, mp["shared_gate_proj"])
+        su = qeinsum("...e,ef->...f", x2, mp["shared_up"])
+        sact = jax.nn.silu(sg.astype(jnp.float32)).astype(sg.dtype) * su
+        if constrain is not None:
+            sact = constrain(sact, sact.ndim - 1)
+        shared = qeinsum("...f,fe->...e", sact, mp["shared_down"])
+        if "shared_gate" not in mp:
+            return shared
+        gatev = jax.nn.sigmoid(
+            jnp.einsum("...e,e->...", x2, mp["shared_gate"]).astype(
+                jnp.float32))
+        return shared * gatev[..., None].astype(shared.dtype)
 
 
 _GROUPED_MIN_TOKENS = 64  # below this, dense dispatch wins on dispatch cost
 
 
-def moe_ffn_grouped(x: jnp.ndarray, mp: Params, cfg) -> jnp.ndarray:
-    """Dropless grouped dispatch: top-k cost instead of all-expert cost.
-
-    Flattens tokens, sorts the (token, slot) pairs by routed expert, runs the
-    three expert matmuls as ``jax.lax.ragged_dot`` grouped contractions (one
-    MXU pass over exactly T*k rows), and scatter-adds the weighted expert
-    outputs back per token.  Numerically equivalent to the dense dispatch —
-    no capacity factor, no dropped tokens — at k/X of its FLOPs (8x cheaper
-    for a 64-expert top-8 model).  Used for large-T prefill and training on
-    an unsharded expert dim; the dense path stays for decode (HBM-bound:
-    every expert's weights are read once regardless) and for expert-parallel
-    meshes, where the einsum + psum formulation lets XLA shard the expert
-    dim (ragged groups can't span devices).
-    """
-    lead = x.shape[:-1]
-    e = x.shape[-1]
-    k, nx = cfg.num_experts_per_tok, cfg.num_experts
-    x2 = x.reshape(-1, e)
-    n = x2.shape[0]
-
+def _whole_dispatch(x2: jnp.ndarray, vals: jnp.ndarray, idx: jnp.ndarray,
+                    mp: Params, cfg, dtype) -> jnp.ndarray:
+    """The routed experts of a layer held whole on ``[n, E]`` rows: every
+    (token, expert) pair, sorted by expert, through three ``ragged_dot``
+    contractions (or the block-sparse Pallas kernel)."""
     from arks_tpu.models.quant import dequantize
-
-    # Profile scopes (docs/monitoring.md): routing and the sort into expert
-    # order; the dequantised expert weights; the grouped matmuls.
+    from arks_tpu.ops.moe_kernel import grouped_ffn, moe_impl
+    n, e = x2.shape
+    k, nx = cfg.num_experts_per_tok, cfg.num_experts
     with jax.named_scope("arks.moe_route"):
-        logits = jnp.einsum("te,ex->tx", x2, mp["router"])
-        vals, idx = router_topk(logits, cfg)                # [T, k]
-
         flat_expert = idx.reshape(-1)                       # [T*k]
         order = jnp.argsort(flat_expert)
         token_of = order // k                               # source token
         xs = jnp.take(x2, token_of, axis=0)                 # [T*k, E] sorted
         group_sizes = jnp.bincount(flat_expert, length=nx)
 
-    from arks_tpu.ops.moe_kernel import grouped_ffn, moe_impl
     if moe_impl() == "pallas":
         # Block-sparse Pallas grouped matmul with the dequant FUSED:
         # int8 per-channel scales fold into the accumulator; int4 group
@@ -148,7 +304,7 @@ def moe_ffn_grouped(x: jnp.ndarray, mp: Params, cfg) -> jnp.ndarray:
         with jax.named_scope("arks.moe_dot"):
             down = grouped_ffn(xs, jnp.take(flat_expert, order),
                                group_sizes, mp["w_gate"], mp["w_up"],
-                               mp["w_down"], x.dtype)
+                               mp["w_down"], dtype)
     else:
         # ragged_dot needs plain arrays; dequantized expert weights
         # materialize here (prefill-only path — dense/decode keeps the
@@ -157,7 +313,7 @@ def moe_ffn_grouped(x: jnp.ndarray, mp: Params, cfg) -> jnp.ndarray:
             # Traced in the order it always was (dequantise, contract,
             # three times over): the scopes add names, not a schedule.
             with jax.named_scope("arks.moe_dequant"):
-                w = dequantize(w, x.dtype)
+                w = dequantize(w, dtype)
             with jax.named_scope("arks.moe_dot"):
                 return jax.lax.ragged_dot(rows, w, group_sizes)
 
@@ -170,28 +326,74 @@ def moe_ffn_grouped(x: jnp.ndarray, mp: Params, cfg) -> jnp.ndarray:
 
     with jax.named_scope("arks.moe_route"):
         w = jnp.take(vals.reshape(-1), order).astype(down.dtype)   # [T*k]
-        out = jnp.zeros((n, e), down.dtype).at[token_of].add(
+        return jnp.zeros((n, e), down.dtype).at[token_of].add(
             down * w[:, None])
 
-    if cfg.shared_expert_intermediate_size:
-        from arks_tpu.models.quant import qeinsum
-        sg = qeinsum("te,ef->tf", x2, mp["shared_gate_proj"])
-        su = qeinsum("te,ef->tf", x2, mp["shared_up"])
-        sact = jax.nn.silu(sg.astype(jnp.float32)).astype(sg.dtype) * su
-        shared = qeinsum("tf,fe->te", sact, mp["shared_down"])
-        gatev = jax.nn.sigmoid(
-            jnp.einsum("te,e->t", x2, mp["shared_gate"]).astype(jnp.float32))
-        out = out + shared * gatev[:, None].astype(shared.dtype)
-    return out.reshape(*lead, e)
+
+def moe_ffn_grouped(x: jnp.ndarray, mp: Params, cfg,
+                    row_valid: jnp.ndarray | None = None):
+    """Dropless grouped dispatch: top-k cost instead of all-expert cost.
+
+    Flattens tokens, sorts the (token, slot) pairs by routed expert, runs the
+    three expert matmuls as ``jax.lax.ragged_dot`` grouped contractions (one
+    MXU pass over exactly T*k rows), and scatter-adds the weighted expert
+    outputs back per token.  Numerically equivalent to the dense dispatch —
+    no capacity factor, no dropped tokens — at k/X of its FLOPs (8x cheaper
+    for a 64-expert top-8 model).  Used for large-T prefill and training on
+    an unsharded expert dim; the dense path stays for decode (HBM-bound:
+    every expert's weights are read once regardless) and for expert-parallel
+    meshes, where the einsum + psum formulation lets XLA shard the expert
+    dim (ragged groups can't span devices).
+
+    Under a share (``cfg.expert_parallel_size`` > 1) the pairs whose expert
+    lives on another chip are never gathered and the held experts run as
+    :func:`_share_dispatch`'s rounds of fixed size.  ``row_valid`` [T] (rows
+    that carry a token) makes the call return ``(out, held_pairs)``: the
+    valid rows' pairs that landed on a held expert."""
+    lead = x.shape[:-1]
+    e = x.shape[-1]
+    k, nx = cfg.num_experts_per_tok, cfg.num_experts
+    share = cfg.expert_parallel_size > 1
+    x2 = x.reshape(-1, e)
+    n = x2.shape[0]
+
+    # Profile scopes (docs/monitoring.md): routing and the sort into expert
+    # order; the dequantised expert weights; the grouped matmuls.
+    with jax.named_scope("arks.moe_route"):
+        logits = jnp.einsum("te,ex->tx", x2, mp["router"])
+        vals, idx = router_topk(logits, cfg, mp.get("router_bias"))  # [T, k]
+
+    from arks_tpu.ops.moe_kernel import moe_impl
+    if share and moe_impl() == "pallas":
+        raise NotImplementedError(
+            "ARKS_MOE_KERNEL=pallas: the block-sparse kernel's group "
+            "padding does not take a share of a layer (rows of absent "
+            "experts); serve a share through the XLA path")
+    if share:
+        out, held = _share_dispatch(x2, vals, idx, mp, cfg, row_valid)
+    else:
+        out = _whole_dispatch(x2, vals, idx, mp, cfg, x.dtype)
+
+    if "shared_gate_proj" in mp:
+        out = out + _shared_expert(x2, mp, cfg)
+    out = out.reshape(*lead, e)
+    if row_valid is None:
+        return out
+    held_pairs = jnp.sum(held) if share else jnp.sum(row_valid) * k
+    return out, held_pairs.astype(jnp.int32)
 
 
 def moe_ffn(x: jnp.ndarray, mp: Params, cfg, constrain=None,
-            grouped: bool | None = None) -> jnp.ndarray:
+            grouped: bool | None = None,
+            row_valid: jnp.ndarray | None = None):
     """MoE feed-forward on [..., E] activations (works for [B, T, E] prefill
     and [B, E] decode).  ``constrain(t, expert_dim_index)`` optionally pins
     the expert dim of intermediates to the model axis.  ``grouped`` forces
     (True) or forbids (False) the dropless grouped path; None = auto (large
-    unsharded token batches)."""
+    unsharded token batches).  With ``row_valid`` (the leading dims of
+    ``x``, bool: rows that carry a token) the return is ``(out,
+    held_pairs)``: the valid rows' (token, expert) pairs that landed on an
+    expert held here (all of them where the layer is held whole)."""
     if grouped is None:
         import math
         n_tokens = math.prod(x.shape[:-1])
@@ -201,12 +403,19 @@ def moe_ffn(x: jnp.ndarray, mp: Params, cfg, constrain=None,
         grouped = (constrain is None and x.ndim >= 3
                    and n_tokens >= _GROUPED_MIN_TOKENS)
     if grouped:
-        return moe_ffn_grouped(x, mp, cfg)
+        return moe_ffn_grouped(x, mp, cfg, row_valid)
     from arks_tpu.models.quant import qeinsum
 
     with jax.named_scope("arks.moe_route"):
         logits = jnp.einsum("...e,ex->...x", x, mp["router"])
-        weights = router_weights(logits, cfg).astype(x.dtype)  # [.., X]
+        weights = router_weights(logits, cfg, mp.get("router_bias"))
+        held_pairs = None
+        if row_valid is not None:
+            # A chosen expert's weight is never zero (a softmax or a
+            # sigmoid), so the non-zero held columns are the held pairs.
+            held_pairs = jnp.sum((weights != 0) & row_valid[..., None]
+                                 ).astype(jnp.int32)
+        weights = weights.astype(x.dtype)                      # [.., X]
 
     # The dense dispatch keeps the dequant fused into the contraction, so
     # it has no arks.moe_dequant of its own.
@@ -219,14 +428,6 @@ def moe_ffn(x: jnp.ndarray, mp: Params, cfg, constrain=None,
         down = qeinsum("...xf,xfe->...xe", act, mp["w_down"])  # per expert
         out = jnp.einsum("...xe,...x->...e", down, weights)    # psum over EP
 
-    if cfg.shared_expert_intermediate_size:
-        sg = qeinsum("...e,ef->...f", x, mp["shared_gate_proj"])
-        su = qeinsum("...e,ef->...f", x, mp["shared_up"])
-        sact = jax.nn.silu(sg.astype(jnp.float32)).astype(sg.dtype) * su
-        if constrain is not None:
-            sact = constrain(sact, sact.ndim - 1)
-        shared = qeinsum("...f,fe->...e", sact, mp["shared_down"])
-        gatev = jax.nn.sigmoid(
-            jnp.einsum("...e,e->...", x, mp["shared_gate"]).astype(jnp.float32))
-        out = out + shared * gatev[..., None].astype(shared.dtype)
-    return out
+    if "shared_gate_proj" in mp:
+        out = out + _shared_expert(x, mp, cfg, constrain)
+    return out if row_valid is None else (out, held_pairs)

@@ -55,13 +55,18 @@ def _int4_group(group: int | None) -> int:
 MATMUL_KEYS = frozenset({
     "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "lm_head",
     "shared_gate_proj", "shared_up", "shared_down",
+    # The latent block (transformer.py): query down / up, the latent's
+    # down-projection, and [W_uk | W_uv], which the step absorbs.
+    "wq_a", "wq_b", "wkv_a", "wkv_b",
 })
 # Router logits feed a softmax over experts — tiny and precision-sensitive,
 # so it stays full width, as do norms, biases and the scalar shared gate.
 SKIP_KEYS = frozenset({
     "attn_norm", "mlp_norm", "final_norm", "bq", "bk", "bv", "router",
-    "shared_gate",
+    "shared_gate", "q_norm", "kv_norm", "router_bias",
 })
+NORM_KEYS = frozenset({"attn_norm", "mlp_norm", "final_norm", "q_norm",
+                       "kv_norm"})
 
 
 def weight_bits(weight_dtype: str) -> int:
@@ -251,7 +256,7 @@ def init_params_quantized(cfg, key, dtype=jnp.bfloat16, bits: int = 8,
                 continue
             counter[0] += 1
             sub = jax.random.fold_in(key, counter[0])
-            if name in ("attn_norm", "mlp_norm", "final_norm"):
+            if name in NORM_KEYS:
                 kind, axis = "ones", 0
             elif name in ("bq", "bk", "bv"):
                 kind, axis = "zeros", 0

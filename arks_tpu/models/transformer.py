@@ -83,10 +83,15 @@ class PagedKVCache(NamedTuple):
     pairs into nibble bytes along the page axis ([L, N, Hkv, page//2, D]
     int8) while the scale stripes keep full token resolution — which is
     also how int4-ness is detected (pool page rows != scale page).
+
+    A LATENT pool (latent attention, ``cfg.latent``): ``k`` is ``[L,
+    num_pages, 1, page, R]``, one row a token, the normed latent and the
+    rotary key lanes, which is key AND value of every head; it is stored
+    once: ``v`` is None, and so are the scales (bf16 only).
     """
 
     k: jnp.ndarray
-    v: jnp.ndarray
+    v: jnp.ndarray | None
     k_scale: jnp.ndarray | None = None
     v_scale: jnp.ndarray | None = None
 
@@ -112,14 +117,80 @@ class PagedKVCache(NamedTuple):
             return self.k.dtype.itemsize * 8
         return 4 if self.k.shape[3] != self.k_scale.shape[3] else 8
 
+    @property
+    def latent(self) -> bool:
+        return self.v is None
+
+    @property
+    def token_bytes(self) -> int:
+        """Bytes the pool holds for one token, all layers."""
+        return sum(x.size * x.dtype.itemsize
+                   for x in self if x is not None) // (
+            self.num_pages * self.page)
+
 
 # ---------------------------------------------------------------------------
 # Parameter init + sharding specs
 # ---------------------------------------------------------------------------
 
 
+def _init_latent_params(cfg: ModelConfig, key: jax.Array,
+                        dtype: jnp.dtype) -> Params:
+    """The DeepSeek-V3 / kimi_k2 tree: ``dense_layers`` (the first
+    ``first_k_dense`` layers, SwiGLU of ``intermediate_size``) and
+    ``layers`` (the routed ones), each stacked, both with the latent
+    attention's leaves: ``wq_a`` [E, q_lora], ``q_norm``, ``wq_b``
+    [q_lora, H x (nope + rope)], ``wkv_a`` [E, kv_lora + rope],
+    ``kv_norm``, ``wkv_b`` [kv_lora, H x (nope + v)] (per head
+    ``[W_uk | W_uv]``, as ``kv_b_proj`` lays them out), ``wo`` [H x v, E]."""
+    e, f, v, h = (cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size,
+                  cfg.num_heads)
+    keys = iter(jax.random.split(key, 24))
+
+    def w(shape, scale=0.02):
+        return (jax.random.normal(next(keys), shape, jnp.float32)
+                * scale).astype(dtype)
+
+    def attn(l: int) -> Params:
+        return {
+            "attn_norm": jnp.ones((l, e), dtype),
+            "wq_a": w((l, e, cfg.q_lora_rank)),
+            "q_norm": jnp.ones((l, cfg.q_lora_rank), dtype),
+            "wq_b": w((l, cfg.q_lora_rank, cfg.q_dim)),
+            "wkv_a": w((l, e, cfg.latent_row)),
+            "kv_norm": jnp.ones((l, cfg.kv_lora_rank), dtype),
+            "wkv_b": w((l, cfg.kv_lora_rank,
+                        h * (cfg.qk_nope_head_dim + cfg.v_head_dim))),
+            "wo": w((l, cfg.attn_out_dim, e)),
+            "mlp_norm": jnp.ones((l, e), dtype),
+        }
+
+    from arks_tpu.models import moe
+    routed = attn(cfg.num_routed_layers)
+    routed.update(moe.init_moe_params(cfg, next(keys), dtype))
+    params: Params = {
+        "embed": w((v, e)),
+        "layers": routed,
+        "final_norm": jnp.ones((e,), dtype),
+    }
+    if cfg.first_k_dense:
+        ld = cfg.first_k_dense
+        params["dense_layers"] = dict(
+            attn(ld), w_gate=w((ld, e, f)), w_up=w((ld, e, f)),
+            w_down=w((ld, f, e)))
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = w((e, v))
+    return params
+
+
 def init_params(cfg: ModelConfig, key: jax.Array, dtype: jnp.dtype | None = None) -> Params:
     dtype = dtype or jnp.dtype(cfg.dtype)
+    if cfg.latent:
+        return _init_latent_params(cfg, key, dtype)
+    if cfg.first_k_dense:
+        raise NotImplementedError(
+            f"model {cfg.name!r}: a dense prefix (first_k_dense="
+            f"{cfg.first_k_dense}) is built for the latent block only")
     l, e, f, v = cfg.num_layers, cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size
     qd, kvd = cfg.q_dim, cfg.kv_dim
     keys = iter(jax.random.split(key, 16))
@@ -164,6 +235,10 @@ def shard_kv_heads(cfg: ModelConfig, tp: int) -> bool:
 
 def param_pspecs(cfg: ModelConfig, tp: int = 1) -> Params:
     """PartitionSpec pytree matching ``init_params`` (leading [L] dim on layers)."""
+    if cfg.latent:
+        raise NotImplementedError(
+            f"model {cfg.name!r}: the latent block has no sharding rules "
+            "(tensor / data / pipeline parallelism are not supported)")
     kv = P(None, None, AXIS_MODEL) if shard_kv_heads(cfg, tp) else P(None, None, None)
     kvb = P(None, AXIS_MODEL) if shard_kv_heads(cfg, tp) else P(None, None)
     layers: Params = {
@@ -202,10 +277,16 @@ def cache_head_dim(cfg: ModelConfig, pad_head: bool = False) -> int:
     models with head_dim < 128 (qwen2.5-0.5b, tiny test configs) ride the
     compiled Pallas decode kernels instead of the XLA fallback.  Zero
     padding is EXACT: padded K lanes add 0 to every q.k score and padded V
-    lanes produce output columns the caller slices off."""
-    if pad_head and cfg.head_dim % 128 != 0:
-        return -(-cfg.head_dim // 128) * 128
-    return cfg.head_dim
+    lanes produce output columns the caller slices off.
+
+    A latent model stores one row a token, ``kv_lora_rank +
+    qk_rope_head_dim`` wide (576 -> 640 padded: the rotary lanes' tile is
+    half zeros; the value lanes, the first ``kv_lora_rank``, are whole
+    tiles)."""
+    d = cfg.latent_row if cfg.latent else cfg.head_dim
+    if pad_head and d % 128 != 0:
+        return -(-d // 128) * 128
+    return d
 
 
 def pad_heads(x: jnp.ndarray, d_store: int) -> jnp.ndarray:
@@ -260,6 +341,12 @@ def init_paged_cache(cfg: ModelConfig, num_pages: int, page: int,
     dtype = dtype or jnp.dtype(cfg.dtype)
     shape = (cfg.num_layers, num_pages, cfg.num_kv_heads, page,
              cache_head_dim(cfg, pad_head))
+    if cfg.latent:
+        if quantized:
+            raise ValueError(
+                f"model {cfg.name!r}: a latent page is bf16 only (an "
+                "int8 / int4 latent row is not built)")
+        return PagedKVCache(k=jnp.zeros(shape, dtype), v=None)
     if quantized:
         if kv_bits not in (4, 8):
             raise ValueError(f"quantized kv_bits must be 4 or 8, got {kv_bits}")
@@ -374,7 +461,12 @@ def _block_tail(h: jnp.ndarray, attn: jnp.ndarray, lp: Params,
 # so arks.ffn is what is left: the norm, and a dense FFN whole.
 @_scope("arks.ffn")
 def _mlp(h: jnp.ndarray, lp: Params, cfg: ModelConfig, mesh: Mesh | None,
-         batch_axis: str | None, seq_axis: str | None = None) -> jnp.ndarray:
+         batch_axis: str | None, seq_axis: str | None = None,
+         row_valid: jnp.ndarray | None = None):
+    """The FFN half of a block on normed ``h``: routed where the layer
+    tree has a router (a model's dense prefix has none).  With
+    ``row_valid`` a routed layer returns ``(out, held_pairs)``
+    (:func:`moe.moe_ffn`)."""
     x = rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps)
 
     def _int_spec(ndim: int, sharded_dim: int) -> list:
@@ -389,7 +481,7 @@ def _mlp(h: jnp.ndarray, lp: Params, cfg: ModelConfig, mesh: Mesh | None,
         spec[sharded_dim] = AXIS_MODEL
         return spec
 
-    if cfg.num_experts:
+    if cfg.num_experts and "router" in lp:
         from arks_tpu.models import moe
         tp = mesh.shape.get(AXIS_MODEL, 1) if mesh is not None else 1
 
@@ -400,7 +492,8 @@ def _mlp(h: jnp.ndarray, lp: Params, cfg: ModelConfig, mesh: Mesh | None,
                 return t  # expert dim replicated in this regime
             return _constrain(t, mesh, *_int_spec(t.ndim, dim))
 
-        return moe.moe_ffn(x, lp, cfg, constrain if mesh is not None else None)
+        return moe.moe_ffn(x, lp, cfg, constrain if mesh is not None else None,
+                           row_valid=row_valid)
     gate = qeinsum("...e,ef->...f", x, lp["w_gate"])
     up = qeinsum("...e,ef->...f", x, lp["w_up"])
     act = jax.nn.silu(gate.astype(jnp.float32)).astype(gate.dtype) * up
@@ -416,6 +509,108 @@ def _unembed(h_last: jnp.ndarray, params: Params, cfg: ModelConfig,
     table = params["embed"] if tied else params["lm_head"]
     logits = unembed_logits(h_last, table, tied)
     return _constrain(logits, mesh, batch_axis, None)
+
+
+def _wkv_b(lp: Params, cfg: ModelConfig, dtype) -> tuple[jnp.ndarray,
+                                                         jnp.ndarray]:
+    """``kv_b_proj`` as the absorbed form uses it: (W_uk [C, H, nope],
+    W_uv [C, H, v]) with C = kv_lora_rank, widened to ``dtype``."""
+    from arks_tpu.models.quant import dequantize
+    w = dequantize(lp["wkv_b"], dtype).reshape(
+        cfg.kv_lora_rank, cfg.num_heads,
+        cfg.qk_nope_head_dim + cfg.v_head_dim)
+    return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
+
+
+@_scope("arks.mla_q")
+def _mla_q(x: jnp.ndarray, lp: Params, cfg: ModelConfig,
+           positions: jnp.ndarray) -> jnp.ndarray:
+    """Normed ``x`` [B, T, E] -> the ABSORBED queries [B, T, H, C + rope]:
+    down, norm, up, RoPE on the rotary lanes, and ``q_nope W_uk^T`` so that
+    a head's score against a cached row is one dot over the row."""
+    b, t = x.shape[:2]
+    cq = rms_norm(qeinsum("...e,er->...r", x, lp["wq_a"]), lp["q_norm"],
+                  cfg.rms_norm_eps)
+    q = qeinsum("...r,rq->...q", cq, lp["wq_b"]).reshape(
+        b, t, cfg.num_heads, cfg.head_dim)
+    q_nope, q_rope = (q[..., :cfg.qk_nope_head_dim],
+                      q[..., cfg.qk_nope_head_dim:])
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, cfg.rope_yarn)
+    w_uk, _ = _wkv_b(lp, cfg, x.dtype)
+    q_abs = jnp.einsum("bthn,chn->bthc", q_nope, w_uk)
+    return jnp.concatenate([q_abs, q_rope], axis=-1)
+
+
+@_scope("arks.mla_kv")
+def _mla_kv(x: jnp.ndarray, lp: Params, cfg: ModelConfig,
+            positions: jnp.ndarray) -> jnp.ndarray:
+    """Normed ``x`` [B, T, E] -> the row a token caches [B, T, C + rope]:
+    the normed latent and the rotary key lanes all heads share."""
+    kv = qeinsum("...e,er->...r", x, lp["wkv_a"])
+    c = rms_norm(kv[..., :cfg.kv_lora_rank], lp["kv_norm"], cfg.rms_norm_eps)
+    k_r = apply_rope(kv[..., None, cfg.kv_lora_rank:], positions,
+                     cfg.rope_theta, cfg.rope_yarn)[..., 0, :]
+    return jnp.concatenate([c, k_r], axis=-1)
+
+
+@_scope("arks.mla_out")
+def _mla_out(attn: jnp.ndarray, lp: Params, cfg: ModelConfig) -> jnp.ndarray:
+    """``attn`` [T, H, C] (probabilities times the latent rows) -> [T, E]:
+    un-absorb ``W_uv`` per head, then the output projection."""
+    _, w_uv = _wkv_b(lp, cfg, attn.dtype)
+    o = jnp.einsum("thc,chv->thv", attn, w_uv)
+    return qeinsum("...q,qe->...e", o.reshape(o.shape[0], cfg.attn_out_dim),
+                   lp["wo"])
+
+
+def _mixed_step_latent(params, cfg, cache, tables, tokens, token_slot,
+                       token_pos, sample_src, seq_q_start, seq_q_len,
+                       seq_pos_start, mesh):
+    """:func:`mixed_step` for the latent block: the dense prefix stack and
+    the routed stack, each its own scan, one after the other over ONE
+    latent pool (a layer's index in the pool is its index in the model).
+    One attention path, the absorbed one, for chunks and decode lanes
+    alike.  Returns (logits, cache, held_pairs): the valid rows' (token,
+    expert) pairs, summed over the routed layers, that landed on an expert
+    held here."""
+    from arks_tpu.ops.attention import paged_latent_update_and_attend
+    cover = tables.shape[1] * cache.page
+    rope_pos = jnp.minimum(token_pos, cover - 1)[None]           # [1, T]
+    valid = (token_slot >= 0)[None]
+    first = params["dense_layers"] if cfg.first_k_dense else params["layers"]
+    with _scope("arks.embed"):
+        h = embed_lookup(params["embed"], tokens[None],
+                         first["attn_norm"].dtype)               # [1, T, E]
+
+    def body(carry, xs):
+        h, pool = carry
+        lp, layer = xs
+        x = rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps)
+        attn, pool = paged_latent_update_and_attend(
+            _mla_q(x, lp, cfg, rope_pos)[0], _mla_kv(x, lp, cfg, rope_pos)[0],
+            pool, tables, token_slot, token_pos, seq_q_start, seq_q_len,
+            seq_pos_start, layer, dv=cfg.kv_lora_rank,
+            scale=cfg.softmax_scale)
+        h = h + _mla_out(attn, lp, cfg)[None]
+        if "router" in lp:
+            y, held = _mlp(h, lp, cfg, mesh, None, row_valid=valid)
+        else:
+            y, held = _mlp(h, lp, cfg, mesh, None), jnp.int32(0)
+        return (h + y, pool), held
+
+    pool, held, at = cache.k, jnp.int32(0), 0
+    for name in ("dense_layers", "layers"):
+        if name not in params:
+            continue
+        n = params[name]["attn_norm"].shape[0]
+        (h, pool), per_layer = jax.lax.scan(
+            body, (h, pool),
+            (params[name], at + jnp.arange(n, dtype=jnp.int32)))
+        held, at = held + jnp.sum(per_layer), at + n
+    with _scope("arks.lm_head"):
+        h_sel = jnp.take(h[0], sample_src.astype(jnp.int32), axis=0)  # [B, E]
+    logits = _unembed(h_sel, params, cfg, mesh, None)
+    return logits, PagedKVCache(k=pool, v=None), held
 
 
 def prefill_layer(
@@ -903,6 +1098,7 @@ def mixed_step(
     seq_q_len: jnp.ndarray,    # [B] int32 — lane's token count (0 inactive)
     seq_pos_start: jnp.ndarray,  # [B] int32 — lane's first global position
     mesh: Mesh | None = None,
+    with_held: bool = False,
 ) -> tuple[jnp.ndarray, PagedKVCache]:
     """One unified mixed prefill+decode forward: a flat ``[T]`` token batch
     carrying every decoding slot's next token PLUS one or more sequences'
@@ -918,7 +1114,18 @@ def mixed_step(
     stalling decode.  Padding tokens (token_slot < 0) drop their writes and
     attend nothing; their activations are garbage no sample_src points at.
     Numerically equivalent to the legacy paths (same math, blockwise — only
-    fp reassociation differs across chunk boundaries)."""
+    fp reassociation differs across chunk boundaries).
+
+    A latent model (``cfg.latent``) runs :func:`_mixed_step_latent` over
+    its latent pool; ``with_held`` (latent models only) adds its third
+    result, the count of routed pairs that landed on experts held here."""
+    if cfg.latent:
+        out = _mixed_step_latent(params, cfg, cache, tables, tokens,
+                                 token_slot, token_pos, sample_src,
+                                 seq_q_start, seq_q_len, seq_pos_start, mesh)
+        return out if with_held else out[:2]
+    if with_held:
+        raise NotImplementedError("with_held: latent models only")
     t_flat = tokens.shape[0]
     cover = tables.shape[1] * cache.page
     # RoPE positions must be real for valid tokens; padding rows only need

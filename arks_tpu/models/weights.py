@@ -50,6 +50,23 @@ def _hf_tensors(path: str) -> dict[str, np.ndarray]:
     return out
 
 
+class LatentCheckpointError(NotImplementedError):
+    """A latent-attention (DeepSeek-V3 / kimi_k2) checkpoint: the name
+    mapping (``q_a_proj`` / ``kv_a_proj_with_mqa`` / ``kv_b_proj``, the
+    expert and ``e_score_correction_bias`` tensors) and the permutation of
+    the rotary columns from the published interleaved form to this repo's
+    rotate-half form are not built; such a model is served from seeded
+    random weights only.  Raised instead of mapping its tensors onto the
+    GQA block's names."""
+
+
+def _refuse_latent(cfg: ModelConfig, path: str) -> None:
+    if cfg.latent:
+        raise LatentCheckpointError(
+            f"model {cfg.name!r}: cannot load the checkpoint at {path}: "
+            + " ".join(LatentCheckpointError.__doc__.split()))
+
+
 def params_from_hf(cfg: ModelConfig, path: str, dtype: Any = None,
                    weight_dtype: str = "bf16", shards: int = 1) -> tf.Params:
     """Convert a HuggingFace Qwen2/Llama checkpoint directory to arks params.
@@ -60,6 +77,7 @@ def params_from_hf(cfg: ModelConfig, path: str, dtype: Any = None,
     ONE full-width leaf — the only way a ~15GB bf16 7B checkpoint reaches a
     16GB chip.
     """
+    _refuse_latent(cfg, path)
     dtype = jnp.dtype(dtype or cfg.dtype)
     t = _hf_tensors(path)
     l = cfg.num_layers
@@ -422,6 +440,8 @@ def load_params(cfg: ModelConfig, model_path: str | None, mesh=None,
     quantize = _weight_bits(weight_dtype)
     if model_path:
         kind = weights_kind(model_path)
+        if kind:
+            _refuse_latent(cfg, model_path)
         if kind == "orbax":
             log.info("loading Orbax checkpoint from %s", orbax_path(model_path))
             return load_orbax(cfg, model_path, mesh, dtype, weight_dtype)

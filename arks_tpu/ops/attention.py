@@ -520,6 +520,90 @@ def paged_mixed_update_and_attend(
     return out.reshape(t_flat, h, d)[..., :d_model], kp, vp, ks, vs
 
 
+def latent_kernel_blockers(r_store: int, dv: int, mesh=None) -> list[str]:
+    """Why the Pallas latent-page kernels cannot serve this shape (empty:
+    they can); :func:`kernel_blockers`' counterpart for a latent pool.
+    ``r_store`` is the STORED row width, ``dv`` the value lanes."""
+    out = []
+    if jax.default_backend() == "tpu" and (r_store % 128 or dv % 128):
+        out.append(f"latent row {r_store} / value lanes {dv} not 128-lane "
+                   "aligned")
+    if mesh is not None and mesh.size > 1:
+        out.append("a device mesh (the latent pool is not sharded)")
+    return out
+
+
+def paged_latent_update_and_attend(
+    q: jnp.ndarray,          # [T, H, R] absorbed queries [q~ | q_rope]
+    row_new: jnp.ndarray,    # [T, R] latent rows [c_kv | k_rope]
+    pool: jnp.ndarray,       # [L, N, 1, P, R_store] the latent pool
+    tables: jnp.ndarray,     # [B, MaxP] int32 — lane b == slot b
+    token_slot: jnp.ndarray,   # [T] int32 slot per token (-1 = padding)
+    token_pos: jnp.ndarray,    # [T] int32 global position per token
+    seq_q_start: jnp.ndarray,  # [B] int32
+    seq_q_len: jnp.ndarray,    # [B] int32
+    seq_pos_start: jnp.ndarray,  # [B] int32
+    layer,
+    *,
+    dv: int,
+    scale: float,
+    impl: str | None = None,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """:func:`paged_mixed_update_and_attend` over a LATENT pool: one row a
+    token (the normed latent and the rotary key lanes) is key and value of
+    every head.  Writes the rows through the block tables, then row t
+    attends its slot's rows at positions [0, token_pos[t]]:
+    ``softmax(scale * q . row) @ row[:dv]`` per head.  Returns
+    (out [T, H, dv], pool).  The Pallas path is the ragged mixed kernel
+    over the same block layout and work list with Hkv = 1 and the H heads
+    as the query group; the XLA gather below is the CPU path and the
+    tests' oracle.  Callers decide between them with
+    :func:`latent_kernel_blockers`: there is no silent fallback here."""
+    from arks_tpu.ops.paged_attention import paged_gather_kv
+    t_flat, h, _ = q.shape
+    page, r = pool.shape[3], pool.shape[4]
+    cover = tables.shape[1] * page
+    q = _pad_last(q, r)
+    row_new = _pad_last(row_new, r)
+    tables_tok = jnp.take(tables, jnp.maximum(token_slot, 0), axis=0)
+    write_idx = jnp.where(token_slot < 0, cover, token_pos)
+
+    if (impl or default_decode_impl()) != "pallas":
+        with jax.named_scope("arks.attn_kernel"):
+            # Padding rows go to a page past the pool, which jit drops.
+            oob = write_idx >= cover
+            safe = jnp.where(oob, 0, write_idx)
+            pg = jnp.take_along_axis(tables_tok, (safe // page)[:, None],
+                                     axis=1)[:, 0]
+            pg = jnp.where(oob, pool.shape[1], pg)
+            pool = pool.at[layer, pg, 0, safe % page].set(
+                row_new.astype(pool.dtype))
+            rows = paged_gather_kv(pool, tables_tok, layer)[:, 0]  # [T,S,R]
+            scores = jnp.einsum("thr,tsr->ths", q, rows,
+                                preferred_element_type=jnp.float32) * scale
+            lens = jnp.where(token_slot < 0, 0, token_pos + 1)
+            valid = jnp.arange(cover)[None] < lens[:, None]
+            scores = jnp.where(valid[:, None], scores, _NEG_INF)
+            probs = _softmax(scores, axis=-1).astype(rows.dtype)
+            out = jnp.einsum("ths,tsv->thv", probs, rows[..., :dv],
+                             preferred_element_type=jnp.float32)
+        return out.astype(q.dtype), pool
+
+    from arks_tpu.ops.paged_attention import (
+        paged_kv_update, paged_mixed_attention_flat)
+    interpret = jax.default_backend() != "tpu"
+    with jax.named_scope("arks.mla_kv"):       # the page write
+        pool, _ = paged_kv_update(pool, None, row_new[:, None, :], None,
+                                  write_idx, tables_tok, layer,
+                                  interpret=interpret)
+    with jax.named_scope("arks.attn_layout"):
+        out = paged_mixed_attention_flat(
+            q[:, None], pool, None, tables, token_slot, seq_q_start,
+            seq_q_len, seq_pos_start, layer, interpret=interpret,
+            latent_v=dv, scale=scale)
+    return out[:, 0], pool
+
+
 def paged_decode_update_and_attend(
     q: jnp.ndarray,        # [B, H, D]
     k_new: jnp.ndarray,    # [B, Hkv, D]

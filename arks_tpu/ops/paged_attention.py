@@ -171,7 +171,7 @@ def mixed_grid_plan(qmax: int, *, hkv: int, g: int, d: int, page: int,
         head_group = hkv
     if block_q is None:
         block_q = int(tuned.get("block_q", 0)) or _default_block_q(
-            qmax, lanes)
+            qmax, lanes, g)
     block_q = max(1, min(int(block_q), qmax))
     if dma_depth is None:
         dma_depth = int(tuned.get("dma_depth", 0)) or 2
@@ -188,7 +188,7 @@ def mixed_grid_plan(qmax: int, *, hkv: int, g: int, d: int, page: int,
     return plan
 
 
-def _default_block_q(qmax: int, lanes: int | None) -> int:
+def _default_block_q(qmax: int, lanes: int | None, g: int = 1) -> int:
     """Queries per work item where the tune table has no entry.
 
     A per-lane block (``lanes`` None) keeps 32.  The flat batch's blocks
@@ -200,11 +200,16 @@ def _default_block_q(qmax: int, lanes: int | None) -> int:
     the batch (8 slots + 256 rows) 32 re-streams its causal prefix four
     times less often.  Measured on a v5e at qwen2.5-7b and mixtral widths
     (PERF.md, PR 25): 8 beat 4, 16 and 32 at 192 and 64 lanes, 32 beat 16
-    and 8 at 8 lanes."""
+    and 8 at 8 lanes.
+
+    A work item holds ``g x block_q`` query rows with their softmax state
+    in VMEM, so the flat batch's block is also held to 512 such rows, never
+    under one sublane tile of 8: it binds from 17 queries a KV head up (a
+    latent pool's one row serves all 64 heads: blocks of 8)."""
     if lanes is None:
         return min(qmax, 32)
     rows_per_lane = -(-(lanes + qmax - 1) // lanes)
-    return min(qmax, 32, -(-rows_per_lane // 8) * 8)
+    return min(qmax, 32, -(-rows_per_lane // 8) * 8, max(8, 512 // g))
 
 
 def build_mixed_work_list(pos_start: jnp.ndarray, q_len: jnp.ndarray, *,
@@ -752,12 +757,18 @@ def _mixed_softmax_block(q_ref, kbuf, vbuf, ksbuf, vsbuf, m_ref, l_ref,
     _, hkv, g, bq, d = q_ref.shape
     q = q_ref[0].reshape(hkv, g * bq, d)
     kt = kbuf[buf]
-    vt = vbuf[buf]
-    if int4:
-        kt = _unpack_int4_tile(kt)
-        vt = _unpack_int4_tile(vt)
-    k = kt.astype(q.dtype)                 # [Hkv, page, D]
-    v = vt.astype(q.dtype)
+    if vbuf is None:
+        # A latent page: the values are the row's first lanes, as wide as
+        # the accumulator (the same tile, read from HBM once, used twice).
+        k = kt.astype(q.dtype)             # [1, page, R]
+        v = k[..., :acc_ref.shape[-1]]
+    else:
+        vt = vbuf[buf]
+        if int4:
+            kt = _unpack_int4_tile(kt)
+            vt = _unpack_int4_tile(vt)
+        k = kt.astype(q.dtype)             # [Hkv, page, D]
+        v = vt.astype(q.dtype)
     scores = jax.lax.dot_general(
         q, k, (((2,), (2,)), ((0,), (0,))),
         preferred_element_type=jnp.float32) * scale   # [Hkv, G*BQ, page]
@@ -876,11 +887,11 @@ def _paged_mixed_kernel(layer_ref, tables_ref, pos_start_ref, qlen_ref,
 def _paged_mixed_ragged_kernel(layer_ref, tables_ref, pos_start_ref,
                                wl_seq_ref, wl_hg_ref, wl_qb_ref,
                                wl_plo_ref, wl_pages_ref, wl_blk_ref,
-                               q_ref, kpool, vpool, *rest,
+                               q_ref, kpool, *rest,
                                page: int, block_q: int, scale: float,
                                quantized: bool, int4: bool, depth: int,
                                head_group: int, carry: bool,
-                               emit_state: bool):
+                               emit_state: bool, latent: bool = False):
     """RAGGED work-list grid: one grid step per (sequence, head_group,
     q_block) work item, the page loop INSIDE the kernel bounded by that
     item's own causal page span [wl_plo, wl_pages).  q_len=0 lanes and
@@ -907,9 +918,15 @@ def _paged_mixed_ragged_kernel(layer_ref, tables_ref, pos_start_ref,
 
     DMAs are ``depth``-way multi-buffered (depth=2 reduces exactly to the
     dense kernel's double buffering; the accumulation order is identical
-    for any depth, so tuned depths preserve byte identity)."""
+    for any depth, so tuned depths preserve byte identity).
+
+    ``latent``: the pool is ONE array of latent rows (Hkv = 1, every head
+    of the model a query row of the one group): there is no value pool,
+    one copy a page, and the values are the leading lanes of the key tile
+    (as many as the output is wide)."""
     del wl_blk_ref      # the index maps' column (compacted layout)
     rest = list(rest)
+    vpool = None if latent else rest.pop(0)
     if quantized:
         kspool, vspool = rest[:2]
         rest = rest[2:]
@@ -930,6 +947,9 @@ def _paged_mixed_ragged_kernel(layer_ref, tables_ref, pos_start_ref,
         rest = rest[1:]
     if quantized:
         kbuf, vbuf, ksbuf, vsbuf, m_ref, l_ref, acc_ref, sem = rest
+    elif latent:
+        kbuf, m_ref, l_ref, acc_ref, sem = rest
+        vbuf = ksbuf = vsbuf = None
     else:
         kbuf, vbuf, m_ref, l_ref, acc_ref, sem = rest
         ksbuf = vsbuf = None
@@ -949,8 +969,9 @@ def _paged_mixed_ragged_kernel(layer_ref, tables_ref, pos_start_ref,
         pg = tables_ref[s_i, page_i]
         pltpu.make_async_copy(kpool.at[lyr, pg, pl.ds(h0, head_group)],
                               kbuf.at[buf], sem.at[0, buf]).start()
-        pltpu.make_async_copy(vpool.at[lyr, pg, pl.ds(h0, head_group)],
-                              vbuf.at[buf], sem.at[1, buf]).start()
+        if vpool is not None:
+            pltpu.make_async_copy(vpool.at[lyr, pg, pl.ds(h0, head_group)],
+                                  vbuf.at[buf], sem.at[1, buf]).start()
         if quantized:
             # The f32 scale stripe [Hkv, P] is tiled (Hkv, 128) in HBM: a
             # sub-tile head slice cannot be DMA'd (Mosaic: "slice shape
@@ -964,8 +985,9 @@ def _paged_mixed_ragged_kernel(layer_ref, tables_ref, pos_start_ref,
     def wait_copies(buf):
         pltpu.make_async_copy(kpool.at[lyr, 0, pl.ds(0, head_group)],
                               kbuf.at[buf], sem.at[0, buf]).wait()
-        pltpu.make_async_copy(vpool.at[lyr, 0, pl.ds(0, head_group)],
-                              vbuf.at[buf], sem.at[1, buf]).wait()
+        if vpool is not None:
+            pltpu.make_async_copy(vpool.at[lyr, 0, pl.ds(0, head_group)],
+                                  vbuf.at[buf], sem.at[1, buf]).wait()
         if quantized:
             pltpu.make_async_copy(kspool.at[lyr, 0], ksbuf.at[buf],
                                   sem.at[2, buf]).wait()
@@ -1012,7 +1034,8 @@ def _paged_mixed_ragged_kernel(layer_ref, tables_ref, pos_start_ref,
             return loop_c
 
         jax.lax.fori_loop(plo, npages, body, 0)
-        _, hg, g, bq, d = q_ref.shape
+        _, hg, g, bq, _ = q_ref.shape
+        d = acc_ref.shape[-1]
         if emit_state:
             mo_ref[:] = m_ref[:].reshape(1, hg, g, bq, 128)
             lo_ref[:] = l_ref[:].reshape(1, hg, g, bq, 128)
@@ -1024,14 +1047,16 @@ def _paged_mixed_ragged_kernel(layer_ref, tables_ref, pos_start_ref,
 
 def _mixed_scratch(k_pool, v_pool, *, nbuf: int, head_group: int,
                    hkv: int, g: int, d: int, page: int, block_q: int,
-                   quantized: bool):
+                   quantized: bool, dv: int | None = None):
     """VMEM scratch of one mixed-attention work item: ``nbuf`` page
-    buffers, the online-softmax state, the DMA semaphores."""
+    buffers (one set where ``v_pool`` is None: a latent pool), the
+    online-softmax state (the accumulator ``dv`` wide, ``d`` by default),
+    the DMA semaphores."""
     kv_rows = k_pool.shape[3]            # page//2 byte rows for int4 pools
-    scratch = [
-        pltpu.VMEM((nbuf, head_group, kv_rows, d), k_pool.dtype),
-        pltpu.VMEM((nbuf, head_group, kv_rows, d), v_pool.dtype),
-    ]
+    scratch = [pltpu.VMEM((nbuf, head_group, kv_rows, d), k_pool.dtype)]
+    if v_pool is not None:
+        scratch.append(
+            pltpu.VMEM((nbuf, head_group, kv_rows, d), v_pool.dtype))
     n_sem = 2
     if quantized:
         scratch += [pltpu.VMEM((nbuf, hkv, page), jnp.float32),
@@ -1040,7 +1065,7 @@ def _mixed_scratch(k_pool, v_pool, *, nbuf: int, head_group: int,
     scratch += [
         pltpu.VMEM((head_group, g * block_q, 128), jnp.float32),  # m
         pltpu.VMEM((head_group, g * block_q, 128), jnp.float32),  # l
-        pltpu.VMEM((head_group, g * block_q, d), jnp.float32),    # acc
+        pltpu.VMEM((head_group, g * block_q, dv or d), jnp.float32),  # acc
         pltpu.SemaphoreType.DMA((n_sem, nbuf)),
     ]
     return scratch
@@ -1049,7 +1074,8 @@ def _mixed_scratch(k_pool, v_pool, *, nbuf: int, head_group: int,
 def _ragged_launch(qp, k_pool, v_pool, tables32, pos32, work_list, layer,
                    k_scale, v_scale, carry_state=None, *, compact: bool,
                    block_q: int, dma_depth: int, interpret: bool,
-                   head_group: int, emit_state: bool = False):
+                   head_group: int, emit_state: bool = False,
+                   latent_v: int = 0, scale: float | None = None):
     """The ragged work-list ``pallas_call``, one grid step per entry of
     ``work_list`` (:func:`build_mixed_work_list`).  ``qp`` holds the
     queries in ``block_q``-row blocks, in one of two layouts that differ
@@ -1062,11 +1088,24 @@ def _ragged_launch(qp, k_pool, v_pool, tables32, pos32, work_list, layer,
 
     The output (or, with ``emit_state``, the raw f32 m / l / acc) comes
     back in the layout ``qp`` has; ``carry_state`` is read through the
-    same map.  Blocks no real item owns are never written."""
+    same map.  Blocks no real item owns are never written.
+
+    ``latent_v`` > 0 is the latent page (``v_pool`` None, ``k_pool``
+    ``[L, N, 1, P, R]`` full width): scores over all R lanes, values the
+    first ``latent_v`` lanes of the same tile, the output ``latent_v``
+    wide; the call is named ``paged_latent_attention_ragged``.  ``scale``
+    multiplies the scores (``1 / sqrt(d)`` by default)."""
     lead, hkv, g, qrows, d = qp.shape
     quantized = k_scale is not None
     page = pool_page_tokens(k_pool, k_scale)
     carry = carry_state is not None
+    latent = latent_v > 0
+    if latent and (v_pool is not None or quantized or carry or emit_state
+                   or hkv != 1):
+        raise ValueError("a latent page is one full-width pool of one row "
+                         "a token: no value pool, no scales, no carried "
+                         "softmax state")
+    dv = latent_v or d
 
     if compact:
         def q_map(i, layer_p, tables_p, pos_p, seq_p, hg_p, qb_p, plo_p,
@@ -1080,6 +1119,7 @@ def _ragged_launch(qp, k_pool, v_pool, tables32, pos32, work_list, layer,
             return (seq_p[i], hg_p[i], 0, qb_p[i], 0)
 
     blk = dict(q=(1, head_group, g, block_q, d),
+               o=(1, head_group, g, block_q, dv),
                ml=(1, head_group, g, block_q, 128))
     carry_inputs, carry_specs = [], []
     if carry:
@@ -1099,10 +1139,11 @@ def _ragged_launch(qp, k_pool, v_pool, tables32, pos32, work_list, layer,
             jax.ShapeDtypeStruct((lead, hkv, g, qrows, 128), jnp.float32),
             jax.ShapeDtypeStruct((lead, hkv, g, qrows, d), jnp.float32))
     else:
-        out_specs = pl.BlockSpec(blk["q"], q_map)
-        out_shape = jax.ShapeDtypeStruct(qp.shape, qp.dtype)
+        out_specs = pl.BlockSpec(blk["o"], q_map)
+        out_shape = jax.ShapeDtypeStruct(qp.shape[:-1] + (dv,), qp.dtype)
 
-    pool_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 2   # manual DMA
+    pools = [k_pool] if latent else [k_pool, v_pool]
+    pool_specs = [pl.BlockSpec(memory_space=pl.ANY)] * len(pools)  # manual DMA
     scale_inputs = [k_scale, v_scale] if quantized else []
     scale_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 2 if quantized else []
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -1113,13 +1154,16 @@ def _ragged_launch(qp, k_pool, v_pool, tables32, pos32, work_list, layer,
         out_specs=out_specs,
         scratch_shapes=_mixed_scratch(
             k_pool, v_pool, nbuf=dma_depth, head_group=head_group, hkv=hkv,
-            g=g, d=d, page=page, block_q=block_q, quantized=quantized),
+            g=g, d=d, page=page, block_q=block_q, quantized=quantized,
+            dv=dv),
     )
     kernel = functools.partial(
         _paged_mixed_ragged_kernel, page=page, block_q=block_q,
-        scale=1.0 / (d ** 0.5), quantized=quantized,
+        scale=1.0 / (d ** 0.5) if scale is None else scale,
+        quantized=quantized,
         int4=is_int4_pool(k_pool, k_scale), depth=dma_depth,
-        head_group=head_group, carry=carry, emit_state=emit_state)
+        head_group=head_group, carry=carry, emit_state=emit_state,
+        **({"latent": True} if latent else {}))
     # The call alone is the kernel in a profile; the layout work around it
     # stays with the caller's scope (arks.attn_layout in the mixed step).
     with jax.named_scope("arks.attn_kernel"):
@@ -1132,9 +1176,10 @@ def _ragged_launch(qp, k_pool, v_pool, tables32, pos32, work_list, layer,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
             interpret=interpret,
-            name="paged_mixed_attention_ragged",
+            name="paged_latent_attention_ragged" if latent
+            else "paged_mixed_attention_ragged",
         )(jnp.asarray(layer, jnp.int32).reshape(1), tables32, pos32,
-          *work_list, qp, k_pool, v_pool, *scale_inputs, *carry_inputs)
+          *work_list, qp, *pools, *scale_inputs, *carry_inputs)
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "interpret", "grid",
@@ -1233,11 +1278,13 @@ def _paged_mixed_call(q, k_pool, v_pool, tables, pos_start, q_len, layer,
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "nb", "interpret",
-                                             "dma_depth", "head_group"))
+                                             "dma_depth", "head_group",
+                                             "latent_v", "scale"))
 def _paged_mixed_flat_call(q, k_pool, v_pool, tables, token_slot, q_start,
                            q_len, pos_start, layer, k_scale, v_scale, *,
                            block_q: int, nb: int, dma_depth: int,
-                           interpret: bool, head_group: int):
+                           interpret: bool, head_group: int,
+                           latent_v: int = 0, scale: float | None = None):
     """Jitted ragged launch over the FLAT batch's queries ``[T, Hkv, G,
     D]`` in the block-compacted layout: ``nb`` blocks of ``block_q`` rows,
     one per real (lane, q_block) pair (``nb`` is the plan's static bound
@@ -1267,7 +1314,8 @@ def _paged_mixed_flat_call(q, k_pool, v_pool, tables, token_slot, q_start,
         jnp.transpose(qb, (0, 2, 3, 1, 4)), k_pool, v_pool,
         tables.astype(jnp.int32), pos32, work_list, layer, k_scale, v_scale,
         compact=True, block_q=block_q, dma_depth=dma_depth,
-        interpret=interpret, head_group=head_group)
+        interpret=interpret, head_group=head_group, latent_v=latent_v,
+        scale=scale)
     # Straight out of the kernel's layout by (block, row): a transpose to
     # row-major first would copy the whole output once more.
     flat = out[out_rows // block_q, :, :, out_rows % block_q]
@@ -1343,6 +1391,8 @@ def paged_mixed_attention_flat(
     grid: str | None = None,
     dma_depth: int | None = None,
     head_group: int | None = None,
+    latent_v: int = 0,
+    scale: float | None = None,
 ) -> jnp.ndarray:
     """[T, Hkv, G, D] ragged mixed attention straight over the flat batch:
     row t of lane s = token_slot[t] sits at global position
@@ -1357,7 +1407,14 @@ def paged_mixed_attention_flat(
     real q blocks (:func:`mixed_grid_plan`).  The ragged grid lays the
     queries out block-compacted (:func:`_paged_mixed_flat_call`); the
     dense grid, the byte-identity reference, keeps the per-lane
-    ``[S, Hkv, G, qmax, D]`` layout it needs."""
+    ``[S, Hkv, G, qmax, D]`` layout it needs.
+
+    A LATENT pool (``latent_v`` > 0, ``v_pool`` None, ``k_pool`` ``[L, N,
+    1, P, R]``): one row a token serves every head, so Hkv is 1 and the
+    model's heads are the G query rows of the one group; scores are over
+    all R lanes times ``scale``, values are the row's first ``latent_v``
+    lanes, and the result is ``[T, 1, G, latent_v]``.  Same block layout,
+    same work list; ragged grid only."""
     t_flat, hkv, g, d = q.shape
     s = q_len.shape[0]
     # +1: with every lane a q_len = K block (t_flat == S * K, one lane)
@@ -1368,12 +1425,16 @@ def paged_mixed_attention_flat(
                            kv=pool_kv_name(k_pool, k_scale),
                            block_q=block_q, grid=grid, dma_depth=dma_depth,
                            head_group=head_group, lanes=s)
+    if latent_v and plan["grid"] != "ragged":
+        raise ValueError("ARKS_MIXED_GRID=dense: a latent page is served "
+                         "by the ragged work-list grid only")
     if plan["grid"] == "ragged":
         return _paged_mixed_flat_call(
             q, k_pool, v_pool, tables, token_slot, q_start, q_len,
             pos_start, layer, k_scale, v_scale, block_q=plan["block_q"],
             nb=plan["nb"], dma_depth=plan["dma_depth"],
-            interpret=interpret, head_group=plan["head_group"])
+            interpret=interpret, head_group=plan["head_group"],
+            latent_v=latent_v, scale=scale)
     span = q_start[:, None] + jnp.arange(qmax, dtype=jnp.int32)
     qs = jnp.take(q, jnp.minimum(span, t_flat - 1).reshape(-1),
                   axis=0).reshape(s, qmax, hkv, g, d)
@@ -1399,11 +1460,15 @@ _UPDATE_CHUNK_INT8 = 32   # int8 sublane tile
 _SCALE_CHUNK = 128        # f32 lane tile
 
 
-def _paged_update_kernel(layer_ref, idx_ref, tables_ref, kn_ref, vn_ref,
-                         kp_in, vp_in, kp_out, vp_out, kscr, vscr, sem,
-                         *, page: int, chunk: int):
-    del kp_in, vp_in
-    b, hkv, _, d = kn_ref.shape
+def _paged_update_kernel(layer_ref, idx_ref, tables_ref, *refs,
+                         page: int, chunk: int):
+    """``refs``: per pool the new rows, the pool (aliased input), the pool
+    (output) and a chunk of scratch, pools side by side in each group (K
+    and V; a latent pool is one), then the semaphores."""
+    n = len(refs) // 4
+    new_refs, outs, scrs, sem = (refs[:n], refs[2 * n:3 * n],
+                                 refs[3 * n:4 * n], refs[4 * n])
+    b, hkv, _, d = new_refs[0].shape
     max_pos = tables_ref.shape[1] * page
     lyr = layer_ref[0]
 
@@ -1418,24 +1483,24 @@ def _paged_update_kernel(layer_ref, idx_ref, tables_ref, kn_ref, vn_ref,
         pg = tables_ref[i, idx // page]
         off = idx % page
         base = (off // chunk) * chunk
-        dst_k = kp_out.at[pl.ds(lyr, 1), pl.ds(pg, 1), :, pl.ds(base, chunk)]
-        dst_v = vp_out.at[pl.ds(lyr, 1), pl.ds(pg, 1), :, pl.ds(base, chunk)]
-        rk = pltpu.make_async_copy(dst_k, kscr, sem.at[0])
-        rv = pltpu.make_async_copy(dst_v, vscr, sem.at[1])
-        rk.start()
-        rv.start()
-        rk.wait()
-        rv.wait()
+        dsts = [out.at[pl.ds(lyr, 1), pl.ds(pg, 1), :, pl.ds(base, chunk)]
+                for out in outs]
+        reads = [pltpu.make_async_copy(dst, scr, sem.at[j])
+                 for j, (dst, scr) in enumerate(zip(dsts, scrs))]
+        for r in reads:
+            r.start()
+        for r in reads:
+            r.wait()
         row = jax.lax.broadcasted_iota(jnp.int32, (1, 1, hkv, chunk, d), 3)
         hit = row == (off - base)
-        kscr[:] = jnp.where(hit, kn_ref[pl.ds(i, 1)][None], kscr[:])
-        vscr[:] = jnp.where(hit, vn_ref[pl.ds(i, 1)][None], vscr[:])
-        wk = pltpu.make_async_copy(kscr, dst_k, sem.at[0])
-        wv = pltpu.make_async_copy(vscr, dst_v, sem.at[1])
-        wk.start()
-        wv.start()
-        wk.wait()
-        wv.wait()
+        for new_ref, scr in zip(new_refs, scrs):
+            scr[:] = jnp.where(hit, new_ref[pl.ds(i, 1)][None], scr[:])
+        writes = [pltpu.make_async_copy(scr, dst, sem.at[j])
+                  for j, (dst, scr) in enumerate(zip(dsts, scrs))]
+        for w in writes:
+            w.start()
+        for w in writes:
+            w.wait()
 
     jax.lax.fori_loop(0, b, body, 0)
 
@@ -1443,49 +1508,47 @@ def _paged_update_kernel(layer_ref, idx_ref, tables_ref, kn_ref, vn_ref,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_kv_update(
     k_pool: jnp.ndarray,   # [L, N, Hkv, P, D]
-    v_pool: jnp.ndarray,
+    v_pool: jnp.ndarray | None,   # None: a latent pool, one row a token
     k_new: jnp.ndarray,    # [B, Hkv, D]
-    v_new: jnp.ndarray,
+    v_new: jnp.ndarray | None,
     write_idx: jnp.ndarray,  # [B] int32 position per slot
     tables: jnp.ndarray,     # [B, MaxP] int32
     layer,
     interpret: bool = False,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
+) -> tuple[jnp.ndarray, jnp.ndarray | None]:
     """Write one KV row per slot at its table-mapped page, in place."""
     _, n, hkv, page, d = k_pool.shape
     if page % _UPDATE_CHUNK != 0:
         raise ValueError(f"page {page} must be a multiple of {_UPDATE_CHUNK}")
-    kn = k_new.astype(k_pool.dtype)[:, :, None, :]
-    vn = v_new.astype(v_pool.dtype)[:, :, None, :]
+    pools = [k_pool] if v_pool is None else [k_pool, v_pool]
+    news = [x.astype(p.dtype)[:, :, None, :]
+            for x, p in zip((k_new, v_new), pools)]
     layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
+    np_ = len(pools)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),
-                  pl.BlockSpec(memory_space=pltpu.VMEM),
-                  pl.BlockSpec(memory_space=pl.ANY),
-                  pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=(pl.BlockSpec(memory_space=pl.ANY),
-                   pl.BlockSpec(memory_space=pl.ANY)),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)] * np_
+        + [pl.BlockSpec(memory_space=pl.ANY)] * np_,
+        out_specs=tuple(pl.BlockSpec(memory_space=pl.ANY) for _ in pools),
         scratch_shapes=[
-            pltpu.VMEM((1, 1, hkv, _UPDATE_CHUNK, d), k_pool.dtype),
-            pltpu.VMEM((1, 1, hkv, _UPDATE_CHUNK, d), v_pool.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
+            pltpu.VMEM((1, 1, hkv, _UPDATE_CHUNK, d), p.dtype)
+            for p in pools] + [pltpu.SemaphoreType.DMA((2,))],
     )
     kernel = functools.partial(_paged_update_kernel, page=page,
                                chunk=_UPDATE_CHUNK)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=(jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
-                   jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)),
-        # 0=layer, 1=idx, 2=tables, 3=kn, 4=vn, 5=k_pool, 6=v_pool.
-        input_output_aliases={5: 0, 6: 1},
+        out_shape=tuple(jax.ShapeDtypeStruct(p.shape, p.dtype)
+                        for p in pools),
+        # 0=layer, 1=idx, 2=tables, then the new rows, then the pools.
+        input_output_aliases={3 + np_ + j: j for j in range(np_)},
         interpret=interpret,
         name="paged_kv_update",
     )(layer_arr, write_idx.astype(jnp.int32), tables.astype(jnp.int32),
-      kn, vn, k_pool, v_pool)
+      *news, *pools)
+    return (out[0], None) if v_pool is None else (out[0], out[1])
 
 
 def _paged_update_quant_kernel(layer_ref, idx_ref, tables_ref,

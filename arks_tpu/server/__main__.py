@@ -117,6 +117,17 @@ def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
                    help="per-request fault retry budget before a culprit "
                         "request fails alone with an engine_fault 500 "
                         "(sets ARKS_FAULT_RETRIES; default 1)")
+    p.add_argument("--expert-parallel-size", type=int, default=1,
+                   help="chips that share each routed layer, experts apart "
+                        "(expert parallelism as ONE chip sees it): this pod "
+                        "holds the model config's expert count, the router "
+                        "scores that many times this size, and the layer "
+                        "returns its own experts' part of the result.  A "
+                        "deployment's gang sets it per pod; 1 = the pod "
+                        "holds every expert")
+    p.add_argument("--expert-parallel-rank", type=int, default=0,
+                   help="which share of the routed layers this pod holds "
+                        "(0 .. expert-parallel-size - 1)")
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--platform", default=None, help="force a jax platform (cpu for tests)")
     p.add_argument("--disaggregation-mode", choices=("prefill", "decode"),
@@ -165,6 +176,9 @@ def build_engine(args: argparse.Namespace):
     else:
         cfg = get_config(args.model)
         model_path = args.model_path
+    if args.expert_parallel_size != 1 or args.expert_parallel_rank:
+        cfg = cfg.with_expert_share(args.expert_parallel_size,
+                                    args.expert_parallel_rank)
 
     devs = jax.devices()
     n_dev = len(devs)
@@ -311,6 +325,12 @@ def build_server(args: argparse.Namespace, engine):
         else:
             engine.register_model(name, model_path=path or None)
 
+    if args.disagg and engine.cfg.latent:
+        raise ValueError(
+            f"model {engine.cfg.name!r} (latent attention): "
+            f"--disaggregation-mode {args.disagg} hands K and V blocks "
+            "between pods (kv_transfer, the AKV1 format); a latent page "
+            "is not carried")
     if args.disagg == "prefill":
         from arks_tpu.server.disagg import PrefillServer
         # No decode loop: the engine only runs detached prefills.

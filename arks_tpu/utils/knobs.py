@@ -410,6 +410,14 @@ def _knob(name: str) -> Knob:
             "this)") from None
 
 
+def is_set(name: str) -> bool:
+    """Whether the environment states the knob (a registry default does
+    not count): for a caller whose own default differs from the
+    registry's and that has to tell a request from a default."""
+    _knob(name)
+    return os.environ.get(name) is not None
+
+
 def raw(name: str, fallback: str | None = None) -> str | None:
     """The raw string value: environment, else the registry default,
     else ``fallback`` (for knobs whose default is computed at the call

@@ -313,6 +313,75 @@ def kernel_parity(*, kv: str, hkv: int, g: int, d: int, page: int,
     return res
 
 
+def latent_parity(*, heads: int, row: int, value: int, page: int,
+                  max_pages: int, chunk: int, decode_lanes: int,
+                  chunk_lanes: int = 1, layers: int = 2, seed: int = SEED,
+                  rel_bound: float = PARITY_REL_BOUND) -> dict:
+    """``kernel_parity`` for a LATENT pool: one ``row``-wide row a token
+    (stored lane-padded, as the engine stores it) that is key and value of
+    all ``heads`` heads, through ``paged_latent_update_and_attend``: the
+    row write and ``paged_latent_attention_ragged`` (Hkv = 1, the heads as
+    the query group, values the first ``value`` lanes of the key tile)
+    against the XLA gather.  The written pool bit for bit, the output
+    within ``rel_bound`` of the oracle's largest value."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from arks_tpu.ops.attention import paged_latent_update_and_attend
+
+    stored = -(-row // 128) * 128
+    c = _parity_lanes(hkv=1, d=stored, page=page, max_pages=max_pages,
+                      chunk=chunk, decode_lanes=decode_lanes, kv="bf16",
+                      layers=layers, seed=seed, chunk_lanes=chunk_lanes)
+    lanes = c["lanes"]
+    t_flat = lanes + chunk
+    live = jnp.arange(stored) < row             # padded lanes hold zeros
+    pool = jnp.where(live, c["kp"], 0)
+    q = jax.random.normal(c["keys"][0], (t_flat, heads, row), jnp.bfloat16)
+    new = jax.random.normal(c["keys"][1], (t_flat, row), jnp.bfloat16)
+    token_slot = np.full((t_flat,), -1, np.int32)
+    token_pos = np.zeros((t_flat,), np.int32)
+    for lane in range(lanes):
+        at = slice(c["q_start"][lane], c["q_start"][lane] + c["q_len"][lane])
+        token_slot[at] = lane
+        token_pos[at] = c["pos0"][lane] + np.arange(c["q_len"][lane])
+
+    def run(impl: str):
+        fn = jax.jit(functools.partial(
+            paged_latent_update_and_attend, dv=value, scale=row ** -0.5,
+            impl=impl))
+        out = fn(q, new, pool, jnp.asarray(c["tables"]),
+                 jnp.asarray(token_slot), jnp.asarray(token_pos),
+                 jnp.asarray(c["q_start"]), jnp.asarray(c["q_len"]),
+                 jnp.asarray(c["pos0"]), jnp.asarray(1, jnp.int32))
+        return [np.asarray(x) for x in out]
+
+    t0 = time.monotonic()
+    got, ref = run("pallas"), run("xla")
+    valid = token_slot >= 0
+    out_g = got[0][valid].astype(np.float32)
+    out_r = ref[0][valid].astype(np.float32)
+    check(np.isfinite(out_g).all(), "latent parity: kernel output not finite")
+    max_diff = float(np.max(np.abs(out_g - out_r)))
+    ref_max = float(np.max(np.abs(out_r)))
+    pool_equal = bool(np.array_equal(got[1], ref[1]))
+    res = {"heads": heads, "row": row, "stored": stored, "value": value,
+           "page": page, "lanes": lanes, "chunk": chunk,
+           "chunk_lanes": chunk_lanes, "max_abs_diff": max_diff,
+           "ref_max_abs": ref_max, "rel_diff": max_diff / ref_max,
+           "rel_bound": rel_bound, "pool_bit_equal": pool_equal,
+           "seconds": round(time.monotonic() - t0, 2)}
+    emit(phase="latent_parity", **res)
+    check(pool_equal, "latent parity: updated pool differs from the oracle's")
+    check(max_diff <= rel_bound * ref_max,
+          f"latent parity: max|kernel - oracle| = {max_diff:.5f} exceeds "
+          f"{rel_bound:.5f} x {ref_max:.3f}")
+    return res
+
+
 def head_group_parity(*, kv: str, hkv: int, g: int, d: int, page: int,
                       max_pages: int, chunk: int, decode_lanes: int,
                       layers: int = 2, seed: int = SEED,
@@ -932,6 +1001,11 @@ CHIP_PARITY = dict(hkv=4, g=7, d=128, page=256, max_pages=4, chunk=256,
 # The benchmark's flood at its 192 slots: 115 streams decoding while the
 # chunk's 256 rows are shared among 76 prompts (3-4 rows each).
 CHIP_PARITY_FLOOD = dict(CHIP_PARITY, decode_lanes=115, chunk_lanes=76)
+# A latent pool at Kimi-K2.5's widths (64 heads over one 512 + 64 row a
+# token, stored 640 wide) under a flood-like lane mix: 12 streams decoding
+# while the chunk's 256 rows are shared among 3 prompts.
+CHIP_PARITY_LATENT = dict(heads=64, row=576, value=512, page=256, max_pages=4,
+                          chunk=256, decode_lanes=12, chunk_lanes=3)
 
 
 def run(tp4: bool) -> dict:
@@ -961,6 +1035,7 @@ def run(tp4: bool) -> dict:
         for kv in ("int8", "int4"):
             kernel_parity(kv=kv, **CHIP_PARITY)
         kernel_parity(kv="int8", **CHIP_PARITY_FLOOD)
+        latent_parity(**CHIP_PARITY_LATENT)
         head_group_parity(kv="int8", **CHIP_PARITY)
         run_pod(server_argv(MODEL, num_slots=NUM_SLOTS,
                             max_model_len=MAX_MODEL_LEN, weight_dtype="int8",

@@ -1,0 +1,37 @@
+"""The benchmark's references and manifest under the tier-1 gate.
+
+``benchmarks/tests`` is the instrument's own suite and runs apart from
+``tests/`` (its rehearsal files start whole benchmark runs).  Its PURE
+cases, those that run in this process on the CPU in about a minute, are
+what holds a reference family to the program (the weights a seed means bit
+for bit, the served log-probabilities, the lower-precision controls) and
+the manifest to the contract; imported here, each counts in the tier-1 run
+and a PR that breaks a reference cannot pass the gate unnoticed.  Nothing
+is copied: the functions are the instrument's own."""
+
+import os
+import sys
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from benchmarks.tests.test_manifest import (  # noqa: E402,F401
+    test_a_new_cell_loads_from_added_files_alone,
+    test_a_new_family_loads_from_added_files_alone,
+    test_manifest_and_every_file_it_names,
+    test_validator_names_a_fault_of_a_family_or_a_share,
+    test_validator_names_the_fault,
+)
+from benchmarks.tests.test_reference import (  # noqa: E402,F401
+    test_seeded_weights_are_the_programs_bit_for_bit,
+    test_served_logprobs_against_the_reference,
+    test_the_quantile_is_nearest_rank_and_the_verdict_wants_enough_positions,
+    test_the_routing_margin_is_small_where_two_experts_tie,
+)
+from benchmarks.tests.test_reference_mla_moe import (  # noqa: E402,F401
+    test_seeded_weights_are_the_programs_bit_for_bit as
+    test_mla_moe_seeded_weights_are_the_programs_bit_for_bit,
+    test_served_logprobs_against_the_reference as
+    test_mla_moe_served_logprobs_against_the_reference,
+    test_the_family_keeps_the_contract_and_imports_nothing_of_the_program,
+    test_the_routing_margin_is_in_biased_score_units,
+)

@@ -90,6 +90,43 @@ def _mixed_flat(s, kv, lanes, chunk, hkv=HKV, **plan):
         lane, lane, lane, ks, vs)
 
 
+# Kimi-K2.5 (benchmarks/configs/kimi-k2.5-ep32-l9): 64 heads over ONE latent
+# row a token, 512 + 64 lanes stored as five 128-lane tiles, 9 layers, 16
+# slots x 8192 tokens, a 1024-row chunk.
+LAT = dict(layers=9, heads=64, row=640, value=512, slots=16, ctx=8192,
+           chunk=1024)
+
+
+def _latent_pool(s):
+    pages = LAT["slots"] * LAT["ctx"] // PAGE // 4
+    return s((LAT["layers"], pages, 1, PAGE, LAT["row"]), jnp.bfloat16)
+
+
+def _latent_flat(s, chunk, **plan):
+    """The latent kernel over the flat batch, as the latent block calls
+    it: Hkv = 1, the 64 heads as the query group, values from the key
+    tile's first 512 lanes (chunk 0 = the pipelined step)."""
+    lanes, t = LAT["slots"], LAT["slots"] + chunk
+
+    def fn(qq, pool, tables, tslot, qstart, qlen, pos):
+        return pa.paged_mixed_attention_flat(
+            qq, pool, None, tables, tslot, qstart, qlen, pos, 3,
+            latent_v=LAT["value"], scale=0.1, **plan)
+
+    lane = s((lanes,), jnp.int32)
+    return jax.jit(fn).lower(
+        s((t, 1, LAT["heads"], LAT["row"]), jnp.bfloat16), _latent_pool(s),
+        s((lanes, LAT["ctx"] // PAGE), jnp.int32), s((t,), jnp.int32),
+        lane, lane, lane)
+
+
+def _latent_update(s):
+    t = LAT["slots"] + LAT["chunk"]
+    return pa.paged_kv_update.lower(
+        _latent_pool(s), None, s((t, 1, LAT["row"]), jnp.bfloat16), None,
+        s((t,), jnp.int32), s((t, LAT["ctx"] // PAGE), jnp.int32), 3)
+
+
 def _paged_decode(s, kv):
     kp, vp, ks, vs = _pool(s, kv, HKV)
     return pa.paged_decode_attention.lower(
@@ -172,6 +209,15 @@ CASES = {
         s, "int8", SLOTS, CHUNK, head_group=1),
     "flat-int8-seq-hkv1": lambda s: _mixed_flat(s, "int8", SLOTS, CHUNK,
                                                 hkv=1),
+    # The latent page at Kimi-K2.5's widths: the sequential step at the
+    # block the plan chooses (8: 512 query rows a work item) and at 16,
+    # the pipelined step, and the one-pool row write.  (A 576-wide row is
+    # refused: "Slice shape along dimension 4 must be aligned to tiling
+    # (128)"; XLA lays such an array out 640 wide in HBM anyway.)
+    "latent-seq": lambda s: _latent_flat(s, LAT["chunk"]),
+    "latent-seq-bq16": lambda s: _latent_flat(s, LAT["chunk"], block_q=16),
+    "latent-pipe": lambda s: _latent_flat(s, 0),
+    "latent-update": lambda s: _latent_update(s),
     # tp=4 leaves one KV head per chip.
     "mixed-int8-chunk-hkv1": lambda s: _mixed(s, "int8", CHUNK + 1, hkv=1),
     "update-int8-hkv1": lambda s: _update(s, "int8", hkv=1),
