@@ -49,6 +49,22 @@ Checks per reachable function:
                        should be seen in review;
 - ``lock-acquire``     explicit ``.acquire()`` (unbounded block).
 
+One more check runs over its own, narrower reachable set — what the
+engine thread does between a sequential step's ``wait`` and the next
+``dispatch``.  Its roots are discovered, not listed: the engine methods
+that open a ``wait`` or a ``dispatch`` step section (``tag + "wait"`` —
+the mixed and spec-mixed issue and resolve functions; the resolve tails
+are INCLUDED here, since fan-out and promotion live in them):
+
+- ``eager-device-call``  ``jnp.asarray`` / ``jnp.array`` /
+                       ``jax.device_put`` / ``jax.random.*`` outside a
+                       jitted function: each is a call into JAX of its
+                       own (a transfer, or a tiny program) that drops and
+                       retakes the GIL while the device idles.  A step's
+                       host values are operands of its ONE program
+                       (``_OperandPack``); what a program needs computed
+                       is computed inside it.
+
 Plus three surface contracts the old guard carried: ``trace-evt-impl``
 (``Tracer.evt`` / ``_Ring`` stay lock- and serialization-free),
 ``sketch-import`` (``prefix_sketch`` stays importable without jax or the
@@ -137,6 +153,9 @@ EXPECTED_TAILS = (
     "_residency_step",
 )
 
+# The step sections whose functions root ``eager-device-call``.
+SEQ_STEP_SECTIONS = ("wait", "dispatch")
+
 SERIAL_CALLS = {"json.dumps", "json.loads", "pickle.dumps",
                 "pickle.loads", "pickle.dump", "pickle.load",
                 "time.sleep", "marshal.dumps", "marshal.loads"}
@@ -210,6 +229,36 @@ def _function_findings(fn, findings: list[Finding]) -> None:
                         "lock held on the issue-side hot path (keep the "
                         "critical section bounded and host-only)",
                         detail=expr, severity="warn"))
+
+
+def _seq_step_roots(methods: dict) -> dict[str, set[str]]:
+    """Section -> the engine methods that open it (``tag + "<section>"``
+    as a step-section span's name)."""
+    out: dict[str, set[str]] = {sec: set() for sec in SEQ_STEP_SECTIONS}
+    for name, node in methods.items():
+        for sub in ast.walk(node):
+            if (isinstance(sub, ast.BinOp) and isinstance(sub.op, ast.Add)
+                    and isinstance(sub.right, ast.Constant)
+                    and sub.right.value in out):
+                out[sub.right.value].add(name)
+    return out
+
+
+def _eager_device_calls(fn, findings: list[Finding]) -> None:
+    qual = f"{fn.cls}.{fn.name}" if fn.cls else fn.name
+    for node in ast.walk(fn.node):
+        if not (isinstance(node, ast.Call)
+                and isinstance(node.func, ast.Attribute)):
+            continue
+        full = ast.unparse(node.func)
+        if (full in ("jnp.asarray", "jnp.array", "jax.device_put")
+                or full.startswith("jax.random.")):
+            findings.append(Finding(
+                RULE, "eager-device-call", fn.path, node.lineno, qual,
+                "eager call into JAX between a sequential step's wait and "
+                "the next dispatch (make the value an operand of the "
+                "step's program, or compute it inside one)",
+                detail=ast.unparse(node)))
 
 
 def _trace_evt_impl(tree: SourceTree, findings: list[Finding]) -> None:
@@ -292,6 +341,18 @@ def check(tree: SourceTree) -> list[Finding]:
                     "sanctioned host-sync tail renamed/removed — the "
                     "issue-side guard is only meaningful while the sync "
                     "tails exist"))
+
+        seq = _seq_step_roots(methods)
+        for sec, names in seq.items():
+            if not names:
+                findings.append(Finding(
+                    RULE, "contract", ENGINE, 1, ENGINE_CLASS,
+                    f"no engine method opens a {sec!r} step section — "
+                    "eager-device-call has lost its roots"))
+        seq_roots = [graph.find(ENGINE, ENGINE_CLASS, n)
+                     for n in sorted(set().union(*seq.values()))]
+        for nid in sorted(graph.reachable([r for r in seq_roots if r])):
+            _eager_device_calls(graph.nodes[nid], findings)
 
     roots = [nid for nid in (graph.find(*r) for r in ROOTS) if nid]
     reach = graph.reachable(

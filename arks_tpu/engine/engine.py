@@ -269,7 +269,10 @@ class _ChunkState:
     ids: list[int]
     pos: int      # tokens already prefilled
     seed: int     # sampling seed (key = PRNGKey(seed))
-    key: jax.Array  # base sampling key (PRNGKey(seed))
+    # Base sampling key (PRNGKey(seed)), kept on the HOST: the step packs
+    # it into its override columns and the promotion folds it inside its
+    # program, so no step reads it back from the device.
+    key: np.ndarray
     # Paged layout: the prompt's chained page digests (computed at match
     # time), registered into the allocator's prefix index at promote.
     digests: list | None = None
@@ -636,6 +639,15 @@ class EngineMetrics:
             "dispatches issued while a request was prefilling or waited "
             "in the admission queue (mixed_chunk_tokens_total over this "
             "is the share of the budget the steps used)")
+        # Calls the engine thread makes into JAX on the sequential step's
+        # path (programs and host-to-device transfers), by site: over the
+        # dispatch count (mixed_batch_tokens_count) it reads how often a
+        # step speaks to the device (docs/monitoring.md).
+        self.step_device_calls_total = r.counter(
+            "step_device_calls_total",
+            "Device programs and transfers the engine thread issued on "
+            "the sequential step path, by site (step, promote, admit, "
+            "clear, draft, warm)")
         # XLA compilations seen by this process (jax.monitoring): which
         # step recompiled is an operator's question, not only a bench's.
         self.xla_compilations_total = r.counter(
@@ -860,6 +872,63 @@ def _scoped(phase: str):
                 self.trace.evt("", "phase." + phase, "E")
         return wrapper
     return deco
+
+
+class _OperandPack:
+    """The small host operands of one device program, of several 32-bit
+    dtypes, laid out in ONE int32 host buffer that the program slices
+    apart: a call then hands the device one transfer with its dispatch,
+    where an operand each cost a transfer each (0.1 ms of the engine
+    thread apiece on a v5e, and a drop of the GIL; PERF.md).  ``fields``
+    is ``(name, dtype, shape, fill)``; bool fields are stored as 0 / 1."""
+
+    def __init__(self, fields):
+        self._fields = []
+        off = 0
+        for name, dtype, shape, _fill in fields:
+            n = int(np.prod(shape))
+            self._fields.append((name, np.dtype(dtype), tuple(shape), off, n))
+            off += n
+        self.size = off
+        self._template = np.zeros((off,), np.int32)
+        views = self._views(self._template)
+        for name, _dtype, _shape, fill in fields:
+            views[name][...] = fill
+
+    def _views(self, buf: np.ndarray) -> dict:
+        out = {}
+        for name, dtype, shape, off, n in self._fields:
+            seg = buf[off: off + n]
+            if dtype != np.bool_:
+                seg = seg.view(dtype)
+            out[name] = seg.reshape(shape)
+        return out
+
+    def host(self) -> tuple[np.ndarray, dict]:
+        """A fresh buffer at the fields' fill values, and the fields as
+        named views into it (what is written through them is the operand)."""
+        buf = self._template.copy()
+        return buf, self._views(buf)
+
+    def host_from(self, values: dict) -> np.ndarray:
+        """The buffer holding ``values`` (a follower's payload)."""
+        buf, views = self.host()
+        for name, view in views.items():
+            view[...] = values[name]
+        return buf
+
+    def unpack(self, buf) -> dict:
+        """Inside the program: the fields as arrays of their own dtypes
+        and shapes (slices and bitcasts of the one operand)."""
+        out = {}
+        for name, dtype, shape, off, n in self._fields:
+            seg = jax.lax.slice(buf, (off,), (off + n,)).reshape(shape)
+            if dtype == np.bool_:
+                seg = seg != 0
+            elif dtype != np.int32:
+                seg = jax.lax.bitcast_convert_type(seg, dtype)
+            out[name] = seg
+        return out
 
 
 def _named_jit(name: str, fn, **jit_kw):
@@ -2111,8 +2180,33 @@ class InferenceEngine:
         # directly would share one process-wide cache across engines and
         # make compiled_program_variants() report shapes traced by OTHER
         # engines (order-dependent compile-budget counts under pytest).
-        self._set_slot_fn = _named_jit(
-            "arks_set_slot", sampler_mod.set_slot, donate_argnums=(0,))
+        #
+        # The sizes M the promotion program is compiled for: a step's
+        # completing prompts are padded up to the next one (a step cannot
+        # complete more prompts than it has lanes or chunk tokens), with
+        # rows that name no slot and so write nothing.  Few sizes: each
+        # costs the first step 0.4 s (1.5 s with a cold compile cache) on
+        # a v5e, a padded row costs 2.4 KB of operand.
+        cap = max(1, min(self.ecfg.num_slots, self._mixed_budget or 1))
+        nb, ns = sampler_mod.LOGIT_BIAS_MAX, sampler_mod.SUPPRESS_MAX
+        self._promote_packs = packs = {
+            m: _OperandPack([
+                ("slots", np.int32, (m,), self.ecfg.num_slots),
+                ("scalars_f", np.float32, (m, 4), 0.0),
+                ("scalars_i", np.int32, (m, 4), 0),
+                ("keys", np.uint32, (m, 2), 0),
+                ("fold", bool, (m,), False),
+                ("bias_ids", np.int32, (m, nb), -1),
+                ("bias_vals", np.float32, (m, nb), 0.0),
+                ("suppress_ids", np.int32, (m, ns), -1)])
+            for m in sorted({m for m in (1, 8) if m < cap} | {cap})}
+        by_len = {pk.size: pk for pk in packs.values()}
+        self._promote_fn = _named_jit(
+            "arks_promote",
+            lambda state, operands: sampler_mod.promote_slots(
+                state, **by_len[operands.shape[0]].unpack(operands)),
+            donate_argnums=(0,))
+        self._promote_warm = False
         self._clear_pen_fn = _named_jit(
             "arks_clear_penalties", sampler_mod.clear_slot_penalties,
             donate_argnums=(0,))
@@ -2252,13 +2346,21 @@ class InferenceEngine:
                 return jnp.concatenate([ids, jnp.stack(
                     [held[0], jnp.sum(valid).astype(jnp.int32)])])
 
-            def mixed_prog(params, cache, sampling, tokens, token_slot,
-                           token_pos, tables, feed_tokens, feed_active,
-                           lengths, sample_src, seq_q_start, seq_q_len,
-                           seq_pos_start, ov_mask, ov_temp, ov_top_p,
-                           ov_top_k, ov_key, ov_bias_ids, ov_bias_vals,
-                           ov_sup, ov_min_until, ov_guide, ov_guide_row,
-                           gtables, want_lp: bool):
+            self._mixed_pack = pack = _OperandPack(self._mixed_fields(
+                self.ecfg.num_slots + self._mixed_budget))
+
+            def mixed_prog(params, cache, sampling, operands, gtables,
+                           want_lp: bool):
+                return mixed_body(params, cache, sampling, gtables, want_lp,
+                                  **pack.unpack(operands))
+
+            def mixed_body(params, cache, sampling, gtables, want_lp: bool,
+                           *, tokens, token_slot, token_pos, tables,
+                           feed_tokens, feed_active, lengths, sample_src,
+                           seq_q_start, seq_q_len, seq_pos_start, ov_mask,
+                           ov_temp, ov_top_p, ov_top_k, ov_key, ov_bias_ids,
+                           ov_bias_vals, ov_sup, ov_min_until, ov_guide,
+                           ov_guide_row):
                 sampling = sampler_mod.count_tokens(sampling, feed_tokens,
                                                     feed_active)
                 logits, cache, *held = tf.mixed_step(
@@ -2425,15 +2527,24 @@ class InferenceEngine:
                          ).reshape(-1)
             vsrc = jnp.arange(B * DK, dtype=jnp.int32)
 
+            self._spec_pack = spack = _OperandPack(self._mixed_fields(
+                B * DK + self._mixed_budget, spec=True))
+
             def spec_mixed_prog(params, dparams, cache, dcache, sampling,
-                                tokens, token_slot, token_pos, tables,
-                                feed_tokens, feed_active, lengths,
-                                sample_src, seq_q_start, seq_q_len,
-                                seq_pos_start, spec_enable, ov_mask,
-                                ov_temp, ov_top_p, ov_top_k, ov_key,
-                                ov_bias_ids, ov_bias_vals, ov_sup,
-                                ov_min_until, ov_guide, ov_guide_row,
-                                gtables, want_lp: bool):
+                                operands, gtables, want_lp: bool):
+                return spec_mixed_body(params, dparams, cache, dcache,
+                                       sampling, gtables, want_lp,
+                                       **spack.unpack(operands))
+
+            def spec_mixed_body(params, dparams, cache, dcache, sampling,
+                                gtables, want_lp: bool, *, tokens,
+                                token_slot, token_pos, tables, feed_tokens,
+                                feed_active, lengths, sample_src,
+                                seq_q_start, seq_q_len, seq_pos_start,
+                                spec_enable, ov_mask, ov_temp, ov_top_p,
+                                ov_top_k, ov_key, ov_bias_ids, ov_bias_vals,
+                                ov_sup, ov_min_until, ov_guide,
+                                ov_guide_row):
                 # Feed-time counting: spec-DISABLED penalized lanes
                 # advance one normally-sampled token per dispatch, so
                 # their counts must evolve; eligible lanes are
@@ -3031,7 +3142,7 @@ class InferenceEngine:
         cls_host, trans_host, ver = self.guides.snapshot()
         self._emit("guides", class_ids=cls_host, trans=trans_host,
                    version=ver)
-        self._guide_dev = (jnp.asarray(cls_host), jnp.asarray(trans_host))
+        self._guide_dev = jax.device_put((cls_host, trans_host))
         self._guide_ver = ver
 
     def _emit(self, op: str, **payload) -> None:
@@ -4324,15 +4435,8 @@ class InferenceEngine:
                 self._release_slot_pages(slot)
                 self._free.append(slot)
                 self._unpin_guide(req)
-                p = req.params
-                if (p.presence_penalty or p.frequency_penalty
-                        or p.logit_bias or p.min_tokens
-                        or p.guide is not None):
-                    # Re-arm shaped()'s fast paths (same as _finish): the
-                    # admit program already wrote this slot's shaping rows.
-                    self._emit("clear_penalties", slot=slot)
-                    self._sampling = self._clear_pen_fn(
-                        self._sampling, jnp.asarray(slot, jnp.int32))
+                # The admit program already wrote this slot's shaping rows.
+                self._clear_shaping(slot, req.params)
                 req.outputs.put(RequestOutput(
                     request_id=req.request_id, token_ids=[], finished=True,
                     finish_reason="abort", num_prompt_tokens=len(ids)))
@@ -4493,7 +4597,7 @@ class InferenceEngine:
             # shape); the host side drops the padded entries.
             pages = [p for _, p in grp] + [grp[0][1]] * (G - len(grp))
             out = self._spill_gather_fn(self._cache,
-                                        jnp.asarray(pages, jnp.int32))
+                                        np.array(pages, np.int32))
             for arr in out:
                 if arr is not None:
                     arr.copy_to_host_async()
@@ -5376,11 +5480,7 @@ class InferenceEngine:
         self._slots.pop(slot)
         self._release_slot_pages(slot)
         self._free.append(slot)
-        if (p.presence_penalty or p.frequency_penalty or p.logit_bias
-                or p.min_tokens or p.guide is not None):
-            self._emit("clear_penalties", slot=slot)
-            self._sampling = self._clear_pen_fn(self._sampling,
-                                                jnp.asarray(slot, jnp.int32))
+        self._clear_shaping(slot, p)
         self._swap_pending.append(_SwapState(rec=rec, staged=staged, row=row))
         self._preempt_last[rid] = time.monotonic()
         self.trace.evt(rid, "park.preempt", "B", n_pages)
@@ -5418,11 +5518,7 @@ class InferenceEngine:
         self._release_slot_pages(slot)
         self._free.append(slot)
         self._unpin_guide(st.request)
-        if (p.presence_penalty or p.frequency_penalty or p.logit_bias
-                or p.min_tokens or p.guide is not None):
-            self._emit("clear_penalties", slot=slot)
-            self._sampling = self._clear_pen_fn(self._sampling,
-                                                jnp.asarray(slot, jnp.int32))
+        self._clear_shaping(slot, p)
         self._preempt_last[rid] = time.monotonic()
         self.trace.evt(rid, "park.preempt", "B", "replay")
         self.metrics.requests_preempted_total.inc(
@@ -5594,13 +5690,12 @@ class InferenceEngine:
             gid = -1
             if rec.request.params.guide is not None:
                 gid, _ = self._guide_cols(rec.request.params)
-            self._apply_set_slot(slot, rec.request.params,
-                                 jnp.asarray(entry["key"]),
-                                 num_prompt=rec.num_prompt, guide=gid,
+            self._apply_set_slot(slot, rec.request.params, entry["key"],
+                                 False, num_prompt=rec.num_prompt,
+                                 guide=gid,
                                  guide_row=int(entry["guide_row"]))
             self._sampling = self._restore_counts_fn(
-                self._sampling, jnp.asarray(slot, jnp.int32),
-                jnp.asarray(entry["counts"]))
+                self._sampling, np.int32(slot), entry["counts"])
         except Exception as e:
             self._free.append(slot)
             del self._swapped[rid]
@@ -6645,7 +6740,7 @@ class InferenceEngine:
             k = k[:, :, : self.ecfg.max_cache_len]
             v = v[:, :, : self.ecfg.max_cache_len]
         p = req.params
-        key = jnp.asarray(sampler_mod.np_prng_key(pf.seed))
+        key = sampler_mod.np_prng_key(pf.seed)
         try:
             slot = self._free.pop()
             if self._paged:
@@ -6688,7 +6783,7 @@ class InferenceEngine:
                        stop_ids=list(p.stop_token_ids),
                        ignore_eos=p.ignore_eos,
                        num_prompt=pf.num_prompt, guide=gid, guide_row=grow)
-            self._apply_set_slot(slot, p, jax.random.fold_in(key, 1),
+            self._apply_set_slot(slot, p, key, True,
                                  num_prompt=pf.num_prompt, guide=gid,
                                  guide_row=grow)
         except Exception as e:
@@ -6861,24 +6956,50 @@ class InferenceEngine:
                 "(evicted without a pin?)")
         return g.guide_id, g.start_row
 
-    def _apply_set_slot(self, slot: int, p, key, num_prompt: int = 0,
-                        guide: int = -1, guide_row: int = 0) -> None:
-        """Write one slot's sampling params through the donated jit (array
-        args keep one compiled program across requests; python floats would
-        retrace per distinct value).  ``guide_row`` is the POST-first-token
-        DFA row (resolved by the caller — followers receive it by value, so
-        they never need the leader's guide registry)."""
-        bias_ids, bias_vals, sup, _mf, min_until =             self._shape_cols(p, num_prompt)
-        self._sampling = self._set_slot_fn(
-            self._sampling, jnp.asarray(slot, jnp.int32),
-            jnp.asarray(p.temperature, jnp.float32),
-            jnp.asarray(p.top_p, jnp.float32),
-            jnp.asarray(p.top_k, jnp.int32), key,
-            jnp.asarray(p.presence_penalty, jnp.float32),
-            jnp.asarray(p.frequency_penalty, jnp.float32),
-            jnp.asarray(bias_ids), jnp.asarray(bias_vals),
-            jnp.asarray(sup), jnp.asarray(min_until, jnp.int32),
-            jnp.asarray(guide, jnp.int32), jnp.asarray(guide_row, jnp.int32))
+    def _apply_set_slot(self, slot: int, p, key: np.ndarray, fold: bool,
+                        num_prompt: int = 0, guide: int = -1,
+                        guide_row: int = 0, site: str = "admit") -> None:
+        """One slot through ``_apply_set_slots``."""
+        self._apply_set_slots(
+            [(slot, p, key, fold, num_prompt, guide, guide_row)], site)
+
+    def _apply_set_slots(self, rows: list, site: str,
+                         size: int | None = None) -> None:
+        """Write the sampling rows of ``rows`` (each ``(slot, params, key,
+        fold, num_prompt, guide, guide_row)``) through ONE call of the
+        donated promotion program, whatever their number.  Its operand is
+        one host buffer (_OperandPack) with explicit dtypes, padded up to
+        a size of ``_promote_packs`` (so the program compiles once a size,
+        at ``_warm_promote``, and a python float cannot retrace it per
+        value); ``key`` is a HOST key, the request's base key where
+        ``fold`` (folded inside the program) or a snapshot written as it
+        is.  ``guide_row`` is the POST-first-token DFA row (resolved by
+        the caller — followers receive it by value, so they never need the
+        leader's guide registry)."""
+        m = size or next(s for s in self._promote_packs if s >= len(rows))
+        operands, a = self._promote_packs[m].host()
+        for i, (slot, p, key, fold, num_prompt, guide, guide_row) \
+                in enumerate(rows):
+            (a["bias_ids"][i], a["bias_vals"][i], a["suppress_ids"][i], _mf,
+             min_until) = self._shape_cols(p, num_prompt)
+            a["slots"][i] = slot
+            a["scalars_f"][i] = (p.temperature, p.top_p, p.presence_penalty,
+                                 p.frequency_penalty)
+            a["scalars_i"][i] = (p.top_k, min_until, guide, guide_row)
+            a["keys"][i] = key
+            a["fold"][i] = fold
+        self.metrics.step_device_calls_total.inc(1, site=site)
+        self._sampling = self._promote_fn(self._sampling, operands)
+
+    def _warm_promote(self) -> None:
+        """Compile the promotion program for every size it takes, before
+        the first sequential step's dispatch: a call with no rows writes
+        nothing.  Followers mirror each call, so a gang compiles (and
+        runs) the same programs in the same order."""
+        for m in self._promote_packs:
+            self._emit("set_slots", rows=[], size=m)
+            self._apply_set_slots([], "warm", size=m)
+        self._promote_warm = True
 
     def _register_slot(self, req: Request, slot: int, first: int,
                        num_prompt: int, first_lp=None,
@@ -6897,10 +7018,10 @@ class InferenceEngine:
             try:
                 self._emit("draft_prefill", tokens=padded, length=len(ids),
                            slot=slot)
+                self.metrics.step_device_calls_total.inc(1, site="draft")
                 self._draft_cache = self._draft_prefill_fn(
-                    self._draft_params, self._draft_cache,
-                    jnp.asarray(padded),
-                    jnp.asarray([len(ids)], jnp.int32), jnp.asarray(slot))
+                    self._draft_params, self._draft_cache, padded,
+                    np.array([len(ids)], np.int32), np.int32(slot))
             except Exception as e:
                 # Not registered yet, nothing emitted yet: the survivor
                 # re-queues and re-admits with its pinned seed (same
@@ -7121,8 +7242,8 @@ class InferenceEngine:
                                          num_prompt=len(ids))]) from e
         self._prefilling[slot] = _ChunkState(request=req, ids=ids,
                                              pos=prefix_len, seed=seed,
-                                             key=jnp.asarray(
-                                                 sampler_mod.np_prng_key(seed)),
+                                             key=sampler_mod.np_prng_key(
+                                                 seed),
                                              digests=digests)
         self.trace.evt(req.request_id, "queue", "E")
         self.trace.evt(req.request_id, "prefill", "B", len(ids))
@@ -7221,9 +7342,8 @@ class InferenceEngine:
                    logit_bias=list(p.logit_bias), min_tokens=p.min_tokens,
                    stop_ids=list(p.stop_token_ids), ignore_eos=p.ignore_eos,
                    num_prompt=len(st.ids), guide=gid, guide_row=grow1)
-        self._apply_set_slot(slot, p, jax.random.fold_in(st.key, 1),
-                             num_prompt=len(st.ids), guide=gid,
-                             guide_row=grow1)
+        self._apply_set_slot(slot, p, st.key, True, num_prompt=len(st.ids),
+                             guide=gid, guide_row=grow1)
         self._register_slot(st.request, slot, first, len(st.ids),
                             first_lp=first_lp, seed=st.seed)
         if self._paged and self._chunk:
@@ -8034,37 +8154,40 @@ class InferenceEngine:
             if int(self._lengths[slot]) + 1 + rows > self.ecfg.max_cache_len:
                 self._finish(slot, "length")
 
-    def _mixed_batch_arrays(self, t_budget: int) -> dict:
-        """Empty host-side arrays for one mixed/spec-mixed batch: the flat
-        token view, the per-lane sampler view, and the completion-override
-        columns — ONE definition, so the plain and spec builders cannot
-        drift on padding conventions."""
-        num_slots = self.ecfg.num_slots
-        sentinel = self._park_sentinel()
-        return dict(
-            tokens=np.zeros((t_budget,), np.int32),
-            token_slot=np.full((t_budget,), -1, np.int32),
-            token_pos=np.full((t_budget,), sentinel, np.int32),
-            sample_src=np.zeros((num_slots,), np.int32),
-            feed_tokens=np.zeros((num_slots,), np.int32),
-            feed_active=np.zeros((num_slots,), bool),
-            seq_q_start=np.zeros((num_slots,), np.int32),
-            seq_q_len=np.zeros((num_slots,), np.int32),
-            seq_pos_start=np.zeros((num_slots,), np.int32),
-            ov_mask=np.zeros((num_slots,), bool),
-            ov_temp=np.zeros((num_slots,), np.float32),
-            ov_top_p=np.ones((num_slots,), np.float32),
-            ov_top_k=np.zeros((num_slots,), np.int32),
-            ov_key=np.zeros((num_slots, 2), np.uint32),
-            ov_bias_ids=np.full((num_slots, sampler_mod.LOGIT_BIAS_MAX), -1,
-                                np.int32),
-            ov_bias_vals=np.zeros((num_slots, sampler_mod.LOGIT_BIAS_MAX),
-                                  np.float32),
-            ov_sup=np.full((num_slots, sampler_mod.SUPPRESS_MAX), -1,
-                           np.int32),
-            ov_min_until=np.zeros((num_slots,), np.int32),
-            ov_guide=np.full((num_slots,), -1, np.int32),
-            ov_guide_row=np.zeros((num_slots,), np.int32))
+    def _mixed_fields(self, t_budget: int, spec: bool = False) -> list:
+        """The host operands of one mixed (``spec``: spec-mixed) batch as
+        fields of an _OperandPack, with the values an empty batch holds:
+        the block tables and lengths, the flat token view, the per-lane
+        sampler view, and the completion-override columns — ONE
+        definition, so the plain and spec builders (and a follower's
+        replay) cannot drift on padding conventions."""
+        b = self.ecfg.num_slots
+        nb, ns = sampler_mod.LOGIT_BIAS_MAX, sampler_mod.SUPPRESS_MAX
+        i32, f32 = np.int32, np.float32
+        return [
+            ("tables", i32, (b, self._max_pages), 0),
+            ("lengths", i32, (b,), 0),
+            ("tokens", i32, (t_budget,), 0),
+            ("token_slot", i32, (t_budget,), -1),
+            ("token_pos", i32, (t_budget,), self._park_sentinel()),
+            ("sample_src", i32, (b,), 0),
+            ("feed_tokens", i32, (b,), 0),
+            ("feed_active", bool, (b,), False),
+            ("seq_q_start", i32, (b,), 0),
+            ("seq_q_len", i32, (b,), 0),
+            ("seq_pos_start", i32, (b,), 0),
+            *([("spec_enable", bool, (b,), False)] if spec else []),
+            ("ov_mask", bool, (b,), False),
+            ("ov_temp", f32, (b,), 0.0),
+            ("ov_top_p", f32, (b,), 1.0),
+            ("ov_top_k", i32, (b,), 0),
+            ("ov_key", np.uint32, (b, 2), 0),
+            ("ov_bias_ids", i32, (b, nb), -1),
+            ("ov_bias_vals", f32, (b, nb), 0.0),
+            ("ov_sup", i32, (b, ns), -1),
+            ("ov_min_until", i32, (b,), 0),
+            ("ov_guide", i32, (b,), -1),
+            ("ov_guide_row", i32, (b,), 0)]
 
     def _fill_chunk_lanes(self, a: dict, t: int):
         """Round-robin prefill-chunk fill starting at flat index ``t``: an
@@ -8118,7 +8241,7 @@ class InferenceEngine:
                 a["ov_temp"][slot] = p.temperature
                 a["ov_top_p"][slot] = p.top_p
                 a["ov_top_k"][slot] = p.top_k
-                a["ov_key"][slot] = np.asarray(st.key)
+                a["ov_key"][slot] = st.key
                 a["ov_bias_ids"][slot] = bias_ids
                 a["ov_bias_vals"][slot] = bias_vals
                 a["ov_sup"][slot] = sup
@@ -8269,6 +8392,8 @@ class InferenceEngine:
                 return False
             self._ensure_guides_uploaded()
             self._grow_slot_pages(rows)
+            if not self._promote_warm:
+                self._warm_promote()
             return True
         finally:
             if sec:
@@ -8328,7 +8453,6 @@ class InferenceEngine:
         if not self._mixed_begin(1, tag):
             return None
         self._faults.fire("decode")
-        num_slots = self.ecfg.num_slots
         dec_slots = list(self._slots.keys())
         if self._residency is not None:
             # Engaged slots decode through _residency_step — their lanes
@@ -8342,7 +8466,7 @@ class InferenceEngine:
         evt = self.trace.evt
         if sec:
             evt("", tag + "pack", "B")
-        a = self._mixed_batch_arrays(num_slots + self._mixed_budget)
+        operands, a = self._mixed_pack.host()
 
         t = 0
         for slot in dec_slots:
@@ -8364,34 +8488,20 @@ class InferenceEngine:
         want_lp = want_lp or any(
             st.request.params.logprobs is not None
             for _, st, _, _ in completing)
-        lengths = np.array(self._lengths)
-        tables = self._tables.copy()
+        a["lengths"][...] = self._lengths
+        a["tables"][...] = self._tables
         n_chunk = sum(take for _, take in chunk_take)
         if sec:
             evt("", tag + "pack", "E", (t, n_chunk, len(self._prefilling)))
         # qmax mirrors the dispatcher: t_flat - b_lanes + 1.
         self._mixed_account(a, t, n_chunk, self._mixed_budget + 1, tag)
-        self._emit("mixed", tables=tables, lengths=lengths, lp=want_lp,
-                   **a)
+        self._emit("mixed", lp=want_lp, **a)
         t0 = time.monotonic()
-        if sec:
-            evt("", tag + "put", "B")
-        args = (self.params, self._cache, self._sampling,
-                jnp.asarray(a["tokens"]), jnp.asarray(a["token_slot"]),
-                jnp.asarray(a["token_pos"]), jnp.asarray(tables),
-                jnp.asarray(a["feed_tokens"]), jnp.asarray(a["feed_active"]),
-                jnp.asarray(lengths), jnp.asarray(a["sample_src"]),
-                jnp.asarray(a["seq_q_start"]), jnp.asarray(a["seq_q_len"]),
-                jnp.asarray(a["seq_pos_start"]), jnp.asarray(a["ov_mask"]),
-                jnp.asarray(a["ov_temp"]), jnp.asarray(a["ov_top_p"]),
-                jnp.asarray(a["ov_top_k"]), jnp.asarray(a["ov_key"]),
-                jnp.asarray(a["ov_bias_ids"]), jnp.asarray(a["ov_bias_vals"]),
-                jnp.asarray(a["ov_sup"]), jnp.asarray(a["ov_min_until"]),
-                jnp.asarray(a["ov_guide"]), jnp.asarray(a["ov_guide_row"]),
+        args = (self.params, self._cache, self._sampling, operands,
                 self._guide_dev)
         if sec:
-            evt("", tag + "put", "E")
             evt("", tag + "dispatch", "B")
+        self.metrics.step_device_calls_total.inc(1, site="step")
         lp_devs = None
         if want_lp:
             ids_dev, clps, lvals, lids, self._cache, self._sampling = \
@@ -8468,30 +8578,39 @@ class InferenceEngine:
 
     def _promote_completing(self, completing, ids, want_lp, lp_host) -> None:
         """Promote sequences whose prompt completed inside a mixed (or
-        spec-mixed) batch: set_slot + registration — the same tail as the
+        spec-mixed) batch: ONE promotion program for all of them
+        (_apply_set_slots), then registration — the same tail as the
         legacy final chunk, minus its extra sample_one dispatch."""
+        if not completing:
+            return
+        rows, payload, firsts = [], [], []
         for slot, st, gid, grow0 in completing:
-            del self._prefilling[slot]
             p = st.request.params
             first = int(ids[slot])
+            grow1 = self.guides.next_row(grow0, first) if gid >= 0 else 0
+            rows.append((slot, p, st.key, True, len(st.ids), gid, grow1))
+            firsts.append(first)
+            if self.dispatcher is not None:
+                payload.append(dict(
+                    slot=slot, temperature=p.temperature, top_p=p.top_p,
+                    top_k=p.top_k, seed=st.seed,
+                    presence=p.presence_penalty,
+                    frequency=p.frequency_penalty,
+                    logit_bias=list(p.logit_bias),
+                    min_tokens=p.min_tokens,
+                    stop_ids=list(p.stop_token_ids),
+                    ignore_eos=p.ignore_eos, num_prompt=len(st.ids),
+                    guide=gid, guide_row=grow1))
+        self._emit("set_slots", rows=payload)
+        self._apply_set_slots(rows, "promote")
+        for (slot, st, gid, _), first in zip(completing, firsts):
+            del self._prefilling[slot]
+            p = st.request.params
             first_lp = None
             if want_lp and p.logprobs is not None and lp_host is not None:
                 clps, lvals, lids = lp_host
                 first_lp = self._lp_entry(clps[slot], lvals[slot],
                                           lids[slot], p.logprobs)
-            grow1 = self.guides.next_row(grow0, first) if gid >= 0 else 0
-            self._emit("set_slot", slot=slot, temperature=p.temperature,
-                       top_p=p.top_p, top_k=p.top_k, seed=st.seed,
-                       presence=p.presence_penalty,
-                       frequency=p.frequency_penalty,
-                       logit_bias=list(p.logit_bias),
-                       min_tokens=p.min_tokens,
-                       stop_ids=list(p.stop_token_ids),
-                       ignore_eos=p.ignore_eos, num_prompt=len(st.ids),
-                       guide=gid, guide_row=grow1)
-            self._apply_set_slot(slot, p, jax.random.fold_in(st.key, 1),
-                                 num_prompt=len(st.ids), guide=gid,
-                                 guide_row=grow1)
             self._register_slot(st.request, slot, first, len(st.ids),
                                 first_lp=first_lp, seed=st.seed)
             # Zero-cost harvest, as in the legacy chunk path: every full
@@ -8528,8 +8647,7 @@ class InferenceEngine:
         evt = self.trace.evt
         if sec:
             evt("", tag + "pack", "B")
-        a = self._mixed_batch_arrays(spec_t + self._mixed_budget)
-        spec_enable = np.zeros((num_slots,), bool)
+        operands, a = self._spec_pack.host()
 
         dec_slots = list(self._slots.keys())
         for slot in dec_slots:
@@ -8545,7 +8663,7 @@ class InferenceEngine:
             a["seq_q_start"][slot] = r0
             a["seq_q_len"][slot] = DK
             a["seq_pos_start"][slot] = self._lengths[slot]
-            spec_enable[slot] = st.spec_ok
+            a["spec_enable"][slot] = st.spec_ok
 
         completing, chunk_take, t = self._fill_chunk_lanes(a, spec_t)
 
@@ -8554,8 +8672,8 @@ class InferenceEngine:
         want_lp = want_lp or any(
             st.request.params.logprobs is not None
             for _, st, _, _ in completing)
-        lengths = np.array(self._lengths)
-        tables = self._tables.copy()
+        a["lengths"][...] = self._lengths
+        a["tables"][...] = self._tables
         n_chunk = sum(take for _, take in chunk_take)
         rows = len(dec_slots) * DK + n_chunk
         if sec:
@@ -8564,28 +8682,14 @@ class InferenceEngine:
         self._mixed_account(a, rows, n_chunk,
                             spec_t + self._mixed_budget - num_slots + 1,
                             tag)
-        self._emit("spec_mixed", tables=tables, lengths=lengths,
-                   lp=want_lp, spec_enable=spec_enable.copy(), **a)
+        self._emit("spec_mixed", lp=want_lp, **a)
         t0 = time.monotonic()
-        if sec:
-            evt("", tag + "put", "B")
         args = (self.params, self._draft_params, self._cache,
-                self._draft_cache, self._sampling,
-                jnp.asarray(a["tokens"]), jnp.asarray(a["token_slot"]),
-                jnp.asarray(a["token_pos"]), jnp.asarray(tables),
-                jnp.asarray(a["feed_tokens"]), jnp.asarray(a["feed_active"]),
-                jnp.asarray(lengths), jnp.asarray(a["sample_src"]),
-                jnp.asarray(a["seq_q_start"]), jnp.asarray(a["seq_q_len"]),
-                jnp.asarray(a["seq_pos_start"]), jnp.asarray(spec_enable),
-                jnp.asarray(a["ov_mask"]), jnp.asarray(a["ov_temp"]),
-                jnp.asarray(a["ov_top_p"]), jnp.asarray(a["ov_top_k"]),
-                jnp.asarray(a["ov_key"]), jnp.asarray(a["ov_bias_ids"]),
-                jnp.asarray(a["ov_bias_vals"]), jnp.asarray(a["ov_sup"]),
-                jnp.asarray(a["ov_min_until"]), jnp.asarray(a["ov_guide"]),
-                jnp.asarray(a["ov_guide_row"]), self._guide_dev)
+                self._draft_cache, self._sampling, operands,
+                self._guide_dev)
         if sec:
-            evt("", tag + "put", "E")
             evt("", tag + "dispatch", "B")
+        self.metrics.step_device_calls_total.inc(1, site="step")
         lp_devs = None
         if want_lp:
             (out_dev, counts_dev, comp_dev, clps, lvals, lids, self._cache,
@@ -8701,20 +8805,25 @@ class InferenceEngine:
             self._alloc.decref(pages)
         self._lengths[slot] = self._park_sentinel()
 
+    def _clear_shaping(self, slot: int, p) -> None:
+        """Re-arm shaped()'s lax.cond fast paths when a slot whose request
+        had penalties, a bias, min_tokens or a guide is freed: a stale row
+        on a FREE slot would keep every future dispatch paying the shaping
+        reads."""
+        if not (p.presence_penalty or p.frequency_penalty or p.logit_bias
+                or p.min_tokens or p.guide is not None):
+            return
+        self._emit("clear_penalties", slot=slot)
+        self.metrics.step_device_calls_total.inc(1, site="clear")
+        self._sampling = self._clear_pen_fn(self._sampling, np.int32(slot))
+
     def _finish(self, slot: int, reason: str) -> None:
         st = self._slots.pop(slot)
         self._release_slot_pages(slot)
         self._free.append(slot)
         self._unpin_guide(st.request)
         p = st.request.params
-        if (p.presence_penalty or p.frequency_penalty or p.logit_bias
-                or p.min_tokens or p.guide is not None):
-            # Re-arm shaped()'s lax.cond fast paths: a stale penalty/bias/
-            # suppression row on a FREE slot would keep every future
-            # dispatch paying the shaping reads.
-            self._emit("clear_penalties", slot=slot)
-            self._sampling = self._clear_pen_fn(self._sampling,
-                                                jnp.asarray(slot, jnp.int32))
+        self._clear_shaping(slot, p)
         gen = st.generated
         # The stop token itself is not part of the output text.
         if reason == "stop" and gen and self._is_stop(st, gen[-1]):

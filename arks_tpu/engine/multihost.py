@@ -326,6 +326,27 @@ class DispatchFollower:
             jnp.asarray(p.get("guide_row", 0), jnp.int32),
             eng._guide_dev)
 
+    @staticmethod
+    def _slot_row(p: dict, sampler_mod) -> tuple:
+        """A ``set_slot`` payload as a row of ``_apply_set_slots``: the
+        base key rebuilt from the seed on the host, folded inside the
+        program as on the leader."""
+        from arks_tpu.engine.types import SamplingParams
+
+        params = SamplingParams(
+            temperature=p["temperature"], top_p=p["top_p"],
+            top_k=p["top_k"],
+            presence_penalty=p.get("presence", 0.0),
+            frequency_penalty=p.get("frequency", 0.0),
+            logit_bias=tuple((int(t), float(b))
+                             for t, b in p.get("logit_bias", ())),
+            min_tokens=p.get("min_tokens", 0),
+            stop_token_ids=tuple(p.get("stop_ids", ())),
+            ignore_eos=p.get("ignore_eos", False))
+        return (p["slot"], params, sampler_mod.np_prng_key(p["seed"]), True,
+                p.get("num_prompt", 0), p.get("guide", -1),
+                p.get("guide_row", 0))
+
     def _apply(self, eng, jax, jnp, op: str, p: dict) -> None:
         from arks_tpu.engine import sampler as sampler_mod
 
@@ -397,25 +418,15 @@ class DispatchFollower:
             eng._cache = eng._insert_fn(
                 eng._cache, jnp.asarray(p["k"]), jnp.asarray(p["v"]),
                 jnp.asarray(p["slot"]))
-        elif op == "set_slot":
-            from arks_tpu.engine.types import SamplingParams
-
-            key = jnp.asarray(sampler_mod.np_prng_key(p["seed"]))
-            params = SamplingParams(
-                temperature=p["temperature"], top_p=p["top_p"],
-                top_k=p["top_k"],
-                presence_penalty=p.get("presence", 0.0),
-                frequency_penalty=p.get("frequency", 0.0),
-                logit_bias=tuple((int(t), float(b))
-                                 for t, b in p.get("logit_bias", ())),
-                min_tokens=p.get("min_tokens", 0),
-                stop_token_ids=tuple(p.get("stop_ids", ())),
-                ignore_eos=p.get("ignore_eos", False))
-            eng._apply_set_slot(p["slot"], params,
-                                self._jax.random.fold_in(key, 1),
-                                num_prompt=p.get("num_prompt", 0),
-                                guide=p.get("guide", -1),
-                                guide_row=p.get("guide_row", 0))
+        elif op in ("set_slot", "set_slots"):
+            # One slot's registration, or all of a step's promotions (and,
+            # with no rows, the warm-up of one compiled size): the same
+            # call of the same program as the leader's.
+            rows = [p] if op == "set_slot" else p["rows"]
+            eng._apply_set_slots(
+                [self._slot_row(r, sampler_mod) for r in rows],
+                "promote" if op == "set_slots" else "admit",
+                size=p.get("size"))
         elif op == "recover":
             # Leader entered fault recovery: log the surviving-request
             # manifest (the streams about to be replayed through ordinary
@@ -509,24 +520,7 @@ class DispatchFollower:
             # lockstep without the guide/seed registries).
             fn = eng._mixed_lp_fn if p.get("lp") else eng._mixed_fn
             out = fn(eng.params, eng._cache, eng._sampling,
-                     jnp.asarray(p["tokens"]), jnp.asarray(p["token_slot"]),
-                     jnp.asarray(p["token_pos"]), jnp.asarray(p["tables"]),
-                     jnp.asarray(p["feed_tokens"]),
-                     jnp.asarray(p["feed_active"]),
-                     jnp.asarray(p["lengths"]),
-                     jnp.asarray(p["sample_src"]),
-                     jnp.asarray(p["seq_q_start"]),
-                     jnp.asarray(p["seq_q_len"]),
-                     jnp.asarray(p["seq_pos_start"]),
-                     jnp.asarray(p["ov_mask"]), jnp.asarray(p["ov_temp"]),
-                     jnp.asarray(p["ov_top_p"]), jnp.asarray(p["ov_top_k"]),
-                     jnp.asarray(p["ov_key"]),
-                     jnp.asarray(p["ov_bias_ids"]),
-                     jnp.asarray(p["ov_bias_vals"]),
-                     jnp.asarray(p["ov_sup"]),
-                     jnp.asarray(p["ov_min_until"]),
-                     jnp.asarray(p["ov_guide"]),
-                     jnp.asarray(p["ov_guide_row"]), eng._guide_dev)
+                     eng._mixed_pack.host_from(p), eng._guide_dev)
             eng._cache, eng._sampling = out[-2], out[-1]
             jax.block_until_ready(out[0])
         elif op == "draft_prefill":
@@ -547,25 +541,7 @@ class DispatchFollower:
                   else eng._spec_mixed_fn)
             out = fn(eng.params, eng._draft_params, eng._cache,
                      eng._draft_cache, eng._sampling,
-                     jnp.asarray(p["tokens"]), jnp.asarray(p["token_slot"]),
-                     jnp.asarray(p["token_pos"]), jnp.asarray(p["tables"]),
-                     jnp.asarray(p["feed_tokens"]),
-                     jnp.asarray(p["feed_active"]),
-                     jnp.asarray(p["lengths"]),
-                     jnp.asarray(p["sample_src"]),
-                     jnp.asarray(p["seq_q_start"]),
-                     jnp.asarray(p["seq_q_len"]),
-                     jnp.asarray(p["seq_pos_start"]),
-                     jnp.asarray(p["spec_enable"]),
-                     jnp.asarray(p["ov_mask"]), jnp.asarray(p["ov_temp"]),
-                     jnp.asarray(p["ov_top_p"]), jnp.asarray(p["ov_top_k"]),
-                     jnp.asarray(p["ov_key"]),
-                     jnp.asarray(p["ov_bias_ids"]),
-                     jnp.asarray(p["ov_bias_vals"]),
-                     jnp.asarray(p["ov_sup"]),
-                     jnp.asarray(p["ov_min_until"]),
-                     jnp.asarray(p["ov_guide"]),
-                     jnp.asarray(p["ov_guide_row"]), eng._guide_dev)
+                     eng._spec_pack.host_from(p), eng._guide_dev)
             eng._cache, eng._draft_cache, eng._sampling = \
                 out[-3], out[-2], out[-1]
             jax.block_until_ready(out[1])

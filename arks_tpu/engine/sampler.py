@@ -194,35 +194,6 @@ def np_suppress_col(stop_ids) -> np.ndarray:
     return col
 
 
-def set_slot(state: SamplingState, slot: int | jnp.ndarray, temperature: float,
-             top_p: float, top_k: int, key: jnp.ndarray,
-             presence: float = 0.0, frequency: float = 0.0,
-             bias_ids=None, bias_vals=None, suppress_ids=None,
-             min_until: int = 0, guide: int = -1,
-             guide_row: int = 0) -> SamplingState:
-    nb = state.bias_ids.shape[1]
-    ns = state.suppress_ids.shape[1]
-    return SamplingState(
-        temperature=state.temperature.at[slot].set(temperature),
-        top_p=state.top_p.at[slot].set(top_p),
-        top_k=state.top_k.at[slot].set(top_k),
-        key=state.key.at[slot].set(key),
-        presence=state.presence.at[slot].set(presence),
-        frequency=state.frequency.at[slot].set(frequency),
-        counts=state.counts.at[slot].set(0),
-        bias_ids=state.bias_ids.at[slot].set(
-            jnp.full((nb,), -1, jnp.int32) if bias_ids is None else bias_ids),
-        bias_vals=state.bias_vals.at[slot].set(
-            jnp.zeros((nb,), jnp.float32) if bias_vals is None else bias_vals),
-        suppress_ids=state.suppress_ids.at[slot].set(
-            jnp.full((ns,), -1, jnp.int32) if suppress_ids is None
-            else suppress_ids),
-        min_until=state.min_until.at[slot].set(min_until),
-        guide=state.guide.at[slot].set(guide),
-        guide_row=state.guide_row.at[slot].set(guide_row),
-    )
-
-
 def transient_state(temperature, top_p, top_k, key,
                     vocab_size: int, bias_ids=None, bias_vals=None,
                     suppress_ids=None, min_first=None, guide=None,
@@ -310,6 +281,27 @@ def set_slots(state: SamplingState, slots: jnp.ndarray, temperature,
         guide_row=state.guide_row.at[slots].set(
             jnp.zeros((m,), jnp.int32) if guide_row is None else guide_row),
     )
+
+
+def promote_slots(state: SamplingState, slots, scalars_f, scalars_i, keys,
+                  fold, bias_ids, bias_vals, suppress_ids) -> SamplingState:
+    """The step loop's slot registration: write M slots' sampling rows in
+    one program whose operands are HOST arrays (the jit's own argument
+    path transfers them; nothing runs eagerly).  ``scalars_f`` [M, 4] =
+    (temperature, top_p, presence, frequency), ``scalars_i`` [M, 4] =
+    (top_k, min_until, guide, guide_row).  ``fold`` [M] marks the rows
+    whose key is the request's BASE key and is advanced here, bit for bit
+    as ``jax.random.fold_in(key, 1)`` (a prompt that just sampled its
+    first token); the others carry a key that is written as it is (a
+    swapped-out slot's snapshot).  Rows whose slot is out of range (the
+    padding up to the compiled size M) write nothing."""
+    folded = jax.vmap(lambda k: jax.random.fold_in(k, 1))(keys)
+    return set_slots(
+        state, slots, scalars_f[:, 0], scalars_f[:, 1], scalars_i[:, 0],
+        jnp.where(fold[:, None], folded, keys), scalars_f[:, 2],
+        scalars_f[:, 3], bias_ids, bias_vals, suppress_ids,
+        min_until=scalars_i[:, 1], guide=scalars_i[:, 2],
+        guide_row=scalars_i[:, 3])
 
 
 def clear_slot_penalties(state: SamplingState,

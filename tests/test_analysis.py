@@ -13,6 +13,8 @@ import subprocess
 import sys
 import time
 
+import pytest
+
 from arks_tpu.analysis import SourceTree, repo_root, run_rules
 from arks_tpu.analysis.baseline import MAX_SUPPRESSIONS, Baseline
 from arks_tpu.analysis.callgraph import CallGraph
@@ -133,6 +135,55 @@ def test_hotpath_contract_flags_missing_tails():
     # the fixture has neither _step_pipelined nor the sync tails
     assert "InferenceEngine._step_pipelined" in contract
     assert any(q.endswith("._resolve_mixed") for q in contract)
+
+
+_SEQ_STEP_FIXTURE = {
+    "arks_tpu/engine/engine.py": (
+        "import jax\n"
+        "import jax.numpy as jnp\n"
+        "import numpy as np\n"
+        "class InferenceEngine:\n"
+        "    def _issue_x(self, tag):\n"
+        "        self.trace.evt('', tag + 'dispatch', 'B')\n"
+        "        self._pack()\n"
+        "        return self._fn(np.zeros(3))\n"
+        "    def _resolve_x(self, tag):\n"
+        "        self.trace.evt('', tag + 'wait', 'B')\n"
+        "        self._promote()\n"
+        "    def _pack(self):\n"
+        "        return jnp.asarray(self.buf)\n"
+        "    def _promote(self):\n"
+        "        k = jax.random.fold_in(self.key, 1)\n"
+        "        return jax.device_put(k), jnp.array([1])\n"
+        "    def _admit(self):\n"
+        "        return jnp.asarray(self.other)\n"
+    ),
+}
+
+
+@pytest.mark.parametrize("qualname,detail", [
+    ("InferenceEngine._pack", "jnp.asarray(self.buf)"),
+    ("InferenceEngine._promote", "jax.random.fold_in(self.key, 1)"),
+    ("InferenceEngine._promote", "jax.device_put(k)"),
+    ("InferenceEngine._promote", "jnp.array([1])"),
+])
+def test_hotpath_flags_eager_device_calls_between_wait_and_dispatch(
+        qualname, detail):
+    """Roots are the methods that open a ``wait`` / ``dispatch`` section —
+    the resolve tail included; what they reach may not call into JAX
+    eagerly."""
+    findings = run_rules(SourceTree(_SEQ_STEP_FIXTURE), ["hotpath"])
+    eager = {(f.qualname, f.detail) for f in findings
+             if f.check == "eager-device-call"}
+    assert (qualname, detail) in eager
+    assert not any(q == "InferenceEngine._admit" for q, _ in eager)
+
+
+def test_hotpath_eager_check_reports_lost_roots():
+    findings = run_rules(SourceTree(_ENGINE_FIXTURE), ["hotpath"])
+    lost = [f.message for f in findings if f.check == "contract"
+            and "eager-device-call has lost its roots" in f.message]
+    assert len(lost) == 2          # neither a wait nor a dispatch section
 
 
 # ----------------------------------------------------------- acceptance

@@ -15,8 +15,9 @@ from arks_tpu.engine import EngineConfig, InferenceEngine, Request, SamplingPara
 from arks_tpu.engine.tokenizer import ByteTokenizer
 from arks_tpu.models import get_config
 
-# One mixed program + its logprob twin, set_slot/clear_penalties state
-# writes, and the handful of single-shape helpers the engine always jits.
+# One mixed program + its logprob twin, the promotion program (one variant
+# a padded size) and clear_penalties state writes, and the handful of
+# single-shape helpers the engine always jits.
 # The point is the ORDER of magnitude: the legacy scheduler's admit family
 # alone is len(buckets) x len(admit_sizes) x 2 programs.
 MIXED_TOTAL_BUDGET = 14
@@ -67,7 +68,10 @@ def test_mixed_workload_compile_variant_budget(monkeypatch):
     total = sum(variants.values())
     assert total <= MIXED_TOTAL_BUDGET, variants
     for name, n in variants.items():
-        assert n <= MIXED_PER_PROGRAM_BUDGET, (name, variants)
+        # The promotion program compiles once a padded size, at warm-up.
+        cap = (len(eng._promote_packs) if name == "_promote_fn"
+               else MIXED_PER_PROGRAM_BUDGET)
+        assert n <= cap, (name, variants)
     # The admit family must not have compiled at all: mixed mode routes
     # every prompt through the chunked path.
     assert variants.get("_admit_fn", 0) == 0, variants

@@ -42,6 +42,14 @@ def test_no_blocking_fetches_on_the_issue_path():
     assert not _errors("blocking-fetch"), _errors("blocking-fetch")
 
 
+def test_no_eager_device_call_between_wait_and_dispatch():
+    """A sequential step's host values are operands of its one program:
+    no ``jnp.asarray`` / ``jnp.array`` / ``jax.device_put`` /
+    ``jax.random.*`` reachable from the functions that open its ``wait``
+    and ``dispatch`` sections."""
+    assert not _errors("eager-device-call"), _errors("eager-device-call")
+
+
 def test_no_sweep_reachable_from_step_loop():
     assert not _errors("autotune-sweep"), _errors("autotune-sweep")
 

@@ -28,7 +28,7 @@ from arks_tpu.models import get_config
 from arks_tpu.obs import profiler as prof_mod
 from arks_tpu.obs.trace import Tracer
 
-SECTIONS = ("retire", "pack", "count", "put", "dispatch", "wait", "fanout",
+SECTIONS = ("retire", "pack", "count", "dispatch", "wait", "fanout",
             "promote")
 
 
