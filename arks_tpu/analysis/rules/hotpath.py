@@ -123,11 +123,6 @@ ROOTS = (
     # like the _resolve_* family.
     ("arks_tpu/engine/residency.py", "ResidencyManager", "_ensure_staged"),
     ("arks_tpu/engine/residency.py", "ResidencyManager", "_span_tables"),
-    # Depth-0 sampler fusion: the fused step's issue half dispatches the
-    # whole token step (forward + sample) in one call and must stay free
-    # of blocking fetches — the host sync belongs to its
-    # _pipe_resolve_one tail alone.
-    (ENGINE, ENGINE_CLASS, "_step_fused"),
     # Elastic resize: the reshard plan builds per-leaf device_put calls
     # from live params at the drained boundary — issue-side by design
     # (survivors are parked on host; a blocking fetch here stretches the

@@ -37,10 +37,9 @@ RULE = "knobs"
 REGISTRY_PATH = "arks_tpu/utils/knobs.py"
 ACCESSORS = {"raw", "get_str", "get_int", "get_float", "get_bool",
              "get_list", "push", "is_registered"}
-# Knobs read by out-of-package surfaces only (bench.py, launch scripts)
-# or exported into runtime containers: exempt from the unused-knob scan.
-EXTERNAL_OK = {"ARKS_BENCH_DRAFT_MODEL",
-               "ARKS_GANG_LEADER_ADDRESS", "ARKS_GANG_SIZE",
+# Knobs read by out-of-package surfaces only (launch scripts) or exported
+# into runtime containers: exempt from the unused-knob scan.
+EXTERNAL_OK = {"ARKS_GANG_LEADER_ADDRESS", "ARKS_GANG_SIZE",
                "ARKS_GANG_WORKER_INDEX",
                # read through a computed name (workloads.
                # default_runtime_image's f-string) — the dynamic-knob-name

@@ -117,8 +117,8 @@ class EngineConfig:
     # kernel).  int4 packs token pairs into one byte (same per-token scale
     # stripes) — half the page bytes again; requires the paged layout
     # (dequant is fused on the mixed kernel's page stream; there is no
-    # int4 slot-cache kernel).  auto = int8 on real TPU (the production
-    # default bench.py measures), engine dtype elsewhere (CPU tests stay
+    # int4 slot-cache kernel).  auto = int8 on real TPU (what the
+    # benchmark's cells run), engine dtype elsewhere (CPU tests stay
     # full-width).
     kv_cache_dtype: str = "auto"
     # "bf16"|"int8"|"int4": weight-only quantization (models.quant).
@@ -129,10 +129,9 @@ class EngineConfig:
     # single-chip, or the freed HBM becomes KV pages.
     weight_dtype: str = "bf16"
     # "auto"|"slot"|"paged": device KV layout.  "paged" = block-table pool
-    # (ops.paged_attention) with zero-copy on-device prefix sharing —
-    # measured FASTER than the slot cache at production shapes
-    # (tools/bench_kernels.py: 0.96x int8 b192, 0.78x bf16 b96) and it
-    # works on multi-host gangs.  "auto" = paged on TPU whenever the
+    # (ops.paged_attention) with zero-copy on-device prefix sharing; the
+    # layout every benchmark cell runs (PERF.md §4), and it works on
+    # multi-host gangs.  "auto" = paged on TPU whenever the
     # engine shape allows (no pp / dp, lane-aligned head_dim,
     # chunk == page alignment); slot elsewhere — the slot layout remains
     # the fallback for those paths.  Speculative decoding REQUIRES paged
@@ -657,20 +656,12 @@ class EngineMetrics:
         self.xla_compile_seconds_total = r.counter(
             "xla_compile_seconds_total",
             "Seconds spent in backend (XLA) compilation")
-        # Ragged-grid padding waste (ops.paged_attention ragged work list):
-        # steps_total counts the page-compute steps the ACTIVE grid mode
-        # executes per mixed dispatch; ideal_total counts the per-sequence
-        # causal minimum (what the ragged work list runs).  Their ratio is
-        # the padding-waste factor — 1.0 under ARKS_MIXED_GRID=ragged,
-        # up to S*num_qb*max_pages/ideal under the dense fallback
-        # (docs/monitoring.md has the alert row).
+        # The page-compute steps the ragged work list runs per mixed
+        # dispatch: each (sequence, q block) item's own causal page count
+        # (ops.paged_attention.build_mixed_work_list).
         self.mixed_grid_steps_total = r.counter(
             "mixed_grid_steps_total",
             "Page-compute grid steps executed by mixed dispatches")
-        self.mixed_grid_steps_ideal_total = r.counter(
-            "mixed_grid_steps_ideal_total",
-            "Per-sequence causal minimum page-compute steps for the same "
-            "mixed dispatches")
         # A latent model's pool traffic and a share's routing, counted on
         # the device inside the step and returned with its token ids (no
         # transfer of their own): latent rows written into the pool (one a
@@ -722,14 +713,8 @@ class EngineMetrics:
         self.residency_prefetch_pages_total = r.counter(
             "residency_prefetch_pages_total",
             "Cold KV pages restored into staging by residency prefetch")
-        self.sampler_fused_dispatch_total = r.counter(
-            "sampler_fused_dispatch_total",
-            "Steady-state decode dispatches issued through the fused "
-            "attention+sampler program (ARKS_SAMPLER_FUSE) with zero "
-            "host-side prep arrays")
         # Scheduler phase breakdown (seconds of engine-thread wall time):
-        # where a serving cycle actually goes — the counters bench_serving
-        # scrapes to attribute throughput loss (admit vs chunk vs decode).
+        # where a serving cycle actually goes (admit vs chunk vs decode).
         self.scheduler_seconds_total = r.counter(
             "scheduler_seconds_total",
             "Engine-thread wall seconds by scheduler phase")
@@ -1075,9 +1060,7 @@ class InferenceEngine:
         # Deferred admissions: issued batches whose first tokens haven't
         # been fetched yet (FIFO).  Resolving lazily (is_ready polling in
         # step) keeps the engine thread issuing decode dispatches instead
-        # of blocking on every admit program's round-trip — the r04 bench
-        # measured 92% of engine wall in blocking admit resolves at
-        # saturation.
+        # of blocking on every admit program's round-trip.
         self._pending_admits: "deque" = deque()
         # Request count across the deque, maintained by the engine thread
         # at every mutation: num_running reads it cross-thread (iterating
@@ -1105,10 +1088,6 @@ class InferenceEngine:
             raise ValueError(
                 f"ARKS_PIPELINE_DEPTH={pipe_depth}: must be >= 0")
         self._pipe_depth_cfg = pipe_depth
-        # Depth-0 sampler fusion (ARKS_SAMPLER_FUSE): steady-state decode
-        # issues the fused attention+sampler pipe program with immediate
-        # resolve instead of the classic host-prepped mixed batch.
-        self._sampler_fuse_cfg = knobs.get_str("ARKS_SAMPLER_FUSE") != "0"
 
         # ---- SLO tiers + preemptive KV swap (ARKS_PREEMPT) -------------
         # Tier ladder (metric labels + admission semantics; arks_tpu.slo)
@@ -1197,7 +1176,7 @@ class InferenceEngine:
         self._switch_t0: dict[str, float] = {}   # first-park time per model
         # Dispatch accounting while a model load is in flight: proves the
         # resident model kept full pipeline depth during the overlap
-        # (bench --workload multi-model asserts on this).
+        # (tests/test_multi_model.py asserts on this).
         self._switch_stats = {"dispatches": 0, "max_depth": 0}
         self.last_switch_stats: dict | None = None
 
@@ -1796,9 +1775,8 @@ class InferenceEngine:
         else:
             self._pipe_rows = (1 if self._mixed
                                else engine_cfg.steps_per_dispatch)
-        # The pipe programs (pipelined decode and depth-0 sampler fusion
-        # dispatch the same ones) serve single-device engines only: a
-        # meshed engine resolves to depth 0 and says so.  They have never
+        # The pipe programs serve single-device engines only: a meshed
+        # engine resolves to depth 0 and says so.  They have never
         # served under a mesh, and turning them on there changes what
         # tp > 1 replicas return (two SPMD compilations of the step round
         # differently; the one four-chip run that compared the greedy
@@ -1806,7 +1784,6 @@ class InferenceEngine:
         # teacher-forced comparison on chips (ROADMAP S7).
         meshed = mesh is not None and mesh.size > 1
         self._pipe_depth = 0 if meshed else self._pipe_depth_cfg
-        self._sampler_fuse = self._sampler_fuse_cfg and not meshed
         if meshed and self._pipe_depth_cfg:
             log.warning("ARKS_PIPELINE_DEPTH=%d: pipelined decode is off "
                         "under a device mesh (%s); this engine runs at "
@@ -1835,11 +1812,9 @@ class InferenceEngine:
 
         # Surface the RESOLVED configuration — the auto decisions, not the
         # requested ones — as an _info gauge and one startup log line, so
-        # bench_serving / Grafana / an operator can tell which perf
-        # envelope this replica actually runs (round-3 verdict: the
-        # kv_layout=auto decision was logged-only and invisible outside).
+        # the benchmark's expect_labels, Grafana and an operator can tell
+        # which path this replica actually runs.
         from arks_tpu.ops import autotune
-        from arks_tpu.ops.paged_attention import mixed_grid_mode
         self._admit_sizes = self._admit_batch_sizes()
         self.resolved_config = {
             "kv_layout": "paged" if self._paged else "slot",
@@ -1857,7 +1832,9 @@ class InferenceEngine:
             "expert_share": f"{cfg.expert_parallel_rank}/"
                             f"{cfg.expert_parallel_size}",
             "kernel_tune": autotune.mode(),
-            "mixed_grid": mixed_grid_mode(),
+            # The one grid the mixed attention call has (the label stays
+            # for the dashboards and deploy files that expect it).
+            "mixed_grid": "ragged",
             "weight_dtype": self.ecfg.weight_dtype or "native",
             "model": self.ecfg.model,
             "mixed_step": str(bool(self._mixed)).lower(),
@@ -2042,9 +2019,7 @@ class InferenceEngine:
             donate_argnums=(0,))
 
         # Fused BATCHED admission: M queued prompts prefill + sample +
-        # insert + set_slot in ONE dispatch.  Under churn admissions were
-        # 71% of engine wall time as single dispatches (bench_serving.py's
-        # scheduler_seconds_total breakdown); batching amortizes the
+        # insert + set_slot in ONE dispatch: batching amortizes the
         # per-dispatch round-trip AND raises prefill MXU utilization.  One
         # compiled program per (bucket, M, lp) combination — M is drawn
         # from _admit_batch_sizes() so the variant count stays bounded.
@@ -3044,13 +3019,12 @@ class InferenceEngine:
                     "kv_layout=paged is incompatible with: "
                     + ", ".join(blockers))
             return True
-        # auto: paged wherever supported — it measured faster than the
-        # slot layout at production shapes and adds on-device prefix
-        # sharing (tools/bench_kernels.py).  CPU stays on the slot layout
-        # (interpret-mode kernels are test-only) EXCEPT for draft engines:
-        # speculation requires the mixed scheduler, whose CPU path runs
-        # the XLA oracle — resolving slot there would turn a valid spec
-        # config into an init error.
+        # auto: paged wherever supported — the layout every benchmark
+        # cell runs, with on-device prefix sharing.  CPU stays on the slot
+        # layout (interpret-mode kernels are test-only) EXCEPT for draft
+        # engines: speculation requires the mixed scheduler, whose CPU path
+        # runs the XLA oracle — resolving slot there would turn a valid
+        # spec config into an init error.
         if blockers:
             if int4:
                 raise ValueError(
@@ -3761,16 +3735,6 @@ class InferenceEngine:
             td = time.monotonic()
             self.metrics.scheduler_seconds_total.inc(td - t0, phase="decode")
             t0 = td
-        if self._fuse_ready():
-            # Depth-0 sampler fusion: steady-state pure decode rides the
-            # fused attention+sampler program with an immediate resolve —
-            # one device program per step, no host-side sampler prep.
-            if sec:
-                self.trace.evt("", "phase.step.head", "E")
-            self._step_fused()
-            self.metrics.scheduler_seconds_total.inc(
-                time.monotonic() - t0, phase="mixed")
-            return True
         if self._residency_active():
             # Windowed-residency slots: span-by-span decode on the host
             # loop (cold pages stream through staging while resident
@@ -5999,8 +5963,8 @@ class InferenceEngine:
         resident = not isinstance(got, LoadTicket)
         if not resident:
             if target not in self._model_loads:
-                # Fresh load kicked: reset the overlap accounting the
-                # bench asserts on (full depth during the load window).
+                # Fresh load kicked: reset the overlap accounting (full
+                # depth during the load window).
                 self._model_loads[target] = got
                 self._switch_t0.setdefault(target, got.t0)
                 self._switch_stats = {"dispatches": 0, "max_depth": 0}
@@ -7484,33 +7448,11 @@ class InferenceEngine:
         publishes, which the admission check below then catches."""
         if not self._pipe_depth:
             return False
-        return self._steady_ready()
-
-    def _fuse_ready(self) -> bool:
-        """Depth-0 sampler fusion (ARKS_SAMPLER_FUSE): a steady-state
-        pure-decode iteration issues the fused attention+sampler pipe
-        program (count_tokens -> mixed_step -> sample -> liveness, one
-        device program, ZERO host-side prep arrays) and resolves it
-        immediately, instead of packing the classic ~20-array mixed
-        batch.  Shares the pipelined path's readiness gates exactly —
-        anything host-side (prefill chunks, transient first-token
-        override columns, admissions, aborts, oversized stop sets)
-        falls back to the classic _issue_mixed/_resolve_mixed pair, as
-        do speculative engines (their spec-mixed dispatch carries
-        per-slot verify blocks the fused columns don't)."""
-        if self._pipe_depth or not self._sampler_fuse or not self._mixed:
-            return False
-        if self._draft_cfg is not None:
-            return False
-        return self._steady_ready()
-
-    def _steady_ready(self) -> bool:
-        """Shared steady-state gate of the pipelined and fused paths."""
         if not self._slots:
             return False
         if self._residency_active():
             # Windowed-residency slots decode span-by-span on the host
-            # loop — neither steady-state device program covers them.
+            # loop — the pipe programs do not cover them.
             return False
         if self._prefilling or self._pending_admits:
             return False
@@ -7667,13 +7609,8 @@ class InferenceEngine:
 
     def _pipe_kick_warmup(self) -> None:
         """Start the one-shot background compile of both pipe-program
-        variants (with/without logprobs).  Idempotent; engine-thread.
-        Depth-0 engines warm them too when sampler fusion is on — the
-        fused path dispatches the same programs."""
-        fuse = (self._sampler_fuse and self._mixed
-                and self._draft_cfg is None)
-        if self._pipe_warm_state is not None or not (self._pipe_depth
-                                                     or fuse):
+        variants (with/without logprobs).  Idempotent; engine-thread."""
+        if self._pipe_warm_state is not None or not self._pipe_depth:
             return
         self._pipe_warm_state = "compiling"
         sig = self._pipe_signature()
@@ -7739,25 +7676,6 @@ class InferenceEngine:
         if self._spills:
             # Harvest landed spill gathers (steady-state evictions come
             # from _pipe_issue's page growth); ready-only, never blocks.
-            self._resolve_spills()
-
-    @_scoped("mixed")
-    def _step_fused(self) -> None:
-        """One depth-0 fused iteration (ARKS_SAMPLER_FUSE): issue the
-        attention+sampler pipe program FRESH from the host mirrors and
-        resolve it immediately.  The host stays authoritative — the
-        threaded device state is dropped after every resolve, so the
-        fused path is the classic sequential loop with the host-side
-        sampler prep folded into the dispatch, not a hidden pipeline."""
-        self._pipe_issue()
-        if self._pipe_inflight:
-            self.metrics.sampler_fused_dispatch_total.inc()
-            self._pipe_resolve_one()
-        self._pipe_state = None
-        self._pipe_cols = None
-        self._pipe_cols_np = None
-        self._pipe_last_resolve = None
-        if self._spills:
             self._resolve_spills()
 
     def _pipe_issue(self) -> None:
@@ -7876,8 +7794,8 @@ class InferenceEngine:
         if self._model_loads:
             # Dispatch accounting for the switch-overlap claim: decode
             # dispatches issued while another model's weights stream, and
-            # the pipeline depth they sustained (the multi-model bench
-            # asserts full depth — plain host counters, no device sync).
+            # the pipeline depth they sustained (plain host counters, no
+            # device sync).
             self._switch_stats["dispatches"] += 1
             if len(self._pipe_inflight) > self._switch_stats["max_depth"]:
                 self._switch_stats["max_depth"] = len(self._pipe_inflight)
@@ -8057,10 +7975,9 @@ class InferenceEngine:
         self._faults.fire("resolve")
         t_wait = time.monotonic()
         toks = np.asarray(toks)  # [K, B] — host sync point
-        # Pure device-stream wait, free of overlapped host work: the
-        # trustworthy device-bound signal for bench_serving's attribution
-        # (the phase-seconds breakdown attributes WALL time, which in
-        # overlap mode can land waits in whichever phase fetches first).
+        # Pure device-stream wait, free of overlapped host work (the
+        # phase-seconds breakdown attributes WALL time, which in overlap
+        # mode can land waits in whichever phase fetches first).
         self.metrics.decode_resolve_wait_seconds_total.inc(
             time.monotonic() - t_wait, mode="sequential")
         if lp_devs is not None:
@@ -8256,13 +8173,13 @@ class InferenceEngine:
         return completing, chunk_take, t
 
     def _mixed_grid_counters(self, pos_start, q_len, qmax: int) -> None:
-        """Account the padding-waste counter pair for one mixed dispatch:
-        mixed_grid_steps_total (what the active grid mode executes) and
-        mixed_grid_steps_ideal_total (the per-sequence causal minimum),
-        and mixed_q_layout_rows_total (the query rows the plan lays out
-        for the kernel, whatever the batch holds).
+        """Account one mixed dispatch's plan counters:
+        mixed_grid_steps_total (the page-compute steps the work list
+        runs), mixed_q_layout_rows_total (the query rows the plan lays out
+        for the kernel, whatever the batch holds) and the
+        mixed_kv_bytes pair.
         The counters describe the grid PLAN — they are meaningful under
-        either attention impl, which is what lets the sparse-batch waste
+        either attention impl, which is what lets the sparse-batch
         test run on the XLA oracle.  Inputs are the host-side numpy batch
         arrays — no device fetches here (hot-path guard covers this)."""
         plan = self._grid_plans.get(qmax)
@@ -8277,13 +8194,10 @@ class InferenceEngine:
                 page=self._page_size(), kv=kv, lanes=pos_start.shape[0])
             self._grid_plans[qmax] = plan
         from arks_tpu.engine.paged import mixed_grid_steps, mixed_kv_bytes
-        ideal, dense = mixed_grid_steps(
+        self.metrics.mixed_grid_steps_total.inc(mixed_grid_steps(
             pos_start, q_len, page=self._page_size(),
             block_q=plan["block_q"], num_qb=plan["num_qb"],
-            max_pages=self._max_pages)
-        actual = ideal if plan["grid"] == "ragged" else dense
-        self.metrics.mixed_grid_steps_total.inc(actual)
-        self.metrics.mixed_grid_steps_ideal_total.inc(ideal)
+            max_pages=self._max_pages))
         self.metrics.mixed_q_layout_rows_total.inc(plan["q_rows"])
         b_actual, b_ideal = mixed_kv_bytes(
             pos_start, q_len, page=self._page_size(),
@@ -8351,9 +8265,6 @@ class InferenceEngine:
                        "in the AKV1 format)")
         if knobs.get_str("ARKS_MIXED_STEP") == "0":
             why.append("ARKS_MIXED_STEP=0 (the legacy scheduler)")
-        if (knobs.raw("ARKS_MIXED_GRID") or "ragged").lower() != "ragged":
-            why.append("ARKS_MIXED_GRID=dense (the latent kernel is the "
-                       "ragged work-list grid)")
         if why:
             raise ValueError(
                 f"model {cfg.name!r} (latent attention, one latent row a "

@@ -27,7 +27,7 @@ Invariance contracts (the hard gates for any scheduler change):
 - replay/swap-resume entries (priority < 0) ride a separate urgent heap
   served before everything, exempt from bounds, fairness, and aging —
   they were already decoding before their fault/preemption;
-- ``ARKS_FAIR=0`` degrades to the old flat priority heap (the bench
+- ``ARKS_FAIR=0`` degrades to the old flat priority heap (the tests'
   control arm), bounds still enforceable;
 - engine-internal re-queues (fault survivors, preempt replay, guide /
   model unparks) use unbounded ``put`` — a request the engine already

@@ -136,7 +136,7 @@ class FaultInjector:
 
     def arm(self, entry: str) -> None:
         """Add one ``phase:nth:kind`` spec (env parsing and the
-        bench/chaos harness's programmatic injection)."""
+        chaos harness's programmatic injection)."""
         entry = entry.strip()
         if not entry:
             return

@@ -74,17 +74,13 @@ def pages_needed(length: int, rows: int, page: int, max_pages: int) -> int:
 
 
 def mixed_grid_steps(pos_start, q_len, *, page: int, block_q: int,
-                     num_qb: int, max_pages: int) -> tuple[int, int]:
-    """(ideal, dense) page-compute step counts for one mixed dispatch —
-    the host-side numpy mirror of ops.paged_attention.build_mixed_work_list.
-
-    ``ideal`` is what the ragged work-list grid executes: each active
+                     num_qb: int, max_pages: int) -> int:
+    """Page-compute steps of one mixed dispatch — the host-side numpy
+    mirror of ops.paged_attention.build_mixed_work_list: each active
     (seq, q_block) item visits exactly its own causal page count, q_len=0
-    lanes and padding items visit zero.  ``dense`` is the legacy grid's
-    S * num_qb * max_pages (every lane pays the worst case).  The counter
-    pair metrics these feed (mixed_grid_steps_total vs _ideal_total)
-    describes the grid PLAN, so it is meaningful under either
-    ARKS_MIXED_GRID mode and either attention impl.
+    lanes and padding items visit zero.  The counter it feeds
+    (mixed_grid_steps_total) describes the grid PLAN, so it reads the same
+    under either attention impl.
 
     Inputs must already be host numpy arrays (the engine's issue path
     holds them that way) — no device fetches happen here; the hot-path
@@ -95,10 +91,7 @@ def mixed_grid_steps(pos_start, q_len, *, page: int, block_q: int,
     active = q_lo < ql[:, None]
     kv_end = np.where(active, pos[:, None] + np.minimum(q_lo + block_q,
                                                         ql[:, None]), 0)
-    pages = np.minimum(-(-kv_end // page), max_pages)
-    ideal = int(pages.sum())
-    dense = int(pos.shape[0]) * num_qb * max_pages
-    return ideal, dense
+    return int(np.minimum(-(-kv_end // page), max_pages).sum())
 
 
 def mixed_kv_bytes(pos_start, q_len, *, page: int, block_q: int,
@@ -124,14 +117,11 @@ def mixed_kv_bytes(pos_start, q_len, *, page: int, block_q: int,
     ``page_head_bytes``: bytes one (page, head) KV block moves — K + V
     (+ scale rows when quantized); the engine derives it from the pool
     dtypes so int4 packing halves it automatically."""
+    actual = mixed_grid_steps(
+        pos_start, q_len, page=page, block_q=block_q, num_qb=num_qb,
+        max_pages=max_pages) * hkv * page_head_bytes
     pos = pos_start.astype(np.int64, copy=False)
     ql = q_len.astype(np.int64, copy=False)
-    q_lo = (np.arange(num_qb, dtype=np.int64) * block_q)[None, :]
-    active = q_lo < ql[:, None]
-    kv_end = np.where(active, pos[:, None] + np.minimum(q_lo + block_q,
-                                                        ql[:, None]), 0)
-    pages = np.minimum(-(-kv_end // page), max_pages)
-    actual = int(pages.sum()) * hkv * page_head_bytes
     seq_end = np.where(ql > 0, pos + ql, 0)
     seq_pages = np.minimum(-(-seq_end // page), max_pages)
     ideal = int(seq_pages.sum()) * hkv * page_head_bytes

@@ -338,7 +338,7 @@ class Gateway:
         class Server(ThreadingHTTPServer):
             # Absorb connection bursts (hundreds of concurrent clients
             # reconnecting at once): the default backlog of 5 makes the
-            # kernel RST the overflow (measured in tools/bench_gateway.py).
+            # kernel RST the overflow.
             request_queue_size = 512
             daemon_threads = True
 

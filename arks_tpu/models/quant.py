@@ -220,8 +220,9 @@ def init_params_quantized(cfg, key, dtype=jnp.bfloat16, bits: int = 8,
     ones norms, zeros biases) but generates + quantizes each leaf inside its
     own jit, so peak device memory is the quantized tree plus ONE
     full-width leaf — a bf16 init of a 7B model (~15 GB) would not even fit
-    the chip that the quantized model is for.  Used by bench.py and
-    anywhere random weights of an HBM-limited model are needed.
+    the chip that the quantized model is for.  Used by the server's
+    random-weight path (the benchmark's configurations) and anywhere
+    random weights of an HBM-limited model are needed.
     ``bits=4`` = w4a16 (matmul weights int4 groupwise, embedding int8).
     """
     import functools

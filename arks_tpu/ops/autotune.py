@@ -16,8 +16,8 @@ Modes (``ARKS_KERNEL_TUNE``):
                this is exactly ``off``, so fresh deployments stay
                byte-identical until an operator opts into a sweep.
 - ``sweep``  — like ``cached``, but a missing entry triggers a benchmark
-               sweep at warm-up (InferenceEngine.__init__ /
-               bench.py) and persists the winner.
+               sweep at warm-up (InferenceEngine.__init__) and persists
+               the winner.
 
 The split between :func:`lookup` (pure dict read, allowed at kernel trace
 time and on the engine issue path) and :func:`ensure` (may sweep — warm-up

@@ -37,7 +37,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from arks_tpu.utils import knobs
 
 _NEG_INF = -1e30
 
@@ -99,7 +98,7 @@ def unpack_int4_pool(pool: jnp.ndarray) -> jnp.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# Mixed-grid planning: block sizes, q padding, grid mode
+# Mixed-grid planning: block sizes, q padding
 # ---------------------------------------------------------------------------
 
 
@@ -110,25 +109,14 @@ def pool_kv_name(k_pool: jnp.ndarray, k_scale: jnp.ndarray | None) -> str:
     return "int4" if is_int4_pool(k_pool, k_scale) else "int8"
 
 
-def mixed_grid_mode() -> str:
-    """ARKS_MIXED_GRID: 'ragged' (work-list grid, default) | 'dense' (the
-    legacy (S, num_qb, max_pages) grid, kept as the byte-identity
-    reference and fallback)."""
-    m = (knobs.raw("ARKS_MIXED_GRID") or "ragged").lower()
-    if m not in ("ragged", "dense"):
-        raise ValueError(f"ARKS_MIXED_GRID={m!r} (expected ragged|dense)")
-    return m
-
-
 def mixed_grid_plan(qmax: int, *, hkv: int, g: int, d: int, page: int,
                     kv: str, block_q: int | None = None,
-                    grid: str | None = None,
                     dma_depth: int | None = None,
                     head_group: int | None = None,
                     lanes: int | None = None) -> dict:
     """Resolve the mixed kernel's static launch parameters — ONE place, so
-    the kernel wrapper, the engine's grid-step counters, and bench.py can
-    never disagree on what actually launches.
+    the kernel wrapper and the engine's grid-step counters can never
+    disagree on what actually launches.
 
     ``qmax`` is the widest query span one lane can have: the per-lane
     block's Q for :func:`paged_mixed_attention`, ``t_flat - lanes + 1``
@@ -146,17 +134,16 @@ def mixed_grid_plan(qmax: int, *, hkv: int, g: int, d: int, page: int,
     single item's KV and accumulator VMEM footprint by hkv/head_group,
     which is what lets a tuned entry raise block_q — fewer q-blocks means
     each causal page prefix is re-streamed fewer times, which is where
-    the GQA bytes-moved win actually comes from.  Only the ragged grid
-    understands grouping; invalid divisors fall back to hkv rather than
-    raising so stale tune tables can never break a launch.
+    the GQA bytes-moved win actually comes from.  Invalid divisors fall
+    back to hkv rather than raising so stale tune tables can never break a
+    launch.
 
     With ``lanes`` (the flat batch's lane count) the plan also carries the
     block-compacted query layout's size: ``nb``, the static bound on real
     (lane, q_block) pairs — every lane has at most ceil(q_len / block_q)
     blocks and the lanes share ``lanes + qmax - 1`` rows, so
     ``nb = lanes + ceil((qmax - 1) / block_q)`` — and ``q_rows``, the
-    query rows one dispatch lays out for the kernel (``nb * block_q``;
-    ``lanes * qpad`` under the dense grid's per-lane layout)."""
+    query rows one dispatch lays out for the kernel (``nb * block_q``)."""
     from arks_tpu.ops import autotune
 
     qmax = max(int(qmax), 1)
@@ -176,15 +163,12 @@ def mixed_grid_plan(qmax: int, *, hkv: int, g: int, d: int, page: int,
     if dma_depth is None:
         dma_depth = int(tuned.get("dma_depth", 0)) or 2
     dma_depth = max(2, int(dma_depth))
-    if grid is None:
-        grid = mixed_grid_mode()
     qpad = -(-qmax // block_q) * block_q
     plan = dict(block_q=block_q, qpad=qpad, num_qb=qpad // block_q,
-                dma_depth=dma_depth, grid=grid, head_group=head_group)
+                dma_depth=dma_depth, head_group=head_group)
     if lanes is not None:
         nb = int(lanes) + -(-(qmax - 1) // block_q)
-        plan.update(nb=nb, q_rows=nb * block_q if grid == "ragged"
-                    else int(lanes) * qpad)
+        plan.update(nb=nb, q_rows=nb * block_q)
     return plan
 
 
@@ -749,11 +733,10 @@ def _group_heads(stripe: jnp.ndarray, h0, head_group: int) -> jnp.ndarray:
 def _mixed_softmax_block(q_ref, kbuf, vbuf, ksbuf, vsbuf, m_ref, l_ref,
                          acc_ref, buf, si, pos0, q_lo, *, page, scale,
                          quantized, int4, h0=None):
-    """One page of online-softmax accumulation — the SHARED compute body of
-    the dense and ragged mixed kernels, so byte-identity between the two
-    grids is structural, not coincidental.  ``h0`` (grouped ragged items
-    only) is the first KV head of the item's group inside the scale
-    buffers, which always hold the page's whole head stripe."""
+    """One page of online-softmax accumulation, the compute body of the
+    ragged mixed kernel.  ``h0`` (grouped items only) is the first KV head
+    of the item's group inside the scale buffers, which always hold the
+    page's whole head stripe."""
     _, hkv, g, bq, d = q_ref.shape
     q = q_ref[0].reshape(hkv, g * bq, d)
     kt = kbuf[buf]
@@ -798,92 +781,6 @@ def _mixed_softmax_block(q_ref, kbuf, vbuf, ksbuf, vsbuf, m_ref, l_ref,
     l_ref[:] = l_next
 
 
-def _paged_mixed_kernel(layer_ref, tables_ref, pos_start_ref, qlen_ref,
-                        q_ref, kpool, vpool, *rest,
-                        page: int, block_q: int, scale: float,
-                        quantized: bool, int4: bool):
-    """DENSE grid: one SEQUENCE per grid row, ``block_q`` queries per
-    q-block, pages on the innermost axis — (S, num_qb, max_pages) grid
-    steps regardless of how much of the batch is real.  Kept as the
-    byte-identity reference and ARKS_MIXED_GRID=dense fallback; the
-    ragged work-list kernel below is the default.  Query i of sequence s
-    sits at global position pos_start[s]+i and attends cache positions
-    [0, pos_start[s]+i] (write-then-attend as everywhere).  Pages wholly
-    past a q-block's causal end are masked off with pl.when."""
-    if quantized:
-        kspool, vspool, o_ref, kbuf, vbuf, ksbuf, vsbuf, m_ref, l_ref, \
-            acc_ref, sem = rest
-    else:
-        o_ref, kbuf, vbuf, m_ref, l_ref, acc_ref, sem = rest
-        kspool = vspool = ksbuf = vsbuf = None
-    s_i = pl.program_id(0)
-    qb = pl.program_id(1)
-    si = pl.program_id(2)
-    num_pages = pl.num_programs(2)
-    lyr = layer_ref[0]
-    pos0 = pos_start_ref[s_i]
-    qlen = qlen_ref[s_i]
-    q_lo = qb * block_q
-    # KV positions this q-block can causally see end just past its last
-    # VALID query; empty blocks (q_lo >= qlen) see nothing.
-    kv_end = jnp.where(q_lo < qlen,
-                       pos0 + jnp.minimum(q_lo + block_q, qlen), 0)
-
-    def start_copies(page_i, buf):
-        pg = tables_ref[s_i, page_i]
-        pltpu.make_async_copy(kpool.at[lyr, pg], kbuf.at[buf],
-                              sem.at[0, buf]).start()
-        pltpu.make_async_copy(vpool.at[lyr, pg], vbuf.at[buf],
-                              sem.at[1, buf]).start()
-        if quantized:
-            pltpu.make_async_copy(kspool.at[lyr, pg], ksbuf.at[buf],
-                                  sem.at[2, buf]).start()
-            pltpu.make_async_copy(vspool.at[lyr, pg], vsbuf.at[buf],
-                                  sem.at[3, buf]).start()
-
-    def wait_copies(buf):
-        pltpu.make_async_copy(kpool.at[lyr, 0], kbuf.at[buf],
-                              sem.at[0, buf]).wait()
-        pltpu.make_async_copy(vpool.at[lyr, 0], vbuf.at[buf],
-                              sem.at[1, buf]).wait()
-        if quantized:
-            pltpu.make_async_copy(kspool.at[lyr, 0], ksbuf.at[buf],
-                                  sem.at[2, buf]).wait()
-            pltpu.make_async_copy(vspool.at[lyr, 0], vsbuf.at[buf],
-                                  sem.at[3, buf]).wait()
-
-    @pl.when(si == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-        @pl.when(kv_end > 0)
-        def _():
-            start_copies(0, 0)
-
-    valid = si * page < kv_end
-
-    # Double buffering: kick page si+1's copies before computing page si.
-    @pl.when(valid & ((si + 1) * page < kv_end))
-    def _prefetch():
-        start_copies(si + 1, (si + 1) % 2)
-
-    @pl.when(valid)
-    def _block():
-        buf = si % 2
-        wait_copies(buf)
-        _mixed_softmax_block(q_ref, kbuf, vbuf, ksbuf, vsbuf, m_ref, l_ref,
-                             acc_ref, buf, si, pos0, q_lo, page=page,
-                             scale=scale, quantized=quantized, int4=int4)
-
-    @pl.when(si == num_pages - 1)
-    def _finish():
-        _, hkv, g, bq, d = q_ref.shape
-        out = acc_ref[:] / (l_ref[..., :1] + 1e-9)
-        o_ref[:] = out.reshape(1, hkv, g, bq, d).astype(o_ref.dtype)
-
-
 def _paged_mixed_ragged_kernel(layer_ref, tables_ref, pos_start_ref,
                                wl_seq_ref, wl_hg_ref, wl_qb_ref,
                                wl_plo_ref, wl_pages_ref, wl_blk_ref,
@@ -916,9 +813,9 @@ def _paged_mixed_ragged_kernel(layer_ref, tables_ref, pos_start_ref,
     exact — the per-page update sequence is identical and the final
     acc/(l+eps) division happens exactly once, on the last span.
 
-    DMAs are ``depth``-way multi-buffered (depth=2 reduces exactly to the
-    dense kernel's double buffering; the accumulation order is identical
-    for any depth, so tuned depths preserve byte identity).
+    DMAs are ``depth``-way multi-buffered (depth=2 is double buffering;
+    the accumulation order is identical for any depth, so tuned depths
+    preserve byte identity).
 
     ``latent``: the pool is ONE array of latent rows (Hkv = 1, every head
     of the model a query row of the one group): there is no value pool,
@@ -1084,7 +981,7 @@ def _ragged_launch(qp, k_pool, v_pool, tables32, pos32, work_list, layer,
     - ``compact``: ``[NB, Hkv, G, block_q, D]``, block ``blk[i]`` — one
       block per real (lane, q_block) pair, the flat batch's layout;
     - per lane: ``[S, Hkv, G, qpad, D]``, block ``(seq[i], qb[i])`` — the
-      dense block a caller of :func:`paged_mixed_attention` brings.
+      block a caller of :func:`paged_mixed_attention` brings.
 
     The output (or, with ``emit_state``, the raw f32 m / l / acc) comes
     back in the layout ``qp`` has; ``carry_state`` is read through the
@@ -1182,87 +1079,41 @@ def _ragged_launch(qp, k_pool, v_pool, tables32, pos32, work_list, layer,
           *work_list, qp, *pools, *scale_inputs, *carry_inputs)
 
 
-@functools.partial(jax.jit, static_argnames=("block_q", "interpret", "grid",
+@functools.partial(jax.jit, static_argnames=("block_q", "interpret",
                                              "dma_depth", "head_group",
                                              "emit_state"))
 def _paged_mixed_call(q, k_pool, v_pool, tables, pos_start, q_len, layer,
                       k_scale, v_scale, page_lo=None, page_hi=None,
                       carry_state=None, *, block_q: int, dma_depth: int,
-                      grid: str, interpret: bool, head_group: int,
-                      emit_state: bool):
+                      interpret: bool, head_group: int, emit_state: bool):
     """Jitted mixed-attention launch over a per-lane ``[S, Hkv, G, Q, D]``
     query block, with FULLY RESOLVED statics — the public wrapper resolves
-    the plan (env + autotune) per call so flipping ARKS_MIXED_GRID / the
-    tune table between calls can never hit a stale jit cache entry keyed
-    on unresolved defaults.  The q axis is padded to the plan's q blocks
-    here and sliced back; the ragged grid visits the lanes' real blocks
-    through the per-lane index map of :func:`_ragged_launch` (the flat
-    batch's block-compacted layout is :func:`_paged_mixed_flat_call`)."""
-    s, hkv, g, qmax, d = q.shape
-    quantized = k_scale is not None
-    int4 = is_int4_pool(k_pool, k_scale)
+    the plan (autotune) per call so changing the tune table between calls
+    can never hit a stale jit cache entry keyed on unresolved defaults.
+    The q axis is padded to the plan's q blocks here and sliced back; the
+    grid visits the lanes' real blocks through the per-lane index map of
+    :func:`_ragged_launch` (the flat batch's block-compacted layout is
+    :func:`_paged_mixed_flat_call`)."""
+    _, hkv, _, qmax, _ = q.shape
     page = pool_page_tokens(k_pool, k_scale)
-    max_pages = tables.shape[1]
-    carry = carry_state is not None
-    if grid == "dense" and (head_group != hkv or carry or emit_state
-                            or page_lo is not None or page_hi is not None):
-        raise ValueError(
-            "head grouping / span bounds / carried state need the ragged "
-            "work-list grid (ARKS_MIXED_GRID=ragged); the dense grid is "
-            "the legacy byte-identity reference only")
     qpad = -(-qmax // block_q) * block_q
-    num_qb = qpad // block_q
     qp = q if qpad == qmax else jnp.pad(
         q, ((0, 0), (0, 0), (0, 0), (0, qpad - qmax), (0, 0)))
     tables32 = tables.astype(jnp.int32)
     pos32 = pos_start.astype(jnp.int32)
     qlen32 = q_len.astype(jnp.int32)
-
-    if grid == "dense":
-        def q_map(s_i, qb, si, *prefetch):
-            del si, prefetch
-            return (s_i, 0, 0, qb, 0)
-
-        scale_inputs = [k_scale, v_scale] if quantized else []
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,  # layer, tables, pos_start, q_len
-            grid=(s, num_qb, max_pages),
-            in_specs=[pl.BlockSpec((1, hkv, g, block_q, d), q_map)]
-            # Pools and scale stripes stay in HBM (manual DMA).
-            + [pl.BlockSpec(memory_space=pl.ANY)] * (2 + len(scale_inputs)),
-            out_specs=pl.BlockSpec((1, hkv, g, block_q, d), q_map),
-            scratch_shapes=_mixed_scratch(
-                k_pool, v_pool, nbuf=2, head_group=hkv, hkv=hkv, g=g, d=d,
-                page=page, block_q=block_q, quantized=quantized),
-        )
-        kernel = functools.partial(_paged_mixed_kernel, page=page,
-                                   block_q=block_q, scale=1.0 / (d ** 0.5),
-                                   quantized=quantized, int4=int4)
-        with jax.named_scope("arks.attn_kernel"):
-            out = pl.pallas_call(
-                kernel,
-                grid_spec=grid_spec,
-                out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
-                compiler_params=pltpu.CompilerParams(
-                    dimension_semantics=("parallel", "arbitrary",
-                                         "arbitrary")),
-                interpret=interpret,
-                name="paged_mixed_attention_dense",
-            )(jnp.asarray(layer, jnp.int32).reshape(1), tables32, pos32,
-              qlen32, qp, k_pool, v_pool, *scale_inputs)
-    else:
-        work_list = build_mixed_work_list(
-            pos32, qlen32, page=page, block_q=block_q, num_qb=num_qb,
-            max_pages=max_pages, head_groups=hkv // head_group,
-            page_lo=page_lo, page_hi=page_hi)
-        out = _ragged_launch(
-            qp, k_pool, v_pool, tables32, pos32, work_list, layer, k_scale,
-            v_scale, carry_state, compact=False, block_q=block_q,
-            dma_depth=dma_depth, interpret=interpret, head_group=head_group,
-            emit_state=emit_state)
-    # Rows past q_len[s] are undefined (dense: skipped blocks; ragged:
-    # never-visited items) — zero them so both grids return IDENTICAL
-    # bytes everywhere, not just on the rows callers keep.
+    work_list = build_mixed_work_list(
+        pos32, qlen32, page=page, block_q=block_q, num_qb=qpad // block_q,
+        max_pages=tables.shape[1], head_groups=hkv // head_group,
+        page_lo=page_lo, page_hi=page_hi)
+    out = _ragged_launch(
+        qp, k_pool, v_pool, tables32, pos32, work_list, layer, k_scale,
+        v_scale, carry_state, compact=False, block_q=block_q,
+        dma_depth=dma_depth, interpret=interpret, head_group=head_group,
+        emit_state=emit_state)
+    # Rows past q_len[s] are undefined (never-visited items) — zero them
+    # so the call returns the same bytes everywhere, not just on the rows
+    # callers keep.
     if emit_state:
         m, l, a = out
         validp = (jnp.arange(qpad, dtype=jnp.int32)[None, :]
@@ -1335,7 +1186,6 @@ def paged_mixed_attention(
     v_scale: jnp.ndarray | None = None,
     block_q: int | None = None,
     interpret: bool = False,
-    grid: str | None = None,        # "ragged" | "dense" | None (env)
     dma_depth: int | None = None,
     head_group: int | None = None,  # KV heads per work item (None = tuned)
     page_lo: jnp.ndarray | None = None,   # [S] span start (pages)
@@ -1347,9 +1197,8 @@ def paged_mixed_attention(
     attends its table pages over positions [0, pos_start[s]+i].  Rows past
     q_len[s] are zeroed — the ONE kernel serving decode lanes (q_len=1),
     prefill chunks, and spec verify rows (q_len=K) in a single dispatch.
-    The plan (block_q via autotune, grid mode via ARKS_MIXED_GRID, DMA
-    depth, GQA head grouping) is resolved HERE, outside jit, then passed
-    as statics.
+    The plan (block_q via autotune, DMA depth, GQA head grouping) is
+    resolved HERE, outside jit, then passed as statics.
 
     Span-bounded calls (page_lo/page_hi + carry_state/emit_state) chain
     the online-softmax state across page ranges — the windowed-residency
@@ -1362,14 +1211,14 @@ def paged_mixed_attention(
     plan = mixed_grid_plan(qmax, hkv=hkv, g=g, d=d,
                            page=pool_page_tokens(k_pool, k_scale),
                            kv=pool_kv_name(k_pool, k_scale),
-                           block_q=block_q, grid=grid, dma_depth=dma_depth,
+                           block_q=block_q, dma_depth=dma_depth,
                            head_group=head_group)
     return _paged_mixed_call(q, k_pool, v_pool, tables, pos_start, q_len,
                              layer, k_scale, v_scale, page_lo, page_hi,
                              carry_state,
                              block_q=plan["block_q"],
                              dma_depth=plan["dma_depth"],
-                             grid=plan["grid"], interpret=interpret,
+                             interpret=interpret,
                              head_group=plan["head_group"],
                              emit_state=emit_state)
 
@@ -1388,7 +1237,6 @@ def paged_mixed_attention_flat(
     v_scale: jnp.ndarray | None = None,
     block_q: int | None = None,
     interpret: bool = False,
-    grid: str | None = None,
     dma_depth: int | None = None,
     head_group: int | None = None,
     latent_v: int = 0,
@@ -1404,17 +1252,15 @@ def paged_mixed_attention_flat(
 
     The plan is resolved HERE, outside jit, from the flat shape: ``qmax =
     T - S + 1`` is the widest span one lane can have, ``nb`` the bound on
-    real q blocks (:func:`mixed_grid_plan`).  The ragged grid lays the
-    queries out block-compacted (:func:`_paged_mixed_flat_call`); the
-    dense grid, the byte-identity reference, keeps the per-lane
-    ``[S, Hkv, G, qmax, D]`` layout it needs.
+    real q blocks (:func:`mixed_grid_plan`); the queries are laid out
+    block-compacted (:func:`_paged_mixed_flat_call`).
 
     A LATENT pool (``latent_v`` > 0, ``v_pool`` None, ``k_pool`` ``[L, N,
     1, P, R]``): one row a token serves every head, so Hkv is 1 and the
     model's heads are the G query rows of the one group; scores are over
     all R lanes times ``scale``, values are the row's first ``latent_v``
     lanes, and the result is ``[T, 1, G, latent_v]``.  Same block layout,
-    same work list; ragged grid only."""
+    same work list."""
     t_flat, hkv, g, d = q.shape
     s = q_len.shape[0]
     # +1: with every lane a q_len = K block (t_flat == S * K, one lane)
@@ -1423,32 +1269,14 @@ def paged_mixed_attention_flat(
     plan = mixed_grid_plan(qmax, hkv=hkv, g=g, d=d,
                            page=pool_page_tokens(k_pool, k_scale),
                            kv=pool_kv_name(k_pool, k_scale),
-                           block_q=block_q, grid=grid, dma_depth=dma_depth,
+                           block_q=block_q, dma_depth=dma_depth,
                            head_group=head_group, lanes=s)
-    if latent_v and plan["grid"] != "ragged":
-        raise ValueError("ARKS_MIXED_GRID=dense: a latent page is served "
-                         "by the ragged work-list grid only")
-    if plan["grid"] == "ragged":
-        return _paged_mixed_flat_call(
-            q, k_pool, v_pool, tables, token_slot, q_start, q_len,
-            pos_start, layer, k_scale, v_scale, block_q=plan["block_q"],
-            nb=plan["nb"], dma_depth=plan["dma_depth"],
-            interpret=interpret, head_group=plan["head_group"],
-            latent_v=latent_v, scale=scale)
-    span = q_start[:, None] + jnp.arange(qmax, dtype=jnp.int32)
-    qs = jnp.take(q, jnp.minimum(span, t_flat - 1).reshape(-1),
-                  axis=0).reshape(s, qmax, hkv, g, d)
-    out_seq = _paged_mixed_call(
-        jnp.transpose(qs, (0, 2, 3, 1, 4)), k_pool, v_pool, tables,
-        pos_start, q_len, layer, k_scale, v_scale,
-        block_q=plan["block_q"], dma_depth=plan["dma_depth"], grid="dense",
+    return _paged_mixed_flat_call(
+        q, k_pool, v_pool, tables, token_slot, q_start, q_len,
+        pos_start, layer, k_scale, v_scale, block_q=plan["block_q"],
+        nb=plan["nb"], dma_depth=plan["dma_depth"],
         interpret=interpret, head_group=plan["head_group"],
-        emit_state=False)
-    rows = jnp.transpose(out_seq, (0, 3, 1, 2, 4)).reshape(
-        s * qmax, hkv, g, d)
-    q_valid = jnp.arange(qmax, dtype=jnp.int32)[None] < q_len[:, None]
-    scatter_idx = jnp.where(q_valid, span, t_flat)          # OOB dropped
-    return jnp.zeros_like(q).at[scatter_idx.reshape(-1)].set(rows)
+        latent_v=latent_v, scale=scale)
 
 
 # ---------------------------------------------------------------------------

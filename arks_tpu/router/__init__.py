@@ -332,8 +332,8 @@ class _SketchPoller:
                 log.warning("sketch poll failed", exc_info=True)
 
     def poll_once(self) -> None:
-        """One refresh round over the current decode set (also the test/
-        bench entry point — deterministic, no thread required)."""
+        """One refresh round over the current decode set (also the tests'
+        entry point — deterministic, no thread required)."""
         _, decode = self.router.discovery.backends()
         m = self.router.metrics
         now = time.monotonic()

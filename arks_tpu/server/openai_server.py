@@ -439,8 +439,7 @@ class OpenAIServer:
         class Server(ThreadingHTTPServer):
             # A burst of N-hundred concurrent (re)connects overflows the
             # default backlog of 5 and the kernel RSTs the overflow —
-            # clients saw "connection reset by peer" under load
-            # (bench_serving.py).
+            # clients saw "connection reset by peer" under load.
             request_queue_size = 512
             daemon_threads = True
 

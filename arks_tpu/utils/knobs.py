@@ -73,12 +73,6 @@ _k("ARKS_PIPELINE_DEPTH", "int", "2",
 _k("ARKS_MIXED_STEP", "enum", "auto",
    "Single mixed prefill+decode dispatch per step: auto = on where "
    "supported.", "engine", ("auto", "0", "1"))
-_k("ARKS_SAMPLER_FUSE", "enum", "1",
-   "Fuse sampler prep into steady-state depth-0 decode dispatches (the "
-   "pipelined program with immediate resolve: zero host-side prep "
-   "arrays between attention and sampling).  Kill switch; gated off "
-   "automatically around prefill, transient overrides, speculative "
-   "drafts and oversized stop sets.", "engine", ("0", "1"))
 _k("ARKS_RESIDENCY_WINDOW_PAGES", "int", "0",
    "Windowed-residency attention: device-page budget per slot for "
    "contexts larger than the device pool — cold pages spill to the "
@@ -131,7 +125,7 @@ _k("ARKS_QUEUE_AGING_S", "float", "0",
 _k("ARKS_FAIR", "bool", "1",
    "Tenant-fair admission: weighted deficit round-robin across tenants "
    "within each SLO tier. 0 reverts to the flat priority heap (the "
-   "bench control arm).", "engine")
+   "tests' control arm).", "engine")
 _k("ARKS_FAIR_QUANTUM_TOKENS", "int", "512",
    "Token credit (prompt + max_tokens cost units) each tenant earns per "
    "fair-queue round-robin visit.", "engine")
@@ -220,9 +214,6 @@ _k("ARKS_ATTN_BLOCK_S", "int", "256",
    "Sequence block of the Pallas decode attention grid.", "kernels")
 _k("ARKS_ATTN_BLOCK_B", "int", "16",
    "Batch block of the Pallas decode attention grid.", "kernels")
-_k("ARKS_MIXED_GRID", "enum", "ragged",
-   "Mixed-attention grid mode: ragged work-list or dense fallback.",
-   "kernels", ("ragged", "dense"))
 _k("ARKS_MOE_KERNEL", "enum", "auto",
    "MoE grouped-matmul implementation (auto resolves to the xla "
    "ragged_dot path until the Pallas kernel wins on hardware).",
@@ -387,11 +378,6 @@ _k("ARKS_GANG_SIZE", "str", None,
 _k("ARKS_GANG_WORKER_INDEX", "str", None,
    "Exported into runtime containers as the worker rank (not read "
    "in-process).", "control")
-
-# ---------------------------------------------------------------- bench
-_k("ARKS_BENCH_DRAFT_MODEL", "str", None,
-   "Draft model path/name enabling the speculative-decoding bench "
-   "ladder.", "bench")
 
 
 # ------------------------------------------------------------ accessors

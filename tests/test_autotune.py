@@ -172,7 +172,6 @@ def test_engine_sweep_mode_tunes_mixed_kernel(monkeypatch):
 
     monkeypatch.setenv("ARKS_MIXED_STEP", "1")
     monkeypatch.setenv("ARKS_ATTN_IMPL", "pallas")
-    monkeypatch.setenv("ARKS_MIXED_GRID", "ragged")
     cfg = get_config("tiny")
 
     def run(tune_mode):

@@ -224,16 +224,14 @@ CASES = {
     # qwen2.5-0.5b: 2 KV heads, head dim 64 stored lane-padded at 128.
     "mixed-int8-chunk-d64-padded": lambda s: _mixed(s, "int8", CHUNK + 1,
                                                     hkv=2),
-    # The rest of what serves somewhere: other page sizes, the dense grid
-    # (ARKS_MIXED_GRID=dense), the windowed-residency span (raw softmax
-    # state out), the legacy paged decode step, the slot layout (dp
-    # engines), the MoE kernel's float and int4 forms.
+    # The rest of what serves somewhere: other page sizes, the
+    # windowed-residency span (raw softmax state out), the legacy paged
+    # decode step, the slot layout (dp engines), the MoE kernel's float
+    # and int4 forms.
     "mixed-int8-chunk-page128": lambda s: _mixed(s, "int8", CHUNK + 1,
                                                  page=128),
     "mixed-int8-chunk-page512": lambda s: _mixed(s, "int8", CHUNK + 1,
                                                  page=512),
-    "mixed-int8-chunk-dense-grid": lambda s: _mixed(s, "int8", CHUNK + 1,
-                                                    grid="dense"),
     "mixed-int8-chunk-emit-state": lambda s: _mixed(s, "int8", CHUNK + 1,
                                                     emit_state=True),
     "paged-decode-int8": lambda s: _paged_decode(s, "int8"),

@@ -212,12 +212,12 @@ def test_meshed_engine_resolves_to_depth_zero_and_says_so(monkeypatch):
     from arks_tpu.parallel.mesh import make_mesh
     monkeypatch.setenv("ARKS_PIPELINE_DEPTH", "2")
     one = _tiny_engine()
-    assert one.resolved_config["pipeline_depth"] == "2" and one._sampler_fuse
+    assert one.resolved_config["pipeline_depth"] == "2" and one._pipe_depth == 2
     eng = _tiny_engine(mesh=make_mesh(tensor_parallel=2,
                                       devices=jax.devices()[:2]))
     assert eng.resolved_config["tensor_parallel"] == "2"
     assert eng.resolved_config["pipeline_depth"] == "0"
-    assert eng._pipe_depth == 0 and not eng._sampler_fuse
+    assert eng._pipe_depth == 0
     assert eng._pipe_warm_wait(1.0) is None      # nothing to build
 
 

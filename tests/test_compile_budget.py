@@ -144,7 +144,7 @@ def test_ragged_kernel_family_budget_with_tuned_cache(monkeypatch, tmp_path):
     jitted kernel launcher (_paged_mixed_call) must compile exactly ONE
     variant for the whole mixed workload — a tuned entry swaps the statics'
     VALUES, it must never add a compiled variant next to the default, and
-    the engine-level budget is unchanged from the dense-era census."""
+    the engine-level budget is unchanged by a tuned table."""
     from arks_tpu.ops import autotune, paged_attention
     from arks_tpu.models import transformer as tf
 
@@ -152,7 +152,6 @@ def test_ragged_kernel_family_budget_with_tuned_cache(monkeypatch, tmp_path):
     monkeypatch.setenv("ARKS_KERNEL_TUNE", "cached")
     monkeypatch.setenv("ARKS_KERNEL_TUNE_CACHE", str(cache))
     monkeypatch.setenv("ARKS_ATTN_IMPL", "pallas")
-    monkeypatch.setenv("ARKS_MIXED_GRID", "ragged")
     monkeypatch.setenv("ARKS_MIXED_STEP", "1")
     autotune.invalidate_cache()
 
@@ -191,12 +190,12 @@ def test_ragged_kernel_family_budget_with_tuned_cache(monkeypatch, tmp_path):
 
     # The tuned entry reached the resolved plan (counters memoize it).
     plan = eng._grid_plans[eng._mixed_budget + 1]
-    assert plan["block_q"] == 8 and plan["grid"] == "ragged", plan
+    assert plan["block_q"] == 8, plan
     # Inside the engine the launcher is INLINED into the jitted step
     # programs — its own cache must not have grown (no stray eager launch
     # escaped the step programs).
     assert paged_attention._paged_mixed_call._cache_size() == kernel_before
-    # Engine-level census unchanged from the dense-grid era.
+    # Engine-level census: one sequential mixed program, whatever the table.
     variants = eng.compiled_program_variants()
     assert sum(variants.values()) <= MIXED_TOTAL_BUDGET, variants
     assert variants.get("_mixed_fn", 0) == 1, variants
@@ -217,7 +216,6 @@ def test_mixed_kernel_launcher_variant_census(monkeypatch, tmp_path):
     cache = tmp_path / "kernel_tune.json"
     monkeypatch.setenv("ARKS_KERNEL_TUNE", "cached")
     monkeypatch.setenv("ARKS_KERNEL_TUNE_CACHE", str(cache))
-    monkeypatch.setenv("ARKS_MIXED_GRID", "ragged")
     autotune.invalidate_cache()
 
     l, s, hkv, g, maxp, page, d, qmax = 1, 2, 1, 1, 2, 8, 8, 4

@@ -264,8 +264,8 @@ def test_metrics_populated(engine):
 
 def test_resolved_config_surfaced(engine):
     """The RESOLVED engine configuration (auto decisions included) rides
-    /metrics as an _info gauge and the engine object, so bench_serving and
-    dashboards can tell which perf envelope produced a number."""
+    /metrics as an _info gauge and the engine object, so the benchmark's
+    expect_labels and dashboards can tell which path produced a number."""
     rc = engine.resolved_config
     assert rc["kv_layout"] in ("paged", "slot")
     assert rc["decode_impl"] in ("pallas", "xla")
@@ -276,7 +276,7 @@ def test_resolved_config_surfaced(engine):
     assert f'kv_layout="{rc["kv_layout"]}"' in text
     assert f'decode_impl="{rc["decode_impl"]}"' in text
     # The pure device-wait counter rides every decode resolve (the
-    # overlap-mode-trustworthy signal bench_serving reports).  Drive one
+    # overlap-mode-trustworthy signal).  Drive one
     # tiny request HERE so a sample line exists even when this test runs
     # alone, then assert a non-comment line (comment lines start '# ').
     req = Request("rc-cfg", [5, 6, 7], SamplingParams(

@@ -452,8 +452,19 @@ def test_edge_shed_rejects_most_over_share_tenant(world):
         # alice: prospective share (0+1)/1 = 1 < flood's 5 -> admitted.
         with _post(gw, {"model": "m1", "messages": []}) as r:
             assert r.status == 200
+        # The handler counts alice's request out AFTER the client has its
+        # reply: planting the next state before that lands would lose one
+        # of its five to the late decrement (4 in flight < the cap of 5,
+        # and the shed below never fires: the failure seen under load).
+        deadline = time.monotonic() + 10
+        while time.monotonic() < deadline:
+            with gw._inflight_lock:
+                if "team-a/alice" not in gw._inflight:
+                    break
+            time.sleep(0.005)
         # Now alice IS the most over-share prospective tenant.
         with gw._inflight_lock:
+            assert "team-a/alice" not in gw._inflight
             gw._inflight.clear()
             gw._inflight["team-a/alice"] = 5
         try:

@@ -550,8 +550,7 @@ def test_engine_all_guides_pinned_rejects_cleanly(monkeypatch):
 
 @pytest.mark.slow
 def test_guided_cold_vs_warm_admit_bench():
-    """Micro-benchmark (BENCH rounds track bench.py's guided_cold_start_s;
-    this is the CPU-tier counterpart): admit-to-first-token with a cold vs
+    """Micro-benchmark, CPU tier: admit-to-first-token with a cold vs
     warm guide, plus the headline assertion that scheduler progress during
     a background compile stays bounded on CPU."""
     cfg = get_config("tiny")

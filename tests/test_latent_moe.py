@@ -691,7 +691,6 @@ def test_engine_serves_a_latent_share_and_counts_what_it_holds():
     ({}, {"ARKS_PREEMPT": "1"}, "KV swap"),
     ({}, {"ARKS_PEER_ADDRS": "10.0.0.1:8080"}, "peer fetch"),
     ({}, {"ARKS_MIXED_STEP": "0"}, "legacy scheduler"),
-    ({}, {"ARKS_MIXED_GRID": "dense"}, "ragged work-list"),
 ])
 def test_a_latent_model_refuses_by_name_what_still_speaks_k_and_v(
         over, env, word, monkeypatch):

@@ -179,6 +179,62 @@ def test_pooled_streams_byte_identical_to_single_model_engines(
     assert sum(eng.metrics.engine_faults_total._values.values()) == 0
 
 
+def test_decode_keeps_full_depth_while_the_second_models_weights_load(
+        monkeypatch):
+    """The second model's first request lands while the first model is
+    mid-decode: while its weights load (the loader is held open on an
+    event, a stand-in for a multi-GB checkpoint read) every step still
+    issues a pipelined dispatch, and the switch's dispatch accounting
+    reads the FULL pipeline depth for the load window."""
+    depth = 2
+    cfg_b = _second_cfg()
+    _, eng = _mk_pool_engine(monkeypatch, depth, cfg_b)
+    entry = eng.pool.entry(cfg_b.name)
+    load, gate = entry.loader, threading.Event()
+
+    def gated_loader():
+        params = load()
+        assert gate.wait(120), "the test never released the loader"
+        return params
+
+    entry.loader = gated_loader
+    def piped():
+        """Pipelined dispatches so far (one observation each)."""
+        return sum(n for _, _, n in
+                   eng.metrics.pipeline_depth_occupancy._data.values())
+
+    live = Request("a-live", [5, 6, 7], SamplingParams(
+        max_tokens=48, temperature=0.0, ignore_eos=True))
+    eng.add_request(live)
+    for _ in range(200):
+        eng.step(block_s=0.01)
+        if piped() >= 2:
+            break
+    assert piped() >= 2, "never pipelined"
+    cold = Request("b-cold", [9] * 5, SamplingParams(
+        max_tokens=4, temperature=0.0, ignore_eos=True), model=cfg_b.name)
+    eng.add_request(cold)
+    for _ in range(200):
+        eng.step(block_s=0.01)
+        if eng._model_loads:
+            break
+    assert eng._model_loads, "the second model's load never started"
+    before = piped()
+    for _ in range(12):
+        eng.step(block_s=0.01)
+    assert eng._model_loads, "the load finished behind the closed gate"
+    assert piped() - before >= 12, \
+        "a model load in flight knocked decoding off the pipelined path"
+    gate.set()
+    _drive(eng)
+    _quiesce(eng, depth)
+    assert _collect(live)[1].finish_reason == "length"
+    assert _collect(cold)[1].finish_reason == "length"
+    stats = eng.last_switch_stats
+    assert stats["overlap_dispatches"] >= 12, stats
+    assert stats["overlap_max_depth"] == depth, stats
+
+
 @pytest.mark.chaos
 @pytest.mark.parametrize("depth", [0, 2])
 def test_model_switch_fault_recovers_byte_identical(monkeypatch, depth):

@@ -93,6 +93,57 @@ def test_pipeline_token_parity_depths(monkeypatch, mixed, kw):
         assert occ, "pipelined path never engaged"
 
 
+_TRAFFIC = {
+    "plain": dict(max_tokens=12, temperature=0.8, top_p=0.9, top_k=40,
+                  seed=7, ignore_eos=True),
+    "guided": dict(max_tokens=8, temperature=0.0, guide=("json", "")),
+    "logprob": dict(max_tokens=12, temperature=0.0, ignore_eos=True,
+                    logprobs=2),
+}
+
+
+@pytest.mark.parametrize("traffic", sorted(_TRAFFIC))
+def test_depth0_steady_decode_is_the_mixed_dispatch(monkeypatch, traffic):
+    """A depth-0 mixed engine has ONE decode path: every model dispatch of
+    a run, steady state included, is the sequential ``mixed`` op (no pipe
+    program is warmed or issued), one a step, and the streams (ids,
+    logprob floats, finish reasons) are those of a depth-2 engine, whose
+    steady state rides ``decode_pipe``."""
+    def run(depth):
+        cfg, eng = _mk_engine(monkeypatch, depth, "1", prefill_chunk=16,
+                              kv_layout="paged", prefix_cache_mb=0)
+        eng.dispatcher = RecordingDispatcher()
+        reqs = [Request(f"r{i}", [int(x) % cfg.vocab_size for x in p],
+                        SamplingParams(**_TRAFFIC[traffic]))
+                for i, p in enumerate([[5, 6, 7], list(range(3, 40)),
+                                       [9] * 20])]
+        for r in reqs:
+            eng.add_request(r)
+        for _ in range(4000):       # .idle: a guide's compile parks all three
+            eng.step(block_s=0.01)
+            if eng.idle:
+                break
+        outs = [(ids, lps, fin.finish_reason)
+                for ids, lps, fin in map(_collect, reqs)]
+        ops = [op for op, _ in eng.dispatcher.ops]
+        return outs, ops, eng
+
+    outs0, ops0, eng0 = run(0)
+    assert eng0._pipe_warm_state is None and not eng0._pipe_exec
+    assert "decode_pipe" not in ops0
+    model_ops = [op for op in ops0 if op in ("mixed", "decode", "chunk",
+                                             "chunk_paged", "admit_batch")]
+    assert model_ops and set(model_ops) == {"mixed"}
+    # One token a stream a dispatch: at least as many dispatches as the
+    # longest stream has tokens.
+    assert len(model_ops) >= max(len(ids) for ids, _, _ in outs0)
+    assert not eng0.metrics.pipeline_depth_occupancy._data
+
+    outs2, ops2, _ = run(2)
+    assert "decode_pipe" in ops2, "the depth-2 engine never pipelined"
+    assert outs0 == outs2
+
+
 def test_pipeline_one_dispatch_per_iteration_and_depth_bound(monkeypatch):
     """Emit-stream contract: in steady state exactly ONE model dispatch is
     issued per scheduler iteration, and the advertised occupancy never
@@ -296,8 +347,11 @@ def test_pipeline_survives_parked_guide_compile(monkeypatch):
         return orig(rx)
 
     eng.guides._build = gated_build
+    # A BOUNDED grammar: under ``ab+a`` a greedy tiny model may prefer
+    # ``b`` for all 24 tokens (which it does depends on where the request
+    # lands) and finish "length"; here the third ``b`` leaves only ``a``.
     greq = Request("g", [9, 9], SamplingParams(
-        max_tokens=24, temperature=0.0, guide=("regex", r"ab+a")))
+        max_tokens=24, temperature=0.0, guide=("regex", r"ab{1,3}a")))
     eng.add_request(greq)
     deadline = _time.monotonic() + 2.0
     while _time.monotonic() < deadline and not eng._awaiting_guide:
@@ -316,7 +370,7 @@ def test_pipeline_survives_parked_guide_compile(monkeypatch):
     ids, _, fin = _collect(greq)
     assert fin.finish_reason == "stop"
     import re
-    assert re.fullmatch(r"ab+a", ByteTokenizer().decode(ids))
+    assert re.fullmatch(r"ab{1,3}a", ByteTokenizer().decode(ids))
     _, _, lfin = _collect(load)
     assert lfin.finish_reason == "length"
 

@@ -1,12 +1,12 @@
-"""Ragged work-list grid: engine-level stream identity vs the dense grid
-(the pre-refactor kernel), padding-waste counters, and autotune config
-surfacing.
+"""Ragged work-list grid: engine-level stream identity against the XLA
+gather oracle, the grid-plan counters, and the block-compacted query
+layout.
 
-The kernel-level bitwise identity between the two grids lives in
-test_paged_attention.py; HERE the gate is the serving stream: the same
-workload through ARKS_MIXED_GRID=ragged and =dense must emit byte-identical
-token streams with the Pallas mixed path engaged (interpret mode on CPU),
-at pipeline depths 0 and 2, for plain, guided, and speculative traffic.
+The kernel-level parity lives in test_paged_attention.py; HERE the gate is
+the serving stream: the same workload through the Pallas mixed path
+(interpret mode on CPU) and through the XLA gather path (``impl="xla"``)
+must emit the same token streams, at pipeline depths 0 and 2, for plain,
+guided, and speculative traffic.
 """
 
 import numpy as np
@@ -17,10 +17,8 @@ from arks_tpu.engine.tokenizer import ByteTokenizer
 from arks_tpu.models import get_config
 
 
-def _mk_engine(monkeypatch, *, grid, depth=0, impl="pallas", spec=False,
-               **kw):
+def _mk_engine(monkeypatch, *, depth=0, impl="pallas", spec=False, **kw):
     monkeypatch.setenv("ARKS_MIXED_STEP", "1")
-    monkeypatch.setenv("ARKS_MIXED_GRID", grid)
     monkeypatch.setenv("ARKS_ATTN_IMPL", impl)
     monkeypatch.setenv("ARKS_PIPELINE_DEPTH", str(depth))
     cfg = get_config("tiny")
@@ -55,15 +53,19 @@ def _collect(req):
     return ids, fin.finish_reason
 
 
-def _run_workload(eng, cfg, guided=False):
-    """Plain greedy + fixed-seed sampled (+ optionally guided) requests —
-    chunked and one-shot prompt shapes, more requests than slots."""
+def _run_workload(eng, cfg, guided=False, sampled=False):
+    """Greedy (+ optionally guided) requests — chunked and one-shot prompt
+    shapes, more requests than slots.  ``sampled`` makes the chunked one a
+    fixed-seed sampled stream: the Pallas path and the oracle agree to a
+    tolerance, not to the bit, so only greedy streams are compared across
+    them; the sampled one is compared across depths of the Pallas path."""
     reqs = [
         Request("g0", [5, 6, 7], SamplingParams(
             max_tokens=5, temperature=0.0, ignore_eos=True)),
-        Request("s0", [int(x) % cfg.vocab_size for x in range(3, 40)],
-                SamplingParams(max_tokens=5, temperature=0.8, top_p=0.9,
-                               seed=7, ignore_eos=True)),
+        Request("c0", [int(x) % cfg.vocab_size for x in range(3, 40)],
+                SamplingParams(max_tokens=5, ignore_eos=True,
+                               **(dict(temperature=0.8, top_p=0.9, seed=7)
+                                  if sampled else dict(temperature=0.0)))),
         Request("g1", [9] * 20, SamplingParams(
             max_tokens=5, temperature=0.0, ignore_eos=True)),
     ]
@@ -77,64 +79,144 @@ def _run_workload(eng, cfg, guided=False):
 
 
 @pytest.mark.parametrize("depth", [0, 2])
-def test_stream_identity_ragged_vs_dense(monkeypatch, depth):
-    """Plain + guided traffic through the Pallas mixed path: the ragged
-    grid's token streams are byte-identical to the dense grid's at this
-    pipeline depth."""
+def test_stream_identity_ragged_vs_oracle(monkeypatch, depth):
+    """Plain + guided traffic: the Pallas mixed path's token streams are
+    those of the XLA gather oracle at this pipeline depth."""
     outs = {}
-    for grid in ("ragged", "dense"):
-        cfg, eng = _mk_engine(monkeypatch, grid=grid, depth=depth)
-        assert eng.resolved_config["mixed_grid"] == grid
-        outs[grid] = _run_workload(eng, cfg, guided=True)
-    assert outs["ragged"] == outs["dense"]
+    for impl in ("pallas", "xla"):
+        cfg, eng = _mk_engine(monkeypatch, impl=impl, depth=depth)
+        assert eng.resolved_config["mixed_grid"] == "ragged"
+        assert eng.resolved_config["decode_impl"] == impl
+        outs[impl] = _run_workload(eng, cfg, guided=True)
+    assert outs["pallas"] == outs["xla"]
 
 
 @pytest.mark.parametrize("depth", [0, 2])
 def test_stream_identity_spec_traffic(monkeypatch, depth):
-    """Speculative traffic (draft+verify ride the mixed dispatch): ragged
-    and dense grids emit identical accepted streams at this depth."""
+    """Speculative traffic (draft+verify ride the mixed dispatch): the
+    Pallas path and the XLA oracle emit identical accepted streams at this
+    depth."""
     outs = {}
-    for grid in ("ragged", "dense"):
-        cfg, eng = _mk_engine(monkeypatch, grid=grid, depth=depth,
+    for impl in ("pallas", "xla"):
+        cfg, eng = _mk_engine(monkeypatch, impl=impl, depth=depth,
                               spec=True)
-        outs[grid] = _run_workload(eng, cfg)
-    assert outs["ragged"] == outs["dense"]
+        outs[impl] = _run_workload(eng, cfg)
+    assert outs["pallas"] == outs["xla"]
+
+
+def test_pallas_sampled_streams_identical_across_depths(monkeypatch):
+    """The sequential mixed step and the pipelined one run the SAME kernel:
+    a fixed-seed sampled stream (with greedy and guided ones beside it)
+    through the Pallas path is the same bytes at depth 0 and at depth 2."""
+    outs = {}
+    for depth in (0, 2):
+        cfg, eng = _mk_engine(monkeypatch, depth=depth)
+        outs[depth] = _run_workload(eng, cfg, guided=True, sampled=True)
+    assert outs[0] == outs[2]
 
 
 def test_sparse_batch_grid_steps_drop_to_ideal(monkeypatch):
-    """3 active requests in a 64-slot engine: the ragged grid's executed
-    page-compute steps equal the per-sequence causal ideal — and sit far
-    below the dense grid's S*num_qb*max_pages.  Counters describe the grid
+    """3 active requests in a 64-slot engine: the counter equals
+    ``mixed_grid_steps`` of the batches dispatched (each item's own causal
+    page count) and sits far below S*num_qb*max_pages, what a grid over
+    every lane's widest chunk would run.  Counters describe the grid
     PLAN, so this runs on the fast XLA oracle."""
-    cfg, eng = _mk_engine(monkeypatch, grid="ragged", impl="xla",
-                          num_slots=64)
+    from arks_tpu.engine.paged import mixed_grid_steps
+    cfg, eng = _mk_engine(monkeypatch, impl="xla", num_slots=64)
+    batches = []
+    count = eng._mixed_grid_counters
+
+    def recording(pos_start, q_len, qmax):
+        batches.append((pos_start.copy(), q_len.copy(), qmax))
+        return count(pos_start, q_len, qmax)
+
+    eng._mixed_grid_counters = recording
     for i in range(3):
         eng.add_request(Request(f"r{i}", [5 + i, 6, 7], SamplingParams(
             max_tokens=4, temperature=0.0, ignore_eos=True)))
     _drive(eng)
     steps = eng.metrics.mixed_grid_steps_total.total()
-    ideal = eng.metrics.mixed_grid_steps_ideal_total.total()
-    assert steps == ideal > 0
-    # The dense plan for the same dispatches: every issued dispatch pays
-    # S * num_qb * max_pages.
+    want = sum(mixed_grid_steps(
+        pos, ql, page=eng._page_size(),
+        block_q=eng._grid_plans[qmax]["block_q"],
+        num_qb=eng._grid_plans[qmax]["num_qb"], max_pages=eng._max_pages)
+        for pos, ql, qmax in batches)
+    assert steps == want > 0
     plan = next(iter(eng._grid_plans.values()))
     n_dispatches = sum(
         n for _, _, n in eng.metrics.mixed_batch_tokens._data.values())
-    dense = 64 * plan["num_qb"] * eng._max_pages * n_dispatches
-    assert steps < dense / 10, (steps, dense)
+    assert n_dispatches == len(batches)
+    every_lane = 64 * plan["num_qb"] * eng._max_pages * n_dispatches
+    assert steps < every_lane / 10, (steps, every_lane)
 
 
-def test_gqa_bytes_sweep_hits_group_factor(monkeypatch):
-    """The bench GQA sweep's acceptance shape: at g=8 the grouped tuned
-    plan moves >= g fewer KV bytes than the ungrouped baseline (the win
-    arrives through the larger tuned block_q that head grouping's VMEM
-    headroom affords), and the grouped plan reaches the
-    fetch-each-block-once ideal.  Plan-only — no kernel launches; the
-    bitwise identity of the grouped kernel lives in
+# GQA sweep shape: (hkv, d, page, max_pages, qmax).
+_GQA_SHAPE = (8, 16, 16, 16, 64)
+_GQA_VMEM_BUDGET = 18432  # f32 lanes; hg=1 affords block_q=qmax, hg=8 only 4
+
+
+def _gqa_vmem_block_q(hg: int, g: int) -> int:
+    """Largest q block the modeled VMEM budget affords one (hg-head,
+    g-share) work item: double-buffered KV blocks (2 in flight) + q tile
+    + f32 accumulator.  Grouping divides the whole footprint by
+    hkv/head_group, which is the headroom the tuned plan re-invests in
+    block_q."""
+    hkv, d, page, _, qmax = _GQA_SHAPE
+    comp = (_GQA_VMEM_BUDGET // hg - 4 * page * d) // (2 * g * d)
+    if comp >= qmax:
+        return qmax
+    bq = 1
+    while bq * 2 <= comp:
+        bq *= 2
+    return bq
+
+
+def _gqa_bytes_sweep() -> dict:
+    """GQA head-group sweep (g in {1, 4, 8}), plan-only — no kernel
+    launches.  The head-grouped DMA schedule wins KV bytes THROUGH
+    block_q: grouping shrinks a work item's VMEM footprint by
+    hkv/head_group, the tuned plan re-invests that headroom in a larger q
+    block, and fewer q blocks re-stream each causal page prefix fewer
+    times.  The bytes-moved pair (mixed_kv_bytes actual vs
+    fetch-each-block-once ideal) for the ungrouped baseline against the
+    grouped tuned plan."""
+    from arks_tpu.engine.paged import mixed_kv_bytes
+    from arks_tpu.ops import paged_attention as pa
+
+    hkv, d, page, maxp, qmax = _GQA_SHAPE
+    # Decode-heavy lanes: a long causal prefix (the re-stream cost the
+    # grouping exists to cut) plus a short second lane.
+    pos = np.zeros(4, np.int32)
+    ql = np.zeros(4, np.int32)
+    pos[:2] = (maxp * page - qmax, page)
+    ql[:2] = (qmax, 8)
+    phb = page * d * 4 * 2  # f32 K + V bytes per (page, head) block
+    out: dict = {}
+    for g in (1, 4, 8):
+        byt = {}
+        for name, hg in (("base", hkv), ("grouped", 1)):
+            plan = pa.mixed_grid_plan(
+                qmax, hkv=hkv, g=g, d=d, page=page, kv="float32",
+                block_q=_gqa_vmem_block_q(hg, g), head_group=hg)
+            b_act, b_ideal = mixed_kv_bytes(
+                pos, ql, page=page, block_q=plan["block_q"],
+                num_qb=plan["num_qb"], max_pages=maxp, hkv=hkv,
+                page_head_bytes=phb)
+            byt[name] = b_act
+            out[f"gqa_g{g}_{name}_kv_bytes"] = b_act
+            out[f"gqa_g{g}_kv_bytes_ideal"] = b_ideal
+        out[f"gqa_g{g}_bytes_ratio"] = byt["base"] / byt["grouped"]
+    return out
+
+
+def test_gqa_bytes_sweep_hits_group_factor():
+    """At g=8 the grouped tuned plan moves >= g fewer KV bytes than the
+    ungrouped baseline (the win arrives through the larger tuned block_q
+    that head grouping's VMEM headroom affords), and the grouped plan
+    reaches the fetch-each-block-once ideal.  Plan-only — no kernel
+    launches; the bitwise identity of the grouped kernel lives in
     test_paged_attention.py."""
-    monkeypatch.delenv("ARKS_MIXED_GRID", raising=False)
-    import bench
-    r = bench.measure_gqa_bytes_sweep()
+    r = _gqa_bytes_sweep()
     assert r["gqa_g8_bytes_ratio"] >= 8
     assert r["gqa_g8_grouped_kv_bytes"] == r["gqa_g8_kv_bytes_ideal"]
     # The win scales with the GQA share factor.
@@ -150,8 +232,7 @@ def test_kv_bytes_moved_counter_pair(monkeypatch):
     XLA oracle drives them; actual >= ideal always, and with the
     head-group factor covering every kv head in one pass the pair
     converges for single-page decode dispatches."""
-    cfg, eng = _mk_engine(monkeypatch, grid="ragged", impl="xla",
-                          num_slots=4)
+    cfg, eng = _mk_engine(monkeypatch, impl="xla", num_slots=4)
     for i in range(2):
         eng.add_request(Request(f"r{i}", [5 + i, 6, 7], SamplingParams(
             max_tokens=4, temperature=0.0, ignore_eos=True)))
@@ -167,20 +248,6 @@ def test_kv_bytes_moved_counter_pair(monkeypatch):
         assert actual == ideal
 
 
-def test_dense_grid_counts_padding_waste(monkeypatch):
-    """Under ARKS_MIXED_GRID=dense the counter pair splits: steps_total
-    records the dense grid's full S*num_qb*max_pages while ideal_total
-    stays at the causal minimum — the waste ratio operators alert on."""
-    cfg, eng = _mk_engine(monkeypatch, grid="dense", impl="xla",
-                          num_slots=8)
-    eng.add_request(Request("r0", [5, 6, 7], SamplingParams(
-        max_tokens=3, temperature=0.0, ignore_eos=True)))
-    _drive(eng)
-    steps = eng.metrics.mixed_grid_steps_total.total()
-    ideal = eng.metrics.mixed_grid_steps_ideal_total.total()
-    assert ideal > 0 and steps > ideal
-
-
 # ---------------------------------------------------------------------------
 # Block-compacted query layout (the flat batch's way into the ragged kernel)
 # ---------------------------------------------------------------------------
@@ -188,8 +255,8 @@ def test_dense_grid_counts_padding_waste(monkeypatch):
 # The mixed step hands the kernel its queries in blocks of block_q rows, one
 # per real (lane, q block) pair, filled by one gather from the flat batch and
 # read back by one.  On every real row the bytes must be those of the
-# per-lane call (paged_mixed_attention over the dense [S, Hkv, G, Q, D] block
-# of the same batch) and of the dense grid.
+# per-lane call (paged_mixed_attention over the [S, Hkv, G, Q, D] block of the
+# same batch), and the values those of the XLA gather oracle.
 
 _LANES, _CHUNK, _BQ = 6, 12, 4      # t_flat = 18, qmax = 13, nb = 6 + 3
 
@@ -258,8 +325,7 @@ def _flat_pools(kv):
 @pytest.mark.parametrize("head_group", [None, 1])
 @pytest.mark.parametrize("kv", ["int8", "bf16"])
 @pytest.mark.parametrize("batch", _BATCHES)
-def test_compacted_layout_bytes_match_per_lane_and_dense(batch, kv,
-                                                         head_group):
+def test_compacted_layout_bytes_match_per_lane(batch, kv, head_group):
     import jax.numpy as jnp
     from arks_tpu.ops.paged_attention import (
         paged_mixed_attention, paged_mixed_attention_flat)
@@ -270,27 +336,62 @@ def test_compacted_layout_bytes_match_per_lane_and_dense(batch, kv,
             jnp.asarray(q_len), jnp.asarray(pos))
     kw = dict(k_scale=kps, v_scale=vps, block_q=_BQ, interpret=True)
     got = np.asarray(paged_mixed_attention_flat(
-        q, kp, vp, tables, *args, 1, grid="ragged", head_group=head_group,
+        q, kp, vp, tables, *args, 1, head_group=head_group,
         **kw).astype(jnp.float32))
-    dense = np.asarray(paged_mixed_attention_flat(
-        q, kp, vp, tables, *args, 1, grid="dense", **kw
-    ).astype(jnp.float32))
-    # The per-lane call over the dense block of the same batch.
+    # The per-lane call over the [S, Hkv, G, qmax, D] block of the same
+    # batch.
     qmax = t_flat - _LANES + 1
     span = np.minimum(q_start[:, None] + np.arange(qmax)[None], t_flat - 1)
     block = jnp.transpose(q[span.reshape(-1)].reshape(
         _LANES, qmax, *q.shape[1:]), (0, 2, 3, 1, 4))
     lane = np.asarray(paged_mixed_attention(
-        block, kp, vp, tables, args[3], args[2], 1, grid="ragged",
+        block, kp, vp, tables, args[3], args[2], 1,
         head_group=head_group, **kw).astype(jnp.float32))
     real = token_slot >= 0
     assert np.isfinite(got).all()
     # Padding rows come back as zeros, whatever their block held.
     np.testing.assert_array_equal(got[~real], 0.0)
-    np.testing.assert_array_equal(got, dense)
     for t in np.flatnonzero(real):
         s = token_slot[t]
         np.testing.assert_array_equal(got[t], lane[s, :, :, t - q_start[s]])
+    if batch != "empty":
+        assert np.abs(got[real]).max() > 0
+
+
+@pytest.mark.parametrize("kv", ["int8", "bf16"])
+@pytest.mark.parametrize("batch", _BATCHES)
+def test_compacted_layout_matches_xla_gather_oracle(batch, kv):
+    """The flat call against the path ``impl="xla"`` serves: every flat
+    row's own table gathered whole, masked attention over [0, its
+    position].  The tuned-default block_q (no ``block_q`` argument), so the
+    plan is the one the mixed step resolves."""
+    import jax.numpy as jnp
+    from arks_tpu.ops.attention import (
+        _decode_attention_xla_quant, decode_attention_xla)
+    from arks_tpu.ops.paged_attention import (
+        paged_gather_kv, paged_mixed_attention_flat)
+    q, kp, vp, kps, vps, tables = _flat_pools(kv)
+    token_slot, q_start, q_len, pos = _flat_batch(batch)
+    got = np.asarray(paged_mixed_attention_flat(
+        q, kp, vp, tables, jnp.asarray(token_slot), jnp.asarray(q_start),
+        jnp.asarray(q_len), jnp.asarray(pos), 1, k_scale=kps, v_scale=vps,
+        interpret=True).astype(jnp.float32))
+    real = token_slot >= 0
+    lane = np.maximum(token_slot, 0)
+    token_pos = pos[lane] + np.arange(token_slot.shape[0]) - q_start[lane]
+    lens = jnp.asarray(np.where(real, token_pos + 1, 0), jnp.int32)
+    tables_tok = tables[jnp.asarray(lane)]
+    kc = paged_gather_kv(kp, tables_tok, 1)
+    vc = paged_gather_kv(vp, tables_tok, 1)
+    if kps is not None:
+        want = _decode_attention_xla_quant(
+            q, kc, vc, paged_gather_kv(kps, tables_tok, 1),
+            paged_gather_kv(vps, tables_tok, 1), lens)
+    else:
+        want = decode_attention_xla(q, kc, vc, lens)
+    want = np.asarray(want.astype(jnp.float32))
+    np.testing.assert_array_equal(got[~real], 0.0)
+    np.testing.assert_allclose(got[real], want[real], atol=3e-2, rtol=3e-2)
     if batch != "empty":
         assert np.abs(got[real]).max() > 0
 
@@ -306,8 +407,7 @@ def test_block_rank_arithmetic_matches_numpy_loop(batch, block_q):
     token_slot, q_start, q_len, pos = _flat_batch(batch)
     t_flat = token_slot.shape[0]
     plan = mixed_grid_plan(t_flat - _LANES + 1, hkv=2, g=3, d=32, page=16,
-                           kv="bfloat16", block_q=block_q, lanes=_LANES,
-                           grid="ragged")
+                           kv="bfloat16", block_q=block_q, lanes=_LANES)
     nb = plan["nb"]
     assert nb == _LANES + -(-_CHUNK // block_q)
     base, src, out = map(np.asarray, mixed_block_layout(
@@ -352,7 +452,7 @@ def test_pipelined_shape_lays_out_one_row_a_lane(lanes):
     import jax.numpy as jnp
     from arks_tpu.ops import paged_attention as pa
     plan = pa.mixed_grid_plan(1, hkv=2, g=3, d=32, page=16, kv="bfloat16",
-                              lanes=lanes, grid="ragged")
+                              lanes=lanes)
     assert (plan["block_q"], plan["nb"], plan["q_rows"]) == (1, lanes, lanes)
 
     def grid_of(fn, *args):
@@ -375,21 +475,19 @@ def test_pipelined_shape_lays_out_one_row_a_lane(lanes):
     lane = jnp.arange(lanes, dtype=jnp.int32)
     ones = jnp.ones((lanes,), jnp.int32)
     flat = grid_of(lambda q: pa.paged_mixed_attention_flat(
-        q, kp, kp, tables, lane, lane, ones, ones, 0, interpret=True,
-        grid="ragged"), jnp.zeros((lanes, 2, 3, 32), jnp.bfloat16))
+        q, kp, kp, tables, lane, lane, ones, ones, 0, interpret=True),
+        jnp.zeros((lanes, 2, 3, 32), jnp.bfloat16))
     per_lane = grid_of(lambda q: pa.paged_mixed_attention(
-        q, kp, kp, tables, ones, ones, 0, interpret=True, grid="ragged"),
+        q, kp, kp, tables, ones, ones, 0, interpret=True),
         jnp.zeros((lanes, 2, 3, 1, 32), jnp.bfloat16))
     assert flat == per_lane == [(lanes,)]
 
 
-@pytest.mark.parametrize("grid", ["ragged", "dense"])
-def test_q_layout_rows_counter_follows_the_plan(monkeypatch, grid):
+def test_q_layout_rows_counter_follows_the_plan(monkeypatch):
     """mixed_q_layout_rows_total rises by the plan's q_rows a dispatch
-    (nb x block_q under the ragged grid, lanes x the padded widest chunk
-    under the dense one), whatever the batch holds: to be read against the
-    real rows, the sum of mixed_batch_tokens."""
-    cfg, eng = _mk_engine(monkeypatch, grid=grid, impl="xla", num_slots=8)
+    (nb x block_q), whatever the batch holds: to be read against the real
+    rows, the sum of mixed_batch_tokens."""
+    cfg, eng = _mk_engine(monkeypatch, impl="xla", num_slots=8)
     eng.add_request(Request("r0", [5, 6, 7], SamplingParams(
         max_tokens=3, temperature=0.0, ignore_eos=True)))
     _drive(eng)
@@ -398,10 +496,5 @@ def test_q_layout_rows_counter_follows_the_plan(monkeypatch, grid):
         n for _, _, n in eng.metrics.mixed_batch_tokens._data.values())
     rows = eng.metrics.mixed_q_layout_rows_total.total()
     assert n_dispatches > 0 and rows == n_dispatches * plan["q_rows"]
-    budget = eng._mixed_budget
-    if grid == "ragged":
-        assert plan["q_rows"] == plan["nb"] * plan["block_q"]
-        assert plan["nb"] == 8 + -(-budget // plan["block_q"])
-    else:
-        assert plan["q_rows"] == 8 * plan["qpad"]
-        assert plan["q_rows"] > 8 + budget
+    assert plan["q_rows"] == plan["nb"] * plan["block_q"]
+    assert plan["nb"] == 8 + -(-eng._mixed_budget // plan["block_q"])

@@ -337,3 +337,98 @@ def test_connection_error_invalidates_the_dead_backends_sketch(monkeypatch):
     finally:
         r.stop()
         good.stop()
+
+
+# ---------------------------------------------------------------------------
+# A real fleet: two engines behind OpenAIServers behind a Router
+# ---------------------------------------------------------------------------
+
+def _fleet_workload(vocab, chunk=16, clients=4, turns=3):
+    """A shared system prefix, then per-client histories that each turn
+    extend the PREVIOUS prompt plus fresh tokens, in a shuffled arrival
+    order (so a round-robin counter cannot fake affinity by arithmetic)."""
+    import random
+    rng = random.Random(42)
+    lo, hi = 3, min(200, vocab)
+    system = [rng.randrange(lo, hi) for _ in range(2 * chunk)]
+    histories = [list(system) for _ in range(clients)]
+    seq = []
+    for turn in range(turns):
+        for ci in rng.sample(range(clients), clients):
+            prompt = histories[ci] + [rng.randrange(lo, hi)
+                                      for _ in range(chunk)]
+            seq.append((f"c{ci}-t{turn}", turn, prompt))
+            histories[ci] = prompt
+    return seq
+
+
+def _run_fleet(monkeypatch, policy):
+    from arks_tpu.engine import EngineConfig, InferenceEngine
+    from arks_tpu.engine.tokenizer import ByteTokenizer
+    from arks_tpu.models import get_config
+    from arks_tpu.server import OpenAIServer
+    cfg = get_config("tiny")
+    monkeypatch.setenv("ARKS_PREFIX_HOST_MB", "8")
+    engines, servers, router = [], [], None
+    try:
+        for _ in range(2):
+            # prefix_cache_mb=1: a retention surplus, so a session's history
+            # STAYS device-resident on its home backend — the locality the
+            # policies compete to exploit.
+            eng = InferenceEngine(cfg, EngineConfig(
+                model="tiny", num_slots=2, max_cache_len=128,
+                prefill_buckets=(16, 32), steps_per_dispatch=4,
+                prefill_chunk=16, kv_layout="paged", prefix_cache_mb=1),
+                ByteTokenizer())
+            eng.start()
+            srv = OpenAIServer(eng, served_model_name="tiny",
+                               host="127.0.0.1", port=0)
+            srv.start(background=True)
+            engines.append(eng)
+            servers.append(srv)
+        monkeypatch.setenv("ARKS_PREFILL_ADDRS", "")
+        monkeypatch.setenv("ARKS_DECODE_ADDRS", ",".join(
+            f"127.0.0.1:{s.port}" for s in servers))
+        # The test polls the sketches itself, between turns.
+        monkeypatch.setenv("ARKS_ROUTER_SKETCH_POLL_S", "600")
+        router = Router(Discovery(None), "tiny", host="127.0.0.1", port=0,
+                        policy=policy, unified=True)
+        router.start(background=True)
+        texts, last_turn = {}, -1
+        for rid, turn, prompt in _fleet_workload(cfg.vocab_size):
+            if turn != last_turn:
+                if router.sketch_on:
+                    router.sketches.poll_once()
+                last_turn = turn
+            with _post(router, json.dumps({
+                    "model": "tiny", "prompt": prompt, "max_tokens": 4,
+                    "temperature": 0, "ignore_eos": True}).encode()) as r:
+                texts[rid] = json.load(r)["choices"][0]["text"]
+        hit = sum(e.metrics.prefix_cache_hit_tokens_total.get(tier=t)
+                  for e in engines for t in ("device", "host"))
+        query = sum(e.metrics.prefix_cache_query_tokens_total.total()
+                    for e in engines)
+        hits = int(router.metrics.route_decisions_total.get(
+            reason="sketch_hit"))
+        return texts, int(query - hit), hits
+    finally:
+        if router is not None:
+            router.stop()
+        for s in servers:
+            s.stop()
+        for e in engines:
+            e.stop()
+
+
+def test_sketch_routing_reprefills_less_than_round_robin_on_a_real_fleet(
+        monkeypatch):
+    """The same multi-turn shared-prefix workload through two real engines
+    behind a real Router, once per policy on a fresh fleet: every request's
+    generated text is the same whichever replica served it, and sketch
+    routing re-prefills strictly fewer tokens (prefix-query tokens less the
+    per-tier hit tokens, summed over the backends) than round robin."""
+    sk_texts, sk_reprefill, sk_hits = _run_fleet(monkeypatch, "cache_aware")
+    rr_texts, rr_reprefill, _ = _run_fleet(monkeypatch, "round_robin")
+    assert sk_texts == rr_texts
+    assert sk_hits > 0, "no request was routed by a sketch hit"
+    assert sk_reprefill < rr_reprefill, (sk_reprefill, rr_reprefill)

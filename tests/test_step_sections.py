@@ -347,10 +347,7 @@ def test_step_scopes_are_on_the_mixed_step(monkeypatch):
 
 
 def test_compilation_counters_count_a_fresh_shape_once(monkeypatch):
-    _, eng = _mk_engine(monkeypatch)
-    # The counter is the process's: let the engine's own off-thread
-    # builds finish before counting this test's.
-    eng._pipe_warm_wait(300)
+    _, eng = _mk_engine(monkeypatch)     # depth 0: nothing builds off-thread
     x7, x9 = jnp.ones((7,)), jnp.ones((9,))
     jax.block_until_ready((x7, x9))
     fn = jax.jit(lambda x: x * 3.0 + 1.0)
