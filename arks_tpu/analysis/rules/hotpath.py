@@ -65,6 +65,17 @@ are INCLUDED here, since fan-out and promotion live in them):
                        (``_OperandPack``); what a program needs computed
                        is computed inside it.
 
+And one over the whole engine file:
+
+- ``direct-output-put``  ``<request>.outputs.put(...)`` in
+                       ``arks_tpu/engine/engine.py`` outside the delivery
+                       helpers (``_deliver``, ``_flush_deferred``).  A
+                       saturated resolve keeps its frames back until the
+                       next dispatch is out; a put that went round the
+                       helper could overtake an earlier frame of its
+                       request (a token after its ``finished``, an abort
+                       before the tokens it ends).
+
 Plus three surface contracts the old guard carried: ``trace-evt-impl``
 (``Tracer.evt`` / ``_Ring`` stay lock- and serialization-free),
 ``sketch-import`` (``prefix_sketch`` stays importable without jax or the
@@ -150,6 +161,9 @@ EXPECTED_TAILS = (
 
 # The step sections whose functions root ``eager-device-call``.
 SEQ_STEP_SECTIONS = ("wait", "dispatch")
+
+# The only engine methods that may put into a request's ``outputs``.
+DELIVERY_HELPERS = ("_deliver", "_flush_deferred")
 
 SERIAL_CALLS = {"json.dumps", "json.loads", "pickle.dumps",
                 "pickle.loads", "pickle.dump", "pickle.load",
@@ -256,6 +270,28 @@ def _eager_device_calls(fn, findings: list[Finding]) -> None:
                 detail=ast.unparse(node)))
 
 
+def _direct_output_puts(tree: SourceTree, findings: list[Finding]) -> None:
+    """Every ``<x>.outputs.put(...)`` of the engine file, module-level
+    classes included, but for the delivery helpers' own."""
+    allowed = set()
+    for node in ast.walk(tree.tree(ENGINE)):
+        if (isinstance(node, ast.FunctionDef)
+                and node.name in DELIVERY_HELPERS):
+            allowed.update(id(sub) for sub in ast.walk(node))
+    for node in ast.walk(tree.tree(ENGINE)):
+        if (isinstance(node, ast.Call) and id(node) not in allowed
+                and isinstance(node.func, ast.Attribute)
+                and node.func.attr == "put"
+                and isinstance(node.func.value, ast.Attribute)
+                and node.func.value.attr == "outputs"):
+            findings.append(Finding(
+                RULE, "direct-output-put", ENGINE, node.lineno,
+                ENGINE_CLASS,
+                "output put outside the delivery helper (route it through "
+                "self._deliver so it cannot overtake a deferred frame)",
+                detail=ast.unparse(node.func)))
+
+
 def _trace_evt_impl(tree: SourceTree, findings: list[Finding]) -> None:
     path = "arks_tpu/obs/trace.py"
     if path not in tree.files:
@@ -348,6 +384,15 @@ def check(tree: SourceTree) -> list[Finding]:
                      for n in sorted(set().union(*seq.values()))]
         for nid in sorted(graph.reachable([r for r in seq_roots if r])):
             _eager_device_calls(graph.nodes[nid], findings)
+
+        for helper in DELIVERY_HELPERS:
+            if helper not in methods:
+                findings.append(Finding(
+                    RULE, "contract", ENGINE, 1,
+                    f"{ENGINE_CLASS}.{helper}",
+                    "delivery helper renamed/removed — direct-output-put "
+                    "has lost its one sanctioned door"))
+        _direct_output_puts(tree, findings)
 
     roots = [nid for nid in (graph.find(*r) for r in ROOTS) if nid]
     reach = graph.reachable(

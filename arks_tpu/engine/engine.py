@@ -647,6 +647,24 @@ class EngineMetrics:
             "Device programs and transfers the engine thread issued on "
             "the sequential step path, by site (step, promote, admit, "
             "clear, draft, warm)")
+        # Frames handed to the requests' output queues, and those of them
+        # that waited for the next dispatch first (deferred delivery: a
+        # resolve that found more callers queued than slots free).
+        self.fanout_outputs_total = r.counter(
+            "fanout_outputs_total",
+            "Output frames (token deltas, first tokens, finish and error "
+            "frames) the engine handed to its requests' readers")
+        self.fanout_deferred_outputs_total = r.counter(
+            "fanout_deferred_outputs_total",
+            "Output frames delivered behind the next dispatch because "
+            "more requests waited in the admission queue than slots were "
+            "free (over fanout_outputs_total: the share of frames a "
+            "saturated engine held back while it handed the device its "
+            "next step)")
+        # An untouched counter renders no sample: the pair stands on
+        # /metrics from the first scrape, so that a share of 0 reads 0.
+        self.fanout_outputs_total.inc(0)
+        self.fanout_deferred_outputs_total.inc(0)
         # XLA compilations seen by this process (jax.monitoring): which
         # step recompiled is an operator's question, not only a bench's.
         self.xla_compilations_total = r.counter(
@@ -1012,6 +1030,11 @@ class InferenceEngine:
         # pick order is exactly the old PriorityQueue order.
         self._queue = fairqueue.FairQueue()
         self._queue_seq = 0
+        # Deferred delivery (_deliver / _flush_deferred): None while every
+        # output goes straight to its reader; a list, in the order
+        # produced, from a resolve that found callers waiting for a slot
+        # until just after the next dispatch.
+        self._deferred: list | None = None
         self._queued_rids: set[str] = set()
         # Deadline-aware shedding (ARKS_SHED_DEADLINE): a popped request
         # whose queue wait already exceeds factor x its tier's ttft_ms
@@ -2898,16 +2921,17 @@ class InferenceEngine:
     @property
     def idle(self) -> bool:
         """No decoding slots, no queued admissions, no chunked prefills,
-        deferred admit batches, or requests parked on a guide compile,
-        host-tier restore, or model switch — the drain gate (servers must
-        not poke at privates)."""
+        deferred admit batches or output frames, or requests parked on a
+        guide compile, host-tier restore, or model switch — the drain gate
+        (servers must not poke at privates)."""
         return (not self._slots and self._queue.empty()
                 and not self._prefilling and not self._pending_admits
                 and not self._awaiting_guide
                 and not self._awaiting_restore
                 and not self._awaiting_fetch
                 and not self._awaiting_model
-                and not self._swap_pending and not self._swapped)
+                and not self._swap_pending and not self._swapped
+                and self._deferred is None)
 
     # ------------------------------------------------------------------
     # Scheduler loop
@@ -3148,7 +3172,9 @@ class InferenceEngine:
             # call outlived stop()'s join window): no scheduler remains to
             # resolve deferred admissions, so fail their clients here ON
             # the engine thread — the only thread allowed to touch
-            # _pending_admits/_pending_n/_free.
+            # _pending_admits/_pending_n/_free.  What a saturated resolve
+            # held back for the next dispatch goes out first.
+            self._flush_deferred()
             self._abort_pending_admits()
             self._abort_awaiting_guide()
             self._abort_awaiting_restores()
@@ -3206,6 +3232,10 @@ class InferenceEngine:
         """Top-level fault handler: attempt quarantine + token-replay
         recovery, escalating to the blanket abort-everything path only
         when recovery itself keeps faulting (crash-loop guard)."""
+        # What a saturated resolve held back was produced before the
+        # fault and counts as emitted: the clients get it before any
+        # error frame or replayed token.
+        self._flush_deferred()
         self._set_state("recovering")
         self._recover_t0 = time.monotonic()
         attempts = max(self._fault_retries + 2, 3)
@@ -3492,7 +3522,7 @@ class InferenceEngine:
                        error: str | None) -> None:
         self._unpin_guide(sv.request)
         self._fault_counts.pop(sv.request.request_id, None)
-        sv.request.outputs.put(RequestOutput(
+        self._deliver(sv.request, RequestOutput(
             request_id=sv.request.request_id, token_ids=[], finished=True,
             finish_reason=reason, error=error,
             num_prompt_tokens=sv.num_prompt,
@@ -3535,7 +3565,7 @@ class InferenceEngine:
             self._finish(slot, "abort")
         for slot, st in list(self._prefilling.items()):
             self._unpin_guide(st.request)
-            st.request.outputs.put(RequestOutput(
+            self._deliver(st.request, RequestOutput(
                 request_id=st.request.request_id, token_ids=[],
                 finished=True, finish_reason="abort",
                 num_prompt_tokens=len(st.ids)))
@@ -3739,7 +3769,9 @@ class InferenceEngine:
             # Windowed-residency slots: span-by-span decode on the host
             # loop (cold pages stream through staging while resident
             # spans attend).  Runs before the classic mixed dispatch so
-            # windowed slots never enter its lanes.
+            # windowed slots never enter its lanes.  Its forward is a
+            # host-sync round trip of its own: deliver first.
+            self._flush_deferred()
             worked = self._residency_step() or worked
             tw = time.monotonic()
             self.metrics.scheduler_seconds_total.inc(tw - t0,
@@ -3805,6 +3837,10 @@ class InferenceEngine:
                 pending = (self._issue_spec_mixed() if spec
                            else self._issue_mixed())
                 issued = pending is not None
+            # A deferral leaves right behind the dispatch, inside the
+            # issue; a step that issued nothing delivers it here, so none
+            # outlives the step after the resolve that opened it.
+            self._flush_deferred()
             t1 = time.monotonic()
             if issued:
                 self.metrics.scheduler_seconds_total.inc(t1 - t0,
@@ -4068,7 +4104,7 @@ class InferenceEngine:
                     self._release_slot_pages(slot)
                     self._free.append(slot)
                 self._unpin_guide(req)
-                req.outputs.put(RequestOutput(
+                self._deliver(req, RequestOutput(
                     request_id=req.request_id, token_ids=[], finished=True,
                     finish_reason="abort", num_prompt_tokens=len(ids)))
 
@@ -4095,7 +4131,7 @@ class InferenceEngine:
             if req.request_id in self._aborted:
                 self._aborted.discard(req.request_id)
                 self._unpin_guide(req)
-                req.outputs.put(RequestOutput(
+                self._deliver(req, RequestOutput(
                     request_id=req.request_id, token_ids=[], finished=True,
                     finish_reason="abort"))
                 return
@@ -4115,7 +4151,7 @@ class InferenceEngine:
                 1, reason="deadline", tier=tier,
                 tenant=self._tenant_labels.label(req.tenant))
             self.trace.evt(req.request_id, "shed", "I", round(waited, 3))
-            req.outputs.put(RequestOutput(
+            self._deliver(req, RequestOutput(
                 request_id=req.request_id, token_ids=[], finished=True,
                 finish_reason="error",
                 error=(f"shed_deadline: queued {waited:.2f}s, tier "
@@ -4159,7 +4195,7 @@ class InferenceEngine:
             if gate == "park":
                 return
             if gate is not None:
-                req.outputs.put(RequestOutput(
+                self._deliver(req, RequestOutput(
                     request_id=req.request_id, token_ids=[], finished=True,
                     finish_reason="error",
                     error=f"guide_compile_failed: {gate}",
@@ -4173,7 +4209,7 @@ class InferenceEngine:
             ids, padded = self._prepare_prompt(req.prompt_ids)
         except ContextLengthExceededError as e:
             self._unpin_guide(req)
-            req.outputs.put(RequestOutput(
+            self._deliver(req, RequestOutput(
                 request_id=req.request_id, token_ids=[], finished=True,
                 finish_reason="error", error="context_length_exceeded",
                 num_prompt_tokens=len(req.prompt_ids)))
@@ -4401,7 +4437,7 @@ class InferenceEngine:
                 self._unpin_guide(req)
                 # The admit program already wrote this slot's shaping rows.
                 self._clear_shaping(slot, req.params)
-                req.outputs.put(RequestOutput(
+                self._deliver(req, RequestOutput(
                     request_id=req.request_id, token_ids=[], finished=True,
                     finish_reason="abort", num_prompt_tokens=len(ids)))
                 continue
@@ -4718,7 +4754,7 @@ class InferenceEngine:
                     self._alloc.decref(rec.pages)
                     self._free.append(rec.slot)
                     self._unpin_guide(rec.request)
-                    rec.request.outputs.put(RequestOutput(
+                    self._deliver(rec.request, RequestOutput(
                         request_id=rid, token_ids=[], finished=True,
                         finish_reason="abort",
                         num_prompt_tokens=rec.rec.num_prompt,
@@ -4754,7 +4790,7 @@ class InferenceEngine:
                 self._alloc.decref(rec.shared)
                 self._alloc.decref(rec.pages)
                 self._unpin_guide(rec.request)
-                rec.request.outputs.put(RequestOutput(
+                self._deliver(rec.request, RequestOutput(
                     request_id=rid, token_ids=[], finished=True,
                     finish_reason="abort", num_prompt_tokens=len(rec.ids)))
                 continue
@@ -4803,7 +4839,7 @@ class InferenceEngine:
         for rec in self._awaiting_restore:
             self.metrics.num_requests_waiting.inc(-1)
             self._unpin_guide(rec.request)
-            rec.request.outputs.put(RequestOutput(
+            self._deliver(rec.request, RequestOutput(
                 request_id=rec.request.request_id, token_ids=[],
                 finished=True, finish_reason="abort",
                 num_prompt_tokens=len(rec.ids)))
@@ -5090,7 +5126,7 @@ class InferenceEngine:
                 did = True
                 self.metrics.num_requests_waiting.inc(-1)
                 self._unpin_guide(st.request)
-                st.request.outputs.put(RequestOutput(
+                self._deliver(st.request, RequestOutput(
                     request_id=rid, token_ids=[], finished=True,
                     finish_reason="abort", num_prompt_tokens=len(st.ids)))
                 continue
@@ -5172,7 +5208,7 @@ class InferenceEngine:
         for st in self._awaiting_fetch:
             self.metrics.num_requests_waiting.inc(-1)
             self._unpin_guide(st.request)
-            st.request.outputs.put(RequestOutput(
+            self._deliver(st.request, RequestOutput(
                 request_id=st.request.request_id, token_ids=[],
                 finished=True, finish_reason="abort",
                 num_prompt_tokens=len(st.ids)))
@@ -5712,7 +5748,7 @@ class InferenceEngine:
         its state was off-device)."""
         self.metrics.num_requests_waiting.inc(-1)
         self._unpin_guide(rec.request)
-        rec.request.outputs.put(RequestOutput(
+        self._deliver(rec.request, RequestOutput(
             request_id=rec.request.request_id, token_ids=[],
             finished=True, finish_reason="abort",
             num_prompt_tokens=rec.num_prompt,
@@ -5822,7 +5858,7 @@ class InferenceEngine:
                 or not (want == self._primary_model or self.pool.has(want))):
             error = ("model_not_found" if self.pool is not None
                      and self.dispatcher is None else "multi_model_unsupported")
-            req.outputs.put(RequestOutput(
+            self._deliver(req, RequestOutput(
                 request_id=req.request_id, token_ids=[], finished=True,
                 finish_reason="error", error=error,
                 num_prompt_tokens=len(req.prompt_ids)))
@@ -5839,7 +5875,7 @@ class InferenceEngine:
         no scheduler remains to switch models for them."""
         for req, _want, _t in self._awaiting_model:
             self.metrics.num_requests_waiting.inc(-1)
-            req.outputs.put(RequestOutput(
+            self._deliver(req, RequestOutput(
                 request_id=req.request_id, token_ids=[], finished=True,
                 finish_reason="abort", num_prompt_tokens=len(req.prompt_ids)))
         self._awaiting_model = []
@@ -5855,7 +5891,7 @@ class InferenceEngine:
                 continue
             self.metrics.num_requests_waiting.inc(-1)
             self._fault_counts.pop(req.request_id, None)
-            req.outputs.put(RequestOutput(
+            self._deliver(req, RequestOutput(
                 request_id=req.request_id, token_ids=[], finished=True,
                 finish_reason="error", error=error,
                 num_prompt_tokens=len(req.prompt_ids)))
@@ -5914,7 +5950,7 @@ class InferenceEngine:
                     keep.append((req, want, t))
                     continue
                 self.metrics.num_requests_waiting.inc(-1)
-                req.outputs.put(RequestOutput(
+                self._deliver(req, RequestOutput(
                     request_id=req.request_id, token_ids=[], finished=True,
                     finish_reason="abort",
                     num_prompt_tokens=len(req.prompt_ids)))
@@ -6277,6 +6313,7 @@ class InferenceEngine:
         worked = self._resize_evict_slots() or worked
         if not self._drained_for_resize():
             return worked
+        self._flush_deferred()   # the reshard takes seconds
         self._execute_resize(req)
         return True
 
@@ -6687,7 +6724,7 @@ class InferenceEngine:
             # first-token logprob data (pre-upgrade prefill peer): serving
             # a partial stream would be silently wrong — reject cleanly.
             self._unpin_guide(req)
-            req.outputs.put(RequestOutput(
+            self._deliver(req, RequestOutput(
                 request_id=req.request_id, token_ids=[], finished=True,
                 finish_reason="error", error="logprobs_unavailable",
                 num_prompt_tokens=pf.num_prompt))
@@ -6696,7 +6733,7 @@ class InferenceEngine:
         k, v = jnp.asarray(pf.k), jnp.asarray(pf.v)
         if pf.num_prompt > usable:
             self._unpin_guide(req)
-            req.outputs.put(RequestOutput(
+            self._deliver(req, RequestOutput(
                 request_id=req.request_id, token_ids=[], finished=True,
                 finish_reason="abort", num_prompt_tokens=pf.num_prompt))
             return
@@ -6846,7 +6883,7 @@ class InferenceEngine:
                 self._aborted.discard(req.request_id)
             if was_aborted:
                 self.metrics.num_requests_waiting.inc(-1)
-                req.outputs.put(RequestOutput(
+                self._deliver(req, RequestOutput(
                     request_id=req.request_id, token_ids=[], finished=True,
                     finish_reason="abort",
                     num_prompt_tokens=len(req.prompt_ids)))
@@ -6858,7 +6895,7 @@ class InferenceEngine:
             self.trace.evt(req.request_id, "park.guide", "E")
             if ticket.error is not None:
                 self.metrics.num_requests_waiting.inc(-1)
-                req.outputs.put(RequestOutput(
+                self._deliver(req, RequestOutput(
                     request_id=req.request_id, token_ids=[], finished=True,
                     finish_reason="error",
                     error=f"guide_compile_failed: {ticket.error}",
@@ -6883,7 +6920,7 @@ class InferenceEngine:
         no scheduler remains to unpark them."""
         for req, _ in self._awaiting_guide:
             self.metrics.num_requests_waiting.inc(-1)
-            req.outputs.put(RequestOutput(
+            self._deliver(req, RequestOutput(
                 request_id=req.request_id, token_ids=[], finished=True,
                 finish_reason="abort",
                 num_prompt_tokens=len(req.prompt_ids)))
@@ -7066,7 +7103,7 @@ class InferenceEngine:
         if self._check_finished(slot):
             return
         st.num_emitted = 1
-        req.outputs.put(RequestOutput(
+        self._deliver(req, RequestOutput(
             request_id=req.request_id, token_ids=[first],
             num_prompt_tokens=num_prompt, ttft_s=ttft,
             logprobs=list(st.logprobs) if st.logprobs else None))
@@ -7228,7 +7265,7 @@ class InferenceEngine:
                 self._release_slot_pages(slot)
                 self._free.append(slot)
                 self._unpin_guide(st.request)
-                st.request.outputs.put(RequestOutput(
+                self._deliver(st.request, RequestOutput(
                     request_id=rid, token_ids=[], finished=True,
                     finish_reason="abort", num_prompt_tokens=len(st.ids)))
                 return
@@ -7667,6 +7704,10 @@ class InferenceEngine:
         device already finished."""
         if len(self._pipe_inflight) < self._pipe_depth:
             self._pipe_issue()
+        # A saturated sequential resolve may have left a deferral open:
+        # it leaves behind this step's issue (the resolves below run
+        # behind a dispatch anyway and never open one).
+        self._flush_deferred("phase.decode.deliver")
         if len(self._pipe_inflight) >= self._pipe_depth:
             self._pipe_resolve_one()
         else:
@@ -7999,11 +8040,14 @@ class InferenceEngine:
 
     def _fanout_decode_tokens(self, slot: int, col: list, lp_rows,
                               dt: float) -> None:
-        """Per-slot tail shared by the sequential resolve and the
-        pipelined resolve: append the dispatch's K tokens (truncating at
-        the first stop token or the max_tokens cutoff — everything past it
-        is overshoot the device computed but the client never sees),
-        advance the host mirrors, and finish or stream the delta."""
+        """Per-slot tail shared by every resolve (the mixed one hands it
+        a one-token column, the spec-mixed one the accepted block, the
+        legacy and the pipelined one a dispatch's K rows): append the
+        tokens (truncating at the first stop token or the max_tokens
+        cutoff — everything past it is overshoot the device computed but
+        the client never sees), advance the host mirrors, and finish or
+        stream the delta.  This is the bookkeeping the next batch needs;
+        the frame itself goes through _deliver."""
         st = self._slots[slot]
         K = len(col)
         n_lp = st.request.params.logprobs
@@ -8032,7 +8076,7 @@ class InferenceEngine:
             lp_delta = (st.logprobs[st.num_emitted:]
                         if n_lp is not None else None)
             st.num_emitted = len(st.generated)
-            st.request.outputs.put(RequestOutput(
+            self._deliver(st.request, RequestOutput(
                 request_id=st.request.request_id, token_ids=delta,
                 num_prompt_tokens=st.num_prompt,
                 logprobs=lp_delta))
@@ -8062,7 +8106,7 @@ class InferenceEngine:
                 self._release_slot_pages(slot)
                 self._free.append(slot)
                 self._unpin_guide(st.request)
-                st.request.outputs.put(RequestOutput(
+                self._deliver(st.request, RequestOutput(
                     request_id=rid, token_ids=[], finished=True,
                     finish_reason="abort", num_prompt_tokens=len(st.ids)))
                 consumed.add(rid)
@@ -8423,6 +8467,7 @@ class InferenceEngine:
         if sec:
             evt("", tag + "dispatch", "E",
                 "arks_mixed_seq_lp" if want_lp else "arks_mixed_seq")
+        self._flush_deferred(tag + "deliver")
         return (dec_slots, completing, chunk_take, want_lp, ids_dev,
                 lp_devs, t0)
 
@@ -8454,33 +8499,15 @@ class InferenceEngine:
         if sec:
             evt("", tag + "wait", "E")
             evt("", tag + "fanout", "B")
+        self._defer_if_saturated()
         n_live = len(self._slots)
         dt = max(time.monotonic() - t0 - exclude_s, 1e-6)
         for slot in dec_slots:
-            st = self._slots[slot]
-            tok = int(ids[slot])
-            n_lp = st.request.params.logprobs
-            st.generated.append(tok)
-            if want_lp and n_lp is not None:
-                st.logprobs.append(self._lp_entry(
-                    clps[slot], lvals[slot], lids[slot], n_lp))
-            self._lengths[slot] += 1
-            self._last_token[slot] = tok
-            self.metrics.generation_tokens_total.inc(1)
-            self.metrics.time_per_output_token_seconds.observe(dt)
-            self.metrics.tpot_seconds.observe(
-                dt, tier=self._slo.tier_of(st.request.params.priority))
-            if (self._is_stop(st, tok)
-                    or len(st.generated) >= st.request.params.max_tokens):
-                self._finish(slot, self._finish_reason(st))
-            else:
-                delta = st.generated[st.num_emitted:]
-                lp_delta = (st.logprobs[st.num_emitted:]
-                            if n_lp is not None else None)
-                st.num_emitted = len(st.generated)
-                st.request.outputs.put(RequestOutput(
-                    request_id=st.request.request_id, token_ids=delta,
-                    num_prompt_tokens=st.num_prompt, logprobs=lp_delta))
+            lp_rows = None
+            if (want_lp and self._slots[slot].request.params.logprobs
+                    is not None):
+                lp_rows = ([clps[slot]], [lvals[slot]], [lids[slot]])
+            self._fanout_decode_tokens(slot, [int(ids[slot])], lp_rows, dt)
         if sec:
             evt("", tag + "fanout", "E",
                 (len(dec_slots), n_live - len(self._slots)))
@@ -8613,6 +8640,7 @@ class InferenceEngine:
         if sec:
             evt("", tag + "dispatch", "E",
                 "arks_spec_mixed_lp" if want_lp else "arks_spec_mixed")
+        self._flush_deferred(tag + "deliver")
         return (dec_slots, completing, chunk_take, want_lp, out_dev,
                 counts_dev, comp_dev, lp_devs, t0)
 
@@ -8644,6 +8672,7 @@ class InferenceEngine:
         if sec:
             evt("", tag + "wait", "E")
             evt("", tag + "fanout", "B")
+        self._defer_if_saturated()
         n_live = len(self._slots)
         dt = max(time.monotonic() - t0 - exclude_s, 1e-6)
         n_spec = accepted = 0
@@ -8745,7 +8774,7 @@ class InferenceEngine:
         lp_delta = None
         if p.logprobs is not None and st.logprobs:
             lp_delta = st.logprobs[st.num_emitted: len(final_ids)]
-        st.request.outputs.put(RequestOutput(
+        self._deliver(st.request, RequestOutput(
             request_id=st.request.request_id,
             token_ids=delta,
             logprobs=lp_delta,
@@ -8757,3 +8786,55 @@ class InferenceEngine:
         self.metrics.request_success_total.inc(reason=reason)
         self.metrics.num_requests_running.set(len(self._slots))
         self.trace.evt(st.request.request_id, "finish", "I", reason)
+
+    # ------------------------------------------------------------------
+    # Output delivery
+    # ------------------------------------------------------------------
+
+    def _deliver(self, req: Request, out: RequestOutput) -> None:
+        """Hand ``out`` to ``req``'s reader: the ONE door from the engine
+        thread to ``request.outputs`` (arkslint ``direct-output-put``), so
+        that no frame overtakes an earlier one of its request.  While a
+        deferral is open the frame joins it instead and leaves with
+        _flush_deferred."""
+        if self._deferred is not None:
+            self._deferred.append((req, out))
+            return
+        req.outputs.put(out)
+        self.metrics.fanout_outputs_total.inc(1)
+
+    def _defer_if_saturated(self) -> None:
+        """Open a deferral iff more requests wait in the admission queue
+        than slots are free: _admit pops while a slot is free, so callers
+        are then waiting for a SLOT, every millisecond the device idles is
+        queue time for one of them, and the next step's batch needs this
+        step's token VALUES, not their delivery.  Each put wakes a reader
+        thread that wants the GIL (docs/monitoring.md, ``deliver``); with
+        nobody waiting the streams go first."""
+        if self._deferred is None and self._queue.qsize() > len(self._free):
+            self._deferred = []
+
+    def _flush_deferred(self, section: str = "phase.step.deliver") -> None:
+        """Close the open deferral, if any, and deliver its frames in the
+        order they were produced, as the step section ``section``.  Called
+        right after the next step's dispatch (the device is busy, and the
+        engine thread's next blocking call, the wait, gives the readers
+        the GIL), and before anything that keeps the engine thread from
+        that dispatch: a step that issues nothing, the residency forward,
+        a resize at its drained boundary, recovery, the loop's exit.  (An
+        open deferral means a non-empty queue, so the engine is neither
+        idle nor drained for a model switch or a scale to zero.)"""
+        batch = self._deferred
+        if batch is None:
+            return
+        self._deferred = None
+        sec = self.profiler.sections
+        if sec:
+            self.trace.evt("", section, "B")
+        for req, out in batch:
+            req.outputs.put(out)
+        if batch:
+            self.metrics.fanout_outputs_total.inc(len(batch))
+            self.metrics.fanout_deferred_outputs_total.inc(len(batch))
+        if sec:
+            self.trace.evt("", section, "E", len(batch))

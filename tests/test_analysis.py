@@ -186,6 +186,41 @@ def test_hotpath_eager_check_reports_lost_roots():
     assert len(lost) == 2          # neither a wait nor a dispatch section
 
 
+_DELIVERY_FIXTURE = {
+    "arks_tpu/engine/engine.py": (
+        "class _Gate:\n"
+        "    def put(self, out):\n"
+        "        self._inner.put(out)\n"
+        "class InferenceEngine:\n"
+        "    def _deliver(self, req, out):\n"
+        "        req.outputs.put(out)\n"
+        "    def _flush_deferred(self):\n"
+        "        for req, out in self._deferred:\n"
+        "            req.outputs.put(out)\n"
+        "    def _finish(self, st):\n"
+        "        self._deliver(st.request, 1)\n"
+        "    def _abort(self, st):\n"
+        "        st.request.outputs.put(2)\n"
+        "        self._queue.put(st)\n"
+    ),
+}
+
+
+def test_hotpath_flags_an_output_put_outside_the_delivery_helpers():
+    findings = run_rules(SourceTree(_DELIVERY_FIXTURE), ["hotpath"])
+    direct = [(f.line, f.detail) for f in findings
+              if f.check == "direct-output-put"]
+    # the helpers' own puts, a gate's inner queue and the admission queue
+    # are no findings; the one that goes round the helper is
+    assert direct == [(13, "st.request.outputs.put")]
+    # ... and an engine without the helpers has lost its one door
+    lost = [f.qualname for f in run_rules(SourceTree(_ENGINE_FIXTURE),
+                                          ["hotpath"])
+            if f.check == "contract" and "direct-output-put" in f.message]
+    assert lost == ["InferenceEngine._deliver",
+                    "InferenceEngine._flush_deferred"]
+
+
 # ----------------------------------------------------------- acceptance
 
 # The hand-curated allowlist the analyzer replaced (tests/

@@ -50,6 +50,13 @@ def test_no_eager_device_call_between_wait_and_dispatch():
     assert not _errors("eager-device-call"), _errors("eager-device-call")
 
 
+def test_no_output_put_outside_the_delivery_helper():
+    """Every frame of ``engine.py`` reaches its reader through
+    ``_deliver`` (or leaves a deferral through ``_flush_deferred``): a
+    direct ``.outputs.put(`` could overtake a deferred frame."""
+    assert not _errors("direct-output-put"), _errors("direct-output-put")
+
+
 def test_no_sweep_reachable_from_step_loop():
     assert not _errors("autotune-sweep"), _errors("autotune-sweep")
 

@@ -134,3 +134,28 @@ def test_census_matches_live_registries():
     missing = live - static
     assert not missing, f"live families invisible to the census: {missing}"
     assert len(static) > 40  # the census actually saw the real registries
+
+
+def test_fanout_counter_pair_renders_beside_each_other():
+    """Deferred delivery's pair (PR 30): every frame handed to a reader,
+    and those of them that left behind the next dispatch; both unlabeled
+    counters, both on the engine's /metrics from the first scrape on (a
+    share needs its denominator even while it reads 0)."""
+    from arks_tpu.engine.engine import EngineMetrics
+    m = EngineMetrics()
+    kinds = {fam.name: fam.type for fam in m.registry.families()}
+    assert kinds["fanout_outputs_total"] == "counter"
+    assert kinds["fanout_deferred_outputs_total"] == "counter"
+
+    def rendered():
+        return {line.rpartition(" ")[0]: float(line.rpartition(" ")[2])
+                for line in m.registry.render().splitlines()
+                if line.startswith("fanout_")}
+
+    assert rendered() == {"fanout_outputs_total": 0.0,
+                          "fanout_deferred_outputs_total": 0.0}
+    m.fanout_outputs_total.inc(5)
+    m.fanout_deferred_outputs_total.inc(2)
+    assert rendered() == {"fanout_outputs_total": 5.0,
+                          "fanout_deferred_outputs_total": 2.0}
+

@@ -28,8 +28,11 @@ from arks_tpu.models import get_config
 from arks_tpu.obs import profiler as prof_mod
 from arks_tpu.obs.trace import Tracer
 
-SECTIONS = ("retire", "pack", "count", "dispatch", "wait", "fanout",
-            "promote")
+# ``deliver`` (PR 30): the workload below puts three requests on two
+# slots, so its resolves find a request waiting for a slot and hand their
+# frames out behind the next dispatch.
+SECTIONS = ("retire", "pack", "count", "dispatch", "deliver", "wait",
+            "fanout", "promote")
 
 
 def _mk_engine(monkeypatch, *, depth=0, spec=False, **kw):
@@ -162,7 +165,10 @@ def test_pipelined_and_spec_steps_have_their_sections(monkeypatch, tmp_path,
     cfg, eng = _mk_engine(monkeypatch, depth=2, spec=spec)
     _workload(eng, cfg, "warm")
     eng.profiler.start(str(tmp_path / "p"))
-    _workload(eng, cfg, "in")
+    # other prompts than the warm-up's: a prompt found in the prefix cache
+    # prefills in one step, the step after it is pipelined, and what its
+    # resolve held back leaves as ``phase.decode.deliver``
+    _workload(eng, cfg, "in", shift=50)
     names = {s["name"] for s in eng.profiler.stop()["spans"]}
     assert {"phase.decode.issue", "phase.decode.resolve"} <= names
     tag = "phase.spec." if spec else "phase.mixed."
