@@ -156,6 +156,14 @@ class EngineConfig:
     # prefilled.  Requires prefill_chunk (reuse lands on chunk boundaries);
     # single-host only (harvest needs fully-addressable arrays).
     prefix_cache_mb: int = 256
+    # Pages of the full-attention pool of a model with window and full
+    # attention layers (0: every slot's whole context, num_slots x
+    # max_cache_len).  Below that, admission RESERVES a request's pages
+    # (prompt + max_tokens) before it takes a slot, and a request the
+    # pool cannot hold yet waits at the head of the queue
+    # (InferenceEngine._pool_fits).  Refused by name for any other model:
+    # their admission paths count slots only.
+    kv_pool_pages: int = 0
     seed: int = 0
 
     def resolve_kv_cache_dtype(self) -> str:
@@ -717,7 +725,34 @@ class EngineMetrics:
         # KV streaming waste factor (docs/monitoring.md alert).
         self.mixed_kv_bytes_total = r.counter(
             "mixed_kv_bytes_total",
-            "KV bytes streamed from HBM by mixed dispatches (plan mirror)")
+            "KV bytes streamed from HBM by mixed dispatches (plan mirror), "
+            "a layer of each kind (full; window: a window layer's launch)")
+        # A model with window and full attention layers (two page pools).
+        self.kv_pages_in_use = r.gauge(
+            "kv_pages_in_use",
+            "Pool pages held by slots or the prefix index, by kind (full: "
+            "pages that live as long as their sequence; window: a window "
+            "layer's, released behind the window)")
+        self.kv_pages_reserved = r.gauge(
+            "kv_pages_reserved",
+            "Pages of the full pool that admission has promised to the "
+            "live sequences (prompt + max_tokens each), under "
+            "--kv-pool-pages; 0 where the pool holds every slot's whole "
+            "context")
+        self.admission_page_waits_total = r.counter(
+            "admission_page_waits_total",
+            "Requests that found a free slot and not the pages of the full "
+            "pool their prompt and max_tokens need, and waited at the head "
+            "of the queue for them (--kv-pool-pages)")
+        self.kv_window_pages_released_total = r.counter(
+            "kv_window_pages_released_total",
+            "Window-layer pages released because they lay wholly behind "
+            "their slot's window (not: pages of finished sequences)")
+        self.kv_window_page_steps_total = r.counter(
+            "kv_window_page_steps_total",
+            "Window-layer pages summed over mixed dispatches, by state "
+            "(held: in use at the dispatch; unreleased: what the same "
+            "sequences would hold had no page been released)")
         self.mixed_kv_bytes_ideal_total = r.counter(
             "mixed_kv_bytes_ideal_total",
             "KV bytes a perfect once-per-page schedule would stream for "
@@ -1295,9 +1330,12 @@ class InferenceEngine:
         self.ecfg = engine_cfg
         if cfg.latent:
             self._latent_preflight(cfg, engine_cfg, draft_cfg)
+        if cfg.windowed:
+            self._windowed_preflight(cfg, engine_cfg, draft_cfg)
         # The step returns two counts beside its token ids (held pairs,
         # valid rows): _count_held.
-        self._held_stat = bool(cfg.latent and cfg.num_experts)
+        self._held_stat = bool((cfg.latent or cfg.windowed)
+                               and cfg.num_experts)
         # Per-model KV dtype preference: a checkpoint that ships
         # kv_cache_dtype in its ModelConfig wins over the engine's "auto"
         # (an explicit EngineConfig setting still overrides the model).
@@ -1431,6 +1469,23 @@ class InferenceEngine:
         self._alloc = None
         self._tables = None
         self._slot_pages: dict[int, list[int]] = {}
+        # The window layers' pages (engine/paged.py::WindowPages): a model
+        # with window and full attention layers only.
+        self._win = None
+        # The pages of the full pool that admission may promise (0: the
+        # pool holds every slot's whole context, and a free slot is
+        # promise enough), what each slot was promised, and the request
+        # that waits for pages at the head of the queue (_pool_fits).
+        self._pool_budget = 0
+        self._pool_reserved: dict[int, int] = {}
+        self._pool_waiting = None
+        if engine_cfg.kv_pool_pages and not (self._paged and cfg.windowed):
+            raise ValueError(
+                f"kv_pool_pages={engine_cfg.kv_pool_pages}: an admission "
+                "that reserves pages exists for a model with window and "
+                "full attention layers on the paged layout only (every "
+                "other admission path counts slots, and its pool holds "
+                "every slot's whole context)")
         if self._paged:
             from arks_tpu.engine.paged import PageAllocator
             page = self._page_size()
@@ -1442,16 +1497,22 @@ class InferenceEngine:
                        else jnp.dtype(self._cache_dtype(dtype)).itemsize * 8)
             d_store = tf.cache_head_dim(cfg, self._pad_head())
             # K and V; a latent page holds its one row once.
-            page_bytes = (cfg.num_layers * cfg.num_kv_heads * page
+            # (A page of the full-attention pool: every layer, or the
+            # full layers of a model that also has window layers.)
+            page_bytes = (cfg.num_full_layers * cfg.num_kv_heads * page
                           * d_store * kv_bits // 8
                           * (1 if cfg.latent else 2))
             if engine_cfg.kv_quantized:
-                page_bytes += cfg.num_layers * cfg.num_kv_heads * page * 4 * 2
+                page_bytes += (cfg.num_full_layers * cfg.num_kv_heads
+                               * page * 4 * 2)
             extra = 0
             # Retention pages only help when prefix sharing can actually
             # register/match them, which rides the chunk path — under pp
-            # (chunking off) they would be permanently dead HBM.
-            if engine_cfg.prefix_cache_mb and self._chunk:
+            # (chunking off) they would be permanently dead HBM; nor does a
+            # model with window layers register any
+            # (_register_prompt_pages).
+            if (engine_cfg.prefix_cache_mb and self._chunk
+                    and not cfg.windowed):
                 extra = max(engine_cfg.prefix_cache_mb * 2**20 // page_bytes, 0)
                 # The byte budget is tuned for 7B-class pools; cap by
                 # proportion so tiny test models don't allocate huge pools.
@@ -1476,12 +1537,31 @@ class InferenceEngine:
                 per_slot = window
                 self._residency_window = window
             num_pages = engine_cfg.num_slots * per_slot + extra
+            if engine_cfg.kv_pool_pages:
+                # The full pool sized to what the callers can occupy, not
+                # to num_slots x max_cache_len: admission then reserves
+                # pages.  One sequence of the whole context must fit, or
+                # its request could never be admitted.
+                if not max_pages <= engine_cfg.kv_pool_pages <= num_pages:
+                    raise ValueError(
+                        f"kv_pool_pages={engine_cfg.kv_pool_pages}: must "
+                        f"hold one whole context ({max_pages} pages of "
+                        f"{page} tokens) and at most every slot's "
+                        f"({num_pages})")
+                num_pages = self._pool_budget = engine_cfg.kv_pool_pages
             self._page_bytes = page_bytes
-            self._cache = tf.init_paged_cache(
-                cfg, num_pages, page, self._cache_dtype(dtype),
-                quantized=engine_cfg.kv_quantized,
-                pad_head=self._pad_head(),
-                kv_bits=min(engine_cfg.kv_bits, 8))
+            if cfg.windowed:
+                from arks_tpu.engine.paged import (WindowPages,
+                                                   window_pages_per_slot)
+                # Rows one dispatch burst writes a slot: a prefill chunk's
+                # budget, or a decode row a dispatch in flight.
+                rows = max(self._mixed_budget_cfg(),
+                           self._pipe_depth_cfg + 1)
+                self._win = WindowPages(
+                    engine_cfg.num_slots, max_pages, page,
+                    cfg.sliding_window, window_pages_per_slot(
+                        cfg.sliding_window, rows, page, max_pages))
+            self._cache = self._init_paged_cache(num_pages, dtype)
             if mesh is not None:
                 self._cache = self._shard_paged(self._cache)
             self._alloc = PageAllocator(num_pages, page)
@@ -1495,6 +1575,14 @@ class InferenceEngine:
                      "%d bytes a token%s", num_pages, page, extra,
                      self._cache.token_bytes,
                      " (one latent row, stored once)" if cfg.latent else "")
+            if self._win is not None:
+                log.info("window layers: %d pages x %d tokens of their own "
+                         "(%d a slot: window %d + the rows of a step), %d "
+                         "bytes a token over %d layers; the full pool "
+                         "above holds %d layers", self._win.alloc.num_pages,
+                         page, self._win.per_slot, cfg.sliding_window,
+                         self._cache.win.token_bytes, cfg.num_window_layers,
+                         cfg.num_full_layers)
         else:
             self._max_pages = 0
             self._page_bytes = 0
@@ -1534,7 +1622,8 @@ class InferenceEngine:
             raise ValueError(
                 f"ARKS_PREFIX_HOST_MB={host_mb}: must be >= 0")
         self._host_mb = host_mb if (self._paged and self._chunk
-                                    and host_mb and not cfg.latent) else 0
+                                    and host_mb and not cfg.latent
+                                    and not cfg.windowed) else 0
         if keep_tiers is not None:
             # Elastic rebuild: adopt the surviving tier-1 store (blocks
             # are full logical host arrays — mesh-shape-independent).
@@ -1708,9 +1797,10 @@ class InferenceEngine:
                 f"(resolved kv_layout={'paged' if self._paged else 'slot'}, "
                 f"prefill_chunk={self._chunk or None}, "
                 f"ARKS_MIXED_STEP={_mx})")
-        if cfg.latent and not self._mixed:
+        if (cfg.latent or cfg.windowed) and not self._mixed:
             raise ValueError(
-                f"model {cfg.name!r} (latent attention) is served by the "
+                f"model {cfg.name!r} (latent attention, or window layers) "
+                "is served by the "
                 "mixed scheduler only; the legacy scheduler speaks K and V "
                 f"(resolved kv_layout={'paged' if self._paged else 'slot'}, "
                 f"prefill_chunk={self._chunk or None}, "
@@ -1720,13 +1810,18 @@ class InferenceEngine:
         # (_mixed_grid_counters): the plan is static per engine shape, so
         # the issue path pays one dict hit per dispatch.
         self._grid_plans: dict[int, dict] = {}
+        # A SECOND, smaller shape of the sequential step's program, for
+        # the steps that carry a prompt's tail or no prompt row at all: a
+        # step costs its whole shape whatever it carries, and with a
+        # budget of several pages most of a short step was padding
+        # (PERF.md).  A quarter of the budget, where the budget is four
+        # pages or more; 0 where it is not (one shape, as ever).
+        self._mixed_tail = 0
         if self._mixed:
-            budget = knobs.get_int("ARKS_MIXED_CHUNK_TOKENS",
-                                   fallback=self._chunk)
-            if budget < 1:
-                raise ValueError(
-                    f"ARKS_MIXED_CHUNK_TOKENS={budget}: must be >= 1")
-            self._mixed_budget = min(budget, engine_cfg.max_cache_len)
+            self._mixed_budget = self._mixed_budget_cfg()
+            if (self._paged and self._draft_cfg is None
+                    and self._mixed_budget >= 4 * self._page_size()):
+                self._mixed_tail = self._mixed_budget // 4
         self._decode_impl = self._resolve_decode_impl()
         if (self._mixed and self._decode_impl == "pallas"
                 and jax.default_backend() == "tpu"):
@@ -1849,7 +1944,10 @@ class InferenceEngine:
             "kv_dtype": self.ecfg.resolve_kv_cache_dtype(),
             # What a page holds: K and V per KV head, or ONE latent row a
             # token (latent attention), which is key and value at once.
-            "kv_page": "latent" if cfg.latent else "kv",
+            # "kv+window": two pools, the window layers' pages released
+            # behind the window.
+            "kv_page": ("latent" if cfg.latent else
+                        "kv+window" if cfg.windowed else "kv"),
             # This chip's share of each routed layer ("rank/size"; "0/1":
             # every expert is held here).
             "expert_share": f"{cfg.expert_parallel_rank}/"
@@ -2344,13 +2442,21 @@ class InferenceEngine:
                 return jnp.concatenate([ids, jnp.stack(
                     [held[0], jnp.sum(valid).astype(jnp.int32)])])
 
-            self._mixed_pack = pack = _OperandPack(self._mixed_fields(
+            self._mixed_pack = _OperandPack(self._mixed_fields(
                 self.ecfg.num_slots + self._mixed_budget))
+            # The tail shape's operands (None: one shape).  The program
+            # knows its shape by the operand's length.
+            self._mixed_tail_pack = _OperandPack(self._mixed_fields(
+                self.ecfg.num_slots + self._mixed_tail)) \
+                if self._mixed_tail else None
+            self._mixed_tail_warm = not self._mixed_tail
+            packs = {pk.size: pk for pk in (self._mixed_pack,
+                                            self._mixed_tail_pack) if pk}
 
             def mixed_prog(params, cache, sampling, operands, gtables,
                            want_lp: bool):
                 return mixed_body(params, cache, sampling, gtables, want_lp,
-                                  **pack.unpack(operands))
+                                  **packs[operands.shape[0]].unpack(operands))
 
             def mixed_body(params, cache, sampling, gtables, want_lp: bool,
                            *, tokens, token_slot, token_pos, tables,
@@ -2358,13 +2464,14 @@ class InferenceEngine:
                            seq_q_start, seq_q_len, seq_pos_start, ov_mask,
                            ov_temp, ov_top_p, ov_top_k, ov_key, ov_bias_ids,
                            ov_bias_vals, ov_sup, ov_min_until, ov_guide,
-                           ov_guide_row):
+                           ov_guide_row, win_tables=None):
                 sampling = sampler_mod.count_tokens(sampling, feed_tokens,
                                                     feed_active)
                 logits, cache, *held = tf.mixed_step(
                     params, cfg, cache, tables, tokens, token_slot,
                     token_pos, sample_src, seq_q_start, seq_q_len,
-                    seq_pos_start, mesh, with_held=held_stat)
+                    seq_pos_start, mesh, with_held=held_stat,
+                    win_tables=win_tables)
                 # The override columns are sampler work too (arks.sampler
                 # in a profile, like the sampler's own functions).
                 with jax.named_scope("arks.sampler"):
@@ -2433,6 +2540,11 @@ class InferenceEngine:
                            dead_len, sstate, tables, gtables, want_lp: bool):
                 eff = jnp.where(alive, lengths, jnp.int32(sentinel))
                 sstate = sampler_mod.count_tokens(sstate, tokens, alive)
+                # A model with window layers hands over both kinds of
+                # table (_tables_arg).
+                win_tables = None
+                if isinstance(tables, tuple):
+                    tables, win_tables = tables
                 # Decode-only flat batch, lane t == slot t: dead lanes
                 # park at the sentinel position (writes dropped, nothing
                 # attended) exactly like the host-built batch's padding.
@@ -2440,7 +2552,7 @@ class InferenceEngine:
                     params, cfg, cache, tables, tokens,
                     jnp.where(alive, lane, jnp.int32(-1)), eff,
                     lane, lane, alive.astype(jnp.int32), eff, mesh,
-                    with_held=held_stat)
+                    with_held=held_stat, win_tables=win_tables)
                 fed = alive
                 nxt, sstate = sampler_mod.sample(logits, sstate, alive,
                                                  eff, guide_tables=gtables)
@@ -2930,12 +3042,40 @@ class InferenceEngine:
                 and not self._awaiting_restore
                 and not self._awaiting_fetch
                 and not self._awaiting_model
+                and self._pool_waiting is None
                 and not self._swap_pending and not self._swapped
                 and self._deferred is None)
 
     # ------------------------------------------------------------------
     # Scheduler loop
     # ------------------------------------------------------------------
+
+    def _mixed_budget_cfg(self) -> int:
+        """ARKS_MIXED_CHUNK_TOKENS as a mixed engine takes it: the prefill
+        rows of one step (default: one chunk), inside the cache."""
+        budget = knobs.get_int("ARKS_MIXED_CHUNK_TOKENS",
+                               fallback=self._chunk)
+        if budget < 1:
+            raise ValueError(
+                f"ARKS_MIXED_CHUNK_TOKENS={budget}: must be >= 1")
+        return min(budget, self.ecfg.max_cache_len)
+
+    def _init_paged_cache(self, num_pages: int, dtype):
+        """The paged pool(s) as this engine's configuration shapes them: a
+        model with window layers gets its window pool beside."""
+        win = ({"win_pages": self._win.alloc.num_pages}
+               if self._win is not None else {})
+        return tf.init_paged_cache(
+            self.cfg, num_pages, self._page_size(), self._cache_dtype(dtype),
+            quantized=self.ecfg.kv_quantized, pad_head=self._pad_head(),
+            kv_bits=min(self.ecfg.kv_bits, 8), **win)
+
+    def _tables_arg(self):
+        """The block tables as a pipe program takes them: a model with
+        window layers hands over both kinds."""
+        if self._win is None:
+            return jnp.asarray(self._tables)
+        return (jnp.asarray(self._tables), jnp.asarray(self._win.tables))
 
     def _cache_dtype(self, engine_dtype):
         kvd = self.ecfg.resolve_kv_cache_dtype()
@@ -3004,6 +3144,10 @@ class InferenceEngine:
                 new = self._alloc.alloc(need - len(row))
                 self._tables[slot, len(row): len(row) + len(new)] = new
                 row.extend(new)
+            if self._win is not None:
+                # From the RESOLVED length: whatever is in flight reads at
+                # or past it (WindowPages).
+                self._win_cover(slot, int(self._lengths[slot]), rows)
         # Any eviction the allocations caused must spill BEFORE the
         # caller's dispatch can write the recycled pages (stream order).
         self._spill_flush()
@@ -3180,6 +3324,7 @@ class InferenceEngine:
             self._abort_awaiting_restores()
             self._abort_awaiting_fetches()
             self._abort_awaiting_model()
+            self._abort_pool_waiting()
             self._abort_swapped()
 
     def _run_loop(self) -> None:
@@ -3502,6 +3647,8 @@ class InferenceEngine:
         live |= {req.request_id for req, _ in self._awaiting_guide}
         live |= {rec.request.request_id for rec in self._awaiting_restore}
         live |= {req.request_id for req, _, _ in self._awaiting_model}
+        if self._pool_waiting is not None:
+            live.add(self._pool_waiting[0].request_id)
         live |= {sw.rec.request.request_id for sw in self._swap_pending}
         live |= set(self._swapped)
         return live
@@ -3574,6 +3721,7 @@ class InferenceEngine:
         self._abort_awaiting_restores()
         self._abort_awaiting_fetches()
         self._abort_awaiting_model()
+        self._abort_pool_waiting()
         # Preempted victims fail too, and their SwapStore entries go with
         # them — swapped-out KV may carry the poison back on resume.
         self._abort_swapped()
@@ -3624,11 +3772,13 @@ class InferenceEngine:
         if self._paged:
             from arks_tpu.engine.paged import PageAllocator
             page = self._page_size()
-            self._cache = tf.init_paged_cache(
-                self.cfg, self._alloc.num_pages, page,
-                self._cache_dtype(dtype), quantized=self.ecfg.kv_quantized,
-                pad_head=self._pad_head(),
-                kv_bits=min(self.ecfg.kv_bits, 8))
+            if self._win is not None:
+                from arks_tpu.engine.paged import WindowPages
+                w = self._win
+                self._win = WindowPages(self.ecfg.num_slots, w.max_pages,
+                                        page, w.window, w.per_slot)
+            self._cache = self._init_paged_cache(self._alloc.num_pages,
+                                                 dtype)
             if self.mesh is not None:
                 self._cache = self._shard_paged(self._cache)
             self._alloc = PageAllocator(self._alloc.num_pages, page)
@@ -3636,6 +3786,7 @@ class InferenceEngine:
                 self._alloc.on_evict = self._note_evicted
             self._tables[:] = 0
             self._slot_pages.clear()
+            self._pool_reserved.clear()
             if self._residency is not None:
                 # Windowed slots' host stores reference the pre-reset
                 # stream; their requests token-replay from the top, so the
@@ -3994,7 +4145,15 @@ class InferenceEngine:
             # requests already collected in ``groups`` hold no slot and are
             # invisible to _run's recovery — the handler below must abort
             # them or their clients block forever.
-            while self._free and self._queue.qsize() > 0:
+            while self._free and (self._queue.qsize() > 0
+                                  or self._pool_waiting is not None):
+                if self._pool_waiting is not None:
+                    # The head of the queue waits for pages of the full
+                    # pool; nothing overtakes it (_pool_wait).
+                    if not self._pool_admit_waiting():
+                        break
+                    admitted = True
+                    continue
                 n_grouped = sum(len(v) for v in groups.values())
                 if n_grouped >= len(self._free):
                     break
@@ -4279,6 +4438,8 @@ class InferenceEngine:
             # tokens reach the model through mixed dispatches, so the
             # bucketed one-shot admit programs never compile (the variant
             # family collapses to one budget-shaped program).
+            if self._pool_budget and not self._pool_fits(req, ids):
+                return self._pool_wait(req, ids)
             return self._start_chunked(req, ids)
 
         return (req, ids, padded)
@@ -4469,6 +4630,66 @@ class InferenceEngine:
                     self.metrics.prefix_cache_usage_bytes.set(
                         self._prefix.bytes_used, tier="host")
 
+    # -- a full pool under num_slots x max_cache_len (kv_pool_pages) ----
+    # Admission reserves a request's pages of the FULL pool for its whole
+    # life before it takes a slot, so allocation on the step path still
+    # cannot fail; the window layers' pool needs no count of its own (a
+    # slot holds at most WindowPages.per_slot of its pages whatever the
+    # context, so a free slot is that promise).  A request the pool cannot
+    # hold yet waits at the HEAD of the queue and nothing overtakes it: a
+    # long prompt is not starved by the short ones behind it.
+
+    def _pool_need(self, req: Request, ids) -> int:
+        """Full-pool pages a request can come to own: its prompt, its
+        ``max_tokens``, and the rows the dispatches in flight write past
+        them (pages_needed, as _start_chunked and _grow_slot_pages ask)."""
+        from arks_tpu.engine.paged import pages_needed
+        last = min(len(ids) + req.params.max_tokens, self.ecfg.max_cache_len)
+        rows = self.ecfg.steps_per_dispatch * (self._pipe_depth_cfg + 1)
+        return pages_needed(last, rows, self._page_size(), self._max_pages)
+
+    def _pool_fits(self, req: Request, ids) -> bool:
+        return (sum(self._pool_reserved.values()) + self._pool_need(req, ids)
+                <= self._pool_budget)
+
+    def _pool_wait(self, req: Request, ids) -> None:
+        """Park ``req`` (popped, prepared) until the pool can hold it."""
+        self._pool_waiting = (req, ids)
+        self.metrics.num_requests_waiting.inc(1)
+        self.metrics.admission_page_waits_total.inc(1)
+
+    def _pool_admit_waiting(self) -> bool:
+        """Admit the request that waits for pages, if they are there now
+        (or drop it, if its client has gone).  False: it still waits."""
+        req, ids = self._pool_waiting
+        with self._abort_lock:
+            gone = req.request_id in self._aborted
+            self._aborted.discard(req.request_id)
+        if not gone and not self._pool_fits(req, ids):
+            return False
+        self._pool_waiting = None
+        self.metrics.num_requests_waiting.inc(-1)
+        if gone:
+            self._unpin_guide(req)
+            self._deliver(req, RequestOutput(
+                request_id=req.request_id, token_ids=[], finished=True,
+                finish_reason="abort"))
+        else:
+            self._start_chunked(req, ids)
+        return True
+
+    def _abort_pool_waiting(self) -> None:
+        """Fail the request that waits for pages (stop / blanket abort)."""
+        if self._pool_waiting is None:
+            return
+        req, ids = self._pool_waiting
+        self._pool_waiting = None
+        self.metrics.num_requests_waiting.inc(-1)
+        self._unpin_guide(req)
+        self._deliver(req, RequestOutput(
+            request_id=req.request_id, token_ids=[], finished=True,
+            finish_reason="abort", num_prompt_tokens=len(ids)))
+
     def _assign_slot_pages(self, slot: int, total: int,
                            head_pages=()) -> np.ndarray:
         """Allocate a slot's pages (optionally headed by already-incref'd
@@ -4485,7 +4706,20 @@ class InferenceEngine:
         self._spill_flush()
         return row
 
+    def _win_cover(self, slot: int, start: int, rows: int) -> None:
+        """Own the window-layer pages of ``rows`` rows of ``slot`` from
+        position ``start`` and release those behind its window
+        (WindowPages.cover), counting the released."""
+        gone = self._win.cover(slot, start, rows)
+        if gone:
+            self.metrics.kv_window_pages_released_total.inc(gone)
+
     def _register_prompt_pages(self, ids, pages, digests=None) -> None:
+        if self._win is not None:
+            # No prefix is ever indexed, so none is ever matched: a hit
+            # would start a prompt behind window pages that are gone
+            # (_windowed_preflight says so at construction).
+            return
         from arks_tpu.engine.paged import chain_digests
         page = self._page_size()
         nreg = min(len(ids) // page, len(pages))
@@ -4858,6 +5092,10 @@ class InferenceEngine:
         import hashlib
         sig = "|".join(str(x) for x in (
             self.cfg.name, self._page_size(), self.cfg.num_layers,
+            # (layers that keep a sequence's every page; window layers
+            # keep a window of their own)
+            *((self.cfg.num_full_layers, self.cfg.sliding_window)
+              if self.cfg.windowed else ()),
             self.cfg.num_kv_heads, self._page_bytes,
             self.ecfg.kv_quantized, self.ecfg.kv_bits,
             self.ecfg.resolve_kv_cache_dtype()))
@@ -7207,6 +7445,8 @@ class InferenceEngine:
             try:
                 self._faults.fire("pages")
                 self._assign_slot_pages(slot, total, head_pages=shared)
+                if self._pool_budget:
+                    self._pool_reserved[slot] = self._pool_need(req, ids)
             except Exception as e:
                 self._alloc.decref(shared)
                 self._free.append(slot)
@@ -7618,7 +7858,7 @@ class InferenceEngine:
                 jnp.asarray(np.zeros((n,), np.int32))]
         if self._draft_cfg is not None:
             cols.append(jnp.asarray(np.zeros((n,), bool)))
-        tables = jnp.asarray(self._tables) if self._paged else None
+        tables = self._tables_arg() if self._paged else None
         if self._draft_cfg is not None:
             args = (self.params, self._draft_params, self._cache,
                     self._draft_cache, *state, *cols, self._sampling,
@@ -7780,7 +8020,7 @@ class InferenceEngine:
             state = self._pipe_state
         want_lp = any(st.request.params.logprobs is not None
                       for st in self._slots.values())
-        tables_arg = jnp.asarray(self._tables) if self._paged else None
+        tables_arg = self._tables_arg() if self._paged else None
         payload = dict(lp=want_lp, fresh=fresh,
                        tables=self._tables.copy() if self._paged else None,
                        occupancy=len(self._pipe_inflight) + 1)
@@ -8127,6 +8367,8 @@ class InferenceEngine:
         i32, f32 = np.int32, np.float32
         return [
             ("tables", i32, (b, self._max_pages), 0),
+            *([("win_tables", i32, (b, self._max_pages), 0)]
+              if self._win is not None else []),
             ("lengths", i32, (b,), 0),
             ("tokens", i32, (t_budget,), 0),
             ("token_slot", i32, (t_budget,), -1),
@@ -8150,7 +8392,7 @@ class InferenceEngine:
             ("ov_guide", i32, (b,), -1),
             ("ov_guide_row", i32, (b,), 0)]
 
-    def _fill_chunk_lanes(self, a: dict, t: int):
+    def _fill_chunk_lanes(self, a: dict, t: int, budget: int = 0):
         """Round-robin prefill-chunk fill starting at flat index ``t``: an
         even quota per prefilling sequence first, FIFO greedy for the
         leftover — a burst of long prompts shares the budget instead of
@@ -8161,9 +8403,9 @@ class InferenceEngine:
         completing: list = []
         chunk_take: list[tuple[int, int]] = []
         pre = list(self._prefilling.items())
-        if not pre or not self._mixed_budget:
+        budget = budget or self._mixed_budget
+        if not pre or not budget:
             return completing, chunk_take, t
-        budget = self._mixed_budget
         quota = max(budget // len(pre), 1)
         takes: dict[int, int] = {}
         for slot, st in pre:
@@ -8248,8 +8490,40 @@ class InferenceEngine:
             block_q=plan["block_q"], num_qb=plan["num_qb"],
             max_pages=self._max_pages, hkv=self.cfg.num_kv_heads,
             page_head_bytes=self._page_head_bytes())
-        self.metrics.mixed_kv_bytes_total.inc(b_actual)
+        self.metrics.mixed_kv_bytes_total.inc(b_actual, kind="full")
         self.metrics.mixed_kv_bytes_ideal_total.inc(b_ideal)
+        if self._win is None:
+            return
+        # A window layer's launch: its own group size, so a plan of its
+        # own; the same mirror told the window.
+        wplan = self._grid_plans.get(("window", qmax))
+        if wplan is None:
+            from arks_tpu.ops.paged_attention import mixed_grid_plan
+            cfg = self.cfg
+            wplan = mixed_grid_plan(
+                qmax, hkv=cfg.num_kv_heads,
+                g=cfg.window_num_heads // cfg.num_kv_heads,
+                d=tf.cache_head_dim(cfg, self._pad_head()),
+                page=self._page_size(),
+                kv=self.ecfg.resolve_kv_cache_dtype(),
+                lanes=pos_start.shape[0])
+            self._grid_plans[("window", qmax)] = wplan
+        w_actual, _ = mixed_kv_bytes(
+            pos_start, q_len, page=self._page_size(),
+            block_q=wplan["block_q"], num_qb=wplan["num_qb"],
+            max_pages=self._max_pages, hkv=self.cfg.num_kv_heads,
+            page_head_bytes=self._page_head_bytes(),
+            window=self.cfg.sliding_window)
+        self.metrics.mixed_kv_bytes_total.inc(w_actual, kind="window")
+        win = self._win
+        self.metrics.kv_window_page_steps_total.inc(win.pages_in_use,
+                                                    state="held")
+        self.metrics.kv_window_page_steps_total.inc(win.unreleased_pages,
+                                                    state="unreleased")
+        self.metrics.kv_pages_in_use.set(win.pages_in_use, kind="window")
+        self.metrics.kv_pages_in_use.set(
+            self._alloc.num_pages - self._alloc.free_pages, kind="full")
+        self.metrics.kv_pages_reserved.set(sum(self._pool_reserved.values()))
 
     def _count_held(self, ids: np.ndarray) -> None:
         """A latent routed model's step hands back two counts behind its
@@ -8263,6 +8537,68 @@ class InferenceEngine:
         self.metrics.moe_routed_pairs_total.inc(
             rows * cfg.num_experts_per_tok * cfg.num_routed_layers)
         self.metrics.moe_held_pairs_total.inc(held)
+
+    def _kv_mover_refusals(self, ecfg: "EngineConfig", draft_cfg,
+                           moves: str, mesh_why: str) -> list[str]:
+        """What a model whose pages are not "K and V of every layer in one
+        pool" (a latent row; two pools) cannot be served with, each by
+        name: a mesh, a draft, no chunked prefill, and every mover of KV
+        blocks that was ASKED for (``moves`` says what they move; the host
+        tier is on by default, so its default is off for such a model,
+        _init_model_state, and only a tier asked for is refused)."""
+        why = []
+        if self.mesh is not None and self.mesh.size > 1:
+            why.append(f"a device mesh {dict(self.mesh.shape)} (tensor / "
+                       f"data / context / pipeline parallelism: {mesh_why})")
+        if ecfg.draft_model or draft_cfg is not None:
+            why.append("speculative decoding (the draft and the verify "
+                       f"rows move {moves})")
+        if not ecfg.prefill_chunk:
+            why.append("prefill_chunk off (the mixed scheduler needs "
+                       "chunked prefill)")
+        for knob, what in (
+                ("ARKS_PREFIX_HOST_MB", "the host spill tier"),
+                ("ARKS_PREFIX_DISK_MB", "the disk spill tier"),
+                ("ARKS_RESIDENCY_WINDOW_PAGES", "windowed residency")):
+            if knobs.is_set(knob) and knobs.get_int(knob) > 0:
+                why.append(f"{knob} ({what} moves {moves})")
+        if knobs.get_bool("ARKS_PREEMPT"):
+            why.append(f"ARKS_PREEMPT (the KV swap moves {moves})")
+        if [a for a in knobs.get_list("ARKS_PEER_ADDRS") if a.strip()]:
+            why.append(f"ARKS_PEER_ADDRS (peer fetch carries {moves} in the "
+                       "AKV1 format)")
+        if knobs.get_str("ARKS_MIXED_STEP") == "0":
+            why.append("ARKS_MIXED_STEP=0 (the legacy scheduler)")
+        return why
+
+    def _windowed_preflight(self, cfg: ModelConfig, ecfg: "EngineConfig",
+                            draft_cfg) -> None:
+        """A model with window and full attention layers is served by the
+        mixed scheduler over TWO page pools on one device: the full
+        layers' pages live as long as the sequence, the window layers' are
+        released behind the window (engine/paged.py::WindowPages).
+        Everything else that moves KV packs "every layer's page" of one
+        pool; asked for, it is refused here, at construction, by name, and
+        nothing falls back quietly.  The device prefix index is OFF for
+        such a model, whatever --prefix-cache-mb says: a matched prefix's
+        full pages would still be there and its window pages gone, so no
+        prompt is ever indexed or matched (_register_prompt_pages)."""
+        if ecfg.kv_layout == "auto":
+            ecfg.kv_layout = "paged"
+        why = []
+        if ecfg.kv_layout != "paged":
+            why.append(f"kv_layout={ecfg.kv_layout} (the slot layout keeps "
+                       "every position of every layer)")
+        why += self._kv_mover_refusals(
+            ecfg, draft_cfg, "every layer's page of one pool",
+            "layers of two head counts have no sharding rules")
+        if why:
+            raise ValueError(
+                f"model {cfg.name!r} (window and full attention layers over "
+                "two page pools) cannot be served with: " + "; ".join(why))
+        if ecfg.prefix_cache_mb:
+            log.info("model %s: the device prefix index is off (a matched "
+                     "prefix's window pages would be gone)", cfg.name)
 
     def _latent_preflight(self, cfg: ModelConfig, ecfg: "EngineConfig",
                           draft_cfg) -> None:
@@ -8284,31 +8620,9 @@ class InferenceEngine:
         if ecfg.kv_layout != "paged":
             why.append(f"kv_layout={ecfg.kv_layout} (the slot layout holds "
                        "K and V per head)")
-        if self.mesh is not None and self.mesh.size > 1:
-            why.append(f"a device mesh {dict(self.mesh.shape)} (tensor / "
-                       "data / context / pipeline parallelism: the latent "
-                       "block has no sharding rules)")
-        if ecfg.draft_model or draft_cfg is not None:
-            why.append("speculative decoding (the draft and the verify "
-                       "rows speak K and V)")
-        if not ecfg.prefill_chunk:
-            why.append("prefill_chunk off (the mixed scheduler needs "
-                       "chunked prefill)")
-        # The host tier is on by default: for a latent model the default
-        # is off (_init_model_state), and only a tier ASKED for is refused.
-        for knob, what in (
-                ("ARKS_PREFIX_HOST_MB", "the host spill tier"),
-                ("ARKS_PREFIX_DISK_MB", "the disk spill tier"),
-                ("ARKS_RESIDENCY_WINDOW_PAGES", "windowed residency")):
-            if knobs.is_set(knob) and knobs.get_int(knob) > 0:
-                why.append(f"{knob} ({what} moves K and V blocks)")
-        if knobs.get_bool("ARKS_PREEMPT"):
-            why.append("ARKS_PREEMPT (the KV swap moves K and V blocks)")
-        if [a for a in knobs.get_list("ARKS_PEER_ADDRS") if a.strip()]:
-            why.append("ARKS_PEER_ADDRS (peer fetch carries K and V blocks "
-                       "in the AKV1 format)")
-        if knobs.get_str("ARKS_MIXED_STEP") == "0":
-            why.append("ARKS_MIXED_STEP=0 (the legacy scheduler)")
+        why += self._kv_mover_refusals(
+            ecfg, draft_cfg, "K and V blocks",
+            "the latent block has no sharding rules")
         if why:
             raise ValueError(
                 f"model {cfg.name!r} (latent attention, one latent row a "
@@ -8349,11 +8663,47 @@ class InferenceEngine:
             self._grow_slot_pages(rows)
             if not self._promote_warm:
                 self._warm_promote()
+            if not self._mixed_tail_warm:
+                self._warm_mixed_tail()
             return True
         finally:
             if sec:
                 self.trace.evt("", tag + "retire", "E",
                                n0 - len(self._slots))
+
+    def _mixed_shape(self):
+        """(operand pack, prefill rows) of the sequential step about to be
+        packed: the tail shape when every prompt row the prefilling
+        sequences still have fits it (none at all included), else the
+        whole budget's."""
+        if self._mixed_tail_pack is not None and sum(
+                len(st.ids) - st.pos
+                for st in self._prefilling.values()) <= self._mixed_tail:
+            return self._mixed_tail_pack, self._mixed_tail
+        return self._mixed_pack, self._mixed_budget
+
+    def _mixed_pack_of(self, rows: int) -> "_OperandPack":
+        """The pack a mixed batch of ``rows`` flat tokens was built from
+        (a follower's replay)."""
+        tail = self._mixed_tail_pack
+        if tail is not None and rows == self.ecfg.num_slots + self._mixed_tail:
+            return tail
+        return self._mixed_pack
+
+    def _warm_mixed_tail(self) -> None:
+        """Compile the tail shape's two programs before the first
+        sequential step's dispatch (the full shape's compile with the
+        first step that needs them; a tail step may first come inside a
+        window that must not compile): a batch with no row writes
+        nothing.  Followers mirror each call."""
+        for lp in (False, True):
+            operands, a = self._mixed_tail_pack.host()
+            self._emit("mixed", lp=lp, **a)
+            fn = self._mixed_lp_fn if lp else self._mixed_fn
+            out = fn(self.params, self._cache, self._sampling, operands,
+                     self._guide_dev)
+            self._cache, self._sampling = out[-2], out[-1]
+        self._mixed_tail_warm = True
 
     def _mixed_account(self, a: dict, rows: int, n_chunk: int, qmax: int,
                        tag: str) -> None:
@@ -8421,7 +8771,8 @@ class InferenceEngine:
         evt = self.trace.evt
         if sec:
             evt("", tag + "pack", "B")
-        operands, a = self._mixed_pack.host()
+        pack, budget = self._mixed_shape()
+        operands, a = pack.host()
 
         t = 0
         for slot in dec_slots:
@@ -8436,7 +8787,7 @@ class InferenceEngine:
             a["seq_pos_start"][slot] = self._lengths[slot]
             t += 1
 
-        completing, chunk_take, t = self._fill_chunk_lanes(a, t)
+        completing, chunk_take, t = self._fill_chunk_lanes(a, t, budget)
 
         want_lp = any(self._slots[s].request.params.logprobs is not None
                       for s in dec_slots)
@@ -8445,11 +8796,17 @@ class InferenceEngine:
             for _, st, _, _ in completing)
         a["lengths"][...] = self._lengths
         a["tables"][...] = self._tables
+        if self._win is not None:
+            # A chunk's window pages, now that the step's takes are known
+            # (the decoding slots' were covered with their full pages).
+            for slot, take in chunk_take:
+                self._win_cover(slot, self._prefilling[slot].pos, take)
+            a["win_tables"][...] = self._win.tables
         n_chunk = sum(take for _, take in chunk_take)
         if sec:
             evt("", tag + "pack", "E", (t, n_chunk, len(self._prefilling)))
         # qmax mirrors the dispatcher: t_flat - b_lanes + 1.
-        self._mixed_account(a, t, n_chunk, self._mixed_budget + 1, tag)
+        self._mixed_account(a, t, n_chunk, budget + 1, tag)
         self._emit("mixed", lp=want_lp, **a)
         t0 = time.monotonic()
         args = (self.params, self._cache, self._sampling, operands,
@@ -8743,6 +9100,9 @@ class InferenceEngine:
         pages = self._slot_pages.pop(slot, [])
         if pages:
             self._alloc.decref(pages)
+        if self._win is not None:
+            self._win.release(slot)
+        self._pool_reserved.pop(slot, None)
         self._lengths[slot] = self._park_sentinel()
 
     def _clear_shaping(self, slot: int, p) -> None:

@@ -520,7 +520,8 @@ class DispatchFollower:
             # lockstep without the guide/seed registries).
             fn = eng._mixed_lp_fn if p.get("lp") else eng._mixed_fn
             out = fn(eng.params, eng._cache, eng._sampling,
-                     eng._mixed_pack.host_from(p), eng._guide_dev)
+                     eng._mixed_pack_of(len(p["tokens"])).host_from(p),
+                     eng._guide_dev)
             eng._cache, eng._sampling = out[-2], out[-1]
             jax.block_until_ready(out[0])
         elif op == "draft_prefill":

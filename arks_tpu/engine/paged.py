@@ -37,7 +37,7 @@ from arks_tpu.prefix_sketch import chain_digests, iter_chain_digests
 
 __all__ = ["OutOfPagesError", "iter_chain_digests", "chain_digests",
            "pages_needed", "mixed_grid_steps", "mixed_kv_bytes",
-           "PageAllocator"]
+           "PageAllocator", "WindowPages", "window_pages_per_slot"]
 
 
 class OutOfPagesError(RuntimeError):
@@ -74,11 +74,13 @@ def pages_needed(length: int, rows: int, page: int, max_pages: int) -> int:
 
 
 def mixed_grid_steps(pos_start, q_len, *, page: int, block_q: int,
-                     num_qb: int, max_pages: int) -> int:
+                     num_qb: int, max_pages: int, window: int = 0) -> int:
     """Page-compute steps of one mixed dispatch — the host-side numpy
     mirror of ops.paged_attention.build_mixed_work_list: each active
-    (seq, q_block) item visits exactly its own causal page count, q_len=0
-    lanes and padding items visit zero.  The counter it feeds
+    (seq, q_block) item visits exactly its own causal page count (with
+    ``window``, a window layer: the pages from the one that holds the
+    lowest key its first query attends), q_len=0 lanes and padding items
+    visit zero.  The counter it feeds
     (mixed_grid_steps_total) describes the grid PLAN, so it reads the same
     under either attention impl.
 
@@ -91,12 +93,16 @@ def mixed_grid_steps(pos_start, q_len, *, page: int, block_q: int,
     active = q_lo < ql[:, None]
     kv_end = np.where(active, pos[:, None] + np.minimum(q_lo + block_q,
                                                         ql[:, None]), 0)
-    return int(np.minimum(-(-kv_end // page), max_pages).sum())
+    pages = np.minimum(-(-kv_end // page), max_pages)
+    if window:
+        first = np.maximum(pos[:, None] + q_lo - (window - 1), 0) // page
+        pages = pages - np.minimum(np.where(active, first, 0), pages)
+    return int(pages.sum())
 
 
 def mixed_kv_bytes(pos_start, q_len, *, page: int, block_q: int,
                    num_qb: int, max_pages: int, hkv: int,
-                   page_head_bytes: int) -> tuple[int, int]:
+                   page_head_bytes: int, window: int = 0) -> tuple[int, int]:
     """(actual, ideal) KV bytes one mixed dispatch streams from HBM — the
     host-side mirror of the ragged kernel's DMA schedule, feeding the
     mixed_kv_bytes_total / _ideal_total counter pair.
@@ -116,14 +122,21 @@ def mixed_kv_bytes(pos_start, q_len, *, page: int, block_q: int,
 
     ``page_head_bytes``: bytes one (page, head) KV block moves — K + V
     (+ scale rows when quantized); the engine derives it from the pool
-    dtypes so int4 packing halves it automatically."""
+    dtypes so int4 packing halves it automatically.
+
+    ``window`` (a window layer): both counts leave out the pages wholly
+    below the window of the item's (the lane's) first query."""
     actual = mixed_grid_steps(
         pos_start, q_len, page=page, block_q=block_q, num_qb=num_qb,
-        max_pages=max_pages) * hkv * page_head_bytes
+        max_pages=max_pages, window=window) * hkv * page_head_bytes
     pos = pos_start.astype(np.int64, copy=False)
     ql = q_len.astype(np.int64, copy=False)
     seq_end = np.where(ql > 0, pos + ql, 0)
     seq_pages = np.minimum(-(-seq_end // page), max_pages)
+    if window:
+        first = np.maximum(pos - (window - 1), 0) // page
+        seq_pages = seq_pages - np.minimum(np.where(ql > 0, first, 0),
+                                           seq_pages)
     ideal = int(seq_pages.sum()) * hkv * page_head_bytes
     return actual, ideal
 
@@ -263,3 +276,92 @@ class PageAllocator:
     @property
     def hit_rate(self) -> float:
         return self.hit_tokens / self.query_tokens if self.query_tokens else 0.0
+
+
+def window_pages_per_slot(window: int, rows: int, page: int,
+                          max_pages: int) -> int:
+    """The most window-layer pages one slot holds: the pages that meet the
+    ``window - 1`` keys behind the first of ``rows`` rows written in one
+    dispatch burst (a prefill chunk's budget, or a decode row times the
+    pipeline's depth) and the rows themselves, wherever the first falls in
+    its page.  Window 512, a 1024-row chunk, pages of 256: 7."""
+    return min(-(-(window - 1 + rows) // page) + 1, max_pages)
+
+
+class WindowPages:
+    """Host authority over the WINDOW layers' pages of a model with window
+    and full attention layers: an allocator, block tables and lifetimes of
+    their own.  A full-attention page lives as long as its sequence; a
+    window page is released once it lies wholly behind the window of the
+    next row its slot writes, so a slot holds at most
+    :func:`window_pages_per_slot` of them whatever its context, and the
+    pool (slots x that many) can never run out.
+
+    ``tables[slot, j]`` is the page that holds positions ``[j x page,
+    (j + 1) x page)`` of the slot; entries behind the window go STALE when
+    their page is released (they may name a page another slot now owns)
+    and are never read: the window launch's work list starts at the page
+    that holds the lowest key a query attends
+    (``ops.paged_attention.build_mixed_work_list``).
+
+    When a page may go: :meth:`cover` is called with the slot's RESOLVED
+    length, before the dispatch that writes from it (under pipelined
+    decode the host's lengths lag the device's by the dispatches in
+    flight).  Every dispatch still in flight reads at positions at or past
+    that length, so its window starts at or past the bound the release is
+    made on; and a page handed on to another slot is written by a later
+    dispatch of the same stream.  Engine thread only."""
+
+    def __init__(self, num_slots: int, max_pages: int, page: int,
+                 window: int, per_slot: int) -> None:
+        self.page, self.window, self.per_slot = page, window, per_slot
+        self.max_pages = max_pages
+        self.alloc = PageAllocator(num_slots * per_slot, page)
+        self.tables = np.zeros((num_slots, max_pages), np.int32)
+        # slot -> [index of the first page held, the pages from it on,
+        # every page the slot was given so far].
+        self._held: dict[int, list] = {}
+        # Pages the held slots would hold had none been released: the sum
+        # of the pages each was given.
+        self.unreleased_pages = 0
+
+    @property
+    def pages_in_use(self) -> int:
+        return self.alloc.num_pages - self.alloc.free_pages
+
+    def held(self, slot: int) -> tuple[int, list[int]]:
+        first, pages, _ = self._held.get(slot, (0, [], 0))
+        return first, list(pages)
+
+    def cover(self, slot: int, start: int, rows: int) -> int:
+        """Before a dispatch burst that writes ``rows`` rows of ``slot``
+        from position ``start``: release the pages wholly behind ``start -
+        window + 1`` (the lowest key the first of them attends) and own
+        every page from the one that holds it up to the last row's.
+        Returns the pages released."""
+        lo = max(start - self.window + 1, 0) // self.page
+        hi = min((start + max(rows, 1) - 1) // self.page, self.max_pages - 1)
+        rec = self._held.setdefault(slot, [lo, [], 0])
+        first, pages, _ = rec
+        gone = min(max(lo - first, 0), len(pages))
+        if gone:
+            self.alloc.decref(pages[:gone])
+            del pages[:gone]
+        rec[0] = first = first + gone if pages else lo
+        need = hi + 1 - (first + len(pages))
+        if need > 0:
+            new = self.alloc.alloc(need)
+            at = first + len(pages)
+            self.tables[slot, at: at + need] = new
+            pages.extend(new)
+            rec[2] += need
+            self.unreleased_pages += need
+        return gone
+
+    def release(self, slot: int) -> None:
+        """The slot is done: every page back (not counted as released
+        behind a window)."""
+        _, pages, given = self._held.pop(slot, (0, [], 0))
+        if pages:
+            self.alloc.decref(pages)
+        self.unreleased_pages -= given

@@ -81,10 +81,73 @@ class ModelConfig:
     # experts [rank x held, (rank + 1) x held).  Size 1 = all of them.
     expert_parallel_size: int = 1
     expert_parallel_rank: int = 0
+    # Window and full attention layers in one model (``laguna``;
+    # sliding_window 0 = every layer attends its whole context).  After the
+    # ``first_k_dense`` leading layers (full attention, dense FFN) the
+    # layers come in periods of ``window_period`` window layers and one
+    # full layer; what is left over behind the last whole period is a tail
+    # of window layers (Laguna-S-2.1: 1 + 11 x (3 + 1) + 3 = 48).  A window
+    # layer's query attends the keys at most
+    # ``sliding_window - 1`` positions behind it, has ``window_num_heads``
+    # query heads (``num_heads``: a full layer's) over the same
+    # ``num_kv_heads``, and rotates the whole head under plain RoPE at
+    # ``window_rope_theta``.  A full layer rotates the first
+    # ``partial_rotary_factor`` of a head at ``rope_theta``, under
+    # ``rope_hf_yarn`` where set: (factor, original context, beta_fast,
+    # beta_slow, attention_factor), HF's YaRN, whose attention factor
+    # multiplies cos and sin.  ``attn_gate``: one sigmoid scalar a head,
+    # from the sublayer's normed input, on the head's output.
+    sliding_window: int = 0
+    window_period: int = 0
+    window_num_heads: int = 0
+    window_rope_theta: float = 10000.0
+    partial_rotary_factor: float = 1.0
+    rope_hf_yarn: tuple[float, ...] = ()
+    attn_gate: bool = False
 
     @property
     def q_dim(self) -> int:
         return self.num_heads * self.head_dim
+
+    @property
+    def windowed(self) -> bool:
+        return self.sliding_window > 0
+
+    @property
+    def num_periods(self) -> int:
+        """Periods of window layers and one full layer behind the prefix."""
+        if not self.windowed:
+            return 0
+        return (self.num_layers - self.first_k_dense) // (
+            self.window_period + 1)
+
+    @property
+    def window_tail(self) -> int:
+        """Window layers behind the last whole period."""
+        if not self.windowed:
+            return 0
+        return (self.num_layers - self.first_k_dense) % (
+            self.window_period + 1)
+
+    @property
+    def num_window_layers(self) -> int:
+        return self.num_periods * self.window_period + self.window_tail
+
+    @property
+    def num_full_layers(self) -> int:
+        """Layers that keep every page of a sequence."""
+        return self.num_layers - self.num_window_layers
+
+    def heads_of(self, window: bool) -> int:
+        return self.window_num_heads if window else self.num_heads
+
+    def layer_kinds(self) -> tuple[str, ...]:
+        """``"full"`` / ``"window"`` of every layer, in model order."""
+        if not self.windowed:
+            return ("full",) * self.num_layers
+        return ("full",) * self.first_k_dense + (
+            ("window",) * self.window_period + ("full",)) * self.num_periods \
+            + ("window",) * self.window_tail
 
     @property
     def latent(self) -> bool:
@@ -165,6 +228,13 @@ class ModelConfig:
             else self.num_layers
         blocks = self.num_layers * (attn + norms) + routed * mlp \
             + (self.num_layers - routed) * dense
+        if self.windowed:
+            # A window layer's projections have its own head count.
+            blocks += self.num_window_layers * 2 * e * self.head_dim * (
+                self.window_num_heads - self.num_heads)
+        if self.attn_gate:
+            blocks += e * (self.num_full_layers * self.num_heads
+                           + self.num_window_layers * self.window_num_heads)
         head = 0 if self.tie_word_embeddings else e * v
         return v * e + blocks + e + head
 
@@ -195,6 +265,24 @@ class ModelConfig:
         if d.get("kv_lora_rank") or d.get("n_routed_experts"):
             return _from_deepseek_v3(d, name or model_type or "hf-model",
                                      tuple(eos))
+        if model_type == "laguna":
+            return _from_laguna(d, name or model_type, tuple(eos))
+        # What only the ``laguna`` reader understands: on this path each
+        # would be dropped, and the model served as another model.
+        for k in ("layer_types", "rope_parameters",
+                  "num_attention_heads_per_layer"):
+            if d.get(k):
+                raise ValueError(
+                    f"{k} in a config of model_type {model_type!r}: only "
+                    "model_type 'laguna' is read with layers of more than "
+                    "one kind; serving this model with one kind of layer "
+                    "would be another model")
+        if d.get("sliding_window") and d.get("use_sliding_window", True):
+            raise ValueError(
+                f"sliding_window={d['sliding_window']} in a config of "
+                f"model_type {model_type!r}: only model_type 'laguna' is "
+                "read with window layers; serving this model with full "
+                "attention in every layer would be another model")
         if d.get("rope_scaling"):
             raise ValueError(
                 f"rope_scaling={d['rope_scaling']!r}: only the latent-"
@@ -302,6 +390,136 @@ def _from_deepseek_v3(d: dict[str, Any], name: str,
     )
 
 
+def _from_laguna(d: dict[str, Any], name: str,
+                 eos: tuple[int, ...]) -> ModelConfig:
+    """The ``laguna`` block (poolside): GQA layers of two kinds, window and
+    full, each with a head count and a RoPE of its own, a per-head output
+    gate, softmax-routed experts with a scaling factor behind a dense
+    prefix, a sigmoid-gated shared expert.  Key for key from the published
+    file; what the block cannot express is refused, not approximated."""
+    def refuse(what: str) -> None:
+        raise ValueError(f"config {name!r}: {what} is not supported (the "
+                         "model would be served as another model)")
+
+    layers = int(d["num_hidden_layers"])
+    kinds = list(d.get("layer_types") or [])
+    heads_per = list(d.get("num_attention_heads_per_layer") or [])
+    mlp = list(d.get("mlp_layer_types")
+               or ["dense" if l in (d.get("mlp_only_layers") or ())
+                   else "sparse" for l in range(layers)])
+    for key, got in (("layer_types", kinds), ("mlp_layer_types", mlp),
+                     ("num_attention_heads_per_layer", heads_per)):
+        if len(got) != layers:
+            refuse(f"{key} with {len(got)} entries for {layers} layers")
+    if float(d.get("moe_router_logit_softcapping", 0) or 0) != 0:
+        refuse("moe_router_logit_softcapping="
+               f"{d['moe_router_logit_softcapping']}")
+    if d.get("moe_apply_router_weight_on_input"):
+        refuse("moe_apply_router_weight_on_input")
+    gating = d.get("gating", False)
+    if gating not in (False, None, True, "per-head", "per_head"):
+        refuse(f"gating={gating!r} (only per-head)")
+    for g in d.get("gating_types") or ():
+        if g != "per_head":
+            refuse(f"a gating_types entry {g!r} (only per_head)")
+    if d.get("attention_bias"):
+        refuse("attention_bias")
+    if int(d.get("decoder_sparse_step", 1) or 1) != 1:
+        refuse(f"decoder_sparse_step={d['decoder_sparse_step']}")
+    window = int(d.get("sliding_window") or 0)
+    if window < 1:
+        refuse("a laguna config without sliding_window")
+    # The dense layers are a prefix of full-attention layers; behind it,
+    # periods of window layers and one full layer, and a tail of window
+    # layers short of a period.
+    first = 0
+    while first < layers and mlp[first] == "dense":
+        first += 1
+    if "dense" in mlp[first:] or first >= layers:
+        refuse(f"mlp_layer_types {mlp} (dense layers must be a prefix)")
+    if any(k != "full_attention" for k in kinds[:first]):
+        refuse("a window layer inside the dense prefix")
+    rest = kinds[first:]
+    period = rest.index("full_attention") if "full_attention" in rest else 0
+    want = (["sliding_attention"] * period + ["full_attention"]) * (
+        len(rest) // (period + 1)) \
+        + ["sliding_attention"] * (len(rest) % (period + 1))
+    if not period or rest != want:
+        refuse(f"layer_types {kinds} (behind the dense prefix: periods of "
+               "window layers and one full layer, then window layers)")
+    full_heads = {h for h, k in zip(heads_per, kinds)
+                  if k == "full_attention"}
+    win_heads = {h for h, k in zip(heads_per, kinds)
+                 if k == "sliding_attention"}
+    if len(full_heads) != 1 or len(win_heads) != 1:
+        refuse(f"num_attention_heads_per_layer {heads_per} (one head count "
+               "a kind of layer)")
+    heads, wheads = full_heads.pop(), win_heads.pop()
+    if heads != d["num_attention_heads"]:
+        refuse(f"num_attention_heads {d['num_attention_heads']} beside "
+               f"{heads} heads in the full layers")
+    rp = d.get("rope_parameters") or {}
+    full, win = rp.get("full_attention"), rp.get("sliding_attention")
+    if not full or not win:
+        refuse("rope_parameters without full_attention and "
+               "sliding_attention")
+    legacy = d.get("rope_scaling")
+    if legacy and legacy != full:
+        refuse(f"rope_scaling {legacy!r} beside another "
+               "rope_parameters.full_attention")
+    if win.get("rope_type", "default") != "default" \
+            or float(win.get("partial_rotary_factor", 1)) != 1:
+        refuse(f"sliding_attention rope {win!r} (only plain RoPE over the "
+               "whole head)")
+    yarn: tuple[float, ...] = ()
+    if full.get("rope_type", "default") == "yarn":
+        if full.get("mscale") or full.get("mscale_all_dim") \
+                or full.get("truncate") is False:
+            refuse(f"yarn with mscale / truncate keys {full!r}")
+        factor = float(full["factor"])
+        yarn = (factor, float(full["original_max_position_embeddings"]),
+                float(full.get("beta_fast", 32)),
+                float(full.get("beta_slow", 1)),
+                float(full.get("attention_factor")
+                      or 0.1 * math.log(factor) + 1.0))
+    elif full.get("rope_type", "default") != "default":
+        refuse(f"full_attention rope_type {full.get('rope_type')!r}")
+    kv = d.get("num_key_value_heads", heads)
+    if heads % kv or wheads % kv:
+        refuse(f"{heads} / {wheads} query heads over {kv} KV heads")
+    return ModelConfig(
+        name=name,
+        vocab_size=d["vocab_size"],
+        hidden_size=d["hidden_size"],
+        intermediate_size=d["intermediate_size"],
+        num_layers=layers,
+        num_heads=heads,
+        num_kv_heads=kv,
+        head_dim=d.get("head_dim", d["hidden_size"] // heads),
+        rope_theta=float(full.get("rope_theta", 10000.0)),
+        rms_norm_eps=float(d.get("rms_norm_eps", 1e-6)),
+        tie_word_embeddings=bool(d.get("tie_word_embeddings", False)),
+        max_position_embeddings=int(d.get("max_position_embeddings", 32768)),
+        eos_token_ids=eos,
+        num_experts=int(d["num_experts"]),
+        num_experts_per_tok=int(d["num_experts_per_tok"]),
+        moe_intermediate_size=int(d["moe_intermediate_size"]),
+        shared_expert_intermediate_size=int(
+            d.get("shared_expert_intermediate_size", 0) or 0),
+        norm_topk_prob=bool(d.get("norm_topk_prob", False)),
+        routed_scaling_factor=float(d.get("moe_routed_scaling_factor", 1.0)),
+        first_k_dense=first,
+        sliding_window=window,
+        window_period=period,
+        window_num_heads=wheads,
+        window_rope_theta=float(win.get("rope_theta", 10000.0)),
+        partial_rotary_factor=float(full.get("partial_rotary_factor", 1)),
+        rope_hf_yarn=yarn,
+        attn_gate=bool(gating),
+        kv_cache_dtype=str(d.get("kv_cache_dtype", "auto")),
+    )
+
+
 _REGISTRY: dict[str, ModelConfig] = {}
 
 
@@ -382,6 +600,24 @@ register_config(ModelConfig(
     qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
     rope_yarn=(4.0, 64.0, 32.0, 1.0, 1.0, 1.0), first_k_dense=1,
     scoring_func="sigmoid", routed_scaling_factor=2.5, n_shared_experts=1,
+))
+
+# Window and full attention layers in one model (the ``laguna`` block) at
+# CPU-test size: 1 dense full layer, then 2 periods of 2 window layers (6
+# heads, window 16, plain RoPE) and 1 full layer (4 heads, half of each head
+# rotated under YaRN), 2 KV heads, a per-head gate, 16 softmax-routed
+# experts top-4 times 2.5 and a gated shared expert.
+register_config(ModelConfig(
+    name="tiny-swa-moe", vocab_size=512, hidden_size=64,
+    intermediate_size=128, num_layers=7, num_heads=4, num_kv_heads=2,
+    head_dim=16, rope_theta=500000.0, eos_token_ids=(0,),
+    num_experts=16, num_experts_per_tok=4, moe_intermediate_size=32,
+    shared_expert_intermediate_size=32, norm_topk_prob=True,
+    routed_scaling_factor=2.5, first_k_dense=1, sliding_window=16,
+    window_period=2, window_num_heads=6, window_rope_theta=10000.0,
+    partial_rotary_factor=0.5,
+    rope_hf_yarn=(4.0, 32.0, 32.0, 1.0, 1.1386294361119891),
+    attn_gate=True,
 ))
 
 # MoE families (HF: mistralai/Mixtral-8x7B-Instruct-v0.1, Qwen/Qwen2-57B-A14B).

@@ -53,8 +53,11 @@ def shared_width(cfg) -> int:
             or cfg.n_shared_experts * cfg.moe_intermediate_size)
 
 
-def init_moe_params(cfg, key, dtype) -> Params:
-    l, e = cfg.num_routed_layers, cfg.hidden_size
+def init_moe_params(cfg, key, dtype, layers: int | None = None) -> Params:
+    """The routed FFN's leaves, stacked ``layers`` deep (every routed layer
+    of the model where not given)."""
+    l = cfg.num_routed_layers if layers is None else layers
+    e = cfg.hidden_size
     x, fm = cfg.num_experts, cfg.moe_intermediate_size
     keys = iter(jax.random.split(key, 9))
 
@@ -116,7 +119,8 @@ def router_topk(logits: jnp.ndarray, cfg, bias: jnp.ndarray | None = None
 
     - ``softmax``: softmax over all experts, top-k selected; renormalized
       when ``norm_topk_prob`` (Mixtral semantics — equal to softmax over
-      the top-k logits);
+      the top-k logits), then times ``routed_scaling_factor`` (``laguna``;
+      1.0 elsewhere);
     - ``sigmoid`` (DeepSeek-V3 ``noaux_tc``, no group limit): scores are
       sigmoids; the top-k of ``score + bias`` are CHOSEN, their weights are
       the UNBIASED scores, normalised over the chosen when
@@ -133,6 +137,8 @@ def router_topk(logits: jnp.ndarray, cfg, bias: jnp.ndarray | None = None
     vals, idx = jax.lax.top_k(probs, k)
     if cfg.norm_topk_prob:
         vals = vals / (jnp.sum(vals, axis=-1, keepdims=True) + 1e-9)
+    if cfg.routed_scaling_factor != 1.0:
+        vals = vals * cfg.routed_scaling_factor
     return vals, idx
 
 

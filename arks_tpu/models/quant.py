@@ -64,6 +64,8 @@ MATMUL_KEYS = frozenset({
 SKIP_KEYS = frozenset({
     "attn_norm", "mlp_norm", "final_norm", "bq", "bk", "bv", "router",
     "shared_gate", "q_norm", "kv_norm", "router_bias",
+    # The per-head output gate [E, H]: a sigmoid's input, tiny.
+    "attn_gate",
 })
 NORM_KEYS = frozenset({"attn_norm", "mlp_norm", "final_norm", "q_norm",
                        "kv_norm"})

@@ -88,12 +88,21 @@ class PagedKVCache(NamedTuple):
     num_pages, 1, page, R]``, one row a token, the normed latent and the
     rotary key lanes, which is key AND value of every head; it is stored
     once: ``v`` is None, and so are the scales (bf16 only).
+
+    A model with WINDOW layers (``cfg.windowed``) has two pools with page
+    counts and lifetimes of their own: the four arrays above are the
+    FULL-attention layers' (``L`` = ``cfg.num_full_layers``, a layer's
+    index its rank among them), and ``win`` is the window layers' pool,
+    a ``PagedKVCache`` of the same page size over ``cfg.num_window_layers``
+    layers, addressed through block tables of its own whose entries
+    behind a slot's window the engine has released.
     """
 
     k: jnp.ndarray
     v: jnp.ndarray | None
     k_scale: jnp.ndarray | None = None
     v_scale: jnp.ndarray | None = None
+    win: "PagedKVCache | None" = None
 
     @property
     def quantized(self) -> bool:
@@ -123,9 +132,10 @@ class PagedKVCache(NamedTuple):
 
     @property
     def token_bytes(self) -> int:
-        """Bytes the pool holds for one token, all layers."""
+        """Bytes the pool holds for one token, all its layers (a window
+        pool counts its own)."""
         return sum(x.size * x.dtype.itemsize
-                   for x in self if x is not None) // (
+                   for x in self[:4] if x is not None) // (
             self.num_pages * self.page)
 
 
@@ -183,10 +193,59 @@ def _init_latent_params(cfg: ModelConfig, key: jax.Array,
     return params
 
 
+def _init_windowed_params(cfg: ModelConfig, key: jax.Array,
+                          dtype: jnp.dtype) -> Params:
+    """The ``laguna`` tree, a stacked tree a kind of layer: ``dense_layers``
+    (the ``first_k_dense`` leading layers: full attention, SwiGLU of
+    ``intermediate_size``), ``layers`` (one full-attention routed layer a
+    period) and ``win_layers`` (``window_period`` window layers a period,
+    in model order), each with projections of its own head count:
+    ``wq`` [E, H x D], ``wk`` / ``wv`` [E, Hkv x D], ``wo`` [H x D, E] and
+    the per-head gate ``attn_gate`` [E, H]."""
+    e, f, v = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size
+    keys = iter(jax.random.split(key, 32))
+
+    def w(shape, scale=0.02):
+        return (jax.random.normal(next(keys), shape, jnp.float32)
+                * scale).astype(dtype)
+
+    def attn(l: int, heads: int) -> Params:
+        out = {
+            "attn_norm": jnp.ones((l, e), dtype),
+            "wq": w((l, e, heads * cfg.head_dim)),
+            "wk": w((l, e, cfg.kv_dim)),
+            "wv": w((l, e, cfg.kv_dim)),
+            "wo": w((l, heads * cfg.head_dim, e)),
+            "mlp_norm": jnp.ones((l, e), dtype),
+        }
+        if cfg.attn_gate:
+            out["attn_gate"] = w((l, e, heads))
+        return out
+
+    from arks_tpu.models import moe
+    params: Params = {"embed": w((v, e)),
+                      "final_norm": jnp.ones((e,), dtype)}
+    for name, l, heads in (
+            ("layers", cfg.num_periods, cfg.num_heads),
+            ("win_layers", cfg.num_window_layers, cfg.window_num_heads)):
+        params[name] = dict(attn(l, heads), **moe.init_moe_params(
+            cfg, next(keys), dtype, layers=l))
+    if cfg.first_k_dense:
+        ld = cfg.first_k_dense
+        params["dense_layers"] = dict(
+            attn(ld, cfg.num_heads), w_gate=w((ld, e, f)),
+            w_up=w((ld, e, f)), w_down=w((ld, f, e)))
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = w((e, v))
+    return params
+
+
 def init_params(cfg: ModelConfig, key: jax.Array, dtype: jnp.dtype | None = None) -> Params:
     dtype = dtype or jnp.dtype(cfg.dtype)
     if cfg.latent:
         return _init_latent_params(cfg, key, dtype)
+    if cfg.windowed:
+        return _init_windowed_params(cfg, key, dtype)
     if cfg.first_k_dense:
         raise NotImplementedError(
             f"model {cfg.name!r}: a dense prefix (first_k_dense="
@@ -239,6 +298,11 @@ def param_pspecs(cfg: ModelConfig, tp: int = 1) -> Params:
         raise NotImplementedError(
             f"model {cfg.name!r}: the latent block has no sharding rules "
             "(tensor / data / pipeline parallelism are not supported)")
+    if cfg.windowed:
+        raise NotImplementedError(
+            f"model {cfg.name!r}: layers of two head counts have no "
+            "sharding rules (tensor / data / pipeline parallelism are not "
+            "supported)")
     kv = P(None, None, AXIS_MODEL) if shard_kv_heads(cfg, tp) else P(None, None, None)
     kvb = P(None, AXIS_MODEL) if shard_kv_heads(cfg, tp) else P(None, None)
     layers: Params = {
@@ -337,8 +401,21 @@ def init_paged_cache(cfg: ModelConfig, num_pages: int, page: int,
                      dtype: jnp.dtype | None = None,
                      quantized: bool = False,
                      pad_head: bool = False,
-                     kv_bits: int = 8) -> PagedKVCache:
+                     kv_bits: int = 8, win_pages: int = 0) -> PagedKVCache:
+    """``win_pages`` (a model with window layers): the pages of the window
+    layers' pool; ``num_pages`` are then the full-attention layers'."""
     dtype = dtype or jnp.dtype(cfg.dtype)
+    if cfg.windowed:
+        if win_pages < 1:
+            raise ValueError(f"model {cfg.name!r}: window layers keep a "
+                             "pool of their own (win_pages)")
+        import dataclasses
+        pools = [init_paged_cache(
+            dataclasses.replace(cfg, sliding_window=0, num_layers=layers),
+            n, page, dtype, quantized, pad_head, kv_bits)
+            for layers, n in ((cfg.num_full_layers, num_pages),
+                              (cfg.num_window_layers, win_pages))]
+        return pools[0]._replace(win=pools[1])
     shape = (cfg.num_layers, num_pages, cfg.num_kv_heads, page,
              cache_head_dim(cfg, pad_head))
     if cfg.latent:
@@ -611,6 +688,136 @@ def _mixed_step_latent(params, cfg, cache, tables, tokens, token_slot,
         h_sel = jnp.take(h[0], sample_src.astype(jnp.int32), axis=0)  # [B, E]
     logits = _unembed(h_sel, params, cfg, mesh, None)
     return logits, PagedKVCache(k=pool, v=None), held
+
+
+def _kind_qkv(h: jnp.ndarray, lp: Params, cfg: ModelConfig,
+              positions: jnp.ndarray, window: bool):
+    """:func:`_block_qkv` for a layer of one kind of a model with window
+    and full layers: the kind's head count and RoPE (window: the whole
+    head at ``window_rope_theta``; full: the first
+    ``partial_rotary_factor`` of a head under ``rope_hf_yarn``).  Also
+    returns the per-head gate ``sigmoid(x Wg)`` [B, T, H] from the same
+    normed input (None where the model has none)."""
+    b, t = h.shape[:2]
+    heads = cfg.heads_of(window)
+    with _scope("arks.attn_win_qkv" if window else "arks.attn_qkv"):
+        x = rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps)
+        q, k, v = _qkv(x, lp, cfg)
+        q = q.reshape(b, t, heads, cfg.head_dim)
+        k = k.reshape(b, t, cfg.num_kv_heads, cfg.head_dim)
+        v = v.reshape(b, t, cfg.num_kv_heads, cfg.head_dim)
+        if window:
+            rope = functools.partial(apply_rope, positions=positions,
+                                     theta=cfg.window_rope_theta)
+        else:
+            rot = int(cfg.head_dim * cfg.partial_rotary_factor)
+            rope = functools.partial(
+                apply_rope, positions=positions, theta=cfg.rope_theta,
+                yarn=cfg.rope_hf_yarn[:4],
+                rotary_dim=None if rot == cfg.head_dim else rot,
+                attention_factor=(cfg.rope_hf_yarn[4] if cfg.rope_hf_yarn
+                                  else None))
+        q, k = rope(q), rope(k)
+    gate = None
+    if cfg.attn_gate:
+        with _scope("arks.attn_gate"):
+            gate = jax.nn.sigmoid(jnp.einsum(
+                "...e,eh->...h", x, lp["attn_gate"]).astype(jnp.float32))
+    return q, k, v, gate
+
+
+def _mixed_step_windowed(params, cfg, cache, tables, win_tables, tokens,
+                         token_slot, token_pos, sample_src, seq_q_start,
+                         seq_q_len, seq_pos_start, mesh):
+    """:func:`mixed_step` for a model with window and full layers
+    (``cfg.windowed``): the dense prefix's stack, then a scan over the
+    periods, each the period's window layers (an inner scan that takes
+    them out of ``win_layers`` by index) and its full layer, then the
+    tail of window layers behind the last whole period.  Full layers write and
+    read the full pool through ``tables``; window layers the window pool
+    (``cache.win``) through ``win_tables``, the same ragged launch told
+    the window.  Returns (logits, cache, held_pairs)."""
+    from arks_tpu.ops.attention import paged_mixed_update_and_attend
+    t_flat = tokens.shape[0]
+    cover = tables.shape[1] * cache.page
+    rope_pos = jnp.minimum(token_pos, cover - 1)[None]           # [1, T]
+    valid = (token_slot >= 0)[None]
+    first = cfg.first_k_dense
+    head = params["dense_layers"] if first else params["layers"]
+    with _scope("arks.embed"):
+        h = embed_lookup(params["embed"], tokens[None],
+                         head["attn_norm"].dtype)                # [1, T, E]
+
+    def layer(h, lp, pool, tbl, index, window: bool):
+        q, k, v, gate = _kind_qkv(h, lp, cfg, rope_pos, window)
+        attn, *pool = paged_mixed_update_and_attend(
+            q[0], k[0], v[0], pool[0], pool[1], tbl, token_slot, token_pos,
+            seq_q_start, seq_q_len, seq_pos_start, index, mesh, False,
+            k_scale=pool[2], v_scale=pool[3],
+            window=cfg.sliding_window if window else 0)
+        if gate is not None:
+            with _scope("arks.attn_gate"):
+                attn = attn * gate[0][..., None].astype(attn.dtype)
+        attn = attn.reshape(1, t_flat, cfg.heads_of(window) * cfg.head_dim)
+        with _scope("arks.attn_win_out" if window else "arks.attn_out"):
+            h = h + qeinsum("...q,qe->...e", attn, lp["wo"])
+        if "router" in lp:
+            y, held = _mlp(h, lp, cfg, mesh, None, row_valid=valid)
+        else:
+            y, held = _mlp(h, lp, cfg, mesh, None), jnp.int32(0)
+        return h + y, tuple(pool), held
+
+    full, win = tuple(cache[:4]), tuple(cache.win[:4])
+    held = jnp.int32(0)
+    if first:
+        def dense_body(carry, xs):
+            h, full = carry
+            h, full, n = layer(h, xs[0], full, tables, xs[1], False)
+            return (h, full), n
+
+        (h, full), n = jax.lax.scan(
+            dense_body, (h, full),
+            (params["dense_layers"], jnp.arange(first, dtype=jnp.int32)))
+        held = held + jnp.sum(n)
+
+    per = cfg.window_period
+
+    def window_layers(h, win, start, count: int):
+        """``count`` window layers from index ``start`` of the flat stack,
+        each taken out by index, one slice a layer (a scan over the stack
+        cut into periods would copy a period's layers out, then each of
+        them again)."""
+        def win_body(c, j):
+            h, win = c
+            at = start + j
+            lp = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
+                a, at, 0, keepdims=False), params["win_layers"])
+            h, win, n = layer(h, lp, win, win_tables, at, True)
+            return (h, win), n
+
+        (h, win), n = jax.lax.scan(
+            win_body, (h, win), jnp.arange(count, dtype=jnp.int32))
+        return h, win, jnp.sum(n)
+
+    def period_body(carry, xs):
+        h, full, win = carry
+        flp, i = xs
+        h, win, n = window_layers(h, win, i * per, per)
+        h, full, m = layer(h, flp, full, tables, first + i, False)
+        return (h, full, win), n + m
+
+    (h, full, win), n = jax.lax.scan(
+        period_body, (h, full, win),
+        (params["layers"], jnp.arange(cfg.num_periods, dtype=jnp.int32)))
+    held = held + jnp.sum(n)
+    if cfg.window_tail:
+        h, win, n = window_layers(h, win, cfg.num_periods * per,
+                                  cfg.window_tail)
+        held = held + n
+    with _scope("arks.lm_head"):
+        h_sel = jnp.take(h[0], sample_src.astype(jnp.int32), axis=0)  # [B, E]
+    logits = _unembed(h_sel, params, cfg, mesh, None)
+    return logits, PagedKVCache(*full, win=PagedKVCache(*win)), held
 
 
 def prefill_layer(
@@ -1099,6 +1306,7 @@ def mixed_step(
     seq_pos_start: jnp.ndarray,  # [B] int32 — lane's first global position
     mesh: Mesh | None = None,
     with_held: bool = False,
+    win_tables: jnp.ndarray | None = None,  # [B, MaxP] — window layers'
 ) -> tuple[jnp.ndarray, PagedKVCache]:
     """One unified mixed prefill+decode forward: a flat ``[T]`` token batch
     carrying every decoding slot's next token PLUS one or more sequences'
@@ -1117,15 +1325,24 @@ def mixed_step(
     fp reassociation differs across chunk boundaries).
 
     A latent model (``cfg.latent``) runs :func:`_mixed_step_latent` over
-    its latent pool; ``with_held`` (latent models only) adds its third
+    its latent pool, a model with window layers (``cfg.windowed``)
+    :func:`_mixed_step_windowed` over its two pools, the window layers'
+    through ``win_tables``; ``with_held`` (those two only) adds the third
     result, the count of routed pairs that landed on experts held here."""
     if cfg.latent:
         out = _mixed_step_latent(params, cfg, cache, tables, tokens,
                                  token_slot, token_pos, sample_src,
                                  seq_q_start, seq_q_len, seq_pos_start, mesh)
         return out if with_held else out[:2]
+    if cfg.windowed:
+        out = _mixed_step_windowed(params, cfg, cache, tables, win_tables,
+                                   tokens, token_slot, token_pos, sample_src,
+                                   seq_q_start, seq_q_len, seq_pos_start,
+                                   mesh)
+        return out if with_held else out[:2]
     if with_held:
-        raise NotImplementedError("with_held: latent models only")
+        raise NotImplementedError("with_held: latent and windowed models "
+                                  "only")
     t_flat = tokens.shape[0]
     cover = tables.shape[1] * cache.page
     # RoPE positions must be real for valid tokens; padding rows only need

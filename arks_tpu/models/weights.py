@@ -60,11 +60,22 @@ class LatentCheckpointError(NotImplementedError):
     GQA block's names."""
 
 
+class WindowedCheckpointError(NotImplementedError):
+    """A checkpoint of a model with window and full attention layers
+    (``laguna``): the name mapping of its per-kind projections (a head count
+    a kind of layer), its per-head gate and its expert tensors onto the
+    three stacked trees (``dense_layers`` / ``layers`` / ``win_layers``)
+    is not built; such a model is served from seeded random weights only.
+    Raised instead of stacking layers of two shapes into one tree."""
+
+
 def _refuse_latent(cfg: ModelConfig, path: str) -> None:
-    if cfg.latent:
-        raise LatentCheckpointError(
-            f"model {cfg.name!r}: cannot load the checkpoint at {path}: "
-            + " ".join(LatentCheckpointError.__doc__.split()))
+    for is_kind, err in ((cfg.latent, LatentCheckpointError),
+                         (cfg.windowed, WindowedCheckpointError)):
+        if is_kind:
+            raise err(
+                f"model {cfg.name!r}: cannot load the checkpoint at {path}: "
+                + " ".join(err.__doc__.split()))
 
 
 def params_from_hf(cfg: ModelConfig, path: str, dtype: Any = None,

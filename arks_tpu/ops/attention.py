@@ -133,11 +133,13 @@ def decode_attention_xla(
     k_cache: jnp.ndarray,  # [B, Hkv, S, D]
     v_cache: jnp.ndarray,  # [B, Hkv, S, D]
     lengths: jnp.ndarray,  # [B] int32 — number of valid cache entries per slot
+    lower: jnp.ndarray | None = None,  # [B] int32 — first index attended
 ) -> jnp.ndarray:
     """Masked attention of one query token per slot against the slot KV cache.
 
     Cache index s is valid iff s < lengths[b] (the caller writes the current
-    token's K/V into the cache *before* calling, so lengths includes it).
+    token's K/V into the cache *before* calling, so lengths includes it),
+    and, with ``lower`` (a window layer), s >= lower[b].
     Returns [B, Hkv, G, D].
     """
     b, hkv, g, d = q.shape
@@ -146,6 +148,8 @@ def decode_attention_xla(
     scores = jnp.einsum("bkgd,bksd->bkgs", q, k_cache,
                         preferred_element_type=jnp.float32) * scale
     valid = jnp.arange(s)[None] < lengths[:, None]  # [B, S]
+    if lower is not None:
+        valid = valid & (jnp.arange(s)[None] >= lower[:, None])
     scores = jnp.where(valid[:, None, None], scores, _NEG_INF)
     probs = _softmax(scores, axis=-1).astype(v_cache.dtype)
     out = jnp.einsum("bkgs,bksd->bkgd", probs, v_cache,
@@ -160,6 +164,7 @@ def _decode_attention_xla_quant(
     k_scale: jnp.ndarray,  # [B, Hkv, S] f32
     v_scale: jnp.ndarray,
     lengths: jnp.ndarray,  # [B] int32
+    lower: jnp.ndarray | None = None,  # [B] int32 — first index attended
 ) -> jnp.ndarray:
     """int8 oracle/fallback: per-token scales applied to scores (K) and
     probabilities (V), mirroring the Pallas kernel's folding."""
@@ -170,6 +175,8 @@ def _decode_attention_xla_quant(
                         preferred_element_type=jnp.float32) * scale
     scores = scores * k_scale[:, :, None, :]
     valid = jnp.arange(s)[None] < lengths[:, None]  # [B, S]
+    if lower is not None:
+        valid = valid & (jnp.arange(s)[None] >= lower[:, None])
     scores = jnp.where(valid[:, None, None], scores, _NEG_INF)
     probs = _softmax(scores, axis=-1) * v_scale[:, :, None, :]
     out = jnp.einsum("bkgs,bksd->bkgd", probs.astype(q.dtype),
@@ -398,6 +405,7 @@ def paged_mixed_update_and_attend(
     model_axis: str = "model",
     k_scale: jnp.ndarray | None = None,
     v_scale: jnp.ndarray | None = None,
+    window: int = 0,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray,
            jnp.ndarray | None, jnp.ndarray | None]:
     """Mixed prefill+decode attention over one flat token batch: write every
@@ -415,7 +423,16 @@ def paged_mixed_update_and_attend(
     ``lanes + ceil(chunk budget / block_q)`` blocks whatever the batch
     holds, filled by one gather and read back by one.  A lane's rows are
     contiguous from ``seq_q_start``.  Returns
-    (out [T, H, D], k_pool, v_pool, k_scale, v_scale)."""
+    (out [T, H, D], k_pool, v_pool, k_scale, v_scale).
+
+    ``window`` > 0 (a window layer): token t attends positions
+    ``(p - window, p]`` only.  The work list then starts at the page that
+    holds ``p - window + 1`` of an item's first query and the kernel masks
+    the keys below the bound inside it; table entries before that page are
+    never read, so the caller may have released them.  The same
+    ``pallas_call``, told the bound, under a name of its own
+    (``paged_window_attention_ragged``); the scopes are ``arks.attn_win_*``
+    (set by the caller's layer)."""
     from arks_tpu.ops.paged_attention import (
         is_int4_pool, pool_page_tokens, unpack_int4_pool)
     t_flat, h, d_model = q.shape
@@ -442,7 +459,8 @@ def paged_mixed_update_and_attend(
         from arks_tpu.ops.paged_attention import paged_gather_kv, paged_update_xla
         # The XLA oracle has no layout step of its own: all of it reads as
         # the kernel in a profile (scope names: docs/monitoring.md).
-        with jax.named_scope("arks.attn_kernel"):
+        with jax.named_scope("arks.attn_win_kernel" if window
+                             else "arks.attn_kernel"):
             kp, vp, ks, vs = paged_update_xla(
                 k_pool, v_pool, k_scale, v_scale, k_new, v_new, write_idx,
                 tables_tok, layer)
@@ -453,15 +471,16 @@ def paged_mixed_update_and_attend(
             vc = paged_gather_kv(unpack_int4_pool(vp) if int4 else vp,
                                  tables_tok, layer)
             attend_lens = jnp.where(token_slot < 0, 0, token_pos + 1)
+            lower = (attend_lens - window,) if window else ()
             if quantized:
                 ksc = paged_gather_kv(ks, tables_tok, layer)
                 vsc = paged_gather_kv(vs, tables_tok, layer)
                 out = _decode_attention_xla_quant(
                     q.reshape(t_flat, hkv, g, d), kc, vc, ksc, vsc,
-                    attend_lens)
+                    attend_lens, *lower)
             else:
                 out = decode_attention_xla(q.reshape(t_flat, hkv, g, d), kc,
-                                           vc, attend_lens)
+                                           vc, attend_lens, *lower)
         return out.reshape(t_flat, h, d)[..., :d_model], kp, vp, ks, vs
 
     from arks_tpu.ops.paged_attention import (
@@ -469,9 +488,16 @@ def paged_mixed_update_and_attend(
     )
     interpret = jax.default_backend() != "tpu"
 
+    # A window layer's ops carry scopes of their own (arks.attn_win_*), so
+    # that a profile tells the kinds apart; window is static, so the names
+    # are too.
+    sc_kernel, sc_layout = (("arks.attn_win_kernel", "arks.attn_win_layout")
+                            if window else
+                            ("arks.attn_kernel", "arks.attn_layout"))
+
     def local(qg, kn, vn, kp, vp, ks, vs, tbl, tok_tbl, widx, tslot,
               q_start, qlen, pos0, lyr):
-        with jax.named_scope("arks.attn_kernel"):
+        with jax.named_scope(sc_kernel):
             if quantized:
                 kp, vp, ks, vs = paged_kv_update_quant(
                     kp, vp, ks, vs, kn, vn, widx, tok_tbl, lyr,
@@ -485,10 +511,11 @@ def paged_mixed_update_and_attend(
         # per real (lane, q block) pair) and ONE gather of the T flat rows
         # back out of its output (the pallas_call alone carries
         # arks.attn_kernel: the innermost scope names an op).
-        with jax.named_scope("arks.attn_layout"):
+        with jax.named_scope(sc_layout):
             out = paged_mixed_attention_flat(
                 qg, kp, vp, tbl, tslot, q_start, qlen, pos0, lyr,
-                k_scale=ks, v_scale=vs, interpret=interpret)
+                k_scale=ks, v_scale=vs, interpret=interpret,
+                window=window)
         return out, kp, vp, ks, vs
 
     qg = q.reshape(t_flat, hkv, g, d)

@@ -201,7 +201,7 @@ def build_mixed_work_list(pos_start: jnp.ndarray, q_len: jnp.ndarray, *,
                           max_pages: int, head_groups: int = 1,
                           page_lo: jnp.ndarray | None = None,
                           page_hi: jnp.ndarray | None = None,
-                          n_items: int | None = None):
+                          n_items: int | None = None, window: int = 0):
     """Scalar-prefetch work list for the ragged mixed grid: one item per
     REAL (sequence, head_group, q_block), compacted to the front of a
     fixed-length [S*head_groups*num_qb] list (Pallas grids are static; the
@@ -235,6 +235,13 @@ def build_mixed_work_list(pos_start: jnp.ndarray, q_len: jnp.ndarray, *,
     hook: a caller attending only the resident window clamps the span
     here and carries the online-softmax state across spans.
 
+    ``window`` > 0 (a window layer: query at position p attends keys in
+    ``(p - window, p]``) starts each item at the page that holds the
+    lowest key its FIRST query attends, ``pos_start + qb * block_q -
+    window + 1``: the pages before it are never streamed (the kernel masks
+    the keys below each query's own bound inside the pages it does
+    stream).
+
     Built from fixed-shape jnp ops only: the device-state pipelined
     dispatches derive q_len on device (zero-host-sync), so the list must
     be traceable — no host round trip."""
@@ -262,6 +269,11 @@ def build_mixed_work_list(pos_start: jnp.ndarray, q_len: jnp.ndarray, *,
         pages = jnp.minimum(pages, page_hi.astype(jnp.int32)[seq])
     plo = jnp.zeros_like(pages) if page_lo is None else jnp.minimum(
         page_lo.astype(jnp.int32)[seq], pages)
+    if window:
+        first_key = pos_start.astype(jnp.int32)[seq] + qb * block_q \
+            - (window - 1)
+        plo = jnp.maximum(plo, jnp.minimum(
+            jnp.maximum(first_key, 0) // page, pages))
     pages = jnp.where(pad, 0, pages)
     plo = jnp.where(pad, 0, plo)
     return seq, hg, qb, plo, pages, base[seq] + qb
@@ -732,11 +744,12 @@ def _group_heads(stripe: jnp.ndarray, h0, head_group: int) -> jnp.ndarray:
 
 def _mixed_softmax_block(q_ref, kbuf, vbuf, ksbuf, vsbuf, m_ref, l_ref,
                          acc_ref, buf, si, pos0, q_lo, *, page, scale,
-                         quantized, int4, h0=None):
+                         quantized, int4, h0=None, window=0):
     """One page of online-softmax accumulation, the compute body of the
     ragged mixed kernel.  ``h0`` (grouped items only) is the first KV head
     of the item's group inside the scale buffers, which always hold the
-    page's whole head stripe."""
+    page's whole head stripe.  ``window`` > 0 also masks the keys at or
+    below ``qpos - window``."""
     _, hkv, g, bq, d = q_ref.shape
     q = q_ref[0].reshape(hkv, g * bq, d)
     kt = kbuf[buf]
@@ -761,7 +774,10 @@ def _mixed_softmax_block(q_ref, kbuf, vbuf, ksbuf, vsbuf, m_ref, l_ref,
     row = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
     qpos = pos0 + q_lo + row % bq
     kvpos = si * page + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 2)
-    scores = jnp.where(kvpos <= qpos, scores, _NEG_INF)
+    keep = kvpos <= qpos
+    if window:
+        keep = keep & (kvpos > qpos - window)
+    scores = jnp.where(keep, scores, _NEG_INF)
 
     m_prev = m_ref[:]
     l_prev = l_ref[:]
@@ -788,7 +804,8 @@ def _paged_mixed_ragged_kernel(layer_ref, tables_ref, pos_start_ref,
                                page: int, block_q: int, scale: float,
                                quantized: bool, int4: bool, depth: int,
                                head_group: int, carry: bool,
-                               emit_state: bool, latent: bool = False):
+                               emit_state: bool, latent: bool = False,
+                               window: int = 0):
     """RAGGED work-list grid: one grid step per (sequence, head_group,
     q_block) work item, the page loop INSIDE the kernel bounded by that
     item's own causal page span [wl_plo, wl_pages).  q_len=0 lanes and
@@ -820,7 +837,13 @@ def _paged_mixed_ragged_kernel(layer_ref, tables_ref, pos_start_ref,
     ``latent``: the pool is ONE array of latent rows (Hkv = 1, every head
     of the model a query row of the one group): there is no value pool,
     one copy a page, and the values are the leading lanes of the key tile
-    (as many as the output is wide)."""
+    (as many as the output is wide).
+
+    ``window`` > 0: a window layer.  The work list's ``wl_plo`` already
+    starts an item at the first page its window meets; the softmax block
+    masks the keys below each query's bound.  A query's window always
+    holds its own position, so no row of a real item is left without a
+    key."""
     del wl_blk_ref      # the index maps' column (compacted layout)
     rest = list(rest)
     vpool = None if latent else rest.pop(0)
@@ -927,7 +950,8 @@ def _paged_mixed_ragged_kernel(layer_ref, tables_ref, pos_start_ref,
                                  l_ref, acc_ref, buf, si, pos0, q_lo,
                                  page=page, scale=scale,
                                  quantized=quantized, int4=int4,
-                                 h0=h0 if grouped else None)
+                                 h0=h0 if grouped else None,
+                                 window=window)
             return loop_c
 
         jax.lax.fori_loop(plo, npages, body, 0)
@@ -972,7 +996,8 @@ def _ragged_launch(qp, k_pool, v_pool, tables32, pos32, work_list, layer,
                    k_scale, v_scale, carry_state=None, *, compact: bool,
                    block_q: int, dma_depth: int, interpret: bool,
                    head_group: int, emit_state: bool = False,
-                   latent_v: int = 0, scale: float | None = None):
+                   latent_v: int = 0, scale: float | None = None,
+                   window: int = 0):
     """The ragged work-list ``pallas_call``, one grid step per entry of
     ``work_list`` (:func:`build_mixed_work_list`).  ``qp`` holds the
     queries in ``block_q``-row blocks, in one of two layouts that differ
@@ -991,7 +1016,11 @@ def _ragged_launch(qp, k_pool, v_pool, tables32, pos32, work_list, layer,
     ``[L, N, 1, P, R]`` full width): scores over all R lanes, values the
     first ``latent_v`` lanes of the same tile, the output ``latent_v``
     wide; the call is named ``paged_latent_attention_ragged``.  ``scale``
-    multiplies the scores (``1 / sqrt(d)`` by default)."""
+    multiplies the scores (``1 / sqrt(d)`` by default).
+
+    ``window`` > 0 is a window layer's launch (the work list built with
+    the same ``window``): the same call, told the bound, named
+    ``paged_window_attention_ragged`` under ``arks.attn_win_kernel``."""
     lead, hkv, g, qrows, d = qp.shape
     quantized = k_scale is not None
     page = pool_page_tokens(k_pool, k_scale)
@@ -1002,6 +1031,9 @@ def _ragged_launch(qp, k_pool, v_pool, tables32, pos32, work_list, layer,
         raise ValueError("a latent page is one full-width pool of one row "
                          "a token: no value pool, no scales, no carried "
                          "softmax state")
+    if window and (latent or carry or emit_state):
+        raise ValueError("a window launch is one span of a K/V pool: no "
+                         "latent page, no carried softmax state")
     dv = latent_v or d
 
     if compact:
@@ -1060,10 +1092,12 @@ def _ragged_launch(qp, k_pool, v_pool, tables32, pos32, work_list, layer,
         quantized=quantized,
         int4=is_int4_pool(k_pool, k_scale), depth=dma_depth,
         head_group=head_group, carry=carry, emit_state=emit_state,
-        **({"latent": True} if latent else {}))
+        **({"latent": True} if latent else {}),
+        window=window)
     # The call alone is the kernel in a profile; the layout work around it
     # stays with the caller's scope (arks.attn_layout in the mixed step).
-    with jax.named_scope("arks.attn_kernel"):
+    with jax.named_scope("arks.attn_win_kernel" if window
+                         else "arks.attn_kernel"):
         return pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
@@ -1074,6 +1108,7 @@ def _ragged_launch(qp, k_pool, v_pool, tables32, pos32, work_list, layer,
                 dimension_semantics=("arbitrary",)),
             interpret=interpret,
             name="paged_latent_attention_ragged" if latent
+            else "paged_window_attention_ragged" if window
             else "paged_mixed_attention_ragged",
         )(jnp.asarray(layer, jnp.int32).reshape(1), tables32, pos32,
           *work_list, qp, *pools, *scale_inputs, *carry_inputs)
@@ -1130,12 +1165,13 @@ def _paged_mixed_call(q, k_pool, v_pool, tables, pos_start, q_len, layer,
 
 @functools.partial(jax.jit, static_argnames=("block_q", "nb", "interpret",
                                              "dma_depth", "head_group",
-                                             "latent_v", "scale"))
+                                             "latent_v", "scale", "window"))
 def _paged_mixed_flat_call(q, k_pool, v_pool, tables, token_slot, q_start,
                            q_len, pos_start, layer, k_scale, v_scale, *,
                            block_q: int, nb: int, dma_depth: int,
                            interpret: bool, head_group: int,
-                           latent_v: int = 0, scale: float | None = None):
+                           latent_v: int = 0, scale: float | None = None,
+                           window: int = 0):
     """Jitted ragged launch over the FLAT batch's queries ``[T, Hkv, G,
     D]`` in the block-compacted layout: ``nb`` blocks of ``block_q`` rows,
     one per real (lane, q_block) pair (``nb`` is the plan's static bound
@@ -1157,7 +1193,8 @@ def _paged_mixed_flat_call(q, k_pool, v_pool, tables, token_slot, q_start,
     work_list = build_mixed_work_list(
         pos32, qlen32, page=page, block_q=block_q,
         num_qb=-(-qmax // block_q), max_pages=tables.shape[1],
-        head_groups=n_hg, n_items=nb * n_hg)
+        head_groups=n_hg, n_items=nb * n_hg,
+        window=window)
     _, src_rows, out_rows = mixed_block_layout(
         token_slot, q_start, qlen32, block_q=block_q, nb=nb)
     qb = jnp.take(q, src_rows, axis=0).reshape(nb, block_q, hkv, g, d)
@@ -1166,7 +1203,7 @@ def _paged_mixed_flat_call(q, k_pool, v_pool, tables, token_slot, q_start,
         tables.astype(jnp.int32), pos32, work_list, layer, k_scale, v_scale,
         compact=True, block_q=block_q, dma_depth=dma_depth,
         interpret=interpret, head_group=head_group, latent_v=latent_v,
-        scale=scale)
+        scale=scale, window=window)
     # Straight out of the kernel's layout by (block, row): a transpose to
     # row-major first would copy the whole output once more.
     flat = out[out_rows // block_q, :, :, out_rows % block_q]
@@ -1241,6 +1278,7 @@ def paged_mixed_attention_flat(
     head_group: int | None = None,
     latent_v: int = 0,
     scale: float | None = None,
+    window: int = 0,
 ) -> jnp.ndarray:
     """[T, Hkv, G, D] ragged mixed attention straight over the flat batch:
     row t of lane s = token_slot[t] sits at global position
@@ -1260,7 +1298,11 @@ def paged_mixed_attention_flat(
     model's heads are the G query rows of the one group; scores are over
     all R lanes times ``scale``, values are the row's first ``latent_v``
     lanes, and the result is ``[T, 1, G, latent_v]``.  Same block layout,
-    same work list."""
+    same work list.
+
+    ``window`` > 0 (a window layer): a row at position p attends
+    ``(p - window, p]``; the work list and the kernel are told the bound
+    (:func:`build_mixed_work_list`, :func:`_ragged_launch`)."""
     t_flat, hkv, g, d = q.shape
     s = q_len.shape[0]
     # +1: with every lane a q_len = K block (t_flat == S * K, one lane)
@@ -1276,7 +1318,8 @@ def paged_mixed_attention_flat(
         pos_start, layer, k_scale, v_scale, block_q=plan["block_q"],
         nb=plan["nb"], dma_depth=plan["dma_depth"],
         interpret=interpret, head_group=plan["head_group"],
-        latent_v=latent_v, scale=scale)
+        latent_v=latent_v, scale=scale,
+        window=window)
 
 
 # ---------------------------------------------------------------------------

@@ -54,20 +54,33 @@ def yarn_mscale(yarn: tuple[float, ...]) -> float:
 
 
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
-               yarn: tuple[float, ...] = ()) -> jnp.ndarray:
+               yarn: tuple[float, ...] = (), *, rotary_dim: int | None = None,
+               attention_factor: float | None = None) -> jnp.ndarray:
     """Apply rotary embedding.
 
     x: [..., H, D] with leading dims matching ``positions`` (e.g. x [B, T, H, D]
     with positions [B, T], or x [B, H, D] with positions [B]).
+
+    ``rotary_dim`` (HF ``partial_rotary_factor`` x D): only the first
+    ``rotary_dim`` lanes of a head rotate (rotate-half within them), the
+    rest pass through.  ``attention_factor`` is HF's YaRN: ``yarn`` then
+    gives the blend alone (its first four entries) and cos and sin are
+    multiplied by the factor as stated, so that the rotated lanes' share of
+    a score carries its square and the lanes that pass through do not.
     """
-    d = x.shape[-1]
+    d = x.shape[-1] if rotary_dim is None else rotary_dim
+    if rotary_dim is not None:
+        x, rest = x[..., :d], x[..., d:]
     freqs = rope_freqs(d, theta, yarn)  # [D/2]
     angles = positions.astype(jnp.float32)[..., None, None] * freqs  # [..., 1, D/2]
     sin, cos = jnp.sin(angles), jnp.cos(angles)
-    mscale = yarn_mscale(yarn)
+    mscale = yarn_mscale(yarn) if attention_factor is None \
+        else attention_factor
     if mscale != 1.0:
         sin, cos = sin * mscale, cos * mscale
     x1, x2 = x[..., : d // 2], x[..., d // 2 :]
     x1f, x2f = x1.astype(jnp.float32), x2.astype(jnp.float32)
     out = jnp.concatenate([x1f * cos - x2f * sin, x2f * cos + x1f * sin], axis=-1)
+    if rotary_dim is not None:
+        return jnp.concatenate([out.astype(x.dtype), rest], axis=-1)
     return out.astype(x.dtype)

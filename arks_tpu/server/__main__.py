@@ -76,6 +76,13 @@ def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
                         "contiguous per-slot cache (dp)")
     p.add_argument("--prefix-cache-mb", type=int, default=256,
                    help="host-RAM budget for prefix KV reuse (0 disables)")
+    p.add_argument("--kv-pool-pages", type=int, default=0,
+                   help="a model with window and full attention layers: "
+                        "pages of the full layers' pool (0 = every slot's "
+                        "whole context, num-slots x max-model-len); below "
+                        "that, admission reserves a request's prompt + "
+                        "max_tokens in pages and a request the pool cannot "
+                        "hold yet waits at the head of the queue")
     p.add_argument("--draft-model", default=None,
                    help="speculative decoding: draft model config name or "
                         "dir (must share the target tokenizer); greedy "
@@ -262,6 +269,7 @@ def build_engine(args: argparse.Namespace):
         dtype=args.dtype, kv_cache_dtype=args.kv_cache_dtype,
         weight_dtype=args.weight_dtype, seed=args.seed,
         prefix_cache_mb=args.prefix_cache_mb,
+        kv_pool_pages=args.kv_pool_pages,
         kv_layout=args.kv_layout,
         draft_model=args.draft_model, draft_len=args.draft_len,
     )
@@ -331,6 +339,12 @@ def build_server(args: argparse.Namespace, engine):
             f"--disaggregation-mode {args.disagg} hands K and V blocks "
             "between pods (kv_transfer, the AKV1 format); a latent page "
             "is not carried")
+    if args.disagg and engine.cfg.windowed:
+        raise ValueError(
+            f"model {engine.cfg.name!r} (window and full attention layers): "
+            f"--disaggregation-mode {args.disagg} hands every layer's page "
+            "of one pool between pods (kv_transfer, the AKV1 format); the "
+            "two pools of such a model are not carried")
     if args.disagg == "prefill":
         from arks_tpu.server.disagg import PrefillServer
         # No decode loop: the engine only runs detached prefills.

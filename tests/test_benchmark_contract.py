@@ -27,6 +27,19 @@ from benchmarks.tests.test_reference import (  # noqa: E402,F401
     test_the_quantile_is_nearest_rank_and_the_verdict_wants_enough_positions,
     test_the_routing_margin_is_small_where_two_experts_tie,
 )
+from benchmarks.tests.test_reference_swa_moe import (  # noqa: E402,F401
+    served,
+    test_seeded_weights_are_the_programs_bit_for_bit as
+    test_swa_moe_seeded_weights_are_the_programs_bit_for_bit,
+    test_served_logprobs_against_the_reference as
+    test_swa_moe_served_logprobs_against_the_reference,
+    test_the_family_keeps_the_contract_and_imports_nothing_of_the_program as
+    test_swa_moe_keeps_the_contract_and_imports_nothing_of_the_program,
+    test_the_lower_precision_controls_fail as
+    test_swa_moe_lower_precision_controls_fail,
+    test_the_probes_went_through_both_pools_and_released_window_pages,
+    test_the_routing_margin_is_in_router_logit_units,
+)
 from benchmarks.tests.test_reference_mla_moe import (  # noqa: E402,F401
     test_seeded_weights_are_the_programs_bit_for_bit as
     test_mla_moe_seeded_weights_are_the_programs_bit_for_bit,

@@ -73,7 +73,8 @@ def _mixed(s, kv, q, hkv=HKV, page=PAGE, **plan):
         s((SLOTS,), jnp.int32), ks, vs)
 
 
-def _mixed_flat(s, kv, lanes, chunk, hkv=HKV, **plan):
+def _mixed_flat(s, kv, lanes, chunk, hkv=HKV, g=G, max_pages=MAX_PAGES,
+                **plan):
     """The flat batch through the block-compacted layout, as the mixed
     step calls it: ``lanes + chunk`` rows (chunk 0 = the pipelined step,
     one row a lane)."""
@@ -85,9 +86,34 @@ def _mixed_flat(s, kv, lanes, chunk, hkv=HKV, **plan):
 
     lane = s((lanes,), jnp.int32)
     return jax.jit(fn).lower(
-        s((lanes + chunk, hkv, G, D), jnp.bfloat16), kp, vp,
-        s((lanes, MAX_PAGES), jnp.int32), s((lanes + chunk,), jnp.int32),
+        s((lanes + chunk, hkv, g, D), jnp.bfloat16), kp, vp,
+        s((lanes, max_pages), jnp.int32), s((lanes + chunk,), jnp.int32),
         lane, lane, lane, ks, vs)
+
+
+# Laguna-S-2.1 (benchmarks/configs/laguna-s-2.1-ep8): 8 KV heads under 48
+# query heads in a full layer (g = 6) and 72 in a window layer (g = 9,
+# window 512), int8 pages of 256, 32 slots x 16,384 tokens, a 1024-row
+# chunk.
+LAG = dict(hkv=8, g_full=6, g_win=9, window=512, slots=32, chunk=1024,
+           max_pages=64)
+
+
+def _laguna_flat(s, chunk, window: bool):
+    return _mixed_flat(
+        s, "int8", LAG["slots"], chunk, hkv=LAG["hkv"],
+        g=LAG["g_win"] if window else LAG["g_full"],
+        max_pages=LAG["max_pages"],
+        **({"window": LAG["window"]} if window else {}))
+
+
+def _laguna_update(s):
+    t = LAG["slots"] + LAG["chunk"]
+    kp, vp, ks, vs = _pool(s, "int8", LAG["hkv"])
+    new = s((t, LAG["hkv"], D), jnp.bfloat16)
+    return pa.paged_kv_update_quant.lower(
+        kp, vp, ks, vs, new, new, s((t,), jnp.int32),
+        s((t, LAG["max_pages"]), jnp.int32), 3)
 
 
 # Kimi-K2.5 (benchmarks/configs/kimi-k2.5-ep32-l9): 64 heads over ONE latent
@@ -218,6 +244,14 @@ CASES = {
     "latent-seq-bq16": lambda s: _latent_flat(s, LAT["chunk"], block_q=16),
     "latent-pipe": lambda s: _latent_flat(s, 0),
     "latent-update": lambda s: _latent_update(s),
+    # Window and full layers at Laguna-S-2.1's widths: the window launch
+    # (the same call told the bound; g = 9) and the full layers' (g = 6),
+    # sequential and pipelined, and the row write over 64-page tables.
+    "window-int8-seq": lambda s: _laguna_flat(s, LAG["chunk"], True),
+    "window-int8-pipe": lambda s: _laguna_flat(s, 0, True),
+    "full-g6-int8-seq": lambda s: _laguna_flat(s, LAG["chunk"], False),
+    "full-g6-int8-pipe": lambda s: _laguna_flat(s, 0, False),
+    "update-int8-hkv8-64-pages": lambda s: _laguna_update(s),
     # tp=4 leaves one KV head per chip.
     "mixed-int8-chunk-hkv1": lambda s: _mixed(s, "int8", CHUNK + 1, hkv=1),
     "update-int8-hkv1": lambda s: _update(s, "int8", hkv=1),
@@ -263,4 +297,8 @@ def test_kernel_compiles_for_v5e(chip, case):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
     compiled = CASES[case](spec).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    if case.startswith("window-"):
+        # The window launch has a name of its own in a profile.
+        assert "paged_window_attention_ragged" in text
