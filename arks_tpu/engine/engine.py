@@ -2211,6 +2211,7 @@ class InferenceEngine:
             # passed-through input would alias and read ready instantly).
             self._spill_gather_fn = _named_jit(
                 "arks_spill_gather", tf.gather_pool_pages)
+            self._spill_warm = False
 
             def restore_scatter(cache, kb, vb, ksb, vsb, pages, n_valid):
                 cache = tf.scatter_pool_pages(cache, kb, vb, pages, n_valid,
@@ -8665,6 +8666,8 @@ class InferenceEngine:
                 self._warm_promote()
             if not self._mixed_tail_warm:
                 self._warm_mixed_tail()
+            if not self._spill_warm:
+                self._warm_spill()
             return True
         finally:
             if sec:
@@ -8704,6 +8707,18 @@ class InferenceEngine:
                      self._guide_dev)
             self._cache, self._sampling = out[-2], out[-1]
         self._mixed_tail_warm = True
+
+    def _warm_spill(self) -> None:
+        """Compile the host tier's spill gather before the first
+        sequential step's dispatch (the pool's first eviction may fall
+        inside a window that must not compile): one group of pages
+        gathered into a staging block that is dropped.  Where the tier is
+        off the program is never called; that includes every gang leader
+        (_host_tier_on), so no follower has a call to mirror."""
+        if self._host_tier_on():
+            self._spill_gather_fn(
+                self._cache, np.zeros((self._spill_group,), np.int32))
+        self._spill_warm = True
 
     def _mixed_account(self, a: dict, rows: int, n_chunk: int, qmax: int,
                        tag: str) -> None:

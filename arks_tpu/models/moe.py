@@ -8,8 +8,19 @@ TPU-first formulation:
   expert dim, with unselected experts zeroed by the router-weight tensor.
   Decode is HBM-bound — all expert weights are read once per step no matter
   how many tokens route to them — so compute-all costs nothing extra at
-  serving batch sizes while keeping shapes static for XLA.  (A block-sparse
-  Pallas dispatch for large-T prefill is a later optimization.)
+  serving batch sizes while keeping shapes static for XLA.
+- **Batched dispatch** (:func:`_batched_dispatch`; large token batches on an
+  unsharded expert dim): every expert takes a fixed batch of rows in one
+  expert-major SwiGLU, what it draws beyond them follows in overflow tiles.
+  Quantised leaves are read as they are stored in both: the int8 -> bf16
+  convert sits in the contraction's operand read and the per-channel scale
+  lands on its output, so no full-width copy of an expert stack is written
+  to HBM in any step program.  Timed on a v5e at Mixtral-8x7B's widths
+  (8 experts top-2, int8, 4 layers; PERF.md, PR 33): 64 rows 7.6 ms dense
+  (the HBM rate) against 46 ms for dequantise + ``ragged_dot``; 320 rows
+  14.4 ms batched, 19.8 dense, 58 dequantise + ``ragged_dot``, 37 the
+  block-sparse Pallas kernel (deleted with its knob).  ``jax.lax.ragged_dot``
+  stays for plain (unquantised) leaves: training and float tests.
 - **Expert parallelism = model-axis sharding**: expert dims shard over the
   ``model`` mesh axis (each device holds E/tp experts); activations stay
   replicated across that axis between blocks, so XLA turns the final
@@ -160,19 +171,26 @@ def held_first(cfg) -> int:
 
 
 def _held_capacity(n_tokens: int, cfg) -> int:
-    """Rows each held expert takes in the share's batched dispatch: four
-    times what a uniform router sends an expert, in tiles of 128, at most
-    every token.  What an expert draws beyond them goes through
-    :func:`_share_dispatch`'s overflow tiles: nothing is ever dropped."""
+    """Rows each held expert takes in the batched dispatch, in tiles of
+    128, at most every token.  A share: four times what a uniform router
+    sends an expert (its experts draw few rows of many).  A layer held
+    whole: one and a half times (every pair lands here, the batch is what
+    the step computes, and at 8 experts top-2 four times would be every
+    token: the dense dispatch).  What an expert draws beyond them goes
+    through :func:`_batched_dispatch`'s overflow tiles: nothing is ever
+    dropped."""
     fair = -(-n_tokens * cfg.num_experts_per_tok // cfg.router_width)
-    return min(n_tokens, -(-4 * fair // 128) * 128)
+    if cfg.expert_parallel_size > 1:
+        return min(n_tokens, -(-4 * fair // 128) * 128)
+    return min(n_tokens, -(-3 * fair // 256) * 128)
 
 
 # Overflow tiles a share's dispatch runs whether it needs them or not: the
 # seeded routers of the benchmark's configuration overflow by a few tiles
 # a layer on most seeds and not at all on others, and a step whose time
 # followed that moved a 45 s closed-loop reading by 1-2 % (PERF.md §6,
-# PR 27).  A layer that needs more runs more.
+# PR 27).  A layer that needs more runs more.  (A layer held whole runs a
+# count fixed by its shape: :func:`_batched_dispatch`.)
 _SPARE_TILES = 4
 
 
@@ -180,29 +198,36 @@ def _expert_dot(eq: str, x: jnp.ndarray, w) -> jnp.ndarray:
     """``[X, C, a] x [X, a, b] -> [X, C, b]`` where ``w`` may be a
     quantised leaf: int8 converts in the dot's operand read and scales the
     output per channel (``quant.qeinsum``'s rule with the expert dim
-    leading); int4 dequantises its operand."""
-    from arks_tpu.models.quant import dequantize, is_quantized
+    leading); int4's group scales vary along the contraction, so its
+    dequant is the operand's elementwise producer (``qeinsum``'s rule
+    again)."""
+    from arks_tpu.models.quant import is_quantized, qeinsum
     if not is_quantized(w) or "gs" in w:
-        return jnp.einsum(eq, x, dequantize(w, x.dtype))
+        return qeinsum(eq, x, w)
     return jnp.einsum(eq, x, w["q"].astype(x.dtype)) * w["s"].astype(x.dtype)
 
 
-def _share_dispatch(x2: jnp.ndarray, vals: jnp.ndarray, idx: jnp.ndarray,
-                    mp: Params, cfg, row_valid: jnp.ndarray | None = None
-                    ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """The routed experts HELD HERE on ``[n, E]`` rows: ``(out [n, E],
-    held [n, k] bool)``.  Pairs are sorted by held expert (absent experts
-    and the rows that carry no token last, never gathered: a step's
-    padding rows are all alike and would all land on the same eight
-    experts).  Every held expert then takes its first
-    :func:`_held_capacity` rows in ONE ``[X, C, E]`` batched SwiGLU, the
-    int8 dequant fused into the contraction: work that does not depend on
-    how the router spread the tokens, so a step's time is the same from
-    seed to seed.  What an expert draws beyond C rows (more than four
-    times its fair load) follows in tiles of C rows, one expert a tile, at
-    about a twelfth of the batch's cost each; ``_SPARE_TILES`` of them run
-    in any case (dead where not needed), so that only a layer that needs
-    more than those takes longer."""
+def _batched_dispatch(x2: jnp.ndarray, vals: jnp.ndarray, idx: jnp.ndarray,
+                      mp: Params, cfg, row_valid: jnp.ndarray | None = None
+                      ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The routed experts HELD HERE (a share's, or all of a layer held
+    whole) on ``[n, E]`` rows: ``(out [n, E], held [n, k] bool)``.  Pairs
+    are sorted by held expert (absent experts and the rows that carry no
+    token last, never gathered: a step's padding rows are all alike and
+    would all land on the same experts).  Every held expert then takes
+    its first :func:`_held_capacity` rows in ONE ``[X, C, E]`` batched
+    SwiGLU, the int8 convert fused into the contraction: work that does
+    not depend on how the router spread the tokens, so a step's time is
+    the same from seed to seed.  What an expert draws beyond C rows
+    follows in tiles of C rows, one expert a tile, each a read of that
+    expert's weights.  A share runs as many tiles as its experts need in
+    a loop, ``_SPARE_TILES`` of them in any case (dead where not needed),
+    so that only a layer that needs more than those takes longer.  A
+    layer held whole knows how many pairs it holds, all ``n x k``, so
+    ``(n x k - 1) // C`` tiles always suffice: it runs exactly those,
+    unrolled (a loop would have the compiler copy the expert stacks into
+    its carry), and an expert that draws every row is as exact and costs
+    the step the same as a uniform router."""
     n, e = x2.shape
     k, nx = cfg.num_experts_per_tok, cfg.num_experts
     cap = _held_capacity(n, cfg)
@@ -220,7 +245,6 @@ def _share_dispatch(x2: jnp.ndarray, vals: jnp.ndarray, idx: jnp.ndarray,
         # expert whose running count of tiles passes t.
         tiles = (jnp.maximum(sizes - cap, 0) + cap - 1) // cap      # [X]
         tile_ends = jnp.cumsum(tiles)
-        spare = _SPARE_TILES if cap < n else 0
 
     def slots(out, experts, first, weights):
         """Add what ``experts`` [x] give their rows ``first + [0, C)``."""
@@ -256,8 +280,13 @@ def _share_dispatch(x2: jnp.ndarray, vals: jnp.ndarray, idx: jnp.ndarray,
 
     out = slots(jnp.zeros((n, e), x2.dtype), jnp.arange(nx),
                 jnp.zeros((nx,), jnp.int32), mp)
-    out = jax.lax.fori_loop(0, jnp.maximum(tile_ends[-1], spare),
-                            overflow_tile, out)
+    if cfg.expert_parallel_size > 1:
+        spare = _SPARE_TILES if cap < n else 0
+        out = jax.lax.fori_loop(0, jnp.maximum(tile_ends[-1], spare),
+                                overflow_tile, out)
+    else:
+        for t in range((n * k - 1) // cap if cap < n else 0):
+            out = overflow_tile(jnp.int32(t), out)
     return out, held
 
 
@@ -285,13 +314,12 @@ def _shared_expert(x2: jnp.ndarray, mp: Params, cfg,
 _GROUPED_MIN_TOKENS = 64  # below this, dense dispatch wins on dispatch cost
 
 
-def _whole_dispatch(x2: jnp.ndarray, vals: jnp.ndarray, idx: jnp.ndarray,
-                    mp: Params, cfg, dtype) -> jnp.ndarray:
-    """The routed experts of a layer held whole on ``[n, E]`` rows: every
-    (token, expert) pair, sorted by expert, through three ``ragged_dot``
-    contractions (or the block-sparse Pallas kernel)."""
-    from arks_tpu.models.quant import dequantize
-    from arks_tpu.ops.moe_kernel import grouped_ffn, moe_impl
+def _ragged_dispatch(x2: jnp.ndarray, vals: jnp.ndarray, idx: jnp.ndarray,
+                     mp: Params, cfg) -> jnp.ndarray:
+    """The routed experts of a layer held whole, PLAIN leaves (training,
+    float tests), on ``[n, E]`` rows: every (token, expert) pair, sorted by
+    expert, through three ``ragged_dot`` contractions (differentiable;
+    ``ragged_dot`` takes no quantised leaf, those go the batched way)."""
     n, e = x2.shape
     k, nx = cfg.num_experts_per_tok, cfg.num_experts
     with jax.named_scope("arks.moe_route"):
@@ -300,85 +328,65 @@ def _whole_dispatch(x2: jnp.ndarray, vals: jnp.ndarray, idx: jnp.ndarray,
         token_of = order // k                               # source token
         xs = jnp.take(x2, token_of, axis=0)                 # [T*k, E] sorted
         group_sizes = jnp.bincount(flat_expert, length=nx)
-
-    if moe_impl() == "pallas":
-        # Block-sparse Pallas grouped matmul with the dequant FUSED:
-        # int8 per-channel scales fold into the accumulator; int4 group
-        # scales dequant the weight tile in-register — either way the
-        # full-width expert weights never materialize in HBM (ragged_dot
-        # below forces exactly that materialization).
-        with jax.named_scope("arks.moe_dot"):
-            down = grouped_ffn(xs, jnp.take(flat_expert, order),
-                               group_sizes, mp["w_gate"], mp["w_up"],
-                               mp["w_down"], dtype)
-    else:
-        # ragged_dot needs plain arrays; dequantized expert weights
-        # materialize here (prefill-only path — dense/decode keeps the
-        # fused dequant).
-        def grouped(rows, w):
-            # Traced in the order it always was (dequantise, contract,
-            # three times over): the scopes add names, not a schedule.
-            with jax.named_scope("arks.moe_dequant"):
-                w = dequantize(w, dtype)
-            with jax.named_scope("arks.moe_dot"):
-                return jax.lax.ragged_dot(rows, w, group_sizes)
-
-        gate = grouped(xs, mp["w_gate"])
-        up = grouped(xs, mp["w_up"])
-        with jax.named_scope("arks.moe_dot"):
-            act = jax.nn.silu(gate.astype(jnp.float32)).astype(
-                gate.dtype) * up
-        down = grouped(act, mp["w_down"])                   # [T*k, E]
-
+    with jax.named_scope("arks.moe_dot"):
+        gate = jax.lax.ragged_dot(xs, mp["w_gate"], group_sizes)
+        up = jax.lax.ragged_dot(xs, mp["w_up"], group_sizes)
+        act = jax.nn.silu(gate.astype(jnp.float32)).astype(gate.dtype) * up
+        down = jax.lax.ragged_dot(act, mp["w_down"], group_sizes)  # [T*k, E]
     with jax.named_scope("arks.moe_route"):
         w = jnp.take(vals.reshape(-1), order).astype(down.dtype)   # [T*k]
         return jnp.zeros((n, e), down.dtype).at[token_of].add(
             down * w[:, None])
 
 
+def _batch_pays(n_tokens: int, mp: Params, cfg) -> bool:
+    """Whether the grouped path does less than the dense dispatch on
+    ``n_tokens`` rows.  The quantised experts of a layer held whole go
+    through the batched dispatch, which is the dense one with gathers
+    around it once an expert's batch is every token (64 rows of 8 experts
+    top-2: the dense dispatch runs at the HBM rate there)."""
+    from arks_tpu.models.quant import is_quantized
+    if cfg.expert_parallel_size > 1 or not is_quantized(mp["w_gate"]):
+        return True
+    return _held_capacity(n_tokens, cfg) < n_tokens
+
+
 def moe_ffn_grouped(x: jnp.ndarray, mp: Params, cfg,
                     row_valid: jnp.ndarray | None = None):
     """Dropless grouped dispatch: top-k cost instead of all-expert cost.
 
-    Flattens tokens, sorts the (token, slot) pairs by routed expert, runs the
-    three expert matmuls as ``jax.lax.ragged_dot`` grouped contractions (one
-    MXU pass over exactly T*k rows), and scatter-adds the weighted expert
-    outputs back per token.  Numerically equivalent to the dense dispatch —
-    no capacity factor, no dropped tokens — at k/X of its FLOPs (8x cheaper
-    for a 64-expert top-8 model).  Used for large-T prefill and training on
-    an unsharded expert dim; the dense path stays for decode (HBM-bound:
-    every expert's weights are read once regardless) and for expert-parallel
-    meshes, where the einsum + psum formulation lets XLA shard the expert
-    dim (ragged groups can't span devices).
+    Flattens tokens, sorts the (token, slot) pairs by routed expert, runs
+    the expert matmuls over the sorted rows and scatter-adds the weighted
+    expert outputs back per token.  Numerically equivalent to the dense
+    dispatch — no capacity factor, no dropped tokens.  Used for large-T
+    prefill and training on an unsharded expert dim; the dense path stays
+    for decode (HBM-bound: every expert's weights are read once
+    regardless) and for expert-parallel meshes, where the einsum + psum
+    formulation lets XLA shard the expert dim (groups can't span devices).
 
-    Under a share (``cfg.expert_parallel_size`` > 1) the pairs whose expert
-    lives on another chip are never gathered and the held experts run as
-    :func:`_share_dispatch`'s rounds of fixed size.  ``row_valid`` [T] (rows
-    that carry a token) makes the call return ``(out, held_pairs)``: the
-    valid rows' pairs that landed on a held expert."""
+    Quantised leaves, and any share of a layer (``cfg.expert_parallel_size``
+    > 1: the pairs whose expert lives on another chip are never gathered),
+    run as :func:`_batched_dispatch`'s rounds of fixed size; the plain
+    leaves of a layer held whole as :func:`_ragged_dispatch`.  ``row_valid``
+    [T] (rows that carry a token) makes the call return ``(out,
+    held_pairs)``: the valid rows' pairs that landed on a held expert."""
+    from arks_tpu.models.quant import is_quantized
     lead = x.shape[:-1]
     e = x.shape[-1]
-    k, nx = cfg.num_experts_per_tok, cfg.num_experts
+    k = cfg.num_experts_per_tok
     share = cfg.expert_parallel_size > 1
     x2 = x.reshape(-1, e)
-    n = x2.shape[0]
 
     # Profile scopes (docs/monitoring.md): routing and the sort into expert
-    # order; the dequantised expert weights; the grouped matmuls.
+    # order; the expert matmuls.
     with jax.named_scope("arks.moe_route"):
         logits = jnp.einsum("te,ex->tx", x2, mp["router"])
         vals, idx = router_topk(logits, cfg, mp.get("router_bias"))  # [T, k]
 
-    from arks_tpu.ops.moe_kernel import moe_impl
-    if share and moe_impl() == "pallas":
-        raise NotImplementedError(
-            "ARKS_MOE_KERNEL=pallas: the block-sparse kernel's group "
-            "padding does not take a share of a layer (rows of absent "
-            "experts); serve a share through the XLA path")
-    if share:
-        out, held = _share_dispatch(x2, vals, idx, mp, cfg, row_valid)
+    if share or is_quantized(mp["w_gate"]):
+        out, held = _batched_dispatch(x2, vals, idx, mp, cfg, row_valid)
     else:
-        out = _whole_dispatch(x2, vals, idx, mp, cfg, x.dtype)
+        out = _ragged_dispatch(x2, vals, idx, mp, cfg)
 
     if "shared_gate_proj" in mp:
         out = out + _shared_expert(x2, mp, cfg)
@@ -407,7 +415,8 @@ def moe_ffn(x: jnp.ndarray, mp: Params, cfg, constrain=None,
         # ([B, E]): decode stays dense regardless of slot count — it is
         # HBM-bound and the sort/gather dispatch only adds overhead there.
         grouped = (constrain is None and x.ndim >= 3
-                   and n_tokens >= _GROUPED_MIN_TOKENS)
+                   and n_tokens >= _GROUPED_MIN_TOKENS
+                   and _batch_pays(n_tokens, mp, cfg))
     if grouped:
         return moe_ffn_grouped(x, mp, cfg, row_valid)
     from arks_tpu.models.quant import qeinsum
@@ -423,8 +432,7 @@ def moe_ffn(x: jnp.ndarray, mp: Params, cfg, constrain=None,
                                  ).astype(jnp.int32)
         weights = weights.astype(x.dtype)                      # [.., X]
 
-    # The dense dispatch keeps the dequant fused into the contraction, so
-    # it has no arks.moe_dequant of its own.
+    # The dequant is fused into the contraction.
     with jax.named_scope("arks.moe_dot"):
         gate = qeinsum("...e,xef->...xf", x, mp["w_gate"])
         up = qeinsum("...e,xef->...xf", x, mp["w_up"])

@@ -145,8 +145,13 @@ def qeinsum(eq: str, x: jnp.ndarray, w) -> jnp.ndarray:
 
 
 def dequantize(w, dtype: jnp.dtype) -> jnp.ndarray:
-    """Materialize the full-width weight (grouped-MoE ragged_dot path only —
-    everywhere else use qeinsum so the dequant stays fused)."""
+    """Materialize the full-width weight.  No expert stack comes here
+    since PR 33 (`models/moe.py` reads quantised experts in the
+    contraction: dequantise + ``ragged_dot`` cost 46-58 ms a Mixtral step
+    where the fused forms take 8-14, PERF.md); the one caller left in a
+    step program is the latent block's absorbed ``wkv_b``
+    (`transformer._wkv_b`, a few MB).  Everywhere else use qeinsum so the
+    dequant stays fused."""
     if not is_quantized(w):
         return w
     if "gs" in w:

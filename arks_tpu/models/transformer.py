@@ -534,7 +534,7 @@ def _block_tail(h: jnp.ndarray, attn: jnp.ndarray, lp: Params,
 
 
 # A routed model's parts carry scopes of their own inside moe.py
-# (arks.moe_route / moe_dequant / moe_dot); the innermost scope names an op,
+# (arks.moe_route / moe_dot / moe_shared); the innermost scope names an op,
 # so arks.ffn is what is left: the norm, and a dense FFN whole.
 @_scope("arks.ffn")
 def _mlp(h: jnp.ndarray, lp: Params, cfg: ModelConfig, mesh: Mesh | None,

@@ -214,10 +214,6 @@ _k("ARKS_ATTN_BLOCK_S", "int", "256",
    "Sequence block of the Pallas decode attention grid.", "kernels")
 _k("ARKS_ATTN_BLOCK_B", "int", "16",
    "Batch block of the Pallas decode attention grid.", "kernels")
-_k("ARKS_MOE_KERNEL", "enum", "auto",
-   "MoE grouped-matmul implementation (auto resolves to the xla "
-   "ragged_dot path until the Pallas kernel wins on hardware).",
-   "kernels", ("auto", "pallas", "xla"))
 _k("ARKS_KERNEL_TUNE", "enum", "cached",
    "Kernel autotune mode: off = built-in defaults, cached = use the "
    "persisted table, sweep = retune and persist.", "kernels",

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from arks_tpu.ops import moe_kernel
 from arks_tpu.ops import paged_attention as pa
 from arks_tpu.ops import pallas_attention as sa
 
@@ -193,21 +192,6 @@ def _slot_update(s, kv):
     return sa.kv_cache_update_quant.lower(kc, kc, *scales, new, new, idx, 3)
 
 
-def _moe(s, kind, experts=8, k=4096, n=14336, rows=1024):
-    """Mixtral-8x7B expert widths: bf16, int8 per-channel scales, int4
-    groupwise scales."""
-    xs, tiles = s((rows, k), jnp.bfloat16), s((rows // 128,), jnp.int32)
-    if kind == "bf16":
-        return moe_kernel.grouped_matmul.lower(
-            xs, s((experts, k, n), jnp.bfloat16), tiles)
-    w = s((experts, k, n), jnp.int8)
-    if kind == "int8":
-        return moe_kernel.grouped_matmul.lower(
-            xs, w, tiles, s((experts, 1, n), jnp.float32))
-    return moe_kernel.grouped_matmul.lower(
-        xs, w, tiles, None, s((experts, k // 128, n), jnp.float32))
-
-
 CASES = {
     # The default path: one decode token per lane, and a chunk + 1.
     "mixed-int8-q1": lambda s: _mixed(s, "int8", 1),
@@ -260,8 +244,7 @@ CASES = {
                                                     hkv=2),
     # The rest of what serves somewhere: other page sizes, the
     # windowed-residency span (raw softmax state out), the legacy paged
-    # decode step, the slot layout (dp engines), the MoE kernel's float
-    # and int4 forms.
+    # decode step, the slot layout (dp engines).
     "mixed-int8-chunk-page128": lambda s: _mixed(s, "int8", CHUNK + 1,
                                                  page=128),
     "mixed-int8-chunk-page512": lambda s: _mixed(s, "int8", CHUNK + 1,
@@ -273,8 +256,6 @@ CASES = {
     "slot-decode-int8-b64-s1024": lambda s: _slot_decode(s, "int8"),
     "slot-update-bf16": lambda s: _slot_update(s, "bf16"),
     "slot-update-int8": lambda s: _slot_update(s, "int8"),
-    "moe-grouped-matmul-bf16": lambda s: _moe(s, "bf16"),
-    "moe-grouped-matmul-int4": lambda s: _moe(s, "int4"),
     # Repaired in PR 21 (refused by the chip's compiler before it):
     # int4 nibble shifts on 8-bit vectors ...
     "mixed-int4-chunk": lambda s: _mixed(s, "int4", CHUNK + 1),
@@ -284,10 +265,8 @@ CASES = {
         s, "int8", CHUNK + 1, head_group=1),
     "mixed-int8-chunk-head-group-2": lambda s: _mixed(
         s, "int8", CHUNK + 1, head_group=2),
-    # ... 16.75 MB of scoped VMEM at the default blocks ...
+    # ... and 16.75 MB of scoped VMEM at the default blocks.
     "slot-decode-bf16-b64-s1024": lambda s: _slot_decode(s, "bf16"),
-    # ... and a (1, 128) scale block on an [E, N] array.
-    "moe-grouped-matmul-int8": lambda s: _moe(s, "int8"),
 }
 
 
@@ -302,3 +281,44 @@ def test_kernel_compiles_for_v5e(chip, case):
     if case.startswith("window-"):
         # The window launch has a name of its own in a profile.
         assert "paged_window_attention_ragged" in text
+
+
+@pytest.mark.parametrize("rows", [64, 320])
+@pytest.mark.parametrize("kind", ["int8", "int4"])
+def test_quantised_experts_compile_for_v5e_as_stored(chip, kind, rows):
+    """One routed layer at Mixtral-8x7B's widths (8 experts top-2, 4096 x
+    14336) on the mixtral cell's two step shapes, 64 rows (the dense
+    dispatch) and 320 (the batched one): the compiled program writes no
+    full-width copy of an int8 expert stack (940 MB a leaf; it did, three
+    a layer, until PR 33) and needs no Mosaic kernel."""
+    import types
+
+    from arks_tpu.models import moe
+
+    x, e, f = 8, 4096, 14336
+    cfg = types.SimpleNamespace(
+        num_experts=x, num_experts_per_tok=2, router_width=x,
+        expert_parallel_size=1, expert_parallel_rank=0,
+        scoring_func="softmax", norm_topk_prob=True,
+        routed_scaling_factor=1.0)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def leaf(k, n):
+        if kind == "int8":
+            return {"q": spec((x, k, n), jnp.int8),
+                    "s": spec((x, 1, n), jnp.float32)}
+        return {"q": spec((x, k, n), jnp.int4),
+                "gs": spec((x, k // 128, n), jnp.float32)}
+
+    lp = {"router": spec((e, x), jnp.bfloat16), "w_gate": leaf(e, f),
+          "w_up": leaf(e, f), "w_down": leaf(f, e)}
+    compiled = jax.jit(lambda lp, h: moe.moe_ffn(h, lp, cfg)).lower(
+        lp, spec((1, rows, e), jnp.bfloat16)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text and "ragged-dot" not in text
+    if kind == "int8":
+        assert f"bf16[{x},{e},{f}]" not in text
+        assert f"bf16[{x},{f},{e}]" not in text
+        assert compiled.memory_analysis().temp_size_in_bytes < 400e6

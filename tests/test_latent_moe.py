@@ -294,15 +294,6 @@ def test_rows_that_carry_no_token_take_no_place_in_the_share_dispatch():
                                   np.asarray(only_shared[0, 100:], np.float32))
 
 
-def test_the_pallas_expert_kernel_refuses_a_share(monkeypatch):
-    monkeypatch.setenv("ARKS_MOE_KERNEL", "pallas")
-    cfg = get_config("tiny-mla-moe").with_expert_share(2, 0)
-    p = moe.init_moe_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16)
-    lp = jax.tree.map(lambda x: x[0], p)
-    with pytest.raises(NotImplementedError, match="share"):
-        moe.moe_ffn_grouped(jnp.zeros((1, 64, 64), jnp.bfloat16), lp, cfg)
-
-
 # ---------------------------------------------------------------------------
 # The pool: one latent row a token, stored once
 # ---------------------------------------------------------------------------

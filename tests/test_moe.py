@@ -169,95 +169,130 @@ def test_moe_hf_config_roundtrip():
 
 
 # ---------------------------------------------------------------------------
-# Block-sparse Pallas grouped matmul (ARKS_MOE_KERNEL=pallas)
+# Quantised experts of a layer held whole: read as stored, never widened
 # ---------------------------------------------------------------------------
 
 
-def test_grouped_matmul_kernel_matches_ragged_dot():
-    """pad_groups + grouped_matmul == ragged_dot on the same sorted rows,
-    including the fused int8 dequant."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from arks_tpu.models.quant import quantize_tensor
-    from arks_tpu.ops.moe_kernel import grouped_ffn, grouped_matmul, pad_groups
-
-    rng = np.random.default_rng(0)
-    t, k, n, nx, bt = 37, 32, 48, 4, 8
-    sorted_expert = jnp.asarray(np.sort(rng.integers(0, nx, t)), jnp.int32)
-    group_sizes = jnp.bincount(sorted_expert, length=nx)
-    xs = jnp.asarray(rng.standard_normal((t, k)), jnp.float32)
-    w = jnp.asarray(rng.standard_normal((nx, k, n)), jnp.float32)
-
-    ref = jax.lax.ragged_dot(xs, w, group_sizes)
-    xs_p, dest, bexp = pad_groups(xs, sorted_expert, group_sizes, bt)
-    got = grouped_matmul(xs_p, w, bexp, block_t=bt, block_n=16,
-                         interpret=True)[dest]
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               atol=1e-4, rtol=1e-4)
-
-    # int8 fused dequant vs materialized dequant + ragged_dot.
-    wq = quantize_tensor(w)
-    from arks_tpu.models.quant import dequantize
-    ref_q = jax.lax.ragged_dot(xs, dequantize(wq, jnp.float32), group_sizes)
-    got_q = grouped_matmul(xs_p, wq["q"], bexp, wq["s"].astype(jnp.float32),
-                           block_t=bt, block_n=16, interpret=True)[dest]
-    np.testing.assert_allclose(np.asarray(got_q), np.asarray(ref_q),
-                               atol=1e-3, rtol=1e-3)
-
-    # int4 groupwise fused dequant vs materialized dequant + ragged_dot.
-    from arks_tpu.models.quant import quantize_tensor_int4
-    w4 = quantize_tensor_int4(w, group=8)
-    ref_4 = jax.lax.ragged_dot(xs, dequantize(w4, jnp.float32), group_sizes)
-    got_4 = grouped_matmul(xs_p, w4["q"], bexp,
-                           w_group_scale=w4["gs"].astype(jnp.float32),
-                           block_t=bt, block_n=16, interpret=True)[dest]
-    np.testing.assert_allclose(np.asarray(got_4), np.asarray(ref_4),
-                               atol=1e-3, rtol=1e-3)
+def _quantised_layer(name, bits, forced):
+    """Layer 0 of ``name`` with int8 / int4 expert leaves, and the same
+    leaves widened to float32 (the oracle's).  ``forced``: the router's
+    first input row sends expert 0 every token and the last expert none
+    (the tests set feature 0 of every token to 1)."""
+    from arks_tpu.models import quant
+    cfg = get_config(name)
+    mp = moe.init_moe_params(cfg, jax.random.PRNGKey(0), jnp.float32)
+    lp = jax.tree.map(lambda t: t[0], mp)
+    if forced:
+        push = jnp.zeros((cfg.num_experts,)).at[0].set(50.).at[-1].set(-50.)
+        lp["router"] = lp["router"].at[0].set(push)
+    qp = quant.quantize_params(lp, bits=bits, group=32)
+    wide = {k: (quant.dequantize(v, jnp.float32) if quant.is_quantized(v)
+                else v) for k, v in qp.items()}
+    return cfg, qp, wide
 
 
-def test_moe_grouped_pallas_matches_xla_path(monkeypatch):
-    """The full grouped MoE FFN through the Pallas kernel == the ragged_dot
-    path, float and quantized."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
+def _tokens(cfg, rows, seed=1):
+    x = jax.random.normal(jax.random.PRNGKey(seed),
+                          (1, rows, cfg.hidden_size), jnp.float32)
+    return x.at[..., 0].set(1.0)
 
-    from arks_tpu.models import get_config
-    from arks_tpu.models import transformer as tf
-    from arks_tpu.models.moe import moe_ffn_grouped
-    from arks_tpu.models.quant import quantize_params
 
+@pytest.mark.parametrize("forced", [False, True], ids=["seeded", "forced"])
+@pytest.mark.parametrize("rows", [64, 300, 320])
+@pytest.mark.parametrize("bits", [8, 4], ids=["int8", "int4"])
+def test_quantised_experts_match_the_dense_float32_dispatch(bits, rows,
+                                                            forced):
+    """The dispatch a step takes for a quantised layer held whole (dense
+    at 64 rows, batched with its fixed overflow tiles at 300 and 320, the
+    last tile ragged at 300) against the dense dispatch on the widened
+    float32 leaves, with a seeded router and with one that sends an
+    expert every row and another none."""
+    cfg, qp, wide = _quantised_layer("tiny-mixtral", bits, forced)
+    x = _tokens(cfg, rows)
+    want = moe.moe_ffn(x, wide, cfg, grouped=False)
+    if forced:
+        load = np.asarray(moe.router_weights(
+            jnp.einsum("...e,ex->...x", x, qp["router"]), cfg) != 0
+        ).reshape(rows, -1).sum(0)
+        assert load[0] == rows and load[-1] == 0
+    for grouped in (None, True):
+        got = moe.moe_ffn(x, qp, cfg, grouped=grouped)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-4 * float(jnp.abs(want).max()),
+                                   err_msg=str(grouped))
+
+
+@pytest.mark.parametrize("bits", [8, 4], ids=["int8", "int4"])
+def test_quantised_experts_at_the_cells_shape_and_with_padding_rows(bits):
+    """8 experts top-2 at 320 rows, the shape rule of the benchmark's
+    Mixtral step (a batch of 128 rows an expert and four overflow tiles),
+    with a shared expert beside them; and rows that carry no token: they
+    take no place in the batch and count no pair."""
+    cfg, qp, wide = _quantised_layer("tiny-moe", bits, forced=True)
+    assert moe._held_capacity(320, cfg) == 128
+    assert moe._held_capacity(64, cfg) == 64      # the dense dispatch's
+    x = _tokens(cfg, 320)
+    want = moe.moe_ffn(x, wide, cfg, grouped=False)
+    tol = 2e-4 * float(jnp.abs(want).max())
+    np.testing.assert_allclose(np.asarray(moe.moe_ffn(x, qp, cfg)),
+                               np.asarray(want), atol=tol)
+    valid = (jnp.arange(320) < 201)[None]
+    got, held = moe.moe_ffn(x, qp, cfg, row_valid=valid)
+    assert int(held) == 201 * cfg.num_experts_per_tok
+    np.testing.assert_allclose(np.asarray(got[0, :201]),
+                               np.asarray(want[0, :201]), atol=tol)
+    only_shared = moe._shared_expert(x, qp, cfg)
+    np.testing.assert_allclose(np.asarray(got[0, 201:]),
+                               np.asarray(only_shared[0, 201:]), atol=tol)
+
+
+def test_the_auto_rule_batches_quantised_experts_only_where_it_pays(
+        monkeypatch):
+    """Quantised leaves of a layer held whole go grouped only while an
+    expert's batch is smaller than the step's rows; plain leaves keep the
+    token threshold (``test_moe_grouped_auto_threshold``)."""
+    cfg, qp, _ = _quantised_layer("tiny-moe", 8, forced=False)
+    calls = []
+    real = moe.moe_ffn_grouped
+    monkeypatch.setattr(moe, "moe_ffn_grouped",
+                        lambda *a, **kw: calls.append(1) or real(*a, **kw))
+    moe.moe_ffn(_tokens(cfg, 64), qp, cfg)
+    assert calls == []                      # cap == rows: dense
+    moe.moe_ffn(_tokens(cfg, 320), qp, cfg)
+    assert calls == [1]                     # 128 of 320 rows an expert
+    moe.moe_ffn(_tokens(cfg, 320)[0], qp, cfg)
+    assert calls == [1]                     # decode-shaped: dense
+
+
+@pytest.mark.parametrize("rows", [64, 320])
+@pytest.mark.parametrize("bits", [8, 4], ids=["int8", "int4"])
+def test_no_step_widens_a_quantised_expert_stack(bits, rows):
+    """The traced prefill of a quantised ``tiny-mixtral`` holds no
+    ``ragged_dot`` and multiplies no array of an expert leaf's shape by
+    its scales in the activations' width (int8: the scale lands on the
+    contraction's output)."""
+    from arks_tpu.models import quant
+    cfg = get_config("tiny-mixtral")
+    params = quant.quantize_params(
+        tf.init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16), bits=bits)
+    text = str(jax.make_jaxpr(lambda p, t, n: tf.prefill(p, cfg, t, n))(
+        params, jnp.zeros((1, rows), jnp.int32),
+        jnp.asarray([rows], jnp.int32)))
+    assert "ragged_dot" not in text
+    x, e, f = cfg.num_experts, cfg.hidden_size, cfg.moe_intermediate_size
+    assert f"i{bits}[{x},{e},{f}]" in text
+    if bits == 8:
+        for shape in (f"[{x},{e},{f}]", f"[{x},{f},{e}]"):
+            assert f"bf16{shape} = mul" not in text
+            assert f"bf16{shape} = convert_element_type" in text
+
+
+def test_plain_leaves_keep_ragged_dot():
+    """Unquantised leaves (training, float tests) still take the sorted
+    ``ragged_dot`` contractions, which are differentiable."""
     cfg = get_config("tiny-moe")
-    params = tf.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
-    mp = params["layers"]
-    mp1 = jax.tree.map(lambda a: a[0], mp)  # layer 0 slice
-    x = jax.random.normal(jax.random.PRNGKey(1), (2, 24, cfg.hidden_size),
-                          jnp.float32)
-
-    monkeypatch.setenv("ARKS_MOE_KERNEL", "xla")
-    ref = moe_ffn_grouped(x, mp1, cfg)
-    monkeypatch.setenv("ARKS_MOE_KERNEL", "pallas")
-    got = moe_ffn_grouped(x, mp1, cfg)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               atol=2e-4, rtol=2e-4)
-
-    qp = quantize_params(params)["layers"]
-    qp1 = jax.tree.map(lambda a: a[0], qp)
-    monkeypatch.setenv("ARKS_MOE_KERNEL", "xla")
-    ref_q = moe_ffn_grouped(x, qp1, cfg)
-    monkeypatch.setenv("ARKS_MOE_KERNEL", "pallas")
-    got_q = moe_ffn_grouped(x, qp1, cfg)
-    np.testing.assert_allclose(np.asarray(got_q), np.asarray(ref_q),
-                               atol=2e-3, rtol=2e-3)
-
-    # int4 (w4a16) experts: group-scale dequant fused in the kernel.
-    q4 = quantize_params(params, bits=4)["layers"]
-    q41 = jax.tree.map(lambda a: a[0], q4)
-    monkeypatch.setenv("ARKS_MOE_KERNEL", "xla")
-    ref_4 = moe_ffn_grouped(x, q41, cfg)
-    monkeypatch.setenv("ARKS_MOE_KERNEL", "pallas")
-    got_4 = moe_ffn_grouped(x, q41, cfg)
-    np.testing.assert_allclose(np.asarray(got_4), np.asarray(ref_4),
-                               atol=2e-3, rtol=2e-3)
+    lp = jax.tree.map(lambda t: t[0], moe.init_moe_params(
+        cfg, jax.random.PRNGKey(0), jnp.float32))
+    text = str(jax.make_jaxpr(lambda x: moe.moe_ffn(x, lp, cfg))(
+        _tokens(cfg, 64)))
+    assert "ragged_dot" in text

@@ -187,8 +187,9 @@ def test_int4_sharded_matches_unsharded():
 
 
 def test_int4_moe_forward():
-    """int4 expert weights take the ragged_dot path (the Pallas kernel's
-    fused dequant is int8-only) and stay close to full width."""
+    """int4 expert weights take the dispatch int8 ones take, their group
+    dequant the contraction's operand producer, and stay close to full
+    width."""
     cfg = get_config("tiny-moe")
     params = tf.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
     qparams = quant.quantize_params(params, bits=4)

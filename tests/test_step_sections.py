@@ -374,6 +374,83 @@ def test_compilation_counters_count_a_fresh_shape_once(monkeypatch):
     assert "xla_compile_seconds_total" in text
 
 
+def _churn(eng, cfg, tag, n=5):
+    """Prompts of 33 alike tokens, one after another: with no retention
+    surplus each evicts the pages the one before left in the index."""
+    for i in range(n):
+        req = Request(f"{tag}{i}", [(9 + i) % cfg.vocab_size] * 33,
+                      SamplingParams(max_tokens=3, temperature=0.0,
+                                     ignore_eos=True))
+        eng.add_request(req)
+        _drive(eng)
+        _collect(req)
+
+
+@pytest.mark.parametrize("warm", [True, False], ids=["warmed", "control"])
+def test_the_pools_first_eviction_compiles_nothing_after_warm_up(
+        monkeypatch, warm):
+    """With the host tier on, the spill gather compiles before the first
+    sequential step's dispatch, so the pool's first eviction (inside a
+    benchmark window, PERF.md PR 33) adds nothing to
+    ``xla_compilations_total``; the control, its warm-up taken out, counts
+    the one compilation the warm-up moves.  (The counter is the
+    process's: nothing else compiles between the two readings, depth 0
+    builds nothing off-thread.)"""
+    monkeypatch.setenv("ARKS_PREFIX_HOST_MB", "64")
+    if not warm:
+        monkeypatch.setattr(
+            InferenceEngine, "_warm_spill",
+            lambda self: setattr(self, "_spill_warm", True))
+    cfg, eng = _mk_engine(monkeypatch, prefix_cache_mb=0)
+    assert eng._host_tier_on() and not eng._spill_warm
+    _churn(eng, cfg, "first", n=1)          # every step program, warm-ups
+    assert eng._spill_warm
+    assert eng.metrics.prefix_spill_blocks_total.total() == 0
+    n0 = eng.metrics.xla_compilations_total.get()
+    _churn(eng, cfg, "churn")
+    eng._resolve_spills(force=True)
+    assert eng.metrics.prefix_spill_blocks_total.total() > 0
+    assert eng.metrics.xla_compilations_total.get() == n0 + (not warm)
+
+
+def test_a_gang_leader_warms_no_spill_gather_and_its_followers_replay(
+        monkeypatch):
+    """The host tier is single-host (``_host_tier_on``: off under a
+    dispatcher), so a leader makes no warm-up gather and broadcasts no op
+    for one: a follower fed its op stream replays every op it is sent and
+    lands on the leader's pool."""
+    import numpy as np
+
+    from arks_tpu.engine.multihost import DispatchFollower
+
+    class Recording:
+        def __init__(self):
+            self.ops = []
+
+        def broadcast(self, op, payload):
+            self.ops.append((op, payload))
+
+    monkeypatch.setenv("ARKS_PREFIX_HOST_MB", "64")
+    cfg, leader = _mk_engine(monkeypatch, prefix_cache_mb=0)
+    _, feng = _mk_engine(monkeypatch, prefix_cache_mb=0)
+    leader.dispatcher = Recording()
+    calls = []
+    gather = leader._spill_gather_fn
+    leader._spill_gather_fn = lambda *a: calls.append(1) or gather(*a)
+    _churn(leader, cfg, "l", n=2)
+    assert leader._spill_warm and not calls and not leader._host_tier_on()
+    names = {op for op, _ in leader.dispatcher.ops}
+    assert "mixed" in names and names <= {"mixed", "set_slots", "set_slot"}
+    follower = DispatchFollower.__new__(DispatchFollower)
+    follower.engine = feng
+    follower._jax = jax
+    follower._pipe_state = follower._pipe_cols = None
+    for op, payload in leader.dispatcher.ops:
+        follower._apply(feng, jax, jnp, op, payload)
+    np.testing.assert_array_equal(np.asarray(leader._cache.k),
+                                  np.asarray(feng._cache.k))
+
+
 def test_chunk_budget_counter_rises_only_while_a_prompt_can_use_it(
         monkeypatch):
     cfg, eng = _mk_engine(monkeypatch)
