@@ -753,6 +753,27 @@ class EngineMetrics:
             "Window-layer pages summed over mixed dispatches, by state "
             "(held: in use at the dispatch; unreleased: what the same "
             "sequences would hold had no page been released)")
+        # A model with linear-attention layers: a fixed state a slot beside
+        # the GQA layers' pages (models/transformer.py::LinearState).
+        self.linear_state_bytes = r.gauge(
+            "linear_state_bytes",
+            "Bytes of recurrent state (the delta rule's float32 state and "
+            "the convolution carry of every linear layer) held by slots "
+            "with a live sequence")
+        self.kv_page_bytes = r.gauge(
+            "kv_page_bytes",
+            "Bytes of the full-attention layers' pages held by slots or "
+            "the prefix index (a model with linear-attention layers: its "
+            "GQA layers')")
+        self.linear_state_starts_total = r.counter(
+            "linear_state_starts_total",
+            "Sequences that took a slot at position 0, so that the step "
+            "program read the slot's recurrent state as zeros")
+        self.kv_held_byte_steps_total = r.counter(
+            "kv_held_byte_steps_total",
+            "Bytes held for live sequences summed over dispatches, by kind "
+            "(state: linear_state_bytes; pages: kv_page_bytes), a model "
+            "with linear-attention layers")
         self.mixed_kv_bytes_ideal_total = r.counter(
             "mixed_kv_bytes_ideal_total",
             "KV bytes a perfect once-per-page schedule would stream for "
@@ -1332,9 +1353,11 @@ class InferenceEngine:
             self._latent_preflight(cfg, engine_cfg, draft_cfg)
         if cfg.windowed:
             self._windowed_preflight(cfg, engine_cfg, draft_cfg)
+        if cfg.linear:
+            self._linear_preflight(cfg, engine_cfg, draft_cfg)
         # The step returns two counts beside its token ids (held pairs,
         # valid rows): _count_held.
-        self._held_stat = bool((cfg.latent or cfg.windowed)
+        self._held_stat = bool((cfg.latent or cfg.windowed or cfg.linear)
                                and cfg.num_experts)
         # Per-model KV dtype preference: a checkpoint that ships
         # kv_cache_dtype in its ModelConfig wins over the engine's "auto"
@@ -1472,6 +1495,10 @@ class InferenceEngine:
         # The window layers' pages (engine/paged.py::WindowPages): a model
         # with window and full attention layers only.
         self._win = None
+        # Bytes of state a slot over the linear layers (LinearState), set by
+        # the slots and never by a context; 0: the model has none.  A taken
+        # slot is the whole reservation and the whole count.
+        self._lin_slot_bytes = 0
         # The pages of the full pool that admission may promise (0: the
         # pool holds every slot's whole context, and a free slot is
         # promise enough), what each slot was promised, and the request
@@ -1512,7 +1539,7 @@ class InferenceEngine:
             # model with window layers register any
             # (_register_prompt_pages).
             if (engine_cfg.prefix_cache_mb and self._chunk
-                    and not cfg.windowed):
+                    and not cfg.windowed and not cfg.linear):
                 extra = max(engine_cfg.prefix_cache_mb * 2**20 // page_bytes, 0)
                 # The byte budget is tuned for 7B-class pools; cap by
                 # proportion so tiny test models don't allocate huge pools.
@@ -1564,6 +1591,8 @@ class InferenceEngine:
             self._cache = self._init_paged_cache(num_pages, dtype)
             if mesh is not None:
                 self._cache = self._shard_paged(self._cache)
+            if cfg.linear:
+                self._lin_slot_bytes = self._cache.lin.slot_bytes
             self._alloc = PageAllocator(num_pages, page)
             self._tables = np.zeros((engine_cfg.num_slots, max_pages),
                                     np.int32)
@@ -1582,6 +1611,14 @@ class InferenceEngine:
                          "above holds %d layers", self._win.alloc.num_pages,
                          page, self._win.per_slot, cfg.sliding_window,
                          self._cache.win.token_bytes, cfg.num_window_layers,
+                         cfg.num_full_layers)
+            if self._lin_slot_bytes:
+                log.info("linear layers: %d bytes of %s state a slot over "
+                         "%d layers (%d slots, %.2f GB, whatever the "
+                         "context); the pool above holds the %d GQA layers",
+                         self._lin_slot_bytes, self._cache.lin.s.dtype,
+                         cfg.num_linear_layers, engine_cfg.num_slots,
+                         engine_cfg.num_slots * self._lin_slot_bytes / 1e9,
                          cfg.num_full_layers)
         else:
             self._max_pages = 0
@@ -1623,7 +1660,8 @@ class InferenceEngine:
                 f"ARKS_PREFIX_HOST_MB={host_mb}: must be >= 0")
         self._host_mb = host_mb if (self._paged and self._chunk
                                     and host_mb and not cfg.latent
-                                    and not cfg.windowed) else 0
+                                    and not cfg.windowed
+                                    and not cfg.linear) else 0
         if keep_tiers is not None:
             # Elastic rebuild: adopt the surviving tier-1 store (blocks
             # are full logical host arrays — mesh-shape-independent).
@@ -1797,10 +1835,10 @@ class InferenceEngine:
                 f"(resolved kv_layout={'paged' if self._paged else 'slot'}, "
                 f"prefill_chunk={self._chunk or None}, "
                 f"ARKS_MIXED_STEP={_mx})")
-        if (cfg.latent or cfg.windowed) and not self._mixed:
+        if (cfg.latent or cfg.windowed or cfg.linear) and not self._mixed:
             raise ValueError(
-                f"model {cfg.name!r} (latent attention, or window layers) "
-                "is served by the "
+                f"model {cfg.name!r} (latent attention, window layers or "
+                "linear-attention layers) is served by the "
                 "mixed scheduler only; the legacy scheduler speaks K and V "
                 f"(resolved kv_layout={'paged' if self._paged else 'slot'}, "
                 f"prefill_chunk={self._chunk or None}, "
@@ -1946,8 +1984,17 @@ class InferenceEngine:
             # token (latent attention), which is key and value at once.
             # "kv+window": two pools, the window layers' pages released
             # behind the window.
+            # "kv+state": the GQA layers' pages beside a fixed recurrent
+            # state a slot (linear-attention layers).
             "kv_page": ("latent" if cfg.latent else
-                        "kv+window" if cfg.windowed else "kv"),
+                        "kv+window" if cfg.windowed else
+                        "kv+state" if cfg.linear else "kv"),
+            # The dtype the delta rule's state IS kept in, read off the
+            # cache this engine built ("none": no linear layer): a
+            # deployment's expect_labels holds the step to the precision
+            # its configuration states.
+            "state_dtype": (str(self._cache.lin.s.dtype)
+                            if self._lin_slot_bytes else "none"),
             # This chip's share of each routed layer ("rank/size"; "0/1":
             # every expert is held here).
             "expert_share": f"{cfg.expert_parallel_rank}/"
@@ -3065,7 +3112,9 @@ class InferenceEngine:
         """The paged pool(s) as this engine's configuration shapes them: a
         model with window layers gets its window pool beside."""
         win = ({"win_pages": self._win.alloc.num_pages}
-               if self._win is not None else {})
+               if self._win is not None else
+               {"state_slots": self.ecfg.num_slots} if self.cfg.linear
+               else {})
         return tf.init_paged_cache(
             self.cfg, num_pages, self._page_size(), self._cache_dtype(dtype),
             quantized=self.ecfg.kv_quantized, pad_head=self._pad_head(),
@@ -3149,9 +3198,25 @@ class InferenceEngine:
                 # From the RESOLVED length: whatever is in flight reads at
                 # or past it (WindowPages).
                 self._win_cover(slot, int(self._lengths[slot]), rows)
+        if self._lin_slot_bytes:
+            self._count_state_bytes()
         # Any eviction the allocations caused must spill BEFORE the
         # caller's dispatch can write the recycled pages (stream order).
         self._spill_flush()
+
+    def _count_state_bytes(self) -> None:
+        """A model with linear-attention layers, once a dispatch: what the
+        live sequences hold of each kind, as gauges and summed over the
+        dispatches (docs/monitoring.md)."""
+        m = self.metrics
+        state = (self.ecfg.num_slots - len(self._free)) \
+            * self._lin_slot_bytes
+        pages = (self._alloc.num_pages - self._alloc.free_pages) \
+            * self._page_bytes
+        m.linear_state_bytes.set(state)
+        m.kv_page_bytes.set(pages)
+        m.kv_held_byte_steps_total.inc(state, kind="state")
+        m.kv_held_byte_steps_total.inc(pages, kind="pages")
 
     def _resolve_kv_layout(self) -> bool:
         layout = self.ecfg.kv_layout
@@ -4716,10 +4781,12 @@ class InferenceEngine:
             self.metrics.kv_window_pages_released_total.inc(gone)
 
     def _register_prompt_pages(self, ids, pages, digests=None) -> None:
-        if self._win is not None:
+        if self._win is not None or self._lin_slot_bytes:
             # No prefix is ever indexed, so none is ever matched: a hit
             # would start a prompt behind window pages that are gone
-            # (_windowed_preflight says so at construction).
+            # (_windowed_preflight says so at construction), or behind a
+            # prefix whose recurrent state at its end nobody kept
+            # (_linear_preflight).
             return
         from arks_tpu.engine.paged import chain_digests
         page = self._page_size()
@@ -5097,6 +5164,8 @@ class InferenceEngine:
             # keep a window of their own)
             *((self.cfg.num_full_layers, self.cfg.sliding_window)
               if self.cfg.windowed else ()),
+            *((self.cfg.num_full_layers, "linear")
+              if self.cfg.linear else ()),
             self.cfg.num_kv_heads, self._page_bytes,
             self.ecfg.kv_quantized, self.ecfg.kv_bits,
             self.ecfg.resolve_kv_cache_dtype()))
@@ -7448,6 +7517,8 @@ class InferenceEngine:
                 self._assign_slot_pages(slot, total, head_pages=shared)
                 if self._pool_budget:
                     self._pool_reserved[slot] = self._pool_need(req, ids)
+                if self._lin_slot_bytes:
+                    self.metrics.linear_state_starts_total.inc(1)
             except Exception as e:
                 self._alloc.decref(shared)
                 self._free.append(slot)
@@ -8600,6 +8671,40 @@ class InferenceEngine:
         if ecfg.prefix_cache_mb:
             log.info("model %s: the device prefix index is off (a matched "
                      "prefix's window pages would be gone)", cfg.name)
+
+    def _linear_preflight(self, cfg: ModelConfig, ecfg: "EngineConfig",
+                          draft_cfg) -> None:
+        """A model with linear-attention layers is served by the mixed
+        scheduler on one device: its GQA layers keep pages, its linear
+        layers a fixed state a slot (transformer.py::LinearState) that the
+        step program rewrites in place.  Everything else that moves KV
+        packs "every layer's page": a sequence's linear layers have no
+        page, and their state is not carried; asked for, each is refused
+        here, at construction, by name, and nothing falls back quietly.
+        The device prefix index is OFF for such a model, whatever
+        --prefix-cache-mb says: a hit would need the state AT the prefix's
+        end, which nobody kept (_register_prompt_pages).  Token replay
+        after a fault stays: it re-prefills from position 0, which
+        rebuilds the state."""
+        if ecfg.kv_layout == "auto":
+            ecfg.kv_layout = "paged"
+        why = []
+        if ecfg.kv_layout != "paged":
+            why.append(f"kv_layout={ecfg.kv_layout} (the slot layout keeps "
+                       "K and V of every layer)")
+        why += self._kv_mover_refusals(
+            ecfg, draft_cfg, "every layer's page and no recurrent state",
+            "linear-attention layers and their state have no sharding "
+            "rules")
+        if why:
+            raise ValueError(
+                f"model {cfg.name!r} (linear-attention layers with a fixed "
+                "state a slot beside GQA layers over pages) cannot be "
+                "served with: " + "; ".join(why))
+        if ecfg.prefix_cache_mb:
+            log.info("model %s: the device prefix index is off (a matched "
+                     "prefix's recurrent state at its end is not kept)",
+                     cfg.name)
 
     def _latent_preflight(self, cfg: ModelConfig, ecfg: "EngineConfig",
                           draft_cfg) -> None:

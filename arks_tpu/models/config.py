@@ -104,6 +104,28 @@ class ModelConfig:
     partial_rotary_factor: float = 1.0
     rope_hf_yarn: tuple[float, ...] = ()
     attn_gate: bool = False
+    # Linear-attention and softmax GQA layers in one model (``solar_open2``;
+    # linear_period 0 = none).  Layer 0 is a GQA layer; behind it the layers
+    # come in periods of ``linear_period`` linear layers and one GQA layer,
+    # and what is left over is a tail of linear layers (Solar-Open2-250B:
+    # 1 + 11 x (3 + 1) + 3 = 48).  A linear layer is Kimi Delta Attention
+    # (arXiv:2510.26692): ``linear_num_heads`` heads of ``linear_head_dim``
+    # for queries, keys AND values, each behind a causal depthwise
+    # convolution over the last ``linear_conv`` positions; a decay a channel
+    # and a step size a head (``linear_neg_eigval``: in (0, 2)) drive the
+    # gated delta rule on a float32 state ``[d, d]`` a head a sequence, which
+    # is all the layer keeps of the sequence; the decay and the output gate
+    # come through low-rank pairs of rank ``linear_head_dim``.  The GQA
+    # layers keep pages as ever; ``use_rope`` False: no rotation (NoPE);
+    # ``attn_out_gate``: ``sigmoid(x Wg)`` elementwise over the H x D
+    # attention outputs before the output projection.
+    linear_period: int = 0
+    linear_num_heads: int = 0
+    linear_head_dim: int = 0
+    linear_conv: int = 4
+    linear_neg_eigval: bool = False
+    use_rope: bool = True
+    attn_out_gate: bool = False
 
     @property
     def q_dim(self) -> int:
@@ -114,40 +136,76 @@ class ModelConfig:
         return self.sliding_window > 0
 
     @property
+    def linear(self) -> bool:
+        return self.linear_period > 0
+
+    @property
+    def linear_dim(self) -> int:
+        """Width of a linear layer's queries (keys, values) over its heads."""
+        return self.linear_num_heads * self.linear_head_dim
+
+    @property
+    def head_layers(self) -> int:
+        """Full-attention layers ahead of the first period: the dense
+        prefix, or layer 0 of a model with linear layers."""
+        return 1 if self.linear else self.first_k_dense
+
+    @property
+    def inner_period(self) -> int:
+        """Window (linear) layers a period."""
+        return self.linear_period or (self.window_period if self.windowed
+                                      else 0)
+
+    @property
     def num_periods(self) -> int:
-        """Periods of window layers and one full layer behind the prefix."""
-        if not self.windowed:
+        """Periods of window (linear) layers and one full layer behind the
+        head."""
+        if not self.inner_period:
             return 0
-        return (self.num_layers - self.first_k_dense) // (
-            self.window_period + 1)
+        return (self.num_layers - self.head_layers) // (
+            self.inner_period + 1)
+
+    @property
+    def inner_tail(self) -> int:
+        """Window (linear) layers behind the last whole period."""
+        if not self.inner_period:
+            return 0
+        return (self.num_layers - self.head_layers) % (
+            self.inner_period + 1)
 
     @property
     def window_tail(self) -> int:
-        """Window layers behind the last whole period."""
-        if not self.windowed:
-            return 0
-        return (self.num_layers - self.first_k_dense) % (
-            self.window_period + 1)
+        return self.inner_tail if self.windowed else 0
 
     @property
     def num_window_layers(self) -> int:
         return self.num_periods * self.window_period + self.window_tail
 
     @property
+    def num_linear_layers(self) -> int:
+        """Layers that keep a fixed state a sequence and no page."""
+        if not self.linear:
+            return 0
+        return self.num_periods * self.linear_period + self.inner_tail
+
+    @property
     def num_full_layers(self) -> int:
         """Layers that keep every page of a sequence."""
-        return self.num_layers - self.num_window_layers
+        return self.num_layers - self.num_window_layers \
+            - self.num_linear_layers
 
     def heads_of(self, window: bool) -> int:
         return self.window_num_heads if window else self.num_heads
 
     def layer_kinds(self) -> tuple[str, ...]:
-        """``"full"`` / ``"window"`` of every layer, in model order."""
-        if not self.windowed:
+        """``"full"`` / ``"window"`` / ``"linear"`` of every layer, in model
+        order."""
+        if not self.inner_period:
             return ("full",) * self.num_layers
-        return ("full",) * self.first_k_dense + (
-            ("window",) * self.window_period + ("full",)) * self.num_periods \
-            + ("window",) * self.window_tail
+        inner = "linear" if self.linear else "window"
+        return ("full",) * self.head_layers + (
+            (inner,) * self.inner_period + ("full",)) * self.num_periods \
+            + (inner,) * self.inner_tail
 
     @property
     def latent(self) -> bool:
@@ -235,6 +293,18 @@ class ModelConfig:
         if self.attn_gate:
             blocks += e * (self.num_full_layers * self.num_heads
                            + self.num_window_layers * self.window_num_heads)
+        if self.attn_out_gate:
+            blocks += self.num_full_layers * e * self.q_dim
+        if self.linear:
+            # A linear layer in place of a GQA layer's attention: q, k, v, o
+            # at its own width, the two low-rank pairs, the step size, the
+            # convolutions, the decay's two vectors and the output norm.
+            ld, r = self.linear_dim, self.linear_head_dim
+            lin = (4 * e * ld + 2 * (e * r + r * ld)
+                   + e * self.linear_num_heads
+                   + 3 * self.linear_conv * ld + self.linear_num_heads + ld
+                   + r)
+            blocks += self.num_linear_layers * (lin - attn)
         head = 0 if self.tie_word_embeddings else e * v
         return v * e + blocks + e + head
 
@@ -262,6 +332,26 @@ class ModelConfig:
         # num_experts (Qwen2-MoE).
         num_experts = int(d.get("num_local_experts", d.get("num_experts", 0)) or 0)
         is_mixtral = "mixtral" in arch or model_type == "mixtral"
+        if model_type == "solar_open2":
+            return _from_solar_open2(d, name or model_type, tuple(eos))
+        # What only the ``solar_open2`` reader understands: on any other
+        # path each would be dropped, and the model served as another.
+        for k in sorted(d):
+            if d[k] not in (None, False) and (
+                    k in ("linear_attn_config", "gqa_layers", "gqa_interval",
+                          "use_gqa_gate") or k.startswith("kda_")):
+                raise ValueError(
+                    f"{k} in a config of model_type {model_type!r}: only "
+                    "model_type 'solar_open2' is read with linear-attention "
+                    "layers beside gated GQA layers; serving this model "
+                    "with softmax attention in every layer would be "
+                    "another model")
+        if d.get("use_rope") is False:
+            raise ValueError(
+                f"use_rope=false in a config of model_type {model_type!r}: "
+                "only model_type 'solar_open2' is read without rotary "
+                "positions; serving this model with RoPE would be another "
+                "model")
         if d.get("kv_lora_rank") or d.get("n_routed_experts"):
             return _from_deepseek_v3(d, name or model_type or "hf-model",
                                      tuple(eos))
@@ -520,6 +610,90 @@ def _from_laguna(d: dict[str, Any], name: str,
     )
 
 
+def _from_solar_open2(d: dict[str, Any], name: str,
+                      eos: tuple[int, ...]) -> ModelConfig:
+    """The ``solar_open2`` block (upstage): gated delta-rule linear-attention
+    layers (``linear_attn_config``, the ``kda_*`` keys: Kimi Delta
+    Attention) beside softmax GQA layers without RoPE and with an output
+    gate at ``gqa_layers``, every layer's FFN sigmoid-routed experts beside
+    one ungated shared expert.  Key for key from the published file; what
+    the block cannot express is refused, not approximated."""
+    def refuse(what: str) -> None:
+        raise ValueError(f"config {name!r}: {what} is not supported (the "
+                         "model would be served as another model)")
+
+    layers = int(d["num_hidden_layers"])
+    lin = d.get("linear_attn_config") or {}
+    for k in ("num_heads", "head_dim", "short_conv_kernel_size"):
+        if not lin.get(k):
+            refuse(f"a solar_open2 config without linear_attn_config.{k}")
+    if lin.get("num_kv_heads") not in (None, lin["num_heads"]):
+        refuse(f"linear_attn_config.num_kv_heads={lin['num_kv_heads']} "
+               "(keys and values of fewer heads than queries)")
+    if d.get("kda_use_full_proj"):
+        refuse("kda_use_full_proj (full-rank decay and gate projections)")
+    if d.get("use_rope", False):
+        refuse("use_rope (rotary positions in the GQA layers)")
+    if float(d.get("partial_rotary_factor", 1) or 1) != 1:
+        refuse(f"partial_rotary_factor={d['partial_rotary_factor']}")
+    if int(d.get("first_k_dense_replace", 0) or 0):
+        refuse(f"first_k_dense_replace={d['first_k_dense_replace']} (a "
+               "dense prefix)")
+    if not d.get("n_routed_experts"):
+        refuse("a solar_open2 config without n_routed_experts")
+    if d.get("scoring_func", "sigmoid") != "sigmoid":
+        refuse(f"scoring_func={d['scoring_func']!r} (only sigmoid)")
+    if int(d.get("n_group", 1) or 1) > 1 or int(d.get("topk_group", 1)
+                                                 or 1) > 1:
+        refuse(f"group-limited routing (n_group={d.get('n_group')}, "
+               f"topk_group={d.get('topk_group')})")
+    if d.get("attention_bias"):
+        refuse("attention_bias")
+    if d.get("rope_scaling"):
+        refuse(f"rope_scaling={d['rope_scaling']!r} without RoPE")
+    # GQA at layer 0, then every (gqa_interval + 1)-th; the rest linear.
+    per = int(d.get("gqa_interval") or 0)
+    gqa = [int(l) for l in d.get("gqa_layers") or ()]
+    if per < 1 or gqa != list(range(0, layers, per + 1)):
+        refuse(f"gqa_layers {gqa} with gqa_interval {per} over {layers} "
+               "layers (a GQA layer, then periods of gqa_interval linear "
+               "layers and one GQA layer, then linear layers)")
+    heads = d["num_attention_heads"]
+    kv = d.get("num_key_value_heads", heads)
+    if heads % kv:
+        refuse(f"{heads} query heads over {kv} KV heads")
+    return ModelConfig(
+        name=name,
+        vocab_size=d["vocab_size"],
+        hidden_size=d["hidden_size"],
+        intermediate_size=d["intermediate_size"],
+        num_layers=layers,
+        num_heads=heads,
+        num_kv_heads=kv,
+        head_dim=d.get("head_dim", d["hidden_size"] // heads),
+        rope_theta=float(d.get("rope_theta", 10000.0)),
+        rms_norm_eps=float(d.get("rms_norm_eps", 1e-6)),
+        tie_word_embeddings=bool(d.get("tie_word_embeddings", False)),
+        max_position_embeddings=int(d.get("max_position_embeddings", 32768)),
+        eos_token_ids=eos,
+        num_experts=int(d["n_routed_experts"]),
+        num_experts_per_tok=int(d["num_experts_per_tok"]),
+        moe_intermediate_size=int(d["moe_intermediate_size"]),
+        norm_topk_prob=bool(d.get("norm_topk_prob", True)),
+        scoring_func="sigmoid",
+        routed_scaling_factor=float(d.get("routed_scaling_factor", 1.0)),
+        n_shared_experts=int(d.get("n_shared_experts", 0) or 0),
+        linear_period=per,
+        linear_num_heads=int(lin["num_heads"]),
+        linear_head_dim=int(lin["head_dim"]),
+        linear_conv=int(lin["short_conv_kernel_size"]),
+        linear_neg_eigval=bool(d.get("kda_allow_neg_eigval", False)),
+        use_rope=False,
+        attn_out_gate=bool(d.get("use_gqa_gate", False)),
+        kv_cache_dtype=str(d.get("kv_cache_dtype", "auto")),
+    )
+
+
 _REGISTRY: dict[str, ModelConfig] = {}
 
 
@@ -618,6 +792,22 @@ register_config(ModelConfig(
     partial_rotary_factor=0.5,
     rope_hf_yarn=(4.0, 32.0, 32.0, 1.0, 1.1386294361119891),
     attn_gate=True,
+))
+
+# Linear-attention and gated NoPE GQA layers in one model (the
+# ``solar_open2`` block) at CPU-test size: a GQA layer (4 heads of 16 over 2
+# KV heads, an elementwise output gate), then 2 periods of 2 delta-rule
+# linear layers (4 heads of 16, convolution of 4, negative eigenvalues) and
+# a GQA layer, then a tail of 2 linear layers; every layer 16 sigmoid-routed
+# experts top-4 beside one shared expert.
+register_config(ModelConfig(
+    name="tiny-linear-moe", vocab_size=512, hidden_size=64,
+    intermediate_size=128, num_layers=9, num_heads=4, num_kv_heads=2,
+    head_dim=16, rms_norm_eps=1e-5, eos_token_ids=(0,),
+    num_experts=16, num_experts_per_tok=4, moe_intermediate_size=32,
+    norm_topk_prob=True, scoring_func="sigmoid", n_shared_experts=1,
+    linear_period=2, linear_num_heads=4, linear_head_dim=16, linear_conv=4,
+    linear_neg_eigval=True, use_rope=False, attn_out_gate=True,
 ))
 
 # MoE families (HF: mistralai/Mixtral-8x7B-Instruct-v0.1, Qwen/Qwen2-57B-A14B).

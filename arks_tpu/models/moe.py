@@ -341,12 +341,14 @@ def _ragged_dispatch(x2: jnp.ndarray, vals: jnp.ndarray, idx: jnp.ndarray,
 
 def _batch_pays(n_tokens: int, mp: Params, cfg) -> bool:
     """Whether the grouped path does less than the dense dispatch on
-    ``n_tokens`` rows.  The quantised experts of a layer held whole go
+    ``n_tokens`` rows.  Quantised experts, and any share of a layer, go
     through the batched dispatch, which is the dense one with gathers
     around it once an expert's batch is every token (64 rows of 8 experts
-    top-2: the dense dispatch runs at the HBM rate there)."""
+    top-2 held whole, or of 40 held of 320 top-8: the dense dispatch runs
+    at the HBM rate there, and the batched form of such a step copied every
+    expert leaf out of its stack first, PERF.md §6, PR 36)."""
     from arks_tpu.models.quant import is_quantized
-    if cfg.expert_parallel_size > 1 or not is_quantized(mp["w_gate"]):
+    if cfg.expert_parallel_size == 1 and not is_quantized(mp["w_gate"]):
         return True
     return _held_capacity(n_tokens, cfg) < n_tokens
 

@@ -58,6 +58,9 @@ MATMUL_KEYS = frozenset({
     # The latent block (transformer.py): query down / up, the latent's
     # down-projection, and [W_uk | W_uv], which the step absorbs.
     "wq_a", "wq_b", "wkv_a", "wkv_b",
+    # The ``solar_open2`` block: a GQA layer's elementwise output gate, a
+    # linear layer's low-rank decay and gate pairs.
+    "wg", "w_f1", "w_f2", "w_g1", "w_g2",
 })
 # Router logits feed a softmax over experts — tiny and precision-sensitive,
 # so it stays full width, as do norms, biases and the scalar shared gate.
@@ -66,9 +69,13 @@ SKIP_KEYS = frozenset({
     "shared_gate", "q_norm", "kv_norm", "router_bias",
     # The per-head output gate [E, H]: a sigmoid's input, tiny.
     "attn_gate",
+    # A linear layer's small leaves: the convolutions' taps [K, H x d], the
+    # decay's bias and per-head rate, the step size [E, H], the output's
+    # per-head norm.
+    "conv_q", "conv_k", "conv_v", "dt_bias", "a_log", "w_b", "o_norm",
 })
 NORM_KEYS = frozenset({"attn_norm", "mlp_norm", "final_norm", "q_norm",
-                       "kv_norm"})
+                       "kv_norm", "o_norm"})
 
 
 def weight_bits(weight_dtype: str) -> int:
@@ -252,6 +259,8 @@ def init_params_quantized(cfg, key, dtype=jnp.bfloat16, bits: int = 8,
             if bits == 4 and axis == -2:  # matmul weights; embed stays int8
                 return quantize_tensor_int4(w.astype(dtype), shards=shards)
             return quantize_tensor(w.astype(dtype), axis=axis)
+        if kind == "dt_bias":
+            return tf.shift_dt_bias(w.astype(dtype))
         return w.astype(dtype)
 
     counter = [0]
@@ -272,6 +281,8 @@ def init_params_quantized(cfg, key, dtype=jnp.bfloat16, bits: int = 8,
                 kind, axis = "quant", -1
             elif name in MATMUL_KEYS:
                 kind, axis = "quant", -2
+            elif name == "dt_bias":
+                kind, axis = "dt_bias", 0
             else:
                 kind, axis = "full", 0
             out[name] = gen(sub, tuple(leaf.shape), kind, axis)

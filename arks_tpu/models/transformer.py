@@ -71,6 +71,28 @@ class KVCache(NamedTuple):
         return self.k.shape[3]
 
 
+class LinearState(NamedTuple):
+    """What the linear-attention layers of a model keep of a sequence
+    (``cfg.linear``): a fixed number of bytes a slot, whatever the context.
+
+    ``s`` ``[Ll, num_slots, H, d, d]`` float32: the delta rule's state a
+    head (keys down, values across), layer ``l`` of the model's linear
+    layers in model order.  ``conv`` ``[Ll, num_slots, K - 1, 3 x H x d]``:
+    the last ``K - 1`` rows of the q | k | v projections ahead of the short
+    convolution, oldest first.  A slot's rows are whatever its last
+    sequence left there; a sequence that starts at position 0 reads them as
+    zeros (:func:`_linear_qkv`, :func:`_linear_state`), so nothing zeroes a
+    slot between two steps."""
+
+    s: jnp.ndarray
+    conv: jnp.ndarray
+
+    @property
+    def slot_bytes(self) -> int:
+        """Bytes one slot holds, all linear layers."""
+        return sum(x.size * x.dtype.itemsize for x in self) // self.s.shape[1]
+
+
 class PagedKVCache(NamedTuple):
     """Paged decode cache: pool [num_layers, num_pages, Hkv, page, head_dim].
 
@@ -96,6 +118,10 @@ class PagedKVCache(NamedTuple):
     a ``PagedKVCache`` of the same page size over ``cfg.num_window_layers``
     layers, addressed through block tables of its own whose entries
     behind a slot's window the engine has released.
+
+    A model with LINEAR-attention layers (``cfg.linear``) keeps pages for
+    its GQA layers only (``L`` = ``cfg.num_full_layers``); ``lin`` is what
+    its linear layers keep, a :class:`LinearState` indexed by slot.
     """
 
     k: jnp.ndarray
@@ -103,6 +129,7 @@ class PagedKVCache(NamedTuple):
     k_scale: jnp.ndarray | None = None
     v_scale: jnp.ndarray | None = None
     win: "PagedKVCache | None" = None
+    lin: LinearState | None = None
 
     @property
     def quantized(self) -> bool:
@@ -240,12 +267,90 @@ def _init_windowed_params(cfg: ModelConfig, key: jax.Array,
     return params
 
 
+# A seeded ``dt_bias`` is normal * 0.02 plus this (a choice of the seeded
+# weights, not of the mathematics): softplus(0) = 0.69 would halve the state
+# a step, where a trained model of the family starts its step sizes in
+# [0.001, 0.1] and forgets over hundreds of tokens.  softplus(-4) = 0.018: a
+# decay of 0.98 a step.  ``quant.init_params_quantized`` and the reference
+# family (benchmarks/references/linear_moe.py) apply the same shift.
+LINEAR_DT_BIAS_SHIFT = -4.0
+
+
+def shift_dt_bias(w: jnp.ndarray) -> jnp.ndarray:
+    return (w.astype(jnp.float32) + LINEAR_DT_BIAS_SHIFT).astype(w.dtype)
+
+
+def _init_linear_params(cfg: ModelConfig, key: jax.Array,
+                        dtype: jnp.dtype) -> Params:
+    """The ``solar_open2`` tree, a stacked tree a kind of layer, every layer
+    routed: ``head_layers`` (layer 0, a GQA layer), ``layers`` (one GQA
+    layer a period) and ``lin_layers`` (``linear_period`` linear layers a
+    period and the tail, in model order).  A GQA layer: ``wq`` / ``wk`` /
+    ``wv`` / ``wo`` and the elementwise output gate ``wg`` [E, H x D].  A
+    linear layer, H heads of d: ``wq`` / ``wk`` / ``wv`` [E, H x d],
+    ``conv_q`` / ``conv_k`` / ``conv_v`` [K, H x d] (oldest tap first),
+    the decay ``w_f1`` [E, d] ``w_f2`` [d, H x d] ``dt_bias`` [H x d]
+    ``a_log`` [H], the step size ``w_b`` [E, H], the output's per-head
+    norm ``o_norm`` [d] and gate ``w_g1`` [E, d] ``w_g2`` [d, H x d],
+    ``wo`` [H x d, E]."""
+    e, v = cfg.hidden_size, cfg.vocab_size
+    keys = iter(jax.random.split(key, 48))
+
+    def w(shape, scale=0.02):
+        return (jax.random.normal(next(keys), shape, jnp.float32)
+                * scale).astype(dtype)
+
+    def gqa(l: int) -> Params:
+        out = {
+            "attn_norm": jnp.ones((l, e), dtype),
+            "wq": w((l, e, cfg.q_dim)),
+            "wk": w((l, e, cfg.kv_dim)),
+            "wv": w((l, e, cfg.kv_dim)),
+            "wo": w((l, cfg.q_dim, e)),
+            "mlp_norm": jnp.ones((l, e), dtype),
+        }
+        if cfg.attn_out_gate:
+            out["wg"] = w((l, e, cfg.q_dim))
+        return out
+
+    def lin(l: int) -> Params:
+        ld, r, k = cfg.linear_dim, cfg.linear_head_dim, cfg.linear_conv
+        return {
+            "attn_norm": jnp.ones((l, e), dtype),
+            "wq": w((l, e, ld)), "wk": w((l, e, ld)), "wv": w((l, e, ld)),
+            "conv_q": w((l, k, ld)), "conv_k": w((l, k, ld)),
+            "conv_v": w((l, k, ld)),
+            "w_f1": w((l, e, r)), "w_f2": w((l, r, ld)),
+            "dt_bias": shift_dt_bias(w((l, ld))),
+            "a_log": w((l, cfg.linear_num_heads)),
+            "w_b": w((l, e, cfg.linear_num_heads)),
+            "o_norm": jnp.ones((l, r), dtype),
+            "w_g1": w((l, e, r)), "w_g2": w((l, r, ld)),
+            "wo": w((l, ld, e)),
+            "mlp_norm": jnp.ones((l, e), dtype),
+        }
+
+    from arks_tpu.models import moe
+    params: Params = {"embed": w((v, e)),
+                      "final_norm": jnp.ones((e,), dtype)}
+    for name, l, attn in (("head_layers", 1, gqa),
+                          ("layers", cfg.num_periods, gqa),
+                          ("lin_layers", cfg.num_linear_layers, lin)):
+        params[name] = dict(attn(l), **moe.init_moe_params(
+            cfg, next(keys), dtype, layers=l))
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = w((e, v))
+    return params
+
+
 def init_params(cfg: ModelConfig, key: jax.Array, dtype: jnp.dtype | None = None) -> Params:
     dtype = dtype or jnp.dtype(cfg.dtype)
     if cfg.latent:
         return _init_latent_params(cfg, key, dtype)
     if cfg.windowed:
         return _init_windowed_params(cfg, key, dtype)
+    if cfg.linear:
+        return _init_linear_params(cfg, key, dtype)
     if cfg.first_k_dense:
         raise NotImplementedError(
             f"model {cfg.name!r}: a dense prefix (first_k_dense="
@@ -303,6 +408,11 @@ def param_pspecs(cfg: ModelConfig, tp: int = 1) -> Params:
             f"model {cfg.name!r}: layers of two head counts have no "
             "sharding rules (tensor / data / pipeline parallelism are not "
             "supported)")
+    if cfg.linear:
+        raise NotImplementedError(
+            f"model {cfg.name!r}: linear-attention layers and their state "
+            "have no sharding rules (tensor / data / pipeline parallelism "
+            "are not supported)")
     kv = P(None, None, AXIS_MODEL) if shard_kv_heads(cfg, tp) else P(None, None, None)
     kvb = P(None, AXIS_MODEL) if shard_kv_heads(cfg, tp) else P(None, None)
     layers: Params = {
@@ -401,10 +511,28 @@ def init_paged_cache(cfg: ModelConfig, num_pages: int, page: int,
                      dtype: jnp.dtype | None = None,
                      quantized: bool = False,
                      pad_head: bool = False,
-                     kv_bits: int = 8, win_pages: int = 0) -> PagedKVCache:
+                     kv_bits: int = 8, win_pages: int = 0,
+                     state_slots: int = 0) -> PagedKVCache:
     """``win_pages`` (a model with window layers): the pages of the window
-    layers' pool; ``num_pages`` are then the full-attention layers'."""
+    layers' pool; ``num_pages`` are then the full-attention layers'.
+    ``state_slots`` (a model with linear layers): the slots its
+    :class:`LinearState` holds; the pool is its GQA layers'."""
     dtype = dtype or jnp.dtype(cfg.dtype)
+    if cfg.linear:
+        if state_slots < 1:
+            raise ValueError(f"model {cfg.name!r}: linear layers keep a "
+                             "state a slot (state_slots)")
+        import dataclasses
+        pool = init_paged_cache(
+            dataclasses.replace(cfg, linear_period=0,
+                                num_layers=cfg.num_full_layers),
+            num_pages, page, dtype, quantized, pad_head, kv_bits)
+        ll, h, d = (cfg.num_linear_layers, cfg.linear_num_heads,
+                    cfg.linear_head_dim)
+        return pool._replace(lin=LinearState(
+            s=jnp.zeros((ll, state_slots, h, d, d), jnp.float32),
+            conv=jnp.zeros((ll, state_slots, cfg.linear_conv - 1,
+                            3 * cfg.linear_dim), dtype)))
     if cfg.windowed:
         if win_pages < 1:
             raise ValueError(f"model {cfg.name!r}: window layers keep a "
@@ -697,7 +825,9 @@ def _kind_qkv(h: jnp.ndarray, lp: Params, cfg: ModelConfig,
     head at ``window_rope_theta``; full: the first
     ``partial_rotary_factor`` of a head under ``rope_hf_yarn``).  Also
     returns the per-head gate ``sigmoid(x Wg)`` [B, T, H] from the same
-    normed input (None where the model has none)."""
+    normed input, or (``cfg.attn_out_gate``) the elementwise one [B, T, H,
+    D] (None where the model has none).  ``cfg.use_rope`` False: no
+    rotation of either kind."""
     b, t = h.shape[:2]
     heads = cfg.heads_of(window)
     with _scope("arks.attn_win_qkv" if window else "arks.attn_qkv"):
@@ -706,7 +836,10 @@ def _kind_qkv(h: jnp.ndarray, lp: Params, cfg: ModelConfig,
         q = q.reshape(b, t, heads, cfg.head_dim)
         k = k.reshape(b, t, cfg.num_kv_heads, cfg.head_dim)
         v = v.reshape(b, t, cfg.num_kv_heads, cfg.head_dim)
-        if window:
+        if not cfg.use_rope:
+            def rope(t):
+                return t
+        elif window:
             rope = functools.partial(apply_rope, positions=positions,
                                      theta=cfg.window_rope_theta)
         else:
@@ -723,30 +856,248 @@ def _kind_qkv(h: jnp.ndarray, lp: Params, cfg: ModelConfig,
         with _scope("arks.attn_gate"):
             gate = jax.nn.sigmoid(jnp.einsum(
                 "...e,eh->...h", x, lp["attn_gate"]).astype(jnp.float32))
+    elif cfg.attn_out_gate:
+        with _scope("arks.attn_gate"):
+            gate = jax.nn.sigmoid(qeinsum(
+                "...e,eq->...q", x, lp["wg"]).astype(jnp.float32)).reshape(
+                    b, t, heads, cfg.head_dim)
     return q, k, v, gate
 
 
-def _mixed_step_windowed(params, cfg, cache, tables, win_tables, tokens,
-                         token_slot, token_pos, sample_src, seq_q_start,
-                         seq_q_len, seq_pos_start, mesh):
-    """:func:`mixed_step` for a model with window and full layers
-    (``cfg.windowed``): the dense prefix's stack, then a scan over the
-    periods, each the period's window layers (an inner scan that takes
-    them out of ``win_layers`` by index) and its full layer, then the
-    tail of window layers behind the last whole period.  Full layers write and
-    read the full pool through ``tables``; window layers the window pool
-    (``cache.win``) through ``win_tables``, the same ragged launch told
-    the window.  Returns (logits, cache, held_pairs)."""
+# Rows a block of the linear layers' chunked scan (the delta rule in its
+# chunk form: one triangular solve and a few matrix products a block a
+# head).  Within a block the decays enter as exp(G) on one side of a
+# product and exp(-G) on the other, G the log decay summed from the block's
+# start, so a block whose summed log decay passes float32's range (-87: a
+# mean decay under 0.26 a step over 64 rows) is out of this form's reach.
+LINEAR_CHUNK = 64
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _lane_rows(x: jnp.ndarray, start: jnp.ndarray, offsets: jnp.ndarray):
+    """Rows ``start[b] + offsets[b, j]`` of the flat ``x [T, ..]``: ``[B, J,
+    ..]`` (indices clipped; the caller masks what lies outside a lane)."""
+    idx = jnp.clip(start[:, None] + offsets, 0, x.shape[0] - 1)
+    return jnp.take(x, idx, axis=0)
+
+
+@_scope("arks.linear_qkv")
+def _linear_qkv(x: jnp.ndarray, lp: Params, cfg: ModelConfig,
+                conv: jnp.ndarray, seq_q_start: jnp.ndarray,
+                seq_q_len: jnp.ndarray, fresh: jnp.ndarray):
+    """A linear layer's per-token half on the normed flat batch ``x [T,
+    E]``: the q | k | v projections, the causal depthwise convolution over
+    the last K positions OF THE ROW'S SEQUENCE (a lane's rows are contiguous
+    from ``seq_q_start``; its first K - 1 rows reach into the slot's carry
+    ``conv [B, K - 1, 3 H d]``, read as zeros where ``fresh``), SiLU, the
+    L2 norm of q and k a head and q's scale; the log decay a channel and
+    the step size a head.  Returns (q, k, v [T, H, d], g [T, H, d] f32 <= 0,
+    beta [T, H] f32, the slots' new carry)."""
+    t = x.shape[0]
+    h, d, kk = cfg.linear_num_heads, cfg.linear_head_dim, cfg.linear_conv
+    pre = jnp.concatenate([qeinsum("te,eq->tq", x, lp[n])
+                           for n in ("wq", "wk", "wv")], axis=-1)  # [T, 3Hd]
+    w = jnp.concatenate([lp["conv_q"], lp["conv_k"], lp["conv_v"]],
+                        axis=-1).astype(jnp.float32)               # [K, 3Hd]
+    # Every row against the K - 1 flat rows before it ...
+    y = sum(jnp.roll(pre, i, axis=0).astype(jnp.float32) * w[kk - 1 - i]
+            for i in range(kk))
+    # ... and a lane's first K - 1 rows again, against its carry.
+    carry = jnp.where(fresh[:, None, None], 0, conv)               # [B, K-1, C]
+    first = jnp.arange(kk - 1, dtype=jnp.int32)
+    head = _lane_rows(pre, seq_q_start, jnp.broadcast_to(
+        first, (conv.shape[0], kk - 1)))
+    in_lane = first[None, :] < seq_q_len[:, None]                  # [B, K-1]
+    line = jnp.concatenate(
+        [carry, jnp.where(in_lane[..., None], head, 0)], axis=1)   # [B, 2K-2, C]
+    fix = sum(line[:, i: i + kk - 1].astype(jnp.float32) * w[i]
+              for i in range(kk))                                  # [B, K-1, C]
+    y = y.at[jnp.where(in_lane, seq_q_start[:, None] + first, t)].set(
+        fix, mode="drop")
+    # The carry a lane leaves: the last K - 1 rows of (carry, its rows).
+    back = seq_q_len[:, None] - (kk - 1) + first                   # [B, K-1]
+    tail = _lane_rows(pre, seq_q_start, back)
+    old = jnp.take_along_axis(
+        carry, jnp.clip(back + kk - 1, 0, kk - 2)[..., None], axis=1)
+    new_conv = jnp.where((seq_q_len > 0)[:, None, None],
+                         jnp.where((back >= 0)[..., None], tail, old), conv)
+    y = jax.nn.silu(y).reshape(t, 3, h, d)
+
+    def unit(a):
+        return a * jax.lax.rsqrt(jnp.sum(a * a, -1, keepdims=True) + 1e-6)
+
+    q = unit(y[:, 0]) * d ** -0.5
+    k, v = unit(y[:, 1]), y[:, 2]
+    f = qeinsum("tr,rq->tq", qeinsum("te,er->tr", x, lp["w_f1"]), lp["w_f2"])
+    # (The bias in float32: near -4 a bfloat16 sum would round the rate
+    # itself by a few percent, and the decay compounds over the context.)
+    g = -jnp.exp(lp["a_log"].astype(jnp.float32))[None, :, None] \
+        * jax.nn.softplus(f.astype(jnp.float32)
+                          + lp["dt_bias"].astype(jnp.float32)
+                          ).reshape(t, h, d)
+    beta = jax.nn.sigmoid(jnp.einsum("te,eh->th", x, lp["w_b"]
+                                     ).astype(jnp.float32))
+    if cfg.linear_neg_eigval:
+        beta = 2.0 * beta
+    return q, k, v, g, beta, new_conv.astype(conv.dtype)
+
+
+def _delta_chunk(q, k, v, g, beta, s0):
+    """One block of the gated delta rule in chunk form, a head at a time in
+    parallel: rows ``q, k, v [H, C, d]``, log decay ``g [H, C, d]``, step
+    size ``beta [H, C]`` (a row that carries no token: k = 0, g = 0, beta
+    = 0), state ``s0 [H, d, d]`` before the block.  Returns (o [H, C, d],
+    the state after the block).  With G the log decay summed from the
+    block's start, K+ = k exp(G), K- = k exp(-G), Q+ = q exp(G): the rows'
+    corrections solve ``(I + diag(beta) tril(K+ K-^T, -1)) U = diag(beta)
+    (V - K+ S0)``; ``O = Q+ S0 + tril(Q+ K-^T) U``; ``S = exp(G_C) S0 + (k
+    exp(G_C - G))^T U``.  Float32, every product at the highest precision:
+    the state is kept in float32 and a bfloat16 pass over it would undo
+    that."""
+    c = q.shape[1]
+    gs = jnp.cumsum(g, axis=1)
+    kp, km, qp = k * jnp.exp(gs), k * jnp.exp(-gs), q * jnp.exp(gs)
+
+    def mm(eq, a, b):
+        return jnp.einsum(eq, a, b, precision=_HIGHEST)
+
+    row = jnp.arange(c)
+    below = (row[:, None] > row[None, :])
+    a = jnp.where(below, mm("hid,hjd->hij", kp, km), 0.0)
+    tri = jnp.eye(c, dtype=jnp.float32) + beta[..., None] * a
+    rhs = beta[..., None] * (v - mm("hid,hdv->hiv", kp, s0))
+    u = jax.lax.linalg.triangular_solve(
+        tri, rhs, left_side=True, lower=True, unit_diagonal=True)
+    qk = jnp.where(row[:, None] >= row[None, :],
+                   mm("hid,hjd->hij", qp, km), 0.0)
+    o = mm("hid,hdv->hiv", qp, s0) + mm("hij,hjv->hiv", qk, u)
+    last = gs[:, -1]                                               # [H, d]
+    s1 = jnp.exp(last)[..., None] * s0 + mm(
+        "hjd,hjv->hdv", k * jnp.exp(last[:, None] - gs), u)
+    return o, s1
+
+
+@_scope("arks.linear_state")
+def _linear_state(q, k, v, g, beta, s_all: jnp.ndarray, layer,
+                  seq_q_start: jnp.ndarray, seq_q_len: jnp.ndarray,
+                  fresh: jnp.ndarray):
+    """The gated delta rule over a step's ragged flat batch: ``S' =
+    diag(a_t) S``, ``S <- S' + beta_t k_t (v_t - S'^T k_t)^T``, ``o_t =
+    S^T q_t``, ``a_t = exp(g_t)``, lane b's rows in order from its slot's
+    state ``s_all[layer, b]`` (``s_all [Ll, B, H, d, d]`` float32, rewritten
+    in place; read as zeros where ``fresh``).  A lane
+    of ONE row (a decode lane, a prompt's last token) takes one recurrence
+    step, all such lanes in one elementwise pass over the states; a lane of
+    more rows is walked in blocks of ``LINEAR_CHUNK`` rows by
+    :func:`_delta_chunk`, block after block and lane after lane, as many
+    trips as the batch holds blocks.  Returns (o [T, H, d] f32,
+    ``s_all``)."""
+    t, h, d = q.shape
+    f32 = jnp.float32
+    state = jax.lax.dynamic_index_in_dim(
+        s_all, layer, 0, keepdims=False).astype(f32)
+    c = LINEAR_CHUNK
+    # -- the lanes of one row: one pass over the states ------------------
+    one = seq_q_len == 1
+    at = jnp.clip(seq_q_start, 0, t - 1)
+    q1, k1, v1 = (jnp.take(x, at, axis=0).astype(f32) for x in (q, k, v))
+    a1 = jnp.exp(jnp.take(g, at, axis=0))                          # [B, H, d]
+    b1 = jnp.take(beta, at, axis=0)                                # [B, H]
+    s_dec = a1[..., None] * jnp.where(fresh[:, None, None, None], 0.0, state)
+    u1 = b1[..., None] * (v1 - jnp.sum(s_dec * k1[..., None], axis=2))
+    s_one = s_dec + k1[..., None] * u1[:, :, None, :]
+    o1 = jnp.sum(s_one * q1[..., None], axis=2)                    # [B, H, d]
+    s_all = jax.lax.dynamic_update_index_in_dim(
+        s_all, jnp.where(one[:, None, None, None], s_one, state).astype(
+            s_all.dtype), layer, 0)
+    out = jnp.zeros((t + c, h, d), f32).at[
+        jnp.where(one, at, t + c)].set(o1, mode="drop")
+
+    # -- the lanes of more rows: blocks of C rows, in order ---------------
+    blocks = jnp.where(seq_q_len > 1, -(-seq_q_len // c), 0)       # [B]
+    ends = jnp.cumsum(blocks)
+    pad = ((0, c), (0, 0), (0, 0))
+    qp, kp, vp = (jnp.pad(x.astype(f32), pad) for x in (q, k, v))
+    gp, bp = jnp.pad(g, pad), jnp.pad(beta, pad[:2])
+
+    def block(i, carry):
+        out, s_all = carry
+        lane = jnp.minimum(jnp.searchsorted(ends, i, side="right"),
+                           ends.shape[0] - 1).astype(jnp.int32)
+        nth = i - (ends[lane] - blocks[lane])
+        row0 = seq_q_start[lane] + nth * c
+        live = jnp.arange(c) < seq_q_len[lane] - nth * c           # [C]
+
+        def rows(x):
+            r = jax.lax.dynamic_slice_in_dim(x, row0, c, axis=0)
+            return jnp.swapaxes(jnp.where(
+                live.reshape((c,) + (1,) * (r.ndim - 1)), r, 0), 0, 1)
+
+        s0 = jax.lax.dynamic_slice(
+            s_all, (layer, lane, 0, 0, 0), (1, 1, h, d, d))[0, 0].astype(f32)
+        s0 = jnp.where(fresh[lane] & (nth == 0), 0.0, s0)
+        o, s1 = _delta_chunk(rows(qp), rows(kp), rows(vp), rows(gp),
+                             rows(bp), s0)
+        old = jax.lax.dynamic_slice_in_dim(out, row0, c, axis=0)
+        out = jax.lax.dynamic_update_slice_in_dim(
+            out, jnp.where(live[:, None, None], jnp.swapaxes(o, 0, 1), old),
+            row0, axis=0)
+        s_all = jax.lax.dynamic_update_slice(
+            s_all, s1[None, None].astype(s_all.dtype),
+            (layer, lane, 0, 0, 0))
+        return out, s_all
+
+    out, s_all = jax.lax.fori_loop(0, ends[-1], block, (out, s_all))
+    return out[:t], s_all
+
+
+@_scope("arks.linear_out")
+def _linear_out(o: jnp.ndarray, x: jnp.ndarray, lp: Params,
+                cfg: ModelConfig) -> jnp.ndarray:
+    """``o [T, H, d]`` f32 -> [T, E]: RMS norm a head, times the low-rank
+    gate ``sigmoid(x W_g1 W_g2)`` of the sublayer's normed input ``x``,
+    then the output projection."""
+    t = o.shape[0]
+    gate = jax.nn.sigmoid(qeinsum(
+        "tr,rq->tq", qeinsum("te,er->tr", x, lp["w_g1"]), lp["w_g2"]
+    ).astype(jnp.float32)).reshape(o.shape)
+    y = rms_norm(o, lp["o_norm"], cfg.rms_norm_eps) * gate
+    return qeinsum("tq,qe->te", y.astype(x.dtype).reshape(t, cfg.linear_dim),
+                   lp["wo"])
+
+
+def _mixed_step_periods(params, cfg, cache, tables, win_tables, tokens,
+                        token_slot, token_pos, sample_src, seq_q_start,
+                        seq_q_len, seq_pos_start, mesh):
+    """:func:`mixed_step` for a model whose layers come in periods: the
+    head's stack (``cfg.head_layers`` full-attention layers: a dense prefix,
+    or layer 0), then a scan over the periods, each the period's INNER
+    layers (an inner scan that takes them out of their stack by index) and
+    its full layer, then the tail of inner layers behind the last whole
+    period.  The inner kind is the model's: window layers
+    (``cfg.windowed``), which write and read the window pool (``cache.win``)
+    through ``win_tables``, the same ragged launch told the window; or
+    linear layers (``cfg.linear``), which read and write the slots' state
+    (``cache.lin``) and no page.  Full layers write and read the full pool
+    through ``tables``.  Returns (logits, cache, held_pairs)."""
     from arks_tpu.ops.attention import paged_mixed_update_and_attend
     t_flat = tokens.shape[0]
     cover = tables.shape[1] * cache.page
     rope_pos = jnp.minimum(token_pos, cover - 1)[None]           # [1, T]
     valid = (token_slot >= 0)[None]
-    first = cfg.first_k_dense
-    head = params["dense_layers"] if first else params["layers"]
+    first = cfg.head_layers
+    head_name = "dense_layers" if cfg.first_k_dense else "head_layers"
+    head = params[head_name] if first else params["layers"]
     with _scope("arks.embed"):
         h = embed_lookup(params["embed"], tokens[None],
                          head["attn_norm"].dtype)                # [1, T, E]
+
+    def ffn(h, lp):
+        if "router" in lp:
+            y, held = _mlp(h, lp, cfg, mesh, None, row_valid=valid)
+        else:
+            y, held = _mlp(h, lp, cfg, mesh, None), jnp.int32(0)
+        return h + y, held
 
     def layer(h, lp, pool, tbl, index, window: bool):
         q, k, v, gate = _kind_qkv(h, lp, cfg, rope_pos, window)
@@ -757,67 +1108,93 @@ def _mixed_step_windowed(params, cfg, cache, tables, win_tables, tokens,
             window=cfg.sliding_window if window else 0)
         if gate is not None:
             with _scope("arks.attn_gate"):
-                attn = attn * gate[0][..., None].astype(attn.dtype)
+                gate = gate[0] if gate.ndim == 4 else gate[0][..., None]
+                attn = attn * gate.astype(attn.dtype)
         attn = attn.reshape(1, t_flat, cfg.heads_of(window) * cfg.head_dim)
         with _scope("arks.attn_win_out" if window else "arks.attn_out"):
             h = h + qeinsum("...q,qe->...e", attn, lp["wo"])
-        if "router" in lp:
-            y, held = _mlp(h, lp, cfg, mesh, None, row_valid=valid)
-        else:
-            y, held = _mlp(h, lp, cfg, mesh, None), jnp.int32(0)
-        return h + y, tuple(pool), held
+        h, held = ffn(h, lp)
+        return h, tuple(pool), held
 
-    full, win = tuple(cache[:4]), tuple(cache.win[:4])
+    # A sequence that starts in this step starts from nothing, whatever
+    # its slot's last sequence left in the state.
+    fresh = (seq_q_len > 0) & (seq_pos_start == 0)
+
+    def linear_layer(h, lp, lin, tbl, index, window):
+        del tbl, window
+        s_all, conv_all = lin
+        x = rms_norm(h[0], lp["attn_norm"], cfg.rms_norm_eps)
+        q, k, v, g, beta, conv = _linear_qkv(
+            x, lp, cfg, jax.lax.dynamic_index_in_dim(
+                conv_all, index, 0, keepdims=False),
+            seq_q_start, seq_q_len, fresh)
+        o, s_all = _linear_state(q, k, v, g, beta, s_all, index,
+                                 seq_q_start, seq_q_len, fresh)
+        h = h + _linear_out(o, x, lp, cfg)[None]
+        lin = (s_all,
+               jax.lax.dynamic_update_index_in_dim(conv_all, conv, index, 0))
+        h, held = ffn(h, lp)
+        return h, lin, held
+
+    full = tuple(cache[:4])
+    if cfg.linear:
+        inner, inner_layer = tuple(cache.lin), linear_layer
+        inner_stack, inner_tbl = params["lin_layers"], None
+    else:
+        inner, inner_layer = tuple(cache.win[:4]), layer
+        inner_stack, inner_tbl = params["win_layers"], win_tables
     held = jnp.int32(0)
     if first:
-        def dense_body(carry, xs):
+        def head_body(carry, xs):
             h, full = carry
             h, full, n = layer(h, xs[0], full, tables, xs[1], False)
             return (h, full), n
 
         (h, full), n = jax.lax.scan(
-            dense_body, (h, full),
-            (params["dense_layers"], jnp.arange(first, dtype=jnp.int32)))
+            head_body, (h, full),
+            (params[head_name], jnp.arange(first, dtype=jnp.int32)))
         held = held + jnp.sum(n)
 
-    per = cfg.window_period
+    per = cfg.inner_period
 
-    def window_layers(h, win, start, count: int):
-        """``count`` window layers from index ``start`` of the flat stack,
+    def inner_layers(h, inner, start, count: int):
+        """``count`` inner layers from index ``start`` of the flat stack,
         each taken out by index, one slice a layer (a scan over the stack
         cut into periods would copy a period's layers out, then each of
         them again)."""
-        def win_body(c, j):
-            h, win = c
+        def inner_body(c, j):
+            h, inner = c
             at = start + j
             lp = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
-                a, at, 0, keepdims=False), params["win_layers"])
-            h, win, n = layer(h, lp, win, win_tables, at, True)
-            return (h, win), n
+                a, at, 0, keepdims=False), inner_stack)
+            h, inner, n = inner_layer(h, lp, inner, inner_tbl, at, True)
+            return (h, inner), n
 
-        (h, win), n = jax.lax.scan(
-            win_body, (h, win), jnp.arange(count, dtype=jnp.int32))
-        return h, win, jnp.sum(n)
+        (h, inner), n = jax.lax.scan(
+            inner_body, (h, inner), jnp.arange(count, dtype=jnp.int32))
+        return h, inner, jnp.sum(n)
 
     def period_body(carry, xs):
-        h, full, win = carry
+        h, full, inner = carry
         flp, i = xs
-        h, win, n = window_layers(h, win, i * per, per)
+        h, inner, n = inner_layers(h, inner, i * per, per)
         h, full, m = layer(h, flp, full, tables, first + i, False)
-        return (h, full, win), n + m
+        return (h, full, inner), n + m
 
-    (h, full, win), n = jax.lax.scan(
-        period_body, (h, full, win),
+    (h, full, inner), n = jax.lax.scan(
+        period_body, (h, full, inner),
         (params["layers"], jnp.arange(cfg.num_periods, dtype=jnp.int32)))
     held = held + jnp.sum(n)
-    if cfg.window_tail:
-        h, win, n = window_layers(h, win, cfg.num_periods * per,
-                                  cfg.window_tail)
+    if cfg.inner_tail:
+        h, inner, n = inner_layers(h, inner, cfg.num_periods * per,
+                                   cfg.inner_tail)
         held = held + n
     with _scope("arks.lm_head"):
         h_sel = jnp.take(h[0], sample_src.astype(jnp.int32), axis=0)  # [B, E]
     logits = _unembed(h_sel, params, cfg, mesh, None)
-    return logits, PagedKVCache(*full, win=PagedKVCache(*win)), held
+    if cfg.linear:
+        return logits, PagedKVCache(*full, lin=LinearState(*inner)), held
+    return logits, PagedKVCache(*full, win=PagedKVCache(*inner)), held
 
 
 def prefill_layer(
@@ -1325,24 +1702,26 @@ def mixed_step(
     fp reassociation differs across chunk boundaries).
 
     A latent model (``cfg.latent``) runs :func:`_mixed_step_latent` over
-    its latent pool, a model with window layers (``cfg.windowed``)
-    :func:`_mixed_step_windowed` over its two pools, the window layers'
-    through ``win_tables``; ``with_held`` (those two only) adds the third
-    result, the count of routed pairs that landed on experts held here."""
+    its latent pool, a model with window layers (``cfg.windowed``) or
+    linear-attention layers (``cfg.linear``) :func:`_mixed_step_periods`
+    over its two pools (the window layers' through ``win_tables``) or its
+    pool and its slots' state; ``with_held`` (those three only) adds the
+    third result, the count of routed pairs that landed on experts held
+    here."""
     if cfg.latent:
         out = _mixed_step_latent(params, cfg, cache, tables, tokens,
                                  token_slot, token_pos, sample_src,
                                  seq_q_start, seq_q_len, seq_pos_start, mesh)
         return out if with_held else out[:2]
-    if cfg.windowed:
-        out = _mixed_step_windowed(params, cfg, cache, tables, win_tables,
-                                   tokens, token_slot, token_pos, sample_src,
-                                   seq_q_start, seq_q_len, seq_pos_start,
-                                   mesh)
+    if cfg.windowed or cfg.linear:
+        out = _mixed_step_periods(params, cfg, cache, tables, win_tables,
+                                  tokens, token_slot, token_pos, sample_src,
+                                  seq_q_start, seq_q_len, seq_pos_start,
+                                  mesh)
         return out if with_held else out[:2]
     if with_held:
-        raise NotImplementedError("with_held: latent and windowed models "
-                                  "only")
+        raise NotImplementedError("with_held: latent, windowed and linear "
+                                  "models only")
     t_flat = tokens.shape[0]
     cover = tables.shape[1] * cache.page
     # RoPE positions must be real for valid tokens; padding rows only need

@@ -69,9 +69,19 @@ class WindowedCheckpointError(NotImplementedError):
     Raised instead of stacking layers of two shapes into one tree."""
 
 
+class LinearCheckpointError(NotImplementedError):
+    """A checkpoint of a model with linear-attention layers
+    (``solar_open2``): the name mapping of its delta-rule projections,
+    convolutions, low-rank pairs and expert tensors onto the three stacked
+    trees (``head_layers`` / ``layers`` / ``lin_layers``) is not built; such
+    a model is served from seeded random weights only.  Raised instead of
+    stacking layers of two kinds into one tree."""
+
+
 def _refuse_latent(cfg: ModelConfig, path: str) -> None:
     for is_kind, err in ((cfg.latent, LatentCheckpointError),
-                         (cfg.windowed, WindowedCheckpointError)):
+                         (cfg.windowed, WindowedCheckpointError),
+                         (cfg.linear, LinearCheckpointError)):
         if is_kind:
             raise err(
                 f"model {cfg.name!r}: cannot load the checkpoint at {path}: "

@@ -40,6 +40,20 @@ from benchmarks.tests.test_reference_swa_moe import (  # noqa: E402,F401
     test_the_probes_went_through_both_pools_and_released_window_pages,
     test_the_routing_margin_is_in_router_logit_units,
 )
+from benchmarks.tests.test_reference_linear_moe import (  # noqa: E402,F401
+    linear_served,
+    test_seeded_weights_are_the_programs_bit_for_bit as
+    test_linear_moe_seeded_weights_are_the_programs_bit_for_bit,
+    test_served_logprobs_against_the_reference as
+    test_linear_moe_served_logprobs_against_the_reference,
+    test_the_family_keeps_the_contract_and_imports_nothing_of_the_program as
+    test_linear_moe_keeps_the_contract_and_imports_nothing_of_the_program,
+    test_the_lower_precision_controls_fail as
+    test_linear_moe_lower_precision_controls_fail,
+    test_the_probes_went_through_pages_and_state,
+    test_the_routing_margin_is_in_biased_score_units as
+    test_linear_moe_routing_margin_is_in_biased_score_units,
+)
 from benchmarks.tests.test_reference_mla_moe import (  # noqa: E402,F401
     test_seeded_weights_are_the_programs_bit_for_bit as
     test_mla_moe_seeded_weights_are_the_programs_bit_for_bit,

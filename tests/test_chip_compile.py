@@ -322,3 +322,35 @@ def test_quantised_experts_compile_for_v5e_as_stored(chip, kind, rows):
         assert f"bf16[{x},{e},{f}]" not in text
         assert f"bf16[{x},{f},{e}]" not in text
         assert compiled.memory_analysis().temp_size_in_bytes < 400e6
+
+
+@pytest.mark.parametrize("rows", [0, 1024])
+def test_the_delta_rules_state_update_compiles_for_v5e_in_place(chip, rows):
+    """A linear layer's state update at Solar-Open2-250B's widths (64 heads
+    of 128, 64 slots: 268 MB of float32 state a layer) on the cell's two
+    step shapes, 64 rows (the pipelined step: one recurrence step a lane)
+    and 64 + 1024 (a chunk: the ``while`` over blocks of 64 rows with its
+    triangular solve): the chip's compiler takes both, and the six layers'
+    states (1.6 GB) are rewritten in place: the program's temporaries stay
+    under ONE layer's states, and its output aliases its argument."""
+    from arks_tpu.models import transformer as tf
+
+    h, d, slots, layers = 64, 128, 64, 6
+    t = slots + rows
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    f32, i32 = jnp.float32, jnp.int32
+    compiled = jax.jit(tf._linear_state, donate_argnums=(5,)).lower(
+        spec((t, h, d), f32), spec((t, h, d), f32), spec((t, h, d), f32),
+        spec((t, h, d), f32), spec((t, h), f32),
+        spec((layers, slots, h, d, d), f32), spec((), i32),
+        spec((slots,), i32), spec((slots,), i32), spec((slots,), jnp.bool_)
+    ).compile()
+    text = compiled.as_text()
+    assert "triangular" in text.lower() or "while" in text
+    mem = compiled.memory_analysis()
+    state = layers * slots * h * d * d * 4
+    assert mem.alias_size_in_bytes >= state
+    assert mem.temp_size_in_bytes < state // layers + 300e6

@@ -8,8 +8,29 @@ that tree.  PR 33 rewrote the dispatch of a routed layer held whole with
 QUANTISED experts; ``tiny`` has no experts, and ``tiny-mla-moe`` (the
 latent block) and ``tiny-swa-moe`` (window and full layers) under a share
 run ``moe._batched_dispatch``'s loop as they did, int8 leaves included, so
-all twelve stand.  A PR that means to change one of these
+all twelve stood.  A PR that means to change one of these
 programs re-pins it and says so.
+
+PR 36 re-pinned FOUR, the sequential programs of the two routed presets:
+``moe._batch_pays`` now sends a SHARE's layer to the dense dispatch too
+while an expert's batch (``_held_capacity``) would be every row, as it
+sent a layer held whole since PR 33 (on the chip the batched form of such
+a step copied every expert leaf out of its stack: 41.9 -> 26.3 ms for a
+64-row decode step at Solar-Open2's widths, PERF.md §6).  At these
+presets' 2 + 64 rows an expert's batch (whole tiles of 128) is every row,
+so their 66-row steps are dense now.  The
+benchmark's cells do not move: kimi's and laguna's sequential steps (1032
+/ 1056 / 288 rows against batches of 128 / 256 / 128) stay batched and
+their pipelined steps (8 / 32 rows) were dense already.  The eight other
+pins are PR 32's values still, through ``_mixed_step_periods`` (PR 36's
+one period scan for window and linear inner layers) too.
+
+So that the share's BATCHED loop stays pinned inside a step program, the
+two routed presets are lowered a second time at a shape where an expert's
+batch is smaller than the step (``@wide``: 2 + 512 rows, top-4 of 32
+scored, a batch of 384): these four pins are what commit c459215 (PR 33,
+the parent of PR 36) lowers at the same shape, taken with this file in
+that tree, so at such a shape PR 36's rule changes nothing.
 """
 
 import hashlib
@@ -18,21 +39,25 @@ import pytest
 
 from arks_tpu.engine import EngineConfig, InferenceEngine
 from arks_tpu.engine.tokenizer import ByteTokenizer
-from arks_tpu.models import get_config
+from arks_tpu.models import get_config, moe
 
 PINS = {
     "tiny.seq": "ed53d9ecb362f937",
     "tiny.seq_lp": "31d2702fc1c7b6af",
     "tiny.pipe": "a6579a2a5236124a",
     "tiny.pipe_lp": "d059fb1f2834e04b",
-    "tiny-mla-moe.seq": "217d97c245413946",
-    "tiny-mla-moe.seq_lp": "110bf912e3087c12",
+    "tiny-mla-moe.seq": "6cc9f745a6bfd269",
+    "tiny-mla-moe.seq_lp": "c5e64d4cfdb392a2",
     "tiny-mla-moe.pipe": "892ad0d2c5590329",
     "tiny-mla-moe.pipe_lp": "42fe3682f6a6855b",
-    "tiny-swa-moe.seq": "cd926330e174396e",
-    "tiny-swa-moe.seq_lp": "a25e688a91fafb31",
+    "tiny-swa-moe.seq": "107c9e6196f101d2",
+    "tiny-swa-moe.seq_lp": "353c28114cf97b20",
     "tiny-swa-moe.pipe": "973796d815677dce",
     "tiny-swa-moe.pipe_lp": "e428a82b85955814",
+    "tiny-mla-moe@wide.seq": "ef7ecdee689986d0",
+    "tiny-mla-moe@wide.seq_lp": "901dc7ce4f3539c5",
+    "tiny-swa-moe@wide.seq": "93e12f3a65d1e090",
+    "tiny-swa-moe@wide.seq_lp": "d37658bc2680eb1c",
 }
 
 
@@ -48,18 +73,26 @@ def _programs(eng):
 
 
 def step_program_hashes(model: str, monkeypatch) -> dict:
+    """``model`` is a preset's name, or ``<preset>@wide``: the same preset
+    through a step of 2 + 512 rows, where a share's expert takes a batch
+    of 384 and the batched dispatch's loop is in the program."""
     monkeypatch.setenv("ARKS_PIPELINE_DEPTH", "2")
     monkeypatch.setenv("ARKS_MIXED_STEP", "auto")
-    cfg = get_config(model)
-    kw = dict(model=model, num_slots=2, max_cache_len=128,
+    preset, _, wide = model.partition("@")
+    cfg = get_config(preset)
+    kw = dict(model=preset, num_slots=2, max_cache_len=128,
               prefill_buckets=(16,), prefill_chunk=16, kv_layout="paged")
     if cfg.num_experts:
         # A share, int8 leaves, and a step of 2 + 64 rows: the sequential
-        # programs take the grouped path (the batched dispatch's loop).
+        # programs take the grouped rule (since PR 36: the dense dispatch,
+        # an expert's batch being every row at these sizes).
         cfg = cfg.with_expert_share(2, 0)
         kw.update(weight_dtype="int8", prefill_chunk=64)
     if cfg.windowed:
         kw.update(max_cache_len=256, kv_cache_dtype="bf16")
+    if wide:
+        kw.update(prefill_chunk=512, max_cache_len=1024)
+        assert moe._held_capacity(2 + 512, cfg) == 384
     eng = InferenceEngine(cfg, EngineConfig(**kw), ByteTokenizer())
     try:
         assert eng._pipe_warm_wait(300) == "ready"
@@ -75,7 +108,7 @@ def hashes():
     """Every pinned program's hash, an engine a model, built once."""
     out = {}
     with pytest.MonkeyPatch.context() as mp:
-        for model in sorted({k.split(".")[0] for k in PINS}):
+        for model in sorted({k.rsplit(".", 1)[0] for k in PINS}):
             out.update(step_program_hashes(model, mp))
     return out
 
