@@ -836,6 +836,13 @@ class EngineMetrics:
         self.engine_config_info = r.gauge(
             "engine_config_info",
             "Resolved engine configuration (labels; value is always 1)")
+        # The other build fact, a number: the form the sampler's window
+        # search took for this pod's step shape (sampler.window_blocks).
+        self.sampler_window_blocks = r.gauge(
+            "sampler_window_blocks",
+            "Blocks the sampler cuts a vocabulary row into to find its "
+            "top-k window in two stages at this pod's [slots, vocab] step "
+            "shape (0: one top_k call over the whole row)")
         # ---- Multi-model pool (engine.model_pool) ----------------------
         self.model_pool_resident_bytes = r.gauge(
             "model_pool_resident_bytes",
@@ -2033,6 +2040,15 @@ class InferenceEngine:
         log.info("engine resolved config: %s",
                  " ".join(f"{k}={v}" for k, v in
                           sorted(self.resolved_config.items())))
+        # Static, like the labels above: chosen at trace time from the
+        # step's [slots, vocab] shape, so every step takes it or none does.
+        blocks = sampler_mod.window_blocks(engine_cfg.num_slots,
+                                           cfg.vocab_size)
+        self.metrics.sampler_window_blocks.set(blocks)
+        log.info("sampler window: %s at [%d, %d]",
+                 f"two stages over {blocks} blocks of "
+                 f"{sampler_mod.WINDOW_BLOCK} columns" if blocks
+                 else "one top_k call", engine_cfg.num_slots, cfg.vocab_size)
 
         # ARKS_KERNEL_TUNE=sweep benchmarks candidate kernel blocks for
         # THIS shape now, so _build_programs (and every later dispatch)

@@ -5,8 +5,13 @@ cross the host boundary.
 
 Per-slot params come in as arrays so one compiled program serves any mix of
 greedy/temperature/top-k/top-p requests.  Top-k/top-p work on a static
-``top_k_max``-wide slice of the vocab (lax.top_k), the standard TPU trick to
-avoid sorting the full vocab each step.
+``TOP_K_MAX``-wide window of the vocab, so no step sorts the full vocab.  The
+window itself is found in two stages where the vocabulary is large
+(``_top_window``): the maximum of every ``WINDOW_BLOCK``-column block, the
+``k`` best blocks by that maximum, and ``lax.top_k`` over those blocks'
+columns alone; the same values and ids as one ``lax.top_k`` over the row,
+ties included, for a sixteenth of the sorting at 152,064 columns.  A small
+vocabulary (a chip's share of one, a test model) takes the one call.
 """
 
 from __future__ import annotations
@@ -27,6 +32,59 @@ _NP_KEY_OK: bool | None = None
 # in a profile (docs/monitoring.md; metadata only, the program is unchanged).
 _in_profile = jax.named_scope("arks.sampler")
 
+# The two-stage window (``_top_window``).  WINDOW_BLOCK: one lane tile of the
+# chip, so a block's maximum is a reduction along the minor axis of whole
+# tiles and a chosen block is gathered as one aligned row (32 / 64 columns a
+# block sort fewer values and cost more in layout at 152,064 columns; 256
+# and up sort more).  _MIN_VALUES:
+# the one call costs ~0.3 ns a value of the [lanes, vocab] logits on a v5e,
+# the two stages ~0.25 ms whatever the lanes up to 128 (their second top_k
+# runs with the lanes along the chip's lanes), so under ~a million values
+# the one call wins.  The timed table: PERF.md §6, PR 37.
+WINDOW_BLOCK = 128
+_MIN_VALUES = 1 << 20
+
+
+def window_blocks(lanes: int, vocab: int, k: int = TOP_K_MAX) -> int:
+    """How many blocks ``_top_window`` cuts the rows of a [lanes, vocab]
+    array into for their ``k`` best entries; 0 = it makes the one
+    ``lax.top_k`` call.  THE rule, from the static shape alone: two stages
+    sort ``blocks + k x WINDOW_BLOCK`` values a lane where the one call sorts
+    ``vocab``, so they need the row to be at least twice the gathered
+    blocks, and enough values in all to pay for their fixed cost."""
+    blocks = -(-vocab // WINDOW_BLOCK)
+    return blocks if (blocks >= 2 * k
+                      and lanes * vocab >= _MIN_VALUES) else 0
+
+
+@_in_profile
+def _top_window(x: jnp.ndarray, k: int) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """``jax.lax.top_k(x, k)`` over the last axis of ``x`` [B, V], bit for
+    bit (values descending, equal values by ascending id), sorting fewer
+    values where V is large.
+
+    Every member of the true top-k lies in one of the k best BLOCKS (by
+    maximum descending, then block id ascending): were its block outranked
+    by k others, each of those would hold an entry that precedes it.  The
+    chosen blocks are gathered in ascending block order, so a candidate's
+    position is monotone in its vocab id and the second ``top_k`` breaks
+    ties as the one call does.  Columns that pad the last block are -inf
+    behind every real column, so none is ever chosen over one."""
+    b, v = x.shape
+    nb = window_blocks(b, v, k)
+    if not nb:
+        return jax.lax.top_k(x, k)
+    pad = nb * WINDOW_BLOCK - v
+    if pad:
+        x = jnp.pad(x, ((0, 0), (0, pad)), constant_values=-jnp.inf)
+    blocks = x.reshape(b, nb, WINDOW_BLOCK)
+    _, bid = jax.lax.top_k(blocks.max(axis=-1), k)              # [B, k]
+    bid = jnp.sort(bid, axis=-1)
+    cand = jnp.take_along_axis(blocks, bid[:, :, None], axis=1)  # [B, k, W]
+    vals, pos = jax.lax.top_k(cand.reshape(b, k * WINDOW_BLOCK), k)
+    ids = (jnp.take_along_axis(bid, pos // WINDOW_BLOCK, axis=-1)
+           * WINDOW_BLOCK + pos % WINDOW_BLOCK)
+    return vals, ids
 
 
 def np_prng_key(seed: int) -> np.ndarray:
@@ -66,7 +124,7 @@ def top_logprobs(logits: jnp.ndarray, chosen: jnp.ndarray
     log-softmax) — the conventional reading of the API field, independent
     of temperature/penalty shaping."""
     lp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    vals, ids = jax.lax.top_k(lp, min(TOP_LOGPROBS_MAX, lp.shape[-1]))
+    vals, ids = _top_window(lp, min(TOP_LOGPROBS_MAX, lp.shape[-1]))
     chosen_lp = jnp.take_along_axis(lp, chosen[:, None], -1)[:, 0]
     return chosen_lp, vals, ids.astype(jnp.int32)
 
@@ -436,7 +494,7 @@ def _filtered_scaled(logits: jnp.ndarray, state: SamplingState
     [B, W]) after temperature + top-k + top-p over the TOP_K_MAX window."""
     b, v = logits.shape
     window = min(TOP_K_MAX, v)
-    top_logits, top_idx = jax.lax.top_k(logits, window)  # [B, W], descending
+    top_logits, top_idx = _top_window(logits, window)  # [B, W], descending
     temp = jnp.maximum(state.temperature, 1e-6)[:, None]
     scaled = top_logits / temp
 

@@ -12,7 +12,29 @@ is copied: the functions are the instrument's own."""
 import os
 import sys
 
+import pytest
+
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _registry_and_environment_restored():
+    """``benchmarks/pod.py::build`` registers a configuration under its own
+    name (a test size's is a preset's: ``tiny-mla-moe`` with half its experts
+    held) and exports its deploy ``env`` (``ARKS_MIXED_CHUNK_TOKENS``), for
+    the life of a benchmark process.  Here the process goes on to other test
+    files (one xdist worker runs many: a later ``get_config("tiny-mla-moe")``
+    or a step's chunking would read what a case here left), so both are put
+    back when this file is done (module scope: set up before the imported
+    ``served`` fixtures, which build pods, torn down after them)."""
+    from arks_tpu.models import config
+    registry, environ = dict(config._REGISTRY), dict(os.environ)
+    yield
+    config._REGISTRY.clear()
+    config._REGISTRY.update(registry)
+    os.environ.clear()
+    os.environ.update(environ)
+
 
 from benchmarks.tests.test_manifest import (  # noqa: E402,F401
     test_a_new_cell_loads_from_added_files_alone,

@@ -291,6 +291,52 @@ def test_resolved_config_surfaced(engine):
     assert f'pipeline_depth="{rc["pipeline_depth"]}"' in text
 
 
+def _sampled_streams(eng):
+    """Seeded sampling requests with logprobs through ``eng``: their tokens
+    and top logprobs."""
+    reqs = [Request(f"w{i}", [7 + i, 9, 11], SamplingParams(
+        max_tokens=6, temperature=0.9, top_k=k, top_p=p, seed=100 + i,
+        logprobs=3, ignore_eos=True))
+        for i, (k, p) in enumerate([(0, 0.9), (5, 1.0), (64, 0.9)])]
+    got = []
+    for r in reqs:
+        eng.add_request(r)
+    _drive(eng)
+    for r in reqs:
+        ids, lps = [], []
+        while True:
+            out = r.outputs.get(timeout=60)
+            ids.extend(out.token_ids)
+            lps.extend(out.logprobs or [])
+            if out.finished:
+                break
+        got.append((ids, repr(lps)))
+    return got
+
+
+def test_sampler_window_form_surfaced(engine, monkeypatch):
+    """Which form the sampler's window search took is a build fact on
+    /metrics (0: the one top_k call, every test model), and an engine whose
+    step takes the two-stage form serves the same streams."""
+    from arks_tpu.engine import sampler as sm
+    assert engine.metrics.sampler_window_blocks.get() == 0
+    assert "sampler_window_blocks 0" in engine.metrics.registry.render()
+
+    def build():
+        return InferenceEngine(get_config("tiny"), EngineConfig(
+            model="tiny", num_slots=2, max_cache_len=64,
+            prefill_buckets=(8, 16, 32)), ByteTokenizer())
+
+    want = _sampled_streams(build())
+    # The rule's constants at a test model's scale: 512 columns in 256
+    # blocks of 2, whatever the lanes.
+    monkeypatch.setattr(sm, "WINDOW_BLOCK", 2)
+    monkeypatch.setattr(sm, "_MIN_VALUES", 1)
+    two = build()
+    assert two.metrics.sampler_window_blocks.get() == 256
+    assert _sampled_streams(two) == want
+
+
 def test_cache_len_alignment_rounds_up_for_pallas(monkeypatch):
     """A misaligned --max-model-len must self-correct at startup, not raise
     deep inside the first decode dispatch (kernel DMA tiling constraints)."""

@@ -3,6 +3,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from arks_tpu.engine import sampler as sm
 
@@ -186,3 +187,119 @@ def test_min_tokens_suppresses_stop_until_minimum():
         assert stop not in late[:4]
     finally:
         eng.stop()
+
+
+# ---------------------------------------------------------------------------
+# The window search (sampler._top_window): two stages where the shape pays,
+# the one lax.top_k call elsewhere; the same values and ids either way.
+
+def _lanes_for(vocab):
+    """The fewest lanes at which the rule takes two stages for ``vocab``
+    (enough values in all), so the cases stay small on the CPU."""
+    return -(-sm._MIN_VALUES // vocab)
+
+
+def _window_logits(kind, lanes, vocab, seed):
+    rng = np.random.default_rng(seed)
+    x = (rng.standard_normal((lanes, vocab)) * 3.0).astype(np.float32)
+    if kind == "bf16":       # what an lm_head in bfloat16 leaves: many equal values
+        return np.asarray(jnp.asarray(x).astype(jnp.bfloat16)
+                          .astype(jnp.float32))
+    if kind == "ties":       # half-integers: every window is full of ties
+        return np.round(x * 2.0) / 2.0
+    if kind == "masked":     # a grammar mask: -inf but for fewer than 64 columns
+        keep = rng.integers(0, vocab, size=(lanes, 40))
+        out = np.full((lanes, vocab), -np.inf, np.float32)
+        np.put_along_axis(out, keep, np.take_along_axis(x, keep, 1), 1)
+        return out
+    return x
+
+
+# (vocabulary, the form the rule must take at k = 64, at k = 8).  151,937
+# and 50,257 are divided by no block width; 12,544 is too few blocks for 64
+# of them to be a saving; 512 is a test model's.
+_WINDOW_VOCABS = [(152064, True, True), (32000, True, True),
+                  (24576, True, True), (20480, True, True),
+                  (12544, False, True), (151937, True, True),
+                  (50257, True, True), (512, False, False)]
+
+
+@pytest.mark.parametrize("k", [64, 8])
+@pytest.mark.parametrize("kind", ["f32", "bf16", "ties", "masked"])
+@pytest.mark.parametrize("vocab,two64,two8", _WINDOW_VOCABS)
+def test_top_window_equals_lax_top_k(vocab, two64, two8, kind, k):
+    lanes = _lanes_for(vocab) if vocab > 512 else 4
+    blocks = sm.window_blocks(lanes, vocab, k)
+    assert bool(blocks) == (two64 if k == 64 else two8)
+    if blocks:
+        assert blocks == -(-vocab // sm.WINDOW_BLOCK)
+    x = jnp.asarray(_window_logits(kind, lanes, vocab, seed=vocab + k))
+    vals, ids = jax.jit(sm._top_window, static_argnums=1)(x, k)
+    want_vals, want_ids = jax.lax.top_k(x, k)
+    assert ids.dtype == want_ids.dtype
+    np.testing.assert_array_equal(np.asarray(vals), np.asarray(want_vals))
+    np.testing.assert_array_equal(np.asarray(ids), np.asarray(want_ids))
+
+
+def test_window_rule_follows_the_static_shape():
+    # The cells' step shapes (PERF.md §6, PR 37): qwen, mixtral and solar
+    # take two stages; kimi's 8 lanes and laguna's 98 blocks the one call.
+    assert sm.window_blocks(192, 152064) == 1188
+    assert sm.window_blocks(64, 32000) == 250
+    assert sm.window_blocks(64, 24576) == 192
+    assert sm.window_blocks(8, 20480) == 0
+    assert sm.window_blocks(32, 12544) == 0
+    # One prompt's first token, and every test model: the one call.
+    assert sm.window_blocks(1, 152064) == 0
+    assert sm.window_blocks(192, 512) == 0
+    # Too few lanes for the fixed cost, whatever the vocabulary's blocks.
+    assert sm.window_blocks(4, 152064) == 0
+    jaxpr = str(jax.make_jaxpr(lambda x: sm._top_window(x, 64))(
+        jnp.zeros((4, 512))))
+    assert jaxpr.count("top_k") == 1 and "gather" not in jaxpr
+
+
+def _mixed_lanes(vocab):
+    """Greedy and sampling lanes side by side: top_k 0 / 5 / 64, top_p 0.9 /
+    1.0, one lane penalised."""
+    lanes = 8
+    st = sm.init_sampling_state(lanes, seed=11, vocab_size=vocab)
+    st = st._replace(
+        temperature=jnp.asarray([0.0, 0.7, 1.0, 1.3, 0.0, 0.9, 1.0, 2.0],
+                                jnp.float32),
+        top_k=jnp.asarray([0, 0, 5, 64, 5, 64, 0, 5], jnp.int32),
+        top_p=jnp.asarray([1.0, 0.9, 1.0, 0.9, 0.9, 1.0, 1.0, 0.9],
+                          jnp.float32),
+        presence=jnp.zeros((lanes,), jnp.float32).at[3].set(0.5),
+        counts=st.counts.at[3, :100].set(2))
+    logits = jnp.asarray(_window_logits("bf16", lanes, vocab, seed=5))
+    return logits, st
+
+
+@pytest.mark.parametrize("fn", ["sample", "filtered_probs", "top_logprobs"])
+def test_sampler_outputs_as_with_the_one_call(fn, monkeypatch):
+    """What ``sample``, ``filtered_probs`` and ``top_logprobs`` return
+    through the two-stage window is what they return through the one
+    ``lax.top_k`` call, bit for bit (keys included)."""
+    vocab = 152064
+    logits, st = _mixed_lanes(vocab)
+    assert sm.window_blocks(logits.shape[0], vocab) > 0
+    assert sm.window_blocks(logits.shape[0], vocab, sm.TOP_LOGPROBS_MAX) > 0
+
+    def run():
+        if fn == "sample":
+            out, state = [], st
+            for _ in range(3):
+                ids, state = sm.sample(logits, state)
+                out.append(ids)
+            return out + [state.key]
+        if fn == "filtered_probs":
+            return list(sm.filtered_probs(logits, st))
+        chosen = jnp.argmax(logits, -1).astype(jnp.int32)
+        return list(sm.top_logprobs(logits, chosen))
+
+    got = run()
+    monkeypatch.setattr(sm, "_top_window", jax.lax.top_k)
+    want = run()
+    for g, w in zip(got, want, strict=True):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
