@@ -48,6 +48,7 @@ from arks_tpu.models.config import ModelConfig
 from arks_tpu.models import transformer as tf
 from arks_tpu.obs import logctx
 from arks_tpu.obs import profiler as prof_mod
+from arks_tpu.obs import stepclock as stepclock_mod
 from arks_tpu.obs import trace as trace_mod
 from arks_tpu.utils import knobs
 from arks_tpu.utils import metrics as prom
@@ -792,11 +793,68 @@ class EngineMetrics:
         self.scheduler_seconds_total = r.counter(
             "scheduler_seconds_total",
             "Engine-thread wall seconds by scheduler phase")
-        self.decode_resolve_wait_seconds_total = r.counter(
-            "decode_resolve_wait_seconds_total",
-            "Seconds blocked fetching decode results (pure device-stream "
-            "wait, unpolluted by overlapped host work), split by "
-            "mode=pipelined|sequential")
+        # The step clock (obs/stepclock.py): every dispatch's cycle, on in
+        # every window.  A cycle runs from one model dispatch call's return
+        # to the next one's and has the kind of the dispatch that opened
+        # it; the legs sum to it.  leg="wait" is the engine thread blocked
+        # fetching results (what decode_resolve_wait_seconds_total{mode}
+        # counted until PR 38: the device's leg), "starved" the time the
+        # device provably had nothing queued, "overlap" host work beside a
+        # busy device.  A stalled cycle is in none of the first three
+        # families, only in the last two.
+        self.step_leg_seconds_total = r.counter(
+            "step_leg_seconds_total",
+            "Engine-thread seconds of the step cycles by kind of dispatch "
+            "(seq|seq_tail|pipe|spec|spec_pipe|decode) and leg "
+            "(wait|starved|overlap); the legs sum to the cycles")
+        self.step_call_seconds_total = r.counter(
+            "step_call_seconds_total",
+            "Seconds inside the model dispatch calls themselves (into the "
+            "jitted step and back), by kind of program called; they lie "
+            "inside the starved and overlap legs")
+        self.step_cycle_seconds = r.histogram(
+            "step_cycle_seconds",
+            "A dispatch call's return to the next one's, by kind of the "
+            "dispatch that opened the cycle (its count: the steps by "
+            "shape)", buckets=stepclock_mod.CYCLE_BUCKETS)
+        self.step_stalls_total = r.counter(
+            "step_stalls_total",
+            "Cycles over 8x the trailing median of their kind and over "
+            "0.25 s, by where the time stood (dispatch|wait|host|compile)")
+        self.step_stall_seconds_total = r.counter(
+            "step_stall_seconds_total",
+            "Seconds of the stalled cycles (whole cycles: they are left "
+            "out of step_leg_seconds_total and step_cycle_seconds), by "
+            "where the time stood")
+        # A sound run reads 0, not nothing: every ``where`` stands on
+        # /metrics from the first scrape.
+        for where in stepclock_mod.WHERE:
+            self.step_stalls_total.inc(0, where=where)
+            self.step_stall_seconds_total.inc(0, where=where)
+        # How late the tracer's collector woke from its timed wait, one
+        # observation a flush, off the engine thread: a process that was
+        # not scheduled (or a C call that kept the GIL) shows here whether
+        # or not a step was running.
+        self.host_wake_late_seconds = r.histogram(
+            "host_wake_late_seconds",
+            "How late the trace collector's timed wait returned (one "
+            "observation a flush): every Python thread's stall",
+            buckets=stepclock_mod.LAG_BUCKETS)
+        # The handler threads' lag behind the door: one observation a
+        # STREAM, at its end, of its worst frame's time from the engine's
+        # put (_deliver / _flush_deferred) to the socket flush; and, for a
+        # stream that had a deferred frame, of its worst time from the
+        # frame's making to its put (what PR 30's deferral costs a client).
+        self.stream_deliver_lag_seconds = r.histogram(
+            "stream_deliver_lag_seconds",
+            "A stream's worst lag from the engine's put of a frame to its "
+            "flush on the wire (one observation a stream)",
+            buckets=stepclock_mod.LAG_BUCKETS)
+        self.stream_defer_lag_seconds = r.histogram(
+            "stream_defer_lag_seconds",
+            "A stream's worst lag from a deferred frame's making to its "
+            "put behind the next dispatch (one observation a stream that "
+            "had one)", buckets=stepclock_mod.LAG_BUCKETS)
         # Pipelined decode (ARKS_PIPELINE_DEPTH): in-flight dispatches
         # after each issue.  At depth N steady state this sits at N — a
         # histogram stuck at 1 means the engine keeps leaving the
@@ -1202,6 +1260,12 @@ class InferenceEngine:
         # and returns them; with no window open a section site is one
         # attribute test (self.profiler.sections).
         self.profiler = prof_mod.ProfilerWindows(tracer=self.trace)
+        # The step loop's own clock, on in every window (obs/stepclock.py):
+        # fed at the dispatch and wait sites below, by this thread alone.
+        self.step_clock = stepclock_mod.StepClock(
+            self.metrics, self.trace,
+            state=lambda: (len(self._slots), self._queue.qsize()))
+        self.trace.wake_hist = self.metrics.host_wake_late_seconds
         self._admit_popped = 0   # requests _admit() took off the queue
         _watch_compilations(self)
         self._pipe_seq = 0   # pipelined issue->resolve span pairing
@@ -3445,9 +3509,12 @@ class InferenceEngine:
             finally:
                 self._step_hb = None
             # Auto-arm hook: a step whose wall time jumps past
-            # ARKS_PROF_AUTO_ARM x the trailing median opens a profiler
-            # window by itself (closed after ARKS_PROF_WINDOW_S).
-            prof.on_step(time.monotonic() - t0)
+            # ARKS_PROF_AUTO_ARM x the trailing median of the cycles (the
+            # step clock's, the one the stall rule reads) opens a profiler
+            # window by itself (closed after ARKS_PROF_WINDOW_S).  A step
+            # that waited for a request is judged by nothing.
+            prof.on_step(time.monotonic() - t0,
+                         self.step_clock.last_median if progressed else None)
             if not progressed:
                 time.sleep(0.001)
 
@@ -4158,6 +4225,8 @@ class InferenceEngine:
                 # forever — the busy-path purges only run while slots exist.
                 self._purge_stale_aborts()
                 # Idle: wait briefly for a request, then try admission again.
+                # The time belongs to no leg of any cycle.
+                self.step_clock.idle()
                 try:
                     _, _, req = self._queue.get(timeout=block_s)
                 except queue.Empty:
@@ -8149,6 +8218,7 @@ class InferenceEngine:
             else:
                 self._cache, self._sampling, toks, ntok, nlen, nalive = out
                 lp_devs = None
+        t_ret = time.monotonic()
         self._pipe_state = (ntok, nlen, nalive)
         # Start the device->host copies NOW so the lagged resolve finds
         # them materialized instead of blocking the engine thread.
@@ -8160,6 +8230,8 @@ class InferenceEngine:
             (snapshot, want_lp, toks, lp_devs, K, t0, counts))
         self.metrics.pipeline_depth_occupancy.observe(
             len(self._pipe_inflight))
+        self.step_clock.dispatched("spec_pipe" if spec else "pipe", t0,
+                                   t_ret, len(snapshot) * K)
         if self._model_loads:
             # Dispatch accounting for the switch-overlap claim: decode
             # dispatches issued while another model's weights stream, and
@@ -8201,8 +8273,7 @@ class InferenceEngine:
             lvals = np.asarray(lp_devs[1])   # [K, B, L]
             lids = np.asarray(lp_devs[2])
         now = time.monotonic()
-        self.metrics.decode_resolve_wait_seconds_total.inc(
-            now - t_wait, mode="pipelined")
+        self.step_clock.waited(t_wait, now, len(self._pipe_inflight))
         self.trace.evt("", "pipe", "E", len(snapshot))
         # TPOT from resolve interarrival: in steady state one resolve
         # lands per dispatch, so the gap IS the per-dispatch device time —
@@ -8329,6 +8400,8 @@ class InferenceEngine:
                 self.params, self._cache, jnp.asarray(self._last_token),
                 jnp.asarray(self._lengths), self._sampling, tables_arg,
                 self._guide_dev)
+        self.step_clock.dispatched("decode", t0, time.monotonic(),
+                                   len(self._slots) * K)
         # Snapshot the dispatch's slot set: slots admitted while this
         # dispatch is in flight are NOT part of it (their rows carried the
         # free-slot sentinel at issue).
@@ -8347,8 +8420,7 @@ class InferenceEngine:
         # Pure device-stream wait, free of overlapped host work (the
         # phase-seconds breakdown attributes WALL time, which in overlap
         # mode can land waits in whichever phase fetches first).
-        self.metrics.decode_resolve_wait_seconds_total.inc(
-            time.monotonic() - t_wait, mode="sequential")
+        self.step_clock.waited(t_wait, time.monotonic(), 0)
         if lp_devs is not None:
             clps = np.asarray(lp_devs[0])    # [K, B]
             lvals = np.asarray(lp_devs[1])   # [K, B, L]
@@ -8841,23 +8913,22 @@ class InferenceEngine:
                 self._cache, np.zeros((self._spill_group,), np.int32))
         self._spill_warm = True
 
-    def _mixed_account(self, a: dict, rows: int, n_chunk: int, qmax: int,
-                       tag: str) -> None:
+    def _mixed_account(self, a: dict, rows: int, n_chunk: int, budget: int,
+                       qmax: int, tag: str) -> None:
         """The ``count`` section: the dispatch's counters, all from the
-        host-side batch arrays.  The budget counter rises only while a
-        prompt could have used the budget (one is prefilling or queued),
-        so chunk_tokens / chunk_budget_tokens is the share of the prefill
-        budget the steps took."""
+        host-side batch arrays.  The budget counter rises, by the
+        ``budget`` of the shape the step took (the tail's on a tail step),
+        only while a prompt could have used it (one is prefilling or
+        queued), so chunk_tokens / chunk_budget_tokens is the share of the
+        prefill budget the steps took."""
         sec = self.profiler.sections
         if sec:
             self.trace.evt("", tag + "count", "B")
         self.metrics.mixed_batch_tokens.observe(rows)
         if n_chunk:
             self.metrics.mixed_chunk_tokens_total.inc(n_chunk)
-        if self._mixed_budget and (self._prefilling
-                                   or self._queue.qsize() > 0):
-            self.metrics.mixed_chunk_budget_tokens_total.inc(
-                self._mixed_budget)
+        if budget and (self._prefilling or self._queue.qsize() > 0):
+            self.metrics.mixed_chunk_budget_tokens_total.inc(budget)
         self._mixed_grid_counters(a["seq_pos_start"], a["seq_q_len"], qmax)
         if sec:
             self.trace.evt("", tag + "count", "E")
@@ -8942,7 +9013,7 @@ class InferenceEngine:
         if sec:
             evt("", tag + "pack", "E", (t, n_chunk, len(self._prefilling)))
         # qmax mirrors the dispatcher: t_flat - b_lanes + 1.
-        self._mixed_account(a, t, n_chunk, budget + 1, tag)
+        self._mixed_account(a, t, n_chunk, budget, budget + 1, tag)
         self._emit("mixed", lp=want_lp, **a)
         t0 = time.monotonic()
         args = (self.params, self._cache, self._sampling, operands,
@@ -8957,6 +9028,9 @@ class InferenceEngine:
             lp_devs = (clps, lvals, lids)
         else:
             ids_dev, self._cache, self._sampling = self._mixed_fn(*args)
+        self.step_clock.dispatched(
+            "seq_tail" if pack is self._mixed_tail_pack else "seq", t0,
+            time.monotonic(), t)
         if sec:
             evt("", tag + "dispatch", "E",
                 "arks_mixed_seq_lp" if want_lp else "arks_mixed_seq")
@@ -8981,8 +9055,7 @@ class InferenceEngine:
             evt("", tag + "wait", "B")
         t_wait = time.monotonic()
         ids = np.asarray(ids_dev)   # [B] — host sync point
-        self.metrics.decode_resolve_wait_seconds_total.inc(
-            time.monotonic() - t_wait, mode="sequential")
+        self.step_clock.waited(t_wait, time.monotonic(), 0)
         if self._held_stat:
             self._count_held(ids)
         if lp_devs is not None:
@@ -9110,7 +9183,7 @@ class InferenceEngine:
         if sec:
             evt("", tag + "pack", "E",
                 (rows, n_chunk, len(self._prefilling)))
-        self._mixed_account(a, rows, n_chunk,
+        self._mixed_account(a, rows, n_chunk, self._mixed_budget,
                             spec_t + self._mixed_budget - num_slots + 1,
                             tag)
         self._emit("spec_mixed", lp=want_lp, **a)
@@ -9130,6 +9203,7 @@ class InferenceEngine:
         else:
             (out_dev, counts_dev, comp_dev, self._cache, self._draft_cache,
              self._sampling) = self._spec_mixed_fn(*args)
+        self.step_clock.dispatched("spec", t0, time.monotonic(), rows)
         if sec:
             evt("", tag + "dispatch", "E",
                 "arks_spec_mixed_lp" if want_lp else "arks_spec_mixed")
@@ -9156,8 +9230,7 @@ class InferenceEngine:
         out = np.asarray(out_dev)        # [B, DK] — host sync point
         counts = np.asarray(counts_dev)  # [B]
         comp = np.asarray(comp_dev)      # [B]
-        self.metrics.decode_resolve_wait_seconds_total.inc(
-            time.monotonic() - t_wait, mode="sequential")
+        self.step_clock.waited(t_wait, time.monotonic(), 0)
         lp_host = None
         if lp_devs is not None:
             lp_host = (np.asarray(lp_devs[0]), np.asarray(lp_devs[1]),
@@ -9294,8 +9367,10 @@ class InferenceEngine:
         deferral is open the frame joins it instead and leaves with
         _flush_deferred."""
         if self._deferred is not None:
+            out.t_made = time.monotonic()
             self._deferred.append((req, out))
             return
+        out.t_put = time.monotonic()
         req.outputs.put(out)
         self.metrics.fanout_outputs_total.inc(1)
 
@@ -9328,6 +9403,7 @@ class InferenceEngine:
         if sec:
             self.trace.evt("", section, "B")
         for req, out in batch:
+            out.t_put = time.monotonic()
             req.outputs.put(out)
         if batch:
             self.metrics.fanout_outputs_total.inc(len(batch))

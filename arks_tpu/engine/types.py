@@ -140,3 +140,12 @@ class RequestOutput:
     # request asked for logprobs): each entry is
     # (chosen_logprob, [(token_id, logprob), ...top-N...]).
     logprobs: list | None = None
+    # The step clock's stamps (time.monotonic): when the engine put the
+    # frame on the request's queue, and, for a frame a saturated resolve
+    # held back for the next dispatch, when it was made.  The stream loop
+    # reads them; they are never sent, and two frames that differ only in
+    # them are the same frame.
+    t_put: float | None = dataclasses.field(
+        default=None, compare=False, repr=False)
+    t_made: float | None = dataclasses.field(
+        default=None, compare=False, repr=False)

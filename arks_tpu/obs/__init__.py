@@ -9,6 +9,8 @@ Submodules:
 - ``perfetto`` — Chrome trace-event (Perfetto-loadable) export.
 - ``profiler`` — ``jax.profiler`` windows (HTTP-armed or auto-armed on a
   step-time spike).
+- ``stepclock`` — the step loop's always-on clock: every dispatch's cycle
+  by leg and by kind, and the stall records.
 - ``logctx``   — contextvar-backed logging filter stamping
   ``request_id``/``trace_id`` into log records.
 """

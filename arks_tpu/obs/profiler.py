@@ -6,9 +6,11 @@ Armed two ways:
   the serving port — writes a profiler trace dir an operator can open in
   TensorBoard / Perfetto.
 - **Auto-arm**: when a step's wall time jumps past
-  ``ARKS_PROF_AUTO_ARM`` × the trailing median step time (default 0 =
-  off), a window of ``ARKS_PROF_WINDOW_S`` seconds opens by itself — the
-  profile of the anomaly, captured while it is still happening.
+  ``ARKS_PROF_AUTO_ARM`` × the trailing median of the step cycles (default
+  0 = off; the median is the step clock's, ``obs/stepclock.py``, the one
+  its stall rule reads), a window of ``ARKS_PROF_WINDOW_S`` seconds opens
+  by itself — the profile of the anomaly, captured while it is still
+  happening.
 
 While a window is active the engine run loop wraps each step in a
 ``jax.profiler.TraceAnnotation`` carrying the live request/trace ids, so
@@ -30,7 +32,6 @@ began to collect.
 
 from __future__ import annotations
 
-import collections
 import contextlib
 import logging
 import os
@@ -81,7 +82,6 @@ class ProfilerWindows:
         self.auto_armed_total = 0
         self._lock = threading.Lock()
         self._auto_end: float | None = None
-        self._steps: collections.deque = collections.deque(maxlen=128)
 
     def _anchor(self) -> None:
         import jax
@@ -139,23 +139,19 @@ class ProfilerWindows:
                         **out}
             return {"ok": True, **out}
 
-    def on_step(self, dur_s: float) -> None:
-        """Run-loop hook: feed one step's wall time.  Closes an expired
-        auto window; opens one when the step time spikes past
-        ``auto_mult`` × the trailing median."""
+    def on_step(self, dur_s: float, median_s: float | None = None) -> None:
+        """Run-loop hook: one step's wall time and the trailing median of
+        the step cycles (``StepClock.last_median``; None while it is not
+        warm, or for a step that only waited for a request).  Closes an
+        expired auto window; opens one when the step time spikes past
+        ``auto_mult`` × the median."""
         if self.active:
             if self._auto_end is not None and time.monotonic() > self._auto_end:
                 self.stop()
             return
-        if self.auto_mult <= 0:
+        if self.auto_mult <= 0 or not median_s:
             return
-        steps = self._steps
-        steps.append(dur_s)
-        if len(steps) < 32:
-            return
-        ordered = sorted(steps)
-        med = ordered[len(ordered) // 2]
-        if med > 0 and dur_s > self.auto_mult * med:
+        if dur_s > self.auto_mult * median_s:
             r = self.start()
             if r.get("ok"):
                 self._auto_end = time.monotonic() + self.window_s

@@ -48,11 +48,12 @@ _FLAG_NAMES = {
     "park.preempt": "preempted",
     "slo_violation": "slo_violation",
     "replay": "faulted",
+    "stall": "stalled",
 }
 
 # Engine-scope (rid-less) span names attached to overlapping request
 # traces; everything else engine-scope (phase.* markers) is export-only.
-_ATTACH_NAMES = ("pipe", "spill", "recover")
+_ATTACH_NAMES = ("pipe", "spill", "recover", "stall")
 
 # Events that end a request's timeline.  ``finish`` fires in
 # ``_finish``; ``quarantined`` requests fail outside the slot machinery
@@ -178,6 +179,15 @@ class Tracer:
         self._window: list | None = None
         self._thread: threading.Thread | None = None
         self._stopping = threading.Event()
+        # How late each timed wait of the collector returned: (when, late
+        # by), the last minute of them.  The only sleeper this process has
+        # that is due every flush_s whatever the engine does, so a late
+        # wake says every Python thread stood; a ``stall`` event is given
+        # the latest one inside it (_stall_span).  ``wake_hist``: the
+        # engine's ``host_wake_late_seconds`` histogram, one observation a
+        # flush.
+        self._wakes: collections.deque = collections.deque(maxlen=512)
+        self.wake_hist = None
 
     # ---- hot path -------------------------------------------------------
 
@@ -269,13 +279,30 @@ class Tracer:
             self.flush()
 
     def _loop(self) -> None:
+        due = time.monotonic() + self.flush_s
         while not self._stopping.wait(self.flush_s):
+            self._note_wake(time.monotonic(), due)
             try:
                 self.flush()
             except Exception as e:
                 # Keep the flusher thread alive, but a failed flush means
                 # trace loss — surface it.
                 swallowed("trace.flush", e, warn=True)
+            due = time.monotonic() + self.flush_s
+
+    def _note_wake(self, now: float, due: float) -> None:
+        late = max(now - due, 0.0)
+        self._wakes.append((now, late))
+        if self.wake_hist is not None:
+            self.wake_hist.observe(late)
+
+    def wake_late(self, t0: float, t1: float) -> float | None:
+        """The latest collector wake between ``t0`` and ``t1`` (and the
+        first one after: a wake that a stall delayed returns at its end or
+        just behind it); None where no wake was noted there."""
+        t1 += 2 * self.flush_s
+        return max((late for t, late in list(self._wakes) if t0 <= t <= t1),
+                   default=None)
 
     def flush(self) -> None:
         """Drain the rings and assemble every finished trace.  Safe from
@@ -335,6 +362,16 @@ class Tracer:
                 -1 if name.startswith("phase.") else 0)
             span = {"name": name, "start": t0, "end": t,
                     "arg": arg if arg is not None else a0}
+        elif name == "stall" and isinstance(arg, dict):
+            # The step clock's record of a stalled cycle, written at its
+            # end: the span covers the cycle, so that every request that
+            # lived through any of it overlaps it, and the record learns
+            # how late this collector woke inside it (the same dict is in
+            # the clock's list).
+            t0 = t - arg.get("seconds", 0.0)
+            if arg.get("wake_late_s") is None:
+                arg["wake_late_s"] = self.wake_late(t0, t)
+            span = {"name": name, "start": t0, "end": t, "arg": arg}
         else:
             span = {"name": name, "start": t, "end": t, "arg": arg}
         if self._window is not None:
@@ -391,6 +428,9 @@ class Tracer:
                     and sp["start"] <= t_hi:
                 spans.append({"component": "engine", **sp,
                               "arg": _plain(sp["arg"])})
+                flag = _FLAG_NAMES.get(sp["name"])
+                if flag:
+                    flags.add(flag)
         ctx: TraceCtx | None = meta.get("ctx")
         if ctx is not None:
             for up in ctx.upstream:
@@ -440,6 +480,8 @@ def _plain(arg):
         return arg
     if isinstance(arg, (list, tuple)):
         return [_plain(a) for a in arg]
+    if isinstance(arg, dict):
+        return {str(k): _plain(v) for k, v in arg.items()}
     return str(arg)
 
 

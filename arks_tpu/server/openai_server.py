@@ -45,6 +45,43 @@ log = logging.getLogger("arks_tpu.server")
 HDR_TIER = "x-arks-tier"
 
 
+class _StreamLag:
+    """One stream's lag behind the engine's door, kept by its handler
+    thread in plain attributes: no lock and no registry call a frame.
+    ``took`` reads the stamps of an output as it comes off the request's
+    queue, ``flushed`` the clock once the first frame made of it is on the
+    wire, and ``close`` makes the stream's ONE observation of each family:
+    its worst put-to-wire lag (what the handler threads and the GIL cost
+    this client) and, if a frame of it was deferred, its worst made-to-put
+    lag (what waiting for the next dispatch cost it)."""
+
+    __slots__ = ("put", "worst", "defer")
+
+    def __init__(self) -> None:
+        self.put: float | None = None
+        self.worst = 0.0
+        self.defer: float | None = None
+
+    def took(self, out) -> None:
+        self.put = out.t_put
+        if out.t_made is not None and out.t_put is not None:
+            held = out.t_put - out.t_made
+            if self.defer is None or held > self.defer:
+                self.defer = held
+
+    def flushed(self) -> None:
+        if self.put is not None:
+            lag = time.monotonic() - self.put
+            self.put = None
+            if lag > self.worst:
+                self.worst = lag
+
+    def close(self, metrics) -> None:
+        metrics.stream_deliver_lag_seconds.observe(self.worst)
+        if self.defer is not None:
+            metrics.stream_defer_lag_seconds.observe(self.defer)
+
+
 def _find_stop(text: str, stop_strings: list[str], min_end: int = 0
                ) -> int | None:
     """Earliest index at which any stop string begins, else None.
@@ -1131,11 +1168,14 @@ class OpenAIServer:
         h.send_header("Transfer-Encoding", "chunked")
         h.end_headers()
 
+        lag = _StreamLag()
+
         def send_frame(obj) -> None:
             data = b"data: " + (obj if isinstance(obj, bytes)
                                 else json.dumps(obj).encode()) + b"\n\n"
             h.wfile.write(f"{len(data):x}\r\n".encode() + data + b"\r\n")
             h.wfile.flush()
+            lag.flushed()
 
         rid = req.request_id
         created = int(time.time())
@@ -1167,6 +1207,7 @@ class OpenAIServer:
                 out = first_out if first_out is not None \
                     else req.outputs.get()
                 first_out = None  # _respond peeked the first output
+                lag.took(out)
                 prev_ntok = ntok
                 ntok += len(out.token_ids)
                 if stop_strings and prev_ntok < min_tok:
@@ -1245,6 +1286,8 @@ class OpenAIServer:
             h.wfile.flush()
         except (BrokenPipeError, ConnectionResetError):
             self.engine.abort(req.request_id)
+        finally:
+            lag.close(self.engine.metrics)
 
     def _stream_response(self, h, req: Request, chat: bool, model: str,
                          include_usage: bool, stop_strings: list[str],
@@ -1255,10 +1298,13 @@ class OpenAIServer:
         h.send_header("Transfer-Encoding", "chunked")
         h.end_headers()
 
+        lag = _StreamLag()
+
         def send_frame(obj) -> None:
             data = b"data: " + (obj if isinstance(obj, bytes) else json.dumps(obj).encode()) + b"\n\n"
             h.wfile.write(f"{len(data):x}\r\n".encode() + data + b"\r\n")
             h.wfile.flush()
+            lag.flushed()
 
         rid = req.request_id
         created = int(time.time())
@@ -1349,6 +1395,7 @@ class OpenAIServer:
                 out = first_out if first_out is not None \
                     else req.outputs.get()
                 first_out = None  # _respond peeked the first output
+                lag.took(out)
                 prev_ntok = ntok
                 ntok += len(out.token_ids)
                 if n_lp is not None:
@@ -1433,3 +1480,5 @@ class OpenAIServer:
             # Client went away: release the slot instead of decoding to
             # max_tokens for nobody.
             self.engine.abort(req.request_id)
+        finally:
+            lag.close(self.engine.metrics)

@@ -1,0 +1,6 @@
+"""``deliver_lag_p95_ms`` for the cells whose tail is not judged (it moves
+``output_tok_s`` there); the reading is the same reader's."""
+
+from benchmarks import manifest
+
+read = manifest.load_reader("deliver_lag_p95_ms")

@@ -84,3 +84,11 @@ from benchmarks.tests.test_reference_mla_moe import (  # noqa: E402,F401
     test_the_family_keeps_the_contract_and_imports_nothing_of_the_program,
     test_the_routing_margin_is_in_biased_score_units,
 )
+from benchmarks.tests.test_step_clock_readers import (  # noqa: E402,F401
+    test_a_cycle_mean_is_its_kinds_own,
+    test_a_parent_without_the_family_reads_none,
+    test_stalled_seconds_read_zero_in_a_sound_run_and_sum_every_where,
+    test_the_lag_percentile_is_read_off_the_bucket_deltas,
+    test_the_readers_read_what_the_programs_registry_renders,
+    test_the_starved_share_is_the_starved_leg_over_all_legs_of_all_kinds,
+)

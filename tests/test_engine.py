@@ -275,19 +275,25 @@ def test_resolved_config_surfaced(engine):
     assert "engine_config_info{" in text
     assert f'kv_layout="{rc["kv_layout"]}"' in text
     assert f'decode_impl="{rc["decode_impl"]}"' in text
-    # The pure device-wait counter rides every decode resolve (the
-    # overlap-mode-trustworthy signal).  Drive one
+    # The pure device wait rides every decode resolve (the
+    # overlap-mode-trustworthy signal; the step clock's ``wait`` leg since
+    # PR 38).  Drive one
     # tiny request HERE so a sample line exists even when this test runs
     # alone, then assert a non-comment line (comment lines start '# ').
+    # (A leg is written when its cycle closes, at the NEXT dispatch: a
+    # request of several dispatches.)
     req = Request("rc-cfg", [5, 6, 7], SamplingParams(
-        max_tokens=3, temperature=0.0, ignore_eos=True))
+        max_tokens=13, temperature=0.0, ignore_eos=True))
     engine.add_request(req)
     _drive(engine)
     text = engine.metrics.registry.render()
-    # Split by mode since the pipelined scheduler: either family proves
-    # the counter rides the resolves.
-    assert ('decode_resolve_wait_seconds_total{mode="sequential"}' in text
-            or 'decode_resolve_wait_seconds_total{mode="pipelined"}' in text)
+    # Split by the kind of dispatch waited for: whichever path this
+    # engine resolved to, its leg proves the clock rides the resolves.
+    waits = [ln for ln in text.splitlines()
+             if ln.startswith("step_leg_seconds_total{")
+             and 'leg="wait"' in ln]
+    assert waits and all(float(ln.rpartition(" ")[2]) > 0 for ln in waits)
+    assert "decode_resolve_wait_seconds_total" not in text
     assert f'pipeline_depth="{rc["pipeline_depth"]}"' in text
 
 

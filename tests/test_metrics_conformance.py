@@ -159,3 +159,32 @@ def test_fanout_counter_pair_renders_beside_each_other():
     assert rendered() == {"fanout_outputs_total": 5.0,
                           "fanout_deferred_outputs_total": 2.0}
 
+
+
+def test_step_clock_families_and_their_zero_reading():
+    """The step clock's families (PR 38): counters end in ``_total``, the
+    cycle and lag families are histograms, the resolve wait has no second
+    family, and both stall families read 0 for every ``where`` from the
+    first scrape (a sound run's ``step_stall_s`` is 0.0, not nothing)."""
+    from arks_tpu.engine.engine import EngineMetrics
+    from arks_tpu.obs import stepclock
+    m = EngineMetrics()
+    kinds = {fam.name: fam.type for fam in m.registry.families()}
+    assert {n: kinds[n] for n in kinds if n.startswith(
+        ("step_leg", "step_call", "step_cycle", "step_stall", "host_wake",
+         "stream_"))} == {
+        "step_leg_seconds_total": "counter",
+        "step_call_seconds_total": "counter",
+        "step_cycle_seconds": "histogram",
+        "step_stalls_total": "counter",
+        "step_stall_seconds_total": "counter",
+        "host_wake_late_seconds": "histogram",
+        "stream_deliver_lag_seconds": "histogram",
+        "stream_defer_lag_seconds": "histogram"}
+    assert "decode_resolve_wait_seconds_total" not in kinds
+    lines = m.registry.render().splitlines()
+    for family in ("step_stalls_total", "step_stall_seconds_total"):
+        assert sorted(ln for ln in lines if ln.startswith(family + "{")) \
+            == sorted(f'{family}{{where="{w}"}} 0' for w in stepclock.WHERE)
+    assert m.step_cycle_seconds.buckets[0] == 0.005
+    assert m.step_cycle_seconds.buckets[-1] == 2.0

@@ -442,12 +442,19 @@ def test_profiler_auto_arm_threshold(monkeypatch, tmp_path):
     monkeypatch.setenv("ARKS_PROF_DIR", str(tmp_path / "prof"))
     monkeypatch.setenv("ARKS_PROF_AUTO_ARM", "4.0")
     monkeypatch.setenv("ARKS_PROF_WINDOW_S", "0.05")
+    # The trailing median is the step clock's (PR 38): forty cycles of
+    # 10 ms on a made-up time line.
+    from arks_tpu.engine.engine import EngineMetrics
+    from arks_tpu.obs.stepclock import StepClock
+    clock = StepClock(EngineMetrics())
+    for i in range(42):
+        clock.dispatched("seq", i * 0.01, i * 0.01 + 0.001)
+    assert clock.last_median == pytest.approx(0.01)
     pw = prof_mod.ProfilerWindows()
-    for _ in range(40):
-        pw.on_step(0.01)         # steady trailing median
+    pw.on_step(0.01, clock.last_median)     # steady
     assert not pw.active
-    pw.on_step(0.2)              # 20x the median: arm a window
+    pw.on_step(0.2, clock.last_median)      # 20x the median: arm a window
     assert pw.active
     time.sleep(0.1)
-    pw.on_step(0.01)             # window elapsed: closes itself
+    pw.on_step(0.01, clock.last_median)     # window elapsed: closes itself
     assert not pw.active
