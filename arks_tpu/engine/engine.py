@@ -665,11 +665,10 @@ class EngineMetrics:
             "frames) the engine handed to its requests' readers")
         self.fanout_deferred_outputs_total = r.counter(
             "fanout_deferred_outputs_total",
-            "Output frames delivered behind the next dispatch because "
-            "more requests waited in the admission queue than slots were "
-            "free (over fanout_outputs_total: the share of frames a "
-            "saturated engine held back while it handed the device its "
-            "next step)")
+            "Output frames delivered behind the next dispatch because the "
+            "resolve that made them left nothing in flight on the device "
+            "(over fanout_outputs_total: the share of frames made with "
+            "the device empty)")
         # An untouched counter renders no sample: the pair stands on
         # /metrics from the first scrape, so that a share of 0 reads 0.
         self.fanout_outputs_total.inc(0)
@@ -844,7 +843,7 @@ class EngineMetrics:
         # STREAM, at its end, of its worst frame's time from the engine's
         # put (_deliver / _flush_deferred) to the socket flush; and, for a
         # stream that had a deferred frame, of its worst time from the
-        # frame's making to its put (what PR 30's deferral costs a client).
+        # frame's making to its put (what the deferral costs a client).
         self.stream_deliver_lag_seconds = r.histogram(
             "stream_deliver_lag_seconds",
             "A stream's worst lag from the engine's put of a frame to its "
@@ -1153,8 +1152,8 @@ class InferenceEngine:
         self._queue_seq = 0
         # Deferred delivery (_deliver / _flush_deferred): None while every
         # output goes straight to its reader; a list, in the order
-        # produced, from a resolve that found callers waiting for a slot
-        # until just after the next dispatch.
+        # produced, from a resolve that left nothing in flight on the
+        # device until just after the next dispatch.
         self._deferred: list | None = None
         self._queued_rids: set[str] = set()
         # Deadline-aware shedding (ARKS_SHED_DEADLINE): a popped request
@@ -3462,7 +3461,7 @@ class InferenceEngine:
             # call outlived stop()'s join window): no scheduler remains to
             # resolve deferred admissions, so fail their clients here ON
             # the engine thread — the only thread allowed to touch
-            # _pending_admits/_pending_n/_free.  What a saturated resolve
+            # _pending_admits/_pending_n/_free.  What the last resolve
             # held back for the next dispatch goes out first.
             self._flush_deferred()
             self._abort_pending_admits()
@@ -3526,7 +3525,7 @@ class InferenceEngine:
         """Top-level fault handler: attempt quarantine + token-replay
         recovery, escalating to the blanket abort-everything path only
         when recovery itself keeps faulting (crash-loop guard)."""
-        # What a saturated resolve held back was produced before the
+        # What the last resolve held back was produced before the
         # fault and counts as emitted: the clients get it before any
         # error frame or replayed token.
         self._flush_deferred()
@@ -4161,6 +4160,8 @@ class InferenceEngine:
             if self._slots and self._overlap:
                 pending = self._issue_decode()  # may retire/abort even if None
                 issued = True
+            # A legacy engine defers at its pipeline's last resolve only.
+            self._flush_deferred()
             t1 = time.monotonic()
             if issued:
                 self.metrics.scheduler_seconds_total.inc(t1 - t0, phase="decode")
@@ -5928,6 +5929,9 @@ class InferenceEngine:
         prefix and verifies byte-identity of the re-run."""
         req = rec.request
         rid = req.request_id
+        # What the last resolve held back counts as emitted (num_emitted):
+        # it reaches the client's queue before the gate takes its place.
+        self._flush_deferred()
         gate = req.outputs if isinstance(req.outputs, _ReplayGate) else None
         if gate is None:
             req.outputs = _ReplayGate(req.outputs, self, rid,
@@ -6431,6 +6435,7 @@ class InferenceEngine:
                     [pre], pre[0].params.logprobs is not None))
             worked = True
         if self._drained_for_switch() and not self._resize_active:
+            self._flush_deferred()   # the switch takes seconds
             self._switch_to(target)
             worked = True
         return worked
@@ -8101,9 +8106,9 @@ class InferenceEngine:
         device already finished."""
         if len(self._pipe_inflight) < self._pipe_depth:
             self._pipe_issue()
-        # A saturated sequential resolve may have left a deferral open:
-        # it leaves behind this step's issue (the resolves below run
-        # behind a dispatch anyway and never open one).
+        # The resolve before this step (a sequential one, or the last of
+        # a cold pipeline) left nothing in flight and its deferral open:
+        # it leaves behind this step's issue.
         self._flush_deferred("phase.decode.deliver")
         if len(self._pipe_inflight) >= self._pipe_depth:
             self._pipe_resolve_one()
@@ -8262,6 +8267,10 @@ class InferenceEngine:
         gen) snapshot guard."""
         (snapshot, want_lp, toks, lp_devs, K, t0,
          counts_dev) = self._pipe_inflight.popleft()
+        if not self._pipe_inflight:
+            # The drain's last resolve, or a cold pipeline's: the device
+            # runs dry behind it.  A steady depth-2 resolve puts at once.
+            self._defer_delivery()
         self._faults.fire("resolve")
         t_wait = time.monotonic()
         toks = np.asarray(toks)  # host sync point (async copy usually done)
@@ -9065,7 +9074,7 @@ class InferenceEngine:
         if sec:
             evt("", tag + "wait", "E")
             evt("", tag + "fanout", "B")
-        self._defer_if_saturated()
+        self._defer_delivery()
         n_live = len(self._slots)
         dt = max(time.monotonic() - t0 - exclude_s, 1e-6)
         for slot in dec_slots:
@@ -9238,7 +9247,7 @@ class InferenceEngine:
         if sec:
             evt("", tag + "wait", "E")
             evt("", tag + "fanout", "B")
-        self._defer_if_saturated()
+        self._defer_delivery()
         n_live = len(self._slots)
         dt = max(time.monotonic() - t0 - exclude_s, 1e-6)
         n_spec = accepted = 0
@@ -9374,15 +9383,16 @@ class InferenceEngine:
         req.outputs.put(out)
         self.metrics.fanout_outputs_total.inc(1)
 
-    def _defer_if_saturated(self) -> None:
-        """Open a deferral iff more requests wait in the admission queue
-        than slots are free: _admit pops while a slot is free, so callers
-        are then waiting for a SLOT, every millisecond the device idles is
-        queue time for one of them, and the next step's batch needs this
-        step's token VALUES, not their delivery.  Each put wakes a reader
-        thread that wants the GIL (docs/monitoring.md, ``deliver``); with
-        nobody waiting the streams go first."""
-        if self._deferred is None and self._queue.qsize() > len(self._free):
+    def _defer_delivery(self) -> None:
+        """Open a deferral: the resolve that calls this leaves nothing in
+        flight on the device (a sequential resolve never has a dispatch
+        behind it; a pipelined one calls it when it popped the last), so
+        every millisecond until the next dispatch is one the device
+        idles, and that dispatch needs this step's token VALUES, not
+        their delivery.  Each put wakes a reader thread that wants the
+        GIL (docs/monitoring.md, ``deliver``): the frames wait for the
+        dispatch and leave behind it."""
+        if self._deferred is None:
             self._deferred = []
 
     def _flush_deferred(self, section: str = "phase.step.deliver") -> None:
@@ -9392,9 +9402,10 @@ class InferenceEngine:
         engine thread's next blocking call, the wait, gives the readers
         the GIL), and before anything that keeps the engine thread from
         that dispatch: a step that issues nothing, the residency forward,
-        a resize at its drained boundary, recovery, the loop's exit.  (An
-        open deferral means a non-empty queue, so the engine is neither
-        idle nor drained for a model switch or a scale to zero.)"""
+        a resize or a model switch at its drained boundary, a replay gate
+        taking a victim's queue (preemption, recovery), the loop's exit.
+        (``idle`` is false over an open deferral, so the engine neither
+        sleeps on its queue nor scales to zero over one.)"""
         batch = self._deferred
         if batch is None:
             return

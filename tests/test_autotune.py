@@ -188,6 +188,7 @@ def test_engine_sweep_mode_tunes_mixed_kernel(monkeypatch):
         for _ in range(400):
             eng.step(block_s=0.01)
             if (eng.num_running == 0 and eng._queue.empty()
+                    and eng._deferred is None
                     and not eng._prefilling):
                 break
         ids = []

@@ -65,6 +65,7 @@ def _drive(eng, n_steps=1500):
         except Exception as e:  # noqa: BLE001 — routed exactly like _run_loop
             eng._recover_from_fault(e)
         if (eng.num_running == 0 and eng._queue.empty()
+                and eng._deferred is None
                 and not eng._prefilling and not eng._awaiting_fetch
                 and not eng._awaiting_restore and eng.state == "serving"):
             break
@@ -392,6 +393,7 @@ def test_engine_state_gauge_and_readiness_mapping(monkeypatch):
             eng._recover_from_fault(e)
             states.add(eng.state)
         if (eng.num_running == 0 and eng._queue.empty()
+                and eng._deferred is None
                 and not eng._prefilling and eng.state == "serving"):
             break
     _collect(r)
@@ -801,6 +803,7 @@ def _drive_elastic(eng, n_steps=3000):
         if (eng._resize_req is None and not eng._swapped
                 and not eng._swap_pending and not eng._spills
                 and eng.num_running == 0 and eng._queue.empty()
+                and eng._deferred is None
                 and not eng._prefilling and not eng._awaiting_fetch
                 and not eng._awaiting_restore and eng.state == "serving"):
             break

@@ -58,6 +58,7 @@ def test_mixed_workload_compile_variant_budget(monkeypatch):
     for _ in range(600):
         eng.step(block_s=0.01)
         if (eng.num_running == 0 and eng._queue.empty()
+                and eng._deferred is None
                 and not eng._prefilling):
             break
     for r in reqs:
@@ -119,6 +120,7 @@ def test_spec_workload_compile_variant_budget(monkeypatch):
     for _ in range(600):
         eng.step(block_s=0.01)
         if (eng.num_running == 0 and eng._queue.empty()
+                and eng._deferred is None
                 and not eng._prefilling):
             break
     for r in reqs:
@@ -183,6 +185,7 @@ def test_ragged_kernel_family_budget_with_tuned_cache(monkeypatch, tmp_path):
     for _ in range(600):
         eng.step(block_s=0.01)
         if (eng.num_running == 0 and eng._queue.empty()
+                and eng._deferred is None
                 and not eng._prefilling):
             break
     for r in reqs:

@@ -52,6 +52,7 @@ def _drive(eng, n_steps=2000):
         except Exception as e:  # noqa: BLE001 — routed like _run_loop
             eng._recover_from_fault(e)
         if (eng.num_running == 0 and eng._queue.empty()
+                and eng._deferred is None
                 and not eng._prefilling and not eng._awaiting_fetch
                 and not eng._awaiting_restore and eng.state == "serving"):
             break

@@ -93,7 +93,8 @@ def test_scale_to_zero_and_rearm_on_demand(monkeypatch):
     base_eng.add_request(r0)
     for _ in range(200):
         base_eng.step(block_s=0.01)
-        if base_eng.num_running == 0 and base_eng._queue.empty():
+        if (base_eng.num_running == 0 and base_eng._queue.empty()
+                and base_eng._deferred is None):
             break
     base = _collect(r0)
 
@@ -116,6 +117,7 @@ def test_scale_to_zero_and_rearm_on_demand(monkeypatch):
     for _ in range(400):
         eng.step(block_s=0.01)
         if (eng.armed and eng.num_running == 0 and eng._queue.empty()
+                and eng._deferred is None
                 and not eng._prefilling):
             break
     assert eng.armed, "demand did not re-arm the engine"

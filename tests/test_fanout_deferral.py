@@ -1,11 +1,12 @@
-"""Deferred delivery under saturation (PR 30).
+"""Deferred delivery (PR 30; since PR 39 by what is in flight).
 
-A resolve that finds more requests in the admission queue than slots free
-keeps its frames back (``engine._deferred``) and hands them to their
-readers right after the NEXT dispatch (``phase.<phase>.deliver``); with
-nobody waiting for a slot every frame is put inside the resolve, as
-before.  What the next batch needs (the token appended, the mirrors, the
-stop check, the slot freed) never waits.
+A resolve that leaves NOTHING in flight on the device (a sequential one
+always; a pipelined one that popped the last dispatch) keeps its frames
+back (``engine._deferred``) and hands them to their readers right after
+the NEXT dispatch (``phase.<phase>.deliver``), or in the next ``step()``
+if that issues nothing; a resolve with a newer dispatch in flight behind
+it puts every frame at once.  What the next batch needs (the token
+appended, the mirrors, the stop check, the slot freed) never waits.
 
 One engine a (depth, speculative) pair serves all cases of that pair:
 
@@ -15,15 +16,19 @@ One engine a (depth, speculative) pair serves all cases of that pair:
   once and last; an abort raised while a deferral is open yields the
   deferred tokens, then the abort frame;
 - (c) inside a profiler window a ``deliver`` span begins after the
-  dispatch that follows the resolve it belongs to has ended, and an
-  engine with callers <= slots defers nothing;
+  dispatch that follows the resolve it belongs to has ended, with
+  callers within the slots as with a burst; the drain's last resolve
+  defers and a steady depth-2 resolve does not; a request that ends
+  alone is delivered by the next ``step()``; a first token's frame is
+  put once;
 - (d) the engine never blocks on its queue nor goes idle over a deferral,
   and a step that raises into recovery delivers what was deferred first;
 - (e) ``fanout_outputs_total`` counts the frames the clients received,
-  ``fanout_deferred_outputs_total`` those that left behind a dispatch.
+  ``fanout_deferred_outputs_total`` those made with the device empty.
 """
 
 import queue
+import types
 
 import pytest
 
@@ -97,12 +102,13 @@ def _quiet(eng) -> bool:
             and not eng._prefilling)
 
 
-def _drive(eng, n_steps=3000, each=None):
+def _drive(eng, n_steps=3000, until=None):
+    """Step until the engine is idle (no deferral is open then), or until
+    ``until(eng)``."""
+    until = until or (lambda e: e.idle)
     for _ in range(n_steps):
         eng.step(block_s=0.01)
-        if each is not None:
-            each()
-        if _quiet(eng):
+        if until(eng):
             return
     raise AssertionError("engine did not drain")
 
@@ -136,17 +142,66 @@ def _counts(eng):
             m.fanout_deferred_outputs_total.get())
 
 
+def _observed(hist):
+    return sum(n for _, _, n in hist._data.values())
+
+
+def _dispatches(eng):
+    """Sequential and pipelined dispatches issued so far."""
+    return (_observed(eng.metrics.mixed_batch_tokens),
+            _observed(eng.metrics.pipeline_depth_occupancy))
+
+
 def _solo(eng, reqs):
     out = []
     for r in reqs:
         eng.add_request(r)
-        _drive(eng, each=lambda: _assert_closed(eng))
+        _drive(eng)
         out.append(_stream(_frames(r)))
     return out
 
 
-def _assert_closed(eng):
-    assert eng._deferred is None
+def _drain_now(req):
+    """The frames in the request's queue NOW, with no further step."""
+    frames = []
+    while True:
+        try:
+            frames.append(_reader(req).get_nowait())
+        except queue.Empty:
+            return frames
+
+
+def _watch_resolves(eng, monkeypatch):
+    """Every resolve by its kind, in order: ``seq`` (a sequential one),
+    ``last`` (a pipelined one that left nothing in flight), ``steady``
+    (one with a dispatch behind it); and every frame through ``_deliver``
+    as (the kind of the resolve that made it, ``outside`` for none; the
+    frame; whether the deferral was open).  Returns (resolves, frames)."""
+    resolves, seen, where = [], [], ["outside"]
+
+    def wrap(name, kind):
+        real = getattr(eng, name)
+
+        def inner(self, *a, **kw):
+            where.append(kind(self))
+            resolves.append(where[-1])
+            try:
+                return real(*a, **kw)
+            finally:
+                where.pop()
+        monkeypatch.setattr(eng, name, types.MethodType(inner, eng))
+
+    wrap("_resolve_mixed", lambda e: "seq")
+    wrap("_resolve_spec_mixed", lambda e: "seq")
+    wrap("_pipe_resolve_body",
+         lambda e: "steady" if len(e._pipe_inflight) > 1 else "last")
+    real = eng._deliver
+
+    def deliver(self, req, out):
+        real(req, out)
+        seen.append((where[-1], out, self._deferred is not None))
+    monkeypatch.setattr(eng, "_deliver", types.MethodType(deliver, eng))
+    return resolves, seen
 
 
 # --------------------------------------------- (a), (b), (e): the streams
@@ -158,8 +213,8 @@ def test_a_burst_streams_what_its_requests_stream_alone(served):
     stop_tok = first[0][0][2]
     solo = first + _solo(eng, _requests(cfg, "solo2", stop_tok)[6:])
     all1, def1 = _counts(eng)
-    # callers <= slots: every frame was put inside its resolve
-    assert def1 == def0 and all1 > all0
+    # one caller on two slots: its sequential resolves defer all the same
+    assert 0 < def1 - def0 <= all1 - all0
     assert solo[6] == (first[0][0][:2], "stop")
 
     reqs = _requests(cfg, "burst", stop_tok)
@@ -235,13 +290,27 @@ def test_deliver_runs_behind_the_next_dispatch(served, tmp_path):
     delivers = [i for i, s in enumerate(secs)
                 if s["name"].endswith(".deliver")]
     assert tag + "deliver" in {secs[i]["name"] for i in delivers}
+    _assert_behind_the_next_dispatch(secs, delivers, tag)
+    _, def1 = _counts(eng)
+    assert sum(secs[i]["arg"] for i in delivers) == def1 - def0 > 0
+
+
+def _holds_back(s, tag):
+    """A section in which a resolve leaves nothing in flight: a
+    sequential fan-out, or a pipelined resolve with none behind it (its
+    arg: the dispatches still in flight)."""
+    return (s["name"] == tag + "fanout"
+            or (s["name"] == "phase.decode.resolve" and s["arg"] == 0))
+
+
+def _assert_behind_the_next_dispatch(secs, delivers, tag):
     for i in delivers:
         d, before = secs[i], secs[i - 1]
         assert before["end"] <= d["start"]
         if d["name"] == "phase.step.deliver":
             # a step with nothing to issue (every stream finished in the
             # resolve that held the frames back) delivers without one
-            assert before["name"] == tag + "fanout"
+            assert _holds_back(before, tag), before
             continue
         # the section just before a delivery is the dispatch it hides
         # behind: the sequential step's, or a pipelined issue ...
@@ -249,39 +318,112 @@ def test_deliver_runs_behind_the_next_dispatch(served, tmp_path):
             tag + "deliver": tag + "dispatch",
             "phase.decode.deliver": "phase.decode.issue"}[d["name"]]
         # ... and before that dispatch came the resolve that held the
-        # frames back (its fan-out), with no other delivery in between
-        held = [s["name"] for s in secs[:i - 1]
-                if s["name"] == tag + "fanout"
-                or s["name"].endswith(".deliver")]
-        assert held and held[-1] == tag + "fanout"
-    _, def1 = _counts(eng)
-    assert sum(secs[i]["arg"] for i in delivers) == def1 - def0 > 0
+        # frames back, with no other delivery in between
+        held = [s for s in secs[:i - 1]
+                if _holds_back(s, tag) or s["name"].endswith(".deliver")]
+        assert held and _holds_back(held[-1], tag), held[-1:]
 
 
-def test_an_engine_with_callers_within_its_slots_defers_nothing(served,
-                                                                tmp_path):
-    cfg, eng, _ = served
+def test_callers_within_the_slots_defer_a_sequential_resolves_frames(
+        served, tmp_path, monkeypatch):
+    """... and they are delivered behind the next dispatch."""
+    cfg, eng, spec = served
+    tag = "phase.spec." if spec else "phase.mixed."
+    resolves, seen = _watch_resolves(eng, monkeypatch)
     all0, def0 = _counts(eng)
     assert eng.profiler.start(str(tmp_path / "q"))["ok"]
     try:
-        reqs = _requests(cfg, "fit")[:SLOTS]
+        reqs = _requests(cfg, "fit", shift=53)[:SLOTS]
         for r in reqs:
             eng.add_request(r)
-
-        def each():
-            # delivered inside the resolve: when step() returns, every
-            # token the engine has generated is in its reader's queue
-            _assert_closed(eng)
-            for st in eng._slots.values():
-                assert st.num_emitted == len(st.generated)
-
-        _drive(eng, each=each)
+        _drive(eng)
     finally:
         spans = eng.profiler.stop()["spans"]
     n = sum(len(_frames(r)) for r in reqs)
-    assert not [s for s in spans if s["name"].endswith(".deliver")]
     all1, def1 = _counts(eng)
-    assert def1 == def0 and all1 - all0 == n
+    assert all1 - all0 == n == len(seen)
+    # the frames of the resolves that left the device empty, and no other
+    made_empty = [o for kind, o, _ in seen if kind in ("seq", "last")]
+    assert [o for _, o, held in seen if held] == made_empty
+    assert def1 - def0 == len(made_empty) > 0
+    assert "seq" in resolves
+    if not eng._pipe_depth:
+        assert def1 - def0 == n
+    secs = _sections(spans)
+    delivers = [i for i, s in enumerate(secs)
+                if s["name"].endswith(".deliver")]
+    assert tag + "deliver" in {secs[i]["name"] for i in delivers}
+    _assert_behind_the_next_dispatch(secs, delivers, tag)
+    assert sum(secs[i]["arg"] for i in delivers) == def1 - def0
+
+
+def test_the_drains_last_resolve_defers_and_a_steady_one_does_not(
+        served, monkeypatch):
+    cfg, eng, _ = served
+    resolves, seen = _watch_resolves(eng, monkeypatch)
+    reqs = [_requests(cfg, "pipe", shift=67)[i] for i in (0, 4)]
+    for r in reqs:
+        eng.add_request(r)
+    # idle, and the overshoot dispatch behind the last stream resolved too
+    _drive(eng, until=lambda e: e.idle and not e._pipe_inflight)
+    for r in reqs:
+        assert _stream(_frames(r))[1] == "length"
+    if eng._pipe_depth < 2:
+        assert set(resolves) == {"seq"}
+    else:
+        assert {"seq", "steady", "last"} <= set(resolves)
+    # a frame is held back iff its resolve left the device empty; a steady
+    # resolve's frames (one dispatch still in flight) were put at once
+    assert not [kind for kind, _, held in seen
+                if held != (kind in ("seq", "last"))]
+    steady = [o for kind, o, _ in seen if kind == "steady"]
+    assert bool(steady) == (eng._pipe_depth >= 2)
+    assert all(o.t_made is None and o.t_put is not None for o in steady)
+
+
+def test_a_request_that_ends_alone_is_delivered_by_the_next_step(served):
+    cfg, eng, _ = served
+    req = _requests(cfg, "alone", shift=83)[0]
+    eng.add_request(req)
+    _drive(eng, until=_quiet)
+    # its last resolve is behind it; what that held back is not lost and
+    # the engine is not idle over it
+    got = _drain_now(req)
+    held = [o for r, o in eng._deferred or [] if r is req]
+    assert (got + held)[-1].finished and (held or eng.idle)
+    assert eng.idle == (eng._deferred is None)
+    n0 = _dispatches(eng)
+    eng.step(block_s=0.01)
+    # one step() that issues nothing delivers it
+    assert _dispatches(eng) == n0
+    got += _drain_now(req)
+    assert got[len(got) - len(held):] == held
+    assert _stream(got)[1] == "length"
+    assert eng._deferred is None and eng.idle
+
+
+def test_a_first_tokens_frame_is_put_exactly_once(served):
+    cfg, eng, _ = served
+    n0 = _observed(eng.metrics.time_to_first_token_seconds)
+    _, def0 = _counts(eng)
+    reqs = _requests(cfg, "ttft", shift=97)
+    for r in reqs:
+        eng.add_request(r)
+    _drive(eng)
+    assert _observed(eng.metrics.time_to_first_token_seconds) - n0 == len(
+        reqs)
+    for r in reqs:
+        frames = _drain_now(r)
+        assert _stream(frames)[1] == "length"
+        firsts = [f for f in frames if f.ttft_s is not None]
+        # made in a sequential resolve (a prompt completes in one), kept
+        # for the next dispatch, and put once
+        assert len(firsts) == 1 and firsts[0] is frames[0]
+        assert firsts[0].t_made is not None
+        assert firsts[0].t_made <= firsts[0].t_put
+        assert len({id(f) for f in frames}) == len(frames)
+        assert len(frames[0].token_ids) == 1
+    assert _counts(eng)[1] - def0 >= len(reqs)
 
 
 # ------------------------------------- (d): never slept on, never lost
@@ -363,7 +505,7 @@ def test_a_step_that_raises_delivers_what_was_deferred_first(served):
             eng.step(block_s=0.01)
         except Exception as e:  # noqa: BLE001 — routed as _run_loop does
             eng._recover_from_fault(e)
-        if _quiet(eng) and eng.state == "serving":
+        if eng.idle and eng.state == "serving":
             break
     for r in reqs:
         assert _stream(_frames(r))[1] in ("length", "error")

@@ -56,6 +56,7 @@ def _drive(engine, n_steps=500):
     for _ in range(n_steps):
         engine.step(block_s=0.01)
         if (engine.num_running == 0 and engine._queue.empty()
+                and engine._deferred is None
                 and not engine._prefilling):
             break
 

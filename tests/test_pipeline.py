@@ -102,7 +102,8 @@ def test_serving_engine_with_pipeline_parallelism():
             eng.add_request(r)
         for _ in range(100):
             eng.step(block_s=0.01)
-            if eng.num_running == 0 and eng._queue.empty():
+            if (eng.num_running == 0 and eng._queue.empty()
+                    and eng._deferred is None):
                 break
         outs = []
         for r in reqs:

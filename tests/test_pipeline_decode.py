@@ -53,6 +53,7 @@ def _drive(engine, n_steps=800):
     for _ in range(n_steps):
         engine.step(block_s=0.01)
         if (engine.num_running == 0 and engine._queue.empty()
+                and engine._deferred is None
                 and not engine._prefilling):
             break
 
@@ -160,7 +161,8 @@ def test_pipeline_one_dispatch_per_iteration_and_depth_bound(monkeypatch):
         eng.step(block_s=0.01)
         after = sum(1 for op, _ in eng.dispatcher.ops if op == "decode_pipe")
         per_step.append(after - before)
-        if eng.num_running == 0 and eng._queue.empty():
+        if (eng.num_running == 0 and eng._queue.empty()
+                and eng._deferred is None):
             break
     _collect(r)
     pipe_ops = [p for op, p in eng.dispatcher.ops if op == "decode_pipe"]

@@ -108,7 +108,8 @@ def test_engine_weight_dtype_int8():
     eng.add_request(req)
     for _ in range(50):
         eng.step(block_s=0.01)
-        if eng.num_running == 0 and eng._queue.empty():
+        if (eng.num_running == 0 and eng._queue.empty()
+                and eng._deferred is None):
             break
     out, ids = None, []
     while out is None or not out.finished:
@@ -214,7 +215,8 @@ def test_engine_weight_dtype_int4():
     eng.add_request(req)
     for _ in range(80):
         eng.step(block_s=0.01)
-        if eng.num_running == 0 and eng._queue.empty():
+        if (eng.num_running == 0 and eng._queue.empty()
+                and eng._deferred is None):
             break
     out, ids = None, []
     while out is None or not out.finished:

@@ -47,6 +47,7 @@ def _drive(eng, n_steps=3000):
     for _ in range(n_steps):
         eng.step(block_s=0.01)
         if (eng.num_running == 0 and eng._queue.empty()
+                and eng._deferred is None
                 and not eng._prefilling):
             break
 

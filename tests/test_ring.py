@@ -91,7 +91,8 @@ def test_serving_engine_with_context_parallelism():
         eng.add_request(req)
         for _ in range(100):
             eng.step(block_s=0.01)
-            if eng.num_running == 0 and eng._queue.empty() and not eng._prefilling:
+            if (eng.num_running == 0 and eng._queue.empty()
+                    and eng._deferred is None and not eng._prefilling):
                 break
         ids = []
         while True:
@@ -198,7 +199,8 @@ def test_cp_extends_one_shot_window_for_long_prompts():
     for _ in range(100):
         eng.step(block_s=0.01)
         assert not eng._prefilling
-        if eng.num_running == 0 and eng._queue.empty():
+        if (eng.num_running == 0 and eng._queue.empty()
+                and eng._deferred is None):
             break
     ids = []
     while True:

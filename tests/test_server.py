@@ -175,7 +175,8 @@ def test_small_max_model_len_no_crash():
     eng.add_request(req)
     for _ in range(50):
         eng.step(block_s=0.01)
-        if eng.num_running == 0 and eng._queue.empty():
+        if (eng.num_running == 0 and eng._queue.empty()
+                and eng._deferred is None):
             break
     outs = []
     while True:

@@ -25,6 +25,7 @@ def _drive(engine, n_steps=600):
     for _ in range(n_steps):
         engine.step(block_s=0.01)
         if (engine.num_running == 0 and engine._queue.empty()
+                and engine._deferred is None
                 and not engine._prefilling
                 and not engine._awaiting_guide):
             break

@@ -381,6 +381,7 @@ def _serve(eng, tag, n=5, max_tokens=12):
     for _ in range(3000):
         eng.step(block_s=0.01)
         if (eng.num_running == 0 and eng._queue.empty()
+                and eng._deferred is None
                 and not eng._prefilling and eng.step_clock._kind is None):
             break
     out = {}
@@ -482,14 +483,18 @@ def test_the_budget_counter_adds_the_budget_of_the_shape_the_step_took(
     assert tails - 1 <= _cycles(m, "seq_tail")[0] <= tails
 
 
-def test_a_deferred_frame_keeps_when_it_was_made(monkeypatch):
-    """Four callers on two slots: a saturated resolve holds its frames for
-    the next dispatch; each keeps when it was made and when it was put."""
-    eng = _engine(monkeypatch, 0)
+@pytest.mark.parametrize("depth", [0, 2])
+def test_a_deferred_frame_keeps_when_it_was_made(monkeypatch, depth):
+    """A resolve that leaves nothing in flight holds its frames for the
+    next dispatch; each keeps when it was made and when it was put.  At
+    depth 0 that is every resolve; at depth 2 a steady resolve's frames
+    go straight out and carry no making time."""
+    eng = _engine(monkeypatch, depth)
     got = _serve(eng, "d", n=6)
     frames = [o for f in got.values() for o in f]
     held = [o for o in frames if o.t_made is not None]
-    assert held and len(held) < len(frames)
+    assert held and (len(held) < len(frames) if depth
+                     else len(held) == len(frames))
     assert all(o.t_made <= o.t_put for o in held)
     assert eng.metrics.fanout_deferred_outputs_total.get() == len(held)
 
