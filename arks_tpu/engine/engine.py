@@ -764,7 +764,7 @@ class EngineMetrics:
             "kv_page_bytes",
             "Bytes of the full-attention layers' pages held by slots or "
             "the prefix index (a model with linear-attention layers: its "
-            "GQA layers')")
+            "GQA or latent layers')")
         self.linear_state_starts_total = r.counter(
             "linear_state_starts_total",
             "Sequences that took a slot at position 0, so that the step "
@@ -1419,7 +1419,7 @@ class InferenceEngine:
         tokenizer = self.tokenizer
         self.cfg = cfg
         self.ecfg = engine_cfg
-        if cfg.latent:
+        if cfg.latent and not cfg.linear:
             self._latent_preflight(cfg, engine_cfg, draft_cfg)
         if cfg.windowed:
             self._windowed_preflight(cfg, engine_cfg, draft_cfg)
@@ -1685,7 +1685,8 @@ class InferenceEngine:
             if self._lin_slot_bytes:
                 log.info("linear layers: %d bytes of %s state a slot over "
                          "%d layers (%d slots, %.2f GB, whatever the "
-                         "context); the pool above holds the %d GQA layers",
+                         "context); the pool above holds the %d other "
+                         "layers",
                          self._lin_slot_bytes, self._cache.lin.s.dtype,
                          cfg.num_linear_layers, engine_cfg.num_slots,
                          engine_cfg.num_slots * self._lin_slot_bytes / 1e9,
@@ -2056,7 +2057,10 @@ class InferenceEngine:
             # behind the window.
             # "kv+state": the GQA layers' pages beside a fixed recurrent
             # state a slot (linear-attention layers).
-            "kv_page": ("latent" if cfg.latent else
+            # "latent+state": the latent layers' pages beside the linear
+            # layers' state.
+            "kv_page": ("latent+state" if cfg.latent and cfg.linear else
+                        "latent" if cfg.latent else
                         "kv+window" if cfg.windowed else
                         "kv+state" if cfg.linear else "kv"),
             # The dtype the delta rule's state IS kept in, read off the
@@ -8702,7 +8706,8 @@ class InferenceEngine:
         host."""
         held, rows = int(ids[-2]), int(ids[-1])
         cfg = self.cfg
-        self.metrics.mixed_latent_rows_total.inc(rows * cfg.num_layers)
+        self.metrics.mixed_latent_rows_total.inc(
+            rows * (cfg.num_full_layers if cfg.latent else cfg.num_layers))
         self.metrics.moe_routed_pairs_total.inc(
             rows * cfg.num_experts_per_tok * cfg.num_routed_layers)
         self.metrics.moe_held_pairs_total.inc(held)
@@ -8782,21 +8787,40 @@ class InferenceEngine:
         --prefix-cache-mb says: a hit would need the state AT the prefix's
         end, which nobody kept (_register_prompt_pages).  Token replay
         after a fault stays: it re-prefills from position 0, which
-        rebuilds the state."""
+        rebuilds the state.  Where the other layers are LATENT layers
+        (``cfg.latent``: a bf16 latent pool beside the state) this is the
+        model's one preflight, and it says one thing: the movers speak K
+        and V blocks and carry neither a latent row nor a state."""
+        if cfg.latent and ecfg.kv_cache_dtype == "auto":
+            ecfg.kv_cache_dtype = "bf16"
         if ecfg.kv_layout == "auto":
             ecfg.kv_layout = "paged"
         why = []
+        if cfg.latent and ecfg.kv_cache_dtype != "bf16":
+            why.append(f"kv_cache_dtype={ecfg.kv_cache_dtype} (a latent "
+                       "page is bf16 only: an int8 / int4 latent row is "
+                       "not built)")
         if ecfg.kv_layout != "paged":
             why.append(f"kv_layout={ecfg.kv_layout} (the slot layout keeps "
                        "K and V of every layer)")
-        why += self._kv_mover_refusals(
-            ecfg, draft_cfg, "every layer's page and no recurrent state",
-            "linear-attention layers and their state have no sharding "
-            "rules")
+        if cfg.latent:
+            beside, moves, mesh_why = (
+                "latent-attention layers over one latent row a token",
+                "K and V blocks, and neither a latent row nor a recurrent "
+                "state",
+                "neither the latent block nor the linear layers' state has "
+                "sharding rules")
+        else:
+            beside, moves, mesh_why = (
+                "GQA layers over pages",
+                "every layer's page and no recurrent state",
+                "linear-attention layers and their state have no sharding "
+                "rules")
+        why += self._kv_mover_refusals(ecfg, draft_cfg, moves, mesh_why)
         if why:
             raise ValueError(
                 f"model {cfg.name!r} (linear-attention layers with a fixed "
-                "state a slot beside GQA layers over pages) cannot be "
+                f"state a slot beside {beside}) cannot be "
                 "served with: " + "; ".join(why))
         if ecfg.prefix_cache_mb:
             log.info("model %s: the device prefix index is off (a matched "

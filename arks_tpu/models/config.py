@@ -126,6 +126,34 @@ class ModelConfig:
     linear_neg_eigval: bool = False
     use_rope: bool = True
     attn_out_gate: bool = False
+    # Gated-delta-rule linear layers beside LATENT attention layers in one
+    # model (``gigachat3_5``: ``linear_period`` > 0 AND ``kv_lora_rank`` >
+    # 0).  The ``first_k_dense`` leading layers are LINEAR layers with a
+    # dense FFN; behind them the layers come in periods of ``linear_period``
+    # linear layers and one latent layer, ahead of which a first period cut
+    # short by the dense prefix has ``short_period`` linear layers and its
+    # latent layer (-1: none; GigaChat3.5-432B: 3 dense + (0 + 1) + 9 x (3 +
+    # 1) = 40).  ``linear_head_decay``: the Gated DeltaNet form of the
+    # linear layer (arXiv:2412.06464): ``linear_key_heads`` key (and query)
+    # heads, each shared by ``linear_num_heads / linear_key_heads`` value
+    # heads (0: as many as value heads), ONE log decay a head a token
+    # (``-exp(A_log) softplus(x W_a + dt_bias)``), the output gate
+    # ``linear_gate_scale sigmoid(x W_z)`` from a full projection, the
+    # per-head output norm at ``linear_norm_eps`` (0: ``rms_norm_eps``).
+    # ``attn_out_gate`` on such a model gates the latent block's H x v
+    # outputs.  ``norm_gate`` g > 0: every norm of the model but that
+    # per-head one is ``x / rms(x) * g sigmoid(w)`` (1 at w = 0).
+    # ``norm_post``: sandwich norms, ``h + N(Mixer(N(h)))`` and ``h +
+    # N(FFN(N(h)))``, four norms a layer.  ``swiglu_limit`` c > 0: every
+    # SwiGLU is ``SiLU(min(gate, c)) * clip(up, -c, c)``.
+    short_period: int = -1
+    linear_head_decay: bool = False
+    linear_key_heads: int = 0
+    linear_gate_scale: float = 1.0
+    linear_norm_eps: float = 0.0
+    norm_gate: float = 0.0
+    norm_post: bool = False
+    swiglu_limit: float = 0.0
 
     @property
     def q_dim(self) -> int:
@@ -141,14 +169,38 @@ class ModelConfig:
 
     @property
     def linear_dim(self) -> int:
-        """Width of a linear layer's queries (keys, values) over its heads."""
+        """Width of a linear layer's values (and, where the key heads are
+        not fewer, its queries and keys) over its heads."""
         return self.linear_num_heads * self.linear_head_dim
 
     @property
+    def linear_key_dim(self) -> int:
+        """Width of a linear layer's keys (queries) over their heads."""
+        return (self.linear_key_heads or self.linear_num_heads) \
+            * self.linear_head_dim
+
+    @property
+    def linear_conv_dim(self) -> int:
+        """Channels of a linear layer's short convolution: q | k | v."""
+        return 2 * self.linear_key_dim + self.linear_dim
+
+    @property
+    def linear_head(self) -> bool:
+        """The layers ahead of the first period are LINEAR layers (the
+        dense prefix of a model with linear and latent layers)."""
+        return self.linear and self.latent
+
+    @property
     def head_layers(self) -> int:
-        """Full-attention layers ahead of the first period: the dense
-        prefix, or layer 0 of a model with linear layers."""
-        return 1 if self.linear else self.first_k_dense
+        """Layers ahead of the first period: the dense prefix
+        (full-attention layers; linear ones where ``linear_head``), or
+        layer 0 of a model with linear and GQA layers."""
+        return 1 if self.linear and not self.latent else self.first_k_dense
+
+    @property
+    def lead_layers(self) -> int:
+        """Layers of a first period cut short by the dense prefix."""
+        return self.short_period + 1 if self.short_period >= 0 else 0
 
     @property
     def inner_period(self) -> int:
@@ -162,7 +214,7 @@ class ModelConfig:
         head."""
         if not self.inner_period:
             return 0
-        return (self.num_layers - self.head_layers) // (
+        return (self.num_layers - self.head_layers - self.lead_layers) // (
             self.inner_period + 1)
 
     @property
@@ -170,7 +222,7 @@ class ModelConfig:
         """Window (linear) layers behind the last whole period."""
         if not self.inner_period:
             return 0
-        return (self.num_layers - self.head_layers) % (
+        return (self.num_layers - self.head_layers - self.lead_layers) % (
             self.inner_period + 1)
 
     @property
@@ -186,7 +238,9 @@ class ModelConfig:
         """Layers that keep a fixed state a sequence and no page."""
         if not self.linear:
             return 0
-        return self.num_periods * self.linear_period + self.inner_tail
+        return self.num_periods * self.linear_period + self.inner_tail \
+            + (self.head_layers if self.linear_head else 0) \
+            + max(self.short_period, 0)
 
     @property
     def num_full_layers(self) -> int:
@@ -203,7 +257,10 @@ class ModelConfig:
         if not self.inner_period:
             return ("full",) * self.num_layers
         inner = "linear" if self.linear else "window"
-        return ("full",) * self.head_layers + (
+        head = inner if self.linear_head else "full"
+        lead = ((inner,) * self.short_period + ("full",)
+                if self.short_period >= 0 else ())
+        return (head,) * self.head_layers + lead + (
             (inner,) * self.inner_period + ("full",)) * self.num_periods \
             + (inner,) * self.inner_tail
 
@@ -281,7 +338,7 @@ class ModelConfig:
             if self.shared_expert_intermediate_size:
                 mlp += 3 * e * self.shared_expert_intermediate_size + e
             mlp += 3 * e * self.n_shared_experts * self.moe_intermediate_size
-        norms = 2 * e
+        norms = (4 if self.norm_post else 2) * e
         routed = self.num_routed_layers if self.num_experts \
             else self.num_layers
         blocks = self.num_layers * (attn + norms) + routed * mlp \
@@ -294,8 +351,18 @@ class ModelConfig:
             blocks += e * (self.num_full_layers * self.num_heads
                            + self.num_window_layers * self.window_num_heads)
         if self.attn_out_gate:
-            blocks += self.num_full_layers * e * self.q_dim
-        if self.linear:
+            blocks += self.num_full_layers * e * (
+                self.attn_out_dim if self.latent else self.q_dim)
+        if self.linear and self.linear_head_decay:
+            # The Gated DeltaNet form: q, k, v and their convolution, the
+            # full gate and the output projection, the decay's and the step
+            # size's projections a head, the decay's two vectors, the norm.
+            ld, lh = self.linear_dim, self.linear_num_heads
+            lin = ((e + self.linear_conv) * self.linear_conv_dim
+                   + 2 * e * ld + 2 * e * lh + 2 * lh
+                   + self.linear_head_dim)
+            blocks += self.num_linear_layers * (lin - attn)
+        elif self.linear:
             # A linear layer in place of a GQA layer's attention: q, k, v, o
             # at its own width, the two low-rank pairs, the step size, the
             # convolutions, the decay's two vectors and the output norm.
@@ -334,6 +401,21 @@ class ModelConfig:
         is_mixtral = "mixtral" in arch or model_type == "mixtral"
         if model_type == "solar_open2":
             return _from_solar_open2(d, name or model_type, tuple(eos))
+        if model_type == "gigachat3_5":
+            return _from_gigachat3_5(d, name or model_type, tuple(eos))
+        # What only the ``gigachat3_5`` reader understands: on any other
+        # path each would be dropped, and the model served as another.
+        for k in sorted(d):
+            if (k in _GIGACHAT_ONLY or k.startswith("linear_")) \
+                    and k != "linear_attn_config" \
+                    and d[k] not in (None, False, *_GIGACHAT_ONLY.get(k, ())):
+                raise ValueError(
+                    f"{k}={d[k]!r} in a config of model_type "
+                    f"{model_type!r}: only model_type 'gigachat3_5' is read "
+                    "with gated-delta-rule linear layers beside gated latent "
+                    "attention, sandwich norms, a gated norm and a clamped "
+                    "SwiGLU; serving this model without it would be another "
+                    "model")
         # What only the ``solar_open2`` reader understands: on any other
         # path each would be dropped, and the model served as another.
         for k in sorted(d):
@@ -405,6 +487,20 @@ class ModelConfig:
         )
 
 
+def _deepseek_yarn(rs: dict | None, refuse) -> tuple[float, ...]:
+    """``ModelConfig.rope_yarn`` from a DeepSeek-V3-style ``rope_scaling``
+    (none: plain RoPE); any other type goes to ``refuse``."""
+    if not rs:
+        return ()
+    if rs.get("type", rs.get("rope_type")) != "yarn":
+        refuse(f"rope_scaling type {rs.get('type', rs.get('rope_type'))!r}"
+               " (only yarn)")
+    return (float(rs["factor"]),
+            float(rs["original_max_position_embeddings"]),
+            float(rs.get("beta_fast", 32)), float(rs.get("beta_slow", 1)),
+            float(rs.get("mscale", 1)), float(rs.get("mscale_all_dim", 0)))
+
+
 def _from_deepseek_v3(d: dict[str, Any], name: str,
                       eos: tuple[int, ...]) -> ModelConfig:
     """The DeepSeek-V3 block (``deepseek_v3``, ``kimi_k2``): latent
@@ -434,16 +530,7 @@ def _from_deepseek_v3(d: dict[str, Any], name: str,
         refuse("attention_bias")
     if int(d.get("num_nextn_predict_layers", 0) or 0):
         refuse("multi-token prediction layers")
-    yarn: tuple[float, ...] = ()
-    rs = d.get("rope_scaling")
-    if rs:
-        if rs.get("type", rs.get("rope_type")) != "yarn":
-            refuse(f"rope_scaling type {rs.get('type', rs.get('rope_type'))!r}"
-                   " (only yarn)")
-        yarn = (float(rs["factor"]),
-                float(rs["original_max_position_embeddings"]),
-                float(rs.get("beta_fast", 32)), float(rs.get("beta_slow", 1)),
-                float(rs.get("mscale", 1)), float(rs.get("mscale_all_dim", 0)))
+    yarn = _deepseek_yarn(d.get("rope_scaling"), refuse)
     heads = d["num_attention_heads"]
     layers = d["num_hidden_layers"]
     first = int(d.get("first_k_dense_replace", 0) or 0)
@@ -694,6 +781,140 @@ def _from_solar_open2(d: dict[str, Any], name: str,
     )
 
 
+# Keys only the ``gigachat3_5`` reader understands (beside every
+# ``linear_*`` key), each with the values that say "the usual thing".
+_GIGACHAT_ONLY: dict[str, tuple] = {
+    "full_attention_layers": (), "gated_attention": (),
+    "norm_type": ("RMSNorm",), "layernorm_type": ("pre",),
+    "swiglu_limit": (0,), "use_shared_expert_sigmoid": (),
+}
+
+
+def _from_gigachat3_5(d: dict[str, Any], name: str,
+                      eos: tuple[int, ...]) -> ModelConfig:
+    """The ``gigachat3_5`` block (ai-sage): Gated DeltaNet linear layers
+    (the ``linear_*`` keys: key heads shared by value heads, a decay a head,
+    an output gate from a full projection) beside gated latent-attention
+    layers at ``full_attention_layers`` (the DeepSeek-V3 block with an
+    elementwise output gate), a gated norm in sandwich placement, a clamped
+    SwiGLU, sigmoid-routed experts beside one ungated shared expert behind
+    a dense prefix of LINEAR layers.  Key for key from the published file;
+    what the block cannot express is refused, not approximated.  The
+    multi-token-prediction modules (``num_nextn_predict_layers``,
+    ``nextn_is_sparse``) are read and DROPPED: they do not enter the
+    next-token logits, and nothing here drafts with them."""
+    def refuse(what: str) -> None:
+        raise ValueError(f"config {name!r}: {what} is not supported (the "
+                         "model would be served as another model)")
+
+    for k in ("kv_lora_rank", "q_lora_rank", "qk_nope_head_dim",
+              "qk_rope_head_dim", "v_head_dim", "n_routed_experts",
+              "linear_num_key_heads", "linear_num_value_heads",
+              "linear_key_head_dim", "linear_conv_kernel_dim",
+              "full_attention_layers"):
+        if not d.get(k):
+            refuse(f"a gigachat3_5 config without {k}")
+    for k, want in (("linear_attention_type", "GigaChat35GatedDeltaNet"),
+                    ("linear_gating_type",
+                     "gated_rmsnorm_sigmoid_zero_centered"),
+                    ("hidden_act", "silu"), ("scoring_func", "sigmoid"),
+                    ("topk_method", "noaux_tc")):
+        if d.get(k, want) != want:
+            refuse(f"{k}={d[k]!r} (only {want!r})")
+    if d.get("linear_value_head_dim", d["linear_key_head_dim"]) \
+            != d["linear_key_head_dim"]:
+        refuse(f"linear_value_head_dim={d['linear_value_head_dim']} beside "
+               f"linear_key_head_dim={d['linear_key_head_dim']} (a state "
+               "that is not square)")
+    hk, hv = int(d["linear_num_key_heads"]), int(d["linear_num_value_heads"])
+    if hv % hk:
+        refuse(f"linear_num_value_heads={hv} over linear_num_key_heads={hk}")
+    if int(d.get("n_group", 1) or 1) > 1 or int(d.get("topk_group", 1)
+                                                 or 1) > 1:
+        refuse(f"group-limited routing (n_group={d.get('n_group')}, "
+               f"topk_group={d.get('topk_group')})")
+    if d.get("attention_bias"):
+        refuse("attention_bias")
+    if d.get("use_shared_expert_sigmoid"):
+        refuse("use_shared_expert_sigmoid (a gated shared expert)")
+    if not d.get("use_mla_scaling_factor", True):
+        refuse("use_mla_scaling_factor=false (YaRN without its factor in "
+               "the softmax scale)")
+    heads = d["num_attention_heads"]
+    if d.get("num_key_value_heads", heads) != heads:
+        refuse(f"num_key_value_heads={d['num_key_value_heads']} beside "
+               f"{heads} latent-attention heads")
+    if d.get("qk_head_dim", d["qk_nope_head_dim"] + d["qk_rope_head_dim"]) \
+            != d["qk_nope_head_dim"] + d["qk_rope_head_dim"]:
+        refuse(f"qk_head_dim={d['qk_head_dim']} (not nope + rope)")
+    norm = d.get("norm_type", "RMSNorm")
+    if norm not in ("RMSNorm", "ZeroCenteredGatedNorm"):
+        refuse(f"norm_type={norm!r}")
+    norm_gate = float(d.get("layernorm_gating_weight", 2)) \
+        if norm == "ZeroCenteredGatedNorm" else 0.0
+    if norm == "ZeroCenteredGatedNorm" and norm_gate <= 0:
+        refuse(f"layernorm_gating_weight={d['layernorm_gating_weight']}")
+    placement = d.get("layernorm_type", "pre")
+    if placement not in ("pre", "pre_post"):
+        refuse(f"layernorm_type={placement!r}")
+    yarn = _deepseek_yarn(d.get("rope_scaling"), refuse)
+    layers = int(d["num_hidden_layers"])
+    first = int(d.get("first_k_dense_replace", 0) or 0)
+    full = [int(l) for l in d["full_attention_layers"]]
+    # The dense prefix is linear layers; behind it a first period that the
+    # prefix may have cut short, whole periods, then linear layers.
+    per = full[1] - full[0] - 1 if len(full) > 1 else full[0] - first
+    lead = full[0] - first
+    want = list(range(full[0], layers, per + 1)) if per >= 1 else []
+    if not 0 <= first < layers or not 0 <= lead <= per or full != want:
+        refuse(f"full_attention_layers {full} with first_k_dense_replace "
+               f"{first} over {layers} layers (a dense prefix of linear "
+               "layers, then a latent layer every so many linear layers, "
+               "then linear layers)")
+    return ModelConfig(
+        name=name,
+        vocab_size=d["vocab_size"],
+        hidden_size=d["hidden_size"],
+        intermediate_size=d["intermediate_size"],
+        num_layers=layers,
+        num_heads=heads,
+        num_kv_heads=1,
+        head_dim=d["qk_nope_head_dim"] + d["qk_rope_head_dim"],
+        rope_theta=float(d.get("rope_theta", 10000.0)),
+        rms_norm_eps=float(d.get("rms_norm_eps", 1e-6)),
+        tie_word_embeddings=bool(d.get("tie_word_embeddings", False)),
+        max_position_embeddings=int(d.get("max_position_embeddings", 32768)),
+        eos_token_ids=eos,
+        num_experts=int(d["n_routed_experts"]),
+        num_experts_per_tok=int(d["num_experts_per_tok"]),
+        moe_intermediate_size=int(d["moe_intermediate_size"]),
+        norm_topk_prob=bool(d.get("norm_topk_prob", True)),
+        q_lora_rank=int(d["q_lora_rank"]),
+        kv_lora_rank=int(d["kv_lora_rank"]),
+        qk_nope_head_dim=int(d["qk_nope_head_dim"]),
+        qk_rope_head_dim=int(d["qk_rope_head_dim"]),
+        v_head_dim=int(d["v_head_dim"]),
+        rope_yarn=yarn,
+        first_k_dense=first,
+        scoring_func="sigmoid",
+        routed_scaling_factor=float(d.get("routed_scaling_factor", 1.0)),
+        n_shared_experts=int(d.get("n_shared_experts", 0) or 0),
+        linear_period=per,
+        linear_num_heads=hv,
+        linear_head_dim=int(d["linear_key_head_dim"]),
+        linear_conv=int(d["linear_conv_kernel_dim"]),
+        attn_out_gate=bool(d.get("gated_attention", False)),
+        short_period=lead if lead < per else -1,
+        linear_head_decay=True,
+        linear_key_heads=hk,
+        linear_gate_scale=float(d.get("linear_sigmoid_gate_scale", 1)),
+        linear_norm_eps=float(d.get("linear_attn_o_norm_eps", 0) or 0),
+        norm_gate=norm_gate,
+        norm_post=placement == "pre_post",
+        swiglu_limit=float(d.get("swiglu_limit", 0) or 0),
+    )
+
+
 _REGISTRY: dict[str, ModelConfig] = {}
 
 
@@ -808,6 +1029,29 @@ register_config(ModelConfig(
     norm_topk_prob=True, scoring_func="sigmoid", n_shared_experts=1,
     linear_period=2, linear_num_heads=4, linear_head_dim=16, linear_conv=4,
     linear_neg_eigval=True, use_rope=False, attn_out_gate=True,
+))
+
+# Gated-delta-rule linear layers beside gated latent-attention layers (the
+# ``gigachat3_5`` block) at CPU-test size: 2 dense linear layers, a first
+# period cut short (1 linear layer and its latent layer), a whole period (2
+# linear layers and a latent layer) and a tail of 1 linear layer; a linear
+# layer 2 key heads under 4 value heads of 16, a decay a head; a latent
+# layer 4 heads of 16 | 8 over a 32 + 8 row, gated; the gated norm in
+# sandwich placement, SwiGLU clamped at 0.2 (so that the clamp bites at this
+# size), 16 sigmoid-routed experts top-4 times 2.5 beside a shared one.
+register_config(ModelConfig(
+    name="tiny-latent-linear-moe", vocab_size=512, hidden_size=64,
+    intermediate_size=128, num_layers=8, num_heads=4, num_kv_heads=1,
+    head_dim=24, rope_theta=100000.0, eos_token_ids=(0,),
+    num_experts=16, num_experts_per_tok=4, moe_intermediate_size=32,
+    norm_topk_prob=True, q_lora_rank=48, kv_lora_rank=32,
+    qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+    rope_yarn=(4.0, 64.0, 32.0, 1.0, 1.0, 1.0), first_k_dense=2,
+    scoring_func="sigmoid", routed_scaling_factor=2.5, n_shared_experts=1,
+    linear_period=2, linear_num_heads=4, linear_head_dim=16, linear_conv=4,
+    attn_out_gate=True, short_period=1, linear_head_decay=True,
+    linear_key_heads=2, linear_gate_scale=2.0, linear_norm_eps=1e-6,
+    norm_gate=2.0, norm_post=True, swiglu_limit=0.2,
 ))
 
 # MoE families (HF: mistralai/Mixtral-8x7B-Instruct-v0.1, Qwen/Qwen2-57B-A14B).

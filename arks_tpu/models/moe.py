@@ -64,6 +64,15 @@ def shared_width(cfg) -> int:
             or cfg.n_shared_experts * cfg.moe_intermediate_size)
 
 
+def swiglu(gate: jnp.ndarray, up: jnp.ndarray, limit: float = 0.0
+           ) -> jnp.ndarray:
+    """``SiLU(gate) * up``, the SiLU in float32; with a ``limit`` c > 0
+    (``cfg.swiglu_limit``) ``SiLU(min(gate, c)) * clip(up, -c, c)``."""
+    if limit:
+        gate, up = jnp.minimum(gate, limit), jnp.clip(up, -limit, limit)
+    return jax.nn.silu(gate.astype(jnp.float32)).astype(gate.dtype) * up
+
+
 def init_moe_params(cfg, key, dtype, layers: int | None = None) -> Params:
     """The routed FFN's leaves, stacked ``layers`` deep (every routed layer
     of the model where not given)."""
@@ -258,8 +267,7 @@ def _batched_dispatch(x2: jnp.ndarray, vals: jnp.ndarray, idx: jnp.ndarray,
         with jax.named_scope("arks.moe_dot"):
             gate = _expert_dot("xce,xef->xcf", xs, weights["w_gate"])
             up = _expert_dot("xce,xef->xcf", xs, weights["w_up"])
-            act = jax.nn.silu(gate.astype(jnp.float32)).astype(
-                gate.dtype) * up
+            act = swiglu(gate, up, cfg.swiglu_limit)
             down = _expert_dot("xcf,xfe->xce", act, weights["w_down"])
         with jax.named_scope("arks.moe_route"):
             w = jnp.where(live, jnp.take(flat_w, pair), 0).astype(down.dtype)
@@ -299,7 +307,7 @@ def _shared_expert(x2: jnp.ndarray, mp: Params, cfg,
     with jax.named_scope("arks.moe_shared"):
         sg = qeinsum("...e,ef->...f", x2, mp["shared_gate_proj"])
         su = qeinsum("...e,ef->...f", x2, mp["shared_up"])
-        sact = jax.nn.silu(sg.astype(jnp.float32)).astype(sg.dtype) * su
+        sact = swiglu(sg, su, cfg.swiglu_limit)
         if constrain is not None:
             sact = constrain(sact, sact.ndim - 1)
         shared = qeinsum("...f,fe->...e", sact, mp["shared_down"])
@@ -331,7 +339,7 @@ def _ragged_dispatch(x2: jnp.ndarray, vals: jnp.ndarray, idx: jnp.ndarray,
     with jax.named_scope("arks.moe_dot"):
         gate = jax.lax.ragged_dot(xs, mp["w_gate"], group_sizes)
         up = jax.lax.ragged_dot(xs, mp["w_up"], group_sizes)
-        act = jax.nn.silu(gate.astype(jnp.float32)).astype(gate.dtype) * up
+        act = swiglu(gate, up, cfg.swiglu_limit)
         down = jax.lax.ragged_dot(act, mp["w_down"], group_sizes)  # [T*k, E]
     with jax.named_scope("arks.moe_route"):
         w = jnp.take(vals.reshape(-1), order).astype(down.dtype)   # [T*k]
@@ -438,7 +446,7 @@ def moe_ffn(x: jnp.ndarray, mp: Params, cfg, constrain=None,
     with jax.named_scope("arks.moe_dot"):
         gate = qeinsum("...e,xef->...xf", x, mp["w_gate"])
         up = qeinsum("...e,xef->...xf", x, mp["w_up"])
-        act = jax.nn.silu(gate.astype(jnp.float32)).astype(gate.dtype) * up
+        act = swiglu(gate, up, cfg.swiglu_limit)
         if constrain is not None:
             act = constrain(act, act.ndim - 2)
         down = qeinsum("...xf,xfe->...xe", act, mp["w_down"])  # per expert

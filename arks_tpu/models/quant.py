@@ -61,6 +61,9 @@ MATMUL_KEYS = frozenset({
     # The ``solar_open2`` block: a GQA layer's elementwise output gate, a
     # linear layer's low-rank decay and gate pairs.
     "wg", "w_f1", "w_f2", "w_g1", "w_g2",
+    # The ``gigachat3_5`` block: a linear layer's full output gate (its
+    # latent layers' gate is ``wg``).
+    "w_z",
 })
 # Router logits feed a softmax over experts — tiny and precision-sensitive,
 # so it stays full width, as do norms, biases and the scalar shared gate.
@@ -73,9 +76,13 @@ SKIP_KEYS = frozenset({
     # decay's bias and per-head rate, the step size [E, H], the output's
     # per-head norm.
     "conv_q", "conv_k", "conv_v", "dt_bias", "a_log", "w_b", "o_norm",
+    # The decay's projection a head [E, H] (a softplus's input, tiny) and
+    # the two post-norms of a layer with sandwich norms.
+    "w_a", "attn_post_norm", "mlp_post_norm",
 })
 NORM_KEYS = frozenset({"attn_norm", "mlp_norm", "final_norm", "q_norm",
-                       "kv_norm", "o_norm"})
+                       "kv_norm", "o_norm", "attn_post_norm",
+                       "mlp_post_norm"})
 
 
 def weight_bits(weight_dtype: str) -> int:
@@ -274,7 +281,10 @@ def init_params_quantized(cfg, key, dtype=jnp.bfloat16, bits: int = 8,
             counter[0] += 1
             sub = jax.random.fold_in(key, counter[0])
             if name in NORM_KEYS:
-                kind, axis = "ones", 0
+                # A gated norm's scale is 1 at a weight of zero; the
+                # linear layers' per-head norm is a plain one.
+                kind, axis = ("zeros" if cfg.norm_gate and name != "o_norm"
+                              else "ones"), 0
             elif name in ("bq", "bk", "bv"):
                 kind, axis = "zeros", 0
             elif name == "embed":

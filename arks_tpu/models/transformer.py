@@ -76,10 +76,11 @@ class LinearState(NamedTuple):
     (``cfg.linear``): a fixed number of bytes a slot, whatever the context.
 
     ``s`` ``[Ll, num_slots, H, d, d]`` float32: the delta rule's state a
-    head (keys down, values across), layer ``l`` of the model's linear
-    layers in model order.  ``conv`` ``[Ll, num_slots, K - 1, 3 x H x d]``:
+    (value) head (keys down, values across), layer ``l`` of the model's
+    linear layers in model order.  ``conv`` ``[Ll, num_slots, K - 1, C]``:
     the last ``K - 1`` rows of the q | k | v projections ahead of the short
-    convolution, oldest first.  A slot's rows are whatever its last
+    convolution, oldest first (C = 3 x H x d; with fewer key heads Hk,
+    2 x Hk x d + H x d).  A slot's rows are whatever its last
     sequence left there; a sequence that starts at position 0 reads them as
     zeros (:func:`_linear_qkv`, :func:`_linear_state`), so nothing zeroes a
     slot between two steps."""
@@ -121,7 +122,9 @@ class PagedKVCache(NamedTuple):
 
     A model with LINEAR-attention layers (``cfg.linear``) keeps pages for
     its GQA layers only (``L`` = ``cfg.num_full_layers``); ``lin`` is what
-    its linear layers keep, a :class:`LinearState` indexed by slot.
+    its linear layers keep, a :class:`LinearState` indexed by slot.  Where
+    its other layers are LATENT layers (``cfg.latent`` too), the pool is
+    the latent pool of those layers, ``v`` None, beside ``lin``.
     """
 
     k: jnp.ndarray
@@ -343,8 +346,84 @@ def _init_linear_params(cfg: ModelConfig, key: jax.Array,
     return params
 
 
+def _init_latent_linear_params(cfg: ModelConfig, key: jax.Array,
+                               dtype: jnp.dtype) -> Params:
+    """The ``gigachat3_5`` tree, a stacked tree a kind of layer:
+    ``dense_layers`` (the ``first_k_dense`` leading layers: LINEAR layers
+    with a SwiGLU of ``intermediate_size``), ``lin_layers`` (the routed
+    linear layers, in model order) and ``layers`` (the routed latent
+    layers).  A linear layer in the Gated DeltaNet form, Hk key heads under
+    H value heads of d: ``wq`` / ``wk`` [E, Hk x d], ``wv`` [E, H x d] and
+    ``conv_q`` / ``conv_k`` / ``conv_v`` (the columns of the one q | k | v
+    projection and of its one depthwise convolution, a leaf a part), the
+    decay a head ``w_a`` [E, H] ``dt_bias`` [H] ``a_log`` [H], the step
+    size ``w_b`` [E, H], the gate ``w_z`` [E, H x d], the per-head norm
+    ``o_norm`` [d], ``wo`` [H x d, E].  A latent layer:
+    :func:`_init_latent_params`'s leaves and the gate ``wg`` [E, H x v].
+    Every layer four norms (``cfg.norm_post``); a gated norm's weight is
+    zeros where a plain one's is ones (``cfg.norm_gate``: a scale of 1)."""
+    e, f, v, h = (cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size,
+                  cfg.num_heads)
+    keys = iter(jax.random.split(key, 64))
+    unit = jnp.zeros if cfg.norm_gate else jnp.ones
+
+    def w(shape, scale=0.02):
+        return (jax.random.normal(next(keys), shape, jnp.float32)
+                * scale).astype(dtype)
+
+    def norms(l: int) -> Params:
+        names = ("attn_norm", "mlp_norm") + (
+            ("attn_post_norm", "mlp_post_norm") if cfg.norm_post else ())
+        return {n: unit((l, e), dtype) for n in names}
+
+    def lin(l: int) -> Params:
+        kd, ld, lh, k = (cfg.linear_key_dim, cfg.linear_dim,
+                         cfg.linear_num_heads, cfg.linear_conv)
+        return dict(norms(l), **{
+            "wq": w((l, e, kd)), "wk": w((l, e, kd)), "wv": w((l, e, ld)),
+            "conv_q": w((l, k, kd)), "conv_k": w((l, k, kd)),
+            "conv_v": w((l, k, ld)),
+            "w_a": w((l, e, lh)), "dt_bias": shift_dt_bias(w((l, lh))),
+            "a_log": w((l, lh)), "w_b": w((l, e, lh)),
+            "w_z": w((l, e, ld)),
+            "o_norm": jnp.ones((l, cfg.linear_head_dim), dtype),
+            "wo": w((l, ld, e))})
+
+    def latent(l: int) -> Params:
+        out = dict(norms(l), **{
+            "wq_a": w((l, e, cfg.q_lora_rank)),
+            "q_norm": unit((l, cfg.q_lora_rank), dtype),
+            "wq_b": w((l, cfg.q_lora_rank, cfg.q_dim)),
+            "wkv_a": w((l, e, cfg.latent_row)),
+            "kv_norm": unit((l, cfg.kv_lora_rank), dtype),
+            "wkv_b": w((l, cfg.kv_lora_rank,
+                        h * (cfg.qk_nope_head_dim + cfg.v_head_dim))),
+            "wo": w((l, cfg.attn_out_dim, e))})
+        if cfg.attn_out_gate:
+            out["wg"] = w((l, e, cfg.attn_out_dim))
+        return out
+
+    from arks_tpu.models import moe
+    params: Params = {"embed": w((v, e)), "final_norm": unit((e,), dtype)}
+    for name, l, attn in (
+            ("layers", cfg.num_full_layers, latent),
+            ("lin_layers", cfg.num_linear_layers - cfg.first_k_dense, lin)):
+        params[name] = dict(attn(l), **moe.init_moe_params(
+            cfg, next(keys), dtype, layers=l))
+    if cfg.first_k_dense:
+        ld = cfg.first_k_dense
+        params["dense_layers"] = dict(
+            lin(ld), w_gate=w((ld, e, f)), w_up=w((ld, e, f)),
+            w_down=w((ld, f, e)))
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = w((e, v))
+    return params
+
+
 def init_params(cfg: ModelConfig, key: jax.Array, dtype: jnp.dtype | None = None) -> Params:
     dtype = dtype or jnp.dtype(cfg.dtype)
+    if cfg.latent and cfg.linear:
+        return _init_latent_linear_params(cfg, key, dtype)
     if cfg.latent:
         return _init_latent_params(cfg, key, dtype)
     if cfg.windowed:
@@ -532,7 +611,7 @@ def init_paged_cache(cfg: ModelConfig, num_pages: int, page: int,
         return pool._replace(lin=LinearState(
             s=jnp.zeros((ll, state_slots, h, d, d), jnp.float32),
             conv=jnp.zeros((ll, state_slots, cfg.linear_conv - 1,
-                            3 * cfg.linear_dim), dtype)))
+                            cfg.linear_conv_dim), dtype)))
     if cfg.windowed:
         if win_pages < 1:
             raise ValueError(f"model {cfg.name!r}: window layers keep a "
@@ -623,6 +702,24 @@ def _constrain(x: jnp.ndarray, mesh: Mesh | None, *spec) -> jnp.ndarray:
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, P(*spec)))
 
 
+def _norm(x: jnp.ndarray, w: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
+    """The model's norm: RMS norm times the learnt ``w``, or
+    (``cfg.norm_gate`` g) times ``g sigmoid(w)``, which is 1 at ``w`` = 0."""
+    if cfg.norm_gate:
+        w = cfg.norm_gate * jax.nn.sigmoid(w.astype(jnp.float32))
+    return rms_norm(x, w, cfg.rms_norm_eps)
+
+
+def _post_norm(y: jnp.ndarray, lp: Params, name: str,
+               cfg: ModelConfig) -> jnp.ndarray:
+    """A sublayer's output on its way to the residual add: normed again
+    where the model has sandwich norms (``cfg.norm_post``)."""
+    if not cfg.norm_post:
+        return y
+    with _scope("arks.norm_post"):
+        return _norm(y, lp[name], cfg)
+
+
 def _qkv(h: jnp.ndarray, lp: Params, cfg: ModelConfig):
     q = qeinsum("...e,eq->...q", h, lp["wq"])
     k = qeinsum("...e,ek->...k", h, lp["wk"])
@@ -672,7 +769,7 @@ def _mlp(h: jnp.ndarray, lp: Params, cfg: ModelConfig, mesh: Mesh | None,
     tree has a router (a model's dense prefix has none).  With
     ``row_valid`` a routed layer returns ``(out, held_pairs)``
     (:func:`moe.moe_ffn`)."""
-    x = rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps)
+    x = _norm(h, lp["mlp_norm"], cfg)
 
     def _int_spec(ndim: int, sharded_dim: int) -> list:
         # Intermediate spec: keep batch and (under context parallelism) the
@@ -701,7 +798,8 @@ def _mlp(h: jnp.ndarray, lp: Params, cfg: ModelConfig, mesh: Mesh | None,
                            row_valid=row_valid)
     gate = qeinsum("...e,ef->...f", x, lp["w_gate"])
     up = qeinsum("...e,ef->...f", x, lp["w_up"])
-    act = jax.nn.silu(gate.astype(jnp.float32)).astype(gate.dtype) * up
+    from arks_tpu.models.moe import swiglu
+    act = swiglu(gate, up, cfg.swiglu_limit)
     act = _constrain(act, mesh, *_int_spec(act.ndim, act.ndim - 1))
     return qeinsum("...f,fe->...e", act, lp["w_down"])
 
@@ -709,7 +807,7 @@ def _mlp(h: jnp.ndarray, lp: Params, cfg: ModelConfig, mesh: Mesh | None,
 @_scope("arks.lm_head")
 def _unembed(h_last: jnp.ndarray, params: Params, cfg: ModelConfig,
              mesh: Mesh | None, batch_axis: str | None) -> jnp.ndarray:
-    h_last = rms_norm(h_last, params["final_norm"], cfg.rms_norm_eps)
+    h_last = _norm(h_last, params["final_norm"], cfg)
     tied = cfg.tie_word_embeddings
     table = params["embed"] if tied else params["lm_head"]
     logits = unembed_logits(h_last, table, tied)
@@ -734,8 +832,7 @@ def _mla_q(x: jnp.ndarray, lp: Params, cfg: ModelConfig,
     down, norm, up, RoPE on the rotary lanes, and ``q_nope W_uk^T`` so that
     a head's score against a cached row is one dot over the row."""
     b, t = x.shape[:2]
-    cq = rms_norm(qeinsum("...e,er->...r", x, lp["wq_a"]), lp["q_norm"],
-                  cfg.rms_norm_eps)
+    cq = _norm(qeinsum("...e,er->...r", x, lp["wq_a"]), lp["q_norm"], cfg)
     q = qeinsum("...r,rq->...q", cq, lp["wq_b"]).reshape(
         b, t, cfg.num_heads, cfg.head_dim)
     q_nope, q_rope = (q[..., :cfg.qk_nope_head_dim],
@@ -752,20 +849,27 @@ def _mla_kv(x: jnp.ndarray, lp: Params, cfg: ModelConfig,
     """Normed ``x`` [B, T, E] -> the row a token caches [B, T, C + rope]:
     the normed latent and the rotary key lanes all heads share."""
     kv = qeinsum("...e,er->...r", x, lp["wkv_a"])
-    c = rms_norm(kv[..., :cfg.kv_lora_rank], lp["kv_norm"], cfg.rms_norm_eps)
+    c = _norm(kv[..., :cfg.kv_lora_rank], lp["kv_norm"], cfg)
     k_r = apply_rope(kv[..., None, cfg.kv_lora_rank:], positions,
                      cfg.rope_theta, cfg.rope_yarn)[..., 0, :]
     return jnp.concatenate([c, k_r], axis=-1)
 
 
 @_scope("arks.mla_out")
-def _mla_out(attn: jnp.ndarray, lp: Params, cfg: ModelConfig) -> jnp.ndarray:
+def _mla_out(attn: jnp.ndarray, lp: Params, cfg: ModelConfig,
+             x: jnp.ndarray | None = None) -> jnp.ndarray:
     """``attn`` [T, H, C] (probabilities times the latent rows) -> [T, E]:
-    un-absorb ``W_uv`` per head, then the output projection."""
+    un-absorb ``W_uv`` per head, then (``cfg.attn_out_gate``) times
+    ``sigmoid(x Wg)`` elementwise over the H x v outputs, from the
+    sublayer's normed input ``x`` [T, E], then the output projection."""
     _, w_uv = _wkv_b(lp, cfg, attn.dtype)
     o = jnp.einsum("thc,chv->thv", attn, w_uv)
-    return qeinsum("...q,qe->...e", o.reshape(o.shape[0], cfg.attn_out_dim),
-                   lp["wo"])
+    o = o.reshape(o.shape[0], cfg.attn_out_dim)
+    if cfg.attn_out_gate:
+        with _scope("arks.mla_gate"):
+            o = o * jax.nn.sigmoid(qeinsum(
+                "te,eq->tq", x, lp["wg"]).astype(jnp.float32)).astype(o.dtype)
+    return qeinsum("...q,qe->...e", o, lp["wo"])
 
 
 def _mixed_step_latent(params, cfg, cache, tables, tokens, token_slot,
@@ -892,9 +996,14 @@ def _linear_qkv(x: jnp.ndarray, lp: Params, cfg: ModelConfig,
     ``conv [B, K - 1, 3 H d]``, read as zeros where ``fresh``), SiLU, the
     L2 norm of q and k a head and q's scale; the log decay a channel and
     the step size a head.  Returns (q, k, v [T, H, d], g [T, H, d] f32 <= 0,
-    beta [T, H] f32, the slots' new carry)."""
+    beta [T, H] f32, the slots' new carry).  The Gated DeltaNet form
+    (``cfg.linear_head_decay``): q and k have ``cfg.linear_key_heads``
+    heads, key head j repeated for the value heads it serves; ONE log decay
+    a head, ``g [T, H, 1]``, which :func:`_linear_state` broadcasts over
+    the head's channels."""
     t = x.shape[0]
     h, d, kk = cfg.linear_num_heads, cfg.linear_head_dim, cfg.linear_conv
+    hk = cfg.linear_key_heads or h
     pre = jnp.concatenate([qeinsum("te,eq->tq", x, lp[n])
                            for n in ("wq", "wk", "wv")], axis=-1)  # [T, 3Hd]
     w = jnp.concatenate([lp["conv_q"], lp["conv_k"], lp["conv_v"]],
@@ -921,24 +1030,44 @@ def _linear_qkv(x: jnp.ndarray, lp: Params, cfg: ModelConfig,
         carry, jnp.clip(back + kk - 1, 0, kk - 2)[..., None], axis=1)
     new_conv = jnp.where((seq_q_len > 0)[:, None, None],
                          jnp.where((back >= 0)[..., None], tail, old), conv)
-    y = jax.nn.silu(y).reshape(t, 3, h, d)
+    y = jax.nn.silu(y)
+    if hk == h:
+        y = y.reshape(t, 3, h, d)
+
+        def part(i):
+            return y[:, i]
+    else:
+        edges = (0, hk * d, 2 * hk * d, y.shape[1])
+
+        def part(i):
+            return y[:, edges[i]: edges[i + 1]].reshape(t, -1, d)
 
     def unit(a):
         return a * jax.lax.rsqrt(jnp.sum(a * a, -1, keepdims=True) + 1e-6)
 
-    q = unit(y[:, 0]) * d ** -0.5
-    k, v = unit(y[:, 1]), y[:, 2]
-    f = qeinsum("tr,rq->tq", qeinsum("te,er->tr", x, lp["w_f1"]), lp["w_f2"])
+    q = unit(part(0)) * d ** -0.5
+    k, v = unit(part(1)), part(2)
     # (The bias in float32: near -4 a bfloat16 sum would round the rate
     # itself by a few percent, and the decay compounds over the context.)
-    g = -jnp.exp(lp["a_log"].astype(jnp.float32))[None, :, None] \
-        * jax.nn.softplus(f.astype(jnp.float32)
-                          + lp["dt_bias"].astype(jnp.float32)
-                          ).reshape(t, h, d)
+    if cfg.linear_head_decay:
+        f = jnp.einsum("te,eh->th", x, lp["w_a"])
+        g = (-jnp.exp(lp["a_log"].astype(jnp.float32))[None]
+             * jax.nn.softplus(f.astype(jnp.float32)
+                               + lp["dt_bias"].astype(jnp.float32))
+             )[..., None]
+    else:
+        f = qeinsum("tr,rq->tq", qeinsum("te,er->tr", x, lp["w_f1"]),
+                    lp["w_f2"])
+        g = -jnp.exp(lp["a_log"].astype(jnp.float32))[None, :, None] \
+            * jax.nn.softplus(f.astype(jnp.float32)
+                              + lp["dt_bias"].astype(jnp.float32)
+                              ).reshape(t, h, d)
     beta = jax.nn.sigmoid(jnp.einsum("te,eh->th", x, lp["w_b"]
                                      ).astype(jnp.float32))
     if cfg.linear_neg_eigval:
         beta = 2.0 * beta
+    if hk != h:
+        q, k = (jnp.repeat(a, h // hk, axis=1) for a in (q, k))
     return q, k, v, g, beta, new_conv.astype(conv.dtype)
 
 
@@ -1055,13 +1184,19 @@ def _linear_state(q, k, v, g, beta, s_all: jnp.ndarray, layer,
 def _linear_out(o: jnp.ndarray, x: jnp.ndarray, lp: Params,
                 cfg: ModelConfig) -> jnp.ndarray:
     """``o [T, H, d]`` f32 -> [T, E]: RMS norm a head, times the low-rank
-    gate ``sigmoid(x W_g1 W_g2)`` of the sublayer's normed input ``x``,
-    then the output projection."""
+    gate ``sigmoid(x W_g1 W_g2)`` of the sublayer's normed input ``x`` (the
+    Gated DeltaNet form: ``cfg.linear_gate_scale sigmoid(x W_z)``, a full
+    projection), then the output projection."""
     t = o.shape[0]
-    gate = jax.nn.sigmoid(qeinsum(
-        "tr,rq->tq", qeinsum("te,er->tr", x, lp["w_g1"]), lp["w_g2"]
-    ).astype(jnp.float32)).reshape(o.shape)
-    y = rms_norm(o, lp["o_norm"], cfg.rms_norm_eps) * gate
+    if cfg.linear_head_decay:
+        gate = cfg.linear_gate_scale * jax.nn.sigmoid(qeinsum(
+            "te,eq->tq", x, lp["w_z"]).astype(jnp.float32)).reshape(o.shape)
+    else:
+        gate = jax.nn.sigmoid(qeinsum(
+            "tr,rq->tq", qeinsum("te,er->tr", x, lp["w_g1"]), lp["w_g2"]
+        ).astype(jnp.float32)).reshape(o.shape)
+    y = rms_norm(o, lp["o_norm"],
+                 cfg.linear_norm_eps or cfg.rms_norm_eps) * gate
     return qeinsum("tq,qe->te", y.astype(x.dtype).reshape(t, cfg.linear_dim),
                    lp["wo"])
 
@@ -1070,17 +1205,22 @@ def _mixed_step_periods(params, cfg, cache, tables, win_tables, tokens,
                         token_slot, token_pos, sample_src, seq_q_start,
                         seq_q_len, seq_pos_start, mesh):
     """:func:`mixed_step` for a model whose layers come in periods: the
-    head's stack (``cfg.head_layers`` full-attention layers: a dense prefix,
-    or layer 0), then a scan over the periods, each the period's INNER
-    layers (an inner scan that takes them out of their stack by index) and
-    its full layer, then the tail of inner layers behind the last whole
-    period.  The inner kind is the model's: window layers
+    head's stack (``cfg.head_layers`` layers: a dense prefix, or layer 0;
+    full-attention layers, or linear ones where ``cfg.linear_head``), then a
+    first period cut short by the prefix where the model has one
+    (``cfg.short_period``), then a scan over the periods, each the period's
+    INNER layers (an inner scan that takes them out of their stack by
+    index) and its full layer, then the tail of inner layers behind the
+    last whole period.  The inner kind is the model's: window layers
     (``cfg.windowed``), which write and read the window pool (``cache.win``)
     through ``win_tables``, the same ragged launch told the window; or
     linear layers (``cfg.linear``), which read and write the slots' state
-    (``cache.lin``) and no page.  Full layers write and read the full pool
-    through ``tables``.  Returns (logits, cache, held_pairs)."""
-    from arks_tpu.ops.attention import paged_mixed_update_and_attend
+    (``cache.lin``) and no page.  The full kind is the model's too: GQA
+    layers, or latent layers (``cfg.latent``) over the latent pool; both
+    write and read the full pool through ``tables``.  Returns (logits,
+    cache, held_pairs)."""
+    from arks_tpu.ops.attention import (paged_latent_update_and_attend,
+                                        paged_mixed_update_and_attend)
     t_flat = tokens.shape[0]
     cover = tables.shape[1] * cache.page
     rope_pos = jnp.minimum(token_pos, cover - 1)[None]           # [1, T]
@@ -1097,7 +1237,19 @@ def _mixed_step_periods(params, cfg, cache, tables, win_tables, tokens,
             y, held = _mlp(h, lp, cfg, mesh, None, row_valid=valid)
         else:
             y, held = _mlp(h, lp, cfg, mesh, None), jnp.int32(0)
-        return h + y, held
+        return h + _post_norm(y, lp, "mlp_post_norm", cfg), held
+
+    def latent_layer(h, lp, pool, tbl, index, window):
+        del window
+        x = _norm(h, lp["attn_norm"], cfg)
+        attn, k = paged_latent_update_and_attend(
+            _mla_q(x, lp, cfg, rope_pos)[0], _mla_kv(x, lp, cfg, rope_pos)[0],
+            pool[0], tbl, token_slot, token_pos, seq_q_start, seq_q_len,
+            seq_pos_start, index, dv=cfg.kv_lora_rank,
+            scale=cfg.softmax_scale)
+        y = _mla_out(attn, lp, cfg, x[0])[None]
+        h, held = ffn(h + _post_norm(y, lp, "attn_post_norm", cfg), lp)
+        return h, (k,) + tuple(pool[1:]), held
 
     def layer(h, lp, pool, tbl, index, window: bool):
         q, k, v, gate = _kind_qkv(h, lp, cfg, rope_pos, window)
@@ -1123,14 +1275,15 @@ def _mixed_step_periods(params, cfg, cache, tables, win_tables, tokens,
     def linear_layer(h, lp, lin, tbl, index, window):
         del tbl, window
         s_all, conv_all = lin
-        x = rms_norm(h[0], lp["attn_norm"], cfg.rms_norm_eps)
+        x = _norm(h[0], lp["attn_norm"], cfg)
         q, k, v, g, beta, conv = _linear_qkv(
             x, lp, cfg, jax.lax.dynamic_index_in_dim(
                 conv_all, index, 0, keepdims=False),
             seq_q_start, seq_q_len, fresh)
         o, s_all = _linear_state(q, k, v, g, beta, s_all, index,
                                  seq_q_start, seq_q_len, fresh)
-        h = h + _linear_out(o, x, lp, cfg)[None]
+        h = h + _post_norm(_linear_out(o, x, lp, cfg)[None], lp,
+                           "attn_post_norm", cfg)
         lin = (s_all,
                jax.lax.dynamic_update_index_in_dim(conv_all, conv, index, 0))
         h, held = ffn(h, lp)
@@ -1143,11 +1296,28 @@ def _mixed_step_periods(params, cfg, cache, tables, win_tables, tokens,
     else:
         inner, inner_layer = tuple(cache.win[:4]), layer
         inner_stack, inner_tbl = params["win_layers"], win_tables
+    full_layer = latent_layer if cfg.latent else layer
     held = jnp.int32(0)
-    if first:
+    # Where the head's layers are linear layers, the stacked inner layers'
+    # state sits behind theirs, and the full pool starts at the first
+    # period's layer.  (An offset of zero is left out of the traced index
+    # arithmetic below, so that the older blocks lower the text they did.)
+    inner_base = first if cfg.linear_head else 0
+    full_base = 0 if cfg.linear_head else first
+    if first and cfg.linear_head:
+        def head_body(carry, xs):
+            h, inner = carry
+            h, inner, n = linear_layer(h, xs[0], inner, None, xs[1], True)
+            return (h, inner), n
+
+        (h, inner), n = jax.lax.scan(
+            head_body, (h, inner),
+            (params[head_name], jnp.arange(first, dtype=jnp.int32)))
+        held = held + jnp.sum(n)
+    elif first:
         def head_body(carry, xs):
             h, full = carry
-            h, full, n = layer(h, xs[0], full, tables, xs[1], False)
+            h, full, n = full_layer(h, xs[0], full, tables, xs[1], False)
             return (h, full), n
 
         (h, full), n = jax.lax.scan(
@@ -1167,26 +1337,44 @@ def _mixed_step_periods(params, cfg, cache, tables, win_tables, tokens,
             at = start + j
             lp = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
                 a, at, 0, keepdims=False), inner_stack)
-            h, inner, n = inner_layer(h, lp, inner, inner_tbl, at, True)
+            h, inner, n = inner_layer(
+                h, lp, inner, inner_tbl,
+                at + inner_base if inner_base else at, True)
             return (h, inner), n
 
         (h, inner), n = jax.lax.scan(
             inner_body, (h, inner), jnp.arange(count, dtype=jnp.int32))
         return h, inner, jnp.sum(n)
 
+    # A first period cut short by the dense prefix: its inner layers, then
+    # the first of the stacked full layers; the scan takes the rest.
+    lead, periods = cfg.lead_layers, params["layers"]
+    if lead:
+        if cfg.short_period:
+            h, inner, n = inner_layers(h, inner, 0, cfg.short_period)
+            held = held + n
+        h, full, n = full_layer(
+            h, jax.tree.map(lambda a: a[0], periods), full, tables,
+            full_base, False)
+        held = held + n
+        periods = jax.tree.map(lambda a: a[1:], periods)
+        full_base += 1
+    inner0 = max(cfg.short_period, 0)
+
     def period_body(carry, xs):
         h, full, inner = carry
         flp, i = xs
-        h, inner, n = inner_layers(h, inner, i * per, per)
-        h, full, m = layer(h, flp, full, tables, first + i, False)
+        h, inner, n = inner_layers(
+            h, inner, i * per + inner0 if inner0 else i * per, per)
+        h, full, m = full_layer(h, flp, full, tables, full_base + i, False)
         return (h, full, inner), n + m
 
     (h, full, inner), n = jax.lax.scan(
         period_body, (h, full, inner),
-        (params["layers"], jnp.arange(cfg.num_periods, dtype=jnp.int32)))
+        (periods, jnp.arange(cfg.num_periods, dtype=jnp.int32)))
     held = held + jnp.sum(n)
     if cfg.inner_tail:
-        h, inner, n = inner_layers(h, inner, cfg.num_periods * per,
+        h, inner, n = inner_layers(h, inner, inner0 + cfg.num_periods * per,
                                    cfg.inner_tail)
         held = held + n
     with _scope("arks.lm_head"):
@@ -1703,12 +1891,13 @@ def mixed_step(
 
     A latent model (``cfg.latent``) runs :func:`_mixed_step_latent` over
     its latent pool, a model with window layers (``cfg.windowed``) or
-    linear-attention layers (``cfg.linear``) :func:`_mixed_step_periods`
+    linear-attention layers (``cfg.linear``; beside GQA or latent layers)
+    :func:`_mixed_step_periods`
     over its two pools (the window layers' through ``win_tables``) or its
     pool and its slots' state; ``with_held`` (those three only) adds the
     third result, the count of routed pairs that landed on experts held
     here."""
-    if cfg.latent:
+    if cfg.latent and not cfg.linear:
         out = _mixed_step_latent(params, cfg, cache, tables, tokens,
                                  token_slot, token_pos, sample_src,
                                  seq_q_start, seq_q_len, seq_pos_start, mesh)

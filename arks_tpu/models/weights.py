@@ -78,8 +78,21 @@ class LinearCheckpointError(NotImplementedError):
     stacking layers of two kinds into one tree."""
 
 
+class LatentLinearCheckpointError(NotImplementedError):
+    """A checkpoint of a model with gated-delta-rule linear layers beside
+    gated latent-attention layers (``gigachat3_5``): the name mapping of its
+    fused q | k | v projection and convolution, its decay, gate and
+    sandwich-norm tensors, its latent projections and its expert tensors
+    onto the three stacked trees (``dense_layers`` / ``layers`` /
+    ``lin_layers``) is not built; such a model is served from seeded random
+    weights only.  Raised instead of stacking layers of two kinds into one
+    tree."""
+
+
 def _refuse_latent(cfg: ModelConfig, path: str) -> None:
-    for is_kind, err in ((cfg.latent, LatentCheckpointError),
+    for is_kind, err in ((cfg.latent and cfg.linear,
+                          LatentLinearCheckpointError),
+                         (cfg.latent, LatentCheckpointError),
                          (cfg.windowed, WindowedCheckpointError),
                          (cfg.linear, LinearCheckpointError)):
         if is_kind:

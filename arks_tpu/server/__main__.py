@@ -338,7 +338,9 @@ def build_server(args: argparse.Namespace, engine):
             f"model {engine.cfg.name!r} (latent attention): "
             f"--disaggregation-mode {args.disagg} hands K and V blocks "
             "between pods (kv_transfer, the AKV1 format); a latent page "
-            "is not carried")
+            "is not carried"
+            + (", nor the recurrent state of its linear layers"
+               if engine.cfg.linear else ""))
     if args.disagg and engine.cfg.windowed:
         raise ValueError(
             f"model {engine.cfg.name!r} (window and full attention layers): "

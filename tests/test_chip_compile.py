@@ -300,7 +300,7 @@ def test_quantised_experts_compile_for_v5e_as_stored(chip, kind, rows):
         num_experts=x, num_experts_per_tok=2, router_width=x,
         expert_parallel_size=1, expert_parallel_rank=0,
         scoring_func="softmax", norm_topk_prob=True,
-        routed_scaling_factor=1.0)
+        routed_scaling_factor=1.0, swiglu_limit=0.0)
 
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
@@ -354,3 +354,55 @@ def test_the_delta_rules_state_update_compiles_for_v5e_in_place(chip, rows):
     state = layers * slots * h * d * d * 4
     assert mem.alias_size_in_bytes >= state
     assert mem.temp_size_in_bytes < state // layers + 300e6
+
+
+@pytest.mark.parametrize("rows", [0, 1024])
+def test_a_whole_latent_linear_step_compiles_for_v5e_in_place(
+        chip, rows, monkeypatch):
+    """``mixed_step`` of the ``gigachat3_5`` block at GigaChat3.5-432B's
+    published widths, this chip's share (32 of 256 experts, 5 layers: 7.6
+    GB of int8 weights), on the cell's two step shapes over 64 slots x 80
+    pages: the latent kernel over a block table of 80 pages, the chunked
+    scan with a decay a head, the period scan with a latent full layer and
+    a linear head.  The chip's compiler takes both; the state and the
+    latent pool (2.8 GB) are rewritten in place, and a step's temporaries
+    leave room beside 10.4 GB of weights and caches on a 16 GB chip."""
+    import os
+    from arks_tpu.models import quant, transformer as tf
+    from arks_tpu.models.config import ModelConfig
+
+    # The ops ask these two which branch to trace: the chip's.
+    monkeypatch.setenv("ARKS_ATTN_IMPL", "pallas")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cfg = ModelConfig.from_hf_config(os.path.join(
+        root, "benchmarks", "configs", "gigachat3.5-432b-ep8-l5"),
+        name="g").with_expert_share(8, 0)
+    slots, page, max_pages = 64, 256, 80
+    t = slots + rows
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip)
+
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: quant.init_params_quantized(
+            cfg, jax.random.PRNGKey(0), jnp.bfloat16, bits=8)))
+    cache = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: tf.init_paged_cache(cfg, slots * max_pages, page,
+                                    jnp.bfloat16, pad_head=True,
+                                    state_slots=slots)))
+    assert cache.k.shape == (1, slots * max_pages, 1, page, 640)
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
+
+    compiled = jax.jit(
+        lambda p, c, *a: tf.mixed_step(p, cfg, c, *a, with_held=True),
+        donate_argnums=(1,)).lower(
+        params, cache, ints(slots, max_pages), ints(t), ints(t), ints(t),
+        ints(slots), ints(slots), ints(slots), ints(slots)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 2   # write, attend
+    mem = compiled.memory_analysis()
+    held = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
+    assert mem.alias_size_in_bytes >= held
+    assert mem.temp_size_in_bytes < (2.0e9 if rows else 0.2e9)

@@ -31,6 +31,15 @@ batch is smaller than the step (``@wide``: 2 + 512 rows, top-4 of 32
 scored, a batch of 384): these four pins are what commit c459215 (PR 33,
 the parent of PR 36) lowers at the same shape, taken with this file in
 that tree, so at such a shape PR 36's rule changes nothing.
+
+PR 40 ADDED six pins and moved none: ``tiny-linear-moe`` (the
+``solar_open2`` block) at both shapes, the values of commit 47905df (PR 39,
+the parent of PR 40), taken with this file in that tree.  PR 40 made the
+latent block the period scan's full-layer kind, let the head stack take
+linear layers, and taught ``_linear_qkv`` / ``_linear_out`` a decay a head
+and grouped key heads: every such branch is decided by the configuration
+while the program is traced, and the three presets that share that code
+lower the text they lowered before.
 """
 
 import hashlib
@@ -58,6 +67,12 @@ PINS = {
     "tiny-mla-moe@wide.seq_lp": "901dc7ce4f3539c5",
     "tiny-swa-moe@wide.seq": "93e12f3a65d1e090",
     "tiny-swa-moe@wide.seq_lp": "d37658bc2680eb1c",
+    "tiny-linear-moe.seq": "ad705e220dc61be9",
+    "tiny-linear-moe.seq_lp": "cbbc508ace23f502",
+    "tiny-linear-moe.pipe": "2cd57798e1d25373",
+    "tiny-linear-moe.pipe_lp": "f0e23e787d9b184b",
+    "tiny-linear-moe@wide.seq": "5acf73851d3932a7",
+    "tiny-linear-moe@wide.seq_lp": "61204f2803645f85",
 }
 
 
