@@ -774,6 +774,13 @@ class EngineMetrics:
             "Bytes held for live sequences summed over dispatches, by kind "
             "(state: linear_state_bytes; pages: kv_page_bytes), a model "
             "with linear-attention layers")
+        self.linear_state_lane_steps_total = r.counter(
+            "linear_state_lane_steps_total",
+            "Slots summed over dispatches by what the linear layers' state "
+            "update did with them (step: a lane of one row, the one-step "
+            "kernel's list; chunk: a lane of more rows, the chunked scan; "
+            "idle: no row, the state neither read nor written), a model "
+            "with linear-attention layers")
         self.mixed_kv_bytes_ideal_total = r.counter(
             "mixed_kv_bytes_ideal_total",
             "KV bytes a perfect once-per-page schedule would stream for "
@@ -3300,6 +3307,16 @@ class InferenceEngine:
         m.kv_page_bytes.set(pages)
         m.kv_held_byte_steps_total.inc(state, kind="state")
         m.kv_held_byte_steps_total.inc(pages, kind="pages")
+
+    def _count_state_lanes(self, step: int, chunk: int) -> None:
+        """Beside :meth:`_count_state_bytes`, once the dispatch's rows a
+        slot are known: how many slots the state update's kernel steps
+        (``step``: one row), how many the chunked scan walks (``chunk``),
+        and the rest, which neither touches."""
+        c = self.metrics.linear_state_lane_steps_total
+        c.inc(step, path="step")
+        c.inc(chunk, path="chunk")
+        c.inc(self.ecfg.num_slots - step - chunk, path="idle")
 
     def _resolve_kv_layout(self) -> bool:
         layout = self.ecfg.kv_layout
@@ -8157,6 +8174,9 @@ class InferenceEngine:
         spec = self._draft_cfg is not None
         if self._paged:
             self._grow_slot_pages(K, ahead=len(self._pipe_inflight))
+        if self._lin_slot_bytes:
+            # (A pipelined dispatch is the decoding slots' row each.)
+            self._count_state_lanes(len(self._slots), 0)
         self._ensure_guides_uploaded()
         self._faults.fire("spec" if spec else "decode")
         if fresh:
@@ -8963,6 +8983,10 @@ class InferenceEngine:
         if budget and (self._prefilling or self._queue.qsize() > 0):
             self.metrics.mixed_chunk_budget_tokens_total.inc(budget)
         self._mixed_grid_counters(a["seq_pos_start"], a["seq_q_len"], qmax)
+        if self._lin_slot_bytes:
+            q_len = a["seq_q_len"]
+            self._count_state_lanes(int((q_len == 1).sum()),
+                                    int((q_len > 1).sum()))
         if sec:
             self.trace.evt("", tag + "count", "E")
 

@@ -33,6 +33,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from arks_tpu.models.config import ModelConfig
 from arks_tpu.models.quant import embed_lookup, qeinsum, unembed_logits
 from arks_tpu.ops.attention import decode_update_and_attend, prefill_attention
+from arks_tpu.ops.linear_state import linear_state_step
 from arks_tpu.ops.norms import rms_norm
 from arks_tpu.ops.rope import apply_rope
 
@@ -1116,31 +1117,24 @@ def _linear_state(q, k, v, g, beta, s_all: jnp.ndarray, layer,
     state ``s_all[layer, b]`` (``s_all [Ll, B, H, d, d]`` float32, rewritten
     in place; read as zeros where ``fresh``).  A lane
     of ONE row (a decode lane, a prompt's last token) takes one recurrence
-    step, all such lanes in one elementwise pass over the states; a lane of
-    more rows is walked in blocks of ``LINEAR_CHUNK`` rows by
+    step, all such lanes in one kernel over their slots' states
+    (:func:`arks_tpu.ops.linear_state.linear_state_step`: a state is read
+    once and written once, no other slot's is touched, and the lanes' rows
+    go from the flat batch into the kernel and back by their index); a
+    lane of more rows is walked in blocks of ``LINEAR_CHUNK`` rows by
     :func:`_delta_chunk`, block after block and lane after lane, as many
     trips as the batch holds blocks.  Returns (o [T, H, d] f32,
     ``s_all``)."""
     t, h, d = q.shape
     f32 = jnp.float32
-    state = jax.lax.dynamic_index_in_dim(
-        s_all, layer, 0, keepdims=False).astype(f32)
     c = LINEAR_CHUNK
-    # -- the lanes of one row: one pass over the states ------------------
+    # -- the lanes of one row: one kernel over their slots' states --------
     one = seq_q_len == 1
-    at = jnp.clip(seq_q_start, 0, t - 1)
-    q1, k1, v1 = (jnp.take(x, at, axis=0).astype(f32) for x in (q, k, v))
-    a1 = jnp.exp(jnp.take(g, at, axis=0))                          # [B, H, d]
-    b1 = jnp.take(beta, at, axis=0)                                # [B, H]
-    s_dec = a1[..., None] * jnp.where(fresh[:, None, None, None], 0.0, state)
-    u1 = b1[..., None] * (v1 - jnp.sum(s_dec * k1[..., None], axis=2))
-    s_one = s_dec + k1[..., None] * u1[:, :, None, :]
-    o1 = jnp.sum(s_one * q1[..., None], axis=2)                    # [B, H, d]
-    s_all = jax.lax.dynamic_update_index_in_dim(
-        s_all, jnp.where(one[:, None, None, None], s_one, state).astype(
-            s_all.dtype), layer, 0)
-    out = jnp.zeros((t + c, h, d), f32).at[
-        jnp.where(one, at, t + c)].set(o1, mode="drop")
+    out, s_all = linear_state_step(
+        q, k, v, g, beta, s_all, layer,
+        jnp.flatnonzero(one, size=one.shape[0], fill_value=0), jnp.sum(one),
+        fresh, jnp.clip(seq_q_start, 0, t - 1), pad=c,
+        interpret=jax.default_backend() != "tpu")
 
     # -- the lanes of more rows: blocks of C rows, in order ---------------
     blocks = jnp.where(seq_q_len > 1, -(-seq_q_len // c), 0)       # [B]

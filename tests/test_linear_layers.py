@@ -605,6 +605,77 @@ def test_the_pipelined_path_gives_the_sequential_streams(depth0_streams,
         eng.stop()
 
 
+@pytest.mark.parametrize("depth", ["0", "2"])
+def test_the_engine_counts_the_slots_the_state_update_steps_walks_and_skips(
+        depth, monkeypatch):
+    """``linear_state_lane_steps_total{path}``, once a dispatch from the
+    dispatch's own rows a slot: ``step`` rises by the lanes of one row (the
+    one-step kernel's list: the decoding slots), ``chunk`` by the lanes of
+    more rows (the prefilling ones) and ``idle`` by the rest."""
+    monkeypatch.setenv("ARKS_PIPELINE_DEPTH", depth)
+    eng = _engine()
+    c = eng.metrics.linear_state_lane_steps_total
+    slots = eng.ecfg.num_slots
+    seen, emit = [], eng._emit
+
+    def spy(op, **payload):
+        if op == "mixed":
+            q_len = payload["seq_q_len"]
+            seen.append((int((q_len == 1).sum()), int((q_len > 1).sum()),
+                         tuple(c.get(path=p) for p in ("step", "chunk",
+                                                       "idle"))))
+        return emit(op, **payload)
+
+    monkeypatch.setattr(eng, "_emit", spy)
+    try:
+        assert "linear_state_lane_steps_total{" not in \
+            eng.metrics.registry.render()
+        if depth == "2":
+            assert eng._pipe_warm_wait(600.0) == "ready"
+        toks, _ = _drain(eng, _requests())
+        assert all(len(t) == 10 for t in toks.values())
+        # A sequential dispatch: counted in its ``count`` section, before
+        # it is emitted, by what it emits.
+        before = (0, 0, 0)
+        piped = 0
+        for step, chunk, after in seen:
+            rose = tuple(a - b for a, b in zip(after, before))
+            # (Pipelined dispatches in between: a row a decoding slot.)
+            n = (sum(rose) - slots) // slots
+            piped += n
+            assert sum(rose) == (n + 1) * slots
+            assert rose[1] == chunk and rose[0] >= step
+            assert depth == "2" or rose == (step, chunk,
+                                            slots - step - chunk)
+            before = after
+        assert any(chunk == 2 for _, chunk, _ in seen)     # two prompts
+        assert any((step, chunk) == (1, 1) for step, chunk, _ in seen)
+        assert any((step, chunk) == (2, 0) for step, chunk, _ in seen) \
+            or piped
+        assert (piped > 0) == (depth == "2")
+        # Each request's nine tokens behind its first are a row of its own.
+        assert c.get(path="step") >= 27
+        text = eng.metrics.registry.render()
+        assert all(f'linear_state_lane_steps_total{{path="{p}"}}' in text
+                   for p in ("step", "chunk", "idle"))
+    finally:
+        eng.stop()
+
+
+def test_a_pod_without_linear_layers_has_no_lane_steps_to_count():
+    eng = _engine(get_config("tiny"), kv_layout="paged")
+    try:
+        from arks_tpu.engine.types import Request, SamplingParams
+        _drain(eng, [Request("r", list(range(2, 22)), SamplingParams(
+            max_tokens=3, temperature=0.0, ignore_eos=True))])
+        text = eng.metrics.registry.render()
+        assert "mixed_batch_tokens_count" in text
+        assert "linear_state_lane_steps_total{" not in text
+        assert "kv_held_byte_steps_total{" not in text
+    finally:
+        eng.stop()
+
+
 @pytest.mark.parametrize("over, env, word", [
     (dict(kv_layout="slot"), {}, "slot layout"),
     (dict(prefill_chunk=None), {}, "chunked prefill"),

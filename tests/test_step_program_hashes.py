@@ -40,6 +40,16 @@ linear layers, and taught ``_linear_qkv`` / ``_linear_out`` a decay a head
 and grouped key heads: every such branch is decided by the configuration
 while the program is traced, and the three presets that share that code
 lower the text they lowered before.
+
+PR 41 RE-PINNED those six and moved none of the sixteen others: the lanes
+of one row of ``transformer.py::_linear_state`` take their recurrence step
+in a Pallas kernel now (``ops/linear_state.py``; on the CPU its interpreted
+form is what these programs hold) where a ``jax.numpy`` pass over every
+slot's state stood, so every program of a model WITH linear layers lowers
+other text, as it should.  ``_linear_state`` has one caller, reached only
+where ``cfg.linear``: ``tiny``, ``tiny-mla-moe``, ``tiny-swa-moe`` and their
+``@wide`` shapes hold no such layer and stand at the values they had.  A
+later edit of the kernel's body moves these six again, and re-pins them.
 """
 
 import hashlib
@@ -67,12 +77,12 @@ PINS = {
     "tiny-mla-moe@wide.seq_lp": "901dc7ce4f3539c5",
     "tiny-swa-moe@wide.seq": "93e12f3a65d1e090",
     "tiny-swa-moe@wide.seq_lp": "d37658bc2680eb1c",
-    "tiny-linear-moe.seq": "ad705e220dc61be9",
-    "tiny-linear-moe.seq_lp": "cbbc508ace23f502",
-    "tiny-linear-moe.pipe": "2cd57798e1d25373",
-    "tiny-linear-moe.pipe_lp": "f0e23e787d9b184b",
-    "tiny-linear-moe@wide.seq": "5acf73851d3932a7",
-    "tiny-linear-moe@wide.seq_lp": "61204f2803645f85",
+    "tiny-linear-moe.seq": "9b3850f805af0238",
+    "tiny-linear-moe.seq_lp": "f477da4485ef9839",
+    "tiny-linear-moe.pipe": "492eae6804404b2a",
+    "tiny-linear-moe.pipe_lp": "68b2e433cd58d959",
+    "tiny-linear-moe@wide.seq": "9a577bfcd3314ce9",
+    "tiny-linear-moe@wide.seq_lp": "090ea30fca2bdbe6",
 }
 
 
