@@ -707,6 +707,15 @@ class EngineMetrics:
             "moe_held_pairs_total",
             "(token, expert) pairs of mixed dispatches whose expert is "
             "held by this chip's share of the layer (latent models)")
+        # What a share's batched dispatch ran behind its experts' fixed
+        # batches (models/moe.py): needed / dispatches / routed layers is
+        # the tiles a layer's routers asked for (_SPARE_TILES run in any
+        # case), extra > 0 the steps whose time followed the router.
+        self.moe_overflow_tiles_total = r.counter(
+            "moe_overflow_tiles_total",
+            "Overflow tiles of a share's routed layers in mixed dispatches "
+            "(needed: rows beyond an expert's fixed batch, in tiles; extra: "
+            "those beyond the spare tiles, run in a loop)")
         # Query rows one mixed dispatch lays out for the attention kernel
         # (the plan's nb x block_q under the ragged grid's block-compacted
         # layout; lanes x the padded widest chunk under the dense grid), to
@@ -1432,8 +1441,9 @@ class InferenceEngine:
             self._windowed_preflight(cfg, engine_cfg, draft_cfg)
         if cfg.linear:
             self._linear_preflight(cfg, engine_cfg, draft_cfg)
-        # The step returns two counts beside its token ids (held pairs,
-        # valid rows): _count_held.
+        # The step returns four counts beside its token ids (held pairs,
+        # a share's overflow tiles needed and looped, valid rows):
+        # _count_held.
         self._held_stat = bool((cfg.latent or cfg.windowed or cfg.linear)
                                and cfg.num_experts)
         # Per-model KV dtype preference: a checkpoint that ships
@@ -2573,12 +2583,13 @@ class InferenceEngine:
 
             def with_counts(ids, held, valid):
                 """The step's token ids with, for a latent routed model,
-                two counts behind them (held pairs, valid rows): they
+                four counts behind them (held pairs, overflow tiles
+                needed, those of them the loop ran, valid rows): they
                 ride the ids' transfer (_count_held)."""
                 if not held_stat:
                     return ids
-                return jnp.concatenate([ids, jnp.stack(
-                    [held[0], jnp.sum(valid).astype(jnp.int32)])])
+                return jnp.concatenate(
+                    [ids, held[0], jnp.sum(valid).astype(jnp.int32)[None]])
 
             self._mixed_pack = _OperandPack(self._mixed_fields(
                 self.ecfg.num_slots + self._mixed_budget))
@@ -8719,18 +8730,22 @@ class InferenceEngine:
         self.metrics.kv_pages_reserved.set(sum(self._pool_reserved.values()))
 
     def _count_held(self, ids: np.ndarray) -> None:
-        """A latent routed model's step hands back two counts behind its
-        token ids (the last two entries: (token, expert) pairs that landed
-        on experts held here, and the rows that carried a token): the
-        three counters of docs/monitoring.md, from values already on the
-        host."""
-        held, rows = int(ids[-2]), int(ids[-1])
+        """A latent routed model's step hands back four counts behind its
+        token ids (the last four entries: (token, expert) pairs that landed
+        on experts held here, the overflow tiles its layers' batched
+        dispatch needed, those of them beyond the spare ones, and the rows
+        that carried a token): the counters of docs/monitoring.md, from
+        values already on the host."""
+        held, needed, extra, rows = (int(v) for v in ids[-4:])
         cfg = self.cfg
         self.metrics.mixed_latent_rows_total.inc(
             rows * (cfg.num_full_layers if cfg.latent else cfg.num_layers))
         self.metrics.moe_routed_pairs_total.inc(
             rows * cfg.num_experts_per_tok * cfg.num_routed_layers)
         self.metrics.moe_held_pairs_total.inc(held)
+        if cfg.expert_parallel_size > 1:
+            self.metrics.moe_overflow_tiles_total.inc(needed, kind="needed")
+            self.metrics.moe_overflow_tiles_total.inc(extra, kind="extra")
 
     def _kv_mover_refusals(self, ecfg: "EngineConfig", draft_cfg,
                            moves: str, mesh_why: str) -> list[str]:

@@ -53,6 +53,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 Params = dict
 
@@ -217,29 +218,43 @@ def _expert_dot(eq: str, x: jnp.ndarray, w) -> jnp.ndarray:
 
 
 def _batched_dispatch(x2: jnp.ndarray, vals: jnp.ndarray, idx: jnp.ndarray,
-                      mp: Params, cfg, row_valid: jnp.ndarray | None = None
-                      ) -> tuple[jnp.ndarray, jnp.ndarray]:
+                      mp: Params, cfg, row_valid: jnp.ndarray | None = None,
+                      stack: tuple | None = None
+                      ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """The routed experts HELD HERE (a share's, or all of a layer held
-    whole) on ``[n, E]`` rows: ``(out [n, E], held [n, k] bool)``.  Pairs
-    are sorted by held expert (absent experts and the rows that carry no
-    token last, never gathered: a step's padding rows are all alike and
-    would all land on the same experts).  Every held expert then takes
-    its first :func:`_held_capacity` rows in ONE ``[X, C, E]`` batched
-    SwiGLU, the int8 convert fused into the contraction: work that does
-    not depend on how the router spread the tokens, so a step's time is
-    the same from seed to seed.  What an expert draws beyond C rows
+    whole) on ``[n, E]`` rows: ``(out [n, E], held [n, k] bool, tiles)``.
+    Pairs are sorted by held expert (absent experts and the rows that
+    carry no token last, never gathered: a step's padding rows are all
+    alike and would all land on the same experts).  Every held expert then
+    takes its first :func:`_held_capacity` rows in ONE ``[X, C, E]``
+    batched SwiGLU, the int8 convert fused into the contraction: work that
+    does not depend on how the router spread the tokens, so a step's time
+    is the same from seed to seed.  What an expert draws beyond C rows
     follows in tiles of C rows, one expert a tile, each a read of that
-    expert's weights.  A share runs as many tiles as its experts need in
-    a loop, ``_SPARE_TILES`` of them in any case (dead where not needed),
-    so that only a layer that needs more than those takes longer.  A
-    layer held whole knows how many pairs it holds, all ``n x k``, so
-    ``(n x k - 1) // C`` tiles always suffice: it runs exactly those,
-    unrolled (a loop would have the compiler copy the expert stacks into
-    its carry), and an expert that draws every row is as exact and costs
-    the step the same as a uniform router."""
+    expert's weights.  A count of tiles fixed by the shape runs unrolled
+    (dead where not needed), so that an expert's slice fuses into the
+    dots' operand reads.  A layer held whole knows how many pairs it
+    holds, all ``n x k``, so ``(n x k - 1) // C`` tiles always suffice,
+    and an expert that draws every row is as exact and costs the step the
+    same as a uniform router.  A share runs ``_SPARE_TILES``, and what its
+    experts need beyond those in a loop, so that only a layer that needs
+    more takes longer and nothing is ever dropped; ``tiles`` (a share's;
+    None of a layer held whole) is int32 ``[2]``: the overflow tiles the
+    layer needed, and the trips that loop made.
+
+    A tile takes its expert's weights out of ``stack`` = ``(tree, layer)``,
+    the STACKED tree that the program was handed and the index at which
+    ``mp`` lies in it (None: ``mp`` is a lone layer, a stack with no
+    leading index), by one ``dynamic_slice`` at ``(layer, expert)``: an
+    operand of a ``while`` has to be a buffer, the stack is one already (a
+    parameter of the program), and a layer's slice of it would be made one
+    first, every expert leaf copied whole a layer a step (PERF.md §6,
+    PR 44)."""
     n, e = x2.shape
     k, nx = cfg.num_experts_per_tok, cfg.num_experts
+    share = cfg.expert_parallel_size > 1
     cap = _held_capacity(n, cfg)
+    tree, lead = (mp, ()) if stack is None else (stack[0], (stack[1],))
     with jax.named_scope("arks.moe_route"):
         local = idx - held_first(cfg)
         held = (local >= 0) & (local < nx)
@@ -281,21 +296,34 @@ def _batched_dispatch(x2: jnp.ndarray, vals: jnp.ndarray, idx: jnp.ndarray,
             ex = jnp.minimum(jnp.searchsorted(tile_ends, t, side="right"),
                              nx - 1)
             nth = t - (jnp.take(tile_ends, ex) - jnp.take(tiles, ex))
-            one = {name: jax.tree.map(
-                lambda a: jax.lax.dynamic_index_in_dim(a, ex, 0), mp[name])
-                for name in ("w_gate", "w_up", "w_down")}
+
+            def expert(a):
+                """``a [*lead, X, ..]`` -> ``[1, ..]``, the expert's."""
+                at = len(lead) + 1
+                start = [np.zeros([], ex.dtype)] * a.ndim
+                start[:at] = [*lead, ex]
+                return jax.lax.dynamic_slice(
+                    a, start, (1,) * at + a.shape[at:],
+                    allow_negative_indices=[i < at for i in range(a.ndim)]
+                ).reshape((1,) + a.shape[at:])
+
+            one = {name: jax.tree.map(expert, tree[name])
+                   for name in ("w_gate", "w_up", "w_down")}
         return slots(out, ex[None], (cap * (1 + nth))[None], one)
 
     out = slots(jnp.zeros((n, e), x2.dtype), jnp.arange(nx),
                 jnp.zeros((nx,), jnp.int32), mp)
-    if cfg.expert_parallel_size > 1:
-        spare = _SPARE_TILES if cap < n else 0
-        out = jax.lax.fori_loop(0, jnp.maximum(tile_ends[-1], spare),
-                                overflow_tile, out)
-    else:
-        for t in range((n * k - 1) // cap if cap < n else 0):
-            out = overflow_tile(jnp.int32(t), out)
-    return out, held
+    fixed = 0
+    if cap < n:
+        fixed = _SPARE_TILES if share else (n * k - 1) // cap
+    for t in range(fixed):
+        out = overflow_tile(jnp.int32(t), out)
+    if not share:
+        return out, held, None
+    needed = tile_ends[-1]
+    out = jax.lax.fori_loop(fixed, jnp.maximum(needed, fixed),
+                            overflow_tile, out)
+    return out, held, jnp.stack([needed, jnp.maximum(needed - fixed, 0)])
 
 
 def _shared_expert(x2: jnp.ndarray, mp: Params, cfg,
@@ -361,8 +389,15 @@ def _batch_pays(n_tokens: int, mp: Params, cfg) -> bool:
     return _held_capacity(n_tokens, cfg) < n_tokens
 
 
+def _counts(held_pairs: jnp.ndarray) -> jnp.ndarray:
+    """:func:`moe_ffn`'s counts of a layer that ran no overflow loop."""
+    return jnp.stack([held_pairs.astype(jnp.int32), jnp.int32(0),
+                      jnp.int32(0)])
+
+
 def moe_ffn_grouped(x: jnp.ndarray, mp: Params, cfg,
-                    row_valid: jnp.ndarray | None = None):
+                    row_valid: jnp.ndarray | None = None,
+                    stack: tuple | None = None):
     """Dropless grouped dispatch: top-k cost instead of all-expert cost.
 
     Flattens tokens, sorts the (token, slot) pairs by routed expert, runs
@@ -378,8 +413,8 @@ def moe_ffn_grouped(x: jnp.ndarray, mp: Params, cfg,
     > 1: the pairs whose expert lives on another chip are never gathered),
     run as :func:`_batched_dispatch`'s rounds of fixed size; the plain
     leaves of a layer held whole as :func:`_ragged_dispatch`.  ``row_valid``
-    [T] (rows that carry a token) makes the call return ``(out,
-    held_pairs)``: the valid rows' pairs that landed on a held expert."""
+    [T] (rows that carry a token) makes the call return ``(out, counts)``,
+    :func:`moe_ffn`'s; ``stack`` is :func:`_batched_dispatch`'s."""
     from arks_tpu.models.quant import is_quantized
     lead = x.shape[:-1]
     e = x.shape[-1]
@@ -394,7 +429,8 @@ def moe_ffn_grouped(x: jnp.ndarray, mp: Params, cfg,
         vals, idx = router_topk(logits, cfg, mp.get("router_bias"))  # [T, k]
 
     if share or is_quantized(mp["w_gate"]):
-        out, held = _batched_dispatch(x2, vals, idx, mp, cfg, row_valid)
+        out, held, tiles = _batched_dispatch(x2, vals, idx, mp, cfg,
+                                             row_valid, stack)
     else:
         out = _ragged_dispatch(x2, vals, idx, mp, cfg)
 
@@ -403,21 +439,29 @@ def moe_ffn_grouped(x: jnp.ndarray, mp: Params, cfg,
     out = out.reshape(*lead, e)
     if row_valid is None:
         return out
-    held_pairs = jnp.sum(held) if share else jnp.sum(row_valid) * k
-    return out, held_pairs.astype(jnp.int32)
+    if share:
+        return out, jnp.concatenate([jnp.sum(held)[None], tiles]
+                                    ).astype(jnp.int32)
+    return out, _counts(jnp.sum(row_valid) * k)
 
 
 def moe_ffn(x: jnp.ndarray, mp: Params, cfg, constrain=None,
             grouped: bool | None = None,
-            row_valid: jnp.ndarray | None = None):
+            row_valid: jnp.ndarray | None = None,
+            stack: tuple | None = None):
     """MoE feed-forward on [..., E] activations (works for [B, T, E] prefill
     and [B, E] decode).  ``constrain(t, expert_dim_index)`` optionally pins
     the expert dim of intermediates to the model axis.  ``grouped`` forces
     (True) or forbids (False) the dropless grouped path; None = auto (large
     unsharded token batches).  With ``row_valid`` (the leading dims of
-    ``x``, bool: rows that carry a token) the return is ``(out,
-    held_pairs)``: the valid rows' (token, expert) pairs that landed on an
-    expert held here (all of them where the layer is held whole)."""
+    ``x``, bool: rows that carry a token) the return is ``(out, counts)``,
+    int32 ``[3]``: the valid rows' (token, expert) pairs that landed on an
+    expert held here (all of them where the layer is held whole), the
+    overflow tiles a share's batched dispatch needed, and those of them
+    beyond the spare ones, which its loop ran (0 and 0 from the dense
+    dispatch and from a layer held whole).  ``stack`` = ``(tree, layer)``
+    says where ``mp`` lies in the stacked tree the program was handed
+    (:func:`_batched_dispatch`; None: a lone layer)."""
     if grouped is None:
         import math
         n_tokens = math.prod(x.shape[:-1])
@@ -428,18 +472,17 @@ def moe_ffn(x: jnp.ndarray, mp: Params, cfg, constrain=None,
                    and n_tokens >= _GROUPED_MIN_TOKENS
                    and _batch_pays(n_tokens, mp, cfg))
     if grouped:
-        return moe_ffn_grouped(x, mp, cfg, row_valid)
+        return moe_ffn_grouped(x, mp, cfg, row_valid, stack)
     from arks_tpu.models.quant import qeinsum
 
     with jax.named_scope("arks.moe_route"):
         logits = jnp.einsum("...e,ex->...x", x, mp["router"])
         weights = router_weights(logits, cfg, mp.get("router_bias"))
-        held_pairs = None
+        counts = None
         if row_valid is not None:
             # A chosen expert's weight is never zero (a softmax or a
             # sigmoid), so the non-zero held columns are the held pairs.
-            held_pairs = jnp.sum((weights != 0) & row_valid[..., None]
-                                 ).astype(jnp.int32)
+            counts = _counts(jnp.sum((weights != 0) & row_valid[..., None]))
         weights = weights.astype(x.dtype)                      # [.., X]
 
     # The dequant is fused into the contraction.
@@ -454,4 +497,4 @@ def moe_ffn(x: jnp.ndarray, mp: Params, cfg, constrain=None,
 
     if "shared_gate_proj" in mp:
         out = out + _shared_expert(x, mp, cfg, constrain)
-    return out if row_valid is None else (out, held_pairs)
+    return out if row_valid is None else (out, counts)

@@ -28,6 +28,7 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from arks_tpu.models.config import ModelConfig
@@ -41,6 +42,9 @@ AXIS_DATA = "data"
 AXIS_MODEL = "model"
 
 Params = dict[str, Any]
+
+# ``moe.moe_ffn``'s counts of a layer that has no router.
+_NO_COUNTS = np.zeros((3,), np.int32)
 
 
 class KVCache(NamedTuple):
@@ -765,11 +769,12 @@ def _block_tail(h: jnp.ndarray, attn: jnp.ndarray, lp: Params,
 @_scope("arks.ffn")
 def _mlp(h: jnp.ndarray, lp: Params, cfg: ModelConfig, mesh: Mesh | None,
          batch_axis: str | None, seq_axis: str | None = None,
-         row_valid: jnp.ndarray | None = None):
+         row_valid: jnp.ndarray | None = None, stack: tuple | None = None):
     """The FFN half of a block on normed ``h``: routed where the layer
     tree has a router (a model's dense prefix has none).  With
-    ``row_valid`` a routed layer returns ``(out, held_pairs)``
-    (:func:`moe.moe_ffn`)."""
+    ``row_valid`` a routed layer returns ``(out, counts)``; ``stack`` =
+    ``(tree, index)`` is the stacked tree ``lp`` was taken out of and
+    where (both :func:`moe.moe_ffn`'s)."""
     x = _norm(h, lp["mlp_norm"], cfg)
 
     def _int_spec(ndim: int, sharded_dim: int) -> list:
@@ -796,7 +801,7 @@ def _mlp(h: jnp.ndarray, lp: Params, cfg: ModelConfig, mesh: Mesh | None,
             return _constrain(t, mesh, *_int_spec(t.ndim, dim))
 
         return moe.moe_ffn(x, lp, cfg, constrain if mesh is not None else None,
-                           row_valid=row_valid)
+                           row_valid=row_valid, stack=stack)
     gate = qeinsum("...e,ef->...f", x, lp["w_gate"])
     up = qeinsum("...e,ef->...f", x, lp["w_up"])
     from arks_tpu.models.moe import swiglu
@@ -880,9 +885,8 @@ def _mixed_step_latent(params, cfg, cache, tables, tokens, token_slot,
     the routed stack, each its own scan, one after the other over ONE
     latent pool (a layer's index in the pool is its index in the model).
     One attention path, the absorbed one, for chunks and decode lanes
-    alike.  Returns (logits, cache, held_pairs): the valid rows' (token,
-    expert) pairs, summed over the routed layers, that landed on an expert
-    held here."""
+    alike.  Returns (logits, cache, counts): :func:`moe.moe_ffn`'s three,
+    each summed over the routed layers."""
     from arks_tpu.ops.attention import paged_latent_update_and_attend
     cover = tables.shape[1] * cache.page
     rope_pos = jnp.minimum(token_pos, cover - 1)[None]           # [1, T]
@@ -892,9 +896,10 @@ def _mixed_step_latent(params, cfg, cache, tables, tokens, token_slot,
         h = embed_lookup(params["embed"], tokens[None],
                          first["attn_norm"].dtype)               # [1, T, E]
 
-    def body(carry, xs):
+    def body(stack, base, carry, xs):
         h, pool = carry
-        lp, layer = xs
+        lp, at = xs                    # the layer, and its index in stack
+        layer = at + base if base else at
         x = rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps)
         attn, pool = paged_latent_update_and_attend(
             _mla_q(x, lp, cfg, rope_pos)[0], _mla_kv(x, lp, cfg, rope_pos)[0],
@@ -903,20 +908,21 @@ def _mixed_step_latent(params, cfg, cache, tables, tokens, token_slot,
             scale=cfg.softmax_scale)
         h = h + _mla_out(attn, lp, cfg)[None]
         if "router" in lp:
-            y, held = _mlp(h, lp, cfg, mesh, None, row_valid=valid)
+            y, held = _mlp(h, lp, cfg, mesh, None, row_valid=valid,
+                           stack=(stack, at))
         else:
-            y, held = _mlp(h, lp, cfg, mesh, None), jnp.int32(0)
+            y, held = _mlp(h, lp, cfg, mesh, None), _NO_COUNTS
         return (h + y, pool), held
 
-    pool, held, at = cache.k, jnp.int32(0), 0
+    pool, held, base = cache.k, _NO_COUNTS, 0
     for name in ("dense_layers", "layers"):
         if name not in params:
             continue
         n = params[name]["attn_norm"].shape[0]
         (h, pool), per_layer = jax.lax.scan(
-            body, (h, pool),
-            (params[name], at + jnp.arange(n, dtype=jnp.int32)))
-        held, at = held + jnp.sum(per_layer), at + n
+            functools.partial(body, params[name], base), (h, pool),
+            (params[name], jnp.arange(n, dtype=jnp.int32)))
+        held, base = held + jnp.sum(per_layer, axis=0), base + n
     with _scope("arks.lm_head"):
         h_sel = jnp.take(h[0], sample_src.astype(jnp.int32), axis=0)  # [B, E]
     logits = _unembed(h_sel, params, cfg, mesh, None)
@@ -1211,8 +1217,11 @@ def _mixed_step_periods(params, cfg, cache, tables, win_tables, tokens,
     linear layers (``cfg.linear``), which read and write the slots' state
     (``cache.lin``) and no page.  The full kind is the model's too: GQA
     layers, or latent layers (``cfg.latent``) over the latent pool; both
-    write and read the full pool through ``tables``.  Returns (logits,
-    cache, held_pairs)."""
+    write and read the full pool through ``tables``.  A layer function's
+    ``src`` is ``(stack, index)``: the stacked tree of ``params`` its ``lp``
+    was taken out of and where, for the routed FFN's overflow loop
+    (:func:`moe.moe_ffn`).  Returns (logits, cache, counts), that
+    function's three summed over the routed layers."""
     from arks_tpu.ops.attention import (paged_latent_update_and_attend,
                                         paged_mixed_update_and_attend)
     t_flat = tokens.shape[0]
@@ -1226,14 +1235,15 @@ def _mixed_step_periods(params, cfg, cache, tables, win_tables, tokens,
         h = embed_lookup(params["embed"], tokens[None],
                          head["attn_norm"].dtype)                # [1, T, E]
 
-    def ffn(h, lp):
+    def ffn(h, lp, src):
         if "router" in lp:
-            y, held = _mlp(h, lp, cfg, mesh, None, row_valid=valid)
+            y, held = _mlp(h, lp, cfg, mesh, None, row_valid=valid,
+                           stack=src)
         else:
-            y, held = _mlp(h, lp, cfg, mesh, None), jnp.int32(0)
+            y, held = _mlp(h, lp, cfg, mesh, None), _NO_COUNTS
         return h + _post_norm(y, lp, "mlp_post_norm", cfg), held
 
-    def latent_layer(h, lp, pool, tbl, index, window):
+    def latent_layer(h, lp, src, pool, tbl, index, window):
         del window
         x = _norm(h, lp["attn_norm"], cfg)
         attn, k = paged_latent_update_and_attend(
@@ -1242,10 +1252,10 @@ def _mixed_step_periods(params, cfg, cache, tables, win_tables, tokens,
             seq_pos_start, index, dv=cfg.kv_lora_rank,
             scale=cfg.softmax_scale)
         y = _mla_out(attn, lp, cfg, x[0])[None]
-        h, held = ffn(h + _post_norm(y, lp, "attn_post_norm", cfg), lp)
+        h, held = ffn(h + _post_norm(y, lp, "attn_post_norm", cfg), lp, src)
         return h, (k,) + tuple(pool[1:]), held
 
-    def layer(h, lp, pool, tbl, index, window: bool):
+    def layer(h, lp, src, pool, tbl, index, window: bool):
         q, k, v, gate = _kind_qkv(h, lp, cfg, rope_pos, window)
         attn, *pool = paged_mixed_update_and_attend(
             q[0], k[0], v[0], pool[0], pool[1], tbl, token_slot, token_pos,
@@ -1259,14 +1269,14 @@ def _mixed_step_periods(params, cfg, cache, tables, win_tables, tokens,
         attn = attn.reshape(1, t_flat, cfg.heads_of(window) * cfg.head_dim)
         with _scope("arks.attn_win_out" if window else "arks.attn_out"):
             h = h + qeinsum("...q,qe->...e", attn, lp["wo"])
-        h, held = ffn(h, lp)
+        h, held = ffn(h, lp, src)
         return h, tuple(pool), held
 
     # A sequence that starts in this step starts from nothing, whatever
     # its slot's last sequence left in the state.
     fresh = (seq_q_len > 0) & (seq_pos_start == 0)
 
-    def linear_layer(h, lp, lin, tbl, index, window):
+    def linear_layer(h, lp, src, lin, tbl, index, window):
         del tbl, window
         s_all, conv_all = lin
         x = _norm(h[0], lp["attn_norm"], cfg)
@@ -1280,7 +1290,7 @@ def _mixed_step_periods(params, cfg, cache, tables, win_tables, tokens,
                            "attn_post_norm", cfg)
         lin = (s_all,
                jax.lax.dynamic_update_index_in_dim(conv_all, conv, index, 0))
-        h, held = ffn(h, lp)
+        h, held = ffn(h, lp, src)
         return h, lin, held
 
     full = tuple(cache[:4])
@@ -1291,7 +1301,7 @@ def _mixed_step_periods(params, cfg, cache, tables, win_tables, tokens,
         inner, inner_layer = tuple(cache.win[:4]), layer
         inner_stack, inner_tbl = params["win_layers"], win_tables
     full_layer = latent_layer if cfg.latent else layer
-    held = jnp.int32(0)
+    held = _NO_COUNTS
     # Where the head's layers are linear layers, the stacked inner layers'
     # state sits behind theirs, and the full pool starts at the first
     # period's layer.  (An offset of zero is left out of the traced index
@@ -1301,23 +1311,25 @@ def _mixed_step_periods(params, cfg, cache, tables, win_tables, tokens,
     if first and cfg.linear_head:
         def head_body(carry, xs):
             h, inner = carry
-            h, inner, n = linear_layer(h, xs[0], inner, None, xs[1], True)
+            h, inner, n = linear_layer(h, xs[0], (head, xs[1]), inner, None,
+                                       xs[1], True)
             return (h, inner), n
 
         (h, inner), n = jax.lax.scan(
             head_body, (h, inner),
-            (params[head_name], jnp.arange(first, dtype=jnp.int32)))
-        held = held + jnp.sum(n)
+            (head, jnp.arange(first, dtype=jnp.int32)))
+        held = held + jnp.sum(n, axis=0)
     elif first:
         def head_body(carry, xs):
             h, full = carry
-            h, full, n = full_layer(h, xs[0], full, tables, xs[1], False)
+            h, full, n = full_layer(h, xs[0], (head, xs[1]), full, tables,
+                                    xs[1], False)
             return (h, full), n
 
         (h, full), n = jax.lax.scan(
             head_body, (h, full),
-            (params[head_name], jnp.arange(first, dtype=jnp.int32)))
-        held = held + jnp.sum(n)
+            (head, jnp.arange(first, dtype=jnp.int32)))
+        held = held + jnp.sum(n, axis=0)
 
     per = cfg.inner_period
 
@@ -1332,13 +1344,13 @@ def _mixed_step_periods(params, cfg, cache, tables, win_tables, tokens,
             lp = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
                 a, at, 0, keepdims=False), inner_stack)
             h, inner, n = inner_layer(
-                h, lp, inner, inner_tbl,
+                h, lp, (inner_stack, at), inner, inner_tbl,
                 at + inner_base if inner_base else at, True)
             return (h, inner), n
 
         (h, inner), n = jax.lax.scan(
             inner_body, (h, inner), jnp.arange(count, dtype=jnp.int32))
-        return h, inner, jnp.sum(n)
+        return h, inner, jnp.sum(n, axis=0)
 
     # A first period cut short by the dense prefix: its inner layers, then
     # the first of the stacked full layers; the scan takes the rest.
@@ -1348,8 +1360,8 @@ def _mixed_step_periods(params, cfg, cache, tables, win_tables, tokens,
             h, inner, n = inner_layers(h, inner, 0, cfg.short_period)
             held = held + n
         h, full, n = full_layer(
-            h, jax.tree.map(lambda a: a[0], periods), full, tables,
-            full_base, False)
+            h, jax.tree.map(lambda a: a[0], periods),
+            (params["layers"], jnp.int32(0)), full, tables, full_base, False)
         held = held + n
         periods = jax.tree.map(lambda a: a[1:], periods)
         full_base += 1
@@ -1360,13 +1372,17 @@ def _mixed_step_periods(params, cfg, cache, tables, win_tables, tokens,
         flp, i = xs
         h, inner, n = inner_layers(
             h, inner, i * per + inner0 if inner0 else i * per, per)
-        h, full, m = full_layer(h, flp, full, tables, full_base + i, False)
+        # The index is into the tree the program was handed, not into
+        # what ``lead`` left of it: that slice would be a buffer to make.
+        h, full, m = full_layer(
+            h, flp, (params["layers"], i + 1 if lead else i), full, tables,
+            full_base + i, False)
         return (h, full, inner), n + m
 
     (h, full, inner), n = jax.lax.scan(
         period_body, (h, full, inner),
         (periods, jnp.arange(cfg.num_periods, dtype=jnp.int32)))
-    held = held + jnp.sum(n)
+    held = held + jnp.sum(n, axis=0)
     if cfg.inner_tail:
         h, inner, n = inner_layers(h, inner, inner0 + cfg.num_periods * per,
                                    cfg.inner_tail)

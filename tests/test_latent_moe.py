@@ -227,7 +227,7 @@ def test_the_four_shares_of_a_layer_add_up_to_the_uncut_reference(grouped):
                                 row_valid=jnp.ones((1, 96), bool))
         only_shared = moe._shared_expert(x, part, cfg)
         total += np.asarray((out - only_shared).astype(jnp.float32))[0]
-        held_all += int(held)
+        held_all += int(held[0])
     total += np.asarray(shared)
     assert held_all == 96 * 4            # every chosen pair lands on one chip
     # bfloat16 activations against float32: a few parts in a thousand of the
@@ -258,15 +258,96 @@ def test_an_expert_that_draws_more_rows_than_the_batch_holds_overflows_in_tiles(
     x = jax.random.normal(jax.random.PRNGKey(4), (1, 256, 64), jnp.bfloat16)
     valid = jnp.ones((1, 256), bool)
     want, held_d = moe.moe_ffn(x, part, cfg8, grouped=False, row_valid=valid)
+    assert held_d.tolist() == [256 * 2, 0, 0]
     # no overflow; one tile an expert and two spare ones, all dead; two tiles
-    # an expert, the last ragged
-    for cap in (256, 200, 96):
+    # an expert, the last ragged; five tiles an expert, six of them in the
+    # loop behind the four spare ones
+    for cap, tiles in ((256, 0), (200, 2), (96, 4), (48, 10)):
         monkeypatch.setattr(moe, "_held_capacity", lambda n, cfg: cap)
         got, held = moe.moe_ffn(x, part, cfg8, grouped=True, row_valid=valid)
-        assert int(held) == int(held_d) == 256 * 2
+        assert held.tolist() == [256 * 2, tiles,
+                                 max(tiles - moe._SPARE_TILES, 0)]
         np.testing.assert_allclose(np.asarray(got, np.float32),
                                    np.asarray(want, np.float32),
                                    atol=0.02 * float(jnp.abs(want).max()))
+
+
+@pytest.mark.parametrize("leaves", ["float32", "int8"])
+@pytest.mark.parametrize("router", ["one expert", "spread"])
+def test_a_scanned_layers_overflow_loop_reads_its_own_layers_experts(
+        router, leaves, monkeypatch):
+    """Three routed layers with DIFFERENT weights under one scan, as a step
+    program runs them: the layer's slice beside the stacked tree and its
+    index in it.  A bias that sends every row to ONE held expert makes
+    each layer run tiles far past the spare ones, in the loop that takes
+    its expert out of the stack by (layer, expert): the scan gives what
+    the dense dispatch gives layer after layer, the counts read the tiles
+    the layers needed, and a loop handed ANOTHER layer's index reads that
+    layer's expert and does not.  A router that overflows nowhere runs
+    the spare tiles dead and no trip of the loop."""
+    cfg = get_config("tiny-mla-moe").with_expert_share(2, 1)
+    layers, n, cap = 3, 256, 32 if router == "one expert" else 128
+    monkeypatch.setattr(moe, "_held_capacity", lambda n, cfg: cap)
+    stack = moe.init_moe_params(cfg, jax.random.PRNGKey(3), jnp.float32,
+                                layers=layers)
+    # The scale of the preset's hidden states, so that a layer's output
+    # moves the next layer's routing.
+    stack = {k: v * 4 if k.startswith("w_") else v for k, v in stack.items()}
+    bias = jnp.zeros((layers, cfg.router_width), jnp.float32)
+    if router == "one expert":
+        bias = bias.at[:, moe.held_first(cfg) + 5].set(9.0)
+    stack["router_bias"] = bias
+    if leaves == "int8":
+        stack = quant.quantize_params(stack, bits=8)
+    x = jax.random.normal(jax.random.PRNGKey(4), (1, n, cfg.hidden_size),
+                          jnp.float32)
+    valid = (jnp.arange(n) < n - 6)[None]
+
+    def layer(l):
+        return jax.tree.map(lambda a: a[l], stack)
+
+    # Layer after layer through the dense dispatch, and the tiles each
+    # layer's router asks for, counted on the host.
+    want, held, needed, extra = x, 0, 0, 0
+    for l in range(layers):
+        lp = layer(l)
+        _, idx = moe.router_topk(
+            jnp.einsum("te,ex->tx", want[0], lp["router"]), cfg,
+            lp["router_bias"])
+        local = np.asarray(idx)[:n - 6] - moe.held_first(cfg)
+        sizes = np.bincount(local[(local >= 0) & (local < cfg.num_experts)],
+                            minlength=cfg.num_experts)
+        tiles = int(np.sum(-(-np.maximum(sizes - cap, 0) // cap)))
+        needed, extra = needed + tiles, extra + max(
+            tiles - moe._SPARE_TILES, 0)
+        y, counts = moe.moe_ffn(want, lp, cfg, grouped=False,
+                                row_valid=valid)
+        want, held = want + y, held + int(counts[0])
+
+    @jax.jit
+    def scanned(x, shift):
+        def body(h, xs):
+            lp, at = xs
+            y, counts = moe.moe_ffn(h, lp, cfg, grouped=True,
+                                    row_valid=valid,
+                                    stack=(stack, (at + shift) % layers))
+            return h + y, counts
+        h, counts = jax.lax.scan(
+            body, x, (stack, jnp.arange(layers, dtype=jnp.int32)))
+        return h, jnp.sum(counts, axis=0)
+
+    got, counts = scanned(x, 0)
+    assert counts.tolist() == [held, needed, extra]
+    tol = 2e-4 * float(jnp.abs(want).max())
+    np.testing.assert_allclose(np.asarray(got[0, :n - 6]),
+                               np.asarray(want[0, :n - 6]), atol=tol)
+    if router == "spread":
+        assert (needed, extra) == (0, 0)
+        return
+    # every row on one expert: 7 tiles a layer behind a batch of 32 rows
+    assert needed >= 7 * layers and extra >= 3 * layers
+    wrong, _ = scanned(x, 1)
+    assert float(jnp.abs(wrong - want)[0, :n - 6].max()) > 100 * tol
 
 
 def test_rows_that_carry_no_token_take_no_place_in_the_share_dispatch():
@@ -285,7 +366,8 @@ def test_rows_that_carry_no_token_take_no_place_in_the_share_dispatch():
     valid = (jnp.arange(256) < 100)[None]
     got, held = moe.moe_ffn(x, part, cfg8, grouped=True, row_valid=valid)
     want, held_d = moe.moe_ffn(x, part, cfg8, grouped=False, row_valid=valid)
-    assert int(held) == int(held_d) == 100 * 2
+    # 100 rows an expert past a batch of 256: no overflow tile
+    assert held.tolist() == held_d.tolist() == [100 * 2, 0, 0]
     tol = 0.02 * float(jnp.abs(want).max())
     np.testing.assert_allclose(np.asarray(got[0, :100], np.float32),
                                np.asarray(want[0, :100], np.float32), atol=tol)
@@ -660,12 +742,38 @@ def test_engine_serves_a_latent_share_and_counts_what_it_holds():
         assert routed == rows * 4 * 2           # top-4, two routed layers
         held = m.moe_held_pairs_total.get()
         assert 0 < held < routed                # this chip's half, roughly
+        # A share's pod renders both kinds; these 16-row steps take the
+        # dense dispatch, which has no overflow tile.
+        tiles = "\n".join(m.moe_overflow_tiles_total.collect())
+        assert 'moe_overflow_tiles_total{kind="needed"} 0' in tiles
+        assert 'moe_overflow_tiles_total{kind="extra"} 0' in tiles
         # The device-tier prefix cache shares pages by id: it keeps
         # working over latent pages, and the stream does not change.
         hits0 = m.prefix_cache_hit_tokens_total.total()
         again = _drain(eng, [Request("b", prompt, sp)])["b"]
         assert again == first
         assert m.prefix_cache_hit_tokens_total.total() - hits0 >= 32
+    finally:
+        eng.stop()
+
+
+@pytest.mark.parametrize("share", [False, True])
+def test_a_steps_four_counts_land_on_their_counters(share):
+    """The four int32 behind a step's token ids (held pairs, overflow tiles
+    needed, those the loop ran, valid rows): a share's pod counts the tiles
+    by kind, a pod that holds every expert renders no sample of them."""
+    cfg = get_config("tiny-mla-moe")
+    eng = _engine(cfg=cfg.with_expert_share(2, 0) if share else cfg)
+    try:
+        eng._count_held(np.asarray([9, 9, 9, 40, 11, 3, 20], np.int32))
+        m = eng.metrics
+        assert m.moe_held_pairs_total.get() == 40
+        assert m.moe_routed_pairs_total.get() == 20 * 4 * 2
+        assert m.mixed_latent_rows_total.get() == 20 * 3
+        tiles = m.moe_overflow_tiles_total
+        assert (tiles.get(kind="needed"), tiles.get(kind="extra")) == (
+            (11, 3) if share else (0, 0))
+        assert ("moe_overflow_tiles_total{" in m.registry.render()) == share
     finally:
         eng.stop()
 

@@ -238,7 +238,8 @@ def test_quantised_experts_at_the_cells_shape_and_with_padding_rows(bits):
                                np.asarray(want), atol=tol)
     valid = (jnp.arange(320) < 201)[None]
     got, held = moe.moe_ffn(x, qp, cfg, row_valid=valid)
-    assert int(held) == 201 * cfg.num_experts_per_tok
+    # a layer held whole: every valid pair, and no share's overflow counts
+    assert held.tolist() == [201 * cfg.num_experts_per_tok, 0, 0]
     np.testing.assert_allclose(np.asarray(got[0, :201]),
                                np.asarray(want[0, :201]), atol=tol)
     only_shared = moe._shared_expert(x, qp, cfg)
@@ -296,3 +297,61 @@ def test_plain_leaves_keep_ragged_dot():
     text = str(jax.make_jaxpr(lambda x: moe.moe_ffn(x, lp, cfg))(
         _tokens(cfg, 64)))
     assert "ragged_dot" in text
+
+
+@pytest.mark.parametrize("preset", ["tiny-mla-moe", "tiny-linear-moe",
+                                    "tiny-latent-linear-moe"])
+def test_a_whole_steps_overflow_loops_read_the_layers_own_experts(
+        preset, monkeypatch):
+    """``mixed_step`` of the blocks that serve a share (the latent scans;
+    the period scan with linear, GQA and latent layers, a routed head
+    stack, a first period cut short, a tail; window layers are the GQA
+    layer function under another flag) on a chunk of 100 rows beside one of
+    50, int8 leaves, float32 activations: with an expert's batch forced
+    down to 8 rows every routed layer runs far more overflow tiles than
+    the spare ones, each read out of the stacked tree at the index the
+    caller handed down, and the logits are the dense dispatch's.  (Every
+    layer's weights differ: an index into the wrong stack, or off by the
+    layer a first period took, reads another layer's expert.)"""
+    from arks_tpu.models import quant
+    cfg = get_config(preset).with_expert_share(2, 1)
+    params = jax.tree.map(
+        lambda a: a.astype(jnp.float32) if a.dtype == jnp.bfloat16 else a,
+        quant.init_params_quantized(cfg, jax.random.PRNGKey(11),
+                                    jnp.bfloat16, bits=8))
+    slots, page, max_pages, rows = 2, 16, 16, 160
+    tables = jnp.arange(slots * max_pages, dtype=jnp.int32).reshape(
+        slots, max_pages)
+    cache = tf.init_paged_cache(
+        cfg, slots * max_pages, page, jnp.float32,
+        win_pages=slots * max_pages if cfg.windowed else 0,
+        state_slots=slots if cfg.linear else 0)
+    tokens = np.zeros(rows, np.int32)
+    slot = np.full(rows, -1, np.int32)
+    pos = np.full(rows, page * max_pages, np.int32)
+    src, qs, ql = (np.zeros(slots, np.int32) for _ in range(3))
+    at = 1                                            # a padding row ahead
+    for lane, n in ((0, 100), (1, 50)):
+        tokens[at:at + n] = np.random.default_rng(lane).integers(2, 500, n)
+        slot[at:at + n], pos[at:at + n] = lane, np.arange(n)
+        qs[lane], ql[lane], src[lane] = at, n, at + n - 1
+        at += n
+    kw = {"win_tables": tables} if cfg.windowed else {}
+
+    def step():
+        logits, _, counts = jax.jit(lambda c: tf.mixed_step(
+            params, cfg, c, tables, *(jnp.asarray(a) for a in (
+                tokens, slot, pos, src, qs, ql, np.zeros(slots, np.int32))),
+            with_held=True, **kw))(cache)
+        return np.asarray(logits), counts.tolist()
+
+    monkeypatch.setattr(moe, "_batch_pays", lambda n, mp, cfg: False)
+    want, (held, *tiles) = step()
+    assert tiles == [0, 0]
+    monkeypatch.setattr(moe, "_batch_pays", lambda n, mp, cfg: True)
+    monkeypatch.setattr(moe, "_held_capacity", lambda n, cfg: 8)
+    got, (held_b, needed, extra) = step()
+    assert held_b == held
+    assert extra == needed - moe._SPARE_TILES * cfg.num_routed_layers
+    assert extra > 20 * cfg.num_routed_layers
+    np.testing.assert_allclose(got, want, atol=2e-5 * np.abs(want).max())

@@ -50,10 +50,33 @@ other text, as it should.  ``_linear_state`` has one caller, reached only
 where ``cfg.linear``: ``tiny``, ``tiny-mla-moe``, ``tiny-swa-moe`` and their
 ``@wide`` shapes hold no such layer and stand at the values they had.  A
 later edit of the kernel's body moves these six again, and re-pins them.
+
+PR 44 RE-PINNED all eighteen of the three routed presets and moved none of
+``tiny``'s four, for two reasons.  The six ``@wide`` programs moved because
+the dispatch they exist to pin changed: a share's ``_SPARE_TILES`` overflow
+tiles run unrolled, what a layer needs beyond them in a loop that takes its
+expert out of the STACKED tree by (layer, expert) where the loop from tile 0
+closed over the layer's slice (on the chip that slice was a buffer to make:
+three int8 expert leaves copied whole a layer a step).  The twelve others
+(2 + 64 rows: the dense dispatch, no tile at all) moved only by the counts:
+a routed layer hands back int32 ``[3]`` (held pairs, overflow tiles needed,
+those the loop ran) where a scalar stood, ``with_counts`` puts four entries
+behind the ids where two stood, and ``_mixed_step_latent``'s scans hand a
+layer its index in ITS stack (the pool's index is that plus the stack's
+base).  NEW: ``whole-layer``, the branch mixtral runs and no step pin covers
+(a layer held WHOLE, int8 leaves, 8 experts top-2 at 320 rows: the batched
+dispatch with four unrolled tiles), ``moe.moe_ffn`` lowered on shapes alone;
+its value is what commit 24dfdfe (PR 41, the parent of PR 44) lowers, taken
+with ``whole_layer_hash`` in that tree, so that the tile's one reading
+(``dynamic_slice`` with an empty lead) is shown to be the text
+``dynamic_index_in_dim`` of the layer's leaf was.
 """
 
 import hashlib
+import types
 
+import jax
+import jax.numpy as jnp
 import pytest
 
 from arks_tpu.engine import EngineConfig, InferenceEngine
@@ -65,24 +88,24 @@ PINS = {
     "tiny.seq_lp": "31d2702fc1c7b6af",
     "tiny.pipe": "a6579a2a5236124a",
     "tiny.pipe_lp": "d059fb1f2834e04b",
-    "tiny-mla-moe.seq": "6cc9f745a6bfd269",
-    "tiny-mla-moe.seq_lp": "c5e64d4cfdb392a2",
-    "tiny-mla-moe.pipe": "892ad0d2c5590329",
-    "tiny-mla-moe.pipe_lp": "42fe3682f6a6855b",
-    "tiny-swa-moe.seq": "107c9e6196f101d2",
-    "tiny-swa-moe.seq_lp": "353c28114cf97b20",
-    "tiny-swa-moe.pipe": "973796d815677dce",
-    "tiny-swa-moe.pipe_lp": "e428a82b85955814",
-    "tiny-mla-moe@wide.seq": "ef7ecdee689986d0",
-    "tiny-mla-moe@wide.seq_lp": "901dc7ce4f3539c5",
-    "tiny-swa-moe@wide.seq": "93e12f3a65d1e090",
-    "tiny-swa-moe@wide.seq_lp": "d37658bc2680eb1c",
-    "tiny-linear-moe.seq": "9b3850f805af0238",
-    "tiny-linear-moe.seq_lp": "f477da4485ef9839",
-    "tiny-linear-moe.pipe": "492eae6804404b2a",
-    "tiny-linear-moe.pipe_lp": "68b2e433cd58d959",
-    "tiny-linear-moe@wide.seq": "9a577bfcd3314ce9",
-    "tiny-linear-moe@wide.seq_lp": "090ea30fca2bdbe6",
+    "tiny-mla-moe.seq": "732da02806d9628e",
+    "tiny-mla-moe.seq_lp": "2ad822bb0ae0d3a5",
+    "tiny-mla-moe.pipe": "fb42b9e5a6ce6007",
+    "tiny-mla-moe.pipe_lp": "5947869b382e5fe8",
+    "tiny-swa-moe.seq": "656c1440df89f528",
+    "tiny-swa-moe.seq_lp": "bf47ab082f6494f1",
+    "tiny-swa-moe.pipe": "b816b56c164ad306",
+    "tiny-swa-moe.pipe_lp": "6b850b4575db179d",
+    "tiny-mla-moe@wide.seq": "4b30be9951402722",
+    "tiny-mla-moe@wide.seq_lp": "e252e4dd36ebe3f1",
+    "tiny-swa-moe@wide.seq": "72817726151c441f",
+    "tiny-swa-moe@wide.seq_lp": "b5e4558e99013cef",
+    "tiny-linear-moe.seq": "25c3b65a31869dbe",
+    "tiny-linear-moe.seq_lp": "f91e0295929aed93",
+    "tiny-linear-moe.pipe": "dd83bf5075eb475f",
+    "tiny-linear-moe.pipe_lp": "66007edafc0a5a68",
+    "tiny-linear-moe@wide.seq": "75c03053b4c8f947",
+    "tiny-linear-moe@wide.seq_lp": "a576efe531063750",
 }
 
 
@@ -141,3 +164,32 @@ def hashes():
 @pytest.mark.parametrize("program", sorted(PINS))
 def test_step_program_hashes_equal_to_the_parents(hashes, program):
     assert hashes[program] == PINS[program]
+
+
+def whole_layer_hash() -> str:
+    """``moe.moe_ffn`` on a layer held WHOLE with int8 leaves, 8 experts
+    top-2 of 64 x 96 at 320 rows (an expert's batch 128 rows, four overflow
+    tiles, unrolled): mixtral's branch at test size, lowered on shapes."""
+    x, e, f, rows = 8, 64, 96, 320
+    cfg = types.SimpleNamespace(
+        num_experts=x, num_experts_per_tok=2, router_width=x,
+        expert_parallel_size=1, expert_parallel_rank=0,
+        scoring_func="softmax", norm_topk_prob=True,
+        routed_scaling_factor=1.0, swiglu_limit=0.0)
+    assert moe._held_capacity(rows, cfg) == 128
+
+    def leaf(k, n):
+        return {"q": jax.ShapeDtypeStruct((x, k, n), jnp.int8),
+                "s": jax.ShapeDtypeStruct((x, 1, n), jnp.float32)}
+
+    lp = {"router": jax.ShapeDtypeStruct((e, x), jnp.bfloat16),
+          "w_gate": leaf(e, f), "w_up": leaf(e, f), "w_down": leaf(f, e)}
+    text = jax.jit(lambda lp, h: moe.moe_ffn(h, lp, cfg)).lower(
+        lp, jax.ShapeDtypeStruct((1, rows, e), jnp.bfloat16)).as_text()
+    # four tiles x three leaves x (values, scales), each its own slice
+    assert text.count("stablehlo.dynamic_slice") >= 24
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+def test_the_whole_layers_batched_program_equals_the_parents():
+    assert whole_layer_hash() == "51f24c26fa75cc54"
