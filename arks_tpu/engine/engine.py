@@ -725,6 +725,13 @@ class EngineMetrics:
             "mixed_q_layout_rows_total",
             "Query rows laid out for the mixed attention kernel by mixed "
             "dispatches (plan mirror)")
+        # Blocks the KV row write reads and writes back for a dispatch, a
+        # layer: distinct (slot, position // the pool's block rows) among
+        # its live rows.  mixed_batch_tokens_sum over it = rows a block.
+        self.mixed_kv_write_blocks_total = r.counter(
+            "mixed_kv_write_blocks_total",
+            "Blocks (a slot's aligned group of 16 bf16 / 32 int8 / 64 int4 "
+            "rows) the KV row write touches in mixed dispatches, a layer")
         # KV bytes-moved pair (engine/paged.mixed_kv_bytes): bytes_total
         # mirrors the ragged kernel's actual DMA schedule (every q-block
         # re-streams its causal page prefix at the PLAN's block_q — the
@@ -1936,6 +1943,9 @@ class InferenceEngine:
         # (_mixed_grid_counters): the plan is static per engine shape, so
         # the issue path pays one dict hit per dispatch.
         self._grid_plans: dict[int, dict] = {}
+        # Token positions a block of the KV row write holds (set with the
+        # first plan: the pool's width decides it).
+        self._kv_write_block = 0
         # A SECOND, smaller shape of the sequential step's program, for
         # the steps that carry a prompt's tail or no prompt row at all: a
         # step costs its whole shape whatever it carries, and with a
@@ -8666,17 +8676,20 @@ class InferenceEngine:
         """Account one mixed dispatch's plan counters:
         mixed_grid_steps_total (the page-compute steps the work list
         runs), mixed_q_layout_rows_total (the query rows the plan lays out
-        for the kernel, whatever the batch holds) and the
-        mixed_kv_bytes pair.
+        for the kernel, whatever the batch holds), mixed_kv_write_blocks_total
+        (the blocks the row write moves: a lane's rows are one run of
+        positions) and the mixed_kv_bytes pair.
         The counters describe the grid PLAN — they are meaningful under
         either attention impl, which is what lets the sparse-batch
         test run on the XLA oracle.  Inputs are the host-side numpy batch
         arrays — no device fetches here (hot-path guard covers this)."""
         plan = self._grid_plans.get(qmax)
         if plan is None:
-            from arks_tpu.ops.paged_attention import mixed_grid_plan
+            from arks_tpu.ops.paged_attention import (mixed_grid_plan,
+                                                      update_block_tokens)
             kvd = self.ecfg.resolve_kv_cache_dtype()
             kv = kvd if kvd in ("int8", "int4") else str(self._cache.k.dtype)
+            self._kv_write_block = update_block_tokens(kv)
             plan = mixed_grid_plan(
                 qmax, hkv=self.cfg.num_kv_heads,
                 g=self.cfg.num_heads // self.cfg.num_kv_heads,
@@ -8689,6 +8702,10 @@ class InferenceEngine:
             block_q=plan["block_q"], num_qb=plan["num_qb"],
             max_pages=self._max_pages))
         self.metrics.mixed_q_layout_rows_total.inc(plan["q_rows"])
+        blk = self._kv_write_block
+        self.metrics.mixed_kv_write_blocks_total.inc(int((
+            ((pos_start + q_len - 1) // blk - pos_start // blk + 1)
+            * (q_len > 0)).sum()))
         b_actual, b_ideal = mixed_kv_bytes(
             pos_start, q_len, page=self._page_size(),
             block_q=plan["block_q"], num_qb=plan["num_qb"],

@@ -49,13 +49,15 @@ _SMEM_BYTES = 2**20  # a v5e core's scalar memory
 
 def mixed_step_row_limit(max_pages: int) -> int:
     """The most flat rows (slots + chunk budget) a mixed step may carry on
-    a TPU: the KV row-write kernels (``ops/paged_attention.py::
-    paged_kv_update*``) prefetch ONE block-table row per flat token into
+    a TPU: until PR 45 the KV row-write kernels (``ops/paged_attention.py::
+    paged_kv_update*``) prefetched ONE block-table row per flat token into
     SMEM, each padded to whole 128-lane tiles of int32, and the chip
     refuses a dispatch whose operands pass ``_SMEM_BYTES`` (1 MiB on a
     v5e: "would exceed memory ... space=smem").  The write positions (4
     bytes a row) and the layer index share that memory.  32 pages a slot
-    (8192 tokens of 256) gives 2030 rows."""
+    (8192 tokens of 256) gives 2030 rows.  The row write now prefetches
+    three vectors of a row's length and no table; the limit stands until
+    a step of more rows has been compiled and run (ROADMAP M4)."""
     return (_SMEM_BYTES - 1024) // (-(-max_pages // 128) * 128 * 4 + 4)
 
 

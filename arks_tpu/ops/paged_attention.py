@@ -1329,51 +1329,243 @@ def paged_mixed_attention_flat(
 _UPDATE_CHUNK = 16        # bf16 sublane tile
 _UPDATE_CHUNK_INT8 = 32   # int8 sublane tile
 _SCALE_CHUNK = 128        # f32 lane tile
+# Scratch slots a pool: one block merging, one being read for the next
+# run, two whose write-backs are still on their way.
+_UPDATE_RING = 4
 
 
-def _paged_update_kernel(layer_ref, idx_ref, tables_ref, *refs,
-                         page: int, chunk: int):
-    """``refs``: per pool the new rows, the pool (aliased input), the pool
-    (output) and a chunk of scratch, pools side by side in each group (K
-    and V; a latent pool is one), then the semaphores."""
-    n = len(refs) // 4
-    new_refs, outs, scrs, sem = (refs[:n], refs[2 * n:3 * n],
-                                 refs[3 * n:4 * n], refs[4 * n])
-    b, hkv, _, d = new_refs[0].shape
-    max_pos = tables_ref.shape[1] * page
+def update_block_tokens(kv: str) -> int:
+    """Token positions in one block of the row write, by the pool's KV
+    width (:func:`pool_kv_name`)."""
+    return {"int8": _UPDATE_CHUNK_INT8,
+            "int4": 2 * _UPDATE_CHUNK_INT8}.get(kv, _UPDATE_CHUNK)
+
+
+def _paged_update_kernel(layer_ref, idx_ref, pages_ref, starts_ref, nruns_ref,
+                         *refs, page: int, cover: int, groups: tuple,
+                         int4: bool):
+    """One read-modify-write a touched BLOCK, the next block's read in
+    flight while this one merges.
+
+    ``groups``: ``(pools, rows, width)`` a group of pools that move the same
+    block: ``rows`` token positions stored as ``width`` units of the pool's
+    fourth axis (K and V pages: 16 bf16 rows, 32 int8 rows, 64 int4 tokens
+    in 32 byte rows; the two scale pools: a 128-lane group).  The first
+    group's block is the finest and every other group's holds it whole.
+    ``refs``: per pool the new rows, the pool (aliased input), the pool
+    (output) and a ring of ``_UPDATE_RING`` blocks of scratch, pools in
+    group order; then the DMA semaphores ``[pool, ring slot]`` and the
+    write-backs in flight (SMEM).
+
+    Scalar prefetch (:func:`_update_runs`): a row's position
+    (``>= cover``: a padding row) and the page id its table gives it, and
+    the *runs*: ``starts_ref[r]`` the first flat row of run ``r`` of
+    ``nruns_ref[0]``, ``starts_ref[nruns]`` the row count.  A run's live
+    rows fall in ONE block of every group, and stand in front of its
+    padding rows.  The kernel walks the runs: a group whose block changes
+    with the next run has that block's read started before this run's rows
+    are merged, row by row in flat order, into the copy in VMEM; the copy
+    goes back once, when the group's block closes, and that write is
+    waited for only when its ring slot is wanted again, or when a later
+    run reads the same block (the packers never lay that out; the
+    comparison of block keys keeps it right).  Two runs in a row over one
+    block (a padding row between them) merge into one copy."""
+    np_ = sum(n for n, _, _ in groups)
+    news, outs, rings = (refs[:np_], refs[2 * np_:3 * np_],
+                         refs[3 * np_:4 * np_])
+    sem, going = refs[4 * np_:]
+    first = [sum(n for n, _, _ in groups[:g]) for g in range(len(groups))]
+    b = idx_ref.shape[0]
+    nruns = nruns_ref[0]
     lyr = layer_ref[0]
+    ring = _UPDATE_RING
 
-    def body(i, _):
-        @pl.when(idx_ref[i] < max_pos)
-        def _():
-            _write_row(i)
-        return 0
+    def place(i):
+        """(page id, offset in the page) of live row ``i``."""
+        return pages_ref[i], idx_ref[i] % page
 
-    def _write_row(i):
-        idx = idx_ref[i]
-        pg = tables_ref[i, idx // page]
-        off = idx % page
-        base = (off // chunk) * chunk
-        dsts = [out.at[pl.ds(lyr, 1), pl.ds(pg, 1), :, pl.ds(base, chunk)]
-                for out in outs]
-        reads = [pltpu.make_async_copy(dst, scr, sem.at[j])
-                 for j, (dst, scr) in enumerate(zip(dsts, scrs))]
-        for r in reads:
-            r.start()
-        for r in reads:
-            r.wait()
-        row = jax.lax.broadcasted_iota(jnp.int32, (1, 1, hkv, chunk, d), 3)
-        hit = row == (off - base)
-        for new_ref, scr in zip(new_refs, scrs):
-            scr[:] = jnp.where(hit, new_ref[pl.ds(i, 1)][None], scr[:])
-        writes = [pltpu.make_async_copy(scr, dst, sem.at[j])
-                  for j, (dst, scr) in enumerate(zip(dsts, scrs))]
-        for w in writes:
-            w.start()
-        for w in writes:
-            w.wait()
+    def key(g, pg, off):
+        rows = groups[g][1]
+        return pg * (page // rows) + off // rows
 
-    jax.lax.fori_loop(0, b, body, 0)
+    def moves(g, pg, off, slot, back: bool):
+        """The group's copies of block ``(pg, off // rows)``: pool to ring
+        slot, or ``back``."""
+        n, rows, width = groups[g]
+        out = []
+        for j in range(first[g], first[g] + n):
+            blk = outs[j].at[pl.ds(lyr, 1), pl.ds(pg, 1), :,
+                             pl.ds((off // rows) * width, width)]
+            scr = rings[j].at[slot]
+            out.append(pltpu.make_async_copy(
+                *((scr, blk) if back else (blk, scr)), sem.at[j, slot]))
+        return out
+
+    def landed(g, k):
+        """Wait for the write-back in flight from ring slot ``k`` (a wait
+        needs the copy's shape, not its place)."""
+        for c in moves(g, 0, 0, k, True):
+            c.wait()
+        going[g * ring + k] = -1
+
+    for s in range(len(groups) * ring):
+        going[s] = -1
+
+    def merge(i, slots):
+        """Row ``i`` into its blocks' copies: a select of one row of a
+        page block, of one lane of a scale group."""
+        off = idx_ref[i] % page
+        for g, (n, rows, width) in enumerate(groups):
+            unit = (off % rows) * width // rows
+            for j in range(first[g], first[g] + n):
+                scr = rings[j].at[slots[g]]
+                old = scr[...]
+                hit = jax.lax.broadcasted_iota(jnp.int32, old.shape, 3) == unit
+                new = news[j][pl.ds(i, 1)]
+                new = (new[None] if old.ndim == 5
+                       else new.reshape(1, 1, old.shape[2], 1))
+                if int4 and g == 0:
+                    # Merge ONE nibble of the hit byte: low nibble = even
+                    # token (keep 0xF0), high = odd (keep 0x0F); rows of a
+                    # run that share a byte merge in flat order, the second
+                    # over the first.  The bit math runs on int32 (Mosaic
+                    # legalises no 8-bit vector shift on a v5e) and
+                    # truncates back: the new value is in [-7, 7], so value
+                    # << 4 and either merge stay inside int8's range.
+                    old = old.astype(jnp.int32)
+                    new = new.astype(jnp.int32)
+                    new = jnp.where(
+                        (off % 2) == 0,
+                        jnp.bitwise_or(jnp.bitwise_and(old, -16),
+                                       jnp.bitwise_and(new, 15)),
+                        jnp.bitwise_or(jnp.bitwise_and(old, 15),
+                                       jnp.left_shift(new, 4)))
+                scr[...] = jnp.where(hit, new, old).astype(scr.dtype)
+
+    # ``count`` a group: the blocks it has closed, so its open block stands
+    # in ring slot ``count % ring``; ``opened``: this run is the open
+    # block's first, its read not yet waited for.
+    def run(r, carry):
+        pg, off, count, opened = carry
+        i0, i1 = starts_ref[r], starts_ref[r + 1]
+        last = r + 1 == nruns
+        npg, noff = place(jnp.minimum(i1, b - 1))
+        slots = [c % ring for c in count]
+        closes = []
+        for g in range(len(groups)):
+            nxt = jnp.where(last, -1, key(g, npg, noff))
+            closes.append(nxt != key(g, pg, off))
+            nslot = (count[g] + 1) % ring
+
+            @pl.when(closes[g] & jnp.logical_not(last))
+            def _():
+                for k in range(ring):
+                    held = going[g * ring + k]
+
+                    @pl.when((held >= 0) & ((nslot == k) | (held == nxt)))
+                    def _():
+                        landed(g, k)
+                for c in moves(g, npg, noff, nslot, False):
+                    c.start()
+
+            @pl.when(opened[g])
+            def _():
+                for c in moves(g, pg, off, slots[g], False):
+                    c.wait()
+
+        def row(i, _):
+            @pl.when(idx_ref[i] < cover)
+            def _():
+                merge(i, slots)
+            return 0
+
+        jax.lax.fori_loop(i0, i1, row, 0)
+        for g in range(len(groups)):
+            @pl.when(closes[g])
+            def _():
+                for c in moves(g, pg, off, slots[g], True):
+                    c.start()
+                going[g * ring + slots[g]] = key(g, pg, off)
+        return (npg, noff,
+                tuple(c + x.astype(jnp.int32) for c, x in zip(count, closes)),
+                tuple(closes))
+
+    pg0, off0 = place(jnp.minimum(starts_ref[0], b - 1))
+
+    @pl.when(nruns > 0)
+    def _():
+        for g in range(len(groups)):
+            for c in moves(g, pg0, off0, 0, False):
+                c.start()
+
+    jax.lax.fori_loop(
+        0, nruns, run,
+        (pg0, off0, (jnp.int32(0),) * len(groups),
+         (jnp.bool_(True),) * len(groups)))
+    for g in range(len(groups)):
+        for k in range(ring):
+            @pl.when(going[g * ring + k] >= 0)
+            def _():
+                landed(g, k)
+
+
+def _update_runs(write_idx, tables, page: int, rows: int):
+    """What the row write's kernel walks, from the batch itself: ``(page id
+    a row, first row a run [B + 1], run count [1])``.  A run opens at every
+    live row (``write_idx`` inside the table's coverage) that does not
+    continue the row before it in the same block of ``rows`` positions:
+    "same block" is the page id and the block index actually read, so two
+    slots' runs side by side, whose positions continue each other, and a
+    window pool's stale table entries are told apart.  A handful of
+    vector ops over ``[B]`` where the kernel's scalar core would walk the
+    rows one by one (~4 us a call against ~45 us at 1056 rows on a v5e,
+    PERF.md §6), and the kernel prefetches three ``[B]`` vectors where it
+    prefetched a table row a flat row."""
+    b = write_idx.shape[0]
+    live = write_idx < tables.shape[1] * page
+    safe = jnp.where(live, write_idx, 0)
+    pages = jnp.take_along_axis(tables, (safe // page)[:, None], axis=1)[:, 0]
+    key = jnp.where(live, pages * (page // rows) + safe % page // rows, -1)
+    opens = live & (key != jnp.concatenate([jnp.full((1,), -1), key[:-1]]))
+    starts = jnp.nonzero(opens, size=b + 1, fill_value=b)[0]
+    return pages, starts.astype(jnp.int32), opens.sum(dtype=jnp.int32)[None]
+
+
+def _paged_update_call(name: str, pools, news, page: int, groups, int4: bool,
+                       write_idx, tables, layer, interpret: bool):
+    """The row write over ``pools`` (in group order) of ``page`` tokens a
+    page, each rewritten in place."""
+    np_ = len(pools)
+    widths = [w for n, _, w in groups for _ in range(n)]
+    write_idx, tables = write_idx.astype(jnp.int32), tables.astype(jnp.int32)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)] * np_
+        + [pl.BlockSpec(memory_space=pl.ANY)] * np_,
+        out_specs=tuple(pl.BlockSpec(memory_space=pl.ANY) for _ in pools),
+        scratch_shapes=[
+            pltpu.VMEM((_UPDATE_RING, 1, 1, p.shape[2], w) + p.shape[4:],
+                       p.dtype)
+            for p, w in zip(pools, widths)]
+        + [pltpu.SemaphoreType.DMA((np_, _UPDATE_RING)),
+           pltpu.SMEM((len(groups) * _UPDATE_RING,), jnp.int32)],
+    )
+    kernel = functools.partial(
+        _paged_update_kernel, page=page, cover=tables.shape[1] * page,
+        groups=tuple(groups), int4=int4)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=tuple(jax.ShapeDtypeStruct(p.shape, p.dtype)
+                        for p in pools),
+        # 0=layer, 1=idx, 2=page ids, 3=run starts, 4=run count, then the
+        # new rows, then the pools.
+        input_output_aliases={5 + np_ + j: j for j in range(np_)},
+        interpret=interpret,
+        name=name,
+    )(jnp.asarray(layer, jnp.int32).reshape(1), write_idx,
+      *_update_runs(write_idx, tables, page, groups[0][1]), *news, *pools)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -1388,126 +1580,17 @@ def paged_kv_update(
     interpret: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray | None]:
     """Write one KV row per slot at its table-mapped page, in place."""
-    _, n, hkv, page, d = k_pool.shape
+    page = k_pool.shape[3]
     if page % _UPDATE_CHUNK != 0:
         raise ValueError(f"page {page} must be a multiple of {_UPDATE_CHUNK}")
     pools = [k_pool] if v_pool is None else [k_pool, v_pool]
     news = [x.astype(p.dtype)[:, :, None, :]
             for x, p in zip((k_new, v_new), pools)]
-    layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
-    np_ = len(pools)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)] * np_
-        + [pl.BlockSpec(memory_space=pl.ANY)] * np_,
-        out_specs=tuple(pl.BlockSpec(memory_space=pl.ANY) for _ in pools),
-        scratch_shapes=[
-            pltpu.VMEM((1, 1, hkv, _UPDATE_CHUNK, d), p.dtype)
-            for p in pools] + [pltpu.SemaphoreType.DMA((2,))],
-    )
-    kernel = functools.partial(_paged_update_kernel, page=page,
-                               chunk=_UPDATE_CHUNK)
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=tuple(jax.ShapeDtypeStruct(p.shape, p.dtype)
-                        for p in pools),
-        # 0=layer, 1=idx, 2=tables, then the new rows, then the pools.
-        input_output_aliases={3 + np_ + j: j for j in range(np_)},
-        interpret=interpret,
-        name="paged_kv_update",
-    )(layer_arr, write_idx.astype(jnp.int32), tables.astype(jnp.int32),
-      *news, *pools)
+    out = _paged_update_call(
+        "paged_kv_update", pools, news, page,
+        [(len(pools), _UPDATE_CHUNK, _UPDATE_CHUNK)], False,
+        write_idx, tables, layer, interpret)
     return (out[0], None) if v_pool is None else (out[0], out[1])
-
-
-def _paged_update_quant_kernel(layer_ref, idx_ref, tables_ref,
-                               kn_ref, vn_ref, ksn_ref, vsn_ref,
-                               kp_in, vp_in, kss_in, vss_in,
-                               kp_out, vp_out, kss_out, vss_out,
-                               kscr, vscr, ksscr, vsscr, sem,
-                               *, page: int, int4: bool):
-    del kp_in, vp_in, kss_in, vss_in
-    b, hkv, _, d = kn_ref.shape
-    max_pos = tables_ref.shape[1] * page
-    ch = _UPDATE_CHUNK_INT8
-    sch = _SCALE_CHUNK
-    lyr = layer_ref[0]
-
-    def body(i, _):
-        @pl.when(idx_ref[i] < max_pos)
-        def _():
-            _write_row(i)
-        return 0
-
-    def _write_row(i):
-        idx = idx_ref[i]
-        pg = tables_ref[i, idx // page]
-        off = idx % page
-        # int4 pools store nibble pairs: the token's BYTE row is off//2 and
-        # the read-modify-write below merges one nibble.  Rows in the same
-        # dispatch that share a byte (positions 2t and 2t+1 of a prefill
-        # chunk) are safe: the fori loop is sequential, so the second
-        # merge reads the first one's write.  All scale/position math
-        # stays in token units.
-        boff = off // 2 if int4 else off
-        base = (boff // ch) * ch
-        sbase = (off // sch) * sch
-        dst_k = kp_out.at[pl.ds(lyr, 1), pl.ds(pg, 1), :, pl.ds(base, ch)]
-        dst_v = vp_out.at[pl.ds(lyr, 1), pl.ds(pg, 1), :, pl.ds(base, ch)]
-        dst_ks = kss_out.at[pl.ds(lyr, 1), pl.ds(pg, 1), :, pl.ds(sbase, sch)]
-        dst_vs = vss_out.at[pl.ds(lyr, 1), pl.ds(pg, 1), :, pl.ds(sbase, sch)]
-        copies = [pltpu.make_async_copy(dst_k, kscr, sem.at[0]),
-                  pltpu.make_async_copy(dst_v, vscr, sem.at[1]),
-                  pltpu.make_async_copy(dst_ks, ksscr, sem.at[2]),
-                  pltpu.make_async_copy(dst_vs, vsscr, sem.at[3])]
-        for c in copies:
-            c.start()
-        for c in copies:
-            c.wait()
-        row = jax.lax.broadcasted_iota(jnp.int32, (1, 1, hkv, ch, d), 3)
-        hit = row == (boff - base)
-        if int4:
-            # Merge ONE nibble of the hit byte: low nibble = even token
-            # (keep 0xF0), high = odd (keep 0x0F).  The bit math runs on
-            # int32 (Mosaic legalises no 8-bit vector shift on a v5e) and
-            # truncates back: the new value is in [-7, 7], so value << 4
-            # and either merge stay inside int8's range.
-            even = (off % 2) == 0
-
-            def merge(scr, new_ref):
-                old = scr[:].astype(jnp.int32)
-                new = new_ref[pl.ds(i, 1)][None].astype(jnp.int32)
-                merged = jnp.where(
-                    even,
-                    jnp.bitwise_or(jnp.bitwise_and(old, -16),
-                                   jnp.bitwise_and(new, 15)),
-                    jnp.bitwise_or(jnp.bitwise_and(old, 15),
-                                   jnp.left_shift(new, 4)))
-                scr[:] = jnp.where(hit, merged, old).astype(jnp.int8)
-
-            merge(kscr, kn_ref)
-            merge(vscr, vn_ref)
-        else:
-            kscr[:] = jnp.where(hit, kn_ref[pl.ds(i, 1)][None], kscr[:])
-            vscr[:] = jnp.where(hit, vn_ref[pl.ds(i, 1)][None], vscr[:])
-        lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, hkv, sch), 3)
-        shit = lane == (off - sbase)
-        ksn = ksn_ref[pl.ds(i, 1)].reshape(1, 1, hkv, 1)
-        vsn = vsn_ref[pl.ds(i, 1)].reshape(1, 1, hkv, 1)
-        ksscr[:] = jnp.where(shit, ksn, ksscr[:])
-        vsscr[:] = jnp.where(shit, vsn, vsscr[:])
-        back = [pltpu.make_async_copy(kscr, dst_k, sem.at[0]),
-                pltpu.make_async_copy(vscr, dst_v, sem.at[1]),
-                pltpu.make_async_copy(ksscr, dst_ks, sem.at[2]),
-                pltpu.make_async_copy(vsscr, dst_vs, sem.at[3])]
-        for c in back:
-            c.start()
-        for c in back:
-            c.wait()
-
-    jax.lax.fori_loop(0, b, body, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -1525,10 +1608,12 @@ def paged_kv_update_quant(
 ):
     """int8/int4 variant: quantize the new rows, write values + per-token
     scales in place through the table.  int4 pools (pool page rows !=
-    scale page) get the fused nibble merge in the kernel."""
+    scale page) store nibble pairs: a block of 32 BYTE rows holds 64
+    tokens, and the kernel merges one nibble a row.  All position math
+    stays in token units."""
     from arks_tpu.ops.pallas_attention import quantize_kv
 
-    _, n, hkv, rows, d = k_pool.shape
+    rows = k_pool.shape[3]
     page = k_scale.shape[3]
     int4 = rows != page
     if page % _SCALE_CHUNK != 0:
@@ -1540,38 +1625,13 @@ def paged_kv_update_quant(
             f"{_UPDATE_CHUNK_INT8}")
     kq, ks = quantize_kv(k_new, qmax=7 if int4 else 127)
     vq, vs = quantize_kv(v_new, qmax=7 if int4 else 127)
-    layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)] * 4
-        + [pl.BlockSpec(memory_space=pl.ANY)] * 4,
-        out_specs=tuple([pl.BlockSpec(memory_space=pl.ANY)] * 4),
-        scratch_shapes=[
-            pltpu.VMEM((1, 1, hkv, _UPDATE_CHUNK_INT8, d), k_pool.dtype),
-            pltpu.VMEM((1, 1, hkv, _UPDATE_CHUNK_INT8, d), v_pool.dtype),
-            pltpu.VMEM((1, 1, hkv, _SCALE_CHUNK), jnp.float32),
-            pltpu.VMEM((1, 1, hkv, _SCALE_CHUNK), jnp.float32),
-            pltpu.SemaphoreType.DMA((4,)),
-        ],
-    )
-    kernel = functools.partial(_paged_update_quant_kernel, page=page,
-                               int4=int4)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=(jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
-                   jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype),
-                   jax.ShapeDtypeStruct(k_scale.shape, jnp.float32),
-                   jax.ShapeDtypeStruct(v_scale.shape, jnp.float32)),
-        # 0=layer, 1=idx, 2=tables, 3=kq, 4=vq, 5=ks, 6=vs,
-        # 7=k_pool, 8=v_pool, 9=k_scale, 10=v_scale.
-        input_output_aliases={7: 0, 8: 1, 9: 2, 10: 3},
-        interpret=interpret,
-        name="paged_kv_update_quant",
-    )(layer_arr, write_idx.astype(jnp.int32), tables.astype(jnp.int32),
-      kq[:, :, None, :], vq[:, :, None, :], ks, vs,
-      k_pool, v_pool, k_scale, v_scale)
+    return _paged_update_call(
+        "paged_kv_update_quant", [k_pool, v_pool, k_scale, v_scale],
+        [kq[:, :, None, :], vq[:, :, None, :], ks, vs], page,
+        [(2, update_block_tokens("int4" if int4 else "int8"),
+          _UPDATE_CHUNK_INT8),
+         (2, _SCALE_CHUNK, _SCALE_CHUNK)], int4,
+        write_idx, tables, layer, interpret)
 
 
 # ---------------------------------------------------------------------------

@@ -13,6 +13,7 @@ Nothing runs and nothing is timed.  Skipped where the installation cannot
 describe the topology (no libtpu).
 """
 
+import math
 import os
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs under /tmp
@@ -106,13 +107,29 @@ def _laguna_flat(s, chunk, window: bool):
         **({"window": LAG["window"]} if window else {}))
 
 
+def _row_write(pools, new, idx, tables):
+    """The row write as a step program holds it: the pools donated, so
+    that the compiled program shows whether they are rewritten in place."""
+    pools = [p for p in pools if p is not None]
+
+    def fn(*a):
+        pools, (new, idx, tables) = a[:-3], a[-3:]
+        if len(pools) == 4:
+            return pa.paged_kv_update_quant(*pools, new, new, idx, tables, 3)
+        if len(pools) == 2:
+            return pa.paged_kv_update(*pools, new, new, idx, tables, 3)
+        return pa.paged_kv_update(pools[0], None, new, None, idx, tables,
+                                  3)[0]
+
+    return jax.jit(fn, donate_argnums=tuple(range(len(pools)))).lower(
+        *pools, new, idx, tables)
+
+
 def _laguna_update(s):
     t = LAG["slots"] + LAG["chunk"]
-    kp, vp, ks, vs = _pool(s, "int8", LAG["hkv"])
-    new = s((t, LAG["hkv"], D), jnp.bfloat16)
-    return pa.paged_kv_update_quant.lower(
-        kp, vp, ks, vs, new, new, s((t,), jnp.int32),
-        s((t, LAG["max_pages"]), jnp.int32), 3)
+    return _row_write(_pool(s, "int8", LAG["hkv"]),
+                      s((t, LAG["hkv"], D), jnp.bfloat16), s((t,), jnp.int32),
+                      s((t, LAG["max_pages"]), jnp.int32))
 
 
 # Kimi-K2.5 (benchmarks/configs/kimi-k2.5-ep32-l9): 64 heads over ONE latent
@@ -145,11 +162,10 @@ def _latent_flat(s, chunk, **plan):
         lane, lane, lane)
 
 
-def _latent_update(s):
-    t = LAT["slots"] + LAT["chunk"]
-    return pa.paged_kv_update.lower(
-        _latent_pool(s), None, s((t, 1, LAT["row"]), jnp.bfloat16), None,
-        s((t,), jnp.int32), s((t, LAT["ctx"] // PAGE), jnp.int32), 3)
+def _latent_update(s, slots=LAT["slots"]):
+    t = slots + LAT["chunk"]
+    return _row_write([_latent_pool(s)], s((t, 1, LAT["row"]), jnp.bfloat16),
+                      s((t,), jnp.int32), s((t, LAT["ctx"] // PAGE), jnp.int32))
 
 
 def _paged_decode(s, kv):
@@ -159,15 +175,10 @@ def _paged_decode(s, kv):
         s((SLOTS, MAX_PAGES), jnp.int32), s((SLOTS,), jnp.int32), 3, ks, vs)
 
 
-def _update(s, kv, hkv=HKV):
-    t = SLOTS + CHUNK           # the mixed step's flat token batch
-    kp, vp, ks, vs = _pool(s, kv, hkv)
-    new = s((t, hkv, D), jnp.bfloat16)
-    idx, tables = s((t,), jnp.int32), s((t, MAX_PAGES), jnp.int32)
-    if kv == "bf16":
-        return pa.paged_kv_update.lower(kp, vp, new, new, idx, tables, 3)
-    return pa.paged_kv_update_quant.lower(kp, vp, ks, vs, new, new, idx,
-                                          tables, 3)
+def _update(s, kv, hkv=HKV, slots=SLOTS):
+    t = slots + CHUNK           # the mixed step's flat token batch
+    return _row_write(_pool(s, kv, hkv), s((t, hkv, D), jnp.bfloat16),
+                      s((t,), jnp.int32), s((t, MAX_PAGES), jnp.int32))
 
 
 def _slot_cache(s, kv):
@@ -200,6 +211,11 @@ CASES = {
     "mixed-bf16-chunk": lambda s: _mixed(s, "bf16", CHUNK + 1),
     "update-bf16": lambda s: _update(s, "bf16"),
     "update-int8": lambda s: _update(s, "int8"),
+    # The row write at the benchmark cells' row counts: qwen's 192 slots +
+    # 256 (448 rows), kimi's 8 + 1024 (1032), laguna's 32 + 1024 (1056,
+    # "update-int8-hkv8-64-pages" below).
+    "update-int8-448-rows": lambda s: _update(s, "int8", slots=192),
+    "latent-update-1032-rows": lambda s: _latent_update(s, slots=8),
     # The same through the flat batch's block-compacted query layout:
     # the sequential step (slots + chunk rows) at each block_q the plan
     # may choose, the pipelined step (blocks of one row), 192 slots (the
@@ -281,6 +297,16 @@ def test_kernel_compiles_for_v5e(chip, case):
     if case.startswith("window-"):
         # The window launch has a name of its own in a profile.
         assert "paged_window_attention_ragged" in text
+    if "update" in case and not case.startswith("slot-"):
+        # The row write rewrites its pools IN PLACE: every pool is
+        # aliased to its output, and nothing the size of the smallest
+        # (a scale pool) stands among the program's temporaries.
+        mem = compiled.memory_analysis()
+        pools = [a for a in jax.tree.leaves(compiled.args_info)
+                 if len(a.shape) >= 4]
+        sizes = [math.prod(a.shape) * a.dtype.itemsize for a in pools]
+        assert mem.alias_size_in_bytes >= sum(sizes)
+        assert mem.temp_size_in_bytes < min(sizes)
 
 
 @pytest.mark.parametrize("rows", [64, 320])

@@ -4,6 +4,8 @@ The compiled-TPU counterpart is chip_smoke.py's kernel parity phases and
 tests/test_chip_compile.py; here the same math runs in interpret mode so
 CPU CI exercises the kernel bodies."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -84,45 +86,163 @@ def test_paged_attention_shared_pages():
                                atol=1e-6)
 
 
-def test_paged_update_matches_oracle():
-    q, kp, vp, _, _, tables, lengths = _setup(page=16)
-    b, hkv, d = 4, 2, 32
-    key = jax.random.PRNGKey(9)
-    kn = jax.random.normal(key, (b, hkv, d), jnp.float32)
-    vn = jax.random.normal(jax.random.fold_in(key, 1), (b, hkv, d), jnp.float32)
-    for layer in (0, 1):
-        got_k, got_v = paged_kv_update(kp, vp, kn, vn, lengths, tables,
-                                       layer, interpret=True)
-        ref_k, ref_v, _, _ = paged_update_xla(
-            kp, vp, None, None, kn, vn, lengths, tables, layer)
-        np.testing.assert_allclose(np.asarray(got_k), np.asarray(ref_k))
-        np.testing.assert_allclose(np.asarray(got_v), np.asarray(ref_v))
+# The row write (``paged_kv_update`` / ``paged_kv_update_quant``): one
+# oracle test over pool kinds x batch layouts.  Every layout is laid into the
+# same 72 flat rows (the rest padding), so a kind compiles its kernel and
+# its oracle once.
+_UPD = dict(l=2, n=14, hkv=2, page=128, d=32, slots=4, max_pages=3, rows=72)
+_PAD = None   # a padding row: its position is past the table's coverage
 
 
-def test_paged_update_quant_matches_oracle():
-    q, kp, vp, kps, vps, tables, lengths = _setup(quantized=True, page=128)
-    b, hkv, d = 4, 2, 32
-    key = jax.random.PRNGKey(11)
-    kn = jax.random.normal(key, (b, hkv, d), jnp.float32)
-    vn = jax.random.normal(jax.random.fold_in(key, 1), (b, hkv, d), jnp.float32)
-    got = paged_kv_update_quant(kp, vp, kps, vps, kn, vn, lengths, tables,
-                                1, interpret=True)
-    ref = paged_update_xla(kp, vp, kps, vps, kn, vn, lengths, tables, 1)
+def _lone(t):
+    """Flat row ``t`` of the ring-wrap layout: neighbours never share a
+    page, and no two rows a position."""
+    return (t % 4, (t // 4) * 34 + t % 4)
+
+
+UPDATE_LAYOUTS = {
+    # One decode row a slot, ragged positions (the lanes of a decode step).
+    "a-row-a-slot": [(i, 1 + (i * 7919) % 383) for i in range(4)],
+    # Nothing but padding: the pools come back untouched.
+    "all-padding": [],
+    # A chunk that starts and ends inside one block (of 16 and of 32).
+    "chunk-inside-a-block": [(0, p) for p in range(35, 45)],
+    # A chunk over blocks, a scale group and the page boundary at 128.
+    "chunk-across-a-page": [(1, p) for p in range(90, 141)],
+    # Two slots' runs side by side whose positions continue each other: the
+    # same block index of two different pages.
+    "two-slots-side-by-side": ([(0, p) for p in range(10, 20)]
+                               + [(1, p) for p in range(20, 30)]),
+    # Padding before, inside (mid-block and at a block's edge) and after.
+    "padding-in-a-run": ([_PAD, _PAD] + [(2, p) for p in range(60, 64)]
+                         + [_PAD] + [(2, p) for p in range(64, 71)]
+                         + [_PAD, _PAD] + [(2, p) for p in range(71, 76)]
+                         + [_PAD]),
+    # Lone rows only, some of them neighbours in a page but not in a block.
+    "lone-rows": [(0, 5), (0, 70), (1, 5), (2, 200), (2, 130), (3, 383),
+                  (3, 0), (0, 140)],
+    # 44 lone rows: the ring of scratch slots wraps eleven times, and rows
+    # come back to blocks whose write is still on its way.
+    "ring-wraps": [_lone(t) for t in range(44)],
+    # Two int4 rows in one byte (6, 7), two in neighbouring bytes (9, 10).
+    "rows-sharing-a-byte": [(3, 6), (3, 7), (3, 9), (3, 10)],
+    # Two chunks' rows shuffled: rows that are NOT neighbours share a block,
+    # so a block is read again right behind its own write.
+    "shuffled": [(0, p) for p in range(40)] + [(1, p) for p in range(100, 131)],
+}
+
+
+@functools.cache
+def _update_pools(kind: str):
+    """``(pools, tables, kernel, oracle)``; pools = (k, v, k_scale,
+    v_scale) with None where the kind has none."""
+    u = _UPD
+    ks = jax.random.split(jax.random.PRNGKey(5), 6)
+    shape = (u["l"], u["n"], u["hkv"], u["page"], u["d"])
+    tables = jax.random.permutation(ks[4], u["n"])[
+        :u["slots"] * u["max_pages"]].reshape(
+            u["slots"], u["max_pages"]).astype(jnp.int32)
+    if kind in ("bf16", "latent"):
+        kp = jax.random.normal(ks[0], shape, jnp.bfloat16)
+        vp = None if kind == "latent" else jax.random.normal(
+            ks[1], shape, jnp.bfloat16)
+
+        def kernel(kp, vp, ksc, vsc, kn, vn, idx, tbl, layer, interpret):
+            return paged_kv_update(kp, vp, kn, vn, idx, tbl, layer,
+                                   interpret=interpret) + (None, None)
+
+        def oracle(kp, vp, ksc, vsc, kn, vn, idx, tbl, layer):
+            # The oracle writes pairs: a latent pool stands in for both.
+            out = paged_update_xla(kp, kp if vp is None else vp, None, None,
+                                   kn, kn if vn is None else vn, idx, tbl,
+                                   layer)
+            return (out[0], None if vp is None else out[1], None, None)
+
+        return (kp, vp, None, None), tables, kernel, jax.jit(oracle)
+    top = 8 if kind == "int4" else 128
+    kp = jax.random.randint(ks[0], shape, 1 - top, top, jnp.int8)
+    vp = jax.random.randint(ks[1], shape, 1 - top, top, jnp.int8)
+    if kind == "int4":
+        kp, vp = pack_int4(kp, axis=3), pack_int4(vp, axis=3)
+    ksc = jax.random.uniform(ks[2], shape[:4], jnp.float32, 0.01, 0.03)
+    vsc = jax.random.uniform(ks[3], shape[:4], jnp.float32, 0.01, 0.03)
+
+    def kernel(kp, vp, ksc, vsc, kn, vn, idx, tbl, layer, interpret):
+        return paged_kv_update_quant(kp, vp, ksc, vsc, kn, vn, idx, tbl,
+                                     layer, interpret=interpret)
+
+    return (kp, vp, ksc, vsc), tables, kernel, jax.jit(paged_update_xla)
+
+
+def _update_batch(layout: str, tables, seed: int = 0):
+    """The flat batch of a layout: ``(k_new, v_new, write_idx, per-row
+    tables)``; ``shuffled`` permutes its rows."""
+    u = _UPD
+    rows = list(UPDATE_LAYOUTS[layout])
+    if layout == "shuffled":
+        rows = [rows[i] for i in np.random.default_rng(seed).permutation(
+            len(rows))]
+    rows += [_PAD] * (u["rows"] - len(rows))
+    cover = u["max_pages"] * u["page"]
+    idx = np.asarray([cover + 7 if r is _PAD else r[1] for r in rows],
+                     np.int32)
+    slot = np.asarray([0 if r is _PAD else r[0] for r in rows])
+    key = jax.random.PRNGKey(17 + seed)
+    kn = jax.random.normal(key, (u["rows"], u["hkv"], u["d"]), jnp.float32)
+    vn = jax.random.normal(jax.random.fold_in(key, 1), kn.shape, jnp.float32)
+    return kn, vn, jnp.asarray(idx), jnp.asarray(tables)[slot]
+
+
+def _assert_same_bytes(got, ref):
     for g, r in zip(got, ref):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(r))
+        assert (g is None) == (r is None)
+        if g is not None:
+            g, r = np.asarray(g), np.asarray(r)
+            assert g.dtype == r.dtype and g.shape == r.shape
+            np.testing.assert_array_equal(g.view(np.uint8), r.view(np.uint8))
 
 
-def test_paged_update_out_of_range_dropped():
-    """write_idx beyond the table's coverage must not corrupt the pool."""
-    _, kp, vp, _, _, tables, _ = _setup(page=16)
-    b, hkv, d = 4, 2, 32
-    kn = jnp.ones((b, hkv, d), jnp.float32)
-    vn = jnp.ones((b, hkv, d), jnp.float32)
-    idx = jnp.full((b,), 4 * 16, jnp.int32)  # == max_pages * page
-    got_k, got_v = paged_kv_update(kp, vp, kn, vn, idx, tables, 0,
-                                   interpret=True)
-    np.testing.assert_allclose(np.asarray(got_k), np.asarray(kp))
-    np.testing.assert_allclose(np.asarray(got_v), np.asarray(vp))
+@pytest.mark.parametrize("layout", sorted(UPDATE_LAYOUTS))
+@pytest.mark.parametrize("kind", ["bf16", "latent", "int8", "int4"])
+def test_paged_update_matches_oracle(kind, layout):
+    """The block-wise row write leaves every pool BYTE FOR BYTE what
+    ``paged_update_xla``'s scatter leaves: K and V pages, a latent pool, int8
+    pages with their scale groups, int4 nibbles."""
+    pools, tables, kernel, oracle = _update_pools(kind)
+    kn, vn, idx, tbl = _update_batch(layout, tables)
+    if kind == "latent":
+        vn = None
+    got = kernel(*pools, kn, vn, idx, tbl, 1, True)
+    _assert_same_bytes(got, oracle(*pools, kn, vn, idx, tbl, 1))
+    touched = any(r is not _PAD for r in UPDATE_LAYOUTS[layout])
+    assert touched != all(
+        np.array_equal(np.asarray(g), np.asarray(p))
+        for g, p in zip(got, pools) if g is not None)
+    # The other layer is nobody's to touch.
+    _assert_same_bytes([g if g is None else g[0] for g in got],
+                       [p if p is None else p[0] for p in pools])
+
+
+@pytest.mark.parametrize("layout", ["ring-wraps", "shuffled",
+                                    "chunk-across-a-page",
+                                    "padding-in-a-run"])
+@pytest.mark.parametrize("kind", ["latent", "int8", "int4"])
+def test_paged_update_waits_for_what_it_reads(kind, layout):
+    """The same under the TPU interpreter, whose DMAs move their bytes when
+    they are WAITED for: a block read again before its write-back was
+    waited for reads stale bytes there, and a copy nobody waits for never
+    lands.  Three seeds of ``shuffled`` bring a block back at every distance
+    the ring allows."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    pools, tables, kernel, oracle = _update_pools(kind)
+    mode = pltpu.InterpretParams(dma_execution_mode="on_wait")
+    for seed in range(3 if layout == "shuffled" else 1):
+        kn, vn, idx, tbl = _update_batch(layout, tables, seed)
+        if kind == "latent":
+            vn = None
+        _assert_same_bytes(kernel(*pools, kn, vn, idx, tbl, 1, mode),
+                           oracle(*pools, kn, vn, idx, tbl, 1))
 
 
 # ---------------------------------------------------------------------------
@@ -548,29 +668,6 @@ def test_mixed_grid_plan_pads_awkward_qmax():
             np.testing.assert_allclose(
                 np.asarray(out[s, :, :, i], np.float32), ref[s, :, :, i],
                 atol=2e-5, rtol=2e-5)
-
-
-def test_paged_update_quant_int4_matches_oracle():
-    """int4 RMW update kernel vs the two-parity-pass XLA oracle: packed
-    values bitwise identical; scales allclose (the jitted wrapper compiles
-    amax/7 as a reciprocal multiply — 1-ULP vs the eager oracle)."""
-    _, (kp, vp), _, kps, vps, tables = _setup_int4(page=128)
-    b, hkv, d = 4, 2, 32
-    key = jax.random.PRNGKey(11)
-    kn = jax.random.normal(key, (b, hkv, d), jnp.float32)
-    vn = jax.random.normal(jax.random.fold_in(key, 1), (b, hkv, d),
-                           jnp.float32)
-    # Odd AND even token offsets in one batch: both nibble paths taken.
-    lengths = jnp.asarray([1, 2, 129, 256], jnp.int32)
-    got = paged_kv_update_quant(kp, vp, kps, vps, kn, vn, lengths, tables,
-                                1, interpret=True)
-    ref = paged_update_xla(kp, vp, kps, vps, kn, vn, lengths, tables, 1)
-    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(ref[0]))
-    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(ref[1]))
-    np.testing.assert_allclose(np.asarray(got[2]), np.asarray(ref[2]),
-                               atol=1e-6, rtol=1e-5)
-    np.testing.assert_allclose(np.asarray(got[3]), np.asarray(ref[3]),
-                               atol=1e-6, rtol=1e-5)
 
 
 def test_paged_mixed_attention_decode_lane_matches_decode_kernel():

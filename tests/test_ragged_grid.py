@@ -487,10 +487,22 @@ def test_pipelined_shape_lays_out_one_row_a_lane(lanes):
 def test_q_layout_rows_counter_follows_the_plan(monkeypatch):
     """mixed_q_layout_rows_total rises by the plan's q_rows a dispatch
     (nb x block_q), whatever the batch holds: to be read against the real
-    rows, the sum of mixed_batch_tokens."""
+    rows, the sum of mixed_batch_tokens.  mixed_kv_write_blocks_total
+    rises by the distinct (slot, position // block rows) the dispatch's
+    live rows touch (16 rows a block of this float32 pool): what the row
+    write reads and writes back a layer."""
     cfg, eng = _mk_engine(monkeypatch, impl="xla", num_slots=8)
-    eng.add_request(Request("r0", [5, 6, 7], SamplingParams(
-        max_tokens=3, temperature=0.0, ignore_eos=True)))
+    seen = []
+    inner = eng._mixed_grid_counters
+
+    def spy(pos_start, q_len, qmax):
+        seen.append((pos_start.copy(), q_len.copy()))
+        inner(pos_start, q_len, qmax)
+
+    monkeypatch.setattr(eng, "_mixed_grid_counters", spy)
+    for i, n in enumerate((3, 40, 21)):
+        eng.add_request(Request(f"r{i}", list(range(5, 5 + n)), SamplingParams(
+            max_tokens=3, temperature=0.0, ignore_eos=True)))
     _drive(eng)
     plan = next(iter(eng._grid_plans.values()))
     n_dispatches = sum(
@@ -499,3 +511,11 @@ def test_q_layout_rows_counter_follows_the_plan(monkeypatch):
     assert n_dispatches > 0 and rows == n_dispatches * plan["q_rows"]
     assert plan["q_rows"] == plan["nb"] * plan["block_q"]
     assert plan["nb"] == 8 + -(-eng._mixed_budget // plan["block_q"])
+    blk = eng._kv_write_block
+    assert blk == 16 and len(seen) == n_dispatches
+    want = sum(len({(s, p // blk) for s in range(len(ql))
+                    for p in range(ps[s], ps[s] + ql[s])})
+               for ps, ql in seen)
+    live = sum(s for _, s, _ in eng.metrics.mixed_batch_tokens._data.values())
+    got = eng.metrics.mixed_kv_write_blocks_total.total()
+    assert got == want and n_dispatches <= got < live

@@ -70,6 +70,23 @@ its value is what commit 24dfdfe (PR 41, the parent of PR 44) lowers, taken
 with ``whole_layer_hash`` in that tree, so that the tile's one reading
 (``dynamic_slice`` with an empty lead) is shown to be the text
 ``dynamic_index_in_dim`` of the layer's leaf was.
+
+PR 45 moved NONE of the twenty-two (ISSUE 45 expected all of them to move;
+they stand, and here is why).  It rewrote the KV row write's Pallas kernel
+(``ops/paged_attention.py::paged_kv_update`` / ``paged_kv_update_quant``: one
+read-modify-write a touched block, the next block's read in flight).  Every
+preset here holds a page pool, but an engine on the CPU that nobody steers
+resolves to the XLA attention branch, whose row write is
+``paged_update_xla``'s scatter: these programs never carried the kernel's
+interpreted form, and no line of them is traced through the file's changed
+half.  So the pins say only that the step programs AROUND the write are the
+text they were.  What the kernel itself leaves in the pools is held byte
+for byte against that scatter by ``tests/test_paged_attention.py::
+test_paged_update_matches_oracle`` (pool kinds x batch layouts) and
+``test_paged_update_waits_for_what_it_reads``; that the chip's compiler
+takes it, in place, at the cells' row counts by ``tests/test_chip_compile.py``;
+and that no cell's outputs move by the driver's cells on the chip
+(``logprob_err`` on a pair's seed), not by pins.
 """
 
 import hashlib
