@@ -1118,6 +1118,75 @@ def _watch_compilations(engine: "InferenceEngine") -> None:
     jax.monitoring.register_event_duration_secs_listener(on_duration)
 
 
+def _kv_page(cfg: ModelConfig) -> str:
+    """What a slot of the model holds (the label ``kv_page``).  A page: K
+    and V per KV head ("kv"), or ONE latent row a token (latent attention),
+    which is key and value at once ("latent").  Beside its pages:
+    "+window", a second pool, the window layers' pages released behind the
+    window; "+state", a fixed recurrent state a slot (linear-attention
+    layers)."""
+    return ("latent" if cfg.latent else "kv") + (
+        "+window" if cfg.windowed else "+state" if cfg.linear else "")
+
+
+@dataclasses.dataclass(frozen=True)
+class _Block:
+    """What is said of a block whose slot is NOT K and V pages of every
+    layer in one pool, where it is refused something
+    (InferenceEngine._block_preflight)."""
+    what: str         # the sentence that names the block
+    slot_keeps: str   # what the slot layout does, that such a slot is not
+    moves: str        # what the movers of KV blocks move
+    mesh_why: str     # why no mesh
+    latent_page: bool = False   # kv_cache_dtype: bf16 only, "auto" is bf16
+    # Why the device prefix index is OFF for such a model, whatever
+    # --prefix-cache-mb says: no prompt is ever indexed or matched
+    # (_register_prompt_pages).  None: the index shares PAGES by id and
+    # keeps working.
+    no_index: str | None = None
+
+
+_BLOCKS = {
+    # A bf16 latent pool.
+    "latent": _Block(
+        "latent attention, one latent row a token",
+        "holds K and V per head", "K and V blocks",
+        "the latent block has no sharding rules", latent_page=True),
+    # TWO page pools: the full layers' pages live as long as the sequence,
+    # the window layers' are released behind the window
+    # (engine/paged.py::WindowPages).  A matched prefix's full pages would
+    # still be there and its window pages gone.
+    "kv+window": _Block(
+        "window and full attention layers over two page pools",
+        "keeps every position of every layer",
+        "every layer's page of one pool",
+        "layers of two head counts have no sharding rules",
+        no_index="window pages would be gone"),
+    # The GQA layers keep pages, the linear layers a fixed state a slot
+    # (transformer.py::LinearState) that the step program rewrites in
+    # place: a sequence's linear layers have no page, and their state is
+    # not carried.  A prefix hit would need the state AT the prefix's end,
+    # which nobody kept.
+    "kv+state": _Block(
+        "linear-attention layers with a fixed state a slot beside GQA "
+        "layers over pages",
+        "keeps K and V of every layer",
+        "every layer's page and no recurrent state",
+        "linear-attention layers and their state have no sharding rules",
+        no_index="recurrent state at its end is not kept"),
+    # A bf16 latent pool beside the state: the movers speak K and V blocks
+    # and carry neither.
+    "latent+state": _Block(
+        "linear-attention layers with a fixed state a slot beside "
+        "latent-attention layers over one latent row a token",
+        "keeps K and V of every layer",
+        "K and V blocks, and neither a latent row nor a recurrent state",
+        "neither the latent block nor the linear layers' state has "
+        "sharding rules", latent_page=True,
+        no_index="recurrent state at its end is not kept"),
+}
+
+
 class InferenceEngine:
     def __init__(
         self,
@@ -1400,9 +1469,9 @@ class InferenceEngine:
             self.pool.adopt(cfg.name, cfg, self.params, pinned=True)
             self.pool.acquire(cfg.name)   # active-model ref, held until switch
             if self._draft_cfg is not None:
-                # Satellite of ROADMAP item 3: the draft rides the shared
-                # pool (pinned co-resident with the flagship) instead of a
-                # second free-floating load_params tree.
+                # The draft rides the shared pool (pinned co-resident with
+                # the flagship) instead of a second free-floating
+                # load_params tree.
                 self.pool.adopt(self._draft_cfg.name, self._draft_cfg,
                                 self._draft_params, pinned=True)
             if self.pool.metrics is None:
@@ -1442,12 +1511,7 @@ class InferenceEngine:
         tokenizer = self.tokenizer
         self.cfg = cfg
         self.ecfg = engine_cfg
-        if cfg.latent and not cfg.linear:
-            self._latent_preflight(cfg, engine_cfg, draft_cfg)
-        if cfg.windowed:
-            self._windowed_preflight(cfg, engine_cfg, draft_cfg)
-        if cfg.linear:
-            self._linear_preflight(cfg, engine_cfg, draft_cfg)
+        self._block_preflight(cfg, engine_cfg, draft_cfg)
         # The step returns four counts beside its token ids (held pairs,
         # a share's overflow tiles needed and looped, valid rows):
         # _count_held.
@@ -1753,10 +1817,8 @@ class InferenceEngine:
         if host_mb < 0:
             raise ValueError(
                 f"ARKS_PREFIX_HOST_MB={host_mb}: must be >= 0")
-        self._host_mb = host_mb if (self._paged and self._chunk
-                                    and host_mb and not cfg.latent
-                                    and not cfg.windowed
-                                    and not cfg.linear) else 0
+        self._host_mb = host_mb if (self._paged and self._chunk and host_mb
+                                    and _kv_page(cfg) not in _BLOCKS) else 0
         if keep_tiers is not None:
             # Elastic rebuild: adopt the surviving tier-1 store (blocks
             # are full logical host arrays — mesh-shape-independent).
@@ -2035,7 +2097,7 @@ class InferenceEngine:
         # tp > 1 replicas return (two SPMD compilations of the step round
         # differently; the one four-chip run that compared the greedy
         # streams found them different), so that waits for a
-        # teacher-forced comparison on chips (ROADMAP S7).
+        # teacher-forced comparison on chips (ROADMAP S11).
         meshed = mesh is not None and mesh.size > 1
         self._pipe_depth = 0 if meshed else self._pipe_depth_cfg
         if meshed and self._pipe_depth_cfg:
@@ -2078,18 +2140,7 @@ class InferenceEngine:
             "overlap": str(bool(self._overlap)).lower(),
             "kv_cache_dtype": self.ecfg.resolve_kv_cache_dtype(),
             "kv_dtype": self.ecfg.resolve_kv_cache_dtype(),
-            # What a page holds: K and V per KV head, or ONE latent row a
-            # token (latent attention), which is key and value at once.
-            # "kv+window": two pools, the window layers' pages released
-            # behind the window.
-            # "kv+state": the GQA layers' pages beside a fixed recurrent
-            # state a slot (linear-attention layers).
-            # "latent+state": the latent layers' pages beside the linear
-            # layers' state.
-            "kv_page": ("latent+state" if cfg.latent and cfg.linear else
-                        "latent" if cfg.latent else
-                        "kv+window" if cfg.windowed else
-                        "kv+state" if cfg.linear else "kv"),
+            "kv_page": _kv_page(cfg),
             # The dtype the delta rule's state IS kept in, read off the
             # cache this engine built ("none": no linear layer): a
             # deployment's expect_labels holds the step to the precision
@@ -4912,9 +4963,8 @@ class InferenceEngine:
         if self._win is not None or self._lin_slot_bytes:
             # No prefix is ever indexed, so none is ever matched: a hit
             # would start a prompt behind window pages that are gone
-            # (_windowed_preflight says so at construction), or behind a
-            # prefix whose recurrent state at its end nobody kept
-            # (_linear_preflight).
+            # (_block_preflight says so at construction), or behind a
+            # prefix whose recurrent state at its end nobody kept.
             return
         from arks_tpu.engine.paged import chain_digests
         page = self._page_size()
@@ -8764,21 +8814,44 @@ class InferenceEngine:
             self.metrics.moe_overflow_tiles_total.inc(needed, kind="needed")
             self.metrics.moe_overflow_tiles_total.inc(extra, kind="extra")
 
-    def _kv_mover_refusals(self, ecfg: "EngineConfig", draft_cfg,
-                           moves: str, mesh_why: str) -> list[str]:
-        """What a model whose pages are not "K and V of every layer in one
-        pool" (a latent row; two pools) cannot be served with, each by
-        name: a mesh, a draft, no chunked prefill, and every mover of KV
-        blocks that was ASKED for (``moves`` says what they move; the host
-        tier is on by default, so its default is off for such a model,
-        _init_model_state, and only a tier asked for is refused)."""
+    def _block_preflight(self, cfg: ModelConfig, ecfg: "EngineConfig",
+                         draft_cfg) -> None:
+        """What ``cfg``'s block cannot be served with, refused here, at
+        construction, each by name (as ops.attention.kernel_blockers does
+        for a kernel); nothing falls back quietly.  A block whose slot
+        holds K and V pages of every layer in one pool (``_BLOCKS`` has no
+        row for it) is refused nothing.  Every other block is served by the
+        mixed scheduler on one device over pages, and everything else that
+        moves KV speaks K and V blocks of "every layer's page" of one
+        pool: a mesh, a draft, no chunked prefill, and every mover of KV
+        blocks that was ASKED for (the host tier is on by default, so its
+        default is off for such a model, _init_model_state, and only a
+        tier asked for is refused).  A model that is latent AND linear
+        says what it is once, in one sentence.  Token replay after a fault
+        stays for every block: it re-prefills from position 0, which
+        rebuilds a recurrent state too."""
+        block = _BLOCKS.get(_kv_page(cfg))
+        if block is None:
+            return
+        if block.latent_page and ecfg.kv_cache_dtype == "auto":
+            ecfg.kv_cache_dtype = "bf16"
+        if ecfg.kv_layout == "auto":
+            ecfg.kv_layout = "paged"
         why = []
+        if block.latent_page and ecfg.kv_cache_dtype != "bf16":
+            why.append(f"kv_cache_dtype={ecfg.kv_cache_dtype} (a latent "
+                       "page is bf16 only: an int8 / int4 latent row is "
+                       "not built)")
+        if ecfg.kv_layout != "paged":
+            why.append(f"kv_layout={ecfg.kv_layout} (the slot layout "
+                       f"{block.slot_keeps})")
         if self.mesh is not None and self.mesh.size > 1:
             why.append(f"a device mesh {dict(self.mesh.shape)} (tensor / "
-                       f"data / context / pipeline parallelism: {mesh_why})")
+                       "data / context / pipeline parallelism: "
+                       f"{block.mesh_why})")
         if ecfg.draft_model or draft_cfg is not None:
             why.append("speculative decoding (the draft and the verify "
-                       f"rows move {moves})")
+                       f"rows move {block.moves})")
         if not ecfg.prefill_chunk:
             why.append("prefill_chunk off (the mixed scheduler needs "
                        "chunked prefill)")
@@ -8787,125 +8860,20 @@ class InferenceEngine:
                 ("ARKS_PREFIX_DISK_MB", "the disk spill tier"),
                 ("ARKS_RESIDENCY_WINDOW_PAGES", "windowed residency")):
             if knobs.is_set(knob) and knobs.get_int(knob) > 0:
-                why.append(f"{knob} ({what} moves {moves})")
+                why.append(f"{knob} ({what} moves {block.moves})")
         if knobs.get_bool("ARKS_PREEMPT"):
-            why.append(f"ARKS_PREEMPT (the KV swap moves {moves})")
+            why.append(f"ARKS_PREEMPT (the KV swap moves {block.moves})")
         if [a for a in knobs.get_list("ARKS_PEER_ADDRS") if a.strip()]:
-            why.append(f"ARKS_PEER_ADDRS (peer fetch carries {moves} in the "
-                       "AKV1 format)")
+            why.append("ARKS_PEER_ADDRS (peer fetch carries "
+                       f"{block.moves} in the AKV1 format)")
         if knobs.get_str("ARKS_MIXED_STEP") == "0":
             why.append("ARKS_MIXED_STEP=0 (the legacy scheduler)")
-        return why
-
-    def _windowed_preflight(self, cfg: ModelConfig, ecfg: "EngineConfig",
-                            draft_cfg) -> None:
-        """A model with window and full attention layers is served by the
-        mixed scheduler over TWO page pools on one device: the full
-        layers' pages live as long as the sequence, the window layers' are
-        released behind the window (engine/paged.py::WindowPages).
-        Everything else that moves KV packs "every layer's page" of one
-        pool; asked for, it is refused here, at construction, by name, and
-        nothing falls back quietly.  The device prefix index is OFF for
-        such a model, whatever --prefix-cache-mb says: a matched prefix's
-        full pages would still be there and its window pages gone, so no
-        prompt is ever indexed or matched (_register_prompt_pages)."""
-        if ecfg.kv_layout == "auto":
-            ecfg.kv_layout = "paged"
-        why = []
-        if ecfg.kv_layout != "paged":
-            why.append(f"kv_layout={ecfg.kv_layout} (the slot layout keeps "
-                       "every position of every layer)")
-        why += self._kv_mover_refusals(
-            ecfg, draft_cfg, "every layer's page of one pool",
-            "layers of two head counts have no sharding rules")
         if why:
-            raise ValueError(
-                f"model {cfg.name!r} (window and full attention layers over "
-                "two page pools) cannot be served with: " + "; ".join(why))
-        if ecfg.prefix_cache_mb:
+            raise ValueError(f"model {cfg.name!r} ({block.what}) cannot be "
+                             "served with: " + "; ".join(why))
+        if block.no_index and ecfg.prefix_cache_mb:
             log.info("model %s: the device prefix index is off (a matched "
-                     "prefix's window pages would be gone)", cfg.name)
-
-    def _linear_preflight(self, cfg: ModelConfig, ecfg: "EngineConfig",
-                          draft_cfg) -> None:
-        """A model with linear-attention layers is served by the mixed
-        scheduler on one device: its GQA layers keep pages, its linear
-        layers a fixed state a slot (transformer.py::LinearState) that the
-        step program rewrites in place.  Everything else that moves KV
-        packs "every layer's page": a sequence's linear layers have no
-        page, and their state is not carried; asked for, each is refused
-        here, at construction, by name, and nothing falls back quietly.
-        The device prefix index is OFF for such a model, whatever
-        --prefix-cache-mb says: a hit would need the state AT the prefix's
-        end, which nobody kept (_register_prompt_pages).  Token replay
-        after a fault stays: it re-prefills from position 0, which
-        rebuilds the state.  Where the other layers are LATENT layers
-        (``cfg.latent``: a bf16 latent pool beside the state) this is the
-        model's one preflight, and it says one thing: the movers speak K
-        and V blocks and carry neither a latent row nor a state."""
-        if cfg.latent and ecfg.kv_cache_dtype == "auto":
-            ecfg.kv_cache_dtype = "bf16"
-        if ecfg.kv_layout == "auto":
-            ecfg.kv_layout = "paged"
-        why = []
-        if cfg.latent and ecfg.kv_cache_dtype != "bf16":
-            why.append(f"kv_cache_dtype={ecfg.kv_cache_dtype} (a latent "
-                       "page is bf16 only: an int8 / int4 latent row is "
-                       "not built)")
-        if ecfg.kv_layout != "paged":
-            why.append(f"kv_layout={ecfg.kv_layout} (the slot layout keeps "
-                       "K and V of every layer)")
-        if cfg.latent:
-            beside, moves, mesh_why = (
-                "latent-attention layers over one latent row a token",
-                "K and V blocks, and neither a latent row nor a recurrent "
-                "state",
-                "neither the latent block nor the linear layers' state has "
-                "sharding rules")
-        else:
-            beside, moves, mesh_why = (
-                "GQA layers over pages",
-                "every layer's page and no recurrent state",
-                "linear-attention layers and their state have no sharding "
-                "rules")
-        why += self._kv_mover_refusals(ecfg, draft_cfg, moves, mesh_why)
-        if why:
-            raise ValueError(
-                f"model {cfg.name!r} (linear-attention layers with a fixed "
-                f"state a slot beside {beside}) cannot be "
-                "served with: " + "; ".join(why))
-        if ecfg.prefix_cache_mb:
-            log.info("model %s: the device prefix index is off (a matched "
-                     "prefix's recurrent state at its end is not kept)",
-                     cfg.name)
-
-    def _latent_preflight(self, cfg: ModelConfig, ecfg: "EngineConfig",
-                          draft_cfg) -> None:
-        """A latent-attention model is served by the mixed scheduler over a
-        bf16 latent pool on one device.  Everything else that moves KV
-        still speaks K and V; asked for, it is refused here, at
-        construction, by name (as ops.attention.kernel_blockers does for a
-        kernel), and nothing falls back quietly.  The device-tier prefix
-        cache shares PAGES by id and keeps working."""
-        if ecfg.kv_cache_dtype == "auto":
-            ecfg.kv_cache_dtype = "bf16"
-        if ecfg.kv_layout == "auto":
-            ecfg.kv_layout = "paged"
-        why = []
-        if ecfg.kv_cache_dtype != "bf16":
-            why.append(f"kv_cache_dtype={ecfg.kv_cache_dtype} (a latent "
-                       "page is bf16 only: an int8 / int4 latent row is "
-                       "not built)")
-        if ecfg.kv_layout != "paged":
-            why.append(f"kv_layout={ecfg.kv_layout} (the slot layout holds "
-                       "K and V per head)")
-        why += self._kv_mover_refusals(
-            ecfg, draft_cfg, "K and V blocks",
-            "the latent block has no sharding rules")
-        if why:
-            raise ValueError(
-                f"model {cfg.name!r} (latent attention, one latent row a "
-                "token) cannot be served with: " + "; ".join(why))
+                     "prefix's %s)", cfg.name, block.no_index)
 
     def _page_head_bytes(self) -> int:
         """Bytes one (page, KV head) block moves over the mixed kernel's

@@ -211,17 +211,14 @@ class ModelConfig:
     @property
     def num_periods(self) -> int:
         """Periods of window (linear) layers and one full layer behind the
-        head."""
-        if not self.inner_period:
-            return 0
+        head; without such layers every layer behind the head is a period
+        of its own."""
         return (self.num_layers - self.head_layers - self.lead_layers) // (
             self.inner_period + 1)
 
     @property
     def inner_tail(self) -> int:
         """Window (linear) layers behind the last whole period."""
-        if not self.inner_period:
-            return 0
         return (self.num_layers - self.head_layers - self.lead_layers) % (
             self.inner_period + 1)
 
@@ -254,8 +251,6 @@ class ModelConfig:
     def layer_kinds(self) -> tuple[str, ...]:
         """``"full"`` / ``"window"`` / ``"linear"`` of every layer, in model
         order."""
-        if not self.inner_period:
-            return ("full",) * self.num_layers
         inner = "linear" if self.linear else "window"
         head = inner if self.linear_head else "full"
         lead = ((inner,) * self.short_period + ("full",)

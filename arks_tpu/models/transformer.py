@@ -878,57 +878,6 @@ def _mla_out(attn: jnp.ndarray, lp: Params, cfg: ModelConfig,
     return qeinsum("...q,qe->...e", o, lp["wo"])
 
 
-def _mixed_step_latent(params, cfg, cache, tables, tokens, token_slot,
-                       token_pos, sample_src, seq_q_start, seq_q_len,
-                       seq_pos_start, mesh):
-    """:func:`mixed_step` for the latent block: the dense prefix stack and
-    the routed stack, each its own scan, one after the other over ONE
-    latent pool (a layer's index in the pool is its index in the model).
-    One attention path, the absorbed one, for chunks and decode lanes
-    alike.  Returns (logits, cache, counts): :func:`moe.moe_ffn`'s three,
-    each summed over the routed layers."""
-    from arks_tpu.ops.attention import paged_latent_update_and_attend
-    cover = tables.shape[1] * cache.page
-    rope_pos = jnp.minimum(token_pos, cover - 1)[None]           # [1, T]
-    valid = (token_slot >= 0)[None]
-    first = params["dense_layers"] if cfg.first_k_dense else params["layers"]
-    with _scope("arks.embed"):
-        h = embed_lookup(params["embed"], tokens[None],
-                         first["attn_norm"].dtype)               # [1, T, E]
-
-    def body(stack, base, carry, xs):
-        h, pool = carry
-        lp, at = xs                    # the layer, and its index in stack
-        layer = at + base if base else at
-        x = rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps)
-        attn, pool = paged_latent_update_and_attend(
-            _mla_q(x, lp, cfg, rope_pos)[0], _mla_kv(x, lp, cfg, rope_pos)[0],
-            pool, tables, token_slot, token_pos, seq_q_start, seq_q_len,
-            seq_pos_start, layer, dv=cfg.kv_lora_rank,
-            scale=cfg.softmax_scale)
-        h = h + _mla_out(attn, lp, cfg)[None]
-        if "router" in lp:
-            y, held = _mlp(h, lp, cfg, mesh, None, row_valid=valid,
-                           stack=(stack, at))
-        else:
-            y, held = _mlp(h, lp, cfg, mesh, None), _NO_COUNTS
-        return (h + y, pool), held
-
-    pool, held, base = cache.k, _NO_COUNTS, 0
-    for name in ("dense_layers", "layers"):
-        if name not in params:
-            continue
-        n = params[name]["attn_norm"].shape[0]
-        (h, pool), per_layer = jax.lax.scan(
-            functools.partial(body, params[name], base), (h, pool),
-            (params[name], jnp.arange(n, dtype=jnp.int32)))
-        held, base = held + jnp.sum(per_layer, axis=0), base + n
-    with _scope("arks.lm_head"):
-        h_sel = jnp.take(h[0], sample_src.astype(jnp.int32), axis=0)  # [B, E]
-    logits = _unembed(h_sel, params, cfg, mesh, None)
-    return logits, PagedKVCache(k=pool, v=None), held
-
-
 def _kind_qkv(h: jnp.ndarray, lp: Params, cfg: ModelConfig,
               positions: jnp.ndarray, window: bool):
     """:func:`_block_qkv` for a layer of one kind of a model with window
@@ -1201,31 +1150,71 @@ def _linear_out(o: jnp.ndarray, x: jnp.ndarray, lp: Params,
                    lp["wo"])
 
 
-def _mixed_step_periods(params, cfg, cache, tables, win_tables, tokens,
-                        token_slot, token_pos, sample_src, seq_q_start,
-                        seq_q_len, seq_pos_start, mesh):
-    """:func:`mixed_step` for a model whose layers come in periods: the
+def mixed_step(
+    params: Params,
+    cfg: ModelConfig,
+    cache: PagedKVCache,
+    tables: jnp.ndarray,       # [B, MaxP] int32 — lane b == slot b
+    tokens: jnp.ndarray,       # [T] int32 flat mixed token batch
+    token_slot: jnp.ndarray,   # [T] int32 slot per token (-1 = padding)
+    token_pos: jnp.ndarray,    # [T] int32 global position per token
+    sample_src: jnp.ndarray,   # [B] int32 — flat index each lane samples from
+    seq_q_start: jnp.ndarray,  # [B] int32 — lane's first flat-token index
+    seq_q_len: jnp.ndarray,    # [B] int32 — lane's token count (0 inactive)
+    seq_pos_start: jnp.ndarray,  # [B] int32 — lane's first global position
+    mesh: Mesh | None = None,
+    with_held: bool = False,
+    win_tables: jnp.ndarray | None = None,  # [B, MaxP] — window layers'
+) -> tuple[jnp.ndarray, PagedKVCache]:
+    """One unified mixed prefill+decode forward: a flat ``[T]`` token batch
+    carrying every decoding slot's next token PLUS one or more sequences'
+    prefill-chunk tokens runs the model ONCE, writing all KV rows into the
+    paged pool in place (write-then-attend, causal within each chunk) and
+    returning logits only at ``sample_src`` — the last valid position of
+    each lane that samples this step (decode lanes, and prefill lanes that
+    just finished their prompt).  Returns (logits [B, V] f32, cache).
+
+    This is the single-dispatch continuous-batching step: it replaces the
+    chunk_step × decode_loop (× bucketed admit) program family for paged
+    engines, so N prefills make progress per scheduler iteration without
+    stalling decode.  Padding tokens (token_slot < 0) drop their writes and
+    attend nothing; their activations are garbage no sample_src points at.
+    Numerically equivalent to the legacy paths (same math, blockwise — only
+    fp reassociation differs across chunk boundaries).
+
+    ONE forward for every block, as ``cfg.layer_kinds()`` describes it: the
     head's stack (``cfg.head_layers`` layers: a dense prefix, or layer 0;
     full-attention layers, or linear ones where ``cfg.linear_head``), then a
     first period cut short by the prefix where the model has one
     (``cfg.short_period``), then a scan over the periods, each the period's
     INNER layers (an inner scan that takes them out of their stack by
     index) and its full layer, then the tail of inner layers behind the
-    last whole period.  The inner kind is the model's: window layers
+    last whole period.  A block without inner layers (``cfg.inner_period``
+    0: every layer a period) traces neither the inner scan nor its carry.
+    The inner kind is the model's: window layers
     (``cfg.windowed``), which write and read the window pool (``cache.win``)
     through ``win_tables``, the same ragged launch told the window; or
     linear layers (``cfg.linear``), which read and write the slots' state
     (``cache.lin``) and no page.  The full kind is the model's too: GQA
-    layers, or latent layers (``cfg.latent``) over the latent pool; both
-    write and read the full pool through ``tables``.  A layer function's
+    layers, or latent layers (``cfg.latent``) over the latent pool, one
+    attention path, the absorbed one, for chunks and decode lanes alike;
+    both write and read the full pool through ``tables``.  A layer
+    function's
     ``src`` is ``(stack, index)``: the stacked tree of ``params`` its ``lp``
     was taken out of and where, for the routed FFN's overflow loop
-    (:func:`moe.moe_ffn`).  Returns (logits, cache, counts), that
-    function's three summed over the routed layers."""
+    (:func:`moe.moe_ffn`).
+
+    ``with_held``: a routed layer is handed the mask of the valid rows and
+    ``src``, and the step returns a third result, counts: that function's
+    three summed over the routed layers.  Without it a routed layer is
+    handed neither (its padding rows are routed like any other; no valid
+    row's output depends on them)."""
     from arks_tpu.ops.attention import (paged_latent_update_and_attend,
                                         paged_mixed_update_and_attend)
     t_flat = tokens.shape[0]
     cover = tables.shape[1] * cache.page
+    # RoPE positions must be real for valid tokens; padding rows only need
+    # a value the cache ops drop (their write_idx is routed past coverage).
     rope_pos = jnp.minimum(token_pos, cover - 1)[None]           # [1, T]
     valid = (token_slot >= 0)[None]
     first = cfg.head_layers
@@ -1234,9 +1223,13 @@ def _mixed_step_periods(params, cfg, cache, tables, win_tables, tokens,
     with _scope("arks.embed"):
         h = embed_lookup(params["embed"], tokens[None],
                          head["attn_norm"].dtype)                # [1, T, E]
+    kv_sharded = mesh is not None and shard_kv_heads(
+        cfg, mesh.shape.get(AXIS_MODEL, 1))
 
     def ffn(h, lp, src):
-        if "router" in lp:
+        # Without ``with_held`` every layer's counts are the constant, and
+        # what is summed of them below is dead code the lowering drops.
+        if with_held and "router" in lp:
             y, held = _mlp(h, lp, cfg, mesh, None, row_valid=valid,
                            stack=src)
         else:
@@ -1259,14 +1252,15 @@ def _mixed_step_periods(params, cfg, cache, tables, win_tables, tokens,
         q, k, v, gate = _kind_qkv(h, lp, cfg, rope_pos, window)
         attn, *pool = paged_mixed_update_and_attend(
             q[0], k[0], v[0], pool[0], pool[1], tbl, token_slot, token_pos,
-            seq_q_start, seq_q_len, seq_pos_start, index, mesh, False,
-            k_scale=pool[2], v_scale=pool[3],
+            seq_q_start, seq_q_len, seq_pos_start, index, mesh, kv_sharded,
+            model_axis=AXIS_MODEL, k_scale=pool[2], v_scale=pool[3],
             window=cfg.sliding_window if window else 0)
         if gate is not None:
             with _scope("arks.attn_gate"):
                 gate = gate[0] if gate.ndim == 4 else gate[0][..., None]
                 attn = attn * gate.astype(attn.dtype)
         attn = attn.reshape(1, t_flat, cfg.heads_of(window) * cfg.head_dim)
+        attn = _constrain(attn, mesh, None, None, AXIS_MODEL)
         with _scope("arks.attn_win_out" if window else "arks.attn_out"):
             h = h + qeinsum("...q,qe->...e", attn, lp["wo"])
         h, held = ffn(h, lp, src)
@@ -1294,12 +1288,15 @@ def _mixed_step_periods(params, cfg, cache, tables, win_tables, tokens,
         return h, lin, held
 
     full = tuple(cache[:4])
+    per = cfg.inner_period
     if cfg.linear:
         inner, inner_layer = tuple(cache.lin), linear_layer
         inner_stack, inner_tbl = params["lin_layers"], None
-    else:
+    elif per:
         inner, inner_layer = tuple(cache.win[:4]), layer
         inner_stack, inner_tbl = params["win_layers"], win_tables
+    else:
+        inner = ()         # every layer a full layer: no inner kind at all
     full_layer = latent_layer if cfg.latent else layer
     held = _NO_COUNTS
     # Where the head's layers are linear layers, the stacked inner layers'
@@ -1308,30 +1305,20 @@ def _mixed_step_periods(params, cfg, cache, tables, win_tables, tokens,
     # arithmetic below, so that the older blocks lower the text they did.)
     inner_base = first if cfg.linear_head else 0
     full_base = 0 if cfg.linear_head else first
-    if first and cfg.linear_head:
+    if first:
+        # (A linear layer takes neither the tables nor the flag.)
+        head_layer = linear_layer if cfg.linear_head else full_layer
+
         def head_body(carry, xs):
-            h, inner = carry
-            h, inner, n = linear_layer(h, xs[0], (head, xs[1]), inner, None,
-                                       xs[1], True)
-            return (h, inner), n
+            h, kept, n = head_layer(carry[0], xs[0], (head, xs[1]), carry[1],
+                                    tables, xs[1], False)
+            return (h, kept), n
 
-        (h, inner), n = jax.lax.scan(
-            head_body, (h, inner),
+        (h, kept), n = jax.lax.scan(
+            head_body, (h, inner if cfg.linear_head else full),
             (head, jnp.arange(first, dtype=jnp.int32)))
+        full, inner = (full, kept) if cfg.linear_head else (kept, inner)
         held = held + jnp.sum(n, axis=0)
-    elif first:
-        def head_body(carry, xs):
-            h, full = carry
-            h, full, n = full_layer(h, xs[0], (head, xs[1]), full, tables,
-                                    xs[1], False)
-            return (h, full), n
-
-        (h, full), n = jax.lax.scan(
-            head_body, (h, full),
-            (head, jnp.arange(first, dtype=jnp.int32)))
-        held = held + jnp.sum(n, axis=0)
-
-    per = cfg.inner_period
 
     def inner_layers(h, inner, start, count: int):
         """``count`` inner layers from index ``start`` of the flat stack,
@@ -1370,14 +1357,15 @@ def _mixed_step_periods(params, cfg, cache, tables, win_tables, tokens,
     def period_body(carry, xs):
         h, full, inner = carry
         flp, i = xs
-        h, inner, n = inner_layers(
-            h, inner, i * per + inner0 if inner0 else i * per, per)
+        if per:
+            h, inner, n = inner_layers(
+                h, inner, i * per + inner0 if inner0 else i * per, per)
         # The index is into the tree the program was handed, not into
         # what ``lead`` left of it: that slice would be a buffer to make.
         h, full, m = full_layer(
             h, flp, (params["layers"], i + 1 if lead else i), full, tables,
-            full_base + i, False)
-        return (h, full, inner), n + m
+            full_base + i if full_base else i, False)
+        return (h, full, inner), n + m if per else m
 
     (h, full, inner), n = jax.lax.scan(
         period_body, (h, full, inner),
@@ -1390,9 +1378,10 @@ def _mixed_step_periods(params, cfg, cache, tables, win_tables, tokens,
     with _scope("arks.lm_head"):
         h_sel = jnp.take(h[0], sample_src.astype(jnp.int32), axis=0)  # [B, E]
     logits = _unembed(h_sel, params, cfg, mesh, None)
-    if cfg.linear:
-        return logits, PagedKVCache(*full, lin=LinearState(*inner)), held
-    return logits, PagedKVCache(*full, win=PagedKVCache(*inner)), held
+    cache = PagedKVCache(*full,
+                         win=PagedKVCache(*inner) if cfg.windowed else None,
+                         lin=LinearState(*inner) if cfg.linear else None)
+    return (logits, cache, held) if with_held else (logits, cache)
 
 
 def prefill_layer(
@@ -1867,94 +1856,6 @@ def prefill_chunk_paged(
     return logits, PagedKVCache(k=kc, v=vc, k_scale=ksc, v_scale=vsc)
 
 
-def mixed_step(
-    params: Params,
-    cfg: ModelConfig,
-    cache: PagedKVCache,
-    tables: jnp.ndarray,       # [B, MaxP] int32 — lane b == slot b
-    tokens: jnp.ndarray,       # [T] int32 flat mixed token batch
-    token_slot: jnp.ndarray,   # [T] int32 slot per token (-1 = padding)
-    token_pos: jnp.ndarray,    # [T] int32 global position per token
-    sample_src: jnp.ndarray,   # [B] int32 — flat index each lane samples from
-    seq_q_start: jnp.ndarray,  # [B] int32 — lane's first flat-token index
-    seq_q_len: jnp.ndarray,    # [B] int32 — lane's token count (0 inactive)
-    seq_pos_start: jnp.ndarray,  # [B] int32 — lane's first global position
-    mesh: Mesh | None = None,
-    with_held: bool = False,
-    win_tables: jnp.ndarray | None = None,  # [B, MaxP] — window layers'
-) -> tuple[jnp.ndarray, PagedKVCache]:
-    """One unified mixed prefill+decode forward: a flat ``[T]`` token batch
-    carrying every decoding slot's next token PLUS one or more sequences'
-    prefill-chunk tokens runs the model ONCE, writing all KV rows into the
-    paged pool in place (write-then-attend, causal within each chunk) and
-    returning logits only at ``sample_src`` — the last valid position of
-    each lane that samples this step (decode lanes, and prefill lanes that
-    just finished their prompt).  Returns (logits [B, V] f32, cache).
-
-    This is the single-dispatch continuous-batching step: it replaces the
-    chunk_step × decode_loop (× bucketed admit) program family for paged
-    engines, so N prefills make progress per scheduler iteration without
-    stalling decode.  Padding tokens (token_slot < 0) drop their writes and
-    attend nothing; their activations are garbage no sample_src points at.
-    Numerically equivalent to the legacy paths (same math, blockwise — only
-    fp reassociation differs across chunk boundaries).
-
-    A latent model (``cfg.latent``) runs :func:`_mixed_step_latent` over
-    its latent pool, a model with window layers (``cfg.windowed``) or
-    linear-attention layers (``cfg.linear``; beside GQA or latent layers)
-    :func:`_mixed_step_periods`
-    over its two pools (the window layers' through ``win_tables``) or its
-    pool and its slots' state; ``with_held`` (those three only) adds the
-    third result, the count of routed pairs that landed on experts held
-    here."""
-    if cfg.latent and not cfg.linear:
-        out = _mixed_step_latent(params, cfg, cache, tables, tokens,
-                                 token_slot, token_pos, sample_src,
-                                 seq_q_start, seq_q_len, seq_pos_start, mesh)
-        return out if with_held else out[:2]
-    if cfg.windowed or cfg.linear:
-        out = _mixed_step_periods(params, cfg, cache, tables, win_tables,
-                                  tokens, token_slot, token_pos, sample_src,
-                                  seq_q_start, seq_q_len, seq_pos_start,
-                                  mesh)
-        return out if with_held else out[:2]
-    if with_held:
-        raise NotImplementedError("with_held: latent, windowed and linear "
-                                  "models only")
-    t_flat = tokens.shape[0]
-    cover = tables.shape[1] * cache.page
-    # RoPE positions must be real for valid tokens; padding rows only need
-    # a value the cache ops drop (their write_idx is routed past coverage).
-    rope_pos = jnp.minimum(token_pos, cover - 1)[None]           # [1, T]
-    with _scope("arks.embed"):
-        h = embed_lookup(params["embed"], tokens[None],
-                         params["layers"]["attn_norm"].dtype)    # [1, T, E]
-    kv_sharded = mesh is not None and shard_kv_heads(
-        cfg, mesh.shape.get(AXIS_MODEL, 1))
-    from arks_tpu.ops.attention import paged_mixed_update_and_attend
-
-    def body(carry, xs):
-        h, kc, vc, ksc, vsc = carry
-        lp, layer = xs
-        q, k, v = _block_qkv(h, lp, cfg, rope_pos)   # [1, T, H(.kv), D]
-        attn, kc, vc, ksc, vsc = paged_mixed_update_and_attend(
-            q[0], k[0], v[0], kc, vc, tables, token_slot, token_pos,
-            seq_q_start, seq_q_len, seq_pos_start, layer, mesh, kv_sharded,
-            model_axis=AXIS_MODEL, k_scale=ksc, v_scale=vsc)
-        attn = attn.reshape(1, t_flat, cfg.q_dim)
-        attn = _constrain(attn, mesh, None, None, AXIS_MODEL)
-        h = _block_tail(h, attn, lp, cfg, mesh, None)
-        return (h, kc, vc, ksc, vsc), None
-
-    (h, kc, vc, ksc, vsc), _ = jax.lax.scan(
-        body, (h, cache.k, cache.v, cache.k_scale, cache.v_scale),
-        (params["layers"], jnp.arange(cfg.num_layers, dtype=jnp.int32)))
-    with _scope("arks.lm_head"):
-        h_sel = jnp.take(h[0], sample_src.astype(jnp.int32), axis=0)  # [B, E]
-    logits = _unembed(h_sel, params, cfg, mesh, None)
-    return logits, PagedKVCache(k=kc, v=vc, k_scale=ksc, v_scale=vsc)
-
-
 def extract(cache: KVCache, slot: jnp.ndarray,
             dtype: jnp.dtype | None = None) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Read one slot's KV back out time-major ``[L, 1, S, Hkv, D]`` — the
@@ -2051,20 +1952,6 @@ def decode_step(
     logits = _unembed(h, params, cfg, mesh, batch_axis)
     cls = PagedKVCache if paged else KVCache
     return logits, cls(k=ks, v=vs, k_scale=kss, v_scale=vss)
-
-
-class DecodeState(NamedTuple):
-    """Device-resident decode state for the pipelined dispatch path: the
-    arrays the NEXT decode dispatch consumes from the PREVIOUS one without
-    a host round-trip (engine ARKS_PIPELINE_DEPTH).  Host mirrors lag by
-    the in-flight depth; dead slots self-mask (pad token, writes dropped
-    at the sentinel) until the host retires them at resolve time."""
-
-    tokens: jnp.ndarray   # [B] i32 — last sampled token (0 for dead slots)
-    lengths: jnp.ndarray  # [B] i32 — absolute lengths (only alive slots'
-                          # values are meaningful; dead/free rows keep
-                          # advancing harmlessly, masked by ``alive``)
-    alive: jnp.ndarray    # [B] bool — device-computed liveness
 
 
 def decode_state_step(
@@ -2169,11 +2056,6 @@ def verify_step(
 # ---------------------------------------------------------------------------
 
 
-def make_prefill_fn(cfg: ModelConfig, mesh: Mesh | None = None):
-    fn = functools.partial(prefill, cfg=cfg, mesh=mesh)
-    return jax.jit(lambda params, tokens, lengths: fn(params, tokens=tokens, lengths=lengths))
-
-
 def make_decode_fn(cfg: ModelConfig, mesh: Mesh | None = None,
                    batch_axis: str | None = None):
     fn = functools.partial(decode_step, cfg=cfg, mesh=mesh, batch_axis=batch_axis)
@@ -2181,8 +2063,3 @@ def make_decode_fn(cfg: ModelConfig, mesh: Mesh | None = None,
         lambda params, cache, tokens, lengths: fn(params, cache=cache, tokens=tokens, lengths=lengths),
         donate_argnums=(1,),
     )
-
-
-def make_insert_fn(cfg: ModelConfig, mesh: Mesh | None = None):
-    del cfg, mesh
-    return jax.jit(insert, donate_argnums=(0,))

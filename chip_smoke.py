@@ -1023,7 +1023,7 @@ def run(tp4: bool) -> dict:
         check(dev["count"] == 4, f"--tp4 needs 4 chips, found {dev['count']}")
         # A meshed engine resolves to depth 0: the pipelined programs have
         # only ever served single-device engines (engine.py, "The pipe
-        # programs"; ROADMAP S7).
+        # programs"; ROADMAP S11).
         tp_compare(MODEL, tp=4, num_slots=NUM_SLOTS,
                    max_model_len=MAX_MODEL_LEN, weight_dtype="int8",
                    labels_one={**TPU_LABELS, "tensor_parallel": "1"},

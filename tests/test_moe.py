@@ -299,6 +299,46 @@ def test_plain_leaves_keep_ragged_dot():
     assert "ragged_dot" in text
 
 
+def _whole_step(cfg, key: int, lanes, rows: int, max_pages: int,
+                shapes_only: bool = False):
+    """``(with_held -> ((params, cache) -> mixed_step(...)), params,
+    cache)`` of ``cfg`` on seeded int8 weights and float32 activations
+    (their shapes alone with ``shapes_only``) over a flat batch of ``rows``
+    rows: a padding row, then lane i's ``lanes[i]`` rows from position 0,
+    then padding."""
+    from arks_tpu.models import quant
+    slots, page = len(lanes), 16
+
+    def state():
+        params = jax.tree.map(
+            lambda a: a.astype(jnp.float32) if a.dtype == jnp.bfloat16 else a,
+            quant.init_params_quantized(cfg, jax.random.PRNGKey(key),
+                                        jnp.bfloat16, bits=8))
+        return params, tf.init_paged_cache(
+            cfg, slots * max_pages, page, jnp.float32,
+            win_pages=slots * max_pages if cfg.windowed else 0,
+            state_slots=slots if cfg.linear else 0)
+
+    params, cache = jax.eval_shape(state) if shapes_only else state()
+    tables = jnp.arange(slots * max_pages, dtype=jnp.int32).reshape(
+        slots, max_pages)
+    tokens = np.zeros(rows, np.int32)
+    slot = np.full(rows, -1, np.int32)
+    pos = np.full(rows, page * max_pages, np.int32)
+    src, qs, ql = (np.zeros(slots, np.int32) for _ in range(3))
+    at = 1                                            # a padding row ahead
+    for lane, n in enumerate(lanes):
+        tokens[at:at + n] = np.random.default_rng(lane).integers(2, 500, n)
+        slot[at:at + n], pos[at:at + n] = lane, np.arange(n)
+        qs[lane], ql[lane], src[lane] = at, n, at + n - 1
+        at += n
+    kw = {"win_tables": tables} if cfg.windowed else {}
+    return lambda held: lambda p, c: tf.mixed_step(
+        p, cfg, c, tables, *(jnp.asarray(a) for a in (
+            tokens, slot, pos, src, qs, ql, np.zeros(slots, np.int32))),
+        with_held=held, **kw), params, cache
+
+
 @pytest.mark.parametrize("preset", ["tiny-mla-moe", "tiny-linear-moe",
                                     "tiny-latent-linear-moe"])
 def test_a_whole_steps_overflow_loops_read_the_layers_own_experts(
@@ -313,36 +353,11 @@ def test_a_whole_steps_overflow_loops_read_the_layers_own_experts(
     caller handed down, and the logits are the dense dispatch's.  (Every
     layer's weights differ: an index into the wrong stack, or off by the
     layer a first period took, reads another layer's expert.)"""
-    from arks_tpu.models import quant
     cfg = get_config(preset).with_expert_share(2, 1)
-    params = jax.tree.map(
-        lambda a: a.astype(jnp.float32) if a.dtype == jnp.bfloat16 else a,
-        quant.init_params_quantized(cfg, jax.random.PRNGKey(11),
-                                    jnp.bfloat16, bits=8))
-    slots, page, max_pages, rows = 2, 16, 16, 160
-    tables = jnp.arange(slots * max_pages, dtype=jnp.int32).reshape(
-        slots, max_pages)
-    cache = tf.init_paged_cache(
-        cfg, slots * max_pages, page, jnp.float32,
-        win_pages=slots * max_pages if cfg.windowed else 0,
-        state_slots=slots if cfg.linear else 0)
-    tokens = np.zeros(rows, np.int32)
-    slot = np.full(rows, -1, np.int32)
-    pos = np.full(rows, page * max_pages, np.int32)
-    src, qs, ql = (np.zeros(slots, np.int32) for _ in range(3))
-    at = 1                                            # a padding row ahead
-    for lane, n in ((0, 100), (1, 50)):
-        tokens[at:at + n] = np.random.default_rng(lane).integers(2, 500, n)
-        slot[at:at + n], pos[at:at + n] = lane, np.arange(n)
-        qs[lane], ql[lane], src[lane] = at, n, at + n - 1
-        at += n
-    kw = {"win_tables": tables} if cfg.windowed else {}
+    run, *state = _whole_step(cfg, 11, (100, 50), rows=160, max_pages=16)
 
     def step():
-        logits, _, counts = jax.jit(lambda c: tf.mixed_step(
-            params, cfg, c, tables, *(jnp.asarray(a) for a in (
-                tokens, slot, pos, src, qs, ql, np.zeros(slots, np.int32))),
-            with_held=True, **kw))(cache)
+        logits, _, counts = jax.jit(run(True))(*state)
         return np.asarray(logits), counts.tolist()
 
     monkeypatch.setattr(moe, "_batch_pays", lambda n, mp, cfg: False)
@@ -355,3 +370,31 @@ def test_a_whole_steps_overflow_loops_read_the_layers_own_experts(
     assert extra == needed - moe._SPARE_TILES * cfg.num_routed_layers
     assert extra > 20 * cfg.num_routed_layers
     np.testing.assert_allclose(got, want, atol=2e-5 * np.abs(want).max())
+
+
+@pytest.mark.parametrize("preset", [
+    "tiny", "tiny-mixtral", "tiny-mla-moe", "tiny-swa-moe", "tiny-linear-moe",
+    "tiny-latent-linear-moe"])
+def test_every_block_honours_with_held(preset):
+    """``mixed_step`` is one forward for every block, and ``with_held`` its
+    one static argument: True, a routed layer is handed the mask of the
+    valid rows and the step returns counts (every routed pair of a valid
+    row, for a model that holds every expert; zeros for one without
+    experts); False, two results.  No valid row's logits depend on whether
+    the padding rows were masked (run for the blocks without inner layers,
+    where ``with_held`` is new; the others, which every engine test of
+    theirs serves ``with_held``, are traced both ways and not run)."""
+    cfg = get_config(preset)
+    lanes = (20, 1)                                   # a chunk, a decode row
+    step, *state = _whole_step(cfg, 5, lanes, rows=32, max_pages=4,
+                               shapes_only=bool(cfg.inner_period))
+    assert jax.eval_shape(step(True), *state)[2].shape == (3,)
+    assert len(jax.eval_shape(step(False), *state)) == 2
+    if cfg.inner_period:
+        return
+    logits, _, counts = jax.jit(step(True))(*state)
+    plain, _ = jax.jit(step(False))(*state)
+    assert counts.tolist() == [
+        sum(lanes) * cfg.num_experts_per_tok * cfg.num_routed_layers, 0, 0]
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(plain),
+                               atol=2e-5 * np.abs(np.asarray(plain)).max())

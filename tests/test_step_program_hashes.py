@@ -61,7 +61,8 @@ three int8 expert leaves copied whole a layer a step).  The twelve others
 (2 + 64 rows: the dense dispatch, no tile at all) moved only by the counts:
 a routed layer hands back int32 ``[3]`` (held pairs, overflow tiles needed,
 those the loop ran) where a scalar stood, ``with_counts`` puts four entries
-behind the ids where two stood, and ``_mixed_step_latent``'s scans hand a
+behind the ids where two stood, and the latent forward's two scans (its own
+function until PR 46) hand a
 layer its index in ITS stack (the pool's index is that plus the stack's
 base).  NEW: ``whole-layer``, the branch mixtral runs and no step pin covers
 (a layer held WHOLE, int8 leaves, 8 experts top-2 at 320 rows: the batched
@@ -87,6 +88,36 @@ test_paged_update_matches_oracle`` (pool kinds x batch layouts) and
 takes it, in place, at the cells' row counts by ``tests/test_chip_compile.py``;
 and that no cell's outputs move by the driver's cells on the chip
 (``logprob_err`` on a pair's seed), not by pins.
+
+PR 46 ADDED eight pins, then folded the three forwards of
+``transformer.py::mixed_step`` into one period scan, and RE-PINNED the six of
+``tiny-mla-moe`` for one op; the twenty-five others (the sixteen old ones of
+``tiny``, ``tiny-swa-moe``, ``tiny-linear-moe``, ``whole-layer``, and the
+eight new ones) stand.  NEW, taken BEFORE any edit of ``transformer.py``:
+``tiny-mixtral`` (a dense block WITH routed layers, lowered as its cell
+runs it: layers held WHOLE, int8 leaves, so this preset alone gets no
+share here) and ``tiny-latent-linear-moe`` (the ``gigachat3_5`` block under
+a share); their values are what commit fcc7e93 (PR 45, the parent of PR
+46) lowers, taken with this file in that tree (a ``git archive`` of it).
+The fold: a block without inner layers (``tiny``, ``tiny-mixtral``: no head
+stack; ``tiny-mla-moe``: its dense prefix is the head stack) is the period
+scan with NO inner layers a period, and the scan then traces no inner scan
+and carries nothing for one; ``layer()`` took the mesh arguments of the
+dense body; ``with_held`` False hands a routed layer neither the mask nor
+its stack and the scans emit no counts, which is what a dense block's
+program held (mixtral's routed layers are handed what they were: none of
+its four moved).  Every such branch is decided by the configuration while
+the program is traced.  The ONE op that differs, in all six programs of
+``tiny-mla-moe``, is the index of a routed layer's page in the latent pool
+inside the scan over the routed stack: ``stablehlo.add %at, %c1`` (the
+deleted latent forward wrote ``at + base``) is ``stablehlo.add %c1, %at``
+(the period scan writes ``full_base + i``, as ``tiny-swa-moe``'s and
+``tiny-linear-moe``'s pinned programs always did); a ``diff`` of the two
+texts shows that line and no other.  An integer add commutes: compiled for
+the CPU, the parent's and the change's four programs of the preset are the
+same HLO line for line once source locations are stripped (4,067 / 4,250 /
+3,921 / 4,067 lines), and kimi's cell is among those run on the chip before
+and after (PERF.md §6, PR 46).
 """
 
 import hashlib
@@ -105,16 +136,16 @@ PINS = {
     "tiny.seq_lp": "31d2702fc1c7b6af",
     "tiny.pipe": "a6579a2a5236124a",
     "tiny.pipe_lp": "d059fb1f2834e04b",
-    "tiny-mla-moe.seq": "732da02806d9628e",
-    "tiny-mla-moe.seq_lp": "2ad822bb0ae0d3a5",
-    "tiny-mla-moe.pipe": "fb42b9e5a6ce6007",
-    "tiny-mla-moe.pipe_lp": "5947869b382e5fe8",
+    "tiny-mla-moe.seq": "b38c58786d908443",
+    "tiny-mla-moe.seq_lp": "4e0d054764e1599f",
+    "tiny-mla-moe.pipe": "85a6cd2125ffb229",
+    "tiny-mla-moe.pipe_lp": "ff217f97a370ca70",
     "tiny-swa-moe.seq": "656c1440df89f528",
     "tiny-swa-moe.seq_lp": "bf47ab082f6494f1",
     "tiny-swa-moe.pipe": "b816b56c164ad306",
     "tiny-swa-moe.pipe_lp": "6b850b4575db179d",
-    "tiny-mla-moe@wide.seq": "4b30be9951402722",
-    "tiny-mla-moe@wide.seq_lp": "e252e4dd36ebe3f1",
+    "tiny-mla-moe@wide.seq": "bba1b61e2b1fa6a6",
+    "tiny-mla-moe@wide.seq_lp": "605c9e49c7d4e5ab",
     "tiny-swa-moe@wide.seq": "72817726151c441f",
     "tiny-swa-moe@wide.seq_lp": "b5e4558e99013cef",
     "tiny-linear-moe.seq": "25c3b65a31869dbe",
@@ -123,6 +154,14 @@ PINS = {
     "tiny-linear-moe.pipe_lp": "66007edafc0a5a68",
     "tiny-linear-moe@wide.seq": "75c03053b4c8f947",
     "tiny-linear-moe@wide.seq_lp": "a576efe531063750",
+    "tiny-mixtral.seq": "22ac0aa9bceb712b",
+    "tiny-mixtral.seq_lp": "6b8dc4bb07366585",
+    "tiny-mixtral.pipe": "c0b97eb0fc943682",
+    "tiny-mixtral.pipe_lp": "1a19199bb5ca75a5",
+    "tiny-latent-linear-moe.seq": "5976443c5b63dce0",
+    "tiny-latent-linear-moe.seq_lp": "0c7d6b61622cde62",
+    "tiny-latent-linear-moe.pipe": "8b8e6456119c2122",
+    "tiny-latent-linear-moe.pipe_lp": "6d5c51de4ebb50c8",
 }
 
 
@@ -150,8 +189,10 @@ def step_program_hashes(model: str, monkeypatch) -> dict:
     if cfg.num_experts:
         # A share, int8 leaves, and a step of 2 + 64 rows: the sequential
         # programs take the grouped rule (since PR 36: the dense dispatch,
-        # an expert's batch being every row at these sizes).
-        cfg = cfg.with_expert_share(2, 0)
+        # an expert's batch being every row at these sizes).  Mixtral's
+        # layers are held WHOLE, as its cell runs them: no share.
+        if preset != "tiny-mixtral":
+            cfg = cfg.with_expert_share(2, 0)
         kw.update(weight_dtype="int8", prefill_chunk=64)
     if cfg.windowed:
         kw.update(max_cache_len=256, kv_cache_dtype="bf16")
