@@ -760,6 +760,11 @@ class EngineMetrics:
             "Requests that found a free slot and not the pages of the full "
             "pool their prompt and max_tokens need, and waited at the head "
             "of the queue for them (--kv-pool-pages)")
+        self.kv_pool_page_bytes = r.gauge(
+            "kv_pool_page_bytes",
+            "Bytes one page of a pool holds over all the pool's layers, at "
+            "the stored widths of its keys, values and scales, by kind "
+            "(full | window); a model with window layers")
         self.kv_window_pages_released_total = r.counter(
             "kv_window_pages_released_total",
             "Window-layer pages released because they lay wholly behind "
@@ -1681,12 +1686,15 @@ class InferenceEngine:
             kv_bits = (engine_cfg.kv_bits if engine_cfg.kv_quantized
                        else jnp.dtype(self._cache_dtype(dtype)).itemsize * 8)
             d_store = tf.cache_head_dim(cfg, self._pad_head())
-            # K and V; a latent page holds its one row once.
+            # K and V, each at its stored width; a latent page holds its
+            # one row once.
             # (A page of the full-attention pool: every layer, or the
             # full layers of a model that also has window layers.)
             page_bytes = (cfg.num_full_layers * cfg.num_kv_heads * page
-                          * d_store * kv_bits // 8
-                          * (1 if cfg.latent else 2))
+                          * (d_store + (0 if cfg.latent else
+                                        tf.cache_value_dim(
+                                            cfg, self._pad_head())))
+                          * kv_bits // 8)
             if engine_cfg.kv_quantized:
                 page_bytes += (cfg.num_full_layers * cfg.num_kv_heads
                                * page * 4 * 2)
@@ -1763,6 +1771,10 @@ class InferenceEngine:
                      self._cache.token_bytes,
                      " (one latent row, stored once)" if cfg.latent else "")
             if self._win is not None:
+                for kind, pool in (("full", self._cache),
+                                   ("window", self._cache.win)):
+                    self.metrics.kv_pool_page_bytes.set(
+                        pool.token_bytes * page, kind=kind)
                 log.info("window layers: %d pages x %d tokens of their own "
                          "(%d a slot: window %d + the rows of a step), %d "
                          "bytes a token over %d layers; the full pool "
@@ -2141,6 +2153,12 @@ class InferenceEngine:
             "kv_cache_dtype": self.ecfg.resolve_kv_cache_dtype(),
             "kv_dtype": self.ecfg.resolve_kv_cache_dtype(),
             "kv_page": _kv_page(cfg),
+            # KV heads a layer ("full/window" where the window layers have
+            # a count of their own) and the kinds of layer whose softmax
+            # carries a sink logit a head ("none": no layer's does).
+            "kv_heads": (f"{cfg.num_kv_heads}/{cfg.window_kv_heads}"
+                         if cfg.window_kv_heads else str(cfg.num_kv_heads)),
+            "attn_sink": ",".join(cfg.attn_sink) or "none",
             # The dtype the delta rule's state IS kept in, read off the
             # cache this engine built ("none": no linear layer): a
             # deployment's expect_labels holds the step to the precision
@@ -3304,7 +3322,8 @@ class InferenceEngine:
         from arks_tpu.ops.attention import default_decode_impl
         return (jax.default_backend() == "tpu"
                 and default_decode_impl() == "pallas"
-                and self.cfg.head_dim % 128 != 0
+                and (self.cfg.head_dim % 128 != 0
+                     or self.cfg.value_dim % 128 != 0)
                 and self._pp == 1)
 
     def _park_sentinel(self) -> int:
@@ -8772,8 +8791,8 @@ class InferenceEngine:
             from arks_tpu.ops.paged_attention import mixed_grid_plan
             cfg = self.cfg
             wplan = mixed_grid_plan(
-                qmax, hkv=cfg.num_kv_heads,
-                g=cfg.window_num_heads // cfg.num_kv_heads,
+                qmax, hkv=cfg.kv_heads_of(True),
+                g=cfg.window_num_heads // cfg.kv_heads_of(True),
                 d=tf.cache_head_dim(cfg, self._pad_head()),
                 page=self._page_size(),
                 kv=self.ecfg.resolve_kv_cache_dtype(),
@@ -8782,8 +8801,8 @@ class InferenceEngine:
         w_actual, _ = mixed_kv_bytes(
             pos_start, q_len, page=self._page_size(),
             block_q=wplan["block_q"], num_qb=wplan["num_qb"],
-            max_pages=self._max_pages, hkv=self.cfg.num_kv_heads,
-            page_head_bytes=self._page_head_bytes(),
+            max_pages=self._max_pages, hkv=self.cfg.kv_heads_of(True),
+            page_head_bytes=self._page_head_bytes(self._cache.win),
             window=self.cfg.sliding_window)
         self.metrics.mixed_kv_bytes_total.inc(w_actual, kind="window")
         win = self._win
@@ -8875,16 +8894,17 @@ class InferenceEngine:
             log.info("model %s: the device prefix index is off (a matched "
                      "prefix's %s)", cfg.name, block.no_index)
 
-    def _page_head_bytes(self) -> int:
-        """Bytes one (page, KV head) block moves over the mixed kernel's
-        page stream: K + V rows (int4 pools store packed nibble rows, so
-        the row count already reflects the halving) plus the f32 scale
-        rows for quantized pools."""
-        k = self._cache.k
-        per = (1 if self._cache.v is None else 2) \
-            * k.shape[3] * k.shape[4] * k.dtype.itemsize
-        if self._cache.k_scale is not None:
-            per += 2 * self._cache.k_scale.shape[3] * 4
+    def _page_head_bytes(self, pool=None) -> int:
+        """Bytes one (page, KV head) block of ``pool`` (the full layers'
+        where not given) moves over the mixed kernel's page stream: K + V
+        rows, each at its stored width (int4 pools store packed nibble
+        rows, so the row count already reflects the halving) plus the f32
+        scale rows for quantized pools."""
+        pool = self._cache if pool is None else pool
+        per = sum(x.shape[3] * x.shape[4] * x.dtype.itemsize
+                  for x in (pool.k, pool.v) if x is not None)
+        if pool.k_scale is not None:
+            per += 2 * pool.k_scale.shape[3] * 4
         return per
 
     # Step-section spans (``phase.<phase>.<section>``, docs/monitoring.md):

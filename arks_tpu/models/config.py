@@ -89,14 +89,22 @@ class ModelConfig:
     # of window layers (Laguna-S-2.1: 1 + 11 x (3 + 1) + 3 = 48).  A window
     # layer's query attends the keys at most
     # ``sliding_window - 1`` positions behind it, has ``window_num_heads``
-    # query heads (``num_heads``: a full layer's) over the same
-    # ``num_kv_heads``, and rotates the whole head under plain RoPE at
-    # ``window_rope_theta``.  A full layer rotates the first
+    # query heads (``num_heads``: a full layer's) over ``window_kv_heads``
+    # KV heads (0: the full layers' ``num_kv_heads``), and rotates the first
+    # ``window_partial_rotary_factor`` of a head (1: the whole head) under
+    # plain RoPE at ``window_rope_theta``.  A full layer rotates the first
     # ``partial_rotary_factor`` of a head at ``rope_theta``, under
     # ``rope_hf_yarn`` where set: (factor, original context, beta_fast,
     # beta_slow, attention_factor), HF's YaRN, whose attention factor
     # multiplies cos and sin.  ``attn_gate``: one sigmoid scalar a head,
     # from the sublayer's normed input, on the head's output.
+    # ``mimo_v2`` beside ``laguna``: a first period cut short by the dense
+    # prefix (``short_period`` window layers and its full layer; MiMo-V2.5:
+    # 1 + (4 + 1) + 7 x (5 + 1) = 48); values ``v_head_dim`` wide under keys
+    # and queries of ``head_dim`` (0: as wide), times ``attn_value_scale``
+    # before they are cached; ``attn_sink``: the kinds of layer ("full",
+    # "window") whose softmax has one learnt logit a query head in its
+    # denominator, which takes mass and has no value.
     sliding_window: int = 0
     window_period: int = 0
     window_num_heads: int = 0
@@ -104,6 +112,10 @@ class ModelConfig:
     partial_rotary_factor: float = 1.0
     rope_hf_yarn: tuple[float, ...] = ()
     attn_gate: bool = False
+    window_kv_heads: int = 0
+    window_partial_rotary_factor: float = 1.0
+    attn_value_scale: float = 1.0
+    attn_sink: tuple[str, ...] = ()
     # Linear-attention and softmax GQA layers in one model (``solar_open2``;
     # linear_period 0 = none).  Layer 0 is a GQA layer; behind it the layers
     # come in periods of ``linear_period`` linear layers and one GQA layer,
@@ -228,7 +240,10 @@ class ModelConfig:
 
     @property
     def num_window_layers(self) -> int:
-        return self.num_periods * self.window_period + self.window_tail
+        if not self.windowed:
+            return 0
+        return self.num_periods * self.window_period + self.window_tail \
+            + max(self.short_period, 0)
 
     @property
     def num_linear_layers(self) -> int:
@@ -247,6 +262,18 @@ class ModelConfig:
 
     def heads_of(self, window: bool) -> int:
         return self.window_num_heads if window else self.num_heads
+
+    def kv_heads_of(self, window: bool) -> int:
+        return (self.window_kv_heads if window else 0) or self.num_kv_heads
+
+    def sink_of(self, window: bool) -> bool:
+        """A layer of the kind has a sink logit a head in its softmax."""
+        return ("window" if window else "full") in self.attn_sink
+
+    @property
+    def value_dim(self) -> int:
+        """Width of a head's values (and of its attention output)."""
+        return self.v_head_dim or self.head_dim
 
     def layer_kinds(self) -> tuple[str, ...]:
         """``"full"`` / ``"window"`` / ``"linear"`` of every layer, in model
@@ -271,8 +298,7 @@ class ModelConfig:
     @property
     def attn_out_dim(self) -> int:
         """Width of the concatenated heads the output projection takes."""
-        return self.num_heads * (self.v_head_dim if self.latent
-                                 else self.head_dim)
+        return self.num_heads * self.value_dim
 
     @property
     def router_width(self) -> int:
@@ -319,7 +345,11 @@ class ModelConfig:
                     + self.attn_out_dim * e
                     + self.q_lora_rank + self.kv_lora_rank)
         else:
-            attn = e * self.q_dim + 2 * e * self.kv_dim + self.q_dim * e
+            def gqa(heads: int, kv: int) -> int:
+                return (e * (heads + kv) * self.head_dim
+                        + (e * kv + heads * e) * self.value_dim)
+
+            attn = gqa(self.num_heads, self.num_kv_heads)
         if self.qkv_bias:
             attn += self.q_dim + 2 * self.kv_dim
         dense = 3 * e * f
@@ -339,9 +369,13 @@ class ModelConfig:
         blocks = self.num_layers * (attn + norms) + routed * mlp \
             + (self.num_layers - routed) * dense
         if self.windowed:
-            # A window layer's projections have its own head count.
-            blocks += self.num_window_layers * 2 * e * self.head_dim * (
-                self.window_num_heads - self.num_heads)
+            # A window layer's projections have its own head counts.
+            blocks += self.num_window_layers * (
+                gqa(self.window_num_heads, self.kv_heads_of(True)) - attn)
+        for kind in self.attn_sink:
+            blocks += (self.num_window_layers * self.window_num_heads
+                       if kind == "window"
+                       else self.num_full_layers * self.num_heads)
         if self.attn_gate:
             blocks += e * (self.num_full_layers * self.num_heads
                            + self.num_window_layers * self.window_num_heads)
@@ -394,6 +428,20 @@ class ModelConfig:
         # num_experts (Qwen2-MoE).
         num_experts = int(d.get("num_local_experts", d.get("num_experts", 0)) or 0)
         is_mixtral = "mixtral" in arch or model_type == "mixtral"
+        if model_type == "mimo_v2":
+            return _from_mimo_v2(d, name or model_type, tuple(eos))
+        # What only the ``mimo_v2`` reader understands: on any other path
+        # each would be dropped, and the model served as another.
+        for k in sorted(d):
+            if d[k] not in (None, False, *_MIMO_ONLY.get(k, ())) and (
+                    k in _MIMO_ONLY or k.startswith("swa_")):
+                raise ValueError(
+                    f"{k}={d[k]!r} in a config of model_type "
+                    f"{model_type!r}: only model_type 'mimo_v2' is read "
+                    "with window layers of their own KV head count, a sink "
+                    "logit a head, values narrower than keys and a value "
+                    "scale; serving this model without it would be another "
+                    "model")
         if model_type == "solar_open2":
             return _from_solar_open2(d, name or model_type, tuple(eos))
         if model_type == "gigachat3_5":
@@ -441,15 +489,16 @@ class ModelConfig:
             if d.get(k):
                 raise ValueError(
                     f"{k} in a config of model_type {model_type!r}: only "
-                    "model_type 'laguna' is read with layers of more than "
-                    "one kind; serving this model with one kind of layer "
-                    "would be another model")
+                    "model_type 'laguna' is read with these keys for layers "
+                    "of more than one kind ('mimo_v2' with "
+                    "hybrid_layer_pattern); serving this model with one "
+                    "kind of layer would be another model")
         if d.get("sliding_window") and d.get("use_sliding_window", True):
             raise ValueError(
                 f"sliding_window={d['sliding_window']} in a config of "
-                f"model_type {model_type!r}: only model_type 'laguna' is "
-                "read with window layers; serving this model with full "
-                "attention in every layer would be another model")
+                f"model_type {model_type!r}: only model_types 'laguna' and "
+                "'mimo_v2' are read with window layers; serving this model "
+                "with full attention in every layer would be another model")
         if d.get("rope_scaling"):
             raise ValueError(
                 f"rope_scaling={d['rope_scaling']!r}: only the latent-"
@@ -496,6 +545,21 @@ def _deepseek_yarn(rs: dict | None, refuse) -> tuple[float, ...]:
             float(rs.get("mscale", 1)), float(rs.get("mscale_all_dim", 0)))
 
 
+def _sigmoid_routing(d: dict[str, Any], refuse) -> None:
+    """What ``moe.router_topk``'s sigmoid rule is: sigmoid scores, the
+    top-k of score + a selection bias (``noaux_tc``), one group.  Anything
+    else goes to ``refuse``."""
+    if d.get("scoring_func", "softmax") != "sigmoid":
+        refuse(f"scoring_func={d.get('scoring_func', 'softmax')!r} with "
+               "n_routed_experts (only sigmoid)")
+    if d.get("topk_method", "noaux_tc") != "noaux_tc":
+        refuse(f"topk_method={d['topk_method']!r} (only noaux_tc)")
+    if int(d.get("n_group", 1) or 1) > 1 or int(d.get("topk_group", 1)
+                                                 or 1) > 1:
+        refuse(f"group-limited routing (n_group={d.get('n_group')}, "
+               f"topk_group={d.get('topk_group')})")
+
+
 def _from_deepseek_v3(d: dict[str, Any], name: str,
                       eos: tuple[int, ...]) -> ModelConfig:
     """The DeepSeek-V3 block (``deepseek_v3``, ``kimi_k2``): latent
@@ -510,15 +574,7 @@ def _from_deepseek_v3(d: dict[str, Any], name: str,
               "qk_rope_head_dim", "v_head_dim", "n_routed_experts"):
         if not d.get(k):
             refuse(f"a DeepSeek-V3-style block without {k}")
-    if d.get("scoring_func", "softmax") != "sigmoid":
-        refuse(f"scoring_func={d.get('scoring_func', 'softmax')!r} with "
-               "n_routed_experts (only sigmoid)")
-    if d.get("topk_method", "noaux_tc") != "noaux_tc":
-        refuse(f"topk_method={d['topk_method']!r} (only noaux_tc)")
-    if int(d.get("n_group", 1) or 1) > 1 or int(d.get("topk_group", 1)
-                                                 or 1) > 1:
-        refuse(f"group-limited routing (n_group={d.get('n_group')}, "
-               f"topk_group={d.get('topk_group')})")
+    _sigmoid_routing(d, refuse)
     if int(d.get("moe_layer_freq", 1) or 1) != 1:
         refuse(f"moe_layer_freq={d['moe_layer_freq']}")
     if d.get("attention_bias"):
@@ -688,6 +744,139 @@ def _from_laguna(d: dict[str, Any], name: str,
         partial_rotary_factor=float(full.get("partial_rotary_factor", 1)),
         rope_hf_yarn=yarn,
         attn_gate=bool(gating),
+        kv_cache_dtype=str(d.get("kv_cache_dtype", "auto")),
+    )
+
+
+# Keys only the ``mimo_v2`` reader understands (beside every ``swa_*`` key),
+# each with the values that say "the usual thing".
+_MIMO_ONLY: dict[str, tuple] = {
+    "hybrid_layer_pattern": (), "hybrid_block_size": (),
+    "add_full_attention_sink_bias": (), "add_swa_attention_sink_bias": (),
+    "attention_value_scale": (1, 1.0),
+}
+
+
+def _from_mimo_v2(d: dict[str, Any], name: str,
+                  eos: tuple[int, ...]) -> ModelConfig:
+    """The ``mimo_v2`` block (XiaomiMiMo; the language model of
+    MiMo-V2-Flash / MiMo-V2.5): GQA layers of two kinds by
+    ``hybrid_layer_pattern`` (0 full, 1 window), each with a KV head count
+    and a RoPE base of its own, keys and queries ``head_dim`` wide and values
+    ``v_head_dim`` wide times ``attention_value_scale``, the first
+    ``partial_rotary_factor`` of a head rotated in both kinds, a learnt sink
+    logit a head in the softmax of the kinds ``add_*_attention_sink_bias``
+    name; sigmoid-routed experts with a selection bias and no shared expert
+    behind the dense layers ``moe_layer_freq`` marks 0.  Key for key from
+    the published file; what the block cannot express is refused, not
+    approximated.  ``attention_projection_layout`` is how a checkpoint lays
+    q | k | v out, not mathematics; the family's draft (MTP) layers and its
+    vision and audio towers are not in this file and are not built."""
+    def refuse(what: str) -> None:
+        raise ValueError(f"config {name!r}: {what} is not supported (the "
+                         "model would be served as another model)")
+
+    layers = int(d["num_hidden_layers"])
+    for k in ("hybrid_layer_pattern", "sliding_window", "n_routed_experts",
+              "num_experts_per_tok", "moe_intermediate_size"):
+        if not d.get(k):
+            refuse(f"a mimo_v2 config without {k}")
+    pattern = [int(k) for k in d["hybrid_layer_pattern"]]
+    freq = d.get("moe_layer_freq", 1)
+    freq = [int(f) for f in freq] if isinstance(freq, (list, tuple)) \
+        else [int(freq or 1)] * layers
+    for key, got in (("hybrid_layer_pattern", pattern),
+                     ("moe_layer_freq", freq)):
+        if len(got) != layers or set(got) - {0, 1}:
+            refuse(f"{key} {got} (one 0 or 1 a layer, {layers} layers)")
+    if d.get("hybrid_block_size") is not None:
+        refuse(f"hybrid_block_size={d['hybrid_block_size']}")
+    _sigmoid_routing(d, refuse)
+    if d.get("hidden_act", "silu") != "silu":
+        refuse(f"hidden_act={d['hidden_act']!r} (only silu)")
+    if d.get("attention_bias"):
+        refuse("attention_bias")
+    if d.get("tie_word_embeddings"):
+        refuse("tie_word_embeddings")
+    window = int(d["sliding_window"])
+    for k in ("sliding_window_size", "attention_chunk_size"):
+        # The window under its other names: a chunk of the window's own
+        # span adds no mask to a window layer.
+        if d.get(k) not in (None, window):
+            refuse(f"{k}={d[k]} beside sliding_window={window}")
+    rs = d.get("rope_scaling") or {}
+    if rs.get("rope_type", rs.get("type", "default")) != "default":
+        refuse(f"rope_scaling {rs!r} (only the default type: plain RoPE)")
+    heads, dk = int(d["num_attention_heads"]), int(d["head_dim"])
+    dv = int(d.get("v_head_dim") or dk)
+    for k, want in (("swa_head_dim", dk), ("swa_v_head_dim", dv)):
+        if d.get(k, want) != want:
+            refuse(f"{k}={d[k]} beside {want} in the full layers (one "
+                   "width of keys and one of values)")
+    wheads = int(d.get("swa_num_attention_heads") or heads)
+    kv = int(d.get("num_key_value_heads") or heads)
+    wkv = int(d.get("swa_num_key_value_heads") or kv)
+    if heads % kv or wheads % wkv:
+        refuse(f"{heads} / {wheads} query heads over {kv} / {wkv} KV heads")
+    # The dense layers are a prefix of full-attention layers; behind it a
+    # first period that the prefix may have cut short, whole periods of
+    # window layers and one full layer, then window layers.
+    first = 0
+    while first < layers and not freq[first]:
+        first += 1
+    if 0 in freq[first:] or first >= layers:
+        refuse(f"moe_layer_freq {freq} (dense layers must be a prefix)")
+    if any(pattern[:first]):
+        refuse("a window layer inside the dense prefix")
+    rest = pattern[first:]
+    full = [i for i, k in enumerate(rest) if not k]
+    if not full:
+        refuse(f"hybrid_layer_pattern {pattern} (no full layer behind the "
+               "dense prefix)")
+    per = full[1] - full[0] - 1 if len(full) > 1 else full[0]
+    lead = full[0]
+    if per < 1 or lead > per \
+            or full != list(range(lead, len(rest), per + 1)):
+        refuse(f"hybrid_layer_pattern {pattern} (behind the dense prefix: "
+               "a full layer every so many window layers, then window "
+               "layers)")
+    sink = tuple(kind for kind, k in (
+        ("full", "add_full_attention_sink_bias"),
+        ("window", "add_swa_attention_sink_bias")) if d.get(k))
+    rotary = float(d.get("partial_rotary_factor", 1) or 1)
+    return ModelConfig(
+        name=name,
+        vocab_size=d["vocab_size"],
+        hidden_size=d["hidden_size"],
+        intermediate_size=d["intermediate_size"],
+        num_layers=layers,
+        num_heads=heads,
+        num_kv_heads=kv,
+        head_dim=dk,
+        rope_theta=float(d.get("rope_theta", 10000.0)),
+        rms_norm_eps=float(d.get("layernorm_epsilon",
+                                 d.get("rms_norm_eps", 1e-6))),
+        max_position_embeddings=int(d.get("max_position_embeddings", 32768)),
+        eos_token_ids=eos,
+        num_experts=int(d["n_routed_experts"]),
+        num_experts_per_tok=int(d["num_experts_per_tok"]),
+        moe_intermediate_size=int(d["moe_intermediate_size"]),
+        norm_topk_prob=bool(d.get("norm_topk_prob", True)),
+        v_head_dim=0 if dv == dk else dv,
+        first_k_dense=first,
+        scoring_func="sigmoid",
+        routed_scaling_factor=float(d.get("routed_scaling_factor") or 1.0),
+        n_shared_experts=int(d.get("n_shared_experts") or 0),
+        sliding_window=window,
+        window_period=per,
+        window_num_heads=wheads,
+        window_rope_theta=float(d.get("swa_rope_theta", 10000.0)),
+        partial_rotary_factor=rotary,
+        short_period=lead if lead < per else -1,
+        window_kv_heads=0 if wkv == kv else wkv,
+        window_partial_rotary_factor=rotary,
+        attn_value_scale=float(d.get("attention_value_scale") or 1.0),
+        attn_sink=sink,
         kv_cache_dtype=str(d.get("kv_cache_dtype", "auto")),
     )
 
@@ -1008,6 +1197,26 @@ register_config(ModelConfig(
     partial_rotary_factor=0.5,
     rope_hf_yarn=(4.0, 32.0, 32.0, 1.0, 1.1386294361119891),
     attn_gate=True,
+))
+
+# The ``mimo_v2`` block at CPU-test size: 1 dense full layer, a first period
+# cut to 1 window layer and its full layer, 2 whole periods of 2 window
+# layers (8 heads over 4 KV heads, window 16, a sink logit a head) and 1
+# full layer (8 heads over 2 KV heads, no sink); keys 24 and values 16 wide,
+# the first 8 lanes of a head rotated in both kinds, values times 0.707; 16
+# sigmoid-routed experts top-4 with a selection bias, no shared expert.
+register_config(ModelConfig(
+    name="tiny-swa-sink-moe", vocab_size=512, hidden_size=64,
+    intermediate_size=128, num_layers=9, num_heads=8, num_kv_heads=2,
+    head_dim=24, rope_theta=10000000.0, rms_norm_eps=1e-5,
+    eos_token_ids=(0,),
+    num_experts=16, num_experts_per_tok=4, moe_intermediate_size=32,
+    norm_topk_prob=True, v_head_dim=16, first_k_dense=1,
+    scoring_func="sigmoid", sliding_window=16, window_period=2,
+    window_num_heads=8, window_rope_theta=10000.0,
+    partial_rotary_factor=0.334, short_period=1, window_kv_heads=4,
+    window_partial_rotary_factor=0.334, attn_value_scale=0.707,
+    attn_sink=("window",),
 ))
 
 # Linear-attention and gated NoPE GQA layers in one model (the

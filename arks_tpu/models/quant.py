@@ -70,8 +70,9 @@ MATMUL_KEYS = frozenset({
 SKIP_KEYS = frozenset({
     "attn_norm", "mlp_norm", "final_norm", "bq", "bk", "bv", "router",
     "shared_gate", "q_norm", "kv_norm", "router_bias",
-    # The per-head output gate [E, H]: a sigmoid's input, tiny.
-    "attn_gate",
+    # The per-head output gate [E, H]: a sigmoid's input, tiny; the sink
+    # logit a head [H] of a softmax's denominator.
+    "attn_gate", "attn_sink",
     # A linear layer's small leaves: the convolutions' taps [K, H x d], the
     # decay's bias and per-head rate, the step size [E, H], the output's
     # per-head norm.

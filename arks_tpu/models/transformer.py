@@ -230,13 +230,15 @@ def _init_latent_params(cfg: ModelConfig, key: jax.Array,
 
 def _init_windowed_params(cfg: ModelConfig, key: jax.Array,
                           dtype: jnp.dtype) -> Params:
-    """The ``laguna`` tree, a stacked tree a kind of layer: ``dense_layers``
-    (the ``first_k_dense`` leading layers: full attention, SwiGLU of
-    ``intermediate_size``), ``layers`` (one full-attention routed layer a
-    period) and ``win_layers`` (``window_period`` window layers a period,
-    in model order), each with projections of its own head count:
-    ``wq`` [E, H x D], ``wk`` / ``wv`` [E, Hkv x D], ``wo`` [H x D, E] and
-    the per-head gate ``attn_gate`` [E, H]."""
+    """The ``laguna`` / ``mimo_v2`` tree, a stacked tree a kind of layer:
+    ``dense_layers`` (the ``first_k_dense`` leading layers: full attention,
+    SwiGLU of ``intermediate_size``), ``layers`` (the full-attention routed
+    layers: one a period, a cut-short first period's ahead of them) and
+    ``win_layers`` (the window layers, in model order), each with
+    projections of its own head counts: ``wq`` [E, H x D], ``wk`` [E, Hkv x
+    D], ``wv`` [E, Hkv x Dv], ``wo`` [H x Dv, E], the per-head gate
+    ``attn_gate`` [E, H] and, where the kind has one, the sink logit a head
+    ``attn_sink`` [H]."""
     e, f, v = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size
     keys = iter(jax.random.split(key, 32))
 
@@ -244,31 +246,34 @@ def _init_windowed_params(cfg: ModelConfig, key: jax.Array,
         return (jax.random.normal(next(keys), shape, jnp.float32)
                 * scale).astype(dtype)
 
-    def attn(l: int, heads: int) -> Params:
+    def attn(l: int, window: bool) -> Params:
+        heads, kv = cfg.heads_of(window), cfg.kv_heads_of(window)
         out = {
             "attn_norm": jnp.ones((l, e), dtype),
             "wq": w((l, e, heads * cfg.head_dim)),
-            "wk": w((l, e, cfg.kv_dim)),
-            "wv": w((l, e, cfg.kv_dim)),
-            "wo": w((l, heads * cfg.head_dim, e)),
+            "wk": w((l, e, kv * cfg.head_dim)),
+            "wv": w((l, e, kv * cfg.value_dim)),
+            "wo": w((l, heads * cfg.value_dim, e)),
             "mlp_norm": jnp.ones((l, e), dtype),
         }
         if cfg.attn_gate:
             out["attn_gate"] = w((l, e, heads))
+        if cfg.sink_of(window):
+            out["attn_sink"] = w((l, heads))
         return out
 
     from arks_tpu.models import moe
     params: Params = {"embed": w((v, e)),
                       "final_norm": jnp.ones((e,), dtype)}
-    for name, l, heads in (
-            ("layers", cfg.num_periods, cfg.num_heads),
-            ("win_layers", cfg.num_window_layers, cfg.window_num_heads)):
-        params[name] = dict(attn(l, heads), **moe.init_moe_params(
+    for name, l, window in (
+            ("layers", cfg.num_full_layers - cfg.first_k_dense, False),
+            ("win_layers", cfg.num_window_layers, True)):
+        params[name] = dict(attn(l, window), **moe.init_moe_params(
             cfg, next(keys), dtype, layers=l))
     if cfg.first_k_dense:
         ld = cfg.first_k_dense
         params["dense_layers"] = dict(
-            attn(ld, cfg.num_heads), w_gate=w((ld, e, f)),
+            attn(ld, False), w_gate=w((ld, e, f)),
             w_up=w((ld, e, f)), w_down=w((ld, f, e)))
     if not cfg.tie_word_embeddings:
         params["lm_head"] = w((e, v))
@@ -541,10 +546,22 @@ def cache_head_dim(cfg: ModelConfig, pad_head: bool = False) -> int:
     qk_rope_head_dim`` wide (576 -> 640 padded: the rotary lanes' tile is
     half zeros; the value lanes, the first ``kv_lora_rank``, are whole
     tiles)."""
-    d = cfg.latent_row if cfg.latent else cfg.head_dim
-    if pad_head and d % 128 != 0:
-        return -(-d // 128) * 128
-    return d
+    return _lane_padded(cfg.latent_row if cfg.latent else cfg.head_dim,
+                        pad_head)
+
+
+def _lane_padded(d: int, pad_head: bool) -> int:
+    return -(-d // 128) * 128 if pad_head and d % 128 else d
+
+
+def cache_value_dim(cfg: ModelConfig, pad_head: bool = False) -> int:
+    """Stored width of a head's VALUES, padded as :func:`cache_head_dim`
+    pads the keys: the same width, but for a GQA model whose values are
+    narrower than its keys (``cfg.v_head_dim``: keys 192 stored as 256
+    lanes, values 128 as 128)."""
+    if cfg.latent or not cfg.v_head_dim:
+        return cache_head_dim(cfg, pad_head)
+    return _lane_padded(cfg.v_head_dim, pad_head)
 
 
 def pad_heads(x: jnp.ndarray, d_store: int) -> jnp.ndarray:
@@ -623,13 +640,18 @@ def init_paged_cache(cfg: ModelConfig, num_pages: int, page: int,
                              "pool of their own (win_pages)")
         import dataclasses
         pools = [init_paged_cache(
-            dataclasses.replace(cfg, sliding_window=0, num_layers=layers),
+            dataclasses.replace(cfg, sliding_window=0, num_layers=layers,
+                                num_kv_heads=kv),
             n, page, dtype, quantized, pad_head, kv_bits)
-            for layers, n in ((cfg.num_full_layers, num_pages),
-                              (cfg.num_window_layers, win_pages))]
+            for layers, n, kv in (
+                (cfg.num_full_layers, num_pages, cfg.num_kv_heads),
+                (cfg.num_window_layers, win_pages, cfg.kv_heads_of(True)))]
         return pools[0]._replace(win=pools[1])
     shape = (cfg.num_layers, num_pages, cfg.num_kv_heads, page,
              cache_head_dim(cfg, pad_head))
+    # (The values' pool: as wide as the keys' but where the model's values
+    # are narrower than its keys.)
+    vwidth = cache_value_dim(cfg, pad_head)
     if cfg.latent:
         if quantized:
             raise ValueError(
@@ -642,12 +664,13 @@ def init_paged_cache(cfg: ModelConfig, num_pages: int, page: int,
         if kv_bits == 4 and page % 2:
             raise ValueError(f"int4 page size {page} must be even")
         rows = page // 2 if kv_bits == 4 else page
-        vshape = shape[:3] + (rows, shape[4])
         return PagedKVCache(
-            k=jnp.zeros(vshape, jnp.int8), v=jnp.zeros(vshape, jnp.int8),
+            k=jnp.zeros(shape[:3] + (rows, shape[4]), jnp.int8),
+            v=jnp.zeros(shape[:3] + (rows, vwidth), jnp.int8),
             k_scale=jnp.zeros(shape[:-1], jnp.float32),
             v_scale=jnp.zeros(shape[:-1], jnp.float32))
-    return PagedKVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
+    return PagedKVCache(k=jnp.zeros(shape, dtype),
+                        v=jnp.zeros(shape[:4] + (vwidth,), dtype))
 
 
 def paged_cache_pspecs(cfg: ModelConfig, tp: int = 1,
@@ -881,27 +904,33 @@ def _mla_out(attn: jnp.ndarray, lp: Params, cfg: ModelConfig,
 def _kind_qkv(h: jnp.ndarray, lp: Params, cfg: ModelConfig,
               positions: jnp.ndarray, window: bool):
     """:func:`_block_qkv` for a layer of one kind of a model with window
-    and full layers: the kind's head count and RoPE (window: the whole
-    head at ``window_rope_theta``; full: the first
-    ``partial_rotary_factor`` of a head under ``rope_hf_yarn``).  Also
+    and full layers: the kind's head counts and RoPE (window: the first
+    ``window_partial_rotary_factor`` of a head at ``window_rope_theta``;
+    full: the first ``partial_rotary_factor`` of a head under
+    ``rope_hf_yarn``); the values ``cfg.value_dim`` wide and times
+    ``cfg.attn_value_scale``, as they are cached.  Also
     returns the per-head gate ``sigmoid(x Wg)`` [B, T, H] from the same
     normed input, or (``cfg.attn_out_gate``) the elementwise one [B, T, H,
     D] (None where the model has none).  ``cfg.use_rope`` False: no
     rotation of either kind."""
     b, t = h.shape[:2]
-    heads = cfg.heads_of(window)
+    heads, kv_heads = cfg.heads_of(window), cfg.kv_heads_of(window)
     with _scope("arks.attn_win_qkv" if window else "arks.attn_qkv"):
         x = rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps)
         q, k, v = _qkv(x, lp, cfg)
         q = q.reshape(b, t, heads, cfg.head_dim)
-        k = k.reshape(b, t, cfg.num_kv_heads, cfg.head_dim)
-        v = v.reshape(b, t, cfg.num_kv_heads, cfg.head_dim)
+        k = k.reshape(b, t, kv_heads, cfg.head_dim)
+        v = v.reshape(b, t, kv_heads, cfg.value_dim)
+        if cfg.attn_value_scale != 1.0:
+            v = (v.astype(jnp.float32) * cfg.attn_value_scale).astype(v.dtype)
         if not cfg.use_rope:
             def rope(t):
                 return t
         elif window:
-            rope = functools.partial(apply_rope, positions=positions,
-                                     theta=cfg.window_rope_theta)
+            rot = int(cfg.head_dim * cfg.window_partial_rotary_factor)
+            rope = functools.partial(
+                apply_rope, positions=positions, theta=cfg.window_rope_theta,
+                rotary_dim=None if rot == cfg.head_dim else rot)
         else:
             rot = int(cfg.head_dim * cfg.partial_rotary_factor)
             rope = functools.partial(
@@ -1254,12 +1283,13 @@ def mixed_step(
             q[0], k[0], v[0], pool[0], pool[1], tbl, token_slot, token_pos,
             seq_q_start, seq_q_len, seq_pos_start, index, mesh, kv_sharded,
             model_axis=AXIS_MODEL, k_scale=pool[2], v_scale=pool[3],
-            window=cfg.sliding_window if window else 0)
+            window=cfg.sliding_window if window else 0,
+            sink=lp["attn_sink"] if cfg.sink_of(window) else None)
         if gate is not None:
             with _scope("arks.attn_gate"):
                 gate = gate[0] if gate.ndim == 4 else gate[0][..., None]
                 attn = attn * gate.astype(attn.dtype)
-        attn = attn.reshape(1, t_flat, cfg.heads_of(window) * cfg.head_dim)
+        attn = attn.reshape(1, t_flat, cfg.heads_of(window) * cfg.value_dim)
         attn = _constrain(attn, mesh, None, None, AXIS_MODEL)
         with _scope("arks.attn_win_out" if window else "arks.attn_out"):
             h = h + qeinsum("...q,qe->...e", attn, lp["wo"])

@@ -69,6 +69,18 @@ class WindowedCheckpointError(NotImplementedError):
     Raised instead of stacking layers of two shapes into one tree."""
 
 
+class MimoCheckpointError(NotImplementedError):
+    """A ``mimo_v2`` checkpoint (window layers with a sink logit a head and
+    KV heads of their own, values narrower than keys): its name mapping
+    onto the three stacked trees is written (:func:`mimo_v2_tree`) and held
+    to seeded leaves, but no checkpoint of the family has been on this
+    machine, and the published files fuse q | k | v into one ``qkv_proj``
+    (``attention_projection_layout: fused_qkv``) whose row order nothing
+    here has been checked against; such a model is served from seeded
+    random weights only.  Raised instead of loading tensors nobody has
+    compared."""
+
+
 class LinearCheckpointError(NotImplementedError):
     """A checkpoint of a model with linear-attention layers
     (``solar_open2``): the name mapping of its delta-rule projections,
@@ -93,12 +105,80 @@ def _refuse_latent(cfg: ModelConfig, path: str) -> None:
     for is_kind, err in ((cfg.latent and cfg.linear,
                           LatentLinearCheckpointError),
                          (cfg.latent, LatentCheckpointError),
+                         (cfg.windowed and cfg.scoring_func == "sigmoid",
+                          MimoCheckpointError),
                          (cfg.windowed, WindowedCheckpointError),
                          (cfg.linear, LinearCheckpointError)):
         if is_kind:
             raise err(
                 f"model {cfg.name!r}: cannot load the checkpoint at {path}: "
                 + " ".join(err.__doc__.split()))
+
+
+def mimo_v2_tree(cfg: ModelConfig, t: dict[str, np.ndarray],
+                 dtype: Any) -> tf.Params:
+    """The ``mimo_v2`` name mapping: published tensor names (``[out, in]``
+    projections, a layer at a time) onto ``tf.init_params``'s three stacked
+    trees, on the host: ``dense_layers`` (the dense prefix), ``layers`` (the
+    routed full layers) and ``win_layers`` (the window layers), each in
+    model order.  ``self_attn.{q,k,v,o}_proj`` are a kind's projections (a
+    fused ``qkv_proj`` is refused: :class:`MimoCheckpointError`),
+    ``self_attn.attention_sink_bias`` the sink logit a head of the kinds
+    that have one, ``mlp.gate.weight`` the router at its whole width and
+    ``mlp.gate.e_score_correction_bias`` its selection bias,
+    ``mlp.experts.{e}.*`` the experts, of which a share keeps its own."""
+    if any(".qkv_proj." in k for k in t):
+        raise MimoCheckpointError(
+            f"model {cfg.name!r}: " + " ".join(
+                MimoCheckpointError.__doc__.split()))
+    from arks_tpu.models.moe import held_first
+    first = held_first(cfg)
+
+    def mat(name: str) -> np.ndarray:
+        return np.asarray(t[name].T, dtype)
+
+    def attn(i: int, window: bool) -> dict:
+        base = f"model.layers.{i}."
+        out = {"attn_norm": np.asarray(t[base + "input_layernorm.weight"],
+                                       dtype),
+               "mlp_norm": np.asarray(
+                   t[base + "post_attention_layernorm.weight"], dtype)}
+        for leaf, name in (("wq", "q_proj"), ("wk", "k_proj"),
+                           ("wv", "v_proj"), ("wo", "o_proj")):
+            out[leaf] = mat(f"{base}self_attn.{name}.weight")
+        if cfg.sink_of(window):
+            out["attn_sink"] = np.asarray(
+                t[base + "self_attn.attention_sink_bias"], dtype)
+        return out
+
+    def ffn(base: str) -> dict:
+        return {leaf: mat(f"{base}{name}.weight") for leaf, name in (
+            ("w_gate", "gate_proj"), ("w_up", "up_proj"),
+            ("w_down", "down_proj"))}
+
+    trees: dict[str, list] = {"dense_layers": [], "layers": [],
+                              "win_layers": []}
+    for i, kind in enumerate(cfg.layer_kinds()):
+        lp = attn(i, kind == "window")
+        mlp = f"model.layers.{i}.mlp."
+        if i < cfg.first_k_dense:
+            trees["dense_layers"].append(dict(lp, **ffn(mlp)))
+            continue
+        experts = [ffn(f"{mlp}experts.{first + e}.")
+                   for e in range(cfg.num_experts)]
+        lp.update({k: np.stack([x[k] for x in experts])
+                   for k in ("w_gate", "w_up", "w_down")},
+                  router=mat(mlp + "gate.weight"),
+                  router_bias=np.asarray(
+                      t[mlp + "gate.e_score_correction_bias"], dtype))
+        trees["win_layers" if kind == "window" else "layers"].append(lp)
+    params: tf.Params = {
+        name: {k: np.stack([lp[k] for lp in rows]) for k in rows[0]}
+        for name, rows in trees.items() if rows}
+    params.update(embed=np.asarray(t["model.embed_tokens.weight"], dtype),
+                  final_norm=np.asarray(t["model.norm.weight"], dtype),
+                  lm_head=mat("lm_head.weight"))
+    return params
 
 
 def params_from_hf(cfg: ModelConfig, path: str, dtype: Any = None,

@@ -95,10 +95,18 @@ def _use_pallas(impl: str | None, d_store: int, mesh, kv_sharded: bool,
                                     int4_decode=int4_decode))
 
 
-def _softmax(scores: jnp.ndarray, axis: int) -> jnp.ndarray:
-    scores = scores - jnp.max(scores, axis=axis, keepdims=True)
-    unnorm = jnp.exp(scores)
-    return unnorm / (jnp.sum(unnorm, axis=axis, keepdims=True) + 1e-9)
+def _softmax(scores: jnp.ndarray, axis: int,
+             sink: jnp.ndarray | None = None) -> jnp.ndarray:
+    """``sink`` (broadcastable to ``scores`` with ``axis`` of size 1): one
+    more logit in the denominator, which takes mass and has no value."""
+    top = jnp.max(scores, axis=axis, keepdims=True)
+    if sink is not None:
+        top = jnp.maximum(top, sink)
+    unnorm = jnp.exp(scores - top)
+    total = jnp.sum(unnorm, axis=axis, keepdims=True)
+    if sink is not None:
+        total = total + jnp.exp(sink - top)
+    return unnorm / (total + 1e-9)
 
 
 def prefill_attention(
@@ -134,13 +142,14 @@ def decode_attention_xla(
     v_cache: jnp.ndarray,  # [B, Hkv, S, D]
     lengths: jnp.ndarray,  # [B] int32 — number of valid cache entries per slot
     lower: jnp.ndarray | None = None,  # [B] int32 — first index attended
+    sink: jnp.ndarray | None = None,   # [Hkv, G] f32 — a sink logit a head
 ) -> jnp.ndarray:
     """Masked attention of one query token per slot against the slot KV cache.
 
     Cache index s is valid iff s < lengths[b] (the caller writes the current
     token's K/V into the cache *before* calling, so lengths includes it),
-    and, with ``lower`` (a window layer), s >= lower[b].
-    Returns [B, Hkv, G, D].
+    and, with ``lower`` (a window layer), s >= lower[b].  The values may be
+    narrower than the keys.  Returns [B, Hkv, G, Dv].
     """
     b, hkv, g, d = q.shape
     s = k_cache.shape[2]
@@ -151,10 +160,17 @@ def decode_attention_xla(
     if lower is not None:
         valid = valid & (jnp.arange(s)[None] >= lower[:, None])
     scores = jnp.where(valid[:, None, None], scores, _NEG_INF)
-    probs = _softmax(scores, axis=-1).astype(v_cache.dtype)
+    probs = _softmax(scores, -1, _sink_column(sink)).astype(v_cache.dtype)
     out = jnp.einsum("bkgs,bksd->bkgd", probs, v_cache,
                      preferred_element_type=jnp.float32)
     return out.astype(q.dtype)
+
+
+def _sink_column(sink: jnp.ndarray | None) -> jnp.ndarray | None:
+    """``[Hkv, G]`` sink logits as :func:`_softmax` takes them beside
+    ``[B, Hkv, G, S]`` scores (None where there is no sink)."""
+    return None if sink is None else sink.astype(
+        jnp.float32)[None, :, :, None]
 
 
 def _decode_attention_xla_quant(
@@ -165,6 +181,7 @@ def _decode_attention_xla_quant(
     v_scale: jnp.ndarray,
     lengths: jnp.ndarray,  # [B] int32
     lower: jnp.ndarray | None = None,  # [B] int32 — first index attended
+    sink: jnp.ndarray | None = None,   # [Hkv, G] f32 — a sink logit a head
 ) -> jnp.ndarray:
     """int8 oracle/fallback: per-token scales applied to scores (K) and
     probabilities (V), mirroring the Pallas kernel's folding."""
@@ -178,7 +195,8 @@ def _decode_attention_xla_quant(
     if lower is not None:
         valid = valid & (jnp.arange(s)[None] >= lower[:, None])
     scores = jnp.where(valid[:, None, None], scores, _NEG_INF)
-    probs = _softmax(scores, axis=-1) * v_scale[:, :, None, :]
+    probs = _softmax(scores, -1, _sink_column(sink)) \
+        * v_scale[:, :, None, :]
     out = jnp.einsum("bkgs,bksd->bkgd", probs.astype(q.dtype),
                      v_cache.astype(q.dtype),
                      preferred_element_type=jnp.float32)
@@ -406,6 +424,7 @@ def paged_mixed_update_and_attend(
     k_scale: jnp.ndarray | None = None,
     v_scale: jnp.ndarray | None = None,
     window: int = 0,
+    sink: jnp.ndarray | None = None,   # [H] — a sink logit a query head
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray,
            jnp.ndarray | None, jnp.ndarray | None]:
     """Mixed prefill+decode attention over one flat token batch: write every
@@ -432,22 +451,31 @@ def paged_mixed_update_and_attend(
     never read, so the caller may have released them.  The same
     ``pallas_call``, told the bound, under a name of its own
     (``paged_window_attention_ragged``); the scopes are ``arks.attn_win_*``
-    (set by the caller's layer)."""
+    (set by the caller's layer).
+
+    The values may be narrower than the keys (``v_new [T, Hkv, Dv]`` and a
+    value pool of its own stored width): the result is ``[T, H, Dv]``.
+    ``sink``: one learnt logit a query head in the softmax denominator,
+    which takes mass and has no value (both paths; the kernel's running
+    maximum and sum start from it)."""
     from arks_tpu.ops.paged_attention import (
         is_int4_pool, pool_page_tokens, unpack_int4_pool)
     t_flat, h, d_model = q.shape
+    dv_model = v_new.shape[-1]
     hkv = k_pool.shape[2]
     g = h // hkv
     int4 = is_int4_pool(k_pool, k_scale)
     page = pool_page_tokens(k_pool, k_scale)
     cover = tables.shape[1] * page
-    d = k_pool.shape[-1]
+    d, dv = k_pool.shape[-1], v_pool.shape[-1]
     if d != d_model:
         # Lane padding (see decode_update_and_attend): pad to the stored
         # head dim, prescale q to keep the effective 1/sqrt(d_model) scale.
         q = _pad_last(q, d) * ((d / d_model) ** 0.5)
         k_new = _pad_last(k_new, d)
-        v_new = _pad_last(v_new, d)
+    v_new = _pad_last(v_new, dv)
+    if sink is not None:
+        sink = sink.astype(jnp.float32).reshape(hkv, g)
     quantized = k_scale is not None
     use_pallas = _use_pallas(impl, d, mesh, kv_sharded, model_axis)
 
@@ -477,11 +505,12 @@ def paged_mixed_update_and_attend(
                 vsc = paged_gather_kv(vs, tables_tok, layer)
                 out = _decode_attention_xla_quant(
                     q.reshape(t_flat, hkv, g, d), kc, vc, ksc, vsc,
-                    attend_lens, *lower)
+                    attend_lens, *lower, sink=sink)
             else:
                 out = decode_attention_xla(q.reshape(t_flat, hkv, g, d), kc,
-                                           vc, attend_lens, *lower)
-        return out.reshape(t_flat, h, d)[..., :d_model], kp, vp, ks, vs
+                                           vc, attend_lens, *lower,
+                                           sink=sink)
+        return out.reshape(t_flat, h, dv)[..., :dv_model], kp, vp, ks, vs
 
     from arks_tpu.ops.paged_attention import (
         paged_kv_update, paged_kv_update_quant, paged_mixed_attention_flat,
@@ -496,7 +525,7 @@ def paged_mixed_update_and_attend(
                             ("arks.attn_kernel", "arks.attn_layout"))
 
     def local(qg, kn, vn, kp, vp, ks, vs, tbl, tok_tbl, widx, tslot,
-              q_start, qlen, pos0, lyr):
+              q_start, qlen, pos0, lyr, snk=None):
         with jax.named_scope(sc_kernel):
             if quantized:
                 kp, vp, ks, vs = paged_kv_update_quant(
@@ -515,7 +544,7 @@ def paged_mixed_update_and_attend(
             out = paged_mixed_attention_flat(
                 qg, kp, vp, tbl, tslot, q_start, qlen, pos0, lyr,
                 k_scale=ks, v_scale=vs, interpret=interpret,
-                window=window)
+                window=window, sink=snk)
         return out, kp, vp, ks, vs
 
     qg = q.reshape(t_flat, hkv, g, d)
@@ -523,9 +552,12 @@ def paged_mixed_update_and_attend(
         out, kp, vp, ks, vs = local(qg, k_new, v_new, k_pool, v_pool,
                                     k_scale, v_scale, tables, tables_tok,
                                     write_idx, token_slot, seq_q_start,
-                                    seq_q_len, seq_pos_start, layer)
-        return out.reshape(t_flat, h, d)[..., :d_model], kp, vp, ks, vs
+                                    seq_q_len, seq_pos_start, layer, sink)
+        return out.reshape(t_flat, h, dv)[..., :dv_model], kp, vp, ks, vs
 
+    if sink is not None:
+        raise NotImplementedError("a sink logit under a device mesh (the "
+                                  "block has no sharding rules)")
     from jax.sharding import PartitionSpec as P
     model = model_axis if kv_sharded else None
     qspec = P(None, model, None, None)
@@ -544,7 +576,7 @@ def paged_mixed_update_and_attend(
                              k_scale, v_scale, tables, tables_tok,
                              write_idx, token_slot, seq_q_start, seq_q_len,
                              seq_pos_start, jnp.asarray(layer, jnp.int32))
-    return out.reshape(t_flat, h, d)[..., :d_model], kp, vp, ks, vs
+    return out.reshape(t_flat, h, dv)[..., :dv_model], kp, vp, ks, vs
 
 
 def latent_kernel_blockers(r_store: int, dv: int, mesh=None) -> list[str]:

@@ -805,7 +805,7 @@ def _paged_mixed_ragged_kernel(layer_ref, tables_ref, pos_start_ref,
                                quantized: bool, int4: bool, depth: int,
                                head_group: int, carry: bool,
                                emit_state: bool, latent: bool = False,
-                               window: int = 0):
+                               window: int = 0, sink: bool = False):
     """RAGGED work-list grid: one grid step per (sequence, head_group,
     q_block) work item, the page loop INSIDE the kernel bounded by that
     item's own causal page span [wl_plo, wl_pages).  q_len=0 lanes and
@@ -843,7 +843,13 @@ def _paged_mixed_ragged_kernel(layer_ref, tables_ref, pos_start_ref,
     starts an item at the first page its window meets; the softmax block
     masks the keys below each query's bound.  A query's window always
     holds its own position, so no row of a real item is left without a
-    key."""
+    key.
+
+    ``sink``: one more input behind the scale pools, ``[Hkv, G x block_q,
+    128]`` float32 held whole in VMEM: a learnt logit a query head, laid
+    out as the running maximum is.  The softmax state starts from it (m =
+    the logit, l = exp(0) = 1, nothing accumulated): the sink takes mass
+    in the denominator and has no value."""
     del wl_blk_ref      # the index maps' column (compacted layout)
     rest = list(rest)
     vpool = None if latent else rest.pop(0)
@@ -852,6 +858,7 @@ def _paged_mixed_ragged_kernel(layer_ref, tables_ref, pos_start_ref,
         rest = rest[2:]
     else:
         kspool = vspool = None
+    sink_ref = rest.pop(0) if sink else None
     if carry:
         mi_ref, li_ref, ai_ref = rest[:3]
         rest = rest[3:]
@@ -928,6 +935,10 @@ def _paged_mixed_ragged_kernel(layer_ref, tables_ref, pos_start_ref,
             m_ref[:] = mi_ref[0].reshape(m_ref.shape)
             l_ref[:] = li_ref[0].reshape(l_ref.shape)
             acc_ref[:] = ai_ref[0].reshape(acc_ref.shape)
+        elif sink:
+            m_ref[:] = sink_ref[pl.ds(h0, head_group)]
+            l_ref[:] = jnp.ones_like(l_ref)
+            acc_ref[:] = jnp.zeros_like(acc_ref)
         else:
             m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
             l_ref[:] = jnp.zeros_like(l_ref)
@@ -972,12 +983,12 @@ def _mixed_scratch(k_pool, v_pool, *, nbuf: int, head_group: int,
     """VMEM scratch of one mixed-attention work item: ``nbuf`` page
     buffers (one set where ``v_pool`` is None: a latent pool), the
     online-softmax state (the accumulator ``dv`` wide, ``d`` by default),
-    the DMA semaphores."""
+    the DMA semaphores.  A value pool's buffers are as wide as the pool."""
     kv_rows = k_pool.shape[3]            # page//2 byte rows for int4 pools
     scratch = [pltpu.VMEM((nbuf, head_group, kv_rows, d), k_pool.dtype)]
     if v_pool is not None:
-        scratch.append(
-            pltpu.VMEM((nbuf, head_group, kv_rows, d), v_pool.dtype))
+        scratch.append(pltpu.VMEM(
+            (nbuf, head_group, kv_rows, v_pool.shape[-1]), v_pool.dtype))
     n_sem = 2
     if quantized:
         scratch += [pltpu.VMEM((nbuf, hkv, page), jnp.float32),
@@ -997,7 +1008,7 @@ def _ragged_launch(qp, k_pool, v_pool, tables32, pos32, work_list, layer,
                    block_q: int, dma_depth: int, interpret: bool,
                    head_group: int, emit_state: bool = False,
                    latent_v: int = 0, scale: float | None = None,
-                   window: int = 0):
+                   window: int = 0, sink: jnp.ndarray | None = None):
     """The ragged work-list ``pallas_call``, one grid step per entry of
     ``work_list`` (:func:`build_mixed_work_list`).  ``qp`` holds the
     queries in ``block_q``-row blocks, in one of two layouts that differ
@@ -1020,7 +1031,14 @@ def _ragged_launch(qp, k_pool, v_pool, tables32, pos32, work_list, layer,
 
     ``window`` > 0 is a window layer's launch (the work list built with
     the same ``window``): the same call, told the bound, named
-    ``paged_window_attention_ragged`` under ``arks.attn_win_kernel``."""
+    ``paged_window_attention_ragged`` under ``arks.attn_win_kernel``.
+
+    A value pool narrower (or wider) than the key pool: the accumulator,
+    the carried state's and the output are as wide as the VALUES.
+    ``sink`` ``[Hkv, G]`` float32: a learnt logit a query head that every
+    item's softmax state starts from (the kernel's docstring); it is
+    handed over once, ``[Hkv, G x block_q, 128]``, a block that never
+    moves."""
     lead, hkv, g, qrows, d = qp.shape
     quantized = k_scale is not None
     page = pool_page_tokens(k_pool, k_scale)
@@ -1034,7 +1052,10 @@ def _ragged_launch(qp, k_pool, v_pool, tables32, pos32, work_list, layer,
     if window and (latent or carry or emit_state):
         raise ValueError("a window launch is one span of a K/V pool: no "
                          "latent page, no carried softmax state")
-    dv = latent_v or d
+    if sink is not None and (latent or carry):
+        raise ValueError("a sink logit starts the softmax state of a K/V "
+                         "pool's one span: no latent page, no carried state")
+    dv = latent_v or v_pool.shape[-1]
 
     if compact:
         def q_map(i, layer_p, tables_p, pos_p, seq_p, hg_p, qb_p, plo_p,
@@ -1047,6 +1068,8 @@ def _ragged_launch(qp, k_pool, v_pool, tables32, pos32, work_list, layer,
             del layer_p, tables_p, pos_p, plo_p, pages_p, blk_p
             return (seq_p[i], hg_p[i], 0, qb_p[i], 0)
 
+    # (The accumulator's blocks: ``o``'s shape, which is ``q``'s wherever
+    # the values are as wide as the keys.)
     blk = dict(q=(1, head_group, g, block_q, d),
                o=(1, head_group, g, block_q, dv),
                ml=(1, head_group, g, block_q, 128))
@@ -1058,15 +1081,15 @@ def _ragged_launch(qp, k_pool, v_pool, tables32, pos32, work_list, layer,
         carry_inputs = list(carry_state)
         carry_specs = [pl.BlockSpec(blk["ml"], q_map),
                        pl.BlockSpec(blk["ml"], q_map),
-                       pl.BlockSpec(blk["q"], q_map)]
+                       pl.BlockSpec(blk["o"], q_map)]
     if emit_state:
         out_specs = (pl.BlockSpec(blk["ml"], q_map),
                      pl.BlockSpec(blk["ml"], q_map),
-                     pl.BlockSpec(blk["q"], q_map))
+                     pl.BlockSpec(blk["o"], q_map))
         out_shape = (
             jax.ShapeDtypeStruct((lead, hkv, g, qrows, 128), jnp.float32),
             jax.ShapeDtypeStruct((lead, hkv, g, qrows, 128), jnp.float32),
-            jax.ShapeDtypeStruct((lead, hkv, g, qrows, d), jnp.float32))
+            jax.ShapeDtypeStruct((lead, hkv, g, qrows, dv), jnp.float32))
     else:
         out_specs = pl.BlockSpec(blk["o"], q_map)
         out_shape = jax.ShapeDtypeStruct(qp.shape[:-1] + (dv,), qp.dtype)
@@ -1075,6 +1098,13 @@ def _ragged_launch(qp, k_pool, v_pool, tables32, pos32, work_list, layer,
     pool_specs = [pl.BlockSpec(memory_space=pl.ANY)] * len(pools)  # manual DMA
     scale_inputs = [k_scale, v_scale] if quantized else []
     scale_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 2 if quantized else []
+    if sink is not None:
+        # Row r of a head's G x block_q rows is query head r // block_q.
+        scale_inputs.append(jnp.broadcast_to(
+            sink.astype(jnp.float32)[:, :, None, None],
+            (hkv, g, block_q, 128)).reshape(hkv, g * block_q, 128))
+        scale_specs.append(pl.BlockSpec(
+            (hkv, g * block_q, 128), lambda i, *prefetch: (0, 0, 0)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=9,  # layer, tables, pos_start, work list x6
         grid=(work_list[0].shape[0],),
@@ -1093,7 +1123,7 @@ def _ragged_launch(qp, k_pool, v_pool, tables32, pos32, work_list, layer,
         int4=is_int4_pool(k_pool, k_scale), depth=dma_depth,
         head_group=head_group, carry=carry, emit_state=emit_state,
         **({"latent": True} if latent else {}),
-        window=window)
+        window=window, sink=sink is not None)
     # The call alone is the kernel in a profile; the layout work around it
     # stays with the caller's scope (arks.attn_layout in the mixed step).
     with jax.named_scope("arks.attn_win_kernel" if window
@@ -1167,7 +1197,8 @@ def _paged_mixed_call(q, k_pool, v_pool, tables, pos_start, q_len, layer,
                                              "dma_depth", "head_group",
                                              "latent_v", "scale", "window"))
 def _paged_mixed_flat_call(q, k_pool, v_pool, tables, token_slot, q_start,
-                           q_len, pos_start, layer, k_scale, v_scale, *,
+                           q_len, pos_start, layer, k_scale, v_scale,
+                           sink=None, *,
                            block_q: int, nb: int, dma_depth: int,
                            interpret: bool, head_group: int,
                            latent_v: int = 0, scale: float | None = None,
@@ -1203,7 +1234,7 @@ def _paged_mixed_flat_call(q, k_pool, v_pool, tables, token_slot, q_start,
         tables.astype(jnp.int32), pos32, work_list, layer, k_scale, v_scale,
         compact=True, block_q=block_q, dma_depth=dma_depth,
         interpret=interpret, head_group=head_group, latent_v=latent_v,
-        scale=scale, window=window)
+        scale=scale, window=window, sink=sink)
     # Straight out of the kernel's layout by (block, row): a transpose to
     # row-major first would copy the whole output once more.
     flat = out[out_rows // block_q, :, :, out_rows % block_q]
@@ -1279,6 +1310,7 @@ def paged_mixed_attention_flat(
     latent_v: int = 0,
     scale: float | None = None,
     window: int = 0,
+    sink: jnp.ndarray | None = None,   # [Hkv, G] f32
 ) -> jnp.ndarray:
     """[T, Hkv, G, D] ragged mixed attention straight over the flat batch:
     row t of lane s = token_slot[t] sits at global position
@@ -1302,7 +1334,11 @@ def paged_mixed_attention_flat(
 
     ``window`` > 0 (a window layer): a row at position p attends
     ``(p - window, p]``; the work list and the kernel are told the bound
-    (:func:`build_mixed_work_list`, :func:`_ragged_launch`)."""
+    (:func:`build_mixed_work_list`, :func:`_ragged_launch`).
+
+    A value pool of another width than the key pool: the result is ``[T,
+    Hkv, G, Dv]``.  ``sink``: a learnt logit a query head in every row's
+    softmax denominator (:func:`_ragged_launch`)."""
     t_flat, hkv, g, d = q.shape
     s = q_len.shape[0]
     # +1: with every lane a q_len = K block (t_flat == S * K, one lane)
@@ -1315,7 +1351,7 @@ def paged_mixed_attention_flat(
                            head_group=head_group, lanes=s)
     return _paged_mixed_flat_call(
         q, k_pool, v_pool, tables, token_slot, q_start, q_len,
-        pos_start, layer, k_scale, v_scale, block_q=plan["block_q"],
+        pos_start, layer, k_scale, v_scale, sink, block_q=plan["block_q"],
         nb=plan["nb"], dma_depth=plan["dma_depth"],
         interpret=interpret, head_group=plan["head_group"],
         latent_v=latent_v, scale=scale,

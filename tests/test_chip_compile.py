@@ -52,12 +52,15 @@ def chip():
     cc.reset_cache()
 
 
-def _pool(s, kv, hkv, page=PAGE):
+def _pool(s, kv, hkv, page=PAGE, dk=D, dv=D):
+    """K, V and the two scale pools; ``dk`` / ``dv``: the stored widths of
+    keys and values where they differ."""
     if kv == "bf16":
         return (s((L, N_PAGES, hkv, page, D), jnp.bfloat16),) * 2 + (None,) * 2
     rows = page // 2 if kv == "int4" else page
-    return ((s((L, N_PAGES, hkv, rows, D), jnp.int8),) * 2
-            + (s((L, N_PAGES, hkv, page), jnp.float32),) * 2)
+    return (s((L, N_PAGES, hkv, rows, dk), jnp.int8),
+            s((L, N_PAGES, hkv, rows, dv), jnp.int8)) \
+        + (s((L, N_PAGES, hkv, page), jnp.float32),) * 2
 
 
 def _mixed(s, kv, q, hkv=HKV, page=PAGE, **plan):
@@ -74,21 +77,24 @@ def _mixed(s, kv, q, hkv=HKV, page=PAGE, **plan):
 
 
 def _mixed_flat(s, kv, lanes, chunk, hkv=HKV, g=G, max_pages=MAX_PAGES,
-                **plan):
+                dk=D, dv=D, sink=False, **plan):
     """The flat batch through the block-compacted layout, as the mixed
     step calls it: ``lanes + chunk`` rows (chunk 0 = the pipelined step,
-    one row a lane)."""
-    kp, vp, ks, vs = _pool(s, kv, hkv)
+    one row a lane).  ``sink``: a logit a query head handed to the
+    launch."""
+    kp, vp, ks, vs = _pool(s, kv, hkv, dk=dk, dv=dv)
 
-    def fn(qq, kp, vp, tables, tslot, qstart, qlen, pos, ks, vs):
+    def fn(qq, kp, vp, tables, tslot, qstart, qlen, pos, ks, vs, *snk):
         return pa.paged_mixed_attention_flat(
-            qq, kp, vp, tables, tslot, qstart, qlen, pos, 3, ks, vs, **plan)
+            qq, kp, vp, tables, tslot, qstart, qlen, pos, 3, ks, vs, **plan,
+            **({"sink": snk[0]} if snk else {}))
 
     lane = s((lanes,), jnp.int32)
     return jax.jit(fn).lower(
-        s((lanes + chunk, hkv, g, D), jnp.bfloat16), kp, vp,
+        s((lanes + chunk, hkv, g, dk), jnp.bfloat16), kp, vp,
         s((lanes, max_pages), jnp.int32), s((lanes + chunk,), jnp.int32),
-        lane, lane, lane, ks, vs)
+        lane, lane, lane, ks, vs,
+        *((s((hkv, g), jnp.float32),) if sink else ()))
 
 
 # Laguna-S-2.1 (benchmarks/configs/laguna-s-2.1-ep8): 8 KV heads under 48
@@ -130,6 +136,22 @@ def _laguna_update(s):
     return _row_write(_pool(s, "int8", LAG["hkv"]),
                       s((t, LAG["hkv"], D), jnp.bfloat16), s((t,), jnp.int32),
                       s((t, LAG["max_pages"]), jnp.int32))
+
+
+# MiMo-V2.5 (benchmarks/configs/mimo-v2.5-ep16-l13): 64 query heads over 8
+# KV heads in a window layer (g = 8, window 128: half a page, a sink logit a
+# head) and over 4 in a full layer (g = 16), keys 192 wide stored as 256
+# lanes and values 128, int8 pages of 256, 64 slots x 8,192 tokens.
+MIMO = dict(hkv_win=8, hkv_full=4, heads=64, window=128, slots=64,
+            max_pages=32, dk=256, dv=128)
+
+
+def _mimo_flat(s, chunk, window: bool):
+    hkv = MIMO["hkv_win"] if window else MIMO["hkv_full"]
+    return _mixed_flat(
+        s, "int8", MIMO["slots"], chunk, hkv=hkv, g=MIMO["heads"] // hkv,
+        max_pages=MIMO["max_pages"], dk=MIMO["dk"], dv=MIMO["dv"],
+        **({"window": MIMO["window"], "sink": True} if window else {}))
 
 
 # Kimi-K2.5 (benchmarks/configs/kimi-k2.5-ep32-l9): 64 heads over ONE latent
@@ -252,6 +274,15 @@ CASES = {
     "full-g6-int8-seq": lambda s: _laguna_flat(s, LAG["chunk"], False),
     "full-g6-int8-pipe": lambda s: _laguna_flat(s, 0, False),
     "update-int8-hkv8-64-pages": lambda s: _laguna_update(s),
+    # Window and full layers at MiMo-V2.5's widths, the pipelined step's
+    # launches: the window launch with its sink (g = 8, a window of half a
+    # page) and the full layers' (g = 16), keys stored 256 and values 128
+    # lanes wide.  (A 192-lane key page is refused: "Slice shape along
+    # dimension 4 must be aligned to tiling (128), but is 192".)  The
+    # 1,088-row step's launches and its row writes of rows of two widths
+    # compile inside the share's whole step below.
+    "window-sink-k256-v128-int8-pipe": lambda s: _mimo_flat(s, 0, True),
+    "full-g16-k256-v128-int8-pipe": lambda s: _mimo_flat(s, 0, False),
     # tp=4 leaves one KV head per chip.
     "mixed-int8-chunk-hkv1": lambda s: _mixed(s, "int8", CHUNK + 1, hkv=1),
     "update-int8-hkv1": lambda s: _update(s, "int8", hkv=1),
@@ -399,6 +430,7 @@ SHARES = {
     "laguna-s-2.1-ep8": (8, 32, 64, 512, 32 * 7, True),
     "solar-open2-250b-ep8-l8": (8, 64, 20, 64 * 20, 0, True),
     "gigachat3.5-432b-ep8-l5": (8, 64, 80, 64 * 80, 0, False),
+    "mimo-v2.5-ep16-l13": (16, 64, 32, 1280, 64 * 6, True),
 }
 _STEPS: dict = {}
 
@@ -452,7 +484,8 @@ def _share_step(chip, monkeypatch, name: str, rows: int):
 @pytest.mark.parametrize("name,rows,temp_mb", [
     ("kimi-k2.5-ep32-l9", 1024, 260), ("laguna-s-2.1-ep8", 1024, 480),
     ("solar-open2-250b-ep8-l8", 256, 220),
-    ("gigachat3.5-432b-ep8-l5", 1024, 360)])
+    ("gigachat3.5-432b-ep8-l5", 1024, 360),
+    ("mimo-v2.5-ep16-l13", 1024, 800)])
 def test_a_shares_chunk_step_copies_no_expert_leaf_out_of_its_stack(
         chip, monkeypatch, name, rows, temp_mb):
     """The chunk-carrying step of each share configuration at its published

@@ -118,6 +118,20 @@ the CPU, the parent's and the change's four programs of the preset are the
 same HLO line for line once source locations are stripped (4,067 / 4,250 /
 3,921 / 4,067 lines), and kimi's cell is among those run on the chip before
 and after (PERF.md §6, PR 46).
+
+PR 47 ADDED four pins and moved none of the thirty: ``tiny-swa-sink-moe``
+(the ``mimo_v2`` block: the windowed block told a KV head count a kind, a
+value width, a sink logit a head of the window layers, a value scale, a
+rotary share in both kinds, a first period cut short, sigmoid routing and
+no shared expert) under a share, the values of PR 47's own tree.  The block
+rides ``tiny-swa-moe``'s code: ``_kind_qkv``, ``layer()`` of the period
+scan, ``paged_mixed_update_and_attend`` and (on the chip) ``_ragged_launch``
+gained branches that the configuration decides while the program is traced
+(``cfg.v_head_dim``, ``cfg.window_kv_heads``, ``cfg.attn_sink``,
+``cfg.attn_value_scale``, ``cfg.window_partial_rotary_factor``), and
+``ops/attention.py::_softmax`` took a sink argument; with none of them set
+every older preset lowers the text it lowered, ``tiny-swa-moe``'s eight
+programs included.
 """
 
 import hashlib
@@ -162,6 +176,10 @@ PINS = {
     "tiny-latent-linear-moe.seq_lp": "0c7d6b61622cde62",
     "tiny-latent-linear-moe.pipe": "8b8e6456119c2122",
     "tiny-latent-linear-moe.pipe_lp": "6d5c51de4ebb50c8",
+    "tiny-swa-sink-moe.seq": "8e94d0fd90b06a82",
+    "tiny-swa-sink-moe.seq_lp": "8435f8a7278fcd06",
+    "tiny-swa-sink-moe.pipe": "9dc16b7f9648b7d1",
+    "tiny-swa-sink-moe.pipe_lp": "ff7fc0011206caf6",
 }
 
 
