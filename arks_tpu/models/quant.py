@@ -27,7 +27,11 @@ arksapplication_types.go:292); this module is the TPU-native counterpart.
 A quantized leaf is a pytree-compatible dict: int8 = ``{"q": int8,
 "s": f32}`` with s = [.., 1, N] for matmul weights [.., K, N] (the
 embedding [V, E] carries s = [V, 1]); int4 = ``{"q": int4 [.., K, N],
-"gs": f32 [.., K/G, N]}``.
+"gs": f32 [.., K/G, N]}``.  The GQA blocks' q / k / v projections are
+stored head-split with the contraction dimension minor, ``[L, H, D, E]``
+(`transformer.init_params` says why): their scales are ``[L, H, D, 1]`` /
+``[L, H, D, E/G]``, the same numbers as the ``[L, E, H x D]`` leaf's
+(:func:`contraction_axis` tells the two apart).
 """
 
 from __future__ import annotations
@@ -65,6 +69,19 @@ MATMUL_KEYS = frozenset({
     # latent layers' gate is ``wg``).
     "w_z",
 })
+# The leaves a GQA stack stores head-split, ``[L, H, D, E]``
+# (`transformer.split_heads`).  The linear layers' leaves of the same names
+# are plain ``[L, E, H x d]`` matmuls: the rank says which.
+HEAD_SPLIT_KEYS = frozenset({"wq", "wk", "wv"})
+
+
+def contraction_axis(name: str, ndim: int) -> int:
+    """The contraction dimension of a STACKED matmul leaf: -2 (``[.., K,
+    N]``), and -1 for a head-split projection ``[L, H, D, E]``, the only
+    leaf of rank 4 under those names."""
+    return -1 if name in HEAD_SPLIT_KEYS and ndim == 4 else -2
+
+
 # Router logits feed a softmax over experts — tiny and precision-sensitive,
 # so it stays full width, as do norms, biases and the scalar shared gate.
 SKIP_KEYS = frozenset({
@@ -108,9 +125,10 @@ def quantize_tensor(w: jnp.ndarray, axis: int = -2) -> dict:
 
 
 def quantize_tensor_int4(w: jnp.ndarray, group: int | None = None,
-                         shards: int = 1) -> dict:
+                         shards: int = 1, axis: int = -2) -> dict:
     """Symmetric int4 quantization of a matmul weight [.., K, N] with one
-    scale per (``group`` reduction rows x output channel).
+    scale per (``group`` reduction rows x output channel); ``axis`` -1: of
+    a weight [.., N, K], group scales [.., N, K/G].
 
     ``shards``: the mesh's model-axis size.  A row-parallel leaf shards
     its contraction dim K, and group scales shard with it, so the group
@@ -118,6 +136,9 @@ def quantize_tensor_int4(w: jnp.ndarray, group: int | None = None,
     to the largest divisor that fits — also covers small test-sized
     weights (group <= K).
     """
+    if axis == -1:
+        out = quantize_tensor_int4(jnp.swapaxes(w, -1, -2), group, shards)
+        return {k: jnp.swapaxes(v, -1, -2) for k, v in out.items()}
     w32 = w.astype(jnp.float32)
     k = w32.shape[-2]
     local = max(k // max(shards, 1), 1)
@@ -135,11 +156,15 @@ def quantize_tensor_int4(w: jnp.ndarray, group: int | None = None,
 
 
 def _dequant_int4(w, dtype: jnp.dtype) -> jnp.ndarray:
+    """The groups tile the contraction dimension: the one dimension in
+    which the scales are fewer than the values (none: groups of one)."""
     q, gs = w["q"], w["gs"]
-    ngroups = gs.shape[-2]
-    g = q.shape[-2] // ngroups
-    grp = q.astype(dtype).reshape(*q.shape[:-2], ngroups, g, q.shape[-1])
-    return (grp * gs[..., :, None, :].astype(dtype)).reshape(q.shape)
+    axis = next((i for i, (a, b) in enumerate(zip(q.shape, gs.shape))
+                 if a != b), q.ndim - 2)
+    grp = q.astype(dtype).reshape(
+        *q.shape[:axis], gs.shape[axis], q.shape[axis] // gs.shape[axis],
+        *q.shape[axis + 1:])
+    return (grp * jnp.expand_dims(gs, axis + 1).astype(dtype)).reshape(q.shape)
 
 
 def qeinsum(eq: str, x: jnp.ndarray, w) -> jnp.ndarray:
@@ -150,13 +175,19 @@ def qeinsum(eq: str, x: jnp.ndarray, w) -> jnp.ndarray:
     is constant along the contraction dim), broadcasting over trailing dims.
     int4: groupwise scales vary along the contraction dim, so the dequant
     is an elementwise producer of the weight operand (fused by XLA).
+    The contraction dim is read off ``eq``: the one letter of ``w``'s that
+    the output lacks (``"...e,eq->...q"``: -2; ``"...e,hde->...hd"``: -1);
+    the output ends in ``w``'s other dims, in ``w``'s order.
     """
     if not is_quantized(w):
         return jnp.einsum(eq, x, w)
     if "gs" in w:
         return jnp.einsum(eq, x, _dequant_int4(w, x.dtype))
+    ins, out = eq.split("->")
+    spec = ins.split(",")[1]
+    (axis,) = (i - len(spec) for i, c in enumerate(spec) if c not in out)
     y = jnp.einsum(eq, x, w["q"].astype(x.dtype))
-    return y * jnp.squeeze(w["s"], axis=-2).astype(y.dtype)
+    return y * jnp.squeeze(w["s"], axis=axis).astype(y.dtype)
 
 
 def dequantize(w, dtype: jnp.dtype) -> jnp.ndarray:
@@ -222,9 +253,10 @@ def quantize_params(params: dict, bits: int = 8,
         elif name == "embed":
             out[name] = quantize_tensor(leaf, axis=-1)
         elif name in MATMUL_KEYS:
-            out[name] = (quantize_tensor_int4(leaf, group, shards)
+            axis = contraction_axis(name, leaf.ndim)
+            out[name] = (quantize_tensor_int4(leaf, group, shards, axis)
                          if bits == 4
-                         else quantize_tensor(leaf, axis=-2))
+                         else quantize_tensor(leaf, axis=axis))
         else:
             assert name in SKIP_KEYS, (
                 f"param leaf {name!r} is in neither MATMUL_KEYS nor "
@@ -271,6 +303,18 @@ def init_params_quantized(cfg, key, dtype=jnp.bfloat16, bits: int = 8,
             return tf.shift_dt_bias(w.astype(dtype))
         return w.astype(dtype)
 
+    # A head-split projection is drawn and quantised as the [L, E, H x D]
+    # matmul it is, by the program every other matmul leaf takes, and
+    # stored [L, H, D, E] by a program of its own: fused into ONE, the
+    # chip's compiler divides ``w / s`` another way and 3.8 % of the int8
+    # values come out one step off the values the same draw gives in the
+    # drawn order (PERF.md section 6, PR 48), which is what a seed means
+    # (benchmarks/references/_common.py).  What stands beside the stored
+    # leaf for a moment is its int8 copy, a quarter of the float32 draw.
+    @functools.partial(jax.jit, static_argnames=("heads",))
+    def stored(leaf, heads):
+        return {n: tf.split_heads(a, heads) for n, a in leaf.items()}
+
     counter = [0]
 
     def build(subtree):
@@ -292,6 +336,11 @@ def init_params_quantized(cfg, key, dtype=jnp.bfloat16, bits: int = 8,
                 kind, axis = "quant", -1
             elif name in MATMUL_KEYS:
                 kind, axis = "quant", -2
+                if contraction_axis(name, leaf.ndim) == -1:
+                    *lead, h, d, e = leaf.shape
+                    out[name] = stored(
+                        gen(sub, (*lead, e, h * d), kind, axis), h)
+                    continue
             elif name == "dt_bias":
                 kind, axis = "dt_bias", 0
             else:
@@ -323,9 +372,9 @@ def quantize_pspecs(specs: dict, bits: int = 8) -> dict:
                 continue
             # All matmul specs are full-rank (param_pspecs/moe_pspecs emit
             # one entry per dim), so the scale spec is the weight spec with
-            # the contraction dim (always -2) replicated.
+            # the contraction dim replicated.
             s_entries = list(leaf)
-            s_entries[-2] = None
+            s_entries[contraction_axis(name, len(leaf))] = None
             out[name] = {"q": leaf, "s": P(*s_entries)}
         else:
             out[name] = leaf

@@ -179,6 +179,15 @@ class PagedKVCache(NamedTuple):
 # ---------------------------------------------------------------------------
 
 
+def split_heads(w, heads: int):
+    """A GQA projection ``[.., E, H x D]`` as it is STORED: ``[.., H, D,
+    E]``, heads split and the contraction dimension minor (a jax or a numpy
+    array; :func:`init_params` says why).  A published ``[H x D, E]``
+    weight takes the reshape alone."""
+    w = w.swapaxes(-1, -2)
+    return w.reshape(*w.shape[:-2], heads, w.shape[-2] // heads, w.shape[-1])
+
+
 def _init_latent_params(cfg: ModelConfig, key: jax.Array,
                         dtype: jnp.dtype) -> Params:
     """The DeepSeek-V3 / kimi_k2 tree: ``dense_layers`` (the first
@@ -235,8 +244,9 @@ def _init_windowed_params(cfg: ModelConfig, key: jax.Array,
     SwiGLU of ``intermediate_size``), ``layers`` (the full-attention routed
     layers: one a period, a cut-short first period's ahead of them) and
     ``win_layers`` (the window layers, in model order), each with
-    projections of its own head counts: ``wq`` [E, H x D], ``wk`` [E, Hkv x
-    D], ``wv`` [E, Hkv x Dv], ``wo`` [H x Dv, E], the per-head gate
+    projections of its own head counts: ``wq`` [H, D, E], ``wk`` [Hkv, D,
+    E], ``wv`` [Hkv, Dv, E] (head-split: :func:`init_params`), ``wo`` [H x
+    Dv, E], the per-head gate
     ``attn_gate`` [E, H] and, where the kind has one, the sink logit a head
     ``attn_sink`` [H]."""
     e, f, v = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size
@@ -250,9 +260,9 @@ def _init_windowed_params(cfg: ModelConfig, key: jax.Array,
         heads, kv = cfg.heads_of(window), cfg.kv_heads_of(window)
         out = {
             "attn_norm": jnp.ones((l, e), dtype),
-            "wq": w((l, e, heads * cfg.head_dim)),
-            "wk": w((l, e, kv * cfg.head_dim)),
-            "wv": w((l, e, kv * cfg.value_dim)),
+            "wq": split_heads(w((l, e, heads * cfg.head_dim)), heads),
+            "wk": split_heads(w((l, e, kv * cfg.head_dim)), kv),
+            "wv": split_heads(w((l, e, kv * cfg.value_dim)), kv),
             "wo": w((l, heads * cfg.value_dim, e)),
             "mlp_norm": jnp.ones((l, e), dtype),
         }
@@ -299,7 +309,8 @@ def _init_linear_params(cfg: ModelConfig, key: jax.Array,
     routed: ``head_layers`` (layer 0, a GQA layer), ``layers`` (one GQA
     layer a period) and ``lin_layers`` (``linear_period`` linear layers a
     period and the tail, in model order).  A GQA layer: ``wq`` / ``wk`` /
-    ``wv`` / ``wo`` and the elementwise output gate ``wg`` [E, H x D].  A
+    ``wv`` (head-split: :func:`init_params`) / ``wo`` and the elementwise
+    output gate ``wg`` [E, H x D].  A
     linear layer, H heads of d: ``wq`` / ``wk`` / ``wv`` [E, H x d],
     ``conv_q`` / ``conv_k`` / ``conv_v`` [K, H x d] (oldest tap first),
     the decay ``w_f1`` [E, d] ``w_f2`` [d, H x d] ``dt_bias`` [H x d]
@@ -316,9 +327,9 @@ def _init_linear_params(cfg: ModelConfig, key: jax.Array,
     def gqa(l: int) -> Params:
         out = {
             "attn_norm": jnp.ones((l, e), dtype),
-            "wq": w((l, e, cfg.q_dim)),
-            "wk": w((l, e, cfg.kv_dim)),
-            "wv": w((l, e, cfg.kv_dim)),
+            "wq": split_heads(w((l, e, cfg.q_dim)), cfg.num_heads),
+            "wk": split_heads(w((l, e, cfg.kv_dim)), cfg.num_kv_heads),
+            "wv": split_heads(w((l, e, cfg.kv_dim)), cfg.num_kv_heads),
             "wo": w((l, cfg.q_dim, e)),
             "mlp_norm": jnp.ones((l, e), dtype),
         }
@@ -431,6 +442,26 @@ def _init_latent_linear_params(cfg: ModelConfig, key: jax.Array,
 
 
 def init_params(cfg: ModelConfig, key: jax.Array, dtype: jnp.dtype | None = None) -> Params:
+    """The parameter tree, layers stacked (leading ``[L]``), a stack a kind
+    of layer; a matmul leaf is ``[.., K, N]``, contraction dimension first.
+
+    But for the q / k / v projections of every GQA stack (dense, routed,
+    window and full kinds, ``solar_open2``'s GQA layers): ``wq`` [L, H, D,
+    E], ``wk`` [L, Hkv, D, E], ``wv`` [L, Hkv, Dv, E], that kind's heads
+    split and the contraction dimension MINOR (:func:`split_heads`; the
+    numbers are those of the ``[L, E, H x D]`` draw).  That is the order the
+    step programs' dots read them in: the compiler emits the projection
+    with its output already head-major and its weight contraction-minor, so
+    an ``[L, E, H x D]`` leaf was transposed whole before the layer loop,
+    every step, and a layer's slice written out of that copy again (13.5 %
+    of mimo's device time; PERF.md section 6, PR 48).  Head-split, the dot's
+    fusion takes the stacked leaf and the layer index and reads the bytes
+    once.  One order for all three leaves of every GQA stack.  The latent
+    block's ``wq_a`` / ``wq_b`` / ``wkv_*`` and the linear layers' ``wq`` /
+    ``wk`` / ``wv`` (another consumer: no head-major dot) are plain
+    matmuls; a quantised leaf's scales follow its leaf
+    (`quant.contraction_axis`).  Biases ``bq`` / ``bk`` / ``bv`` stay [L, H
+    x D]."""
     dtype = dtype or jnp.dtype(cfg.dtype)
     if cfg.latent and cfg.linear:
         return _init_latent_linear_params(cfg, key, dtype)
@@ -453,9 +484,9 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype: jnp.dtype | None = None
 
     layers: Params = {
         "attn_norm": jnp.ones((l, e), dtype),
-        "wq": w(next(keys), (l, e, qd)),
-        "wk": w(next(keys), (l, e, kvd)),
-        "wv": w(next(keys), (l, e, kvd)),
+        "wq": split_heads(w(next(keys), (l, e, qd)), cfg.num_heads),
+        "wk": split_heads(w(next(keys), (l, e, kvd)), cfg.num_kv_heads),
+        "wv": split_heads(w(next(keys), (l, e, kvd)), cfg.num_kv_heads),
         "wo": w(next(keys), (l, qd, e)),
         "mlp_norm": jnp.ones((l, e), dtype),
     }
@@ -502,11 +533,11 @@ def param_pspecs(cfg: ModelConfig, tp: int = 1) -> Params:
             f"model {cfg.name!r}: linear-attention layers and their state "
             "have no sharding rules (tensor / data / pipeline parallelism "
             "are not supported)")
-    kv = P(None, None, AXIS_MODEL) if shard_kv_heads(cfg, tp) else P(None, None, None)
+    kv = P(None, AXIS_MODEL if shard_kv_heads(cfg, tp) else None, None, None)
     kvb = P(None, AXIS_MODEL) if shard_kv_heads(cfg, tp) else P(None, None)
     layers: Params = {
         "attn_norm": P(None, None),
-        "wq": P(None, None, AXIS_MODEL),
+        "wq": P(None, AXIS_MODEL, None, None),
         "wk": kv,
         "wv": kv,
         "wo": P(None, AXIS_MODEL, None),
@@ -749,13 +780,15 @@ def _post_norm(y: jnp.ndarray, lp: Params, name: str,
 
 
 def _qkv(h: jnp.ndarray, lp: Params, cfg: ModelConfig):
-    q = qeinsum("...e,eq->...q", h, lp["wq"])
-    k = qeinsum("...e,ek->...k", h, lp["wk"])
-    v = qeinsum("...e,ek->...k", h, lp["wv"])
+    """Normed ``h`` [.., E] -> q [.., H, D], k [.., Hkv, D], v [.., Hkv,
+    Dv], the head counts the layer's leaves have (:func:`init_params`)."""
+    q = qeinsum("...e,hde->...hd", h, lp["wq"])
+    k = qeinsum("...e,hde->...hd", h, lp["wk"])
+    v = qeinsum("...e,hde->...hd", h, lp["wv"])
     if cfg.qkv_bias:
-        q = q + lp["bq"]
-        k = k + lp["bk"]
-        v = v + lp["bv"]
+        q = q + lp["bq"].reshape(q.shape[-2:])
+        k = k + lp["bk"].reshape(k.shape[-2:])
+        v = v + lp["bv"].reshape(v.shape[-2:])
     return q, k, v
 
 
@@ -764,12 +797,8 @@ def _block_qkv(h: jnp.ndarray, lp: Params, cfg: ModelConfig,
                positions: jnp.ndarray):
     """Pre-norm + qkv projection + head split + rope for a [B, T, E] block —
     shared by one-shot and chunked prefill so their math can never diverge."""
-    b, t = h.shape[:2]
     x = rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps)
     q, k, v = _qkv(x, lp, cfg)
-    q = q.reshape(b, t, cfg.num_heads, cfg.head_dim)
-    k = k.reshape(b, t, cfg.num_kv_heads, cfg.head_dim)
-    v = v.reshape(b, t, cfg.num_kv_heads, cfg.head_dim)
     q = apply_rope(q, positions, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_theta)
     return q, k, v
@@ -913,14 +942,9 @@ def _kind_qkv(h: jnp.ndarray, lp: Params, cfg: ModelConfig,
     normed input, or (``cfg.attn_out_gate``) the elementwise one [B, T, H,
     D] (None where the model has none).  ``cfg.use_rope`` False: no
     rotation of either kind."""
-    b, t = h.shape[:2]
-    heads, kv_heads = cfg.heads_of(window), cfg.kv_heads_of(window)
     with _scope("arks.attn_win_qkv" if window else "arks.attn_qkv"):
         x = rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps)
         q, k, v = _qkv(x, lp, cfg)
-        q = q.reshape(b, t, heads, cfg.head_dim)
-        k = k.reshape(b, t, kv_heads, cfg.head_dim)
-        v = v.reshape(b, t, kv_heads, cfg.value_dim)
         if cfg.attn_value_scale != 1.0:
             v = (v.astype(jnp.float32) * cfg.attn_value_scale).astype(v.dtype)
         if not cfg.use_rope:
@@ -949,7 +973,7 @@ def _kind_qkv(h: jnp.ndarray, lp: Params, cfg: ModelConfig,
         with _scope("arks.attn_gate"):
             gate = jax.nn.sigmoid(qeinsum(
                 "...e,eq->...q", x, lp["wg"]).astype(jnp.float32)).reshape(
-                    b, t, heads, cfg.head_dim)
+                    q.shape)
     return q, k, v, gate
 
 
@@ -1955,9 +1979,6 @@ def decode_step(
         with _scope("arks.attn_qkv"):
             x = rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps)
             q, k, v = _qkv(x, lp, cfg)
-            q = q.reshape(b, cfg.num_heads, cfg.head_dim)
-            k = k.reshape(b, cfg.num_kv_heads, cfg.head_dim)
-            v = v.reshape(b, cfg.num_kv_heads, cfg.head_dim)
             q = apply_rope(q, rope_idx, cfg.rope_theta)
             k = apply_rope(k, rope_idx, cfg.rope_theta)
         if paged:

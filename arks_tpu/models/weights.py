@@ -143,9 +143,15 @@ def mimo_v2_tree(cfg: ModelConfig, t: dict[str, np.ndarray],
                                        dtype),
                "mlp_norm": np.asarray(
                    t[base + "post_attention_layernorm.weight"], dtype)}
-        for leaf, name in (("wq", "q_proj"), ("wk", "k_proj"),
-                           ("wv", "v_proj"), ("wo", "o_proj")):
-            out[leaf] = mat(f"{base}self_attn.{name}.weight")
+        # q / k / v stay [out, in] as published, heads split: the stored
+        # order (tf.init_params).
+        for leaf, name, heads in (
+                ("wq", "q_proj", cfg.heads_of(window)),
+                ("wk", "k_proj", cfg.kv_heads_of(window)),
+                ("wv", "v_proj", cfg.kv_heads_of(window))):
+            w = np.asarray(t[f"{base}self_attn.{name}.weight"], dtype)
+            out[leaf] = w.reshape(heads, -1, w.shape[-1])
+        out["wo"] = mat(base + "self_attn.o_proj.weight")
         if cfg.sink_of(window):
             out["attn_sink"] = np.asarray(
                 t[base + "self_attn.attention_sink_bias"], dtype)
@@ -204,11 +210,18 @@ def params_from_hf(cfg: ModelConfig, path: str, dtype: Any = None,
     def stack(fmt: str, transpose: bool = False) -> np.ndarray:
         return _stack_layers(t, l, dtype, fmt, transpose)
 
+    def heads(fmt: str, n: int) -> np.ndarray:
+        # [out, in] as published, heads split: the stored order
+        # (tf.init_params).
+        return stack(fmt).reshape(l, n, cfg.head_dim, -1)
+
     layers: tf.Params = {
         "attn_norm": stack("model.layers.{}.input_layernorm.weight"),
-        "wq": stack("model.layers.{}.self_attn.q_proj.weight", True),
-        "wk": stack("model.layers.{}.self_attn.k_proj.weight", True),
-        "wv": stack("model.layers.{}.self_attn.v_proj.weight", True),
+        "wq": heads("model.layers.{}.self_attn.q_proj.weight", cfg.num_heads),
+        "wk": heads("model.layers.{}.self_attn.k_proj.weight",
+                    cfg.num_kv_heads),
+        "wv": heads("model.layers.{}.self_attn.v_proj.weight",
+                    cfg.num_kv_heads),
         "wo": stack("model.layers.{}.self_attn.o_proj.weight", True),
         "mlp_norm": stack("model.layers.{}.post_attention_layernorm.weight"),
     }
@@ -243,9 +256,9 @@ def _quantize_leaf(leaf, axis: int, bits: int = 8, shards: int = 1):
     x = jnp.asarray(leaf)
     # donate: the full-width device copy is freed as soon as the quantized
     # outputs exist, bounding the transient to one leaf.
-    if bits == 4 and axis == -2:  # matmul weights; the embedding stays int8
-        fn = jax.jit(functools.partial(quantize_tensor_int4, shards=shards),
-                     donate_argnums=(0,))
+    if bits == 4:
+        fn = jax.jit(functools.partial(quantize_tensor_int4, shards=shards,
+                                       axis=axis), donate_argnums=(0,))
     else:
         fn = jax.jit(functools.partial(quantize_tensor, axis=axis),
                      donate_argnums=(0,))
@@ -258,7 +271,7 @@ def _leaves_to_device(host_params: dict, bits: int,
     quantizing matmul leaves on arrival when requested (``bits`` =
     0 = no quantization | 8 | 4).  ``shards`` = mesh model-axis size
     (int4 groups align to shards)."""
-    from arks_tpu.models.quant import MATMUL_KEYS
+    from arks_tpu.models.quant import MATMUL_KEYS, contraction_axis
 
     def walk(sub: dict) -> dict:
         out = {}
@@ -266,9 +279,10 @@ def _leaves_to_device(host_params: dict, bits: int,
             if isinstance(leaf, dict):
                 out[name] = walk(leaf)
             elif bits and name == "embed":
-                out[name] = _quantize_leaf(leaf, -1, bits)
+                out[name] = _quantize_leaf(leaf, -1)  # int8 either way
             elif bits and name in MATMUL_KEYS:
-                out[name] = _quantize_leaf(leaf, -2, bits, shards)
+                out[name] = _quantize_leaf(
+                    leaf, contraction_axis(name, leaf.ndim), bits, shards)
             else:
                 out[name] = jnp.asarray(leaf)
         return out
@@ -346,6 +360,22 @@ def save_orbax(params: tf.Params, model_path: str) -> str:
     return path
 
 
+def check_restored_shapes(restored, template, path: str) -> None:
+    """Orbax hands back a leaf in the shape it was SAVED in, whatever the
+    template says: a checkpoint written with another stored order (the
+    q / k / v leaves were [L, E, H x D] until they became [L, H, D, E],
+    `tf.init_params`) is refused here, by leaf, and not by an einsum
+    deep in the first step."""
+    flat = jax.tree_util.tree_flatten_with_path(template)[0]
+    for (keys, want), got in zip(flat, jax.tree.leaves(restored)):
+        if tuple(got.shape) != tuple(want.shape):
+            raise ValueError(
+                f"checkpoint at {path}: leaf {jax.tree_util.keystr(keys)} "
+                f"has shape {tuple(got.shape)}, this build stores "
+                f"{tuple(want.shape)} (docs/model-usage.md, how the weight "
+                "tree is stored): convert the checkpoint again")
+
+
 def load_orbax(cfg: ModelConfig, model_path: str, mesh=None,
                dtype: Any = None, weight_dtype: str = "bf16") -> tf.Params:
     """Load an Orbax checkpoint, sharded directly to the mesh when given —
@@ -380,6 +410,7 @@ def load_orbax(cfg: ModelConfig, model_path: str, mesh=None,
             template)
     ckptr = ocp.StandardCheckpointer()
     params = ckptr.restore(path, template)
+    check_restored_shapes(params, template, path)
     if quantize:
         shards = mesh.shape.get(tf.AXIS_MODEL, 1) if mesh is not None else 1
         if mesh is not None:

@@ -247,9 +247,6 @@ def pp_decode_step_paged(
                 lp, layer = xs
                 x = tf.rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps)
                 q, k, v = tf._qkv(x, lp, cfg)
-                q = q.reshape(mbs, cfg.num_heads, cfg.head_dim)
-                k = k.reshape(mbs, cfg.num_kv_heads, cfg.head_dim)
-                v = v.reshape(mbs, cfg.num_kv_heads, cfg.head_dim)
                 q = tf.apply_rope(q, rope_idx, cfg.rope_theta)
                 k = tf.apply_rope(k, rope_idx, cfg.rope_theta)
                 # XLA impl for the same reason as the slot pp path: tiny
@@ -376,9 +373,6 @@ def pp_decode_step(
                 lp, layer = xs
                 x = tf.rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps)
                 q, k, v = tf._qkv(x, lp, cfg)
-                q = q.reshape(mbs, cfg.num_heads, cfg.head_dim)
-                k = k.reshape(mbs, cfg.num_kv_heads, cfg.head_dim)
-                v = v.reshape(mbs, cfg.num_kv_heads, cfg.head_dim)
                 q = tf.apply_rope(q, write_idx, cfg.rope_theta)
                 k = tf.apply_rope(k, write_idx, cfg.rope_theta)
                 attn, kc, vc, ks, vs = decode_update_and_attend(

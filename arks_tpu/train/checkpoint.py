@@ -139,4 +139,8 @@ def restore_train_state(manager, cfg, optimizer, mesh=None,
                 s.shape, s.dtype,
                 sharding=jax.sharding.SingleDeviceSharding(dev)),
             abstract)
-    return manager.restore(step, args=ocp.args.StandardRestore(template))
+    state = manager.restore(step, args=ocp.args.StandardRestore(template))
+    from arks_tpu.models.weights import check_restored_shapes
+    check_restored_shapes(state, template,
+                          os.path.join(str(manager.directory), str(step)))
+    return state

@@ -481,6 +481,26 @@ def _share_step(chip, monkeypatch, name: str, rows: int):
     return _STEPS[name, rows]
 
 
+def _unfused_s8(text: str) -> list:
+    """``(dims, line)`` of every int8 array a compiled program defines as
+    the result of a ``copy`` or a ``fusion`` OUTSIDE its fused computations
+    (those a ``fusion(... calls=%name)`` names): a buffer the step writes."""
+    import re
+    fused = set(re.findall(r"fusion\([^\n]*calls=%([\w.\-]+)", text))
+    made = re.compile(r"= s8\[([\d,]+)\]\S* (?:fusion|copy)\(")
+    found, comp = [], None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if head:
+            comp = head.group(1)
+        elif comp not in fused:
+            m = made.search(line)
+            if m:
+                found.append((tuple(map(int, m.group(1).split(","))),
+                              line.strip()[:120]))
+    return found
+
+
 @pytest.mark.parametrize("name,rows,temp_mb", [
     ("kimi-k2.5-ep32-l9", 1024, 260), ("laguna-s-2.1-ep8", 1024, 480),
     ("solar-open2-250b-ep8-l8", 256, 220),
@@ -499,23 +519,85 @@ def test_a_shares_chunk_step_copies_no_expert_leaf_out_of_its_stack(
     210, gigachat 3 x 470: whole steps of 578 / 705 / 734 / 1618 MB of
     temporaries, 211 / 419 / 167 / 302 now; laguna's are a 340 MB copy of
     its window layers' fused qkv stack, once a step and not this test's)."""
-    import re
     cfg, _, compiled = _share_step(chip, monkeypatch, name, rows)
     text = compiled.as_text()
-    fused = set(re.findall(r"fusion\([^\n]*calls=%([\w.\-]+)", text))
     x, e, f = cfg.num_experts, cfg.hidden_size, cfg.moe_intermediate_size
-    leaf = re.compile(r"= s8\[(?:1,)?%d,(?:%d,%d|%d,%d)\]\S* (fusion|copy)\("
-                      % (x, e, f, f, e))
-    found, comp = [], None
-    for line in text.splitlines():
-        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
-        if head:
-            comp = head.group(1)
-        elif comp not in fused and leaf.search(line):
-            found.append(line.strip()[:120])
+    found = [line for dims, line in _unfused_s8(text)
+             if dims[-3:] in ((x, e, f), (x, f, e))
+             and dims[:-3] in ((), (1,))]
     assert not found, found
     assert "while" in text
     assert compiled.memory_analysis().temp_size_in_bytes < temp_mb * 1e6
+
+
+@pytest.mark.parametrize("name,rows,temp_mb", [
+    ("mimo-v2.5-ep16-l13", 0, 30), ("mimo-v2.5-ep16-l13", 1024, 260),
+    ("laguna-s-2.1-ep8", 1024, 120),
+    ("solar-open2-250b-ep8-l8", 256, 220)])
+def test_a_step_writes_no_buffer_of_a_qkv_leaf(chip, monkeypatch, name, rows,
+                                               temp_mb):
+    """The step programs this module compiles of the configurations with
+    a GQA stack, and mimo's 64-row one (laguna's 32-row program reads the
+    same, 360 -> 11 MB of temporaries: PERF.md section 6, PR 48; left out
+    for the gate's time), at their published widths: outside its fusions the compiled program defines NO
+    int8 buffer of a whole stacked ``wq`` / ``wk`` / ``wv`` leaf and none of
+    one layer's ``wq``, in any shape or layout (by element count: the
+    compiler hands the same bytes on as ``s8[10,4096,12288]``, ``s8[1,
+    4096,12288]`` or ``s8[64,192,4096]``).  The leaves are stored as the
+    dots read them, ``[L, H, D, E]`` (``tf.init_params``).  Stored ``[L, E,
+    H x D]`` (until PR 48) every program transposed the stacked leaves whole
+    in ``main``, ahead of every loop (mimo ``copy s8[10,4096,12288]{1,2,0}``
+    503 MB + 63 + 50, laguna 340 + 38, solar 2 x 34) and wrote a layer's
+    slice out of the copy again inside the inner scan (mimo 50 MB x 10,
+    laguna 28 x 12), or sliced and then transposed a layer (the full
+    layers' scan; qwen's and mixtral's dense scan): 13.5 % of mimo's device
+    time, temporaries of 675 / 751 MB (mimo) and 360 / 419 (laguna) where
+    9 / 185 and 11 / 45 are left.  A layer's ``wk`` / ``wv`` (a few MB) is
+    let be: none is written today either."""
+    import math
+    from arks_tpu.models import quant, transformer as tf
+    cfg, _, compiled = _share_step(chip, monkeypatch, name, rows)
+    shapes = jax.eval_shape(lambda k: tf.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    sizes = {}
+    for stack, tree in shapes.items():
+        if not isinstance(tree, dict):
+            continue
+        for leaf in sorted(quant.HEAD_SPLIT_KEYS & set(tree)):
+            if tree[leaf].ndim != 4:        # a linear layer's: plain matmul
+                continue
+            sizes[math.prod(tree[leaf].shape)] = f"{stack}/{leaf}"
+            if leaf == "wq":
+                sizes[math.prod(tree[leaf].shape[1:])] = f"a layer of {stack}/wq"
+    assert sizes
+    found = [(sizes[math.prod(dims)], line)
+             for dims, line in _unfused_s8(compiled.as_text())
+             if math.prod(dims) in sizes]
+    assert not found, found
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_mb * 1e6
+
+
+def test_the_scan_for_written_buffers_sees_the_copies_it_is_there_for():
+    """``_unfused_s8`` on lines of the parent's compiled mimo step (PR 47):
+    the whole-leaf transpose in ``main`` and the slice in the inner scan's
+    body are found, what a fused computation defines is not."""
+    text = "\n".join([
+        "%fused_computation.174 (p0: s8[10,4096,12288]) -> s8[1,4096,12288] {",
+        "  %ds.1 = s8[1,4096,12288]{1,2,0:T(8,128)(4,1)} fusion(%p0), "
+        "kind=kLoop, calls=%inner",
+        "}",
+        "%body (p: (s32[], s8[10,4096,12288])) -> (s32[]) {",
+        "  %constant_dynamic-slice_fusion.28 = s8[1,4096,12288]{1,2,0:"
+        "T(8,128)(4,1)} fusion(%gte.1, %sel), kind=kLoop, "
+        "calls=%fused_computation.174",
+        "  %bitcast.764 = s8[64,192,4096]{2,1,0} bitcast(%x)",
+        "}",
+        "ENTRY %main.98 (p0: s8[10,4096,12288]) -> f32[64,19072] {",
+        "  %copy.534 = s8[10,4096,12288]{1,2,0:T(8,128)(4,1)} copy(%p0)",
+        "  %copy-done.1 = s8[1,4,192,4096]{3,2,1,0} copy-done(%cs)",
+        "}"])
+    assert [dims for dims, _ in _unfused_s8(text)] == [
+        (1, 4096, 12288), (10, 4096, 12288)]
 
 
 @pytest.mark.parametrize("rows", [0, 1024])

@@ -7,6 +7,8 @@ TPU-native mechanisms that fit 7B-class (int8) and 13B-class (int4) models
 on one 16GB v5e chip (BASELINE.md north-star config).
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -224,3 +226,156 @@ def test_engine_weight_dtype_int4():
         ids.extend(out.token_ids)
     assert len(ids) == 4
     assert all(0 <= t < cfg.vocab_size for t in ids)
+
+
+# ---------------------------------------------------------------------------
+# The stored order of the GQA blocks' q / k / v leaves (PR 48)
+# ---------------------------------------------------------------------------
+
+def _flat(tree, pre=""):
+    for k, v in tree.items():
+        if isinstance(v, dict) and not quant.is_quantized(v):
+            yield from _flat(v, pre + k + "/")
+        else:
+            yield pre + k, v
+
+
+@functools.partial(jax.jit, static_argnames=("shape", "bits"))
+def _drawn(key, shape, bits):
+    """A matmul leaf as a seed means it (one program, as the generator's:
+    XLA divides by a constant its own way)."""
+    w = (jax.random.normal(key, shape, jnp.float32) * 0.02).astype(
+        jnp.bfloat16)
+    return (quant.quantize_tensor_int4(w) if bits == 4
+            else quant.quantize_tensor(w, axis=-2))
+
+
+# (preset, bits, stacks with head-split q / k / v, stacks whose q / k / v
+# are plain matmuls).  The whole of every family's tree against its
+# reference, in the drawn order: tests/test_benchmark_contract.py.
+@pytest.mark.parametrize("name,bits,split,plain", [
+    ("tiny", 8, ["layers"], []), ("tiny", 4, ["layers"], []),
+    ("tiny-swa-sink-moe", 8, ["dense_layers", "layers", "win_layers"], []),
+    ("tiny-linear-moe", 8, ["head_layers", "layers"], ["lin_layers"]),
+])
+def test_seeded_leaves_are_the_drawn_ones_stored_head_split(name, bits,
+                                                            split, plain):
+    """``init_params_quantized`` against the rule a seed means, leaf for
+    leaf over the attention projections: leaf ``n`` of the tree (depth
+    first, a counter from 1) is drawn ``normal(fold_in(key, n), [L, E, H x
+    D]) * 0.02`` in the LOGICAL shape, rounded to bfloat16 and quantised
+    along ``E``; a GQA stack's q / k / v are that leaf's ``split_heads``
+    (values AND scales), a linear layer's q / k / v and every ``wo`` the
+    draw itself."""
+    cfg = get_config(name)
+    key = jax.random.PRNGKey(2**31 + 48)
+    got = dict(_flat(quant.init_params_quantized(cfg, key, jnp.bfloat16,
+                                                 bits=bits)))
+    seen = set()
+    for n, (path, leaf) in enumerate(got.items(), 1):
+        stack, _, leafname = path.rpartition("/")
+        if leafname not in ("wq", "wk", "wv", "wo"):
+            continue
+        is_split = leafname != "wo" and stack in split
+        assert is_split or leafname == "wo" or stack in plain, path
+        seen.add(stack)
+        shape = leaf["q"].shape
+        if is_split:
+            l, h, d, e = shape
+            assert e == cfg.hidden_size
+            want = {k: tf.split_heads(v, h) for k, v in _drawn(
+                jax.random.fold_in(key, n), (l, e, h * d), bits).items()}
+        else:
+            assert len(shape) == 3
+            want = _drawn(jax.random.fold_in(key, n), shape, bits)
+        assert sorted(leaf) == sorted(want)
+        for k in want:
+            assert leaf[k].shape == want[k].shape, (path, k)
+            assert np.array_equal(np.asarray(leaf[k].astype(jnp.float32)),
+                                  np.asarray(want[k].astype(jnp.float32))), (
+                path, k)
+    assert seen == set(split + plain)
+
+
+@pytest.mark.parametrize("bits", [8, 4])
+def test_quantize_params_of_a_float_tree_agrees_with_the_stored_order(bits):
+    """A float tree (its q / k / v already stored head-split) quantised
+    leaf by leaf is the quantised LOGICAL leaf stored head-split: the scale
+    an output channel (the groups along ``E``) is the same number either
+    way.  The names alone do not decide: a linear layer's ``wq`` is a plain
+    matmul."""
+    keys = iter(jax.random.split(jax.random.PRNGKey(3), 8))
+    logical = {"wq": jax.random.normal(next(keys), (2, 256, 4 * 8)),
+               "wk": jax.random.normal(next(keys), (2, 256, 2 * 8))}
+    full = {"layers": {"wq": tf.split_heads(logical["wq"], 4),
+                       "wk": tf.split_heads(logical["wk"], 2),
+                       "wo": jax.random.normal(next(keys), (2, 32, 256))},
+            "lin_layers": {"wq": jax.random.normal(next(keys), (2, 256, 32))}}
+    got = quant.quantize_params(full, bits=bits)
+
+    def rule(w):
+        return (quant.quantize_tensor_int4(w) if bits == 4
+                else quant.quantize_tensor(w, axis=-2))
+
+    want = {"layers": {"wq": {k: tf.split_heads(v, 4)
+                              for k, v in rule(logical["wq"]).items()},
+                       "wk": {k: tf.split_heads(v, 2)
+                              for k, v in rule(logical["wk"]).items()},
+                       "wo": rule(full["layers"]["wo"])},
+            "lin_layers": {"wq": rule(full["lin_layers"]["wq"])}}
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    assert got["layers"]["wq"]["gs" if bits == 4 else "s"].shape == (
+        (2, 4, 8, 2) if bits == 4 else (2, 4, 8, 1))
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert a.shape == b.shape
+        assert np.array_equal(np.asarray(a, np.float32),
+                              np.asarray(b, np.float32))
+
+
+@pytest.mark.parametrize("kind", ["float", "int8", "int4"])
+def test_the_projection_reads_a_head_split_leaf_as_the_matmul_it_is(kind):
+    """``_qkv`` on the stored leaves is ``x W`` of the logical ``[E, H x
+    D]`` matrices split into heads, biases and all; a quantised leaf's
+    ``dequantize`` is the leaf in its own shape."""
+    cfg = get_config("tiny")            # qkv biases
+    params = tf.init_params(cfg, jax.random.PRNGKey(5), jnp.float32)
+    lp = {k: v[1] for k, v in params["layers"].items()}
+    for b in ("bq", "bk", "bv"):
+        lp[b] = jnp.linspace(-1, 1, lp[b].size, dtype=jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(6), (2, 5, cfg.hidden_size))
+    ref = lp
+    if kind != "float":
+        bits = int(kind[3:])
+        lp = dict(lp, **quant.quantize_params(
+            {k: params["layers"][k] for k in ("wq", "wk", "wv")}, bits=bits))
+        lp = {k: (jax.tree.map(lambda a: a[1], v)
+                  if k in ("wq", "wk", "wv") else v) for k, v in lp.items()}
+        for k in ("wq", "wk", "wv"):
+            deq = quant.dequantize(lp[k], jnp.float32)
+            assert deq.shape == ref[k].shape
+            assert _rel_err(deq, ref[k]) < (0.15 if bits == 4 else 0.01)
+        ref = dict(ref, **{k: quant.dequantize(lp[k], jnp.float32)
+                           for k in ("wq", "wk", "wv")})
+    for got, w, b, heads in zip(
+            tf._qkv(x, lp, cfg), ("wq", "wk", "wv"), ("bq", "bk", "bv"),
+            (cfg.num_heads, cfg.num_kv_heads, cfg.num_kv_heads)):
+        logical = ref[w].reshape(-1, cfg.hidden_size).T      # [E, H x D]
+        want = (x @ logical + ref[b]).reshape(2, 5, heads, cfg.head_dim)
+        assert got.shape == want.shape
+        assert _rel_err(got, want) < 1e-5
+
+
+def test_the_partition_specs_shard_the_heads_of_a_head_split_leaf():
+    from jax.sharding import PartitionSpec as P
+    cfg = get_config("tiny-gqa")
+    specs = tf.param_pspecs(cfg, tp=2)["layers"]
+    assert specs["wq"] == P(None, "model", None, None)
+    assert specs["wk"] == specs["wv"] == P(None, "model", None, None)
+    assert tf.param_pspecs(cfg, tp=8)["layers"]["wk"] == P(
+        None, None, None, None)                   # 2 KV heads: replicated
+    for bits, scale in ((8, "s"), (4, "gs")):
+        q = quant.quantize_pspecs({"layers": specs}, bits)["layers"]
+        assert q["wq"][scale] == q["wq"]["q"] == specs["wq"]
+        assert q["wo"]["q"] == P(None, "model", None)
+    assert quant.quantize_pspecs({"layers": specs}, 8)["layers"]["wo"][
+        "s"] == P(None, None, None)               # [L, 1, E]

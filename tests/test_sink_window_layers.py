@@ -189,9 +189,17 @@ def test_a_checkpoint_raises_by_name_and_the_mapping_reads_seeded_leaves(
         base = f"model.layers.{i}."
         t[base + "input_layernorm.weight"] = lp["attn_norm"]
         t[base + "post_attention_layernorm.weight"] = lp["mlp_norm"]
+        # q / k / v are stored [H, D, E]: the published [out, in] matrix
+        # with its rows split into heads, so x W^T is the step's dot.
         for leaf, hf in (("wq", "q_proj"), ("wk", "k_proj"),
-                         ("wv", "v_proj"), ("wo", "o_proj")):
-            t[f"{base}self_attn.{hf}.weight"] = lp[leaf].T
+                         ("wv", "v_proj")):
+            w = lp[leaf].reshape(-1, lp[leaf].shape[-1])
+            t[f"{base}self_attn.{hf}.weight"] = w
+            x = np.linspace(-1, 1, w.shape[1], dtype=np.float32)
+            assert np.allclose(
+                (x @ w.T).reshape(lp[leaf].shape[:2]),
+                np.einsum("e,hde->hd", x, lp[leaf]), atol=1e-5)
+        t[base + "self_attn.o_proj.weight"] = lp["wo"].T
         if "attn_sink" in lp:
             t[base + "self_attn.attention_sink_bias"] = lp["attn_sink"]
         ffn = (("w_gate", "gate_proj"), ("w_up", "up_proj"),
@@ -255,12 +263,13 @@ def test_the_tree_has_a_stack_a_kind_with_its_own_projections():
     cfg = get_config("tiny-swa-sink-moe")
     p = jax.eval_shape(lambda k: tf.init_params(cfg, k),
                        jax.random.PRNGKey(0))
-    assert p["dense_layers"]["wq"].shape == (1, 64, 8 * 24)
+    # q / k / v head-split, the contraction dimension minor (init_params).
+    assert p["dense_layers"]["wq"].shape == (1, 8, 24, 64)
     # The cut-short period's full layer stands ahead of the periods'.
-    assert p["layers"]["wk"].shape == (3, 64, 2 * 24)
-    assert p["layers"]["wv"].shape == (3, 64, 2 * 16)
-    assert p["win_layers"]["wk"].shape == (5, 64, 4 * 24)
-    assert p["win_layers"]["wv"].shape == (5, 64, 4 * 16)
+    assert p["layers"]["wk"].shape == (3, 2, 24, 64)
+    assert p["layers"]["wv"].shape == (3, 2, 16, 64)
+    assert p["win_layers"]["wk"].shape == (5, 4, 24, 64)
+    assert p["win_layers"]["wv"].shape == (5, 4, 16, 64)
     assert p["win_layers"]["wo"].shape == (5, 8 * 16, 64)
     assert p["win_layers"]["attn_sink"].shape == (5, 8)
     assert "attn_sink" not in p["layers"] and "attn_sink" not in \

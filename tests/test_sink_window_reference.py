@@ -17,10 +17,13 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 # done, as in the file this one stands beside.
 from test_benchmark_contract import (  # noqa: E402,F401
     _registry_and_environment_restored,
+    seeded_tree_as_drawn,
+)
+from benchmarks.tests import (  # noqa: E402
+    test_reference_swa_sink_moe as _swa_sink_moe,
 )
 from benchmarks.tests.test_reference_swa_sink_moe import (  # noqa: E402,F401
     sink_served,
-    test_seeded_weights_are_the_programs_bit_for_bit,
     test_served_logprobs_against_the_reference,
     test_the_family_keeps_the_contract_and_imports_nothing_of_the_program,
     test_the_lower_precision_controls_fail as _controls,
@@ -33,3 +36,7 @@ from benchmarks.tests.test_reference_swa_sink_moe import (  # noqa: E402,F401
 
 def test_the_int4_page_control_fails():
     _controls("kv_int4")
+
+
+def test_seeded_weights_are_the_programs_bit_for_bit(seeded_tree_as_drawn):
+    _swa_sink_moe.test_seeded_weights_are_the_programs_bit_for_bit()

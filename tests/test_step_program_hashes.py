@@ -132,6 +132,20 @@ gained branches that the configuration decides while the program is traced
 ``ops/attention.py::_softmax`` took a sink argument; with none of them set
 every older preset lowers the text it lowered, ``tiny-swa-moe``'s eight
 programs included.
+
+PR 48 RE-PINNED the twenty-four programs of the five presets WITH a GQA
+stack (``tiny``, ``tiny-mixtral``, ``tiny-swa-moe`` at both shapes,
+``tiny-swa-sink-moe``, ``tiny-linear-moe`` at both shapes: its two GQA
+layers) and moved none of the ten others (``tiny-mla-moe`` at both shapes,
+``tiny-latent-linear-moe``: latent and linear projections only) nor the
+whole-layer pin.  The q / k / v leaves of a GQA stack are stored ``[L, H,
+D, E]`` (heads split, the contraction dimension minor: what the chip's
+step programs read, ``tf.init_params``) where they were ``[L, E, H x D]``,
+so ``_qkv`` contracts ``"...e,hde->...hd"`` and its callers' reshapes are
+gone: every program that projects q / k / v takes other operands and lowers
+another dot, as it should; the numbers are the parent's (the seeded leaves
+are its leaves transposed, ``tests/test_quant.py``; the served
+log-probabilities hold to every reference as before).
 """
 
 import hashlib
@@ -146,40 +160,40 @@ from arks_tpu.engine.tokenizer import ByteTokenizer
 from arks_tpu.models import get_config, moe
 
 PINS = {
-    "tiny.seq": "ed53d9ecb362f937",
-    "tiny.seq_lp": "31d2702fc1c7b6af",
-    "tiny.pipe": "a6579a2a5236124a",
-    "tiny.pipe_lp": "d059fb1f2834e04b",
+    "tiny.seq": "0c278ada45cabbd2",
+    "tiny.seq_lp": "491d011c67699524",
+    "tiny.pipe": "4e1c1288c0815891",
+    "tiny.pipe_lp": "51ca9446ab2834d8",
     "tiny-mla-moe.seq": "b38c58786d908443",
     "tiny-mla-moe.seq_lp": "4e0d054764e1599f",
     "tiny-mla-moe.pipe": "85a6cd2125ffb229",
     "tiny-mla-moe.pipe_lp": "ff217f97a370ca70",
-    "tiny-swa-moe.seq": "656c1440df89f528",
-    "tiny-swa-moe.seq_lp": "bf47ab082f6494f1",
-    "tiny-swa-moe.pipe": "b816b56c164ad306",
-    "tiny-swa-moe.pipe_lp": "6b850b4575db179d",
+    "tiny-swa-moe.seq": "a74550b8ca32440a",
+    "tiny-swa-moe.seq_lp": "dcd6bb16baa882d2",
+    "tiny-swa-moe.pipe": "a5acbcd901fe6084",
+    "tiny-swa-moe.pipe_lp": "fab7b8e5752c34a9",
     "tiny-mla-moe@wide.seq": "bba1b61e2b1fa6a6",
     "tiny-mla-moe@wide.seq_lp": "605c9e49c7d4e5ab",
-    "tiny-swa-moe@wide.seq": "72817726151c441f",
-    "tiny-swa-moe@wide.seq_lp": "b5e4558e99013cef",
-    "tiny-linear-moe.seq": "25c3b65a31869dbe",
-    "tiny-linear-moe.seq_lp": "f91e0295929aed93",
-    "tiny-linear-moe.pipe": "dd83bf5075eb475f",
-    "tiny-linear-moe.pipe_lp": "66007edafc0a5a68",
-    "tiny-linear-moe@wide.seq": "75c03053b4c8f947",
-    "tiny-linear-moe@wide.seq_lp": "a576efe531063750",
-    "tiny-mixtral.seq": "22ac0aa9bceb712b",
-    "tiny-mixtral.seq_lp": "6b8dc4bb07366585",
-    "tiny-mixtral.pipe": "c0b97eb0fc943682",
-    "tiny-mixtral.pipe_lp": "1a19199bb5ca75a5",
+    "tiny-swa-moe@wide.seq": "649deb3ae377df58",
+    "tiny-swa-moe@wide.seq_lp": "4496a82922472d4b",
+    "tiny-linear-moe.seq": "89d61e6062a43366",
+    "tiny-linear-moe.seq_lp": "ff1f7609a933d0f2",
+    "tiny-linear-moe.pipe": "751a7238e2d7bee6",
+    "tiny-linear-moe.pipe_lp": "383c201b8f57bd2c",
+    "tiny-linear-moe@wide.seq": "2bd75c31061e26d8",
+    "tiny-linear-moe@wide.seq_lp": "dbd12882c4040a2d",
+    "tiny-mixtral.seq": "3c2050866b3daf71",
+    "tiny-mixtral.seq_lp": "a7fd1b110fab0779",
+    "tiny-mixtral.pipe": "5e7345d839d5dd71",
+    "tiny-mixtral.pipe_lp": "76e8e6dcde5eb955",
     "tiny-latent-linear-moe.seq": "5976443c5b63dce0",
     "tiny-latent-linear-moe.seq_lp": "0c7d6b61622cde62",
     "tiny-latent-linear-moe.pipe": "8b8e6456119c2122",
     "tiny-latent-linear-moe.pipe_lp": "6d5c51de4ebb50c8",
-    "tiny-swa-sink-moe.seq": "8e94d0fd90b06a82",
-    "tiny-swa-sink-moe.seq_lp": "8435f8a7278fcd06",
-    "tiny-swa-sink-moe.pipe": "9dc16b7f9648b7d1",
-    "tiny-swa-sink-moe.pipe_lp": "ff7fc0011206caf6",
+    "tiny-swa-sink-moe.seq": "cab321671b8a404a",
+    "tiny-swa-sink-moe.seq_lp": "7db0abc59856f3c6",
+    "tiny-swa-sink-moe.pipe": "ad254d3741195bb9",
+    "tiny-swa-sink-moe.pipe_lp": "7616bbc242b77010",
 }
 
 

@@ -84,6 +84,46 @@ def test_params_from_hf_shapes_and_forward(tmp_path, name):
     assert np.isfinite(np.asarray(logits)).all()
 
 
+@pytest.mark.parametrize("name", ["tiny", "tiny-mixtral"])
+def test_params_from_hf_keeps_q_k_v_as_published_heads_split(tmp_path, name):
+    """A published ``[out, in]`` projection IS the stored order but for the
+    head split: no transpose on the way in, the published matrix back by a
+    reshape, and the step's dot is ``x W^T`` (``wo`` and the FFN leaves are
+    ``[in, out]``, transposed as they always were)."""
+    cfg = get_config(name)
+    t = _rng_tensors(cfg)
+    save_file(t, str(tmp_path / "model.safetensors"))
+    layers = w.params_from_hf(cfg, str(tmp_path), jnp.float32)["layers"]
+    x = np.linspace(-1, 1, cfg.hidden_size, dtype=np.float32)
+    for leaf, hf, heads in (("wq", "q_proj", cfg.num_heads),
+                            ("wk", "k_proj", cfg.num_kv_heads),
+                            ("wv", "v_proj", cfg.num_kv_heads)):
+        got = np.asarray(layers[leaf])
+        assert got.shape == (cfg.num_layers, heads, cfg.head_dim,
+                             cfg.hidden_size)
+        for i in range(cfg.num_layers):
+            pub = t[f"model.layers.{i}.self_attn.{hf}.weight"]
+            assert np.array_equal(got[i].reshape(pub.shape), pub)
+            assert np.allclose(np.einsum("e,hde->hd", x, got[i]).ravel(),
+                               x @ pub.T, atol=1e-4)
+    assert np.array_equal(np.asarray(layers["wo"][0]),
+                          t["model.layers.0.self_attn.o_proj.weight"].T)
+
+
+def test_an_orbax_checkpoint_in_another_stored_order_is_refused_by_leaf(
+        tmp_path):
+    """Orbax restores a leaf in the shape it was saved in, whatever the
+    template: a tree saved with ``[L, E, H x D]`` projections is named and
+    refused instead of failing in the first step's einsum."""
+    cfg = get_config("tiny-gqa")
+    params = tf.init_params(cfg, jax.random.PRNGKey(3), jnp.float32)
+    old = dict(params, layers=dict(params["layers"], wq=params["layers"][
+        "wq"].reshape(cfg.num_layers, cfg.q_dim, -1).swapaxes(-1, -2)))
+    w.save_orbax(old, str(tmp_path))
+    with pytest.raises(ValueError, match=r"wq.*convert the checkpoint"):
+        w.load_orbax(cfg, str(tmp_path), None, jnp.float32)
+
+
 def test_orbax_roundtrip_sharded(tmp_path):
     cfg = get_config("tiny-gqa")
     params = tf.init_params(cfg, jax.random.PRNGKey(3), jnp.float32)
