@@ -45,6 +45,7 @@ from arks_tpu.engine.tokenizer import Tokenizer
 from arks_tpu.engine.types import (PrefilledState, Request, RequestOutput,
                                    SamplingParams)
 from arks_tpu.models.config import ModelConfig
+from arks_tpu.models import moe as moe_mod
 from arks_tpu.models import transformer as tf
 from arks_tpu.obs import logctx
 from arks_tpu.obs import profiler as prof_mod
@@ -716,6 +717,16 @@ class EngineMetrics:
             "Overflow tiles of a share's routed layers in mixed dispatches "
             "(needed: rows beyond an expert's fixed batch, in tiles; extra: "
             "those beyond the spare tiles, run in a loop)")
+        # The rows those layers put through their experts' contractions
+        # (models/moe.py::share_rows: an expert's batch and the spare
+        # tiles, the loop's trips, or every row for every held expert in
+        # the dense dispatch): moe_held_pairs_total over it is how full the
+        # experts' batches ran.
+        self.moe_batch_rows_total = r.counter(
+            "moe_batch_rows_total",
+            "Rows a share's routed layers computed an expert contraction "
+            "for in mixed dispatches (batches, spare tiles and the loop's "
+            "tiles; rows x held experts under the dense dispatch)")
         # Query rows one mixed dispatch lays out for the attention kernel
         # (the plan's nb x block_q under the ragged grid's block-compacted
         # layout; lanes x the padded widest chunk under the dense grid), to
@@ -8389,7 +8400,7 @@ class InferenceEngine:
         t_wait = time.monotonic()
         toks = np.asarray(toks)  # host sync point (async copy usually done)
         if self._held_stat:
-            self._count_held(toks[0])
+            self._count_held(toks[0], self.ecfg.num_slots)
         counts = None if counts_dev is None else np.asarray(counts_dev)
         if lp_devs is not None:
             clps = np.asarray(lp_devs[0])    # [K, B]
@@ -8815,13 +8826,14 @@ class InferenceEngine:
             self._alloc.num_pages - self._alloc.free_pages, kind="full")
         self.metrics.kv_pages_reserved.set(sum(self._pool_reserved.values()))
 
-    def _count_held(self, ids: np.ndarray) -> None:
+    def _count_held(self, ids: np.ndarray, n_rows: int) -> None:
         """A latent routed model's step hands back four counts behind its
         token ids (the last four entries: (token, expert) pairs that landed
         on experts held here, the overflow tiles its layers' batched
         dispatch needed, those of them beyond the spare ones, and the rows
         that carried a token): the counters of docs/monitoring.md, from
-        values already on the host."""
+        values already on the host.  ``n_rows``: the rows of the step's
+        program, by which a share's dispatch sizes its batches."""
         held, needed, extra, rows = (int(v) for v in ids[-4:])
         cfg = self.cfg
         self.metrics.mixed_latent_rows_total.inc(
@@ -8832,6 +8844,9 @@ class InferenceEngine:
         if cfg.expert_parallel_size > 1:
             self.metrics.moe_overflow_tiles_total.inc(needed, kind="needed")
             self.metrics.moe_overflow_tiles_total.inc(extra, kind="extra")
+            fixed, a_tile = moe_mod.share_rows(n_rows, cfg)
+            self.metrics.moe_batch_rows_total.inc(
+                fixed * cfg.num_routed_layers + extra * a_tile)
 
     def _block_preflight(self, cfg: ModelConfig, ecfg: "EngineConfig",
                          draft_cfg) -> None:
@@ -9113,7 +9128,7 @@ class InferenceEngine:
                 "arks_mixed_seq_lp" if want_lp else "arks_mixed_seq")
         self._flush_deferred(tag + "deliver")
         return (dec_slots, completing, chunk_take, want_lp, ids_dev,
-                lp_devs, t0)
+                lp_devs, t0, self.ecfg.num_slots + budget)
 
     @_scoped("mixed")
     def _resolve_mixed(self, rec, exclude_s: float = 0.0) -> None:
@@ -9123,7 +9138,7 @@ class InferenceEngine:
         same tail as the legacy final chunk, minus its extra sample_one
         dispatch)."""
         (dec_slots, completing, chunk_take, want_lp, ids_dev,
-         lp_devs, t0) = rec
+         lp_devs, t0, n_rows) = rec
         self._faults.fire("resolve")
         tag = "phase.mixed."
         sec = self.profiler.sections
@@ -9134,7 +9149,7 @@ class InferenceEngine:
         ids = np.asarray(ids_dev)   # [B] — host sync point
         self.step_clock.waited(t_wait, time.monotonic(), 0)
         if self._held_stat:
-            self._count_held(ids)
+            self._count_held(ids, n_rows)
         if lp_devs is not None:
             clps = np.asarray(lp_devs[0])
             lvals = np.asarray(lp_devs[1])

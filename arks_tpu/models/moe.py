@@ -182,26 +182,55 @@ def held_first(cfg) -> int:
 
 def _held_capacity(n_tokens: int, cfg) -> int:
     """Rows each held expert takes in the batched dispatch, in tiles of
-    128, at most every token.  A share: four times what a uniform router
-    sends an expert (its experts draw few rows of many).  A layer held
-    whole: one and a half times (every pair lands here, the batch is what
-    the step computes, and at 8 experts top-2 four times would be every
-    token: the dense dispatch).  What an expert draws beyond them goes
-    through :func:`_batched_dispatch`'s overflow tiles: nothing is ever
-    dropped."""
+    128, at most every token.  A share: the fewest tiles that hold THREE
+    times what a uniform router sends an expert, so that the batch follows
+    what lands (its experts draw few rows of many).  At the benchmark's
+    whole-budget steps, 34-42 fair rows of ~1,060, that is ONE tile; four
+    times was rounded up to two, and the seeded routers put 13 % (laguna)
+    and 11 % (gigachat) of those rows to use where they now fill 27 and
+    22 % (PERF.md section 6, PR 49: laguna's step 90.6 -> 72.6 ms, of it
+    the batch's scatter-add 13.9 -> 7.3 and its three dots 13.8 -> 7.6);
+    the price is overflow tiles, 2.0-2.9 a layer in laguna's windows where
+    0.2-0.5 were needed, each a 32nd of the batch.  A layer held whole: one
+    and a half times (every pair lands here, the batch is what the step
+    computes, and at 8 experts top-2 four times would be every token: the
+    dense dispatch).  What an expert draws beyond them goes through
+    :func:`_batched_dispatch`'s overflow tiles: nothing is ever dropped."""
     fair = -(-n_tokens * cfg.num_experts_per_tok // cfg.router_width)
     if cfg.expert_parallel_size > 1:
-        return min(n_tokens, -(-4 * fair // 128) * 128)
+        return min(n_tokens, -(-3 * fair // 128) * 128)
     return min(n_tokens, -(-3 * fair // 256) * 128)
 
 
-# Overflow tiles a share's dispatch runs whether it needs them or not: the
-# seeded routers of the benchmark's configuration overflow by a few tiles
-# a layer on most seeds and not at all on others, and a step whose time
-# followed that moved a 45 s closed-loop reading by 1-2 % (PERF.md §6,
-# PR 27).  A layer that needs more runs more.  (A layer held whole runs a
-# count fixed by its shape: :func:`_batched_dispatch`.)
+# Overflow tiles a share's dispatch runs whether it needs them or not, so
+# that a step's time does not follow a seed's router: a 45 s closed-loop
+# reading moved by 1-2 % with it (PERF.md §6, PR 27).  What a layer needs
+# is its router's skew, which no shape says: at one 128-row tile an expert
+# the windows of PR 49 read 2.0-2.9 tiles a layer-step by seed (laguna; 5
+# at the 95th percentile, 10 the most), 0.64 (mimo; 3, 6), 0.02 (gigachat)
+# and 0.009 (kimi, PR 44) under near-equal shapes.  Four leave the loop
+# 0.1-0.7 trips a layer-step in laguna's windows (~45 us each: under 0.5 ms
+# of a 73 ms step, and over seven seeds the step's cycle did not follow
+# them), 0.02 in mimo's and none elsewhere; four more would cost ~3 ms of
+# EVERY laguna step and ~2 ms of kimi's, more than they steady.  A layer
+# that needs more runs more.  (A layer held whole runs a count fixed by its
+# shape: :func:`_batched_dispatch`.)
 _SPARE_TILES = 4
+
+
+def share_rows(n_tokens: int, cfg) -> tuple[int, int]:
+    """What a SHARE's routed layer puts through each of its experts'
+    contractions in a step program of ``n_tokens`` rows: ``(rows whatever
+    the router does, rows a trip of the overflow loop adds)``.  The dense
+    dispatch computes every row for every held expert; the batched one
+    an expert's batch and the spare tiles (``moe_batch_rows_total``,
+    docs/monitoring.md: held pairs over these rows is how full the
+    experts' batches are).  :func:`moe_ffn`'s own rule, for the flat
+    ``[1, n, E]`` batch of a step (a share is never meshed)."""
+    if not _batch_pays(n_tokens, None, cfg):
+        return n_tokens * cfg.num_experts, 0
+    cap = _held_capacity(n_tokens, cfg)
+    return (cfg.num_experts + _SPARE_TILES) * cap, cap
 
 
 def _expert_dot(eq: str, x: jnp.ndarray, w) -> jnp.ndarray:
@@ -347,7 +376,7 @@ def _shared_expert(x2: jnp.ndarray, mp: Params, cfg,
         return shared * gatev[..., None].astype(shared.dtype)
 
 
-_GROUPED_MIN_TOKENS = 64  # below this, dense dispatch wins on dispatch cost
+_GROUPED_MIN_TOKENS = 64
 
 
 def _ragged_dispatch(x2: jnp.ndarray, vals: jnp.ndarray, idx: jnp.ndarray,
@@ -377,13 +406,17 @@ def _ragged_dispatch(x2: jnp.ndarray, vals: jnp.ndarray, idx: jnp.ndarray,
 
 def _batch_pays(n_tokens: int, mp: Params, cfg) -> bool:
     """Whether the grouped path does less than the dense dispatch on
-    ``n_tokens`` rows.  Quantised experts, and any share of a layer, go
-    through the batched dispatch, which is the dense one with gathers
-    around it once an expert's batch is every token (64 rows of 8 experts
-    top-2 held whole, or of 40 held of 320 top-8: the dense dispatch runs
-    at the HBM rate there, and the batched form of such a step copied every
-    expert leaf out of its stack first, PERF.md §6, PR 36)."""
+    ``n_tokens`` rows (under ``_GROUPED_MIN_TOKENS`` the dense one wins on
+    dispatch cost).  Quantised experts, and any share of a layer (``mp``
+    is not read for one), go through the batched dispatch, which is the
+    dense one with gathers around it once an expert's batch is every token
+    (64 rows of 8 experts top-2 held whole, or of 40 held of 320 top-8: the
+    dense dispatch runs at the HBM rate there, and the batched form of such
+    a step copied every expert leaf out of its stack first, PERF.md §6,
+    PR 36)."""
     from arks_tpu.models.quant import is_quantized
+    if n_tokens < _GROUPED_MIN_TOKENS:
+        return False
     if cfg.expert_parallel_size == 1 and not is_quantized(mp["w_gate"]):
         return True
     return _held_capacity(n_tokens, cfg) < n_tokens
@@ -469,7 +502,6 @@ def moe_ffn(x: jnp.ndarray, mp: Params, cfg, constrain=None,
         # ([B, E]): decode stays dense regardless of slot count — it is
         # HBM-bound and the sort/gather dispatch only adds overhead there.
         grouped = (constrain is None and x.ndim >= 3
-                   and n_tokens >= _GROUPED_MIN_TOKENS
                    and _batch_pays(n_tokens, mp, cfg))
     if grouped:
         return moe_ffn_grouped(x, mp, cfg, row_valid, stack)

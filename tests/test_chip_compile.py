@@ -530,6 +530,24 @@ def test_a_shares_chunk_step_copies_no_expert_leaf_out_of_its_stack(
     assert compiled.memory_analysis().temp_size_in_bytes < temp_mb * 1e6
 
 
+@pytest.mark.parametrize("name", ["laguna-s-2.1-ep8", "mimo-v2.5-ep16-l13",
+                                  "gigachat3.5-432b-ep8-l5"])
+def test_a_whole_budget_steps_batch_is_one_tile_an_expert(chip, monkeypatch,
+                                                          name):
+    """The whole-budget step of the three share configurations whose fair
+    load is 34-42 rows of ~1,060, compiled for the described v5e: the
+    batched dispatch's three contractions run on ``bf16[X,128,F]`` /
+    ``bf16[X,128,E]`` (one 128-row tile an expert: 3 x fair in tiles), not
+    on the ``[X,256,*]`` that 4 x fair was rounded up to until PR 49, half
+    of whose rows no pair ever landed on.  (That the tiles behind the batch
+    still copy no expert leaf is the test above, on these same programs.)"""
+    cfg, _, compiled = _share_step(chip, monkeypatch, name, 1024)
+    text = compiled.as_text()
+    x, e, f = cfg.num_experts, cfg.hidden_size, cfg.moe_intermediate_size
+    assert f"bf16[{x},128,{f}]" in text and f"bf16[{x},128,{e}]" in text
+    assert f"bf16[{x},256," not in text
+
+
 @pytest.mark.parametrize("name,rows,temp_mb", [
     ("mimo-v2.5-ep16-l13", 0, 30), ("mimo-v2.5-ep16-l13", 1024, 260),
     ("laguna-s-2.1-ep8", 1024, 120),

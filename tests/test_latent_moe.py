@@ -272,6 +272,48 @@ def test_an_expert_that_draws_more_rows_than_the_batch_holds_overflows_in_tiles(
                                    atol=0.02 * float(jnp.abs(want).max()))
 
 
+@pytest.mark.parametrize("drawn", [352, 1056], ids=["a third", "every row"])
+def test_one_tile_an_expert_is_exact_when_an_expert_draws_far_more(drawn):
+    """The capacity AS THE RULE GIVES IT, nothing patched, at the shape of
+    laguna's whole-budget step (1,056 rows, top-10 of 256, 32 held: one
+    tile of 128 rows an expert): a router that sends ``drawn`` rows to ONE
+    held expert on top of what the seeded weights spread gives the dense
+    dispatch's output, the tiles counted by hand from the router's own
+    choice: a third of the rows is two tiles, inside the spare ones; every
+    row is eight, of which the loop runs those past the spare ones."""
+    n, star = 1056, 5
+    cfg = ModelConfig.from_hf_config(
+        _tiny_config(n_routed_experts=32, num_experts_per_tok=10),
+        name="laguna-shaped").with_expert_share(8, 0)
+    assert (cfg.router_width, cfg.num_experts) == (256, 32)
+    assert moe._held_capacity(n, cfg) == 128
+    lp = jax.tree.map(lambda a: a[0], moe.init_moe_params(
+        cfg, jax.random.PRNGKey(3), jnp.float32, layers=1))
+    x = jax.random.normal(jax.random.PRNGKey(4), (1, n, cfg.hidden_size),
+                          jnp.float32)
+    # Feature 0 carries the choice: the star expert's score is ~1 on the
+    # first ``drawn`` rows and ~0 on the others, whatever the bias adds.
+    x = x.at[0, :, 0].set(jnp.where(jnp.arange(n) < drawn, 1.0, -1.0))
+    lp["router"] = lp["router"].at[0, :].set(0.0).at[0, star].set(40.0)
+    lp["router_bias"] = jnp.zeros_like(lp["router_bias"])
+    valid = jnp.ones((1, n), bool)
+    _, idx = moe.router_topk(jnp.einsum("te,ex->tx", x[0], lp["router"]),
+                             cfg, lp["router_bias"])
+    idx = np.asarray(idx)
+    sizes = np.bincount(idx[idx < cfg.num_experts],
+                        minlength=cfg.num_experts)
+    assert sizes[star] == drawn and np.delete(sizes, star).max() <= 128
+    needed = int(np.sum(-(-np.maximum(sizes - 128, 0) // 128)))
+    assert needed == {352: 2, 1056: 8}[drawn]
+    want, held_d = moe.moe_ffn(x, lp, cfg, grouped=False, row_valid=valid)
+    got, held = moe.moe_ffn(x, lp, cfg, row_valid=valid)       # the auto rule
+    assert held_d.tolist() == [int(sizes.sum()), 0, 0]
+    assert held.tolist() == [int(sizes.sum()), needed,
+                             max(needed - moe._SPARE_TILES, 0)]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5 * float(jnp.abs(want).max()))
+
+
 @pytest.mark.parametrize("leaves", ["float32", "int8"])
 @pytest.mark.parametrize("router", ["one expert", "spread"])
 def test_a_scanned_layers_overflow_loop_reads_its_own_layers_experts(
@@ -765,7 +807,7 @@ def test_a_steps_four_counts_land_on_their_counters(share):
     cfg = get_config("tiny-mla-moe")
     eng = _engine(cfg=cfg.with_expert_share(2, 0) if share else cfg)
     try:
-        eng._count_held(np.asarray([9, 9, 9, 40, 11, 3, 20], np.int32))
+        eng._count_held(np.asarray([9, 9, 9, 40, 11, 3, 20], np.int32), 16)
         m = eng.metrics
         assert m.moe_held_pairs_total.get() == 40
         assert m.moe_routed_pairs_total.get() == 20 * 4 * 2
@@ -776,6 +818,54 @@ def test_a_steps_four_counts_land_on_their_counters(share):
         assert ("moe_overflow_tiles_total{" in m.registry.render()) == share
     finally:
         eng.stop()
+
+
+def test_a_shares_pod_counts_the_rows_its_experts_computed():
+    """``moe_batch_rows_total`` beside ``moe_held_pairs_total``, read off
+    the rendered registry (what ``/metrics`` serves), the rows counted by
+    hand.  The served steps of this tiny share are 2 + 16 rows, the dense
+    dispatch: every row for each of the 16 held experts (of 32 scored), two
+    routed layers a step.  A step of 2 + 1024 rows is the batched one: a
+    batch of 512 rows an expert (three times the fair 129, in tiles of 128)
+    and the spare tiles a layer, plus a tile for every trip of the loop.
+    Held pairs over those rows is how full the experts' batches ran."""
+    import re
+    from arks_tpu.engine.types import Request, SamplingParams
+    cfg = get_config("tiny-mla-moe").with_expert_share(2, 0)
+    eng = _engine(cfg=cfg)
+
+    def read(name):
+        return float(re.search(rf"^{name} (\S+)$",
+                               eng.metrics.registry.render(), re.M).group(1))
+
+    try:
+        sp = SamplingParams(max_tokens=5, temperature=0.0, ignore_eos=True)
+        _drain(eng, [Request("a", list(range(2, 42)), sp)])
+        steps = int(read("mixed_batch_tokens_count"))
+        assert steps >= 3 + 4                  # three chunks, four decode steps
+        rows = steps * (2 + 16) * 16 * 2
+        assert read("moe_batch_rows_total") == rows
+        held = read("moe_held_pairs_total")
+        assert held == eng.metrics.moe_held_pairs_total.get()
+        assert 0 < held / rows < 44 * 4 * 2 / rows
+        # A whole-budget step's counts as the program hands them back: 9
+        # tiles needed over its two layers, 3 of them in the loop.
+        assert moe.share_rows(2 + 1024, cfg) == (
+            (16 + moe._SPARE_TILES) * 512, 512)
+        eng._count_held(np.asarray([7, 7, 900, 9, 3, 1000], np.int32),
+                        2 + 1024)
+        assert read("moe_batch_rows_total") == rows + (
+            2 * (16 + moe._SPARE_TILES) + 3) * 512
+    finally:
+        eng.stop()
+    # A pod that holds every expert renders no sample of it.
+    whole = _engine()
+    try:
+        whole._count_held(np.asarray([7, 40, 0, 0, 20], np.int32), 18)
+        assert re.search(r"^moe_batch_rows_total \S+$",
+                         whole.metrics.registry.render(), re.M) is None
+    finally:
+        whole.stop()
 
 
 @pytest.mark.parametrize("over, env, word", [

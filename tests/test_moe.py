@@ -247,6 +247,67 @@ def test_quantised_experts_at_the_cells_shape_and_with_padding_rows(bits):
                                np.asarray(only_shared[0, 201:]), atol=tol)
 
 
+# The benchmark's share configurations: (chips a layer, slots), and what the
+# rule reads of each (k, router width, held experts, fair rows of a
+# whole-budget step).
+_SHARES = {
+    "kimi-k2.5-ep32-l9": (32, 8),          # 8 of 384, 12 held: fair 22
+    "laguna-s-2.1-ep8": (8, 32),           # 10 of 256, 32 held: fair 42
+    "solar-open2-250b-ep8-l8": (8, 64),    # 8 of 320, 40 held: fair 28
+    "gigachat3.5-432b-ep8-l5": (8, 64),    # 8 of 256, 32 held: fair 34
+    "mimo-v2.5-ep16-l13": (16, 64),        # 8 of 256, 16 held: fair 34
+}
+
+
+@pytest.mark.parametrize("step", ["pipelined", "quarter", "whole-budget"])
+@pytest.mark.parametrize("name", sorted(_SHARES))
+def test_a_shares_batch_an_expert_is_one_tile_at_every_cells_step(name, step):
+    """The capacity rule as a table, on the configurations' own files: a
+    share's expert takes the fewest 128-row tiles that hold three times its
+    fair load, so ONE tile at every chunk-carrying step of the five cells
+    (laguna, gigachat and mimo took two at their whole-budget steps while
+    the rule said four times: 168 / 136 / 136 rows rounded up to 256, and
+    every routed layer ran 8192 / 8192 / 4096 batch rows for the ~1,300 /
+    1,100 / 540 pairs that land), and every row at a pipelined step, which
+    is the dense dispatch's.  ``share_rows`` is what the host counts a
+    layer (``moe_batch_rows_total``)."""
+    import os
+    from arks_tpu.models.config import ModelConfig
+    share, slots = _SHARES[name]
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cfg = ModelConfig.from_hf_config(
+        os.path.join(root, "benchmarks", "configs", name),
+        name="m").with_expert_share(share, 0)
+    n = slots + {"pipelined": 0, "quarter": 256, "whole-budget": 1024}[step]
+    if step == "pipelined":
+        assert moe._held_capacity(n, cfg) == n
+        assert not moe._batch_pays(n, None, cfg)
+        assert moe.share_rows(n, cfg) == (n * cfg.num_experts, 0)
+        return
+    fair = -(-n * cfg.num_experts_per_tok // cfg.router_width)
+    assert 3 * fair <= 128
+    assert moe._held_capacity(n, cfg) == 128
+    assert moe._batch_pays(n, None, cfg)
+    assert moe.share_rows(n, cfg) == (
+        (cfg.num_experts + moe._SPARE_TILES) * 128, 128)
+
+
+def test_a_shares_batch_grows_by_tiles_with_the_fair_load():
+    """Past one tile the rule goes on in whole tiles of 128 (it is not a
+    cap of one): 3 x fair rounded up, at most every row; a layer held whole
+    keeps one and a half times its fair load."""
+    import types
+    cfg = types.SimpleNamespace(num_experts=32, num_experts_per_tok=10,
+                                router_width=256, expert_parallel_size=8,
+                                expert_parallel_rank=0)
+    caps = {n: moe._held_capacity(n, cfg) for n in (96, 1056, 1100, 2080,
+                                                    4128, 8224)}
+    assert caps == {96: 96, 1056: 128, 1100: 256, 2080: 256, 4128: 512,
+                    8224: 1024}
+    cfg.expert_parallel_size, cfg.router_width = 1, 32
+    assert moe._held_capacity(1056, cfg) == 512      # 1.5 x 330, in tiles
+
+
 def test_the_auto_rule_batches_quantised_experts_only_where_it_pays(
         monkeypatch):
     """Quantised leaves of a layer held whole go grouped only while an

@@ -146,6 +146,24 @@ gone: every program that projects q / k / v takes other operands and lowers
 another dot, as it should; the numbers are the parent's (the seeded leaves
 are its leaves transposed, ``tests/test_quant.py``; the served
 log-probabilities hold to every reference as before).
+
+PR 49 RE-PINNED the six ``@wide`` programs and moved none of the
+twenty-eight others nor the whole-layer pin.  The ``@wide`` programs exist
+to pin a share's batched dispatch, and that dispatch's SHAPE changed: a
+share's batch an expert is the fewest 128-row tiles that hold THREE times
+its fair load (``moe._held_capacity``; it was four times), so at 2 + 512
+rows, top-4 of 32 scored (fair 65), an expert's batch and every overflow
+tile behind it are 256 rows where they were 384; the ops are the same ops
+on other extents (at the benchmark's whole-budget steps the same rule
+gives one tile of 128 rows where two stood: laguna, gigachat, mimo).
+Every program of 2 + 64 rows stands because the dense dispatch runs there
+(an expert's batch would be every row under either multiple), the
+pipelined programs with them; ``tiny`` has no experts; ``tiny-mixtral`` and
+the whole-layer pin are the branch of a layer held WHOLE (one and a half
+times the fair load, ``(n x k - 1) // cap`` tiles), which PR 49 does not
+touch.  The new counter ``moe_batch_rows_total`` is worked out on the host
+from the step's shape and the ``extra`` the step already hands back
+(``moe.share_rows``), so no program gained an output.
 """
 
 import hashlib
@@ -172,16 +190,16 @@ PINS = {
     "tiny-swa-moe.seq_lp": "dcd6bb16baa882d2",
     "tiny-swa-moe.pipe": "a5acbcd901fe6084",
     "tiny-swa-moe.pipe_lp": "fab7b8e5752c34a9",
-    "tiny-mla-moe@wide.seq": "bba1b61e2b1fa6a6",
-    "tiny-mla-moe@wide.seq_lp": "605c9e49c7d4e5ab",
-    "tiny-swa-moe@wide.seq": "649deb3ae377df58",
-    "tiny-swa-moe@wide.seq_lp": "4496a82922472d4b",
+    "tiny-mla-moe@wide.seq": "e4da81ec065116e7",
+    "tiny-mla-moe@wide.seq_lp": "50211ef484486342",
+    "tiny-swa-moe@wide.seq": "b7f9b493752f97e3",
+    "tiny-swa-moe@wide.seq_lp": "bce16710442f9285",
     "tiny-linear-moe.seq": "89d61e6062a43366",
     "tiny-linear-moe.seq_lp": "ff1f7609a933d0f2",
     "tiny-linear-moe.pipe": "751a7238e2d7bee6",
     "tiny-linear-moe.pipe_lp": "383c201b8f57bd2c",
-    "tiny-linear-moe@wide.seq": "2bd75c31061e26d8",
-    "tiny-linear-moe@wide.seq_lp": "dbd12882c4040a2d",
+    "tiny-linear-moe@wide.seq": "f0a10237b64f3ed5",
+    "tiny-linear-moe@wide.seq_lp": "15a7fd4e8399715b",
     "tiny-mixtral.seq": "3c2050866b3daf71",
     "tiny-mixtral.seq_lp": "a7fd1b110fab0779",
     "tiny-mixtral.pipe": "5e7345d839d5dd71",
@@ -211,7 +229,8 @@ def _programs(eng):
 def step_program_hashes(model: str, monkeypatch) -> dict:
     """``model`` is a preset's name, or ``<preset>@wide``: the same preset
     through a step of 2 + 512 rows, where a share's expert takes a batch
-    of 384 and the batched dispatch's loop is in the program."""
+    of 256 (384 until PR 49) and the batched dispatch's loop is in the
+    program."""
     monkeypatch.setenv("ARKS_PIPELINE_DEPTH", "2")
     monkeypatch.setenv("ARKS_MIXED_STEP", "auto")
     preset, _, wide = model.partition("@")
@@ -230,7 +249,7 @@ def step_program_hashes(model: str, monkeypatch) -> dict:
         kw.update(max_cache_len=256, kv_cache_dtype="bf16")
     if wide:
         kw.update(prefill_chunk=512, max_cache_len=1024)
-        assert moe._held_capacity(2 + 512, cfg) == 384
+        assert moe._held_capacity(2 + 512, cfg) == 256
     eng = InferenceEngine(cfg, EngineConfig(**kw), ByteTokenizer())
     try:
         assert eng._pipe_warm_wait(300) == "ready"
