@@ -7,7 +7,14 @@ what holds a reference family to the program (the weights a seed means bit
 for bit, the served log-probabilities, the lower-precision controls) and
 the manifest to the contract; imported here, each counts in the tier-1 run
 and a PR that breaks a reference cannot pass the gate unnoticed.  Nothing
-is copied: the functions are the instrument's own."""
+is copied: the functions are the instrument's own.
+
+This file holds the manifest, the step-clock readers, the decoder family
+and ``mla_moe``; every other reference family is a file of its own,
+``tests/test_contract_<family>.py`` (``--dist loadfile`` hands a file to one
+worker, and a family's pods are most of its cost: ROADMAP D9).  What a
+benchmark pod registers and exports is put back when a file is done
+(``conftest.py::_registry_and_environment_restored``)."""
 
 import os
 import sys
@@ -16,63 +23,10 @@ import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-
-@pytest.fixture(scope="module", autouse=True)
-def _registry_and_environment_restored():
-    """``benchmarks/pod.py::build`` registers a configuration under its own
-    name (a test size's is a preset's: ``tiny-mla-moe`` with half its experts
-    held) and exports its deploy ``env`` (``ARKS_MIXED_CHUNK_TOKENS``), for
-    the life of a benchmark process.  Here the process goes on to other test
-    files (one xdist worker runs many: a later ``get_config("tiny-mla-moe")``
-    or a step's chunking would read what a case here left), so both are put
-    back when this file is done (module scope: set up before the imported
-    ``served`` fixtures, which build pods, torn down after them)."""
-    from arks_tpu.models import config
-    registry, environ = dict(config._REGISTRY), dict(os.environ)
-    yield
-    config._REGISTRY.clear()
-    config._REGISTRY.update(registry)
-    os.environ.clear()
-    os.environ.update(environ)
+pytestmark = pytest.mark.usefixtures("_registry_and_environment_restored")
 
 
-@pytest.fixture
-def seeded_tree_as_drawn(monkeypatch):
-    """The families' ``test_seeded_weights_are_the_programs_bit_for_bit``
-    compare ``init_params_quantized``'s tree with the reference's leaf for
-    leaf in the shape a leaf is DRAWN in, ``[L, E, H x D]`` (files of the
-    benchmark: not every PR's to edit).  Since PR 48 the program stores the
-    GQA stacks' q / k / v projections ``[L, H, D, E]`` (``tf.init_params``):
-    the same numbers, transposed.  Those cases see the stored tree in the
-    drawn order here; the stored order itself is held by
-    ``tests/test_quant.py``."""
-    import jax
-    from arks_tpu.models import quant
-    stored = quant.init_params_quantized
-
-    def drawn(tree):
-        out = {}
-        for name, leaf in tree.items():
-            if isinstance(leaf, dict) and not quant.is_quantized(leaf):
-                out[name] = drawn(leaf)
-            elif name in quant.HEAD_SPLIT_KEYS and jax.tree.leaves(
-                    leaf)[0].ndim == 4:
-                out[name] = jax.tree.map(
-                    lambda a: a.reshape(a.shape[0], -1, a.shape[-1])
-                    .swapaxes(-1, -2), leaf)
-            else:
-                out[name] = leaf
-        return out
-
-    monkeypatch.setattr(quant, "init_params_quantized",
-                        lambda *a, **k: drawn(stored(*a, **k)))
-
-
-from benchmarks.tests import (  # noqa: E402
-    test_reference as _decoder,
-    test_reference_linear_moe as _linear_moe,
-    test_reference_swa_moe as _swa_moe,
-)
+from benchmarks.tests import test_reference as _decoder  # noqa: E402
 from benchmarks.tests.test_manifest import (  # noqa: E402,F401
     test_a_new_cell_loads_from_added_files_alone,
     test_a_new_family_loads_from_added_files_alone,
@@ -84,43 +38,6 @@ from benchmarks.tests.test_reference import (  # noqa: E402,F401
     test_served_logprobs_against_the_reference,
     test_the_quantile_is_nearest_rank_and_the_verdict_wants_enough_positions,
     test_the_routing_margin_is_small_where_two_experts_tie,
-)
-from benchmarks.tests.test_reference_swa_moe import (  # noqa: E402,F401
-    served,
-    test_served_logprobs_against_the_reference as
-    test_swa_moe_served_logprobs_against_the_reference,
-    test_the_family_keeps_the_contract_and_imports_nothing_of_the_program as
-    test_swa_moe_keeps_the_contract_and_imports_nothing_of_the_program,
-    test_the_lower_precision_controls_fail as
-    test_swa_moe_lower_precision_controls_fail,
-    test_the_probes_went_through_both_pools_and_released_window_pages,
-    test_the_routing_margin_is_in_router_logit_units,
-)
-from benchmarks.tests.test_reference_linear_moe import (  # noqa: E402,F401
-    linear_served,
-    test_served_logprobs_against_the_reference as
-    test_linear_moe_served_logprobs_against_the_reference,
-    test_the_family_keeps_the_contract_and_imports_nothing_of_the_program as
-    test_linear_moe_keeps_the_contract_and_imports_nothing_of_the_program,
-    test_the_lower_precision_controls_fail as
-    test_linear_moe_lower_precision_controls_fail,
-    test_the_probes_went_through_pages_and_state,
-    test_the_routing_margin_is_in_biased_score_units as
-    test_linear_moe_routing_margin_is_in_biased_score_units,
-)
-from benchmarks.tests.test_reference_latent_linear_moe import (  # noqa: E402,F401,E501
-    latent_linear_served,
-    test_seeded_weights_are_the_programs_bit_for_bit as
-    test_latent_linear_moe_seeded_weights_are_the_programs_bit_for_bit,
-    test_served_logprobs_against_the_reference as
-    test_latent_linear_moe_served_logprobs_against_the_reference,
-    test_the_family_keeps_the_contract_and_imports_nothing_of_the_program as
-    test_latent_linear_moe_keeps_the_contract_and_imports_nothing,
-    test_the_lower_precision_control_fails as
-    test_latent_linear_moe_lower_precision_control_fails,
-    test_the_probes_went_through_latent_pages_and_state,
-    test_the_routing_margin_is_in_biased_score_units as
-    test_latent_linear_moe_routing_margin_is_in_biased_score_units,
 )
 from benchmarks.tests.test_reference_mla_moe import (  # noqa: E402,F401
     test_seeded_weights_are_the_programs_bit_for_bit as
@@ -144,13 +61,3 @@ from benchmarks.tests.test_step_clock_readers import (  # noqa: E402,F401
 def test_seeded_weights_are_the_programs_bit_for_bit(name,
                                                      seeded_tree_as_drawn):
     _decoder.test_seeded_weights_are_the_programs_bit_for_bit(name)
-
-
-def test_swa_moe_seeded_weights_are_the_programs_bit_for_bit(
-        seeded_tree_as_drawn):
-    _swa_moe.test_seeded_weights_are_the_programs_bit_for_bit()
-
-
-def test_linear_moe_seeded_weights_are_the_programs_bit_for_bit(
-        seeded_tree_as_drawn):
-    _linear_moe.test_seeded_weights_are_the_programs_bit_for_bit()

@@ -13,17 +13,17 @@ step/_recover_from_fault contract the engine thread runs (_run_loop), so
 faults land deterministically.
 """
 
-import os
+import functools
 import random
 import time
 
 import pytest
 
-from arks_tpu.engine import EngineConfig, InferenceEngine, Request, SamplingParams
+from arks_tpu.engine import Request, SamplingParams
 from arks_tpu.engine.faults import FaultInjector, InjectedFault, Watchdog
 from arks_tpu.engine.paged import chain_digests
-from arks_tpu.engine.tokenizer import ByteTokenizer
-from arks_tpu.models import get_config
+
+import harness
 
 pytestmark = pytest.mark.chaos
 
@@ -47,39 +47,16 @@ def _mk_engine(monkeypatch, depth=0, mixed="0", inject=None, retries=None,
         monkeypatch.delenv("ARKS_FAULT_RETRIES", raising=False)
     else:
         monkeypatch.setenv("ARKS_FAULT_RETRIES", str(retries))
-    cfg = get_config("tiny")
-    defaults = dict(model="tiny", num_slots=2, max_cache_len=64,
-                    prefill_buckets=(8, 16, 32), steps_per_dispatch=4)
-    defaults.update(kw)
-    eng = InferenceEngine(cfg, EngineConfig(**defaults), ByteTokenizer())
-    if depth:
-        assert eng._pipe_warm_wait(300) == "ready"
-    return cfg, eng
+    eng = harness.warmed("tiny", base=dict(
+        num_slots=2, max_cache_len=64, prefill_buckets=(8, 16, 32),
+        steps_per_dispatch=4), **kw)
+    return eng.cfg, eng
 
 
-def _drive(eng, n_steps=1500):
-    """The engine thread's own step/recover contract, synchronously."""
-    for _ in range(n_steps):
-        try:
-            eng.step(block_s=0.01)
-        except Exception as e:  # noqa: BLE001 — routed exactly like _run_loop
-            eng._recover_from_fault(e)
-        if (eng.num_running == 0 and eng._queue.empty()
-                and eng._deferred is None
-                and not eng._prefilling and not eng._awaiting_fetch
-                and not eng._awaiting_restore and eng.state == "serving"):
-            break
+_drive = functools.partial(harness.drive, recover=True)
 
 
-def _collect(req, timeout=120):
-    ids, fin = [], None
-    while True:
-        out = req.outputs.get(timeout=timeout)
-        ids.extend(out.token_ids)
-        if out.finished:
-            fin = out
-            break
-    return ids, fin
+_collect = harness.collect
 
 
 def _workload(cfg):
@@ -94,6 +71,30 @@ def _workload(cfg):
     return reqs
 
 
+_CLEAN = {}
+
+
+def _clean(scenario, *args, **kw):
+    """The fault-free pass of ``scenario(monkeypatch, *args, **kw)``, run
+    once a module: what a faulted pass (always on a fresh engine of its own)
+    is held against.  Its environment is its own and is put back."""
+    at = (scenario.__name__, repr(args), repr(sorted(kw.items())))
+    if at not in _CLEAN:
+        with pytest.MonkeyPatch.context() as mp:
+            _CLEAN[at] = scenario(mp, *args, **kw)
+    return _CLEAN[at]
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _clean_passes_dropped(tmp_path_factory):
+    _CLEAN["dir"] = tmp_path_factory.mktemp("chaos-clean")
+    yield
+    del _CLEAN["dir"]
+    for _, eng, *_ in _CLEAN.values():
+        eng.stop()
+    _CLEAN.clear()
+
+
 def _run(monkeypatch, depth, mixed, kw, inject=None, retries=None):
     cfg, eng = _mk_engine(monkeypatch, depth, mixed, inject=inject,
                           retries=retries, **kw)
@@ -102,6 +103,8 @@ def _run(monkeypatch, depth, mixed, kw, inject=None, retries=None):
         eng.add_request(r)
     _drive(eng)
     return [_collect(r) for r in reqs], eng
+
+
 
 
 @pytest.mark.parametrize("depth", [0, 2])
@@ -113,7 +116,7 @@ def test_decode_fault_recovers_all_streams_byte_identical(
     in-flight stream byte-identically (same tokens, same finish reasons)
     on both engine layouts and at pipeline depths 0 and 2, with the fault
     and recovery metrics advancing."""
-    base, _ = _run(monkeypatch, depth, mixed, kw)
+    base, _ = _clean(_run, depth, mixed, kw)
     got, eng = _run(monkeypatch, depth, mixed, kw, inject="decode:3:runtime")
     assert [f.finish_reason for _, f in got] == ["length", "length"]
     assert got == base, "surviving streams diverged from the fault-free run"
@@ -133,7 +136,7 @@ def test_spec_fault_recovers_all_streams_byte_identical(monkeypatch, depth):
     or the pipelined spec issue at depth 2) must recover every in-flight
     stream byte-identically via token replay — spec engines joined the
     recovery contract when the fused spec loop was retired."""
-    base, _ = _run(monkeypatch, depth, *SPEC)
+    base, _ = _clean(_run, depth, *SPEC)
     got, eng = _run(monkeypatch, depth, *SPEC, inject="spec:3:runtime")
     assert [f.finish_reason for _, f in got] == ["length", "length"]
     assert got == base, "surviving spec streams diverged from the fault-free run"
@@ -148,7 +151,7 @@ def test_spec_repeated_fault_quarantines_only_the_culprit(monkeypatch):
     """Spec phase fault -> everyone replays; the FIRST replay operation
     then faults too -> that request fails ALONE while the other spec
     stream finishes byte-identical to the fault-free run."""
-    base, _ = _run(monkeypatch, 0, *SPEC)
+    base, _ = _clean(_run, 0, *SPEC)
     got, eng = _run(monkeypatch, 0, *SPEC,
                     inject="spec:3:runtime,replay:1:runtime")
     reasons = [f.finish_reason for _, f in got]
@@ -171,7 +174,7 @@ def test_repeated_fault_quarantines_only_the_culprit(monkeypatch, mixed, kw):
     faults too -> that request has exhausted ARKS_FAULT_RETRIES=1 and
     fails ALONE with finish_reason="error"/engine_fault, while the other
     stream still finishes byte-identical to the fault-free run."""
-    base, _ = _run(monkeypatch, 0, mixed, kw)
+    base, _ = _clean(_run, 0, mixed, kw)
     got, eng = _run(monkeypatch, 0, mixed, kw,
                     inject="decode:3:runtime,replay:1:runtime")
     reasons = [f.finish_reason for _, f in got]
@@ -210,7 +213,7 @@ def test_admit_fault_requeues_requests(monkeypatch):
     """A fault inside the fused admission dispatch must re-queue the
     batch's requests (nothing was emitted yet) and the streams come out
     byte-identical to a fault-free run — pinned engine-assigned seeds."""
-    base, _ = _run(monkeypatch, 0, *SLOT)
+    base, _ = _clean(_run, 0, *SLOT)
     got, eng = _run(monkeypatch, 0, *SLOT, inject="admit:1:runtime")
     assert got == base
     assert sum(eng.metrics.requests_recovered_total._values.values()) >= 1
@@ -247,7 +250,7 @@ def test_admit_fair_fault_requeues_through_the_fair_queue(monkeypatch):
     request re-queues through the fair queue (nothing was emitted yet)
     and EVERY stream — both tenants — comes out byte-identical to the
     fault-free run."""
-    base, _ = _run_tenants(monkeypatch)
+    base, _ = _clean(_run_tenants)
     got, eng = _run_tenants(monkeypatch, inject="admit_fair:2:runtime")
     assert got == base, \
         "streams diverged after the admit_fair fault"
@@ -264,7 +267,7 @@ def test_admit_fair_repeated_fault_quarantines_only_the_culprit(
     request (the sole culprit), every other stream — same tenant and
     the other tenant alike — finishes byte-identical to the fault-free
     run, and the fair queue keeps serving."""
-    base, _ = _run_tenants(monkeypatch)
+    base, _ = _clean(_run_tenants)
     got, eng = _run_tenants(monkeypatch, inject="admit_fair:2:runtime",
                             retries=0)
     reasons = [f.finish_reason for _, f in got]
@@ -410,7 +413,7 @@ def test_randomized_chaos_sweep(monkeypatch, mixed, kw):
     hold in EVERY round — each stream either matches the fault-free run
     exactly or fails alone with an engine_fault error; the engine always
     returns to "serving"."""
-    base, _ = _run(monkeypatch, 0, mixed, kw)
+    base, _ = _clean(_run, 0, mixed, kw)
     base_by_rid = {fin.request_id: (ids, fin.finish_reason)
                    for ids, fin in base}
     rng = random.Random(1234)
@@ -531,7 +534,7 @@ def test_restore_fault_is_isolated_to_the_restoring_request(monkeypatch):
     the retry budget the restoring request re-queues (its retry hits the
     host tier again — it survives the device reset), and the co-resident
     decoding stream is byte-identical to the fault-free run."""
-    base, beng = _restore_scenario(monkeypatch)
+    base, beng = _clean(_restore_scenario)
     assert beng.metrics.prefix_restore_blocks_total.total() > 0, \
         "scenario never exercised the restore path"
     got, eng = _restore_scenario(monkeypatch, inject="restore:1:runtime")
@@ -549,7 +552,7 @@ def test_restore_fault_quarantines_only_the_culprit(monkeypatch):
     request ALONE (finish_reason="error"/engine_fault); the innocent
     decoding stream still finishes byte-identical to the fault-free
     run."""
-    base, _ = _restore_scenario(monkeypatch)
+    base, _ = _clean(_restore_scenario)
     got, eng = _restore_scenario(monkeypatch, inject="restore:1:runtime",
                                  retries=0)
     (by_ids, by_fin), (_, v_fin) = got
@@ -560,7 +563,7 @@ def test_restore_fault_quarantines_only_the_culprit(monkeypatch):
     assert eng.state == "serving"
 
 
-def _disk_scenario(monkeypatch, depth, ddir, inject=None, retries=None,
+def _disk_scenario(monkeypatch, depth, ddir=None, inject=None, retries=None,
                    wait_disk=True):
     """Tier-2 traffic on the tiered cache: a warm prompt spills into the
     host tier under churn, a capacity squeeze evicts it into the DISK
@@ -569,7 +572,9 @@ def _disk_scenario(monkeypatch, depth, ddir, inject=None, retries=None,
     "peer_fetch" phase."""
     monkeypatch.setenv("ARKS_PREFIX_HOST_MB", "64")
     monkeypatch.setenv("ARKS_PREFIX_DISK_MB", "8")
-    monkeypatch.setenv("ARKS_PREFIX_DISK_DIR", str(ddir))
+    # (a clean pass, shared by the module's tests, in a directory of its own)
+    monkeypatch.setenv("ARKS_PREFIX_DISK_DIR",
+                       str(ddir or _CLEAN["dir"] / f"depth{depth}"))
     cfg, eng = _mk_engine(monkeypatch, depth, "auto", inject=inject,
                           retries=retries, prefill_chunk=16,
                           kv_layout="paged", prefix_cache_mb=0)
@@ -637,7 +642,7 @@ def test_disk_spill_fault_leaves_streams_intact(monkeypatch, depth,
     finishes byte-identical to the fault-free run, and the engine keeps
     serving — the warm blocks simply never reach disk (dropped spill,
     re-prefill on return)."""
-    base, beng = _disk_scenario(monkeypatch, depth, tmp_path / "b")
+    base, beng = _clean(_disk_scenario, depth)
     assert beng.metrics.prefix_peer_fetch_blocks_total.get(
         source="disk") == 2, "scenario never exercised the disk tier"
     got, eng = _disk_scenario(monkeypatch, depth, tmp_path / "f",
@@ -658,7 +663,7 @@ def test_fetch_resolve_fault_recovers_within_budget(monkeypatch, depth,
     retry budget the fetching request re-queues, its retry re-parks on
     the disk tier and restores, and both it and the co-resident decoding
     stream finish byte-identical to the fault-free run."""
-    base, beng = _disk_scenario(monkeypatch, depth, tmp_path / "b")
+    base, beng = _clean(_disk_scenario, depth)
     assert beng.metrics.prefix_peer_fetch_blocks_total.get(
         source="disk") == 2, "scenario never exercised the disk fetch"
     got, eng = _disk_scenario(monkeypatch, depth, tmp_path / "f",
@@ -677,7 +682,7 @@ def test_fetch_resolve_fault_quarantines_only_the_fetcher(monkeypatch,
     request ALONE (finish_reason="error"/engine_fault); the innocent
     decoding stream still finishes byte-identical to the fault-free
     run."""
-    base, _ = _disk_scenario(monkeypatch, 0, tmp_path / "b")
+    base, _ = _clean(_disk_scenario, 0)
     got, eng = _disk_scenario(monkeypatch, 0, tmp_path / "f",
                               inject="peer_fetch:1:runtime", retries=0)
     (by_ids, by_fin), (_, v_fin) = got
@@ -724,7 +729,7 @@ def test_residency_fault_recovers_all_streams_byte_identical(
     back through engagement), the co-resident classic-path stream
     replays too, and BOTH finish byte-identical to the fault-free run at
     pipeline depths 0 and 2."""
-    base, beng = _residency_scenario(monkeypatch, depth)
+    base, beng = _clean(_residency_scenario, depth)
     assert beng.metrics.residency_spans_total.total() > 0, \
         "scenario never engaged the windowed path"
     got, eng = _residency_scenario(monkeypatch, depth,
@@ -743,7 +748,7 @@ def test_residency_fault_quarantines_only_the_engaged_culprit(monkeypatch):
     stream alone (finish_reason="error"/engine_fault) — the culprit set
     is the window-engaged slots, never the co-resident classic-path
     stream, which finishes byte-identical to the fault-free run."""
-    base, _ = _residency_scenario(monkeypatch, 0)
+    base, _ = _clean(_residency_scenario, 0)
     got, eng = _residency_scenario(monkeypatch, 0,
                                    inject="residency:1:runtime", retries=0)
     (_, w_fin), (by_ids, by_fin) = got
@@ -846,7 +851,7 @@ def test_live_resize_preserves_streams_byte_identical(monkeypatch, depth):
     finish byte-identical to a run that never resized, the request
     completes "ok", and the engine reports the new shape — at pipeline
     depths 0 and 2."""
-    base, _, _ = _resize_scenario(monkeypatch, depth, resize=False)
+    base, _, _ = _clean(_resize_scenario, depth, resize=False)
     got, eng, hold = _resize_scenario(monkeypatch, depth)
     assert hold.outcome == "ok", hold.error
     assert [f.finish_reason for _, f in got] == ["length", "length"]
@@ -874,7 +879,7 @@ def test_resize_seam_fault_recovers_streams_byte_identical(
     shape (old for the first two seams, new for the last), and EVERY
     stream still finishes byte-identical to the never-resized run —
     nobody is quarantined (the resize serves no specific request)."""
-    base, _, _ = _resize_scenario(monkeypatch, depth, resize=False)
+    base, _, _ = _clean(_resize_scenario, depth, resize=False)
     got, eng, hold = _resize_scenario(
         monkeypatch, depth, inject=f"resize:{seam}:runtime")
     assert hold.outcome == "error"
@@ -892,7 +897,7 @@ def test_resize_seam_fault_zero_retries_quarantines_nobody(monkeypatch):
     NOBODY: the drained streams were preserved (swapped or re-queued)
     before the seam fired, so the culprit set is empty and every stream
     replays to a byte-identical finish."""
-    base, _, _ = _resize_scenario(monkeypatch, 0, resize=False)
+    base, _, _ = _clean(_resize_scenario, 0, resize=False)
     got, eng, hold = _resize_scenario(monkeypatch, 0,
                                       inject="resize:2:runtime", retries=0)
     assert hold.outcome == "error"
@@ -910,7 +915,7 @@ def test_randomized_resize_sweep(monkeypatch):
     either matches the never-resized run exactly or fails alone with an
     engine_fault error — and the engine always returns to "serving" at
     a coherent shape."""
-    base, _, _ = _resize_scenario(monkeypatch, 0, resize=False)
+    base, _, _ = _clean(_resize_scenario, 0, resize=False)
     base_by_rid = {fin.request_id: (ids, fin.finish_reason)
                    for ids, fin in base}
     rng = random.Random(4321)

@@ -24,7 +24,7 @@ MIXED_TOTAL_BUDGET = 14
 MIXED_PER_PROGRAM_BUDGET = 2  # lp twins are separate jit objects already
 
 
-def _drain(req, timeout=120):
+def _ids_of(req, timeout=120):
     while True:
         out = req.outputs.get(timeout=timeout)
         if out.finished:
@@ -62,7 +62,7 @@ def test_mixed_workload_compile_variant_budget(monkeypatch):
                 and not eng._prefilling):
             break
     for r in reqs:
-        assert _drain(r).finished
+        assert _ids_of(r).finished
 
     variants = eng.compiled_program_variants()
     assert variants, "no jitted programs discovered on the engine"
@@ -124,7 +124,7 @@ def test_spec_workload_compile_variant_budget(monkeypatch):
                 and not eng._prefilling):
             break
     for r in reqs:
-        assert _drain(r).finished
+        assert _ids_of(r).finished
     assert eng._spec_proposed > 0
 
     variants = eng.compiled_program_variants()
@@ -189,7 +189,7 @@ def test_ragged_kernel_family_budget_with_tuned_cache(monkeypatch, tmp_path):
                 and not eng._prefilling):
             break
     for r in reqs:
-        assert _drain(r).finished
+        assert _ids_of(r).finished
 
     # The tuned entry reached the resolved plan (counters memoize it).
     plan = eng._grid_plans[eng._mixed_budget + 1]

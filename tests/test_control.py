@@ -7,17 +7,7 @@ import time
 
 import pytest
 
-
-def wait_for(predicate, timeout=15.0, interval=0.05):
-    """Poll until predicate() is truthy (needed where progress rides the
-    GangSet controller's periodic resync rather than a store event)."""
-    deadline = time.monotonic() + timeout
-    while time.monotonic() < deadline:
-        v = predicate()
-        if v:
-            return v
-        time.sleep(interval)
-    raise AssertionError("condition not met within timeout")
+from harness import wait_for  # noqa: E402
 
 from arks_tpu.control import resources as res
 from arks_tpu.control.manager import build_manager

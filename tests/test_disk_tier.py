@@ -14,6 +14,7 @@ The acceptance surface of the fleet-prefix PR's persistence half:
   returned to a restore.
 """
 
+import functools
 import os
 
 import numpy as np
@@ -26,6 +27,8 @@ from arks_tpu.engine.paged import chain_digests
 from arks_tpu.engine.prefix_cache import DiskPrefixTier
 from arks_tpu.engine.tokenizer import ByteTokenizer
 from arks_tpu.models import get_config
+
+import harness
 
 
 def _mk(monkeypatch, ddir, host_mb="64", disk_mb="8", **kw):
@@ -43,30 +46,10 @@ def _mk(monkeypatch, ddir, host_mb="64", disk_mb="8", **kw):
                                 ByteTokenizer())
 
 
-def _drive(eng, n_steps=2000):
-    """The engine thread's step/recover contract, synchronously — with
-    the fetch park and the disk spill queue in the liveness condition."""
-    for _ in range(n_steps):
-        try:
-            eng.step(block_s=0.01)
-        except Exception as e:  # noqa: BLE001 — routed like _run_loop
-            eng._recover_from_fault(e)
-        if (eng.num_running == 0 and eng._queue.empty()
-                and eng._deferred is None
-                and not eng._prefilling and not eng._awaiting_fetch
-                and not eng._awaiting_restore and eng.state == "serving"):
-            break
+_drive = functools.partial(harness.drive, recover=True)
 
 
-def _collect(req, timeout=120):
-    ids, fin = [], None
-    while True:
-        out = req.outputs.get(timeout=timeout)
-        ids.extend(out.token_ids)
-        if out.finished:
-            fin = out
-            break
-    return ids, fin
+_collect = harness.collect
 
 
 def _run_one(eng, rid, ids, max_tokens=4):

@@ -4,22 +4,13 @@ protocol (URL building, merge-patch content types, status subresource,
 error mapping) is exercised end to end, the role the reference's Kind
 suite plays (test/e2e/e2e_test.go:45-270)."""
 
-import time
 
 import pytest
 
 from arks_tpu.control.k8s_client import ApiError, FakeApiServer, KubeApi
 from arks_tpu.control.live import FINALIZER, GV, LiveOperator
 
-
-def wait_for(predicate, timeout=30.0, interval=0.05):
-    deadline = time.monotonic() + timeout
-    while time.monotonic() < deadline:
-        v = predicate()
-        if v:
-            return v
-        time.sleep(interval)
-    raise AssertionError("condition not met within timeout")
+from harness import wait_for  # noqa: E402
 
 
 @pytest.fixture()

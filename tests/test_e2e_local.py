@@ -7,6 +7,7 @@ calls the gateway with a token and gets an engine-generated completion with
 metered usage.
 """
 
+import functools
 import json
 import time
 import urllib.request
@@ -17,6 +18,8 @@ from arks_tpu.control import resources as res
 from arks_tpu.control.manager import build_manager
 from arks_tpu.control.workloads import LocalProcessDriver
 from arks_tpu.gateway.server import Gateway
+
+import harness
 
 
 @pytest.fixture(scope="module")
@@ -36,14 +39,9 @@ def stack(tmp_path_factory):
         driver.teardown(gs)
 
 
-def wait_for(predicate, timeout=120.0, interval=0.25):
-    deadline = time.monotonic() + timeout
-    while time.monotonic() < deadline:
-        v = predicate()
-        if v:
-            return v
-        time.sleep(interval)
-    raise AssertionError("condition not met within timeout")
+# (a gang of processes comes up in tens of seconds: the harness's longest
+# wait, polled at a process's pace)
+wait_for = functools.partial(harness.wait_for, timeout=120.0, interval=0.25)
 
 
 def test_quickstart_end_to_end(stack):
@@ -70,7 +68,7 @@ def test_quickstart_end_to_end(stack):
 
     # Engine subprocess boot: jax import + compile, tens of seconds on CPU.
     wait_for(lambda: store.get(res.Application, "tiny-app").status.get("phase")
-             == res.PHASE_RUNNING, timeout=180)
+             == res.PHASE_RUNNING, timeout=120)
     ep = wait_for(lambda: (store.get(res.Endpoint, "tiny-served").status.get("routes")
                            or None), timeout=30)
     assert ep[0]["backend"]["addresses"]
@@ -133,7 +131,7 @@ def _launch_gang(store, name, served, extra_args=()):
     store.create(res.Endpoint(name=served, spec={"defaultWeight": 1}))
     # Two engine processes boot + distributed rendezvous + compile.
     wait_for(lambda: store.get(res.Application, name).status.get("phase")
-             == res.PHASE_RUNNING, timeout=240)
+             == res.PHASE_RUNNING, timeout=120)
     ep = wait_for(lambda: (store.get(res.Endpoint, served).status.get("routes")
                            or None), timeout=30)
     return ep[0]["backend"]["addresses"][0]
@@ -250,7 +248,7 @@ def test_gang_member_death_restarts_group_and_serving_recovers(stack):
         except Exception:
             return False
 
-    wait_for(served_again, timeout=240, interval=2.0)
+    wait_for(served_again, timeout=120, interval=2.0)
 
 
 def test_follower_wedge_unreadies_gang_then_restarts(stack, monkeypatch):

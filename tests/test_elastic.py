@@ -26,6 +26,8 @@ from arks_tpu.models import get_config
 from arks_tpu.router import Discovery, Router
 from arks_tpu.server import OpenAIServer
 
+import harness
+
 
 def _mk_engine(monkeypatch, **kw):
     cfg = get_config("tiny")
@@ -42,15 +44,7 @@ def _greedy(cfg, rid, prompt, max_tokens=10):
                                   ignore_eos=True))
 
 
-def _collect(req, timeout=120):
-    ids, fin = [], None
-    while True:
-        out = req.outputs.get(timeout=timeout)
-        ids.extend(out.token_ids)
-        if out.finished:
-            fin = out
-            break
-    return ids, fin
+_collect = harness.collect
 
 
 # ---------------------------------------------------------------------------

@@ -8,6 +8,8 @@ from arks_tpu.engine import EngineConfig, InferenceEngine, Request, SamplingPara
 from arks_tpu.engine.tokenizer import ByteTokenizer
 from arks_tpu.models import get_config
 
+import harness
+
 
 @pytest.fixture(scope="module")
 def engine():
@@ -18,24 +20,10 @@ def engine():
     yield eng
 
 
-def _collect(req: Request, timeout=60):
-    ids, finished = [], None
-    while True:
-        out = req.outputs.get(timeout=timeout)
-        ids.extend(out.token_ids)
-        if out.finished:
-            finished = out
-            break
-    return ids, finished
+_collect = harness.collect
 
 
-def _drive(engine, n_steps=200):
-    for _ in range(n_steps):
-        engine.step(block_s=0.01)
-        if (engine.num_running == 0 and engine._queue.empty()
-                and engine._deferred is None
-                and not engine._prefilling):
-            break
+_drive = harness.drive
 
 
 def test_single_request_greedy(engine):

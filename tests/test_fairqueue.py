@@ -34,7 +34,7 @@ def _q(**kw):
     return FairQueue(**kw)
 
 
-def _drain(q):
+def _pop_all(q):
     out = []
     while not q.empty():
         out.append(q.get_nowait()[2].request_id)
@@ -53,7 +53,7 @@ def test_single_tenant_keeps_tier_then_fifo_order():
              (1, 4, _req("r4", priority=1))]
     for it in items:
         q.put(it)
-    assert _drain(q) == ["r1", "r3", "r0", "r4", "r2"]
+    assert _pop_all(q) == ["r1", "r3", "r0", "r4", "r2"]
 
 
 def test_two_tenants_interleave_within_a_tier():
@@ -61,7 +61,7 @@ def test_two_tenants_interleave_within_a_tier():
     for i in range(3):
         q.put((0, 2 * i, _req(f"a{i}", tenant="ns/a")))
         q.put((0, 2 * i + 1, _req(f"b{i}", tenant="ns/b")))
-    order = _drain(q)
+    order = _pop_all(q)
     # Each tenant's own order is FIFO, and service interleaves: DRR
     # guarantees bandwidth fairness (both tenants appear in every window
     # of three picks), not strict alternation.
@@ -76,7 +76,7 @@ def test_flood_does_not_starve_the_other_tenant():
     for i in range(50):
         q.put((0, i, _req(f"a{i}", tenant="ns/flood")))
     q.put((0, 50, _req("v0", tenant="ns/victim")))
-    order = _drain(q)
+    order = _pop_all(q)
     # The victim is served within a couple of picks, not after the flood.
     assert order.index("v0") <= 2, order
 
@@ -98,7 +98,7 @@ def test_tiers_stay_strict_across_tenants():
     q.put((0, 1, _req("fast-b", tenant="ns/b")))
     q.put((1, 2, _req("slow-b", tenant="ns/b", priority=1)))
     q.put((0, 3, _req("fast-a", tenant="ns/a")))
-    order = _drain(q)
+    order = _pop_all(q)
     assert set(order[:2]) == {"fast-b", "fast-a"}
     assert set(order[2:]) == {"slow-a", "slow-b"}
 
@@ -163,7 +163,7 @@ def test_plain_mode_is_the_old_heap():
     for i in range(40):
         q.put((0, i, _req(f"a{i}", tenant="ns/flood")))
     q.put((0, 40, _req("v0", tenant="ns/victim")))
-    order = _drain(q)
+    order = _pop_all(q)
     # FIFO within the tier: the victim waits behind the whole flood —
     # exactly the starvation the fair mode exists to fix (and the bench's
     # ARKS_FAIR=0 control arm).
@@ -208,7 +208,7 @@ def test_aging_promotes_in_arrival_order():
     # elapsed 10s / 4s = 2 rungs: both tier-2 entries reach tier 0, in
     # arrival order, behind nothing (same tier now) — seq keeps them
     # ordered among themselves and against the fresh tier-0 entry.
-    order = _drain(q)
+    order = _pop_all(q)
     assert order == ["old-a", "old-b", "fresh"]
 
 
@@ -219,7 +219,7 @@ def test_aging_plain_mode_matches():
     q.put((2, 0, old))
     q.put((1, 1, _req("mid", priority=1)))
     q.age_tick(time.monotonic(), aging_s=4.0)
-    assert _drain(q) == ["old", "mid"]
+    assert _pop_all(q) == ["old", "mid"]
 
 
 def test_aging_never_touches_urgent():
@@ -320,7 +320,7 @@ def _flood_engine(monkeypatch, depth, fair, **env):
         steps_per_dispatch=1, prefill_chunk=16, kv_layout="paged",
         prefix_cache_mb=0), ByteTokenizer())
     if depth:
-        assert eng._pipe_warm_wait(300) == "ready"
+        assert eng._pipe_warm_wait(120) == "ready"
     return eng
 
 

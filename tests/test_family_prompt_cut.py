@@ -1,0 +1,202 @@
+"""One family, every block that keeps a state a slot (ROADMAP D9): the step
+program on float32 activations against the reference family's full forward,
+a prompt cut at odd lengths and then decoded, decode and prefill lanes in
+one flat batch, a slot another sequence just left; and the controls that
+show a stale or a rounded state WOULD be seen.  A block joins by a row of
+``BLOCKS``; what only one block has to say of its stepper (the readings a
+published config leaves open) stands here too, behind the one fixture."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from arks_tpu.models import quant, transformer as tf
+from arks_tpu.models.config import get_config
+
+import harness
+
+# preset: the reference family; whether the gated norms' weights (zeros as
+# seeded: a scale of 1 under either reading of the norm) are redrawn at 0.5
+# sigma in program and reference alike, so that the norm's form reaches the
+# logits; how far a state rounded to bfloat16 after every token parts, in
+# logit sigmas.
+BLOCKS = {
+    "tiny-linear-moe": dict(family="linear_moe", norms=False, rounded=1e-2),
+    "tiny-latent-linear-moe": dict(family="latent_linear_moe", norms=True,
+                                   rounded=5e-3),
+}
+
+
+def _norm_leaf(name: str) -> bool:
+    return name.endswith("_norm") and name != "o_norm"
+
+
+@pytest.fixture(scope="module", params=sorted(BLOCKS))
+def stepper(request):
+    """The step program on float32 activations over the family's own
+    weights (what is stored in bfloat16 widened, which is exact), sequences
+    through 4 slots: (fresh cache, step, reference forward, the block's
+    row)."""
+    preset, block = request.param, BLOCKS[request.param]
+    ref, config = harness.reference(preset, block["family"],
+                                    n_routed_experts=8)
+    seed = 11
+    cfg = dataclasses.replace(get_config(preset), num_experts=8)
+    cfg = cfg.with_expert_share(2, 1)
+    params = jax.tree.map(
+        lambda a: a.astype(jnp.float32) if a.dtype == jnp.bfloat16 else a,
+        quant.init_params_quantized(cfg, jax.random.PRNGKey(seed),
+                                    jnp.bfloat16, bits=8))
+    weights = ref.generate_weights(config, seed)
+    rng = np.random.default_rng(3)
+    for path in sorted(weights) if block["norms"] else ():
+        if _norm_leaf(path.rsplit("/", 1)[-1]):
+            assert not weights[path].any()           # seeded: a scale of 1
+            w = (rng.standard_normal(weights[path].shape) * 0.5).astype(
+                np.float32)
+            weights[path] = w
+            tree, _, leaf = path.rpartition("/")
+            (params[tree] if tree else params)[leaf] = jnp.asarray(w)
+    step = jax.jit(lambda c, *a: tf.mixed_step(params, cfg, c, *a))
+    slots, page, max_pages = 4, 16, 16
+    tables = jnp.arange(slots * max_pages, dtype=jnp.int32).reshape(
+        slots, max_pages)
+
+    def fresh_cache():
+        return tf.init_paged_cache(cfg, slots * max_pages, page, jnp.float32,
+                                   state_slots=slots)
+
+    def run(cache, lanes, rows=100):
+        """One step over ``lanes``: {slot: (token ids, first position)}.
+        Returns (logits at each lane's last row, cache)."""
+        a = dict(tokens=np.zeros(rows, np.int32),
+                 slot=np.full(rows, -1, np.int32),
+                 pos=np.full(rows, page * max_pages, np.int32),
+                 src=np.zeros(slots, np.int32), qs=np.zeros(slots, np.int32),
+                 ql=np.zeros(slots, np.int32), ps=np.zeros(slots, np.int32))
+        at = 1                                        # a padding row ahead
+        for slot, (ids, p0) in lanes.items():
+            n = len(ids)
+            a["tokens"][at:at + n], a["slot"][at:at + n] = ids, slot
+            a["pos"][at:at + n] = np.arange(p0, p0 + n)
+            a["qs"][slot], a["ql"][slot], a["ps"][slot] = at, n, p0
+            a["src"][slot] = at + n - 1
+            at += n
+        logits, cache = step(cache, tables, *(jnp.asarray(a[k]) for k in (
+            "tokens", "slot", "pos", "src", "qs", "ql", "ps")))
+        return {s: np.asarray(logits[s]) for s in lanes}, cache
+
+    def want(ids, rows, **over):
+        return ref.forward(dict(config, **over), weights,
+                           np.asarray(ids, np.int32)[None],
+                           np.asarray(rows, np.int32)[None])[0]
+
+    return fresh_cache, run, want, dict(block, cfg=cfg)
+
+
+def test_a_prompt_cut_at_odd_lengths_then_decoded_is_the_full_forward(
+        stepper):
+    """Chunks of 70, 63, 1, 1, 37 rows (ends inside blocks of the scan,
+    inside pages of 16, single rows between chunks), then decode steps
+    through the block's pages and state: each step's logits are the
+    reference's one forward at that position.  Meanwhile ANOTHER sequence
+    decodes and then prefills in the same flat batches, into a slot whose
+    last sequence left its state there and reads zeros at position 0."""
+    fresh_cache, run, want, block = stepper
+    rng = np.random.default_rng(5)
+    a_ids = rng.integers(2, 258, 180).astype(np.int32)
+    b_ids = rng.integers(2, 258, 90).astype(np.int32)
+    c_ids = rng.integers(2, 258, 40).astype(np.int32)
+    cache = fresh_cache()
+    cfg = block["cfg"]
+    # The pool holds the attention layers (a latent pool one row a token and
+    # no values), the state the linear ones.
+    assert cache.k.shape[0] == cfg.num_full_layers
+    assert (cache.v is None) == bool(cfg.latent)
+    assert cache.lin.s.shape[0] == cfg.num_linear_layers == 6
+    # Slot 0 is left dirty by sequence C, which then ends.
+    _, cache = run(cache, {0: (c_ids, 0)})
+    assert float(jnp.abs(cache.lin.s[:, 0]).max()) > 0
+    got_a, got_b, pa, pb = [], [], 0, 0
+    plan = [(70, 20), (63, 1), (1, 1), (1, 30), (37, 1), (1, 37), (1, 0),
+            (1, 0), (5, 0)]
+    for ta, tb in plan:
+        lanes = {2: (a_ids[pa:pa + ta], pa)}
+        if tb:
+            lanes[0] = (b_ids[pb:pb + tb], pb)        # reuses C's slot
+        out, cache = run(cache, lanes)
+        pa, pb = pa + ta, pb + tb
+        got_a.append((pa - 1, out[2]))
+        if tb:
+            got_b.append((pb - 1, out[0]))
+    for ids, got in ((a_ids, got_a), (b_ids, got_b)):
+        rows = [r for r, _ in got]
+        ref_logits = want(ids, rows)
+        for (r, lg), w in zip(got, ref_logits):
+            # float32 on both sides: 1e-5 of a logit sigma as read, the
+            # chunk form's triangular solve against the token recurrence.
+            assert np.abs(lg - w).max() < 2e-4 * w.std() + 1e-6, r
+    # Slot 1 and 3 were never touched.
+    assert not float(jnp.abs(cache.lin.s[:, (1, 3)]).max())
+
+
+def test_a_stale_state_would_show(stepper):
+    """The control of the test above: the same prompt into the dirty slot
+    WITHOUT starting at position 0 reads what the last sequence left."""
+    fresh_cache, run, want, _ = stepper
+    rng = np.random.default_rng(6)
+    ids = rng.integers(2, 258, 30).astype(np.int32)
+    cache = fresh_cache()
+    _, cache = run(cache, {0: (rng.integers(2, 258, 40).astype(np.int32), 0)})
+    clean, _ = run(cache, {0: (ids, 0)})
+    w = want(ids, [29])[0]
+    assert np.abs(clean[0] - w).max() < 2e-4 * w.std() + 1e-6
+    # Position 1 on: the program reads the slot's state (and pages that
+    # hold another sequence's keys): far off.
+    dirty, _ = run(cache, {0: (ids[1:], 1)})
+    assert np.abs(dirty[0] - w).max() > 0.05 * w.std()
+
+
+def test_a_state_kept_in_bfloat16_shows_where_the_activations_are_float32(
+        stepper):
+    """The control the chip cannot read (PERF.md §2: there bfloat16
+    activations put a floor under every position that a bfloat16 state
+    does not rise above): on float32 activations the step program is the
+    float32-state reference to 2e-4 of a logit sigma, and the reference
+    whose state is rounded to bfloat16 after every token is fifty times
+    that away from both."""
+    fresh_cache, run, want, block = stepper
+    rng = np.random.default_rng(8)
+    ids = rng.integers(2, 258, 96).astype(np.int32)
+    got, _ = run(fresh_cache(), {1: (ids, 0)})
+    sound = want(ids, [95])[0]
+    rounded = want(ids, [95], reference_state_dtype="bfloat16")[0]
+    assert np.abs(got[1] - sound).max() < 2e-4 * sound.std() + 1e-6
+    assert np.abs(got[1] - rounded).max() > block["rounded"] * sound.std()
+
+
+@pytest.mark.parametrize("stepper", ["tiny-latent-linear-moe"], indirect=True)
+@pytest.mark.parametrize("reading", [
+    "norm_sigmoid", "post_norm", "gate_scale", "conv", "state", "mscale",
+    "gate", "router_bias", "swiglu_limit"])
+def test_program_and_reference_hold_the_same_reading_of_every_open_key(
+        stepper, reading):
+    """Each reading the published config leaves open (``deploy.json``'s
+    ``assumed``), computed the OTHER way by the reference on the same
+    weights, parts from the program by a hundred times and more what the
+    shared reading does (read at this size: 0.04 of a logit sigma for the
+    softmax scale's ``m^2``, 0.6 and more for every other)."""
+    fresh_cache, run, want, _ = stepper
+    rng = np.random.default_rng(9)
+    ids = rng.integers(2, 258, 120).astype(np.int32)
+    cache = fresh_cache()
+    got1, cache = run(cache, {3: (ids[:90], 0)}, rows=100)
+    got2, _ = run(cache, {3: (ids[90:], 90)}, rows=100)
+    same, other = (want(ids, [89, 119], **over) for over in (
+        {}, {"reference_without": [reading]}))
+    for lg, s, o in zip((got1[3], got2[3]), same, other):
+        assert np.abs(lg - s).max() < 2e-4 * s.std() + 1e-6
+        assert np.abs(lg - o).max() > 0.02 * s.std(), reading

@@ -57,14 +57,14 @@ def served(request):
         kw.update(draft_model="tiny", draft_len=3)
     eng = InferenceEngine(cfg, EngineConfig(**kw), ByteTokenizer())
     if depth:
-        assert eng._pipe_warm_wait(300) == "ready"
+        assert eng._pipe_warm_wait(120) == "ready"
     try:
         yield cfg, eng, spec
     finally:
         mp.undo()
 
 
-def _requests(cfg, tag, stop_tok=None, shift=0):
+def _traffic(cfg, tag, stop_tok=None, shift=0):
     """Six requests (seven with ``stop_tok``: one that ends on a stop
     token): one- and multi-chunk prompts, greedy and seeded sampling.
     ``shift`` makes the prompts other ones, which no earlier case left in
@@ -161,7 +161,7 @@ def _solo(eng, reqs):
     return out
 
 
-def _drain_now(req):
+def _frames_now(req):
     """The frames in the request's queue NOW, with no further step."""
     frames = []
     while True:
@@ -209,15 +209,15 @@ def _watch_resolves(eng, monkeypatch):
 def test_a_burst_streams_what_its_requests_stream_alone(served):
     cfg, eng, _ = served
     all0, def0 = _counts(eng)
-    first = _solo(eng, _requests(cfg, "solo"))
+    first = _solo(eng, _traffic(cfg, "solo"))
     stop_tok = first[0][0][2]
-    solo = first + _solo(eng, _requests(cfg, "solo2", stop_tok)[6:])
+    solo = first + _solo(eng, _traffic(cfg, "solo2", stop_tok)[6:])
     all1, def1 = _counts(eng)
     # one caller on two slots: its sequential resolves defer all the same
     assert 0 < def1 - def0 <= all1 - all0
     assert solo[6] == (first[0][0][:2], "stop")
 
-    reqs = _requests(cfg, "burst", stop_tok)
+    reqs = _traffic(cfg, "burst", stop_tok)
     for r in reqs:
         eng.add_request(r)
     _drive(eng)
@@ -234,7 +234,7 @@ def test_a_burst_streams_what_its_requests_stream_alone(served):
 
 def test_an_abort_follows_the_tokens_deferred_before_it(served):
     cfg, eng, _ = served
-    reqs = _requests(cfg, "ab")
+    reqs = _traffic(cfg, "ab")
     for r in reqs:
         eng.add_request(r)
     victim = held = None
@@ -278,7 +278,7 @@ def test_deliver_runs_behind_the_next_dispatch(served, tmp_path):
     _, def0 = _counts(eng)
     assert eng.profiler.start(str(tmp_path / "p"))["ok"]
     try:
-        reqs = _requests(cfg, "win", shift=101)
+        reqs = _traffic(cfg, "win", shift=101)
         for r in reqs:
             eng.add_request(r)
         _drive(eng)
@@ -333,7 +333,7 @@ def test_callers_within_the_slots_defer_a_sequential_resolves_frames(
     all0, def0 = _counts(eng)
     assert eng.profiler.start(str(tmp_path / "q"))["ok"]
     try:
-        reqs = _requests(cfg, "fit", shift=53)[:SLOTS]
+        reqs = _traffic(cfg, "fit", shift=53)[:SLOTS]
         for r in reqs:
             eng.add_request(r)
         _drive(eng)
@@ -361,13 +361,22 @@ def test_the_drains_last_resolve_defers_and_a_steady_one_does_not(
         served, monkeypatch):
     cfg, eng, _ = served
     resolves, seen = _watch_resolves(eng, monkeypatch)
-    reqs = [_requests(cfg, "pipe", shift=67)[i] for i in (0, 4)]
-    for r in reqs:
-        eng.add_request(r)
-    # idle, and the overshoot dispatch behind the last stream resolved too
-    _drive(eng, until=lambda e: e.idle and not e._pipe_inflight)
-    for r in reqs:
-        assert _stream(_frames(r))[1] == "length"
+    # Whether a pipelined resolve finds a dispatch behind it is a race of
+    # the host against the device (a step resolves at once what is ready):
+    # on a loaded machine every dispatch of two short streams can be done
+    # before the host looks.  The traffic repeats, on other prompts, until
+    # one resolve was a steady one; every check below holds of every round.
+    for attempt in range(8):
+        reqs = [_traffic(cfg, f"pipe{attempt}", shift=67 + attempt)[i]
+                for i in (0, 4)]
+        for r in reqs:
+            eng.add_request(r)
+        # idle, and the overshoot dispatch behind the last stream resolved
+        _drive(eng, until=lambda e: e.idle and not e._pipe_inflight)
+        for r in reqs:
+            assert _stream(_frames(r))[1] == "length"
+        if eng._pipe_depth < 2 or "steady" in resolves:
+            break
     if eng._pipe_depth < 2:
         assert set(resolves) == {"seq"}
     else:
@@ -383,12 +392,12 @@ def test_the_drains_last_resolve_defers_and_a_steady_one_does_not(
 
 def test_a_request_that_ends_alone_is_delivered_by_the_next_step(served):
     cfg, eng, _ = served
-    req = _requests(cfg, "alone", shift=83)[0]
+    req = _traffic(cfg, "alone", shift=83)[0]
     eng.add_request(req)
     _drive(eng, until=_quiet)
     # its last resolve is behind it; what that held back is not lost and
     # the engine is not idle over it
-    got = _drain_now(req)
+    got = _frames_now(req)
     held = [o for r, o in eng._deferred or [] if r is req]
     assert (got + held)[-1].finished and (held or eng.idle)
     assert eng.idle == (eng._deferred is None)
@@ -396,7 +405,7 @@ def test_a_request_that_ends_alone_is_delivered_by_the_next_step(served):
     eng.step(block_s=0.01)
     # one step() that issues nothing delivers it
     assert _dispatches(eng) == n0
-    got += _drain_now(req)
+    got += _frames_now(req)
     assert got[len(got) - len(held):] == held
     assert _stream(got)[1] == "length"
     assert eng._deferred is None and eng.idle
@@ -406,14 +415,14 @@ def test_a_first_tokens_frame_is_put_exactly_once(served):
     cfg, eng, _ = served
     n0 = _observed(eng.metrics.time_to_first_token_seconds)
     _, def0 = _counts(eng)
-    reqs = _requests(cfg, "ttft", shift=97)
+    reqs = _traffic(cfg, "ttft", shift=97)
     for r in reqs:
         eng.add_request(r)
     _drive(eng)
     assert _observed(eng.metrics.time_to_first_token_seconds) - n0 == len(
         reqs)
     for r in reqs:
-        frames = _drain_now(r)
+        frames = _frames_now(r)
         assert _stream(frames)[1] == "length"
         firsts = [f for f in frames if f.ttft_s is not None]
         # made in a sequential resolve (a prompt completes in one), kept
@@ -439,7 +448,7 @@ def test_the_engine_never_waits_on_its_queue_over_a_deferral(served,
         return real_get(*a, **kw)
 
     monkeypatch.setattr(eng._queue, "get", get)
-    reqs = _requests(cfg, "drain")
+    reqs = _traffic(cfg, "drain")
     for r in reqs:
         eng.add_request(r)
     _drive(eng)
@@ -461,7 +470,7 @@ def test_the_engine_never_waits_on_its_queue_over_a_deferral(served,
 def test_the_engine_thread_drains_a_burst_and_stops_clean(served):
     cfg, eng, _ = served
     _, def0 = _counts(eng)
-    reqs = _requests(cfg, "thr")
+    reqs = _traffic(cfg, "thr")
     eng.start()
     try:
         for r in reqs:
@@ -476,7 +485,7 @@ def test_the_engine_thread_drains_a_burst_and_stops_clean(served):
 
 def test_a_step_that_raises_delivers_what_was_deferred_first(served):
     cfg, eng, spec = served
-    reqs = _requests(cfg, "flt")
+    reqs = _traffic(cfg, "flt")
     for r in reqs:
         eng.add_request(r)
     held = None

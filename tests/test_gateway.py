@@ -15,6 +15,8 @@ from arks_tpu.control import resources as res
 from arks_tpu.control.store import Store
 from arks_tpu.gateway.server import Gateway
 
+import harness
+
 PROMPT_TOKENS, COMPLETION_TOKENS = 7, 5
 
 
@@ -103,6 +105,16 @@ def world():
     backend.stop()
 
 
+def _token_index_caught_up(gw, store):
+    """The gateway's token index is fed by a watch thread: wait until it
+    holds alice's token as the store has it now (a fixed sleep is a bet on
+    the host's clock)."""
+    want = store.get(res.Token, "alice", "team-a").spec
+    harness.wait_for(
+        lambda: gw.qos._by_token["sk-alice"].spec == want, timeout=10,
+        interval=0.01, what="the token index to hold alice's updated token")
+
+
 def _post(gw, body, token="sk-alice", path="/v1/chat/completions"):
     req = urllib.request.Request(
         f"http://127.0.0.1:{gw.port}{path}",
@@ -144,7 +156,7 @@ def test_unknown_model_404(world):
     t = store.get(res.Token, "alice", "team-a")
     t.spec["qos"].append({"endpoint": {"name": "ghost"}, "rateLimits": []})
     store.update(t)
-    time.sleep(0.2)
+    _token_index_caught_up(gw, store)
     code, _ = _err(lambda: _post(gw, {"model": "ghost"}))
     assert code == 404
 
@@ -204,7 +216,7 @@ def test_quota_exhaustion_429(world):
     t = store.get(res.Token, "alice", "team-a")
     t.spec["qos"][0]["rateLimits"] = [{"type": "rpm", "value": 100}]
     store.update(t)
-    time.sleep(0.3)  # token index pump
+    _token_index_caught_up(gw, store)
     for _ in range(5):  # 5 * 12 = 60 >= limit 60
         _post(gw, {"model": "m1"}).read()
     code, body = _err(lambda: _post(gw, {"model": "m1"}))
@@ -384,7 +396,7 @@ def test_quota_429_carries_retry_after(world):
     t = store.get(res.Token, "alice", "team-a")
     t.spec["qos"][0]["rateLimits"] = [{"type": "rpm", "value": 100}]
     store.update(t)
-    time.sleep(0.3)
+    _token_index_caught_up(gw, store)
     for _ in range(5):
         _post(gw, {"model": "m1"}).read()
     try:

@@ -392,7 +392,8 @@ def test_concurrent_compiles_of_same_key_build_once():
     for t in threads:
         t.start()
     for t in threads:
-        t.join()
+        t.join(60)
+        assert not t.is_alive(), "a compile of [0-9]+ did not return in 60 s"
     assert len(builds) == 1, "same-key compiles must dedupe onto one build"
     assert len(out) == 6 and all(g is out[0] for g in out)
 

@@ -12,10 +12,8 @@ by name.
 
 The served-against-reference comparison (with the must-fail controls) is
 ``benchmarks/tests/test_reference_latent_linear_moe.py``, imported into
-tier-1 by ``tests/test_benchmark_contract.py``."""
+tier-1 by ``tests/test_contract_latent_linear_moe.py``."""
 
-import dataclasses
-import json
 import os
 
 import jax
@@ -23,16 +21,15 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from arks_tpu.models import moe, quant, transformer as tf
+from arks_tpu.models import moe, transformer as tf
 from arks_tpu.models.config import ModelConfig, get_config
-# The float64 recurrence (a ``[n, H, 1]`` log decay broadcasts in it as a
-# ``[n, H, d]`` one), the ragged layouts, the three requests on two slots
-# and the loop that drains them: the ``solar_open2`` block's.
-from test_linear_layers import _LAYOUTS, _drain, _recurrence, _requests
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-CONFIGS = os.path.join(ROOT, "benchmarks", "configs")
-GIGA = os.path.join(CONFIGS, "gigachat3.5-432b-ep8-l5")
+import harness
+# The float64 recurrence (a ``[n, H, 1]`` log decay broadcasts in it as a
+# ``[n, H, d]`` one) and the ragged layouts: the ``solar_open2`` block's.
+from test_linear_layers import _LAYOUTS, _recurrence
+
+GIGA = "gigachat3.5-432b-ep8-l5"
 TINY = "tiny-latent-linear-moe"
 
 
@@ -41,18 +38,10 @@ def _published() -> dict:
     file with what its ``reduced`` lists put back (40 layers behind 3 dense
     ones, a latent layer every fourth, 256 experts, the whole vocabulary,
     the two draft modules)."""
-    with open(os.path.join(GIGA, "config.json")) as f:
-        d = json.load(f)
-    d.update(num_hidden_layers=40, first_k_dense_replace=3,
-             full_attention_layers=list(range(3, 40, 4)),
-             n_routed_experts=256, vocab_size=128256,
-             num_nextn_predict_layers=2)
-    return d
-
-
-def _tiny_config(**over) -> dict:
-    with open(os.path.join(CONFIGS, TINY, "config.json")) as f:
-        return {**json.load(f), "n_routed_experts": 16, **over}
+    return harness.published(
+        GIGA, num_hidden_layers=40, first_k_dense_replace=3,
+        full_attention_layers=list(range(3, 40, 4)),
+        n_routed_experts=256, vocab_size=128256, num_nextn_predict_layers=2)
 
 
 # ---------------------------------------------------------------------------
@@ -106,10 +95,10 @@ def test_the_draft_modules_are_read_and_dropped_not_refused():
 
 
 def test_the_benchmark_configuration_is_a_dense_layer_a_period_and_a_share():
-    with open(os.path.join(GIGA, "deploy.json")) as f:
-        deploy = json.load(f)
+    deploy = harness.deploy(GIGA)
     share = deploy["share"]
-    cfg = ModelConfig.from_hf_config(GIGA, name="g").with_expert_share(
+    cfg = ModelConfig.from_hf_config(
+        os.path.join(harness.CONFIGS, GIGA), name="g").with_expert_share(
         share["chips_per_layer"], share["index"])
     assert cfg.layer_kinds() == ("linear",) * 4 + ("full",)
     assert (cfg.head_layers, cfg.short_period, cfg.num_periods) == (1, -1, 1)
@@ -117,8 +106,7 @@ def test_the_benchmark_configuration_is_a_dense_layer_a_period_and_a_share():
     assert cfg.vocab_size * 8 == share["published"]["vocab_size"]
     assert 7.5e9 < cfg.num_params() < 7.8e9     # one byte a parameter
     assert deploy["state_dtype"] == "float32"
-    pub = _published()
-    here = json.load(open(os.path.join(GIGA, "config.json")))
+    pub, here = _published(), harness.published(GIGA)
     assert sorted(k for k in pub if pub[k] != here[k]) \
         == sorted(deploy["reduced"])
     # What a slot holds whatever the context, and a token's latent row.
@@ -130,42 +118,13 @@ def test_the_benchmark_configuration_is_a_dense_layer_a_period_and_a_share():
 
 
 def test_the_tiny_preset_is_what_its_config_file_says():
-    cfg = ModelConfig.from_hf_config(_tiny_config(), name=TINY)
+    cfg = ModelConfig.from_hf_config(
+        harness.published(TINY, n_routed_experts=16), name=TINY)
     assert cfg == get_config(TINY)
     assert cfg.layer_kinds() == ("linear", "linear", "linear", "full",
                                  "linear", "linear", "full", "linear")
     assert (cfg.head_layers, cfg.short_period, cfg.num_periods,
             cfg.inner_tail) == (2, 1, 1, 1)
-
-
-@pytest.mark.parametrize("change, word", [
-    (dict(full_attention_layers=[3, 5]), "full_attention_layers"),
-    (dict(full_attention_layers=[1, 4, 7]), "full_attention_layers"),
-    (dict(full_attention_layers=[]), "full_attention_layers"),
-    (dict(linear_attention_type="KimiDeltaAttention"),
-     "linear_attention_type"),
-    (dict(linear_gating_type="swish"), "linear_gating_type"),
-    (dict(linear_value_head_dim=32), "linear_value_head_dim"),
-    (dict(linear_num_key_heads=3), "linear_num_value_heads"),
-    (dict(linear_conv_kernel_dim=None), "linear_conv_kernel_dim"),
-    (dict(norm_type="LayerNorm"), "norm_type"),
-    (dict(layernorm_type="post"), "layernorm_type"),
-    (dict(layernorm_gating_weight=0), "layernorm_gating_weight"),
-    (dict(hidden_act="gelu"), "hidden_act"),
-    (dict(scoring_func="softmax"), "scoring_func"),
-    (dict(topk_method="greedy"), "topk_method"),
-    (dict(n_group=4, topk_group=2), "group-limited"),
-    (dict(attention_bias=True), "attention_bias"),
-    (dict(use_shared_expert_sigmoid=True), "use_shared_expert_sigmoid"),
-    (dict(use_mla_scaling_factor=False), "use_mla_scaling_factor"),
-    (dict(num_key_value_heads=2), "num_key_value_heads"),
-    (dict(qk_head_dim=32), "qk_head_dim"),
-    (dict(kv_lora_rank=0), "kv_lora_rank"),
-    (dict(rope_scaling=dict(type="linear", factor=2)), "rope_scaling"),
-])
-def test_from_hf_config_refuses_what_the_block_cannot_express(change, word):
-    with pytest.raises(ValueError, match=word):
-        ModelConfig.from_hf_config(_tiny_config(**change), name="m")
 
 
 @pytest.mark.parametrize("key, value", [
@@ -295,215 +254,8 @@ def test_key_heads_are_shared_by_value_heads_behind_one_convolution():
 
 
 # ---------------------------------------------------------------------------
-# The step program against the reference family's full forward
+# The clamp
 # ---------------------------------------------------------------------------
-
-
-def _reference():
-    import sys
-    sys.path.insert(0, ROOT)
-    from benchmarks import manifest
-    with open(os.path.join(CONFIGS, TINY, "deploy.json")) as f:
-        deploy = json.load(f)
-    config = manifest.with_share(_tiny_config(n_routed_experts=8), deploy)
-    return manifest.load_reference("latent_linear_moe"), config
-
-
-def _norm_leaf(name: str) -> bool:
-    return name.endswith("_norm") and name != "o_norm"
-
-
-@pytest.fixture(scope="module")
-def stepper():
-    """The step program on float32 activations over the family's own
-    weights (what is stored in bfloat16 widened, which is exact), sequences
-    through 4 slots: (fresh cache, step, reference forward).  The gated
-    norms' weights, zeros as seeded (a scale of 1 under either reading of
-    the norm), are redrawn at 0.5 sigma in program and reference alike, so
-    that the norm's form reaches the logits."""
-    ref, config = _reference()
-    seed = 11
-    cfg = get_config(TINY)
-    cfg = dataclasses.replace(cfg, num_experts=8).with_expert_share(2, 1)
-    params = jax.tree.map(
-        lambda a: a.astype(jnp.float32) if a.dtype == jnp.bfloat16 else a,
-        quant.init_params_quantized(cfg, jax.random.PRNGKey(seed),
-                                    jnp.bfloat16, bits=8))
-    weights = ref.generate_weights(config, seed)
-    rng = np.random.default_rng(3)
-    for path in sorted(weights):
-        if _norm_leaf(path.rsplit("/", 1)[-1]):
-            assert not weights[path].any()           # seeded: a scale of 1
-            w = (rng.standard_normal(weights[path].shape) * 0.5).astype(
-                np.float32)
-            weights[path] = w
-            tree, _, leaf = path.rpartition("/")
-            if tree:
-                params[tree][leaf] = jnp.asarray(w)
-            else:
-                params[leaf] = jnp.asarray(w)
-    step = jax.jit(lambda c, *a: tf.mixed_step(params, cfg, c, *a))
-    slots, page, max_pages = 4, 16, 16
-    tables = jnp.arange(slots * max_pages, dtype=jnp.int32).reshape(
-        slots, max_pages)
-
-    def fresh_cache():
-        return tf.init_paged_cache(cfg, slots * max_pages, page, jnp.float32,
-                                   state_slots=slots)
-
-    def run(cache, lanes, rows=100):
-        """One step over ``lanes``: {slot: (token ids, first position)}.
-        Returns (logits at each lane's last row, cache)."""
-        a = dict(tokens=np.zeros(rows, np.int32),
-                 slot=np.full(rows, -1, np.int32),
-                 pos=np.full(rows, page * max_pages, np.int32),
-                 src=np.zeros(slots, np.int32), qs=np.zeros(slots, np.int32),
-                 ql=np.zeros(slots, np.int32), ps=np.zeros(slots, np.int32))
-        at = 1                                        # a padding row ahead
-        for slot, (ids, p0) in lanes.items():
-            n = len(ids)
-            a["tokens"][at:at + n], a["slot"][at:at + n] = ids, slot
-            a["pos"][at:at + n] = np.arange(p0, p0 + n)
-            a["qs"][slot], a["ql"][slot], a["ps"][slot] = at, n, p0
-            a["src"][slot] = at + n - 1
-            at += n
-        logits, cache = step(cache, tables, *(jnp.asarray(a[k]) for k in (
-            "tokens", "slot", "pos", "src", "qs", "ql", "ps")))
-        return {s: np.asarray(logits[s]) for s in lanes}, cache
-
-    def want(ids, rows, **over):
-        return ref.forward(dict(config, **over), weights,
-                           np.asarray(ids, np.int32)[None],
-                           np.asarray(rows, np.int32)[None])[0]
-
-    return fresh_cache, run, want
-
-
-def test_a_prompt_cut_at_odd_lengths_then_decoded_is_the_full_forward(
-        stepper):
-    """Chunks of 70, 63, 1, 1, 37 rows (ends inside blocks of the scan,
-    inside pages of 16, single rows between chunks), then decode steps
-    through latent pages and state: each step's logits are the reference's
-    one forward at that position.  Meanwhile ANOTHER sequence decodes and
-    then prefills in the same flat batches, into a slot whose last sequence
-    left its state there and reads zeros at position 0."""
-    fresh_cache, run, want = stepper
-    rng = np.random.default_rng(5)
-    a_ids = rng.integers(2, 258, 180).astype(np.int32)
-    b_ids = rng.integers(2, 258, 90).astype(np.int32)
-    c_ids = rng.integers(2, 258, 40).astype(np.int32)
-    cache = fresh_cache()
-    assert cache.v is None and cache.k.shape[0] == 2      # the latent layers
-    assert cache.lin.s.shape[0] == 6                      # the linear ones
-    _, cache = run(cache, {0: (c_ids, 0)})
-    assert float(jnp.abs(cache.lin.s[:, 0]).max()) > 0
-    got_a, got_b, pa, pb = [], [], 0, 0
-    plan = [(70, 20), (63, 1), (1, 1), (1, 30), (37, 1), (1, 37), (1, 0),
-            (1, 0), (5, 0)]
-    for ta, tb in plan:
-        lanes = {2: (a_ids[pa:pa + ta], pa)}
-        if tb:
-            lanes[0] = (b_ids[pb:pb + tb], pb)        # reuses C's slot
-        out, cache = run(cache, lanes)
-        pa, pb = pa + ta, pb + tb
-        got_a.append((pa - 1, out[2]))
-        if tb:
-            got_b.append((pb - 1, out[0]))
-    for ids, got in ((a_ids, got_a), (b_ids, got_b)):
-        rows = [r for r, _ in got]
-        ref_logits = want(ids, rows)
-        for (r, lg), w in zip(got, ref_logits):
-            # float32 on both sides: 1e-5 of a logit sigma as read, the
-            # chunk form's triangular solve against the token recurrence.
-            assert np.abs(lg - w).max() < 2e-4 * w.std() + 1e-6, r
-    assert not float(jnp.abs(cache.lin.s[:, (1, 3)]).max())
-
-
-def test_a_stale_state_would_show(stepper):
-    fresh_cache, run, want = stepper
-    rng = np.random.default_rng(6)
-    ids = rng.integers(2, 258, 30).astype(np.int32)
-    cache = fresh_cache()
-    _, cache = run(cache, {0: (rng.integers(2, 258, 40).astype(np.int32), 0)})
-    clean, _ = run(cache, {0: (ids, 0)})
-    w = want(ids, [29])[0]
-    assert np.abs(clean[0] - w).max() < 2e-4 * w.std() + 1e-6
-    dirty, _ = run(cache, {0: (ids[1:], 1)})
-    assert np.abs(dirty[0] - w).max() > 0.05 * w.std()
-
-
-def test_a_state_kept_in_bfloat16_shows_where_the_activations_are_float32(
-        stepper):
-    fresh_cache, run, want = stepper
-    rng = np.random.default_rng(8)
-    ids = rng.integers(2, 258, 96).astype(np.int32)
-    got, _ = run(fresh_cache(), {1: (ids, 0)})
-    sound = want(ids, [95])[0]
-    rounded = want(ids, [95], reference_state_dtype="bfloat16")[0]
-    assert np.abs(got[1] - sound).max() < 2e-4 * sound.std() + 1e-6
-    assert np.abs(got[1] - rounded).max() > 5e-3 * sound.std()
-
-
-@pytest.mark.parametrize("reading", [
-    "norm_sigmoid", "post_norm", "gate_scale", "conv", "state", "mscale",
-    "gate", "router_bias", "swiglu_limit"])
-def test_program_and_reference_hold_the_same_reading_of_every_open_key(
-        stepper, reading):
-    """Each reading the published config leaves open (``deploy.json``'s
-    ``assumed``), computed the OTHER way by the reference on the same
-    weights, parts from the program by a hundred times and more what the
-    shared reading does (read at this size: 0.04 of a logit sigma for the
-    softmax scale's ``m^2``, 0.6 and more for every other)."""
-    fresh_cache, run, want = stepper
-    rng = np.random.default_rng(9)
-    ids = rng.integers(2, 258, 120).astype(np.int32)
-    cache = fresh_cache()
-    got1, cache = run(cache, {3: (ids[:90], 0)}, rows=100)
-    got2, _ = run(cache, {3: (ids[90:], 90)}, rows=100)
-    same, other = (want(ids, [89, 119], **over) for over in (
-        {}, {"reference_without": [reading]}))
-    for lg, s, o in zip((got1[3], got2[3]), same, other):
-        assert np.abs(lg - s).max() < 2e-4 * s.std() + 1e-6
-        assert np.abs(lg - o).max() > 0.02 * s.std(), reading
-
-
-# ---------------------------------------------------------------------------
-# A share of a layer
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("grouped", [True, False])
-def test_the_shares_of_a_routed_layer_add_up_to_the_uncut_layer(grouped):
-    """Eight chips hold two experts each of a 16-expert layer (sigmoid
-    scores, a selection bias, top-4 normalised, times 2.5, every SwiGLU
-    clamped); the routed parts their layers return, the shared expert
-    (which every chip computes alike) counted once, add up to the layer
-    held whole, and the clamp bites at this size."""
-    cfg = get_config(TINY)
-    mp = jax.tree.map(lambda a: a[0], moe.init_moe_params(
-        cfg, jax.random.PRNGKey(7), jnp.float32, layers=1))
-    assert "router_bias" in mp and "shared_gate" not in mp
-    x = jax.random.normal(jax.random.PRNGKey(2), (1, 96, 64), jnp.float32)
-    valid = jnp.ones((1, 96), bool)
-    whole, pairs = moe.moe_ffn(x, mp, cfg, grouped=False, row_valid=valid)
-    assert pairs.tolist() == [96 * 4, 0, 0]
-    unclamped, _ = moe.moe_ffn(x, mp, dataclasses.replace(
-        cfg, swiglu_limit=0.0), grouped=False, row_valid=valid)
-    assert float(jnp.abs(whole - unclamped).max()) \
-        > 0.05 * float(jnp.abs(whole).max())
-    shared = moe._shared_expert(x, mp, cfg)
-    eighth = dataclasses.replace(cfg, num_experts=2)
-    total, held_all = jnp.zeros_like(whole), 0
-    for rank in range(8):
-        part = dict(mp, **{k: mp[k][rank * 2:(rank + 1) * 2]
-                           for k in ("w_gate", "w_up", "w_down")})
-        out, held = moe.moe_ffn(x, part, eighth.with_expert_share(8, rank),
-                                grouped=grouped, row_valid=valid)
-        total = total + out - shared
-        held_all += int(held[0])
-    assert held_all == 96 * 4
-    np.testing.assert_allclose(np.asarray(total + shared),
-                               np.asarray(whole), rtol=2e-4, atol=2e-6)
 
 
 def test_the_clamp_is_the_stated_function():
@@ -540,40 +292,34 @@ def test_the_pool_is_the_latent_layers_and_the_state_a_fixed_size_a_slot():
                             state_slots=3)
 
 
-def _engine(cfg=None, **over):
-    from arks_tpu.engine.engine import EngineConfig, InferenceEngine
-    from arks_tpu.engine.tokenizer import ByteTokenizer
-    cfg = cfg or get_config(TINY)
-    kw = dict(model=cfg.name, num_slots=2, max_cache_len=256,
-              prefill_buckets=(16,), prefill_chunk=16, weight_dtype="int8",
-              seed=3)
-    kw.update(over)
-    return InferenceEngine(cfg, EngineConfig(**kw), ByteTokenizer())
+def _counted(eng):
+    m = eng.metrics
+    return dict(
+        starts=m.linear_state_starts_total.total(),
+        state_steps=m.kv_held_byte_steps_total.get(kind="state"),
+        page_steps=m.kv_held_byte_steps_total.get(kind="pages"),
+        latent_rows=m.mixed_latent_rows_total.total(),
+        hits=m.prefix_cache_hit_tokens_total.total())
 
 
 @pytest.fixture(scope="module")
 def depth0_streams():
-    eng = _engine()
+    """One drain of one engine: its streams, and what its counters rose by
+    across the drain."""
     seen = []
-    try:
+    with harness.fresh(TINY) as eng:
         labels = dict(eng.resolved_config)
         assert eng._cache.k.shape[0] == 2 and eng._cache.v is None
         assert eng._cache.lin is not None
-        toks, lps = _drain(
-            eng, _requests(logprobs=1),
+        before = _counted(eng)
+        toks, lps = harness.drain(
+            eng, harness.requests(logprobs=1),
             lambda e: seen.append((e.metrics.linear_state_bytes.get(),
                                    e.metrics.kv_page_bytes.get())))
-        m = eng.metrics
-        stats = dict(
-            labels=labels, starts=m.linear_state_starts_total.total(),
-            state_steps=m.kv_held_byte_steps_total.get(kind="state"),
-            page_steps=m.kv_held_byte_steps_total.get(kind="pages"),
-            latent_rows=m.mixed_latent_rows_total.total(),
-            slot_bytes=eng._lin_slot_bytes, page_bytes=eng._page_bytes,
-            hits=m.prefix_cache_hit_tokens_total.total(), seen=seen,
-            rendered=m.registry.render())
-    finally:
-        eng.stop()
+        stats = {k: v - before[k] for k, v in _counted(eng).items()}
+        stats.update(labels=labels, slot_bytes=eng._lin_slot_bytes,
+                     page_bytes=eng._page_bytes, seen=seen,
+                     rendered=eng.metrics.registry.render())
     return toks, lps, stats
 
 
@@ -600,96 +346,14 @@ def test_the_engine_labels_and_counts_latent_pages_and_state(depth0_streams):
     assert s["hits"] == 0                    # no prefix is indexed or matched
 
 
-def test_the_pipelined_path_gives_the_sequential_streams(depth0_streams,
-                                                         monkeypatch):
-    toks0, lps0, _ = depth0_streams
-    monkeypatch.setenv("ARKS_PIPELINE_DEPTH", "2")
-    eng = _engine()
-    try:
-        assert eng.resolved_config["pipeline_depth"] == "2"
-        assert eng._pipe_warm_wait(600.0) == "ready"
-        toks, lps = _drain(eng, _requests(logprobs=1))
-        assert eng.metrics.pipeline_depth_occupancy._data   # it engaged
-        for rid in toks:
-            same = next((i for i, (a, b) in enumerate(
-                zip(toks[rid], toks0[rid])) if a != b), len(toks[rid]))
-            assert same >= 1, (rid, toks[rid], toks0[rid])
-            n = min(same + 1, len(lps[rid]))
-            np.testing.assert_allclose(lps[rid][:n], lps0[rid][:n],
-                                       atol=2e-3)
-        assert sum(toks[r] == toks0[r] for r in toks) >= 2
-        assert len(eng._free) == eng.ecfg.num_slots
-    finally:
-        eng.stop()
-
-
-@pytest.mark.parametrize("over, env, word", [
-    (dict(kv_cache_dtype="int8"), {}, "int8 / int4 latent row"),
-    (dict(kv_cache_dtype="int4"), {}, "int8 / int4 latent row"),
-    (dict(kv_layout="slot"), {}, "slot layout"),
-    (dict(prefill_chunk=None), {}, "chunked prefill"),
-    (dict(draft_model="tiny-gqa"), {}, "speculative"),
-    ({}, {"ARKS_PREFIX_HOST_MB": "64"}, "host spill tier"),
-    ({}, {"ARKS_PREFIX_DISK_MB": "64"}, "disk spill tier"),
-    ({}, {"ARKS_RESIDENCY_WINDOW_PAGES": "6"}, "windowed residency"),
-    ({}, {"ARKS_PREEMPT": "1"}, "KV swap"),
-    ({}, {"ARKS_PEER_ADDRS": "10.0.0.1:8080"}, "peer fetch"),
-    ({}, {"ARKS_MIXED_STEP": "0"}, "legacy scheduler"),
-])
-def test_each_refused_argument_is_named_in_one_sentence(over, env, word,
-                                                        monkeypatch):
-    """A model that is latent AND linear passes ONE preflight, whose
-    sentence says what the model is once and names each refused argument;
-    the two older preflights' sentences are not joined."""
-    for k, v in env.items():
-        monkeypatch.setenv(k, v)
-    with pytest.raises(ValueError, match=word) as e:
-        _engine(**over)
-    text = str(e.value)
-    assert text.count("cannot be served with") == 1
-    assert "a fixed state a slot beside latent-attention layers" in text
-    assert "GQA layers" not in text
-    if "draft_model" in over or any(k.startswith(("ARKS_PRE", "ARKS_PEER",
-                                                  "ARKS_RES")) for k in env):
-        assert "neither a latent row nor a recurrent state" in text
-
-
 def test_every_refused_argument_of_a_pod_is_named_together(monkeypatch):
     monkeypatch.setenv("ARKS_PREEMPT", "1")
     monkeypatch.setenv("ARKS_PREFIX_HOST_MB", "64")
     with pytest.raises(ValueError) as e:
-        _engine(kv_cache_dtype="int8", draft_model="tiny-gqa")
+        harness.engine(TINY, kv_cache_dtype="int8", draft_model="tiny-gqa")
     for word in ("kv_cache_dtype=int8", "speculative decoding",
                  "ARKS_PREFIX_HOST_MB", "ARKS_PREEMPT"):
         assert word in str(e.value)
-
-
-def test_a_mesh_and_disaggregation_are_refused():
-    from arks_tpu.engine.engine import EngineConfig, InferenceEngine
-    from arks_tpu.engine.tokenizer import ByteTokenizer
-    from arks_tpu.parallel.mesh import make_mesh
-    cfg = get_config(TINY)
-    mesh = make_mesh(tensor_parallel=2, devices=jax.devices()[:2])
-    with pytest.raises(ValueError, match="device mesh") as e:
-        InferenceEngine(cfg, EngineConfig(
-            model=cfg.name, num_slots=2, max_cache_len=64,
-            prefill_buckets=(16,), prefill_chunk=16, tensor_parallel=2),
-            ByteTokenizer(), mesh=mesh)
-    assert "neither the latent block nor the linear layers' state" \
-        in str(e.value)
-    with pytest.raises(NotImplementedError, match="sharding rules"):
-        tf.param_pspecs(cfg, 2)
-    from arks_tpu.server.__main__ import build_engine, build_server, parse_args
-    ns = parse_args(["--model", TINY, "--platform", "cpu",
-                     "--num-slots", "2", "--max-model-len", "64",
-                     "--tensor-parallel-size", "1",
-                     "--disaggregation-mode", "prefill"])
-    eng = build_engine(ns)
-    try:
-        with pytest.raises(ValueError, match="nor the recurrent state"):
-            build_server(ns, eng)
-    finally:
-        eng.stop()
 
 
 # ---------------------------------------------------------------------------
@@ -745,14 +409,14 @@ def test_the_accepted_readers_read_the_new_family_unedited():
                        ("mla_share.tput", 10.0), ("moe_share.tput", 35.0)):
         assert manifest.load_reader(name)({"device": dev}) \
             == pytest.approx(want)
-    ref, config = _reference()
+    ref, config = harness.reference(TINY, "latent_linear_moe",
+                                    n_routed_experts=8)
     a = ref.arch(config)
     assert ref.kernel_shapes(a) == {"heads": 4, "row": 40, "value": 32,
                                     "layers": 2}
     assert ref.linear_kernel_shapes(a) == {
         "heads": 4, "head_dim": 16, "layers": 6, "state_bytes": 4}
-    with open(os.path.join(GIGA, "config.json")) as f:
-        big = ref.arch(json.load(f))
+    big = ref.arch(harness.published(GIGA))
     assert ref.kernel_shapes(big) == {"heads": 64, "row": 576, "value": 512,
                                       "layers": 1}
     assert ref.linear_kernel_shapes(big) == {

@@ -9,7 +9,6 @@ forward, and what a latent model refuses by name.
 The reference is ``benchmarks/references/mla_moe.py`` (the NON-absorbed,
 published form, float32, no cache); it imports nothing of the program."""
 
-import json
 import os
 
 import jax
@@ -20,8 +19,9 @@ import pytest
 from arks_tpu.models import moe, quant, transformer as tf
 from arks_tpu.models.config import ModelConfig, get_config
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-KIMI = os.path.join(ROOT, "benchmarks", "configs", "kimi-k2.5-ep32-l9")
+import harness
+
+KIMI = os.path.join(harness.CONFIGS, "kimi-k2.5-ep32-l9")
 
 
 def _reference():
@@ -32,9 +32,8 @@ def _reference():
 def _tiny_config(**over) -> dict:
     """The public-style ``config.json`` of the preset ``tiny-mla-moe``
     (benchmarks/configs/tiny-mla-moe holds 8 of its 16 experts)."""
-    with open(os.path.join(ROOT, "benchmarks", "configs", "tiny-mla-moe",
-                           "config.json")) as f:
-        return {**json.load(f), "n_routed_experts": 16, **over}
+    return harness.published("tiny-mla-moe",
+                             **{"n_routed_experts": 16, **over})
 
 
 # ---------------------------------------------------------------------------
@@ -75,19 +74,6 @@ def test_the_tiny_preset_is_what_its_config_file_says_and_counts_its_leaves():
         x.size for x in jax.tree.leaves(params))
     half = preset.with_expert_share(2, 1)
     assert half.router_width == 32          # a share HOLDS num_experts
-
-
-@pytest.mark.parametrize("change, word", [
-    (dict(scoring_func="softmax"), "scoring_func"),
-    (dict(topk_method="group_limited_greedy"), "topk_method"),
-    (dict(n_group=8), "group-limited"),
-    (dict(topk_group=4), "group-limited"),
-    (dict(rope_scaling={"type": "linear", "factor": 4}), "rope_scaling"),
-    (dict(num_nextn_predict_layers=1), "multi-token"),
-])
-def test_from_hf_config_refuses_what_the_block_cannot_express(change, word):
-    with pytest.raises(ValueError, match=word):
-        ModelConfig.from_hf_config(_tiny_config(**change))
 
 
 def test_a_gqa_config_with_a_rope_scaling_is_refused_not_served_plain():
@@ -733,39 +719,17 @@ def test_quantized_init_makes_both_stacks_and_the_share_leaf_by_leaf():
 # ---------------------------------------------------------------------------
 
 
-def _engine(monkeypatch=None, cfg=None, **over):
-    from arks_tpu.engine.engine import EngineConfig, InferenceEngine
-    from arks_tpu.engine.tokenizer import ByteTokenizer
-    cfg = cfg or get_config("tiny-mla-moe")
-    kw = dict(model=cfg.name, num_slots=2, max_cache_len=128,
-              prefill_buckets=(16,), prefill_chunk=16, weight_dtype="int8")
-    kw.update(over)
-    return InferenceEngine(cfg, EngineConfig(**kw), ByteTokenizer())
-
-
-def _drain(eng, reqs):
-    for r in reqs:
-        eng.add_request(r)
-    done, toks = set(), {r.request_id: [] for r in reqs}
-    for _ in range(400):
-        eng.step()
-        for r in reqs:
-            while not r.outputs.empty():
-                o = r.outputs.get()
-                toks[r.request_id] += o.token_ids
-                if o.finished:
-                    assert o.finish_reason == "length", o.error
-                    done.add(r.request_id)
-        if len(done) == len(reqs):
-            return toks
-    raise AssertionError("requests did not finish")
+# Every engine here is FRESH: the tests read counters from zero (the
+# rendered registry has no sample of a counter nothing has moved), call
+# ``_count_held`` by hand, or leave pages in the prefix index.
+_SHAPE = harness.SHAPE["tiny-mla-moe"]
 
 
 def test_engine_serves_a_latent_share_and_counts_what_it_holds():
     from arks_tpu.engine.types import Request, SamplingParams
     cfg = ModelConfig.from_hf_config(_tiny_config(n_routed_experts=8),
                                      name="tiny-mla-half")
-    eng = _engine(cfg=cfg.with_expert_share(2, 1))
+    eng = harness.engine(cfg.with_expert_share(2, 1), **_SHAPE)
     try:
         labels = eng.resolved_config
         assert labels["kv_page"] == "latent"
@@ -776,7 +740,7 @@ def test_engine_serves_a_latent_share_and_counts_what_it_holds():
         assert eng._page_bytes == eng._cache.token_bytes * eng._page_size()
         prompt = list(range(2, 42))
         sp = SamplingParams(max_tokens=5, temperature=0.0, ignore_eos=True)
-        first = _drain(eng, [Request("a", prompt, sp)])["a"]
+        first = harness.drain(eng, [Request("a", prompt, sp)])[0]["a"]
         m = eng.metrics
         rows = m.mixed_latent_rows_total.get() / cfg.num_layers
         assert rows == len(prompt) + 4          # every row fed, once
@@ -792,7 +756,7 @@ def test_engine_serves_a_latent_share_and_counts_what_it_holds():
         # The device-tier prefix cache shares pages by id: it keeps
         # working over latent pages, and the stream does not change.
         hits0 = m.prefix_cache_hit_tokens_total.total()
-        again = _drain(eng, [Request("b", prompt, sp)])["b"]
+        again = harness.drain(eng, [Request("b", prompt, sp)])[0]["b"]
         assert again == first
         assert m.prefix_cache_hit_tokens_total.total() - hits0 >= 32
     finally:
@@ -805,7 +769,7 @@ def test_a_steps_four_counts_land_on_their_counters(share):
     needed, those the loop ran, valid rows): a share's pod counts the tiles
     by kind, a pod that holds every expert renders no sample of them."""
     cfg = get_config("tiny-mla-moe")
-    eng = _engine(cfg=cfg.with_expert_share(2, 0) if share else cfg)
+    eng = harness.engine(cfg.with_expert_share(2, 0) if share else cfg)
     try:
         eng._count_held(np.asarray([9, 9, 9, 40, 11, 3, 20], np.int32), 16)
         m = eng.metrics
@@ -832,7 +796,7 @@ def test_a_shares_pod_counts_the_rows_its_experts_computed():
     import re
     from arks_tpu.engine.types import Request, SamplingParams
     cfg = get_config("tiny-mla-moe").with_expert_share(2, 0)
-    eng = _engine(cfg=cfg)
+    eng = harness.engine(cfg)
 
     def read(name):
         return float(re.search(rf"^{name} (\S+)$",
@@ -840,7 +804,7 @@ def test_a_shares_pod_counts_the_rows_its_experts_computed():
 
     try:
         sp = SamplingParams(max_tokens=5, temperature=0.0, ignore_eos=True)
-        _drain(eng, [Request("a", list(range(2, 42)), sp)])
+        harness.drain(eng, [Request("a", list(range(2, 42)), sp)])
         steps = int(read("mixed_batch_tokens_count"))
         assert steps >= 3 + 4                  # three chunks, four decode steps
         rows = steps * (2 + 16) * 16 * 2
@@ -859,65 +823,10 @@ def test_a_shares_pod_counts_the_rows_its_experts_computed():
     finally:
         eng.stop()
     # A pod that holds every expert renders no sample of it.
-    whole = _engine()
+    whole = harness.engine("tiny-mla-moe")
     try:
         whole._count_held(np.asarray([7, 40, 0, 0, 20], np.int32), 18)
         assert re.search(r"^moe_batch_rows_total \S+$",
                          whole.metrics.registry.render(), re.M) is None
     finally:
         whole.stop()
-
-
-@pytest.mark.parametrize("over, env, word", [
-    (dict(kv_cache_dtype="int8"), {}, "bf16 only"),
-    (dict(kv_cache_dtype="int4"), {}, "bf16 only"),
-    (dict(kv_layout="slot"), {}, "slot layout"),
-    (dict(prefill_chunk=None), {}, "chunked prefill"),
-    (dict(draft_model="tiny-gqa"), {}, "speculative"),
-    ({}, {"ARKS_PREFIX_HOST_MB": "64"}, "host spill tier"),
-    ({}, {"ARKS_PREFIX_DISK_MB": "64"}, "disk spill tier"),
-    ({}, {"ARKS_RESIDENCY_WINDOW_PAGES": "6"}, "windowed residency"),
-    ({}, {"ARKS_PREEMPT": "1"}, "KV swap"),
-    ({}, {"ARKS_PEER_ADDRS": "10.0.0.1:8080"}, "peer fetch"),
-    ({}, {"ARKS_MIXED_STEP": "0"}, "legacy scheduler"),
-])
-def test_a_latent_model_refuses_by_name_what_still_speaks_k_and_v(
-        over, env, word, monkeypatch):
-    for k, v in env.items():
-        monkeypatch.setenv(k, v)
-    with pytest.raises(ValueError, match=word) as e:
-        _engine(**over)
-    assert "latent attention" in str(e.value)
-
-
-def test_a_latent_model_refuses_a_mesh_and_disaggregation(monkeypatch):
-    from arks_tpu.parallel.mesh import make_mesh
-    from arks_tpu.engine.engine import EngineConfig, InferenceEngine
-    from arks_tpu.engine.tokenizer import ByteTokenizer
-    cfg = get_config("tiny-mla-moe")
-    mesh = make_mesh(tensor_parallel=2, devices=jax.devices()[:2])
-    with pytest.raises(ValueError, match="device mesh"):
-        InferenceEngine(cfg, EngineConfig(
-            model=cfg.name, num_slots=2, max_cache_len=64,
-            prefill_buckets=(16,), prefill_chunk=16, tensor_parallel=2),
-            ByteTokenizer(), mesh=mesh)
-    from arks_tpu.server.__main__ import build_engine, build_server, parse_args
-    ns = parse_args(["--model", "tiny-mla-moe", "--platform", "cpu",
-                     "--num-slots", "2", "--max-model-len", "64",
-                     "--tensor-parallel-size", "1",
-                     "--disaggregation-mode", "prefill",
-                     "--expert-parallel-size", "2",
-                     "--expert-parallel-rank", "1"])
-    eng = build_engine(ns)
-    try:
-        assert eng.resolved_config["expert_share"] == "1/2"
-        with pytest.raises(ValueError, match="kv_transfer"):
-            build_server(ns, eng)
-        with pytest.raises(ValueError, match="kv_transfer"):
-            eng.prefill_detached([2, 3, 4], None)
-    finally:
-        eng.stop()
-    with pytest.raises(ValueError, match="rank"):
-        cfg.with_expert_share(2, 2)
-    with pytest.raises(ValueError, match="no routed experts"):
-        get_config("tiny").with_expert_share(2, 0)

@@ -9,10 +9,8 @@ the engine at pipeline depth 0 and 2, and what such a model refuses by name.
 
 The served-against-reference comparison (with the must-fail controls) is
 ``benchmarks/tests/test_reference_linear_moe.py``, imported into tier-1 by
-``tests/test_benchmark_contract.py``."""
+``tests/test_contract_linear_moe.py``."""
 
-import dataclasses
-import json
 import os
 
 import jax
@@ -20,29 +18,22 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from arks_tpu.engine import paged
-from arks_tpu.models import moe, quant, transformer as tf
+from arks_tpu.models import transformer as tf
 from arks_tpu.models.config import ModelConfig, get_config
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-CONFIGS = os.path.join(ROOT, "benchmarks", "configs")
-SOLAR = os.path.join(CONFIGS, "solar-open2-250b-ep8-l8")
+import harness
+
+TINY = "tiny-linear-moe"
+SOLAR = "solar-open2-250b-ep8-l8"
 
 
 def _published() -> dict:
     """Solar-Open2-250B's published ``config.json``: the benchmark's file
     with what its ``reduced`` lists put back (48 layers, 320 experts, the
     whole vocabulary, a GQA layer every fourth)."""
-    with open(os.path.join(SOLAR, "config.json")) as f:
-        d = json.load(f)
-    d.update(num_hidden_layers=48, n_routed_experts=320, vocab_size=196608,
-             gqa_layers=list(range(0, 48, 4)))
-    return d
-
-
-def _tiny_config(**over) -> dict:
-    with open(os.path.join(CONFIGS, "tiny-linear-moe", "config.json")) as f:
-        return {**json.load(f), "n_routed_experts": 16, **over}
+    return harness.published(
+        SOLAR, num_hidden_layers=48, n_routed_experts=320, vocab_size=196608,
+        gqa_layers=list(range(0, 48, 4)))
 
 
 # ---------------------------------------------------------------------------
@@ -75,10 +66,10 @@ def test_from_hf_config_reads_the_published_file_key_for_key():
 
 
 def test_the_benchmark_configuration_is_whole_periods_and_a_share():
-    with open(os.path.join(SOLAR, "deploy.json")) as f:
-        deploy = json.load(f)
+    deploy = harness.deploy(SOLAR)
     share = deploy["share"]
-    cfg = ModelConfig.from_hf_config(SOLAR, name="s").with_expert_share(
+    cfg = ModelConfig.from_hf_config(
+        os.path.join(harness.CONFIGS, SOLAR), name="s").with_expert_share(
         share["chips_per_layer"], share["index"])
     assert cfg.layer_kinds() == ("full",) + ("linear",) * 3 + ("full",) \
         + ("linear",) * 3
@@ -86,36 +77,16 @@ def test_the_benchmark_configuration_is_whole_periods_and_a_share():
     assert cfg.vocab_size * 8 == share["published"]["vocab_size"]
     assert 6.3e9 < cfg.num_params() < 6.5e9     # one byte a parameter
     assert deploy["state_dtype"] == "float32"
-    pub, here = _published(), json.load(open(os.path.join(SOLAR,
-                                                          "config.json")))
+    pub, here = _published(), harness.published(SOLAR)
     assert sorted(k for k in pub if pub[k] != here[k]) \
         == sorted(deploy["reduced"])
 
 
 def test_the_tiny_preset_is_what_its_config_file_says():
-    cfg = ModelConfig.from_hf_config(_tiny_config(), name="tiny-linear-moe")
+    cfg = ModelConfig.from_hf_config(
+        harness.published(TINY, n_routed_experts=16), name=TINY)
     assert cfg == get_config("tiny-linear-moe")
     assert cfg.layer_kinds() == ("full", "linear", "linear") * 3
-
-
-@pytest.mark.parametrize("change, word", [
-    (dict(gqa_layers=[0, 3, 7]), "gqa_layers"),
-    (dict(gqa_interval=0), "gqa_layers"),
-    (dict(kda_use_full_proj=True), "kda_use_full_proj"),
-    (dict(use_rope=True), "use_rope"),
-    (dict(first_k_dense_replace=1), "first_k_dense_replace"),
-    (dict(scoring_func="softmax"), "scoring_func"),
-    (dict(n_group=4, topk_group=2), "group-limited"),
-    (dict(linear_attn_config=dict(short_conv_kernel_size=4, head_dim=16,
-                                  num_heads=4, num_kv_heads=2)),
-     "num_kv_heads"),
-    (dict(linear_attn_config=dict(head_dim=16, num_heads=4)),
-     "short_conv_kernel_size"),
-    (dict(partial_rotary_factor=0.5), "partial_rotary_factor"),
-])
-def test_from_hf_config_refuses_what_the_block_cannot_express(change, word):
-    with pytest.raises(ValueError, match=word):
-        ModelConfig.from_hf_config(_tiny_config(**change), name="m")
 
 
 @pytest.mark.parametrize("key, value", [
@@ -258,178 +229,6 @@ def test_the_convolution_reaches_into_the_slots_carry_and_leaves_one():
 
 
 # ---------------------------------------------------------------------------
-# The step program against the reference family's full forward
-# ---------------------------------------------------------------------------
-
-
-def _reference():
-    import sys
-    sys.path.insert(0, ROOT)
-    from benchmarks import manifest
-    with open(os.path.join(CONFIGS, "tiny-linear-moe", "deploy.json")) as f:
-        deploy = json.load(f)
-    config = manifest.with_share(_tiny_config(n_routed_experts=8), deploy)
-    return manifest.load_reference("linear_moe"), config
-
-
-@pytest.fixture(scope="module")
-def stepper():
-    """The step program on float32 activations over the family's own
-    weights (what is stored in bfloat16 widened, which is exact), two
-    sequences through 4 slots: (params, step function, reference forward)."""
-    ref, config = _reference()
-    seed = 11
-    cfg = get_config("tiny-linear-moe")
-    cfg = dataclasses.replace(cfg, num_experts=8).with_expert_share(2, 1)
-    params = jax.tree.map(
-        lambda a: a.astype(jnp.float32) if a.dtype == jnp.bfloat16 else a,
-        quant.init_params_quantized(cfg, jax.random.PRNGKey(seed),
-                                    jnp.bfloat16, bits=8))
-    weights = ref.generate_weights(config, seed)
-    step = jax.jit(lambda c, *a: tf.mixed_step(params, cfg, c, *a))
-    slots, page, max_pages = 4, 16, 16
-    tables = jnp.arange(slots * max_pages, dtype=jnp.int32).reshape(
-        slots, max_pages)
-
-    def fresh_cache():
-        return tf.init_paged_cache(cfg, slots * max_pages, page, jnp.float32,
-                                   state_slots=slots)
-
-    def run(cache, lanes, rows=100):
-        """One step over ``lanes``: {slot: (token ids, first position)}.
-        Returns (logits at each lane's last row, cache)."""
-        a = dict(tokens=np.zeros(rows, np.int32),
-                 slot=np.full(rows, -1, np.int32),
-                 pos=np.full(rows, page * max_pages, np.int32),
-                 src=np.zeros(slots, np.int32), qs=np.zeros(slots, np.int32),
-                 ql=np.zeros(slots, np.int32), ps=np.zeros(slots, np.int32))
-        at = 1                                        # a padding row ahead
-        for slot, (ids, p0) in lanes.items():
-            n = len(ids)
-            a["tokens"][at:at + n], a["slot"][at:at + n] = ids, slot
-            a["pos"][at:at + n] = np.arange(p0, p0 + n)
-            a["qs"][slot], a["ql"][slot], a["ps"][slot] = at, n, p0
-            a["src"][slot] = at + n - 1
-            at += n
-        logits, cache = step(cache, tables, *(jnp.asarray(a[k]) for k in (
-            "tokens", "slot", "pos", "src", "qs", "ql", "ps")))
-        return {s: np.asarray(logits[s]) for s in lanes}, cache
-
-    def want(ids, rows, **over):
-        return ref.forward(dict(config, **over), weights,
-                           np.asarray(ids, np.int32)[None],
-                           np.asarray(rows, np.int32)[None])[0]
-
-    return fresh_cache, run, want
-
-
-def test_a_prompt_cut_at_odd_lengths_then_decoded_is_the_full_forward(
-        stepper):
-    """Chunks of 70, 63, 1, 1, 37 rows (ends inside blocks of the scan,
-    inside pages of 16, single rows between chunks), then decode steps:
-    each step's logits are the reference's one forward at that position.
-    Meanwhile ANOTHER sequence decodes and then prefills in the same flat
-    batches, into a slot whose last sequence left its state there."""
-    fresh_cache, run, want = stepper
-    rng = np.random.default_rng(5)
-    a_ids = rng.integers(2, 258, 180).astype(np.int32)
-    b_ids = rng.integers(2, 258, 90).astype(np.int32)
-    c_ids = rng.integers(2, 258, 40).astype(np.int32)
-    cache = fresh_cache()
-    # Slot 0 is left dirty by sequence C, which then ends.
-    _, cache = run(cache, {0: (c_ids, 0)})
-    assert float(jnp.abs(cache.lin.s[:, 0]).max()) > 0
-    got_a, got_b, pa, pb = [], [], 0, 0
-    plan = [(70, 20), (63, 1), (1, 1), (1, 30), (37, 1), (1, 37), (1, 0),
-            (1, 0), (5, 0)]
-    for ta, tb in plan:
-        lanes = {2: (a_ids[pa:pa + ta], pa)}
-        if tb:
-            lanes[0] = (b_ids[pb:pb + tb], pb)        # reuses C's slot
-        out, cache = run(cache, lanes)
-        pa, pb = pa + ta, pb + tb
-        got_a.append((pa - 1, out[2]))
-        if tb:
-            got_b.append((pb - 1, out[0]))
-    for ids, got in ((a_ids, got_a), (b_ids, got_b)):
-        rows = [r for r, _ in got]
-        ref_logits = want(ids, rows)
-        for (r, lg), w in zip(got, ref_logits):
-            assert np.abs(lg - w).max() < 2e-4 * w.std() + 1e-6, r
-    # Slot 1 and 3 were never touched.
-    assert not float(jnp.abs(cache.lin.s[:, (1, 3)]).max())
-
-
-def test_a_stale_state_would_show(stepper):
-    """The control of the test above: the same prompt into the dirty slot
-    WITHOUT starting at position 0 reads what the last sequence left."""
-    fresh_cache, run, want = stepper
-    rng = np.random.default_rng(6)
-    ids = rng.integers(2, 258, 30).astype(np.int32)
-    cache = fresh_cache()
-    _, cache = run(cache, {0: (rng.integers(2, 258, 40).astype(np.int32), 0)})
-    clean, _ = run(cache, {0: (ids, 0)})
-    w = want(ids, [29])[0]
-    assert np.abs(clean[0] - w).max() < 2e-4 * w.std() + 1e-6
-    # Position 1 on: the program reads the slot's state (and pages that
-    # hold another sequence's keys): far off.
-    dirty, _ = run(cache, {0: (ids[1:], 1)})
-    assert np.abs(dirty[0] - w).max() > 0.05 * w.std()
-
-
-def test_a_state_kept_in_bfloat16_shows_where_the_activations_are_float32(
-        stepper):
-    """The control the chip cannot read (PERF.md §2: there bfloat16
-    activations put a floor under every position that a bfloat16 state
-    does not rise above): on float32 activations the step program is the
-    float32-state reference to 2e-4 of a logit sigma, and the reference
-    whose state is rounded to bfloat16 after every token is fifty times
-    that away from both."""
-    fresh_cache, run, want = stepper
-    rng = np.random.default_rng(8)
-    ids = rng.integers(2, 258, 96).astype(np.int32)
-    got, _ = run(fresh_cache(), {1: (ids, 0)})
-    sound = want(ids, [95])[0]
-    rounded = want(ids, [95], reference_state_dtype="bfloat16")[0]
-    assert np.abs(got[1] - sound).max() < 2e-4 * sound.std() + 1e-6
-    assert np.abs(got[1] - rounded).max() > 1e-2 * sound.std()
-
-
-# ---------------------------------------------------------------------------
-# A share of a layer
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("grouped", [True, False])
-def test_the_shares_of_a_routed_layer_add_up_to_the_uncut_layer(grouped):
-    """Eight chips hold two experts each of a 16-expert layer (sigmoid
-    scores, a selection bias, top-4 normalised); the routed parts their
-    layers return, the shared expert (which every chip computes alike)
-    counted once, add up to the layer held whole."""
-    cfg = get_config("tiny-linear-moe")
-    mp = jax.tree.map(lambda a: a[0], moe.init_moe_params(
-        cfg, jax.random.PRNGKey(7), jnp.float32, layers=1))
-    assert "router_bias" in mp and "shared_gate" not in mp
-    x = jax.random.normal(jax.random.PRNGKey(2), (1, 96, 64), jnp.float32)
-    valid = jnp.ones((1, 96), bool)
-    whole, pairs = moe.moe_ffn(x, mp, cfg, grouped=False, row_valid=valid)
-    assert pairs.tolist() == [96 * 4, 0, 0]
-    shared = moe._shared_expert(x, mp, cfg)
-    eighth = dataclasses.replace(cfg, num_experts=2)
-    total, held_all = jnp.zeros_like(whole), 0
-    for rank in range(8):
-        part = dict(mp, **{k: mp[k][rank * 2:(rank + 1) * 2]
-                           for k in ("w_gate", "w_up", "w_down")})
-        out, held = moe.moe_ffn(x, part, eighth.with_expert_share(8, rank),
-                                grouped=grouped, row_valid=valid)
-        total = total + out - shared
-        held_all += int(held[0])
-    assert held_all == 96 * 4            # every chosen pair lands on one chip
-    np.testing.assert_allclose(np.asarray(total + shared),
-                               np.asarray(whole), rtol=2e-4, atol=2e-6)
-
-
-# ---------------------------------------------------------------------------
 # The slots' state: the holder, the cache tuple, the engine
 # ---------------------------------------------------------------------------
 
@@ -448,73 +247,33 @@ def test_the_state_is_a_fixed_number_of_bytes_a_slot():
         tf.init_paged_cache(cfg, 8, 16, jnp.bfloat16)
 
 
-def _engine(cfg=None, **over):
-    from arks_tpu.engine.engine import EngineConfig, InferenceEngine
-    from arks_tpu.engine.tokenizer import ByteTokenizer
-    cfg = cfg or get_config("tiny-linear-moe")
-    kw = dict(model=cfg.name, num_slots=2, max_cache_len=256,
-              prefill_buckets=(16,), prefill_chunk=16, weight_dtype="int8",
-              kv_cache_dtype="bf16", seed=3)
-    kw.update(over)
-    return InferenceEngine(cfg, EngineConfig(**kw), ByteTokenizer())
-
-
-def _requests(n_decode=10, logprobs=None):
-    from arks_tpu.engine.types import Request, SamplingParams
-    rng = np.random.default_rng(1)
-    sp = SamplingParams(max_tokens=n_decode, temperature=0.0,
-                        ignore_eos=True, logprobs=logprobs)
-    # Three requests on two slots: the third takes a slot one of the
-    # others just left.
-    return [Request(f"r{i}", (2 + rng.integers(0, 200, n)).tolist(), sp)
-            for i, n in enumerate((70, 9, 133))]
-
-
-def _drain(eng, reqs, each_step=None):
-    for r in reqs:
-        eng.add_request(r)
-    done, toks = set(), {r.request_id: [] for r in reqs}
-    lps = {r.request_id: [] for r in reqs}
-    for _ in range(1000):
-        eng.step()
-        if each_step is not None:
-            each_step(eng)
-        for r in reqs:
-            while not r.outputs.empty():
-                o = r.outputs.get()
-                toks[r.request_id] += o.token_ids
-                lps[r.request_id] += [lp for lp, _ in (o.logprobs or ())]
-                if o.finished:
-                    assert o.finish_reason == "length", o.error
-                    done.add(r.request_id)
-        if len(done) == len(reqs):
-            return toks, lps
-    raise AssertionError("requests did not finish")
+def _counted(eng, seen):
+    m = eng.metrics
+    return dict(
+        starts=m.linear_state_starts_total.total(),
+        state_steps=m.kv_held_byte_steps_total.get(kind="state"),
+        page_steps=m.kv_held_byte_steps_total.get(kind="pages"),
+        hits=m.prefix_cache_hit_tokens_total.total(), seen=len(seen))
 
 
 @pytest.fixture(scope="module")
 def depth0_streams():
-    eng = _engine()
+    """One drain of one engine: its streams, and what its counters rose by
+    across the drain."""
     seen = []
-    try:
+    with harness.fresh(TINY) as eng:
         assert eng.resolved_config["kv_page"] == "kv+state"
         assert eng.resolved_config["pipeline_depth"] == "0"
         # The pool holds the three GQA layers of nine.
         assert eng._cache.k.shape[0] == 3 and eng._cache.lin is not None
-        toks, lps = _drain(
-            eng, _requests(logprobs=1),
+        before = _counted(eng, seen)
+        toks, lps = harness.drain(
+            eng, harness.requests(logprobs=1),
             lambda e: seen.append((e.metrics.linear_state_bytes.get(),
                                    e.ecfg.num_slots - len(e._free))))
-        m = eng.metrics
-        stats = dict(
-            starts=m.linear_state_starts_total.total(),
-            state_steps=m.kv_held_byte_steps_total.get(kind="state"),
-            page_steps=m.kv_held_byte_steps_total.get(kind="pages"),
-            slot_bytes=eng._lin_slot_bytes,
-            state_dtype=eng.resolved_config["state_dtype"],
-            hits=m.prefix_cache_hit_tokens_total.total(), seen=seen)
-    finally:
-        eng.stop()
+        stats = {k: v - before[k] for k, v in _counted(eng, seen).items()}
+        stats.update(slot_bytes=eng._lin_slot_bytes, seen=seen,
+                     state_dtype=eng.resolved_config["state_dtype"])
     return toks, lps, stats
 
 
@@ -541,8 +300,7 @@ def test_the_engine_says_what_the_state_is_kept_in(depth0_streams,
     the pod to what its configuration states, so the harness refuses a pod
     whose state is stored narrower before it times anything."""
     assert depth0_streams[2]["state_dtype"] == "float32"
-    with open(os.path.join(SOLAR, "deploy.json")) as f:
-        deploy = json.load(f)
+    deploy = harness.deploy(SOLAR)
     assert deploy["expect_labels"]["state_dtype"] == deploy["state_dtype"]
     real = tf.init_paged_cache
 
@@ -551,12 +309,10 @@ def test_the_engine_says_what_the_state_is_kept_in(depth0_streams,
         return cache if cache.lin is None else cache._replace(
             lin=cache.lin._replace(s=cache.lin.s.astype(jnp.bfloat16)))
     monkeypatch.setattr(tf, "init_paged_cache", narrow)
-    eng = _engine()
-    try:
+    # Fresh: it is built on a patched cache.
+    with harness.fresh(TINY) as eng:
         assert eng.resolved_config["state_dtype"] == "bfloat16"
         assert 'state_dtype="bfloat16"' in eng.metrics.registry.render()
-    finally:
-        eng.stop()
 
 
 def test_no_prefix_is_reused_for_a_model_with_linear_layers(depth0_streams):
@@ -565,44 +321,14 @@ def test_no_prefix_is_reused_for_a_model_with_linear_layers(depth0_streams):
     the same, in whichever slot."""
     toks, _, s = depth0_streams
     assert s["hits"] == 0
-    eng = _engine()
-    try:
-        first, _ = _drain(eng, _requests()[2:])
-        again, _ = _drain(eng, _requests()[2:])
+    with harness.fresh(TINY) as eng:
+        hits = eng.metrics.prefix_cache_hit_tokens_total.total()
+        first, _ = harness.drain(eng, harness.requests()[2:])
+        again, _ = harness.drain(eng, harness.requests()[2:])
         assert first == again
         assert first["r2"] == toks["r2"]
-        assert eng.metrics.prefix_cache_hit_tokens_total.total() == 0
+        assert eng.metrics.prefix_cache_hit_tokens_total.total() == hits
         assert eng._alloc.retained_pages == 0
-    finally:
-        eng.stop()
-
-
-def test_the_pipelined_path_gives_the_sequential_streams(depth0_streams,
-                                                         monkeypatch):
-    """Depth 2 runs a step ahead of the host: a lane the device found dead
-    takes no recurrence step, and the streams are the sequential path's."""
-    toks0, lps0, _ = depth0_streams
-    monkeypatch.setenv("ARKS_PIPELINE_DEPTH", "2")
-    eng = _engine()
-    try:
-        assert eng.resolved_config["pipeline_depth"] == "2"
-        assert eng._pipe_warm_wait(600.0) == "ready"
-        toks, lps = _drain(eng, _requests(logprobs=1))
-        assert eng.metrics.pipeline_depth_occupancy._data   # it engaged
-        for rid in toks:
-            # Two compiled programs round differently: where two logits
-            # tie, the streams may part; up to there they are equal, and
-            # there the two chosen log-probabilities are (a tie).
-            same = next((i for i, (a, b) in enumerate(
-                zip(toks[rid], toks0[rid])) if a != b), len(toks[rid]))
-            assert same >= 1, (rid, toks[rid], toks0[rid])
-            n = min(same + 1, len(lps[rid]))
-            np.testing.assert_allclose(lps[rid][:n], lps0[rid][:n],
-                                       atol=2e-3)
-        assert sum(toks[r] == toks0[r] for r in toks) >= 2
-        assert len(eng._free) == eng.ecfg.num_slots
-    finally:
-        eng.stop()
 
 
 @pytest.mark.parametrize("depth", ["0", "2"])
@@ -613,7 +339,9 @@ def test_the_engine_counts_the_slots_the_state_update_steps_walks_and_skips(
     one-step kernel's list: the decoding slots), ``chunk`` by the lanes of
     more rows (the prefilling ones) and ``idle`` by the rest."""
     monkeypatch.setenv("ARKS_PIPELINE_DEPTH", depth)
-    eng = _engine()
+    # Fresh: ``_emit`` is patched, and the counter is read from its first
+    # sample (no series is rendered before it).
+    eng = harness.engine(TINY)
     c = eng.metrics.linear_state_lane_steps_total
     slots = eng.ecfg.num_slots
     seen, emit = [], eng._emit
@@ -631,8 +359,8 @@ def test_the_engine_counts_the_slots_the_state_update_steps_walks_and_skips(
         assert "linear_state_lane_steps_total{" not in \
             eng.metrics.registry.render()
         if depth == "2":
-            assert eng._pipe_warm_wait(600.0) == "ready"
-        toks, _ = _drain(eng, _requests())
+            assert eng._pipe_warm_wait(120.0) == "ready"
+        toks, _ = harness.drain(eng, harness.requests())
         assert all(len(t) == 10 for t in toks.values())
         # A sequential dispatch: counted in its ``count`` section, before
         # it is emitted, by what it emits.
@@ -663,65 +391,15 @@ def test_the_engine_counts_the_slots_the_state_update_steps_walks_and_skips(
 
 
 def test_a_pod_without_linear_layers_has_no_lane_steps_to_count():
-    eng = _engine(get_config("tiny"), kv_layout="paged")
-    try:
+    # Fresh: its prompt's page stays in the prefix index.
+    with harness.fresh("tiny", kv_layout="paged") as eng:
         from arks_tpu.engine.types import Request, SamplingParams
-        _drain(eng, [Request("r", list(range(2, 22)), SamplingParams(
+        harness.drain(eng, [Request("r", list(range(2, 22)), SamplingParams(
             max_tokens=3, temperature=0.0, ignore_eos=True))])
         text = eng.metrics.registry.render()
         assert "mixed_batch_tokens_count" in text
         assert "linear_state_lane_steps_total{" not in text
         assert "kv_held_byte_steps_total{" not in text
-    finally:
-        eng.stop()
-
-
-@pytest.mark.parametrize("over, env, word", [
-    (dict(kv_layout="slot"), {}, "slot layout"),
-    (dict(prefill_chunk=None), {}, "chunked prefill"),
-    (dict(draft_model="tiny-gqa"), {}, "speculative"),
-    ({}, {"ARKS_PREFIX_HOST_MB": "64"}, "host spill tier"),
-    ({}, {"ARKS_PREFIX_DISK_MB": "64"}, "disk spill tier"),
-    ({}, {"ARKS_RESIDENCY_WINDOW_PAGES": "6"}, "windowed residency"),
-    ({}, {"ARKS_PREEMPT": "1"}, "KV swap"),
-    ({}, {"ARKS_PEER_ADDRS": "10.0.0.1:8080"}, "peer fetch"),
-    ({}, {"ARKS_MIXED_STEP": "0"}, "legacy scheduler"),
-    (dict(kv_pool_pages=16), {}, "kv_pool_pages"),
-])
-def test_a_model_with_linear_layers_refuses_by_name_what_packs_every_layers_pages(
-        over, env, word, monkeypatch):
-    for k, v in env.items():
-        monkeypatch.setenv(k, v)
-    with pytest.raises(ValueError, match=word) as e:
-        _engine(**over)
-    if "kv_pool_pages" not in over:
-        assert "a fixed state a slot" in str(e.value)
-
-
-def test_a_model_with_linear_layers_refuses_a_mesh_and_disaggregation():
-    from arks_tpu.engine.engine import EngineConfig, InferenceEngine
-    from arks_tpu.engine.tokenizer import ByteTokenizer
-    from arks_tpu.parallel.mesh import make_mesh
-    cfg = get_config("tiny-linear-moe")
-    mesh = make_mesh(tensor_parallel=2, devices=jax.devices()[:2])
-    with pytest.raises(ValueError, match="device mesh"):
-        InferenceEngine(cfg, EngineConfig(
-            model=cfg.name, num_slots=2, max_cache_len=64,
-            prefill_buckets=(16,), prefill_chunk=16, tensor_parallel=2),
-            ByteTokenizer(), mesh=mesh)
-    with pytest.raises(NotImplementedError, match="their state"):
-        tf.param_pspecs(cfg, 2)
-    from arks_tpu.server.__main__ import build_engine, build_server, parse_args
-    ns = parse_args(["--model", "tiny-linear-moe", "--platform", "cpu",
-                     "--num-slots", "2", "--max-model-len", "64",
-                     "--tensor-parallel-size", "1",
-                     "--disaggregation-mode", "prefill"])
-    eng = build_engine(ns)
-    try:
-        with pytest.raises(ValueError, match="recurrent state"):
-            build_server(ns, eng)
-    finally:
-        eng.stop()
 
 
 # ---------------------------------------------------------------------------
@@ -792,7 +470,7 @@ def test_the_linear_readers_read_the_scopes_and_the_byte_steps():
     assert manifest.load_reader("kv_state_resident_share")(ctx) == 75.0
     # The roofline: one decode token a layer of the tiny family in a slice
     # whose scope took 1 ms.
-    ref, config = _reference()
+    ref, config = harness.reference(TINY, "linear_moe", n_routed_experts=8)
     run = {"records": [{"frames": [(0.5, 1)], "prompt_tokens": 9,
                         "first": 0.1, "sent": 0.2}]}
     dev["scope_seconds"]["arks.linear_state"] = 1e-3

@@ -11,15 +11,7 @@ import pytest
 from arks_tpu.control.k8s_client import ApiError, FakeKubeApi
 from arks_tpu.control.live import FINALIZER, GV, LiveOperator
 
-
-def wait_for(predicate, timeout=30.0, interval=0.05):
-    deadline = time.monotonic() + timeout
-    while time.monotonic() < deadline:
-        v = predicate()
-        if v:
-            return v
-        time.sleep(interval)
-    raise AssertionError("condition not met within timeout")
+from harness import wait_for  # noqa: E402
 
 
 @pytest.fixture()

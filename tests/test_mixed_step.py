@@ -2,6 +2,7 @@
 vs the legacy chunk+decode path, single-dispatch-per-step, aborts mid-
 prefill, and guides publishing while mixed batches flow."""
 
+import functools
 import json
 import time
 
@@ -10,6 +11,8 @@ import pytest
 from arks_tpu.engine import EngineConfig, InferenceEngine, Request, SamplingParams
 from arks_tpu.engine.tokenizer import ByteTokenizer
 from arks_tpu.models import get_config
+
+import harness
 
 # Every op on the dispatch channel that runs the MODEL (admission state
 # writes like set_slot/clear_penalties are not dispatches of the model).
@@ -39,26 +42,10 @@ def _mk_engine(monkeypatch, mixed: str, **kw):
     return cfg, InferenceEngine(cfg, ecfg, ByteTokenizer())
 
 
-def _collect(req, timeout=120):
-    ids, lps, fin = [], [], None
-    while True:
-        out = req.outputs.get(timeout=timeout)
-        ids.extend(out.token_ids)
-        if out.logprobs:
-            lps.extend(out.logprobs)
-        if out.finished:
-            fin = out
-            break
-    return ids, lps, fin
+_collect = functools.partial(harness.collect, logprobs=True)
 
 
-def _drive(engine, n_steps=500):
-    for _ in range(n_steps):
-        engine.step(block_s=0.01)
-        if (engine.num_running == 0 and engine._queue.empty()
-                and engine._deferred is None
-                and not engine._prefilling):
-            break
+_drive = harness.drive
 
 
 def test_mixed_matches_legacy_token_exact(monkeypatch):

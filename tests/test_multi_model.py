@@ -38,6 +38,8 @@ from arks_tpu.engine.model_pool import ModelPool
 from arks_tpu.engine.tokenizer import ByteTokenizer
 from arks_tpu.models import get_config
 
+import harness
+
 # The flagship paged/mixed layout; multi-model rides the same scheduler.
 DEFAULTS = dict(num_slots=2, max_cache_len=64, prefill_buckets=(8, 16, 32),
                 steps_per_dispatch=4, prefill_chunk=16, kv_layout="paged")
@@ -70,7 +72,7 @@ def _mk_pool_engine(monkeypatch, depth, cfg_b, inject=None, retries=None):
                           ByteTokenizer(), pool=ModelPool())
     eng.register_model(cfg_b)
     if depth:
-        assert eng._pipe_warm_wait(300) == "ready"
+        assert eng._pipe_warm_wait(120) == "ready"
     return cfg, eng
 
 
@@ -79,7 +81,7 @@ def _mk_single_engine(monkeypatch, depth, cfg):
     eng = InferenceEngine(cfg, EngineConfig(model=cfg.name, **DEFAULTS),
                           ByteTokenizer())
     if depth:
-        assert eng._pipe_warm_wait(300) == "ready"
+        assert eng._pipe_warm_wait(120) == "ready"
     return eng
 
 
@@ -100,18 +102,10 @@ def _quiesce(eng, depth):
     # The active context's pipe warmup compiles on a daemon thread; join
     # it before the test returns so nothing races interpreter teardown.
     if depth:
-        assert eng._pipe_warm_wait(600) == "ready"
+        assert eng._pipe_warm_wait(120) == "ready"
 
 
-def _collect(req, timeout=120):
-    ids, fin = [], None
-    while True:
-        out = req.outputs.get(timeout=timeout)
-        ids.extend(out.token_ids)
-        if out.finished:
-            fin = out
-            break
-    return ids, fin
+_collect = harness.collect
 
 
 # (model-slot, prompt, greedy?) — interleaved across the two models,
@@ -126,7 +120,7 @@ WORKLOAD = [
 ]
 
 
-def _requests(cfg_b, only=None):
+def _traffic(cfg_b, only=None):
     reqs = []
     for i, (slot, prompt, greedy) in enumerate(WORKLOAD):
         if only is not None and slot != only:
@@ -143,7 +137,7 @@ def _single_model_baseline(monkeypatch, depth, cfg_b):
     base = {}
     for slot, cfg in (("a", get_config("tiny")), ("b", cfg_b)):
         eng = _mk_single_engine(monkeypatch, depth, cfg)
-        reqs = _requests(cfg_b, only=slot)
+        reqs = _traffic(cfg_b, only=slot)
         for r in reqs:
             r.model = None  # single-model engine: no routing field
             eng.add_request(r)
@@ -157,7 +151,7 @@ def _single_model_baseline(monkeypatch, depth, cfg_b):
 def _pooled_run(monkeypatch, depth, cfg_b, inject=None, retries=None):
     cfg, eng = _mk_pool_engine(monkeypatch, depth, cfg_b,
                                inject=inject, retries=retries)
-    reqs = _requests(cfg_b)
+    reqs = _traffic(cfg_b)
     for r in reqs:
         eng.add_request(r)
     _drive(eng)

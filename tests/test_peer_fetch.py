@@ -11,6 +11,7 @@ with strictly fewer chunk-prefilled tokens.  A peer dying mid-fetch
 degrades to re-prefill of the unfetched span; the request is unharmed.
 """
 
+import functools
 import http.server
 import threading
 import urllib.error
@@ -26,6 +27,8 @@ from arks_tpu.engine.paged import chain_digests
 from arks_tpu.engine.tokenizer import ByteTokenizer
 from arks_tpu.models import get_config
 from arks_tpu.server import OpenAIServer
+
+import harness
 
 
 def _mk(monkeypatch, peer_fetch="0"):
@@ -46,28 +49,10 @@ def _mk(monkeypatch, peer_fetch="0"):
     return cfg, eng
 
 
-def _drive(eng, n_steps=2000):
-    for _ in range(n_steps):
-        try:
-            eng.step(block_s=0.01)
-        except Exception as e:  # noqa: BLE001 — routed like _run_loop
-            eng._recover_from_fault(e)
-        if (eng.num_running == 0 and eng._queue.empty()
-                and eng._deferred is None
-                and not eng._prefilling and not eng._awaiting_fetch
-                and not eng._awaiting_restore and eng.state == "serving"):
-            break
+_drive = functools.partial(harness.drive, recover=True)
 
 
-def _collect(req, timeout=120):
-    ids, fin = [], None
-    while True:
-        out = req.outputs.get(timeout=timeout)
-        ids.extend(out.token_ids)
-        if out.finished:
-            fin = out
-            break
-    return ids, fin
+_collect = harness.collect
 
 
 def _run_one(eng, rid, ids, peer_hint=None, max_tokens=4):

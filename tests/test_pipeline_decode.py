@@ -4,12 +4,22 @@ overshoot truncation, slot-reuse-after-overshoot KV correctness, multihost
 follower replay of the pipelined op stream, and the emit-stream depth
 bound."""
 
+import functools
+import contextlib
+
 import numpy as np
 import pytest
 
 from arks_tpu.engine import EngineConfig, InferenceEngine, Request, SamplingParams
 from arks_tpu.engine.tokenizer import ByteTokenizer
 from arks_tpu.models import get_config
+
+import harness
+
+# This file's engine: ``tiny`` on two slots of 64 tokens, the legacy slot
+# scheduler unless a test says otherwise.
+BASE = dict(num_slots=2, max_cache_len=64, prefill_buckets=(8, 16, 32),
+            steps_per_dispatch=4)
 
 
 class RecordingDispatcher:
@@ -23,45 +33,35 @@ class RecordingDispatcher:
 def _mk_engine(monkeypatch, depth, mixed="0", **kw):
     monkeypatch.setenv("ARKS_PIPELINE_DEPTH", str(depth))
     monkeypatch.setenv("ARKS_MIXED_STEP", mixed)
-    cfg = get_config("tiny")
-    defaults = dict(model="tiny", num_slots=2, max_cache_len=64,
-                    prefill_buckets=(8, 16, 32), steps_per_dispatch=4)
-    defaults.update(kw)
-    eng = InferenceEngine(cfg, EngineConfig(**defaults), ByteTokenizer())
-    if depth and isinstance(depth, int) and depth > 0:
-        # Deterministic engagement: serving warms the pipe programs in the
-        # background and stays sequential meanwhile; tests wait so short
-        # workloads can't finish before the pipelined path opens.
-        assert eng._pipe_warm_wait(300) == "ready"
-    return cfg, eng
+    eng = harness.warmed("tiny", base=BASE, **kw)
+    return eng.cfg, eng
 
 
-def _collect(req, timeout=120):
-    ids, lps, fin = [], [], None
-    while True:
-        out = req.outputs.get(timeout=timeout)
-        ids.extend(out.token_ids)
-        if out.logprobs:
-            lps.extend(out.logprobs)
-        if out.finished:
-            fin = out
-            break
-    return ids, lps, fin
+@contextlib.contextmanager
+def _served_by(monkeypatch, depth, mixed="0", **kw):
+    """``_mk_engine``'s engine, stopped behind the test."""
+    eng = _mk_engine(monkeypatch, depth, mixed, **kw)[1]
+    try:
+        yield eng
+    finally:
+        eng.stop()
 
 
-def _drive(engine, n_steps=800):
-    for _ in range(n_steps):
-        engine.step(block_s=0.01)
-        if (engine.num_running == 0 and engine._queue.empty()
-                and engine._deferred is None
-                and not engine._prefilling):
-            break
+def _engaged(eng) -> int:
+    """Pipelined dispatches the engine has observed."""
+    return sum(n for _, _, n in
+               eng.metrics.pipeline_depth_occupancy._data.values())
 
 
-def _run_workload(monkeypatch, depth, mixed="0", **kw):
+_collect = functools.partial(harness.collect, logprobs=True)
+
+
+_drive = harness.drive
+
+
+def _run_workload(eng, depth):
     """Greedy + fixed-seed sampled + logprob requests with slot churn
     (more requests than slots); returns each request's full output."""
-    cfg, eng = _mk_engine(monkeypatch, depth, mixed, **kw)
     assert eng._pipe_depth == (depth if depth >= 0 else 0)
     prompts = [[5, 6, 7], list(range(3, 23)), [9] * 5, [4] * 12, [8, 3]]
     reqs = []
@@ -70,11 +70,12 @@ def _run_workload(monkeypatch, depth, mixed="0", **kw):
                             temperature=0.0 if i % 2 == 0 else 0.8,
                             top_p=0.9, top_k=40, seed=7 + i, ignore_eos=True,
                             logprobs=2 if i == 2 else None)
-        reqs.append(Request(f"r{i}", [int(x) % cfg.vocab_size for x in p], sp))
+        reqs.append(Request(f"r{i}", [int(x) % eng.cfg.vocab_size for x in p],
+                            sp))
     for r in reqs:
         eng.add_request(r)
     _drive(eng)
-    return [_collect(r) for r in reqs], eng
+    return [_collect(r) for r in reqs]
 
 
 @pytest.mark.parametrize("mixed,kw", [
@@ -85,13 +86,14 @@ def test_pipeline_token_parity_depths(monkeypatch, mixed, kw):
     """Depths 1/2/3 must produce BYTE-IDENTICAL streams (tokens, logprobs,
     finish reasons) to the sequential path (depth 0), on both the legacy
     slot engine and the mixed paged engine."""
-    base, _ = _run_workload(monkeypatch, 0, mixed, **kw)
+    with _served_by(monkeypatch, 0, mixed, **kw) as eng:
+        base = _run_workload(eng, 0)
     for depth in (1, 2, 3):
-        got, eng = _run_workload(monkeypatch, depth, mixed, **kw)
-        assert got == base, f"depth {depth} diverged from sequential"
-        # The pipelined path actually ran (occupancy histogram observed).
-        occ = eng.metrics.pipeline_depth_occupancy._data
-        assert occ, "pipelined path never engaged"
+        with _served_by(monkeypatch, depth, mixed, **kw) as eng:
+            got = _run_workload(eng, depth)
+            assert got == base, f"depth {depth} diverged from sequential"
+            # The pipelined path actually ran (occupancy histogram observed).
+            assert _engaged(eng), "pipelined path never engaged"
 
 
 _TRAFFIC = {
@@ -179,37 +181,39 @@ def test_pipeline_one_dispatch_per_iteration_and_depth_bound(monkeypatch):
 def test_pipeline_midstream_abort(monkeypatch):
     """An abort raised while dispatches are in flight drains the pipeline
     and frees the slot; the engine keeps serving afterwards."""
-    cfg, eng = _mk_engine(monkeypatch, 2)
-    victim = Request("v", [5, 6, 7], SamplingParams(
-        max_tokens=10_000, temperature=0.0, ignore_eos=True))
-    eng.add_request(victim)
-    for _ in range(50):
-        eng.step(block_s=0.01)
-        if eng._pipe_inflight:
-            break
-    assert eng._pipe_inflight, "pipeline never engaged"
-    eng.abort("v")
+    with _served_by(monkeypatch, 2) as eng:
+        victim = Request("v", [5, 6, 7], SamplingParams(
+            max_tokens=10_000, temperature=0.0, ignore_eos=True))
+        eng.add_request(victim)
+        for _ in range(50):
+            eng.step(block_s=0.01)
+            if eng._pipe_inflight:
+                break
+        assert eng._pipe_inflight, "pipeline never engaged"
+        eng.abort("v")
+        _drive(eng)
+        ids, _, fin = _collect(victim)
+        assert fin.finish_reason == "abort"
+        assert not eng._pipe_inflight and eng._pipe_state is None
+        # Slot is reusable: a fresh request completes normally.
+        nxt = Request("n", [9, 9], SamplingParams(
+            max_tokens=4, temperature=0.0, ignore_eos=True))
+        eng.add_request(nxt)
+        _drive(eng)
+        ids2, _, fin2 = _collect(nxt)
+        assert fin2.finish_reason == "length" and len(ids2) == 4
+
+
+def _serve(eng, request):
+    eng.add_request(request)
     _drive(eng)
-    ids, _, fin = _collect(victim)
-    assert fin.finish_reason == "abort"
-    assert not eng._pipe_inflight and eng._pipe_state is None
-    # Slot is reusable: a fresh request completes normally.
-    nxt = Request("n", [9, 9], SamplingParams(
-        max_tokens=4, temperature=0.0, ignore_eos=True))
-    eng.add_request(nxt)
-    _drive(eng)
-    ids2, _, fin2 = _collect(nxt)
-    assert fin2.finish_reason == "length" and len(ids2) == 4
+    return _collect(request)
 
 
 def _greedy_probe(monkeypatch, prompt, n):
-    _, eng = _mk_engine(monkeypatch, 0)
-    r = Request("probe", prompt, SamplingParams(
-        max_tokens=n, temperature=0.0, ignore_eos=True))
-    eng.add_request(r)
-    _drive(eng)
-    ids, _, _ = _collect(r)
-    return ids
+    with _served_by(monkeypatch, 0) as eng:
+        return _serve(eng, Request("probe", prompt, SamplingParams(
+            max_tokens=n, temperature=0.0, ignore_eos=True)))[0]
 
 
 def test_pipeline_stop_overshoot_truncation(monkeypatch):
@@ -220,13 +224,10 @@ def test_pipeline_stop_overshoot_truncation(monkeypatch):
     stop = probe[9]  # lands mid-dispatch (K=4) with the pipeline deep
 
     def run(depth):
-        _, eng = _mk_engine(monkeypatch, depth)
-        r = Request("s", [5, 6, 7], SamplingParams(
-            max_tokens=64, temperature=0.0, ignore_eos=True,
-            stop_token_ids=(int(stop),)))
-        eng.add_request(r)
-        _drive(eng)
-        return _collect(r)
+        with _served_by(monkeypatch, depth) as eng:
+            return _serve(eng, Request("s", [5, 6, 7], SamplingParams(
+                max_tokens=64, temperature=0.0, ignore_eos=True,
+                stop_token_ids=(int(stop),))))
 
     base = run(0)
     for depth in (2, 3):
@@ -384,12 +385,8 @@ def test_pipeline_enabled_for_spec_engines(monkeypatch):
     Byte-identity across depths is asserted in
     tests/test_spec_decode.py::test_pipeline_depth_parity."""
     monkeypatch.setenv("ARKS_PIPELINE_DEPTH", "2")
-    cfg = get_config("tiny")
-    ecfg = EngineConfig(model="tiny", num_slots=2, max_cache_len=64,
-                        prefill_buckets=(8, 16, 32), steps_per_dispatch=4,
-                        prefill_chunk=16, kv_layout="paged",
-                        draft_model="tiny", draft_len=3)
-    eng = InferenceEngine(cfg, ecfg, ByteTokenizer())
+    eng = harness.engine("tiny", base=BASE, prefill_chunk=16,
+                         kv_layout="paged", draft_model="tiny", draft_len=3)
     assert eng._pipe_depth == 2
     assert eng.resolved_config["pipeline_depth"] == "2"
     assert eng._pipe_rows == 3
@@ -413,16 +410,14 @@ def test_pipeline_oversized_stop_set_falls_back(monkeypatch):
     big_stops = tuple(range(100, 100 + sampler_mod.STOP_IDS_MAX + 4))
 
     def run(depth):
-        _, eng = _mk_engine(monkeypatch, depth)
-        r = Request("big", [5, 6, 7], SamplingParams(
-            max_tokens=8, temperature=0.0, ignore_eos=True,
-            stop_token_ids=big_stops))
-        eng.add_request(r)
-        _drive(eng)
-        return _collect(r), eng
+        with _served_by(monkeypatch, depth) as eng:
+            out = _serve(eng, Request("big", [5, 6, 7], SamplingParams(
+                max_tokens=8, temperature=0.0, ignore_eos=True,
+                stop_token_ids=big_stops)))
+            return out, _engaged(eng)
 
     base, _ = run(0)
-    got, eng = run(2)
+    got, engaged = run(2)
     assert got == base
     # The oversized stop set kept the pipeline cold.
-    assert not eng.metrics.pipeline_depth_occupancy._data
+    assert not engaged

@@ -22,9 +22,9 @@ contract (the _run_loop shape) so faults land deterministically.
 
 import pytest
 
-from arks_tpu.engine import EngineConfig, InferenceEngine, Request, SamplingParams
-from arks_tpu.engine.tokenizer import ByteTokenizer
-from arks_tpu.models import get_config
+from arks_tpu.engine import Request, SamplingParams
+
+import harness
 
 CHUNK = 16
 
@@ -40,16 +40,11 @@ def _mk_engine(monkeypatch, depth=0, host_mb=64, preempt=True, inject=None,
         monkeypatch.delenv("ARKS_FAULT_INJECT", raising=False)
     else:
         monkeypatch.setenv("ARKS_FAULT_INJECT", inject)
-    cfg = get_config("tiny")
-    defaults = dict(model="tiny", num_slots=1, max_cache_len=64,
-                    prefill_buckets=(8, 16, 32), steps_per_dispatch=1,
-                    prefill_chunk=CHUNK, kv_layout="paged",
-                    prefix_cache_mb=0)
-    defaults.update(kw)
-    eng = InferenceEngine(cfg, EngineConfig(**defaults), ByteTokenizer())
-    if depth:
-        assert eng._pipe_warm_wait(300) == "ready"
-    return cfg, eng
+    eng = harness.warmed("tiny", base=dict(
+        num_slots=1, max_cache_len=64, prefill_buckets=(8, 16, 32),
+        steps_per_dispatch=1, prefill_chunk=CHUNK, kv_layout="paged",
+        prefix_cache_mb=0), **kw)
+    return eng.cfg, eng
 
 
 def _drive(eng, n_steps=4000):
@@ -63,15 +58,7 @@ def _drive(eng, n_steps=4000):
             break
 
 
-def _collect(req, timeout=120):
-    ids, fin = [], None
-    while True:
-        out = req.outputs.get(timeout=timeout)
-        ids.extend(out.token_ids)
-        if out.finished:
-            fin = out
-            break
-    return ids, fin
+_collect = harness.collect
 
 
 def _victims(cfg, guided=False):

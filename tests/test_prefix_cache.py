@@ -11,6 +11,8 @@ from arks_tpu.engine.prefix_cache import PrefixKVCache
 from arks_tpu.engine.tokenizer import ByteTokenizer
 from arks_tpu.models import get_config
 
+import harness
+
 
 def _kv(t, seed=0):
     rng = np.random.default_rng(seed)
@@ -81,24 +83,10 @@ def test_lru_eviction_by_bytes():
 # ---------------------------------------------------------------------------
 
 
-def _drive(engine, n_steps=300):
-    for _ in range(n_steps):
-        engine.step(block_s=0.01)
-        if (engine.num_running == 0 and engine._queue.empty()
-                and engine._deferred is None
-                and not engine._prefilling):
-            break
+_drive = harness.drive
 
 
-def _collect(req, timeout=60):
-    ids, finished = [], None
-    while True:
-        out = req.outputs.get(timeout=timeout)
-        ids.extend(out.token_ids)
-        if out.finished:
-            finished = out
-            break
-    return ids, finished
+_collect = harness.collect
 
 
 @pytest.fixture(scope="module")

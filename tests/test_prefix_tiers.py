@@ -21,6 +21,8 @@ from arks_tpu.engine.prefix_cache import HostPrefixTier
 from arks_tpu.engine.tokenizer import ByteTokenizer
 from arks_tpu.models import get_config
 
+import harness
+
 CHUNK = 16  # page size for every engine below
 
 
@@ -28,19 +30,14 @@ def _mk_engine(monkeypatch, host_mb, depth=0, mixed="auto", **kw):
     monkeypatch.setenv("ARKS_PIPELINE_DEPTH", str(depth))
     monkeypatch.setenv("ARKS_MIXED_STEP", mixed)
     monkeypatch.setenv("ARKS_PREFIX_HOST_MB", str(host_mb))
-    cfg = get_config("tiny")
     # prefix_cache_mb=0: zero retention surplus, so finished prompts'
     # index-retained pages are evicted (and spilled) by the next
     # admissions — the shape that exercises the tiers hardest.
-    defaults = dict(model="tiny", num_slots=2, max_cache_len=64,
-                    prefill_buckets=(8, 16, 32), steps_per_dispatch=4,
-                    prefill_chunk=CHUNK, kv_layout="paged",
-                    prefix_cache_mb=0)
-    defaults.update(kw)
-    eng = InferenceEngine(cfg, EngineConfig(**defaults), ByteTokenizer())
-    if depth:
-        assert eng._pipe_warm_wait(300) == "ready"
-    return cfg, eng
+    eng = harness.warmed("tiny", base=dict(
+        num_slots=2, max_cache_len=64, prefill_buckets=(8, 16, 32),
+        steps_per_dispatch=4, prefill_chunk=CHUNK, kv_layout="paged",
+        prefix_cache_mb=0), **kw)
+    return eng.cfg, eng
 
 
 def _drive(eng, n_steps=4000):

@@ -12,35 +12,25 @@ guided, and speculative traffic.
 import numpy as np
 import pytest
 
-from arks_tpu.engine import EngineConfig, InferenceEngine, Request, SamplingParams
-from arks_tpu.engine.tokenizer import ByteTokenizer
-from arks_tpu.models import get_config
+from arks_tpu.engine import Request, SamplingParams
+
+import harness
 
 
 def _mk_engine(monkeypatch, *, depth=0, impl="pallas", spec=False, **kw):
     monkeypatch.setenv("ARKS_MIXED_STEP", "1")
     monkeypatch.setenv("ARKS_ATTN_IMPL", impl)
     monkeypatch.setenv("ARKS_PIPELINE_DEPTH", str(depth))
-    cfg = get_config("tiny")
-    defaults = dict(model="tiny", num_slots=2, max_cache_len=64,
+    defaults = dict(num_slots=2, max_cache_len=64,
                     prefill_buckets=(8, 16, 32), steps_per_dispatch=4,
                     prefill_chunk=16, kv_layout="paged", prefix_cache_mb=0)
     if spec:
         defaults.update(draft_model="tiny", draft_len=3)
-    defaults.update(kw)
-    eng = InferenceEngine(cfg, EngineConfig(**defaults), ByteTokenizer())
-    if depth:
-        assert eng._pipe_warm_wait(300) == "ready"
-    return cfg, eng
+    eng = harness.warmed("tiny", base=defaults, **kw)
+    return eng.cfg, eng
 
 
-def _drive(eng, n_steps=2000):
-    for _ in range(n_steps):
-        eng.step(block_s=0.01)
-        if (eng.num_running == 0 and eng._queue.empty()
-                and eng._deferred is None
-                and not eng._prefilling):
-            break
+_drive = harness.drive
 
 
 def _collect(req):

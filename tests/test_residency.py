@@ -14,12 +14,16 @@ embed/qkv/update/tail/sampler functions) with only the attend swapped
 for the bitwise-proven span chain.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
 from arks_tpu.engine import EngineConfig, InferenceEngine, Request, SamplingParams
 from arks_tpu.engine.tokenizer import ByteTokenizer
 from arks_tpu.models import get_config
+
+import harness
 
 WINDOW = 6  # pages; pool = num_slots * WINDOW
 
@@ -32,37 +36,17 @@ def _mk_engine(monkeypatch, *, window, depth=0, impl="pallas", **kw):
         monkeypatch.setenv("ARKS_RESIDENCY_WINDOW_PAGES", str(window))
     else:
         monkeypatch.delenv("ARKS_RESIDENCY_WINDOW_PAGES", raising=False)
-    cfg = get_config("tiny")
-    defaults = dict(model="tiny", num_slots=1, max_cache_len=256,
-                    prefill_buckets=(8, 16, 32), steps_per_dispatch=4,
-                    prefill_chunk=16, kv_layout="paged", prefix_cache_mb=0)
-    defaults.update(kw)
-    eng = InferenceEngine(cfg, EngineConfig(**defaults), ByteTokenizer())
-    if depth:
-        assert eng._pipe_warm_wait(300) == "ready"
-    return cfg, eng
+    eng = harness.warmed("tiny", base=dict(
+        num_slots=1, max_cache_len=256, prefill_buckets=(8, 16, 32),
+        steps_per_dispatch=4, prefill_chunk=16, kv_layout="paged",
+        prefix_cache_mb=0), **kw)
+    return eng.cfg, eng
 
 
-def _drive(eng, n_steps=3000):
-    for _ in range(n_steps):
-        eng.step(block_s=0.01)
-        if (eng.num_running == 0 and eng._queue.empty()
-                and eng._deferred is None
-                and not eng._prefilling):
-            break
+_drive = harness.drive
 
 
-def _collect(req):
-    ids, lps, fin = [], [], None
-    while True:
-        out = req.outputs.get(timeout=300)
-        ids.extend(out.token_ids)
-        if out.logprobs:
-            lps.extend(out.logprobs)
-        if out.finished:
-            fin = out
-            break
-    return ids, lps, fin
+_collect = functools.partial(harness.collect, logprobs=True)
 
 
 # Prompt (40 tokens, chunked prefill) + 70 decode tokens = 110-token

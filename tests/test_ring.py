@@ -15,6 +15,21 @@ from arks_tpu.ops.attention import prefill_attention
 from arks_tpu.parallel.mesh import make_mesh
 from arks_tpu.parallel.ring import ring_prefill_attention
 
+import harness
+
+# Two engines' greedy tokens on random weights part where two logits tie
+# (ROADMAP D9), so the engine tests below compare with
+# ``harness.streams_agree`` where the parent of PR 50 wrote ``==``: equal up
+# to the first tie, the chosen log-probabilities there within this, and all
+# streams but one whole to their ends (the first test's only one is).  The
+# limit stands between two readings (PR 50, this machine): sound runs part
+# part by 0.0010-0.0019 (bf16 activations summed in another order; too near
+# the harness's 2e-3 for a machine that vectorises otherwise), and a ring
+# that attends to its own chunk alone by 0.040, at the FIRST token, which
+# also differs (in the first test: the paged engines' prompts are prefilled
+# in chunks by the mixed step and trace no ring at all, ROADMAP D9).
+RING_ATOL = 1e-2
+
 
 @pytest.mark.parametrize("cp,h,hkv", [(8, 4, 4), (4, 8, 2), (2, 4, 1)])
 def test_ring_attention_matches_dense_causal(cp, h, hkv):
@@ -87,29 +102,24 @@ def test_serving_engine_with_context_parallelism():
                             context_parallel=cp, prefix_cache_mb=0)
         eng = InferenceEngine(cfg, ecfg, ByteTokenizer())
         req = Request("r", prompt, SamplingParams(max_tokens=6, temperature=0.0,
-                                                  ignore_eos=True))
-        eng.add_request(req)
-        for _ in range(100):
-            eng.step(block_s=0.01)
-            if (eng.num_running == 0 and eng._queue.empty()
-                    and eng._deferred is None and not eng._prefilling):
-                break
-        ids = []
-        while True:
-            out = req.outputs.get(timeout=60)
-            ids.extend(out.token_ids)
-            if out.finished:
-                return ids, out
+                                                  ignore_eos=True, logprobs=1))
+        try:
+            eng.add_request(req)
+            harness.drive(eng, 100)
+            ids, lps, last = harness.collect(req, timeout=60, logprobs=True)
+        finally:
+            eng.stop()
+        return ({"r": ids}, {"r": [lp for lp, _ in lps]}), last
 
-    ids_cp, fin_cp = run(2)
-    ids_one, _ = run(1)
+    (got, fin_cp), (want, _) = run(2), run(1)
     assert fin_cp.num_prompt_tokens == 32
-    assert ids_cp == ids_one
+    harness.streams_agree(got, want, atol=RING_ATOL, whole=1)
 
 
 def _run_cp_engine(prompts, cp, layout, sequential=False):
     """Drive an engine at (cp, kv_layout) over ``prompts``; returns
-    (per-prompt greedy ids, paged prefix hit tokens).  ``sequential``
+    ((greedy ids, their log-probabilities) a request id, paged prefix hit
+    tokens).  ``sequential``
     waits out each request before adding the next (so earlier prompts'
     pages are registered before later ones admit — concurrent admission
     would batch them into one dispatch)."""
@@ -124,30 +134,33 @@ def _run_cp_engine(prompts, cp, layout, sequential=False):
                         kv_layout=layout, prefill_chunk=16)
     eng = InferenceEngine(cfg, ecfg, ByteTokenizer())
     eng.start()
-    outs = []
+    toks, lps = {}, {}
     try:
-        def drain(r):
-            ids = []
+        def collect(r):
+            toks[r.request_id], lps[r.request_id] = [], []
             while True:
                 out = r.outputs.get(timeout=120)
-                ids.extend(out.token_ids)
+                toks[r.request_id] += out.token_ids
+                lps[r.request_id] += [lp for lp, _ in (out.logprobs or ())]
                 if out.finished:
-                    return ids
+                    assert out.finish_reason == "length", out.error
+                    return
 
         reqs = []
         for i, p in enumerate(prompts):
             r = Request(f"r{i}", list(p), SamplingParams(
-                max_tokens=6, temperature=0.0, ignore_eos=True))
+                max_tokens=6, temperature=0.0, ignore_eos=True, logprobs=1))
             eng.add_request(r)
             if sequential:
-                outs.append(drain(r))
+                collect(r)
             else:
                 reqs.append(r)
-        outs.extend(drain(r) for r in reqs)
+        for r in reqs:
+            collect(r)
         hit = eng._alloc.hit_tokens if layout == "paged" else 0
     finally:
         eng.stop()
-    return outs, hit
+    return (toks, lps), hit
 
 
 def test_engine_paged_with_context_parallelism():
@@ -159,8 +172,9 @@ def test_engine_paged_with_context_parallelism():
     prompts = ([int(x) % cfg.vocab_size for x in range(5, 37)],
                [5, 6, 7, 8, 9, 10, 11, 12],
                [int(x) % cfg.vocab_size for x in range(3, 48)])
-    assert _run_cp_engine(prompts, 2, "paged")[0] == \
-        _run_cp_engine(prompts, 1, "slot")[0]
+    harness.streams_agree(_run_cp_engine(prompts, 2, "paged")[0],
+                          _run_cp_engine(prompts, 1, "slot")[0],
+                          atol=RING_ATOL, whole=2)
 
 
 def test_engine_paged_cp_prefix_sharing():
@@ -171,7 +185,7 @@ def test_engine_paged_cp_prefix_sharing():
     prompts = ([7] * 33, [7] * 33 + [9, 10, 11])
     ref, _ = _run_cp_engine(prompts, 1, "slot", sequential=True)
     got, hit = _run_cp_engine(prompts, 2, "paged", sequential=True)
-    assert got == ref
+    harness.streams_agree(got, ref, atol=RING_ATOL, whole=1)
     assert hit >= 32  # two full 16-token pages reused on device
 
 

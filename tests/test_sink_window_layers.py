@@ -13,7 +13,6 @@ The served-against-reference comparison (with the must-fail controls) is
 ``tests/test_sink_window_reference.py``."""
 
 import dataclasses
-import json
 import os
 
 import jax
@@ -21,20 +20,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from arks_tpu.models import moe, transformer as tf, weights
+from arks_tpu.models import transformer as tf, weights
 from arks_tpu.models.config import ModelConfig, get_config
 from arks_tpu.ops import paged_attention as pa
 from arks_tpu.ops.attention import paged_mixed_update_and_attend
 
-import test_window_layers as window_layers
+import harness
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-CONFIGS = os.path.join(ROOT, "benchmarks", "configs")
-
-
-def _config(name: str) -> dict:
-    with open(os.path.join(CONFIGS, name, "config.json")) as f:
-        return json.load(f)
+CONFIGS = harness.CONFIGS
+TINY = "tiny-swa-sink-moe"
+_config = harness.published
 
 
 def _published() -> dict:
@@ -42,11 +37,10 @@ def _published() -> dict:
     its ``reduced`` lists put back (48 layers, 256 experts, the whole
     vocabulary; the first period cut to four window layers by the dense
     layer)."""
-    d = _config("mimo-v2.5-ep16-l13")
-    d.update(num_hidden_layers=48, n_routed_experts=256, vocab_size=152576,
-             hybrid_layer_pattern=[0, 1, 1, 1, 1, 0] + [1, 1, 1, 1, 1, 0] * 7,
-             moe_layer_freq=[0] + [1] * 47)
-    return d
+    return harness.published(
+        "mimo-v2.5-ep16-l13", num_hidden_layers=48, n_routed_experts=256,
+        vocab_size=152576, moe_layer_freq=[0] + [1] * 47,
+        hybrid_layer_pattern=[0, 1, 1, 1, 1, 0] + [1, 1, 1, 1, 1, 0] * 7)
 
 
 # ---------------------------------------------------------------------------
@@ -104,32 +98,6 @@ def test_the_tiny_preset_is_what_its_config_file_says():
     assert got == get_config("tiny-swa-sink-moe")
     assert got.layer_kinds() == ("full", "window", "full", "window",
                                  "window", "full", "window", "window", "full")
-
-
-@pytest.mark.parametrize("change, word", [
-    ({"scoring_func": "softmax"}, "only sigmoid"),
-    ({"topk_method": "greedy"}, "only noaux_tc"),
-    ({"n_group": 8, "topk_group": 4}, "group-limited"),
-    ({"hybrid_block_size": 4}, "hybrid_block_size"),
-    ({"attention_chunk_size": 64}, "attention_chunk_size=64"),
-    ({"sliding_window_size": 32}, "sliding_window_size=32"),
-    ({"swa_head_dim": 32}, "swa_head_dim"),
-    ({"swa_v_head_dim": 8}, "swa_v_head_dim"),
-    ({"rope_scaling": {"rope_type": "yarn", "factor": 4}}, "rope_scaling"),
-    ({"attention_bias": True}, "attention_bias"),
-    ({"hidden_act": "gelu"}, "hidden_act"),
-    ({"hybrid_layer_pattern": [0, 1, 0, 1, 1, 1, 0, 1, 0]},
-     "a full layer every so many"),
-    ({"hybrid_layer_pattern": [1, 1, 0, 1, 1, 0, 1, 1, 0]},
-     "inside the dense prefix"),
-    ({"moe_layer_freq": [0, 1, 1, 0, 1, 1, 1, 1, 1]}, "must be a prefix"),
-    ({"swa_num_key_value_heads": 3}, "KV heads"),
-    ({"sliding_window": 0}, "without sliding_window"),
-])
-def test_from_hf_config_refuses_what_the_block_cannot_express(change, word):
-    with pytest.raises(ValueError, match=word):
-        ModelConfig.from_hf_config(dict(_config("tiny-swa-sink-moe"),
-                                        **change), name="t")
 
 
 _OTHERS = {
@@ -280,31 +248,6 @@ def test_the_tree_has_a_stack_a_kind_with_its_own_projections():
     assert n == cfg.num_params()
 
 
-def test_the_shares_of_a_routed_layer_add_up_to_the_uncut_layer():
-    """Two chips hold eight experts each of the preset's 16; with no shared
-    expert to count once, the parts their layers return add up to the layer
-    held whole, and every chosen pair lands on one chip."""
-    cfg = get_config("tiny-swa-sink-moe")
-    mp = jax.tree.map(lambda a: a[0], moe.init_moe_params(
-        cfg, jax.random.PRNGKey(7), jnp.float32, layers=1))
-    assert "shared_up" not in mp and "router_bias" in mp
-    x = jax.random.normal(jax.random.PRNGKey(2), (1, 96, 64), jnp.float32)
-    valid = jnp.ones((1, 96), bool)
-    whole, pairs = moe.moe_ffn(x, mp, cfg, grouped=False, row_valid=valid)
-    assert pairs.tolist() == [96 * 4, 0, 0]
-    half = dataclasses.replace(cfg, num_experts=8)
-    total, held_all = jnp.zeros_like(whole), 0
-    for rank in range(2):
-        part = dict(mp, **{k: mp[k][rank * 8:(rank + 1) * 8]
-                           for k in ("w_gate", "w_up", "w_down")})
-        out, held = moe.moe_ffn(x, part, half.with_expert_share(2, rank),
-                                grouped=False, row_valid=valid)
-        total, held_all = total + out, held_all + int(held[0])
-    assert held_all == 96 * 4
-    np.testing.assert_allclose(np.asarray(total), np.asarray(whole),
-                               rtol=2e-4, atol=2e-6)
-
-
 # ---------------------------------------------------------------------------
 # The launch with a value width and a sink; the row write of two widths
 # ---------------------------------------------------------------------------
@@ -443,38 +386,29 @@ def test_a_sink_is_refused_where_the_softmax_state_is_carried():
 # ---------------------------------------------------------------------------
 
 
-def _engine():
-    return window_layers._engine(get_config("tiny-swa-sink-moe"))
-
-
-def _drain(eng, n_requests=3, n_decode=8):
-    """The log-probabilities ``test_window_layers``' three requests (70, 9
-    and 133 tokens; the first ``n_requests`` of them) are served."""
-    reqs = window_layers._requests(n_decode, logprobs=1)[:n_requests]
-    return window_layers._drain(eng, reqs)[1]
-
-
 @pytest.fixture(scope="module")
 def oracle_run():
-    """One engine on the XLA path, three requests over both pools: what it
-    labels itself, what it counts, the log-probabilities it served."""
-    eng = _engine()
-    try:
-        labels = dict(eng.resolved_config)
-        lps = _drain(eng)
+    """One engine on the XLA path, the three requests over both pools: what it labels itself, what its counters rose by, the
+    log-probabilities it served."""
+    def counted(eng):
         m = eng.metrics
-        stats = dict(
-            kv_full=m.mixed_kv_bytes_total.get(kind="full"),
-            kv_window=m.mixed_kv_bytes_total.get(kind="window"),
+        return dict(kv_full=m.mixed_kv_bytes_total.get(kind="full"),
+                    kv_window=m.mixed_kv_bytes_total.get(kind="window"),
+                    released=m.kv_window_pages_released_total.total())
+
+    with harness.fresh(TINY) as eng:
+        labels = dict(eng.resolved_config)
+        before = counted(eng)
+        _, lps = harness.serve(eng)
+        stats = {k: v - before[k] for k, v in counted(eng).items()}
+        m = eng.metrics
+        stats.update(
             page_full=m.kv_pool_page_bytes.get(kind="full"),
             page_window=m.kv_pool_page_bytes.get(kind="window"),
-            released=m.kv_window_pages_released_total.total(),
             head_full=eng._page_head_bytes(),
             head_window=eng._page_head_bytes(eng._cache.win),
             free_after=eng._win.alloc.free_pages,
             win_pages=eng._win.alloc.num_pages)
-    finally:
-        eng.stop()
     return labels, lps, stats
 
 
@@ -512,11 +446,8 @@ def test_the_kernel_path_serves_the_oracle_paths_numbers(oracle_run,
     later one follows whichever token a tie of these tiny logits chose."""
     _, lps0, _ = oracle_run
     monkeypatch.setenv("ARKS_ATTN_IMPL", "pallas")
-    eng = _engine()
-    try:
+    with harness.fresh(TINY) as eng:
         assert eng.resolved_config["decode_impl"] == "pallas"
-        lps = _drain(eng, n_requests=2, n_decode=2)
+        _, lps = harness.drain(eng, harness.requests(2, logprobs=1)[:2])
         for rid in lps:
             np.testing.assert_allclose(lps[rid][0], lps0[rid][0], atol=1e-2)
-    finally:
-        eng.stop()

@@ -14,11 +14,9 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 # What a benchmark pod registers and exports is put back when this file is
-# done, as in the file this one stands beside.
-from test_benchmark_contract import (  # noqa: E402,F401
-    _registry_and_environment_restored,
-    seeded_tree_as_drawn,
-)
+# done, as in the files this one stands beside.
+pytestmark = pytest.mark.usefixtures("_registry_and_environment_restored")
+
 from benchmarks.tests import (  # noqa: E402
     test_reference_swa_sink_moe as _swa_sink_moe,
 )

@@ -20,24 +20,13 @@ from arks_tpu.engine import EngineConfig, InferenceEngine, Request, SamplingPara
 from arks_tpu.engine.tokenizer import ByteTokenizer
 from arks_tpu.models import get_config, transformer as tf
 
-
-def _drive(engine, n_steps=600):
-    for _ in range(n_steps):
-        engine.step(block_s=0.01)
-        if (engine.num_running == 0 and engine._queue.empty()
-                and engine._deferred is None
-                and not engine._prefilling
-                and not engine._awaiting_guide):
-            break
+import harness
 
 
-def _collect(req, timeout=60):
-    ids, fin = [], None
-    while True:
-        out = req.outputs.get(timeout=timeout)
-        ids.extend(out.token_ids)
-        if out.finished:
-            return ids, out
+_drive = harness.drive
+
+
+_collect = harness.collect
 
 
 def _mk_engine(draft_model, depth=0, draft_len=4, shared_params=None,
@@ -63,7 +52,7 @@ def _mk_engine(draft_model, depth=0, draft_len=4, shared_params=None,
             ekw["draft_cfg"] = cfg
     eng = InferenceEngine(cfg, ecfg, ByteTokenizer(), **ekw)
     if depth:
-        assert eng._pipe_warm_wait(300) == "ready", eng._pipe_warm_state
+        assert eng._pipe_warm_wait(120) == "ready", eng._pipe_warm_state
     return cfg, eng
 
 

@@ -23,6 +23,8 @@ from arks_tpu.obs import stepclock
 from arks_tpu.obs import trace as trace_mod
 from arks_tpu.obs.stepclock import StepClock
 
+import harness
+
 LEGS = stepclock.LEGS
 
 
@@ -361,15 +363,9 @@ def _engine(monkeypatch, depth, **over):
     monkeypatch.setenv("ARKS_TRACE", "1")
     monkeypatch.setenv("ARKS_PIPELINE_DEPTH", str(depth))
     monkeypatch.setenv("ARKS_MIXED_STEP", "auto")
-    kw = dict(model="tiny", num_slots=2, max_cache_len=64,
-              prefill_buckets=(8, 16, 32), steps_per_dispatch=4,
-              prefill_chunk=16, kv_layout="paged")
-    kw.update(over)
-    eng = InferenceEngine(get_config("tiny"), EngineConfig(**kw),
-                          ByteTokenizer())
-    if depth:
-        assert eng._pipe_warm_wait(300) == "ready"
-    return eng
+    return harness.warmed("tiny", base=dict(
+        num_slots=2, max_cache_len=64, prefill_buckets=(8, 16, 32),
+        steps_per_dispatch=4, prefill_chunk=16, kv_layout="paged"), **over)
 
 
 def _serve(eng, tag, n=5, max_tokens=12):

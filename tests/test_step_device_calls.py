@@ -71,7 +71,7 @@ THREE = ((4, "greedy_bias_min"), (5, "sampled_penalties"), (6, "sampled_guide"))
 ONE = ((7, "sampled_penalties"),)
 
 
-def _requests(tag: str, lanes, shift: int) -> list[Request]:
+def _traffic(tag: str, lanes, shift: int) -> list[Request]:
     return [Request(f"{tag}-{i}", [(11 * i + shift + j) % 200 + 3
                                    for j in range(n)],
                     _params(kind, seed=1000 + 17 * i + shift))
@@ -151,8 +151,8 @@ class _Driven:
 def driven(request):
     d = _Driven(spec=request.param == "spec")
     # Warm-up: every program and guide the cases below use.
-    d.run(_requests("warm3", THREE, shift=1))
-    d.run(_requests("warm1", ONE, shift=2))
+    d.run(_traffic("warm3", THREE, shift=1))
+    d.run(_traffic("warm1", ONE, shift=2))
     yield d
     d.eng.stop()
 
@@ -170,7 +170,7 @@ def test_two_device_calls_a_step_and_none_eager(driven, monkeypatch,
         children={"random": _Counted(jax.random, log, "jax.random")}))
     compiles0 = d.eng.metrics.xla_compilations_total.get()
     lanes = THREE if n_complete == 3 else ONE
-    _, steps = d.run(_requests(f"c{n_complete}", lanes, shift=20 + n_complete))
+    _, steps = d.run(_traffic(f"c{n_complete}", lanes, shift=20 + n_complete))
     assert log == [], log
     # Every size of the promotion program compiled at warm-up.
     assert d.eng.metrics.xla_compilations_total.get() == compiles0
@@ -200,13 +200,13 @@ def test_promotion_sizes_compile_at_warm_up_only():
         sizes = tuple(d.eng._promote_packs)
         assert sizes == (1, 4)         # 4 slots: a step completes at most 4
         assert d.calls()["warm"] == 0
-        d.run(_requests("w", ONE, shift=3))
+        d.run(_traffic("w", ONE, shift=3))
         assert d.calls()["warm"] == len(sizes)
         assert d.eng.compiled_program_variants()["_promote_fn"] == len(sizes)
-        d.run(_requests("w3", THREE, shift=4))   # guide, bias, penalties
+        d.run(_traffic("w3", THREE, shift=4))   # guide, bias, penalties
         compiles = d.eng.metrics.xla_compilations_total.get()
-        d.run(_requests("x3", THREE, shift=5))   # 3 rows padded to 4
-        d.run(_requests("x2", THREE[:2], shift=6))
+        d.run(_traffic("x3", THREE, shift=5))   # 3 rows padded to 4
+        d.run(_traffic("x2", THREE[:2], shift=6))
         assert d.eng.metrics.xla_compilations_total.get() == compiles
         assert d.calls()["warm"] == len(sizes)
         assert d.eng.compiled_program_variants()["_promote_fn"] == len(sizes)
@@ -217,7 +217,7 @@ def test_promotion_sizes_compile_at_warm_up_only():
 def test_folded_key_is_fold_in_of_the_seed(driven):
     d = driven
     d.keys.clear()
-    d.run(_requests("k", THREE, shift=40))
+    d.run(_traffic("k", THREE, shift=40))
     assert len(d.keys) == 3
     for seed, row in d.keys:
         want = np.asarray(jax.random.fold_in(jax.random.PRNGKey(seed), 1))
@@ -236,7 +236,7 @@ def test_streams_equal_the_one_slot_form(driven, monkeypatch, lanes):
     step's prompts against ``_apply_set_slot`` once a prompt."""
     d = driven
     eng = d.eng
-    batched, steps = d.run(_requests("b", lanes, shift=60))
+    batched, steps = d.run(_traffic("b", lanes, shift=60))
     assert max(s[2] for s in steps) == 3
 
     def one_by_one(completing, ids, want_lp, lp_host):
@@ -250,7 +250,7 @@ def test_streams_equal_the_one_slot_form(driven, monkeypatch, lanes):
             eng._register_slot(st.request, slot, first, len(st.ids),
                                seed=st.seed)
     monkeypatch.setattr(eng, "_promote_completing", one_by_one)
-    single, steps1 = d.run(_requests("s", lanes, shift=60))
+    single, steps1 = d.run(_traffic("s", lanes, shift=60))
     assert max(s[1]["promote"] for s in steps1) == 3
     assert single == batched
     assert all(len(s) > 0 for s in batched)
@@ -276,7 +276,7 @@ def test_follower_replays_the_packed_step_and_the_promotion(spec):
     leader, feng = _Driven(spec), _Driven(spec)
     try:
         leader.eng.dispatcher = _Recording()
-        leader.run(_requests("l", THREE, shift=70))
+        leader.run(_traffic("l", THREE, shift=70))
         ops = leader.eng.dispatcher.ops
         names = [op for op, _ in ops]
         sizes = len(leader.eng._promote_packs)

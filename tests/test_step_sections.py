@@ -14,6 +14,7 @@ anchors, named programs and the compilation counters (PR 24).
 - token streams are byte-identical with a window open and closed.
 """
 
+import functools
 import glob
 import time
 
@@ -21,12 +22,12 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from arks_tpu.engine import EngineConfig, InferenceEngine, Request, SamplingParams
+from arks_tpu.engine import InferenceEngine, Request, SamplingParams
 from arks_tpu.engine.engine import _named_jit
-from arks_tpu.engine.tokenizer import ByteTokenizer
-from arks_tpu.models import get_config
 from arks_tpu.obs import profiler as prof_mod
 from arks_tpu.obs.trace import Tracer
+
+import harness
 
 # ``deliver`` (PR 30): the workload below puts three requests on two
 # slots, so its resolves find a request waiting for a slot and hand their
@@ -39,26 +40,16 @@ def _mk_engine(monkeypatch, *, depth=0, spec=False, **kw):
     monkeypatch.setenv("ARKS_TRACE", "1")
     monkeypatch.setenv("ARKS_PIPELINE_DEPTH", str(depth))
     monkeypatch.setenv("ARKS_MIXED_STEP", "auto")
-    cfg = get_config("tiny")
-    defaults = dict(model="tiny", num_slots=2, max_cache_len=64,
+    defaults = dict(num_slots=2, max_cache_len=64,
                     prefill_buckets=(8, 16, 32), steps_per_dispatch=4,
                     prefill_chunk=16, kv_layout="paged")
     if spec:
         defaults.update(draft_model="tiny", draft_len=3)
-    defaults.update(kw)
-    eng = InferenceEngine(cfg, EngineConfig(**defaults), ByteTokenizer())
-    if depth:
-        assert eng._pipe_warm_wait(300) == "ready"
-    return cfg, eng
+    eng = harness.warmed("tiny", base=defaults, **kw)
+    return eng.cfg, eng
 
 
-def _drive(eng, n_steps=2000):
-    for _ in range(n_steps):
-        eng.step(block_s=0.01)
-        if (eng.num_running == 0 and eng._queue.empty()
-                and eng._deferred is None
-                and not eng._prefilling):
-            break
+_drive = harness.drive
 
 
 def _collect(req):

@@ -12,6 +12,7 @@ recorder, and the two serving gates —
   park/unpark pair and the pipelined issue->resolve spans.
 """
 
+import functools
 import json
 import time
 import urllib.error
@@ -24,6 +25,8 @@ from arks_tpu.engine.tokenizer import ByteTokenizer
 from arks_tpu.models import get_config
 from arks_tpu.obs import trace as trace_mod
 from arks_tpu.obs.trace import TraceCtx, Tracer, TraceStore
+
+import harness
 
 
 # ------------------------------------------------------------ W3C context
@@ -140,7 +143,8 @@ def test_flight_recorder_tail_orders_across_threads(monkeypatch):
     tr.evt("x", "queue", "B")
     th = threading.Thread(target=lambda: tr.evt("", "spill", "I", 3))
     th.start()
-    th.join()
+    th.join(30)
+    assert not th.is_alive(), "the spill event's thread did not end in 30 s"
     tr.evt("x", "finish", "I", "stop")
     tail = tr.tail(10)
     assert [r["name"] for r in tail] == ["queue", "spill", "finish"]
@@ -171,29 +175,16 @@ def _mk_engine(monkeypatch, *, depth=0, trace="1", spec=False, **kw):
     monkeypatch.setenv("ARKS_TRACE", trace)
     monkeypatch.setenv("ARKS_PIPELINE_DEPTH", str(depth))
     monkeypatch.setenv("ARKS_MIXED_STEP", "auto")
-    cfg = get_config("tiny")
-    defaults = dict(model="tiny", num_slots=2, max_cache_len=64,
+    defaults = dict(num_slots=2, max_cache_len=64,
                     prefill_buckets=(8, 16, 32), steps_per_dispatch=4,
                     prefill_chunk=16, kv_layout="paged")
     if spec:
         defaults.update(draft_model="tiny", draft_len=3)
-    defaults.update(kw)
-    eng = InferenceEngine(cfg, EngineConfig(**defaults), ByteTokenizer())
-    if depth:
-        assert eng._pipe_warm_wait(300) == "ready"
-    return cfg, eng
+    eng = harness.warmed("tiny", base=defaults, **kw)
+    return eng.cfg, eng
 
 
-def _drive(eng, n_steps=2000):
-    for _ in range(n_steps):
-        try:
-            eng.step(block_s=0.01)
-        except Exception as e:  # noqa: BLE001 — routed like _run_loop
-            eng._recover_from_fault(e)
-        if (eng.num_running == 0 and eng._queue.empty()
-                and eng._deferred is None
-                and not eng._prefilling and eng.state == "serving"):
-            break
+_drive = functools.partial(harness.drive, recover=True)
 
 
 def _collect(req):
@@ -317,7 +308,7 @@ def test_gateway_router_engine_one_trace(monkeypatch):
         model="tiny", num_slots=2, max_cache_len=64,
         prefill_buckets=(8, 16, 32), steps_per_dispatch=4,
         prefill_chunk=16, kv_layout="paged"), ByteTokenizer())
-    assert engine._pipe_warm_wait(300) == "ready"
+    assert engine._pipe_warm_wait(120) == "ready"
     engine.start()
     srv = OpenAIServer(engine, served_model_name="m1",
                        host="127.0.0.1", port=0)

@@ -192,7 +192,7 @@ def test_trainer_cli_end_to_end_with_resume(tmp_path):
              "--steps", str(steps), "--lr", "3e-3",
              "--ckpt-dir", str(ckpt), "--ckpt-every", "5",
              "--log-every", "5", "--platform", "cpu"],
-            capture_output=True, text=True, timeout=420)
+            capture_output=True, text=True, timeout=120)
         assert r.returncode == 0, r.stderr[-2000:]
         return r.stderr  # logging goes to stderr
 
@@ -223,7 +223,7 @@ def test_trainer_cli_resume_fence_rejects_changed_shape(tmp_path):
              "--data", str(data), "--seq-len", "32", "--steps", "2",
              "--ckpt-dir", str(tmp_path / "run"), "--platform", "cpu",
              *extra],
-            capture_output=True, text=True, timeout=420)
+            capture_output=True, text=True, timeout=120)
 
     assert run(["--batch-size", "4"]).returncode == 0
     r = run(["--batch-size", "8"])

@@ -9,9 +9,8 @@ whose entries behind the window are stale, the window layers' page pool
 
 The served-against-reference comparison (with the must-fail controls) is
 ``benchmarks/tests/test_reference_swa_moe.py``, imported into tier-1 by
-``tests/test_benchmark_contract.py``."""
+``tests/test_contract_swa_moe.py``."""
 
-import json
 import math
 import os
 
@@ -26,28 +25,23 @@ from arks_tpu.models.config import ModelConfig, get_config
 from arks_tpu.ops import paged_attention as pa
 from arks_tpu.ops.rope import apply_rope
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-LAGUNA = os.path.join(ROOT, "benchmarks", "configs", "laguna-s-2.1-ep8")
+import harness
+
+TINY = "tiny-swa-moe"
+LAGUNA = os.path.join(harness.CONFIGS, "laguna-s-2.1-ep8")
 
 
 def _published() -> dict:
     """Laguna-S-2.1's published ``config.json``: the benchmark's file with
     what its ``reduced`` lists put back (48 layers, 256 experts, the whole
     vocabulary)."""
-    with open(os.path.join(LAGUNA, "config.json")) as f:
-        d = json.load(f)
-    d.update(num_hidden_layers=48, num_experts=256, vocab_size=100352)
+    d = harness.published("laguna-s-2.1-ep8", num_hidden_layers=48,
+                          num_experts=256, vocab_size=100352)
     for k in ("layer_types", "mlp_layer_types", "gating_types",
               "num_attention_heads_per_layer"):
         head, period = d[k][:1], d[k][1:5]
         d[k] = head + (period * 12)[:47]
     return d
-
-
-def _tiny_config(**over) -> dict:
-    with open(os.path.join(ROOT, "benchmarks", "configs", "tiny-swa-moe",
-                           "config.json")) as f:
-        return {**json.load(f), "num_experts": 16, **over}
 
 
 # ---------------------------------------------------------------------------
@@ -97,36 +91,13 @@ def test_the_benchmark_configuration_is_whole_periods_and_a_share():
 
 def test_the_tiny_preset_is_what_its_config_file_says():
     want = get_config("tiny-swa-moe")
-    got = ModelConfig.from_hf_config(_tiny_config(), name="tiny-swa-moe")
+    got = ModelConfig.from_hf_config(
+        harness.published(TINY, num_experts=16), name=TINY)
     assert got == want
     shapes = jax.eval_shape(lambda k: tf.init_params(got, k),
                             jax.random.PRNGKey(0))
     n = sum(math.prod(x.shape) for x in jax.tree.leaves(shapes))
     assert n == got.num_params()
-
-
-@pytest.mark.parametrize("change, word", [
-    (dict(moe_router_logit_softcapping=30.0), "softcapping"),
-    (dict(moe_apply_router_weight_on_input=True),
-     "moe_apply_router_weight_on_input"),
-    (dict(gating_types=["per_head"] * 6 + ["per_layer"]), "gating_types"),
-    (dict(gating="elementwise"), "gating="),
-    (dict(layer_types=["full_attention"] * 7), "layer_types"),
-    (dict(layer_types=["full_attention", "sliding_attention",
-                       "full_attention"] + ["sliding_attention"] * 4),
-     "layer_types"),
-    (dict(num_attention_heads_per_layer=[4, 6, 8, 4, 6, 6, 4]),
-     "one head count"),
-    (dict(mlp_layer_types=["sparse", "dense"] + ["sparse"] * 5),
-     "dense layers must be a prefix"),
-    (dict(sliding_window=None), "sliding_window"),
-    (dict(attention_bias=True), "attention_bias"),
-    (dict(rope_scaling={"rope_type": "linear", "factor": 2.0}),
-     "rope_scaling"),
-])
-def test_from_hf_config_refuses_what_the_block_cannot_express(change, word):
-    with pytest.raises(ValueError, match=word):
-        ModelConfig.from_hf_config(_tiny_config(**change), name="bad")
 
 
 @pytest.mark.parametrize("key", ["layer_types", "sliding_window",
@@ -139,7 +110,7 @@ def test_a_plain_config_with_a_window_key_is_refused_not_served_full(key):
                  intermediate_size=128, num_hidden_layers=2,
                  num_attention_heads=8, num_key_value_heads=4)
     assert ModelConfig.from_hf_config(plain, name="ok").num_layers == 2
-    laguna = _tiny_config()
+    laguna = harness.published(TINY, num_experts=16)
     with pytest.raises(ValueError, match=key):
         ModelConfig.from_hf_config({**plain, key: laguna[key]}, name="bad")
 
@@ -148,10 +119,10 @@ def test_a_sliding_window_that_the_file_switches_off_is_no_window():
     """Qwen2's published files carry ``sliding_window`` beside
     ``use_sliding_window: false`` (the benchmark's qwen2.5-7b does)."""
     cfg = ModelConfig.from_hf_config(
-        os.path.join(ROOT, "benchmarks", "configs", "qwen2.5-7b"), name="q")
+        os.path.join(harness.CONFIGS, "qwen2.5-7b"), name="q")
     assert not cfg.windowed and cfg.num_layers == 28
     cfg = ModelConfig.from_hf_config(
-        os.path.join(ROOT, "benchmarks", "configs", "mixtral-8x7b-l4"),
+        os.path.join(harness.CONFIGS, "mixtral-8x7b-l4"),
         name="m")                                   # sliding_window: null
     assert not cfg.windowed
 
@@ -229,34 +200,6 @@ def test_softmax_routing_scales_after_the_normalisation():
     one = dataclasses.replace(cfg, routed_scaling_factor=1.0)
     assert "mul" not in str(jax.make_jaxpr(
         lambda x: moe.router_topk(x, one)[0])(logits)).split("div")[-1]
-
-
-@pytest.mark.parametrize("grouped", [True, False])
-def test_the_shares_of_a_routed_layer_add_up_to_the_uncut_layer(grouped):
-    """Four chips hold four experts each of a 16-expert layer; the parts
-    their layers return, the shared expert (which every chip computes
-    alike) counted once, add up to the layer held whole."""
-    cfg = get_config("tiny-swa-moe")
-    mp = jax.tree.map(lambda a: a[0], moe.init_moe_params(
-        cfg, jax.random.PRNGKey(7), jnp.float32, layers=1))
-    x = jax.random.normal(jax.random.PRNGKey(2), (1, 96, 64), jnp.float32)
-    valid = jnp.ones((1, 96), bool)
-    whole, pairs = moe.moe_ffn(x, mp, cfg, grouped=False, row_valid=valid)
-    assert pairs.tolist() == [96 * 4, 0, 0]
-    shared = moe._shared_expert(x, mp, cfg)
-    import dataclasses
-    quarter = dataclasses.replace(cfg, num_experts=4)
-    total, held_all = jnp.zeros_like(whole), 0
-    for rank in range(4):
-        part = dict(mp, **{k: mp[k][rank * 4:(rank + 1) * 4]
-                           for k in ("w_gate", "w_up", "w_down")})
-        out, held = moe.moe_ffn(x, part, quarter.with_expert_share(4, rank),
-                                grouped=grouped, row_valid=valid)
-        total = total + out - shared
-        held_all += int(held[0])
-    assert held_all == 96 * 4            # every chosen pair lands on one chip
-    np.testing.assert_allclose(np.asarray(total + shared),
-                               np.asarray(whole), rtol=2e-4, atol=2e-6)
 
 
 # ---------------------------------------------------------------------------
@@ -461,84 +404,31 @@ def test_a_page_is_never_released_while_a_dispatched_step_can_read_it():
 # ---------------------------------------------------------------------------
 
 
-def _engine(cfg=None, **over):
-    from arks_tpu.engine.engine import EngineConfig, InferenceEngine
-    from arks_tpu.engine.tokenizer import ByteTokenizer
-    cfg = cfg or get_config("tiny-swa-moe")
-    kw = dict(model=cfg.name, num_slots=3, max_cache_len=256,
-              prefill_buckets=(16,), prefill_chunk=16, weight_dtype="int8",
-              kv_cache_dtype="bf16", seed=3)
-    kw.update(over)
-    return InferenceEngine(cfg, EngineConfig(**kw), ByteTokenizer())
-
-
-def _requests(n_decode=12, logprobs=None):
-    from arks_tpu.engine.types import Request, SamplingParams
-    rng = np.random.default_rng(1)
-    sp = SamplingParams(max_tokens=n_decode, temperature=0.0,
-                        ignore_eos=True, logprobs=logprobs)
-    return [Request(f"r{i}", (2 + rng.integers(0, 200, n)).tolist(), sp)
-            for i, n in enumerate((70, 9, 133))]
-
-
-def _drain(eng, reqs, each_step=None):
-    for r in reqs:
-        eng.add_request(r)
-    done, toks = set(), {r.request_id: [] for r in reqs}
-    lps = {r.request_id: [] for r in reqs}
-    for _ in range(1000):
-        eng.step()
-        if each_step is not None:
-            each_step(eng)
-        for r in reqs:
-            while not r.outputs.empty():
-                o = r.outputs.get()
-                toks[r.request_id] += o.token_ids
-                lps[r.request_id] += [lp for lp, _ in (o.logprobs or ())]
-                if o.finished:
-                    assert o.finish_reason == "length", o.error
-                    done.add(r.request_id)
-        if len(done) == len(reqs):
-            return toks, lps
-    raise AssertionError("requests did not finish")
-
-
-def _held_invariant(eng):
-    """After every step: no slot holds more window pages than the
-    per-slot bound or the same page twice, and the pool's free list holds
-    every page no live slot holds."""
-    win = eng._win
-    held = 0
-    for slot in list(eng._slots) + list(eng._prefilling):
-        first, pages = win.held(slot)
-        assert len(pages) <= win.per_slot
-        assert len(set(pages)) == len(pages)
-        held += len(pages)
-    assert win.pages_in_use == held
-    assert win.alloc.free_pages == win.alloc.num_pages - held
+def _counted(eng):
+    m = eng.metrics
+    return dict(
+        released=m.kv_window_pages_released_total.total(),
+        held_steps=m.kv_window_page_steps_total.get(state="held"),
+        unreleased_steps=m.kv_window_page_steps_total.get(state="unreleased"),
+        kv_full=m.mixed_kv_bytes_total.get(kind="full"),
+        kv_window=m.mixed_kv_bytes_total.get(kind="window"),
+        hits=m.prefix_cache_hit_tokens_total.total())
 
 
 @pytest.fixture(scope="module")
 def depth0_streams():
-    eng = _engine()
-    try:
+    """One drain of one engine: its streams, and what its counters rose by
+    across the drain."""
+    with harness.fresh(TINY) as eng:
         assert eng.resolved_config["kv_page"] == "kv+window"
         assert eng.resolved_config["pipeline_depth"] == "0"
-        toks, lps = _drain(eng, _requests(logprobs=1), _held_invariant)
-        m = eng.metrics
-        stats = dict(
-            released=m.kv_window_pages_released_total.total(),
-            held_steps=m.kv_window_page_steps_total.get(state="held"),
-            unreleased_steps=m.kv_window_page_steps_total.get(
-                state="unreleased"),
-            kv_full=m.mixed_kv_bytes_total.get(kind="full"),
-            kv_window=m.mixed_kv_bytes_total.get(kind="window"),
+        before = _counted(eng)
+        toks, lps = harness.serve(eng)
+        stats = {k: v - before[k] for k, v in _counted(eng).items()}
+        stats.update(
             per_slot=eng._win.per_slot, win_pages=eng._win.alloc.num_pages,
             full_pages=eng._alloc.num_pages,
-            free_after=eng._win.alloc.free_pages,
-            hits=m.prefix_cache_hit_tokens_total.total())
-    finally:
-        eng.stop()
+            free_after=eng._win.alloc.free_pages)
     return toks, lps, stats
 
 
@@ -566,45 +456,14 @@ def test_no_prefix_is_reused_for_a_model_with_window_layers(depth0_streams):
     computed twice and reads the same."""
     toks, _, s = depth0_streams
     assert s["hits"] == 0
-    eng = _engine()
-    try:
-        first, _ = _drain(eng, _requests()[2:])
-        again, _ = _drain(eng, _requests()[2:])
+    with harness.fresh(TINY) as eng:
+        hits = eng.metrics.prefix_cache_hit_tokens_total.total()
+        first, _ = harness.drain(eng, harness.requests(12)[2:])
+        again, _ = harness.drain(eng, harness.requests(12)[2:])
         assert first == again
         assert first["r2"] == toks["r2"]
-        assert eng.metrics.prefix_cache_hit_tokens_total.total() == 0
+        assert eng.metrics.prefix_cache_hit_tokens_total.total() == hits
         assert eng._alloc.retained_pages == 0
-    finally:
-        eng.stop()
-
-
-def test_the_pipelined_path_gives_the_sequential_streams(depth0_streams,
-                                                         monkeypatch):
-    """Depth 2 runs a step ahead of the host's lengths: the pages are
-    covered from the resolved length for every dispatch in flight, and the
-    streams are the sequential path's."""
-    toks0, lps0, _ = depth0_streams
-    monkeypatch.setenv("ARKS_PIPELINE_DEPTH", "2")
-    eng = _engine()
-    try:
-        assert eng.resolved_config["pipeline_depth"] == "2"
-        assert eng._pipe_warm_wait(600.0) == "ready"
-        toks, lps = _drain(eng, _requests(logprobs=1), _held_invariant)
-        assert eng.metrics.pipeline_depth_occupancy._data   # it engaged
-        for rid in toks:
-            # Two compiled programs round differently: where two logits
-            # tie, the streams may part; up to there they are equal, and
-            # there the two chosen log-probabilities are (a tie).
-            same = next((i for i, (a, b) in enumerate(
-                zip(toks[rid], toks0[rid])) if a != b), len(toks[rid]))
-            assert same >= 1, (rid, toks[rid], toks0[rid])
-            n = min(same + 1, len(lps[rid]))
-            np.testing.assert_allclose(lps[rid][:n], lps0[rid][:n],
-                                       atol=2e-3)
-        assert sum(toks[r] == toks0[r] for r in toks) >= 2
-        assert eng._win.alloc.free_pages == eng._win.alloc.num_pages
-    finally:
-        eng.stop()
 
 
 def test_the_kernel_path_gives_the_oracle_paths_streams(depth0_streams,
@@ -613,32 +472,18 @@ def test_the_kernel_path_gives_the_oracle_paths_streams(depth0_streams,
     the window launch over the released tables."""
     toks0, lps0, _ = depth0_streams
     monkeypatch.setenv("ARKS_ATTN_IMPL", "pallas")
-    eng = _engine()
-    try:
+    with harness.fresh(TINY) as eng:
         assert eng.resolved_config["decode_impl"] == "pallas"
-        toks, lps = _drain(eng, _requests(logprobs=1))
+        toks, lps = harness.drain(eng, harness.requests(12, logprobs=1))
         for rid in lps:
             np.testing.assert_allclose(lps[rid][:4], lps0[rid][:4],
                                        atol=5e-2)
         assert toks["r1"][:2] == toks0["r1"][:2]
-    finally:
-        eng.stop()
 
 
 # ---------------------------------------------------------------------------
 # A full pool under slots x context: admission reserves pages
 # ---------------------------------------------------------------------------
-
-
-def _pool_invariant(eng):
-    """After every step: what admission promised fits the pool, and no
-    slot owns more full pages than it was promised."""
-    _held_invariant(eng)
-    assert sum(eng._pool_reserved.values()) <= eng._pool_budget
-    for slot in list(eng._slots) + list(eng._prefilling):
-        assert len(eng._slot_pages[slot]) <= eng._pool_reserved[slot]
-    assert (eng._alloc.num_pages - eng._alloc.free_pages
-            <= sum(eng._pool_reserved.values()))
 
 
 @pytest.mark.parametrize("depth", ["0", "2"])
@@ -652,35 +497,32 @@ def test_a_full_pool_under_the_worst_case_admits_by_pages(depth0_streams,
     monkeypatch.setenv("ARKS_PIPELINE_DEPTH", depth)
     toks0, _, s0 = depth0_streams
     assert s0["full_pages"] >= 48
-    eng = _engine(kv_pool_pages=16)
-    try:
+    with harness.fresh(TINY, kv_pool_pages=16) as eng:
         assert eng._alloc.num_pages == eng._pool_budget == 16
-        reqs = _requests()
+        reqs = harness.requests(12)
         need = [eng._pool_need(r, r.prompt_ids) for r in reqs]
         assert need[0] + need[1] <= 16 < sum(need)
         waited = []
+        waits = eng.metrics.admission_page_waits_total.total()
 
         def each(e):
-            _pool_invariant(e)
+            harness.pool_invariant(e)
             if e._pool_waiting is not None:
                 waited.append(e._pool_waiting[0].request_id)
                 assert len(e._free) >= 1       # a slot was free: pages not
-        toks, _ = _drain(eng, reqs, each)
+        toks, _ = harness.drain(eng, reqs, each)
         assert toks == toks0
         assert set(waited) == {"r2"}
         m = eng.metrics
-        assert m.admission_page_waits_total.total() == 1
+        assert m.admission_page_waits_total.total() - waits == 1
         assert m.num_requests_waiting.get() == 0
         assert not eng._pool_reserved and eng._pool_waiting is None
         assert eng._alloc.free_pages == 16 and eng.idle
-    finally:
-        eng.stop()
 
 
 def test_a_request_that_waits_for_pages_can_be_aborted():
-    eng = _engine(kv_pool_pages=16)
-    try:
-        reqs = _requests()
+    with harness.fresh(TINY, kv_pool_pages=16) as eng:
+        reqs = harness.requests(12)
         for r in reqs:
             eng.add_request(r)
         for _ in range(50):
@@ -698,18 +540,16 @@ def test_a_request_that_waits_for_pages_can_be_aborted():
         while not reqs[2].outputs.empty():
             out.append(reqs[2].outputs.get())
         assert [o.finish_reason for o in out if o.finished] == ["abort"]
-    finally:
-        eng.stop()
 
 
 def test_kv_pool_pages_is_refused_by_name_where_nothing_reserves_pages():
     with pytest.raises(ValueError, match="one whole context"):
-        _engine(kv_pool_pages=15)              # 256 tokens = 16 pages
+        harness.engine(TINY, kv_pool_pages=15)    # 256 tokens = 16 pages
     with pytest.raises(ValueError, match="one whole context"):
-        _engine(kv_pool_pages=49)              # over every slot's
+        harness.engine(TINY, kv_pool_pages=49)    # over every slot's
     with pytest.raises(ValueError, match="reserves pages"):
-        _engine(get_config("tiny"), kv_pool_pages=16, kv_layout="paged",
-                weight_dtype="bf16")
+        harness.engine("tiny", kv_pool_pages=16, kv_layout="paged",
+                        weight_dtype="bf16")
 
 
 # ---------------------------------------------------------------------------
@@ -729,7 +569,8 @@ def _shapes_drain(eng, reqs):
                                  for st in eng._prefilling.values())))
         return pack, budget
     eng._mixed_shape = spy
-    toks, lps = _drain(eng, reqs, _held_invariant if eng._win else None)
+    toks, lps = harness.drain(
+        eng, reqs, harness.held_invariant if eng._win else None)
     return toks, lps, seen
 
 
@@ -740,16 +581,17 @@ def test_a_short_sequential_step_takes_the_tail_shape(model, monkeypatch):
     every other the whole budget's; both are compiled before the first
     dispatch, and the streams are those of the one-shape engine."""
     monkeypatch.setenv("ARKS_MIXED_CHUNK_TOKENS", "64")
-    over = {} if model == "tiny-swa-moe" else dict(
-        kv_layout="paged", weight_dtype="bf16")
+    over = {} if model == TINY else dict(
+        num_slots=3, kv_layout="paged", weight_dtype="bf16")
     runs = {}
     for tail in (True, False):
-        eng = _engine(get_config(model), **over)
-        try:
+        # Fresh: ``_mixed_shape`` is patched, the tail pack taken away, and
+        # the programs an engine has compiled are counted from none.
+        with harness.fresh(model, **over) as eng:
             assert eng._mixed_budget == 64 and eng._mixed_tail == 16
             if not tail:                       # the one-shape engine
                 eng._mixed_tail_pack, eng._mixed_tail_warm = None, True
-            runs[tail] = _shapes_drain(eng, _requests(logprobs=1))
+            runs[tail] = _shapes_drain(eng, harness.requests(12, logprobs=1))
             assert eng._mixed_tail_warm
             # Programs compiled: the tail shape's two at the first
             # dispatch, the whole budget's as a step asks for it (these
@@ -757,8 +599,6 @@ def test_a_short_sequential_step_takes_the_tail_shape(model, monkeypatch):
             sizes = (eng._mixed_fn._cache_size(),
                      eng._mixed_lp_fn._cache_size())
             assert sizes == ((1, 2) if tail else (0, 1)), sizes
-        finally:
-            eng.stop()
     toks, lps, seen = runs[True]
     toks1, lps1, seen1 = runs[False]
     assert toks == toks1
@@ -775,58 +615,9 @@ def test_a_short_sequential_step_takes_the_tail_shape(model, monkeypatch):
 
 
 def test_a_budget_under_four_pages_keeps_one_shape():
-    eng = _engine()
-    try:
+    with harness.fresh(TINY) as eng:
         assert eng._mixed_budget == 16 and eng._mixed_tail == 0
         assert eng._mixed_tail_pack is None and eng._mixed_tail_warm
-    finally:
-        eng.stop()
-
-
-@pytest.mark.parametrize("over, env, word", [
-    (dict(kv_layout="slot"), {}, "slot layout"),
-    (dict(prefill_chunk=None), {}, "chunked prefill"),
-    (dict(draft_model="tiny-gqa"), {}, "speculative"),
-    ({}, {"ARKS_PREFIX_HOST_MB": "64"}, "host spill tier"),
-    ({}, {"ARKS_PREFIX_DISK_MB": "64"}, "disk spill tier"),
-    ({}, {"ARKS_RESIDENCY_WINDOW_PAGES": "6"}, "windowed residency"),
-    ({}, {"ARKS_PREEMPT": "1"}, "KV swap"),
-    ({}, {"ARKS_PEER_ADDRS": "10.0.0.1:8080"}, "peer fetch"),
-    ({}, {"ARKS_MIXED_STEP": "0"}, "legacy scheduler"),
-])
-def test_a_model_with_window_layers_refuses_by_name_what_packs_one_pool(
-        over, env, word, monkeypatch):
-    for k, v in env.items():
-        monkeypatch.setenv(k, v)
-    with pytest.raises(ValueError, match=word) as e:
-        _engine(**over)
-    assert "two page pools" in str(e.value)
-
-
-def test_a_model_with_window_layers_refuses_a_mesh_and_disaggregation():
-    from arks_tpu.engine.engine import EngineConfig, InferenceEngine
-    from arks_tpu.engine.tokenizer import ByteTokenizer
-    from arks_tpu.parallel.mesh import make_mesh
-    cfg = get_config("tiny-swa-moe")
-    mesh = make_mesh(tensor_parallel=2, devices=jax.devices()[:2])
-    with pytest.raises(ValueError, match="device mesh"):
-        InferenceEngine(cfg, EngineConfig(
-            model=cfg.name, num_slots=2, max_cache_len=64,
-            prefill_buckets=(16,), prefill_chunk=16, tensor_parallel=2),
-            ByteTokenizer(), mesh=mesh)
-    with pytest.raises(NotImplementedError, match="two head counts"):
-        tf.param_pspecs(cfg, 2)
-    from arks_tpu.server.__main__ import build_engine, build_server, parse_args
-    ns = parse_args(["--model", "tiny-swa-moe", "--platform", "cpu",
-                     "--num-slots", "2", "--max-model-len", "64",
-                     "--tensor-parallel-size", "1",
-                     "--disaggregation-mode", "prefill"])
-    eng = build_engine(ns)
-    try:
-        with pytest.raises(ValueError, match="two pools"):
-            build_server(ns, eng)
-    finally:
-        eng.stop()
 
 
 # ---------------------------------------------------------------------------
