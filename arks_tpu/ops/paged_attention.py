@@ -742,6 +742,19 @@ def _group_heads(stripe: jnp.ndarray, h0, head_group: int) -> jnp.ndarray:
     return out
 
 
+def _lanes(x: jnp.ndarray, n: int) -> jnp.ndarray:
+    """A softmax-state value ``[.., 128]``, the same number in every lane
+    (the running maximum, the sum, the correction: that is how the kernel
+    keeps them), as ``[.., n]``.  Tiled along the lanes where ``n`` is
+    whole tiles: the value's own vregs read again.  Taking lane 0 and
+    broadcasting it, the same numbers, is a cross-lane move a vreg a
+    visit, and was a seventh of the latent launch's time and two fifths of
+    the GQA launches' (PERF.md, PR 51)."""
+    if n % x.shape[-1]:
+        return jnp.broadcast_to(x[..., :1], x.shape[:-1] + (n,))
+    return jnp.concatenate([x] * (n // x.shape[-1]), axis=-1)
+
+
 def _mixed_softmax_block(q_ref, kbuf, vbuf, ksbuf, vsbuf, m_ref, l_ref,
                          acc_ref, buf, si, pos0, q_lo, *, page, scale,
                          quantized, int4, h0=None, window=0):
@@ -784,7 +797,7 @@ def _mixed_softmax_block(q_ref, kbuf, vbuf, ksbuf, vsbuf, m_ref, l_ref,
     m_curr = jnp.max(scores, axis=2, keepdims=True)
     m_next = jnp.maximum(m_prev, jnp.broadcast_to(m_curr, m_prev.shape))
     correction = jnp.exp(m_prev - m_next)
-    p = jnp.exp(scores - m_next[..., :1])
+    p = jnp.exp(scores - _lanes(m_next, scores.shape[-1]))
     l_curr = jnp.sum(p, axis=2, keepdims=True)
     l_next = l_prev * correction + jnp.broadcast_to(l_curr, l_prev.shape)
     if quantized:
@@ -792,7 +805,7 @@ def _mixed_softmax_block(q_ref, kbuf, vbuf, ksbuf, vsbuf, m_ref, l_ref,
     pv = jax.lax.dot_general(
         p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32)           # [Hkv, G*BQ, D]
-    acc_ref[:] = acc_ref[:] * correction[..., :1] + pv
+    acc_ref[:] = acc_ref[:] * _lanes(correction, pv.shape[-1]) + pv
     m_ref[:] = m_next
     l_ref[:] = l_next
 
@@ -973,7 +986,7 @@ def _paged_mixed_ragged_kernel(layer_ref, tables_ref, pos_start_ref,
             lo_ref[:] = l_ref[:].reshape(1, hg, g, bq, 128)
             ao_ref[:] = acc_ref[:].reshape(1, hg, g, bq, d)
         else:
-            out = acc_ref[:] / (l_ref[..., :1] + 1e-9)
+            out = acc_ref[:] / (_lanes(l_ref[:], d) + 1e-9)
             o_ref[:] = out.reshape(1, hg, g, bq, d).astype(o_ref.dtype)
 
 

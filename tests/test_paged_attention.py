@@ -684,3 +684,170 @@ def test_paged_mixed_attention_decode_lane_matches_decode_kernel():
                                  block_b=1, interpret=True)
     np.testing.assert_allclose(np.asarray(out[:, :, :, 0]), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# The softmax state read lane-tiled (PR 51)
+# ---------------------------------------------------------------------------
+
+
+def _lane0_broadcast(x, n):
+    """The oracle: a softmax-state value ``[.., 128]`` widened to ``n``
+    lanes the way the block did before PR 51, lane 0 sliced out and
+    broadcast (``scores - m_next[..., :1]``, ``acc * correction[..., :1]``,
+    ``acc / (l[..., :1] + eps)``)."""
+    return jnp.broadcast_to(x[..., :1], x.shape[:-1] + (n,))
+
+
+@pytest.fixture
+def lane0_broadcast(monkeypatch):
+    """A context in which every ragged launch widens its softmax state by
+    the oracle.  The jitted launches look ``_lanes`` up when they TRACE, so
+    their caches go on the way in and on the way out."""
+    import contextlib
+
+    from arks_tpu.ops import paged_attention as pa
+
+    def forget():
+        pa._paged_mixed_call.clear_cache()
+        pa._paged_mixed_flat_call.clear_cache()
+
+    @contextlib.contextmanager
+    def oracle():
+        forget()
+        with monkeypatch.context() as mp:
+            mp.setattr(pa, "_lanes", _lane0_broadcast)
+            yield
+        forget()
+    yield oracle
+    forget()
+
+
+# Whole lane tiles everywhere the state is widened: pages of 128 keys,
+# heads of 128 lanes (a latent row of 256 with values of 128).
+_TILED = dict(page=128, block_q=8, window=300, hkv=2, g=2, d=128,
+              max_pages=6)
+
+# Lanes (pos_start, q_len) of a batch, by what the span meets.
+_TILED_SPANS = {
+    # Every key of every lane in page 0.
+    "one-page": [(24, 8), (0, 2), (72, 1)],
+    # A two-block chunk over five pages and a decode lane over six.
+    "many-pages": [(560, 16), (700, 1), (0, 0)],
+    # Blocks whose queries sit on both sides of a page's end (124..131,
+    # 250..257) and one that ends on it (248..255).
+    "block-straddles-a-page": [(124, 8), (250, 8), (248, 8)],
+    # The lowest key of the block's first query (pos - 299) is the first
+    # key of page 1 (128) / of page 2 (256); the third lane's LAST query
+    # (428) is the first that no longer holds page 1's first key.
+    "window-edge-on-a-page-boundary": [(427, 8), (555, 16), (421, 8)],
+    # ... and in the middle of one.
+    "window-edge-inside-a-page": [(480, 8), (700, 1), (610, 12)],
+    # Rows past q_len in the last block of a lane.
+    "rows-past-q_len": [(320, 5), (130, 11), (720, 1)],
+}
+
+
+def _tiled_flat_batch(lanes):
+    """The flat batch of ``lanes`` ((pos_start, q_len) a lane): the lanes'
+    rows one after the other, two padding rows at the end."""
+    q_len = np.asarray([n for _, n in lanes], np.int32)
+    q_start = (np.cumsum(q_len) - q_len).astype(np.int32)
+    token_slot = np.full((int(q_len.sum()) + 2,), -1, np.int32)
+    for s, (start, n) in enumerate(zip(q_start, q_len)):
+        token_slot[start:start + n] = s
+    return (jnp.asarray(token_slot), jnp.asarray(q_start),
+            jnp.asarray(q_len),
+            jnp.asarray([p for p, _ in lanes], jnp.int32))
+
+
+def _tiled_pools(kind, lanes: int):
+    """Pools, tables, the query shape and the launch's keywords of a
+    ``kind``."""
+    c = _TILED
+    ks = jax.random.split(jax.random.PRNGKey(51), 6)
+    n = lanes * c["max_pages"] + 2
+    tables = jax.random.permutation(ks[0], n)[:lanes * c["max_pages"]] \
+        .reshape(lanes, c["max_pages"]).astype(jnp.int32)
+    if kind == "latent":
+        pools = (jax.random.normal(ks[1], (2, n, 1, c["page"], 256),
+                                   jnp.float32), None, None, None)
+        return pools, tables, (1, c["hkv"] * c["g"], 256), dict(
+            latent_v=128, scale=256 ** -0.5)
+    hkv, g, d = c["hkv"], c["g"], c["d"]
+    shape = (2, n, hkv, c["page"], d)
+    if kind == "gqa-int8":
+        pools = (jax.random.randint(ks[1], shape, -127, 128, jnp.int8),
+                 jax.random.randint(ks[2], shape, -127, 128, jnp.int8),
+                 jax.random.uniform(ks[3], shape[:4], jnp.float32, .01, .03),
+                 jax.random.uniform(ks[4], shape[:4], jnp.float32, .01, .03))
+    else:
+        pools = (jax.random.normal(ks[1], shape, jnp.float32),
+                 jax.random.normal(ks[2], shape, jnp.float32), None, None)
+    kw = dict(window=c["window"]) if kind.startswith("window") else {}
+    if kind == "window+sink":
+        kw["sink"] = jax.random.normal(ks[5], (hkv, g), jnp.float32)
+    return pools, tables, (hkv, g, d), kw
+
+
+@pytest.mark.parametrize("span", sorted(_TILED_SPANS))
+@pytest.mark.parametrize("kind", ["gqa", "gqa-int8", "window", "window+sink",
+                                  "latent"])
+def test_lane_tiled_softmax_state_is_the_lane0_broadcast_bytes(
+        kind, span, lane0_broadcast):
+    """The launch that reads its running maximum, correction and sum
+    lane-tiled is BIT FOR BIT the one that slices lane 0 out and
+    broadcasts it, by kind of launch and by what the span meets."""
+    from arks_tpu.ops.paged_attention import paged_mixed_attention_flat
+    lanes = _TILED_SPANS[span]
+    pools, tables, (hkv, g, d), kw = _tiled_pools(kind, len(lanes))
+    token_slot, q_start, q_len, pos = _tiled_flat_batch(lanes)
+    q = jax.random.normal(jax.random.PRNGKey(5),
+                          (token_slot.shape[0], hkv, g, d), jnp.float32)
+
+    def launch():
+        return np.asarray(paged_mixed_attention_flat(
+            q, pools[0], pools[1], tables, token_slot, q_start, q_len, pos,
+            1, pools[2], pools[3], block_q=_TILED["block_q"], interpret=True,
+            **kw))
+
+    got = launch()
+    with lane0_broadcast():
+        want = launch()
+    assert np.isfinite(got).all() and np.abs(got).max() > 0
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("kv", ["f32", "int8"])
+def test_lane_tiled_softmax_state_carries_an_empty_span(kv, lane0_broadcast):
+    """A ``carry`` call whose span is empty (every page of the lane fell in
+    the earlier span) passes the state through and normalises it; the other
+    lanes' spans run on both sides of the split.  Emitted state and final
+    output are the lane-0-broadcast bytes, and the single call's."""
+    q, kp, vp, kps, vps, tables, _ = _setup(quantized=kv == "int8",
+                                            page=128, d=128)
+    b, hkv, g, d = q.shape
+    qm = jax.random.normal(jax.random.PRNGKey(12), (b, hkv, g, 4, d),
+                           jnp.float32)
+    pos_start = jnp.asarray([3 * 128 + 5, 2 * 128, 128 + 1, 3], jnp.int32)
+    q_len = jnp.asarray([4, 1, 3, 2], jnp.int32)
+    kwargs = dict(k_scale=kps, v_scale=vps, block_q=4, interpret=True)
+    split = jnp.full((b,), 2, jnp.int32)
+
+    def chained():
+        state = paged_mixed_attention(
+            qm, kp, vp, tables, pos_start, q_len, 0, page_hi=split,
+            emit_state=True, **kwargs)
+        out = paged_mixed_attention(
+            qm, kp, vp, tables, pos_start, q_len, 0, page_lo=split,
+            carry_state=state, **kwargs)
+        return [np.asarray(x) for x in (*state, out)]
+
+    got = chained()
+    with lane0_broadcast():
+        want = chained()
+    for a, b_ in zip(got, want):
+        np.testing.assert_array_equal(a, b_)
+    whole = paged_mixed_attention(qm, kp, vp, tables, pos_start, q_len, 0,
+                                  **kwargs)
+    np.testing.assert_array_equal(np.asarray(whole), got[-1])
