@@ -1362,16 +1362,18 @@ class InferenceEngine:
         # call trace.evt — tests/test_hotpath_guard.py enforces it) and
         # doubles as the flight recorder the watchdog/fault dumps attach.
         self.trace = trace_mod.Tracer()
-        # One control: a profiler window (engine.profiler.start/stop, the
-        # /v1/profiler endpoints) also switches the step-section spans on
-        # and returns them; with no window open a section site is one
-        # attribute test (self.profiler.sections).
-        self.profiler = prof_mod.ProfilerWindows(tracer=self.trace)
         # The step loop's own clock, on in every window (obs/stepclock.py):
         # fed at the dispatch and wait sites below, by this thread alone.
         self.step_clock = stepclock_mod.StepClock(
             self.metrics, self.trace,
             state=lambda: (len(self._slots), self._queue.qsize()))
+        # One control: a profiler window (engine.profiler.start/stop, the
+        # /v1/profiler endpoints) also switches the step-section spans on
+        # and returns them; with no window open a section site is one
+        # attribute test (self.profiler.sections).  It reads the step clock
+        # at its two ends and returns the cycles that closed in between.
+        self.profiler = prof_mod.ProfilerWindows(tracer=self.trace,
+                                                 clock=self.step_clock)
         self.trace.wake_hist = self.metrics.host_wake_late_seconds
         self._admit_popped = 0   # requests _admit() took off the queue
         _watch_compilations(self)

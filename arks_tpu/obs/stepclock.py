@@ -2,11 +2,13 @@
 where the end-to-end numbers are read.
 
 The step-section spans (``phase.<phase>.<section>``) name WHAT the host
-did, but only inside a profiler window, whose Python tracer slows the very
-host work they time.  This clock is on like the request spans are, in
-every window: it says HOW LONG each leg of a cycle was, from
+did, but only inside a profiler window.  This clock is on like the request
+spans are, in every window: it says HOW LONG each leg of a cycle was, from
 ``time.monotonic`` readings the step loop takes at its dispatch and wait
-sites.  It is written by the engine thread alone and has no switch.
+sites.  It is written by the engine thread alone and has no switch.  A
+profiler window reads it at its two ends (:meth:`StepClock.snapshot`,
+:func:`window`), so the cycles of a traced slice can be held against the
+same cycles of the untraced run.
 
 **A cycle** runs from the return of one model dispatch call to the return
 of the next, and carries the ``kind`` of the dispatch that OPENED it, the
@@ -37,12 +39,18 @@ the ``host``, or ``compile`` when the process compiled in it), kept as a
 record (:attr:`StepClock.stalls`) and written once into the tracer's ring
 as the engine-scope event ``stall``, which the collector lays on every
 request trace that lived through it (docs/monitoring.md, "Reading a stall
-record").
+record").  The record says what held the process: ``cpu_s``, the process's
+own CPU seconds across the cycle, and ``gc_s``, the seconds of it the
+garbage collector ran.
 """
 
 from __future__ import annotations
 
 import collections
+import contextlib
+import gc
+import time
+import weakref
 
 LEGS = ("wait", "starved", "overlap")
 WHERE = ("dispatch", "wait", "host", "compile")
@@ -70,6 +78,51 @@ CYCLE_BUCKETS = [0.005, 0.0075, 0.01, 0.015, 0.02, 0.03, 0.04, 0.05, 0.065,
 # (host_wake_late_seconds): 0.5 ms - 5 s.
 LAG_BUCKETS = [0.0005, 0.001, 0.002, 0.003, 0.005, 0.0075, 0.01, 0.015,
                0.02, 0.03, 0.05, 0.075, 0.1, 0.15, 0.25, 0.5, 1.0, 2.5, 5.0]
+
+# One kind's cumulative totals, in this order (``StepClock.snapshot``).
+KIND_TOTALS = ("cycles", "cycle_s", "wait_s", "starved_s", "overlap_s",
+               "call_s")
+_NO_TOTALS = (0, 0.0, 0.0, 0.0, 0.0, 0.0)
+
+
+def window(before: tuple, after: tuple) -> dict:
+    """What the clock accounted between two :meth:`StepClock.snapshot`
+    readings: ``{"kinds": {kind: {cycles, cycle_s, wait_s, starved_s,
+    overlap_s, call_s}}, "stall_s"}``.  Whole cycles only, by when they
+    CLOSED: for every kind the three legs sum to ``cycle_s``; a kind that
+    closed no cycle and made no call between the two is left out."""
+    kinds = {}
+    for kind, b in after[0].items():
+        a = before[0].get(kind, _NO_TOTALS)
+        if b != a:
+            kinds[kind] = {f: y - x for f, x, y in zip(KIND_TOTALS, a, b)}
+    return {"kinds": kinds, "stall_s": after[1] - before[1]}
+
+
+def _watch_gc(clock: "StepClock") -> None:
+    """Feed ``clock`` the collector's start and stop (``gc.callbacks``).
+    The hook holds the clock weakly and leaves the list with it: an engine
+    that is dropped leaves nothing behind.  (Never from inside the hook:
+    the collector walks the list by index while it calls.)"""
+    ref = weakref.ref(clock)
+
+    def hook(phase: str, info: dict) -> None:
+        c = ref()
+        if c is None:
+            return
+        gen = info["generation"]
+        if phase == "start":
+            c._gc_t0[gen] = time.monotonic()
+        elif c._gc_t0[gen] is not None:     # (a stop whose start it saw)
+            c._gc_s += time.monotonic() - c._gc_t0[gen]
+            c._gc_t0[gen] = None
+
+    def unhook() -> None:
+        with contextlib.suppress(ValueError):
+            gc.callbacks.remove(hook)
+
+    gc.callbacks.append(hook)
+    weakref.finalize(clock, unhook).atexit = False
 
 
 class _Trail:
@@ -122,6 +175,27 @@ class StepClock:
         # Cycles a dispatch opened and idle() dropped: with the
         # histogram's counts and the stalls, every dispatch.
         self.abandoned = 0
+        # Plain cumulative totals beside the families: ({kind: KIND_TOTALS
+        # values}, stalled seconds, stalls).  Replaced WHOLE at a dispatch's
+        # return and never written into afterwards, so a thread that reads
+        # the attribute (a profiler window's two ends) holds one consistent
+        # object: whole cycles, every kind's legs summing to its cycle_s.
+        self._totals: tuple = ({}, 0.0, 0)
+        # What a stall record says of the process: its own CPU seconds at
+        # the open cycle's beginning, and the collector's seconds so far
+        # (summed by the gc.callbacks hook, start stamps by generation).
+        self._cpu_open = 0.0
+        self._gc_t0: list[float | None] = [None, None, None]
+        self._gc_s = 0.0
+        self._gc_open = 0.0
+        _watch_gc(self)
+
+    def snapshot(self) -> tuple:
+        """The cumulative totals as they stood at the last dispatch's
+        return: ``({kind: (cycles, cycle_s, wait_s, starved_s, overlap_s,
+        call_s)}, stall_s, stalls)``.  Safe from any thread; the difference
+        of two is :func:`window`'s."""
+        return self._totals
 
     def waited(self, t0: float, t1: float, inflight: int) -> None:
         """A resolve's blocking fetch ran from ``t0`` to ``t1`` and left
@@ -139,15 +213,25 @@ class StepClock:
         ``t_ret``: closes the open cycle there and opens the next."""
         call = t_ret - t_call
         compiled = self._compiles.total()
-        if self._kind is None or not self._close(
-                t_ret, call, compiled != self._compiled):
+        cpu = time.process_time()
+        kinds, stall_s, stalls = self._totals
+        kinds = dict(kinds)
+        if self._kind is not None and self._close(
+                t_ret, call, compiled != self._compiled, cpu, kinds):
+            stall_s, stalls = stall_s + (t_ret - self._t_open), stalls + 1
+        else:
             self._calls.inc(call, kind=kind)
+            t = kinds.get(kind, _NO_TOTALS)
+            kinds[kind] = t[:5] + (t[5] + call,)
+        self._totals = (kinds, stall_s, stalls)
         self._kind = kind
         self._t_open = t_ret
         self._rows = rows
         self._wait = 0.0
         self._starved_from = None
         self._compiled = compiled
+        self._cpu_open = cpu
+        self._gc_open = self._gc_s
 
     def idle(self) -> None:
         """The pod has nothing to run: the open cycle belongs to no leg."""
@@ -155,8 +239,10 @@ class StepClock:
             self._kind = None
             self.abandoned += 1
 
-    def _close(self, t_ret: float, call: float, compiled: bool) -> bool:
-        """Account the open cycle; True if it was a stall."""
+    def _close(self, t_ret: float, call: float, compiled: bool, cpu: float,
+               kinds: dict) -> bool:
+        """Account the open cycle (``kinds``: the totals being built for
+        this dispatch's return); True if it was a stall."""
         kind = self._kind
         cycle = t_ret - self._t_open
         wait = self._wait
@@ -169,17 +255,23 @@ class StepClock:
         trail.add(cycle)
         if (median is not None and cycle > STALL_MIN_S
                 and cycle > STALL_X * median):
-            self._stalled(t_ret, kind, cycle, median, call, wait, compiled)
+            self._stalled(t_ret, kind, cycle, median, call, wait, compiled,
+                          cpu)
             return True
+        overlap = max(cycle - wait - starved, 0.0)
         legs = self._legs
         legs.inc(wait, kind=kind, leg="wait")
         legs.inc(starved, kind=kind, leg="starved")
-        legs.inc(max(cycle - wait - starved, 0.0), kind=kind, leg="overlap")
+        legs.inc(overlap, kind=kind, leg="overlap")
         self._cycles.observe(cycle, kind=kind)
+        n, cycle_s, wait_s, starved_s, overlap_s, call_s = kinds.get(
+            kind, _NO_TOTALS)
+        kinds[kind] = (n + 1, cycle_s + cycle, wait_s + wait,
+                       starved_s + starved, overlap_s + overlap, call_s)
         return False
 
-    def _stalled(self, t_ret, kind, cycle, median, call, wait,
-                 compiled) -> None:
+    def _stalled(self, t_ret, kind, cycle, median, call, wait, compiled,
+                 cpu) -> None:
         # The leg that held the excess: a stalled cycle is over eight
         # medians long, so its longest part is over two and no sound leg.
         host = cycle - call - wait
@@ -198,10 +290,16 @@ class StepClock:
         # the stall, filled in by the collector when it folds the event
         # (obs/trace.py): about as late as the stall is long = every
         # Python thread stood; punctual = only this thread's call blocked.
+        # cpu_s: the process's own CPU seconds across the cycle (all its
+        # threads): near 0 = it was not scheduled, near ``seconds`` or over
+        # = something computed, holding the GIL or beside it.  gc_s: the
+        # collector's part of the cycle.
         record = {"t_monotonic": t_ret, "kind": kind, "where": where,
                   "seconds": cycle, "median_s": median, "call_s": call,
                   "wait_s": wait, "rows": self._rows, "streams": streams,
-                  "queued": queued, "wake_late_s": None}
+                  "queued": queued, "wake_late_s": None,
+                  "cpu_s": cpu - self._cpu_open,
+                  "gc_s": self._gc_s - self._gc_open}
         self.stalls.append(record)
         if self._tracer is not None:
             self._tracer.evt("", "stall", "I", record)

@@ -432,9 +432,11 @@ class OpenAIServer:
                 if self.path == "/v1/profiler/start":
                     # On-demand jax.profiler window (operator tooling —
                     # exempt from the drain gate, like GET diagnostics).
+                    # "python": true asks for the Python tracer too.
                     return self._json(
                         200, server.engine.profiler.start(
-                            body.get("logdir") or None))
+                            body.get("logdir") or None,
+                            python=body.get("python") is True))
                 if self.path == "/v1/profiler/stop":
                     return self._json(200, server.engine.profiler.stop())
                 if self.path == "/v1/elastic/resize":

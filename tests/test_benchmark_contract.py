@@ -9,8 +9,8 @@ the manifest to the contract; imported here, each counts in the tier-1 run
 and a PR that breaks a reference cannot pass the gate unnoticed.  Nothing
 is copied: the functions are the instrument's own.
 
-This file holds the manifest, the step-clock readers, the decoder family
-and ``mla_moe``; every other reference family is a file of its own,
+This file holds the manifest, the step-clock readers (the window's and the
+traced slice's), the decoder family and ``mla_moe``; every other reference family is a file of its own,
 ``tests/test_contract_<family>.py`` (``--dist loadfile`` hands a file to one
 worker, and a family's pods are most of its cost: ROADMAP D9).  What a
 benchmark pod registers and exports is put back when a file is done
@@ -46,6 +46,14 @@ from benchmarks.tests.test_reference_mla_moe import (  # noqa: E402,F401
     test_mla_moe_served_logprobs_against_the_reference,
     test_the_family_keeps_the_contract_and_imports_nothing_of_the_program,
     test_the_routing_margin_is_in_biased_score_units,
+)
+from benchmarks.tests.test_slice_readers import (  # noqa: E402,F401
+    test_a_run_without_a_marked_slice_reads_none,
+    test_a_slice_that_stood_still_says_so_by_itself,
+    test_the_ratio_is_none_when_the_window_ran_none_of_the_slices_kinds,
+    test_the_ratio_weighs_the_windows_means_by_the_slices_own_kinds,
+    test_the_readers_read_what_the_programs_own_window_hands_back,
+    test_the_starved_share_is_the_starved_leg_over_all_legs_of_the_slice,
 )
 from benchmarks.tests.test_step_clock_readers import (  # noqa: E402,F401
     test_a_cycle_mean_is_its_kinds_own,

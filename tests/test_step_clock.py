@@ -6,7 +6,10 @@ engine cases assert counts, sums against the run's own wall time as an
 upper bound, and which families stand on ``/metrics``.
 """
 
+import gc
 import json
+import sys
+import threading
 import time
 import types
 import urllib.request
@@ -338,6 +341,319 @@ def test_the_profilers_auto_arm_reads_the_clocks_median(monkeypatch,
     pw.on_step(0.3, s.clock.last_median)
     assert pw.active
     pw.stop()
+
+
+# ---------------------------------------------------------------------------
+# The plain totals, and the profiler window that marks them (PR 52)
+# ---------------------------------------------------------------------------
+
+
+def _kinds(clock):
+    return {k: dict(zip(stepclock.KIND_TOTALS, v))
+            for k, v in clock.snapshot()[0].items()}
+
+
+def test_the_totals_are_the_families_own_numbers():
+    """``snapshot()`` carries what the families carry, as plain numbers:
+    a stalled cycle is in neither's legs and in both's stalled seconds."""
+    m, s = _warm()
+    for _ in range(3):
+        s.step(kind="seq_tail", wait=0.020)
+    s.step(kind="pipe", gap=0.9)                # closes a sound seq_tail
+    s.step()                                    # closes pipe's first: cold
+    kinds, stall_s, stalls = s.clock.snapshot()
+    assert set(kinds) == {"seq", "seq_tail", "pipe"}
+    for kind, tot in _kinds(s.clock).items():
+        n, total = _cycles(m, kind)
+        legs = _legs(m, kind)
+        assert tot["cycles"] == n and tot["cycle_s"] == pytest.approx(total)
+        assert (tot["wait_s"], tot["starved_s"], tot["overlap_s"]) == (
+            pytest.approx(legs["wait"]), pytest.approx(legs["starved"]),
+            pytest.approx(legs["overlap"]))
+        assert tot["call_s"] == pytest.approx(
+            m.step_call_seconds_total.get(kind=kind))
+        assert tot["wait_s"] + tot["starved_s"] + tot["overlap_s"] \
+            == pytest.approx(tot["cycle_s"])
+    assert (stall_s, stalls) == (0.0, 0)
+    s.step(gap=3.0)
+    s.step()
+    kinds, stall_s, stalls = s.clock.snapshot()
+    assert stalls == 1 and stall_s == pytest.approx(3.053)
+    assert stall_s == pytest.approx(sum(v for _, v in _stalls(m).values()))
+    assert kinds["seq"][0] == _cycles(m, "seq")[0]
+    # The object a reader holds is never written into afterwards.
+    held = s.clock.snapshot()
+    frozen = (dict(held[0]), held[1], held[2])
+    for _ in range(5):
+        s.step()
+    assert (dict(held[0]), held[1], held[2]) == frozen
+    assert s.clock.snapshot()[0]["seq"][0] == frozen[0]["seq"][0] + 5
+
+
+def test_a_reader_thread_never_sees_a_half_written_total():
+    """The engine thread is the only writer and replaces the totals whole:
+    whatever instant another thread reads them, every kind's legs sum to
+    its cycle seconds and its cycles are what the legs account for."""
+    m = EngineMetrics()
+    s = _Seq(StepClock(m))
+    stop = threading.Event()
+    seen, bad = [0], []
+
+    def reader():
+        last = 0
+        while not stop.is_set():
+            kinds, stall_s, stalls = s.clock.snapshot()
+            n = 0
+            for kind, t in kinds.items():
+                tot = dict(zip(stepclock.KIND_TOTALS, t))
+                legs = tot["wait_s"] + tot["starved_s"] + tot["overlap_s"]
+                # Every cycle here is 57 ms, 50 of them waited.
+                if (abs(legs - tot["cycle_s"]) > 1e-6
+                        or abs(tot["cycle_s"] - 0.057 * tot["cycles"]) > 1e-6
+                        or abs(tot["wait_s"] - 0.050 * tot["cycles"]) > 1e-6):
+                    bad.append((kind, tot))
+                n += tot["cycles"]
+            if n < last:
+                bad.append(("went back", n, last))
+            last = n
+            seen[0] += 1
+
+    readers = [threading.Thread(target=reader, daemon=True) for _ in range(6)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for r in readers:
+            r.start()
+        deadline = time.monotonic() + 20
+        for i in range(20000):
+            s.step(kind=("seq", "seq_tail", "pipe")[i % 3])
+            if time.monotonic() > deadline:
+                break
+    finally:
+        stop.set()
+        for r in readers:
+            r.join(10)
+        sys.setswitchinterval(interval)
+    assert not any(r.is_alive() for r in readers)
+    assert seen[0] > 100 and not bad, bad[:3]
+
+
+class _MadeUpTime:
+    """``time`` for the clock and the profiler: both read what the test
+    says it is."""
+
+    def __init__(self, seq):
+        self.seq, self.cpu = seq, 50.0
+
+    def monotonic(self):
+        return self.seq.t
+
+    def monotonic_ns(self):
+        return int(self.seq.t * 1e9)
+
+    def process_time(self):
+        return self.cpu
+
+    strftime = staticmethod(time.strftime)
+
+
+@pytest.fixture
+def window(monkeypatch, tmp_path):
+    """A warm clock on a made-up time line and a profiler window over it
+    whose ``jax.profiler`` calls are recorded, not made."""
+    import jax
+    from arks_tpu.obs import profiler as prof_mod
+    m, s = _warm()
+    fake = _MadeUpTime(s)
+    monkeypatch.setattr(prof_mod, "time", fake)
+    monkeypatch.setattr(stepclock, "time", fake)
+    calls = []
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda d, **kw: calls.append((d, kw)))
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    prof = prof_mod.ProfilerWindows(str(tmp_path), clock=s.clock)
+    return types.SimpleNamespace(m=m, s=s, prof=prof, time=fake,
+                                 calls=calls, dir=str(tmp_path / "w"))
+
+
+def test_a_window_returns_the_whole_cycles_that_closed_inside_it(window):
+    s, prof = window.s, window.prof
+    for _ in range(4):
+        s.step()                        # closed before start(): outside
+    before = _kinds(s.clock)["seq"]
+    assert prof.start(window.dir)["ok"]
+    for _ in range(5):
+        s.step(kind="seq", wait=0.060)
+        s.step(kind="pipe", wait=0.010, overlap=0.004, gap=0.0)
+    out = prof.stop()
+    assert out["ok"] and out["python"] is False
+    for _ in range(3):
+        s.step()                        # closed after stop(): outside
+    clock = out["clock"]
+    assert set(clock) == {"kinds", "stall_s", "stalls"}
+    assert set(clock["kinds"]) == {"seq", "pipe"}
+    for k in clock["kinds"].values():
+        assert set(k) == set(stepclock.KIND_TOTALS)
+        assert k["wait_s"] + k["starved_s"] + k["overlap_s"] \
+            == pytest.approx(k["cycle_s"])
+    # The cycle open at start() closed inside (a sound 57 ms one), the
+    # fifth pipe cycle was still open at stop().
+    seq, pipe = clock["kinds"]["seq"], clock["kinds"]["pipe"]
+    assert (seq["cycles"], pipe["cycles"]) == (6, 4)
+    assert seq["cycle_s"] == pytest.approx(0.057 + 5 * 0.067)
+    assert pipe["cycle_s"] == pytest.approx(4 * 0.015)
+    assert pipe["starved_s"] == pytest.approx(4 * 0.001)   # the next call
+    assert seq["call_s"] == pytest.approx(0.005)    # every call made inside
+    assert pipe["call_s"] == pytest.approx(0.005)
+    assert (clock["stall_s"], clock["stalls"]) == (0.0, [])
+    assert _kinds(s.clock)["seq"]["cycles"] == before["cycles"] + 6 + 2
+    # Kept for in-process readers, the same content.
+    assert prof.last_window["clock"] == clock
+    assert prof.last_window["python"] is False
+    json.dumps(out)                     # the HTTP response carries it
+    # A second window starts from its own beginning.
+    assert prof.start(window.dir)["ok"]
+    s.step()
+    assert prof.stop()["clock"]["kinds"]["seq"]["cycles"] == 1
+    # Without a clock a window returns no such key.
+    from arks_tpu.obs import profiler as prof_mod
+    bare = prof_mod.ProfilerWindows(window.dir)
+    assert bare.start(window.dir)["ok"] and "clock" not in bare.stop()
+
+
+def test_a_stall_inside_the_window_is_in_its_clock_and_one_before_is_not(
+        window):
+    s, prof = window.s, window.prof
+    s.step(gap=2.0)
+    s.step()                            # a stall before the window
+    assert len(s.clock.stalls) == 1
+    assert prof.start(window.dir)["ok"]
+    s.step()
+    s.step(wait=1.5)
+    s.step()
+    s.step()
+    clock = prof.stop()["clock"]
+    s.step(gap=2.0)
+    s.step()                            # and one after it
+    assert len(s.clock.stalls) == 3
+    (rec,) = clock["stalls"]
+    assert rec["where"] == "wait" and rec["seconds"] == pytest.approx(1.507)
+    assert clock["stall_s"] == pytest.approx(1.507)
+    assert rec == s.clock.stalls[1] and rec is not s.clock.stalls[1]
+    # The stalled cycle is in no kind's sums: of the four cycles that
+    # closed inside, three were sound.
+    assert clock["kinds"]["seq"]["cycles"] == 3
+    assert clock["kinds"]["seq"]["cycle_s"] == pytest.approx(3 * 0.057)
+    assert prof.last_window["clock"]["stalls"] == [rec]
+
+
+def test_a_stall_record_says_what_held_the_process(window):
+    """``cpu_s``: the process's own CPU seconds across the stalled cycle;
+    ``gc_s``: the collector's seconds inside it (one ``gc.callbacks`` hook
+    of the clock's).  A process that was not scheduled reads both near 0,
+    a long collection reads ``gc_s`` near ``seconds``."""
+    s, fake = window.s, window.time
+
+    def collection_takes(phase, info, seconds=[0.0]):
+        if phase == "start":
+            s.t += seconds[0]           # after the clock's own hook ran
+            fake.cpu += seconds[0]
+
+    gc.callbacks.append(collection_takes)
+    try:
+        s.step()
+        fake.cpu += 0.004
+        s.step(gap=3.0)                 # not scheduled: no CPU, no gc
+        s.step()
+        rec = s.clock.stalls[-1]
+        assert rec["seconds"] == pytest.approx(3.053)
+        assert rec["cpu_s"] == pytest.approx(0.0) and rec["gc_s"] == 0.0
+        # A collection of 0.8 s inside the next stalled cycle.
+        s.step()
+        collection_takes.__defaults__[0][0] = 0.8
+        gc.collect()
+        collection_takes.__defaults__[0][0] = 0.0
+        s.step()
+        rec = s.clock.stalls[-1]
+        assert rec["where"] == "host"
+        assert rec["seconds"] == pytest.approx(0.857)
+        assert rec["gc_s"] == pytest.approx(0.8)
+        assert rec["cpu_s"] == pytest.approx(0.8)
+        # It is the cycle's own: the next stall starts from nothing.
+        s.step(gap=2.0)
+        fake.cpu += 1.9                 # something computed all along
+        s.step()
+        rec = s.clock.stalls[-1]
+        assert rec["gc_s"] == 0.0 and rec["cpu_s"] == pytest.approx(1.9)
+        json.dumps(rec)
+    finally:
+        gc.callbacks.remove(collection_takes)
+
+
+def test_a_dropped_clock_takes_its_gc_hook_along():
+    gc.collect()
+    n = len(gc.callbacks)
+    clock = StepClock(EngineMetrics())
+    assert len(gc.callbacks) == n + 1
+    del clock
+    gc.collect()                        # the hook sees its clock gone
+    gc.collect()
+    assert len(gc.callbacks) <= n
+
+
+@pytest.mark.parametrize("how", ["default", "python", "http-default",
+                                 "http-python", "http-truthy-string",
+                                 "auto-armed"])
+def test_who_gets_the_profilers_python_tracer(how, window, monkeypatch,
+                                              request):
+    """A window runs WITHOUT the Python tracer unless it is asked for:
+    ``start(python=True)``, the HTTP body's ``{"python": true}``, and the
+    auto-armed window.  The host tracer stays as JAX sets it."""
+    import jax
+    prof, calls = window.prof, window.calls
+    if how.startswith("http"):
+        srv = request.getfixturevalue("server")
+        prof = srv.engine.profiler
+        body = {"http-default": {"logdir": window.dir},
+                "http-python": {"logdir": window.dir, "python": True},
+                "http-truthy-string": {"logdir": window.dir,
+                                       "python": "no"}}[how]
+        req = urllib.request.Request(
+            f"http://127.0.0.1:{srv.port}/v1/profiler/start",
+            data=json.dumps(body).encode(),
+            headers={"Content-Type": "application/json"})
+        with urllib.request.urlopen(req, timeout=60) as r:
+            started = json.load(r)
+    elif how == "auto-armed":
+        prof.auto_mult = 4.0
+        prof.on_step(0.3, 0.057)
+        started = {"ok": prof.active, "python": prof._python}
+    else:
+        started = prof.start(window.dir, python=how == "python")
+    want = how in ("python", "http-python", "auto-armed")
+    try:
+        assert started["ok"] and started["python"] is want
+        ((d, kw),) = calls
+        options = kw["profiler_options"]
+        assert isinstance(options, jax.profiler.ProfileOptions)
+        assert options.python_tracer_level == (1 if want else 0)
+        assert options.host_tracer_level \
+            == jax.profiler.ProfileOptions().host_tracer_level
+    finally:
+        stopped = prof.stop()
+    assert stopped["ok"] and stopped["python"] is want
+    assert prof.last_window["python"] is want
+
+
+def test_a_jax_without_profile_options_starts_the_trace_as_it_does(
+        window, monkeypatch):
+    import jax
+    monkeypatch.delattr(jax.profiler, "ProfileOptions")
+    started = window.prof.start(window.dir)
+    assert window.calls == [(window.dir, {})]
+    # That JAX's own default traces Python: the window says so.
+    assert started["ok"] and started["python"] is True
+    assert window.prof.stop()["ok"]
 
 
 # ---------------------------------------------------------------------------

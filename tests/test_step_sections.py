@@ -11,11 +11,16 @@ anchors, named programs and the compilation counters (PR 24).
 - every jitted program of an engine carries a name that starts ``arks_``;
 - ``xla_compilations_total`` counts a fresh shape once and a cache hit
   never;
-- token streams are byte-identical with a window open and closed.
+- token streams are byte-identical with a window open and closed;
+- a window's profile holds Python frames only where they were asked for,
+  and a quiet window leaves its spans beside the profile, on the profile's
+  clock (PR 52).
 """
 
 import functools
 import glob
+import json
+import os
 import time
 
 import jax
@@ -283,6 +288,65 @@ def test_a_window_writes_two_anchors_that_join_the_clocks(monkeypatch,
     # sits where its own monotonic reading says
     assert probe == pytest.approx(t_probe + offsets[0], abs=5e-3)
     assert started["t0_monotonic"] <= t_probe <= stopped["t1_monotonic"]
+
+
+@pytest.mark.parametrize("python", [False, True],
+                         ids=["quiet", "python-frames"])
+def test_a_profile_has_python_frames_only_where_they_were_asked_for(
+        monkeypatch, tmp_path, python):
+    """The default window's profile holds no event of the Python tracer
+    (``$file:line function``) and keeps the host tracer's (the annotations);
+    its spans lie beside it as a Chrome trace file, moved onto the
+    profile's clock by the opening anchor.  ``python=True`` has the frames
+    and no such file (they are its host detail)."""
+    from jax.profiler import ProfileData
+    monkeypatch.setenv("ARKS_TRACE", "1")
+    tracer = Tracer()
+    prof = prof_mod.ProfilerWindows(str(tmp_path), tracer=tracer)
+    d = str(tmp_path / "p")
+    assert prof.start(d, python=python)["python"] is python
+    tracer.evt("", "phase.mixed.pack", "B")
+    t_probe = time.monotonic()
+    with jax.profiler.TraceAnnotation("arks_step[probe]"):
+        sum(len(str(i)) for i in range(200))        # Python calls to hook
+        jnp.ones((4,)).block_until_ready()
+    tracer.evt("", "phase.mixed.pack", "E", [3, 2, 1])
+    tracer.evt("", "phase.decode.issue", "B")       # still open at stop()
+    out = prof.stop()
+    assert out["ok"] and out["python"] is python
+    path = glob.glob(os.path.join(d, "plugins", "profile", "*",
+                                  "*.xplane.pb"))[0]
+    frames, probe, offsets = 0, None, []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                frames += e.name.startswith("$")
+                off = prof_mod.anchor_offset_s(e.name, e.start_ns * 1e-9)
+                if off is not None:
+                    offsets.append((e.start_ns, off))
+                elif e.name == "arks_step[probe]":
+                    probe = e.start_ns * 1e-9
+    assert len(offsets) == 2 and probe is not None
+    spans_file = os.path.join(d, prof_mod.SPANS_FILE)
+    if python:
+        assert frames > 0 and not os.path.exists(spans_file)
+        return
+    assert frames == 0
+    with open(spans_file) as f:
+        events = [e for e in json.load(f)["traceEvents"] if e["ph"] == "X"]
+    assert [e["name"] for e in events] == ["phase.mixed.pack"]
+    (pack,) = events
+    assert pack["args"] == {"arg": [3, 2, 1]}
+    (span,) = out["spans"]                  # ... which stay on time.monotonic
+    assert span["start"] <= t_probe <= span["end"]
+    # On the profile's clock: where the span's own reading lies by the
+    # opening anchor, and so beside the probe annotation (the anchor is
+    # good to the microseconds between its reading and its own begin).
+    assert pack["ts"] * 1e-6 == pytest.approx(
+        span["start"] + min(offsets)[1], abs=1e-6)
+    assert pack["dur"] * 1e-6 == pytest.approx(span["end"] - span["start"],
+                                               abs=1e-6)
+    assert abs(probe - pack["ts"] * 1e-6) < 5e-3
 
 
 # ------------------------------------------------------ names and counters
