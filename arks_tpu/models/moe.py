@@ -11,7 +11,9 @@ TPU-first formulation:
   serving batch sizes while keeping shapes static for XLA.
 - **Batched dispatch** (:func:`_batched_dispatch`; large token batches on an
   unsharded expert dim): every expert takes a fixed batch of rows in one
-  expert-major SwiGLU, what it draws beyond them follows in overflow tiles.
+  expert-major SwiGLU, what it draws beyond them follows in overflow tiles,
+  and one contraction with a selection matrix puts the experts' rows back
+  on their tokens (:func:`_combine`).
   Quantised leaves are read as they are stored in both: the int8 -> bf16
   convert sits in the contraction's operand read and the per-channel scale
   lands on its output, so no full-width copy of an expert stack is written
@@ -189,7 +191,8 @@ def _held_capacity(n_tokens: int, cfg) -> int:
     times was rounded up to two, and the seeded routers put 13 % (laguna)
     and 11 % (gigachat) of those rows to use where they now fill 27 and
     22 % (PERF.md section 6, PR 49: laguna's step 90.6 -> 72.6 ms, of it
-    the batch's scatter-add 13.9 -> 7.3 and its three dots 13.8 -> 7.6);
+    the batch's combine, a scatter-add until PR 53, 13.9 -> 7.3 and its
+    three dots 13.8 -> 7.6);
     the price is overflow tiles, 2.0-2.9 a layer in laguna's windows where
     0.2-0.5 were needed, each a 32nd of the batch.  A layer held whole: one
     and a half times (every pair lands here, the batch is what the step
@@ -246,6 +249,70 @@ def _expert_dot(eq: str, x: jnp.ndarray, w) -> jnp.ndarray:
     return jnp.einsum(eq, x, w["q"].astype(x.dtype)) * w["s"].astype(x.dtype)
 
 
+# Rows of a flat batch up to which :func:`_combine` contracts.  The
+# contraction runs at the MXU's rate, 2 x n x S x E operations at 186-192
+# TFLOP/s bf16 on a v5e (165 at n = 320); the scatter-add it replaces goes
+# slot by slot at 55 GB/s at its best, so both are linear in S x E and n
+# alone decides.  The combine alone, ms a layer, scatter-add / contraction
+# (my chip runs, PR 53, PERF.md section 6; S = the batch and the unrolled
+# tiles at n, by :func:`_held_capacity`):
+#        32 held of 256, top-10,   12 held of 384, top-8,  8 of 8, top-2,
+#        E 3072 (laguna's share)   E 7168 (kimi's share)   E 4096 (mixtral)
+#   n   320  S  4608  0.52 / 0.06  S 2048  0.60 / 0.06  S  1536  0.23 / 0.03
+#     1,056     4608  0.56 / 0.16    2048  1.12 / 0.17
+#     2,048     9216  1.12 / 0.61    4096  2.23 / 0.65     9984  1.51 / 0.88
+#     3,072    13824  1.69 / 1.36                         14976  2.53 / 1.97
+#     3,584    18432  2.27 / 2.11
+#     4,096    18432  2.32 / 2.42    6144  4.14 / 1.90    19968  3.38 / 3.49
+# 3,584 is the most rows read at which the contraction won in every family;
+# at 4,096 it loses 3-4 % in two and wins 2.2 x in the third.  Every step
+# program of the benchmark has n <= 1,088.
+_CONTRACT_MAX_TOKENS = 3584
+
+
+def _contract_pays(n_tokens: int) -> bool:
+    """Whether :func:`_combine` puts a batch's slots back on ``n_tokens``
+    tokens by a contraction (else by a scatter-add): the shape's own rule,
+    as :func:`_batch_pays` is the dispatch's."""
+    return n_tokens <= _CONTRACT_MAX_TOKENS
+
+
+def _combine(n: int, down: jnp.ndarray, token_of: jnp.ndarray,
+             w: jnp.ndarray, out: jnp.ndarray | None = None) -> jnp.ndarray:
+    """The batched dispatch's combine: ``[n, E]`` rows, token ``t``'s the
+    sum over the slots ``s`` with ``token_of[s] == t`` of ``w[s] x
+    down[s]`` (``down [S, E]``; a dead slot has weight 0 and any token), on
+    top of ``out`` where given.  ONE contraction on the MXU of the selection
+    matrix ``[n, S]``, ``w[s]`` at ``(token_of[s], s)`` (an iota compare
+    that the compiler builds inside the dot's fusion, never in HBM), with
+    the rows: a token's products are exact in float32, summed in float32 and
+    rounded once.  The scatter-add this was until PR 53 rounds each product
+    and every add and goes slot by slot, dead slots too (0.83 of kimi's 2.65
+    ms a routed layer, 0.44 of laguna's 1.47); it stays for a flat batch past
+    :func:`_contract_pays`, where the contraction's ``n x S`` loses to it.
+
+    A contraction multiplies every row by every token's zeros, so a value
+    that is not finite would reach EVERY token of the step (0 x NaN), where
+    the scatter-add kept it on its own: it counts as 0 here, and the token
+    that brought it keeps it in the residual stream.  Such rows are real
+    (gigachat's cell, the backlog at its ramp's start, two seeds of three:
+    with them in, every stream of the step decoded garbage until the
+    backlog cleared; PERF.md section 6, PR 53)."""
+    dtype = down.dtype
+    if not _contract_pays(n):
+        if out is None:
+            out = jnp.zeros((n, down.shape[1]), dtype)
+        return out.at[token_of].add(down * w[:, None].astype(dtype))
+    down = jnp.where(jnp.isfinite(down), down, 0)
+    sel = jnp.where(token_of[None, :] == jnp.arange(n)[:, None],
+                    w[None, :], 0).astype(dtype)
+    got = jnp.einsum("ns,se->ne", sel, down,
+                     preferred_element_type=jnp.float32)
+    if out is not None:
+        got = got + out
+    return got.astype(dtype)
+
+
 def _batched_dispatch(x2: jnp.ndarray, vals: jnp.ndarray, idx: jnp.ndarray,
                       mp: Params, cfg, row_valid: jnp.ndarray | None = None,
                       stack: tuple | None = None
@@ -269,7 +336,10 @@ def _batched_dispatch(x2: jnp.ndarray, vals: jnp.ndarray, idx: jnp.ndarray,
     experts need beyond those in a loop, so that only a layer that needs
     more takes longer and nothing is ever dropped; ``tiles`` (a share's;
     None of a layer held whole) is int32 ``[2]``: the overflow tiles the
-    layer needed, and the trips that loop made.
+    layer needed, and the trips that loop made.  The batch's rows and the
+    unrolled tiles' go back on their tokens in ONE :func:`_combine` a layer
+    (the tiles' ``down`` dots write into the concatenated ``[S, E]`` in
+    place), a trip of the loop combines its own tile onto the result.
 
     A tile takes its expert's weights out of ``stack`` = ``(tree, layer)``,
     the STACKED tree that the program was handed and the index at which
@@ -290,7 +360,10 @@ def _batched_dispatch(x2: jnp.ndarray, vals: jnp.ndarray, idx: jnp.ndarray,
         if row_valid is not None:
             held = held & row_valid.reshape(-1, 1)
         flat_expert = jnp.where(held, local, nx).reshape(-1)        # [n*k]
-        sizes = jnp.bincount(flat_expert, length=nx + 1)[:nx]
+        # (A compare and a sum, not ``bincount``: that is a scatter-add
+        # of n x k updates, one after the other on the chip.)
+        sizes = jnp.sum(flat_expert[:, None] == jnp.arange(nx)[None, :],
+                        axis=0, dtype=jnp.int32)
         starts = jnp.cumsum(sizes) - sizes
         order = jnp.argsort(flat_expert)
         flat_w = vals.reshape(-1)
@@ -299,8 +372,10 @@ def _batched_dispatch(x2: jnp.ndarray, vals: jnp.ndarray, idx: jnp.ndarray,
         tiles = (jnp.maximum(sizes - cap, 0) + cap - 1) // cap      # [X]
         tile_ends = jnp.cumsum(tiles)
 
-    def slots(out, experts, first, weights):
-        """Add what ``experts`` [x] give their rows ``first + [0, C)``."""
+    def slots(experts, first, weights):
+        """What ``experts`` [x] give their rows ``first + [0, C)``, slot by
+        slot: ``(down [x C, E], token [x C], weight [x C])``, the weight of
+        a slot that holds no pair 0."""
         with jax.named_scope("arks.moe_route"):
             c = first[:, None] + jnp.arange(cap)[None, :]           # [x, C]
             live = c < jnp.take(sizes, experts)[:, None]
@@ -314,11 +389,10 @@ def _batched_dispatch(x2: jnp.ndarray, vals: jnp.ndarray, idx: jnp.ndarray,
             act = swiglu(gate, up, cfg.swiglu_limit)
             down = _expert_dot("xcf,xfe->xce", act, weights["w_down"])
         with jax.named_scope("arks.moe_route"):
-            w = jnp.where(live, jnp.take(flat_w, pair), 0).astype(down.dtype)
-            return out.at[token_of.reshape(-1)].add(
-                (down * w[..., None]).reshape(-1, e))
+            w = jnp.where(live, jnp.take(flat_w, pair), 0)
+        return down.reshape(-1, e), token_of.reshape(-1), w.reshape(-1)
 
-    def overflow_tile(t, out):
+    def overflow_tile(t):
         with jax.named_scope("arks.moe_route"):
             # A spare tile (t past the last one needed) runs on the last
             # expert from past the end of its rows: every slot dead.
@@ -338,20 +412,25 @@ def _batched_dispatch(x2: jnp.ndarray, vals: jnp.ndarray, idx: jnp.ndarray,
 
             one = {name: jax.tree.map(expert, tree[name])
                    for name in ("w_gate", "w_up", "w_down")}
-        return slots(out, ex[None], (cap * (1 + nth))[None], one)
+        return slots(ex[None], (cap * (1 + nth))[None], one)
 
-    out = slots(jnp.zeros((n, e), x2.dtype), jnp.arange(nx),
-                jnp.zeros((nx,), jnp.int32), mp)
     fixed = 0
     if cap < n:
         fixed = _SPARE_TILES if share else (n * k - 1) // cap
-    for t in range(fixed):
-        out = overflow_tile(jnp.int32(t), out)
+    parts = [slots(jnp.arange(nx), jnp.zeros((nx,), jnp.int32), mp)]
+    parts += [overflow_tile(jnp.int32(t)) for t in range(fixed)]
+    with jax.named_scope("arks.moe_route"):
+        out = _combine(n, *(jnp.concatenate(a) for a in zip(*parts)))
     if not share:
         return out, held, None
+
+    def trip(t, out):
+        part = overflow_tile(t)
+        with jax.named_scope("arks.moe_route"):
+            return _combine(n, *part, out=out)
+
     needed = tile_ends[-1]
-    out = jax.lax.fori_loop(fixed, jnp.maximum(needed, fixed),
-                            overflow_tile, out)
+    out = jax.lax.fori_loop(fixed, jnp.maximum(needed, fixed), trip, out)
     return out, held, jnp.stack([needed, jnp.maximum(needed - fixed, 0)])
 
 
@@ -434,8 +513,10 @@ def moe_ffn_grouped(x: jnp.ndarray, mp: Params, cfg,
     """Dropless grouped dispatch: top-k cost instead of all-expert cost.
 
     Flattens tokens, sorts the (token, slot) pairs by routed expert, runs
-    the expert matmuls over the sorted rows and scatter-adds the weighted
-    expert outputs back per token.  Numerically equivalent to the dense
+    the expert matmuls over the sorted rows and puts the weighted expert
+    outputs back on their tokens (:func:`_combine`'s contraction in the
+    batched dispatch, a scatter-add in the ragged one).  Numerically
+    equivalent to the dense
     dispatch — no capacity factor, no dropped tokens.  Used for large-T
     prefill and training on an unsharded expert dim; the dense path stays
     for decode (HBM-bound: every expert's weights are read once

@@ -347,7 +347,7 @@ def test_quantised_experts_compile_for_v5e_as_stored(chip, kind, rows):
     14336) on the mixtral cell's two step shapes, 64 rows (the dense
     dispatch) and 320 (the batched one): the compiled program writes no
     full-width copy of an int8 expert stack (940 MB a leaf; it did, three
-    a layer, until PR 33) and needs no Mosaic kernel."""
+    a layer, until PR 33), needs no Mosaic kernel and holds no scatter."""
     import types
 
     from arks_tpu.models import moe
@@ -375,6 +375,8 @@ def test_quantised_experts_compile_for_v5e_as_stored(chip, kind, rows):
         lp, spec((1, rows, e), jnp.bfloat16)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" not in text and "ragged-dot" not in text
+    # The combine is a contraction and the experts' sizes a sum (PR 53).
+    assert " scatter(" not in text
     if kind == "int8":
         assert f"bf16[{x},{e},{f}]" not in text
         assert f"bf16[{x},{f},{e}]" not in text
@@ -481,24 +483,30 @@ def _share_step(chip, monkeypatch, name: str, rows: int):
     return _STEPS[name, rows]
 
 
-def _unfused_s8(text: str) -> list:
-    """``(dims, line)`` of every int8 array a compiled program defines as
-    the result of a ``copy`` or a ``fusion`` OUTSIDE its fused computations
-    (those a ``fusion(... calls=%name)`` names): a buffer the step writes."""
+def _outside_fusions(text: str):
+    """The lines of a compiled program that stand OUTSIDE its fused
+    computations (those a ``fusion(... calls=%name)`` names): what they
+    define is a buffer the step writes."""
     import re
     fused = set(re.findall(r"fusion\([^\n]*calls=%([\w.\-]+)", text))
-    made = re.compile(r"= s8\[([\d,]+)\]\S* (?:fusion|copy)\(")
-    found, comp = [], None
+    comp = None
     for line in text.splitlines():
         head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
         if head:
             comp = head.group(1)
         elif comp not in fused:
-            m = made.search(line)
-            if m:
-                found.append((tuple(map(int, m.group(1).split(","))),
-                              line.strip()[:120]))
-    return found
+            yield line
+
+
+def _unfused_s8(text: str) -> list:
+    """``(dims, line)`` of every int8 array a compiled program defines as
+    the result of a ``copy`` or a ``fusion`` outside its fused
+    computations."""
+    import re
+    made = re.compile(r"= s8\[([\d,]+)\]\S* (?:fusion|copy)\(")
+    return [(tuple(map(int, m.group(1).split(","))), line.strip()[:120])
+            for line in _outside_fusions(text)
+            for m in [made.search(line)] if m]
 
 
 @pytest.mark.parametrize("name,rows,temp_mb", [
@@ -528,6 +536,38 @@ def test_a_shares_chunk_step_copies_no_expert_leaf_out_of_its_stack(
     assert not found, found
     assert "while" in text
     assert compiled.memory_analysis().temp_size_in_bytes < temp_mb * 1e6
+
+
+@pytest.mark.parametrize("name,rows", [
+    ("kimi-k2.5-ep32-l9", 1024), ("laguna-s-2.1-ep8", 1024),
+    ("solar-open2-250b-ep8-l8", 256), ("gigachat3.5-432b-ep8-l5", 1024),
+    ("mimo-v2.5-ep16-l13", 1024)])
+def test_a_shares_chunk_step_combines_by_one_contraction(chip, monkeypatch,
+                                                         name, rows):
+    """The same compiled chunk steps (the test above's programs): under
+    ``arks.moe_route`` the chip's program holds NO ``scatter`` (until PR 53
+    the combine was six or seven scatter-adds a routed layer, slot by slot,
+    and the experts' sizes one more), and the combine is a convolution that
+    emits ``bf16[n, E]`` from the ``[S, E]`` rows of the batch and the spare
+    tiles, its selection matrix built inside the fusion: no ``[n, S]``
+    buffer is defined outside one."""
+    import re
+    cfg, _, compiled = _share_step(chip, monkeypatch, name, rows)
+    text = compiled.as_text()
+    n = SHARES[name][1] + rows
+    s = (cfg.num_experts + 4) * 128
+    scatters = [line.strip()[:140] for line in text.splitlines()
+                if re.search(r"= \S+ scatter\(", line)
+                and "arks.moe_route" in line]
+    assert not scatters, scatters
+    combine = [line for line in text.splitlines()
+               if "ns,se->ne/dot_general" in line and " convolution(" in line]
+    assert combine and all(f"[{n},{cfg.hidden_size}]" in line
+                           for line in combine)
+    outside = [line.strip()[:140] for line in _outside_fusions(text)
+               if "arks.moe_route" in line
+               and re.search(rf"= bf16\[{n},{s}\]", line)]
+    assert not outside, outside
 
 
 @pytest.mark.parametrize("name", ["laguna-s-2.1-ep8", "mimo-v2.5-ep16-l13",

@@ -178,17 +178,23 @@ def _quantised_layer(name, bits, forced):
     leaves widened to float32 (the oracle's).  ``forced``: the router's
     first input row sends expert 0 every token and the last expert none
     (the tests set feature 0 of every token to 1)."""
-    from arks_tpu.models import quant
     cfg = get_config(name)
     mp = moe.init_moe_params(cfg, jax.random.PRNGKey(0), jnp.float32)
     lp = jax.tree.map(lambda t: t[0], mp)
     if forced:
         push = jnp.zeros((cfg.num_experts,)).at[0].set(50.).at[-1].set(-50.)
         lp["router"] = lp["router"].at[0].set(push)
+    return (cfg, *_quantised(lp, bits))
+
+
+def _quantised(lp, bits):
+    """``lp`` with int8 / int4 expert leaves, and the same leaves widened
+    to float32 (the oracle's)."""
+    from arks_tpu.models import quant
     qp = quant.quantize_params(lp, bits=bits, group=32)
     wide = {k: (quant.dequantize(v, jnp.float32) if quant.is_quantized(v)
                 else v) for k, v in qp.items()}
-    return cfg, qp, wide
+    return qp, wide
 
 
 def _tokens(cfg, rows, seed=1):
@@ -259,6 +265,15 @@ _SHARES = {
 }
 
 
+def _cell_config(name):
+    """The benchmark's configuration ``name``, from its own file."""
+    import os
+    from arks_tpu.models.config import ModelConfig
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    return ModelConfig.from_hf_config(
+        os.path.join(root, "benchmarks", "configs", name), name="m")
+
+
 @pytest.mark.parametrize("step", ["pipelined", "quarter", "whole-budget"])
 @pytest.mark.parametrize("name", sorted(_SHARES))
 def test_a_shares_batch_an_expert_is_one_tile_at_every_cells_step(name, step):
@@ -271,13 +286,8 @@ def test_a_shares_batch_an_expert_is_one_tile_at_every_cells_step(name, step):
     1,100 / 540 pairs that land), and every row at a pipelined step, which
     is the dense dispatch's.  ``share_rows`` is what the host counts a
     layer (``moe_batch_rows_total``)."""
-    import os
-    from arks_tpu.models.config import ModelConfig
     share, slots = _SHARES[name]
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    cfg = ModelConfig.from_hf_config(
-        os.path.join(root, "benchmarks", "configs", name),
-        name="m").with_expert_share(share, 0)
+    cfg = _cell_config(name).with_expert_share(share, 0)
     n = slots + {"pipelined": 0, "quarter": 256, "whole-budget": 1024}[step]
     if step == "pipelined":
         assert moe._held_capacity(n, cfg) == n
@@ -306,6 +316,201 @@ def test_a_shares_batch_grows_by_tiles_with_the_fair_load():
                     8224: 1024}
     cfg.expert_parallel_size, cfg.router_width = 1, 32
     assert moe._held_capacity(1056, cfg) == 512      # 1.5 x 330, in tiles
+
+
+def _cell_layer(name, share, bits=0, star=None):
+    """One routed layer shaped as the benchmark's configuration ``name``
+    routes (its own ``config.json``: k, the router's width, the experts a
+    chip of ``share`` holds, its scoring) at test widths, 64 x 32: ``(cfg,
+    leaves, the same leaves widened to float32)``, quantised to ``bits``.
+    With ``star`` the router's first input row sends THAT held expert every
+    token whose feature 0 is 1 (``_tokens`` sets it)."""
+    import dataclasses
+    cfg = dataclasses.replace(_cell_config(name), hidden_size=64,
+                              moe_intermediate_size=32)
+    if share > 1:
+        cfg = cfg.with_expert_share(share, 0)
+    lp = jax.tree.map(lambda t: t[0], moe.init_moe_params(
+        cfg, jax.random.PRNGKey(3), jnp.float32, layers=1))
+    if star is not None:
+        lp["router"] = lp["router"].at[0, moe.held_first(cfg) + star].set(50.)
+    return (cfg, *(_quantised(lp, bits) if bits else (lp, lp)))
+
+
+# Every chunk-carrying step program of the benchmark's routed cells: the
+# five shares (``_SHARES``) at their quarter and whole-budget shapes and
+# mixtral, a layer held whole, at its one (64 slots + a page).
+_COMBINE_STEPS = [(name, _SHARES[name][0], _SHARES[name][1] + chunk)
+                  for name in sorted(_SHARES) for chunk in (256, 1024)
+                  ] + [("mixtral-8x7b-l4", 1, 64 + 256)]
+
+
+@pytest.mark.parametrize("router", ["seeded", "one expert"])
+@pytest.mark.parametrize("bits", [8, 4], ids=["int8", "int4"])
+@pytest.mark.parametrize("name,share,n", _COMBINE_STEPS,
+                         ids=[f"{c[0]}-{c[2]}" for c in _COMBINE_STEPS])
+def test_the_combine_contracts_at_every_cells_chunk_step(name, share, n, bits,
+                                                         router):
+    """The batched dispatch AS THE AUTO RULE TAKES IT at every cell's
+    chunk-carrying step shape, its combine one contraction (nothing
+    patched), against the dense dispatch on the widened float32 leaves,
+    with rows that carry no token ahead of the chunk and behind it.  Under
+    the seeded router the tiles whose count the shape fixes run dead or
+    nearly; under one that sends every valid row to ONE held expert that
+    expert fills its batch, every unrolled tile and, in a share, the trips
+    of the loop behind them, counted here by hand.  The counts are the
+    dense dispatch's own (which counts non-zero weights and sorts
+    nothing)."""
+    cfg, qp, wide = _cell_layer(name, share, bits,
+                                star=2 if router == "one expert" else None)
+    cap = moe._held_capacity(n, cfg)
+    assert cap == 128 and moe._batch_pays(n, qp, cfg)
+    assert moe._contract_pays(n)
+    x = _tokens(cfg, n)
+    valid = ((jnp.arange(n) > 0) & (jnp.arange(n) < n - n // 5))[None]
+    want, counts_d = moe.moe_ffn(x, wide, cfg, grouped=False, row_valid=valid)
+    got, counts = moe.moe_ffn(x, qp, cfg, row_valid=valid)
+    # What the router chose, on the host: the pairs that land on an expert
+    # held here, and the tiles those experts need beyond their batch.
+    vals, idx = moe.router_topk(
+        jnp.einsum("te,ex->tx", x[0], qp["router"]), cfg,
+        qp.get("router_bias"))
+    local = np.asarray(idx)[np.asarray(valid[0])] - moe.held_first(cfg)
+    sizes = np.bincount(local[(local >= 0) & (local < cfg.num_experts)],
+                        minlength=cfg.num_experts)
+    needed = int(np.sum(-(-np.maximum(sizes - cap, 0) // cap)))
+    if router == "one expert":
+        assert sizes[2] == int(valid.sum())
+        assert n < 512 or needed > moe._SPARE_TILES    # the loop runs
+    assert counts_d.tolist() == [int(sizes.sum()), 0, 0]
+    if share > 1:
+        assert counts.tolist() == [
+            int(sizes.sum()), needed, max(needed - moe._SPARE_TILES, 0)]
+    else:
+        assert counts.tolist() == counts_d.tolist()
+    # The dispatch's own ``held`` and ``tiles``, as the sort made them.
+    _, held, tiles = moe._batched_dispatch(x[0], vals, idx, qp, cfg, valid[0])
+    local = np.asarray(idx) - moe.held_first(cfg)
+    np.testing.assert_array_equal(
+        np.asarray(held), (local >= 0) & (local < cfg.num_experts)
+        & np.asarray(valid[0])[:, None])
+    assert (tiles is None) if share == 1 else (
+        tiles.tolist() == counts.tolist()[1:])
+    # A row that carries no token takes no place in the batch: the shared
+    # expert's alone, where the configuration has one.
+    if "shared_gate_proj" in wide:
+        want = jnp.where(valid[..., None], want,
+                         moe._shared_expert(x, wide, cfg))
+    else:
+        want = jnp.where(valid[..., None], want, 0.0)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-4 * float(jnp.abs(want).max()))
+
+
+def _lowered_ops(lowered, scope: str, op: str) -> list:
+    """The scope paths under which the lowered program holds an ``op``
+    inside ``scope``: the text's location table names every op it holds by
+    its name stack and primitive (``"jit(f)/arks.moe_route/scatter-add"``),
+    one entry a site."""
+    import re
+    return sorted(set(re.findall(
+        rf'loc\("([^"]*{re.escape(scope)}[^"]*/{op})"',
+        lowered.as_text(debug_info=True))))
+
+
+@pytest.mark.parametrize("name,share,n,contracts", [
+    ("laguna-s-2.1-ep8", 8, 32 + 1024, True),
+    ("kimi-k2.5-ep32-l9", 32, 8 + 256, True),
+    ("mixtral-8x7b-l4", 1, 64 + 256, True),
+    ("laguna-s-2.1-ep8", 8, 32 + 8192, False),
+    ("mixtral-8x7b-l4", 1, 64 + 8192, False),
+], ids=["share-1056", "share-264", "whole-320", "share-8224", "whole-8256"])
+def test_the_lowered_layer_scatters_only_beyond_the_crossover(name, share, n,
+                                                              contracts):
+    """The form of the combine follows the step's rows alone: the lowered
+    routed layer of a share and of a layer held whole holds NO ``scatter``
+    under ``arks.moe_route`` at the cells' step shapes (the combine is the
+    ``ns,se->ne`` contraction there, the experts' sizes a compare and a
+    sum), and beyond :func:`moe._contract_pays`'s crossover the scatter-add
+    is back and the contraction gone: one site for the batch and its
+    unrolled tiles, and one in a share's loop."""
+    cfg, qp, _ = _cell_layer(name, share, bits=8)
+    low = jax.jit(lambda x, v: moe.moe_ffn(x, qp, cfg, row_valid=v)).lower(
+        jax.ShapeDtypeStruct((1, n, cfg.hidden_size), jnp.bfloat16),
+        jax.ShapeDtypeStruct((1, n), jnp.bool_))
+    scatters = _lowered_ops(low, "arks.moe_route", "scatter-add")
+    dots = _lowered_ops(low, "arks.moe_route", "ns,se->ne/dot_general")
+    sites = 2 if share > 1 else 1
+    assert (len(scatters), len(dots)) == ((0, sites) if contracts
+                                          else (sites, 0)), (scatters, dots)
+    assert _lowered_ops(low, "", "scatter[-a-z]*") == scatters
+
+
+def test_the_contraction_is_no_farther_from_float32_than_the_scatter_add(
+        monkeypatch):
+    """bfloat16 rows at laguna's whole-budget step (4,608 slots onto 1,056
+    tokens, a quarter of them live, ten to a token): the contraction sums a
+    token's products in float32 and rounds once, the scatter-add rounds
+    each product and every add.  The combine alone against float64 on the
+    same bfloat16 rows and weights: the contraction's error is the smaller,
+    by rms and at the worst element; and through the whole layer, where the
+    bfloat16 dots ahead of the combine carry most of the error, it is no
+    larger."""
+    n, s, e = 1056, 4608, 64
+    rng = np.random.default_rng(53)
+    token = jnp.asarray(np.repeat(np.arange(n), 10)[rng.permutation(
+        10 * n)[:s]], jnp.int32)
+    w = jnp.where(jnp.asarray(rng.random(s) < 0.25),
+                  jnp.asarray(rng.random(s), jnp.float32), 0)
+    down = jax.random.normal(jax.random.PRNGKey(1), (s, e), jnp.bfloat16)
+    exact = np.zeros((n, e))
+    np.add.at(exact, np.asarray(token), np.asarray(down, np.float64)
+              * np.asarray(w.astype(jnp.bfloat16), np.float64)[:, None])
+
+    cfg, lp, _ = _cell_layer("laguna-s-2.1-ep8", 8)
+    x = _tokens(cfg, n)
+    want = np.asarray(moe.moe_ffn(x, lp, cfg, grouped=False))
+    half = jax.tree.map(lambda a: a.astype(jnp.bfloat16), lp)
+
+    def errors(contracts):
+        monkeypatch.setattr(moe, "_contract_pays", lambda *a: contracts)
+        alone = np.asarray(moe._combine(n, down, token, w), np.float64)
+        layer = np.asarray(moe.moe_ffn(x.astype(jnp.bfloat16), half, cfg,
+                                       grouped=True), np.float32)
+        return [f(d) for d in (alone - exact, layer - want)
+                for f in (lambda d: float(np.sqrt(np.mean(d * d))),
+                          lambda d: float(np.abs(d).max()))]
+
+    contraction, scatter = errors(True), errors(False)
+    assert contraction[0] < 0.9 * scatter[0]           # the combine: rms
+    assert contraction[1] <= scatter[1]                # its worst element
+    assert contraction[2] <= 1.01 * scatter[2]         # the layer: rms
+
+
+def test_a_row_that_is_not_finite_stays_off_the_other_tokens():
+    """The contraction multiplies every slot's row by every token's zeros:
+    a NaN in a dead slot or an infinity in a live one would reach all
+    ``n`` tokens (0 x NaN).  Such values count as 0 in the combine (the
+    token that brought one keeps it in the residual stream), so every
+    token reads what it reads with those values zeroed, all finite."""
+    n, s, e = 300, 640, 64
+    rng = np.random.default_rng(7)
+    token = jnp.asarray(rng.integers(0, n, s), jnp.int32)
+    w = jnp.where(jnp.asarray(rng.random(s) < 0.3),
+                  jnp.asarray(rng.random(s), jnp.float32), 0)
+    down = jax.random.normal(jax.random.PRNGKey(2), (s, e), jnp.bfloat16)
+    dead, live = int(np.argmin(np.asarray(w))), int(np.argmax(np.asarray(w)))
+    bad = down.at[dead].set(jnp.nan).at[live, 3].set(jnp.inf)
+    clean = down.at[dead].set(0).at[live, 3].set(0)
+    assert moe._contract_pays(n)
+    got = np.asarray(moe._combine(n, bad, token, w), np.float32)
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(
+        got, np.asarray(moe._combine(n, clean, token, w), np.float32))
+    # on top of a carried result too (a trip of a share's loop)
+    carried = jnp.ones((n, e), jnp.bfloat16)
+    assert np.isfinite(np.asarray(
+        moe._combine(n, bad, token, w, out=carried), np.float32)).all()
 
 
 def test_the_auto_rule_batches_quantised_experts_only_where_it_pays(

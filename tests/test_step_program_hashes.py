@@ -164,6 +164,22 @@ times the fair load, ``(n x k - 1) // cap`` tiles), which PR 49 does not
 touch.  The new counter ``moe_batch_rows_total`` is worked out on the host
 from the step's shape and the ``extra`` the step already hands back
 (``moe.share_rows``), so no program gained an output.
+
+PR 53 RE-PINNED the six ``@wide`` programs and the whole-layer pin and
+moved none of the twenty-eight others.  Both are the batched dispatch, and
+its COMBINE changed: the batch's rows and the unrolled tiles' go back on
+their tokens in one ``ns,se->ne`` contraction with a selection matrix
+(``moe._combine``; a trip of a share's loop contracts its own tile) where
+every part was scatter-added (values that are not finite count as 0 in
+it: a contraction would hand them to every token), and the experts' sizes
+are a compare and a sum where ``jnp.bincount`` was a second scatter-add; the sort, the
+gathers, the three expert dots, ``held``, ``tiles`` and the three counts
+are the ops they were (``tests/test_moe.py`` holds the result to the dense
+float32 dispatch at every cell's step shape and counts the tiles by hand).
+Every program of 2 + 64 rows stands (the dense dispatch), the pipelined
+programs and ``tiny`` with them; ``tiny-mixtral``'s 66-row step is dense
+too, which is why the branch its cell's 320-row step takes is the
+whole-layer pin's.
 """
 
 import hashlib
@@ -190,16 +206,16 @@ PINS = {
     "tiny-swa-moe.seq_lp": "dcd6bb16baa882d2",
     "tiny-swa-moe.pipe": "a5acbcd901fe6084",
     "tiny-swa-moe.pipe_lp": "fab7b8e5752c34a9",
-    "tiny-mla-moe@wide.seq": "e4da81ec065116e7",
-    "tiny-mla-moe@wide.seq_lp": "50211ef484486342",
-    "tiny-swa-moe@wide.seq": "b7f9b493752f97e3",
-    "tiny-swa-moe@wide.seq_lp": "bce16710442f9285",
+    "tiny-mla-moe@wide.seq": "67bee340629f998c",
+    "tiny-mla-moe@wide.seq_lp": "e36a454baf84097c",
+    "tiny-swa-moe@wide.seq": "d4d3888ce31c3b21",
+    "tiny-swa-moe@wide.seq_lp": "2fba917a987e327a",
     "tiny-linear-moe.seq": "89d61e6062a43366",
     "tiny-linear-moe.seq_lp": "ff1f7609a933d0f2",
     "tiny-linear-moe.pipe": "751a7238e2d7bee6",
     "tiny-linear-moe.pipe_lp": "383c201b8f57bd2c",
-    "tiny-linear-moe@wide.seq": "f0a10237b64f3ed5",
-    "tiny-linear-moe@wide.seq_lp": "15a7fd4e8399715b",
+    "tiny-linear-moe@wide.seq": "8dba54e083eabb7d",
+    "tiny-linear-moe@wide.seq_lp": "01fe01ed2d2ba045",
     "tiny-mixtral.seq": "3c2050866b3daf71",
     "tiny-mixtral.seq_lp": "a7fd1b110fab0779",
     "tiny-mixtral.pipe": "5e7345d839d5dd71",
@@ -302,4 +318,4 @@ def whole_layer_hash() -> str:
 
 
 def test_the_whole_layers_batched_program_equals_the_parents():
-    assert whole_layer_hash() == "51f24c26fa75cc54"
+    assert whole_layer_hash() == "b95ece34f63baefb"
