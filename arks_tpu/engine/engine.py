@@ -708,6 +708,12 @@ class EngineMetrics:
             "moe_held_pairs_total",
             "(token, expert) pairs of mixed dispatches whose expert is "
             "held by this chip's share of the layer (latent models)")
+        # A model whose routers score identity experts: the pairs that
+        # landed on one and cost nothing.  routed = held + zero + absent.
+        self.moe_zero_pairs_total = r.counter(
+            "moe_zero_pairs_total",
+            "(token, expert) pairs of mixed dispatches that landed on an "
+            "identity (zero-compute) expert (models that have them)")
         # What a share's batched dispatch ran behind its experts' fixed
         # batches (models/moe.py): needed / dispatches / routed layers is
         # the tiles a layer's routers asked for (_SPARE_TILES run in any
@@ -1531,7 +1537,8 @@ class InferenceEngine:
         self.ecfg = engine_cfg
         self._block_preflight(cfg, engine_cfg, draft_cfg)
         # The step returns four counts beside its token ids (held pairs,
-        # a share's overflow tiles needed and looped, valid rows):
+        # a share's overflow tiles needed and looped, valid rows; a fifth
+        # ahead of the rows where the routers score identity experts):
         # _count_held.
         self._held_stat = bool((cfg.latent or cfg.windowed or cfg.linear)
                                and cfg.num_experts)
@@ -1702,8 +1709,9 @@ class InferenceEngine:
             # K and V, each at its stored width; a latent page holds its
             # one row once.
             # (A page of the full-attention pool: every layer, or the
-            # full layers of a model that also has window layers.)
-            page_bytes = (cfg.num_full_layers * cfg.num_kv_heads * page
+            # full layers of a model that also has window layers; a
+            # shortcut layer's two attention sublayers each.)
+            page_bytes = (cfg.num_attn_sublayers * cfg.num_kv_heads * page
                           * (d_store + (0 if cfg.latent else
                                         tf.cache_value_dim(
                                             cfg, self._pad_head())))
@@ -2676,7 +2684,8 @@ class InferenceEngine:
             def with_counts(ids, held, valid):
                 """The step's token ids with, for a latent routed model,
                 four counts behind them (held pairs, overflow tiles
-                needed, those of them the loop ran, valid rows): they
+                needed, those of them the loop ran, valid rows; with
+                identity experts their pairs ahead of the rows): they
                 ride the ids' transfer (_count_held)."""
                 if not held_stat:
                     return ids
@@ -8835,11 +8844,18 @@ class InferenceEngine:
         dispatch needed, those of them beyond the spare ones, and the rows
         that carried a token): the counters of docs/monitoring.md, from
         values already on the host.  ``n_rows``: the rows of the step's
-        program, by which a share's dispatch sizes its batches."""
-        held, needed, extra, rows = (int(v) for v in ids[-4:])
+        program, by which a share's dispatch sizes its batches.  Where the
+        routers score identity experts a fifth count, the pairs that
+        landed on one, stands ahead of the rows."""
         cfg = self.cfg
+        if cfg.zero_experts:
+            held, needed, extra, zero, rows = (int(v) for v in ids[-5:])
+            self.metrics.moe_zero_pairs_total.inc(zero)
+        else:
+            held, needed, extra, rows = (int(v) for v in ids[-4:])
         self.metrics.mixed_latent_rows_total.inc(
-            rows * (cfg.num_full_layers if cfg.latent else cfg.num_layers))
+            rows * (cfg.num_attn_sublayers if cfg.latent
+                    else cfg.num_layers))
         self.metrics.moe_routed_pairs_total.inc(
             rows * cfg.num_experts_per_tok * cfg.num_routed_layers)
         self.metrics.moe_held_pairs_total.inc(held)

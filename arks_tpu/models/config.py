@@ -166,6 +166,28 @@ class ModelConfig:
     norm_gate: float = 0.0
     norm_post: bool = False
     swiglu_limit: float = 0.0
+    # The shortcut block (``longcat_flash``: ``attn_sublayers`` 2).  A layer
+    # holds TWO latent-attention sublayers, each followed by a dense SwiGLU
+    # of ``intermediate_size``, and ONE routed layer that reads the first
+    # sublayer's normed output and whose result joins the residual stream
+    # only at the layer's end, over the second attention and both dense
+    # FFNs (`transformer.mixed_step`'s ``shortcut_layer``).  The latent pool
+    # keeps a row a token an attention SUBLAYER (``num_attn_sublayers``).
+    # ``zero_experts``: identity experts the router scores behind the real
+    # ones (ids at or above ``num_experts x expert_parallel_size``): a pair
+    # that lands on one adds ``g x`` and reads no weight.
+    # ``router_select_bias``: softmax scores, the top-k of score + a learnt
+    # selection bias chosen, the UNBIASED scores the weights (no
+    # renormalisation: ``norm_topk_prob`` False).  ``mla_q_scale`` /
+    # ``mla_kv_scale``: what multiplies the normed query latent and the
+    # normed key/value latent (the rotary key lanes not) ahead of their up
+    # projections (``mla_scale_q_lora`` / ``mla_scale_kv_lora``:
+    # (hidden / rank)^0.5).
+    attn_sublayers: int = 1
+    zero_experts: int = 0
+    router_select_bias: bool = False
+    mla_q_scale: float = 1.0
+    mla_kv_scale: float = 1.0
 
     @property
     def q_dim(self) -> int:
@@ -301,9 +323,35 @@ class ModelConfig:
         return self.num_heads * self.value_dim
 
     @property
-    def router_width(self) -> int:
-        """Experts the router scores: the held ones times the shares."""
+    def shortcut(self) -> bool:
+        """The shortcut block: two attention sublayers a layer."""
+        return self.attn_sublayers > 1
+
+    @property
+    def num_attn_sublayers(self) -> int:
+        """Leading dimension of the full pool: the attention sublayers that
+        keep every page of a sequence (a layer's one; the shortcut block's
+        two, sublayer ``j`` of layer ``i`` at ``2 i + j``)."""
+        return self.num_full_layers * self.attn_sublayers
+
+    @property
+    def num_real_experts(self) -> int:
+        """Experts with weights over all the shares; the identity experts'
+        ids start here."""
         return self.num_experts * self.expert_parallel_size
+
+    @property
+    def router_width(self) -> int:
+        """Experts the router scores: the held ones times the shares, and
+        the identity experts (which no share holds: they have no weights)
+        behind them."""
+        return self.num_real_experts + self.zero_experts
+
+    @property
+    def select_bias(self) -> bool:
+        """The router has a learnt selection bias (``router_bias``): added
+        to the scores for the top-k, never to the weights."""
+        return self.scoring_func == "sigmoid" or self.router_select_bias
 
     @property
     def num_routed_layers(self) -> int:
@@ -358,8 +406,8 @@ class ModelConfig:
             # What is HELD here; the router keeps its whole width.
             mlp = self.num_experts * 3 * e * self.moe_intermediate_size \
                 + e * self.router_width
-            if self.scoring_func == "sigmoid":
-                mlp += self.router_width          # the selection bias
+            if self.select_bias:
+                mlp += self.router_width
             if self.shared_expert_intermediate_size:
                 mlp += 3 * e * self.shared_expert_intermediate_size + e
             mlp += 3 * e * self.n_shared_experts * self.moe_intermediate_size
@@ -368,6 +416,10 @@ class ModelConfig:
             else self.num_layers
         blocks = self.num_layers * (attn + norms) + routed * mlp \
             + (self.num_layers - routed) * dense
+        if self.shortcut:
+            # Two attention sublayers and two dense FFNs a layer, four
+            # norms, beside the routed layer counted above.
+            blocks += self.num_layers * (attn + 2 * e + 2 * dense)
         if self.windowed:
             # A window layer's projections have its own head counts.
             blocks += self.num_window_layers * (
@@ -428,6 +480,18 @@ class ModelConfig:
         # num_experts (Qwen2-MoE).
         num_experts = int(d.get("num_local_experts", d.get("num_experts", 0)) or 0)
         is_mixtral = "mixtral" in arch or model_type == "mixtral"
+        if model_type == "longcat_flash":
+            return _from_longcat_flash(d, name or model_type, tuple(eos))
+        # What only the ``longcat_flash`` reader understands: on any other
+        # path each would be dropped, and the model served as another.
+        for k in _LONGCAT_ONLY:
+            if d.get(k):
+                raise ValueError(
+                    f"{k}={d[k]!r} in a config of model_type "
+                    f"{model_type!r}: only model_type 'longcat_flash' is "
+                    "read with identity (zero-compute) experts and scaled "
+                    "query / key-value latents; serving this model without "
+                    "them would be another model")
         if model_type == "mimo_v2":
             return _from_mimo_v2(d, name or model_type, tuple(eos))
         # What only the ``mimo_v2`` reader understands: on any other path
@@ -1099,6 +1163,93 @@ def _from_gigachat3_5(d: dict[str, Any], name: str,
     )
 
 
+# Keys only the ``longcat_flash`` reader understands.
+_LONGCAT_ONLY = ("zero_expert_num", "mla_scale_q_lora", "mla_scale_kv_lora")
+
+
+def _from_longcat_flash(d: dict[str, Any], name: str,
+                        eos: tuple[int, ...]) -> ModelConfig:
+    """The ``longcat_flash`` block (meituan-longcat): ``num_layers`` layers
+    of TWO latent-attention sublayers (the DeepSeek-V3 attention under plain
+    RoPE, the query latent and the key/value latent scaled by (hidden /
+    rank)^0.5 where ``mla_scale_q_lora`` / ``mla_scale_kv_lora``), each
+    followed by a dense SwiGLU of ``ffn_hidden_size``, and ONE routed layer
+    on a shortcut from the first sublayer's normed output to the layer's
+    end: ``n_routed_experts`` experts of ``expert_ffn_hidden_size`` and
+    ``zero_expert_num`` identity experts scored by one softmax, the top
+    ``moe_topk`` of score + a selection bias chosen, the unbiased scores
+    times ``routed_scaling_factor`` the weights, no shared expert.  Key for
+    key from the published file; what the block cannot express is refused,
+    not approximated."""
+    def refuse(what: str) -> None:
+        raise ValueError(f"config {name!r}: {what} is not supported (the "
+                         "model would be served as another model)")
+
+    for k in ("num_layers", "ffn_hidden_size", "expert_ffn_hidden_size",
+              "moe_topk", "n_routed_experts", "kv_lora_rank", "q_lora_rank",
+              "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim"):
+        if not d.get(k):
+            refuse(f"a longcat_flash config without {k}")
+    for k, want in (("zero_expert_type", "identity"), ("hidden_act", "silu"),
+                    ("attention_method", "MLA")):
+        if d.get(k, want) != want:
+            refuse(f"{k}={d[k]!r} (only {want!r})")
+    if int(d.get("num_nextn_predict_layers", 0) or 0):
+        refuse("multi-token prediction layers")
+    if d.get("router_bias"):
+        refuse("router_bias (a bias term in the router's projection)")
+    if d.get("rope_scaling"):
+        refuse(f"rope_scaling={d['rope_scaling']!r} (this block rotates "
+               "under plain RoPE)")
+    if d.get("attention_bias"):
+        refuse("attention_bias")
+    if d.get("norm_topk_prob"):
+        refuse("norm_topk_prob (the chosen experts' scores renormalised)")
+    if int(d.get("n_shared_experts", 0) or 0):
+        refuse(f"n_shared_experts={d['n_shared_experts']}")
+    if int(d.get("first_k_dense_replace", 0) or 0):
+        refuse(f"first_k_dense_replace={d['first_k_dense_replace']}")
+    if int(d.get("n_group", 1) or 1) > 1 or int(d.get("topk_group", 1)
+                                                 or 1) > 1:
+        refuse(f"group-limited routing (n_group={d.get('n_group')}, "
+               f"topk_group={d.get('topk_group')})")
+    if d.get("sliding_window"):
+        refuse(f"sliding_window={d['sliding_window']}")
+    hidden = int(d["hidden_size"])
+    return ModelConfig(
+        name=name,
+        vocab_size=d["vocab_size"],
+        hidden_size=hidden,
+        intermediate_size=int(d["ffn_hidden_size"]),
+        num_layers=int(d["num_layers"]),
+        num_heads=d["num_attention_heads"],
+        num_kv_heads=1,
+        head_dim=d["qk_nope_head_dim"] + d["qk_rope_head_dim"],
+        rope_theta=float(d.get("rope_theta", 10000.0)),
+        rms_norm_eps=float(d.get("rms_norm_eps", 1e-6)),
+        tie_word_embeddings=bool(d.get("tie_word_embeddings", False)),
+        max_position_embeddings=int(d.get("max_position_embeddings", 32768)),
+        eos_token_ids=eos,
+        num_experts=int(d["n_routed_experts"]),
+        num_experts_per_tok=int(d["moe_topk"]),
+        moe_intermediate_size=int(d["expert_ffn_hidden_size"]),
+        norm_topk_prob=False,
+        q_lora_rank=int(d["q_lora_rank"]),
+        kv_lora_rank=int(d["kv_lora_rank"]),
+        qk_nope_head_dim=int(d["qk_nope_head_dim"]),
+        qk_rope_head_dim=int(d["qk_rope_head_dim"]),
+        v_head_dim=int(d["v_head_dim"]),
+        routed_scaling_factor=float(d.get("routed_scaling_factor", 1.0)),
+        attn_sublayers=2,
+        zero_experts=int(d.get("zero_expert_num", 0) or 0),
+        router_select_bias=True,
+        mla_q_scale=(hidden / int(d["q_lora_rank"])) ** 0.5
+        if d.get("mla_scale_q_lora") else 1.0,
+        mla_kv_scale=(hidden / int(d["kv_lora_rank"])) ** 0.5
+        if d.get("mla_scale_kv_lora") else 1.0,
+    )
+
+
 _REGISTRY: dict[str, ModelConfig] = {}
 
 
@@ -1256,6 +1407,25 @@ register_config(ModelConfig(
     attn_out_gate=True, short_period=1, linear_head_decay=True,
     linear_key_heads=2, linear_gate_scale=2.0, linear_norm_eps=1e-6,
     norm_gate=2.0, norm_post=True, swiglu_limit=0.2,
+))
+
+# The ``longcat_flash`` block at CPU-test size: 2 layers of two latent
+# sublayers (4 heads of 16 | 8 over a 32 + 8 row, plain RoPE, the query
+# latent times (64 / 48)^0.5 and the key/value latent times (64 / 32)^0.5),
+# each behind it a dense SwiGLU of 128, and on the shortcut 16 experts of 32
+# and 8 identity experts under one softmax, top-4 of score + a selection
+# bias, times 2.5, no shared expert.
+register_config(ModelConfig(
+    name="tiny-shortcut-mla-moe", vocab_size=512, hidden_size=64,
+    intermediate_size=128, num_layers=2, num_heads=4, num_kv_heads=1,
+    head_dim=24, rope_theta=10000000.0, rms_norm_eps=1e-5,
+    eos_token_ids=(0,),
+    num_experts=16, num_experts_per_tok=4, moe_intermediate_size=32,
+    norm_topk_prob=False, q_lora_rank=48, kv_lora_rank=32,
+    qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+    routed_scaling_factor=2.5, attn_sublayers=2, zero_experts=8,
+    router_select_bias=True, mla_q_scale=(64 / 48) ** 0.5,
+    mla_kv_scale=(64 / 32) ** 0.5,
 ))
 
 # MoE families (HF: mistralai/Mixtral-8x7B-Instruct-v0.1, Qwen/Qwen2-57B-A14B).

@@ -49,6 +49,20 @@ parallelism as one chip sees it): the router keeps its whole width
 result its own experts give (plus the shared expert, which every chip
 computes alike).  What the absent experts would add is left out; on one
 chip the layer runs without its exchange.
+
+**A softmax router with a selection bias** (``cfg.router_select_bias``,
+the ``longcat_flash`` block): one softmax over the router's whole width,
+the top-k of ``p + router_bias`` chosen, the unbiased ``p`` times
+``routed_scaling_factor`` the weights, nothing renormalised.
+
+**Identity (zero-compute) experts** (``cfg.zero_experts`` Z > 0): the
+router's width is ``W = X x size + Z`` and the ids at or above ``X x size``
+name experts with no weights, each the identity: a pair that lands on one
+adds ``g x``.  No dispatch gathers such a pair (it enters no batch, no
+overflow tile and no one-hot column); a token's identity weights are summed
+and multiplied onto ``x`` once (:func:`_zero_experts`, scope
+``arks.moe_zero``).  Under a share every chip computes that part alike, as
+it does a shared expert: it is counted once.
 """
 
 from __future__ import annotations
@@ -76,6 +90,23 @@ def swiglu(gate: jnp.ndarray, up: jnp.ndarray, limit: float = 0.0
     return jax.nn.silu(gate.astype(jnp.float32)).astype(gate.dtype) * up
 
 
+# A softmax router's probabilities are of the order of 1 / W, where the
+# seeded draw of every leaf (normal x 0.02) would decide the top-k alone and
+# for every token alike.  The seeded selection bias of such a router is the
+# draw times ``SELECT_BIAS_SCALE / W``: a deviation of half a uniform
+# probability, which moves the choice where scores are close and leaves it
+# to the token elsewhere (a learnt bias balances loads on that scale).
+SELECT_BIAS_SCALE = 25.0
+
+
+def seeded_select_bias(w: jnp.ndarray) -> jnp.ndarray:
+    """The seeded draw ``w`` [.., W] of a softmax router's selection bias as
+    it is stored (``cfg.router_select_bias``; the sigmoid routers' bias is
+    the draw itself: their scores are of the order of 1)."""
+    return (w.astype(jnp.float32) * (SELECT_BIAS_SCALE / w.shape[-1])
+            ).astype(w.dtype)
+
+
 def init_moe_params(cfg, key, dtype, layers: int | None = None) -> Params:
     """The routed FFN's leaves, stacked ``layers`` deep (every routed layer
     of the model where not given)."""
@@ -100,9 +131,11 @@ def init_moe_params(cfg, key, dtype, layers: int | None = None) -> Params:
         p["shared_down"] = w(next(keys), (l, fs, e))
     if cfg.shared_expert_intermediate_size:
         p["shared_gate"] = w(next(keys), (l, e))
-    if cfg.scoring_func == "sigmoid":
+    if cfg.select_bias:
         # Non-zero, so that what selects and what weighs differ.
         p["router_bias"] = w(next(keys), (l, cfg.router_width))
+        if cfg.router_select_bias:
+            p["router_bias"] = seeded_select_bias(p["router_bias"])
     return p
 
 
@@ -124,7 +157,7 @@ def moe_pspecs(cfg, axis_model: str, shard_experts: bool) -> Params:
         p["shared_down"] = P(None, axis_model, None)
     if cfg.shared_expert_intermediate_size:
         p["shared_gate"] = P(None, None)
-    if cfg.scoring_func == "sigmoid":
+    if cfg.select_bias:
         p["router_bias"] = P(None, None)
     return p
 
@@ -147,7 +180,12 @@ def router_topk(logits: jnp.ndarray, cfg, bias: jnp.ndarray | None = None
     - ``sigmoid`` (DeepSeek-V3 ``noaux_tc``, no group limit): scores are
       sigmoids; the top-k of ``score + bias`` are CHOSEN, their weights are
       the UNBIASED scores, normalised over the chosen when
-      ``norm_topk_prob``, times ``routed_scaling_factor``."""
+      ``norm_topk_prob``, times ``routed_scaling_factor``;
+    - ``softmax`` with a ``bias`` (``cfg.router_select_bias``, the
+      ``longcat_flash`` block): softmax over all experts, the identity ones
+      among them; the top-k of ``p + bias`` are CHOSEN, their weights are
+      the UNBIASED ``p`` times ``routed_scaling_factor``.  An id at or
+      above ``cfg.num_real_experts`` is an identity expert's."""
     k = cfg.num_experts_per_tok
     if cfg.scoring_func == "sigmoid":
         scores = jax.nn.sigmoid(logits.astype(jnp.float32))
@@ -157,7 +195,11 @@ def router_topk(logits: jnp.ndarray, cfg, bias: jnp.ndarray | None = None
             vals = vals / (jnp.sum(vals, axis=-1, keepdims=True) + 1e-20)
         return vals * cfg.routed_scaling_factor, idx
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    vals, idx = jax.lax.top_k(probs, k)
+    if bias is not None:
+        _, idx = jax.lax.top_k(probs + bias.astype(jnp.float32), k)
+        vals = jnp.take_along_axis(probs, idx, axis=-1)
+    else:
+        vals, idx = jax.lax.top_k(probs, k)
     if cfg.norm_topk_prob:
         vals = vals / (jnp.sum(vals, axis=-1, keepdims=True) + 1e-9)
     if cfg.routed_scaling_factor != 1.0:
@@ -170,11 +212,31 @@ def router_weights(logits: jnp.ndarray, cfg,
     """[.., W] router logits → [.., X] combine weights of the experts HELD
     here (unselected experts zero) — the dense-dispatch form of
     router_topk; under a share, the held columns of the whole width."""
-    vals, idx = router_topk(logits, cfg, bias)
+    return _held_weights(*router_topk(logits, cfg, bias), cfg)
+
+
+def _held_weights(vals: jnp.ndarray, idx: jnp.ndarray, cfg) -> jnp.ndarray:
+    """:func:`router_topk`'s pairs as [.., X] weights of the held experts;
+    absent and identity experts fall off the one-hot."""
     if cfg.expert_parallel_size > 1:
-        idx = idx - held_first(cfg)       # absent experts fall off the one-hot
+        idx = idx - held_first(cfg)
     onehot = jax.nn.one_hot(idx, cfg.num_experts, dtype=vals.dtype)  # [.., k, X]
     return jnp.einsum("...k,...kx->...x", vals, onehot)
+
+
+def _zero_experts(x: jnp.ndarray, vals: jnp.ndarray, idx: jnp.ndarray, cfg,
+                  row_valid: jnp.ndarray | None
+                  ) -> tuple[jnp.ndarray, jnp.ndarray | None]:
+    """The identity experts' part of a routed layer on ``x`` [.., E] with
+    :func:`router_topk`'s pairs [.., k]: ``(sum of the weights that landed
+    on an identity expert) x``, and, with ``row_valid`` [..], how many
+    pairs of the valid rows did."""
+    with jax.named_scope("arks.moe_zero"):
+        zero = idx >= cfg.num_real_experts
+        g = jnp.sum(jnp.where(zero, vals, 0), axis=-1, keepdims=True)
+        pairs = None if row_valid is None else jnp.sum(
+            zero & row_valid[..., None], dtype=jnp.int32)
+        return x * g.astype(x.dtype), pairs
 
 
 def held_first(cfg) -> int:
@@ -466,6 +528,9 @@ def _ragged_dispatch(x2: jnp.ndarray, vals: jnp.ndarray, idx: jnp.ndarray,
     ``ragged_dot`` takes no quantised leaf, those go the batched way)."""
     n, e = x2.shape
     k, nx = cfg.num_experts_per_tok, cfg.num_experts
+    if cfg.zero_experts:
+        # An identity pair sorts behind every group and gets no row of any.
+        vals = jnp.where(idx < nx, vals, 0)
     with jax.named_scope("arks.moe_route"):
         flat_expert = idx.reshape(-1)                       # [T*k]
         order = jnp.argsort(flat_expert)
@@ -479,6 +544,8 @@ def _ragged_dispatch(x2: jnp.ndarray, vals: jnp.ndarray, idx: jnp.ndarray,
         down = jax.lax.ragged_dot(act, mp["w_down"], group_sizes)  # [T*k, E]
     with jax.named_scope("arks.moe_route"):
         w = jnp.take(vals.reshape(-1), order).astype(down.dtype)   # [T*k]
+        if cfg.zero_experts:
+            down = jnp.where(w[:, None] != 0, down, 0)
         return jnp.zeros((n, e), down.dtype).at[token_of].add(
             down * w[:, None])
 
@@ -550,13 +617,25 @@ def moe_ffn_grouped(x: jnp.ndarray, mp: Params, cfg,
 
     if "shared_gate_proj" in mp:
         out = out + _shared_expert(x2, mp, cfg)
+    zero_pairs = None
+    if cfg.zero_experts:
+        part, zero_pairs = _zero_experts(
+            x2, vals, idx, cfg,
+            None if row_valid is None else row_valid.reshape(-1))
+        out = out + part
     out = out.reshape(*lead, e)
     if row_valid is None:
         return out
     if share:
-        return out, jnp.concatenate([jnp.sum(held)[None], tiles]
-                                    ).astype(jnp.int32)
-    return out, _counts(jnp.sum(row_valid) * k)
+        counts = jnp.concatenate([jnp.sum(held)[None], tiles]
+                                 ).astype(jnp.int32)
+    else:
+        # Held whole, every pair lands here but the identity experts'.
+        counts = _counts(jnp.sum(row_valid) * k - (
+            0 if zero_pairs is None else zero_pairs))
+    if zero_pairs is not None:
+        counts = jnp.concatenate([counts, zero_pairs[None]])
+    return out, counts
 
 
 def moe_ffn(x: jnp.ndarray, mp: Params, cfg, constrain=None,
@@ -573,7 +652,9 @@ def moe_ffn(x: jnp.ndarray, mp: Params, cfg, constrain=None,
     expert held here (all of them where the layer is held whole), the
     overflow tiles a share's batched dispatch needed, and those of them
     beyond the spare ones, which its loop ran (0 and 0 from the dense
-    dispatch and from a layer held whole).  ``stack`` = ``(tree, layer)``
+    dispatch and from a layer held whole); a layer with identity experts
+    (``cfg.zero_experts``) returns ``[4]``, the pairs that landed on one
+    last: ``routed = held + zero + absent``.  ``stack`` = ``(tree, layer)``
     says where ``mp`` lies in the stacked tree the program was handed
     (:func:`_batched_dispatch`; None: a lone layer)."""
     if grouped is None:
@@ -590,7 +671,8 @@ def moe_ffn(x: jnp.ndarray, mp: Params, cfg, constrain=None,
 
     with jax.named_scope("arks.moe_route"):
         logits = jnp.einsum("...e,ex->...x", x, mp["router"])
-        weights = router_weights(logits, cfg, mp.get("router_bias"))
+        vals, idx = router_topk(logits, cfg, mp.get("router_bias"))
+        weights = _held_weights(vals, idx, cfg)
         counts = None
         if row_valid is not None:
             # A chosen expert's weight is never zero (a softmax or a
@@ -610,4 +692,9 @@ def moe_ffn(x: jnp.ndarray, mp: Params, cfg, constrain=None,
 
     if "shared_gate_proj" in mp:
         out = out + _shared_expert(x, mp, cfg, constrain)
+    if cfg.zero_experts:
+        part, zero_pairs = _zero_experts(x, vals, idx, cfg, row_valid)
+        out = out + part
+        if row_valid is not None:
+            counts = jnp.concatenate([counts, zero_pairs[None]])
     return out if row_valid is None else (out, counts)

@@ -68,6 +68,10 @@ MATMUL_KEYS = frozenset({
     # The ``gigachat3_5`` block: a linear layer's full output gate (its
     # latent layers' gate is ``wg``).
     "w_z",
+    # The ``longcat_flash`` block: the dense SwiGLU behind each of a
+    # layer's two attention sublayers, [L, 2, K, N] (``w_gate`` / ``w_up`` /
+    # ``w_down`` are the layer's routed experts').
+    "ffn_gate", "ffn_up", "ffn_down",
 })
 # The leaves a GQA stack stores head-split, ``[L, H, D, E]``
 # (`transformer.split_heads`).  The linear layers' leaves of the same names
@@ -281,7 +285,7 @@ def init_params_quantized(cfg, key, dtype=jnp.bfloat16, bits: int = 8,
     """
     import functools
 
-    from arks_tpu.models import transformer as tf
+    from arks_tpu.models import moe, transformer as tf
 
     if bits not in (4, 8):
         raise ValueError(f"bits={bits}")
@@ -301,6 +305,8 @@ def init_params_quantized(cfg, key, dtype=jnp.bfloat16, bits: int = 8,
             return quantize_tensor(w.astype(dtype), axis=axis)
         if kind == "dt_bias":
             return tf.shift_dt_bias(w.astype(dtype))
+        if kind == "select_bias":
+            return moe.seeded_select_bias(w.astype(dtype))
         return w.astype(dtype)
 
     # A head-split projection is drawn and quantised as the [L, E, H x D]
@@ -343,6 +349,8 @@ def init_params_quantized(cfg, key, dtype=jnp.bfloat16, bits: int = 8,
                     continue
             elif name == "dt_bias":
                 kind, axis = "dt_bias", 0
+            elif name == "router_bias" and cfg.router_select_bias:
+                kind, axis = "select_bias", 0
             else:
                 kind, axis = "full", 0
             out[name] = gen(sub, tuple(leaf.shape), kind, axis)

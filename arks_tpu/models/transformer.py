@@ -43,8 +43,6 @@ AXIS_MODEL = "model"
 
 Params = dict[str, Any]
 
-# ``moe.moe_ffn``'s counts of a layer that has no router.
-_NO_COUNTS = np.zeros((3,), np.int32)
 
 
 class KVCache(NamedTuple):
@@ -115,7 +113,9 @@ class PagedKVCache(NamedTuple):
     A LATENT pool (latent attention, ``cfg.latent``): ``k`` is ``[L,
     num_pages, 1, page, R]``, one row a token, the normed latent and the
     rotary key lanes, which is key AND value of every head; it is stored
-    once: ``v`` is None, and so are the scales (bf16 only).
+    once: ``v`` is None, and so are the scales (bf16 only).  ``L`` counts
+    attention SUBLAYERS (``cfg.num_attn_sublayers``: two a layer of the
+    shortcut block).
 
     A model with WINDOW layers (``cfg.windowed``) has two pools with page
     counts and lifetimes of their own: the four arrays above are the
@@ -235,6 +235,55 @@ def _init_latent_params(cfg: ModelConfig, key: jax.Array,
     if not cfg.tie_word_embeddings:
         params["lm_head"] = w((e, v))
     return params
+
+
+def _init_shortcut_params(cfg: ModelConfig, key: jax.Array,
+                          dtype: jnp.dtype) -> Params:
+    """The ``longcat_flash`` tree: ONE stacked tree ``layers``.  A layer's
+    two attention sublayers and the dense FFN behind each are stacked once
+    more, ``[L, 2, ..]``, sublayer ``j`` at ``[:, j]``:
+    :func:`_init_latent_params`'s attention leaves and norms (``attn_norm``
+    ahead of an attention, ``mlp_norm`` behind it) and the dense SwiGLU
+    ``ffn_gate`` / ``ffn_up`` [L, 2, E, F], ``ffn_down`` [L, 2, F, E]; the
+    layer's one routed FFN has `moe.init_moe_params`'s leaves ([L, ..]: the
+    router ``cfg.router_width`` wide, identity experts included, with its
+    selection bias, and the held experts)."""
+    e, f, v, h = (cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size,
+                  cfg.num_heads)
+    l = cfg.num_layers
+    keys = iter(jax.random.split(key, 16))
+
+    def w(shape, scale=0.02):
+        return (jax.random.normal(next(keys), shape, jnp.float32)
+                * scale).astype(dtype)
+
+    from arks_tpu.models import moe
+    layers = {
+        "attn_norm": jnp.ones((l, 2, e), dtype),
+        "wq_a": w((l, 2, e, cfg.q_lora_rank)),
+        "q_norm": jnp.ones((l, 2, cfg.q_lora_rank), dtype),
+        "wq_b": w((l, 2, cfg.q_lora_rank, cfg.q_dim)),
+        "wkv_a": w((l, 2, e, cfg.latent_row)),
+        "kv_norm": jnp.ones((l, 2, cfg.kv_lora_rank), dtype),
+        "wkv_b": w((l, 2, cfg.kv_lora_rank,
+                    h * (cfg.qk_nope_head_dim + cfg.v_head_dim))),
+        "wo": w((l, 2, cfg.attn_out_dim, e)),
+        "mlp_norm": jnp.ones((l, 2, e), dtype),
+        "ffn_gate": w((l, 2, e, f)), "ffn_up": w((l, 2, e, f)),
+        "ffn_down": w((l, 2, f, e)),
+    }
+    layers.update(moe.init_moe_params(cfg, next(keys), dtype))
+    params: Params = {"embed": w((v, e)), "layers": layers,
+                      "final_norm": jnp.ones((e,), dtype)}
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = w((e, v))
+    return params
+
+
+# A shortcut layer's leaves that are stacked by sublayer, ``[2, ..]``.
+_SUBLAYER_LEAVES = ("attn_norm", "wq_a", "q_norm", "wq_b", "wkv_a",
+                    "kv_norm", "wkv_b", "wo", "mlp_norm", "ffn_gate",
+                    "ffn_up", "ffn_down")
 
 
 def _init_windowed_params(cfg: ModelConfig, key: jax.Array,
@@ -465,6 +514,8 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype: jnp.dtype | None = None
     dtype = dtype or jnp.dtype(cfg.dtype)
     if cfg.latent and cfg.linear:
         return _init_latent_linear_params(cfg, key, dtype)
+    if cfg.shortcut:
+        return _init_shortcut_params(cfg, key, dtype)
     if cfg.latent:
         return _init_latent_params(cfg, key, dtype)
     if cfg.windowed:
@@ -678,7 +729,8 @@ def init_paged_cache(cfg: ModelConfig, num_pages: int, page: int,
                 (cfg.num_full_layers, num_pages, cfg.num_kv_heads),
                 (cfg.num_window_layers, win_pages, cfg.kv_heads_of(True)))]
         return pools[0]._replace(win=pools[1])
-    shape = (cfg.num_layers, num_pages, cfg.num_kv_heads, page,
+    # (A pool row an attention SUBLAYER: a shortcut layer holds two.)
+    shape = (cfg.num_attn_sublayers, num_pages, cfg.num_kv_heads, page,
              cache_head_dim(cfg, pad_head))
     # (The values' pool: as wide as the keys' but where the model's values
     # are narrower than its keys.)
@@ -761,11 +813,16 @@ def _constrain(x: jnp.ndarray, mesh: Mesh | None, *spec) -> jnp.ndarray:
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, P(*spec)))
 
 
-def _norm(x: jnp.ndarray, w: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
+def _norm(x: jnp.ndarray, w: jnp.ndarray, cfg: ModelConfig,
+          scale: float = 1.0) -> jnp.ndarray:
     """The model's norm: RMS norm times the learnt ``w``, or
-    (``cfg.norm_gate`` g) times ``g sigmoid(w)``, which is 1 at ``w`` = 0."""
+    (``cfg.norm_gate`` g) times ``g sigmoid(w)``, which is 1 at ``w`` = 0;
+    times ``scale`` in the same float32 product (a latent's
+    ``cfg.mla_q_scale`` / ``mla_kv_scale``)."""
     if cfg.norm_gate:
         w = cfg.norm_gate * jax.nn.sigmoid(w.astype(jnp.float32))
+    if scale != 1.0:
+        w = w.astype(jnp.float32) * scale
     return rms_norm(x, w, cfg.rms_norm_eps)
 
 
@@ -888,9 +945,12 @@ def _mla_q(x: jnp.ndarray, lp: Params, cfg: ModelConfig,
            positions: jnp.ndarray) -> jnp.ndarray:
     """Normed ``x`` [B, T, E] -> the ABSORBED queries [B, T, H, C + rope]:
     down, norm, up, RoPE on the rotary lanes, and ``q_nope W_uk^T`` so that
-    a head's score against a cached row is one dot over the row."""
+    a head's score against a cached row is one dot over the row.  The
+    query latent is scaled in its norm (``cfg.mla_q_scale``; the up
+    projection is linear, so every lane of every head is)."""
     b, t = x.shape[:2]
-    cq = _norm(qeinsum("...e,er->...r", x, lp["wq_a"]), lp["q_norm"], cfg)
+    cq = _norm(qeinsum("...e,er->...r", x, lp["wq_a"]), lp["q_norm"], cfg,
+               cfg.mla_q_scale)
     q = qeinsum("...r,rq->...q", cq, lp["wq_b"]).reshape(
         b, t, cfg.num_heads, cfg.head_dim)
     q_nope, q_rope = (q[..., :cfg.qk_nope_head_dim],
@@ -905,9 +965,12 @@ def _mla_q(x: jnp.ndarray, lp: Params, cfg: ModelConfig,
 def _mla_kv(x: jnp.ndarray, lp: Params, cfg: ModelConfig,
             positions: jnp.ndarray) -> jnp.ndarray:
     """Normed ``x`` [B, T, E] -> the row a token caches [B, T, C + rope]:
-    the normed latent and the rotary key lanes all heads share."""
+    the normed latent (times ``cfg.mla_kv_scale``, which so reaches the
+    no-rope keys and the values through ``W_kvb``) and the rotary key lanes
+    all heads share."""
     kv = qeinsum("...e,er->...r", x, lp["wkv_a"])
-    c = _norm(kv[..., :cfg.kv_lora_rank], lp["kv_norm"], cfg)
+    c = _norm(kv[..., :cfg.kv_lora_rank], lp["kv_norm"], cfg,
+              cfg.mla_kv_scale)
     k_r = apply_rope(kv[..., None, cfg.kv_lora_rank:], positions,
                      cfg.rope_theta, cfg.rope_yarn)[..., 0, :]
     return jnp.concatenate([c, k_r], axis=-1)
@@ -1250,8 +1313,11 @@ def mixed_step(
     linear layers (``cfg.linear``), which read and write the slots' state
     (``cache.lin``) and no page.  The full kind is the model's too: GQA
     layers, or latent layers (``cfg.latent``) over the latent pool, one
-    attention path, the absorbed one, for chunks and decode lanes alike;
-    both write and read the full pool through ``tables``.  A layer
+    attention path, the absorbed one, for chunks and decode lanes alike,
+    or shortcut layers (``cfg.shortcut``: two latent sublayers a layer over
+    pool rows ``2 i`` and ``2 i + 1``, a dense FFN behind each, the routed
+    layer's result carried from the first sublayer to the layer's end);
+    all write and read the full pool through ``tables``.  A layer
     function's
     ``src`` is ``(stack, index)``: the stacked tree of ``params`` its ``lp``
     was taken out of and where, for the routed FFN's overflow loop
@@ -1259,7 +1325,8 @@ def mixed_step(
 
     ``with_held``: a routed layer is handed the mask of the valid rows and
     ``src``, and the step returns a third result, counts: that function's
-    three summed over the routed layers.  Without it a routed layer is
+    three (four where the routers score identity experts) summed over the
+    routed layers.  Without it a routed layer is
     handed neither (its padding rows are routed like any other; no valid
     row's output depends on them)."""
     from arks_tpu.ops.attention import (paged_latent_update_and_attend,
@@ -1278,6 +1345,9 @@ def mixed_step(
                          head["attn_norm"].dtype)                # [1, T, E]
     kv_sharded = mesh is not None and shard_kv_heads(
         cfg, mesh.shape.get(AXIS_MODEL, 1))
+    # ``moe.moe_ffn``'s counts of a layer that has no router (a fourth where
+    # the model's routers score identity experts).
+    no_counts = np.zeros((4 if cfg.zero_experts else 3,), np.int32)
 
     def ffn(h, lp, src):
         # Without ``with_held`` every layer's counts are the constant, and
@@ -1286,7 +1356,7 @@ def mixed_step(
             y, held = _mlp(h, lp, cfg, mesh, None, row_valid=valid,
                            stack=src)
         else:
-            y, held = _mlp(h, lp, cfg, mesh, None), _NO_COUNTS
+            y, held = _mlp(h, lp, cfg, mesh, None), no_counts
         return h + _post_norm(y, lp, "mlp_post_norm", cfg), held
 
     def latent_layer(h, lp, src, pool, tbl, index, window):
@@ -1300,6 +1370,47 @@ def mixed_step(
         y = _mla_out(attn, lp, cfg, x[0])[None]
         h, held = ffn(h + _post_norm(y, lp, "attn_post_norm", cfg), lp, src)
         return h, (k,) + tuple(pool[1:]), held
+
+    def shortcut_layer(h, lp, src, pool, tbl, index, window):
+        """Two latent sublayers, each with its dense FFN, pool rows ``2 i``
+        and ``2 i + 1``; the routed layer reads the first sublayer's normed
+        output and joins the stream at the layer's end."""
+        del window
+        from arks_tpu.models import moe
+        k = pool[0]
+        stack, at = src
+        for j in range(cfg.attn_sublayers):
+            # A sublayer's leaves come out of the STACKED tree by (layer,
+            # sublayer), one slice a use: ``lp``'s ``[2, ..]`` slice of a
+            # leaf has both sublayers' dots for users, so the compiler
+            # writes it out a layer a step before either reads it.
+            sub = {n: jax.tree.map(
+                lambda a: jax.lax.dynamic_slice(
+                    a, (at, j) + (0,) * (a.ndim - 2),
+                    (1, 1) + a.shape[2:]).reshape(a.shape[2:]),
+                stack[n]) for n in _SUBLAYER_LEAVES}
+            x = _norm(h, sub["attn_norm"], cfg)
+            attn, k = paged_latent_update_and_attend(
+                _mla_q(x, sub, cfg, rope_pos)[0],
+                _mla_kv(x, sub, cfg, rope_pos)[0], k, tbl, token_slot,
+                token_pos, seq_q_start, seq_q_len, seq_pos_start,
+                cfg.attn_sublayers * index + j, dv=cfg.kv_lora_rank,
+                scale=cfg.softmax_scale)
+            h = h + _mla_out(attn, sub, cfg, x[0])[None]
+            with _scope("arks.ffn"):
+                x = _norm(h, sub["mlp_norm"], cfg)
+            if j == 0:
+                if with_held:
+                    shortcut, held = moe.moe_ffn(x, lp, cfg, row_valid=valid,
+                                                 stack=src)
+                else:
+                    shortcut, held = moe.moe_ffn(x, lp, cfg), no_counts
+            with _scope("arks.ffn"):
+                act = moe.swiglu(
+                    qeinsum("...e,ef->...f", x, sub["ffn_gate"]),
+                    qeinsum("...e,ef->...f", x, sub["ffn_up"]))
+                h = h + qeinsum("...f,fe->...e", act, sub["ffn_down"])
+        return h + shortcut, (k,) + tuple(pool[1:]), held
 
     def layer(h, lp, src, pool, tbl, index, window: bool):
         q, k, v, gate = _kind_qkv(h, lp, cfg, rope_pos, window)
@@ -1351,8 +1462,9 @@ def mixed_step(
         inner_stack, inner_tbl = params["win_layers"], win_tables
     else:
         inner = ()         # every layer a full layer: no inner kind at all
-    full_layer = latent_layer if cfg.latent else layer
-    held = _NO_COUNTS
+    full_layer = (shortcut_layer if cfg.shortcut
+                  else latent_layer if cfg.latent else layer)
+    held = no_counts
     # Where the head's layers are linear layers, the stacked inner layers'
     # state sits behind theirs, and the full pool starts at the first
     # period's layer.  (An offset of zero is left out of the traced index

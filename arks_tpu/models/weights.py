@@ -101,9 +101,22 @@ class LatentLinearCheckpointError(NotImplementedError):
     tree."""
 
 
+class ShortcutCheckpointError(NotImplementedError):
+    """A checkpoint of a model with two latent-attention sublayers a layer
+    and a routed layer on a shortcut (``longcat_flash``): the name mapping
+    of its paired attention, norm and dense-FFN tensors (``self_attn.0`` /
+    ``.1``, ``mlps.0`` / ``.1``) onto leaves stacked by sublayer, of its
+    router (``classifier``, ``e_score_correction_bias``) and expert tensors,
+    and the permutation of the rotary columns to this repo's rotate-half
+    form are not built; such a model is served from seeded random weights
+    only.  Raised instead of mapping its tensors onto the latent block's
+    names."""
+
+
 def _refuse_latent(cfg: ModelConfig, path: str) -> None:
     for is_kind, err in ((cfg.latent and cfg.linear,
                           LatentLinearCheckpointError),
+                         (cfg.shortcut, ShortcutCheckpointError),
                          (cfg.latent, LatentCheckpointError),
                          (cfg.windowed and cfg.scoring_func == "sigmoid",
                           MimoCheckpointError),

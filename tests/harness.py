@@ -44,6 +44,7 @@ SHAPE = {
     "tiny-swa-sink-moe": dict(num_slots=3),
     "tiny-latent-linear-moe": dict(kv_cache_dtype="auto"),
     "tiny-mla-moe": dict(max_cache_len=128, kv_cache_dtype="auto", seed=0),
+    "tiny-shortcut-mla-moe": dict(kv_cache_dtype="auto"),
 }
 # Tokens a request decodes in a block's shared streams.
 DECODE = {"tiny-swa-moe": 12, "tiny-swa-sink-moe": 12}

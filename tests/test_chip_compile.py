@@ -357,7 +357,7 @@ def test_quantised_experts_compile_for_v5e_as_stored(chip, kind, rows):
         num_experts=x, num_experts_per_tok=2, router_width=x,
         expert_parallel_size=1, expert_parallel_rank=0,
         scoring_func="softmax", norm_topk_prob=True,
-        routed_scaling_factor=1.0, swiglu_limit=0.0)
+        routed_scaling_factor=1.0, swiglu_limit=0.0, zero_experts=0)
 
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
@@ -433,6 +433,7 @@ SHARES = {
     "solar-open2-250b-ep8-l8": (8, 64, 20, 64 * 20, 0, True),
     "gigachat3.5-432b-ep8-l5": (8, 64, 80, 64 * 80, 0, False),
     "mimo-v2.5-ep16-l13": (16, 64, 32, 1280, 64 * 6, True),
+    "longcat-flash-ep32-l6": (32, 64, 20, 64 * 20, 0, False),
 }
 _STEPS: dict = {}
 
@@ -513,7 +514,8 @@ def _unfused_s8(text: str) -> list:
     ("kimi-k2.5-ep32-l9", 1024, 260), ("laguna-s-2.1-ep8", 1024, 480),
     ("solar-open2-250b-ep8-l8", 256, 220),
     ("gigachat3.5-432b-ep8-l5", 1024, 360),
-    ("mimo-v2.5-ep16-l13", 1024, 800)])
+    ("mimo-v2.5-ep16-l13", 1024, 800),
+    ("longcat-flash-ep32-l6", 1024, 700)])
 def test_a_shares_chunk_step_copies_no_expert_leaf_out_of_its_stack(
         chip, monkeypatch, name, rows, temp_mb):
     """The chunk-carrying step of each share configuration at its published
@@ -536,6 +538,17 @@ def test_a_shares_chunk_step_copies_no_expert_leaf_out_of_its_stack(
     assert not found, found
     assert "while" in text
     assert compiled.memory_analysis().temp_size_in_bytes < temp_mb * 1e6
+    if cfg.shortcut:
+        # Nor a layer's ``[2, ..]`` slice of a leaf stacked by sublayer
+        # (PR 54: taken through the scan's slice, both sublayers' dots were
+        # its users and every layer wrote 0.64 GB out before reading it;
+        # `mixed_step`'s ``shortcut_layer`` takes (layer, sublayer) out of
+        # the stacked tree a use).  What is left are the stacked ``wq_b`` and
+        # ``wkv_b`` transposed whole once a step, as the latent block's
+        # layer slices of them are (ROADMAP S12).
+        by_sublayer = [line for dims, line in _unfused_s8(text)
+                       if dims[0] == 2 or dims[:2] == (1, 2)]
+        assert not by_sublayer, by_sublayer
 
 
 @pytest.mark.parametrize("name,rows", [

@@ -1,4 +1,5 @@
-"""One family, every block that keeps a state a slot (ROADMAP D9): the step
+"""One family, every block that keeps a state a slot, and the block of two
+latent sublayers a layer (ROADMAP D9): the step
 program on float32 activations against the reference family's full forward,
 a prompt cut at odd lengths and then decoded, decode and prefill lanes in
 one flat batch, a slot another sequence just left; and the controls that
@@ -22,8 +23,10 @@ import harness
 # seeded: a scale of 1 under either reading of the norm) are redrawn at 0.5
 # sigma in program and reference alike, so that the norm's form reaches the
 # logits; how far a state rounded to bfloat16 after every token parts, in
-# logit sigmas.
+# logit sigmas (None: the block keeps pages alone, no state to round).
 BLOCKS = {
+    "tiny-shortcut-mla-moe": dict(family="shortcut_mla_moe", norms=False,
+                                  rounded=None),
     "tiny-linear-moe": dict(family="linear_moe", norms=False, rounded=1e-2),
     "tiny-latent-linear-moe": dict(family="latent_linear_moe", norms=True,
                                    rounded=5e-3),
@@ -67,7 +70,7 @@ def stepper(request):
 
     def fresh_cache():
         return tf.init_paged_cache(cfg, slots * max_pages, page, jnp.float32,
-                                   state_slots=slots)
+                                   state_slots=slots if cfg.linear else 0)
 
     def run(cache, lanes, rows=100):
         """One step over ``lanes``: {slot: (token ids, first position)}.
@@ -114,12 +117,17 @@ def test_a_prompt_cut_at_odd_lengths_then_decoded_is_the_full_forward(
     cfg = block["cfg"]
     # The pool holds the attention layers (a latent pool one row a token and
     # no values), the state the linear ones.
-    assert cache.k.shape[0] == cfg.num_full_layers
+    # (The shortcut block: two attention sublayers a layer, a pool row
+    # each, and no state.)
+    assert cache.k.shape[0] == cfg.num_attn_sublayers \
+        == cfg.num_full_layers * (2 if cfg.shortcut else 1)
     assert (cache.v is None) == bool(cfg.latent)
-    assert cache.lin.s.shape[0] == cfg.num_linear_layers == 6
+    assert (cache.lin is None) == bool(cfg.shortcut)
+    if cfg.linear:
+        assert cache.lin.s.shape[0] == cfg.num_linear_layers == 6
     # Slot 0 is left dirty by sequence C, which then ends.
     _, cache = run(cache, {0: (c_ids, 0)})
-    assert float(jnp.abs(cache.lin.s[:, 0]).max()) > 0
+    assert not cfg.linear or float(jnp.abs(cache.lin.s[:, 0]).max()) > 0
     got_a, got_b, pa, pb = [], [], 0, 0
     plan = [(70, 20), (63, 1), (1, 1), (1, 30), (37, 1), (1, 37), (1, 0),
             (1, 0), (5, 0)]
@@ -140,7 +148,11 @@ def test_a_prompt_cut_at_odd_lengths_then_decoded_is_the_full_forward(
             # chunk form's triangular solve against the token recurrence.
             assert np.abs(lg - w).max() < 2e-4 * w.std() + 1e-6, r
     # Slot 1 and 3 were never touched.
-    assert not float(jnp.abs(cache.lin.s[:, (1, 3)]).max())
+    if cfg.linear:
+        assert not float(jnp.abs(cache.lin.s[:, (1, 3)]).max())
+    else:
+        assert not float(jnp.abs(cache.k[:, 16:32]).max())
+        assert not float(jnp.abs(cache.k[:, 48:]).max())
 
 
 def test_a_stale_state_would_show(stepper):
@@ -160,6 +172,9 @@ def test_a_stale_state_would_show(stepper):
     assert np.abs(dirty[0] - w).max() > 0.05 * w.std()
 
 
+@pytest.mark.parametrize(
+    "stepper", sorted(b for b, row in BLOCKS.items() if row["rounded"]),
+    indirect=True)
 def test_a_state_kept_in_bfloat16_shows_where_the_activations_are_float32(
         stepper):
     """The control the chip cannot read (PERF.md §2: there bfloat16

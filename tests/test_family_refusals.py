@@ -22,8 +22,28 @@ _FILE = {
     "tiny-swa-moe": dict(num_experts=16),
     "tiny-mla-moe": dict(n_routed_experts=16),
     "tiny-swa-sink-moe": {},
+    "tiny-shortcut-mla-moe": dict(n_routed_experts=16),
 }
 _UNREADABLE = {
+    "tiny-shortcut-mla-moe": [
+        (dict(zero_expert_type="copy"), "zero_expert_type"),
+        (dict(router_bias=True), "router_bias"),
+        (dict(num_nextn_predict_layers=1), "multi-token"),
+        (dict(rope_scaling={"type": "yarn", "factor": 4}), "rope_scaling"),
+        (dict(attention_bias=True), "attention_bias"),
+        (dict(norm_topk_prob=True), "norm_topk_prob"),
+        (dict(hidden_act="gelu"), "hidden_act"),
+        (dict(attention_method="MHA"), "attention_method"),
+        (dict(n_shared_experts=1), "n_shared_experts"),
+        (dict(n_group=8, topk_group=4), "group-limited"),
+        (dict(moe_topk=None), "without moe_topk"),
+        (dict(expert_ffn_hidden_size=0), "without expert_ffn_hidden_size"),
+        (dict(kv_lora_rank=0), "without kv_lora_rank"),
+        # The other readers refuse what only this one reads.
+        (dict(model_type="kimi_k2"), "only model_type 'longcat_flash'"),
+        (dict(model_type="deepseek_v3", zero_expert_num=0,
+              mla_scale_q_lora=False), "mla_scale_kv_lora"),
+    ],
     "tiny-linear-moe": [
         (dict(gqa_layers=[0, 3, 7]), "gqa_layers"),
         (dict(gqa_interval=0), "gqa_layers"),
@@ -164,6 +184,7 @@ _UNSERVABLE = {
     "tiny-latent-linear-moe": _narrow_rows("int8 / int4 latent row")
     + _ONE_POOL,
     "tiny-mla-moe": _narrow_rows("bf16 only") + _ONE_POOL,
+    "tiny-shortcut-mla-moe": _narrow_rows("bf16 only") + _ONE_POOL,
 }
 # ONE preflight a block (``engine.py::_BLOCKS``): its sentence says what the
 # model is once, in these words, and names each refused argument; and what it
@@ -178,6 +199,7 @@ _IS = {
         "latent-attention layers over one latent row a token",
         "neither a latent row nor a recurrent state"),
     "tiny-mla-moe": ("latent attention, one latent row a token", ""),
+    "tiny-shortcut-mla-moe": ("latent attention, one latent row a token", ""),
 }
 # The one refusal of the tables that is no preflight's.
 _OWN_SENTENCE = {"kv_pool_pages": "kv_pool_pages=16: an admission that "
@@ -219,6 +241,11 @@ _UNSHARDED = {
                      ["--expert-parallel-size", "2",
                       "--expert-parallel-rank", "1"], "1/2", "kv_transfer",
                      "kv_transfer"),
+    "tiny-shortcut-mla-moe": ("the latent block has no sharding rules",
+                              "the latent block has no sharding rules",
+                              ["--expert-parallel-size", "2",
+                               "--expert-parallel-rank", "0"], "0/2",
+                              "kv_transfer", "kv_transfer"),
 }
 
 
@@ -259,3 +286,12 @@ def test_a_share_names_a_rank_out_of_range_and_a_model_without_experts():
         get_config("tiny-mla-moe").with_expert_share(2, 2)
     with pytest.raises(ValueError, match="no routed experts"):
         get_config("tiny").with_expert_share(2, 0)
+
+
+def test_a_checkpoint_of_the_shortcut_block_is_refused_by_name(tmp_path):
+    from arks_tpu.models import weights
+    (tmp_path / "model.safetensors").write_bytes(b"")
+    with pytest.raises(weights.ShortcutCheckpointError,
+                       match="stacked by sublayer"):
+        weights.load_params(get_config("tiny-shortcut-mla-moe"),
+                            str(tmp_path))

@@ -180,6 +180,23 @@ Every program of 2 + 64 rows stands (the dense dispatch), the pipelined
 programs and ``tiny`` with them; ``tiny-mixtral``'s 66-row step is dense
 too, which is why the branch its cell's 320-row step takes is the
 whole-layer pin's.
+
+PR 54 ADDED four pins and moved none of the thirty-four nor the whole-layer
+pin: ``tiny-shortcut-mla-moe`` (the ``longcat_flash`` block: two latent
+sublayers and two dense FFNs a layer, a routed layer with 8 identity experts
+behind 16 real ones on a shortcut, softmax scores with a selection bias)
+under a share, the values of PR 54's own tree.  The block is a third
+full-layer kind of the period scan (``shortcut_layer``) and rides the latent
+block's ``_mla_q`` / ``_mla_kv`` / ``_mla_out`` and ``moe.moe_ffn``; what
+those gained is decided by the configuration while the program is traced
+(``cfg.mla_q_scale`` / ``mla_kv_scale`` in ``_norm``, a ``bias`` handed to
+the softmax rule of ``router_topk``, ``cfg.zero_experts`` in both dispatches
+and in the width of the counts, ``cfg.attn_sublayers`` in the pool's leading
+dimension), and ``router_weights`` was split into ``router_topk`` and
+``_held_weights`` (the same ops in the same order), so with none of them
+set every older preset lowers the text it lowered.  (No ``@wide`` shape of
+the new preset: at 2 + 512 rows, top-4 of 24 scored, an expert's batch is
+384 rows, not the 256 that shape asserts.)
 """
 
 import hashlib
@@ -228,6 +245,10 @@ PINS = {
     "tiny-swa-sink-moe.seq_lp": "7db0abc59856f3c6",
     "tiny-swa-sink-moe.pipe": "ad254d3741195bb9",
     "tiny-swa-sink-moe.pipe_lp": "7616bbc242b77010",
+    "tiny-shortcut-mla-moe.seq": "36dbbfa823fa9cd2",
+    "tiny-shortcut-mla-moe.seq_lp": "91cc2ca9e2991c85",
+    "tiny-shortcut-mla-moe.pipe": "45cc309201ad916f",
+    "tiny-shortcut-mla-moe.pipe_lp": "7b8d25aeda014625",
 }
 
 
@@ -301,7 +322,7 @@ def whole_layer_hash() -> str:
         num_experts=x, num_experts_per_tok=2, router_width=x,
         expert_parallel_size=1, expert_parallel_rank=0,
         scoring_func="softmax", norm_topk_prob=True,
-        routed_scaling_factor=1.0, swiglu_limit=0.0)
+        routed_scaling_factor=1.0, swiglu_limit=0.0, zero_experts=0)
     assert moe._held_capacity(rows, cfg) == 128
 
     def leaf(k, n):
