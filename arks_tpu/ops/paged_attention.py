@@ -756,15 +756,17 @@ def _lanes(x: jnp.ndarray, n: int) -> jnp.ndarray:
 
 
 def _mixed_softmax_block(q_ref, kbuf, vbuf, ksbuf, vsbuf, m_ref, l_ref,
-                         acc_ref, buf, si, pos0, q_lo, *, page, scale,
-                         quantized, int4, h0=None, window=0):
+                         acc_ref, buf, si, pos0, q_lo, *, page, block_q,
+                         scale, quantized, int4, h0=None, window=0):
     """One page of online-softmax accumulation, the compute body of the
-    ragged mixed kernel.  ``h0`` (grouped items only) is the first KV head
-    of the item's group inside the scale buffers, which always hold the
-    page's whole head stripe.  ``window`` > 0 also masks the keys at or
-    below ``qpos - window``."""
-    _, hkv, g, bq, d = q_ref.shape
-    q = q_ref[0].reshape(hkv, g * bq, d)
+    ragged mixed kernel.  ``q_ref`` is the item's block as it arrived,
+    ``[1, Hkv, G x block_q, D]`` with the rows already merged (g-major):
+    nothing is laid out again here, once a page.  ``h0`` (grouped items
+    only) is the first KV head of the item's group inside the scale
+    buffers, which always hold the page's whole head stripe.  ``window`` >
+    0 also masks the keys at or below ``qpos - window``."""
+    hkv = q_ref.shape[1]
+    q = q_ref[0]                           # [Hkv, G*BQ, D]
     kt = kbuf[buf]
     if vbuf is None:
         # A latent page: the values are the row's first lanes, as wide as
@@ -785,7 +787,7 @@ def _mixed_softmax_block(q_ref, kbuf, vbuf, ksbuf, vsbuf, m_ref, l_ref,
         scores = scores * _group_heads(ksbuf[buf], h0, hkv)[:, None, :]
     # Row r of the G*BQ axis is query index r % BQ (g-major layout).
     row = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-    qpos = pos0 + q_lo + row % bq
+    qpos = pos0 + q_lo + row % block_q
     kvpos = si * page + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 2)
     keep = kvpos <= qpos
     if window:
@@ -945,9 +947,9 @@ def _paged_mixed_ragged_kernel(layer_ref, tables_ref, pos_start_ref,
     @pl.when(run_gate)
     def _run():
         if carry:
-            m_ref[:] = mi_ref[0].reshape(m_ref.shape)
-            l_ref[:] = li_ref[0].reshape(l_ref.shape)
-            acc_ref[:] = ai_ref[0].reshape(acc_ref.shape)
+            m_ref[:] = mi_ref[0]
+            l_ref[:] = li_ref[0]
+            acc_ref[:] = ai_ref[0]
         elif sink:
             m_ref[:] = sink_ref[pl.ds(h0, head_group)]
             l_ref[:] = jnp.ones_like(l_ref)
@@ -972,22 +974,22 @@ def _paged_mixed_ragged_kernel(layer_ref, tables_ref, pos_start_ref,
             wait_copies(buf)
             _mixed_softmax_block(q_ref, kbuf, vbuf, ksbuf, vsbuf, m_ref,
                                  l_ref, acc_ref, buf, si, pos0, q_lo,
-                                 page=page, scale=scale,
+                                 page=page, block_q=block_q, scale=scale,
                                  quantized=quantized, int4=int4,
                                  h0=h0 if grouped else None,
                                  window=window)
             return loop_c
 
         jax.lax.fori_loop(plo, npages, body, 0)
-        _, hg, g, bq, _ = q_ref.shape
-        d = acc_ref.shape[-1]
+        # The state's scratch and the blocks it leaves through have one
+        # layout, [head_group, G x block_q, .]: whole tiles, stored as held.
         if emit_state:
-            mo_ref[:] = m_ref[:].reshape(1, hg, g, bq, 128)
-            lo_ref[:] = l_ref[:].reshape(1, hg, g, bq, 128)
-            ao_ref[:] = acc_ref[:].reshape(1, hg, g, bq, d)
+            mo_ref[0] = m_ref[:]
+            lo_ref[0] = l_ref[:]
+            ao_ref[0] = acc_ref[:]
         else:
-            out = acc_ref[:] / (_lanes(l_ref[:], d) + 1e-9)
-            o_ref[:] = out.reshape(1, hg, g, bq, d).astype(o_ref.dtype)
+            out = acc_ref[:] / (_lanes(l_ref[:], acc_ref.shape[-1]) + 1e-9)
+            o_ref[0] = out.astype(o_ref.dtype)
 
 
 def _mixed_scratch(k_pool, v_pool, *, nbuf: int, head_group: int,
@@ -996,7 +998,10 @@ def _mixed_scratch(k_pool, v_pool, *, nbuf: int, head_group: int,
     """VMEM scratch of one mixed-attention work item: ``nbuf`` page
     buffers (one set where ``v_pool`` is None: a latent pool), the
     online-softmax state (the accumulator ``dv`` wide, ``d`` by default),
-    the DMA semaphores.  A value pool's buffers are as wide as the pool."""
+    the DMA semaphores.  A value pool's buffers are as wide as the pool.
+    The state is ``[head_group, G x block_q, .]``, the merged rows of the
+    launch's query, output and carried-state blocks
+    (:func:`_ragged_launch`): it is read from and stored to them as held."""
     kv_rows = k_pool.shape[3]            # page//2 byte rows for int4 pools
     scratch = [pltpu.VMEM((nbuf, head_group, kv_rows, d), k_pool.dtype)]
     if v_pool is not None:
@@ -1024,13 +1029,23 @@ def _ragged_launch(qp, k_pool, v_pool, tables32, pos32, work_list, layer,
                    window: int = 0, sink: jnp.ndarray | None = None):
     """The ragged work-list ``pallas_call``, one grid step per entry of
     ``work_list`` (:func:`build_mixed_work_list`).  ``qp`` holds the
-    queries in ``block_q``-row blocks, in one of two layouts that differ
-    only in the index map that finds an item's block:
+    queries in blocks of ``G x block_q`` MERGED rows, g-major (row ``r``
+    of a block is query ``r % block_q`` of head ``r // block_q``: the
+    order of the mask's ``row % block_q`` and of the sink's rows), so that
+    the kernel's block ``[1, head_group, G x block_q, D]`` is whole
+    sublane tiles whatever ``block_q`` is and the body lays nothing out
+    again (a ``[.., G, block_q, D]`` block puts each head's ``block_q``
+    rows in tiles of their own: 64 sixteenth-full tiles of a one-row
+    latent item, gathered at every page, a third of that launch's time on
+    a v5e; PERF.md, PR 55).  Two layouts that differ only in the index map
+    that finds an item's block:
 
-    - ``compact``: ``[NB, Hkv, G, block_q, D]``, block ``blk[i]`` — one
+    - ``compact``: ``[NB, Hkv, G x block_q, D]``, block ``blk[i]`` — one
       block per real (lane, q_block) pair, the flat batch's layout;
-    - per lane: ``[S, Hkv, G, qpad, D]``, block ``(seq[i], qb[i])`` — the
-      block a caller of :func:`paged_mixed_attention` brings.
+    - per lane: ``[S, Hkv, num_qb, G x block_q, D]``, block ``(seq[i],
+      qb[i])``, the q-block axis squeezed out of the kernel's view — what
+      :func:`_paged_mixed_call` lays out of a caller's ``[S, Hkv, G, Q,
+      D]``.
 
     The output (or, with ``emit_state``, the raw f32 m / l / acc) comes
     back in the layout ``qp`` has; ``carry_state`` is read through the
@@ -1052,7 +1067,8 @@ def _ragged_launch(qp, k_pool, v_pool, tables32, pos32, work_list, layer,
     item's softmax state starts from (the kernel's docstring); it is
     handed over once, ``[Hkv, G x block_q, 128]``, a block that never
     moves."""
-    lead, hkv, g, qrows, d = qp.shape
+    hkv, rows, d = qp.shape[1], qp.shape[-2], qp.shape[-1]
+    g = rows // block_q
     quantized = k_scale is not None
     page = pool_page_tokens(k_pool, k_scale)
     carry = carry_state is not None
@@ -1074,18 +1090,20 @@ def _ragged_launch(qp, k_pool, v_pool, tables32, pos32, work_list, layer,
         def q_map(i, layer_p, tables_p, pos_p, seq_p, hg_p, qb_p, plo_p,
                   pages_p, blk_p):
             del layer_p, tables_p, pos_p, seq_p, qb_p, plo_p, pages_p
-            return (blk_p[i], hg_p[i], 0, 0, 0)
+            return (blk_p[i], hg_p[i], 0, 0)
     else:
         def q_map(i, layer_p, tables_p, pos_p, seq_p, hg_p, qb_p, plo_p,
                   pages_p, blk_p):
             del layer_p, tables_p, pos_p, plo_p, pages_p, blk_p
-            return (seq_p[i], hg_p[i], 0, qb_p[i], 0)
+            return (seq_p[i], hg_p[i], qb_p[i], 0, 0)
 
-    # (The accumulator's blocks: ``o``'s shape, which is ``q``'s wherever
-    # the values are as wide as the keys.)
-    blk = dict(q=(1, head_group, g, block_q, d),
-               o=(1, head_group, g, block_q, dv),
-               ml=(1, head_group, g, block_q, 128))
+    # ONE block for the kernel, [1, head_group, G x block_q, width]: the
+    # per-lane layout's q-block axis is squeezed.  (The accumulator's
+    # blocks are ``o``'s, which is ``q``'s wherever the values are as wide
+    # as the keys.)
+    lead = (1, head_group) if compact else (1, head_group, None)
+    blk = dict(q=lead + (rows, d), o=lead + (rows, dv),
+               ml=lead + (rows, 128))
     carry_inputs, carry_specs = [], []
     if carry:
         # Carry arrays have the q layout's shape — exactly what a
@@ -1099,10 +1117,9 @@ def _ragged_launch(qp, k_pool, v_pool, tables32, pos32, work_list, layer,
         out_specs = (pl.BlockSpec(blk["ml"], q_map),
                      pl.BlockSpec(blk["ml"], q_map),
                      pl.BlockSpec(blk["o"], q_map))
-        out_shape = (
-            jax.ShapeDtypeStruct((lead, hkv, g, qrows, 128), jnp.float32),
-            jax.ShapeDtypeStruct((lead, hkv, g, qrows, 128), jnp.float32),
-            jax.ShapeDtypeStruct((lead, hkv, g, qrows, dv), jnp.float32))
+        out_shape = tuple(
+            jax.ShapeDtypeStruct(qp.shape[:-1] + (w,), jnp.float32)
+            for w in (128, 128, dv))
     else:
         out_specs = pl.BlockSpec(blk["o"], q_map)
         out_shape = jax.ShapeDtypeStruct(qp.shape[:-1] + (dv,), qp.dtype)
@@ -1168,20 +1185,29 @@ def _paged_mixed_call(q, k_pool, v_pool, tables, pos_start, q_len, layer,
     query block, with FULLY RESOLVED statics — the public wrapper resolves
     the plan (autotune) per call so changing the tune table between calls
     can never hit a stale jit cache entry keyed on unresolved defaults.
-    The q axis is padded to the plan's q blocks here and sliced back; the
-    grid visits the lanes' real blocks through the per-lane index map of
-    :func:`_ragged_launch` (the flat batch's block-compacted layout is
-    :func:`_paged_mixed_flat_call`)."""
-    _, hkv, _, qmax, _ = q.shape
+    The q axis is padded to the plan's q blocks and laid out in the
+    launch's merged rows here, ``[S, Hkv, num_qb, G x block_q, D]``
+    (:func:`_ragged_launch`), and the output laid back and sliced; the
+    grid visits the lanes' real blocks through the per-lane index map
+    (the flat batch's block-compacted layout is
+    :func:`_paged_mixed_flat_call`).  The raw state of ``emit_state`` is
+    handed out, and ``carry_state`` taken, AS THE LAUNCH HOLDS IT (``[S,
+    Hkv, num_qb, G x block_q, 128 | Dv]``): spans chain without a
+    transpose between them."""
+    s, hkv, g, qmax, d = q.shape
     page = pool_page_tokens(k_pool, k_scale)
     qpad = -(-qmax // block_q) * block_q
+    num_qb = qpad // block_q
     qp = q if qpad == qmax else jnp.pad(
         q, ((0, 0), (0, 0), (0, 0), (0, qpad - qmax), (0, 0)))
+    qp = jnp.transpose(qp.reshape(s, hkv, g, num_qb, block_q, d),
+                       (0, 1, 3, 2, 4, 5)).reshape(
+                           s, hkv, num_qb, g * block_q, d)
     tables32 = tables.astype(jnp.int32)
     pos32 = pos_start.astype(jnp.int32)
     qlen32 = q_len.astype(jnp.int32)
     work_list = build_mixed_work_list(
-        pos32, qlen32, page=page, block_q=block_q, num_qb=qpad // block_q,
+        pos32, qlen32, page=page, block_q=block_q, num_qb=num_qb,
         max_pages=tables.shape[1], head_groups=hkv // head_group,
         page_lo=page_lo, page_hi=page_hi)
     out = _ragged_launch(
@@ -1193,12 +1219,14 @@ def _paged_mixed_call(q, k_pool, v_pool, tables, pos_start, q_len, layer,
     # so the call returns the same bytes everywhere, not just on the rows
     # callers keep.
     if emit_state:
-        m, l, a = out
-        validp = (jnp.arange(qpad, dtype=jnp.int32)[None, :]
-                  < qlen32[:, None])[:, None, None, :, None]
-        return (jnp.where(validp, m, jnp.zeros_like(m)),
-                jnp.where(validp, l, jnp.zeros_like(l)),
-                jnp.where(validp, a, jnp.zeros_like(a)))
+        # Row r of block qb is query qb * block_q + r % block_q.
+        query = (jnp.arange(num_qb, dtype=jnp.int32)[:, None] * block_q
+                 + jnp.arange(g * block_q, dtype=jnp.int32)[None, :]
+                 % block_q)
+        validp = (query[None] < qlen32[:, None, None])[:, None, :, :, None]
+        return tuple(jnp.where(validp, x, jnp.zeros_like(x)) for x in out)
+    out = jnp.transpose(out.reshape(s, hkv, num_qb, g, block_q, -1),
+                        (0, 1, 3, 2, 4, 5)).reshape(s, hkv, g, qpad, -1)
     if qpad != qmax:
         out = out[..., :qmax, :]
     valid = jnp.arange(qmax, dtype=jnp.int32)[None, :] < qlen32[:, None]
@@ -1219,7 +1247,10 @@ def _paged_mixed_flat_call(q, k_pool, v_pool, tables, token_slot, q_start,
     """Jitted ragged launch over the FLAT batch's queries ``[T, Hkv, G,
     D]`` in the block-compacted layout: ``nb`` blocks of ``block_q`` rows,
     one per real (lane, q_block) pair (``nb`` is the plan's static bound
-    on them), filled by ONE gather from the flat rows and read back by ONE
+    on them), handed to the launch with a block's ``G x block_q`` rows
+    merged, ``[nb, Hkv, G x block_q, D]`` (:func:`_ragged_launch`: the
+    merge is a free reshape of the array the transpose has just written),
+    filled by ONE gather from the flat rows and read back by ONE
     gather of T rows (a per-lane layout gives every lane room for the
     widest chunk any lane could have: ``lanes x qmax`` rows for the same
     ``T``).  The grid is ``nb x head groups`` long: the
@@ -1243,14 +1274,16 @@ def _paged_mixed_flat_call(q, k_pool, v_pool, tables, token_slot, q_start,
         token_slot, q_start, qlen32, block_q=block_q, nb=nb)
     qb = jnp.take(q, src_rows, axis=0).reshape(nb, block_q, hkv, g, d)
     out = _ragged_launch(
-        jnp.transpose(qb, (0, 2, 3, 1, 4)), k_pool, v_pool,
+        jnp.transpose(qb, (0, 2, 3, 1, 4)).reshape(
+            nb, hkv, g * block_q, d), k_pool, v_pool,
         tables.astype(jnp.int32), pos32, work_list, layer, k_scale, v_scale,
         compact=True, block_q=block_q, dma_depth=dma_depth,
         interpret=interpret, head_group=head_group, latent_v=latent_v,
         scale=scale, window=window, sink=sink)
     # Straight out of the kernel's layout by (block, row): a transpose to
     # row-major first would copy the whole output once more.
-    flat = out[out_rows // block_q, :, :, out_rows % block_q]
+    flat = out.reshape(nb, hkv, g, block_q, -1)[
+        out_rows // block_q, :, :, out_rows % block_q]
     return jnp.where((token_slot >= 0)[:, None, None, None], flat,
                      jnp.zeros_like(flat))
 
@@ -1284,7 +1317,8 @@ def paged_mixed_attention(
     Span-bounded calls (page_lo/page_hi + carry_state/emit_state) chain
     the online-softmax state across page ranges — the windowed-residency
     building block.  With emit_state the return is the raw f32
-    (m, l, acc) triple (q axis padded to the plan's qpad) instead of the
+    (m, l, acc) triple as the launch holds it (``[S, Hkv, num_qb, G x
+    block_q, .]``, the q axis padded to the plan's blocks) instead of the
     normalized output; feeding it back as carry_state on the next span
     and finishing with emit_state=False reproduces the single-call
     result bitwise."""

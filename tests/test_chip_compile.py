@@ -648,6 +648,34 @@ def test_a_step_writes_no_buffer_of_a_qkv_leaf(chip, monkeypatch, name, rows,
     assert compiled.memory_analysis().temp_size_in_bytes < temp_mb * 1e6
 
 
+@pytest.mark.parametrize("name", ["mimo-v2.5-ep16-l13",
+                                  "longcat-flash-ep32-l6"])
+def test_a_one_row_steps_launch_is_read_back_through_no_copy(
+        chip, monkeypatch, name):
+    """The 64-row step (every lane one row, the pipelined program's shape)
+    of mimo's share and of longcat's: the ragged launch hands back ``[nb,
+    Hkv, G x block_q, Dv]`` in whole ``T(8,128)`` tiles (PR 55: the blocks'
+    rows arrive merged), and what reads it back by (block, row) reads it
+    through a bitcast.  Until then the launch wrote ``[nb, Hkv, G, 1, Dv]``
+    in ``T(2,128)`` tiles, one row to a tile, and every launch of the step
+    was followed by a ``copy`` (mimo) or a ``copy_bitcast_fusion``
+    (longcat) of its whole output into the tiles the gather reads."""
+    import re
+    _, _, compiled = _share_step(chip, monkeypatch, name, 0)
+    lines = list(_outside_fusions(compiled.as_text()))
+    launches = [m.group(1, 2) for line in lines for m in [re.match(
+        r"\s*%([\w.\-]*attention_ragged[\w.\-]*) = (\S+) custom-call\(",
+        line)] if m]
+    assert launches
+    for out, shape in launches:
+        assert "T(8,128)" in shape and shape.count(",") >= 3, shape
+        users = [line.strip() for line in lines
+                 if re.search(rf"%{re.escape(out)}\b", line)
+                 and not re.match(rf"\s*%{re.escape(out)} =", line)]
+        assert users and all(re.match(r"%bitcast[\w.\-]* =", u)
+                             for u in users), users
+
+
 def test_the_scan_for_written_buffers_sees_the_copies_it_is_there_for():
     """``_unfused_s8`` on lines of the parent's compiled mimo step (PR 47):
     the whole-leaf transpose in ``main`` and the slice in the inner scan's

@@ -313,6 +313,17 @@ def _flat_pools(kv):
     return q, kp, vp, kps, vps, tables
 
 
+def _lane_block(q, q_start):
+    """The per-lane ``[S, Hkv, G, qmax, D]`` block of a flat batch ``[T,
+    Hkv, G, D]`` (``qmax = T - S + 1``, the flat call's own bound)."""
+    import jax.numpy as jnp
+    t_flat, lanes = q.shape[0], q_start.shape[0]
+    qmax = t_flat - lanes + 1
+    span = np.minimum(q_start[:, None] + np.arange(qmax)[None], t_flat - 1)
+    return jnp.transpose(q[span.reshape(-1)].reshape(
+        lanes, qmax, *q.shape[1:]), (0, 2, 3, 1, 4))
+
+
 @pytest.mark.parametrize("head_group", [None, 1])
 @pytest.mark.parametrize("kv", ["int8", "bf16"])
 @pytest.mark.parametrize("batch", _BATCHES)
@@ -322,7 +333,6 @@ def test_compacted_layout_bytes_match_per_lane(batch, kv, head_group):
         paged_mixed_attention, paged_mixed_attention_flat)
     q, kp, vp, kps, vps, tables = _flat_pools(kv)
     token_slot, q_start, q_len, pos = _flat_batch(batch)
-    t_flat = token_slot.shape[0]
     args = (jnp.asarray(token_slot), jnp.asarray(q_start),
             jnp.asarray(q_len), jnp.asarray(pos))
     kw = dict(k_scale=kps, v_scale=vps, block_q=_BQ, interpret=True)
@@ -331,12 +341,8 @@ def test_compacted_layout_bytes_match_per_lane(batch, kv, head_group):
         **kw).astype(jnp.float32))
     # The per-lane call over the [S, Hkv, G, qmax, D] block of the same
     # batch.
-    qmax = t_flat - _LANES + 1
-    span = np.minimum(q_start[:, None] + np.arange(qmax)[None], t_flat - 1)
-    block = jnp.transpose(q[span.reshape(-1)].reshape(
-        _LANES, qmax, *q.shape[1:]), (0, 2, 3, 1, 4))
     lane = np.asarray(paged_mixed_attention(
-        block, kp, vp, tables, args[3], args[2], 1,
+        _lane_block(q, q_start), kp, vp, tables, args[3], args[2], 1,
         head_group=head_group, **kw).astype(jnp.float32))
     real = token_slot >= 0
     assert np.isfinite(got).all()
@@ -509,3 +515,253 @@ def test_q_layout_rows_counter_follows_the_plan(monkeypatch):
     live = sum(s for _, s, _ in eng.metrics.mixed_batch_tokens._data.values())
     got = eng.metrics.mixed_kv_write_blocks_total.total()
     assert got == want and n_dispatches <= got < live
+
+
+# ---------------------------------------------------------------------------
+# The launch's blocks arrive with their G x block_q rows merged (PR 55)
+# ---------------------------------------------------------------------------
+#
+# The query, output and carried-state blocks of the ragged launch are
+# [.., G x block_q, width], g-major, where they were [.., G, block_q, width]:
+# a layout, not an algorithm, so every row's bytes are the parent commit's.
+# Each case runs in interpret mode against a dense float64 masked softmax
+# (what the XLA gather oracle computes) AND against the sha256 the parent
+# commit a0b2fc9 gave for the same seeded inputs.  The digests were taken on
+# this image's XLA CPU build; `_ORACLE_SHA` is the control that says the
+# platform still rounds as it did then: the digest of a plain jax.numpy
+# masked softmax over the case's own inputs, no kernel in it.  Where that
+# one moves, the stored bytes say nothing about the kernel and only the
+# oracle comparison is held.
+
+_MERGED_SHAPES = [(64, 1), (64, 8), (7, 8), (6, 1), (16, 1)]
+_MERGED_KINDS = ["latent", "int8", "window", "sink", "lane_int8", "chain"]
+
+
+def _merged_inputs(g, block_q, kind):
+    """Three lanes: a decode row deep in its third page, a chunk of two
+    blocks and a row that starts inside its first page, an idle lane."""
+    import jax
+    import jax.numpy as jnp
+    latent = kind == "latent"
+    quant = kind in ("int8", "sink", "lane_int8", "chain")
+    page = 128 if quant else 16
+    hkv, d, dv = (1, 48, 32) if latent else (2, 32, 32)
+    lanes, max_pages, chunk = 3, 3, 2 * block_q + 1
+    n = lanes * max_pages + 1
+    ks = jax.random.split(jax.random.PRNGKey(55 + 7 * g + block_q), 7)
+    if quant:
+        kp = jax.random.randint(ks[0], (2, n, hkv, page, d), -127, 128,
+                                jnp.int8)
+        vp = jax.random.randint(ks[1], (2, n, hkv, page, dv), -127, 128,
+                                jnp.int8)
+        kps = jax.random.uniform(ks[2], (2, n, hkv, page), jnp.float32,
+                                 0.01, 0.03)
+        vps = jax.random.uniform(ks[3], (2, n, hkv, page), jnp.float32,
+                                 0.01, 0.03)
+    else:
+        kp = jax.random.normal(ks[0], (2, n, hkv, page, d), jnp.bfloat16)
+        vp = None if latent else jax.random.normal(
+            ks[1], (2, n, hkv, page, dv), jnp.bfloat16)
+        kps = vps = None
+    tables = jax.random.permutation(ks[4], n)[:lanes * max_pages].reshape(
+        lanes, max_pages).astype(jnp.int32)
+    t_flat = lanes + chunk
+    q = jax.random.normal(ks[5], (t_flat, hkv, g, d), jnp.bfloat16)
+    token_slot = np.full((t_flat,), -1, np.int32)
+    q_start = np.zeros((lanes,), np.int32)
+    q_len = np.zeros((lanes,), np.int32)
+    pos = np.zeros((lanes,), np.int32)
+    token_slot[0], q_start[0], q_len[0], pos[0] = 0, 0, 1, 2 * page + 5
+    token_slot[1:1 + chunk] = 2
+    q_start[2], q_len[2], pos[2] = 1, chunk, page - 3
+    sink = jax.random.normal(ks[6], (hkv, g), jnp.float32) \
+        if kind == "sink" else None
+    return dict(q=q, kp=kp, vp=vp, kps=kps, vps=vps, tables=tables,
+                token_slot=token_slot, q_start=q_start, q_len=q_len, pos=pos,
+                window=(page + 9 if kind in ("window", "sink") else 0),
+                sink=sink, latent_v=dv if latent else 0, page=page)
+
+
+def _merged_run(pa, g, block_q, kind):
+    """What module ``pa``'s launch hands back for a case, as float32 arrays
+    (the chain: the final output, then the raw state as ``pa`` holds it)."""
+    import jax.numpy as jnp
+    c = _merged_inputs(g, block_q, kind)
+    lane = tuple(jnp.asarray(c[k]) for k in ("pos", "q_len"))
+    kw = dict(k_scale=c["kps"], v_scale=c["vps"], block_q=block_q,
+              interpret=True)
+    if kind in ("lane_int8", "chain"):
+        block = _lane_block(c["q"], c["q_start"])
+        if kind == "lane_int8":
+            out = [pa.paged_mixed_attention(
+                block, c["kp"], c["vp"], c["tables"], *lane, 1,
+                head_group=1, **kw)]
+        else:
+            split = jnp.asarray([2, 0, 1], jnp.int32)
+            state = pa.paged_mixed_attention(
+                block, c["kp"], c["vp"], c["tables"], *lane, 1,
+                page_hi=split, emit_state=True, **kw)
+            out = pa.paged_mixed_attention(
+                block, c["kp"], c["vp"], c["tables"], *lane, 1,
+                page_lo=split, carry_state=state, **kw)
+            out = [out, *state]
+    else:
+        out = [pa.paged_mixed_attention_flat(
+            c["q"], c["kp"], c["vp"], c["tables"],
+            *(jnp.asarray(c[k]) for k in ("token_slot", "q_start", "q_len",
+                                          "pos")),
+            1, latent_v=c["latent_v"],
+            scale=0.2 if kind == "latent" else None, window=c["window"],
+            sink=c["sink"], **kw)]
+    return c, [np.asarray(x.astype(jnp.float32)) for x in out]
+
+
+def _state_as_the_parent_held_it(x, g, block_q):
+    """Raw state ``[S, Hkv, num_qb, G x block_q, W]`` as the ``[S, Hkv, G,
+    qpad, W]`` the parent's launch handed out: the digests' layout."""
+    s, hkv, num_qb, _, w = x.shape
+    return np.transpose(x.reshape(s, hkv, num_qb, g, block_q, w),
+                        (0, 1, 3, 2, 4, 5)).reshape(s, hkv, g, -1, w)
+
+
+def _merged_oracle(c, kind, xp):
+    """[T, Hkv, G, Dv]: every real flat row's masked softmax over its own
+    lane's pages, dense; ``xp`` is numpy (float64: the oracle) or jax.numpy
+    (float32: the platform control)."""
+    f = np.float64 if xp is np else np.float32
+    page, tables = c["page"], np.asarray(c["tables"])
+    kp = xp.asarray(c["kp"][1]).astype(f)
+    if c["kps"] is not None:
+        kp = kp * xp.asarray(c["kps"][1]).astype(f)[..., None]
+        vp = xp.asarray(c["vp"][1]).astype(f) * xp.asarray(
+            c["vps"][1]).astype(f)[..., None]
+    elif c["vp"] is None:
+        vp = kp[..., :c["latent_v"]]
+    else:
+        vp = xp.asarray(c["vp"][1]).astype(f)
+    q = xp.asarray(c["q"]).astype(f)
+    scale = 0.2 if kind == "latent" else q.shape[-1] ** -0.5
+    rows = []
+    for t, s in enumerate(c["token_slot"]):
+        if s < 0:
+            rows.append(xp.zeros(q.shape[1:3] + (vp.shape[-1],), f))
+            continue
+        p = c["pos"][s] + t - c["q_start"][s]
+        k = xp.concatenate([kp[i] for i in tables[s]], axis=1)   # [Hkv,S,D]
+        v = xp.concatenate([vp[i] for i in tables[s]], axis=1)
+        kv = np.arange(k.shape[1])
+        keep = (kv <= p) & ((kv > p - c["window"]) if c["window"] else True)
+        sc = xp.einsum("hgd,hsd->hgs", q[t], k) * scale
+        sc = xp.where(xp.asarray(keep)[None, None], sc, -1e30)
+        if c["sink"] is not None:
+            sc = xp.concatenate(
+                [sc, xp.asarray(c["sink"]).astype(f)[..., None]], axis=-1)
+        w = xp.exp(sc - sc.max(axis=-1, keepdims=True))
+        w = (w / w.sum(axis=-1, keepdims=True))[..., :k.shape[1]]
+        rows.append(xp.einsum("hgs,hsv->hgv", w, v))
+    return xp.stack(rows)
+
+
+def _sha(arrays):
+    import hashlib
+    h = hashlib.sha256()
+    for a in arrays:
+        h.update(np.ascontiguousarray(a, np.float32).tobytes())
+    return h.hexdigest()[:16]
+
+
+_PARENT_SHA = {
+    "latent-g64-bq1": "e7ef120ee874dc4e",
+    "int8-g64-bq1": "1acf30d90d9117ec",
+    "window-g64-bq1": "c34f417ccae7ccea",
+    "sink-g64-bq1": "864b0a077db0e94f",
+    "lane_int8-g64-bq1": "efb7f7725f791294",
+    "chain-g64-bq1": "d6ad34b2008f8661",
+    "latent-g64-bq8": "b338345920633c23",
+    "int8-g64-bq8": "6956fd4525abe60c",
+    "window-g64-bq8": "eefdfc8c8ff72a56",
+    "sink-g64-bq8": "d045395c9d0688e9",
+    "lane_int8-g64-bq8": "38ee247a2b7d5670",
+    "chain-g64-bq8": "f64a939f87f9e139",
+    "latent-g7-bq8": "9b035ce01a176ec3",
+    "int8-g7-bq8": "9033c7787f94fea0",
+    "window-g7-bq8": "b8864bbf081bc980",
+    "sink-g7-bq8": "547086f11149c8ad",
+    "lane_int8-g7-bq8": "c0a0ae523443b92e",
+    "chain-g7-bq8": "1512eddf476545ac",
+    "latent-g6-bq1": "e6377c1b2fe1ac3d",
+    "int8-g6-bq1": "f3e52cc1fd415bb1",
+    "window-g6-bq1": "62b0cab1f1958d27",
+    "sink-g6-bq1": "e75ff3546ab267c5",
+    "lane_int8-g6-bq1": "d4fdddd5485a5044",
+    "chain-g6-bq1": "fc4c505b73619e5e",
+    "latent-g16-bq1": "e6e86a2bd0414b3c",
+    "int8-g16-bq1": "fc79576f0ddd7e38",
+    "window-g16-bq1": "13a27055d96fd176",
+    "sink-g16-bq1": "6c0940ec4e3e8c6b",
+    "lane_int8-g16-bq1": "ee553174c265a4d6",
+    "chain-g16-bq1": "93c1dc55f44dd789",
+}
+_ORACLE_SHA = {
+    "latent-g64-bq1": "21adb8ed4f39390c",
+    "int8-g64-bq1": "0154a3543e9b8b50",
+    "window-g64-bq1": "382bb82a3cd477c1",
+    "sink-g64-bq1": "cb6e2c908fbe3f70",
+    "lane_int8-g64-bq1": "0154a3543e9b8b50",
+    "chain-g64-bq1": "0154a3543e9b8b50",
+    "latent-g64-bq8": "ed148bd43a0dc517",
+    "int8-g64-bq8": "b3ff8bab7415cdb2",
+    "window-g64-bq8": "2894fa50c63ccb17",
+    "sink-g64-bq8": "9a439db619913028",
+    "lane_int8-g64-bq8": "b3ff8bab7415cdb2",
+    "chain-g64-bq8": "b3ff8bab7415cdb2",
+    "latent-g7-bq8": "a2f058faf72f79e0",
+    "int8-g7-bq8": "f736f58fad063d25",
+    "window-g7-bq8": "b1ee5e183ff3c654",
+    "sink-g7-bq8": "569b1621c63a6aa9",
+    "lane_int8-g7-bq8": "f736f58fad063d25",
+    "chain-g7-bq8": "f736f58fad063d25",
+    "latent-g6-bq1": "68b8e0e53ed794e9",
+    "int8-g6-bq1": "9c9a4f20289d4940",
+    "window-g6-bq1": "b37ed30a4b26aa56",
+    "sink-g6-bq1": "86a62f2448a4328b",
+    "lane_int8-g6-bq1": "9c9a4f20289d4940",
+    "chain-g6-bq1": "9c9a4f20289d4940",
+    "latent-g16-bq1": "7b0deb1cb9784b71",
+    "int8-g16-bq1": "9ddf2066a2eb3eee",
+    "window-g16-bq1": "13fe67f5eace0bae",
+    "sink-g16-bq1": "0de5c090a8d6da6c",
+    "lane_int8-g16-bq1": "9ddf2066a2eb3eee",
+    "chain-g16-bq1": "9ddf2066a2eb3eee",
+}
+
+
+@pytest.mark.parametrize("kind", _MERGED_KINDS)
+@pytest.mark.parametrize("g,block_q", _MERGED_SHAPES)
+def test_merged_rows_block_is_the_parents_bytes(g, block_q, kind):
+    import jax.numpy as jnp
+    from arks_tpu.ops import paged_attention as pa
+    c, got = _merged_run(pa, g, block_q, kind)
+    want = _merged_oracle(c, kind, np)
+    out = got[0]
+    real = c["token_slot"] >= 0
+    if kind in ("lane_int8", "chain"):
+        # [S, Hkv, G, qmax, Dv] per lane -> the flat rows.
+        flat = np.zeros(want.shape, np.float32)
+        for t in np.flatnonzero(real):
+            s = c["token_slot"][t]
+            flat[t] = out[s, :, :, t - c["q_start"][s]]
+        rows = np.arange(out.shape[3])[None] < c["q_len"][:, None]
+        np.testing.assert_array_equal(
+            out[~np.broadcast_to(rows[:, None, None, :, None], out.shape)],
+            0.0)
+        out = flat
+    assert np.isfinite(out).all() and np.abs(out[real]).max() > 0
+    np.testing.assert_array_equal(out[~real], 0.0)
+    np.testing.assert_allclose(out[real], want[real], atol=3e-2, rtol=3e-2)
+    key = f"{kind}-g{g}-bq{block_q}"
+    control = _sha([np.asarray(_merged_oracle(c, kind, jnp))])
+    if control == _ORACLE_SHA[key]:
+        got[1:] = [_state_as_the_parent_held_it(x, g, block_q)
+                   for x in got[1:]]
+        assert _sha(got) == _PARENT_SHA[key], key

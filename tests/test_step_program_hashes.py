@@ -197,6 +197,24 @@ dimension), and ``router_weights`` was split into ``router_topk`` and
 set every older preset lowers the text it lowered.  (No ``@wide`` shape of
 the new preset: at 2 + 512 rows, top-4 of 24 scored, an expert's batch is
 384 rows, not the 256 that shape asserts.)
+
+PR 55 moved NONE of the thirty-eight nor the whole-layer pin (ISSUE 55
+expected every program that holds a ragged launch to move; they stand, for
+PR 45's reason).  It changed the LAYOUT of the ragged launch's query, output
+and carried-state blocks (``ops/paged_attention.py::_ragged_launch``: a
+block's ``G x block_q`` rows arrive merged, ``[.., G x block_q, D]``, where
+the kernel merged a ``[.., G, block_q, D]`` block once a page).  An engine on
+the CPU that nobody steers resolves to the XLA attention branch, so none of
+these programs ever carried the launch or the gathers around it, and no
+line of them is traced through the changed functions.  What holds the launch
+itself: ``tests/test_ragged_grid.py::
+test_merged_rows_block_is_the_parents_bytes`` (thirty cases against a dense
+oracle and, byte for byte, against digests the parent commit a0b2fc9 gave
+for the same seeded inputs), the older kernel suites of
+``tests/test_paged_attention.py``, and ``tests/test_chip_compile.py`` (the
+chip's compiler takes the merged block at every cell's shape; in longcat's
+compiled step the launch's output is read through a bitcast where the
+parent's step copied it out of ``T(2,128)`` tiles).
 """
 
 import hashlib
