@@ -819,6 +819,12 @@ class EngineMetrics:
             "kernel's list; chunk: a lane of more rows, the chunked scan; "
             "idle: no row, the state neither read nor written), a model "
             "with linear-attention layers")
+        self.ssm_rows_total = r.counter(
+            "ssm_rows_total",
+            "Rows of mixed dispatches by the path they took through a "
+            "state-space layer's state update (step: the row of a lane of "
+            "one row, the one-step kernel; scan: the rows of a lane of "
+            "more, the chunked scan), a model with state-space layers")
         self.mixed_kv_bytes_ideal_total = r.counter(
             "mixed_kv_bytes_ideal_total",
             "KV bytes a perfect once-per-page schedule would stream for "
@@ -1145,10 +1151,10 @@ def _kv_page(cfg: ModelConfig) -> str:
     and V per KV head ("kv"), or ONE latent row a token (latent attention),
     which is key and value at once ("latent").  Beside its pages:
     "+window", a second pool, the window layers' pages released behind the
-    window; "+state", a fixed recurrent state a slot (linear-attention
-    layers)."""
+    window; "+state", a fixed recurrent state a slot (recurrent layers:
+    linear attention's, or a state-space mixer's)."""
     return ("latent" if cfg.latent else "kv") + (
-        "+window" if cfg.windowed else "+state" if cfg.linear else "")
+        "+window" if cfg.windowed else "+state" if cfg.recurrent else "")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1184,17 +1190,19 @@ _BLOCKS = {
         "every layer's page of one pool",
         "layers of two head counts have no sharding rules",
         no_index="window pages would be gone"),
-    # The GQA layers keep pages, the linear layers a fixed state a slot
+    # The GQA layers keep pages, the RECURRENT layers (linear attention's
+    # delta rule, a state-space mixer's selective scan: ``{recurrent}`` is
+    # the kind's name, ``cfg.recurrent_kind``) a fixed state a slot
     # (transformer.py::LinearState) that the step program rewrites in
-    # place: a sequence's linear layers have no page, and their state is
+    # place: a sequence's recurrent layers have no page, and their state is
     # not carried.  A prefix hit would need the state AT the prefix's end,
     # which nobody kept.
     "kv+state": _Block(
-        "linear-attention layers with a fixed state a slot beside GQA "
+        "{recurrent} layers with a fixed state a slot beside GQA "
         "layers over pages",
         "keeps K and V of every layer",
         "every layer's page and no recurrent state",
-        "linear-attention layers and their state have no sharding rules",
+        "{recurrent} layers and their state have no sharding rules",
         no_index="recurrent state at its end is not kept"),
     # A bf16 latent pool beside the state: the movers speak K and V blocks
     # and carry neither.
@@ -1540,7 +1548,7 @@ class InferenceEngine:
         # a share's overflow tiles needed and looped, valid rows; a fifth
         # ahead of the rows where the routers score identity experts):
         # _count_held.
-        self._held_stat = bool((cfg.latent or cfg.windowed or cfg.linear)
+        self._held_stat = bool((cfg.latent or cfg.windowed or cfg.recurrent)
                                and cfg.num_experts)
         # Per-model KV dtype preference: a checkpoint that ships
         # kv_cache_dtype in its ModelConfig wins over the engine's "auto"
@@ -1726,7 +1734,7 @@ class InferenceEngine:
             # model with window layers register any
             # (_register_prompt_pages).
             if (engine_cfg.prefix_cache_mb and self._chunk
-                    and not cfg.windowed and not cfg.linear):
+                    and not cfg.windowed and not cfg.recurrent):
                 extra = max(engine_cfg.prefix_cache_mb * 2**20 // page_bytes, 0)
                 # The byte budget is tuned for 7B-class pools; cap by
                 # proportion so tiny test models don't allocate huge pools.
@@ -1778,7 +1786,7 @@ class InferenceEngine:
             self._cache = self._init_paged_cache(num_pages, dtype)
             if mesh is not None:
                 self._cache = self._shard_paged(self._cache)
-            if cfg.linear:
+            if cfg.recurrent:
                 self._lin_slot_bytes = self._cache.lin.slot_bytes
             self._alloc = PageAllocator(num_pages, page)
             self._tables = np.zeros((engine_cfg.num_slots, max_pages),
@@ -1804,7 +1812,7 @@ class InferenceEngine:
                          self._cache.win.token_bytes, cfg.num_window_layers,
                          cfg.num_full_layers)
             if self._lin_slot_bytes:
-                log.info("linear layers: %d bytes of %s state a slot over "
+                log.info("recurrent layers: %d bytes of %s state a slot over "
                          "%d layers (%d slots, %.2f GB, whatever the "
                          "context); the pool above holds the %d other "
                          "layers",
@@ -2025,10 +2033,10 @@ class InferenceEngine:
                 f"(resolved kv_layout={'paged' if self._paged else 'slot'}, "
                 f"prefill_chunk={self._chunk or None}, "
                 f"ARKS_MIXED_STEP={_mx})")
-        if (cfg.latent or cfg.windowed or cfg.linear) and not self._mixed:
+        if (cfg.latent or cfg.windowed or cfg.recurrent) and not self._mixed:
             raise ValueError(
                 f"model {cfg.name!r} (latent attention, window layers or "
-                "linear-attention layers) is served by the "
+                "recurrent layers) is served by the "
                 "mixed scheduler only; the legacy scheduler speaks K and V "
                 f"(resolved kv_layout={'paged' if self._paged else 'slot'}, "
                 f"prefill_chunk={self._chunk or None}, "
@@ -3315,7 +3323,7 @@ class InferenceEngine:
         model with window layers gets its window pool beside."""
         win = ({"win_pages": self._win.alloc.num_pages}
                if self._win is not None else
-               {"state_slots": self.ecfg.num_slots} if self.cfg.linear
+               {"state_slots": self.ecfg.num_slots} if self.cfg.recurrent
                else {})
         return tf.init_paged_cache(
             self.cfg, num_pages, self._page_size(), self._cache_dtype(dtype),
@@ -3421,15 +3429,19 @@ class InferenceEngine:
         m.kv_held_byte_steps_total.inc(state, kind="state")
         m.kv_held_byte_steps_total.inc(pages, kind="pages")
 
-    def _count_state_lanes(self, step: int, chunk: int) -> None:
+    def _count_state_lanes(self, step: int, chunk: int,
+                           scan_rows: int = 0) -> None:
         """Beside :meth:`_count_state_bytes`, once the dispatch's rows a
         slot are known: how many slots the state update's kernel steps
-        (``step``: one row), how many the chunked scan walks (``chunk``),
-        and the rest, which neither touches."""
+        (``step``: one row), how many the chunked scan walks (``chunk``,
+        ``scan_rows`` rows in all), and the rest, which neither touches."""
         c = self.metrics.linear_state_lane_steps_total
         c.inc(step, path="step")
         c.inc(chunk, path="chunk")
         c.inc(self.ecfg.num_slots - step - chunk, path="idle")
+        if self.cfg.ssm:
+            self.metrics.ssm_rows_total.inc(step, path="step")
+            self.metrics.ssm_rows_total.inc(scan_rows, path="scan")
 
     def _resolve_kv_layout(self) -> bool:
         layout = self.ecfg.kv_layout
@@ -5385,6 +5397,7 @@ class InferenceEngine:
               if self.cfg.windowed else ()),
             *((self.cfg.num_full_layers, "linear")
               if self.cfg.linear else ()),
+            *((self.cfg.num_full_layers, "ssm") if self.cfg.ssm else ()),
             self.cfg.num_kv_heads, self._page_bytes,
             self.ecfg.kv_quantized, self.ecfg.kv_bits,
             self.ecfg.resolve_kv_cache_dtype()))
@@ -8885,6 +8898,9 @@ class InferenceEngine:
         block = _BLOCKS.get(_kv_page(cfg))
         if block is None:
             return
+        block = dataclasses.replace(block, **{
+            f: getattr(block, f).format(recurrent=cfg.recurrent_kind)
+            for f in ("what", "mesh_why")})
         if block.latent_page and ecfg.kv_cache_dtype == "auto":
             ecfg.kv_cache_dtype = "bf16"
         if ecfg.kv_layout == "auto":
@@ -9039,7 +9055,8 @@ class InferenceEngine:
         if self._lin_slot_bytes:
             q_len = a["seq_q_len"]
             self._count_state_lanes(int((q_len == 1).sum()),
-                                    int((q_len > 1).sum()))
+                                    int((q_len > 1).sum()),
+                                    int(q_len[q_len > 1].sum()))
         if sec:
             self.trace.evt("", tag + "count", "E")
 

@@ -188,6 +188,30 @@ class ModelConfig:
     router_select_bias: bool = False
     mla_q_scale: float = 1.0
     mla_kv_scale: float = 1.0
+    # The one-sublayer block (``nemotron_h``; ``layer_pattern`` "" = every
+    # layer a mixer AND an FFN).  A layer is ONE sublayer under one norm and
+    # one residual, ``h + Mixer(N(h))``, chosen by its character of
+    # ``layer_pattern`` (the published ``hybrid_override_pattern``, whole):
+    # ``M`` a Mamba-2 state-space mixer (arXiv:2405.21060): ``ssm_num_heads``
+    # heads of ``ssm_head_dim`` behind one input projection (z | x B C | dt)
+    # and a causal depthwise convolution with bias over the last ``ssm_conv``
+    # positions of x | B | C, ONE decay a head a token, a float32 state
+    # ``[ssm_head_dim, ssm_state_size]`` a head a sequence, B and C of
+    # ``ssm_groups`` groups (head h reads group h // (heads / groups)), the
+    # gate ahead of a grouped RMS norm; ``*`` a GQA layer (``use_rope``
+    # False: no position signal); ``E`` the routed FFN alone.  The periods
+    # (a run of layers closed by a ``*`` and the ``E`` behind it) need not
+    # be equal (:meth:`pattern_walk`).  ``expert_act`` "relu2": an expert is
+    # TWO matrices, ``relu(x W_up)^2 W_down`` (no gate matrix), the shared
+    # expert too, ``moe_shared_expert_intermediate_size`` wide and ungated.
+    layer_pattern: str = ""
+    ssm_num_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state_size: int = 0
+    ssm_groups: int = 0
+    ssm_conv: int = 4
+    expert_act: str = "swiglu"
+    moe_shared_expert_intermediate_size: int = 0
 
     @property
     def q_dim(self) -> int:
@@ -200,6 +224,55 @@ class ModelConfig:
     @property
     def linear(self) -> bool:
         return self.linear_period > 0
+
+    @property
+    def ssm(self) -> bool:
+        """Layers of ONE sublayer, Mamba-2 mixers among them."""
+        return bool(self.layer_pattern)
+
+    @property
+    def recurrent(self) -> bool:
+        """A slot keeps a fixed state beside its pages, whatever the
+        context: the delta rule's (``linear``) or the selective scan's
+        (``ssm``)."""
+        return self.linear or self.ssm
+
+    @property
+    def recurrent_kind(self) -> str:
+        """What the layers that keep a state a slot are called, in a
+        refusal's words."""
+        return "state-space" if self.ssm else "linear-attention"
+
+    @property
+    def ssm_dim(self) -> int:
+        """Width of a Mamba-2 mixer's x (and of its gate z) over its heads."""
+        return self.ssm_num_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels of a Mamba-2 mixer's short convolution: x | B | C."""
+        return self.ssm_dim + 2 * self.ssm_groups * self.ssm_state_size
+
+    def pattern_walk(self) -> tuple:
+        """``layer_pattern`` as the step walks it, runs of equal things
+        folded: ``((period, times), ..)`` in model order, a period a run of
+        layers closed by a ``*`` and the ``E`` layers behind it (the tail
+        has no ``*``), itself ``((run, times), ..)`` with ``run`` an ``ME``
+        pair or one layer.  Nemotron-3-Nano's 52 layers: five periods
+        ``(ME x 2) M * E``, one ``(ME x 3) M * E``, the tail ``ME x 4``."""
+        import re
+
+        def runs(items) -> tuple:
+            out: list = []
+            for item in items:
+                if out and out[-1][0] == item:
+                    out[-1][1] += 1
+                else:
+                    out.append([item, 1])
+            return tuple((item, n) for item, n in out)
+
+        return runs(runs(re.findall(r"ME|.", period)) for period in
+                    re.findall(r"[^*]*\*E*|[^*]+$", self.layer_pattern))
 
     @property
     def linear_dim(self) -> int:
@@ -270,6 +343,8 @@ class ModelConfig:
     @property
     def num_linear_layers(self) -> int:
         """Layers that keep a fixed state a sequence and no page."""
+        if self.ssm:
+            return self.layer_pattern.count("M")
         if not self.linear:
             return 0
         return self.num_periods * self.linear_period + self.inner_tail \
@@ -279,6 +354,8 @@ class ModelConfig:
     @property
     def num_full_layers(self) -> int:
         """Layers that keep every page of a sequence."""
+        if self.ssm:
+            return self.layer_pattern.count("*")
         return self.num_layers - self.num_window_layers \
             - self.num_linear_layers
 
@@ -299,7 +376,11 @@ class ModelConfig:
 
     def layer_kinds(self) -> tuple[str, ...]:
         """``"full"`` / ``"window"`` / ``"linear"`` of every layer, in model
-        order."""
+        order; of a one-sublayer block ``"ssm"`` / ``"moe"`` / ``"full"``,
+        its pattern's characters."""
+        if self.ssm:
+            return tuple({"M": "ssm", "E": "moe", "*": "full"}[c]
+                         for c in self.layer_pattern)
         inner = "linear" if self.linear else "window"
         head = inner if self.linear_head else "full"
         lead = ((inner,) * self.short_period + ("full",)
@@ -355,6 +436,8 @@ class ModelConfig:
 
     @property
     def num_routed_layers(self) -> int:
+        if self.ssm:
+            return self.layer_pattern.count("E")
         return self.num_layers - self.first_k_dense if self.num_experts else 0
 
     @property
@@ -385,6 +468,22 @@ class ModelConfig:
     def num_params(self) -> int:
         """Approximate parameter count (embeddings + blocks + head)."""
         e, f, v = self.hidden_size, self.intermediate_size, self.vocab_size
+        if self.ssm:
+            # A layer is one sublayer under one norm: the mixer's input and
+            # output projections, its convolution with bias, three vectors a
+            # head and the grouped norm; q, k, v, o; the router with its
+            # bias and two matrices an expert held and the shared one.
+            d_in, c = self.ssm_dim, self.ssm_conv_dim
+            fs = self.moe_shared_expert_intermediate_size
+            kinds = {
+                "M": e * (d_in + c + self.ssm_num_heads) + d_in * e
+                + (self.ssm_conv + 1) * c + 3 * self.ssm_num_heads + d_in,
+                "*": 2 * e * (self.q_dim + self.kv_dim),
+                "E": e * self.router_width + self.router_width
+                + 2 * e * (self.num_experts * self.moe_intermediate_size
+                           + fs)}
+            return (0 if self.tie_word_embeddings else e * v) + v * e + e \
+                + sum(kinds[c] + e for c in self.layer_pattern)
         if self.latent:
             attn = (e * self.q_lora_rank + self.q_lora_rank * self.q_dim
                     + e * self.latent_row
@@ -480,6 +579,20 @@ class ModelConfig:
         # num_experts (Qwen2-MoE).
         num_experts = int(d.get("num_local_experts", d.get("num_experts", 0)) or 0)
         is_mixtral = "mixtral" in arch or model_type == "mixtral"
+        if model_type == "nemotron_h":
+            return _from_nemotron_h(d, name or model_type, tuple(eos))
+        # What only the ``nemotron_h`` reader understands: on any other
+        # path each would be dropped, and the model served as another.
+        for k in sorted(d):
+            if d[k] not in (None, False) and (
+                    k == "hybrid_override_pattern"
+                    or k.startswith(("mamba_", "ssm_"))):
+                raise ValueError(
+                    f"{k}={d[k]!r} in a config of model_type "
+                    f"{model_type!r}: only model_type 'nemotron_h' is read "
+                    "with state-space (Mamba-2) layers and layers of one "
+                    "sublayer; serving this model without them would be "
+                    "another model")
         if model_type == "longcat_flash":
             return _from_longcat_flash(d, name or model_type, tuple(eos))
         # What only the ``longcat_flash`` reader understands: on any other
@@ -609,6 +722,23 @@ def _deepseek_yarn(rs: dict | None, refuse) -> tuple[float, ...]:
             float(rs.get("mscale", 1)), float(rs.get("mscale_all_dim", 0)))
 
 
+def _refuser(name: str):
+    """What every reader says of a file it cannot express: ``refuse(what)``
+    raises, by the configuration's name."""
+    def refuse(what: str) -> None:
+        raise ValueError(f"config {name!r}: {what} is not supported (the "
+                         "model would be served as another model)")
+    return refuse
+
+
+def _one_group(d: dict[str, Any], refuse) -> None:
+    """No group limit on the routing: one group, or none stated."""
+    if int(d.get("n_group", 1) or 1) > 1 or int(d.get("topk_group", 1)
+                                                 or 1) > 1:
+        refuse(f"group-limited routing (n_group={d.get('n_group')}, "
+               f"topk_group={d.get('topk_group')})")
+
+
 def _sigmoid_routing(d: dict[str, Any], refuse) -> None:
     """What ``moe.router_topk``'s sigmoid rule is: sigmoid scores, the
     top-k of score + a selection bias (``noaux_tc``), one group.  Anything
@@ -618,10 +748,7 @@ def _sigmoid_routing(d: dict[str, Any], refuse) -> None:
                "n_routed_experts (only sigmoid)")
     if d.get("topk_method", "noaux_tc") != "noaux_tc":
         refuse(f"topk_method={d['topk_method']!r} (only noaux_tc)")
-    if int(d.get("n_group", 1) or 1) > 1 or int(d.get("topk_group", 1)
-                                                 or 1) > 1:
-        refuse(f"group-limited routing (n_group={d.get('n_group')}, "
-               f"topk_group={d.get('topk_group')})")
+    _one_group(d, refuse)
 
 
 def _from_deepseek_v3(d: dict[str, Any], name: str,
@@ -630,10 +757,7 @@ def _from_deepseek_v3(d: dict[str, Any], name: str,
     attention, a dense prefix, sigmoid-routed experts with a selection
     bias, ungated shared experts, YaRN.  Key for key from the published
     file; what this block cannot express is refused, not approximated."""
-    def refuse(what: str) -> None:
-        raise ValueError(f"config {name!r}: {what} is not supported (the "
-                         "model would be served as another model)")
-
+    refuse = _refuser(name)
     for k in ("kv_lora_rank", "q_lora_rank", "qk_nope_head_dim",
               "qk_rope_head_dim", "v_head_dim", "n_routed_experts"):
         if not d.get(k):
@@ -689,10 +813,7 @@ def _from_laguna(d: dict[str, Any], name: str,
     gate, softmax-routed experts with a scaling factor behind a dense
     prefix, a sigmoid-gated shared expert.  Key for key from the published
     file; what the block cannot express is refused, not approximated."""
-    def refuse(what: str) -> None:
-        raise ValueError(f"config {name!r}: {what} is not supported (the "
-                         "model would be served as another model)")
-
+    refuse = _refuser(name)
     layers = int(d["num_hidden_layers"])
     kinds = list(d.get("layer_types") or [])
     heads_per = list(d.get("num_attention_heads_per_layer") or [])
@@ -836,10 +957,7 @@ def _from_mimo_v2(d: dict[str, Any], name: str,
     approximated.  ``attention_projection_layout`` is how a checkpoint lays
     q | k | v out, not mathematics; the family's draft (MTP) layers and its
     vision and audio towers are not in this file and are not built."""
-    def refuse(what: str) -> None:
-        raise ValueError(f"config {name!r}: {what} is not supported (the "
-                         "model would be served as another model)")
-
+    refuse = _refuser(name)
     layers = int(d["num_hidden_layers"])
     for k in ("hybrid_layer_pattern", "sliding_window", "n_routed_experts",
               "num_experts_per_tok", "moe_intermediate_size"):
@@ -953,10 +1071,7 @@ def _from_solar_open2(d: dict[str, Any], name: str,
     gate at ``gqa_layers``, every layer's FFN sigmoid-routed experts beside
     one ungated shared expert.  Key for key from the published file; what
     the block cannot express is refused, not approximated."""
-    def refuse(what: str) -> None:
-        raise ValueError(f"config {name!r}: {what} is not supported (the "
-                         "model would be served as another model)")
-
+    refuse = _refuser(name)
     layers = int(d["num_hidden_layers"])
     lin = d.get("linear_attn_config") or {}
     for k in ("num_heads", "head_dim", "short_conv_kernel_size"):
@@ -978,10 +1093,7 @@ def _from_solar_open2(d: dict[str, Any], name: str,
         refuse("a solar_open2 config without n_routed_experts")
     if d.get("scoring_func", "sigmoid") != "sigmoid":
         refuse(f"scoring_func={d['scoring_func']!r} (only sigmoid)")
-    if int(d.get("n_group", 1) or 1) > 1 or int(d.get("topk_group", 1)
-                                                 or 1) > 1:
-        refuse(f"group-limited routing (n_group={d.get('n_group')}, "
-               f"topk_group={d.get('topk_group')})")
+    _one_group(d, refuse)
     if d.get("attention_bias"):
         refuse("attention_bias")
     if d.get("rope_scaling"):
@@ -1051,10 +1163,7 @@ def _from_gigachat3_5(d: dict[str, Any], name: str,
     multi-token-prediction modules (``num_nextn_predict_layers``,
     ``nextn_is_sparse``) are read and DROPPED: they do not enter the
     next-token logits, and nothing here drafts with them."""
-    def refuse(what: str) -> None:
-        raise ValueError(f"config {name!r}: {what} is not supported (the "
-                         "model would be served as another model)")
-
+    refuse = _refuser(name)
     for k in ("kv_lora_rank", "q_lora_rank", "qk_nope_head_dim",
               "qk_rope_head_dim", "v_head_dim", "n_routed_experts",
               "linear_num_key_heads", "linear_num_value_heads",
@@ -1077,10 +1186,7 @@ def _from_gigachat3_5(d: dict[str, Any], name: str,
     hk, hv = int(d["linear_num_key_heads"]), int(d["linear_num_value_heads"])
     if hv % hk:
         refuse(f"linear_num_value_heads={hv} over linear_num_key_heads={hk}")
-    if int(d.get("n_group", 1) or 1) > 1 or int(d.get("topk_group", 1)
-                                                 or 1) > 1:
-        refuse(f"group-limited routing (n_group={d.get('n_group')}, "
-               f"topk_group={d.get('topk_group')})")
+    _one_group(d, refuse)
     if d.get("attention_bias"):
         refuse("attention_bias")
     if d.get("use_shared_expert_sigmoid"):
@@ -1181,10 +1287,7 @@ def _from_longcat_flash(d: dict[str, Any], name: str,
     times ``routed_scaling_factor`` the weights, no shared expert.  Key for
     key from the published file; what the block cannot express is refused,
     not approximated."""
-    def refuse(what: str) -> None:
-        raise ValueError(f"config {name!r}: {what} is not supported (the "
-                         "model would be served as another model)")
-
+    refuse = _refuser(name)
     for k in ("num_layers", "ffn_hidden_size", "expert_ffn_hidden_size",
               "moe_topk", "n_routed_experts", "kv_lora_rank", "q_lora_rank",
               "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim"):
@@ -1209,10 +1312,7 @@ def _from_longcat_flash(d: dict[str, Any], name: str,
         refuse(f"n_shared_experts={d['n_shared_experts']}")
     if int(d.get("first_k_dense_replace", 0) or 0):
         refuse(f"first_k_dense_replace={d['first_k_dense_replace']}")
-    if int(d.get("n_group", 1) or 1) > 1 or int(d.get("topk_group", 1)
-                                                 or 1) > 1:
-        refuse(f"group-limited routing (n_group={d.get('n_group')}, "
-               f"topk_group={d.get('topk_group')})")
+    _one_group(d, refuse)
     if d.get("sliding_window"):
         refuse(f"sliding_window={d['sliding_window']}")
     hidden = int(d["hidden_size"])
@@ -1247,6 +1347,96 @@ def _from_longcat_flash(d: dict[str, Any], name: str,
         if d.get("mla_scale_q_lora") else 1.0,
         mla_kv_scale=(hidden / int(d["kv_lora_rank"])) ** 0.5
         if d.get("mla_scale_kv_lora") else 1.0,
+    )
+
+
+def _from_nemotron_h(d: dict[str, Any], name: str,
+                     eos: tuple[int, ...]) -> ModelConfig:
+    """The ``nemotron_h`` block (nvidia; Nemotron-3-Nano): layers of ONE
+    sublayer each by ``hybrid_override_pattern``, a character a layer: ``M``
+    a Mamba-2 mixer (the ``mamba_*`` keys, ``ssm_state_size``, ``n_groups``,
+    ``conv_kernel``), ``*`` a GQA layer that applies no rotation, ``E`` a
+    routed FFN of two-matrix relu^2 experts (``mlp_hidden_act``) under
+    sigmoid routing with a selection bias beside one ungated shared expert
+    ``moe_shared_expert_intermediate_size`` wide.  The pattern is read
+    WHOLE; a ``-`` (a dense FFN layer, the family's dense siblings) is
+    refused by name.  Key for key from the published file; what the block
+    cannot express is refused, not approximated.  Read and unused:
+    ``chunk_size`` (the published kernels' block; the recurrence is exact
+    at any), ``time_step_*`` (the initialiser's), ``expand`` (the mixer's
+    width is heads x head width), ``rope_theta`` / ``partial_rotary_factor``
+    (the family's attention module rotates nothing)."""
+    refuse = _refuser(name)
+    for k in ("hybrid_override_pattern", "mamba_num_heads", "mamba_head_dim",
+              "ssm_state_size", "n_groups", "conv_kernel",
+              "n_routed_experts", "num_experts_per_tok",
+              "moe_intermediate_size"):
+        if not d.get(k):
+            refuse(f"a nemotron_h config without {k}")
+    pattern = str(d["hybrid_override_pattern"])
+    layers = int(d["num_hidden_layers"])
+    if "-" in pattern:
+        refuse(f"hybrid_override_pattern {pattern!r} (a '-' layer: a dense "
+               "FFN alone)")
+    if set(pattern) - set("ME*") or len(pattern) != layers:
+        refuse(f"hybrid_override_pattern {pattern!r} (one of M, E, * a "
+               f"layer, {layers} layers)")
+    if "M" not in pattern or "*" not in pattern:
+        refuse(f"hybrid_override_pattern {pattern!r} (a state a slot AND "
+               "pages: at least one M and one *)")
+    for k, want in (("mlp_hidden_act", "relu2"), ("mamba_hidden_act", "silu"),
+                    ("use_conv_bias", True)):
+        if d.get(k, want) != want:
+            refuse(f"{k}={d[k]!r} (only {want!r})")
+    for k in ("attention_bias", "mamba_proj_bias", "mlp_bias", "use_bias"):
+        if d.get(k):
+            refuse(k)
+    _one_group(d, refuse)
+    if int(d.get("n_shared_experts", 1) or 0) > 1:
+        refuse(f"n_shared_experts={d['n_shared_experts']} (one shared "
+               "expert of moe_shared_expert_intermediate_size)")
+    if d.get("sliding_window"):
+        refuse(f"sliding_window={d['sliding_window']}")
+    heads = int(d["num_attention_heads"])
+    kv = int(d.get("num_key_value_heads") or heads)
+    mh, groups = int(d["mamba_num_heads"]), int(d["n_groups"])
+    if heads % kv:
+        refuse(f"{heads} query heads over {kv} KV heads")
+    if mh % groups:
+        refuse(f"mamba_num_heads={mh} over n_groups={groups}")
+    shared = int(d.get("moe_shared_expert_intermediate_size") or 0) \
+        if int(d.get("n_shared_experts", 1) or 0) else 0
+    return ModelConfig(
+        name=name,
+        vocab_size=d["vocab_size"],
+        hidden_size=d["hidden_size"],
+        intermediate_size=int(d.get("intermediate_size") or 0),
+        num_layers=layers,
+        num_heads=heads,
+        num_kv_heads=kv,
+        head_dim=int(d.get("head_dim") or d["hidden_size"] // heads),
+        rope_theta=float(d.get("rope_theta", 10000.0)),
+        rms_norm_eps=float(d.get("layer_norm_epsilon",
+                                 d.get("norm_eps", 1e-5))),
+        tie_word_embeddings=bool(d.get("tie_word_embeddings", False)),
+        max_position_embeddings=int(d.get("max_position_embeddings", 32768)),
+        eos_token_ids=eos,
+        num_experts=int(d["n_routed_experts"]),
+        num_experts_per_tok=int(d["num_experts_per_tok"]),
+        moe_intermediate_size=int(d["moe_intermediate_size"]),
+        norm_topk_prob=bool(d.get("norm_topk_prob", True)),
+        scoring_func="sigmoid",
+        routed_scaling_factor=float(d.get("routed_scaling_factor", 1.0)),
+        use_rope=False,
+        layer_pattern=pattern,
+        ssm_num_heads=mh,
+        ssm_head_dim=int(d["mamba_head_dim"]),
+        ssm_state_size=int(d["ssm_state_size"]),
+        ssm_groups=groups,
+        ssm_conv=int(d["conv_kernel"]),
+        expert_act="relu2",
+        moe_shared_expert_intermediate_size=shared,
+        kv_cache_dtype=str(d.get("kv_cache_dtype", "auto")),
     )
 
 
@@ -1426,6 +1616,24 @@ register_config(ModelConfig(
     routed_scaling_factor=2.5, attn_sublayers=2, zero_experts=8,
     router_select_bias=True, mla_q_scale=(64 / 48) ** 0.5,
     mla_kv_scale=(64 / 32) ** 0.5,
+))
+
+# The ``nemotron_h`` block at CPU-test size, 15 layers of ONE sublayer: two
+# equal periods ``ME M * E``, a shorter one ``M * E`` (an attention layer
+# directly behind a Mamba layer) and a tail ``ME`` with no attention layer.
+# A Mamba-2 mixer: 8 heads of 8 over 2 groups (4 heads share a group's B and
+# C), state 16, convolution of 4 with bias; a GQA layer 4 heads of 16 over 2
+# KV heads, no rotation; 16 sigmoid-routed two-matrix relu^2 experts top-2
+# times 2.5 beside one ungated shared expert of 48.
+register_config(ModelConfig(
+    name="tiny-ssm-moe", vocab_size=512, hidden_size=64,
+    intermediate_size=32, num_layers=15, num_heads=4, num_kv_heads=2,
+    head_dim=16, rms_norm_eps=1e-5, eos_token_ids=(0,),
+    num_experts=16, num_experts_per_tok=2, moe_intermediate_size=32,
+    norm_topk_prob=True, scoring_func="sigmoid", routed_scaling_factor=2.5,
+    use_rope=False, layer_pattern="MEM*EMEM*EM*EME", ssm_num_heads=8,
+    ssm_head_dim=8, ssm_state_size=16, ssm_groups=2, ssm_conv=4,
+    expert_act="relu2", moe_shared_expert_intermediate_size=48,
 ))
 
 # MoE families (HF: mistralai/Mixtral-8x7B-Instruct-v0.1, Qwen/Qwen2-57B-A14B).

@@ -35,7 +35,16 @@ Weight layout per layer (leading [L] from the stacked-layer convention):
   router      [L, E, X]
   w_gate/up   [L, X, E, Fm]     w_down [L, X, Fm, E]
   shared gate/up [L, E, Fs], shared down [L, Fs, E], shared_gate [L, E]
+  (a two-matrix expert: no w_gate, no shared gate projection, and w_up
+  stored transposed as w_upt [L, X, Fm, E])
 where X = num_experts, Fm = moe_intermediate_size.
+
+**An expert of two matrices** (``cfg.expert_act`` "relu2", the
+``nemotron_h`` block): ``relu(x W_up)^2 W_down``, no gate matrix, the shared
+expert alike; the tree holds neither ``w_gate`` nor ``shared_gate_proj``
+and every dispatch below reads one input projection where a SwiGLU reads
+two (:func:`_hidden`, :func:`expert_act`): a static property of the block,
+no path of its own.
 
 DeepSeek-V3 / kimi_k2 routing (``cfg.scoring_func == "sigmoid"``): the
 router also carries ``router_bias [L, W]``, the shared experts are
@@ -76,8 +85,10 @@ Params = dict
 
 def shared_width(cfg) -> int:
     """Width of the shared expert's FFN (0: none): the sigmoid-gated kind
-    states it, the ungated kind is ``n_shared_experts`` routed widths."""
+    states it, the ungated kind is ``n_shared_experts`` routed widths or,
+    where the file states one, ``moe_shared_expert_intermediate_size``."""
     return (cfg.shared_expert_intermediate_size
+            or cfg.moe_shared_expert_intermediate_size
             or cfg.n_shared_experts * cfg.moe_intermediate_size)
 
 
@@ -88,6 +99,32 @@ def swiglu(gate: jnp.ndarray, up: jnp.ndarray, limit: float = 0.0
     if limit:
         gate, up = jnp.minimum(gate, limit), jnp.clip(up, -limit, limit)
     return jax.nn.silu(gate.astype(jnp.float32)).astype(gate.dtype) * up
+
+
+def two_matrix(cfg) -> bool:
+    """An expert is ``W_up`` and ``W_down`` alone (``cfg.expert_act``
+    "relu2"): the tree holds no ``w_gate`` and no ``shared_gate_proj``, and
+    the routed experts' ``W_up`` as ``w_upt`` (:func:`init_moe_params`)."""
+    return cfg.expert_act == "relu2"
+
+
+def expert_act(gate: jnp.ndarray | None, up: jnp.ndarray, cfg
+               ) -> jnp.ndarray:
+    """An expert's hidden activation: :func:`swiglu` of its two input
+    projections, or of a two-matrix expert (``gate`` None) ``relu(up)^2``,
+    squared in float32."""
+    if gate is not None:
+        return swiglu(gate, up, cfg.swiglu_limit)
+    return jnp.square(jax.nn.relu(up.astype(jnp.float32))).astype(up.dtype)
+
+
+def _hidden(dot, w: Params, cfg) -> jnp.ndarray:
+    """The routed experts' hidden activations: ``dot(leaf, transposed)`` is
+    a dispatch's contraction of its rows with an input projection, ``[.., E,
+    F]`` or (a two-matrix expert's one, ``transposed``) ``[.., F, E]``."""
+    if two_matrix(cfg):
+        return expert_act(None, dot(w["w_upt"], True), cfg)
+    return expert_act(dot(w["w_gate"], False), dot(w["w_up"], False), cfg)
 
 
 # A softmax router's probabilities are of the order of 1 / W, where the
@@ -118,15 +155,25 @@ def init_moe_params(cfg, key, dtype, layers: int | None = None) -> Params:
     def w(k, shape, scale=0.02):
         return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dtype)
 
-    p: Params = {
-        "router": w(next(keys), (l, e, cfg.router_width)),
-        "w_gate": w(next(keys), (l, x, e, fm)),
-        "w_up": w(next(keys), (l, x, e, fm)),
-        "w_down": w(next(keys), (l, x, fm, e)),
-    }
+    gated = not two_matrix(cfg)
+    p: Params = {"router": w(next(keys), (l, e, cfg.router_width))}
+    if gated:
+        p["w_gate"] = w(next(keys), (l, x, e, fm))
+        p["w_up"] = w(next(keys), (l, x, e, fm))
+    else:
+        # Stored TRANSPOSED, [L, X, Fm, E], the numbers of the [.., E, Fm]
+        # draw: where Fm is no whole number of 128-lane tiles and E is
+        # (1856 under 2688) the chip lays a [.., E, Fm] stack out E-minor,
+        # the batched dispatch's dots want it Fm-minor, and a step that
+        # carries a chunk copied the whole stack first, 1.8 GB (compiled
+        # for a described v5e, PERF.md section 6, PR 56).  As it is read
+        # is as it is stored, as `transformer.init_params`'s q / k / v.
+        p["w_upt"] = w(next(keys), (l, x, e, fm)).swapaxes(-1, -2)
+    p["w_down"] = w(next(keys), (l, x, fm, e))
     fs = shared_width(cfg)
     if fs:
-        p["shared_gate_proj"] = w(next(keys), (l, e, fs))
+        if gated:
+            p["shared_gate_proj"] = w(next(keys), (l, e, fs))
         p["shared_up"] = w(next(keys), (l, e, fs))
         p["shared_down"] = w(next(keys), (l, fs, e))
     if cfg.shared_expert_intermediate_size:
@@ -308,7 +355,10 @@ def _expert_dot(eq: str, x: jnp.ndarray, w) -> jnp.ndarray:
     from arks_tpu.models.quant import is_quantized, qeinsum
     if not is_quantized(w) or "gs" in w:
         return qeinsum(eq, x, w)
-    return jnp.einsum(eq, x, w["q"].astype(x.dtype)) * w["s"].astype(x.dtype)
+    scale = w["s"]
+    if eq.split("->")[0][-1] not in eq.split("->")[1]:
+        scale = scale.swapaxes(-1, -2)      # a leaf stored [X, b, a]
+    return jnp.einsum(eq, x, w["q"].astype(x.dtype)) * scale.astype(x.dtype)
 
 
 # Rows of a flat batch up to which :func:`_combine` contracts.  The
@@ -446,9 +496,9 @@ def _batched_dispatch(x2: jnp.ndarray, vals: jnp.ndarray, idx: jnp.ndarray,
             token_of = pair // k
             xs = jnp.take(x2, token_of, axis=0)                     # [x, C, E]
         with jax.named_scope("arks.moe_dot"):
-            gate = _expert_dot("xce,xef->xcf", xs, weights["w_gate"])
-            up = _expert_dot("xce,xef->xcf", xs, weights["w_up"])
-            act = swiglu(gate, up, cfg.swiglu_limit)
+            act = _hidden(lambda leaf, t: _expert_dot(
+                "xce,xfe->xcf" if t else "xce,xef->xcf", xs, leaf),
+                weights, cfg)
             down = _expert_dot("xcf,xfe->xce", act, weights["w_down"])
         with jax.named_scope("arks.moe_route"):
             w = jnp.where(live, jnp.take(flat_w, pair), 0)
@@ -473,7 +523,8 @@ def _batched_dispatch(x2: jnp.ndarray, vals: jnp.ndarray, idx: jnp.ndarray,
                 ).reshape((1,) + a.shape[at:])
 
             one = {name: jax.tree.map(expert, tree[name])
-                   for name in ("w_gate", "w_up", "w_down")}
+                   for name in ("w_gate", "w_up", "w_upt", "w_down")
+                   if name in tree}
         return slots(ex[None], (cap * (1 + nth))[None], one)
 
     fixed = 0
@@ -498,14 +549,16 @@ def _batched_dispatch(x2: jnp.ndarray, vals: jnp.ndarray, idx: jnp.ndarray,
 
 def _shared_expert(x2: jnp.ndarray, mp: Params, cfg,
                    constrain=None) -> jnp.ndarray:
-    """The shared expert's SwiGLU on [.., E] rows: every chip computes it
-    alike; sigmoid-gated by ``shared_gate`` (Qwen2-MoE) or as it is
+    """The shared expert's FFN (a SwiGLU, or the two-matrix form the
+    routed experts have) on [.., E] rows: every chip computes it alike;
+    sigmoid-gated by ``shared_gate`` (Qwen2-MoE) or as it is
     (DeepSeek-V3)."""
     from arks_tpu.models.quant import qeinsum
     with jax.named_scope("arks.moe_shared"):
-        sg = qeinsum("...e,ef->...f", x2, mp["shared_gate_proj"])
+        sg = None if two_matrix(cfg) else qeinsum(
+            "...e,ef->...f", x2, mp["shared_gate_proj"])
         su = qeinsum("...e,ef->...f", x2, mp["shared_up"])
-        sact = swiglu(sg, su, cfg.swiglu_limit)
+        sact = expert_act(sg, su, cfg)
         if constrain is not None:
             sact = constrain(sact, sact.ndim - 1)
         shared = qeinsum("...f,fe->...e", sact, mp["shared_down"])
@@ -538,9 +591,8 @@ def _ragged_dispatch(x2: jnp.ndarray, vals: jnp.ndarray, idx: jnp.ndarray,
         xs = jnp.take(x2, token_of, axis=0)                 # [T*k, E] sorted
         group_sizes = jnp.bincount(flat_expert, length=nx)
     with jax.named_scope("arks.moe_dot"):
-        gate = jax.lax.ragged_dot(xs, mp["w_gate"], group_sizes)
-        up = jax.lax.ragged_dot(xs, mp["w_up"], group_sizes)
-        act = swiglu(gate, up, cfg.swiglu_limit)
+        act = _hidden(lambda leaf, t: jax.lax.ragged_dot(
+            xs, leaf.swapaxes(-1, -2) if t else leaf, group_sizes), mp, cfg)
         down = jax.lax.ragged_dot(act, mp["w_down"], group_sizes)  # [T*k, E]
     with jax.named_scope("arks.moe_route"):
         w = jnp.take(vals.reshape(-1), order).astype(down.dtype)   # [T*k]
@@ -563,7 +615,7 @@ def _batch_pays(n_tokens: int, mp: Params, cfg) -> bool:
     from arks_tpu.models.quant import is_quantized
     if n_tokens < _GROUPED_MIN_TOKENS:
         return False
-    if cfg.expert_parallel_size == 1 and not is_quantized(mp["w_gate"]):
+    if cfg.expert_parallel_size == 1 and not is_quantized(mp["w_down"]):
         return True
     return _held_capacity(n_tokens, cfg) < n_tokens
 
@@ -609,13 +661,13 @@ def moe_ffn_grouped(x: jnp.ndarray, mp: Params, cfg,
         logits = jnp.einsum("te,ex->tx", x2, mp["router"])
         vals, idx = router_topk(logits, cfg, mp.get("router_bias"))  # [T, k]
 
-    if share or is_quantized(mp["w_gate"]):
+    if share or is_quantized(mp["w_down"]):
         out, held, tiles = _batched_dispatch(x2, vals, idx, mp, cfg,
                                              row_valid, stack)
     else:
         out = _ragged_dispatch(x2, vals, idx, mp, cfg)
 
-    if "shared_gate_proj" in mp:
+    if "shared_up" in mp:
         out = out + _shared_expert(x2, mp, cfg)
     zero_pairs = None
     if cfg.zero_experts:
@@ -682,15 +734,14 @@ def moe_ffn(x: jnp.ndarray, mp: Params, cfg, constrain=None,
 
     # The dequant is fused into the contraction.
     with jax.named_scope("arks.moe_dot"):
-        gate = qeinsum("...e,xef->...xf", x, mp["w_gate"])
-        up = qeinsum("...e,xef->...xf", x, mp["w_up"])
-        act = swiglu(gate, up, cfg.swiglu_limit)
+        act = _hidden(lambda leaf, t: qeinsum(
+            "...e,xfe->...xf" if t else "...e,xef->...xf", x, leaf), mp, cfg)
         if constrain is not None:
             act = constrain(act, act.ndim - 2)
         down = qeinsum("...xf,xfe->...xe", act, mp["w_down"])  # per expert
         out = jnp.einsum("...xe,...x->...e", down, weights)    # psum over EP
 
-    if "shared_gate_proj" in mp:
+    if "shared_up" in mp:
         out = out + _shared_expert(x, mp, cfg, constrain)
     if cfg.zero_experts:
         part, zero_pairs = _zero_experts(x, vals, idx, cfg, row_valid)

@@ -72,18 +72,28 @@ MATMUL_KEYS = frozenset({
     # layer's two attention sublayers, [L, 2, K, N] (``w_gate`` / ``w_up`` /
     # ``w_down`` are the layer's routed experts').
     "ffn_gate", "ffn_up", "ffn_down",
+    # The ``nemotron_h`` block: a Mamba-2 mixer's input projection (z | x B
+    # C | dt) and its output projection.
+    "w_in", "w_out",
+    # ... and a two-matrix expert's input projection, [L, X, Fm, E].
+    "w_upt",
 })
 # The leaves a GQA stack stores head-split, ``[L, H, D, E]``
 # (`transformer.split_heads`).  The linear layers' leaves of the same names
 # are plain ``[L, E, H x d]`` matmuls: the rank says which.
 HEAD_SPLIT_KEYS = frozenset({"wq", "wk", "wv"})
+# The leaves stored TRANSPOSED, ``[L, N, K]``, contraction dimension minor
+# (a Mamba-2 mixer's input projection: `transformer._init_ssm_params`; a
+# two-matrix expert's: `moe.init_moe_params`).
+TRANSPOSED_KEYS = frozenset({"w_in", "w_upt"})
 
 
 def contraction_axis(name: str, ndim: int) -> int:
     """The contraction dimension of a STACKED matmul leaf: -2 (``[.., K,
     N]``), and -1 for a head-split projection ``[L, H, D, E]``, the only
-    leaf of rank 4 under those names."""
-    return -1 if name in HEAD_SPLIT_KEYS and ndim == 4 else -2
+    leaf of rank 4 under those names, and for a transposed one."""
+    return -1 if name in TRANSPOSED_KEYS or (
+        name in HEAD_SPLIT_KEYS and ndim == 4) else -2
 
 
 # Router logits feed a softmax over experts — tiny and precision-sensitive,
@@ -101,10 +111,13 @@ SKIP_KEYS = frozenset({
     # The decay's projection a head [E, H] (a softplus's input, tiny) and
     # the two post-norms of a layer with sandwich norms.
     "w_a", "attn_post_norm", "mlp_post_norm",
+    # A Mamba-2 mixer's small leaves: the convolution's taps [K, C] and
+    # bias [C], the skip a head [H], the grouped norm's weight [H x P].
+    "conv_w", "conv_b", "d_skip", "ssm_norm",
 })
 NORM_KEYS = frozenset({"attn_norm", "mlp_norm", "final_norm", "q_norm",
                        "kv_norm", "o_norm", "attn_post_norm",
-                       "mlp_post_norm"})
+                       "mlp_post_norm", "ssm_norm"})
 
 
 def weight_bits(weight_dtype: str) -> int:
@@ -307,6 +320,8 @@ def init_params_quantized(cfg, key, dtype=jnp.bfloat16, bits: int = 8,
             return tf.shift_dt_bias(w.astype(dtype))
         if kind == "select_bias":
             return moe.seeded_select_bias(w.astype(dtype))
+        if kind == "conv_taps":
+            return tf.scale_conv_taps(w.astype(dtype))
         return w.astype(dtype)
 
     # A head-split projection is drawn and quantised as the [L, E, H x D]
@@ -317,9 +332,11 @@ def init_params_quantized(cfg, key, dtype=jnp.bfloat16, bits: int = 8,
     # drawn order (PERF.md section 6, PR 48), which is what a seed means
     # (benchmarks/references/_common.py).  What stands beside the stored
     # leaf for a moment is its int8 copy, a quarter of the float32 draw.
+    # (``heads`` 0: a transposed leaf, [L, K, N] stored [L, N, K].)
     @functools.partial(jax.jit, static_argnames=("heads",))
     def stored(leaf, heads):
-        return {n: tf.split_heads(a, heads) for n, a in leaf.items()}
+        return {n: tf.split_heads(a, heads) if heads
+                else a.swapaxes(-1, -2) for n, a in leaf.items()}
 
     counter = [0]
 
@@ -342,6 +359,10 @@ def init_params_quantized(cfg, key, dtype=jnp.bfloat16, bits: int = 8,
                 kind, axis = "quant", -1
             elif name in MATMUL_KEYS:
                 kind, axis = "quant", -2
+                if name in TRANSPOSED_KEYS:
+                    *lead, n, k = leaf.shape
+                    out[name] = stored(gen(sub, (*lead, k, n), kind, axis), 0)
+                    continue
                 if contraction_axis(name, leaf.ndim) == -1:
                     *lead, h, d, e = leaf.shape
                     out[name] = stored(
@@ -351,6 +372,8 @@ def init_params_quantized(cfg, key, dtype=jnp.bfloat16, bits: int = 8,
                 kind, axis = "dt_bias", 0
             elif name == "router_bias" and cfg.router_select_bias:
                 kind, axis = "select_bias", 0
+            elif name == "conv_w":
+                kind, axis = "conv_taps", 0
             else:
                 kind, axis = "full", 0
             out[name] = gen(sub, tuple(leaf.shape), kind, axis)

@@ -29,12 +29,14 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from arks_tpu.models.config import ModelConfig
 from arks_tpu.models.quant import embed_lookup, qeinsum, unembed_logits
 from arks_tpu.ops.attention import decode_update_and_attend, prefill_attention
 from arks_tpu.ops.linear_state import linear_state_step
+from arks_tpu.ops.ssm_state import heads_a_tile, ssm_state_step
 from arks_tpu.ops.norms import rms_norm
 from arks_tpu.ops.rope import apply_rope
 
@@ -75,18 +77,26 @@ class KVCache(NamedTuple):
 
 
 class LinearState(NamedTuple):
-    """What the linear-attention layers of a model keep of a sequence
-    (``cfg.linear``): a fixed number of bytes a slot, whatever the context.
+    """What the recurrent layers of a model keep of a sequence
+    (``cfg.recurrent``): a fixed number of bytes a slot, whatever the
+    context.
 
-    ``s`` ``[Ll, num_slots, H, d, d]`` float32: the delta rule's state a
+    Linear-attention layers (``cfg.linear``): ``s`` ``[Ll, num_slots, H, d,
+    d]`` float32: the delta rule's state a
     (value) head (keys down, values across), layer ``l`` of the model's
     linear layers in model order.  ``conv`` ``[Ll, num_slots, K - 1, C]``:
     the last ``K - 1`` rows of the q | k | v projections ahead of the short
     convolution, oldest first (C = 3 x H x d; with fewer key heads Hk,
-    2 x Hk x d + H x d).  A slot's rows are whatever its last
+    2 x Hk x d + H x d).  Mamba-2 mixers (``cfg.ssm``): ``s`` ``[Lm,
+    num_slots, H / r, N, r P]`` float32, the selective scan's state ``[P,
+    N]`` a head as its one-step kernel reads it (the state's width down, the
+    widths of r heads of a group across: `ops/ssm_state.pack_state`), and
+    ``conv`` the last
+    ``K - 1`` rows of x | B | C ahead of their convolution (C = H x P + 2
+    x G x N).  A slot's rows are whatever its last
     sequence left there; a sequence that starts at position 0 reads them as
-    zeros (:func:`_linear_qkv`, :func:`_linear_state`), so nothing zeroes a
-    slot between two steps."""
+    zeros (:func:`_carry_conv`, :func:`_linear_state`, :func:`_ssm_state`),
+    so nothing zeroes a slot between two steps."""
 
     s: jnp.ndarray
     conv: jnp.ndarray
@@ -125,9 +135,10 @@ class PagedKVCache(NamedTuple):
     layers, addressed through block tables of its own whose entries
     behind a slot's window the engine has released.
 
-    A model with LINEAR-attention layers (``cfg.linear``) keeps pages for
+    A model with LINEAR-attention layers (``cfg.linear``) or Mamba-2
+    mixers (``cfg.ssm``) keeps pages for
     its GQA layers only (``L`` = ``cfg.num_full_layers``); ``lin`` is what
-    its linear layers keep, a :class:`LinearState` indexed by slot.  Where
+    its recurrent layers keep, a :class:`LinearState` indexed by slot.  Where
     its other layers are LATENT layers (``cfg.latent`` too), the pool is
     the latent pool of those layers, ``v`` None, beside ``lin``.
     """
@@ -352,6 +363,24 @@ def shift_dt_bias(w: jnp.ndarray) -> jnp.ndarray:
     return (w.astype(jnp.float32) + LINEAR_DT_BIAS_SHIFT).astype(w.dtype)
 
 
+# A seeded ``conv_w`` (a Mamba-2 mixer's taps) is normal * 0.02 TIMES this (a
+# choice of the seeded weights, not of the mathematics): std 0.5, the order
+# of a trained mixer's taps (their initialiser is uniform in +-K^-1/2).  At
+# 0.02 x | B | C come out of the convolution at a few hundredths, the state
+# ``sum dt x (x) B`` read out by C is their third power, and nine tenths and
+# more of what a mixer returns is the skip ``D x``: neither the state nor the
+# precision it is kept in would reach the logits, and a state update that
+# wrote the wrong slot would pass the comparison (a linear layer's q and k
+# are L2-normed, so its state is of order one whatever the taps).
+# ``quant.init_params_quantized`` and the reference family
+# (benchmarks/references/ssm_moe.py) apply the same factor.
+SSM_CONV_SCALE = 25.0
+
+
+def scale_conv_taps(w: jnp.ndarray) -> jnp.ndarray:
+    return (w.astype(jnp.float32) * SSM_CONV_SCALE).astype(w.dtype)
+
+
 def _init_linear_params(cfg: ModelConfig, key: jax.Array,
                         dtype: jnp.dtype) -> Params:
     """The ``solar_open2`` tree, a stacked tree a kind of layer, every layer
@@ -490,6 +519,62 @@ def _init_latent_linear_params(cfg: ModelConfig, key: jax.Array,
     return params
 
 
+def _init_ssm_params(cfg: ModelConfig, key: jax.Array,
+                     dtype: jnp.dtype) -> Params:
+    """The ``nemotron_h`` tree, a stacked tree a kind of SUBLAYER, each
+    layer with its one norm: ``ssm_layers`` (the ``M`` layers in model
+    order: ``attn_norm``, the input projection ``w_in`` [z | x B C | dt, E]
+    = [2 H P + 2 G N + H, E], stored TRANSPOSED, contraction dimension
+    minor, as :func:`init_params` stores a GQA stack's projections and for
+    its reason: handed ``[Lm, E, Q]`` the chip's compiler gave the step
+    programs' loops two layouts of the stack and copied it whole, 637 MB,
+    every step; the numbers are those of the ``[Lm, E, Q]`` draw.  The
+    convolution's taps ``conv_w`` [K, H P + 2 G N] (oldest first; seeded
+    times ``SSM_CONV_SCALE``) and bias ``conv_b``, the step size's
+    ``dt_bias`` [H], the decay's ``a_log`` [H], the skip ``d_skip`` [H], the
+    grouped norm's ``ssm_norm`` [H P], ``w_out`` [H P, E]), ``layers`` (the ``*``
+    layers: ``attn_norm`` and the head-split ``wq`` / ``wk`` / ``wv``
+    (:func:`init_params`) / ``wo``) and ``moe_layers`` (the ``E`` layers:
+    ``mlp_norm`` and `moe.init_moe_params`'s leaves, two matrices an
+    expert)."""
+    e, v = cfg.hidden_size, cfg.vocab_size
+    keys = iter(jax.random.split(key, 24))
+
+    def w(shape, scale=0.02):
+        return (jax.random.normal(next(keys), shape, jnp.float32)
+                * scale).astype(dtype)
+
+    from arks_tpu.models import moe
+    lm, la, le = (cfg.num_linear_layers, cfg.num_full_layers,
+                  cfg.num_routed_layers)
+    d_in, c, h = cfg.ssm_dim, cfg.ssm_conv_dim, cfg.ssm_num_heads
+    params: Params = {
+        "embed": w((v, e)),
+        "final_norm": jnp.ones((e,), dtype),
+        "ssm_layers": {
+            "attn_norm": jnp.ones((lm, e), dtype),
+            "w_in": w((lm, e, d_in + c + h)).swapaxes(-1, -2),
+            "conv_w": scale_conv_taps(w((lm, cfg.ssm_conv, c))),
+            "conv_b": w((lm, c)),
+            "dt_bias": shift_dt_bias(w((lm, h))), "a_log": w((lm, h)),
+            "d_skip": w((lm, h)),
+            "ssm_norm": jnp.ones((lm, d_in), dtype),
+            "w_out": w((lm, d_in, e))},
+        "layers": {
+            "attn_norm": jnp.ones((la, e), dtype),
+            "wq": split_heads(w((la, e, cfg.q_dim)), cfg.num_heads),
+            "wk": split_heads(w((la, e, cfg.kv_dim)), cfg.num_kv_heads),
+            "wv": split_heads(w((la, e, cfg.kv_dim)), cfg.num_kv_heads),
+            "wo": w((la, cfg.q_dim, e))},
+        "moe_layers": dict(
+            moe.init_moe_params(cfg, next(keys), dtype, layers=le),
+            mlp_norm=jnp.ones((le, e), dtype)),
+    }
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = w((e, v))
+    return params
+
+
 def init_params(cfg: ModelConfig, key: jax.Array, dtype: jnp.dtype | None = None) -> Params:
     """The parameter tree, layers stacked (leading ``[L]``), a stack a kind
     of layer; a matmul leaf is ``[.., K, N]``, contraction dimension first.
@@ -512,6 +597,8 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype: jnp.dtype | None = None
     (`quant.contraction_axis`).  Biases ``bq`` / ``bk`` / ``bv`` stay [L, H
     x D]."""
     dtype = dtype or jnp.dtype(cfg.dtype)
+    if cfg.ssm:
+        return _init_ssm_params(cfg, key, dtype)
     if cfg.latent and cfg.linear:
         return _init_latent_linear_params(cfg, key, dtype)
     if cfg.shortcut:
@@ -584,6 +671,11 @@ def param_pspecs(cfg: ModelConfig, tp: int = 1) -> Params:
             f"model {cfg.name!r}: linear-attention layers and their state "
             "have no sharding rules (tensor / data / pipeline parallelism "
             "are not supported)")
+    if cfg.ssm:
+        raise NotImplementedError(
+            f"model {cfg.name!r}: state-space layers and their state have "
+            "no sharding rules: a share of the heads is not built (tensor "
+            "/ data / pipeline parallelism are not supported)")
     kv = P(None, AXIS_MODEL if shard_kv_heads(cfg, tp) else None, None, None)
     kvb = P(None, AXIS_MODEL) if shard_kv_heads(cfg, tp) else P(None, None)
     layers: Params = {
@@ -698,24 +790,33 @@ def init_paged_cache(cfg: ModelConfig, num_pages: int, page: int,
                      state_slots: int = 0) -> PagedKVCache:
     """``win_pages`` (a model with window layers): the pages of the window
     layers' pool; ``num_pages`` are then the full-attention layers'.
-    ``state_slots`` (a model with linear layers): the slots its
+    ``state_slots`` (a model with recurrent layers): the slots its
     :class:`LinearState` holds; the pool is its GQA layers'."""
     dtype = dtype or jnp.dtype(cfg.dtype)
-    if cfg.linear:
+    if cfg.recurrent:
         if state_slots < 1:
-            raise ValueError(f"model {cfg.name!r}: linear layers keep a "
-                             "state a slot (state_slots)")
+            raise ValueError(f"model {cfg.name!r}: {cfg.recurrent_kind} "
+                             "layers keep a state a slot (state_slots)")
         import dataclasses
         pool = init_paged_cache(
-            dataclasses.replace(cfg, linear_period=0,
+            dataclasses.replace(cfg, linear_period=0, layer_pattern="",
                                 num_layers=cfg.num_full_layers),
             num_pages, page, dtype, quantized, pad_head, kv_bits)
-        ll, h, d = (cfg.num_linear_layers, cfg.linear_num_heads,
-                    cfg.linear_head_dim)
+        ll = cfg.num_linear_layers
+        if cfg.ssm:
+            # As the one-step kernel reads it (`ops/ssm_state.pack_state`).
+            r = heads_a_tile(cfg.ssm_head_dim,
+                             cfg.ssm_num_heads // cfg.ssm_groups)
+            state = (cfg.ssm_num_heads // r, cfg.ssm_state_size,
+                     r * cfg.ssm_head_dim)
+            taps, channels = cfg.ssm_conv, cfg.ssm_conv_dim
+        else:
+            state = (cfg.linear_num_heads, cfg.linear_head_dim,
+                     cfg.linear_head_dim)
+            taps, channels = cfg.linear_conv, cfg.linear_conv_dim
         return pool._replace(lin=LinearState(
-            s=jnp.zeros((ll, state_slots, h, d, d), jnp.float32),
-            conv=jnp.zeros((ll, state_slots, cfg.linear_conv - 1,
-                            cfg.linear_conv_dim), dtype)))
+            s=jnp.zeros((ll, state_slots, *state), jnp.float32),
+            conv=jnp.zeros((ll, state_slots, taps - 1, channels), dtype)))
     if cfg.windowed:
         if win_pages < 1:
             raise ValueError(f"model {cfg.name!r}: window layers keep a "
@@ -1057,6 +1158,42 @@ def _lane_rows(x: jnp.ndarray, start: jnp.ndarray, offsets: jnp.ndarray):
     return jnp.take(x, idx, axis=0)
 
 
+def _carry_conv(pre: jnp.ndarray, w: jnp.ndarray, conv: jnp.ndarray,
+                seq_q_start: jnp.ndarray, seq_q_len: jnp.ndarray,
+                fresh: jnp.ndarray):
+    """The causal depthwise convolution over the last K positions OF THE
+    ROW'S SEQUENCE on the flat ``pre [T, C]`` with taps ``w [K, C]`` float32
+    (oldest first): a lane's rows are contiguous from ``seq_q_start``; its
+    first K - 1 rows reach into the slot's carry ``conv [B, K - 1, C]``
+    (the last K - 1 rows ahead of the convolution, oldest first), read as
+    zeros where ``fresh``.  Returns (``y [T, C]`` float32, the carry the
+    lanes leave: the last K - 1 rows of (carry, a lane's rows))."""
+    t, kk = pre.shape[0], w.shape[0]
+    # Every row against the K - 1 flat rows before it ...
+    y = sum(jnp.roll(pre, i, axis=0).astype(jnp.float32) * w[kk - 1 - i]
+            for i in range(kk))
+    # ... and a lane's first K - 1 rows again, against its carry.
+    carry = jnp.where(fresh[:, None, None], 0, conv)               # [B, K-1, C]
+    first = jnp.arange(kk - 1, dtype=jnp.int32)
+    head = _lane_rows(pre, seq_q_start, jnp.broadcast_to(
+        first, (conv.shape[0], kk - 1)))
+    in_lane = first[None, :] < seq_q_len[:, None]                  # [B, K-1]
+    line = jnp.concatenate(
+        [carry, jnp.where(in_lane[..., None], head, 0)], axis=1)   # [B, 2K-2, C]
+    fix = sum(line[:, i: i + kk - 1].astype(jnp.float32) * w[i]
+              for i in range(kk))                                  # [B, K-1, C]
+    y = y.at[jnp.where(in_lane, seq_q_start[:, None] + first, t)].set(
+        fix, mode="drop")
+    # The carry a lane leaves: the last K - 1 rows of (carry, its rows).
+    back = seq_q_len[:, None] - (kk - 1) + first                   # [B, K-1]
+    tail = _lane_rows(pre, seq_q_start, back)
+    old = jnp.take_along_axis(
+        carry, jnp.clip(back + kk - 1, 0, kk - 2)[..., None], axis=1)
+    new_conv = jnp.where((seq_q_len > 0)[:, None, None],
+                         jnp.where((back >= 0)[..., None], tail, old), conv)
+    return y, new_conv
+
+
 @_scope("arks.linear_qkv")
 def _linear_qkv(x: jnp.ndarray, lp: Params, cfg: ModelConfig,
                 conv: jnp.ndarray, seq_q_start: jnp.ndarray,
@@ -1080,28 +1217,7 @@ def _linear_qkv(x: jnp.ndarray, lp: Params, cfg: ModelConfig,
                            for n in ("wq", "wk", "wv")], axis=-1)  # [T, 3Hd]
     w = jnp.concatenate([lp["conv_q"], lp["conv_k"], lp["conv_v"]],
                         axis=-1).astype(jnp.float32)               # [K, 3Hd]
-    # Every row against the K - 1 flat rows before it ...
-    y = sum(jnp.roll(pre, i, axis=0).astype(jnp.float32) * w[kk - 1 - i]
-            for i in range(kk))
-    # ... and a lane's first K - 1 rows again, against its carry.
-    carry = jnp.where(fresh[:, None, None], 0, conv)               # [B, K-1, C]
-    first = jnp.arange(kk - 1, dtype=jnp.int32)
-    head = _lane_rows(pre, seq_q_start, jnp.broadcast_to(
-        first, (conv.shape[0], kk - 1)))
-    in_lane = first[None, :] < seq_q_len[:, None]                  # [B, K-1]
-    line = jnp.concatenate(
-        [carry, jnp.where(in_lane[..., None], head, 0)], axis=1)   # [B, 2K-2, C]
-    fix = sum(line[:, i: i + kk - 1].astype(jnp.float32) * w[i]
-              for i in range(kk))                                  # [B, K-1, C]
-    y = y.at[jnp.where(in_lane, seq_q_start[:, None] + first, t)].set(
-        fix, mode="drop")
-    # The carry a lane leaves: the last K - 1 rows of (carry, its rows).
-    back = seq_q_len[:, None] - (kk - 1) + first                   # [B, K-1]
-    tail = _lane_rows(pre, seq_q_start, back)
-    old = jnp.take_along_axis(
-        carry, jnp.clip(back + kk - 1, 0, kk - 2)[..., None], axis=1)
-    new_conv = jnp.where((seq_q_len > 0)[:, None, None],
-                         jnp.where((back >= 0)[..., None], tail, old), conv)
+    y, new_conv = _carry_conv(pre, w, conv, seq_q_start, seq_q_len, fresh)
     y = jax.nn.silu(y)
     if hk == h:
         y = y.reshape(t, 3, h, d)
@@ -1193,8 +1309,7 @@ def _linear_state(q, k, v, g, beta, s_all: jnp.ndarray, layer,
     once and written once, no other slot's is touched, and the lanes' rows
     go from the flat batch into the kernel and back by their index); a
     lane of more rows is walked in blocks of ``LINEAR_CHUNK`` rows by
-    :func:`_delta_chunk`, block after block and lane after lane, as many
-    trips as the batch holds blocks.  Returns (o [T, H, d] f32,
+    :func:`_delta_chunk` (:func:`_walk_blocks`).  Returns (o [T, H, d] f32,
     ``s_all``)."""
     t, h, d = q.shape
     f32 = jnp.float32
@@ -1208,11 +1323,27 @@ def _linear_state(q, k, v, g, beta, s_all: jnp.ndarray, layer,
         interpret=jax.default_backend() != "tpu")
 
     # -- the lanes of more rows: blocks of C rows, in order ---------------
+    out, s_all = _walk_blocks(_delta_chunk, (q, k, v, g, beta), out, s_all,
+                              layer, seq_q_start, seq_q_len, fresh, c)
+    return out[:t], s_all
+
+
+def _walk_blocks(chunk, rows_in, out, s_all, layer, seq_q_start, seq_q_len,
+                 fresh, c: int):
+    """The lanes of MORE than one row of a recurrent layer's flat batch,
+    walked in blocks of ``c`` rows, block after block and lane after lane,
+    as many trips as the batch holds blocks: ``chunk(*rows, s0) -> (o [H,
+    c, ..], s1)`` takes a block's rows of each of ``rows_in`` (``[T, ..]``,
+    handed over head-major ``[.., c, ..]``, a row that carries no token
+    zeros) and the state before the block (the slot's of ``s_all[layer]``,
+    zeros where ``fresh`` at the lane's first block), and its results go
+    into ``out [T + c, H, ..]`` and back into ``s_all``."""
+    f32 = jnp.float32
     blocks = jnp.where(seq_q_len > 1, -(-seq_q_len // c), 0)       # [B]
     ends = jnp.cumsum(blocks)
-    pad = ((0, c), (0, 0), (0, 0))
-    qp, kp, vp = (jnp.pad(x.astype(f32), pad) for x in (q, k, v))
-    gp, bp = jnp.pad(g, pad), jnp.pad(beta, pad[:2])
+    padded = [jnp.pad(x.astype(f32), ((0, c),) + ((0, 0),) * (x.ndim - 1))
+              for x in rows_in]
+    state = s_all.shape[2:]
 
     def block(i, carry):
         out, s_all = carry
@@ -1228,10 +1359,9 @@ def _linear_state(q, k, v, g, beta, s_all: jnp.ndarray, layer,
                 live.reshape((c,) + (1,) * (r.ndim - 1)), r, 0), 0, 1)
 
         s0 = jax.lax.dynamic_slice(
-            s_all, (layer, lane, 0, 0, 0), (1, 1, h, d, d))[0, 0].astype(f32)
+            s_all, (layer, lane, 0, 0, 0), (1, 1, *state))[0, 0].astype(f32)
         s0 = jnp.where(fresh[lane] & (nth == 0), 0.0, s0)
-        o, s1 = _delta_chunk(rows(qp), rows(kp), rows(vp), rows(gp),
-                             rows(bp), s0)
+        o, s1 = chunk(*(rows(x) for x in padded), s0)
         old = jax.lax.dynamic_slice_in_dim(out, row0, c, axis=0)
         out = jax.lax.dynamic_update_slice_in_dim(
             out, jnp.where(live[:, None, None], jnp.swapaxes(o, 0, 1), old),
@@ -1241,8 +1371,7 @@ def _linear_state(q, k, v, g, beta, s_all: jnp.ndarray, layer,
             (layer, lane, 0, 0, 0))
         return out, s_all
 
-    out, s_all = jax.lax.fori_loop(0, ends[-1], block, (out, s_all))
-    return out[:t], s_all
+    return jax.lax.fori_loop(0, ends[-1], block, (out, s_all))
 
 
 @_scope("arks.linear_out")
@@ -1264,6 +1393,130 @@ def _linear_out(o: jnp.ndarray, x: jnp.ndarray, lp: Params,
                  cfg.linear_norm_eps or cfg.rms_norm_eps) * gate
     return qeinsum("tq,qe->te", y.astype(x.dtype).reshape(t, cfg.linear_dim),
                    lp["wo"])
+
+
+@_scope("arks.ssm_in")
+def _ssm_in(x: jnp.ndarray, lp: Params, cfg: ModelConfig, conv: jnp.ndarray,
+            seq_q_start: jnp.ndarray, seq_q_len: jnp.ndarray,
+            fresh: jnp.ndarray):
+    """A Mamba-2 mixer's per-token half on the normed flat batch ``x [T,
+    E]``: the one input projection ``[z | x B C | dt]``, the causal
+    depthwise convolution with bias over the last K positions of the row's
+    sequence (:func:`_carry_conv`, the slot's carry ``conv [B, K - 1, H P +
+    2 G N]``), SiLU, the step size ``dt = softplus(dt + dt_bias)`` and the
+    log decay ``-exp(A_log) dt``, ONE a head.  Returns (z [T, H P], the
+    head's input ``xs [T, H, P]`` f32, ``b, c [T, G, N]`` f32, ``dt, g [T,
+    H]`` f32, the slots' new carry)."""
+    t = x.shape[0]
+    h, p, g, n = (cfg.ssm_num_heads, cfg.ssm_head_dim, cfg.ssm_groups,
+                  cfg.ssm_state_size)
+    d_in, c = cfg.ssm_dim, cfg.ssm_conv_dim
+    zxbcdt = qeinsum("te,qe->tq", x, lp["w_in"])
+    z, pre, dt = (zxbcdt[:, :d_in], zxbcdt[:, d_in: d_in + c],
+                  zxbcdt[:, d_in + c:])
+    y, new_conv = _carry_conv(pre, lp["conv_w"].astype(jnp.float32), conv,
+                              seq_q_start, seq_q_len, fresh)
+    y = jax.nn.silu(y + lp["conv_b"].astype(jnp.float32))
+    xs = y[:, :d_in].reshape(t, h, p)
+    b = y[:, d_in: d_in + g * n].reshape(t, g, n)
+    c = y[:, d_in + g * n:].reshape(t, g, n)
+    # (The bias in float32, as a linear layer's: the decay compounds.)
+    dt = jax.nn.softplus(dt.astype(jnp.float32)
+                         + lp["dt_bias"].astype(jnp.float32))
+    log_decay = -jnp.exp(lp["a_log"].astype(jnp.float32))[None] * dt
+    return z, xs, b, c, dt, log_decay, new_conv.astype(conv.dtype)
+
+
+def _ssd_chunk(x, b, c, g, s0):
+    """One block of the selective scan in Mamba-2's chunk (SSD) form, the
+    heads in parallel: rows ``x [H, C, P]`` (a head's input times its step
+    size), ``b, c [G, C, N]`` (head h reads group ``h // (H / G)``), log
+    decay ``g [H, C]`` (a row that carries no token: x = 0, g = 0), the
+    state before the block ``s0 [H / r, N, r P]`` AS IT IS STORED
+    (`ops/ssm_state.pack_state`: a head's ``[P, N]`` with the state's width
+    down and r heads of a group side by side; its lanes are split into (r,
+    P) here and nothing is transposed, so that the slots' states keep one
+    layout through the step).  Returns (y [H, C, P], the state after the
+    block, stored likewise).  With L the log decay summed from the block's
+    start: ``y_t = exp(L_t) S0 C_t + sum_{s <= t} exp(L_t - L_s) (C_t .
+    B_s) x_s``; ``S = exp(L_C) S0 + sum_s exp(L_C - L_s) x_s (x) B_s``;
+    every exponent is <= 0.  Float32, every product at the highest
+    precision, as :func:`_delta_chunk`."""
+    h, rows, p = x.shape
+    groups, n = b.shape[0], b.shape[2]
+    r = s0.shape[-1] // p
+    tiles = h // groups // r                       # a group's
+    heads = (groups, tiles, r)
+    gs = jnp.cumsum(g, axis=1).reshape(*heads, rows)
+    x = x.reshape(*heads, rows, p)
+    # The block's state in the layout the slots' states are STORED in, said
+    # to the compiler in as many words: the products below contract over N,
+    # and left to itself it lays every slot's state out N-minor for them
+    # and copies all of it, 3 GB, to and from the kernel's layout around
+    # each one-step call (compiled for a described v5e, PR 56).
+    stored = Layout(major_to_minor=(0, 1, 2))
+    s0 = with_layout_constraint(s0, stored).reshape(groups, tiles, n, r, p)
+
+    def mm(eq, *a):
+        return jnp.einsum(eq, *a, precision=_HIGHEST)
+
+    row = jnp.arange(rows)
+    m = jnp.exp(jnp.where(row[:, None] >= row[None, :],
+                          gs[..., :, None] - gs[..., None, :], -jnp.inf))
+    y = mm("gkjts,gkjsp->gkjtp",
+           mm("gtn,gsn->gts", c, b)[:, None, None] * m, x) \
+        + jnp.exp(gs)[..., None] * mm("gtn,gknjp->gkjtp", c, s0)
+    last = gs[..., -1:]                                      # [G, k, r, 1]
+    s1 = jnp.exp(last)[:, :, None] * s0 + mm(
+        "gsn,gkjsp->gknjp", b, x * jnp.exp(last - gs)[..., None])
+    return y.reshape(h, rows, p), with_layout_constraint(
+        s1.reshape(groups * tiles, n, r * p), stored)
+
+
+@_scope("arks.ssm_state")
+def _ssm_state(xs, b, c, dt, g, s_all: jnp.ndarray, layer,
+               seq_q_start: jnp.ndarray, seq_q_len: jnp.ndarray,
+               fresh: jnp.ndarray):
+    """The selective scan over a step's ragged flat batch: ``S_t = a_t
+    S_{t-1} + dt_t x_t (x) B_t``, ``y_t = S_t C_t``, ``a_t = exp(g_t)``,
+    lane b's rows in order from its slot's state ``s_all[layer, b]``
+    (``s_all [Lm, B, H / r, N, r P]``: `ops/ssm_state.pack_state`; rewritten
+    in place; read as zeros where ``fresh``).  As :func:`_linear_state`: a
+    lane of ONE row takes one
+    recurrence step, all such lanes in one kernel over their slots' states
+    (:func:`arks_tpu.ops.ssm_state.ssm_state_step`); a lane of more rows is
+    walked in blocks of ``LINEAR_CHUNK`` rows by :func:`_ssd_chunk`
+    (:func:`_walk_blocks`).  Returns (y [T, H, P] f32, ``s_all``); the skip
+    ``D x_t`` is :func:`_ssm_out`'s."""
+    t = xs.shape[0]
+    xdt = xs * dt[..., None]
+    one = seq_q_len == 1
+    out, s_all = ssm_state_step(
+        xdt, b, c, g, s_all, layer,
+        jnp.flatnonzero(one, size=one.shape[0], fill_value=0), jnp.sum(one),
+        fresh, jnp.clip(seq_q_start, 0, t - 1), pad=LINEAR_CHUNK,
+        interpret=jax.default_backend() != "tpu")
+    out, s_all = _walk_blocks(_ssd_chunk, (xdt, b, c, g), out, s_all, layer,
+                              seq_q_start, seq_q_len, fresh, LINEAR_CHUNK)
+    return out[:t], s_all
+
+
+@_scope("arks.ssm_out")
+def _ssm_out(y: jnp.ndarray, xs: jnp.ndarray, z: jnp.ndarray, lp: Params,
+             cfg: ModelConfig) -> jnp.ndarray:
+    """``y [T, H, P]`` f32 (the state read out) -> [T, E]: plus the skip ``D
+    x_t`` a head, times ``silu(z)``, the gate BEFORE the norm, an RMS norm
+    over each of ``cfg.ssm_groups`` groups of channels times the learnt
+    ``ssm_norm`` [H P], then the output projection."""
+    t = y.shape[0]
+    f32 = jnp.float32
+    y = (y + lp["d_skip"].astype(f32)[None, :, None] * xs).reshape(
+        t, cfg.ssm_dim) * jax.nn.silu(z.astype(f32))
+    grouped = y.reshape(t, cfg.ssm_groups, -1)
+    grouped = grouped * jax.lax.rsqrt(
+        jnp.mean(grouped * grouped, -1, keepdims=True) + cfg.rms_norm_eps)
+    y = grouped.reshape(t, cfg.ssm_dim) * lp["ssm_norm"].astype(f32)
+    return qeinsum("tq,qe->te", y.astype(z.dtype), lp["w_out"])
 
 
 def mixed_step(
@@ -1317,7 +1570,15 @@ def mixed_step(
     or shortcut layers (``cfg.shortcut``: two latent sublayers a layer over
     pool rows ``2 i`` and ``2 i + 1``, a dense FFN behind each, the routed
     layer's result carried from the first sublayer to the layer's end);
-    all write and read the full pool through ``tables``.  A layer
+    all write and read the full pool through ``tables``.  The ONE-SUBLAYER
+    block (``cfg.ssm``) does not fit a period of (inner layers, full layer)
+    pairs of mixer AND FFN: each of its layers is a Mamba-2 mixer, a GQA
+    layer's attention or a routed FFN ALONE, under one norm and one
+    residual, from a stack of its own kind, and its periods are of unequal
+    length; ``walk_pattern`` walks ``cfg.pattern_walk()`` instead, the same
+    layer functions cut at the sublayer (``attend``, ``ffn``) and
+    ``ssm_layer``, which reads and writes the slots' state (``cache.lin``)
+    as a linear layer does, a run of equal periods one scan.  A layer
     function's
     ``src`` is ``(stack, index)``: the stacked tree of ``params`` its ``lp``
     was taken out of and where, for the routed FFN's overflow loop
@@ -1412,7 +1673,8 @@ def mixed_step(
                 h = h + qeinsum("...f,fe->...e", act, sub["ffn_down"])
         return h + shortcut, (k,) + tuple(pool[1:]), held
 
-    def layer(h, lp, src, pool, tbl, index, window: bool):
+    def attend(h, lp, pool, tbl, index, window: bool):
+        """A GQA layer's attention sublayer on the residual stream."""
         q, k, v, gate = _kind_qkv(h, lp, cfg, rope_pos, window)
         attn, *pool = paged_mixed_update_and_attend(
             q[0], k[0], v[0], pool[0], pool[1], tbl, token_slot, token_pos,
@@ -1428,8 +1690,12 @@ def mixed_step(
         attn = _constrain(attn, mesh, None, None, AXIS_MODEL)
         with _scope("arks.attn_win_out" if window else "arks.attn_out"):
             h = h + qeinsum("...q,qe->...e", attn, lp["wo"])
+        return h, tuple(pool)
+
+    def layer(h, lp, src, pool, tbl, index, window: bool):
+        h, pool = attend(h, lp, pool, tbl, index, window)
         h, held = ffn(h, lp, src)
-        return h, tuple(pool), held
+        return h, pool, held
 
     # A sequence that starts in this step starts from nothing, whatever
     # its slot's last sequence left in the state.
@@ -1452,6 +1718,78 @@ def mixed_step(
         h, held = ffn(h, lp, src)
         return h, lin, held
 
+    def ssm_layer(h, lp, lin, index):
+        """A Mamba-2 mixer alone: one norm, one residual."""
+        s_all, conv_all = lin
+        x = _norm(h[0], lp["attn_norm"], cfg)
+        z, xs, b, c, dt, g, conv = _ssm_in(
+            x, lp, cfg, jax.lax.dynamic_index_in_dim(
+                conv_all, index, 0, keepdims=False),
+            seq_q_start, seq_q_len, fresh)
+        y, s_all = _ssm_state(xs, b, c, dt, g, s_all, index, seq_q_start,
+                              seq_q_len, fresh)
+        h = h + _ssm_out(y, xs, z, lp, cfg)[None]
+        return h, (s_all, jax.lax.dynamic_update_index_in_dim(
+            conv_all, conv, index, 0))
+
+    def walk_pattern(h):
+        """The one-sublayer block: ``cfg.pattern_walk()``'s nested runs in
+        model order, a run of equal things ONE traced body under a scan
+        (the five equal periods; the ``ME`` pairs inside a period), every
+        layer taken out of its kind's stack by its index there."""
+        stacks = {"M": params["ssm_layers"], "*": params["layers"],
+                  "E": params["moe_layers"]}
+
+        def counts(node) -> dict:
+            if isinstance(node, str):
+                return {k: node.count(k) for k in stacks}
+            return {k: sum(n * counts(item)[k] for item, n in node)
+                    for k in stacks}
+
+        def sublayer(kind, carry, at):
+            h, full, lin, held = carry
+            at = jnp.asarray(at, jnp.int32)
+            lp = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
+                a, at, 0, keepdims=False), stacks[kind])
+            if kind == "M":
+                h, lin = ssm_layer(h, lp, lin, at)
+            elif kind == "*":
+                h, full = attend(h, lp, full, tables, at, False)
+            else:
+                h, n = ffn(h, lp, (stacks[kind], at))
+                held = held + n
+            return h, full, lin, held
+
+        def run(node, carry, base):
+            """``node`` from the stacks' indices ``base``: a string of
+            layers, or runs ``((node, times), ..)`` one after the other."""
+            if isinstance(node, str):
+                seen = dict.fromkeys(stacks, 0)
+                for kind in node:
+                    carry = sublayer(kind, carry, base[kind] + seen[kind])
+                    seen[kind] += 1
+                return carry
+            for item, times in node:
+                per = counts(item)
+                if times == 1:
+                    carry = run(item, carry, base)
+                else:
+                    carry, _ = jax.lax.scan(
+                        lambda c, i, item=item, per=per, base=base: (run(
+                            item, c, {k: base[k] + i * per[k]
+                                      for k in stacks}), None),
+                        carry, jnp.arange(times, dtype=jnp.int32))
+                base = {k: base[k] + times * per[k] for k in stacks}
+            return carry
+
+        return run(cfg.pattern_walk(), (
+            h, tuple(cache[:4]), tuple(cache.lin), jnp.asarray(no_counts)),
+            dict.fromkeys(stacks, 0))
+
+    if cfg.ssm:
+        h, full, inner, held = walk_pattern(h)
+        return _mixed_tail(h, full, inner, held, params, cfg, sample_src,
+                           mesh, with_held)
     full = tuple(cache[:4])
     per = cfg.inner_period
     if cfg.linear:
@@ -1541,12 +1879,20 @@ def mixed_step(
         h, inner, n = inner_layers(h, inner, inner0 + cfg.num_periods * per,
                                    cfg.inner_tail)
         held = held + n
+    return _mixed_tail(h, full, inner, held, params, cfg, sample_src, mesh,
+                       with_held)
+
+
+def _mixed_tail(h, full, inner, held, params: Params, cfg: ModelConfig,
+                sample_src, mesh, with_held: bool):
+    """What :func:`mixed_step` hands back behind its last layer: the logits
+    of the rows that sample, and the cache put together again."""
     with _scope("arks.lm_head"):
         h_sel = jnp.take(h[0], sample_src.astype(jnp.int32), axis=0)  # [B, E]
     logits = _unembed(h_sel, params, cfg, mesh, None)
     cache = PagedKVCache(*full,
                          win=PagedKVCache(*inner) if cfg.windowed else None,
-                         lin=LinearState(*inner) if cfg.linear else None)
+                         lin=LinearState(*inner) if cfg.recurrent else None)
     return (logits, cache, held) if with_held else (logits, cache)
 
 

@@ -113,8 +113,21 @@ class ShortcutCheckpointError(NotImplementedError):
     names."""
 
 
+class SsmCheckpointError(NotImplementedError):
+    """A checkpoint of a model with layers of one sublayer, Mamba-2
+    state-space mixers among them (``nemotron_h``): the name mapping of its
+    ``backbone.layers.N.mixer`` tensors (a mixer's ``in_proj``, ``conv1d``,
+    ``A_log``, ``D``, ``dt_bias``, grouped ``norm`` and ``out_proj``; an
+    attention layer's projections; a routed layer's ``gate``,
+    ``e_score_correction_bias``, two-matrix experts and shared expert) onto
+    the three stacked trees (``ssm_layers`` / ``layers`` / ``moe_layers``)
+    is not built; such a model is served from seeded random weights only.
+    Raised instead of stacking layers of three kinds into one tree."""
+
+
 def _refuse_latent(cfg: ModelConfig, path: str) -> None:
-    for is_kind, err in ((cfg.latent and cfg.linear,
+    for is_kind, err in ((cfg.ssm, SsmCheckpointError),
+                         (cfg.latent and cfg.linear,
                           LatentLinearCheckpointError),
                          (cfg.shortcut, ShortcutCheckpointError),
                          (cfg.latent, LatentCheckpointError),

@@ -347,12 +347,13 @@ def build_server(args: argparse.Namespace, engine):
             f"--disaggregation-mode {args.disagg} hands every layer's page "
             "of one pool between pods (kv_transfer, the AKV1 format); the "
             "two pools of such a model are not carried")
-    if args.disagg and engine.cfg.linear:
+    if args.disagg and engine.cfg.recurrent:
+        kind = engine.cfg.recurrent_kind
         raise ValueError(
-            f"model {engine.cfg.name!r} (linear-attention layers): "
+            f"model {engine.cfg.name!r} ({kind} layers): "
             f"--disaggregation-mode {args.disagg} hands every layer's page "
             "between pods (kv_transfer, the AKV1 format); the recurrent "
-            "state of such a model's linear layers is not carried")
+            f"state of such a model's {kind} layers is not carried")
     if args.disagg == "prefill":
         from arks_tpu.server.disagg import PrefillServer
         # No decode loop: the engine only runs detached prefills.

@@ -357,7 +357,8 @@ def test_quantised_experts_compile_for_v5e_as_stored(chip, kind, rows):
         num_experts=x, num_experts_per_tok=2, router_width=x,
         expert_parallel_size=1, expert_parallel_rank=0,
         scoring_func="softmax", norm_topk_prob=True,
-        routed_scaling_factor=1.0, swiglu_limit=0.0, zero_experts=0)
+        routed_scaling_factor=1.0, swiglu_limit=0.0, zero_experts=0,
+        expert_act="swiglu")
 
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
@@ -425,6 +426,44 @@ def test_the_delta_rules_state_update_compiles_for_v5e_in_place(
                                      else 100e6)
 
 
+@pytest.mark.parametrize("rows", [0, 1024])
+def test_the_selective_scans_state_update_compiles_for_v5e_in_place(
+        chip, rows, monkeypatch):
+    """A Mamba-2 layer's state update at Nemotron-3-Nano's widths (64 heads
+    of 64 over 8 groups, state 128, 64 slots: 134 MB of float32 state a
+    layer, 23 layers: 3.1 GB) on the cell's two step shapes, 64 rows (the
+    pipelined step: one recurrence step a lane, the kernel of
+    ``ops/ssm_state.py``) and 64 + 1024 (a chunk: the ``while`` over blocks
+    of 64 rows in the SSD form behind the kernel): the chip's compiler
+    takes both, and the layers' states are rewritten in place: the output
+    aliases the argument, and the one-row step holds no copy of even one
+    layer's states."""
+    from arks_tpu.models import transformer as tf
+
+    # ``_ssm_state`` asks this whether to interpret its kernel.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    h, p, n, g, slots, layers = 64, 64, 128, 8, 64, 23
+    t = slots + rows
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    f32, i32 = jnp.float32, jnp.int32
+    compiled = jax.jit(tf._ssm_state, donate_argnums=(5,)).lower(
+        spec((t, h, p), f32), spec((t, g, n), f32), spec((t, g, n), f32),
+        spec((t, h), f32), spec((t, h), f32),
+        spec((layers, slots, h // 2, n, 2 * p), f32), spec((), i32),
+        spec((slots,), i32), spec((slots,), i32), spec((slots,), jnp.bool_)
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "while" in text
+    mem = compiled.memory_analysis()
+    state = layers * slots * h * p * n * 4
+    assert mem.alias_size_in_bytes >= state
+    assert mem.temp_size_in_bytes < (state // layers + 200e6 if rows
+                                     else 100e6)
+
+
 # A share configuration of the benchmark: (chips a layer, slots, pages a
 # slot, pages of the full pool, of the window pool, quantised pages).
 SHARES = {
@@ -434,6 +473,7 @@ SHARES = {
     "gigachat3.5-432b-ep8-l5": (8, 64, 80, 64 * 80, 0, False),
     "mimo-v2.5-ep16-l13": (16, 64, 32, 1280, 64 * 6, True),
     "longcat-flash-ep32-l6": (32, 64, 20, 64 * 20, 0, False),
+    "nemotron-3-nano-30b-ep8": (8, 64, 20, 64 * 20, 0, True),
 }
 _STEPS: dict = {}
 
@@ -472,7 +512,7 @@ def _share_step(chip, monkeypatch, name: str, rows: int):
         lambda: tf.init_paged_cache(
             cfg, pages, 256, jnp.bfloat16, quantized=quantized,
             pad_head=True, win_pages=win_pages,
-            state_slots=slots if cfg.linear else 0)))
+            state_slots=slots if cfg.recurrent else 0)))
     kw = {"win_tables": ints(slots, max_pages)} if cfg.windowed else {}
     compiled = jax.jit(
         lambda p, c, *a, **k: tf.mixed_step(p, cfg, c, *a, with_held=True,
@@ -719,3 +759,27 @@ def test_a_whole_latent_linear_step_compiles_for_v5e_in_place(
     held = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
     assert mem.alias_size_in_bytes >= held
     assert mem.temp_size_in_bytes < (0.36e9 if rows else 0.2e9)
+
+
+@pytest.mark.parametrize("rows", [0, 1024])
+def test_a_whole_one_sublayer_step_compiles_for_v5e_in_place(
+        chip, rows, monkeypatch):
+    """``mixed_step`` of the ``nemotron_h`` block at Nemotron-3-Nano's
+    published widths and WHOLE depth, this chip's share (16 of 128 experts,
+    all 52 layers: 5.3 GB of int8 weights), on the cell's two step shapes
+    over 64 slots x 20 pages: the walk over periods of unequal length (five
+    equal ones one body), the selective scan's kernel and chunked scan, the
+    ragged GQA launch at g = 16 over 2 KV heads, the dispatches of
+    two-matrix experts.  The chip's compiler takes both; the state (3.1 GB)
+    and the pages are rewritten in place, and a step's temporaries leave
+    room beside 9.6 GB of weights and caches on a 16 GB chip."""
+    cfg, cache, compiled = _share_step(chip, monkeypatch,
+                                       "nemotron-3-nano-30b-ep8", rows)
+    assert cache.k.shape == (6, 64 * 20, 2, 256, 128)
+    assert cache.lin.s.shape == (23, 64, 32, 128, 128)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3     # write, attend, the scan
+    mem = compiled.memory_analysis()
+    held = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
+    assert mem.alias_size_in_bytes >= held
+    assert mem.temp_size_in_bytes < (0.6e9 if rows else 0.3e9)

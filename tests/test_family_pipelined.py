@@ -9,7 +9,7 @@ import harness
 
 @pytest.mark.parametrize("preset", [
     "tiny-linear-moe", "tiny-latent-linear-moe", "tiny-swa-moe",
-    "tiny-shortcut-mla-moe"])
+    "tiny-shortcut-mla-moe", "tiny-ssm-moe"])
 def test_the_pipelined_path_gives_the_sequential_streams(preset, monkeypatch):
     """Depth 2 runs a step ahead of the host's lengths: a lane the device
     found dead takes no recurrence step, the window pages are covered from

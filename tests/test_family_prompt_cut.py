@@ -30,6 +30,10 @@ BLOCKS = {
     "tiny-linear-moe": dict(family="linear_moe", norms=False, rounded=1e-2),
     "tiny-latent-linear-moe": dict(family="latent_linear_moe", norms=True,
                                    rounded=5e-3),
+    # (The selective scan's state is a few percent of what a mixer returns
+    # under seeded weights, the skip ``D x`` the rest: a rounded state parts
+    # by less than the delta rule's, and still by fifteen times the 2e-4.)
+    "tiny-ssm-moe": dict(family="ssm_moe", norms=False, rounded=3e-3),
 }
 
 
@@ -70,7 +74,7 @@ def stepper(request):
 
     def fresh_cache():
         return tf.init_paged_cache(cfg, slots * max_pages, page, jnp.float32,
-                                   state_slots=slots if cfg.linear else 0)
+                                   state_slots=slots if cfg.recurrent else 0)
 
     def run(cache, lanes, rows=100):
         """One step over ``lanes``: {slot: (token ids, first position)}.
@@ -123,11 +127,11 @@ def test_a_prompt_cut_at_odd_lengths_then_decoded_is_the_full_forward(
         == cfg.num_full_layers * (2 if cfg.shortcut else 1)
     assert (cache.v is None) == bool(cfg.latent)
     assert (cache.lin is None) == bool(cfg.shortcut)
-    if cfg.linear:
+    if cfg.recurrent:
         assert cache.lin.s.shape[0] == cfg.num_linear_layers == 6
     # Slot 0 is left dirty by sequence C, which then ends.
     _, cache = run(cache, {0: (c_ids, 0)})
-    assert not cfg.linear or float(jnp.abs(cache.lin.s[:, 0]).max()) > 0
+    assert not cfg.recurrent or float(jnp.abs(cache.lin.s[:, 0]).max()) > 0
     got_a, got_b, pa, pb = [], [], 0, 0
     plan = [(70, 20), (63, 1), (1, 1), (1, 30), (37, 1), (1, 37), (1, 0),
             (1, 0), (5, 0)]
@@ -148,7 +152,7 @@ def test_a_prompt_cut_at_odd_lengths_then_decoded_is_the_full_forward(
             # chunk form's triangular solve against the token recurrence.
             assert np.abs(lg - w).max() < 2e-4 * w.std() + 1e-6, r
     # Slot 1 and 3 were never touched.
-    if cfg.linear:
+    if cfg.recurrent:
         assert not float(jnp.abs(cache.lin.s[:, (1, 3)]).max())
     else:
         assert not float(jnp.abs(cache.k[:, 16:32]).max())
@@ -215,3 +219,58 @@ def test_program_and_reference_hold_the_same_reading_of_every_open_key(
     for lg, s, o in zip((got1[3], got2[3]), same, other):
         assert np.abs(lg - s).max() < 2e-4 * s.std() + 1e-6
         assert np.abs(lg - o).max() > 0.02 * s.std(), reading
+
+
+@pytest.mark.parametrize("stepper", ["tiny-ssm-moe"], indirect=True)
+@pytest.mark.parametrize("chunk", [1, 17, 64, 100, 150])
+def test_prefill_in_chunks_of_any_size_then_decode_is_the_full_forward(
+        stepper, chunk):
+    """A prompt of 150 tokens prefilled ``chunk`` rows a step (one row a
+    step: the one-step kernel all the way; 17: ends inside a block of the
+    scan and inside the convolution's reach; 64 and 150: whole blocks, one
+    step), then three decode steps through the pages, the carry and the
+    state: every step's logits are the reference's one full forward."""
+    fresh_cache, run, want, _ = stepper
+    rng = np.random.default_rng(12)
+    ids = rng.integers(2, 258, 153).astype(np.int32)
+    cache, got, at = fresh_cache(), [], 0
+    for n in [chunk] * (150 // chunk) + [150 % chunk] * bool(150 % chunk) \
+            + [1, 1, 1]:
+        out, cache = run(cache, {3: (ids[at:at + n], at)}, rows=160)
+        at += n
+        got.append((at - 1, out[3]))
+    got = got[-8:]
+    for (r, lg), w in zip(got, want(ids, [r for r, _ in got])):
+        assert np.abs(lg - w).max() < 2e-4 * w.std() + 1e-6, (chunk, r)
+
+
+# What dropping one published term of a mixer or of a router does to the
+# logits at this size, in logit sigmas, as read (the largest of two
+# positions; tests/test_ssm_layers.py says why the state and the decay's
+# rate read low at a width of 64): each at least a third of its reading, and
+# ten times and more what program and reference agree to.
+_SSM_TERMS = {"state": 0.1, "conv": 0.5, "conv_bias": 0.1, "a_log": 2e-3,
+              "d_skip": 0.5, "gate": 1.0, "router_bias": 0.1}
+
+
+@pytest.mark.parametrize("stepper", ["tiny-ssm-moe"], indirect=True)
+@pytest.mark.parametrize("term", sorted(_SSM_TERMS))
+def test_every_published_term_of_the_one_sublayer_block_is_live(stepper,
+                                                                term):
+    """The state, the convolution's older taps and its bias, the decay's
+    rate ``A_log``, the skip ``D``, the gate ``silu(z)`` and the selection
+    bias: the reference computed WITHOUT one of them on the same seeded
+    weights parts from the program, which agrees with the whole one."""
+    fresh_cache, run, want, _ = stepper
+    rng = np.random.default_rng(9)
+    ids = rng.integers(2, 258, 120).astype(np.int32)
+    cache = fresh_cache()
+    got1, cache = run(cache, {3: (ids[:90], 0)}, rows=100)
+    got2, _ = run(cache, {3: (ids[90:], 90)}, rows=100)
+    same, other = (want(ids, [89, 119], **over) for over in (
+        {}, {"reference_without": [term]}))
+    far = 0.0
+    for lg, s, o in zip((got1[3], got2[3]), same, other):
+        assert np.abs(lg - s).max() < 2e-4 * s.std() + 1e-6
+        far = max(far, np.abs(lg - o).max() / s.std())
+    assert far > _SSM_TERMS[term], (term, far)

@@ -23,8 +23,34 @@ _FILE = {
     "tiny-mla-moe": dict(n_routed_experts=16),
     "tiny-swa-sink-moe": {},
     "tiny-shortcut-mla-moe": dict(n_routed_experts=16),
+    "tiny-ssm-moe": dict(n_routed_experts=16),
 }
 _UNREADABLE = {
+    "tiny-ssm-moe": [
+        (dict(hybrid_override_pattern="MEM*EMEM*EM*EM-"), "a '-' layer"),
+        (dict(hybrid_override_pattern="MEM*EMEM*EM*EMA"), "one of M, E,"),
+        (dict(hybrid_override_pattern="MEM*EMEM*EM*EM"), "15 layers"),
+        (dict(hybrid_override_pattern="MEMEEMEMEEMEEME"), "at least one M"),
+        (dict(hybrid_override_pattern="*E**E*E**E**E*E"), "at least one M"),
+        (dict(mlp_hidden_act="silu"), "mlp_hidden_act"),
+        (dict(mamba_hidden_act="gelu"), "mamba_hidden_act"),
+        (dict(use_conv_bias=False), "use_conv_bias"),
+        (dict(mamba_proj_bias=True), "mamba_proj_bias"),
+        (dict(attention_bias=True), "attention_bias"),
+        (dict(mlp_bias=True), "mlp_bias"),
+        (dict(n_group=4, topk_group=2), "group-limited"),
+        (dict(n_shared_experts=2), "n_shared_experts"),
+        (dict(n_groups=3), "mamba_num_heads=8 over n_groups=3"),
+        (dict(num_key_value_heads=3), "KV heads"),
+        (dict(sliding_window=64), "sliding_window"),
+        (dict(ssm_state_size=None), "without ssm_state_size"),
+        (dict(conv_kernel=0), "without conv_kernel"),
+        # The other readers refuse what only this one reads.
+        (dict(model_type="solar_open2"), "only model_type 'nemotron_h'"),
+        (dict(model_type="llama", hybrid_override_pattern=None,
+              mamba_num_heads=None, mamba_head_dim=None,
+              mamba_hidden_act=None), "ssm_state_size"),
+    ],
     "tiny-shortcut-mla-moe": [
         (dict(zero_expert_type="copy"), "zero_expert_type"),
         (dict(router_bias=True), "router_bias"),
@@ -181,6 +207,8 @@ _UNSERVABLE = {
     "tiny-linear-moe": _ONE_POOL + [
         (dict(kv_pool_pages=16), {}, "kv_pool_pages", False)],
     "tiny-swa-moe": _ONE_POOL,
+    "tiny-ssm-moe": _ONE_POOL + [
+        (dict(kv_pool_pages=16), {}, "kv_pool_pages", False)],
     "tiny-latent-linear-moe": _narrow_rows("int8 / int4 latent row")
     + _ONE_POOL,
     "tiny-mla-moe": _narrow_rows("bf16 only") + _ONE_POOL,
@@ -194,6 +222,8 @@ _IS = {
                         "beside GQA layers over pages", ""),
     "tiny-swa-moe": ("window and full attention layers over two page pools",
                      ""),
+    "tiny-ssm-moe": ("state-space layers with a fixed state a slot beside "
+                     "GQA layers over pages", ""),
     "tiny-latent-linear-moe": (
         "linear-attention layers with a fixed state a slot beside "
         "latent-attention layers over one latent row a token",
@@ -232,6 +262,11 @@ _UNSHARDED = {
                         [], "0/1", "recurrent state", None),
     "tiny-swa-moe": ("layers of two head counts", "two head counts",
                      [], "0/1", "two pools", None),
+    "tiny-ssm-moe": ("state-space layers and their state have no sharding",
+                     "a share of the heads is not built",
+                     ["--expert-parallel-size", "2",
+                      "--expert-parallel-rank", "1"], "1/2",
+                     "state of such a model's state-space layers", None),
     "tiny-latent-linear-moe": (
         "neither the latent block nor the linear layers' state",
         "sharding rules", [], "0/1", "nor the recurrent state",
@@ -286,6 +321,14 @@ def test_a_share_names_a_rank_out_of_range_and_a_model_without_experts():
         get_config("tiny-mla-moe").with_expert_share(2, 2)
     with pytest.raises(ValueError, match="no routed experts"):
         get_config("tiny").with_expert_share(2, 0)
+
+
+def test_a_checkpoint_of_the_one_sublayer_block_is_refused_by_name(tmp_path):
+    from arks_tpu.models import weights
+    (tmp_path / "model.safetensors").write_bytes(b"")
+    with pytest.raises(weights.SsmCheckpointError,
+                       match="ssm_layers.*seeded random weights only"):
+        weights.load_params(get_config("tiny-ssm-moe"), str(tmp_path))
 
 
 def test_a_checkpoint_of_the_shortcut_block_is_refused_by_name(tmp_path):

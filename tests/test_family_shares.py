@@ -18,8 +18,11 @@ from arks_tpu.models.config import get_config
 # every block but ``tiny-swa-moe`` (softmax); ``tiny-swa-sink-moe`` has no
 # shared expert to count once; ``tiny-shortcut-mla-moe`` has softmax scores
 # WITH a selection bias, no shared expert, and 8 identity experts behind the
-# 16 real ones, whose part is what every chip computes alike.
+# 16 real ones, whose part is what every chip computes alike;
+# ``tiny-ssm-moe`` experts of TWO matrices (relu^2, no gate matrix, the
+# shared expert alike), top-2.
 _BLOCKS = [
+    ("tiny-ssm-moe", 8, (True, False)),
     ("tiny-shortcut-mla-moe", 4, (True, False)),
     ("tiny-linear-moe", 8, (True, False)),
     ("tiny-latent-linear-moe", 8, (True, False)),
@@ -42,10 +45,17 @@ def test_the_shares_of_a_routed_layer_add_up_to_the_uncut_layer(
         preset not in ("tiny-swa-sink-moe", "tiny-shortcut-mla-moe"))
     x = jax.random.normal(jax.random.PRNGKey(2), (1, 96, 64), jnp.float32)
     valid = jnp.ones((1, 96), bool)
+    # An expert of two matrices has no gate matrix, nor has its shared one.
+    assert ("w_gate" in mp) == ("w_up" in mp) == ("w_upt" not in mp) \
+        == (preset != "tiny-ssm-moe")
+    assert ("shared_gate_proj" in mp) == (
+        "shared_up" in mp and preset != "tiny-ssm-moe")
+    experts = [k for k in ("w_gate", "w_up", "w_upt", "w_down") if k in mp]
+    top_k = cfg.num_experts_per_tok
     whole, pairs = moe.moe_ffn(x, mp, cfg, grouped=False, row_valid=valid)
-    # top-4 of every row; the pairs on an identity expert counted apart
+    # top-k of every row; the pairs on an identity expert counted apart
     zero = int(pairs[3]) if cfg.zero_experts else 0
-    assert pairs.tolist() == [96 * 4 - zero, 0, 0] + [zero] * bool(
+    assert pairs.tolist() == [96 * top_k - zero, 0, 0] + [zero] * bool(
         cfg.zero_experts)
     assert (zero > 60) == bool(cfg.zero_experts)      # a third, seeded
     if cfg.swiglu_limit:
@@ -66,7 +76,7 @@ def test_the_shares_of_a_routed_layer_add_up_to_the_uncut_layer(
     total, held_all = jnp.zeros_like(whole), 0
     for rank in range(chips):
         part = dict(mp, **{k: mp[k][rank * held:(rank + 1) * held]
-                           for k in ("w_gate", "w_up", "w_down")})
+                           for k in experts})
         out, pairs = moe.moe_ffn(x, part,
                                  part_cfg.with_expert_share(chips, rank),
                                  grouped=grouped, row_valid=valid)
@@ -75,7 +85,7 @@ def test_the_shares_of_a_routed_layer_add_up_to_the_uncut_layer(
         # Every chip counts the identity pairs alike: routed = held here +
         # zero + absent, the absent ones held on the other chips.
         assert pairs[3:].tolist() == [zero] * bool(cfg.zero_experts)
-    assert held_all + zero == 96 * 4     # every chosen pair lands on one chip
+    assert held_all + zero == 96 * top_k  # every chosen pair lands on a chip
     np.testing.assert_allclose(np.asarray(total + shared),
                                np.asarray(whole), rtol=2e-4, atol=2e-6)
 

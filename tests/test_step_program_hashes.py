@@ -340,7 +340,8 @@ def whole_layer_hash() -> str:
         num_experts=x, num_experts_per_tok=2, router_width=x,
         expert_parallel_size=1, expert_parallel_rank=0,
         scoring_func="softmax", norm_topk_prob=True,
-        routed_scaling_factor=1.0, swiglu_limit=0.0, zero_experts=0)
+        routed_scaling_factor=1.0, swiglu_limit=0.0, zero_experts=0,
+        expert_act="swiglu")
     assert moe._held_capacity(rows, cfg) == 128
 
     def leaf(k, n):
