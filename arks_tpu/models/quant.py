@@ -31,7 +31,8 @@ embedding [V, E] carries s = [V, 1]); int4 = ``{"q": int4 [.., K, N],
 stored head-split with the contraction dimension minor, ``[L, H, D, E]``
 (`transformer.init_params` says why): their scales are ``[L, H, D, 1]`` /
 ``[L, H, D, E/G]``, the same numbers as the ``[L, E, H x D]`` leaf's
-(:func:`contraction_axis` tells the two apart).
+(:func:`contraction_axis` tells the two apart).  So are the latent
+block's ``wq_b`` / ``wkv_b``, ``[.., H, D, K]``.
 """
 
 from __future__ import annotations
@@ -60,7 +61,8 @@ MATMUL_KEYS = frozenset({
     "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "lm_head",
     "shared_gate_proj", "shared_up", "shared_down",
     # The latent block (transformer.py): query down / up, the latent's
-    # down-projection, and [W_uk | W_uv], which the step absorbs.
+    # down-projection, and [W_uk | W_uv], which the step absorbs (the two
+    # up projections head-split: LATENT_SPLIT_KEYS).
     "wq_a", "wq_b", "wkv_a", "wkv_b",
     # The ``solar_open2`` block: a GQA layer's elementwise output gate, a
     # linear layer's low-rank decay and gate pairs.
@@ -86,13 +88,19 @@ HEAD_SPLIT_KEYS = frozenset({"wq", "wk", "wv"})
 # (a Mamba-2 mixer's input projection: `transformer._init_ssm_params`; a
 # two-matrix expert's: `moe.init_moe_params`).
 TRANSPOSED_KEYS = frozenset({"w_in", "w_upt"})
+# The latent block's two up projections, head-split as a GQA stack's are,
+# ``[L, H, nope + rope, q_lora]`` / ``[L, H, nope + v, kv_lora]`` (``[L, 2,
+# H, ..]`` in the shortcut block: `transformer._init_latent_params`).  No
+# other leaf has these names: head-split at every rank.
+LATENT_SPLIT_KEYS = frozenset({"wq_b", "wkv_b"})
 
 
 def contraction_axis(name: str, ndim: int) -> int:
     """The contraction dimension of a STACKED matmul leaf: -2 (``[.., K,
-    N]``), and -1 for a head-split projection ``[L, H, D, E]``, the only
-    leaf of rank 4 under those names, and for a transposed one."""
-    return -1 if name in TRANSPOSED_KEYS or (
+    N]``), and -1 for a head-split projection: ``[L, H, D, E]``, the only
+    leaf of rank 4 under a GQA stack's names, a latent up projection at
+    any rank; and for a transposed one."""
+    return -1 if name in TRANSPOSED_KEYS or name in LATENT_SPLIT_KEYS or (
         name in HEAD_SPLIT_KEYS and ndim == 4) else -2
 
 
@@ -324,9 +332,10 @@ def init_params_quantized(cfg, key, dtype=jnp.bfloat16, bits: int = 8,
             return tf.scale_conv_taps(w.astype(dtype))
         return w.astype(dtype)
 
-    # A head-split projection is drawn and quantised as the [L, E, H x D]
+    # A head-split projection (a GQA stack's q / k / v, the latent block's
+    # two up projections) is drawn and quantised as the [.., E, H x D]
     # matmul it is, by the program every other matmul leaf takes, and
-    # stored [L, H, D, E] by a program of its own: fused into ONE, the
+    # stored [.., H, D, E] by a program of its own: fused into ONE, the
     # chip's compiler divides ``w / s`` another way and 3.8 % of the int8
     # values come out one step off the values the same draw gives in the
     # drawn order (PERF.md section 6, PR 48), which is what a seed means

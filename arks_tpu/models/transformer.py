@@ -204,10 +204,15 @@ def _init_latent_params(cfg: ModelConfig, key: jax.Array,
     """The DeepSeek-V3 / kimi_k2 tree: ``dense_layers`` (the first
     ``first_k_dense`` layers, SwiGLU of ``intermediate_size``) and
     ``layers`` (the routed ones), each stacked, both with the latent
-    attention's leaves: ``wq_a`` [E, q_lora], ``q_norm``, ``wq_b``
-    [q_lora, H x (nope + rope)], ``wkv_a`` [E, kv_lora + rope],
-    ``kv_norm``, ``wkv_b`` [kv_lora, H x (nope + v)] (per head
-    ``[W_uk | W_uv]``, as ``kv_b_proj`` lays them out), ``wo`` [H x v, E]."""
+    attention's leaves: ``wq_a`` [E, q_lora], ``q_norm``, ``wq_b`` [H,
+    nope + rope, q_lora], ``wkv_a`` [E, kv_lora + rope], ``kv_norm``,
+    ``wkv_b`` [H, nope + v, kv_lora] (per head ``[W_uk | W_uv]``, as
+    ``kv_b_proj`` lays them out), ``wo`` [H x v, E].  The two up
+    projections are stored head-split, contraction dimension minor
+    (:func:`split_heads`; :func:`init_params` says why), and are the
+    published ``[out, in]`` ``q_b_proj`` / ``kv_b_proj`` but for a reshape;
+    their numbers are those of the ``[q_lora, H x (nope + rope)]`` /
+    ``[kv_lora, H x (nope + v)]`` draws."""
     e, f, v, h = (cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size,
                   cfg.num_heads)
     keys = iter(jax.random.split(key, 24))
@@ -221,11 +226,11 @@ def _init_latent_params(cfg: ModelConfig, key: jax.Array,
             "attn_norm": jnp.ones((l, e), dtype),
             "wq_a": w((l, e, cfg.q_lora_rank)),
             "q_norm": jnp.ones((l, cfg.q_lora_rank), dtype),
-            "wq_b": w((l, cfg.q_lora_rank, cfg.q_dim)),
+            "wq_b": split_heads(w((l, cfg.q_lora_rank, cfg.q_dim)), h),
             "wkv_a": w((l, e, cfg.latent_row)),
             "kv_norm": jnp.ones((l, cfg.kv_lora_rank), dtype),
-            "wkv_b": w((l, cfg.kv_lora_rank,
-                        h * (cfg.qk_nope_head_dim + cfg.v_head_dim))),
+            "wkv_b": split_heads(w((l, cfg.kv_lora_rank, h * (
+                cfg.qk_nope_head_dim + cfg.v_head_dim))), h),
             "wo": w((l, cfg.attn_out_dim, e)),
             "mlp_norm": jnp.ones((l, e), dtype),
         }
@@ -273,11 +278,11 @@ def _init_shortcut_params(cfg: ModelConfig, key: jax.Array,
         "attn_norm": jnp.ones((l, 2, e), dtype),
         "wq_a": w((l, 2, e, cfg.q_lora_rank)),
         "q_norm": jnp.ones((l, 2, cfg.q_lora_rank), dtype),
-        "wq_b": w((l, 2, cfg.q_lora_rank, cfg.q_dim)),
+        "wq_b": split_heads(w((l, 2, cfg.q_lora_rank, cfg.q_dim)), h),
         "wkv_a": w((l, 2, e, cfg.latent_row)),
         "kv_norm": jnp.ones((l, 2, cfg.kv_lora_rank), dtype),
-        "wkv_b": w((l, 2, cfg.kv_lora_rank,
-                    h * (cfg.qk_nope_head_dim + cfg.v_head_dim))),
+        "wkv_b": split_heads(w((l, 2, cfg.kv_lora_rank, h * (
+            cfg.qk_nope_head_dim + cfg.v_head_dim))), h),
         "wo": w((l, 2, cfg.attn_out_dim, e)),
         "mlp_norm": jnp.ones((l, 2, e), dtype),
         "ffn_gate": w((l, 2, e, f)), "ffn_up": w((l, 2, e, f)),
@@ -492,11 +497,11 @@ def _init_latent_linear_params(cfg: ModelConfig, key: jax.Array,
         out = dict(norms(l), **{
             "wq_a": w((l, e, cfg.q_lora_rank)),
             "q_norm": unit((l, cfg.q_lora_rank), dtype),
-            "wq_b": w((l, cfg.q_lora_rank, cfg.q_dim)),
+            "wq_b": split_heads(w((l, cfg.q_lora_rank, cfg.q_dim)), h),
             "wkv_a": w((l, e, cfg.latent_row)),
             "kv_norm": unit((l, cfg.kv_lora_rank), dtype),
-            "wkv_b": w((l, cfg.kv_lora_rank,
-                        h * (cfg.qk_nope_head_dim + cfg.v_head_dim))),
+            "wkv_b": split_heads(w((l, cfg.kv_lora_rank, h * (
+                cfg.qk_nope_head_dim + cfg.v_head_dim))), h),
             "wo": w((l, cfg.attn_out_dim, e))})
         if cfg.attn_out_gate:
             out["wg"] = w((l, e, cfg.attn_out_dim))
@@ -591,11 +596,15 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype: jnp.dtype | None = None
     of mimo's device time; PERF.md section 6, PR 48).  Head-split, the dot's
     fusion takes the stacked leaf and the layer index and reads the bytes
     once.  One order for all three leaves of every GQA stack.  The latent
-    block's ``wq_a`` / ``wq_b`` / ``wkv_*`` and the linear layers' ``wq`` /
-    ``wk`` / ``wv`` (another consumer: no head-major dot) are plain
-    matmuls; a quantised leaf's scales follow its leaf
-    (`quant.contraction_axis`).  Biases ``bq`` / ``bk`` / ``bv`` stay [L, H
-    x D]."""
+    block's two up projections are stored so too, for the same reason
+    (their copies and slices were 7 % of longcat's device time; PERF.md
+    section 6, PR 57): ``wq_b`` [L, H, nope + rope, q_lora] and ``wkv_b``
+    [L, H, nope + v, kv_lora] (``[L, 2, H, ..]`` in the shortcut block),
+    one order for every latent stack.  The latent block's ``wq_a`` /
+    ``wkv_a`` and the linear layers' ``wq`` / ``wk`` / ``wv`` (another
+    consumer: no head-major dot) are plain matmuls; a quantised leaf's
+    scales follow its leaf (`quant.contraction_axis`).  Biases ``bq`` /
+    ``bk`` / ``bv`` stay [L, H x D]."""
     dtype = dtype or jnp.dtype(cfg.dtype)
     if cfg.ssm:
         return _init_ssm_params(cfg, key, dtype)
@@ -1032,13 +1041,12 @@ def _unembed(h_last: jnp.ndarray, params: Params, cfg: ModelConfig,
 
 def _wkv_b(lp: Params, cfg: ModelConfig, dtype) -> tuple[jnp.ndarray,
                                                          jnp.ndarray]:
-    """``kv_b_proj`` as the absorbed form uses it: (W_uk [C, H, nope],
-    W_uv [C, H, v]) with C = kv_lora_rank, widened to ``dtype``."""
+    """``kv_b_proj`` as the absorbed form uses it: (W_uk [H, nope, C],
+    W_uv [H, v, C]) with C = kv_lora_rank, widened to ``dtype``: the stored
+    order (:func:`_init_latent_params`), no reshape."""
     from arks_tpu.models.quant import dequantize
-    w = dequantize(lp["wkv_b"], dtype).reshape(
-        cfg.kv_lora_rank, cfg.num_heads,
-        cfg.qk_nope_head_dim + cfg.v_head_dim)
-    return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
+    w = dequantize(lp["wkv_b"], dtype)
+    return w[:, :cfg.qk_nope_head_dim], w[:, cfg.qk_nope_head_dim:]
 
 
 @_scope("arks.mla_q")
@@ -1049,16 +1057,14 @@ def _mla_q(x: jnp.ndarray, lp: Params, cfg: ModelConfig,
     a head's score against a cached row is one dot over the row.  The
     query latent is scaled in its norm (``cfg.mla_q_scale``; the up
     projection is linear, so every lane of every head is)."""
-    b, t = x.shape[:2]
     cq = _norm(qeinsum("...e,er->...r", x, lp["wq_a"]), lp["q_norm"], cfg,
                cfg.mla_q_scale)
-    q = qeinsum("...r,rq->...q", cq, lp["wq_b"]).reshape(
-        b, t, cfg.num_heads, cfg.head_dim)
+    q = qeinsum("...r,hdr->...hd", cq, lp["wq_b"])
     q_nope, q_rope = (q[..., :cfg.qk_nope_head_dim],
                       q[..., cfg.qk_nope_head_dim:])
     q_rope = apply_rope(q_rope, positions, cfg.rope_theta, cfg.rope_yarn)
     w_uk, _ = _wkv_b(lp, cfg, x.dtype)
-    q_abs = jnp.einsum("bthn,chn->bthc", q_nope, w_uk)
+    q_abs = jnp.einsum("bthn,hnc->bthc", q_nope, w_uk)
     return jnp.concatenate([q_abs, q_rope], axis=-1)
 
 
@@ -1085,7 +1091,7 @@ def _mla_out(attn: jnp.ndarray, lp: Params, cfg: ModelConfig,
     ``sigmoid(x Wg)`` elementwise over the H x v outputs, from the
     sublayer's normed input ``x`` [T, E], then the output projection."""
     _, w_uv = _wkv_b(lp, cfg, attn.dtype)
-    o = jnp.einsum("thc,chv->thv", attn, w_uv)
+    o = jnp.einsum("thc,hvc->thv", attn, w_uv)
     o = o.reshape(o.shape[0], cfg.attn_out_dim)
     if cfg.attn_out_gate:
         with _scope("arks.mla_gate"):

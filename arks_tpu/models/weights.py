@@ -390,7 +390,8 @@ def check_restored_shapes(restored, template, path: str) -> None:
     """Orbax hands back a leaf in the shape it was SAVED in, whatever the
     template says: a checkpoint written with another stored order (the
     q / k / v leaves were [L, E, H x D] until they became [L, H, D, E],
-    `tf.init_params`) is refused here, by leaf, and not by an einsum
+    the latent block's ``wq_b`` / ``wkv_b`` [L, K, H x D] until [L, H, D,
+    K]: `tf.init_params`) is refused here, by leaf, and not by an einsum
     deep in the first step."""
     flat = jax.tree_util.tree_flatten_with_path(template)[0]
     for (keys, want), got in zip(flat, jax.tree.leaves(restored)):

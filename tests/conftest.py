@@ -169,11 +169,13 @@ def seeded_tree_as_drawn(monkeypatch):
     compare ``init_params_quantized``'s tree with the reference's leaf for
     leaf in the shape a leaf is DRAWN in, ``[L, E, H x D]`` (files of the
     benchmark: not every PR's to edit).  Since PR 48 the program stores the
-    GQA stacks' q / k / v projections ``[L, H, D, E]`` (``tf.init_params``):
+    GQA stacks' q / k / v projections ``[L, H, D, E]`` (``tf.init_params``),
+    since PR 57 the latent block's ``wq_b`` / ``wkv_b`` ``[.., H, D, K]``:
     the same numbers, transposed.  Those cases see the stored tree in the
     drawn order here; the stored order itself is held by
     ``tests/test_quant.py``."""
     import jax
+    import harness
     from arks_tpu.models import quant
     stored = quant.init_params_quantized
 
@@ -182,11 +184,10 @@ def seeded_tree_as_drawn(monkeypatch):
         for name, leaf in tree.items():
             if isinstance(leaf, dict) and not quant.is_quantized(leaf):
                 out[name] = drawn(leaf)
-            elif name in quant.HEAD_SPLIT_KEYS and jax.tree.leaves(
-                    leaf)[0].ndim == 4:
-                out[name] = jax.tree.map(
-                    lambda a: a.reshape(a.shape[0], -1, a.shape[-1])
-                    .swapaxes(-1, -2), leaf)
+            elif name in quant.LATENT_SPLIT_KEYS or (
+                    name in quant.HEAD_SPLIT_KEYS
+                    and jax.tree.leaves(leaf)[0].ndim == 4):
+                out[name] = jax.tree.map(harness.as_drawn, leaf)
             else:
                 out[name] = leaf
         return out

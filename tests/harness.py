@@ -50,6 +50,14 @@ SHAPE = {
 DECODE = {"tiny-swa-moe": 12, "tiny-swa-sink-moe": 12}
 
 
+def as_drawn(w):
+    """A head-split leaf ``[.., H, D, K]`` (``tf.split_heads``: a GQA
+    stack's q / k / v, the latent block's ``wq_b`` / ``wkv_b``) in the order
+    it is DRAWN in, ``[.., K, H x D]``: the same numbers, which is what the
+    reference families generate and a checkpoint of the old order held."""
+    return w.reshape(*w.shape[:-3], -1, w.shape[-1]).swapaxes(-1, -2)
+
+
 def published(name: str, **restored) -> dict:
     """``benchmarks/configs/<name>/config.json`` with ``restored`` laid over
     it: what a benchmark file's ``reduced`` lists put back, or a tiny

@@ -27,6 +27,7 @@ pytestmark = pytest.mark.usefixtures("_registry_and_environment_restored")
 
 
 from benchmarks.tests import test_reference as _decoder  # noqa: E402
+from benchmarks.tests import test_reference_mla_moe as _mla_moe  # noqa: E402
 from benchmarks.tests.test_manifest import (  # noqa: E402,F401
     test_a_new_cell_loads_from_added_files_alone,
     test_a_new_family_loads_from_added_files_alone,
@@ -40,8 +41,6 @@ from benchmarks.tests.test_reference import (  # noqa: E402,F401
     test_the_routing_margin_is_small_where_two_experts_tie,
 )
 from benchmarks.tests.test_reference_mla_moe import (  # noqa: E402,F401
-    test_seeded_weights_are_the_programs_bit_for_bit as
-    test_mla_moe_seeded_weights_are_the_programs_bit_for_bit,
     test_served_logprobs_against_the_reference as
     test_mla_moe_served_logprobs_against_the_reference,
     test_the_family_keeps_the_contract_and_imports_nothing_of_the_program,
@@ -69,3 +68,8 @@ from benchmarks.tests.test_step_clock_readers import (  # noqa: E402,F401
 def test_seeded_weights_are_the_programs_bit_for_bit(name,
                                                      seeded_tree_as_drawn):
     _decoder.test_seeded_weights_are_the_programs_bit_for_bit(name)
+
+
+def test_mla_moe_seeded_weights_are_the_programs_bit_for_bit(
+        seeded_tree_as_drawn):
+    _mla_moe.test_seeded_weights_are_the_programs_bit_for_bit()

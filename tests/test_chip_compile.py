@@ -542,12 +542,26 @@ def _outside_fusions(text: str):
 def _unfused_s8(text: str) -> list:
     """``(dims, line)`` of every int8 array a compiled program defines as
     the result of a ``copy`` or a ``fusion`` outside its fused
-    computations."""
+    computations, a result that is a TUPLE of arrays included (a
+    multi-output fusion: every int8 member counts)."""
     import re
-    made = re.compile(r"= s8\[([\d,]+)\]\S* (?:fusion|copy)\(")
-    return [(tuple(map(int, m.group(1).split(","))), line.strip()[:120])
+    made = re.compile(r"= ([^%=]*?) (?:fusion|copy)\(")
+    return [(tuple(map(int, dims.split(","))), line.strip()[:120])
             for line in _outside_fusions(text)
-            for m in [made.search(line)] if m]
+            for m in [made.search(line)] if m
+            for dims in re.findall(r"\bs8\[([\d,]+)\]", m.group(1))]
+
+
+def _writes_none_of(compiled, sizes: dict, temp_mb: float) -> None:
+    """Outside its fusions ``compiled`` defines no int8 buffer with one of
+    the element counts ``sizes`` names (a leaf in any shape or layout), and
+    its temporaries stand under ``temp_mb``."""
+    assert sizes
+    found = [(sizes[math.prod(dims)], line)
+             for dims, line in _unfused_s8(compiled.as_text())
+             if math.prod(dims) in sizes]
+    assert not found, found
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_mb * 1e6
 
 
 @pytest.mark.parametrize("name,rows,temp_mb", [
@@ -583,9 +597,9 @@ def test_a_shares_chunk_step_copies_no_expert_leaf_out_of_its_stack(
         # (PR 54: taken through the scan's slice, both sublayers' dots were
         # its users and every layer wrote 0.64 GB out before reading it;
         # `mixed_step`'s ``shortcut_layer`` takes (layer, sublayer) out of
-        # the stacked tree a use).  What is left are the stacked ``wq_b`` and
-        # ``wkv_b`` transposed whole once a step, as the latent block's
-        # layer slices of them are (ROADMAP S12).
+        # the stacked tree a use).  The stacked ``wq_b`` / ``wkv_b`` are
+        # read in place too since PR 57 (the test of the latent up
+        # projections below).
         by_sublayer = [line for dims, line in _unfused_s8(text)
                        if dims[0] == 2 or dims[:2] == (1, 2)]
         assert not by_sublayer, by_sublayer
@@ -680,12 +694,45 @@ def test_a_step_writes_no_buffer_of_a_qkv_leaf(chip, monkeypatch, name, rows,
             sizes[math.prod(tree[leaf].shape)] = f"{stack}/{leaf}"
             if leaf == "wq":
                 sizes[math.prod(tree[leaf].shape[1:])] = f"a layer of {stack}/wq"
-    assert sizes
-    found = [(sizes[math.prod(dims)], line)
-             for dims, line in _unfused_s8(compiled.as_text())
-             if math.prod(dims) in sizes]
-    assert not found, found
-    assert compiled.memory_analysis().temp_size_in_bytes < temp_mb * 1e6
+    _writes_none_of(compiled, sizes, temp_mb)
+
+
+@pytest.mark.parametrize("name,rows,temp_mb", [
+    ("longcat-flash-ep32-l6", 0, 80), ("longcat-flash-ep32-l6", 1024, 350),
+    ("kimi-k2.5-ep32-l9", 0, 30), ("kimi-k2.5-ep32-l9", 1024, 260),
+    ("gigachat3.5-432b-ep8-l5", 0, 200)])
+def test_a_step_writes_no_buffer_of_a_latent_up_projection(
+        chip, monkeypatch, name, rows, temp_mb):
+    """The step programs of the three configurations with a latent block,
+    both shapes of longcat's and kimi's, at their published widths: outside
+    its fusions the compiled program defines NO int8 buffer of a whole
+    stacked ``wq_b`` / ``wkv_b``, of one layer's or of one sublayer's, in
+    any shape or layout (by element count).  The leaves are stored as the
+    dots read them, ``[L, H, nope + rope, q_lora]`` / ``[L, H, nope + v,
+    kv_lora]`` (``tf.init_params``).  Stored ``[L, K, N]`` (until PR 57)
+    longcat's programs transposed both stacks whole in ``main``, ahead of
+    the layer loop (``copy s8[6,2,1536,12288]{2,3,1,0}`` 226 MB + ``copy
+    s8[6,2,512,16384]`` 101 MB) and wrote a layer's two ``wq_b`` out of the
+    copy again inside it (a fusion whose result is a TUPLE, ``(s8[1,1,1536,
+    12288], s8[1,1,1536,12288])``), kimi's sliced AND transposed both
+    leaves a layer, gigachat's transposed its one latent layer's: 7 % of
+    longcat's device time, temporaries of 391 / 648 MB (longcat) where 45 /
+    309 are left (kimi 2 / 211 and gigachat 51 as before: their copies
+    were a layer's, live beside nothing)."""
+    from arks_tpu.models import quant, transformer as tf
+    cfg, _, compiled = _share_step(chip, monkeypatch, name, rows)
+    shapes = jax.eval_shape(lambda k: tf.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    sizes = {}
+    for stack, tree in shapes.items():
+        if not isinstance(tree, dict):
+            continue
+        for leaf in sorted(quant.LATENT_SPLIT_KEYS & set(tree)):
+            dims = tree[leaf].shape
+            for lead in range(len(dims) - 2):   # whole, a layer, a sublayer
+                sizes.setdefault(math.prod(dims[lead:]),
+                                 f"{stack}/{leaf}{list(dims[lead:])}")
+    _writes_none_of(compiled, sizes, temp_mb)
 
 
 @pytest.mark.parametrize("name", ["mimo-v2.5-ep16-l13",
@@ -719,7 +766,10 @@ def test_a_one_row_steps_launch_is_read_back_through_no_copy(
 def test_the_scan_for_written_buffers_sees_the_copies_it_is_there_for():
     """``_unfused_s8`` on lines of the parent's compiled mimo step (PR 47):
     the whole-leaf transpose in ``main`` and the slice in the inner scan's
-    body are found, what a fused computation defines is not."""
+    body are found, what a fused computation defines is not.  And on a line
+    of longcat's (PR 56): a fusion whose result is a TUPLE of int8 arrays,
+    a layer's two ``wq_b`` written out of the transposed copy, counts once
+    a member (the scan missed such a line until PR 57)."""
     text = "\n".join([
         "%fused_computation.174 (p0: s8[10,4096,12288]) -> s8[1,4096,12288] {",
         "  %ds.1 = s8[1,4096,12288]{1,2,0:T(8,128)(4,1)} fusion(%p0), "
@@ -730,13 +780,21 @@ def test_the_scan_for_written_buffers_sees_the_copies_it_is_there_for():
         "T(8,128)(4,1)} fusion(%gte.1, %sel), kind=kLoop, "
         "calls=%fused_computation.174",
         "  %bitcast.764 = s8[64,192,4096]{2,1,0} bitcast(%x)",
+        "  %constant_dynamic-slice_fusion.18.remat2 = (s8[1,1,1536,12288]"
+        "{2,3,1,0:T(8,128)(4,1)}, s8[1,1,1536,12288]{2,3,1,0:T(8,128)(4,1)}) "
+        "fusion(%get-tuple-element.1643, %select_n.541), kind=kLoop, "
+        "calls=%fused_computation.334.clone.clone.clone.clone, metadata={"
+        "op_name=\"jit(<lambda>)/while/body/closed_call/dynamic_slice\"}",
+        "  %dot.3 = bf16[64,64,192]{2,1,0} convolution(%a, %b), metadata={"
+        "op_name=\"s8[9,9] fusion(\"}",
         "}",
         "ENTRY %main.98 (p0: s8[10,4096,12288]) -> f32[64,19072] {",
         "  %copy.534 = s8[10,4096,12288]{1,2,0:T(8,128)(4,1)} copy(%p0)",
         "  %copy-done.1 = s8[1,4,192,4096]{3,2,1,0} copy-done(%cs)",
         "}"])
     assert [dims for dims, _ in _unfused_s8(text)] == [
-        (1, 4096, 12288), (10, 4096, 12288)]
+        (1, 4096, 12288), (1, 1, 1536, 12288), (1, 1, 1536, 12288),
+        (10, 4096, 12288)]
 
 
 @pytest.mark.parametrize("rows", [0, 1024])

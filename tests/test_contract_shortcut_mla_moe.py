@@ -14,9 +14,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 pytestmark = pytest.mark.usefixtures("_registry_and_environment_restored")
 
+from benchmarks.tests import test_reference_shortcut_mla_moe as _shortcut  # noqa: E402,E501
 from benchmarks.tests.test_reference_shortcut_mla_moe import (  # noqa: E402,F401,E501
-    test_seeded_weights_are_the_programs_bit_for_bit as
-    test_shortcut_mla_moe_seeded_weights_are_the_programs_bit_for_bit,
     test_served_logprobs_against_the_reference as
     test_shortcut_mla_moe_served_logprobs_against_the_reference,
     test_the_family_keeps_the_contract_and_imports_nothing_of_the_program as
@@ -32,3 +31,8 @@ from benchmarks.tests.test_shortcut_readers import (  # noqa: E402,F401
     test_the_routed_share_sums_its_four_scopes_and_wants_the_identity_part,
     test_the_zero_pair_share_is_a_ratio_of_two_deltas,
 )
+
+
+def test_shortcut_mla_moe_seeded_weights_are_the_programs_bit_for_bit(
+        seeded_tree_as_drawn):
+    _shortcut.test_seeded_weights_are_the_programs_bit_for_bit()

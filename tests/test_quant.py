@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import harness
 from arks_tpu.models import get_config, quant
 from arks_tpu.models import transformer as tf
 from arks_tpu.parallel.mesh import make_mesh
@@ -379,3 +380,183 @@ def test_the_partition_specs_shard_the_heads_of_a_head_split_leaf():
         assert q["wo"]["q"] == P(None, "model", None)
     assert quant.quantize_pspecs({"layers": specs}, 8)["layers"]["wo"][
         "s"] == P(None, None, None)               # [L, 1, E]
+
+
+# ---------------------------------------------------------------------------
+# The stored order of the latent block's wq_b / wkv_b (PR 57)
+# ---------------------------------------------------------------------------
+
+LATENT_PRESETS = ["tiny-mla-moe", "tiny-shortcut-mla-moe",
+                  "tiny-latent-linear-moe"]
+
+
+@pytest.mark.parametrize("bits", [8, 4])
+@pytest.mark.parametrize("name", LATENT_PRESETS)
+def test_seeded_latent_up_projections_are_the_drawn_ones_stored_head_split(
+        name, bits):
+    """``init_params_quantized`` against the rule a seed means, over every
+    latent stack's attention leaves: ``wq_b`` / ``wkv_b`` are the LOGICAL
+    draws ``[.., q_lora, H x (nope + rope)]`` / ``[.., kv_lora, H x (nope +
+    v)]`` (what the reference families draw), quantised along the latent
+    and then stored ``split_heads`` (values AND scales, bit for bit: a
+    shortcut block's by sublayer, ``[L, 2, H, ..]``); ``wq_a`` / ``wkv_a``
+    / ``wo`` are the draw itself."""
+    cfg = get_config(name)
+    key = jax.random.PRNGKey(2**31 + 57)
+    got = dict(_flat(quant.init_params_quantized(cfg, key, jnp.bfloat16,
+                                                 bits=bits)))
+    h = cfg.num_heads
+    width = {"wq_b": (cfg.head_dim, cfg.q_lora_rank),
+             "wkv_b": (cfg.qk_nope_head_dim + cfg.v_head_dim,
+                       cfg.kv_lora_rank)}
+    seen = set()
+    for n, (path, leaf) in enumerate(got.items(), 1):
+        stack, _, leafname = path.rpartition("/")
+        if stack + "/wq_a" not in got:
+            continue                        # not a latent stack
+        if leafname not in ("wq_a", "wq_b", "wkv_a", "wkv_b", "wo"):
+            continue
+        shape = leaf["q"].shape
+        if leafname in width:
+            *lead, heads, d, k = shape
+            assert (heads, d, k) == (h, *width[leafname]), path
+            assert len(lead) == (2 if cfg.shortcut else 1), path
+            seen.add(path)
+            want = {m: tf.split_heads(v, h) for m, v in _drawn(
+                jax.random.fold_in(key, n), (*lead, k, h * d), bits).items()}
+        else:
+            want = _drawn(jax.random.fold_in(key, n), shape, bits)
+        assert sorted(leaf) == sorted(want)
+        for m in want:
+            assert leaf[m].shape == want[m].shape, (path, m)
+            assert np.array_equal(np.asarray(leaf[m].astype(jnp.float32)),
+                                  np.asarray(want[m].astype(jnp.float32))), (
+                path, m)
+    stacks = ["layers"] + ["dense_layers"] * (name == "tiny-mla-moe")
+    assert seen == {f"{s}/{m}" for s in stacks for m in width}
+
+
+@pytest.mark.parametrize("bits", [8, 4])
+def test_quantize_params_of_a_float_latent_tree_agrees_with_the_stored_order(
+        bits):
+    """A float tree (its up projections already stored head-split, at rank
+    4 and at the shortcut block's rank 5) quantised leaf by leaf is the
+    quantised LOGICAL leaf stored head-split; ``wq_a`` stays a plain
+    matmul."""
+    keys = iter(jax.random.split(jax.random.PRNGKey(57), 8))
+    logical = {"wq_b": jax.random.normal(next(keys), (2, 256, 4 * 24)),
+               "wkv_b": jax.random.normal(next(keys), (2, 2, 256, 4 * 8))}
+    full = {"layers": {"wq_b": tf.split_heads(logical["wq_b"], 4),
+                       "wkv_b": tf.split_heads(logical["wkv_b"], 4),
+                       "wq_a": jax.random.normal(next(keys), (2, 64, 256))}}
+    assert full["layers"]["wkv_b"].shape == (2, 2, 4, 8, 256)
+    got = quant.quantize_params(full, bits=bits)
+
+    def rule(w):
+        return (quant.quantize_tensor_int4(w) if bits == 4
+                else quant.quantize_tensor(w, axis=-2))
+
+    want = {"layers": {
+        "wq_b": {k: tf.split_heads(v, 4)
+                 for k, v in rule(logical["wq_b"]).items()},
+        "wkv_b": {k: tf.split_heads(v, 4)
+                  for k, v in rule(logical["wkv_b"]).items()},
+        "wq_a": rule(full["layers"]["wq_a"])}}
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    scale = "gs" if bits == 4 else "s"
+    assert got["layers"]["wq_b"][scale].shape == (
+        (2, 4, 24, 2) if bits == 4 else (2, 4, 24, 1))
+    assert got["layers"]["wkv_b"][scale].shape == (
+        (2, 2, 4, 8, 2) if bits == 4 else (2, 2, 4, 8, 1))
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert a.shape == b.shape
+        assert np.array_equal(np.asarray(a, np.float32),
+                              np.asarray(b, np.float32))
+
+
+@pytest.mark.parametrize("kind", ["float", "int8", "int4"])
+@pytest.mark.parametrize("name", LATENT_PRESETS)
+def test_the_latent_block_reads_its_up_projections_as_the_matmuls_they_are(
+        name, kind):
+    """``_mla_q`` / ``_mla_out`` on the stored leaves against the
+    drawn-order einsums (the form the block had until PR 57: ``cq W_qb``
+    reshaped into heads, ``q_nope W_uk^T`` and ``attn W_uv`` over ``W_kvb``
+    reshaped ``[C, H, nope + v]``), on a layer (and sublayer) taken out of
+    the stack; a quantised leaf's ``dequantize`` is the leaf in its own
+    shape."""
+    cfg = get_config(name)
+    params = tf.init_params(cfg, jax.random.PRNGKey(5), jnp.float32)
+    stack = {k: params["layers"][k] for k in (
+        "wq_a", "q_norm", "wq_b", "wkv_b", "wo") + ("wg",) * cfg.attn_out_gate}
+    if kind != "float":
+        bits = int(kind[3:])
+        stack.update(quant.quantize_params(
+            {k: stack[k] for k in ("wq_b", "wkv_b")}, bits=bits))
+    at = (1, 1) if cfg.shortcut else (1,)
+    lp = {k: jax.tree.map(lambda a: a[at], v) for k, v in stack.items()}
+    h, nope, v, c = (cfg.num_heads, cfg.qk_nope_head_dim, cfg.v_head_dim,
+                     cfg.kv_lora_rank)
+    deq = {k: quant.dequantize(lp[k], jnp.float32) for k in ("wq_b", "wkv_b")}
+    assert deq["wq_b"].shape == (h, cfg.head_dim, cfg.q_lora_rank)
+    assert deq["wkv_b"].shape == (h, nope + v, c)
+    if kind != "float":
+        for k in deq:
+            assert _rel_err(deq[k], params["layers"][k][at]) < (
+                0.15 if bits == 4 else 0.01)
+    wq_b, wkv_b = (harness.as_drawn(deq[k]) for k in ("wq_b", "wkv_b"))
+    assert wq_b.shape == (cfg.q_lora_rank, cfg.q_dim)
+    b, t = 2, 5
+    x = jax.random.normal(jax.random.PRNGKey(6), (b, t, cfg.hidden_size))
+    pos = jnp.arange(b * t, dtype=jnp.int32).reshape(b, t) * 3
+
+    cq = tf._norm(x @ lp["wq_a"], lp["q_norm"], cfg, cfg.mla_q_scale)
+    q = (cq @ wq_b).reshape(b, t, h, cfg.head_dim)
+    w = wkv_b.reshape(c, h, nope + v)
+    want_q = jnp.concatenate([
+        jnp.einsum("bthn,chn->bthc", q[..., :nope], w[..., :nope]),
+        tf.apply_rope(q[..., nope:], pos, cfg.rope_theta, cfg.rope_yarn)],
+        axis=-1)
+    got_q = tf._mla_q(x, lp, cfg, pos)
+    assert got_q.shape == want_q.shape == (b, t, h, cfg.latent_row)
+    assert _rel_err(got_q, want_q) < 1e-5
+
+    attn = jax.random.normal(jax.random.PRNGKey(7), (t, h, c))
+    o = jnp.einsum("thc,chv->thv", attn, w[..., nope:]).reshape(t, h * v)
+    if cfg.attn_out_gate:
+        o = o * jax.nn.sigmoid(x[0] @ lp["wg"])
+    got_o = tf._mla_out(attn, lp, cfg, x[0])
+    assert got_o.shape == (t, cfg.hidden_size)
+    assert _rel_err(got_o, o @ lp["wo"]) < 1e-5
+
+
+@pytest.mark.parametrize("name,ndim,axis", [
+    ("wq_b", 4, -1), ("wq_b", 5, -1), ("wq_b", 3, -1), ("wkv_b", 4, -1),
+    ("wkv_b", 5, -1), ("wq_a", 3, -2), ("wq_a", 4, -2), ("wkv_a", 4, -2),
+    ("wq", 4, -1), ("wq", 3, -2), ("wo", 3, -2), ("w_in", 3, -1)])
+def test_the_contraction_axis_of_a_leaf_by_name_and_rank(name, ndim, axis):
+    """The latent up projections are head-split at every rank (a layer's
+    slice, a stack, a stack by sublayer: they have no plain form); a GQA
+    stack's names at rank 4 alone."""
+    assert quant.contraction_axis(name, ndim) == axis
+
+
+def test_the_partition_specs_of_a_latent_up_projection_follow_its_heads():
+    """The latent block has no sharding rules (``param_pspecs`` refuses it
+    by name); what `quantize_pspecs` makes of a spec for its leaves follows
+    the stored order all the same: the heads' entry kept, the scales' entry
+    along the contraction dimension, the LAST, dropped (int8) or kept whole
+    (int4: the groups tile it)."""
+    from jax.sharding import PartitionSpec as P
+    specs = {"layers": {"wq_b": P(None, "model", None, "data"),
+                        "wkv_b": P(None, None, "model", None, "data"),
+                        "wq_a": P(None, "data", "model")}}
+    q8 = quant.quantize_pspecs(specs, 8)["layers"]
+    assert q8["wq_b"] == {"q": specs["layers"]["wq_b"],
+                          "s": P(None, "model", None, None)}
+    assert q8["wkv_b"]["s"] == P(None, None, "model", None, None)
+    assert q8["wq_a"]["s"] == P(None, None, "model")
+    q4 = quant.quantize_pspecs(specs, 4)["layers"]
+    for k, spec in specs["layers"].items():
+        assert q4[k] == {"q": spec, "gs": spec}
+    with pytest.raises(NotImplementedError, match="no sharding rules"):
+        tf.param_pspecs(get_config("tiny-mla-moe"), tp=2)

@@ -215,6 +215,19 @@ for the same seeded inputs), the older kernel suites of
 chip's compiler takes the merged block at every cell's shape; in longcat's
 compiled step the launch's output is read through a bitcast where the
 parent's step copied it out of ``T(2,128)`` tiles).
+
+PR 57 RE-TOOK the fourteen pins of the three latent presets (``tiny-mla-moe``
+at both shapes, ``tiny-latent-linear-moe``, ``tiny-shortcut-mla-moe``) and
+moved none of the twenty-four others nor the whole-layer pin.  The latent
+block's two up projections are stored head-split, contraction dimension
+minor (``wq_b`` ``[L, H, nope + rope, q_lora]``, ``wkv_b`` ``[L, H, nope + v,
+kv_lora]``; ``[L, 2, ..]`` by sublayer: ``tf.init_params``), so those
+programs take operands of other shapes and ``_mla_q`` / ``_mla_out``
+contract ``"...r,hdr->...hd"``, ``"bthn,hnc->bthc"`` and ``"thc,hvc->thv"``
+with no reshape; the arithmetic is the drawn-order einsums'
+(``tests/test_quant.py``), the seeded int8 values and scales the parent's
+bit for bit.  No other preset has either leaf, and ``quant.contraction_axis``
+answers as before for every other name.
 """
 
 import hashlib
@@ -233,16 +246,16 @@ PINS = {
     "tiny.seq_lp": "491d011c67699524",
     "tiny.pipe": "4e1c1288c0815891",
     "tiny.pipe_lp": "51ca9446ab2834d8",
-    "tiny-mla-moe.seq": "b38c58786d908443",
-    "tiny-mla-moe.seq_lp": "4e0d054764e1599f",
-    "tiny-mla-moe.pipe": "85a6cd2125ffb229",
-    "tiny-mla-moe.pipe_lp": "ff217f97a370ca70",
+    "tiny-mla-moe.seq": "02b551d7c661a2b6",
+    "tiny-mla-moe.seq_lp": "df47431e206ff0ff",
+    "tiny-mla-moe.pipe": "1858476d570bed41",
+    "tiny-mla-moe.pipe_lp": "e07d5d3f3064a040",
     "tiny-swa-moe.seq": "a74550b8ca32440a",
     "tiny-swa-moe.seq_lp": "dcd6bb16baa882d2",
     "tiny-swa-moe.pipe": "a5acbcd901fe6084",
     "tiny-swa-moe.pipe_lp": "fab7b8e5752c34a9",
-    "tiny-mla-moe@wide.seq": "67bee340629f998c",
-    "tiny-mla-moe@wide.seq_lp": "e36a454baf84097c",
+    "tiny-mla-moe@wide.seq": "8ecbddb7cc314d43",
+    "tiny-mla-moe@wide.seq_lp": "0204a6272d21c97c",
     "tiny-swa-moe@wide.seq": "d4d3888ce31c3b21",
     "tiny-swa-moe@wide.seq_lp": "2fba917a987e327a",
     "tiny-linear-moe.seq": "89d61e6062a43366",
@@ -255,18 +268,18 @@ PINS = {
     "tiny-mixtral.seq_lp": "a7fd1b110fab0779",
     "tiny-mixtral.pipe": "5e7345d839d5dd71",
     "tiny-mixtral.pipe_lp": "76e8e6dcde5eb955",
-    "tiny-latent-linear-moe.seq": "5976443c5b63dce0",
-    "tiny-latent-linear-moe.seq_lp": "0c7d6b61622cde62",
-    "tiny-latent-linear-moe.pipe": "8b8e6456119c2122",
-    "tiny-latent-linear-moe.pipe_lp": "6d5c51de4ebb50c8",
+    "tiny-latent-linear-moe.seq": "b478f06241fb7d00",
+    "tiny-latent-linear-moe.seq_lp": "0e6ebb12f767c546",
+    "tiny-latent-linear-moe.pipe": "86391cc2f4792e14",
+    "tiny-latent-linear-moe.pipe_lp": "6f96aa001355e98b",
     "tiny-swa-sink-moe.seq": "cab321671b8a404a",
     "tiny-swa-sink-moe.seq_lp": "7db0abc59856f3c6",
     "tiny-swa-sink-moe.pipe": "ad254d3741195bb9",
     "tiny-swa-sink-moe.pipe_lp": "7616bbc242b77010",
-    "tiny-shortcut-mla-moe.seq": "36dbbfa823fa9cd2",
-    "tiny-shortcut-mla-moe.seq_lp": "91cc2ca9e2991c85",
-    "tiny-shortcut-mla-moe.pipe": "45cc309201ad916f",
-    "tiny-shortcut-mla-moe.pipe_lp": "7b8d25aeda014625",
+    "tiny-shortcut-mla-moe.seq": "28aaa8e3f530f3d8",
+    "tiny-shortcut-mla-moe.seq_lp": "1f3ca9ef4f701287",
+    "tiny-shortcut-mla-moe.pipe": "2ebd0240d9c751ed",
+    "tiny-shortcut-mla-moe.pipe_lp": "ff40a30bcdcf22d7",
 }
 
 

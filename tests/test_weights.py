@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import pytest
 from safetensors.numpy import save_file
 
+import harness
 from arks_tpu.models import get_config
 from arks_tpu.models import transformer as tf
 from arks_tpu.models import weights as w
@@ -122,6 +123,28 @@ def test_an_orbax_checkpoint_in_another_stored_order_is_refused_by_leaf(
     w.save_orbax(old, str(tmp_path))
     with pytest.raises(ValueError, match=r"wq.*convert the checkpoint"):
         w.load_orbax(cfg, str(tmp_path), None, jnp.float32)
+
+
+@pytest.mark.parametrize("leaf", ["wq_b", "wkv_b"])
+@pytest.mark.parametrize("name", ["tiny-mla-moe", "tiny-shortcut-mla-moe"])
+def test_an_orbax_checkpoint_of_drawn_order_latent_leaves_is_refused_by_leaf(
+        tmp_path, name, leaf):
+    """The latent block's up projections were ``[L, K, H x D]`` until they
+    became ``[L, H, D, K]`` (PR 57; ``[L, 2, ..]`` by sublayer in the
+    shortcut block): a tree saved the old way is refused by that leaf's
+    name, a tree saved as stored comes back."""
+    cfg = get_config(name)
+    params = tf.init_params(cfg, jax.random.PRNGKey(3), jnp.float32)
+    new = params["layers"][leaf]
+    old = dict(params, layers=dict(params["layers"],
+                                   **{leaf: harness.as_drawn(new)}))
+    w.save_orbax(old, str(tmp_path / "old"))
+    with pytest.raises(ValueError,
+                       match=rf"'{leaf}'.*convert the checkpoint"):
+        w.load_orbax(cfg, str(tmp_path / "old"), None, jnp.float32)
+    w.save_orbax(params, str(tmp_path / "new"))
+    back = w.load_orbax(cfg, str(tmp_path / "new"), None, jnp.float32)
+    assert np.array_equal(np.asarray(back["layers"][leaf]), np.asarray(new))
 
 
 def test_orbax_roundtrip_sharded(tmp_path):
